@@ -463,6 +463,11 @@ def run_compression_bench(
 
 
 def main() -> None:
+    from flink_parameter_server_tpu.utils.compile_cache import (
+        enable_compile_cache,
+    )
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--rounds", type=int, default=40)
     ap.add_argument("--out", default=None)

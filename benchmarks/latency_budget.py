@@ -138,6 +138,11 @@ def run_budget_bench(
 
 
 def main() -> None:
+    from flink_parameter_server_tpu.utils.compile_cache import (
+        enable_compile_cache,
+    )
+
+    enable_compile_cache()
     p = argparse.ArgumentParser()
     p.add_argument("--rounds", type=int, default=60)
     p.add_argument("--batch", type=int, default=512)

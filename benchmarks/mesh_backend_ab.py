@@ -29,9 +29,8 @@ The verdict paragraph is REPORTED, not gated: on this CPU host the
 system — collective routing is a memcpy, not an ICI hop — while the
 socket arm pays real process boundaries.  The number that transfers
 to TPU is the SHAPE of the win (no serialize/parse/frame in the inner
-loop), not its magnitude; the parked battery job in
-``benchmarks/tpu_day1.py`` prices the real thing in the first TPU
-window.
+loop), not its magnitude; the real thing has not been measured on a
+chip (ROADMAP S7).
 
 Artifacts: ``results/cpu/mesh_backend_ab.{md,json}`` — the JSON
 carries ``ts``/``run_id``, the ``mesh_ab`` section
@@ -314,8 +313,8 @@ ICI, and the costs this backend deletes — frame encode/parse, host
 copies, the per-row codec — are exactly the residual PR 16 measured
 as unremovable from the socket path.  So the number that transfers
 is the parity column and the SHAPE of the cost model, not the
-multiple; the battery job parked in `benchmarks/tpu_day1.py` prices
-the real thing (HBM table, ICI collectives) in the first TPU window.
+multiple; the real thing (HBM table, ICI collectives) has not been
+measured on a chip (ROADMAP S7).
 
 Produced by `benchmarks/mesh_backend_ab.py` on a {os.cpu_count()}-CPU
 host; linted by `tools/check_metric_lines.py --mesh-ab`; folded into
@@ -327,6 +326,11 @@ by tests/test_meshstore.py (committed-artifact lint).
 
 
 def main() -> int:
+    from flink_parameter_server_tpu.utils.compile_cache import (
+        enable_compile_cache,
+    )
+
+    enable_compile_cache()
     p = argparse.ArgumentParser()
     p.add_argument("--rounds", type=int, default=30)
     p.add_argument("--items", type=int, default=256)

@@ -128,6 +128,11 @@ def _render_md(r: Dict) -> str:
 
 
 def main() -> None:
+    from flink_parameter_server_tpu.utils.compile_cache import (
+        enable_compile_cache,
+    )
+
+    enable_compile_cache()
     r = run_nemesis_bench()
     out_dir = os.path.join(REPO, "results", r["platform"])
     os.makedirs(out_dir, exist_ok=True)

@@ -470,6 +470,11 @@ tests/test_adaptive.py (committed-artifact lint).
 
 
 def main() -> int:
+    from flink_parameter_server_tpu.utils.compile_cache import (
+        enable_compile_cache,
+    )
+
+    enable_compile_cache()
     p = argparse.ArgumentParser()
     p.add_argument("--deadline", type=float, default=4.0)
     p.add_argument("--lag-ms", type=float, default=25.0)

@@ -215,6 +215,11 @@ def run_overhead_bench(
 
 
 def main() -> None:
+    from flink_parameter_server_tpu.utils.compile_cache import (
+        enable_compile_cache,
+    )
+
+    enable_compile_cache()
     p = argparse.ArgumentParser()
     p.add_argument("--steps", type=int, default=200)
     p.add_argument("--reps", type=int, default=3)

@@ -374,6 +374,11 @@ def _fmt_mb(b) -> str:
 
 
 def main() -> int:
+    from flink_parameter_server_tpu.utils.compile_cache import (
+        enable_compile_cache,
+    )
+
+    enable_compile_cache()
     p = argparse.ArgumentParser()
     p.add_argument("--arm", choices=("tiered", "dense"), default=None,
                    help="internal: run ONE perf arm and print its JSON")

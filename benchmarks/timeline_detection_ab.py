@@ -306,6 +306,11 @@ tests/test_timeline.py (committed-artifact lint).
 
 
 def main() -> int:
+    from flink_parameter_server_tpu.utils.compile_cache import (
+        enable_compile_cache,
+    )
+
+    enable_compile_cache()
     p = argparse.ArgumentParser()
     p.add_argument("--interval", type=float, default=0.05)
     p.add_argument("--out", default=os.path.join(REPO, "results", "cpu"))

@@ -490,6 +490,11 @@ acceptance test (tests/test_transport.py).
 
 
 def main() -> int:
+    from flink_parameter_server_tpu.utils.compile_cache import (
+        enable_compile_cache,
+    )
+
+    enable_compile_cache()
     p = argparse.ArgumentParser()
     p.add_argument("--rounds", type=int, default=120)
     p.add_argument("--items", type=int, default=2_048)

@@ -46,6 +46,11 @@ def live_bytes_per_device(tree, device):
 
 
 def main(argv=None):
+    from flink_parameter_server_tpu.utils.compile_cache import (
+        enable_compile_cache,
+    )
+
+    enable_compile_cache()
     import jax
     import jax.numpy as jnp
     import optax

@@ -1,0 +1,872 @@
+#!/usr/bin/env python3
+"""Does the system still start on the chip?  One process, a few minutes.
+
+Drives the main path once through the entry points a user calls — online
+matrix factorisation: ``OnlineMatrixFactorization`` +
+``ShardedParamStore.create`` + ``StreamingDriver.run`` — at the full width
+``bench.py`` uses on a TPU (100,000 users x 131,072 items, dim 64,
+bfloat16 table, batch 65,536, Zipf(1.2) items, seed 0), then compiles every
+Pallas kernel the public API can reach and compares each with its XLA
+reference.  With four or more devices stage 1 runs on a dp=2 x ps=2 mesh,
+placement is asserted and a float32 leg compares the mesh with one device.
+
+It measures nothing: set-up seconds and step milliseconds are printed so a
+reader can see that programs compiled and ran, under no metric's name.
+
+Exit codes: 0 every stage passed; 2 no TPU and no ``--cpu-dry-run``; 1 (a
+traceback) a stage failed.  The last stdout line of a chip run is exactly
+``{"ok": <bool>, "device": {"platform": ..., "kind": ..., "count": ...}}``
+with the device as JAX reports it; the line before it is the fuller
+summary (stages, wall seconds, ``"claim": null``).
+
+``--cpu-dry-run`` is the only way to run it off the chip: every size
+shrinks, kernels run with ``interpret=True``, and the last line is the
+summary, which says the run proves control flow only — a dry run never
+prints the chip run's result line.
+"""
+from __future__ import annotations
+
+import argparse
+import dataclasses
+import functools
+import io
+import itertools
+import json
+import os
+import re
+import shutil
+import sys
+import time
+import traceback
+import warnings
+
+import numpy as np
+
+REPO = os.path.dirname(os.path.abspath(__file__))
+DRY_RUN_NOTE = "cpu dry run: proves control flow only, nothing about the chip"
+
+
+class SmokeFailure(AssertionError):
+    """A stage's check did not hold."""
+
+
+def result_line(ok: bool, device: dict) -> str:
+    """The chip run's last stdout line: these keys and no others."""
+    return json.dumps({
+        "ok": bool(ok),
+        "device": {
+            "platform": str(device["platform"]),
+            "kind": str(device["kind"]),
+            "count": int(device["count"]),
+        },
+    })
+
+
+def require(cond, message: str) -> None:
+    if not cond:
+        raise SmokeFailure(message)
+
+
+@dataclasses.dataclass(frozen=True)
+class Sizes:
+    num_users: int
+    num_items: int
+    dim: int
+    batch: int
+    distinct_batches: int  # the stream cycles through this many
+    single_steps: int  # dispatches at steps_per_call=1
+    scan_k: int  # steps per scanned dispatch
+    parity_steps: int
+    kernel_lanes: int
+    flash_seq: int
+
+
+FULL = Sizes(
+    num_users=100_000, num_items=131_072, dim=64, batch=65_536,
+    distinct_batches=4, single_steps=20, scan_k=4, parity_steps=5,
+    kernel_lanes=4096, flash_seq=512,
+)
+DRY = Sizes(
+    num_users=512, num_items=1024, dim=64, batch=1024,
+    distinct_batches=2, single_steps=16, scan_k=2, parity_steps=2,
+    kernel_lanes=64, flash_seq=128,
+)
+# lr and init scale chosen so plain SGD neither stalls at the saddle nor
+# diverges on the Zipf-hot items at batch 65,536 (item 0 takes ~18% of a
+# batch, all summed from one snapshot)
+LEARNING_RATE = 0.02
+INIT_SCALE = 0.1
+# the first scanned dispatch compiles the program, the second still
+# compiles host-side slices of its stacked outputs; the third is steady
+SCAN_DISPATCHES = 3
+
+
+class Smoke:
+    def __init__(self, dry_run: bool, out_dir: str):
+        import jax
+
+        self.jax = jax
+        self.dry_run = dry_run
+        self.sizes = DRY if dry_run else FULL
+        self.out_dir = out_dir
+        # kernels choose interpret mode themselves off the chip; the dry
+        # run says so explicitly, the chip run takes the normal path
+        self.interpret = True if dry_run else None
+        devices = jax.devices()
+        self.device = {
+            "platform": devices[0].platform,
+            "kind": devices[0].device_kind,
+            "count": len(devices),
+        }
+        self.mesh = None
+        if len(devices) >= 4:
+            from flink_parameter_server_tpu.parallel.mesh import make_mesh
+
+            self.mesh = make_mesh(
+                worker_parallelism=2, ps_parallelism=2, devices=devices[:4]
+            )
+        self.stages = {}
+
+    def report(self, stage: str, **fields) -> None:
+        """One line per result, each naming the device it ran on."""
+        self.stages[stage] = fields
+        d = self.device
+        body = " ".join(f"{k}={json.dumps(v)}" for k, v in fields.items())
+        print(
+            f"[{stage}] ok platform: {d['platform']} "
+            f"device_kind: {d['kind']!r} devices: {d['count']} {body}",
+            flush=True,
+        )
+
+    # -- stage 1: the main path -------------------------------------------
+    def _mf_parts(self, dtype, mesh):
+        from flink_parameter_server_tpu import ShardedParamStore
+        from flink_parameter_server_tpu.models.matrix_factorization import (
+            OnlineMatrixFactorization,
+            SGDUpdater,
+        )
+        from flink_parameter_server_tpu.utils.initializers import normal_factor
+
+        s = self.sizes
+        logic = OnlineMatrixFactorization(
+            s.num_users, s.dim, updater=SGDUpdater(LEARNING_RATE),
+            dtype=dtype, mesh=mesh,
+            init_low=-INIT_SCALE, init_high=INIT_SCALE,
+        )
+        store = ShardedParamStore.create(
+            s.num_items, (s.dim,), dtype=dtype, mesh=mesh,
+            init_fn=normal_factor(
+                1, (s.dim,), stddev=INIT_SCALE, dtype=dtype
+            ),
+        )
+        return logic, store
+
+    @functools.cached_property
+    def _ratings(self):
+        from flink_parameter_server_tpu.data.movielens import synthetic_ratings
+
+        s = self.sizes
+        return synthetic_ratings(
+            s.num_users, s.num_items, s.distinct_batches * s.batch,
+            zipf_a=1.2, seed=0,
+        )
+
+    def _stream(self, n_batches: int):
+        """The first ``n_batches`` of one logical stream (re-fed from the
+        start after a resume: the driver's cursor skips what it consumed)."""
+        from flink_parameter_server_tpu.data.streams import microbatches
+
+        s = self.sizes
+        epochs = -(-n_batches // s.distinct_batches)
+        return itertools.islice(
+            microbatches(self._ratings, s.batch, epochs=epochs), n_batches
+        )
+
+    def _assert_placement(self, table, state, spec) -> dict:
+        """Table rows split over ``ps`` on four devices, worker state over
+        ``dp`` — not four whole copies."""
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        mesh = self.mesh
+        shards = table.addressable_shards
+        devices = {sh.device for sh in shards}
+        require(len(devices) == 4, f"table on {len(devices)} devices, not 4")
+        rows = {sh.data.shape[0] for sh in shards}
+        require(
+            rows == {spec.rows_per_shard},
+            f"table shards hold {rows} rows, expected "
+            f"{{{spec.rows_per_shard}}} of {table.shape[0]}",
+        )
+        require(
+            table.sharding.is_equivalent_to(
+                NamedSharding(mesh, P("ps", None)), table.ndim
+            ),
+            f"table sharding {table.sharding}",
+        )
+        require(
+            state.sharding.is_equivalent_to(
+                NamedSharding(mesh, P("dp", None)), state.ndim
+            ),
+            f"worker state sharding {state.sharding}",
+        )
+        state_rows = {sh.data.shape[0] for sh in state.addressable_shards}
+        require(
+            state_rows == {state.shape[0] // 2},
+            f"worker state shards hold {state_rows} rows",
+        )
+        return {
+            "table_devices": sorted(str(d) for d in devices),
+            "rows_per_shard": spec.rows_per_shard,
+            "table_rows": table.shape[0],
+            "state_rows_per_shard": state.shape[0] // 2,
+        }
+
+    def _hlo_collectives(self, logic, store, out_name: str) -> dict:
+        """Read the compiled single step's HLO once: which collectives the
+        GSPMD-partitioned pull/push became, and whether any all-gather
+        rebuilds the whole table.  A finding, not a check."""
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        from flink_parameter_server_tpu.core.transform import make_train_step
+
+        jax, s = self.jax, self.sizes
+        sh = NamedSharding(self.mesh, P("dp"))
+        batch = {
+            "user": jax.ShapeDtypeStruct((s.batch,), np.int32, sharding=sh),
+            "item": jax.ShapeDtypeStruct((s.batch,), np.int32, sharding=sh),
+            "rating": jax.ShapeDtypeStruct(
+                (s.batch,), np.float32, sharding=sh
+            ),
+            "mask": jax.ShapeDtypeStruct((s.batch,), np.bool_, sharding=sh),
+        }
+        state = logic.init_state(jax.random.PRNGKey(0))
+        text = (
+            jax.jit(make_train_step(logic, store.spec), donate_argnums=(0, 1))
+            .lower(store.table, state, batch)
+            .compile()
+            .as_text()
+        )
+        pattern = re.compile(
+            r"= (?P<shape>.+?) (?P<op>all-gather|all-reduce|all-to-all|"
+            r"reduce-scatter|collective-permute)(?:-start)?\("
+        )
+        ops = []
+        for line in text.splitlines():
+            m = pattern.search(line)
+            if m:
+                name = re.search(r'op_name="([^"]*)"', line)
+                ops.append((
+                    m["op"], m["shape"], name.group(1) if name else ""
+                ))
+        with open(os.path.join(self.out_dir, out_name), "w") as f:
+            f.write(text)
+        full = f"[{store.table.shape[0]},{store.table.shape[1]}]"
+        shard = f"[{store.spec.rows_per_shard},{store.table.shape[1]}]"
+        return {
+            "collectives": sorted(
+                {f"{op} {shape} <- {name}" for op, shape, name in ops}
+            ),
+            "all_gathers_full_table": any(
+                op == "all-gather" and full in shape for op, shape, _ in ops
+            ),
+            # the push as GSPMD partitions it: every dp replica scatters
+            # into its copy of the ps shard, then the copies are summed
+            "all_reduces_table_shard": any(
+                op == "all-reduce" and shard in shape and "scatter" in name
+                for op, shape, name in ops
+            ),
+            "hlo_file": out_name,
+        }
+
+    def stage_main_path(self) -> None:
+        import jax.numpy as jnp
+
+        from flink_parameter_server_tpu import DriverConfig, StreamingDriver
+
+        jax, s, mesh = self.jax, self.sizes, self.mesh
+        dtype = jnp.bfloat16
+        ckpt_dir = os.path.join(self.out_dir, "ckpt")
+        shutil.rmtree(ckpt_dir, ignore_errors=True)  # this run's own scratch
+        n_total = s.single_steps + SCAN_DISPATCHES * s.scan_k
+        metric_sink = io.StringIO()
+
+        def run(driver, stream):
+            """driver.run with per-dispatch wall times from a group hook
+            (outs are ready when it fires: metrics_every syncs dispatches)."""
+            marks, out_sharding = [], []
+
+            def hook(global_step, n_steps, table, state, outs):
+                marks.append((time.perf_counter(), n_steps))
+                out_sharding[:] = [outs["prediction"].sharding]
+
+            driver.add_group_hook(hook)
+            t0 = time.perf_counter()
+            result = driver.run(stream, collect_outputs=True)
+            times = [t for t, _ in marks]
+            setup_s = times[0] - t0
+            per_step_ms = [
+                (b - a) * 1e3 / n
+                for a, b, (_, n) in zip(times, times[1:], marks[1:])
+            ]
+            return result, marks, setup_s, per_step_ms, out_sharding[0]
+
+        def rmse_per_step(result):
+            outs = result.worker_outputs[:-1]  # last entry: finish() dump
+            return [
+                float(np.sqrt(np.mean(
+                    np.square(np.asarray(o["error"], np.float32))
+                )))
+                for o in outs
+            ]
+
+        # ~twenty steps, one dispatch each, close-time checkpoint
+        logic, store = self._mf_parts(dtype, mesh)
+        table0 = np.asarray(store.values())
+        first = StreamingDriver(
+            logic, store,
+            config=DriverConfig(
+                checkpoint_dir=ckpt_dir, metrics_every=5, nan_check_every=5,
+                steps_per_call=1,
+            ),
+            metrics_sink=metric_sink,
+        )
+        res1, marks1, setup1, ms1, pred_sharding = run(
+            first, self._stream(s.single_steps)
+        )
+        require(
+            [n for _, n in marks1] == [1] * s.single_steps,
+            f"single-step dispatches: {[n for _, n in marks1]}",
+        )
+        require(first.step_idx == s.single_steps, f"step {first.step_idx}")
+        table1 = np.asarray(first.store.table)
+        state1 = np.asarray(res1.worker_state)
+        require(
+            np.isfinite(table1.astype(np.float32)).all(), "table not finite"
+        )
+        require(
+            not np.array_equal(
+                np.asarray(first.store.values()), table0
+            ),
+            "table unchanged by training",
+        )
+        placement = None
+        if mesh is not None:
+            placement = self._assert_placement(
+                first.store.table, res1.worker_state, store.spec
+            )
+            from jax.sharding import NamedSharding, PartitionSpec as P
+
+            require(
+                pred_sharding.is_equivalent_to(
+                    NamedSharding(mesh, P("dp")), 1
+                ),
+                f"per-record outputs not dp-sharded: {pred_sharding}",
+            )
+
+        # a fresh driver resumes: same step, bitwise-equal table and state
+        logic2, store2 = self._mf_parts(dtype, mesh)
+        second = StreamingDriver(
+            logic2, store2,
+            config=DriverConfig(
+                checkpoint_dir=ckpt_dir, metrics_every=1, nan_check_every=1,
+                steps_per_call=s.scan_k,
+            ),
+            metrics_sink=metric_sink,
+        )
+        require(second.resume(), "no checkpoint to resume from")
+        require(
+            second.step_idx == s.single_steps,
+            f"resumed at step {second.step_idx}, saved {s.single_steps}",
+        )
+        require(
+            np.asarray(second.store.table).tobytes() == table1.tobytes(),
+            "resumed table differs bitwise",
+        )
+        require(
+            np.asarray(second._state).tobytes() == state1.tobytes(),
+            "resumed worker state differs bitwise",
+        )
+        if mesh is not None:
+            self._assert_placement(
+                second.store.table, second._state, store2.spec
+            )
+
+        # the same logical stream again: the cursor skips what was
+        # consumed, then the scanned program — serving attached, so each
+        # dispatch publishes a snapshot
+        service = second.serve_with(publish_every=1)
+        try:
+            res2, marks2, setup2, ms2, _ = run(second, self._stream(n_total))
+            require(
+                [n for _, n in marks2] == [s.scan_k] * SCAN_DISPATCHES,
+                f"scanned dispatches: {[n for _, n in marks2]}",
+            )
+            require(second.step_idx == n_total, f"step {second.step_idx}")
+            if mesh is not None:
+                self._assert_placement(
+                    second.store.table, res2.worker_state, store2.spec
+                )
+            table2 = np.asarray(second.store.values()).astype(np.float32)
+            users2 = np.asarray(res2.worker_state).astype(np.float32)
+            require(np.isfinite(table2).all(), "table not finite after scan")
+            require(
+                not np.array_equal(
+                    table2, table1[: s.num_items].astype(np.float32)
+                ),
+                "table unchanged by the scanned dispatches",
+            )
+            rmse = rmse_per_step(res1) + rmse_per_step(res2)
+            require(len(rmse) == n_total, f"{len(rmse)} step outputs")
+            head, tail = np.mean(rmse[:5]), np.mean(rmse[-5:])
+            require(
+                tail < 0.9 * head,
+                f"training RMSE did not fall: first5 {head:.4f} "
+                f"last5 {tail:.4f}",
+            )
+
+            # three top-K answers from the final snapshot
+            client = service.client()
+            k = 10
+            answers = []
+            for user in (0, 1, s.num_users - 1):
+                ans = client.top_k(user, k=k, timeout=600.0)
+                ids = np.asarray(ans.item_ids)
+                scores = np.asarray(ans.scores, np.float32)
+                require(ids.shape == (k,), f"top-k shape {ids.shape}")
+                require(
+                    ((ids >= 0) & (ids < s.num_items)).all()
+                    and len(set(ids.tolist())) == k,
+                    f"top-k ids out of range or repeated: {ids}",
+                )
+                require(np.isfinite(scores).all(), f"top-k scores {scores}")
+                require(ans.train_step == n_total, f"answer from step "
+                        f"{ans.train_step}")
+                ref = np.sort(table2 @ users2[user])[::-1][:k]
+                require(
+                    np.allclose(scores, ref, rtol=2e-2, atol=2e-3),
+                    f"top-k scores {scores} vs reference {ref}",
+                )
+                answers.append(ids.tolist())
+        finally:
+            service.stop()
+        shutil.rmtree(ckpt_dir, ignore_errors=True)  # ~30 MB; checked above
+
+        self.report(
+            "main_path",
+            users=s.num_users, items=s.num_items, dim=s.dim, batch=s.batch,
+            table_dtype="bfloat16",
+            mesh=None if mesh is None else dict(mesh.shape),
+            single_step_setup_s=round(setup1, 3),
+            single_step_ms=round(float(np.median(ms1)), 3),
+            scan_k=s.scan_k,
+            scan_setup_s=round(setup2, 3),
+            scan_step_ms=round(ms2[-1], 3),
+            steps=n_total, resumed_at=s.single_steps,
+            rmse_first5=round(float(head), 4),
+            rmse_last5=round(float(tail), 4),
+            metric_lines=len(metric_sink.getvalue().splitlines()),
+            topk=answers,
+            placement=placement,
+        )
+
+    # -- stage 2: four chips ------------------------------------------------
+    def stage_mesh(self) -> None:
+        """float32: the same batches on one device and on the dp x ps
+        mesh agree (compared as __graft_entry__.dryrun_multichip does);
+        then the HLO finding."""
+        import jax.numpy as jnp
+
+        from flink_parameter_server_tpu import DriverConfig, StreamingDriver
+
+        s = self.sizes
+
+        def train(mesh):
+            logic, store = self._mf_parts(jnp.float32, mesh)
+            driver = StreamingDriver(
+                logic, store, config=DriverConfig(nan_check_every=1)
+            )
+            res = driver.run(
+                self._stream(s.parity_steps), collect_outputs=True
+            )
+            preds = np.stack([
+                np.asarray(o["prediction"]) for o in res.worker_outputs[:-1]
+            ])
+            return (
+                np.asarray(driver.store.values()),
+                np.asarray(res.worker_state), preds,
+            )
+
+        t0 = time.perf_counter()
+        got = train(self.mesh)
+        want = train(None)
+        for name, a, b in zip(("table", "worker state", "predictions"),
+                              got, want):
+            np.testing.assert_allclose(
+                a, b, rtol=2e-4, atol=2e-5,
+                err_msg=f"mesh {name} diverges from one device",
+            )
+        logic, store = self._mf_parts(jnp.bfloat16, self.mesh)
+        hlo = self._hlo_collectives(logic, store, "mf_step_mesh.hlo.txt")
+        self.report(
+            "mesh_parity",
+            mesh=dict(self.mesh.shape), steps=s.parity_steps,
+            dtype="float32", rtol=2e-4, atol=2e-5,
+            wall_s=round(time.perf_counter() - t0, 3), **hlo,
+        )
+
+    # -- stage 3: kernels ---------------------------------------------------
+    def _kernel_case(self, name, fn, args, check, *, mosaic=True) -> None:
+        """Compile ``fn`` once, require a Mosaic call in its lowering (on
+        the chip), run it, and hand the result to ``check``.  The store's
+        fallback counter must not move: a pass on the XLA scatter would
+        prove nothing about the kernel."""
+        from flink_parameter_server_tpu.core.store import pallas_fallback_count
+
+        jax = self.jax
+        before = pallas_fallback_count()
+        jitted = jax.jit(fn)
+        t0 = time.perf_counter()
+        if mosaic and not self.dry_run:
+            require(
+                "tpu_custom_call" in jitted.lower(*args).as_text(),
+                f"{name}: no Mosaic call in the lowered module",
+            )
+        got = jax.block_until_ready(jitted(*args))
+        setup_s = time.perf_counter() - t0
+        err = check(got)
+        require(
+            pallas_fallback_count() == before,
+            f"{name}: fell back to the XLA scatter",
+        )
+        self.report(
+            f"kernel:{name}", max_abs_err=float(f"{err:.3e}"),
+            setup_s=round(setup_s, 3),
+            mosaic=bool(mosaic and not self.dry_run),
+        )
+
+    @staticmethod
+    def _close(want, tol):
+        """check(got): max |got - want| over the pytree, required <= tol."""
+        import jax
+
+        def check(got):
+            errs = jax.tree.leaves(jax.tree.map(
+                lambda g, w: float(np.max(np.abs(
+                    np.asarray(g, np.float32) - np.asarray(w, np.float32)
+                ))),
+                got, want,
+            ))
+            err = max(errs)
+            require(err <= tol, f"max abs err {err:.3e} > {tol}")
+            return err
+
+        return check
+
+    def stage_kernels(self) -> None:
+        import jax.numpy as jnp
+
+        from flink_parameter_server_tpu import ShardedParamStore
+        from flink_parameter_server_tpu.models.transformer import (
+            TransformerConfig,
+            init_params,
+            lm_loss,
+        )
+        from flink_parameter_server_tpu.ops import packed as pk
+        from flink_parameter_server_tpu.ops import pallas_scatter
+        from flink_parameter_server_tpu.ops.flash_attention import (
+            flash_mha,
+            flash_mha_dp,
+        )
+        from flink_parameter_server_tpu.ops.pallas_mf import (
+            fused_mf_sgd_sharded,
+            make_fused_mf_train_step,
+        )
+        from flink_parameter_server_tpu.ops.pallas_scatter import WINDOW
+        from flink_parameter_server_tpu.ops.sorted_scatter import (
+            sorted_dedup_scatter_add,
+        )
+        from flink_parameter_server_tpu.parallel.mesh import make_mesh
+        from flink_parameter_server_tpu.parallel.ring_attention import (
+            reference_attention,
+        )
+
+        jax, interpret, close = self.jax, self.interpret, self._close
+        rng = np.random.default_rng(0)
+        n = self.sizes.kernel_lanes
+
+        def zipf_ids(cap):
+            return jnp.asarray(rng.zipf(1.3, size=n) % cap, jnp.int32)
+
+        def normal(shape, scale=1.0, dtype=jnp.float32):
+            return jnp.asarray(rng.normal(size=shape) * scale, dtype)
+
+        # ops/pallas_scatter.scatter_add, dense rows of 128 lanes
+        cap, d = 1024, 128
+        table, ids, deltas = normal((cap, d)), zipf_ids(cap), normal((n, d))
+        want = table.at[ids].add(deltas)
+        self._kernel_case(
+            "scatter_dense_d128_f32",
+            lambda t, i, dl: pallas_scatter.scatter_add(
+                t, i, dl, interpret=interpret),
+            (table, ids, deltas), close(want, 1e-3),
+        )
+        # the pure-XLA dedup arm on the same lanes: its unique_indices /
+        # indices_are_sorted promises must hold compiled, too
+        self._kernel_case(
+            "scatter_xla_sorted_d128_f32", sorted_dedup_scatter_add,
+            (table, ids, deltas), close(want, 1e-3), mosaic=False,
+        )
+        order = jnp.argsort(ids)
+        ids_asc, deltas_asc = ids[order], deltas[order]
+        self._kernel_case(
+            "scatter_xla_sorted_presorted_d128_f32",
+            lambda t, i, dl: sorted_dedup_scatter_add(
+                t, i, dl, ids_sorted=True),
+            (table, ids_asc, deltas_asc),
+            close(table.at[ids_asc].add(deltas_asc), 1e-3), mosaic=False,
+        )
+
+        # bfloat16 table: the kernel sums a window in f32 and rounds once,
+        # XLA rounds per add — judge both against the f32 oracle
+        table16, deltas16 = (
+            table.astype(jnp.bfloat16), deltas.astype(jnp.bfloat16)
+        )
+        oracle = table16.astype(jnp.float32).at[ids].add(
+            deltas16.astype(jnp.float32)
+        )
+        err_xla = float(jnp.max(jnp.abs(
+            table16.at[ids].add(deltas16).astype(jnp.float32) - oracle
+        )))
+
+        def check_bf16(got):
+            err = float(jnp.max(jnp.abs(got.astype(jnp.float32) - oracle)))
+            require(
+                err <= err_xla * 1.05 + 1e-3,
+                f"kernel vs f32 {err:.3e} worse than XLA's {err_xla:.3e}",
+            )
+            return err
+
+        self._kernel_case(
+            "scatter_dense_d128_bf16",
+            lambda t, i, dl: pallas_scatter.scatter_add(
+                t, i, dl, interpret=interpret),
+            (table16, ids, deltas16), check_bf16,
+        )
+
+        # lane-packed tables: MF's dim 64 (sub_k=2), FM's dim 16 (sub_k=8)
+        for dl_w in (64, 16):
+            capL = 1000
+            vals = normal((capL, dl_w))
+            nphys = -(-pk.phys_rows(capL, dl_w) // WINDOW) * WINDOW
+            idsL, deltasL = zipf_ids(capL), normal((n, dl_w))
+            wantL = vals.at[idsL].add(deltasL)
+            self._kernel_case(
+                f"scatter_packed_d{dl_w}_sub_k{pk.pack_k(dl_w)}_f32",
+                lambda t, i, dl, w=dl_w: pk.unpack_table(
+                    pallas_scatter.scatter_add(
+                        t, i, dl, interpret=interpret,
+                        sub_k=pk.pack_k(w), sub_width=w),
+                    capL, w),
+                (pk.pack_table(vals, nphys), idsL, deltasL),
+                close(wantL, 1e-3),
+            )
+
+        # the store-level selection: StoreSpec(scatter_impl="pallas",
+        # layout="packed") at dim 64 through ShardedParamStore.push
+        def store_case(name, mesh):
+            cap_s, dim_s = 4096, 64
+            init = normal((cap_s, dim_s), 0.1)
+            ids_s, deltas_s = zipf_ids(cap_s), normal((n, dim_s))
+            store = ShardedParamStore.from_values(
+                init, scatter_impl="pallas", layout="packed", mesh=mesh
+            )
+            require(store.spec.layout == "packed", store.spec.layout)
+            self._kernel_case(
+                name,
+                lambda t, i, dl: ShardedParamStore(store.spec, t)
+                .push(i, dl).values(),
+                (store.table, ids_s, deltas_s),
+                close(init.at[ids_s].add(deltas_s), 1e-3),
+            )
+
+        store_case("store_push_pallas_packed_d64", None)
+        if self.mesh is not None:
+            store_case("store_push_pallas_packed_d64_dp2xps2", self.mesh)
+
+        # ops/pallas_mf through make_fused_mf_train_step, dense and packed
+        lr = 0.05
+        users = jnp.asarray(rng.integers(0, 512, n), jnp.int32)
+        ratings = normal((n,))
+
+        def mf_reference(u_tab, i_tab, items):
+            q, p = i_tab[items], u_tab[users]
+            pred = jnp.sum(p * q, axis=1)
+            e = lr * (ratings - pred)
+            return (
+                i_tab.at[items].add(e[:, None] * p),
+                u_tab.at[users].add(e[:, None] * q),
+                pred,
+            )
+
+        def fused_case(name, dim_f, cap_f, layout):
+            u_tab, i_tab = normal((512, dim_f), 0.1), normal((cap_f, dim_f), 0.1)
+            items = zipf_ids(cap_f)
+            store = ShardedParamStore.from_values(i_tab, layout=layout)
+            step = make_fused_mf_train_step(
+                learning_rate=lr, interpret=interpret, layout=layout,
+                capacity=cap_f, dim=dim_f,
+            )
+            batch = {"user": users, "item": items, "rating": ratings}
+
+            def fn(t, u, b):
+                t, u, out = step(t, u, b)
+                return (
+                    ShardedParamStore(store.spec, t).values(), u,
+                    out["prediction"],
+                )
+
+            self._kernel_case(
+                name, fn, (store.table, u_tab, batch),
+                close(mf_reference(u_tab, i_tab, items), 1e-3),
+            )
+
+        fused_case("fused_mf_dense_d128", 128, 1024, "dense")
+        fused_case("fused_mf_packed_d64", 64, 1000, "packed")
+        if self.mesh is not None:
+            ps_mesh = make_mesh(1, 4, devices=jax.devices()[:4])
+            u_tab, i_tab = normal((512, 128), 0.1), normal((1024, 128), 0.1)
+            items = zipf_ids(1024)
+            want_i, want_u, want_p = mf_reference(u_tab, i_tab, items)
+            self._kernel_case(
+                "fused_mf_sharded_ps4_d128",
+                lambda u, t, us, im, r: fused_mf_sgd_sharded(
+                    u, t, us, im, r, mesh=ps_mesh, learning_rate=lr,
+                    interpret=interpret),
+                (u_tab, i_tab, users, items, ratings),
+                close((want_u, want_i, want_p), 1e-3),
+            )
+
+        # splash flash attention: forward, gradient, and under shard_map
+        B, T, H, D = 2, self.sizes.flash_seq, 4, 64
+        q, k, v = (normal((B, T, H, D), 0.5, jnp.bfloat16) for _ in range(3))
+        self._kernel_case(
+            "flash_mha_fwd_bf16",
+            lambda a, b, c: flash_mha(a, b, c, interpret=interpret),
+            (q, k, v), close(reference_attention(q, k, v), 0.03),
+        )
+
+        def grad_of(fn):
+            return jax.grad(
+                lambda a, b, c: fn(a, b, c).astype(jnp.float32).sum(),
+                argnums=(0, 1, 2),
+            )
+
+        self._kernel_case(
+            "flash_mha_grad_bf16",
+            grad_of(lambda a, b, c: flash_mha(a, b, c, interpret=interpret)),
+            (q, k, v),
+            close(jax.jit(grad_of(reference_attention))(q, k, v), 0.05),
+        )
+        n_dp = 2 if self.mesh is not None else 1
+        dp_mesh = make_mesh(n_dp, 1, devices=jax.devices()[:n_dp])
+        self._kernel_case(
+            "flash_mha_dp_fwd_bf16",
+            lambda a, b, c: flash_mha_dp(
+                a, b, c, mesh=dp_mesh, interpret=interpret),
+            (q, k, v), close(reference_attention(q, k, v), 0.03),
+        )
+
+        # the LM's normal path: flash_attention defaults to "auto", which
+        # on the chip lands on the splash kernel
+        def lm_cfg(flash):
+            return TransformerConfig(
+                vocab_size=512, d_model=256, n_heads=4, n_layers=1,
+                d_ff=512, max_seq=T, flash_attention=flash,
+            )
+
+        params = init_params(jax.random.PRNGKey(0), lm_cfg("auto"))
+        tokens = jnp.asarray(rng.integers(0, 512, (2, T)), jnp.int32)
+        want_loss = jax.jit(
+            lambda p, t: lm_loss(p, {"tokens": t}, lm_cfg("off"))
+        )(params, tokens)
+        self._kernel_case(
+            "transformer_lm_loss_flash_auto",
+            lambda p, t: lm_loss(p, {"tokens": t}, lm_cfg("auto")),
+            (params, tokens), close(want_loss, 0.05),
+        )
+
+
+def main(argv=None) -> int:
+    parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    parser.add_argument(
+        "--cpu-dry-run", action="store_true",
+        help="tiny sizes on the CPU, kernels interpreted: control flow only",
+    )
+    parser.add_argument(
+        "--out", default=os.path.join(REPO, "chiprun_out", "chip_smoke"),
+        help="output directory (checkpoint scratch, report, HLO text)",
+    )
+    args = parser.parse_args(argv)
+
+    sys.path.insert(0, REPO)
+    import jax
+
+    if args.cpu_dry_run:
+        jax.config.update("jax_platforms", "cpu")
+    platform = jax.devices()[0].platform
+    if platform != ("cpu" if args.cpu_dry_run else "tpu"):
+        print(
+            f"chip_smoke: platform is {platform!r}, not 'tpu'; nothing run "
+            f"(--cpu-dry-run exercises the control flow off the chip)",
+            file=sys.stderr,
+        )
+        return 2
+
+    from flink_parameter_server_tpu.utils.compile_cache import (
+        enable_compile_cache,
+    )
+
+    cache_dir = enable_compile_cache()
+    # a kernel that quietly took another path is a failed stage
+    warnings.filterwarnings("error", message=".*falling back.*")
+    os.makedirs(args.out, exist_ok=True)
+    t_start = time.perf_counter()
+    smoke = Smoke(args.cpu_dry_run, args.out)
+    d = smoke.device
+    print(
+        f"chip_smoke: platform: {d['platform']} device_kind: {d['kind']!r} "
+        f"devices: {d['count']} jax {jax.__version__} "
+        f"compile_cache: {cache_dir}",
+        flush=True,
+    )
+    try:
+        smoke.stage_main_path()
+        if smoke.mesh is not None:
+            smoke.stage_mesh()
+        smoke.stage_kernels()
+    except Exception:
+        # a failed stage is the script's failure: say so and stop
+        traceback.print_exc()
+        if not args.cpu_dry_run:
+            print(result_line(False, d), flush=True)
+        return 1
+
+    summary = {
+        "ok": True,
+        "device": d,
+        "dry_run": DRY_RUN_NOTE if args.cpu_dry_run else False,
+        "mesh": None if smoke.mesh is None else dict(smoke.mesh.shape),
+        "stages": list(smoke.stages),
+        "wall_s": round(time.perf_counter() - t_start, 1),
+        "claim": None,
+    }
+    with open(os.path.join(args.out, "chip_smoke.json"), "w") as f:
+        json.dump({**summary, "results": smoke.stages}, f, indent=1)
+    print(json.dumps(summary), flush=True)
+    if not args.cpu_dry_run:
+        print(result_line(True, d), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

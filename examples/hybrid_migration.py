@@ -27,6 +27,11 @@ from flink_parameter_server_tpu.utils.initializers import ranged_random_factor
 
 
 def main():
+    from flink_parameter_server_tpu.utils.compile_cache import (
+        enable_compile_cache,
+    )
+
+    enable_compile_cache()
     params = Parameters.from_args(sys.argv[1:])
     chunk = params.get_int("chunk", 512)
     epochs = params.get_int("epochs", 5)

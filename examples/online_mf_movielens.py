@@ -164,6 +164,11 @@ def _run_with_driver(params, stream, *, num_users, num_items, mesh):
 
 
 def main():
+    from flink_parameter_server_tpu.utils.compile_cache import (
+        enable_compile_cache,
+    )
+
+    enable_compile_cache()
     params = Parameters.from_env().merged_with(
         Parameters.from_args(sys.argv[1:])
     )

@@ -21,6 +21,11 @@ import numpy as np
 
 
 def main():
+    from flink_parameter_server_tpu.utils.compile_cache import (
+        enable_compile_cache,
+    )
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--features", type=int, default=100)
     ap.add_argument("--rounds", type=int, default=24)

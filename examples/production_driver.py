@@ -12,9 +12,9 @@ Usage (ParameterTool-style args — utils/config.py):
         [--steps-per-call 8] [--checkpoint-every 16] [--ckpt-dir DIR]
 
 ``--steps-per-call K`` runs the envelope at dispatch granularity (one
-host round trip per K microbatches — measured 50x at 75 ms host RTT,
-results/cpu/steps_per_call_latency.md); checkpoint/metrics/NaN cadences
-round up to dispatch boundaries.
+host round trip per K microbatches — it amortises host dispatch and has
+not been measured on the chip, ROADMAP S3); checkpoint/metrics/NaN
+cadences round up to dispatch boundaries.
 """
 import os
 import shutil
@@ -47,6 +47,11 @@ class SimulatedPreemption(Exception):
 
 
 def main():
+    from flink_parameter_server_tpu.utils.compile_cache import (
+        enable_compile_cache,
+    )
+
+    enable_compile_cache()
     params = Parameters.from_env().merged_with(
         Parameters.from_args(sys.argv[1:])
     )

@@ -42,6 +42,11 @@ from flink_parameter_server_tpu.utils.initializers import (
 
 
 def main():
+    from flink_parameter_server_tpu.utils.compile_cache import (
+        enable_compile_cache,
+    )
+
+    enable_compile_cache()
     params = Parameters.from_env().merged_with(
         Parameters.from_args(sys.argv[1:])
     )

@@ -16,6 +16,11 @@ import numpy as np
 
 
 def main():
+    from flink_parameter_server_tpu.utils.compile_cache import (
+        enable_compile_cache,
+    )
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--vocab", type=int, default=400)
     ap.add_argument("--rounds", type=int, default=64)

@@ -14,6 +14,11 @@ from flink_parameter_server_tpu.models.topk_recommender import query_topk
 
 
 def main():
+    from flink_parameter_server_tpu.utils.compile_cache import (
+        enable_compile_cache,
+    )
+
+    enable_compile_cache()
     data = synthetic_ratings(500, 800, 60_000, rank=8, noise=0.02, seed=1)
     res = ps_online_mf(
         microbatches(data, 2048, epochs=4, shuffle_seed=0),

@@ -49,6 +49,11 @@ def bigram_batches(n, B, T, vocab, seed=0):
 
 
 def main():
+    from flink_parameter_server_tpu.utils.compile_cache import (
+        enable_compile_cache,
+    )
+
+    enable_compile_cache()
     params = Parameters.from_env().merged_with(
         Parameters.from_args(sys.argv[1:])
     )

@@ -14,6 +14,11 @@ from flink_parameter_server_tpu.models.word2vec import IN, train_skipgram
 
 
 def main():
+    from flink_parameter_server_tpu.utils.compile_cache import (
+        enable_compile_cache,
+    )
+
+    enable_compile_cache()
     vocab = 2000
     tokens = synthetic_corpus(
         vocab, 150_000, num_topics=10, topic_stickiness=0.995, seed=0

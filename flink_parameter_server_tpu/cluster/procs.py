@@ -12,9 +12,13 @@ and with each other on multi-core hosts.
 Design points:
 
   * **spawn, not fork** — a fork would duplicate jax/XLA runtime state
-    and every live thread's locks; spawn starts clean.  The child sets
-    ``JAX_PLATFORMS=cpu`` defensively but never actually imports jax:
-    shards run the ``store_backend="numpy"`` slice
+    and every live thread's locks; spawn starts clean.  The child is
+    STARTED with ``JAX_PLATFORMS=cpu`` in its environment: unpickling
+    its entry function imports this package, which imports jax, which
+    reads that variable then — before any line of the child's own code
+    runs — and a chip belongs to one process, the parent.  The child
+    never initialises a backend: shards run the
+    ``store_backend="numpy"`` slice
     (:class:`~.shard._NumpyStore`), whose in-place fp32 scatter-add is
     both bitwise-comparable to the jax path over client-deduplicated
     ids and ~1000× cheaper to dispatch than an XLA call per push.
@@ -47,15 +51,36 @@ RuntimeError).  Library/pytest imports are unaffected.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import json
 import multiprocessing
 import os
+import threading
 from typing import Optional, Tuple
 
 import numpy as np
 
 _CTX = multiprocessing.get_context("spawn")
+_SPAWN_ENV_LOCK = threading.Lock()
+
+
+@contextlib.contextmanager
+def _cpu_pinned_child_env():
+    """A spawned child inherits ``os.environ`` as it is at ``start()``:
+    hold ``JAX_PLATFORMS=cpu`` there for exactly that long.  (The
+    parent's own jax read the variable at import; changing it now does
+    not move the parent.)"""
+    with _SPAWN_ENV_LOCK:
+        prior = os.environ.get("JAX_PLATFORMS")
+        os.environ["JAX_PLATFORMS"] = "cpu"
+        try:
+            yield
+        finally:
+            if prior is None:
+                del os.environ["JAX_PLATFORMS"]
+            else:
+                os.environ["JAX_PLATFORMS"] = prior
 
 
 # -- deterministic picklable init specs --------------------------------------
@@ -155,7 +180,6 @@ def _shard_proc_main(spec: dict, pipe) -> None:
     the bound address, serve until told to stop (or until the parent
     dies — the pipe EOF).  The WAL dir is the durable half; losing
     this process is the ordinary failure the stack already absorbs."""
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     try:
         from .shard import ParamShard, ShardServer
 
@@ -232,7 +256,8 @@ class ShardProcess:
             name=f"fps-shard-{spec.shard_id}",
             daemon=True,
         )
-        self.proc.start()
+        with _cpu_pinned_child_env():
+            self.proc.start()
         child.close()
         self.host: Optional[str] = None
         self.port: Optional[int] = None

@@ -350,8 +350,8 @@ def push(
             from ..ops import pallas_scatter as _pallas
 
             # Real Mosaic constrains the compiled kernel's shapes
-            # (dim % 128, capacity % 8 — measured, see
-            # benchmarks/mosaic_probe.py).  Interpreter mode (non-TPU)
+            # (dim % 128, capacity % 8 — compiled on a v5e by
+            # chip_smoke.py).  Interpreter mode (non-TPU)
             # has no dim constraint; capacity is window-aligned by
             # rows_per_shard either way.  The packed layout is always
             # eligible (physical width 128 by construction).

@@ -8,12 +8,15 @@ device runs the previous step.
 
 The shared library is built on first use with the system ``g++`` (no
 pip/pybind dependency — plain C ABI via ctypes) and cached under
-``native/build/``.  Every entry point falls back to the pure-numpy path in
-:mod:`.movielens` when a compiler is unavailable.
+``native/build/`` as ``libfps_loader-<sha of the source>.so``: a binary
+is only ever loaded if it was built from the source beside it, whatever
+else a copied tree carries.  Every entry point falls back to the
+pure-numpy path in :mod:`.movielens` when a compiler is unavailable.
 """
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -23,7 +26,7 @@ import numpy as np
 
 _NATIVE_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "native")
 _SRC = os.path.abspath(os.path.join(_NATIVE_DIR, "fps_loader.cpp"))
-_SO = os.path.abspath(os.path.join(_NATIVE_DIR, "build", "libfps_loader.so"))
+_BUILD_DIR = os.path.abspath(os.path.join(_NATIVE_DIR, "build"))
 
 _lib = None
 _lib_lock = threading.Lock()
@@ -33,21 +36,33 @@ class NativeUnavailable(RuntimeError):
     pass
 
 
+def _so_path() -> str:
+    """The binary's name carries a hash of the source it was built from."""
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(_BUILD_DIR, f"libfps_loader-{digest}.so")
+
+
 def _build() -> str:
+    so = None
     try:
-        os.makedirs(os.path.dirname(_SO), exist_ok=True)
-        if os.path.exists(_SO) and os.path.getmtime(_SO) >= os.path.getmtime(
-            _SRC
-        ):
-            return _SO
+        so = _so_path()
+        if os.path.exists(so):
+            return so
+        os.makedirs(_BUILD_DIR, exist_ok=True)
+        # build beside the target and rename: a concurrent builder or
+        # an interrupted g++ never leaves a half-written library under
+        # the final name
+        tmp = f"{so}.{os.getpid()}.tmp"
         cmd = [
             "g++", "-O2", "-std=c++17", "-shared", "-fPIC", "-pthread",
-            _SRC, "-o", _SO,
+            _SRC, "-o", tmp,
         ]
         subprocess.run(cmd, check=True, capture_output=True, timeout=300)
+        os.replace(tmp, so)
     except (OSError, subprocess.SubprocessError) as e:
-        raise NativeUnavailable(f"building {_SO} failed: {e}") from e
-    return _SO
+        raise NativeUnavailable(f"building {so or _SRC} failed: {e}") from e
+    return so
 
 
 def get_lib() -> ctypes.CDLL:

@@ -26,7 +26,6 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from ..utils.compat import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..core.api import WorkerLogic
@@ -310,7 +309,7 @@ def make_locality_mf_step(
         "rating": P(dp_axis),
         "mask": P(dp_axis),
     }
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(ps_axis, None), P(dp_axis, None), batch_spec),

@@ -27,7 +27,6 @@ from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
-from ..utils.compat import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 Array = jax.Array
@@ -185,7 +184,7 @@ def moe_apply(
         ]
         return jnp.where(keep[:, None], out * gate[:, None], 0.0)
 
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(

@@ -20,9 +20,9 @@ Integration contract (matching ``reference_attention``):
   * fp32 softmax accumulation regardless of input dtype (kernel-internal).
 
 ``supports_shape`` gates the compiled path conservatively (T a multiple
-of 128 sublane-tiles, D a multiple of 64 lanes); the on-chip constraint
-set is re-measured by ``benchmarks/kernel_smoke.py`` whenever a TPU is
-live.  Off-TPU the caller should prefer ``reference_attention`` —
+of 128 sublane-tiles, D a multiple of 64 lanes); ``chip_smoke.py``
+compiles forward and gradient at T=512, D=64 on the chip.  Off-TPU the
+caller should prefer ``reference_attention`` —
 interpret mode exists for parity tests, not perf.
 """
 from __future__ import annotations
@@ -94,7 +94,6 @@ def flash_mha_dp(
     mixes batch rows).  Inside a jit whose activations are already
     dp-sharded this is a sharding-preserving no-op wrapper around the
     kernel — the multi-chip deployment of BASELINE config 5."""
-    from ..utils.compat import shard_map
     from jax.sharding import PartitionSpec as P
 
     B = q.shape[0]
@@ -104,7 +103,7 @@ def flash_mha_dp(
             f"flash_mha_dp needs batch {B} divisible by dp={dp}"
         )
     spec = P(dp_axis, None, None, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         lambda a, b, c: flash_mha(a, b, c, interpret=interpret),
         mesh=mesh,
         in_specs=(spec, spec, spec),

@@ -4,7 +4,7 @@ Reference parity: the reference's stores hold *narrow* values — MF item
 factors (dim 64), FM rows (dim 17), PA scalar weights — as JVM objects
 where row width is free (SURVEY.md §2 #3, #7, #9).  On TPU, width is NOT
 free: the VPU/MXU lane width is 128 and real Mosaic requires 128-aligned
-minor dims for dynamic-offset DMA (measured — benchmarks/mosaic_probe.py).
+minor dims for dynamic-offset DMA.
 A (capacity, 17) table either wastes 7/8 of every vector register or is
 ineligible for the pallas scatter kernel entirely.
 

@@ -29,9 +29,9 @@ the last table row (the unfused path predicts against a clipped row), and
 its lane updates no user row (the unfused path still applies the user
 delta from the clipped pull).
 
-Real-Mosaic layout (measured on a v5e with benchmarks/mosaic_probe.py —
-sub-8-row dynamic VMEM slices and non-128-multiple minor dims are
-rejected by the hardware compiler, which interpreter mode cannot see):
+Real-Mosaic layout (sub-8-row dynamic VMEM slices and non-128-multiple
+minor dims are rejected by the hardware compiler, which interpreter mode
+cannot see):
 lanes are processed in GROUPS OF 8 at 8-aligned offsets, the item table
 is read/written in aligned 8-row WINDOWS (item row ``r`` = window
 ``r // 8``, slot ``r % 8``), per-lane rows are extracted/placed with
@@ -43,8 +43,9 @@ otherwise.  A unique window costs ONE 8-row DMA round trip per
 microbatch, so item-side HBM traffic is O(unique windows) — under Zipf
 skew far below the O(batch) row traversals of the unfused step.
 
-Status: logic-verified in interpreter mode on CPU; chunk size and the
-on-chip win await a live TPU (benchmarks/microbench.py mf_fused).
+Status: compiled and checked against the XLA reference on a v5e by
+``chip_smoke.py`` (dense d128 and packed d64); whether it wins on the
+chip is not measured (ROADMAP S4).
 """
 from __future__ import annotations
 
@@ -535,7 +536,6 @@ def fused_mf_sgd_sharded(
     shard owns it), where the single-shard step predicts against the
     routed last row.  Valid lanes — masked included — are identical.
     """
-    from ..utils.compat import shard_map
     from jax.sharding import PartitionSpec as P
 
     if interpret is None:
@@ -605,7 +605,7 @@ def fused_mf_sgd_sharded(
         return new_block, new_users, lane_pred
 
     rep = P()
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(ps_axis, None), rep, rep, rep, rep, rep),

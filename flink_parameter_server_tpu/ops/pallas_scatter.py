@@ -18,8 +18,7 @@ Algorithm (duplicate-compressing windowed read-modify-write):
      occurrence, and adjacent hot ids share a window — HBM traffic is
      O(unique windows) · 8 rows instead of O(batch) serialized rows.
   3. Lane placement never slices a VMEM ref at a per-lane offset (real
-     Mosaic rejects sub-8-row dynamic slices — see
-     benchmarks/mosaic_probe.py for the measured rules).  A group's 8
+     Mosaic rejects sub-8-row dynamic slices).  A group's 8
      delta rows are loaded as one aligned (8, d) tile and placed into
      window slots with an 8×8 one-hot select matmul; groups that sit in
      a single window (the common case for sorted Zipf ids) take one
@@ -139,10 +138,12 @@ def _kernel(ids_ref, deltas_ref, table_ref, out_ref,
             t_j = ids_ref[gbase + j] % sub_k
             t_col = t_col + jnp.where(lane8 == j, t_j, 0)
         G_pad = jnp.pad(G, ((0, 0), (0, table_w - sub_width)))
-        out = jnp.zeros_like(G_pad)
-        for tt in range(sub_k):
+        # native lane rotate: jnp.roll lowers to lane slices, which
+        # Mosaic refuses at widths under 128 (and at size 0 for tt == 0)
+        out = (t_col == 0).astype(G_pad.dtype) * G_pad
+        for tt in range(1, sub_k):
             sel_t = (t_col == tt).astype(G_pad.dtype)
-            out = out + sel_t * jnp.roll(G_pad, tt * sub_width, axis=1)
+            out = out + sel_t * pltpu.roll(G_pad, tt * sub_width, axis=1)
         return out
 
     def group(g, _):

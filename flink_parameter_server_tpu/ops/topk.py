@@ -36,7 +36,6 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from ..utils.compat import shard_map
 
 Array = jax.Array
 
@@ -113,7 +112,7 @@ def sharded_topk(
         final_ids = jnp.take_along_axis(all_ids, pos, axis=1)
         return _pad_topk(final_scores, final_ids, k)
 
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(ps_axis, None), P(*(None,) * queries.ndim)),

@@ -30,7 +30,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from ..utils.compat import shard_map
 
 Array = jax.Array
 
@@ -79,7 +78,7 @@ def shard_pull(
         )
         return jax.lax.psum(vals, ps_axis)
 
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(table_spec, ids_spec),
@@ -120,7 +119,7 @@ def shard_push_add(
     """
     value_rank = table.ndim - 1
     if impl == "pallas":
-        # Real Mosaic's measured shape rules (benchmarks/mosaic_probe.py):
+        # Real Mosaic's shape rules:
         # compiled kernels need 128-aligned row widths and 8-aligned
         # per-shard capacities.  Fall back observably, never silently.
         from ..ops.pallas_scatter import supports_shape
@@ -196,7 +195,7 @@ def shard_push_add(
     if mask is None:
         mask = jnp.ones(ids.shape, dtype=bool)
 
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(table_spec, ids_spec, deltas_spec, mask_spec),

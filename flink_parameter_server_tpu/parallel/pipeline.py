@@ -27,7 +27,6 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
-from ..utils.compat import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 Array = jax.Array
@@ -125,7 +124,7 @@ def pipeline_apply(
         outputs = jax.lax.psum(outputs, pp_axis)
         return outputs.reshape(x_full.shape)
 
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(param_specs, x_spec),

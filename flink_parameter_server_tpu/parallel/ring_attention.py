@@ -25,7 +25,6 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from ..utils.compat import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 Array = jax.Array
@@ -147,7 +146,7 @@ def ring_attention(
             sp_axis=sp_axis, num_blocks=num_blocks, causal=causal,
         )
 
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(spec, spec, spec),

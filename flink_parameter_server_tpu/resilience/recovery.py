@@ -52,7 +52,7 @@ class FailureClass(enum.Enum):
 
 
 def classify_failure(exc: BaseException) -> FailureClass:
-    """Map an exception from the train loop onto the failure taxonomy.
+    """Map an exception from the train loop onto the failure classes.
 
     Explicit tags win (:class:`~.chaos.ChaosError` carries
     ``failure_class`` so tests steer each branch deterministically);

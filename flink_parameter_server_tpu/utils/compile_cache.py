@@ -1,0 +1,39 @@
+"""Where the persistent XLA compilation cache lives.
+
+Entry points (``chip_smoke.py``, ``bench.py``, ``__graft_entry__.py``,
+the ``benchmarks/`` and ``examples/`` scripts that jit) call
+:func:`enable_compile_cache` before their first compile; the package
+never calls it at import.  The directory is part of the cache key, so
+it must not move between runs: it is either where
+``JAX_COMPILATION_CACHE_DIR`` says (JAX reads that variable itself —
+nothing here touches the setting then) or ``<checkout>/.jax_cache``,
+computed from this file's location.
+"""
+from __future__ import annotations
+
+import os
+
+import jax
+
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(  # <checkout>
+        os.path.dirname(  # flink_parameter_server_tpu/
+            os.path.dirname(os.path.abspath(__file__))  # utils/
+        )
+    ),
+    ".jax_cache",
+)
+
+
+def enable_compile_cache() -> str:
+    """Turn the persistent compilation cache on and return its directory."""
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", DEFAULT_CACHE_DIR)
+    # keep small programs too: the defaults skip anything that compiled
+    # in under a second, which is most of a smoke run
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    return jax.config.jax_compilation_cache_dir
+
+
+__all__ = ["DEFAULT_CACHE_DIR", "enable_compile_cache"]

@@ -972,15 +972,14 @@ def test_run_report_carries_elastic_section():
 
 
 def test_bench_elastic_metric_line_guarded(tmp_path):
-    """bench satellite: FPS_BENCH_ELASTIC validates its value and the
-    emitter degrades to a value-None line on failure instead of
-    killing the bench."""
+    """bench satellite: FPS_BENCH_ELASTIC validates its value and,
+    left at its default, emits nothing."""
     import bench
 
     with pytest.raises(SystemExit):
         os.environ["FPS_BENCH_ELASTIC"] = "yes"
         try:
-            bench._emit_elastic_metric("cpu", False)
+            bench._emit_elastic_metric("cpu")
         finally:
             os.environ.pop("FPS_BENCH_ELASTIC", None)
     # default off: emits nothing
@@ -989,5 +988,5 @@ def test_bench_elastic_metric_line_guarded(tmp_path):
 
     buf = io.StringIO()
     with contextlib.redirect_stdout(buf):
-        bench._emit_elastic_metric("cpu", False)
+        bench._emit_elastic_metric("cpu")
     assert buf.getvalue() == ""

@@ -1,7 +1,7 @@
 """Splash flash-attention integration (ops/flash_attention.py).
 
 Interpret mode on CPU proves kernel-call plumbing and numerics; the
-compiled path is exercised by benchmarks/kernel_smoke.py on a live TPU.
+compiled path is exercised by chip_smoke.py on the chip.
 """
 import jax
 import jax.numpy as jnp
@@ -22,39 +22,11 @@ def _qkv(rng, B, T, H, D, dtype):
     return mk(), mk(), mk()
 
 
-_SPLASH_NARROW_OK = None
-
-
-def _require_splash_head_dim(d):
-    """The installed jax (0.4.37) splash kernel raises
-    NotImplementedError for head_dim % 128 != 0 even in interpret mode
-    (NUM_LANES alignment was optional in the seed-era jax these tests
-    were written against).  Probe once and skip the narrow-head cases
-    on such versions; a jax that re-supports them runs them again with
-    no test edit."""
-    global _SPLASH_NARROW_OK
-    if d % 128 == 0:
-        return
-    if _SPLASH_NARROW_OK is None:
-        try:
-            z = jnp.zeros((1, 128, 1, 64), jnp.float32)
-            flash_mha(z, z, z, interpret=True)
-            _SPLASH_NARROW_OK = True
-        except NotImplementedError:
-            _SPLASH_NARROW_OK = False
-    if not _SPLASH_NARROW_OK:
-        pytest.skip(
-            f"installed jax splash kernel requires head_dim % 128 == 0 "
-            f"(got {d})"
-        )
-
-
 @pytest.mark.parametrize(
     "T,D,dtype,tol",
     [(128, 64, jnp.float32, 1e-5), (128, 128, jnp.bfloat16, 0.02)],
 )
 def test_forward_parity(rng, T, D, dtype, tol):
-    _require_splash_head_dim(D)
     q, k, v = _qkv(rng, 2, T, 4, D, dtype)
     got = flash_mha(q, k, v, interpret=True)
     want = reference_attention(q, k, v)
@@ -66,7 +38,6 @@ def test_forward_parity(rng, T, D, dtype, tol):
 
 
 def test_grad_parity(rng):
-    _require_splash_head_dim(64)
     q, k, v = _qkv(rng, 1, 128, 2, 64, jnp.float32)
 
     def loss_flash(q, k, v):
@@ -97,7 +68,6 @@ def test_model_level_parity(rng, monkeypatch):
     LM (the auto-gating wiring in _unsharded_attention, RoPE and
     residuals included).  TPU eligibility is emulated by patching the
     backend probe and routing flash_mha through interpret mode."""
-    _require_splash_head_dim(64)  # d_model=128 / n_heads=2
     import dataclasses
 
     import flink_parameter_server_tpu.models.transformer as tr
@@ -170,7 +140,6 @@ def test_kernel_cache_safe_when_first_use_is_jitted(rng):
     (UnexpectedTracerError on the next grad/jit at that shape)."""
     from flink_parameter_server_tpu.ops.flash_attention import _make_kernel
 
-    _require_splash_head_dim(64)
     _make_kernel.cache_clear()
     T, D = 256, 64  # a shape no other test uses
     q, k, v = _qkv(rng, 1, T, 2, D, jnp.float32)
@@ -194,7 +163,6 @@ def test_flash_mha_dp_parity(rng):
         flash_mha_dp,
     )
 
-    _require_splash_head_dim(64)
     devs = np.array(jax.devices()[:2]).reshape(2, 1)
     mesh = Mesh(devs, ("dp", "ps"))
     q, k, v = _qkv(rng, 4, 128, 2, 64, jnp.float32)
@@ -213,7 +181,6 @@ def test_flash_mha_dp_parity(rng):
 def test_model_level_dp_flash_gating(rng, monkeypatch):
     """forward() on a dp-only mesh routes through flash_mha_dp when
     'auto' resolves eligible (emulated TPU), matching the reference."""
-    _require_splash_head_dim(64)  # d_model=128 / n_heads=2
     import dataclasses
 
     from jax.sharding import Mesh

@@ -97,3 +97,36 @@ def test_parse_crlf_and_no_trailing_newline(tmp_path):
     out = native.load_ratings(str(p), compact_ids=False)
     np.testing.assert_array_equal(out["user"], [1, 2])
     np.testing.assert_allclose(out["rating"], [4.0, 3.5])
+
+
+def test_binary_is_keyed_on_source_hash(tmp_path, monkeypatch):
+    """A binary is only loaded if it was built from the source beside
+    it: the name carries the source's hash, so a library left over from
+    another source (a copied tree, an older checkout) is never picked
+    up by a coin-flip of copy times."""
+    import hashlib
+
+    with open(native._SRC, "rb") as f:
+        src = f.read()
+    digest = hashlib.sha256(src).hexdigest()[:16]
+    assert os.path.basename(native._so_path()) == (
+        f"libfps_loader-{digest}.so"
+    )
+
+    # another source -> another name, built on demand; a newer-looking
+    # stale file under the old fixed name is ignored
+    other = tmp_path / "fps_loader.cpp"
+    other.write_bytes(src + b"\n// changed\n")
+    build = tmp_path / "build"
+    build.mkdir()
+    (build / "libfps_loader.so").write_bytes(b"not a library")
+    monkeypatch.setattr(native, "_SRC", str(other))
+    monkeypatch.setattr(native, "_BUILD_DIR", str(build))
+    so = native._build()
+    assert os.path.basename(so) != f"libfps_loader-{digest}.so"
+    assert os.path.basename(so).startswith("libfps_loader-")
+    assert os.path.getsize(so) > 1000
+    assert native._build() == so  # second call: present, not rebuilt
+    assert sorted(os.listdir(build)) == sorted(
+        ["libfps_loader.so", os.path.basename(so)]
+    )

@@ -482,7 +482,7 @@ def _write_fake_repo(root, current_value, unit="updates/sec"):
             json.dump({
                 "n": n, "rc": 0,
                 "parsed": {
-                    "metric": "widget throughput [CPU FALLBACK]",
+                    "metric": "widget throughput [cold cache]",
                     "value": v, "unit": unit,
                 },
             }, f)
@@ -559,7 +559,7 @@ def test_bench_history_direction_inference():
         assert not bh.higher_is_better("seconds")
         assert not bh.higher_is_better("% slowdown (negative = faster)")
         assert bh.normalize_metric(
-            "x y [CPU FALLBACK: tunnel]  z"
+            "x y [note: cold cache]  z"
         ) == "x y z"
     finally:
         sys.path.remove(os.path.join(REPO, "tools"))

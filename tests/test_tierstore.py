@@ -867,7 +867,7 @@ class TestTooling:
 
         monkeypatch.setenv("FPS_BENCH_TIER", "2")
         with pytest.raises(SystemExit, match="FPS_BENCH_TIER"):
-            bench._emit_tier_metric("cpu", False)
+            bench._emit_tier_metric("cpu")
         monkeypatch.setenv("FPS_BENCH_TIER", "0")
-        bench._emit_tier_metric("cpu", False)
+        bench._emit_tier_metric("cpu")
         assert capsys.readouterr().out == ""

@@ -210,27 +210,6 @@ def test_remat_matches_non_remat_gradients():
     )
 
 
-# Known failure on jax >= 0.4.37 (re-probed 2026-08: still failing on
-# the installed jax 0.4.37 / jaxlib 0.4.36; the utils/compat.py
-# shard_map shim resolves the API rename but NOT this numeric
-# regression): the shard_map-ppermute stage rotation inside
-# forward_pipelined no longer matches the dense oracle on the
-# forced-host CPU backend (the seed-era jax 0.4.3x these tests were
-# written against passed; the kernel itself is unchanged).  Version-
-# gated skip, not xfail: on a jax older than the regression window the
-# tests RUN (and must pass); on 0.4.37+ they skip with the exact bound
-# in the reason, so a future upgrade past the regression re-arms them
-# by flipping the gate below.
-_JAX_VERSION = tuple(int(p) for p in jax.__version__.split(".")[:3])
-_PPERMUTE_PARITY_SKIP = pytest.mark.skipif(
-    _JAX_VERSION >= (0, 4, 37),
-    reason=f"jax >= 0.4.37 (installed: {jax.__version__}) ppermute-"
-    "pipeline parity regression on the CPU backend: shard_map-ppermute "
-    "stage rotation drifts numerically from the dense oracle (verified "
-    "against jax 0.4.37/jaxlib 0.4.36; passes on the seed-era 0.4.3x)",
-)
-
-
 class TestPipelineParallel:
     def _setup(self, pp=4):
         from flink_parameter_server_tpu.models.transformer import (
@@ -246,7 +225,6 @@ class TestPipelineParallel:
         )
         return forward_pipelined, mesh, cfg, params, tokens
 
-    @_PPERMUTE_PARITY_SKIP
     def test_pipelined_forward_matches_dense(self):
         forward_pipelined, mesh, cfg, params, tokens = self._setup()
         logits_pp = jax.jit(
@@ -258,7 +236,6 @@ class TestPipelineParallel:
             np.asarray(logits_pp), np.asarray(logits_dense), atol=3e-4
         )
 
-    @_PPERMUTE_PARITY_SKIP
     def test_pipelined_gradients_match(self):
         forward_pipelined, mesh, cfg, params, tokens = self._setup(pp=2)
 
@@ -289,7 +266,6 @@ class TestPipelineParallel:
                               num_microbatches=3)  # 8 % 3 != 0
 
 
-@_PPERMUTE_PARITY_SKIP
 def test_pipelined_ring_attention_composition():
     """PP × SP: pipelined stages with sp-sharded sequence + ring
     attention inside each stage match the dense oracle."""

@@ -46,7 +46,7 @@ CURRENT = "current"
 
 def normalize_metric(name: str) -> str:
     """Strip volatile decorations so the same metric lines up across
-    rounds: bracketed suffixes (``[CPU FALLBACK: ...]``) and redundant
+    rounds: bracketed suffixes (``[note: ...]``) and redundant
     whitespace."""
     name = re.sub(r"\s*\[[^\]]*\]", "", str(name))
     return " ".join(name.split())
