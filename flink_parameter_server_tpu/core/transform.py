@@ -42,6 +42,8 @@ from .batched import BatchedWorkerLogic
 from .entities import Pull, PullAnswer, Push, PSToWorker, WorkerToPS
 from .store import ShardedParamStore
 from ..parallel.mesh import DP_AXIS
+from ..telemetry.spans import NULL_TRACER, SpanTracer
+from ..training.tracing import scope
 
 T = TypeVar("T")
 P_ = TypeVar("P_")
@@ -388,15 +390,21 @@ def make_train_step(
                     batch,
                 )
         ids = logic.keys(batch)
-        pulled = store_mod.pull(spec, table, ids)
-        state, req, out = logic.step(state, batch, pulled)
+        # ps.* scopes are metadata on the ops' names (docs/observability.md):
+        # a trace reduction finds pull, compute and push by them whatever
+        # XLA numbers its fusions
+        with scope("ps.pull"):
+            pulled = store_mod.pull(spec, table, ids)
+        with scope("ps.compute"):
+            state, req, out = logic.step(state, batch, pulled)
         # the sorted promise holds only if the logic pushes the very ids
         # it pulled — trace-time object identity is exactly that check
         # (a logic pushing derived/other ids gets the unsorted path)
-        table = store_mod.push(
-            spec, table, req.ids, req.deltas, req.mask,
-            ids_sorted=presort and (req.ids is ids),
-        )
+        with scope("ps.push"):
+            table = store_mod.push(
+                spec, table, req.ids, req.deltas, req.mask,
+                ids_sorted=presort and (req.ids is ids),
+            )
         return table, state, out
 
     return step
@@ -470,6 +478,9 @@ def make_scan_train_step(
     return step
 
 
+_END = object()  # the data iterator is exhausted
+
+
 def transform_batched(
     data: Iterable,
     worker_logic: BatchedWorkerLogic,
@@ -489,6 +500,7 @@ def transform_batched(
     skip_batches: int = 0,
     presort: bool = False,
     steps_per_call: int = 1,
+    tracer: SpanTracer = NULL_TRACER,
 ) -> TransformResult:
     """Run the compiled PS loop over an iterable of microbatches.
 
@@ -527,6 +539,14 @@ def transform_batched(
     metrics cadence rounds up to dispatch boundaries — the honest
     granularity, since between scanned steps there is no host-visible
     table at all.
+
+    ``tracer`` (the StreamingDriver hands its own; the default records
+    nothing) gets two spans a dispatch on the calling thread, never
+    overlapping: ``train.batch_wait`` while this loop is blocked on
+    ``data`` for the next batch, and ``train.pull_compute_push`` round
+    the batch's ``device_put`` and the jitted call — the host INSIDE the
+    dispatch, which includes the time the runtime's cap on programs in
+    flight holds it.
     """
     rng = rng if rng is not None else jax.random.PRNGKey(0)
     spec = store.spec
@@ -576,11 +596,12 @@ def transform_batched(
     step_idx = 0
 
     def _run_one(table, state, batch, step_idx):
-        if batch_sharding is not None:
-            batch = jax.tree.map(
-                lambda x: jax.device_put(x, batch_sharding), batch
-            )
-        table, state, out = step(table, state, batch)
+        with tracer.span("pull_compute_push", component="train"):
+            if batch_sharding is not None:
+                batch = jax.tree.map(
+                    lambda x: jax.device_put(x, batch_sharding), batch
+                )
+            table, state, out = step(table, state, batch)
         if on_step is not None:
             on_step(step_idx, out)
         if state_callback is not None:
@@ -592,8 +613,9 @@ def transform_batched(
         return table, state
 
     def _run_group(table, state, group, first_idx):
-        stacked = stack_group(group, scan_sharding)
-        table, state, outs = scan_step(table, state, stacked)
+        with tracer.span("pull_compute_push", component="train"):
+            stacked = stack_group(group, scan_sharding)
+            table, state, outs = scan_step(table, state, stacked)
         if on_step is not None or collect_outputs:
             for i in range(len(group)):
                 out_i = jax.tree.map(lambda x: x[i], outs)
@@ -608,7 +630,12 @@ def transform_batched(
         return table, state
 
     group: List[Any] = []
-    for batch in data:
+    batches = iter(data)
+    while True:
+        with tracer.span("batch_wait", component="train"):
+            batch = next(batches, _END)
+        if batch is _END:
+            break
         if skip_batches > 0:
             skip_batches -= 1
             step_idx += 1

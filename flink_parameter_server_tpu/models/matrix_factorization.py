@@ -32,6 +32,7 @@ from ..core.api import WorkerLogic
 from ..core.batched import BatchedWorkerLogic, PushRequest
 from ..core.store import ShardedParamStore
 from ..parallel.mesh import DP_AXIS
+from ..training.tracing import scope
 from ..utils.initializers import ranged_random_factor
 
 Array = jax.Array
@@ -130,7 +131,8 @@ class OnlineMatrixFactorization(BatchedWorkerLogic):
         if mask is None:
             mask = jnp.ones(users.shape, bool)
 
-        user_vecs = jnp.take(state, users, axis=0)
+        with scope("ps.state_pull"):
+            user_vecs = jnp.take(state, users, axis=0)
         user_delta, item_delta, pred = self.updater.delta(
             ratings, user_vecs, pulled
         )
@@ -144,14 +146,15 @@ class OnlineMatrixFactorization(BatchedWorkerLogic):
             user_delta = user_delta * u_scale[..., None].astype(self.dtype)
             item_delta = item_delta * i_scale[..., None].astype(self.dtype)
         m = mask[..., None].astype(self.dtype)
-        if self.state_scatter == "xla_sorted":
-            from ..ops.sorted_scatter import sorted_dedup_scatter_add
+        with scope("ps.state_push"):
+            if self.state_scatter == "xla_sorted":
+                from ..ops.sorted_scatter import sorted_dedup_scatter_add
 
-            state = sorted_dedup_scatter_add(
-                state, users, user_delta * m, mask
-            )
-        else:
-            state = state.at[users].add(user_delta * m, mode="drop")
+                state = sorted_dedup_scatter_add(
+                    state, users, user_delta * m, mask
+                )
+            else:
+                state = state.at[users].add(user_delta * m, mode="drop")
         out = {"prediction": pred, "error": (ratings - pred) * mask}
         return state, PushRequest(batch["item"], item_delta, mask), out
 

@@ -52,11 +52,15 @@ def pow2_bucket(n: int, cap: int) -> int:
 @dataclasses.dataclass
 class PendingRequest:
     """One admitted request: opaque payload + the future its answer
-    lands in + its admission timestamp (latency accounting)."""
+    lands in + its admission timestamp (latency accounting) + the instant
+    ``next_batch`` popped it (the end of its queue wait).  Both stamps
+    are ``time.perf_counter``: the program's one host clock, the
+    ``SpanTracer``'s."""
 
     payload: Any
     future: Future
     t_submit: float
+    t_pop: float = 0.0
 
 
 class RequestBatcher:
@@ -128,7 +132,7 @@ class RequestBatcher:
                 )
             fut: Future = Future()
             self._queue.append(
-                PendingRequest(payload, fut, time.monotonic())
+                PendingRequest(payload, fut, time.perf_counter())
             )
             self.submitted += 1
             self._cond.notify_all()
@@ -142,13 +146,13 @@ class RequestBatcher:
         its deadline), then pop up to ``max_batch`` requests.  Returns
         ``None`` on ``timeout`` with nothing queued, or when closed and
         drained."""
-        t_end = None if timeout is None else time.monotonic() + timeout
+        t_end = None if timeout is None else time.perf_counter() + timeout
         with self._cond:
             while not self._queue:
                 if self._closed:
                     return None
                 if t_end is not None:
-                    remaining = t_end - time.monotonic()
+                    remaining = t_end - time.perf_counter()
                     if remaining <= 0:
                         return None
                     self._cond.wait(remaining)
@@ -157,12 +161,15 @@ class RequestBatcher:
             # one request is in: flush when full OR at its deadline
             flush_at = self._queue[0].t_submit + self.max_delay
             while len(self._queue) < self.max_batch and not self._closed:
-                now = time.monotonic()
+                now = time.perf_counter()
                 if now >= flush_at:
                     break
                 self._cond.wait(flush_at - now)
             n = min(len(self._queue), self.max_batch)
             batch = [self._queue.popleft() for _ in range(n)]
+            t_pop = time.perf_counter()
+            for p in batch:
+                p.t_pop = t_pop
             self._cond.notify_all()
             return batch
 

@@ -30,6 +30,7 @@ import numpy as np
 from ..core import store as store_mod
 from ..core.store import ShardedParamStore
 from ..models.topk_recommender import query_topk
+from ..telemetry.spans import NULL_TRACER
 from .snapshot import SnapshotManager, TableSnapshot
 
 Array = jax.Array
@@ -79,6 +80,10 @@ class QueryEngine:
         self.snapshots = snapshots
         self._static_user_vectors = user_vectors
         self._fns: Dict[Any, Any] = {}
+        # ServingService.attach_tracer hands the driver's: a read then
+        # records ``serving.<op>_enqueue`` until its jitted call returns
+        # and ``serving.<op>_ready`` until the results are on the host
+        self.tracer = NULL_TRACER
 
     # -- snapshot plumbing -------------------------------------------------
     def _snap(self) -> TableSnapshot:
@@ -155,10 +160,13 @@ class QueryEngine:
     def lookup(self, ids) -> LookupResult:
         """Batched embedding pull against the latest snapshot."""
         snap = self._snap()
-        ids = jnp.asarray(np.asarray(ids, dtype=np.int32))
-        vals = self._lookup_fn()(snap.table, ids)
+        with self.tracer.span("lookup_enqueue", component="serving"):
+            ids = jnp.asarray(np.asarray(ids, dtype=np.int32))
+            vals = self._lookup_fn()(snap.table, ids)
+        with self.tracer.span("lookup_ready", component="serving"):
+            vals = np.asarray(vals)
         return LookupResult(
-            values=np.asarray(vals),
+            values=vals,
             version=snap.version,
             train_step=snap.train_step,
             staleness=self.snapshots.staleness_of(snap),
@@ -189,15 +197,20 @@ class QueryEngine:
             raise ValueError(f"k={k}: must be >= 1")
         snap = self._snap()
         uv = self._user_vectors(snap)
-        uids = jnp.asarray(np.asarray(user_ids, np.int32))
-        if exclude is not None:
-            excl = jnp.asarray(np.asarray(exclude, np.int32))
-            scores, ids = self._topk_fn(k, True)(snap.table, uv, uids, excl)
-        else:
-            scores, ids = self._topk_fn(k, False)(snap.table, uv, uids)
+        with self.tracer.span("topk_enqueue", component="serving"):
+            uids = jnp.asarray(np.asarray(user_ids, np.int32))
+            if exclude is not None:
+                excl = jnp.asarray(np.asarray(exclude, np.int32))
+                scores, ids = self._topk_fn(k, True)(
+                    snap.table, uv, uids, excl
+                )
+            else:
+                scores, ids = self._topk_fn(k, False)(snap.table, uv, uids)
+        with self.tracer.span("topk_ready", component="serving"):
+            scores, ids = np.asarray(scores), np.asarray(ids)
         return TopKResult(
-            scores=np.asarray(scores),
-            item_ids=np.asarray(ids),
+            scores=scores,
+            item_ids=ids,
             version=snap.version,
             train_step=snap.train_step,
             staleness=self.snapshots.staleness_of(snap),

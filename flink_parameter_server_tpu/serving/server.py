@@ -36,9 +36,11 @@ import threading
 from concurrent.futures import Future
 from typing import Any, List, Optional, Sequence, Tuple
 
+import jax
 import numpy as np
 
 from ..core.store import ShardedParamStore, StoreSpec
+from ..telemetry.spans import NULL_TRACER, gen_id
 from ..utils.net import LineServer
 from .batcher import (
     DeadlineExceeded,
@@ -116,6 +118,7 @@ class ServingService:
             self.metrics.bind_registry(get_registry())
         self.dispatch_errors = 0  # batches failed wholesale (loop survived)
         self._health = None  # optional resilience/health.HealthMonitor
+        self.tracer = NULL_TRACER  # StreamingDriver.serve_with hands its own
         self._thread: Optional[threading.Thread] = None
         self._stop = threading.Event()
 
@@ -123,6 +126,23 @@ class ServingService:
         """Beat ``serving_dispatch`` on ``monitor`` from the dispatch
         loop (resilience/health.py stall watchdog wiring)."""
         self._health = monitor
+        return self
+
+    def attach_tracer(self, tracer) -> "ServingService":
+        """Record this service's spans on ``tracer`` (the driver's, so
+        one ring and one profiler trace hold the whole stack):
+        ``train.publish`` with its children on the training thread
+        (serving/snapshot.py); on the dispatch thread
+        ``serving.batch_wait`` while it is idle, ``serving.topk`` /
+        ``serving.lookup`` a batch with children ``*_enqueue`` (until the
+        jitted call returns) and ``*_ready`` (until the results are on
+        the host: the device's queue in front of the kernel, the kernel,
+        the fetch); and one ``serving.queue_wait`` record a served
+        request, admission -> popped, whose ``parent_id`` is the span id
+        of the batch span that serves it."""
+        tracer.annotate_with(jax.profiler.TraceAnnotation)
+        self.tracer = tracer
+        self.snapshots.tracer = self.engine.tracer = tracer
         return self
 
     @classmethod
@@ -197,12 +217,12 @@ class ServingService:
         the first one carrying worker state)."""
         if not self.snapshots.wait_for_snapshot(timeout):
             return False
-        deadline = None if timeout is None else time.monotonic() + timeout
+        deadline = None if timeout is None else time.perf_counter() + timeout
         while True:
             snap = self.snapshots.latest()
             if snap is not None and snap.version >= min_version:
                 return True
-            if deadline is not None and time.monotonic() >= deadline:
+            if deadline is not None and time.perf_counter() >= deadline:
                 return False
             time.sleep(0.005)
 
@@ -249,7 +269,8 @@ class ServingService:
     # -- the dispatch loop -------------------------------------------------
     def _loop(self) -> None:
         while not self._stop.is_set():
-            batch = self.batcher.next_batch(timeout=0.1)
+            with self.tracer.span("batch_wait", component="serving"):
+                batch = self.batcher.next_batch(timeout=0.1)
             if self._health is not None:
                 self._health.beat("serving_dispatch")
             if not batch:
@@ -273,7 +294,7 @@ class ServingService:
             # fail requests whose queue wait already blew the deadline
             # — serving them would return answers nobody is waiting
             # for while fresher requests queue behind them
-            now = time.monotonic()
+            now = time.perf_counter()
             expired = [p for p in batch if now - p.t_submit > dl]
             if expired:
                 batch = [p for p in batch if now - p.t_submit <= dl]
@@ -300,6 +321,22 @@ class ServingService:
         if lookups:
             self._serve_lookups(lookups)
 
+    def _batch_span(self, name: str, pending: List[PendingRequest]):
+        """The ``serving.<name>`` span of one batch, with the queue wait
+        of every request it serves recorded under it."""
+        tracer = self.tracer
+        if not tracer.enabled:
+            return tracer.span(name)
+        span = tracer.span(
+            name, component="serving", trace_id=gen_id(), span_id=gen_id(4)
+        )
+        for p in pending:
+            tracer.record(
+                "queue_wait", p.t_submit, p.t_pop, component="serving",
+                trace_id=span.trace_id, parent_id=span.span_id,
+            )
+        return span
+
     def _serve_topks(self, pending: List[PendingRequest]) -> None:
         n = len(pending)
         bucket = self.batcher.bucket_for(n)
@@ -316,13 +353,14 @@ class ServingService:
                 ex = p.payload.exclude
                 exclude[i, : len(ex)] = ex
         try:
-            res = self.engine.top_k(users, k_max, exclude=exclude)
+            with self._batch_span("topk", pending):
+                res = self.engine.top_k(users, k_max, exclude=exclude)
         except Exception as e:  # NoSnapshot / bad shapes: per-request error
             for p in pending:
                 if not p.future.done():
                     p.future.set_exception(e)
             return
-        now = time.monotonic()
+        now = time.perf_counter()
         lats = []
         for i, p in enumerate(pending):
             k = p.payload.k
@@ -351,13 +389,14 @@ class ServingService:
                 np.asarray(p.payload.ids, np.int64) for p in pending
             ]))
         try:
-            res = self.engine.lookup(ids)
+            with self._batch_span("lookup", pending):
+                res = self.engine.lookup(ids)
         except Exception as e:
             for p in pending:
                 if not p.future.done():
                     p.future.set_exception(e)
             return
-        now = time.monotonic()
+        now = time.perf_counter()
         lats = []
         for i, p in enumerate(pending):
             w = len(p.payload.ids)

@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 
 from ..core.store import ShardedParamStore, StoreSpec
+from ..telemetry.spans import NULL_TRACER
 
 Array = jax.Array
 
@@ -61,6 +62,12 @@ class SnapshotManager:
     last published snapshot (the first offer always publishes).  Every
     ``note_step``/``maybe_publish`` call also advances the live step
     counter that :meth:`staleness` measures against.
+
+    ``tracer`` (``ServingService.attach_tracer`` hands the driver's; the
+    default records nothing) gets one ``train.publish`` span a publish
+    that copies — an offer the cadence declines records none — with
+    children ``train.publish_enqueue`` (the copies handed to the device)
+    and ``train.publish_sync`` (the wait until they are complete).
     """
 
     def __init__(self, spec: StoreSpec, *, publish_every: int = 1):
@@ -72,6 +79,7 @@ class SnapshotManager:
         self._latest: Optional[TableSnapshot] = None
         self._current_step = 0
         self._published = threading.Event()
+        self.tracer = NULL_TRACER
 
     # -- publish side (training thread) -----------------------------------
     def publish(self, table: Array, step: int, aux: Any = None) -> TableSnapshot:
@@ -79,26 +87,31 @@ class SnapshotManager:
         buffers and swap the latest pointer.  Blocks until the copy is
         device-complete so the source buffer is free to be donated the
         moment this returns."""
-        copied = jnp.copy(table)
-        aux_copied = jax.tree.map(
-            lambda x: jnp.copy(x) if isinstance(x, jax.Array) else x, aux
-        )
-        jax.block_until_ready(copied)
-        if aux_copied is not None:
-            jax.block_until_ready(aux_copied)
-        with self._lock:
-            version = (self._latest.version + 1) if self._latest else 1
-            snap = TableSnapshot(
-                spec=self.spec,
-                table=copied,
-                aux=aux_copied,
-                version=version,
-                train_step=int(step),
-                published_at=time.time(),
-            )
-            self._latest = snap
-            self._current_step = max(self._current_step, int(step))
-        self._published.set()
+        tracer = self.tracer
+        with tracer.span("publish", component="train"):
+            with tracer.span("publish_enqueue", component="train"):
+                copied = jnp.copy(table)
+                aux_copied = jax.tree.map(
+                    lambda x: jnp.copy(x) if isinstance(x, jax.Array) else x,
+                    aux,
+                )
+            with tracer.span("publish_sync", component="train"):
+                jax.block_until_ready(copied)
+                if aux_copied is not None:
+                    jax.block_until_ready(aux_copied)
+            with self._lock:
+                version = (self._latest.version + 1) if self._latest else 1
+                snap = TableSnapshot(
+                    spec=self.spec,
+                    table=copied,
+                    aux=aux_copied,
+                    version=version,
+                    train_step=int(step),
+                    published_at=time.time(),
+                )
+                self._latest = snap
+                self._current_step = max(self._current_step, int(step))
+            self._published.set()
         return snap
 
     def maybe_publish(

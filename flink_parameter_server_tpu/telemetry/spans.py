@@ -23,6 +23,15 @@ Overhead discipline: a disabled tracer's ``span()`` returns a shared
 no-op context manager — two attribute reads, no allocation — so the
 driver can leave the call sites in place unconditionally.
 
+Two clocks: ``span()`` also opens an annotation named
+``fps.<component>.<name>`` on the PROFILER's clock for its duration,
+once the code that owns a device has handed the tracer a factory
+(:meth:`SpanTracer.annotate_with` — ``jax.profiler.TraceAnnotation``;
+this module imports no JAX, the socket cluster imports it).  Every
+program span then lies in the ``.xplane.pb`` beside the device's ops
+whenever a profiler session runs, and costs one inactive ``TraceMe``
+when none does.  ``record()`` stays host-clock only.
+
 Stack bookkeeping: per-thread span stacks live in a dict keyed by
 thread ident, with dead-thread entries evicted whenever a NEW thread
 first spans and the table has grown past a small bound — a
@@ -54,6 +63,7 @@ class _NullSpan:
     """Shared do-nothing context manager for the disabled path."""
 
     __slots__ = ()
+    trace_id = span_id = parent_id = None
 
     def __enter__(self):
         return self
@@ -68,7 +78,7 @@ _NULL_SPAN = _NullSpan()
 class _Span:
     __slots__ = (
         "tracer", "name", "component", "t0",
-        "trace_id", "span_id", "parent_id",
+        "trace_id", "span_id", "parent_id", "note",
     )
 
     def __init__(
@@ -86,6 +96,7 @@ class _Span:
         self.trace_id = trace_id
         self.parent_id = parent_id
         self.span_id = span_id
+        self.note = None
 
     def __enter__(self):
         stack = self.tracer._stack()
@@ -99,11 +110,17 @@ class _Span:
         if self.trace_id is not None and self.span_id is None:
             self.span_id = gen_id(4)
         stack.append(self)
+        annotation = self.tracer._annotation
+        if annotation is not None:
+            self.note = annotation(f"fps.{self.component}.{self.name}")
+            self.note.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         t1 = time.perf_counter()
+        if self.note is not None:
+            self.note.__exit__(*exc)
         stack = self.tracer._stack()
         depth = len(stack) - 1
         stack.pop()
@@ -151,6 +168,15 @@ class SpanTracer:
         # once so exported timestamps are meaningful across processes
         self._epoch_wall = time.time()
         self._epoch_perf = time.perf_counter()
+        self._annotation = None
+
+    def annotate_with(self, factory) -> None:
+        """Hand the tracer ``factory(name) -> context manager`` (the code
+        that owns a device passes ``jax.profiler.TraceAnnotation``):
+        every ``span()`` then also opens ``fps.<component>.<name>`` on
+        the profiler's clock.  The first factory stays."""
+        if self._annotation is None:
+            self._annotation = factory
 
     # -- recording ---------------------------------------------------------
     def _stack(self) -> list:
@@ -285,6 +311,10 @@ class SpanTracer:
         return doc
 
 
+# what code that takes a tracer defaults to: records and opens nothing
+NULL_TRACER = SpanTracer(capacity=1, enabled=False)
+
+
 # -- the process-wide default -------------------------------------------------
 _DEFAULT_LOCK = threading.Lock()
 _DEFAULT: Optional[SpanTracer] = None
@@ -309,4 +339,6 @@ def span(name: str, component: str = "host"):
     return get_tracer().span(name, component)
 
 
-__all__ = ["SpanTracer", "gen_id", "get_tracer", "set_tracer", "span"]
+__all__ = [
+    "NULL_TRACER", "SpanTracer", "gen_id", "get_tracer", "set_tracer", "span",
+]

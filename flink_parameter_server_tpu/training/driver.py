@@ -16,7 +16,6 @@ loop implementation, hooked — not duplicated):
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Iterable, Optional
 
 import jax
@@ -28,7 +27,7 @@ from ..core.store import ShardedParamStore
 from ..core.transform import TransformResult, transform_batched
 from ..data.streams import prefetch as prefetch_iter
 from ..telemetry.registry import get_registry
-from ..telemetry.spans import SpanTracer, get_tracer
+from ..telemetry.spans import NULL_TRACER, get_tracer
 from . import checkpoint as ckpt
 from .metrics import StepMetrics
 from .tracing import profile_trace
@@ -162,10 +161,9 @@ class StreamingDriver:
             self.registry = registry
         else:
             self.registry = get_registry() if self.config.telemetry else None
-        self.tracer = (
-            get_tracer() if self.config.telemetry
-            else SpanTracer(capacity=1, enabled=False)
-        )
+        self.tracer = get_tracer() if self.config.telemetry else NULL_TRACER
+        # spans open on the profiler's clock too: this code owns a device
+        self.tracer.annotate_with(jax.profiler.TraceAnnotation)
         self.step_idx = 0
         self._state = None
         self._pending_skip = 0
@@ -279,6 +277,9 @@ class StreamingDriver:
             # one monitor spans the stack: ingest + train beats come
             # from this driver, serving-dispatch beats from the service
             service.attach_health(self.health)
+        # one tracer spans the stack too: the service's publish, batch and
+        # queue-wait spans land beside this driver's
+        service.attach_tracer(self.tracer)
         self._serving = service
         return service
 
@@ -383,12 +384,6 @@ class StreamingDriver:
         sync_steps = cfg.metrics_every > 0
         trace_ctx = {"cm": None}
         first_step_of_run = [True]
-        # dispatch-span boundary: from here (or the previous callback's
-        # exit) to the next callback's entry is one pull→compute→push
-        # dispatch window as the HOST experiences it — recorded
-        # retroactively because the jitted call itself lives inside
-        # transform_batched (wrapping it would mean forking the loop)
-        t_boundary = [time.perf_counter()]
 
         def group_callback(first_idx, n_steps, table, state, outs):
             # One invocation per jitted DISPATCH (n_steps == 1 when
@@ -398,10 +393,6 @@ class StreamingDriver:
             # steps there is no host-visible table to act on).
             if sync_steps:
                 jax.block_until_ready(outs)
-            tracer.record(
-                "pull_compute_push", t_boundary[0], time.perf_counter(),
-                component="train",
-            )
             prev_global = start_step - skip + first_idx
             global_step = prev_global + n_steps
             events = sum(
@@ -432,15 +423,17 @@ class StreamingDriver:
             if self._serving is not None:
                 # snapshot publish (copy-on-publish, cadence-gated) runs
                 # on THIS thread, so the copy is sequenced before the
-                # next dispatch donates the table buffer
-                with tracer.span("publish", component="train"):
-                    self._serving.on_dispatch(table, state, global_step)
-            for hook in self._group_hooks:
+                # next dispatch donates the table buffer; a publish that
+                # copies records its own span (serving/snapshot.py)
+                self._serving.on_dispatch(table, state, global_step)
+            if self._group_hooks:
                 # user/chaos hooks see the applied dispatch before the
                 # checkpoint cadence runs — a hook that raises here
                 # models the worst-case crash point (updates applied,
                 # boundary's checkpoint not yet taken)
-                hook(global_step, n_steps, table, state, outs)
+                with tracer.span("hooks", component="train"):
+                    for hook in self._group_hooks:
+                        hook(global_step, n_steps, table, state, outs)
 
             def crossed(every):
                 # did (prev_global, global_step] cross a multiple of
@@ -513,9 +506,6 @@ class StreamingDriver:
                         # the difference — corrupt-latest stays lossless.
                         self._wal.truncate_through(self._last_ckpt_step)
                     self._last_ckpt_step = global_step
-            # next dispatch's span starts AFTER this callback's overhead
-            # (publish/hooks/checkpoint carry their own spans)
-            t_boundary[0] = time.perf_counter()
 
         prev_handlers = {}
         if cfg.stop_signals:
@@ -554,6 +544,7 @@ class StreamingDriver:
                 skip_batches=skip,
                 presort=cfg.presort,
                 steps_per_call=cfg.steps_per_call,
+                tracer=tracer,
             )
         except BaseException:
             # The in-flight table/state buffers were donated; leave the
@@ -582,11 +573,9 @@ class StreamingDriver:
         if self._serving is not None:
             # close-time publish: post-run queries answer from the FINAL
             # table (the serve-path analogue of the §3.5 model flush)
-            with tracer.span("publish", component="train"):
-                self._serving.on_dispatch(
-                    self.store.table, self._state, self.step_idx,
-                    force=True,
-                )
+            self._serving.on_dispatch(
+                self.store.table, self._state, self.step_idx, force=True,
+            )
         self.save()
         return result
 

@@ -33,25 +33,19 @@ def profile_trace(log_dir: str) -> Iterator[None]:
 
 
 def scope(name: str):
-    """Named scope for phase attribution inside a jitted step: shows up
-    as an annotation on the trace timeline.
+    """Named scope for phase attribution inside a jitted step: metadata
+    on the ``op_name`` of every op traced under it, so a device trace
+    can be reduced by phase whatever XLA names its fusions.  The step's
+    scopes are ``ps.pull`` / ``ps.compute`` / ``ps.push``
+    (``core/transform.make_train_step``) and, inside ``ps.compute``, a
+    logic's own (MF: ``ps.state_pull`` / ``ps.state_push``).
 
     Usage::
 
-        with tracing.scope("pull"):
-            pulled = store.pull(ids)
+        with tracing.scope("ps.pull"):
+            pulled = store_mod.pull(spec, table, ids)
     """
     return jax.named_scope(name)
-
-
-def annotate_step(fn, name: str = "ps_step"):
-    """Wrap a step function so its whole body is one named scope."""
-
-    def wrapped(*args, **kwargs):
-        with jax.named_scope(name):
-            return fn(*args, **kwargs)
-
-    return wrapped
 
 
 # devices whose memory_stats() raised an UNEXPECTED type — warned once
@@ -124,7 +118,6 @@ def register_device_memory_gauges(registry=None) -> int:
 __all__ = [
     "profile_trace",
     "scope",
-    "annotate_step",
     "device_memory_stats",
     "register_device_memory_gauges",
 ]

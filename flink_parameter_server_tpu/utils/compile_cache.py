@@ -33,6 +33,11 @@ def enable_compile_cache() -> str:
     # in under a second, which is most of a smoke run
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    # an executable carries its ops' names (the step's ``ps.*`` scopes,
+    # which a device trace is reduced by): JAX's default key strips them,
+    # so a cache shared with a checkout that names its ops otherwise would
+    # hand back that checkout's names
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     return jax.config.jax_compilation_cache_dir
 
 
