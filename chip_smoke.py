@@ -153,6 +153,18 @@ class Smoke:
             dtype=dtype, mesh=mesh,
             init_low=-INIT_SCALE, init_high=INIT_SCALE,
         )
+        # dim 64 is narrower than the row-write kernel takes: on one chip
+        # the logic says so once (a "falling back" warning, an error here)
+        # and keeps the XLA scatter.  Hear it now; main_path names the arm
+        with warnings.catch_warnings(record=True) as heard:
+            warnings.simplefilter("always")
+            arm = logic.state_update_arm(
+                self.jax.ShapeDtypeStruct((s.num_users, s.dim), dtype),
+                s.batch,
+            )
+        self.state_update = {
+            "arm": arm, "refused": [str(w.message) for w in heard],
+        }
         store = ShardedParamStore.create(
             s.num_items, (s.dim,), dtype=dtype, mesh=mesh,
             init_fn=normal_factor(
@@ -467,6 +479,7 @@ class Smoke:
             metric_lines=len(metric_sink.getvalue().splitlines()),
             topk=answers,
             placement=placement,
+            state_update=self.state_update,
         )
 
     # -- stage 2: four chips ------------------------------------------------
@@ -571,8 +584,13 @@ class Smoke:
             init_params,
             lm_loss,
         )
+        from flink_parameter_server_tpu.core.transform import make_train_step
+        from flink_parameter_server_tpu.models.matrix_factorization import (
+            OnlineMatrixFactorization,
+            SGDUpdater,
+        )
         from flink_parameter_server_tpu.ops import packed as pk
-        from flink_parameter_server_tpu.ops import pallas_scatter
+        from flink_parameter_server_tpu.ops import pallas_scatter, row_update
         from flink_parameter_server_tpu.ops.flash_attention import (
             flash_mha,
             flash_mha_dp,
@@ -624,6 +642,49 @@ class Smoke:
                 t, i, dl, ids_sorted=True),
             (table, ids_asc, deltas_asc),
             close(table.at[ids_asc].add(deltas_asc), 1e-3), mosaic=False,
+        )
+
+        # ops/row_update at the shape class of the MF cells' user state:
+        # f32 rows of 128 lanes, a row count that no 8 divides, uniform
+        # ids with a few planted duplicates (one run longer than a block)
+        rows_u = 100_004 if not self.dry_run else 1_028
+        table_u = normal((rows_u, d))
+        ids_u = np.array(rng.integers(0, rows_u, n), np.int32)
+        ids_u[: n // 8] = ids_u[0]
+        ids_u[-3:] = rows_u - 1
+        ids_u = jnp.asarray(rng.permutation(ids_u))
+        self._kernel_case(
+            "row_update_d128_f32_rows_mod8",
+            lambda t, i, dl: row_update.row_add(
+                t, i, jnp.take(t, i, axis=0), dl, interpret=interpret),
+            (table_u, ids_u, deltas),
+            close(table_u.at[ids_u].add(deltas), 1e-3),
+        )
+
+        # the MF step's DEFAULT arm at that shape class, against the XLA
+        # arm: table, state and both per-record outputs in stream order
+        # (the dry run pins the arm: off the chip the default is XLA's)
+        def mf_step(arm):
+            logic = OnlineMatrixFactorization(
+                rows_u, d, updater=SGDUpdater(0.05), state_scatter=arm,
+                init_low=-INIT_SCALE, init_high=INIT_SCALE,
+            )
+            spec = ShardedParamStore.from_values(table).spec
+            return logic, jax.jit(make_train_step(logic, spec))
+
+        batch_u = {
+            "user": ids_u, "item": zipf_ids(cap), "rating": normal((n,)),
+            "mask": jnp.asarray(rng.random(n) > 0.05),
+        }
+        logic_x, step_x = mf_step("xla")
+        logic_d, step_d = mf_step("sorted_rows" if self.dry_run else None)
+        state_u = logic_x.init_state(jax.random.PRNGKey(0))
+        arm_d = logic_d.state_update_arm(state_u, n)
+        require(arm_d == "sorted_rows", f"default MF state update is {arm_d}")
+        self._kernel_case(
+            "mf_step_default_arm_d128_f32",
+            step_d, (table, state_u, batch_u),
+            close(step_x(table, state_u, batch_u), 1e-3),
         )
 
         # bfloat16 table: the kernel sums a window in f32 and rounds once,

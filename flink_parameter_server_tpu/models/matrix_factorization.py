@@ -30,12 +30,17 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..core.api import WorkerLogic
 from ..core.batched import BatchedWorkerLogic, PushRequest
+from ..core import store as store_mod
 from ..core.store import ShardedParamStore
+from ..ops import row_update
 from ..parallel.mesh import DP_AXIS
 from ..training.tracing import scope
 from ..utils.initializers import ranged_random_factor
 
 Array = jax.Array
+
+# the arms of the worker-state update (OnlineMatrixFactorization.step)
+STATE_ARMS = ("sorted_rows", "xla", "xla_sorted")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -78,7 +83,7 @@ class OnlineMatrixFactorization(BatchedWorkerLogic):
         dtype=jnp.float32,
         dedup_scale: bool = False,
         num_items: Optional[int] = None,
-        state_scatter: str = "xla",
+        state_scatter: Optional[str] = None,
     ):
         self.num_users = num_users
         self.dim = dim
@@ -98,16 +103,29 @@ class OnlineMatrixFactorization(BatchedWorkerLogic):
         self.num_items = num_items
         if dedup_scale and num_items is None:
             raise ValueError("dedup_scale=True requires num_items")
-        # state_scatter="xla_sorted": the worker-state update combines
-        # duplicate-user deltas before the scatter (ops/sorted_scatter)
-        # — the same XLA RMW-serialization fix the store side gets from
-        # scatter_impl="xla_sorted"; hot users serialize the plain
-        # scatter exactly like hot items do.
-        if state_scatter not in ("xla", "xla_sorted"):
+        # state_scatter pins the arm of the worker-state update; None (the
+        # default) takes what the step can see (``state_update_arm``):
+        #   "sorted_rows": the gathered rows and their deltas sorted by
+        #     user, one pipelined row write per unique user
+        #     (ops/row_update) — where the kernel can run: TPU, no mesh,
+        #     float32 rows of k x 128 lanes, a batch its scalar memory holds;
+        #   "xla": the plain scatter-add, one serial read-modify-write a
+        #     lane on the TPU (75 ns a row, PERF.md section 6);
+        #   "xla_sorted": duplicates combined before an XLA scatter
+        #     promised unique and sorted (ops/sorted_scatter).
+        if state_scatter not in (None,) + STATE_ARMS:
             raise ValueError(
-                f"state_scatter={state_scatter!r}: xla|xla_sorted"
+                f"state_scatter={state_scatter!r}: one of {STATE_ARMS} "
+                f"or None"
             )
         self.state_scatter = state_scatter
+        self._fallback_noted = False
+        if (state_scatter is None and mesh is None
+                and jax.default_backend() == "tpu"
+                and row_update.refusal(dim, dtype, 0) is None):
+            # the step of this logic is going to trace the kernel: have
+            # Pallas imported by then
+            row_update.preload()
 
     # -- BatchedWorkerLogic ------------------------------------------------
     def init_state(self, rng: Array) -> Array:
@@ -124,12 +142,38 @@ class OnlineMatrixFactorization(BatchedWorkerLogic):
     def keys(self, batch: Dict[str, Array]) -> Array:
         return batch["item"]
 
+    def state_update_arm(self, state, lanes: int) -> str:
+        """The arm ``step`` compiles for this state and a batch of ``lanes``
+        records (one of ``STATE_ARMS``): the pinned one, else "sorted_rows"
+        wherever its kernel can run.  On a TPU without a mesh a refusal by
+        shape, dtype or batch size is counted and warned of once, beside
+        the store's own refused kernels
+        (``core/store.pallas_fallback_count``)."""
+        if self.state_scatter is not None:
+            return self.state_scatter
+        # under a mesh the state is P(dp, None) and GSPMD partitions the
+        # XLA scatter; off the TPU the kernel would be interpreted
+        if self.mesh is not None or jax.default_backend() != "tpu":
+            return "xla"
+        why = row_update.refusal(state.shape[-1], state.dtype, lanes)
+        if why is None and state.ndim != 2:
+            why = f"state of rank {state.ndim}, the kernel takes rows"
+        if why is None:
+            return "sorted_rows"
+        if not self._fallback_noted:
+            self._fallback_noted = True
+            store_mod._note_scatter_fallback(
+                "sorted_rows", f"the MF worker-state update: {why}"
+            )
+        return "xla"
+
     def step(self, state: Array, batch: Dict[str, Array], pulled: Array):
         users = batch["user"].astype(jnp.int32)
         ratings = batch["rating"].astype(self.dtype)
         mask = batch.get("mask")
         if mask is None:
             mask = jnp.ones(users.shape, bool)
+        arm = self.state_update_arm(state, users.size)
 
         with scope("ps.state_pull"):
             user_vecs = jnp.take(state, users, axis=0)
@@ -145,16 +189,25 @@ class OnlineMatrixFactorization(BatchedWorkerLogic):
             )
             user_delta = user_delta * u_scale[..., None].astype(self.dtype)
             item_delta = item_delta * i_scale[..., None].astype(self.dtype)
-        m = mask[..., None].astype(self.dtype)
         with scope("ps.state_push"):
-            if self.state_scatter == "xla_sorted":
-                from ..ops.sorted_scatter import sorted_dedup_scatter_add
-
-                state = sorted_dedup_scatter_add(
-                    state, users, user_delta * m, mask
+            if arm == "sorted_rows":
+                # everything else stays in stream order (the item push
+                # sums a hot item's deltas in the order it always did);
+                # only the rows gathered above and their deltas are
+                # brought into user order for the kernel
+                state = row_update.row_add(
+                    state, users, user_vecs, user_delta, mask
                 )
             else:
-                state = state.at[users].add(user_delta * m, mode="drop")
+                user_delta = user_delta * mask[..., None].astype(self.dtype)
+                if arm == "xla_sorted":
+                    from ..ops.sorted_scatter import sorted_dedup_scatter_add
+
+                    state = sorted_dedup_scatter_add(
+                        state, users, user_delta, mask
+                    )
+                else:
+                    state = state.at[users].add(user_delta, mode="drop")
         out = {"prediction": pred, "error": (ratings - pred) * mask}
         return state, PushRequest(batch["item"], item_delta, mask), out
 
@@ -189,15 +242,11 @@ def ps_online_mf(
 
     ``scatter_impl`` / ``layout`` reach the item store (see
     :class:`~..core.store.StoreSpec`); ``state_scatter`` the user-state
-    update — it defaults to following ``scatter_impl``, since hot users
-    serialize the state RMW exactly like hot items do.
+    update, where None leaves the logic to take the arm it can run
+    (``state_update_arm``).
     """
     from ..core.transform import transform_batched
 
-    if state_scatter is None:
-        state_scatter = (
-            "xla_sorted" if scatter_impl == "xla_sorted" else "xla"
-        )
     logic = OnlineMatrixFactorization(
         num_users,
         dim,
