@@ -491,6 +491,36 @@ def push(
     return jnp.where(touched, updated, table)
 
 
+def _pad_rows(spec: StoreSpec, pad: int) -> Callable[[Array], Array]:
+    """``values -> values`` with ``pad`` zero rows appended, for ``values``
+    that already live on the spec's mesh, row-sharded or not.  One jitted
+    call with the zeros made INSIDE it: the partitioner then pads each shard
+    in place and passes a few halo rows to its neighbour.  An eager
+    ``jnp.concatenate`` of a ``ps``-sharded array with a zeros ARGUMENT
+    gathers the whole table onto every chip instead, which a table larger
+    than one chip's memory cannot survive (187.8 M x 17 f32 over four v5e
+    chips: RESOURCE_EXHAUSTED, 24 GB asked of 16; PERF.md section 6, PR 28)."""
+
+    def padded(values: Array) -> Array:
+        return jnp.concatenate(
+            [values, jnp.zeros((pad,) + spec.value_shape, spec.dtype)]
+        )
+
+    return jax.jit(padded, out_shardings=spec.sharding())
+
+
+def _lives_on_mesh(spec: StoreSpec, values: Any) -> bool:
+    """Whether ``values`` is a concrete array on exactly the mesh's devices:
+    only then can a jitted program of that mesh take it as it lies."""
+    return (
+        spec.mesh is not None
+        and spec.layout != "packed"  # ``sharding()`` is the packed table's
+        and isinstance(values, jax.Array)
+        and not isinstance(values, jax.core.Tracer)
+        and values.sharding.device_set == set(spec.mesh.devices.flat)
+    )
+
+
 @jax.tree_util.register_pytree_node_class
 class ShardedParamStore:
     """Functional bundle of (spec, table).  All mutators return new stores.
@@ -569,7 +599,11 @@ class ShardedParamStore:
     @staticmethod
     def _place(spec: StoreSpec, values: Array) -> Array:
         pad = spec.padded_capacity - values.shape[0]
-        if pad:
+        if pad and _lives_on_mesh(spec, values):
+            values = _pad_rows(spec, pad)(values)
+        elif pad:
+            # numpy, uncommitted, or committed elsewhere (one device, another
+            # mesh): pad where the values are; ``device_put`` below moves them
             values = jnp.concatenate(
                 [values, jnp.zeros((pad,) + spec.value_shape, spec.dtype)]
             )

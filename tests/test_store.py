@@ -94,6 +94,63 @@ def test_from_values_model_load(mesh):
     )
 
 
+@pytest.mark.parametrize("origin", [
+    "numpy", "uncommitted", "one_device", "other_device", "smaller_mesh",
+    "same_mesh_sharded", "same_mesh_replicated",
+])
+def test_model_load_pads_values_from_anywhere(origin, mesh_devices):
+    """12 rows over ``ps = 4`` need 20 padding rows (shards of 8).  Values
+    that already lie on the mesh are padded by a jitted program of that mesh
+    (``core/store._pad_rows``: a table larger than a chip cannot be padded
+    any other way); values from anywhere else are padded where they are and
+    then moved, as a restore onto another layout needs."""
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from flink_parameter_server_tpu.core import store as store_mod
+    from flink_parameter_server_tpu.parallel.mesh import make_mesh
+
+    mesh = make_mesh(1, 4, devices=mesh_devices[:4])
+    want = np.arange(24.0, dtype=np.float32).reshape(12, 2)
+    values = {
+        "numpy": lambda: want,
+        "uncommitted": lambda: jnp.asarray(want),
+        "one_device": lambda: jax.device_put(want, mesh_devices[0]),
+        "other_device": lambda: jax.device_put(want, mesh_devices[7]),
+        # a store on TWO chips resharded onto four
+        "smaller_mesh": lambda: ShardedParamStore.from_values(
+            want, mesh=make_mesh(1, 2, devices=mesh_devices[4:6])
+        ).values(),
+        "same_mesh_sharded": lambda: jax.device_put(
+            want, NamedSharding(mesh, PartitionSpec("ps"))
+        ),
+        "same_mesh_replicated": lambda: jax.device_put(
+            want, NamedSharding(mesh, PartitionSpec())
+        ),
+    }[origin]()
+    spec = ShardedParamStore.from_values(want, mesh=mesh).spec
+    assert (spec.padded_capacity, spec.rows_per_shard) == (32, 8)
+    assert store_mod._lives_on_mesh(spec, values) == origin.startswith("same")
+    for store in (
+        ShardedParamStore.from_values(values, mesh=mesh),
+        ShardedParamStore.from_spec_values(spec, jnp.asarray(values)
+                                           if origin == "numpy" else values),
+    ):
+        assert store.table.sharding == spec.sharding()
+        np.testing.assert_array_equal(np.asarray(store.values()), want)
+        np.testing.assert_array_equal(np.asarray(store.table)[12:], 0.0)
+        np.testing.assert_array_equal(
+            np.asarray(store.pull(jnp.array([11, 0]))), want[[11, 0]]
+        )
+
+
+def test_model_load_under_a_trace_pads_abstractly(mesh):
+    # ``eval_shape`` / ``jit`` hand ``_place`` a tracer, which lies nowhere
+    traced = jax.eval_shape(
+        lambda: ShardedParamStore.from_values(jnp.ones((12, 2)), mesh=mesh)
+    )
+    assert traced.table.shape == (32, 2)
+
+
 class TestExplicitCollectives:
     """shard_map pull/push — the explicit ICI message plane."""
 

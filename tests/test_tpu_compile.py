@@ -5,10 +5,12 @@ compiler refuses costs a test failure instead of chip time.
 
 All such compiles live in THIS file: the worker that runs it loads the TPU
 library, inside a fixture, and keeps it until it exits."""
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
 
 from flink_parameter_server_tpu import ShardedParamStore
 from flink_parameter_server_tpu.core import store as store_mod
@@ -18,19 +20,49 @@ from flink_parameter_server_tpu.ops import row_update
 
 # mf-hugewiki-k128 (chipbench/configs): the MF cells' shapes
 USERS, ITEMS, DIM, BATCH = 5_008_260, 39_780, 128, 65_536
+# fm-criteo-ps4: cell 4's table (17 f32 lanes pad to 24 on the chip: 16.8 GiB,
+# 4.51 GB a shard) and its one global batch
+FM_ROWS, FM_FIELDS, FM_BATCH = 187_767_412, 39, 32_768
+GB = 1e9
+# an HLO line that APPLIES a collective (a use of its result is `%all-reduce,`)
+COLLECTIVE_OP = re.compile(
+    r" (all-reduce|all-gather|all-to-all|reduce-scatter|collective-permute)"
+    r"(-start)?\("
+)
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
 
     try:
-        topo = topologies.get_topology_desc(
+        return topologies.get_topology_desc(
             platform="tpu", topology_name="v5e:2x2"
         )
     except Exception as e:  # no libtpu here, or its lock is held
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def ps4(topo):
+    """``fm-criteo-ps4`` (chipbench/configs) on the four described chips:
+    its mesh, its store's spec (nothing allocated) and its logic."""
+    from flink_parameter_server_tpu.models import factorization_machine as fmm
+    from flink_parameter_server_tpu.parallel.mesh import make_mesh
+
+    mesh = make_mesh(1, 4, devices=topo.devices)
+    config = fmm.FMConfig(
+        num_features=FM_ROWS, dim=16, learning_rate=1e-5, loss="logistic"
+    )
+    spec = jax.eval_shape(
+        lambda: fmm.make_store(config, mesh=mesh, dtype=jnp.float32)
+    ).spec
+    return mesh, spec, fmm.FactorizationMachine(config)
 
 
 @pytest.fixture()
@@ -130,3 +162,65 @@ def test_mf_step_default_arm_on_tpu_is_the_row_kernel(
     assert state_ops and all("sorted_row_update" in op for op in state_ops), (
         state_ops
     )
+
+
+def test_padding_a_sharded_table_larger_than_a_chip_stays_on_its_shards(
+        ps4, no_compile_cache):
+    """``ShardedParamStore._place`` appends cell 4's 12 padding rows to a
+    187.8 M-row array that is already sharded over ``ps``: each chip pads
+    its own 4.51 GB block and hands a few halo rows on.  The eager
+    concatenate it used before gathered the table on every chip
+    (RESOURCE_EXHAUSTED on the v5e, my chip run, PR 28; the TPU compiler
+    says the same here)."""
+    mesh, spec, _ = ps4
+    pad = spec.padded_capacity - FM_ROWS
+    assert (spec.rows_per_shard, pad) == (46_941_856, 12)
+    values = _shape(
+        NamedSharding(mesh, PartitionSpec("ps", None)), (FM_ROWS, 17),
+        jnp.float32,
+    )
+    compiled = store_mod._pad_rows(spec, pad).lower(values).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert "collective-permute" in text and "all-gather" not in text
+    assert 4.5 * GB < mem.output_size_in_bytes < 4.6 * GB
+    assert mem.temp_size_in_bytes < 0.1 * GB
+    with pytest.raises(jax.errors.JaxRuntimeError, match="RESOURCE_EXHAUSTED"):
+        jax.jit(lambda v, z: jnp.concatenate([v, z])).lower(
+            values,
+            _shape(NamedSharding(mesh, PartitionSpec()), (pad, 17), jnp.float32),
+        ).compile()
+
+
+def test_fm_step_on_four_chips_keeps_its_collective_under_ps_pull(
+        ps4, no_compile_cache):
+    """Cell 4's step, partitioned by GSPMD with ``make_store``'s default
+    arms: the donated 4.51 GB shard is updated in place, and the ONE
+    collective is the all-reduce of the gathered rows, whose ``op_name``
+    carries ``ps.pull``: a device trace reads it under
+    ``store.pull_device_ms`` (docs/observability.md)."""
+    mesh, spec, logic = ps4
+    everywhere = NamedSharding(mesh, PartitionSpec())
+    batch = {
+        "ids": _shape(everywhere, (FM_BATCH, FM_FIELDS), jnp.int32),
+        "values": _shape(everywhere, (FM_BATCH, FM_FIELDS), jnp.float32),
+        "feat_mask": _shape(everywhere, (FM_BATCH, FM_FIELDS), jnp.bool_),
+        "label": _shape(everywhere, (FM_BATCH,), jnp.float32),
+        "mask": _shape(everywhere, (FM_BATCH,), jnp.bool_),
+    }
+    compiled = jax.jit(
+        make_train_step(logic, spec), donate_argnums=(0, 1)
+    ).lower(
+        _shape(spec.sharding(), spec.table_shape(), jnp.float32), (), batch
+    ).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes > 4.5 * GB  # in place
+    assert mem.temp_size_in_bytes < 1.0 * GB
+    collectives = [
+        line for line in compiled.as_text().splitlines()
+        if COLLECTIVE_OP.search(line)
+    ]
+    assert len(collectives) == 1, collectives
+    assert f" f32[{FM_BATCH},{FM_FIELDS},17]" in collectives[0]
+    assert "all-reduce(" in collectives[0]
+    assert 'op_name="jit(step)/ps.pull/' in collectives[0]
+
