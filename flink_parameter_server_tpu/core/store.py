@@ -72,6 +72,15 @@ def _note_pallas_fallback(reason: str) -> None:
     _note_scatter_fallback("pallas", reason)
 
 
+# How often layout="auto" wanted the packed layout and a mesh kept the
+# table dense (one per store built so; `_resolve_layout` says why).
+_PACKED_REFUSALS = 0
+
+
+def packed_refusal_count() -> int:
+    return _PACKED_REFUSALS
+
+
 def _dp_axis_and_divisible(mesh, n: int):
     """(dp_axis or None, batch-divisibility ok) — the shared gate for
     dispatching a push through shard_push_add's all_gather plane."""
@@ -86,12 +95,29 @@ def _dp_axis_and_divisible(mesh, n: int):
 
 
 def _resolve_layout(
-    layout: str, update: Union[str, UpdateFn], value_shape: Tuple[int, ...]
+    layout: str,
+    update: Union[str, UpdateFn],
+    value_shape: Tuple[int, ...],
+    num_shards: int = 1,
 ) -> str:
     """Resolve the table layout, validating packed-layout constraints.
 
-    ``"auto"`` picks packed for narrow-row add-stores (the shapes where
-    lane packing pays — MF/FM/PA) and dense otherwise."""
+    ``"auto"`` reads the row width, the update rule and the shard count:
+    packed for an add-store whose rows are narrower than 128 lanes and
+    whose table lies on ONE shard; dense otherwise.  A narrow dense row is
+    a column of scalars across the table's tiles on the TPU, and its
+    gather and scatter-add walk that column (36 and 121 ns a row for FM's
+    17 lanes on the v5e, against 10 and 22 for the 128-lane physical row
+    that holds seven of them; PERF.md section 6, PR 29).
+
+    Under ``ps > 1`` a narrow add-store stays dense, warned of and counted
+    (:func:`packed_refusal_count`): ``_place`` packs a table whole, where
+    its values lie, before the shards get their parts (``_pack_rows``), so
+    a table sharded because it is larger than one chip cannot be packed
+    from values yet (ROADMAP S9).  ``create`` builds its table under the
+    mesh's ``out_shardings`` and could pack there; the rule is the same
+    for it on purpose, so that a store and its reload from a checkpoint
+    (``from_values``) resolve to one layout."""
     if layout not in ("dense", "packed", "auto"):
         raise ValueError(
             f"layout must be 'dense', 'packed' or 'auto', got {layout!r}"
@@ -100,7 +126,20 @@ def _resolve_layout(
     for s in value_shape:
         width *= int(s)
     if layout == "auto":
-        return "packed" if (update == "add" and width < 128) else "dense"
+        if update != "add" or width >= 128:
+            return "dense"
+        if num_shards > 1:
+            global _PACKED_REFUSALS
+            _PACKED_REFUSALS += 1
+            warnings.warn(
+                f"layout='auto': rows of {width} lanes would be packed "
+                f"{128 // width} to a 128-lane row, but the table is "
+                f"sharded over ps={num_shards}; it stays dense",
+                RuntimeWarning,
+                stacklevel=3,
+            )
+            return "dense"
+        return "packed"
     if layout == "packed" and update != "add":
         # the generic update path applies `update` per logical row on a
         # dense combined table — packing it would need an unpack per push
@@ -254,8 +293,9 @@ def pull(spec: StoreSpec, table: Array, ids: Array) -> Array:
     """Batched pull: ``values[i] = table[ids[i]]`` (sharded gather).
 
     Out-of-range ids are clipped (callers use a validity mask alongside).
-    Packed layout: one physical-row gather + one lane slice (both
-    vectorized XLA gathers — see ops/packed.py)."""
+    Packed layout: one gather of whole 128-lane physical rows, then the
+    lane slice as ``k`` static slices chosen by a ``select`` on
+    ``id % k`` (no per-element gather — see ops/packed.py)."""
     ids = jnp.clip(ids.astype(jnp.int32), 0, spec.padded_capacity - 1)
     if spec.layout == "packed":
         from ..ops.packed import packed_pull
@@ -509,12 +549,59 @@ def _pad_rows(spec: StoreSpec, pad: int) -> Callable[[Array], Array]:
     return jax.jit(padded, out_shardings=spec.sharding())
 
 
+# Physical rows a step of `_pack_rows`: 131,072 x 7 of FM's rows are 0.47 GB
+# once the compiler has laid them 128 lanes wide.
+_PACK_CHUNK = 131_072
+
+
+def _pack_rows(spec: StoreSpec) -> Callable[[Array], Array]:
+    """``values -> table`` for a packed table, as one jitted program that
+    packs ``_PACK_CHUNK`` physical rows at a time into a zeroed table, so
+    the chip holds the values, the table and one chunk.  The table comes
+    out where the values lie, whole; under a mesh ``_place`` then hands it
+    to the shards, so a packed table still has to fit one chip.
+    To reshape ``(n, 17)`` into ``(n / 7, 119)`` the TPU compiler first
+    lays the rows 128 lanes wide: for FM's 49.1 M rows all at once that is
+    25 GB asked of a 16 GB chip, whether the ops are jitted together or
+    run eagerly one by one (the fault PR 28 met under a mesh; PERF.md
+    section 6, PR 29).  The values are not donated: no output has their
+    shape to take their place."""
+    from ..ops.packed import pack_table
+
+    k, d = spec.pack, spec.row_width
+
+    def packed(values: Array) -> Array:
+        values = values.reshape(-1, d)
+        whole = values.shape[0] // k  # physical rows with all k logical rows
+        table = jnp.zeros(spec.table_shape(), spec.dtype)
+        chunk = min(whole, _PACK_CHUNK)
+
+        def pack_chunk(i, table):
+            # the last chunk starts early and packs some rows a second time
+            at = jnp.minimum(i * chunk, whole - chunk)
+            rows = jax.lax.dynamic_slice(values, (at * k, 0), (chunk * k, d))
+            return jax.lax.dynamic_update_slice(
+                table, pack_table(rows, chunk), (at, 0)
+            )
+
+        if whole:
+            table = jax.lax.fori_loop(
+                0, -(-whole // chunk), pack_chunk, table
+            )
+        if values.shape[0] > whole * k:
+            table = jax.lax.dynamic_update_slice(
+                table, pack_table(values[whole * k:], 1), (whole, 0)
+            )
+        return table
+
+    return jax.jit(packed)
+
+
 def _lives_on_mesh(spec: StoreSpec, values: Any) -> bool:
     """Whether ``values`` is a concrete array on exactly the mesh's devices:
     only then can a jitted program of that mesh take it as it lies."""
     return (
         spec.mesh is not None
-        and spec.layout != "packed"  # ``sharding()`` is the packed table's
         and isinstance(values, jax.Array)
         and not isinstance(values, jax.core.Tracer)
         and values.sharding.device_set == set(spec.mesh.devices.flat)
@@ -556,7 +643,10 @@ class ShardedParamStore:
             scatter_impl=scatter_impl,
             mesh=mesh,
             ps_axis=ps_axis,
-            layout=_resolve_layout(layout, update, tuple(value_shape)),
+            layout=_resolve_layout(
+                layout, update, tuple(value_shape),
+                1 if mesh is None else mesh.shape[ps_axis],
+            ),
         )
         return cls(spec, create_table(spec, init_fn))
 
@@ -582,7 +672,10 @@ class ShardedParamStore:
             scatter_impl=scatter_impl,
             mesh=mesh,
             ps_axis=ps_axis,
-            layout=_resolve_layout(layout, update, tuple(values.shape[1:])),
+            layout=_resolve_layout(
+                layout, update, tuple(values.shape[1:]),
+                1 if mesh is None else mesh.shape[ps_axis],
+            ),
         )
         return cls(spec, cls._place(spec, values))
 
@@ -598,22 +691,19 @@ class ShardedParamStore:
 
     @staticmethod
     def _place(spec: StoreSpec, values: Array) -> Array:
-        pad = spec.padded_capacity - values.shape[0]
-        if pad and _lives_on_mesh(spec, values):
-            values = _pad_rows(spec, pad)(values)
-        elif pad:
-            # numpy, uncommitted, or committed elsewhere (one device, another
-            # mesh): pad where the values are; ``device_put`` below moves them
-            values = jnp.concatenate(
-                [values, jnp.zeros((pad,) + spec.value_shape, spec.dtype)]
-            )
         if spec.layout == "packed":
-            from ..ops.packed import pack_table
-
-            values = pack_table(
-                values.reshape(-1, spec.row_width),
-                spec.rows_per_shard * spec.num_shards,
-            )
+            values = _pack_rows(spec)(values)  # pads its own rows
+        else:
+            pad = spec.padded_capacity - values.shape[0]
+            if pad and _lives_on_mesh(spec, values):
+                values = _pad_rows(spec, pad)(values)
+            elif pad:
+                # numpy, uncommitted, or committed elsewhere (one device,
+                # another mesh): pad where the values are; ``device_put``
+                # below moves them
+                values = jnp.concatenate(
+                    [values, jnp.zeros((pad,) + spec.value_shape, spec.dtype)]
+                )
         sharding = spec.sharding()
         if sharding is not None:
             values = jax.device_put(values, sharding)
@@ -659,4 +749,5 @@ __all__ = [
     "push",
     "zeros_init",
     "pallas_fallback_count",
+    "packed_refusal_count",
 ]

@@ -94,14 +94,16 @@ class FactorizationMachine(BatchedWorkerLogic):
 
 def make_store(
     config: FMConfig, *, seed: int = 0, init_stddev: float = 0.01, mesh=None,
-    dtype=None, scatter_impl: str = "xla", layout: str = "dense",
+    dtype=None, scatter_impl: str = "xla", layout: str = "auto",
 ) -> ShardedParamStore:
     """(num_features, 1+dim) store: w zero-init, v ~ N(0, init_stddev).
 
-    The FM row is NARROW (1+dim = 17 for Criteo shapes) — on TPU pass
-    ``layout="packed"`` (or "auto") to pack 7 rows per 128-lane physical
-    row: full vector lanes and pallas-scatter eligibility
-    (ops/packed.py)."""
+    The FM row is NARROW (1+dim = 17 for Criteo shapes), so the default
+    ``layout="auto"`` lets the store pick from what it sees
+    (``core/store._resolve_layout``): with the table on one shard, 7 rows
+    to a 128-lane physical row (ops/packed.py), pull and push then move
+    whole 128-lane rows; under ``ps > 1`` dense, warned of and counted.
+    ``"dense"`` and ``"packed"`` pin a layout."""
     dtype = dtype or jnp.float32
     vinit = normal_factor(seed, (config.dim,), stddev=init_stddev,
                           dtype=dtype)

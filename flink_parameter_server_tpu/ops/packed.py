@@ -13,11 +13,13 @@ logical rows live side-by-side in one ``(phys_capacity, 128)`` physical
 row.  Logical row ``r`` maps to physical row ``r // k``, lane offset
 ``(r % k) * d``:
 
-  * **pull** = one physical-row gather + one ``take_along_axis`` lane
-    slice (both vectorized XLA gathers, batch-sized),
-  * **push** = lane-shift each delta row to its offset (one batch-sized
-    gather), then scatter-add at PHYSICAL row granularity — which is
-    exactly the shape the pallas sorted-window kernel wants (width 128).
+  * **pull** = one gather of whole physical rows + the lane slice down to
+    the logical row: ``k`` static slices and a ``select`` on ``r % k``
+    (one pass over a batch-sized buffer, no per-element gather),
+  * **push** = lane-shift each delta row to its offset (``k`` static pads
+    and the same ``select``), then scatter-add at PHYSICAL row
+    granularity — which is exactly the shape the pallas kernels want
+    (width 128).
     Two logical rows sharing a physical row collide in different lanes,
     so the add semantics are unchanged, and Zipf-hot neighbours now
     share windows (fewer HBM round trips, fuller DMAs).
@@ -27,6 +29,7 @@ unmodified.  ``ShardedParamStore(layout="packed")`` wires it in.
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional, Tuple
 
 import jax
@@ -79,15 +82,36 @@ def unpack_table(packed: Array, capacity: int, row_width: int) -> Array:
     return v[:capacity]
 
 
-def packed_pull(packed: Array, ids: Array, row_width: int) -> Array:
-    """Gather logical rows ``ids`` (pre-clipped) from the packed table."""
-    k = pack_k(row_width)
-    ids = ids.astype(jnp.int32)
-    phys_vals = jnp.take(packed, ids // k, axis=0)  # (n, phys_width)
+def _sub_row_slice(rows: Array, ids: Array, row_width: int) -> Array:
+    """``rows[i, t*d:(t+1)*d]`` with ``t = ids[i] % k``: ``k`` STATIC lane
+    slices and a ``select`` on the sub-row index, one pass over the
+    batch-sized buffer.  Never a ``take_along_axis``: that is a gather of
+    scalars (~10 ns an element on the TPU, PERF.md section 6, PR 29), and
+    never a 0/1 matmul or a masked sum: ``0 * NaN`` would spread one
+    non-finite element over its physical row."""
+    k, d = pack_k(row_width), row_width
     if k == 1:
-        return phys_vals[:, :row_width]
-    cols = (ids % k)[:, None] * row_width + jnp.arange(row_width)[None, :]
-    return jnp.take_along_axis(phys_vals, cols, axis=1)
+        return rows[:, :d]
+    t = (ids.astype(jnp.int32) % k)[:, None]
+    out = rows[:, :d]
+    for j in range(1, k):
+        out = jnp.where(t == j, rows[:, j * d:(j + 1) * d], out)
+    return out
+
+
+@functools.partial(jax.jit, static_argnums=(2,))
+def packed_pull(packed: Array, ids: Array, row_width: int) -> Array:
+    """Gather logical rows ``ids`` (pre-clipped) from the packed table:
+    one gather of whole 128-lane physical rows, then the lane slice.
+    Jitted, so that a pull outside a jitted step (``store.pull(ids)`` by
+    hand, a checkpoint's spot check) is one program and not ``3 k`` eager
+    ones, each compiled on its first use."""
+    ids = ids.astype(jnp.int32)
+    # ids are in range, so no pass to fill rows that are not
+    phys_vals = jnp.take(
+        packed, ids // pack_k(row_width), axis=0, mode="clip"
+    )
+    return _sub_row_slice(phys_vals, ids, row_width)
 
 
 def lane_shift_deltas(deltas: Array, ids: Array, row_width: int) -> Array:
@@ -95,33 +119,27 @@ def lane_shift_deltas(deltas: Array, ids: Array, row_width: int) -> Array:
 
     Row ``i`` carries ``deltas[i]`` at lanes ``[(ids[i] % k) * d, ... + d)``
     and zeros elsewhere — ready to scatter-add at physical-row granularity.
+    ``k`` static pads chosen by a ``select`` on the sub-row index (see
+    :func:`_sub_row_slice` for what it must not be).
     """
     n, d = deltas.shape
     assert d == row_width, (d, row_width)
     k = pack_k(d)
     w = phys_width(d)
+    out = jnp.pad(deltas, ((0, 0), (0, w - d)))
     if k == 1:
-        return jnp.pad(deltas, ((0, 0), (0, w - d)))
-    t = (ids.astype(jnp.int32) % k)[:, None]  # (n, 1) sub-row index
-    lane = jnp.arange(w)[None, :]  # (1, w)
-    src = lane - t * d  # source column per output lane
-    valid = (src >= 0) & (src < d)
-    padded = jnp.pad(deltas, ((0, 0), (0, w - d)))
-    out = jnp.take_along_axis(padded, jnp.clip(src, 0, w - 1), axis=1)
-    return jnp.where(valid, out, jnp.zeros_like(out))
+        return out
+    t = (ids.astype(jnp.int32) % k)[:, None]
+    for j in range(1, k):
+        shifted = jnp.pad(deltas, ((0, 0), (j * d, w - (j + 1) * d)))
+        out = jnp.where(t == j, shifted, out)
+    return out
 
 
 def lane_unshift(rows: Array, ids: Array, row_width: int) -> Array:
     """Inverse of :func:`lane_shift_deltas`: slice each (phys_width,)
     row back down to the (row_width,) slice at its id's lane offset."""
-    k = pack_k(row_width)
-    if k == 1:
-        return rows[:, :row_width]
-    cols = (
-        (ids.astype(jnp.int32) % k)[:, None] * row_width
-        + jnp.arange(row_width)[None, :]
-    )
-    return jnp.take_along_axis(rows, cols, axis=1)
+    return _sub_row_slice(rows, ids, row_width)
 
 
 def packed_phys_ids(ids: Array, row_width: int) -> Array:

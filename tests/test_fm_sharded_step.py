@@ -43,10 +43,13 @@ def _train(cfg, mesh, n=3):
         if mesh is not None:  # one global batch, replicated to the chips
             batch = jax.device_put(batch, NamedSharding(mesh, PartitionSpec()))
         table, _, out = step(table, (), batch)
-    after = fam.rows(type(store)(store.spec, table), (), ids)
+    trained = type(store)(store.spec, table)
+    after = fam.rows(trained, (), ids)
     return {
         "want": ref.apply(cfg, before, ids, batches), "before": before,
-        "after": after, "table": np.asarray(table)[: cfg["num_features"]],
+        # the logical rows: on one device the store packs them 7 to a
+        # physical row by itself, under ``ps = 4`` it keeps them dense
+        "after": after, "table": np.asarray(trained.values()),
         "prediction": np.asarray(out["prediction"]), "store": store,
     }
 
@@ -91,10 +94,13 @@ def test_sharded_step_equals_the_one_device_step_bit_for_bit(runs):
     # Tolerance: none.  The batch is replicated, so every chip computes the
     # same deltas; the partitioned gather all-reduces each row with three
     # zeros (exact); the partitioned scatter-add lands a row's deltas on the
-    # shard that owns it in the order the one-device scatter adds them, so
-    # not a rounding differs.  (On the CPU; on the TPU the benchmark holds the
+    # shard that owns it in the order the one-device scatter adds them
+    # (into the packed table there: a row's deltas in its own 17 lanes of a
+    # physical row), so not a rounding differs.  (On the CPU; on the TPU the benchmark holds the
     # cell to the reference's allowance, which is what users are promised.)
     _, sharded, single = runs
+    assert (sharded["store"].spec.layout, single["store"].spec.layout) == (
+        "dense", "packed")
     np.testing.assert_array_equal(sharded["before"]["feature"],
                                   single["before"]["feature"])
     np.testing.assert_array_equal(sharded["table"], single["table"])

@@ -23,6 +23,9 @@ USERS, ITEMS, DIM, BATCH = 5_008_260, 39_780, 128, 65_536
 # fm-criteo-ps4: cell 4's table (17 f32 lanes pad to 24 on the chip: 16.8 GiB,
 # 4.51 GB a shard) and its one global batch
 FM_ROWS, FM_FIELDS, FM_BATCH = 187_767_412, 39, 32_768
+# fm-criteo: cell 2's table, on one chip (7 rows of 17 lanes to a 128-lane
+# physical row: 7,018,048 x 128 f32, 3.59 GB)
+FM1_ROWS, FM1_PHYS_ROWS = 49_126_310, 7_018_048
 GB = 1e9
 # an HLO line that APPLIES a collective (a use of its result is `%all-reduce,`)
 COLLECTIVE_OP = re.compile(
@@ -59,10 +62,40 @@ def ps4(topo):
     config = fmm.FMConfig(
         num_features=FM_ROWS, dim=16, learning_rate=1e-5, loss="logistic"
     )
-    spec = jax.eval_shape(
-        lambda: fmm.make_store(config, mesh=mesh, dtype=jnp.float32)
-    ).spec
+    # narrow rows, but sharded: the store keeps them dense and says so
+    with pytest.warns(RuntimeWarning, match="sharded over ps=4.*dense"):
+        spec = jax.eval_shape(
+            lambda: fmm.make_store(config, mesh=mesh, dtype=jnp.float32)
+        ).spec
+    assert spec.layout == "dense"
     return mesh, spec, fmm.FactorizationMachine(config)
+
+
+@pytest.fixture(scope="module")
+def fm1():
+    """``fm-criteo`` (chipbench/configs) as ``chipbench/families/fm.py``
+    builds it: ``make_store``'s own layout, nothing allocated."""
+    from flink_parameter_server_tpu.models import factorization_machine as fmm
+
+    config = fmm.FMConfig(
+        num_features=FM1_ROWS, dim=16, learning_rate=1e-5, loss="logistic"
+    )
+    spec = jax.eval_shape(
+        lambda: fmm.make_store(config, dtype=jnp.float32)
+    ).spec
+    assert spec.layout == "packed"
+    assert spec.table_shape() == (FM1_PHYS_ROWS, 128)
+    return spec, fmm.FactorizationMachine(config)
+
+
+def _fm_batch(sharding):
+    return {
+        "ids": _shape(sharding, (FM_BATCH, FM_FIELDS), jnp.int32),
+        "values": _shape(sharding, (FM_BATCH, FM_FIELDS), jnp.float32),
+        "feat_mask": _shape(sharding, (FM_BATCH, FM_FIELDS), jnp.bool_),
+        "label": _shape(sharding, (FM_BATCH,), jnp.float32),
+        "mask": _shape(sharding, (FM_BATCH,), jnp.bool_),
+    }
 
 
 @pytest.fixture()
@@ -199,14 +232,7 @@ def test_fm_step_on_four_chips_keeps_its_collective_under_ps_pull(
     carries ``ps.pull``: a device trace reads it under
     ``store.pull_device_ms`` (docs/observability.md)."""
     mesh, spec, logic = ps4
-    everywhere = NamedSharding(mesh, PartitionSpec())
-    batch = {
-        "ids": _shape(everywhere, (FM_BATCH, FM_FIELDS), jnp.int32),
-        "values": _shape(everywhere, (FM_BATCH, FM_FIELDS), jnp.float32),
-        "feat_mask": _shape(everywhere, (FM_BATCH, FM_FIELDS), jnp.bool_),
-        "label": _shape(everywhere, (FM_BATCH,), jnp.float32),
-        "mask": _shape(everywhere, (FM_BATCH,), jnp.bool_),
-    }
+    batch = _fm_batch(NamedSharding(mesh, PartitionSpec()))
     compiled = jax.jit(
         make_train_step(logic, spec), donate_argnums=(0, 1)
     ).lower(
@@ -224,3 +250,52 @@ def test_fm_step_on_four_chips_keeps_its_collective_under_ps_pull(
     assert "all-reduce(" in collectives[0]
     assert 'op_name="jit(step)/ps.pull/' in collectives[0]
 
+
+
+def test_fm_step_on_one_chip_moves_whole_128_lane_rows(
+        fm1, one_chip, no_compile_cache):
+    """Cell 2's step on the layout ``make_store`` resolves by itself: the
+    donated 3.59 GB table is updated in place, and every gather under
+    ``ps.pull`` / ``ps.push`` takes a whole 128-lane physical row.  A gather
+    with 1-element slices there (``take_along_axis`` for the lane slice or
+    the lane shift) is 10 ns an ELEMENT on the v5e: 460 ms and 3.4 s a
+    step (my chip run, PR 29)."""
+    spec, logic = fm1
+    compiled = jax.jit(
+        make_train_step(logic, spec), donate_argnums=(0, 1)
+    ).lower(
+        _shape(one_chip, spec.table_shape(), jnp.float32), (),
+        _fm_batch(one_chip),
+    ).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes > 3.59 * GB  # in place
+    assert mem.temp_size_in_bytes < 1.5 * GB
+    gathers = [
+        line for line in compiled.as_text().splitlines()
+        if " gather(" in line and re.search(r"ps\.(pull|push)", line)
+    ]
+    assert gathers and all(
+        "slice_sizes={1,128}" in line for line in gathers
+    ), gathers
+
+
+def test_packing_fm_s_table_fits_the_chip_chunk_by_chunk(
+        fm1, one_chip, no_compile_cache):
+    """``ShardedParamStore._place`` packs cell 2's 49.1 M x 17 rows (4.72 GB
+    as they lie on the chip) into the 3.59 GB table in one program that
+    holds one chunk beside them (``core/store._pack_rows``).  Reshaped all
+    at once the rows are first laid 128 lanes wide, 25 GB: the TPU compiler
+    refuses that here as the chip would."""
+    from flink_parameter_server_tpu.ops.packed import pack_table
+
+    spec, _ = fm1
+    values = _shape(one_chip, (FM1_ROWS, 17), jnp.float32)
+    mem = store_mod._pack_rows(spec).lower(values).compile().memory_analysis()
+    assert 3.59 * GB < mem.output_size_in_bytes < 3.6 * GB
+    assert mem.temp_size_in_bytes < 0.5 * GB
+    assert (
+        mem.argument_size_in_bytes + mem.output_size_in_bytes
+        + mem.temp_size_in_bytes
+    ) < 9 * GB  # of the chip's 16
+    with pytest.raises(jax.errors.JaxRuntimeError, match="RESOURCE_EXHAUSTED"):
+        jax.jit(lambda v: pack_table(v, FM1_PHYS_ROWS)).lower(values).compile()
