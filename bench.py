@@ -77,18 +77,8 @@ def tpu_updates_per_sec(
                 f"{sorted(valid)}"
             )
         dtype = valid[name]
-    # FPS_BENCH_FUSED=1: run the fused pull+SGD+push Pallas step
-    # (ops/pallas_mf.py) instead of the unfused gather->SGD->scatter.
-    # Single-shard TPU only — on a multi-chip slice the fused run stays
-    # single-chip (no mesh) so the flag never silently benchmarks the
-    # unfused path under a "fused" label.
-    fused_requested = os.environ.get("FPS_BENCH_FUSED", "0") == "1"
     if dim is None:
-        # The fused/pallas kernels need dim % 128 == 0 on real Mosaic;
-        # the unfused default stays at the reference-shaped 64.
-        raw = os.environ.get(
-            "FPS_BENCH_DIM", "128" if fused_requested else "64"
-        )
+        raw = os.environ.get("FPS_BENCH_DIM", "64")  # the reference's shape
         try:
             dim = int(raw)
         except ValueError:
@@ -97,23 +87,11 @@ def tpu_updates_per_sec(
             ) from None
         if dim <= 0:
             raise SystemExit(f"FPS_BENCH_DIM={dim}: must be positive")
-    # FPS_BENCH_SCATTER=pallas + FPS_BENCH_LAYOUT=packed: the sorted-
-    # window kernel on a lane-packed table (the TPU-native path for the
-    # reference's narrow dim-64 rows; ops/packed.py).  Validate both
-    # knobs BEFORE any use — an invalid value must exit with the clean
-    # one-liner, not a _resolve_layout traceback.
-    scatter_impl = os.environ.get("FPS_BENCH_SCATTER", "xla")
+    # validate BEFORE any use — an invalid value must exit with the clean
+    # one-liner, not a _resolve_layout traceback
     layout = os.environ.get("FPS_BENCH_LAYOUT", "dense")
-    if scatter_impl not in ("xla", "pallas", "xla_sorted"):
-        raise SystemExit(
-            f"FPS_BENCH_SCATTER={scatter_impl!r}: xla|pallas|xla_sorted"
-        )
     if layout not in ("dense", "packed", "auto"):
         raise SystemExit(f"FPS_BENCH_LAYOUT={layout!r}: dense|packed|auto")
-    presort_raw = os.environ.get("FPS_BENCH_PRESORT", "0")
-    if presort_raw not in ("0", "1"):
-        raise SystemExit(f"FPS_BENCH_PRESORT={presort_raw!r}: 0|1")
-    presort = presort_raw == "1"
     # validated up front with the other knobs: a typo must exit in
     # milliseconds, not after compile + warmup
     raw_reps = os.environ.get("FPS_BENCH_REPS", "3")
@@ -125,30 +103,13 @@ def tpu_updates_per_sec(
         ) from None
     if reps <= 0:
         raise SystemExit(f"FPS_BENCH_REPS={reps}: must be positive")
-    from flink_parameter_server_tpu.core.store import _resolve_layout
-
-    _resolves_packed = _resolve_layout(layout, "add", (dim,)) == "packed"
-    if (
-        fused_requested
-        and jax.default_backend() == "tpu"
-        and dim % 128
-        and not _resolves_packed
-    ):
-        raise SystemExit(
-            f"FPS_BENCH_FUSED=1 needs dim % 128 == 0 on TPU (Mosaic lane "
-            f"alignment); got dim={dim}. Set FPS_BENCH_DIM=128 or "
-            f"FPS_BENCH_LAYOUT=packed (the lane-packed kernel runs any "
-            f"width)."
-        )
-
     # Multi-chip TPU: shard over a dp × ps mesh and report PER-CHIP rate.
     # (Only on real TPUs — virtual CPU meshes on this 1-core host trip
     # XLA's collective-rendezvous watchdog at bench-scale steps.)
     mesh = None
     n_chips = 1
     if (
-        not fused_requested
-        and jax.default_backend() == "tpu"
+        jax.default_backend() == "tpu"
         and len(jax.devices()) > 1
         and jax.process_count() == 1  # single-process only: device_put to
         # non-addressable devices would crash on multi-host slices
@@ -160,46 +121,19 @@ def tpu_updates_per_sec(
         mesh = make_mesh(ps_parallelism=ps)  # dp absorbs the rest
         batch = batch * mesh.shape["dp"]  # scale work with dp
 
-    # off the chip a Pallas arm is an error, not another arm's number:
-    # interpret mode is no measurement of the kernel
-    if (fused_requested or scatter_impl == "pallas") and (
-        jax.default_backend() != "tpu"
-    ):
-        raise SystemExit(
-            f"FPS_BENCH_FUSED=1 / FPS_BENCH_SCATTER=pallas need the TPU "
-            f"backend (platform is {jax.default_backend()!r}): the kernels "
-            f"would run interpreted"
-        )
-    fused = fused_requested
-    # the fused kernel sorts internally (sorted-window DMA); a batch
-    # presort would be a second sort reported under the wrong knob
-    if presort and fused:
-        print(
-            "# FPS_BENCH_PRESORT=1 ignored: fused kernel sorts internally; "
-            "reporting presort=false",
-            file=sys.stderr,
-        )
-    presort = presort and not fused
-
     # lr matches cpu_per_record_baseline (both sides numerically stable).
-    # The sorted arm applies to BOTH scatters (item store + user state):
-    # hot users serialize the state RMW exactly like hot items do.
     logic = OnlineMatrixFactorization(
         num_users, dim, updater=SGDUpdater(0.01), dtype=dtype, mesh=mesh,
-        state_scatter=(
-            "xla_sorted" if scatter_impl == "xla_sorted" else "xla"
-        ),
     )
     store = ShardedParamStore.create(
         num_items, (dim,), dtype=dtype,
         init_fn=normal_factor(1, (dim,), dtype=dtype), mesh=mesh,
-        scatter_impl=scatter_impl, layout=layout,
+        layout=layout,
     )
     state = logic.init_state(jax.random.PRNGKey(0))
 
     rng = np.random.default_rng(0)
     items = ((rng.zipf(1.2, batch) - 1) % num_items).astype(np.int32)
-    unique_items = len(np.unique(items))
     data = {
         "user": jnp.asarray(rng.integers(0, num_users, batch).astype(np.int32)),
         "item": jnp.asarray(items),
@@ -213,32 +147,8 @@ def tpu_updates_per_sec(
         sh = NamedSharding(mesh, PartitionSpec("dp"))
         data = {k: jax.device_put(v, sh) for k, v in data.items()}
 
-    if fused:
-        from flink_parameter_server_tpu.ops.pallas_mf import (
-            make_fused_mf_train_step,
-        )
-
-        raw_chunk = os.environ.get("FPS_BENCH_FUSED_CHUNK", "1024")
-        try:
-            chunk = int(raw_chunk)
-        except ValueError:
-            raise SystemExit(
-                f"FPS_BENCH_FUSED_CHUNK={raw_chunk!r}: expected a positive "
-                f"integer"
-            ) from None
-        if chunk <= 0:
-            raise SystemExit(
-                f"FPS_BENCH_FUSED_CHUNK={chunk}: must be positive"
-            )
-        raw_step = make_fused_mf_train_step(
-            learning_rate=0.01, chunk=chunk,
-            layout=store.spec.layout,
-            capacity=num_items, dim=dim,
-        )
-        step = jax.jit(raw_step, donate_argnums=(0, 1))
-    else:
-        raw_step = make_train_step(logic, store.spec, presort=presort)
-        step = jax.jit(raw_step, donate_argnums=(0, 1))
+    raw_step = make_train_step(logic, store.spec)
+    step = jax.jit(raw_step, donate_argnums=(0, 1))
     table = store.table
     for _ in range(warmup_steps):
         table, state, out = step(table, state, data)
@@ -312,12 +222,9 @@ def tpu_updates_per_sec(
         p50_device_ms = float(np.percentile(np.array(dev_lats), 50) * 1e3)
 
     # HBM traffic model for the gather/scatter-bound MF step (the honest
-    # perf yardstick for a bandwidth-bound workload).  Unfused: each side
-    # (user state table, item store) does a batch-row gather (1 read) and
-    # a batch-row scatter RMW (1 read + 1 write) → 6 row-traversals.
-    # Fused (ops/pallas_mf.py): the item side touches each UNIQUE row
-    # once (1 read + 1 write) and the sort adds ~2 permute passes over
-    # the id/lane arrays; the user side is unchanged.
+    # perf yardstick for a bandwidth-bound workload): each side (user state
+    # table, item store) does a batch-row gather (1 read) and a batch-row
+    # scatter RMW (1 read + 1 write) → 6 row-traversals.
     el = jnp.dtype(dtype).itemsize
     # the packed layout moves full physical rows (128 lanes) per
     # pull/push regardless of the logical dim
@@ -327,44 +234,7 @@ def tpu_updates_per_sec(
         row_lanes = phys_width(dim)
     else:
         row_lanes = dim
-    # packed dedup (fused kernel windows, xla_sorted physical scatter)
-    # runs at PHYSICAL-row granularity
-    if store.spec.layout == "packed":
-        unique_phys = len(np.unique(items // store.spec.pack))
-    else:
-        unique_phys = unique_items
-    # batch presort (make_train_step): one argsort over the routed ids
-    # plus a permute (read+write) of the four batch columns
-    # (user+item int32, rating f32, mask bool)
-    presort_bytes = (8 * batch * 4 + 2 * batch * 13) if presort else 0
-    if fused:
-        # user side stays on XLA at dense dim (pallas_mf fuses only the
-        # item half); item side touches each unique (physical) row once
-        hbm_bytes_per_step = (
-            (3 * batch * dim + 2 * unique_phys * row_lanes) * el
-            + 8 * batch * 4  # id sort/permute passes (int32)
-        )
-    elif scatter_impl == "xla_sorted":
-        # per side: B-row gather + B-row delta permute (read+write —
-        # jnp.take(deltas, order) materializes in HBM) + UNIQUE-row
-        # scatter RMW + id sort passes.  Both sides run sorted (store
-        # push + state_scatter); under presort the store-side argsort
-        # is subsumed by the batch sort (ids_sorted fast path), so only
-        # the user-state sort remains.
-        uniq_i = unique_phys
-        uniq_u = len(np.unique(np.asarray(data["user"])))
-        # user state is always dense (dim lanes); only the store side
-        # moves packed physical rows
-        hbm_bytes_per_step = (
-            ((3 * batch + 2 * uniq_i) * row_lanes
-             + (3 * batch + 2 * uniq_u) * dim) * el
-            + (1 if presort else 2) * 8 * batch * 4
-            + presort_bytes
-        )
-    else:
-        hbm_bytes_per_step = (
-            3 * batch * (row_lanes + dim) * el + presort_bytes
-        )
+    hbm_bytes_per_step = 3 * batch * (row_lanes + dim) * el
     step_time = dt / bench_steps
     from flink_parameter_server_tpu.utils.device_peaks import device_peaks
 
@@ -381,11 +251,8 @@ def tpu_updates_per_sec(
         "batch": batch,
         "hbm_bytes_per_step": hbm_bytes_per_step,
         "bandwidth_util": bandwidth_util,
-        "fused_step": fused,
         "dim": dim,
-        "scatter_impl": scatter_impl,
         "layout": layout,
-        "presort": presort,
         "reps": reps,
         "rate_min": float(np.min(rep_rates)) / n_chips,
         "rate_max": float(np.max(rep_rates)) / n_chips,
@@ -1091,11 +958,8 @@ def _headline(device) -> dict:
             "table_dtype": r["table_dtype"],
             "hbm_bytes_per_step": r["hbm_bytes_per_step"],
             "bandwidth_util": round(util, 4) if util else None,
-            "fused_step": r["fused_step"],
             "dim": r["dim"],
-            "scatter_impl": r["scatter_impl"],
             "layout": r["layout"],
-            "presort": r["presort"],
             "reps": r["reps"],
             "rate_min": round(r["rate_min"], 1),
             "rate_max": round(r["rate_max"], 1),

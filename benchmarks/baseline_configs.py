@@ -31,33 +31,17 @@ def _is_tpu() -> bool:
 
 
 def _store_opts() -> dict:
-    """Store construction knobs for the sparse-PS configs (2/3/4):
-    FPS_CFG_SCATTER=xla|pallas, FPS_CFG_LAYOUT=dense|packed|auto.
-    pallas off the chip is an error (interpret mode is not the kernel)."""
-    scatter = os.environ.get("FPS_CFG_SCATTER", "xla")
+    """Store construction knob for the sparse-PS configs (2/3/4):
+    FPS_CFG_LAYOUT=dense|packed|auto."""
     layout = os.environ.get("FPS_CFG_LAYOUT", "dense")
-    if scatter not in ("xla", "pallas", "xla_sorted"):
-        # a typo would silently benchmark XLA while the JSON row records
-        # the typo as the pallas arm (bench.py has the same validation)
-        raise SystemExit(
-            f"FPS_CFG_SCATTER={scatter!r}: xla|pallas|xla_sorted"
-        )
     if layout not in ("dense", "packed", "auto"):
         raise SystemExit(f"FPS_CFG_LAYOUT={layout!r}: dense|packed|auto")
-    if scatter == "pallas" and not _is_tpu():
-        raise SystemExit(
-            "FPS_CFG_SCATTER=pallas needs the TPU backend: the kernel "
-            "would run interpreted"
-        )
-    return {"scatter_impl": scatter, "layout": layout}
+    return {"layout": layout}
 
 
 def _resolved(store) -> dict:
     """What actually ran (layout='auto' resolves at store creation)."""
-    return {
-        "scatter_impl": store.spec.scatter_impl,
-        "layout": store.spec.layout,
-    }
+    return {"layout": store.spec.layout}
 
 
 def _moved_lanes(store) -> int:

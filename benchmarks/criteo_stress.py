@@ -37,14 +37,9 @@ def main():
     ap.add_argument("--steps", type=int, default=50)
     ap.add_argument("--zipf", type=float, default=1.2)
     ap.add_argument(
-        "--scatter", default="pallas",
-        choices=["pallas", "xla", "xla_sorted"],
-    )
-    ap.add_argument(
         "--layout", default="packed", choices=["packed", "dense"],
         help="packed = k narrow rows per 128-lane physical row "
-        "(ops/packed.py) — required for the pallas kernel at FM's "
-        "17-wide rows on real Mosaic",
+        "(ops/packed.py)",
     )
     ap.add_argument(
         "--cpu-scale", action="store_true",
@@ -72,14 +67,6 @@ def main():
 
     if args.cpu_scale:
         args.rows, args.batch, args.steps = 1_048_576, 4_096, 10
-    if platform != "tpu" and args.scatter == "pallas":
-        # interpret-mode pallas is a logic tool, not the kernel — at
-        # stress batch sizes it would run for hours on the host
-        raise SystemExit(
-            f"--scatter pallas needs the TPU backend (platform is "
-            f"{platform!r}); pass --scatter xla or xla_sorted"
-        )
-
     F, K, B, dim = args.rows, args.feats, args.batch, args.dim
     dtype = jnp.bfloat16
 
@@ -96,7 +83,7 @@ def main():
     t0 = time.perf_counter()
     store = ShardedParamStore.create(
         F, (1 + dim,), dtype=dtype, init_fn=init,
-        scatter_impl=args.scatter, layout=args.layout,
+        layout=args.layout,
     )
     jax.block_until_ready(store.table)
     t_init = time.perf_counter() - t0
@@ -162,7 +149,6 @@ def main():
             {
                 "config": "criteo-stress-fm",
                 "platform": platform,
-                "scatter_impl": args.scatter,
                 "table_rows": F,
                 "table_gib": round(table_bytes / 2**30, 3),
                 "table_dtype": "bfloat16",

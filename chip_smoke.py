@@ -530,13 +530,13 @@ class Smoke:
     # -- stage 3: kernels ---------------------------------------------------
     def _kernel_case(self, name, fn, args, check, *, mosaic=True) -> None:
         """Compile ``fn`` once, require a Mosaic call in its lowering (on
-        the chip), run it, and hand the result to ``check``.  The store's
-        fallback counter must not move: a pass on the XLA scatter would
-        prove nothing about the kernel."""
-        from flink_parameter_server_tpu.core.store import pallas_fallback_count
+        the chip), run it, and hand the result to ``check``.  The row
+        kernel's refusal counter must not move: a pass on the XLA scatter
+        would prove nothing about the kernel."""
+        from flink_parameter_server_tpu.ops.row_update import refusal_count
 
         jax = self.jax
-        before = pallas_fallback_count()
+        before = refusal_count()
         jitted = jax.jit(fn)
         t0 = time.perf_counter()
         if mosaic and not self.dry_run:
@@ -548,7 +548,7 @@ class Smoke:
         setup_s = time.perf_counter() - t0
         err = check(got)
         require(
-            pallas_fallback_count() == before,
+            refusal_count() == before,
             f"{name}: fell back to the XLA scatter",
         )
         self.report(
@@ -589,19 +589,10 @@ class Smoke:
             OnlineMatrixFactorization,
             SGDUpdater,
         )
-        from flink_parameter_server_tpu.ops import packed as pk
-        from flink_parameter_server_tpu.ops import pallas_scatter, row_update
+        from flink_parameter_server_tpu.ops import row_update
         from flink_parameter_server_tpu.ops.flash_attention import (
             flash_mha,
             flash_mha_dp,
-        )
-        from flink_parameter_server_tpu.ops.pallas_mf import (
-            fused_mf_sgd_sharded,
-            make_fused_mf_train_step,
-        )
-        from flink_parameter_server_tpu.ops.pallas_scatter import WINDOW
-        from flink_parameter_server_tpu.ops.sorted_scatter import (
-            sorted_dedup_scatter_add,
         )
         from flink_parameter_server_tpu.parallel.mesh import make_mesh
         from flink_parameter_server_tpu.parallel.ring_attention import (
@@ -618,31 +609,8 @@ class Smoke:
         def normal(shape, scale=1.0, dtype=jnp.float32):
             return jnp.asarray(rng.normal(size=shape) * scale, dtype)
 
-        # ops/pallas_scatter.scatter_add, dense rows of 128 lanes
         cap, d = 1024, 128
-        table, ids, deltas = normal((cap, d)), zipf_ids(cap), normal((n, d))
-        want = table.at[ids].add(deltas)
-        self._kernel_case(
-            "scatter_dense_d128_f32",
-            lambda t, i, dl: pallas_scatter.scatter_add(
-                t, i, dl, interpret=interpret),
-            (table, ids, deltas), close(want, 1e-3),
-        )
-        # the pure-XLA dedup arm on the same lanes: its unique_indices /
-        # indices_are_sorted promises must hold compiled, too
-        self._kernel_case(
-            "scatter_xla_sorted_d128_f32", sorted_dedup_scatter_add,
-            (table, ids, deltas), close(want, 1e-3), mosaic=False,
-        )
-        order = jnp.argsort(ids)
-        ids_asc, deltas_asc = ids[order], deltas[order]
-        self._kernel_case(
-            "scatter_xla_sorted_presorted_d128_f32",
-            lambda t, i, dl: sorted_dedup_scatter_add(
-                t, i, dl, ids_sorted=True),
-            (table, ids_asc, deltas_asc),
-            close(table.at[ids_asc].add(deltas_asc), 1e-3), mosaic=False,
-        )
+        table, deltas = normal((cap, d)), normal((n, d))
 
         # ops/row_update at the shape class of the MF cells' user state:
         # f32 rows of 128 lanes, a row count that no 8 divides, uniform
@@ -687,59 +655,14 @@ class Smoke:
             close(step_x(table, state_u, batch_u), 1e-3),
         )
 
-        # bfloat16 table: the kernel sums a window in f32 and rounds once,
-        # XLA rounds per add — judge both against the f32 oracle
-        table16, deltas16 = (
-            table.astype(jnp.bfloat16), deltas.astype(jnp.bfloat16)
-        )
-        oracle = table16.astype(jnp.float32).at[ids].add(
-            deltas16.astype(jnp.float32)
-        )
-        err_xla = float(jnp.max(jnp.abs(
-            table16.at[ids].add(deltas16).astype(jnp.float32) - oracle
-        )))
-
-        def check_bf16(got):
-            err = float(jnp.max(jnp.abs(got.astype(jnp.float32) - oracle)))
-            require(
-                err <= err_xla * 1.05 + 1e-3,
-                f"kernel vs f32 {err:.3e} worse than XLA's {err_xla:.3e}",
-            )
-            return err
-
-        self._kernel_case(
-            "scatter_dense_d128_bf16",
-            lambda t, i, dl: pallas_scatter.scatter_add(
-                t, i, dl, interpret=interpret),
-            (table16, ids, deltas16), check_bf16,
-        )
-
-        # lane-packed tables: MF's dim 64 (sub_k=2), FM's dim 16 (sub_k=8)
-        for dl_w in (64, 16):
-            capL = 1000
-            vals = normal((capL, dl_w))
-            nphys = -(-pk.phys_rows(capL, dl_w) // WINDOW) * WINDOW
-            idsL, deltasL = zipf_ids(capL), normal((n, dl_w))
-            wantL = vals.at[idsL].add(deltasL)
-            self._kernel_case(
-                f"scatter_packed_d{dl_w}_sub_k{pk.pack_k(dl_w)}_f32",
-                lambda t, i, dl, w=dl_w: pk.unpack_table(
-                    pallas_scatter.scatter_add(
-                        t, i, dl, interpret=interpret,
-                        sub_k=pk.pack_k(w), sub_width=w),
-                    capL, w),
-                (pk.pack_table(vals, nphys), idsL, deltasL),
-                close(wantL, 1e-3),
-            )
-
-        # the store-level selection: StoreSpec(scatter_impl="pallas",
-        # layout="packed") at dim 64 through ShardedParamStore.push
+        # the packed store (ops/packed.py) at MF's dim 64, two rows to a
+        # 128-lane row, through ShardedParamStore.push: XLA ops only
         def store_case(name, mesh):
             cap_s, dim_s = 4096, 64
             init = normal((cap_s, dim_s), 0.1)
             ids_s, deltas_s = zipf_ids(cap_s), normal((n, dim_s))
             store = ShardedParamStore.from_values(
-                init, scatter_impl="pallas", layout="packed", mesh=mesh
+                init, layout="packed", mesh=mesh
             )
             require(store.spec.layout == "packed", store.spec.layout)
             self._kernel_case(
@@ -747,65 +670,12 @@ class Smoke:
                 lambda t, i, dl: ShardedParamStore(store.spec, t)
                 .push(i, dl).values(),
                 (store.table, ids_s, deltas_s),
-                close(init.at[ids_s].add(deltas_s), 1e-3),
+                close(init.at[ids_s].add(deltas_s), 1e-3), mosaic=False,
             )
 
-        store_case("store_push_pallas_packed_d64", None)
+        store_case("store_push_packed_d64", None)
         if self.mesh is not None:
-            store_case("store_push_pallas_packed_d64_dp2xps2", self.mesh)
-
-        # ops/pallas_mf through make_fused_mf_train_step, dense and packed
-        lr = 0.05
-        users = jnp.asarray(rng.integers(0, 512, n), jnp.int32)
-        ratings = normal((n,))
-
-        def mf_reference(u_tab, i_tab, items):
-            q, p = i_tab[items], u_tab[users]
-            pred = jnp.sum(p * q, axis=1)
-            e = lr * (ratings - pred)
-            return (
-                i_tab.at[items].add(e[:, None] * p),
-                u_tab.at[users].add(e[:, None] * q),
-                pred,
-            )
-
-        def fused_case(name, dim_f, cap_f, layout):
-            u_tab, i_tab = normal((512, dim_f), 0.1), normal((cap_f, dim_f), 0.1)
-            items = zipf_ids(cap_f)
-            store = ShardedParamStore.from_values(i_tab, layout=layout)
-            step = make_fused_mf_train_step(
-                learning_rate=lr, interpret=interpret, layout=layout,
-                capacity=cap_f, dim=dim_f,
-            )
-            batch = {"user": users, "item": items, "rating": ratings}
-
-            def fn(t, u, b):
-                t, u, out = step(t, u, b)
-                return (
-                    ShardedParamStore(store.spec, t).values(), u,
-                    out["prediction"],
-                )
-
-            self._kernel_case(
-                name, fn, (store.table, u_tab, batch),
-                close(mf_reference(u_tab, i_tab, items), 1e-3),
-            )
-
-        fused_case("fused_mf_dense_d128", 128, 1024, "dense")
-        fused_case("fused_mf_packed_d64", 64, 1000, "packed")
-        if self.mesh is not None:
-            ps_mesh = make_mesh(1, 4, devices=jax.devices()[:4])
-            u_tab, i_tab = normal((512, 128), 0.1), normal((1024, 128), 0.1)
-            items = zipf_ids(1024)
-            want_i, want_u, want_p = mf_reference(u_tab, i_tab, items)
-            self._kernel_case(
-                "fused_mf_sharded_ps4_d128",
-                lambda u, t, us, im, r: fused_mf_sgd_sharded(
-                    u, t, us, im, r, mesh=ps_mesh, learning_rate=lr,
-                    interpret=interpret),
-                (u_tab, i_tab, users, items, ratings),
-                close((want_u, want_i, want_p), 1e-3),
-            )
+            store_case("store_push_packed_d64_dp2xps2", self.mesh)
 
         # splash flash attention: forward, gradient, and under shard_map
         B, T, H, D = 2, self.sizes.flash_seq, 4, 64

@@ -8,8 +8,7 @@ Usage (ParameterTool-style args — utils/config.py):
     python examples/online_mf_movielens.py [--path ratings-file]
         [--socket host:port] [--num-users N] [--num-items M]
         [--dim 32] [--lr 0.05] [--epochs 3] [--batch 4096]
-        [--scatter xla|pallas|xla_sorted] [--layout dense|packed|auto]
-        [--presort 0|1] [--steps-per-call 1] [--chaos SEED]
+        [--layout dense|packed|auto] [--steps-per-call 1] [--chaos SEED]
         [--telemetry-port P]
 
 ``--telemetry-port P`` serves the unified metrics plane live while the
@@ -86,7 +85,6 @@ def _run_with_chaos(params, make_stream, *, num_users, num_items, mesh):
         (params.get_int("dim", 32),),
         init_fn=ranged_random_factor(1, (params.get_int("dim", 32),)),
         mesh=mesh,
-        scatter_impl=params.get("scatter", "xla"),
         layout=params.get("layout", "dense"),
     )
     workdir = tempfile.mkdtemp(prefix="fps_chaos_demo_")
@@ -97,7 +95,6 @@ def _run_with_chaos(params, make_stream, *, num_users, num_items, mesh):
             checkpoint_every=params.get_int("checkpoint-every", 10),
             checkpoint_dir=f"{workdir}/ckpt",
             wal_dir=f"{workdir}/wal",
-            presort=params.get_bool("presort", False),
             steps_per_call=params.get_int("steps-per-call", 1),
         ),
     )
@@ -149,14 +146,12 @@ def _run_with_driver(params, stream, *, num_users, num_items, mesh):
         num_items, (dim,),
         init_fn=ranged_random_factor(1, (dim,)),
         mesh=mesh,
-        scatter_impl=params.get("scatter", "xla"),
         layout=params.get("layout", "dense"),
     )
     driver = StreamingDriver(
         logic, store,
         config=DriverConfig(
             dump_model=False,
-            presort=params.get_bool("presort", False),
             steps_per_call=params.get_int("steps-per-call", 1),
         ),
     )
@@ -282,9 +277,7 @@ def main():
             learning_rate=params.get_float("lr", 0.05),
             mesh=mesh,
             collect_outputs=False,
-            scatter_impl=params.get("scatter", "xla"),
             layout=params.get("layout", "dense"),
-            presort=params.get_bool("presort", False),
             steps_per_call=params.get_int("steps-per-call", 1),
         )
     uf = np.asarray(res.worker_state)

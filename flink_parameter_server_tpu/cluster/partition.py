@@ -12,7 +12,7 @@ and shard must agree), and resize-friendly, so two maps are offered:
     :class:`~..core.store.StoreSpec` already gives a mesh-sharded table
     (row-block sharding over the ``ps`` axis), so a cluster deployed
     this way is byte-compatible with the single-process sharded store.
-    Locality-friendly (a presorted batch walks shards in order), but a
+    Locality-friendly (a batch sorted by key walks shards in order), but a
     shard-count change moves every boundary.
 
   * :class:`ConsistentHashPartitioner` — highest-random-weight

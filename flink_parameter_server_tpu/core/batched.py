@@ -64,17 +64,5 @@ class BatchedWorkerLogic(abc.ABC, Generic[State, Batch, Out]):
         vectors) — counterpart of ``WorkerLogic.close``."""
         return None
 
-    def per_record_leaves(self, batch: Batch) -> Any:
-        """Optional presort contract: a pytree of bools with ``batch``'s
-        structure, True for leaves indexed per record (leading dim =
-        record index).  When overridden, ``presort=True`` permutes
-        exactly the True leaves and VALIDATES their leading dims at
-        trace time — replacing the shape-based default (permute every
-        leaf whose leading dim equals the key count), whose documented
-        trap is a non-per-record leaf that coincidentally matches the
-        batch size (e.g. a (batch, d) per-step constant table).
-        Return ``None`` (the default) to keep the heuristic."""
-        return None
-
 
 __all__ = ["PushRequest", "BatchedWorkerLogic"]

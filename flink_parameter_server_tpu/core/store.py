@@ -37,40 +37,11 @@ from typing import Any, Callable, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 Array = jax.Array
 InitFn = Callable[[Array], Array]  # ids (n,) int32 -> values (n, *value_shape)
 UpdateFn = Callable[[Array, Array], Array]  # (current, combined_delta) -> new
-
-# Trace-time count of pushes where a non-default scatter_impl ("pallas",
-# "xla_sorted") had to fall back to the XLA scatter (batch not divisible
-# by dp, Mosaic shape violation).  The choice is static per compiled
-# step, so one warning per offending trace suffices — a user who
-# configured a specific impl must never *silently* not get it (a bench
-# row would then mislabel which arm actually ran).
-_PALLAS_FALLBACKS = 0
-
-
-def pallas_fallback_count() -> int:
-    return _PALLAS_FALLBACKS
-
-
-def _note_scatter_fallback(impl: str, reason: str) -> None:
-    global _PALLAS_FALLBACKS
-    _PALLAS_FALLBACKS += 1
-    warnings.warn(
-        f"scatter_impl={impl!r} store falling back to XLA scatter: "
-        f"{reason}",
-        RuntimeWarning,
-        stacklevel=3,
-    )
-
-
-def _note_pallas_fallback(reason: str) -> None:
-    _note_scatter_fallback("pallas", reason)
-
 
 # How often layout="auto" wanted the packed layout and a mesh kept the
 # table dense (one per store built so; `_resolve_layout` says why).
@@ -79,19 +50,6 @@ _PACKED_REFUSALS = 0
 
 def packed_refusal_count() -> int:
     return _PACKED_REFUSALS
-
-
-def _dp_axis_and_divisible(mesh, n: int):
-    """(dp_axis or None, batch-divisibility ok) — the shared gate for
-    dispatching a push through shard_push_add's all_gather plane."""
-    from ..parallel.mesh import DP_AXIS
-
-    dp_axis = (
-        DP_AXIS
-        if DP_AXIS in mesh.axis_names and mesh.shape[DP_AXIS] > 1
-        else None
-    )
-    return dp_axis, (dp_axis is None or n % mesh.shape[dp_axis] == 0)
 
 
 def _resolve_layout(
@@ -161,32 +119,16 @@ class StoreSpec:
     # generic dense-update path (see module docstring; intra-batch
     # duplicate deltas are always summed before `update` is applied).
     update: Union[str, UpdateFn] = "add"
-    # "xla" = native XLA scatter; "pallas" = the sorted-run duplicate
-    # -compressing TPU kernel (ops/pallas_scatter.py) — wins under Zipf-hot
-    # id distributions; only valid with update="add" and vector values.
-    # "xla_sorted" = duplicate compression in pure XLA (sort + segment-sum
-    # + unique_indices scatter, ops/sorted_scatter.py) — no Mosaic shape
-    # constraints, runs on any backend; only valid with update="add".
-    scatter_impl: str = "xla"
     mesh: Optional[Mesh] = None
     ps_axis: str = "ps"
     # "dense": one logical row per physical row (the trivial layout).
     # "packed": k = 128 // row_width logical rows per 128-lane physical
     #   row (ops/packed.py) — the TPU-native layout for narrow values
-    #   (MF dim 64, FM dim 17): full vector lanes on every pull/push and
-    #   pallas-kernel eligibility at any width.  Requires update="add".
+    #   (MF dim 64, FM dim 17): full vector lanes on every pull/push.
+    #   Requires update="add".
     layout: str = "dense"
 
     def __post_init__(self) -> None:
-        # A user who configured a specific impl must never silently not
-        # get it: a typo like "sorted" or "xla-sorted" would otherwise
-        # fall through every `== "pallas"` / `== "xla_sorted"` dispatch
-        # and run the plain XLA scatter without a word.
-        valid = ("xla", "pallas", "xla_sorted")
-        if self.scatter_impl not in valid:
-            raise ValueError(
-                f"scatter_impl={self.scatter_impl!r} is not one of {valid}"
-            )
         if self.layout not in ("dense", "packed"):
             raise ValueError(
                 f"layout={self.layout!r} is not one of ('dense', 'packed')"
@@ -216,12 +158,9 @@ class StoreSpec:
 
     @property
     def rows_per_shard(self) -> int:
-        """Per-shard PHYSICAL row count, window-aligned for the pallas
-        kernel.
-
-        Real Mosaic reads/writes the table in aligned 8-row windows
-        (ops/pallas_scatter.WINDOW); aligning every shard's block here
-        means the kernel path never needs a pad-copy of the table."""
+        """Per-shard PHYSICAL row count, aligned to 8 rows: the sublane
+        tile of a float32 table on the TPU, so every shard's block starts
+        and ends on a tile."""
         n = self.num_shards
         logical = (self.capacity + self.pack - 1) // self.pack
         per = (logical + n - 1) // n
@@ -308,7 +247,7 @@ def pull(spec: StoreSpec, table: Array, ids: Array) -> Array:
 def _phys_scatter_args(
     spec: StoreSpec, table: Array, flat_ids: Array, flat_deltas: Array
 ):
-    """(ids, deltas) at PHYSICAL granularity for XLA/sharded scatters.
+    """(ids, deltas) at PHYSICAL granularity for the scatter-add.
 
     Dense: passthrough.  Packed: lane-shift each delta row to its
     sub-row offset and divide ids down to physical rows (the sentinel
@@ -332,25 +271,15 @@ def push(
     ids: Array,
     deltas: Array,
     mask: Optional[Array] = None,
-    *,
-    ids_sorted: bool = False,
 ) -> Array:
     """Batched push: fold ``deltas`` into rows ``ids`` (sharded scatter).
 
     ``mask`` (same leading shape as ``ids``) zeroes out padding lanes — the
     jit-friendly replacement for the reference's variable-length message
     batches (SURVEY.md §7 "Dynamic shapes").  Out-of-range ids are dropped
-    (``mode="drop"``), matching :func:`..parallel.collectives.shard_push_add`.
-
-    ``ids_sorted=True`` is the caller's promise that ``ids`` is ascending
-    with any NEGATIVE lanes at the end (make_train_step's ``presort``
-    sorts by the routed key, which guarantees exactly this): the
-    plain-"xla" scatter then tells XLA ``indices_are_sorted`` (any shard
-    count — that branch never reorders lanes) and "xla_sorted" skips its
-    argsort at ANY shard count (the dp split of a sorted array is
-    contiguous chunks, reassembled in order by the tiled all_gather —
-    see :func:`..parallel.collectives.shard_push_add`).  The pallas
-    shard_map push ignores it (the kernel sorts in-kernel).
+    (``mode="drop"``).  ``update="add"`` is ONE XLA scatter-add, which sums
+    the deltas of a row in the order the batch holds them (part of what the
+    benchmark's reference checks; PERF.md section 6, PR 27 and PR 30).
     """
     vr = len(spec.value_shape)
     lead = tuple(deltas.shape[: deltas.ndim - vr])
@@ -386,130 +315,10 @@ def push(
         )
 
     if spec.update == "add":
-        if spec.scatter_impl == "pallas":
-            from ..ops import pallas_scatter as _pallas
-
-            # Real Mosaic constrains the compiled kernel's shapes
-            # (dim % 128, capacity % 8 — compiled on a v5e by
-            # chip_smoke.py).  Interpreter mode (non-TPU)
-            # has no dim constraint; capacity is window-aligned by
-            # rows_per_shard either way.  The packed layout is always
-            # eligible (physical width 128 by construction).
-            kernel_width = (
-                int(np.prod(table.shape[1:]))
-                if spec.layout == "packed"
-                else spec.row_width
-            )
-            shapes_ok = jax.default_backend() != "tpu" or _pallas.supports_shape(
-                spec.rows_per_shard, kernel_width
-            )
-            if not shapes_ok:
-                _note_pallas_fallback(
-                    f"table row width {kernel_width} not a multiple of 128 "
-                    f"(Mosaic lane alignment; use layout='packed')"
-                )
-            elif spec.num_shards == 1:
-                if (
-                    spec.layout == "packed"
-                    and 1 < spec.pack <= _pallas.MAX_INKERNEL_SUB_K
-                ):
-                    # logical ids + logical-width deltas: the kernel
-                    # lane-shifts in-register, so the HBM delta buffer
-                    # never pays the 128-lane expansion
-                    return _pallas.scatter_add(
-                        table,
-                        flat_ids,
-                        flat_deltas.reshape(-1, spec.row_width),
-                        None,
-                        sub_k=spec.pack,
-                        sub_width=spec.row_width,
-                    )
-                if spec.layout == "packed":
-                    # pack == 1 (row width 65..127 or a non-multiple of
-                    # 128 above it: lane-padded, not packed) and very
-                    # narrow rows (e.g. scalars, pack=128, where sub_k
-                    # unrolled in-kernel rolls would dominate): pre-shift
-                    # XLA-side and scatter at physical granularity
-                    s_ids, s_deltas = _phys_scatter_args(
-                        spec, table, flat_ids, flat_deltas
-                    )
-                    return _pallas.scatter_add(table, s_ids, s_deltas, None)
-                return _pallas.scatter_add(
-                    table, flat_ids, flat_deltas,
-                    None if mask is None else flat_mask,
-                )
-            else:
-                # Sharded: run the kernel per ps shard under shard_map
-                # (the explicit collective plane).  Requires the flat
-                # batch length to divide the dp size for the all_gather
-                # specs; otherwise fall back to XLA scatter.
-                from ..parallel.collectives import shard_push_add
-
-                s_ids, s_deltas = _phys_scatter_args(
-                    spec, table, flat_ids, flat_deltas
-                )
-                n = s_ids.shape[0]
-                dp_axis, divisible = _dp_axis_and_divisible(spec.mesh, n)
-                if divisible:
-                    # mask=None: masked lanes' deltas were zeroed above,
-                    # so a no-op under add — skip the extra mask all_gather
-                    return shard_push_add(
-                        table,
-                        s_ids,
-                        s_deltas,
-                        None,
-                        mesh=spec.mesh,
-                        ps_axis=spec.ps_axis,
-                        dp_axis=dp_axis,
-                        impl="pallas",
-                    )
-                _note_pallas_fallback(
-                    f"flat batch {n} not divisible by "
-                    f"dp={spec.mesh.shape[dp_axis]}"
-                )
         s_ids, s_deltas = _phys_scatter_args(
             spec, table, flat_ids, flat_deltas
         )
-        if spec.scatter_impl == "xla_sorted":
-            # duplicate compression in pure XLA (ops/sorted_scatter.py):
-            # for the packed layout this runs at PHYSICAL granularity, so
-            # Zipf-hot neighbours sharing a physical row combine too
-            if spec.num_shards == 1:
-                from ..ops.sorted_scatter import sorted_dedup_scatter_add
-
-                # ids_sorted survives _phys_scatter_args: the packed
-                # physical id (logical // pack) is monotone and the
-                # negative-lane sentinel (padded_capacity, routed above)
-                # maps to exactly the physical row count = oob
-                return sorted_dedup_scatter_add(
-                    table, s_ids, s_deltas, None,
-                    oob=table.shape[0], ids_sorted=ids_sorted,
-                )
-            from ..parallel.collectives import shard_push_add
-
-            n = s_ids.shape[0]
-            dp_axis, divisible = _dp_axis_and_divisible(spec.mesh, n)
-            if divisible:
-                # the dp split of a globally sorted id array is
-                # contiguous chunks, reassembled in order by the tiled
-                # all_gather — the promise survives sharding
-                return shard_push_add(
-                    table, s_ids, s_deltas, None,
-                    mesh=spec.mesh, ps_axis=spec.ps_axis, dp_axis=dp_axis,
-                    impl="xla_sorted", ids_sorted=ids_sorted,
-                )
-            # plain XLA scatter is still correct — but never silent
-            _note_scatter_fallback(
-                "xla_sorted",
-                f"flat batch {n} not divisible by "
-                f"dp={spec.mesh.shape[dp_axis]}",
-            )
-        # (valid even sharded: this branch never reorders lanes — GSPMD
-        # sees the logical, still-ascending id array)
-        return table.at[s_ids].add(
-            s_deltas.astype(table.dtype), mode="drop",
-            indices_are_sorted=ids_sorted,
-        )
+        return table.at[s_ids].add(s_deltas.astype(table.dtype), mode="drop")
 
     # Generic path: combine duplicates densely, then apply `update` once per
     # touched row.  O(capacity) per step — documented slow path; the add
@@ -630,7 +439,6 @@ class ShardedParamStore:
         dtype: Any = jnp.float32,
         init_fn: Optional[InitFn] = None,
         update: Union[str, UpdateFn] = "add",
-        scatter_impl: str = "xla",
         mesh: Optional[Mesh] = None,
         ps_axis: str = "ps",
         layout: str = "dense",
@@ -640,7 +448,6 @@ class ShardedParamStore:
             value_shape=tuple(value_shape),
             dtype=dtype,
             update=update,
-            scatter_impl=scatter_impl,
             mesh=mesh,
             ps_axis=ps_axis,
             layout=_resolve_layout(
@@ -656,7 +463,6 @@ class ShardedParamStore:
         values: Array,
         *,
         update: Union[str, UpdateFn] = "add",
-        scatter_impl: str = "xla",
         mesh: Optional[Mesh] = None,
         ps_axis: str = "ps",
         layout: str = "dense",
@@ -669,7 +475,6 @@ class ShardedParamStore:
             value_shape=tuple(values.shape[1:]),
             dtype=values.dtype,
             update=update,
-            scatter_impl=scatter_impl,
             mesh=mesh,
             ps_axis=ps_axis,
             layout=_resolve_layout(
@@ -684,7 +489,7 @@ class ShardedParamStore:
         cls, spec: StoreSpec, values: Array
     ) -> "ShardedParamStore":
         """Seed a store carrying the *full* target ``spec`` (update rule,
-        ``scatter_impl``, mesh layout) from an unpadded ``(capacity, ...)``
+        mesh, layout) from an unpadded ``(capacity, ...)``
         value array — the checkpoint-restore path, which must not drop
         spec fields the way a shape-inferred rebuild would."""
         return cls(spec, cls._place(spec, values.astype(spec.dtype)))
@@ -748,6 +553,5 @@ __all__ = [
     "pull",
     "push",
     "zeros_init",
-    "pallas_fallback_count",
     "packed_refusal_count",
 ]

@@ -292,103 +292,18 @@ def _instances(factory_or_instance, n: int, what: str) -> List[Any]:
 # ---------------------------------------------------------------------------
 
 
-def make_train_step(
-    logic: BatchedWorkerLogic,
-    spec,
-    *,
-    presort: bool = False,
-) -> Callable:
+def make_train_step(logic: BatchedWorkerLogic, spec) -> Callable:
     """Build the fused pull→compute→push step (to be jit-compiled).
 
     One call = one microbatch of "events": the reference's per-message hot
     loop (SURVEY.md §3.1) collapsed into gather → math → scatter-add with
-    zero host round-trips.
-
-    ``presort=True``: re-order the whole microbatch by ascending store
-    key on-device before the pull.  Random-row HBM traffic is the MF
-    step's measured bottleneck (r2 trace: gather + scatter at ~3% of
-    HBM peak); sorting makes the pull gather walk ascending addresses
-    and hands the push an ``ids_sorted`` promise, so the plain scatter
-    gets ``indices_are_sorted`` and the "xla_sorted" dedup skips its
-    own argsort — one TPU sort (0.03 ms @64k, 1.3% of the r2 step) buys
-    locality on every table touch.  Sorting changes f32 summation order
-    only (same set of updates per row).  Worker outputs come back in
-    SORTED order; per-record output consumers that need stream order
-    should keep presort off.
-
-    Caveat: by default "the whole microbatch" means every pytree leaf
-    whose leading dimension equals the key count — that is the
-    per-record contract of :mod:`..data.streams` batches.  A logic
-    whose batch carries a NON-per-record array that coincidentally has
-    the batch size as its leading dim (e.g. a (batch, d) per-step
-    constant table) would get its rows permuted too — such logics
-    should override ``BatchedWorkerLogic.per_record_leaves`` to declare
-    exactly which leaves are per-record, which both exempts the
-    constants and turns the convention into a trace-time-validated
-    contract (a declared leaf with the wrong leading dim raises).
+    zero host round-trips.  The batch is taken as the stream delivered it:
+    worker outputs are in stream order, and the push sums a row's deltas
+    in that order.
     """
     from . import store as store_mod
 
     def step(table, state, batch):
-        if presort:
-            ids_pre = logic.keys(batch)
-            ids0 = jnp.asarray(ids_pre).astype(jnp.int32)
-            if ids0.ndim != 1:
-                # multi-pull logics (e.g. PA: (B, K) feature ids) have
-                # no single per-record sort key — argsort along the
-                # wrong axis would silently permute garbage
-                raise ValueError(
-                    f"presort=True needs 1-D store keys, got shape "
-                    f"{tuple(ids0.shape)} (multi-pull logics are not "
-                    f"presortable)"
-                )
-            # sort by the ROUTED key (negatives at the END, on the
-            # sentinel push itself uses) so the order survives push's
-            # negative-lane routing and the ids_sorted promise is honest
-            routed = jnp.where(
-                ids0 < 0, jnp.int32(spec.padded_capacity), ids0
-            )
-            order = jnp.argsort(routed)
-            n = ids0.shape[0]
-            marks = logic.per_record_leaves(batch)
-            if marks is not None:
-                # declared contract: permute exactly the marked leaves,
-                # and validate the declaration at trace time
-                def _permute_marked(x, m):
-                    if not m:
-                        return x
-                    if getattr(x, "ndim", 0) < 1 or x.shape[0] != n:
-                        raise ValueError(
-                            f"per_record_leaves declared a leaf of shape "
-                            f"{getattr(x, 'shape', None)} per-record, but "
-                            f"the batch has {n} records"
-                        )
-                    return jnp.take(x, order, axis=0)
-
-                batch = jax.tree.map(_permute_marked, batch, marks)
-                # the declaration must cover the KEYS leaf: if it was
-                # left unmarked, the batch keys stay unsorted while the
-                # push-identity check below would still hand the sorted
-                # scatter an honest-looking ids_sorted=True — a lie XLA
-                # may miscompile.  Same trace-time identity trick: an
-                # unpermuted keys leaf comes back as the same object.
-                if logic.keys(batch) is ids_pre:
-                    raise ValueError(
-                        "per_record_leaves did not mark the leaf that "
-                        "logic.keys(batch) returns — the sort keys "
-                        "themselves must be declared per-record for "
-                        "presort=True"
-                    )
-            else:
-                # shape heuristic (see docstring caveat)
-                batch = jax.tree.map(
-                    lambda x: (
-                        jnp.take(x, order, axis=0)
-                        if getattr(x, "ndim", 0) >= 1 and x.shape[0] == n
-                        else x
-                    ),
-                    batch,
-                )
         ids = logic.keys(batch)
         # ps.* scopes are metadata on the ops' names (docs/observability.md):
         # a trace reduction finds pull, compute and push by them whatever
@@ -397,14 +312,8 @@ def make_train_step(
             pulled = store_mod.pull(spec, table, ids)
         with scope("ps.compute"):
             state, req, out = logic.step(state, batch, pulled)
-        # the sorted promise holds only if the logic pushes the very ids
-        # it pulled — trace-time object identity is exactly that check
-        # (a logic pushing derived/other ids gets the unsorted path)
         with scope("ps.push"):
-            table = store_mod.push(
-                spec, table, req.ids, req.deltas, req.mask,
-                ids_sorted=presort and (req.ids is ids),
-            )
+            table = store_mod.push(spec, table, req.ids, req.deltas, req.mask)
         return table, state, out
 
     return step
@@ -447,12 +356,7 @@ def stack_group(group, scan_sharding=None):
     return stacked
 
 
-def make_scan_train_step(
-    logic: BatchedWorkerLogic,
-    spec,
-    *,
-    presort: bool = False,
-) -> Callable:
+def make_scan_train_step(logic: BatchedWorkerLogic, spec) -> Callable:
     """K train steps inside ONE jitted call: ``batches`` is a pytree of
     (K, batch, ...) leaves; a ``lax.scan`` runs :func:`make_train_step`'s
     body K times on-device and returns (K, ...)-stacked outputs.
@@ -464,7 +368,7 @@ def make_scan_train_step(
     *dispatches* to cut per-step host overhead).  What K buys has not
     been measured on the chip (ROADMAP S3).
     """
-    base = make_train_step(logic, spec, presort=presort)
+    base = make_train_step(logic, spec)
 
     def step(table, state, batches):
         def body(carry, b):
@@ -498,7 +402,6 @@ def transform_batched(
     ] = None,
     initial_state: Any = None,
     skip_batches: int = 0,
-    presort: bool = False,
     steps_per_call: int = 1,
     tracer: SpanTracer = NULL_TRACER,
 ) -> TransformResult:
@@ -509,10 +412,7 @@ def transform_batched(
     uses for metrics, checkpoints and profiling windows without
     duplicating this loop.  ``skip_batches`` fast-forwards the iterator
     (resume-from-cursor); ``initial_state`` overrides
-    ``worker_logic.init_state`` (restored worker state); ``presort``
-    sorts each microbatch by store key on-device before the pull (HBM
-    locality — see :func:`make_train_step`; worker outputs then come
-    back in sorted, not stream, order).
+    ``worker_logic.init_state`` (restored worker state).
 
     ``steps_per_call=K`` runs K microbatches per jitted dispatch via
     :func:`make_scan_train_step` — one host round trip per K steps
@@ -561,15 +461,11 @@ def transform_batched(
             "access)"
         )
 
-    step = jax.jit(
-        make_train_step(worker_logic, spec, presort=presort),
-        donate_argnums=(0, 1),
-    )
+    step = jax.jit(make_train_step(worker_logic, spec), donate_argnums=(0, 1))
     scan_step = None
     if steps_per_call > 1:
         scan_step = jax.jit(
-            make_scan_train_step(worker_logic, spec, presort=presort),
-            donate_argnums=(0, 1),
+            make_scan_train_step(worker_logic, spec), donate_argnums=(0, 1)
         )
     # The jitted step donates (table, state); start from copies so the
     # caller's store (and any restored state they still hold) stays valid

@@ -94,7 +94,7 @@ class FactorizationMachine(BatchedWorkerLogic):
 
 def make_store(
     config: FMConfig, *, seed: int = 0, init_stddev: float = 0.01, mesh=None,
-    dtype=None, scatter_impl: str = "xla", layout: str = "auto",
+    dtype=None, layout: str = "auto",
 ) -> ShardedParamStore:
     """(num_features, 1+dim) store: w zero-init, v ~ N(0, init_stddev).
 
@@ -114,7 +114,7 @@ def make_store(
 
     return ShardedParamStore.create(
         config.num_features, (1 + config.dim,), init_fn=init, mesh=mesh,
-        dtype=dtype, scatter_impl=scatter_impl, layout=layout,
+        dtype=dtype, layout=layout,
     )
 
 

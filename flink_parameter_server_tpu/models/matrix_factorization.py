@@ -30,7 +30,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..core.api import WorkerLogic
 from ..core.batched import BatchedWorkerLogic, PushRequest
-from ..core import store as store_mod
 from ..core.store import ShardedParamStore
 from ..ops import row_update
 from ..parallel.mesh import DP_AXIS
@@ -40,7 +39,7 @@ from ..utils.initializers import ranged_random_factor
 Array = jax.Array
 
 # the arms of the worker-state update (OnlineMatrixFactorization.step)
-STATE_ARMS = ("sorted_rows", "xla", "xla_sorted")
+STATE_ARMS = ("sorted_rows", "xla")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -104,15 +103,14 @@ class OnlineMatrixFactorization(BatchedWorkerLogic):
         if dedup_scale and num_items is None:
             raise ValueError("dedup_scale=True requires num_items")
         # state_scatter pins the arm of the worker-state update; None (the
-        # default) takes what the step can see (``state_update_arm``):
+        # default) takes what the step can see (``state_update_arm``).  The
+        # pin is how a test reaches the kernel, interpreted, off the TPU:
         #   "sorted_rows": the gathered rows and their deltas sorted by
         #     user, one pipelined row write per unique user
         #     (ops/row_update) — where the kernel can run: TPU, no mesh,
         #     float32 rows of k x 128 lanes, a batch its scalar memory holds;
         #   "xla": the plain scatter-add, one serial read-modify-write a
-        #     lane on the TPU (75 ns a row, PERF.md section 6);
-        #   "xla_sorted": duplicates combined before an XLA scatter
-        #     promised unique and sorted (ops/sorted_scatter).
+        #     lane on the TPU (75 ns a row, PERF.md section 6).
         if state_scatter not in (None,) + STATE_ARMS:
             raise ValueError(
                 f"state_scatter={state_scatter!r}: one of {STATE_ARMS} "
@@ -146,9 +144,8 @@ class OnlineMatrixFactorization(BatchedWorkerLogic):
         """The arm ``step`` compiles for this state and a batch of ``lanes``
         records (one of ``STATE_ARMS``): the pinned one, else "sorted_rows"
         wherever its kernel can run.  On a TPU without a mesh a refusal by
-        shape, dtype or batch size is counted and warned of once, beside
-        the store's own refused kernels
-        (``core/store.pallas_fallback_count``)."""
+        shape, dtype or batch size is counted and warned of once
+        (``ops/row_update.refusal_count``)."""
         if self.state_scatter is not None:
             return self.state_scatter
         # under a mesh the state is P(dp, None) and GSPMD partitions the
@@ -162,9 +159,7 @@ class OnlineMatrixFactorization(BatchedWorkerLogic):
             return "sorted_rows"
         if not self._fallback_noted:
             self._fallback_noted = True
-            store_mod._note_scatter_fallback(
-                "sorted_rows", f"the MF worker-state update: {why}"
-            )
+            row_update.note_refusal("the MF worker-state update", why)
         return "xla"
 
     def step(self, state: Array, batch: Dict[str, Array], pulled: Array):
@@ -200,14 +195,7 @@ class OnlineMatrixFactorization(BatchedWorkerLogic):
                 )
             else:
                 user_delta = user_delta * mask[..., None].astype(self.dtype)
-                if arm == "xla_sorted":
-                    from ..ops.sorted_scatter import sorted_dedup_scatter_add
-
-                    state = sorted_dedup_scatter_add(
-                        state, users, user_delta, mask
-                    )
-                else:
-                    state = state.at[users].add(user_delta, mode="drop")
+                state = state.at[users].add(user_delta, mode="drop")
         out = {"prediction": pred, "error": (ratings - pred) * mask}
         return state, PushRequest(batch["item"], item_delta, mask), out
 
@@ -228,7 +216,6 @@ def ps_online_mf(
     seed: int = 0,
     mesh: Optional[Mesh] = None,
     dedup_scale: bool = False,
-    scatter_impl: str = "xla",
     layout: str = "dense",
     state_scatter: Optional[str] = None,
     **transform_kwargs,
@@ -240,7 +227,7 @@ def ps_online_mf(
     Returns the :class:`TransformResult`; ``result.store.values()`` is the
     final item-factor matrix, ``result.worker_state`` the user factors.
 
-    ``scatter_impl`` / ``layout`` reach the item store (see
+    ``layout`` reaches the item store (see
     :class:`~..core.store.StoreSpec`); ``state_scatter`` the user-state
     update, where None leaves the logic to take the arm it can run
     (``state_update_arm``).
@@ -262,7 +249,6 @@ def ps_online_mf(
         (dim,),
         init_fn=ranged_random_factor(seed + 1, (dim,)),
         mesh=mesh,
-        scatter_impl=scatter_impl,
         layout=layout,
     )
     return transform_batched(

@@ -81,9 +81,7 @@ class CountMinSketch(BatchedWorkerLogic):
         return state, PushRequest(self.keys(batch), deltas, lane_mask), out
 
     def make_store(self, *, mesh=None, **store_opts) -> ShardedParamStore:
-        # store_opts passes through scatter_impl/layout: a Zipf text
-        # stream hammers the same hot cells every batch, the exact case
-        # scatter_impl="xla_sorted" exists for
+        # store_opts reaches ShardedParamStore.create (layout=)
         return ShardedParamStore.create(
             self.config.capacity, (), init_fn=zeros(()), mesh=mesh,
             **store_opts,

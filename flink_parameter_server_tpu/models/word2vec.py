@@ -120,7 +120,6 @@ def make_store(
     seed: int = 0,
     mesh=None,
     init_scale: float = 0.5,
-    scatter_impl: str = "xla",
     layout: str = "dense",
 ) -> ShardedParamStore:
     """(vocab, 2, dim) store; input slot random-uniform (the word2vec
@@ -135,7 +134,7 @@ def make_store(
 
     return ShardedParamStore.create(
         vocab_size, (2, dim), init_fn=init, mesh=mesh,
-        scatter_impl=scatter_impl, layout=layout,
+        layout=layout,
     )
 
 

@@ -45,6 +45,7 @@ from __future__ import annotations
 import functools
 import importlib
 import threading
+import warnings
 from typing import Optional
 
 import jax
@@ -73,6 +74,28 @@ def refusal(width: int, dtype, lanes: int) -> Optional[str]:
             f"ids fit the kernel's scalar memory"
         )
     return None
+
+
+# How often a caller on the kernel's own ground (a TPU, no mesh) was refused
+# and kept its XLA scatter-add instead (`note_refusal`).
+_REFUSALS = 0
+
+
+def refusal_count() -> int:
+    return _REFUSALS
+
+
+def note_refusal(what: str, why: str) -> None:
+    """Count and warn of a :func:`refusal` the caller is about to act on.
+    The choice is static per compiled step, so callers note it once a
+    logic: a step that could have had the kernel never silently lacks it."""
+    global _REFUSALS
+    _REFUSALS += 1
+    warnings.warn(
+        f"ops/row_update falling back to XLA scatter: {what}: {why}",
+        RuntimeWarning,
+        stacklevel=3,
+    )
 
 
 def preload() -> None:
@@ -342,6 +365,6 @@ def row_add(
 
 
 __all__ = [
-    "BLOCK", "MAX_LANES", "preload", "refusal", "row_add", "sort_by_row",
-    "sorted_row_update",
+    "BLOCK", "MAX_LANES", "note_refusal", "preload", "refusal",
+    "refusal_count", "row_add", "sort_by_row", "sorted_row_update",
 ]

@@ -18,13 +18,16 @@ Routing scheme (block layout): shard ``s`` of the ``ps`` axis owns rows
 For a push each shard keeps only its own rows' deltas and scatter-adds them
 locally — zero cross-shard traffic (the partitioning does the routing).
 
+Callers: none inside the package.  ``core/store.pull`` / ``push`` leave the
+partitioning to GSPMD; routing ids to their owner with these two is ROADMAP
+S9 step 2, whose PR calls them or deletes them (ROADMAP D13).
+
 Skew note: hot ids (Criteo, word2vec) all land on one shard under block
 layout just as under the reference's mod-hash; :mod:`..ops.hashing` provides
 an affine id-permutation to spread them.
 """
 from __future__ import annotations
 
-import warnings
 from typing import Optional
 
 import jax
@@ -95,51 +98,13 @@ def shard_push_add(
     mesh: Mesh,
     ps_axis: str = "ps",
     dp_axis: Optional[str] = "dp",
-    impl: str = "xla",
-    ids_sorted: bool = False,
 ) -> Array:
     """Sharded scatter-add: each ``ps`` shard folds in only the rows it
     owns.  When a ``dp`` axis exists, each worker's deltas are first
     all-gathered over ``dp`` (the worker→server "shuffle", now one ICI
     collective) and then locally scatter-added.
-
-    ``impl="pallas"``: each shard's local scatter runs the sorted-run
-    duplicate-compressing kernel (:mod:`..ops.pallas_scatter`) — one HBM
-    read-modify-write per unique local row under Zipf-hot ids.
-    ``impl="xla_sorted"``: the same dedup in pure XLA
-    (:mod:`..ops.sorted_scatter`) — no Mosaic shape constraints.
-
-    ``ids_sorted=True`` (xla_sorted only): the caller promises GLOBALLY
-    ascending flat ids (batch presort).  The dp split is then contiguous
-    chunks of a sorted array and the tiled all_gather reassembles them
-    in dp order, so each shard sees ascending ids — the per-shard
-    argsort + delta permute are skipped entirely (the op handles each
-    shard's out-of-range lanes order-preservingly; see
-    :func:`..ops.sorted_scatter.sorted_dedup_scatter_add`).
     """
     value_rank = table.ndim - 1
-    if impl == "pallas":
-        # Real Mosaic's shape rules:
-        # compiled kernels need 128-aligned row widths and 8-aligned
-        # per-shard capacities.  Fall back observably, never silently.
-        from ..ops.pallas_scatter import supports_shape
-
-        rows_per_shard = table.shape[0] // mesh.shape[ps_axis]
-        row_width = 1
-        for s in table.shape[1:]:
-            row_width *= s
-        if jax.default_backend() == "tpu" and not supports_shape(
-            rows_per_shard, row_width
-        ):
-            warnings.warn(
-                f"shard_push_add impl='pallas' falling back to XLA "
-                f"scatter: per-shard table ({rows_per_shard}, {row_width}) "
-                f"violates Mosaic alignment (need rows % 8 == 0, "
-                f"width % 128 == 0)",
-                RuntimeWarning,
-                stacklevel=2,
-            )
-            impl = "xla"
     vspec = (None,) * value_rank
     table_spec = P(ps_axis, *vspec)
     lead = P(dp_axis) if dp_axis else P(None)
@@ -159,32 +124,6 @@ def shard_push_add(
         rel = local_ids.reshape(-1) - lo
         hit = (rel >= 0) & (rel < rows)
         hit = hit & local_mask.reshape(-1)
-        if impl == "pallas":
-            # the public wrapper owns the lane prep (mask→zero-delta,
-            # sort, sentinel handling) — don't duplicate it here
-            from ..ops.pallas_scatter import scatter_add as pallas_scatter_add
-
-            return pallas_scatter_add(
-                local_table,
-                rel,
-                local_deltas.reshape((-1,) + local_table.shape[1:]),
-                hit,
-            )
-        if impl == "xla_sorted":
-            from ..ops.sorted_scatter import sorted_dedup_scatter_add
-
-            # under ids_sorted the op itself keeps invalid lanes
-            # order-preserving (zero-delta + monotone clip) — the
-            # ascending rel = [negatives][this shard's run][>= rows]
-            # needs no caller-side prep
-            return sorted_dedup_scatter_add(
-                local_table,
-                rel,
-                local_deltas.reshape((-1,) + local_table.shape[1:]),
-                hit,
-                oob=rows,
-                ids_sorted=ids_sorted,
-            )
         rel = jnp.clip(rel, 0, rows - 1)
         d = local_deltas.reshape((-1,) + local_table.shape[1:])
         d = jnp.where(

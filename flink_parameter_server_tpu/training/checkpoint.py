@@ -105,8 +105,7 @@ def _payload_to_state(
             [values, np.zeros((spec.capacity - values.shape[0],) + values.shape[1:], values.dtype)]
         )
     # Rebuild on the *target* spec directly so nothing is dropped in the
-    # round-trip (scatter_impl in particular: a pallas-configured store
-    # must restore as a pallas-configured store).
+    # round-trip (update rule, mesh, layout).
     store = ShardedParamStore.from_spec_values(
         spec, jax.numpy.asarray(values, dtype=spec.dtype)
     )

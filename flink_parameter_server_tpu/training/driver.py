@@ -81,12 +81,6 @@ class DriverConfig:
     # Periodic saves via orbax AsyncCheckpointer: save() returns after the
     # device→host copy, disk writes overlap the next training steps.
     async_checkpoints: bool = False
-    # Batch presort (core/transform.make_train_step): sort each
-    # microbatch by store key on-device before the pull — the HBM
-    # locality lever.  Driver-compatible: metrics count events via the
-    # mask (order-independent) and checkpoints see step boundaries;
-    # only per-record OUTPUT order changes (collect_outputs consumers).
-    presort: bool = False
     # K microbatches per jitted dispatch (core/transform lax.scan path):
     # one host round trip per K steps; amortises host dispatch, not
     # measured on the chip (ROADMAP S3).  The driver runs its envelope
@@ -554,7 +548,6 @@ class StreamingDriver:
                 group_callback=group_callback,
                 initial_state=self._state,
                 skip_batches=skip,
-                presort=cfg.presort,
                 steps_per_call=cfg.steps_per_call,
                 tracer=tracer,
             )

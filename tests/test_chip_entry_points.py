@@ -183,18 +183,6 @@ def test_bench_failed_line_is_reported_and_fails_the_run(
     assert headline["value"] == 1.0
 
 
-def test_bench_pallas_arm_off_chip_is_an_error(monkeypatch):
-    monkeypatch.syspath_prepend(REPO)
-    import bench
-
-    monkeypatch.setenv("FPS_BENCH_SCATTER", "pallas")
-    with pytest.raises(SystemExit, match="need the TPU backend"):
-        bench.tpu_updates_per_sec(
-            num_users=64, num_items=128, dim=8, batch=16,
-            warmup_steps=1, bench_steps=1,
-        )
-
-
 # -- cluster/procs.py ---------------------------------------------------------
 
 

@@ -70,20 +70,17 @@ def test_driver_checkpoint_and_resume(tmp_path):
     assert d2.step_idx == 25
 
 
-@pytest.mark.parametrize("presort", [False, True])
-def test_driver_resume_does_not_double_apply(tmp_path, presort):
+def test_driver_resume_does_not_double_apply(tmp_path):
     """Crash-at-step-K resume: re-feeding the same stream must fast-forward
-    past the consumed prefix, reproducing the uninterrupted run exactly —
-    with and without presort (the cursor counts BATCHES, which presort
-    does not change)."""
+    past the consumed prefix, reproducing the uninterrupted run exactly."""
     # uninterrupted oracle
-    d_full = _driver(None, presort=presort)
+    d_full = _driver(None)
     d_full.run(_stream())
     # interrupted run: checkpoint every 10, stop after 10 steps
-    d_a = _driver(tmp_path, checkpoint_every=10, presort=presort)
+    d_a = _driver(tmp_path, checkpoint_every=10)
     stream = list(_stream())
     d_a.run(iter(stream[:10]))  # "crash" right at the checkpoint
-    d_b = _driver(tmp_path, presort=presort)
+    d_b = _driver(tmp_path)
     assert d_b.resume() and d_b.step_idx == 10
     d_b.run(iter(stream))  # SAME stream from the start; driver skips 10
     assert d_b.step_idx == 20
@@ -293,33 +290,3 @@ def test_request_stop_programmatic(tmp_path):
     d2 = _driver()
     d2.run(_stream(n=3))
     assert d2.step_idx == 3
-
-
-def test_driver_presort_same_final_model():
-    """DriverConfig(presort=True) must train to the same model as the
-    plain driver on the same stream (f32 tolerance) — the knob rides
-    through run() without disturbing metrics/checkpoint plumbing."""
-    from flink_parameter_server_tpu.utils.initializers import normal_factor
-
-    data = synthetic_ratings(80, 120, 3_000, rank=4, noise=0.01, seed=8)
-
-    def run(presort):
-        logic = OnlineMatrixFactorization(
-            80, 8, updater=SGDUpdater(0.08), seed=0
-        )
-        store = ShardedParamStore.create(
-            120, (8,), init_fn=normal_factor(1, (8,)),
-        )
-        drv = StreamingDriver(
-            logic, store,
-            config=DriverConfig(metrics_every=4, presort=presort),
-        )
-        res = drv.run(microbatches(data, 256, epochs=2, shuffle_seed=0))
-        assert drv.metrics is not None and drv.metrics.total_steps > 0
-        return res
-
-    a, b = run(False), run(True)
-    np.testing.assert_allclose(
-        np.asarray(a.store.values()), np.asarray(b.store.values()),
-        atol=5e-5,
-    )

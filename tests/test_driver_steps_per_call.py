@@ -2,10 +2,12 @@
 dispatch granularity (K>1 amortises host dispatch; cadences round UP to
 group boundaries).
 """
+import jax
 import numpy as np
 import pytest
 
 from flink_parameter_server_tpu.core.store import ShardedParamStore
+from flink_parameter_server_tpu.core.transform import transform_batched
 from flink_parameter_server_tpu.data.movielens import synthetic_ratings
 from flink_parameter_server_tpu.data.streams import microbatches
 from flink_parameter_server_tpu.models.matrix_factorization import (
@@ -17,7 +19,10 @@ from flink_parameter_server_tpu.training.driver import (
     StreamingDriver,
     TrainingDiverged,
 )
-from flink_parameter_server_tpu.utils.initializers import ranged_random_factor
+from flink_parameter_server_tpu.utils.initializers import (
+    normal_factor,
+    ranged_random_factor,
+)
 
 
 def _driver(tmpdir=None, **cfg_kw):
@@ -141,43 +146,34 @@ def test_driver_k4_request_stop_drains_and_checkpoints(tmp_path):
 def test_all_knobs_composed_converges(tmp_path):
     """The knob matrix rows are tested pairwise; this is the one
     everything-at-once run: driver envelope (checkpoints + NaN guard +
-    metrics) x steps_per_call=16 x presort x scatter_impl=xla_sorted x
-    state_scatter=xla_sorted x layout=packed x bf16-free dp=8 mesh, at
+    metrics) x steps_per_call=16 x layout=packed x dp=4 x ps=2 mesh, at
     ML-100K-ish scale — must train (beat the zero predictor) and match
-    the plain-XLA dense oracle on the same stream."""
-    from flink_parameter_server_tpu.data.movielens import synthetic_ratings
-    from flink_parameter_server_tpu.data.streams import microbatches
+    the dense one-step-a-dispatch oracle on the same stream."""
     from flink_parameter_server_tpu.parallel.mesh import make_mesh
-    from flink_parameter_server_tpu.utils.initializers import (
-        ranged_random_factor,
-    )
 
     num_users, num_items, dim = 960, 1682, 16
     mesh = make_mesh(ps_parallelism=2)
     data = synthetic_ratings(num_users, num_items, 60_000, rank=6, seed=2)
 
-    def run(scatter, layout, presort, K):
+    def run(layout, K):
         logic = OnlineMatrixFactorization(
             num_users, dim, updater=SGDUpdater(0.05), mesh=mesh,
-            state_scatter=("xla_sorted" if scatter == "xla_sorted"
-                           else "xla"),
         )
         store = ShardedParamStore.create(
             num_items, (dim,), mesh=mesh,
-            init_fn=ranged_random_factor(0, (dim,)),
-            scatter_impl=scatter, layout=layout,
+            init_fn=ranged_random_factor(0, (dim,)), layout=layout,
         )
         cfg = DriverConfig(
-            checkpoint_dir=str(tmp_path / f"{scatter}_{layout}_{K}"),
+            checkpoint_dir=str(tmp_path / f"{layout}_{K}"),
             checkpoint_every=20, nan_check_every=10, metrics_every=20,
-            steps_per_call=K, presort=presort,
+            steps_per_call=K,
         )
         d = StreamingDriver(logic, store, config=cfg)
         d.run(microbatches(data, 2048, epochs=2, shuffle_seed=3))
         return d
 
-    d_all = run("xla_sorted", "packed", True, 16)
-    d_ref = run("xla", "dense", False, 1)
+    d_all = run("packed", 16)
+    d_ref = run("dense", 1)
 
     def rmse(d):
         uf = np.asarray(d._state)
@@ -191,7 +187,7 @@ def test_all_knobs_composed_converges(tmp_path):
     r_all, r_ref = rmse(d_all), rmse(d_ref)
     assert np.isfinite(np.asarray(d_all.store.values())).all()
     assert r_all < 0.9 * base  # genuinely trained
-    # same updates, different summation order/layout only
+    # same updates, different layout and dispatch grouping only
     assert abs(r_all - r_ref) < 0.02, (r_all, r_ref)
 
 
@@ -211,3 +207,82 @@ def test_driver_k4_nan_guard_fires_at_group_boundary(tmp_path):
         d.run(poisoned())
     assert d.step_idx == 4  # rolled back to the durable checkpoint
     assert np.isfinite(np.asarray(d.store.values())).all()
+
+
+# -- transform_batched(steps_per_call=K), below the driver -------------------
+
+
+@pytest.mark.parametrize("spc", [2, 3])
+def test_steps_per_call_matches_single_dispatch(spc):
+    """K steps per jitted dispatch (lax.scan) must be per-step identical
+    to the one-dispatch-per-batch loop — including a tail shorter than K
+    and per-batch worker outputs."""
+    data = synthetic_ratings(60, 90, 2_000, rank=4, noise=0.01, seed=4)
+
+    def run(steps_per_call):
+        logic = OnlineMatrixFactorization(
+            60, 8, updater=SGDUpdater(0.08), seed=0
+        )
+        store = ShardedParamStore.create(
+            90, (8,), init_fn=normal_factor(1, (8,)),
+        )
+        return transform_batched(
+            microbatches(data, 256, epochs=1, shuffle_seed=0),
+            logic, store, rng=jax.random.PRNGKey(0),
+            steps_per_call=steps_per_call,
+        )
+
+    a, b = run(1), run(spc)
+    np.testing.assert_allclose(
+        np.asarray(a.store.values()), np.asarray(b.store.values()),
+        atol=1e-6,
+    )
+    np.testing.assert_allclose(
+        np.asarray(a.worker_state), np.asarray(b.worker_state), atol=1e-6,
+    )
+    assert len(a.worker_outputs) == len(b.worker_outputs)
+    for oa, ob in zip(a.worker_outputs, b.worker_outputs):
+        ja, jb = jax.tree.leaves(oa), jax.tree.leaves(ob)
+        for xa, xb in zip(ja, jb):
+            np.testing.assert_allclose(
+                np.asarray(xa), np.asarray(xb), atol=1e-6
+            )
+
+
+def test_steps_per_call_rejects_state_callback():
+    data = synthetic_ratings(60, 90, 500, rank=2, seed=5)
+    logic = OnlineMatrixFactorization(60, 4, updater=SGDUpdater(0.05))
+    store = ShardedParamStore.create(90, (4,))
+    with pytest.raises(ValueError, match="steps_per_call"):
+        transform_batched(
+            microbatches(data, 128, epochs=1), logic, store,
+            steps_per_call=2, state_callback=lambda *a: None,
+        )
+
+
+def test_steps_per_call_sharded_mesh(mesh):
+    """The scan path on a dp x ps mesh: dp shard moves to axis 1 of the
+    stacked batches; results must match the per-dispatch mesh run."""
+    data = synthetic_ratings(64, 96, 2_048, rank=4, noise=0.01, seed=6)
+
+    def run(steps_per_call):
+        logic = OnlineMatrixFactorization(
+            64, 8, updater=SGDUpdater(0.08), seed=0, mesh=mesh
+        )
+        store = ShardedParamStore.create(
+            96, (8,), init_fn=normal_factor(1, (8,)), mesh=mesh,
+        )
+        return transform_batched(
+            microbatches(data, 256, epochs=1, shuffle_seed=0),
+            logic, store, rng=jax.random.PRNGKey(0), mesh=mesh,
+            collect_outputs=False, steps_per_call=steps_per_call,
+        )
+
+    a, b = run(1), run(4)
+    np.testing.assert_allclose(
+        np.asarray(a.store.values()), np.asarray(b.store.values()),
+        atol=2e-5,
+    )
+    np.testing.assert_allclose(
+        np.asarray(a.worker_state), np.asarray(b.worker_state), atol=2e-5,
+    )

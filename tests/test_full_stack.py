@@ -51,13 +51,13 @@ def test_full_stack_job(tmp_path, mesh):
             r = float(P[u] @ Q[i]) + rng.normal(0, 0.05)
             f.write(f"{u}\t{i}\t{r:.4f}\t0\n")
 
-    # 2. sharded store (pallas scatter) + driver with the full envelope
+    # 2. sharded store + driver with the full envelope
     logic = OnlineMatrixFactorization(
         num_users, 8, updater=SGDUpdater(0.08), mesh=mesh
     )
     store = ShardedParamStore.create(
         num_items, (8,), init_fn=ranged_random_factor(1, (8,)),
-        mesh=mesh, scatter_impl="pallas",
+        mesh=mesh,
     )
     sink = io.StringIO()
     driver = StreamingDriver(

@@ -3,9 +3,9 @@
 The packed layout must be OBSERVATIONALLY IDENTICAL to the dense layout
 through the whole store protocol (pull / push / values / checkpoint) —
 it is purely a physical-layout change (k narrow rows per 128-lane
-physical row) that buys full vector lanes and pallas-kernel eligibility
-for the reference's narrow value shapes (MF dim 64, FM dim 17, PA
-scalars).
+physical row) that buys full vector lanes for the reference's narrow
+value shapes (MF dim 64, FM dim 17, PA scalars).  Push and pull of the
+packed store against a float64 reference: tests/test_store.py's case table.
 """
 import numpy as np
 import jax
@@ -179,70 +179,6 @@ def test_lane_shift_scatter_equivalence():
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-5)
 
 
-@pytest.mark.parametrize("d,impl", [(17, "xla"), (17, "pallas"),
-                                    (64, "pallas"), (1, "xla"),
-                                    (1, "pallas")])
-def test_packed_store_matches_dense(d, impl):
-    rng = np.random.default_rng(3)
-    cap, n = 61, 400
-    init = _rand_init(d)
-    dense = ShardedParamStore.create(
-        cap, (d,), init_fn=init, scatter_impl=impl, layout="dense"
-    )
-    packed = ShardedParamStore.create(
-        cap, (d,), init_fn=init, scatter_impl=impl, layout="packed"
-    )
-    assert packed.table.shape[1] % 128 == 0
-    ids = jnp.asarray(rng.integers(-3, cap + 3, n).astype(np.int32))
-    deltas = jnp.asarray(rng.normal(0, 1, (n, d)).astype(np.float32))
-    mask = jnp.asarray(rng.random(n) > 0.2)
-    np.testing.assert_allclose(
-        np.asarray(packed.pull(jnp.clip(ids, 0, cap - 1))),
-        np.asarray(dense.pull(jnp.clip(ids, 0, cap - 1))),
-        rtol=1e-6,
-    )
-    a = dense.push(ids, deltas, mask)
-    b = packed.push(ids, deltas, mask)
-    np.testing.assert_allclose(
-        np.asarray(a.values()), np.asarray(b.values()), rtol=1e-4, atol=1e-5
-    )
-
-
-def test_packed_store_sharded_mesh(mesh):
-    rng = np.random.default_rng(4)
-    cap, d, n = 100, 17, 256
-    init = _rand_init(d)
-    dense = ShardedParamStore.create(cap, (d,), init_fn=init, mesh=mesh)
-    packed = ShardedParamStore.create(
-        cap, (d,), init_fn=init, mesh=mesh, layout="packed"
-    )
-    ids = jnp.asarray(rng.integers(0, cap, n).astype(np.int32))
-    deltas = jnp.asarray(rng.normal(0, 1, (n, d)).astype(np.float32))
-    np.testing.assert_allclose(
-        np.asarray(packed.pull(ids)), np.asarray(dense.pull(ids)), rtol=1e-6
-    )
-    a = dense.push(ids, deltas)
-    b = packed.push(ids, deltas)
-    np.testing.assert_allclose(
-        np.asarray(a.values()), np.asarray(b.values()), rtol=1e-4, atol=1e-5
-    )
-    # the packed table stays ps-sharded after a push
-    assert b.table.sharding.spec == jax.sharding.PartitionSpec("ps", None)
-
-
-def test_packed_int_counts_exact():
-    cap, d, n = 24, 4, 64
-    dense = ShardedParamStore.create(cap, (d,), dtype=jnp.int32)
-    packed = ShardedParamStore.create(
-        cap, (d,), dtype=jnp.int32, layout="packed"
-    )
-    ids = jnp.asarray(np.arange(n) % cap, jnp.int32)
-    deltas = jnp.ones((n, d), jnp.int32)
-    a = dense.push(ids, deltas)
-    b = packed.push(ids, deltas)
-    np.testing.assert_array_equal(np.asarray(a.values()), np.asarray(b.values()))
-
-
 def test_auto_layout_resolution():
     s = ShardedParamStore.create(10, (17,), layout="auto")
     assert s.spec.layout == "packed"
@@ -314,81 +250,3 @@ def test_packed_checkpoint_roundtrip(tmp_path):
     np.testing.assert_allclose(
         np.asarray(restored.values()), np.asarray(store.values()), rtol=1e-6
     )
-
-
-def test_scatter_add_inkernel_shift_matches_expansion():
-    """scatter_add(sub_k=...) (in-kernel lane shift, logical-width
-    deltas) == phys-granularity scatter of XLA-expanded deltas."""
-    from flink_parameter_server_tpu.ops.pallas_scatter import scatter_add
-
-    rng = np.random.default_rng(7)
-    for d in (17, 64):
-        k = pack_k(d)
-        cap = 96
-        v = jnp.asarray(rng.normal(0, 1, (cap, d)).astype(np.float32))
-        nphys = ((cap + k - 1) // k + 7) // 8 * 8
-        packed = pack_table(v, nphys)
-        n = 500
-        ids = jnp.asarray(rng.integers(-3, cap + 3, n).astype(np.int32))
-        deltas = jnp.asarray(rng.normal(0, 1, (n, d)).astype(np.float32))
-        out = scatter_add(
-            packed, ids, deltas, chunk=64, interpret=True,
-            sub_k=k, sub_width=d,
-        )
-        ref_logical = v.at[jnp.clip(ids, 0, cap - 1)].add(
-            jnp.where(((ids < 0) | (ids >= cap))[:, None], 0.0, deltas)
-        )
-        got = unpack_table(out, cap, d)
-        np.testing.assert_allclose(
-            np.asarray(got), np.asarray(ref_logical), rtol=1e-4, atol=1e-5
-        )
-    # very narrow rows (sub_k > MAX_INKERNEL_SUB_K) must refuse the
-    # in-kernel shift with a remedy (the store pre-shifts instead)
-    with pytest.raises(ValueError, match="pre-shift"):
-        scatter_add(
-            jnp.zeros((8, 128), jnp.float32),
-            jnp.zeros((4,), jnp.int32),
-            jnp.zeros((4, 4), jnp.float32),
-            chunk=8, interpret=True, sub_k=32, sub_width=4,
-        )
-
-
-def test_store_packed_pallas_single_shard_logical_path():
-    """The packed store's single-shard pallas push (in-kernel shift)
-    matches the dense store bit-for-bit within tolerance."""
-    rng = np.random.default_rng(8)
-    cap, d, n = 70, 17, 300
-    init = _rand_init(d)
-    dense = ShardedParamStore.create(cap, (d,), init_fn=init)
-    packed = ShardedParamStore.create(
-        cap, (d,), init_fn=init, scatter_impl="pallas", layout="packed"
-    )
-    ids = jnp.asarray(rng.integers(-2, cap + 2, n).astype(np.int32))
-    deltas = jnp.asarray(rng.normal(0, 1, (n, d)).astype(np.float32))
-    mask = jnp.asarray(rng.random(n) > 0.25)
-    a = dense.push(ids, deltas, mask)
-    b = packed.push(ids, deltas, mask)
-    np.testing.assert_allclose(
-        np.asarray(a.values()), np.asarray(b.values()), rtol=1e-4, atol=1e-5
-    )
-
-
-def test_packed_pack1_width_pallas_push():
-    """Regression: a packed store whose row width gives pack == 1
-    (65..127, lane-padded rather than packed) must route pallas pushes
-    through the XLA-side pre-shift — the in-kernel sub_k path would
-    reshape logical-width deltas against the 128-wide physical table."""
-    import numpy as np
-
-    from flink_parameter_server_tpu.core.store import ShardedParamStore
-
-    store = ShardedParamStore.create(
-        50, (100,), scatter_impl="pallas", layout="packed",
-    )
-    ids = jnp.asarray([0, 3, 3, 49], jnp.int32)
-    deltas = jnp.ones((4, 100), jnp.float32)
-    out = store.push(ids, deltas).values()
-    oracle = np.zeros((50, 100), np.float32)
-    for r in np.asarray(ids):
-        oracle[r] += 1.0
-    np.testing.assert_allclose(np.asarray(out), oracle, atol=1e-5)

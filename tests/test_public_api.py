@@ -103,11 +103,15 @@ MODULE_SYMBOLS = {
     "flink_parameter_server_tpu.models.moe": [
         "MoEConfig", "init_moe_params", "moe_apply", "moe_dense"],
     "flink_parameter_server_tpu.ops.topk": ["dense_topk", "sharded_topk"],
+    "flink_parameter_server_tpu.ops.packed": [
+        "pack_table", "unpack_table", "packed_pull", "lane_shift_deltas",
+        "packed_phys_ids"],
+    "flink_parameter_server_tpu.ops.row_update": [
+        "row_add", "sorted_row_update", "refusal", "refusal_count"],
     "flink_parameter_server_tpu.ops.hashing": [
         "hash_params", "bucket_hash", "sign_hash", "pair_key", "permute_ids"],
     "flink_parameter_server_tpu.ops.dedup": [
         "occurrence_counts", "occurrence_scale"],
-    "flink_parameter_server_tpu.ops.pallas_scatter": ["scatter_add"],
     "flink_parameter_server_tpu.data.streams": [
         "microbatches", "partitioned_microbatches", "sparse_feature_batches",
         "prefetch", "from_collection"],

@@ -3,19 +3,14 @@
 Covers (ADVICE.md r1 + VERDICT.md r1 "weak"):
   * transform_batched must not consume the caller's store/state (donation
     contract now matches transform_dense).
-  * checkpoint restore keeps the full StoreSpec — scatter_impl included.
   * JobCheckpointManager.save(force=True) replaces a step without a
     zero-durable-checkpoint window and leaves no trash dir behind.
   * event-backend routing hash is PYTHONHASHSEED-independent.
-  * eager pallas push does not invalidate the previous store's table.
-  * the sharded pallas→XLA fallback is observable (warning + counter).
 """
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
-from flink_parameter_server_tpu.core import store as store_mod
-from flink_parameter_server_tpu.core.store import ShardedParamStore, StoreSpec
+from flink_parameter_server_tpu.core.store import ShardedParamStore
 from flink_parameter_server_tpu.core.transform import (
     stable_route_hash,
     transform_batched,
@@ -46,25 +41,6 @@ def test_transform_batched_does_not_consume_inputs():
     # input store unchanged and alive; result store differs
     np.testing.assert_allclose(np.asarray(store.values()), before)
     assert not np.allclose(np.asarray(result.store.values()), before)
-
-
-def test_restore_preserves_scatter_impl(tmp_path):
-    spec = StoreSpec(capacity=12, value_shape=(4,), scatter_impl="pallas")
-    store = ShardedParamStore.create(
-        12, (4,), init_fn=ranged_random_factor(2, (4,)), scatter_impl="pallas"
-    )
-    path = str(tmp_path / "ck")
-    checkpoint.save(path, store, step=3)
-    restored, _, _ = checkpoint.restore(path, spec)
-    assert restored.spec.scatter_impl == "pallas"
-    np.testing.assert_allclose(
-        np.asarray(restored.values()), np.asarray(store.values())
-    )
-
-
-def test_from_values_scatter_impl_kwarg():
-    s = ShardedParamStore.from_values(jnp.ones((6, 2)), scatter_impl="pallas")
-    assert s.spec.scatter_impl == "pallas"
 
 
 def test_force_resave_replaces_without_gap(tmp_path):
@@ -116,34 +92,6 @@ def test_stable_route_hash_deterministic():
 
     assert stable_route_hash("user:9") == zlib.crc32(b"user:9")
     assert stable_route_hash("user:9") == stable_route_hash("user:9")
-
-
-def test_eager_pallas_push_preserves_old_store():
-    """push() returns a new store; with scatter_impl='pallas' run eagerly
-    the kernel's buffer aliasing must not invalidate the old table."""
-    store = ShardedParamStore.create(
-        8, (4,), init_fn=ranged_random_factor(1, (4,)), scatter_impl="pallas"
-    )
-    before = np.asarray(store.values()).copy()
-    new = store.push(jnp.array([2, 2, 5]), jnp.ones((3, 4)))
-    # old store still readable and unchanged
-    np.testing.assert_allclose(np.asarray(store.values()), before)
-    got = np.asarray(new.values())
-    np.testing.assert_allclose(got[2], before[2] + 2.0)
-    np.testing.assert_allclose(got[5], before[5] + 1.0)
-
-
-def test_sharded_pallas_fallback_is_observable(mesh):
-    """A pallas-configured sharded store falling back to XLA scatter
-    (batch not divisible by dp) must warn and bump the counter."""
-    store = ShardedParamStore.create(
-        16, (2,), init_fn=ranged_random_factor(1, (2,)),
-        scatter_impl="pallas", mesh=mesh,
-    )
-    n0 = store_mod.pallas_fallback_count()
-    with pytest.warns(RuntimeWarning, match="falling back to XLA scatter"):
-        store.push(jnp.array([1, 2, 3]), jnp.ones((3, 2)))  # 3 % dp=2 != 0
-    assert store_mod.pallas_fallback_count() == n0 + 1
 
 
 def test_mf_dedup_scale_means_duplicate_updates():

@@ -72,24 +72,6 @@ def test_mf_topk_step_packed_pack1_width():
     assert np.isfinite(np.asarray(out["topk_scores"])).all()
 
 
-def test_restore_preserves_xla_sorted_impl(tmp_path):
-    """Checkpoint roundtrip keeps the round-3 scatter_impl value."""
-    from flink_parameter_server_tpu.core.store import StoreSpec
-    from flink_parameter_server_tpu.training import checkpoint
-
-    spec = StoreSpec(capacity=12, value_shape=(4,), scatter_impl="xla_sorted")
-    store = ShardedParamStore.create(
-        12, (4,), init_fn=normal_factor(0, (4,)), scatter_impl="xla_sorted",
-    )
-    path = str(tmp_path / "ck")
-    checkpoint.save(path, store, step=1)
-    restored, _, _ = checkpoint.restore(path, spec)
-    assert restored.spec.scatter_impl == "xla_sorted"
-    np.testing.assert_allclose(
-        np.asarray(restored.values()), np.asarray(store.values())
-    )
-
-
 def test_driver_restores_none_signal_handler(monkeypatch):
     """A prior C-installed handler reads back as None; run() must not
     crash restoring it (TypeError at exit of a successful run)."""

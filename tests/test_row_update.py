@@ -11,7 +11,6 @@ import numpy as np
 import pytest
 
 from flink_parameter_server_tpu import ShardedParamStore
-from flink_parameter_server_tpu.core import store as store_mod
 from flink_parameter_server_tpu.core.transform import make_train_step
 from flink_parameter_server_tpu.models import matrix_factorization as mfm
 from flink_parameter_server_tpu.ops import row_update
@@ -235,17 +234,16 @@ def _arm(monkeypatch, backend, dim, dtype=jnp.float32, mesh=None, lanes=256,
     ("tpu", 256, jnp.float32, None, "sorted_rows"),
     ("cpu", 128, jnp.float32, None, "xla"),
     ("tpu", 128, jnp.float32, "xla", "xla"),
-    ("tpu", 128, jnp.float32, "xla_sorted", "xla_sorted"),
     ("cpu", 64, jnp.float32, "sorted_rows", "sorted_rows"),
 ])
 def test_state_update_arm_is_read_from_what_the_step_sees(
         monkeypatch, backend, dim, dtype, pinned, want):
-    n0 = store_mod.pallas_fallback_count()
+    n0 = row_update.refusal_count()
     with warnings.catch_warnings():
         warnings.simplefilter("error")
         _, arm = _arm(monkeypatch, backend, dim, dtype, state_scatter=pinned)
     assert arm == want
-    assert store_mod.pallas_fallback_count() == n0
+    assert row_update.refusal_count() == n0
 
 
 @pytest.mark.parametrize("dim,dtype,lanes,reason", [
@@ -255,16 +253,16 @@ def test_state_update_arm_is_read_from_what_the_step_sees(
 ])
 def test_refused_state_shape_on_tpu_warns_once_and_counts(
         monkeypatch, dim, dtype, lanes, reason):
-    n0 = store_mod.pallas_fallback_count()
+    n0 = row_update.refusal_count()
     with pytest.warns(RuntimeWarning, match="falling back") as caught:
         logic, arm = _arm(monkeypatch, "tpu", dim, dtype, lanes=lanes)
     assert arm == "xla" and reason in str(caught[0].message)
-    assert store_mod.pallas_fallback_count() == n0 + 1
+    assert row_update.refusal_count() == n0 + 1
     with warnings.catch_warnings():
         warnings.simplefilter("error")  # the second trace is silent
         assert logic.state_update_arm(
             jax.ShapeDtypeStruct((64, dim), dtype), lanes) == "xla"
-    assert store_mod.pallas_fallback_count() == n0 + 1
+    assert row_update.refusal_count() == n0 + 1
 
 
 def test_mesh_keeps_the_xla_arm_silently(monkeypatch):
@@ -272,11 +270,11 @@ def test_mesh_keeps_the_xla_arm_silently(monkeypatch):
 
     mesh = make_mesh(worker_parallelism=2, ps_parallelism=2,
                      devices=jax.devices()[:4])
-    n0 = store_mod.pallas_fallback_count()
+    n0 = row_update.refusal_count()
     with warnings.catch_warnings():
         warnings.simplefilter("error")
         _, arm = _arm(monkeypatch, "tpu", 128, mesh=mesh)
-    assert arm == "xla" and store_mod.pallas_fallback_count() == n0
+    assert arm == "xla" and row_update.refusal_count() == n0
 
 
 @pytest.mark.parametrize("backend,dim,pinned,started", [

@@ -10,6 +10,7 @@ import numpy as np
 import pytest
 
 from flink_parameter_server_tpu.core.store import ShardedParamStore
+from flink_parameter_server_tpu.core.transform import make_train_step
 from flink_parameter_server_tpu.parallel.collectives import (
     shard_pull,
     shard_push_add,
@@ -30,27 +31,6 @@ def test_pull_returns_initialized_values():
     np.testing.assert_allclose(vals[0], vals[2])
     # And match a fresh evaluation of the initializer.
     np.testing.assert_allclose(np.asarray(vals), np.asarray(init(ids)), rtol=1e-6)
-
-
-def test_push_add_with_duplicates_matches_sequential():
-    store = ShardedParamStore.create(10, (2,), init_fn=zeros((2,)))
-    ids = jnp.array([1, 1, 3, 1])
-    deltas = jnp.array([[1.0, 0.0], [2.0, 0.0], [5.0, 5.0], [4.0, 1.0]])
-    out = store.push(ids, deltas)
-    expect = np.zeros((10, 2))
-    for i, d in zip([1, 1, 3, 1], np.asarray(deltas)):
-        expect[i] += d  # sequential reference semantics; add is commutative
-    np.testing.assert_allclose(np.asarray(out.values()), expect)
-
-
-def test_push_mask_drops_padding_lanes():
-    store = ShardedParamStore.create(8, (), init_fn=zeros(()))
-    ids = jnp.array([2, 5, 0])
-    deltas = jnp.array([10.0, 20.0, 99.0])
-    mask = jnp.array([True, True, False])
-    out = store.push(ids, deltas, mask)
-    got = np.asarray(out.values())
-    assert got[2] == 10.0 and got[5] == 20.0 and got[0] == 0.0
 
 
 def test_generic_update_fn():
@@ -188,16 +168,6 @@ class TestExplicitCollectives:
         )
 
 
-def test_push_out_of_range_ids_are_dropped():
-    """OOB pushes must be dropped (mode='drop'), not clipped onto a real
-    row — parity with shard_push_add's hit-mask semantics."""
-    store = ShardedParamStore.create(10, (), init_fn=zeros(()))
-    out = store.push(jnp.array([50, -3, 9]), jnp.array([1.0, 1.0, 2.0]))
-    got = np.asarray(out.values())
-    assert got[9] == 2.0
-    assert got.sum() == 2.0  # nothing else was touched
-
-
 def test_generic_update_fn_sharded(mesh):
     """Custom (non-add) update path on a sharded mesh matches the
     single-device result."""
@@ -231,3 +201,239 @@ def test_push_mask_shape_mismatch_clear_error():
     store = ShardedParamStore.create(8, (), init_fn=zeros(()))
     with pytest.raises(ValueError, match="mask shape"):
         store.push(jnp.array([2, 5, 0]), jnp.ones(3), mask=jnp.array([False]))
+
+
+# -- push then pull, one case table ------------------------------------------
+# What every change to ``core/store.push`` / ``pull`` is held to: the store
+# against a float64 ``np.add.at`` on the same values, over both layouts, the
+# row widths the models use (PA / sketch scalars, FM's 17, MF's 64 and 128,
+# 100 = padded not packed) and the traffic that has broken a scatter before.
+
+CAP = 61  # no multiple of 8, of a pack factor or of a shard count
+WIDTHS = [1, 4, 17, 64, 100, 128]
+TRAFFIC = [
+    "uniform", "zipf_hot", "one_row", "half_masked", "neg_and_oob",
+    "hot_run_over_512", "ids_2d_lane_mask",
+]
+
+
+def _init_values(cap, shape, dtype=np.float32):
+    base = (np.arange(cap)[:, None] * 31 + np.arange(int(np.prod(shape)) or 1)
+            * 7) % 13
+    return ((base - 6.0) / 10.0).astype(dtype).reshape((cap,) + shape)
+
+
+def _traffic(kind, rng, cap, shape):
+    """(ids, deltas, mask or None) for one push."""
+    n = 96
+    mask = None
+    if kind == "uniform":
+        ids = rng.integers(0, cap, n)
+    elif kind == "zipf_hot":
+        ids = (rng.zipf(1.2, n) - 1) % cap
+        ids[rng.permutation(n)[: n // 8]] = 7  # one id on an eighth of the lanes
+    elif kind == "one_row":
+        ids = np.full(n, cap - 1)
+    elif kind == "half_masked":
+        ids = rng.integers(0, cap, n)
+        mask = np.arange(n) % 2 == 0
+    elif kind == "neg_and_oob":
+        ids = rng.integers(-5, cap + 5, n)
+        ids[:4] = [-1, cap, -(2 ** 31), 2 ** 31 - 1]
+    elif kind == "hot_run_over_512":
+        n = 640
+        ids = rng.integers(0, cap, n)
+        ids[40:600] = 3  # 560 lanes in a row
+    elif kind == "ids_2d_lane_mask":  # FM's and PA's (B, K) pulls
+        ids = rng.integers(0, cap, (24, 4))
+        mask = rng.random((24, 4)) > 0.3
+    else:
+        raise AssertionError(kind)
+    ids = np.asarray(ids, np.int32)
+    deltas = rng.normal(0, 1, ids.shape + shape).astype(np.float32)
+    return ids, deltas, mask
+
+
+def _reference(values, ids, deltas, mask):
+    """(want, magnitude) in float64: ``np.add.at`` over the lanes the store
+    keeps (mask true, 0 <= id < capacity)."""
+    cap = values.shape[0]
+    flat = ids.reshape(-1)
+    keep = (flat >= 0) & (flat < cap)
+    if mask is not None:
+        keep &= mask.reshape(-1)
+    d = deltas.reshape((flat.size,) + values.shape[1:]).astype(np.float64)
+    want, mag = values.astype(np.float64), np.abs(values).astype(np.float64)
+    np.add.at(want, flat[keep], d[keep])
+    np.add.at(mag, flat[keep], np.abs(d[keep]))
+    return want, mag
+
+
+# one program a spec and a batch shape, shared by the cases that have both
+_push = jax.jit(lambda store, ids, deltas, mask: store.push(ids, deltas, mask))
+_pull = jax.jit(lambda store, ids: store.pull(ids))
+
+
+def _check_push_pull(store, values, ids, deltas, mask, ulps=64.0):
+    eps = float(jnp.finfo(store.spec.dtype).eps)
+    pushed = _push(
+        store, jnp.asarray(ids), jnp.asarray(deltas),
+        None if mask is None else jnp.asarray(mask),
+    )
+    got = np.asarray(pushed.values()).astype(np.float64)
+    want, mag = _reference(values, ids, deltas, mask)
+    off = np.abs(got - want)
+    # rounding of the sums only; a lost or doubled delta is ~1/n of `mag`
+    assert (off <= ulps * eps * mag + 1e-30).all(), float(
+        (off / (eps * mag + 1e-30)).max()
+    )
+    # the padding rows took nothing
+    assert pushed.table.shape == store.table.shape
+    # pull: the rows as they now stand; a negative id is clipped to row 0,
+    # one past the capacity to a padding row (callers mask those lanes)
+    pulled = np.asarray(_pull(pushed, jnp.asarray(ids)))
+    assert pulled.shape == ids.shape + values.shape[1:]
+    below = ids < values.shape[0]
+    rows = np.asarray(pushed.values())[np.clip(ids, 0, values.shape[0] - 1)]
+    np.testing.assert_array_equal(pulled[below], rows[below])
+
+
+@pytest.mark.parametrize("traffic", TRAFFIC)
+@pytest.mark.parametrize("width", WIDTHS)
+@pytest.mark.parametrize("layout", ["dense", "packed"])
+def test_push_pull_case_table(layout, width, traffic):
+    shape = () if width == 1 and layout == "dense" else (width,)
+    rng = np.random.default_rng([width, TRAFFIC.index(traffic)])
+    values = _init_values(CAP, shape)
+    store = ShardedParamStore.from_values(jnp.asarray(values), layout=layout)
+    assert store.spec.layout == layout
+    if layout == "packed":
+        assert store.table.shape[1] % 128 == 0
+    _check_push_pull(store, values, *_traffic(traffic, rng, CAP, shape))
+
+
+@pytest.mark.parametrize("layout", ["dense", "packed"])
+def test_push_pull_case_table_int32_exact_past_2_24(layout):
+    """Counts are summed as integers: a float32 detour drops increments
+    past 2**24."""
+    values = np.full((CAP, 4), 2 ** 24, np.int32)
+    store = ShardedParamStore.from_values(jnp.asarray(values), layout=layout)
+    ids = np.asarray(np.arange(640) % 5, np.int32)
+    pushed = store.push(jnp.asarray(ids), jnp.ones((640, 4), jnp.int32))
+    want = values.copy()
+    np.add.at(want, ids, 1)
+    np.testing.assert_array_equal(np.asarray(pushed.values()), want)
+    np.testing.assert_array_equal(
+        np.asarray(pushed.pull(jnp.asarray(ids[:7]))), want[ids[:7]]
+    )
+
+
+@pytest.mark.parametrize("layout,width", [("dense", 128), ("packed", 64)])
+def test_push_pull_case_table_bfloat16(layout, width):
+    """bfloat16 tables (half the gather and scatter bytes): XLA rounds every
+    add, so a row's error grows with its duplicates; few of them here."""
+    rng = np.random.default_rng(width)
+    values = np.asarray(
+        jnp.asarray(_init_values(CAP, (width,)), jnp.bfloat16), np.float32)
+    store = ShardedParamStore.from_values(
+        jnp.asarray(values, jnp.bfloat16), layout=layout)
+    ids, deltas, mask = _traffic("half_masked", rng, CAP, (width,))
+    deltas = np.asarray(jnp.asarray(deltas, jnp.bfloat16), np.float32)
+    _check_push_pull(store, values, ids, deltas, mask, ulps=4.0)
+
+
+@pytest.mark.parametrize("width", [17, 128])
+@pytest.mark.parametrize("layout", ["dense", "packed"])
+def test_push_pull_case_table_under_a_dp_x_ps_mesh(layout, width, mesh):
+    """The same table row-sharded over ``ps = 4`` (``dp = 2`` beside it):
+    GSPMD partitions the one gather and the one scatter-add."""
+    rng = np.random.default_rng(width)
+    values = _init_values(CAP, (width,))
+    store = ShardedParamStore.from_values(
+        jnp.asarray(values), layout=layout, mesh=mesh)
+    assert store.spec.num_shards == 4 and store.spec.layout == layout
+    ids, deltas, _ = _traffic("zipf_hot", rng, CAP, (width,))
+    ids[:6] = [-1, CAP, CAP + 40, 0, CAP - 1, 7]
+    mask = rng.random(ids.shape) > 0.2
+    _check_push_pull(store, values, ids, deltas, mask)
+    pushed = store.push(jnp.asarray(ids), jnp.asarray(deltas))
+    assert pushed.table.sharding == store.spec.sharding()
+
+
+def _mf_case(rng, n):
+    from flink_parameter_server_tpu.models.matrix_factorization import (
+        OnlineMatrixFactorization,
+        SGDUpdater,
+    )
+
+    logic = OnlineMatrixFactorization(40, 8, updater=SGDUpdater(0.05))
+    store = ShardedParamStore.from_values(
+        jnp.asarray(_init_values(CAP, (8,))))
+    batch = {
+        "user": rng.integers(0, 40, n).astype(np.int32),
+        "item": ((rng.zipf(1.2, n) - 1) % CAP).astype(np.int32),
+        "rating": rng.normal(0, 1, n).astype(np.float32),
+        "mask": rng.random(n) > 0.1,
+    }
+    return logic, store, batch
+
+
+def _fm_case(rng, n):
+    from flink_parameter_server_tpu.models import factorization_machine as fm
+
+    cfg = fm.FMConfig(num_features=CAP, dim=16)
+    store = fm.make_store(cfg, init_stddev=0.1)
+    assert store.spec.layout == "packed"
+    batch = {
+        "ids": rng.integers(0, CAP, (n, 5)).astype(np.int32),
+        "values": rng.random((n, 5)).astype(np.float32),
+        "feat_mask": rng.random((n, 5)) > 0.2,
+        "label": (rng.integers(0, 2, n) * 2 - 1).astype(np.float32),
+        "mask": rng.random(n) > 0.1,
+    }
+    return fm.FactorizationMachine(cfg), store, batch
+
+
+@pytest.mark.parametrize("case", [_mf_case, _fm_case], ids=["mf", "fm"])
+def test_train_step_outputs_are_in_stream_order(case):
+    """Record ``i``'s output is at position ``i``: a batch handed over in
+    another order gives the same outputs in that order (every record reads
+    the rows as they stood before the step) and, up to the order of the
+    sums, the same table."""
+    rng = np.random.default_rng(5)
+    n = 64
+    logic, store, batch = case(rng, n)
+    perm = rng.permutation(n)
+    step = jax.jit(make_train_step(logic, store.spec))
+    state = logic.init_state(jax.random.PRNGKey(0))
+    table_a, _, out_a = step(store.table, state, batch)
+    table_b, _, out_b = step(
+        store.table, state, {k: v[perm] for k, v in batch.items()})
+    assert set(out_a) >= {"prediction"}
+    for name in out_a:
+        assert out_a[name].shape[0] == n
+        np.testing.assert_allclose(
+            np.asarray(out_a[name])[perm], np.asarray(out_b[name]),
+            rtol=1e-6, atol=1e-7)
+    np.testing.assert_allclose(
+        np.asarray(table_a), np.asarray(table_b), rtol=1e-5, atol=1e-6)
+
+
+def test_topk_exact_dense_matches_sharded(mesh):
+    """The exact serving path agrees between the dense and ps-sharded
+    stores."""
+    from flink_parameter_server_tpu.models.topk_recommender import query_topk
+
+    rng = np.random.default_rng(9)
+    items, d, k = 512, 32, 10
+    vals = rng.normal(size=(items, d)).astype(np.float32)
+    store = ShardedParamStore.from_values(jnp.asarray(vals))
+    sharded = ShardedParamStore.from_values(jnp.asarray(vals), mesh=mesh)
+    vecs = jnp.asarray(rng.normal(size=(8, d)), jnp.float32)
+    uids = jnp.arange(8, dtype=jnp.int32)
+    s_ex, i_ex = query_topk(store, vecs, uids, k)
+    s_sh, i_sh = query_topk(sharded, vecs, uids, k)
+    np.testing.assert_array_equal(np.asarray(i_ex), np.asarray(i_sh))
+    np.testing.assert_allclose(
+        np.asarray(s_ex), np.asarray(s_sh), atol=1e-5
+    )

@@ -170,10 +170,10 @@ def test_mf_step_with_a_batch_over_the_kernels_lanes_keeps_the_xla_arm(
     """131,072 row ids do not fit the kernel's SMEM (Mosaic: RESOURCE_
     EXHAUSTED): the default step says so once, counts, and compiles with
     the XLA scatter as the parent's did."""
-    n0 = store_mod.pallas_fallback_count()
+    n0 = row_update.refusal_count()
     with pytest.warns(RuntimeWarning, match="falling back.*131072 lanes"):
         compiled = _compiled_mf_step(one_chip, monkeypatch, 131_072)
-    assert store_mod.pallas_fallback_count() == n0 + 1
+    assert row_update.refusal_count() == n0 + 1
     assert "sorted_row_update" not in compiled.as_text()
 
 
@@ -181,9 +181,9 @@ def test_mf_step_default_arm_on_tpu_is_the_row_kernel(
         one_chip, no_compile_cache, monkeypatch):
     """At the cells' batch the user state goes through the kernel, and no
     scatter over the state array is left under ``ps.state_push``."""
-    n0 = store_mod.pallas_fallback_count()
+    n0 = row_update.refusal_count()
     compiled = _compiled_mf_step(one_chip, monkeypatch, BATCH)
-    assert store_mod.pallas_fallback_count() == n0
+    assert row_update.refusal_count() == n0
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "sorted_row_update" in text
     # what is left under ps.state_push that yields the whole state array:
