@@ -32,7 +32,7 @@ SURVEY.md §7 "Guiding translation").
 from __future__ import annotations
 
 import dataclasses
-import warnings
+import functools
 from typing import Any, Callable, Optional, Tuple, Union
 
 import jax
@@ -43,38 +43,23 @@ Array = jax.Array
 InitFn = Callable[[Array], Array]  # ids (n,) int32 -> values (n, *value_shape)
 UpdateFn = Callable[[Array, Array], Array]  # (current, combined_delta) -> new
 
-# How often layout="auto" wanted the packed layout and a mesh kept the
-# table dense (one per store built so; `_resolve_layout` says why).
-_PACKED_REFUSALS = 0
-
-
-def packed_refusal_count() -> int:
-    return _PACKED_REFUSALS
-
-
 def _resolve_layout(
     layout: str,
     update: Union[str, UpdateFn],
     value_shape: Tuple[int, ...],
-    num_shards: int = 1,
 ) -> str:
     """Resolve the table layout, validating packed-layout constraints.
 
-    ``"auto"`` reads the row width, the update rule and the shard count:
-    packed for an add-store whose rows are narrower than 128 lanes and
-    whose table lies on ONE shard; dense otherwise.  A narrow dense row is
-    a column of scalars across the table's tiles on the TPU, and its
-    gather and scatter-add walk that column (36 and 121 ns a row for FM's
-    17 lanes on the v5e, against 10 and 22 for the 128-lane physical row
-    that holds seven of them; PERF.md section 6, PR 29).
-
-    Under ``ps > 1`` a narrow add-store stays dense, warned of and counted
-    (:func:`packed_refusal_count`): ``_place`` packs a table whole, where
-    its values lie, before the shards get their parts (``_pack_rows``), so
-    a table sharded because it is larger than one chip cannot be packed
-    from values yet (ROADMAP S9).  ``create`` builds its table under the
-    mesh's ``out_shardings`` and could pack there; the rule is the same
-    for it on purpose, so that a store and its reload from a checkpoint
+    ``"auto"`` reads the row width and the update rule: packed for an
+    add-store whose rows are narrower than 128 lanes, dense otherwise.  A
+    narrow dense row is a column of scalars across the table's tiles on
+    the TPU, and its gather and scatter-add walk that column (36 and 121
+    ns a row for FM's 17 lanes on the v5e, against 10 and 22 for the
+    128-lane physical row that holds seven of them; PERF.md section 6,
+    PR 29).  The shard count is not asked: under a mesh every shard holds
+    its own packed block (``create_table`` initialises and packs each
+    shard's block on its shard, ``_place`` packs values that lie on the
+    mesh shard by shard), so a store and its reload from a checkpoint
     (``from_values``) resolve to one layout."""
     if layout not in ("dense", "packed", "auto"):
         raise ValueError(
@@ -84,20 +69,7 @@ def _resolve_layout(
     for s in value_shape:
         width *= int(s)
     if layout == "auto":
-        if update != "add" or width >= 128:
-            return "dense"
-        if num_shards > 1:
-            global _PACKED_REFUSALS
-            _PACKED_REFUSALS += 1
-            warnings.warn(
-                f"layout='auto': rows of {width} lanes would be packed "
-                f"{128 // width} to a 128-lane row, but the table is "
-                f"sharded over ps={num_shards}; it stays dense",
-                RuntimeWarning,
-                stacklevel=3,
-            )
-            return "dense"
-        return "packed"
+        return "packed" if update == "add" and width < 128 else "dense"
     if layout == "packed" and update != "add":
         # the generic update path applies `update` per logical row on a
         # dense combined table — packing it would need an unpack per push
@@ -207,19 +179,13 @@ def create_table(spec: StoreSpec, init_fn: Optional[InitFn] = None) -> Array:
     descriptors, which exist precisely so that init is reproducible per key.
     """
     init_fn = init_fn or zeros_init(spec)
+    if spec.layout == "packed":
+        return _create_packed(spec, init_fn)()
     ids = jnp.arange(spec.padded_capacity, dtype=jnp.int32)
     out_sharding = spec.sharding()
 
     def build(ids):
-        values = init_fn(ids)
-        if spec.layout == "packed":
-            from ..ops.packed import pack_table
-
-            values = pack_table(
-                values.reshape(-1, spec.row_width),
-                spec.rows_per_shard * spec.num_shards,
-            )
-        return values
+        return init_fn(ids)
 
     if out_sharding is not None:
         build = jax.jit(build, out_shardings=out_sharding)
@@ -228,18 +194,63 @@ def create_table(spec: StoreSpec, init_fn: Optional[InitFn] = None) -> Array:
     return build(ids)
 
 
+def _create_packed(spec: StoreSpec, init_fn: InitFn) -> Callable[[], Array]:
+    """``() -> table`` for a packed spec, as one jitted program: the rows
+    are initialised and packed ``_PACK_CHUNK`` physical rows a ``fori_loop``
+    step, so a chip holds its table and one chunk of logical rows, never
+    all of them (initialised at once, FM's rows are first laid 128 lanes
+    wide: 25 GB asked of a 16 GB chip on one chip, 24 GB on each of four;
+    PERF.md section 6, PR 31).  Under a mesh every shard does so for its
+    own block, inside a ``shard_map`` over ``ps``."""
+    from ..ops.packed import pack_table
+
+    k, d = spec.pack, spec.row_width
+    # a shard's block under a mesh, the whole table without one
+    rows = spec.rows_per_shard
+    chunk = min(rows, _PACK_CHUNK)
+
+    def block(first: Any) -> Array:
+        """``rows`` physical rows whose first logical row is ``first``."""
+
+        def init_chunk(i, table):
+            # the last chunk starts early and packs some rows a second time
+            at = jnp.minimum(i * chunk, rows - chunk)
+            ids = first + at * k + jnp.arange(chunk * k, dtype=jnp.int32)
+            part = init_fn(ids).reshape(-1, d).astype(spec.dtype)
+            return jax.lax.dynamic_update_slice(
+                table, pack_table(part, chunk), (at, 0)
+            )
+
+        table = jnp.zeros((rows, spec.table_shape()[1]), spec.dtype)
+        return jax.lax.fori_loop(0, -(-rows // chunk), init_chunk, table)
+
+    if spec.mesh is None:
+        return jax.jit(lambda: block(0))
+    ps = spec.ps_axis
+    # the loop's carry starts as zeros on every shard and ends as its block
+    return jax.jit(jax.shard_map(
+        lambda: block(jax.lax.axis_index(ps) * (rows * k)),
+        mesh=spec.mesh, in_specs=(), out_specs=P(ps, None), check_vma=False,
+    ), out_shardings=spec.sharding())
+
+
 def pull(spec: StoreSpec, table: Array, ids: Array) -> Array:
     """Batched pull: ``values[i] = table[ids[i]]`` (sharded gather).
 
     Out-of-range ids are clipped (callers use a validity mask alongside).
     Packed layout: one gather of whole 128-lane physical rows, then the
     lane slice as ``k`` static slices chosen by a ``select`` on
-    ``id % k`` (no per-element gather — see ops/packed.py)."""
+    ``id % k`` (no per-element gather — see ops/packed.py); under a mesh
+    each shard slices what it gathered before the one all-reduce
+    (:func:`_packed_pull_on_shards`)."""
     ids = jnp.clip(ids.astype(jnp.int32), 0, spec.padded_capacity - 1)
     if spec.layout == "packed":
         from ..ops.packed import packed_pull
 
-        vals = packed_pull(table, ids.reshape(-1), spec.row_width)
+        if spec.num_shards > 1:
+            vals = _packed_pull_on_shards(spec, table, ids.reshape(-1))
+        else:
+            vals = packed_pull(table, ids.reshape(-1), spec.row_width)
         return vals.reshape(ids.shape + spec.value_shape)
     return jnp.take(table, ids, axis=0)
 
@@ -340,6 +351,48 @@ def push(
     return jnp.where(touched, updated, table)
 
 
+@functools.partial(jax.jit, static_argnums=(0,))
+def _packed_pull_on_shards(spec: StoreSpec, table: Array, ids: Array) -> Array:
+    """The packed pull of ``ids`` (flat, pre-clipped) from a table sharded
+    over ``ps``: each shard gathers physical rows of its own block, slices
+    them down to the logical row and zeroes the rows it does not own; the
+    sum over the shards of those ``(n, row_width)`` answers is the step's
+    ONE all-reduce.  Left to GSPMD the ``jnp.take`` is all-reduced where
+    the gather ends, before the lane slice: 128 lanes a row where
+    ``row_width`` are wanted (FM: ``f32[1277952,128]``, 654 MB a step, for
+    87).  The sum is written outside the ``shard_map``, for the partitioner
+    to place: a ``psum`` inside it reduces the rows as the slice leaves
+    them, row-major and padded to 128 lanes again on the TPU (16.7 ms a
+    step against 5-8; PERF.md section 6, PR 31).  The ids stay split over
+    the mesh's other axes (``dp``) where the batch is.  Jitted for the
+    reason ``packed_pull`` is."""
+    from ..ops.packed import _sub_row_slice
+
+    mesh, ps = spec.mesh, spec.ps_axis
+    k, rows = spec.pack, spec.rows_per_shard
+    others = tuple(a for a in mesh.axis_names if a != ps)
+    if ids.shape[0] % (mesh.size // spec.num_shards):
+        others = ()
+
+    def on_shard(block: Array, ids: Array) -> Array:
+        rel = ids // k - jax.lax.axis_index(ps) * rows
+        mine = (rel >= 0) & (rel < rows)
+        # rows of other shards wrap round to rows spread over this block:
+        # clipped, they would all be its first or its last row, and a
+        # gather that keeps hitting one row takes twice as long a row
+        vals = _sub_row_slice(
+            jnp.take(block, rel, axis=0, mode="wrap"), ids, spec.row_width
+        )
+        return jnp.where(mine[:, None], vals, jnp.zeros_like(vals))[None]
+
+    return jax.shard_map(
+        on_shard,
+        mesh=mesh,
+        in_specs=(P(ps, None), P(others or None)),
+        out_specs=P(ps, others or None, None),
+    )(table, ids).sum(axis=0)
+
+
 def _pad_rows(spec: StoreSpec, pad: int) -> Callable[[Array], Array]:
     """``values -> values`` with ``pad`` zero rows appended, for ``values``
     that already live on the spec's mesh, row-sharded or not.  One jitted
@@ -358,52 +411,214 @@ def _pad_rows(spec: StoreSpec, pad: int) -> Callable[[Array], Array]:
     return jax.jit(padded, out_shardings=spec.sharding())
 
 
-# Physical rows a step of `_pack_rows`: 131,072 x 7 of FM's rows are 0.47 GB
+# Physical rows a step of `_pack_block`: 131,072 x 7 of FM's rows are 0.47 GB
 # once the compiler has laid them 128 lanes wide.
 _PACK_CHUNK = 131_072
 
 
-def _pack_rows(spec: StoreSpec) -> Callable[[Array], Array]:
-    """``values -> table`` for a packed table, as one jitted program that
-    packs ``_PACK_CHUNK`` physical rows at a time into a zeroed table, so
-    the chip holds the values, the table and one chunk.  The table comes
-    out where the values lie, whole; under a mesh ``_place`` then hands it
-    to the shards, so a packed table still has to fit one chip.
+def _pack_block(
+    spec: StoreSpec, rows: int, values: Array, skip: Any = 0,
+    reach: int = 0, beyond: Optional[Array] = None,
+) -> Array:
+    """``rows`` packed physical rows whose first logical row is
+    ``values[skip]`` (``skip <= reach``, and only ``reach`` is static);
+    past the end of ``values`` the rows are ``beyond``'s, then zeros.
+    Packs ``_PACK_CHUNK`` physical rows a ``fori_loop`` step into a zeroed
+    table, so the chip holds the values, the table and one chunk.
     To reshape ``(n, 17)`` into ``(n / 7, 119)`` the TPU compiler first
     lays the rows 128 lanes wide: for FM's 49.1 M rows all at once that is
     25 GB asked of a 16 GB chip, whether the ops are jitted together or
     run eagerly one by one (the fault PR 28 met under a mesh; PERF.md
-    section 6, PR 29).  The values are not donated: no output has their
-    shape to take their place."""
+    section 6, PR 29)."""
     from ..ops.packed import pack_table
 
     k, d = spec.pack, spec.row_width
+    n = values.shape[0]
+    # physical rows that `values` holds whole wherever the block starts
+    whole = (n - reach) // k
+    table = jnp.zeros((rows, spec.table_shape()[1]), spec.dtype)
+    chunk = min(whole, _PACK_CHUNK)
+
+    def pack_chunk(i, table):
+        # the last chunk starts early and packs some rows a second time
+        at = jnp.minimum(i * chunk, whole - chunk)
+        part = jax.lax.dynamic_slice(
+            values, (skip + at * k, 0), (chunk * k, d)
+        )
+        return jax.lax.dynamic_update_slice(
+            table, pack_table(part, chunk), (at, 0)
+        )
+
+    if whole:
+        table = jax.lax.fori_loop(0, -(-whole // chunk), pack_chunk, table)
+    if rows > whole:
+        # the block's end: a few rows of `values`, of `beyond`, and zeros
+        end = (rows - whole) * k
+        edge = [values[whole * k:]] + ([] if beyond is None else [beyond])
+        short = reach + end - sum(e.shape[0] for e in edge)
+        if short > 0:
+            edge.append(jnp.zeros((short, d), spec.dtype))
+        edge = jax.lax.dynamic_slice(
+            jnp.concatenate(edge), (skip, 0), (end, d)
+        )
+        table = jax.lax.dynamic_update_slice(
+            table, pack_table(edge, rows - whole), (whole, 0)
+        )
+    return table
+
+
+def _pack_rows(spec: StoreSpec) -> Callable[[Array], Array]:
+    """``values -> table`` for a packed table, as one jitted program
+    (:func:`_pack_block`).  The table comes out where the values lie,
+    whole; under a mesh ``_place`` then hands it to the shards, which is
+    right for values that fit one place (numpy, one device).  The values
+    are not donated: no output has their shape to take their place."""
 
     def packed(values: Array) -> Array:
-        values = values.reshape(-1, d)
-        whole = values.shape[0] // k  # physical rows with all k logical rows
-        table = jnp.zeros(spec.table_shape(), spec.dtype)
-        chunk = min(whole, _PACK_CHUNK)
-
-        def pack_chunk(i, table):
-            # the last chunk starts early and packs some rows a second time
-            at = jnp.minimum(i * chunk, whole - chunk)
-            rows = jax.lax.dynamic_slice(values, (at * k, 0), (chunk * k, d))
-            return jax.lax.dynamic_update_slice(
-                table, pack_table(rows, chunk), (at, 0)
-            )
-
-        if whole:
-            table = jax.lax.fori_loop(
-                0, -(-whole // chunk), pack_chunk, table
-            )
-        if values.shape[0] > whole * k:
-            table = jax.lax.dynamic_update_slice(
-                table, pack_table(values[whole * k:], 1), (whole, 0)
-            )
-        return table
+        return _pack_block(
+            spec, spec.table_shape()[0], values.reshape(-1, spec.row_width)
+        )
 
     return jax.jit(packed)
+
+
+def _next_shard_in_reach(spec: StoreSpec, n: int) -> bool:
+    """Whether ``n`` rows split evenly over ``ps`` put every shard's packed
+    block inside that shard's rows and its right neighbour's, with room.
+    A packed shard holds ``rows_per_shard * pack`` logical rows, ``ahead``
+    more than a shard of the values (FM on four chips: 46,941,888 against
+    46,941,853), so shard ``s``'s block starts ``s * ahead`` rows into its
+    own values and ends ``(s + 1) * ahead`` rows into the next shard's.
+    False only for tables of a few rows a shard (twice the rows a block
+    may share with a neighbour, and one physical row, do not fit one)."""
+    shards = spec.num_shards
+    reach = (shards - 1) * (spec.rows_per_shard * spec.pack - n // shards)
+    return n % shards == 0 and 2 * reach + spec.pack <= n // shards
+
+
+def _pack_rows_on_mesh(spec: StoreSpec) -> Callable[[Array], Array]:
+    """``values -> table`` for a packed table whose values already lie on
+    the spec's mesh (``_next_shard_in_reach``): each shard packs its own
+    block from its own rows (:func:`_pack_block`) and the few rows at the
+    block's end that its right neighbour holds (one ``ppermute`` of at
+    most ``(shards - 1) * ahead`` rows; the last shard's end is zeros).
+    Neither the values nor the table are ever whole anywhere: a table
+    sharded because it outgrew one chip packs as it lies (FM's 187.8 M
+    rows: 4.51 GB of values, 3.43 GB of table and one chunk a chip)."""
+    shards, ps = spec.num_shards, spec.ps_axis
+
+    def on_shard(values: Array) -> Array:
+        values = values.reshape(-1, spec.row_width)
+        ahead = spec.rows_per_shard * spec.pack - values.shape[0]
+        reach = (shards - 1) * ahead
+        beyond = None
+        if reach:
+            beyond = jax.lax.ppermute(
+                values[:reach], ps, [(s + 1, s) for s in range(shards - 1)]
+            )
+        return _pack_block(
+            spec, spec.rows_per_shard, values,
+            jax.lax.axis_index(ps) * ahead, reach, beyond,
+        )
+
+    # the loop's carry starts as zeros on every shard and ends as its block
+    return jax.jit(jax.shard_map(
+        on_shard, mesh=spec.mesh, in_specs=P(ps), out_specs=P(ps, None),
+        check_vma=False,
+    ))
+
+
+def _unpack_block(
+    spec: StoreSpec, n: int, block: Array, skip: Any = 0, reach: int = 0,
+    before: Optional[Array] = None,
+) -> Array:
+    """:func:`_pack_block` backwards: ``n`` logical rows out of a packed
+    ``block``, the first of them ``skip`` rows BEFORE the block's first
+    (``skip <= reach <= n / 2``, and only ``reach`` is static): those are
+    the last of ``before``'s ``reach`` rows.  ``_PACK_CHUNK`` physical rows
+    a ``fori_loop`` step, for the reason given there."""
+    from ..ops.packed import unpack_table
+
+    k, d = spec.pack, spec.row_width
+
+    def logical(phys: Array) -> Array:
+        return unpack_table(phys, phys.shape[0] * k, d)
+
+    # physical rows whose logical rows all land inside, wherever it starts
+    whole = (n - reach) // k
+    out = jnp.zeros((n, d), spec.dtype)
+    chunk = min(whole, _PACK_CHUNK)
+
+    def unpack_chunk(i, out):
+        # the last chunk starts early and unpacks some rows a second time
+        at = jnp.minimum(i * chunk, whole - chunk)
+        part = jax.lax.dynamic_slice(block, (at, 0), (chunk, block.shape[1]))
+        return jax.lax.dynamic_update_slice(
+            out, logical(part), (skip + at * k, 0)
+        )
+
+    if whole:
+        out = jax.lax.fori_loop(0, -(-whole // chunk), unpack_chunk, out)
+    if reach:
+        # the first rows: the end of `before`, then the block's first
+        head = jnp.concatenate(
+            [before, logical(block[: -(-reach // k)])[:reach]]
+        )
+        head = jax.lax.dynamic_slice(head, (reach - skip, 0), (reach, d))
+        out = jax.lax.dynamic_update_slice(out, head, (0, 0))
+    last = n - whole * k
+    if last:
+        # the last rows: past the last whole chunk, wherever that ended
+        first = (whole * k - reach) // k
+        tail = jax.lax.dynamic_slice(
+            logical(block[first: -(-n // k)]),
+            (whole * k - first * k - skip, 0), (last, d),
+        )
+        out = jax.lax.dynamic_update_slice(out, tail, (n - last, 0))
+    return out
+
+
+@functools.partial(jax.jit, static_argnums=(0,))
+def _unpack_rows(spec: StoreSpec, table: Array) -> Array:
+    """The values (unpadded, logical) of a packed table, as one jitted
+    program (:func:`_unpack_block`); they come out where the partitioner
+    puts them, which is right for a table in one place."""
+    vals = _unpack_block(spec, spec.capacity, table)
+    return vals.reshape((spec.capacity,) + spec.value_shape)
+
+
+@functools.partial(jax.jit, static_argnums=(0,))
+def _unpack_rows_on_mesh(spec: StoreSpec, table: Array) -> Array:
+    """The values of a packed table sharded over ``ps``
+    (``_next_shard_in_reach``): they come out row-sharded over ``ps`` as a
+    dense table's do, each shard unpacking its own block and the few rows
+    before it that end its left neighbour's (:func:`_pack_rows_on_mesh`
+    backwards); never gathered onto one chip."""
+    from ..ops.packed import unpack_table
+
+    shards, ps, k = spec.num_shards, spec.ps_axis, spec.pack
+    n = spec.capacity // shards
+
+    def on_shard(block: Array) -> Array:
+        ahead = block.shape[0] * k - n
+        reach = (shards - 1) * ahead
+        before = None
+        if reach:
+            end = block[reach // -k:]  # the physical rows that hold them
+            before = jax.lax.ppermute(
+                unpack_table(end, end.shape[0] * k, spec.row_width)[-reach:],
+                ps, [(s, s + 1) for s in range(shards - 1)],
+            )
+        vals = _unpack_block(
+            spec, n, block, jax.lax.axis_index(ps) * ahead, reach, before
+        )
+        return vals.reshape((n,) + spec.value_shape)
+
+    # as in the pack: the loop's carry starts as zeros on every shard
+    return jax.shard_map(
+        on_shard, mesh=spec.mesh, in_specs=P(ps, None), out_specs=P(ps),
+        check_vma=False,
+    )(table)
 
 
 def _lives_on_mesh(spec: StoreSpec, values: Any) -> bool:
@@ -450,10 +665,7 @@ class ShardedParamStore:
             update=update,
             mesh=mesh,
             ps_axis=ps_axis,
-            layout=_resolve_layout(
-                layout, update, tuple(value_shape),
-                1 if mesh is None else mesh.shape[ps_axis],
-            ),
+            layout=_resolve_layout(layout, update, tuple(value_shape)),
         )
         return cls(spec, create_table(spec, init_fn))
 
@@ -477,10 +689,7 @@ class ShardedParamStore:
             update=update,
             mesh=mesh,
             ps_axis=ps_axis,
-            layout=_resolve_layout(
-                layout, update, tuple(values.shape[1:]),
-                1 if mesh is None else mesh.shape[ps_axis],
-            ),
+            layout=_resolve_layout(layout, update, tuple(values.shape[1:])),
         )
         return cls(spec, cls._place(spec, values))
 
@@ -496,8 +705,12 @@ class ShardedParamStore:
 
     @staticmethod
     def _place(spec: StoreSpec, values: Array) -> Array:
-        if spec.layout == "packed":
-            values = _pack_rows(spec)(values)  # pads its own rows
+        if spec.layout == "packed":  # either way pads its own rows
+            if _lives_on_mesh(spec, values) and _next_shard_in_reach(
+                spec, values.shape[0]
+            ):
+                return _pack_rows_on_mesh(spec)(values)
+            values = _pack_rows(spec)(values)
         else:
             pad = spec.padded_capacity - values.shape[0]
             if pad and _lives_on_mesh(spec, values):
@@ -528,14 +741,14 @@ class ShardedParamStore:
     def values(self) -> Array:
         """Final model dump (unpadded, LOGICAL layout) — the reference's
         close()-time parameter flush (SURVEY.md §3.5)."""
-        if self.spec.layout == "packed":
-            from ..ops.packed import unpack_table
-
-            vals = unpack_table(
-                self.table, self.spec.capacity, self.spec.row_width
-            )
-            return vals.reshape((self.spec.capacity,) + self.spec.value_shape)
-        return self.table[: self.spec.capacity]
+        spec = self.spec
+        if spec.layout == "packed":
+            if spec.num_shards > 1 and _next_shard_in_reach(
+                spec, spec.capacity
+            ):
+                return _unpack_rows_on_mesh(spec, self.table)
+            return _unpack_rows(spec, self.table)
+        return self.table[: spec.capacity]
 
     # -- pytree plumbing ---------------------------------------------------
     def tree_flatten(self):
@@ -553,5 +766,4 @@ __all__ = [
     "pull",
     "push",
     "zeros_init",
-    "packed_refusal_count",
 ]

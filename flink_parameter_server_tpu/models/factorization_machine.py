@@ -100,10 +100,10 @@ def make_store(
 
     The FM row is NARROW (1+dim = 17 for Criteo shapes), so the default
     ``layout="auto"`` lets the store pick from what it sees
-    (``core/store._resolve_layout``): with the table on one shard, 7 rows
-    to a 128-lane physical row (ops/packed.py), pull and push then move
-    whole 128-lane rows; under ``ps > 1`` dense, warned of and counted.
-    ``"dense"`` and ``"packed"`` pin a layout."""
+    (``core/store._resolve_layout``): 7 rows to a 128-lane physical row
+    (ops/packed.py), on one shard and on every shard of a ``ps`` mesh, so
+    pull and push move whole 128-lane rows.  ``"dense"`` and ``"packed"``
+    pin a layout."""
     dtype = dtype or jnp.float32
     vinit = normal_factor(seed, (config.dim,), stddev=init_stddev,
                           dtype=dtype)

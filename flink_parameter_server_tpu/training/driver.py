@@ -23,7 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core.batched import BatchedWorkerLogic
-from ..core.store import ShardedParamStore, packed_refusal_count
+from ..core.store import ShardedParamStore
 from ..core.transform import TransformResult, transform_batched
 from ..data.streams import prefetch as prefetch_iter
 from ..telemetry.registry import get_registry
@@ -158,16 +158,10 @@ class StreamingDriver:
         self.tracer = get_tracer() if self.config.telemetry else NULL_TRACER
         if self.registry is not None:
             # which layout the store resolved to (fixed when it was built:
-            # a stored value, so the registry holds no driver and no table),
-            # and how often "auto" wanted packed rows and a mesh kept a
-            # table dense
+            # a stored value, so the registry holds no driver and no table)
             self.registry.gauge(
                 "store_layout_packed", component="train"
             ).set(store.spec.layout == "packed")
-            self.registry.gauge(
-                "store_packed_refusals", component="train",
-                fn=packed_refusal_count,
-            )
         # spans open on the profiler's clock too: this code owns a device
         self.tracer.annotate_with(jax.profiler.TraceAnnotation)
         self.step_idx = 0

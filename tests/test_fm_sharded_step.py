@@ -1,5 +1,5 @@
 """The FM step with its table row-sharded over ``ps = 4``
-(``make_mesh(1, 4)``, ``make_store``'s default arms left to GSPMD): against the
+(``make_mesh(1, 4)``, ``make_store``'s default layout: packed on every shard): against the
 plain numpy reference the benchmark's ``correct`` rests on, within the
 allowance its four-chip configuration states, and against the one-device step
 of the same seed.  One logical table: the result may not depend on the number
@@ -47,8 +47,8 @@ def _train(cfg, mesh, n=3):
     after = fam.rows(trained, (), ids)
     return {
         "want": ref.apply(cfg, before, ids, batches), "before": before,
-        # the logical rows: on one device the store packs them 7 to a
-        # physical row by itself, under ``ps = 4`` it keeps them dense
+        # the logical rows: the store packs them 7 to a physical row by
+        # itself, on one device and on every shard under ``ps = 4``
         "after": after, "table": np.asarray(trained.values()),
         "prediction": np.asarray(out["prediction"]), "store": store,
     }
@@ -92,15 +92,15 @@ def test_sharded_step_is_within_the_configurations_allowance(runs):
 
 def test_sharded_step_equals_the_one_device_step_bit_for_bit(runs):
     # Tolerance: none.  The batch is replicated, so every chip computes the
-    # same deltas; the partitioned gather all-reduces each row with three
-    # zeros (exact); the partitioned scatter-add lands a row's deltas on the
-    # shard that owns it in the order the one-device scatter adds them
-    # (into the packed table there: a row's deltas in its own 17 lanes of a
-    # physical row), so not a rounding differs.  (On the CPU; on the TPU the benchmark holds the
+    # same deltas; the sharded pull all-reduces each row with three zeros
+    # (exact); the partitioned scatter-add lands a row's deltas on the
+    # shard that owns it in the order the one-device scatter adds them (a
+    # row's deltas in its own 17 lanes of a physical row, on both), so not
+    # a rounding differs.  (On the CPU; on the TPU the benchmark holds the
     # cell to the reference's allowance, which is what users are promised.)
     _, sharded, single = runs
     assert (sharded["store"].spec.layout, single["store"].spec.layout) == (
-        "dense", "packed")
+        "packed", "packed")
     np.testing.assert_array_equal(sharded["before"]["feature"],
                                   single["before"]["feature"])
     np.testing.assert_array_equal(sharded["table"], single["table"])

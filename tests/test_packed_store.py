@@ -7,6 +7,8 @@ physical row) that buys full vector lanes for the reference's narrow
 value shapes (MF dim 64, FM dim 17, PA scalars).  Push and pull of the
 packed store against a float64 reference: tests/test_store.py's case table.
 """
+import re
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -22,6 +24,13 @@ from flink_parameter_server_tpu.ops.packed import (
     packed_pull,
     phys_width,
     unpack_table,
+)
+
+
+# an HLO line that APPLIES a collective (a use of its result is `%all-reduce,`)
+COLLECTIVE_OP = re.compile(
+    r" (all-reduce|all-gather|all-to-all|reduce-scatter|collective-permute)"
+    r"(-start)?\("
 )
 
 
@@ -137,9 +146,12 @@ def test_packing_in_one_jitted_program_equals_the_eager_packing(
 
 @pytest.mark.parametrize("origin", ["numpy", "uncommitted", "on_the_mesh"])
 def test_packing_under_a_mesh_is_the_same_program(origin, mesh, monkeypatch):
-    """A packed table under ``ps > 1`` is packed by ``_pack_rows`` too (no
-    second, eager way to pack), then handed to the shards: rows padded to
-    the shards' aligned blocks, the table ``ps``-sharded."""
+    """A packed table under ``ps > 1`` is packed by ``_pack_block`` wherever
+    its values lie (no second, eager way to pack): values in one place are
+    packed there, whole, and handed to the shards; values on the mesh are
+    packed shard by shard and the whole-table program is never built.
+    Either way: rows padded to the shards' aligned blocks, the table
+    ``ps``-sharded, the same table as ``pack_table``'s."""
     monkeypatch.setattr(store_mod, "_PACK_CHUNK", 8)
     rows, d = 1000, 17
     want = np.random.default_rng(7).normal(size=(rows, d)).astype(np.float32)
@@ -152,7 +164,15 @@ def test_packing_under_a_mesh_is_the_same_program(origin, mesh, monkeypatch):
             want, jax.sharding.NamedSharding(
                 mesh, jax.sharding.PartitionSpec("ps", None))),
     }[origin]()
+    built = []
+    for name in ("_pack_rows", "_pack_rows_on_mesh"):
+        monkeypatch.setattr(
+            store_mod, name,
+            lambda spec, name=name, make=getattr(store_mod, name): (
+                built.append(name) or make(spec)))
     table = ShardedParamStore._place(spec, values)
+    assert built == [
+        "_pack_rows_on_mesh" if origin == "on_the_mesh" else "_pack_rows"]
     assert table.shape == spec.table_shape()
     assert table.sharding == spec.sharding()
     np.testing.assert_array_equal(
@@ -161,6 +181,215 @@ def test_packing_under_a_mesh_is_the_same_program(origin, mesh, monkeypatch):
             jnp.asarray(want), spec.rows_per_shard * spec.num_shards)))
     np.testing.assert_array_equal(
         np.asarray(ShardedParamStore(spec, table).values()), want)
+
+
+def _ps_mesh(mesh_devices, dp):
+    from flink_parameter_server_tpu.parallel.mesh import make_mesh
+
+    return make_mesh(dp, 4, devices=mesh_devices[:dp * 4])
+
+
+def _on_mesh(values, mesh):
+    """As the benchmark's values lie: row-sharded over ``ps`` where the
+    rows divide, else whole on every device of the mesh."""
+    P = jax.sharding.PartitionSpec
+    rows = P("ps", None) if values.shape[0] % 4 == 0 else P()
+    return jax.device_put(values, jax.sharding.NamedSharding(mesh, rows))
+
+
+# FM's 17-lane rows, 7 to a physical row, 4 shards of 8-aligned blocks:
+# 672 = 3 x (7 x 8 x 4) fills every block exactly; 1000 and 2004 leave each
+# packed shard 30 and 3 logical rows longer than a shard of the values (the
+# real table: 35), so shard s's block starts s x that many rows into its own
+# values and ends in its neighbour's, and their last physical row is partly
+# filled (1000 = 142 x 7 + 6, 2004 = 286 x 7 + 2); 100 rows are too few for
+# one neighbour to reach (packed whole, then placed); 1001 do not divide by
+# 4, so such values cannot lie row-sharded on the mesh.
+@pytest.mark.parametrize("dp", [1, 2])
+@pytest.mark.parametrize("capacity", [672, 1000, 2004, 100, 1001])
+def test_packed_store_under_a_mesh_equals_the_one_shard_store(
+        capacity, dp, mesh_devices, monkeypatch):
+    """One logical table: pull, push and ``values()`` of the packed store
+    sharded over ``ps = 4`` (and ``dp x ps = 2 x 4``) are those of the
+    one-shard packed store bit for bit, built from values that lie on the
+    mesh; ids with duplicates, negative and out-of-range ids, a lane mask."""
+    monkeypatch.setattr(store_mod, "_PACK_CHUNK", 8)
+    d = 17
+    mesh = _ps_mesh(mesh_devices, dp)
+    rng = np.random.default_rng(capacity)
+    want = rng.normal(size=(capacity, d)).astype(np.float32)
+    spec = StoreSpec(capacity=capacity, value_shape=(d,), layout="packed",
+                     mesh=mesh)
+    assert store_mod._next_shard_in_reach(spec, capacity) == (
+        capacity in (672, 1000, 2004))
+    sharded = ShardedParamStore.from_spec_values(spec, _on_mesh(want, mesh))
+    single = ShardedParamStore.from_spec_values(
+        StoreSpec(capacity=capacity, value_shape=(d,), layout="packed"),
+        jnp.asarray(want))
+    assert sharded.table.sharding == spec.sharding()
+    np.testing.assert_array_equal(np.asarray(sharded.values()), want)
+
+    # (the padding rows between ``capacity`` and each store's own
+    # ``padded_capacity`` are addressable, and the two stores pad differently)
+    ids = rng.integers(-9, capacity, size=(96, 5)).astype(np.int32)
+    ids[:, 0] = ids[0, 0]  # one row on a fifth of the lanes
+    ids[:8, 1] = [0, capacity - 1, capacity + 5000, -1, 6, 7, 2 ** 31 - 1,
+                  -2 ** 31]
+    deltas = rng.normal(size=ids.shape + (d,)).astype(np.float32)
+    mask = rng.random(ids.shape) < 0.7
+    ids, deltas, mask = map(jnp.asarray, (ids, deltas, mask))
+    np.testing.assert_array_equal(
+        np.asarray(sharded.pull(ids)), np.asarray(single.pull(ids)))
+    pushed = sharded.push(ids, deltas, mask)
+    assert pushed.table.sharding.is_equivalent_to(spec.sharding(), 2)
+    want_after = np.asarray(single.push(ids, deltas, mask).values())
+    assert not np.array_equal(want_after, want)
+    np.testing.assert_array_equal(np.asarray(pushed.values()), want_after)
+    np.testing.assert_array_equal(
+        np.asarray(pushed.pull(ids)),
+        np.asarray(single.push(ids, deltas, mask).pull(ids)))
+
+
+@pytest.mark.parametrize("shards", [None, (1, 4), (2, 4), (4, 1)])
+@pytest.mark.parametrize("capacity", [5, 672, 1001])
+def test_create_inits_and_packs_chunk_by_chunk_on_every_shard(
+        capacity, shards, mesh_devices, monkeypatch):
+    """``create`` of a packed store holds ``init_fn`` of EVERY row of its
+    table (the padding rows too: initialised, addressable), on one device
+    and with each shard of a mesh building its own block, whether the
+    blocks divide into chunks or the last chunk overlaps (``core/store.
+    _create_packed``); and no collective is needed to build it."""
+    from flink_parameter_server_tpu.parallel.mesh import make_mesh
+
+    monkeypatch.setattr(store_mod, "_PACK_CHUNK", 24)
+    d = 17
+    mesh = shards and make_mesh(
+        *shards, devices=mesh_devices[: shards[0] * shards[1]])
+    store = ShardedParamStore.create(
+        capacity, (d,), init_fn=_rand_init(d), mesh=mesh, layout="auto")
+    spec = store.spec
+    assert spec.layout == "packed" and store.table.shape == spec.table_shape()
+    if mesh is not None:
+        assert store.table.sharding.is_equivalent_to(spec.sharding(), 2)
+        assert not COLLECTIVE_OP.search(
+            store_mod._create_packed(spec, _rand_init(d))
+            .lower().compile().as_text())
+    want = np.asarray(_rand_init(d)(jnp.arange(spec.padded_capacity)))
+    np.testing.assert_array_equal(
+        np.asarray(unpack_table(store.table, spec.padded_capacity, d)), want)
+    np.testing.assert_array_equal(np.asarray(store.values()), want[:capacity])
+
+
+@pytest.mark.parametrize("dp", [1, 2])
+def test_placing_values_on_the_mesh_never_holds_them_or_the_table_whole(
+        dp, mesh_devices, monkeypatch):
+    """``_place`` of values that lie on the mesh: one program in which every
+    shard packs its own block, whose only collective hands the few rows at
+    a block's end over from the right neighbour.  No all-gather, no buffer
+    of the table's or the values' whole shape on a device, the result
+    ``ps``-sharded (a table sharded because it outgrew a chip cannot be
+    built whole; PERF.md section 6, PR 31)."""
+    monkeypatch.setattr(store_mod, "_PACK_CHUNK", 8)
+    rows, d = 2004, 17
+    mesh = _ps_mesh(mesh_devices, dp)
+    spec = StoreSpec(capacity=rows, value_shape=(d,), layout="packed",
+                     mesh=mesh)
+    values = _on_mesh(np.zeros((rows, d), np.float32), mesh)
+    lowered = store_mod._pack_rows_on_mesh(spec).lower(values)
+    compiled = lowered.compile()
+    assert compiled.output_shardings == spec.sharding()
+    text = compiled.as_text()
+    collectives = [
+        line for line in text.splitlines() if COLLECTIVE_OP.search(line)]
+    assert len(collectives) == 1, collectives
+    # 3 shards x 3 rows ahead: what the last-but-one block takes from its
+    # neighbour; the values' 501 rows a shard never travel
+    assert "collective-permute" in collectives[0]
+    assert f"f32[9,{d}]" in collectives[0]
+    whole = (f"f32[{rows},{d}]", "f32[%d,128]" % spec.table_shape()[0])
+    assert not any(shape in text for shape in whole), whole
+    assert f"f32[{rows // 4},{d}]" in text
+    assert f"f32[{spec.rows_per_shard},128]" in text
+    mem = compiled.memory_analysis()
+    if mem is not None:  # a shard's bytes, not the table's
+        assert mem.output_size_in_bytes == spec.rows_per_shard * 128 * 4
+        assert mem.argument_size_in_bytes == rows // 4 * d * 4
+
+
+@pytest.mark.parametrize("dp", [1, 2])
+def test_the_sharded_packed_step_holds_one_all_reduce_of_17_lane_rows(
+        dp, mesh_devices):
+    """FM's step on a packed table under ``ps = 4``: each shard slices the
+    physical rows it gathered down to 17 lanes BEFORE the step's one
+    all-reduce.  Left to GSPMD the ``jnp.take`` is all-reduced 128 lanes
+    wide, 7.5 x the bytes (PERF.md section 6, PR 31).  The scatter-add
+    needs no collective."""
+    from flink_parameter_server_tpu.core.transform import make_train_step
+    from flink_parameter_server_tpu.models import factorization_machine as fmm
+
+    mesh = _ps_mesh(mesh_devices, dp)
+    cfg = fmm.FMConfig(num_features=2004, dim=16)
+    spec = jax.eval_shape(lambda: fmm.make_store(cfg, mesh=mesh)).spec
+    assert spec.layout == "packed"
+    P, batch, fields = jax.sharding.PartitionSpec, 64, 5
+    lead = jax.sharding.NamedSharding(mesh, P("dp") if dp > 1 else P())
+
+    def shape(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=lead)
+
+    compiled = jax.jit(
+        make_train_step(fmm.FactorizationMachine(cfg), spec),
+        donate_argnums=(0, 1),
+    ).lower(
+        jax.ShapeDtypeStruct(
+            spec.table_shape(), jnp.float32, sharding=spec.sharding()),
+        (),
+        {"ids": shape((batch, fields), jnp.int32),
+         "values": shape((batch, fields), jnp.float32),
+         "feat_mask": shape((batch, fields), jnp.bool_),
+         "label": shape((batch,), jnp.float32),
+         "mask": shape((batch,), jnp.bool_)},
+    ).compile()
+    reduces = [
+        line for line in compiled.as_text().splitlines()
+        if re.search(r" all-reduce(-start)?\(", line)]
+    pull = [line for line in reduces if "ps.pull" in line]
+    assert len(pull) == 1, reduces
+    assert f"f32[{batch // dp * fields},17]" in pull[0]
+    assert ",128]" not in pull[0]
+    if dp == 1:  # under dp the push sums the workers' deltas, as dense
+        assert len(reduces) == 1, reduces
+
+
+@pytest.mark.parametrize("dp", [1, 2])
+def test_values_of_a_packed_sharded_table_come_out_sharded(dp, mesh_devices):
+    """The round trip a checkpoint of a sharded deployment makes:
+    ``from_spec_values -> push -> values()``.  The logical rows come out
+    row-sharded over ``ps``, as they went in: unpacked on the shards with a
+    few rows handed to the neighbour, not gathered onto one chip."""
+    mesh = _ps_mesh(mesh_devices, dp)
+    rows, d = 2012, 17
+    rng = np.random.default_rng(dp)
+    want = rng.normal(size=(rows, d)).astype(np.float32)
+    spec = StoreSpec(capacity=rows, value_shape=(d,), layout="packed",
+                     mesh=mesh)
+    store = ShardedParamStore.from_spec_values(spec, _on_mesh(want, mesh))
+    ids = jnp.asarray(rng.integers(0, rows, 300).astype(np.int32))
+    deltas = rng.normal(size=(300, d)).astype(np.float32)
+    np.add.at(want, np.asarray(ids), deltas)
+    values = store.push(ids, jnp.asarray(deltas)).values()
+    np.testing.assert_allclose(np.asarray(values), want, rtol=1e-5, atol=1e-6)
+    rows_over_ps = jax.sharding.NamedSharding(
+        mesh, jax.sharding.PartitionSpec("ps"))
+    assert values.sharding.is_equivalent_to(rows_over_ps, 2)
+    assert {s.data.shape for s in values.addressable_shards} == {
+        (rows // 4, d)}
+    text = store_mod._unpack_rows_on_mesh.lower(
+        spec, store.table).compile().as_text()
+    assert "all-gather" not in text and f"f32[{rows},{d}]" not in text
+    again = ShardedParamStore.from_spec_values(spec, values)
+    np.testing.assert_array_equal(
+        np.asarray(again.values()), np.asarray(values))
 
 
 def test_lane_shift_scatter_equivalence():
@@ -190,45 +419,52 @@ def test_auto_layout_resolution():
         )
 
 
-def test_fm_store_packs_on_one_shard_and_stays_dense_under_ps(mesh_devices):
+def test_fm_store_packs_on_one_shard_and_under_ps(mesh_devices, recwarn):
     """``make_store`` leaves the layout to the store, which reads the row
-    width (17 < 128 lanes), the update rule and the shard count: packed
-    with the table on one shard; dense under ``ps = 4``, said once and
-    counted (``_place`` cannot yet pack a table larger than a chip)."""
+    width (17 < 128 lanes) and the update rule, and not the shard count:
+    packed with the table on one shard and under ``ps = 4`` alike, with
+    nothing said (the refusal of PR 29 went with its reason, PR 31); a
+    store and its reload from values resolve to one layout."""
     from flink_parameter_server_tpu.models import factorization_machine as fmm
     from flink_parameter_server_tpu.parallel.mesh import make_mesh
 
     cfg = fmm.FMConfig(num_features=100, dim=16)
-    n0 = store_mod.packed_refusal_count()
     assert fmm.make_store(cfg).spec.layout == "packed"
     one_shard = make_mesh(4, 1, devices=mesh_devices[:4])
     assert fmm.make_store(cfg, mesh=one_shard).spec.layout == "packed"
     assert fmm.make_store(cfg, layout="dense").spec.layout == "dense"
-    assert store_mod.packed_refusal_count() == n0
     ps4 = make_mesh(1, 4, devices=mesh_devices[:4])
-    with pytest.warns(RuntimeWarning, match="sharded over ps=4.*dense") as w:
-        store = fmm.make_store(cfg, mesh=ps4)
-    assert len(w) == 1
-    assert store.spec.layout == "dense"
-    assert store_mod.packed_refusal_count() == n0 + 1
-    # where an operator reads it: the driver's gauges
+    store = fmm.make_store(cfg, mesh=ps4)
+    assert store.spec.layout == "packed"
+    assert store.table.shape == (32, 128)  # 4 shards x 8 physical rows
+    assert store.table.sharding == store.spec.sharding()
+    reloaded = ShardedParamStore.from_values(
+        store.values(), mesh=ps4, layout="auto")
+    assert reloaded.spec == store.spec
+    np.testing.assert_array_equal(
+        np.asarray(reloaded.values()), np.asarray(store.values()))
+    assert not [w for w in recwarn if "layout" in str(w.message)]
+    assert not hasattr(store_mod, "packed_refusal_count")
+    # where an operator reads it: the driver's gauge
     from flink_parameter_server_tpu.telemetry.registry import MetricsRegistry
     from flink_parameter_server_tpu.training.driver import StreamingDriver
 
+    dense = fmm.make_store(cfg, mesh=ps4, layout="dense")
     seen = {}
-    for name, st in (("one", fmm.make_store(cfg)), ("ps4", store)):
+    for name, st in (("one", fmm.make_store(cfg)), ("ps4", store),
+                     ("ps4_dense", dense)):
         reg = MetricsRegistry()
         StreamingDriver(fmm.FactorizationMachine(cfg), st, registry=reg)
         seen[name] = {k: v[0]["value"] for k, v in reg.snapshot().items()
                       if k.startswith("store_")}
-    assert seen["one"] == {"store_layout_packed": 1.0,
-                           "store_packed_refusals": n0 + 1}
-    assert seen["ps4"] == {"store_layout_packed": 0.0,
-                           "store_packed_refusals": n0 + 1}
-    # a pinned layout asks no question, so it is refused nothing
+    assert seen == {"one": {"store_layout_packed": 1.0},
+                    "ps4": {"store_layout_packed": 1.0},
+                    "ps4_dense": {"store_layout_packed": 0.0}}
+    # a pinned layout is what it says, and wide rows stay dense under a mesh
     assert fmm.make_store(cfg, mesh=ps4, layout="packed").spec.layout == "packed"
-    assert fmm.make_store(cfg, mesh=ps4, layout="dense").spec.layout == "dense"
-    assert store_mod.packed_refusal_count() == n0 + 1
+    assert dense.spec.layout == "dense"
+    assert ShardedParamStore.create(
+        100, (128,), mesh=ps4, layout="auto").spec.layout == "dense"
 
 
 def test_packed_checkpoint_roundtrip(tmp_path):

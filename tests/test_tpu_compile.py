@@ -5,6 +5,7 @@ compiler refuses costs a test failure instead of chip time.
 
 All such compiles live in THIS file: the worker that runs it loads the TPU
 library, inside a fixture, and keeps it until it exits."""
+import dataclasses
 import re
 
 import jax
@@ -20,9 +21,11 @@ from flink_parameter_server_tpu.ops import row_update
 
 # mf-hugewiki-k128 (chipbench/configs): the MF cells' shapes
 USERS, ITEMS, DIM, BATCH = 5_008_260, 39_780, 128, 65_536
-# fm-criteo-ps4: cell 4's table (17 f32 lanes pad to 24 on the chip: 16.8 GiB,
-# 4.51 GB a shard) and its one global batch
+# fm-criteo-ps4: cell 4's table (as values, 17 f32 lanes pad to 24 on the chip:
+# 16.8 GiB, 4.51 GB a shard; packed 7 rows to a 128-lane physical row,
+# 6,705,984 x 128 f32 = 3.43 GB a shard) and its one global batch
 FM_ROWS, FM_FIELDS, FM_BATCH = 187_767_412, 39, 32_768
+FM_SHARD_PHYS_ROWS = 6_705_984
 # fm-criteo: cell 2's table, on one chip (7 rows of 17 lanes to a 128-lane
 # physical row: 7,018,048 x 128 f32, 3.59 GB)
 FM1_ROWS, FM1_PHYS_ROWS = 49_126_310, 7_018_048
@@ -62,12 +65,12 @@ def ps4(topo):
     config = fmm.FMConfig(
         num_features=FM_ROWS, dim=16, learning_rate=1e-5, loss="logistic"
     )
-    # narrow rows, but sharded: the store keeps them dense and says so
-    with pytest.warns(RuntimeWarning, match="sharded over ps=4.*dense"):
-        spec = jax.eval_shape(
-            lambda: fmm.make_store(config, mesh=mesh, dtype=jnp.float32)
-        ).spec
-    assert spec.layout == "dense"
+    # narrow rows: packed on every shard, as on one chip
+    spec = jax.eval_shape(
+        lambda: fmm.make_store(config, mesh=mesh, dtype=jnp.float32)
+    ).spec
+    assert spec.layout == "packed"
+    assert spec.table_shape() == (4 * FM_SHARD_PHYS_ROWS, 128)
     return mesh, spec, fmm.FactorizationMachine(config)
 
 
@@ -199,13 +202,14 @@ def test_mf_step_default_arm_on_tpu_is_the_row_kernel(
 
 def test_padding_a_sharded_table_larger_than_a_chip_stays_on_its_shards(
         ps4, no_compile_cache):
-    """``ShardedParamStore._place`` appends cell 4's 12 padding rows to a
-    187.8 M-row array that is already sharded over ``ps``: each chip pads
-    its own 4.51 GB block and hands a few halo rows on.  The eager
-    concatenate it used before gathered the table on every chip
-    (RESOURCE_EXHAUSTED on the v5e, my chip run, PR 28; the TPU compiler
-    says the same here)."""
+    """``ShardedParamStore._place`` of a table pinned ``"dense"`` (cell 4's
+    before PR 31) appends its 12 padding rows to a 187.8 M-row array that
+    is already sharded over ``ps``: each chip pads its own 4.51 GB block
+    and hands a few halo rows on.  The eager concatenate it used before
+    gathered the table on every chip (RESOURCE_EXHAUSTED on the v5e, my
+    chip run, PR 28; the TPU compiler says the same here)."""
     mesh, spec, _ = ps4
+    spec = dataclasses.replace(spec, layout="dense")
     pad = spec.padded_capacity - FM_ROWS
     assert (spec.rows_per_shard, pad) == (46_941_856, 12)
     values = _shape(
@@ -224,13 +228,81 @@ def test_padding_a_sharded_table_larger_than_a_chip_stays_on_its_shards(
         ).compile()
 
 
+def test_packing_cell_4_s_table_stays_on_its_shards(ps4, no_compile_cache):
+    """``ShardedParamStore._place`` packs the 187.8 M x 17 rows that lie
+    sharded over ``ps`` (4.51 GB a chip) into the 3.43 GB block of each
+    chip, there (``core/store._pack_rows_on_mesh``): the values, the block
+    and one chunk, under the 9.416 GB the dense cell peaked at (ledger,
+    PR 30).  A packed shard holds 35 logical rows more than a shard of the
+    values, so at most 3 x 35 rows come from the right neighbour: the one
+    collective.  Nothing is gathered."""
+    mesh, spec, _ = ps4
+    assert store_mod._next_shard_in_reach(spec, FM_ROWS)
+    assert spec.rows_per_shard * spec.pack - FM_ROWS // 4 == 35
+    values = _shape(
+        NamedSharding(mesh, PartitionSpec("ps", None)), (FM_ROWS, 17),
+        jnp.float32,
+    )
+    compiled = store_mod._pack_rows_on_mesh(spec).lower(values).compile()
+    collectives = [
+        line for line in compiled.as_text().splitlines()
+        if COLLECTIVE_OP.search(line)
+    ]
+    assert len(collectives) == 1, collectives
+    assert "collective-permute" in collectives[0]
+    assert "f32[105,17]" in collectives[0]
+    mem = compiled.memory_analysis()
+    assert 4.5 * GB < mem.argument_size_in_bytes < 4.6 * GB
+    assert 3.43 * GB < mem.output_size_in_bytes < 3.44 * GB
+    assert mem.temp_size_in_bytes < 0.5 * GB
+    assert (
+        mem.argument_size_in_bytes + mem.output_size_in_bytes
+        + mem.temp_size_in_bytes
+    ) < 8.5 * GB
+
+
+def test_unpacking_cell_4_s_table_stays_on_its_shards(ps4, no_compile_cache):
+    """``values()`` of cell 4's packed table (what a checkpoint of the
+    deployment writes): each chip unpacks its own 3.43 GB block into its
+    4.51 GB of logical rows chunk by chunk (``core/store._unpack_block``),
+    taking at most 105 rows from its left neighbour; ``unpack_table`` on
+    the whole block first lays its rows 128 lanes wide, 24 GB a chip."""
+    from flink_parameter_server_tpu.ops.packed import unpack_table
+
+    mesh, spec, _ = ps4
+    table = _shape(spec.sharding(), spec.table_shape(), jnp.float32)
+    compiled = store_mod._unpack_rows_on_mesh.lower(spec, table).compile()
+    collectives = [
+        line for line in compiled.as_text().splitlines()
+        if COLLECTIVE_OP.search(line)
+    ]
+    assert len(collectives) == 1, collectives
+    assert "collective-permute" in collectives[0]
+    assert "f32[105,17]" in collectives[0]
+    mem = compiled.memory_analysis()
+    assert 4.5 * GB < mem.output_size_in_bytes < 4.6 * GB
+    assert mem.temp_size_in_bytes < 0.5 * GB
+    assert (
+        mem.argument_size_in_bytes + mem.output_size_in_bytes
+        + mem.temp_size_in_bytes
+    ) < 8.5 * GB
+    rows_over_ps = NamedSharding(mesh, PartitionSpec("ps"))
+    with pytest.raises(jax.errors.JaxRuntimeError, match="RESOURCE_EXHAUSTED"):
+        jax.jit(
+            lambda t: unpack_table(t, FM_ROWS, 17), out_shardings=rows_over_ps
+        ).lower(table).compile()
+
+
 def test_fm_step_on_four_chips_keeps_its_collective_under_ps_pull(
         ps4, no_compile_cache):
-    """Cell 4's step, partitioned by GSPMD with ``make_store``'s default
-    arms: the donated 4.51 GB shard is updated in place, and the ONE
-    collective is the all-reduce of the gathered rows, whose ``op_name``
-    carries ``ps.pull``: a device trace reads it under
-    ``store.pull_device_ms`` (docs/observability.md)."""
+    """Cell 4's step on the layout ``make_store`` resolves by itself: the
+    donated 3.43 GB shard (``f32[6705984,128]``) is updated in place,
+    every gather moves whole 128-lane rows, and the ONE collective is the
+    all-reduce of the pulled rows AFTER their lane slice, 17 lanes wide
+    (left to GSPMD it is ``f32[1277952,128]``, 7.5 x the bytes), whose
+    ``op_name`` carries ``ps.pull``: a device trace reads it under
+    ``store.pull_device_ms`` (docs/observability.md).  The scatter-add is
+    GSPMD's, into the chip's own block, with no collective."""
     mesh, spec, logic = ps4
     batch = _fm_batch(NamedSharding(mesh, PartitionSpec()))
     compiled = jax.jit(
@@ -239,17 +311,29 @@ def test_fm_step_on_four_chips_keeps_its_collective_under_ps_pull(
         _shape(spec.sharding(), spec.table_shape(), jnp.float32), (), batch
     ).compile()
     mem = compiled.memory_analysis()
-    assert mem.alias_size_in_bytes > 4.5 * GB  # in place
-    assert mem.temp_size_in_bytes < 1.0 * GB
+    assert mem.alias_size_in_bytes > 3.43 * GB  # in place
+    # 1.350 GB here (the dense step's: under 1.0): two ``f32[1277952,128]``
+    # of 654 MB live at once, the gathered physical rows (in the push the
+    # lane-shifted deltas) and their relayout, as on one chip (1.352)
+    assert mem.temp_size_in_bytes < 1.4 * GB
+    text = compiled.as_text()
     collectives = [
-        line for line in compiled.as_text().splitlines()
-        if COLLECTIVE_OP.search(line)
+        line for line in text.splitlines() if COLLECTIVE_OP.search(line)
     ]
     assert len(collectives) == 1, collectives
-    assert f" f32[{FM_BATCH},{FM_FIELDS},17]" in collectives[0]
+    # placed by the partitioner behind the pulled rows' relayout, as the
+    # dense step's was: XLA's own name, which the benchmark's reader knows
+    assert f"%all-reduce = f32[{FM_BATCH},{FM_FIELDS},17]" in collectives[0]
     assert "all-reduce(" in collectives[0]
     assert 'op_name="jit(step)/ps.pull/' in collectives[0]
-
+    assert f"f32[{FM_SHARD_PHYS_ROWS},128]" in text
+    gathers = [
+        line for line in text.splitlines()
+        if " gather(" in line and re.search(r"ps\.(pull|push)", line)
+    ]
+    assert gathers and all(
+        "slice_sizes={1,128}" in line for line in gathers
+    ), gathers
 
 
 def test_fm_step_on_one_chip_moves_whole_128_lane_rows(
@@ -269,7 +353,7 @@ def test_fm_step_on_one_chip_moves_whole_128_lane_rows(
     ).compile()
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes > 3.59 * GB  # in place
-    assert mem.temp_size_in_bytes < 1.5 * GB
+    assert mem.temp_size_in_bytes < 1.4 * GB  # 1.352: two f32[1277952,128]
     gathers = [
         line for line in compiled.as_text().splitlines()
         if " gather(" in line and re.search(r"ps\.(pull|push)", line)
@@ -299,3 +383,86 @@ def test_packing_fm_s_table_fits_the_chip_chunk_by_chunk(
     ) < 9 * GB  # of the chip's 16
     with pytest.raises(jax.errors.JaxRuntimeError, match="RESOURCE_EXHAUSTED"):
         jax.jit(lambda v: pack_table(v, FM1_PHYS_ROWS)).lower(values).compile()
+
+
+def test_unpacking_fm_s_table_fits_the_chip_chunk_by_chunk(
+        fm1, one_chip, no_compile_cache):
+    """``values()`` of cell 2's table on its one chip: the 3.59 GB table,
+    the 4.72 GB of logical rows and one chunk (``core/store._unpack_rows``),
+    where ``unpack_table`` at once asks for 25 GB as packing at once does."""
+    from flink_parameter_server_tpu.ops.packed import unpack_table
+
+    spec, _ = fm1
+    table = _shape(one_chip, spec.table_shape(), jnp.float32)
+    mem = store_mod._unpack_rows.lower(spec, table).compile().memory_analysis()
+    assert 4.7 * GB < mem.output_size_in_bytes < 4.73 * GB
+    assert mem.temp_size_in_bytes < 0.5 * GB
+    assert (
+        mem.argument_size_in_bytes + mem.output_size_in_bytes
+        + mem.temp_size_in_bytes
+    ) < 9 * GB
+    with pytest.raises(jax.errors.JaxRuntimeError, match="RESOURCE_EXHAUSTED"):
+        jax.jit(lambda t: unpack_table(t, FM1_ROWS, 17)).lower(table).compile()
+
+
+def _table_made_at_once(spec, config):
+    """What ``create_table`` ran for a packed spec before PR 31: every row
+    initialised, then one ``pack_table``."""
+    from flink_parameter_server_tpu.models import factorization_machine as fmm
+    from flink_parameter_server_tpu.ops.packed import pack_table
+
+    def build():
+        rows = fmm.make_store(
+            dataclasses.replace(config, num_features=spec.padded_capacity),
+            layout="dense",
+        ).table
+        return pack_table(rows, spec.table_shape()[0])
+
+    return build
+
+
+def test_creating_cell_4_s_table_inits_and_packs_on_its_shards(
+        ps4, no_compile_cache):
+    """``make_store(config, mesh=...)`` at cell 4's size (``train_fm(mesh=
+    ...)``; the cell itself places values): every chip initialises and
+    packs its own 3.43 GB block chunk by chunk (``core/store.
+    _create_packed``), one chunk of rows beside it (0.94 GB) and no
+    collective.  All rows at once are laid 128 lanes wide first: 24 GB
+    asked of each chip."""
+    from flink_parameter_server_tpu.models import factorization_machine as fmm
+
+    mesh, spec, logic = ps4
+    compiled = jax.jit(
+        lambda: fmm.make_store(logic.config, mesh=mesh).table
+    ).lower().compile()
+    assert not [
+        line for line in compiled.as_text().splitlines()
+        if COLLECTIVE_OP.search(line)
+    ]
+    mem = compiled.memory_analysis()
+    assert 3.43 * GB < mem.output_size_in_bytes < 3.44 * GB
+    assert mem.temp_size_in_bytes < 1.0 * GB
+    with pytest.raises(jax.errors.JaxRuntimeError, match="RESOURCE_EXHAUSTED"):
+        jax.jit(
+            _table_made_at_once(spec, logic.config),
+            out_shardings=spec.sharding(),
+        ).lower().compile()
+
+
+def test_creating_fm_s_table_fits_the_chip_chunk_by_chunk(
+        fm1, one_chip, no_compile_cache):
+    """``make_store(config)`` at cell 2's size on its one chip: the 3.59 GB
+    table and one chunk of initialised rows (0.94 GB), where all 49.1 M
+    rows at once ask for 25 GB."""
+    from flink_parameter_server_tpu.models import factorization_machine as fmm
+
+    spec, logic = fm1
+    mem = jax.jit(
+        lambda: fmm.make_store(logic.config).table, out_shardings=one_chip
+    ).lower().compile().memory_analysis()
+    assert 3.59 * GB < mem.output_size_in_bytes < 3.6 * GB
+    assert mem.temp_size_in_bytes < 1.0 * GB
+    with pytest.raises(jax.errors.JaxRuntimeError, match="RESOURCE_EXHAUSTED"):
+        jax.jit(
+            _table_made_at_once(spec, logic.config), out_shardings=one_chip
+        ).lower().compile()
