@@ -50,17 +50,27 @@ def _resolve_layout(
 ) -> str:
     """Resolve the table layout, validating packed-layout constraints.
 
-    ``"auto"`` reads the row width and the update rule: packed for an
-    add-store whose rows are narrower than 128 lanes, dense otherwise.  A
-    narrow dense row is a column of scalars across the table's tiles on
-    the TPU, and its gather and scatter-add walk that column (36 and 121
-    ns a row for FM's 17 lanes on the v5e, against 10 and 22 for the
-    128-lane physical row that holds seven of them; PERF.md section 6,
-    PR 29).  The shard count is not asked: under a mesh every shard holds
-    its own packed block (``create_table`` initialises and packs each
-    shard's block on its shard, ``_place`` packs values that lie on the
-    mesh shard by shard), so a store and its reload from a checkpoint
-    (``from_values``) resolve to one layout."""
+    ``"auto"`` reads the row's shape and the update rule: dense for an
+    add-store whose rows are ONE axis of a whole number of 128 lanes (a
+    row is then a whole number of vector registers as it is), packed for
+    every other add-store.  A narrow dense row is a column of scalars
+    across the table's tiles on the TPU, and its gather and scatter-add
+    walk that column (36 and 121 ns a row for FM's 17 lanes on the v5e,
+    against 10 and 22 for the 128-lane physical row that holds seven of
+    them; PERF.md section 6, PR 29).  A row of 128 lanes or more that has
+    two axes or is no multiple of 128 is packed ONE to a physical row,
+    flat and zero-padded to whole registers (``ops/packed.py``: ``pack_k``
+    1, ``phys_width`` the padded width): left as it is, the TPU holds
+    ``(capacity, 2, 300)`` and ``(capacity, 600)`` capacity-minor (no
+    padding) and the step copies the WHOLE table to a row-major one for
+    its gather and back after its scatter-add, two table-sized passes and
+    a table-sized temporary a step (compiled for a v5e at 3,000,000 rows:
+    7.7-9.2 GB of temporaries; padded flat, 0.65 GB and neither copy;
+    PERF.md section 6, PR 32).  The shard count is not asked: under a
+    mesh every shard holds its own packed block (``create_table``
+    initialises and packs each shard's block on its shard, ``_place``
+    packs values that lie on the mesh shard by shard), so a store and its
+    reload from a checkpoint (``from_values``) resolve to one layout."""
     if layout not in ("dense", "packed", "auto"):
         raise ValueError(
             f"layout must be 'dense', 'packed' or 'auto', got {layout!r}"
@@ -69,7 +79,8 @@ def _resolve_layout(
     for s in value_shape:
         width *= int(s)
     if layout == "auto":
-        return "packed" if update == "add" and width < 128 else "dense"
+        whole = len(value_shape) == 1 and width % 128 == 0
+        return "packed" if update == "add" and not whole else "dense"
     if layout == "packed" and update != "add":
         # the generic update path applies `update` per logical row on a
         # dense combined table — packing it would need an unpack per push
@@ -96,7 +107,9 @@ class StoreSpec:
     # "dense": one logical row per physical row (the trivial layout).
     # "packed": k = 128 // row_width logical rows per 128-lane physical
     #   row (ops/packed.py) — the TPU-native layout for narrow values
-    #   (MF dim 64, FM dim 17): full vector lanes on every pull/push.
+    #   (MF dim 64, FM dim 17): full vector lanes on every pull/push.  A
+    #   row of 128 lanes or more lies alone (k = 1), flat and zero-padded
+    #   to whole 128-lane registers (word2vec's (2, 300): 640 lanes).
     #   Requires update="add".
     layout: str = "dense"
 

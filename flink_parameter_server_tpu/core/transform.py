@@ -404,8 +404,23 @@ def transform_batched(
     skip_batches: int = 0,
     steps_per_call: int = 1,
     tracer: SpanTracer = NULL_TRACER,
+    owns_inputs: bool = False,
 ) -> TransformResult:
     """Run the compiled PS loop over an iterable of microbatches.
+
+    Who owns the table: the jitted step donates ``(table, state)``, so by
+    default the loop starts from COPIES of ``store.table`` and
+    ``initial_state`` and the caller's store stays valid, at the price of
+    two tables alive for the length of the run.  ``owns_inputs=True``
+    hands both over instead: the loop donates the very buffers it was
+    given, ``store.table`` and ``initial_state`` are deleted by the first
+    dispatch, and the one live table is the one a callback sees, then
+    ``result.store``.  The caller must drop its own references.  Every
+    caller that has no further use for what it passes does so: the
+    StreamingDriver (a table over half a chip's memory cannot run
+    otherwise) and the ``train_*`` / ``ps_online_mf`` / PA helpers, whose
+    store is built inside them (an ``initial_state`` passed through them
+    is handed over with it; ``owns_inputs=False`` keeps it).
 
     ``state_callback(step_idx, table, state, out)`` additionally sees the
     live (donated-next-step) table/state — the hook the StreamingDriver
@@ -467,12 +482,14 @@ def transform_batched(
         scan_step = jax.jit(
             make_scan_train_step(worker_logic, spec), donate_argnums=(0, 1)
         )
-    # The jitted step donates (table, state); start from copies so the
-    # caller's store (and any restored state they still hold) stays valid
-    # — the same contract transform_dense gives (dense.py).  A fresh
-    # init_state has no outside owner, so only restored state is copied.
+    # The jitted step donates (table, state); unless the caller handed
+    # them over (`owns_inputs`), start from copies so the caller's store
+    # (and any restored state they still hold) stays valid — the same
+    # contract transform_dense gives (dense.py).  A fresh init_state has
+    # no outside owner, so only restored state is copied.
+    keep = (lambda x: x) if owns_inputs else jnp_copy
     state = (
-        jax.tree.map(jnp_copy, initial_state)
+        jax.tree.map(keep, initial_state)
         if initial_state is not None
         else worker_logic.init_state(rng)
     )
@@ -487,7 +504,7 @@ def transform_batched(
         scan_group_sharding(batch_sharding) if steps_per_call > 1 else None
     )
 
-    table = jnp_copy(store.table)
+    table = keep(store.table)
     worker_outputs: List[Any] = []
     step_idx = 0
 

@@ -123,6 +123,8 @@ def train_fm(data, config: FMConfig, *, seed: int = 0, mesh=None, **kwargs):
     (num_features, 1+dim) model."""
     logic = FactorizationMachine(config)
     store = make_store(config, seed=seed, mesh=mesh)
+    # the store built here has no other owner: the loop takes it, no copy
+    kwargs.setdefault("owns_inputs", True)
     return transform_batched(
         data, logic, store, rng=jax.random.PRNGKey(seed), mesh=mesh, **kwargs
     )

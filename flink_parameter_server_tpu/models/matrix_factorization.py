@@ -251,6 +251,8 @@ def ps_online_mf(
         mesh=mesh,
         layout=layout,
     )
+    # the store built here has no other owner: the loop takes it, no copy
+    transform_kwargs.setdefault("owns_inputs", True)
     return transform_batched(
         ratings, logic, store, rng=jax.random.PRNGKey(seed), mesh=mesh,
         **transform_kwargs,

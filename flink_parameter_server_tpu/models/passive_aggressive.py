@@ -141,6 +141,8 @@ def transform_binary(
     store = ShardedParamStore.create(
         num_features, (), init_fn=zeros(()), mesh=mesh
     )
+    # the store built here has no other owner: the loop takes it, no copy
+    kwargs.setdefault("owns_inputs", True)
     return transform_batched(data, logic, store, mesh=mesh, **kwargs)
 
 
@@ -157,6 +159,8 @@ def transform_multiclass(
     store = ShardedParamStore.create(
         num_features, (num_classes,), init_fn=zeros((num_classes,)), mesh=mesh
     )
+    # the store built here has no other owner: the loop takes it, no copy
+    kwargs.setdefault("owns_inputs", True)
     return transform_batched(data, logic, store, mesh=mesh, **kwargs)
 
 

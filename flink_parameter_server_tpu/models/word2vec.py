@@ -14,6 +14,13 @@ negatives pulls ``(B, N+2)`` rows, computes the loss/gradients as fused
 batched matvecs, and pushes one ``(B, N+2, 2, dim)`` scatter-add (zeros in
 the untouched slot).  Negative sampling happens host-side in the data
 stream (unigram^0.75), or on-device via ``sample_negatives``.
+
+How the rows lie on the chip is the store's to decide
+(``core/store._resolve_layout``, which ``make_store`` asks by default): a
+``(2, dim)`` row is held flat and zero-padded to whole 128-lane registers,
+one logical row to a physical row (``(2, 300)``: 640 lanes), because a
+table left as ``(vocab, 2, dim)`` is copied whole twice a step on the TPU.
+The logic sees ``(2, dim)`` rows either way.
 """
 from __future__ import annotations
 
@@ -25,6 +32,7 @@ import jax.numpy as jnp
 from ..core.batched import BatchedWorkerLogic, PushRequest
 from ..core.store import ShardedParamStore
 from ..core.transform import transform_batched
+from ..training.tracing import scope
 from ..utils.initializers import ranged_random_factor
 
 Array = jax.Array
@@ -86,22 +94,23 @@ class SkipGramNS(BatchedWorkerLogic):
 
         B, d = v.shape
         N = u_neg.shape[1]
-        deltas = jnp.zeros((B, N + 2, 2, d), v.dtype)
-        deltas = deltas.at[:, 0, IN].set(-lr * d_v)
-        deltas = deltas.at[:, 1, OUT].set(-lr * d_upos)
-        deltas = deltas.at[:, 2:, OUT].set(-lr * d_uneg)
-
         mask = batch.get("mask")
         lane_mask = None
         if mask is not None:
             lane_mask = jnp.broadcast_to(mask[:, None], (B, N + 2))
 
-        if self.dedup_scale:
-            from ..ops.dedup import occurrence_scale
+        with scope("ps.delta_build"):
+            # one (2, d) delta a pulled row: zeros in the slot it leaves
+            deltas = jnp.zeros((B, N + 2, 2, d), v.dtype)
+            deltas = deltas.at[:, 0, IN].set(-lr * d_v)
+            deltas = deltas.at[:, 1, OUT].set(-lr * d_upos)
+            deltas = deltas.at[:, 2:, OUT].set(-lr * d_uneg)
+            if self.dedup_scale:
+                from ..ops.dedup import occurrence_scale
 
-            keys = self.keys(batch)
-            scale = occurrence_scale(keys, self.vocab_size, lane_mask)
-            deltas = deltas * scale[..., None, None]
+                keys = self.keys(batch)
+                scale = occurrence_scale(keys, self.vocab_size, lane_mask)
+                deltas = deltas * scale[..., None, None]
 
         loss = -(
             jax.nn.log_sigmoid(pos_logit)
@@ -120,12 +129,17 @@ def make_store(
     seed: int = 0,
     mesh=None,
     init_scale: float = 0.5,
-    layout: str = "dense",
+    dtype=jnp.float32,
+    layout: str = "auto",
 ) -> ShardedParamStore:
     """(vocab, 2, dim) store; input slot random-uniform (the word2vec
-    convention: U(-0.5/dim, 0.5/dim)), output slot zero."""
+    convention: U(-0.5/dim, 0.5/dim)), output slot zero.  ``seed`` may be
+    traced (``jax.jit(lambda seed: make_store(..., seed=seed))``: one
+    program whatever the seed).  ``layout="auto"`` leaves the rows' place
+    on the chip to ``core/store._resolve_layout``."""
     base = ranged_random_factor(
-        seed, (dim,), low=-init_scale / dim, high=init_scale / dim
+        seed, (dim,), low=-init_scale / dim, high=init_scale / dim,
+        dtype=dtype,
     )
 
     def init(ids: Array) -> Array:
@@ -133,7 +147,7 @@ def make_store(
         return jnp.stack([in_emb, jnp.zeros_like(in_emb)], axis=1)
 
     return ShardedParamStore.create(
-        vocab_size, (2, dim), init_fn=init, mesh=mesh,
+        vocab_size, (2, dim), dtype=dtype, init_fn=init, mesh=mesh,
         layout=layout,
     )
 
@@ -164,6 +178,8 @@ def train_skipgram(
         learning_rate, dedup_scale=dedup_scale, vocab_size=vocab_size
     )
     store = make_store(vocab_size, dim, seed=seed, mesh=mesh)
+    # the store built here has no other owner: the loop takes it, no copy
+    kwargs.setdefault("owns_inputs", True)
     return transform_batched(
         pairs, logic, store, rng=jax.random.PRNGKey(seed), mesh=mesh, **kwargs
     )

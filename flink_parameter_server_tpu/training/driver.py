@@ -46,6 +46,16 @@ class TrainingDiverged(RuntimeError):
         self.step = step
 
 
+def _is_live(*trees) -> bool:
+    """No leaf of the given pytrees is a deleted (donated) array."""
+    return not any(
+        leaf.is_deleted()
+        for tree in trees
+        for leaf in jax.tree.leaves(tree)
+        if isinstance(leaf, jax.Array)
+    )
+
+
 def _all_finite(*trees) -> jax.Array:
     """Single fused device-side finiteness reduction over every floating
     leaf of the given pytrees (one host transfer at the bool() call)."""
@@ -128,6 +138,19 @@ class StreamingDriver:
     re-feed the SAME logical stream from the beginning and the driver
     skips what was already consumed.  Pass ``fast_forward=False`` to feed
     a fresh stream instead.
+
+    Who owns the table: the driver does, from construction on.  ``run``
+    hands the table and the worker state it holds to the loop, which
+    donates them to its first dispatch (one table alive for the length of
+    the run, not two), and takes the loop's final ones back, so the
+    ``store`` passed to the constructor is deleted by the first ``run``:
+    read ``driver.store`` (or ``result.store``) afterwards.  During a run
+    ``driver.store`` carries the spec and no table (``table is None``);
+    hooks, publishes and checkpoints get the live table as an argument.
+    After a run that raised it holds the last table a dispatch left
+    (or, with a ``checkpoint_dir``, the last durable checkpoint's); if the
+    dispatch itself failed and no checkpoint exists, none, and ``run`` and
+    ``save`` say so.
     """
 
     def __init__(
@@ -198,9 +221,18 @@ class StreamingDriver:
     # destroy the previous durable checkpoint), old steps are pruned, and
     # async mode overlaps disk writes with training.
 
+    def _require_table(self) -> None:
+        if self.store.table is None:
+            raise RuntimeError(
+                "this driver holds no table: a dispatch that failed consumed "
+                "the one it was handed; resume() from a checkpoint or build "
+                "a new driver"
+            )
+
     def save(self) -> None:
         if self._ckpt_mgr is None:
             return
+        self._require_table()
         # force: an explicit save must land even if this step was already
         # checkpointed (orbax otherwise silently skips duplicate steps)
         with self.tracer.span("checkpoint", component="train"):
@@ -305,6 +337,11 @@ class StreamingDriver:
         collect_outputs: bool = False,
         fast_forward: bool = True,
     ) -> TransformResult:
+        """Train over ``data``.  For the length of the call the loop owns
+        the table and the worker state this driver held (they are donated,
+        not copied); ``self.store`` carries the spec and no table until the
+        loop's final ones come back (class docstring)."""
+        self._require_table()
         cfg = self.config
         spec = self.store.spec
         start_step = self.step_idx
@@ -386,6 +423,7 @@ class StreamingDriver:
         first_step_of_run = [True]
 
         def group_callback(first_idx, n_steps, table, state, outs):
+            live[:] = table, state
             # One invocation per jitted DISPATCH (n_steps == 1 when
             # steps_per_call == 1 — then this is exactly the old
             # per-step state_callback; n_steps == K for scanned groups,
@@ -531,24 +569,41 @@ class StreamingDriver:
             # non-main threads can't install handlers; the flag can still
             # be set externally via request_stop()
 
+        # The loop owns table and state for the length of the run: it
+        # donates the buffers this driver held, so no second table is
+        # alive beside the one being trained (a table over half the
+        # chip's memory could not run otherwise).  `self.store` keeps the
+        # spec and NO table meanwhile, so it never holds a deleted array;
+        # `live` names the buffers the last dispatch left, for the
+        # handler below to take back if the run raises.
+        handed, self.store = self.store, ShardedParamStore(spec, None)
+        handed_state, self._state = self._state, None
+        live = [handed.table, handed_state]
+
         try:
             result = transform_batched(
                 it,
                 self.logic,
-                self.store,
+                handed,
                 rng=self.rng,
                 collect_outputs=collect_outputs,
                 dump_model=cfg.dump_model,
                 group_callback=group_callback,
-                initial_state=self._state,
+                initial_state=handed_state,
                 skip_batches=skip,
                 steps_per_call=cfg.steps_per_call,
                 tracer=tracer,
+                owns_inputs=True,
             )
         except BaseException:
-            # The in-flight table/state buffers were donated; leave the
-            # driver usable by reloading the last durable checkpoint (if
-            # any) before propagating.
+            # Leave the driver usable: take back what the last dispatch
+            # left (a hook or the source raised between dispatches, so
+            # those buffers are live; a dispatch that itself failed may
+            # have consumed them, and then the store keeps no table),
+            # then reload the last durable checkpoint, if any.
+            if _is_live(*live):
+                self.store = ShardedParamStore(spec, live[0])
+                self._state = live[1]
             if self._ckpt_mgr is not None:
                 self.resume()
             raise

@@ -437,3 +437,85 @@ def test_topk_exact_dense_matches_sharded(mesh):
     np.testing.assert_allclose(
         np.asarray(s_ex), np.asarray(s_sh), atol=1e-5
     )
+
+
+# -- wide rows: which layout "auto" resolves, and that it holds ---------------
+# A row of 128 lanes or more that has two axes or is no multiple of 128 lies
+# ONE to a physical row, flat and zero-padded to whole 128-lane registers
+# (the packed layout with a pack factor of 1); a row that is one axis of whole
+# registers stays as it is.  word2vec's (2, 300), its flat (600,), one of its
+# vectors (300,), and MF's (128,).
+WIDE = [((2, 300), "packed", 640), ((600,), "packed", 640),
+        ((300,), "packed", 384), ((2, 64), "packed", 128),
+        ((128,), "dense", 128), ((256,), "dense", 256)]
+
+
+@pytest.mark.parametrize("shape, layout, lanes", WIDE, ids=[str(w[0]) for w in WIDE])
+def test_auto_layout_of_wide_rows_agrees_with_numpy(shape, layout, lanes):
+    rng = np.random.default_rng(lanes + len(shape))
+    values = rng.normal(0, 1, (CAP,) + shape).astype(np.float32)
+    store = ShardedParamStore.from_values(jnp.asarray(values), layout="auto")
+    assert store.spec.layout == layout and store.spec.pack == 1
+    assert store.spec.value_shape == shape
+    want_table = (64, lanes) if layout == "packed" else (64,) + shape
+    assert store.table.shape == want_table == store.spec.table_shape()
+    # the round trip is bit-equal, and so is a pull of every row
+    np.testing.assert_array_equal(np.asarray(store.values()), values)
+    np.testing.assert_array_equal(
+        np.asarray(store.pull(jnp.arange(CAP))), values
+    )
+    for traffic in ("zipf_hot", "neg_and_oob", "ids_2d_lane_mask"):
+        _check_push_pull(store, values, *_traffic(traffic, rng, CAP, shape))
+    # `create` initialises the same rows (in blocks, in place) as `from_values`
+    # places, padding rows and lanes zero
+    created = ShardedParamStore.create(
+        CAP, shape, layout="auto",
+        init_fn=lambda ids: jnp.asarray(values)[jnp.clip(ids, 0, CAP - 1)]
+        * (ids < CAP).reshape((-1,) + (1,) * len(shape)),
+    )
+    assert created.spec == store.spec
+    np.testing.assert_array_equal(
+        np.asarray(created.table), np.asarray(store.table)
+    )
+    if layout == "packed":
+        width = int(np.prod(shape))
+        assert (np.asarray(store.table)[:, width:] == 0).all()
+        # a pinned "dense" keeps the rows as they are
+        pinned = ShardedParamStore.from_values(jnp.asarray(values), layout="dense")
+        assert pinned.table.shape == (64,) + shape
+
+
+def test_a_wide_rank_2_row_under_a_ps_mesh(mesh):
+    rng = np.random.default_rng(5)
+    shape = (2, 300)
+    values = rng.normal(0, 1, (CAP,) + shape).astype(np.float32)
+    store = ShardedParamStore.from_values(
+        jnp.asarray(values), layout="auto", mesh=mesh)
+    assert store.spec.layout == "packed" and store.spec.num_shards == 4
+    assert store.table.shape == (64, 640)
+    assert store.table.sharding == store.spec.sharding()
+    np.testing.assert_array_equal(np.asarray(store.values()), values)
+    ids, deltas, _ = _traffic("zipf_hot", rng, CAP, shape)
+    _check_push_pull(store, values, ids, deltas, rng.random(ids.shape) > 0.2)
+
+
+def test_auto_leaves_a_128_lane_store_and_its_step_as_they_were():
+    """Cell 1's program must not move: ``"auto"`` on one axis of whole
+    registers is the dense layout, spec for spec and step text for text."""
+    from flink_parameter_server_tpu.models.matrix_factorization import (
+        OnlineMatrixFactorization,
+        SGDUpdater,
+    )
+
+    _, _, batch = _mf_case(np.random.default_rng(0), 64)
+    logic = OnlineMatrixFactorization(40, 128, updater=SGDUpdater(0.05))
+    values = jnp.asarray(_init_values(CAP, (128,)))
+    texts = []
+    for layout in ("dense", "auto"):
+        store = ShardedParamStore.from_values(values, layout=layout)
+        state = logic.init_state(jax.random.PRNGKey(0))
+        texts.append(jax.jit(make_train_step(logic, store.spec)).lower(
+            store.table, state, batch
+        ).as_text())
+        assert store.spec.layout == "dense"
+    assert texts[0] == texts[1]

@@ -466,3 +466,84 @@ def test_creating_fm_s_table_fits_the_chip_chunk_by_chunk(
         jax.jit(
             _table_made_at_once(spec, logic.config), out_shardings=one_chip
         ).lower().compile()
+
+
+# the released GoogleNews word2vec table: 3,000,000 words x (2, 300) f32, and a
+# batch of 16,384 pairs with 5 negatives = 114,688 keys
+W2V_VOCAB, W2V_DIM, W2V_BATCH, W2V_NEG = 3_000_000, 300, 16_384, 5
+
+
+@pytest.fixture(scope="module")
+def w2v():
+    from flink_parameter_server_tpu.models import word2vec as w2vm
+
+    spec = jax.eval_shape(
+        lambda: w2vm.make_store(W2V_VOCAB, W2V_DIM, dtype=jnp.float32)
+    ).spec
+    return spec, w2vm.SkipGramNS(0.025), w2vm
+
+
+def _w2v_step(one_chip, spec, logic):
+    batch = {
+        "center": _shape(one_chip, (W2V_BATCH,), jnp.int32),
+        "context": _shape(one_chip, (W2V_BATCH,), jnp.int32),
+        "negatives": _shape(one_chip, (W2V_BATCH, W2V_NEG), jnp.int32),
+        "mask": _shape(one_chip, (W2V_BATCH,), jnp.bool_),
+    }
+    return jax.jit(make_train_step(logic, spec), donate_argnums=(0, 1)).lower(
+        _shape(one_chip, spec.table_shape(), jnp.float32), (), batch
+    )
+
+
+def test_w2v_step_holds_its_table_once_and_copies_no_table(
+        one_chip, w2v, no_compile_cache):
+    """The layout ``make_store`` resolves by itself holds a ``(2, 300)`` row
+    flat in 640 lanes, row-major (``f32[3000000,640]{1,0:T(8,128)}``, 7.68
+    GB): the step updates it in place, gathers and scatter-adds whole rows
+    of it, and no op yields a second table.  Left as ``(vocab, 2, 300)``
+    the chip holds the table vocabulary-minor (``{0,1,2:T(2,128)}``, 7.2 GB,
+    no padding) and the step copies all of it to a row-major table (9.2 GB,
+    300 lanes padded to 384) for the gather and back after the scatter-add,
+    9.6 GB of temporaries beside the table."""
+    spec, logic, _ = w2v
+    assert spec.layout == "packed" and spec.pack == 1
+    assert spec.table_shape() == (W2V_VOCAB, 640)
+    compiled = _w2v_step(one_chip, spec, logic).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 7.68 * GB  # in place
+    assert mem.temp_size_in_bytes < 1.0 * GB  # 0.65 GB here
+    text = compiled.as_text()
+    entry = text[text.index("ENTRY"):]
+    tables = [
+        line.strip() for line in entry.splitlines()
+        if re.search(rf" = f32\[{W2V_VOCAB},", line)
+    ]
+    # the parameter and the scatter-add's fusion: nothing else is table-sized
+    assert len(tables) == 2 and all(
+        f"f32[{W2V_VOCAB},640]{{1,0:T(8,128)}}" in t for t in tables
+    ), tables
+    assert " parameter(" in tables[0] and "ps.push/scatter-add" in tables[1]
+    dense = _w2v_step(
+        one_chip, dataclasses.replace(spec, layout="dense"), logic
+    ).compile()
+    assert dense.memory_analysis().temp_size_in_bytes > 9.0 * GB
+    text = dense.as_text()
+    copies = re.findall(
+        rf"= f32\[{W2V_VOCAB},2,300\]\{{[0-9,]+:T\(2,128\)\}} copy\(",
+        text[text.index("ENTRY"):],
+    )
+    assert len(copies) >= 2, copies
+
+
+def test_w2v_table_is_initialised_in_place_from_a_seed_argument(
+        one_chip, w2v, no_compile_cache):
+    """``make_store`` under a ``jit`` that takes the seed: one program
+    whatever the seed, the 7.68 GB table initialised block by block with no
+    second table beside it."""
+    _, _, w2vm = w2v
+    compiled = jax.jit(
+        lambda seed: w2vm.make_store(W2V_VOCAB, W2V_DIM, seed=seed)
+    ).lower(_shape(one_chip, (), jnp.uint32)).compile()
+    mem = compiled.memory_analysis()
+    assert 7.68 * GB <= mem.output_size_in_bytes < 7.69 * GB
+    assert mem.temp_size_in_bytes < 1.0 * GB  # 0.67 GB here: one block

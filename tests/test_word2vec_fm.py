@@ -177,3 +177,90 @@ def test_sgns_dedup_scale_stabilizes_high_lr():
     )
     assert max(losses) < 10.0, max(losses)  # no explosion
     assert np.mean(losses[-5:]) < 0.8 * np.mean(losses[:5])
+
+
+def _sgns_reference(values, batches, lr):
+    """Plain numpy float64, independent of ``models/``: one bulk-synchronous
+    step a batch (all rows read as they stood before it, the deltas of
+    words that share a row summed), ``values`` of shape (vocab, 2, dim)."""
+    from flink_parameter_server_tpu.models.word2vec import OUT
+
+    values = values.astype(np.float64)
+    for b in batches:
+        v = values[b["center"], IN]
+        outs = np.concatenate([b["context"][:, None], b["negatives"]], axis=1)
+        u = values[outs, OUT]
+        g = 1.0 / (1.0 + np.exp(-np.einsum("bd,bnd->bn", v, u)))
+        g[:, 0] -= 1.0  # the context is the positive
+        dv = -lr * np.einsum("bn,bnd->bd", g, u)
+        du = -lr * g[..., None] * v[:, None, :]
+        np.add.at(values[:, IN], b["center"], dv)
+        np.add.at(values[:, OUT], outs.reshape(-1), du.reshape(-1, v.shape[1]))
+    return values
+
+
+@pytest.mark.parametrize("dim, lanes", [(300, 640), (64, 128), (8, 128)])
+def test_sgns_step_on_the_stores_own_layout_is_the_plain_arithmetic(dim, lanes):
+    """``SkipGramNS`` through ``make_train_step`` on the table as
+    ``make_store`` lays it by itself (a ``(2, dim)`` row flat in whole
+    128-lane registers, or several to one), against numpy: the touched
+    slots move as the reference's, the slot a batch does not address comes
+    back bit-equal.  The table is read as the array it is, not through
+    ``pull``."""
+    from flink_parameter_server_tpu.core.transform import make_train_step
+    from flink_parameter_server_tpu.models.word2vec import (
+        OUT, SkipGramNS, make_store,
+    )
+
+    vocab, batch, lr = 512, 256, 0.025
+    store = make_store(vocab, dim, seed=3)
+    assert store.spec.layout == "packed" and store.table.shape[1] == lanes
+    # output vectors away from 0, so that a centre's vector moves at once
+    store = store.push(
+        jnp.arange(vocab), jnp.full((vocab, 2, dim), 1e-2, jnp.float32)
+    )
+
+    def logical(table):  # the physical table's rows, by a path of its own
+        k, width = store.spec.pack, 2 * dim
+        flat = np.asarray(table)[:, : k * width].reshape(-1, width)
+        return flat[:vocab].reshape(vocab, 2, dim)
+
+    before = logical(store.table)
+    np.testing.assert_array_equal(before, np.asarray(store.values()))
+    rng = np.random.default_rng(dim)
+    hot = rng.zipf(1.3, (3, batch, 7)) % vocab  # duplicates within a batch
+    cold = rng.integers(0, vocab, (3, batch, 7))
+    ids = np.where(rng.random((3, batch, 7)) < 0.5, hot, cold).astype(np.int32)
+    batches = [{
+        "center": i[:, 0] // 2, "context": i[:, 1], "negatives": i[:, 2:],
+        "mask": np.ones(batch, bool),
+    } for i in ids]  # centres from the lower half: the upper stays idle
+    step = jax.jit(make_train_step(SkipGramNS(lr), store.spec))
+    table, state = store.table, ()
+    for b in batches:
+        table, state, _ = step(table, state, b)
+    got, want = logical(table), _sgns_reference(before, batches, lr)
+    moved = np.abs(want - before)
+    assert (moved[: vocab // 2, IN] > 0).any() and (moved[:, OUT] > 0).any()
+    assert np.abs(got - want).max() <= 2e-5 * moved.max()
+    np.testing.assert_allclose(got - before, want - before, rtol=2e-3, atol=1e-7)
+    idle = np.setdiff1d(np.arange(vocab), np.concatenate([b["center"] for b in batches]))
+    assert len(idle) >= vocab // 2
+    np.testing.assert_array_equal(got[idle, IN], before[idle, IN])
+    if k_pad := lanes - store.spec.pack * 2 * dim:
+        assert (np.asarray(table)[:, -k_pad:] == 0).all()  # the padding lanes
+
+
+def test_make_store_takes_a_dtype_and_a_traced_seed():
+    from flink_parameter_server_tpu.models.word2vec import OUT, make_store
+
+    half = make_store(64, 8, seed=1, dtype=jnp.bfloat16)
+    assert half.table.dtype == jnp.bfloat16 and half.spec.value_shape == (2, 8)
+    build = jax.jit(lambda seed: make_store(64, 8, seed=seed))
+    one, other = build(np.uint32(1)), build(np.uint32(2))  # one program
+    assert build._cache_size() == 1
+    np.testing.assert_array_equal(
+        np.asarray(one.values()), np.asarray(make_store(64, 8, seed=1).values())
+    )
+    assert not np.array_equal(np.asarray(one.values()), np.asarray(other.values()))
+    assert (np.asarray(one.values())[:, OUT] == 0).all()
