@@ -159,8 +159,7 @@ class Smoke:
         with warnings.catch_warnings(record=True) as heard:
             warnings.simplefilter("always")
             arm = logic.state_update_arm(
-                self.jax.ShapeDtypeStruct((s.num_users, s.dim), dtype),
-                s.batch,
+                self.jax.ShapeDtypeStruct((s.num_users, s.dim), dtype)
             )
         self.state_update = {
             "arm": arm, "refused": [str(w.message) for w in heard],
@@ -629,6 +628,20 @@ class Smoke:
             close(table_u.at[ids_u].add(deltas), 1e-3),
         )
 
+        # ops/row_update's tile kernel at the shape class of cell 5's push:
+        # f32 rows of 640 lanes (five registers), Zipf ids with long runs;
+        # it adds a row's lanes one by one as XLA does
+        rows_w, w = (100_000 if not self.dry_run else 1_024), 640
+        table_w, deltas_w = normal((rows_w, w)), normal((n, w))
+        ids_w = zipf_ids(rows_w)
+        self._kernel_case(
+            "row_update_tiles_d640_f32",
+            lambda t, i, dl: row_update.scatter_add(
+                t, i, dl, interpret=interpret),
+            (table_w, ids_w, deltas_w),
+            close(table_w.at[ids_w].add(deltas_w), 1e-6),
+        )
+
         # the MF step's DEFAULT arm at that shape class, against the XLA
         # arm: table, state and both per-record outputs in stream order
         # (the dry run pins the arm: off the chip the default is XLA's)
@@ -647,7 +660,7 @@ class Smoke:
         logic_x, step_x = mf_step("xla")
         logic_d, step_d = mf_step("sorted_rows" if self.dry_run else None)
         state_u = logic_x.init_state(jax.random.PRNGKey(0))
-        arm_d = logic_d.state_update_arm(state_u, n)
+        arm_d = logic_d.state_update_arm(state_u)
         require(arm_d == "sorted_rows", f"default MF state update is {arm_d}")
         self._kernel_case(
             "mf_step_default_arm_d128_f32",

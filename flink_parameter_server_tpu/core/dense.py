@@ -14,9 +14,12 @@ from __future__ import annotations
 from typing import Any, Callable, Iterable, List, Optional
 
 import jax
-import optax
 
 from .transform import TransformResult, jnp_copy
+
+# `optax` is imported where an update is applied: the import is 0.7 s (chex,
+# absl, toolz) that every sparse job would else pay for importing the
+# package (a warm set-up is 14-18 s, bounded at 10 %: PERF.md section 6, PR 33)
 
 Array = jax.Array
 PyTree = Any
@@ -49,6 +52,8 @@ class DenseParameterServer:
         updates, new_opt_state = self.optimizer.update(
             grads, self.opt_state, self.params
         )
+        import optax
+
         new_params = optax.apply_updates(self.params, updates)
         return DenseParameterServer(new_params, self.optimizer, new_opt_state)
 
@@ -213,6 +218,8 @@ def make_dense_train_step(
                 f"so dp merges with the model-parallel layout instead of "
                 f"overwriting it"
             )
+
+    import optax
 
     def step(params, opt_state, batch):
         loss, grads = jax.value_and_grad(loss_fn)(params, batch)

@@ -192,6 +192,7 @@ def create_table(spec: StoreSpec, init_fn: Optional[InitFn] = None) -> Array:
     descriptors, which exist precisely so that init is reproducible per key.
     """
     init_fn = init_fn or zeros_init(spec)
+    _preload_tile_kernel(spec)
     if spec.layout == "packed":
         return _create_packed(spec, init_fn)()
     ids = jnp.arange(spec.padded_capacity, dtype=jnp.int32)
@@ -301,9 +302,16 @@ def push(
     ``mask`` (same leading shape as ``ids``) zeroes out padding lanes — the
     jit-friendly replacement for the reference's variable-length message
     batches (SURVEY.md §7 "Dynamic shapes").  Out-of-range ids are dropped
-    (``mode="drop"``).  ``update="add"`` is ONE XLA scatter-add, which sums
-    the deltas of a row in the order the batch holds them (part of what the
-    benchmark's reference checks; PERF.md section 6, PR 27 and PR 30).
+    (``mode="drop"``).  ``update="add"`` is one scatter-add of the batch,
+    duplicates and all, in the arm :func:`_tile_kernel_takes` reads from the
+    spec: ONE XLA scatter-add, which sums the deltas of a row in the order
+    the batch holds them (part of what the benchmark's reference checks;
+    PERF.md section 6, PR 27 and PR 30), or, on a TPU, for physical rows of
+    several 128-lane registers, ``ops/row_update.scatter_add``: the batch
+    sorted by row (stably: a row's deltas stay in the order of the batch
+    and are added one by one, XLA's roundings bit for bit) and every
+    touched tile of eight rows read, added to and written back once a
+    block of lanes (PERF.md section 6, PR 33).
     """
     vr = len(spec.value_shape)
     lead = tuple(deltas.shape[: deltas.ndim - vr])
@@ -342,6 +350,10 @@ def push(
         s_ids, s_deltas = _phys_scatter_args(
             spec, table, flat_ids, flat_deltas
         )
+        if _tile_kernel_takes(spec):
+            from ..ops.row_update import scatter_add
+
+            return scatter_add(table, s_ids, s_deltas.astype(table.dtype))
         return table.at[s_ids].add(s_deltas.astype(table.dtype), mode="drop")
 
     # Generic path: combine duplicates densely, then apply `update` once per
@@ -362,6 +374,60 @@ def push(
     updated = update_fn(table, combined)
     touched = (counts > 0).reshape((-1,) + (1,) * len(spec.value_shape))
     return jnp.where(touched, updated, table)
+
+
+# Physical row widths, in 128-lane registers, from which `push` goes through
+# ops/row_update's tile kernel.  XLA's TPU scatter-add is one serial
+# read-modify-write a lane (~65 ns + ~12 ns a 128-lane piece of the row); the
+# kernel pays a sort, a permute of the deltas, ~20 ns of adds a lane and a
+# read and a write of every touched tile of 8 rows at HBM speed.  On cell 5's
+# ids (114,688 lanes, 48,841 rows) at 2 / 3 / 5 registers a row: XLA 9.95 /
+# 11.71 / 14.28 ms, the kernel path 4.46 / 5.18 / 6.28 (PERF.md section 6,
+# PR 33).  At one register XLA takes 13-22 ns a lane and a tile is eight
+# times a row's bytes: every cell that has such rows keeps XLA.
+_TILE_KERNEL_MIN_REGISTERS = 2
+# table row shapes already warned of (`_tile_kernel_takes`)
+_REFUSALS_NOTED: set = set()
+
+
+def _tile_kernel_takes(spec: StoreSpec) -> bool:
+    """Whether ``push`` applies an ``add`` batch through
+    ``ops/row_update.scatter_add`` instead of XLA's scatter-add, read from
+    what the spec holds: a TPU, no mesh (under one GSPMD partitions the XLA
+    scatter), ``update="add"`` and a physical row of
+    ``_TILE_KERNEL_MIN_REGISTERS`` registers or more, of a shape and dtype
+    the kernel takes.  Static per compiled step.  Such a store that the
+    kernel REFUSES (rows of rank 2 or no multiple of 128 lanes under a
+    pinned ``"dense"`` layout; bfloat16) keeps the XLA arm, is counted and
+    warned of once a row shape (``ops/row_update.refusal_count``)."""
+    from ..ops import row_update
+
+    shape = spec.table_shape()
+    width = 1
+    for s in shape[1:]:
+        width *= int(s)
+    if (spec.update != "add" or spec.mesh is not None
+            or jax.default_backend() != "tpu"
+            or width < _TILE_KERNEL_MIN_REGISTERS * 128):
+        return False
+    why = row_update.tile_refusal(shape, spec.dtype)
+    if why is None:
+        return True
+    key = (shape[1:], jnp.dtype(spec.dtype).name)
+    if key not in _REFUSALS_NOTED:
+        _REFUSALS_NOTED.add(key)
+        row_update.note_refusal("push into a table of wide rows", why)
+    return False
+
+
+def _preload_tile_kernel(spec: StoreSpec) -> None:
+    """Where a store is made whose pushes will trace the tile kernel: have
+    Pallas imported by then, beside the table's staging (the import is ~1 s
+    that the first trace of the step else pays)."""
+    if _tile_kernel_takes(spec):
+        from ..ops.row_update import preload
+
+        preload()
 
 
 @functools.partial(jax.jit, static_argnums=(0,))
@@ -718,6 +784,7 @@ class ShardedParamStore:
 
     @staticmethod
     def _place(spec: StoreSpec, values: Array) -> Array:
+        _preload_tile_kernel(spec)
         if spec.layout == "packed":  # either way pads its own rows
             if _lives_on_mesh(spec, values) and _next_shard_in_reach(
                 spec, values.shape[0]

@@ -108,7 +108,7 @@ class OnlineMatrixFactorization(BatchedWorkerLogic):
         #   "sorted_rows": the gathered rows and their deltas sorted by
         #     user, one pipelined row write per unique user
         #     (ops/row_update) — where the kernel can run: TPU, no mesh,
-        #     float32 rows of k x 128 lanes, a batch its scalar memory holds;
+        #     float32 rows of 128 lanes;
         #   "xla": the plain scatter-add, one serial read-modify-write a
         #     lane on the TPU (75 ns a row, PERF.md section 6).
         if state_scatter not in (None,) + STATE_ARMS:
@@ -120,7 +120,7 @@ class OnlineMatrixFactorization(BatchedWorkerLogic):
         self._fallback_noted = False
         if (state_scatter is None and mesh is None
                 and jax.default_backend() == "tpu"
-                and row_update.refusal(dim, dtype, 0) is None):
+                and row_update.refusal((dim,), dtype) is None):
             # the step of this logic is going to trace the kernel: have
             # Pallas imported by then
             row_update.preload()
@@ -140,21 +140,18 @@ class OnlineMatrixFactorization(BatchedWorkerLogic):
     def keys(self, batch: Dict[str, Array]) -> Array:
         return batch["item"]
 
-    def state_update_arm(self, state, lanes: int) -> str:
-        """The arm ``step`` compiles for this state and a batch of ``lanes``
-        records (one of ``STATE_ARMS``): the pinned one, else "sorted_rows"
-        wherever its kernel can run.  On a TPU without a mesh a refusal by
-        shape, dtype or batch size is counted and warned of once
-        (``ops/row_update.refusal_count``)."""
+    def state_update_arm(self, state) -> str:
+        """The arm ``step`` compiles for this state (one of ``STATE_ARMS``):
+        the pinned one, else "sorted_rows" wherever its kernel can run.  On
+        a TPU without a mesh a refusal by shape or dtype is counted and
+        warned of once (``ops/row_update.refusal_count``)."""
         if self.state_scatter is not None:
             return self.state_scatter
         # under a mesh the state is P(dp, None) and GSPMD partitions the
         # XLA scatter; off the TPU the kernel would be interpreted
         if self.mesh is not None or jax.default_backend() != "tpu":
             return "xla"
-        why = row_update.refusal(state.shape[-1], state.dtype, lanes)
-        if why is None and state.ndim != 2:
-            why = f"state of rank {state.ndim}, the kernel takes rows"
+        why = row_update.refusal(state.shape[1:], state.dtype)
         if why is None:
             return "sorted_rows"
         if not self._fallback_noted:
@@ -168,7 +165,7 @@ class OnlineMatrixFactorization(BatchedWorkerLogic):
         mask = batch.get("mask")
         if mask is None:
             mask = jnp.ones(users.shape, bool)
-        arm = self.state_update_arm(state, users.size)
+        arm = self.state_update_arm(state)
 
         with scope("ps.state_pull"):
             user_vecs = jnp.take(state, users, axis=0)

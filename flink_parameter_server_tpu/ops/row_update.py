@@ -1,4 +1,5 @@
-"""Pallas TPU kernel: one pipelined row WRITE per unique id of a sorted batch.
+"""Pallas TPU kernels: one pipelined row WRITE per unique id of a sorted batch,
+and, for rows wider than a register, one read-modify-write per touched TILE.
 
 Reference parity (SURVEY.md §2 #7, §7 "Hard parts"): the reference's MF
 worker keeps its user vectors in a JVM hash map and updates one vector per
@@ -31,14 +32,31 @@ The state array stays in HBM and is aliased to the output; rows no lane
 names are never touched.  Lanes to drop carry an id >= the row count (they
 sort to the end) and write nothing.
 
-What the compiled kernel takes (:func:`refusal`): float32 rows whose width
-is a multiple of 128 lanes, and at most ``MAX_LANES`` lanes a call (two
-int32 a lane are prefetched into SMEM).  The row count is free (single-row
-DMAs need no 8-row alignment).  A non-finite delta stays in its row: it is
-taken out of the mask matmul (0 x NaN would spread it over its block) and
-its row's element is made NaN by select afterwards (a lone inf reads NaN
-too, where the XLA scatter leaves inf).  A dropped lane's NaN reaches only
-its own run, which writes nothing.
+What the compiled kernel takes (:func:`refusal`): float32 rows of exactly
+128 lanes.  The TPU tiles a 2-D float32 array (8, 128): at 128 lanes a row
+is 512 contiguous bytes; a wider row is 512-byte pieces 4 KB apart, eight
+rows to a tile, and Mosaic takes no DMA of ONE row of it ("slice shape must
+be aligned to tiling (8)"; held as ``(rows, 1, W)`` a row is contiguous and
+its DMA compiles, but XLA then copies the whole table to ``(rows, W)`` for
+every gather and scatter: PERF.md section 6, PR 33).  The row count is free
+(single-row DMAs need no 8-row alignment).  One call takes at most
+``MAX_LANES`` lanes (two int32 a lane are prefetched into SMEM);
+:func:`row_add` gives a larger batch to several calls.  A non-finite delta
+stays in its row: it is taken out of the mask matmul (0 x NaN would spread
+it over its block) and its row's element is made NaN by select afterwards
+(a lone inf reads NaN too, where the XLA scatter leaves inf).  A dropped
+lane's NaN reaches only its own run, which writes nothing.
+
+**Rows of several registers** (``core/store.push`` on a table of wide rows,
+word2vec's 640 lanes: :func:`scatter_add`) go through the second kernel,
+:func:`sorted_tile_add`.  What can be moved alone there is a TILE ROW: eight
+rows, ``8 x W x 4`` contiguous bytes.  Block by block of the sorted lanes,
+the kernel reads the tile rows its lanes touch into VMEM (one DMA each),
+adds every lane's delta to its row's sublane, one float32 add a lane in the
+order of the batch (the sort is stable), and writes the tile rows back: a
+read-modify-write per touched tile row at HBM speed instead of XLA's serial
+one per lane (124 ns a 640-lane row on the v5e), with XLA's roundings, bit
+for bit.  It reads the table itself, so it takes no old rows.
 """
 from __future__ import annotations
 
@@ -46,7 +64,7 @@ import functools
 import importlib
 import threading
 import warnings
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -54,24 +72,51 @@ import jax.numpy as jnp
 Array = jax.Array
 
 BLOCK = 256  # sorted lanes per grid step
-# the kernel prefetches two int32 a lane into SMEM, 1 MiB on the v5e with
-# Mosaic's own share in it: 98,304 lanes compile there and 131,072 run out
-# of it (tests/test_tpu_compile.py)
+# a call of either kernel prefetches two int32 a lane into SMEM, 1 MiB on the
+# v5e with Mosaic's own share in it: 98,304 lanes compile there and 131,072
+# run out of it (tests/test_tpu_compile.py)
 MAX_LANES = 98_304
+# the tile kernel keeps a block's tile rows in VMEM (block x 8 x W x 4 bytes:
+# 5.2 MB at 640 lanes) beside its pipelined deltas: past 1,280 lanes that is
+# over the 16 MiB Mosaic allows a kernel by default, and well under the
+# v5e's 128
+_TILE_VMEM_BYTES = 64 * 2**20
 _INT32_MAX = jnp.iinfo(jnp.int32).max
 
 
-def refusal(width: int, dtype, lanes: int) -> Optional[str]:
-    """Why the compiled kernel cannot take ``lanes`` rows of this width and
-    dtype in one call (None: it can)."""
+def refusal(row: Tuple[int, ...], dtype) -> Optional[str]:
+    """Why the compiled row-write kernel cannot take a state whose rows have
+    this shape (``state.shape[1:]``) and dtype (None: it can)."""
     if jnp.dtype(dtype) != jnp.float32:
         return f"rows are {jnp.dtype(dtype).name}, the kernel sums float32"
-    if width % 128 != 0:
-        return f"row width {width} is not a multiple of 128 lanes"
+    if tuple(row) != (128,):
+        return (
+            f"rows of shape {tuple(row)}: only a row of 128 lanes lies in "
+            f"one piece in the TPU's (8, 128) tiles and can be written alone"
+        )
+    return None
+
+
+def tile_refusal(table_shape: Tuple[int, ...], dtype) -> Optional[str]:
+    """Why :func:`sorted_tile_add` cannot take this table (None: it can)."""
+    if jnp.dtype(dtype) != jnp.float32:
+        return f"rows are {jnp.dtype(dtype).name}, the kernel sums float32"
+    if len(table_shape) != 2 or table_shape[1] % 128 != 0:
+        return (
+            f"rows of shape {tuple(table_shape[1:])}, the kernel takes flat "
+            f"rows of whole 128-lane registers"
+        )
+    if table_shape[0] % 8 != 0:
+        return f"{table_shape[0]} rows are not whole tiles of 8"
+    return None
+
+
+def _too_many(lanes: int) -> Optional[str]:
     if lanes > MAX_LANES:
         return (
-            f"a batch of {lanes} lanes is over the {MAX_LANES} whose row "
-            f"ids fit the kernel's scalar memory"
+            f"{lanes} lanes in one call are over the {MAX_LANES} whose "
+            f"scalars fit the kernel's SMEM (row_add and scatter_add split "
+            f"a batch)"
         )
     return None
 
@@ -291,7 +336,7 @@ def sorted_row_update(
         interpret = jax.default_backend() != "tpu"
     rows, width = state.shape
     n = sorted_ids.shape[0]
-    why = refusal(width, state.dtype, n)
+    why = refusal(state.shape[1:], state.dtype) or _too_many(n)
     if why is not None and not interpret:
         raise ValueError(f"sorted_row_update: {why}")
     block = BLOCK
@@ -338,6 +383,38 @@ def sorted_row_update(
     )(tgt, src, count, aux, old_rows, deltas, state)
 
 
+def _calls(sorted_ids: Array, order: Array):
+    """``(first lane, sorted ids, order)`` of each kernel call a sorted
+    batch takes: as few calls as hold ``MAX_LANES`` lanes each, of equal
+    size in whole blocks.  One call takes the arrays as they are (nothing
+    is sliced)."""
+    lanes = sorted_ids.shape[0]
+    calls = max(1, -(-lanes // MAX_LANES))
+    size = max(1, -(-lanes // (calls * BLOCK))) * BLOCK
+    if size >= lanes:
+        return [(0, sorted_ids, order)]
+    return [
+        (lo, sorted_ids[lo:lo + size], order[lo:lo + size])
+        for lo in range(0, lanes, size)
+    ]
+
+
+def _open_run_reread(state: Array, sorted_ids: Array, old: Array) -> Array:
+    """``old`` for a call that is not a batch's first: the run at its head
+    may have begun in the call before, which then wrote that row (its old
+    value + the sum of the lanes it held).  The one old row the kernel
+    reads of a run, its last lane's, is read again from the state; a run
+    that begins here reads what it had."""
+    first = sorted_ids[0]
+    last = jnp.sum(sorted_ids == first, dtype=jnp.int32) - 1
+    row = jax.lax.dynamic_slice_in_dim(
+        state, jnp.minimum(first, state.shape[0] - 1), 1, axis=0
+    )
+    return jax.lax.dynamic_update_slice_in_dim(
+        old, row.astype(old.dtype), last, axis=0
+    )
+
+
 def row_add(
     state: Array,
     ids: Array,
@@ -347,24 +424,236 @@ def row_add(
     *,
     interpret: Optional[bool] = None,
 ) -> Array:
-    """``state.at[ids].add(deltas)`` through the kernel (masked lanes, ids
-    < 0 and ids >= rows dropped): sort the ids, bring old rows and deltas
-    into that order, one write per unique row.
+    """``state.at[ids].add(deltas)`` through the row-write kernel (masked
+    lanes, ids < 0 and ids >= rows dropped): sort the ids, bring old rows
+    and deltas into that order, one write per unique row.
 
     ``old_rows``: ``state[ids]`` in the order of ``ids``, as the caller has
     gathered it (the MF step has: a kept lane's row must be the state's, a
     dropped lane's may be anything); the kernel's old rows are a permute of
     it, out of fast memory, instead of a second gather out of the state.
+
+    A batch over ``MAX_LANES`` lanes takes several calls, each on its own
+    stretch of the sorted lanes, permuted on its own (no batch-sized buffer
+    of rows is sliced).  A row whose run lies across two calls is written
+    by both, the second time with the whole sum.
     """
     keep = None if mask is None else mask.reshape(-1)
     sid, order = sort_by_row(ids.reshape(-1), keep, state.shape[0])
-    return sorted_row_update(
-        state, sid, jnp.take(old_rows, order, axis=0),
-        jnp.take(deltas, order, axis=0), interpret=interpret,
+    for lo, s, o in _calls(sid, order):
+        old = jnp.take(old_rows, o, axis=0)
+        if lo:
+            old = _open_run_reread(state, s, old)
+        state = sorted_row_update(
+            state, s, old, jnp.take(deltas, o, axis=0), interpret=interpret
+        )
+    return state
+
+
+# -- rows of several registers: a read-modify-write per touched tile row ------
+def _tile_kernel(tiles_ref, words_ref, counts_ref, dl_ref, table_ref, out_ref,
+                 tile_buf, sem, *, block: int):
+    """One grid step = ``block`` sorted lanes, the kept ones first.
+
+    tiles_ref: (N,) int32 SMEM (scalar prefetch) — at the head of each
+      block's stretch, the tile rows (row // 8) its kept lanes touch,
+      ascending, each once.
+    words_ref: (N,) int32 SMEM — per kept lane, its tile row's place in
+      the block's list (bits 0-7) and its row's sublane in the tile (8-10).
+    counts_ref: (2 N / block,) int32 SMEM — per block, how many tile rows
+      and how many kept lanes.
+    dl_ref: (block, W) f32 VMEM — the deltas, sorted.
+    table_ref / out_ref: the aliased (rows, W) table in HBM.
+    tile_buf: (block, 8, W) f32 VMEM — the block's tile rows.
+    """
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    del table_ref  # aliased to out_ref
+    b = pl.program_id(0)
+    base = b * block
+    opened, kept = counts_ref[2 * b], counts_ref[2 * b + 1]
+    reads, writes = 0, 1  # the two semaphores
+
+    def tile_row(j):
+        first = pl.multiple_of(tiles_ref[base + j] * 8, 8)
+        return out_ref.at[pl.ds(first, 8)]
+
+    def await_copies(count, which):
+        # a DMA semaphore counts bytes: one wait the size of a tile row for
+        # each tile row moved
+        def one(j, _):
+            pltpu.make_async_copy(
+                tile_buf.at[0], tile_buf.at[0], sem.at[which]
+            ).wait()
+            return 0
+
+        jax.lax.fori_loop(0, count, one, 0)
+
+    # the block before has written its tile rows back: its last may be this
+    # block's first, and the buffer is about to be filled again
+    @pl.when(b > 0)
+    def _previous():
+        await_copies(counts_ref[2 * (b - 1)], writes)
+
+    def read(j, _):
+        pltpu.make_async_copy(
+            tile_row(j), tile_buf.at[j], sem.at[reads]
+        ).start()
+        return 0
+
+    jax.lax.fori_loop(0, opened, read, 0)
+    await_copies(opened, reads)
+
+    def add(lane, _):
+        # one float32 add a lane, in the order of the batch: what XLA's
+        # scatter-add and a plain ``np.add.at`` do, rounding for rounding
+        word = words_ref[base + lane]
+        at = (word & 255, pl.ds(word >> 8, 1), slice(None))
+        tile_buf[at] = tile_buf[at] + dl_ref[pl.ds(lane, 1), :]
+        return 0
+
+    jax.lax.fori_loop(0, kept, add, 0)
+
+    def write(j, _):
+        pltpu.make_async_copy(
+            tile_buf.at[j], tile_row(j), sem.at[writes]
+        ).start()
+        return 0
+
+    jax.lax.fori_loop(0, opened, write, 0)
+
+    @pl.when(b == pl.num_programs(0) - 1)
+    def _own():
+        await_copies(opened, writes)
+
+
+def _tile_plan(sorted_ids: Array, rows: int, block: int):
+    """The tile kernel's scalars from the sorted ids."""
+    ids = sorted_ids.reshape(-1, block)
+    local = jax.lax.broadcasted_iota(jnp.int32, ids.shape, 1)
+    kept = ids < rows  # the dropped lanes sort to the end
+    tile = ids // 8
+    # a kept lane opens a tile row of its block unless the lane before it
+    # lies in the same (ids ascend)
+    before = jnp.concatenate(
+        [jnp.full_like(tile[:, :1], -1), tile[:, :-1]], axis=1
     )
+    opens = kept & (tile != before)
+    place = jnp.cumsum(opens, axis=1, dtype=jnp.int32) - 1
+    words = place | ((ids % 8) << 8)
+    # the opened tile rows to the front of their block (a take_along_axis
+    # would be a gather of scalars, 10 ns each on the TPU: a sort of the
+    # block that carries them along)
+    tiles = jax.lax.sort(
+        (jnp.where(opens, local, local + block), tile), dimension=1,
+        num_keys=1,
+    )[1]
+    counts = jnp.stack(
+        [jnp.sum(opens, axis=1, dtype=jnp.int32),
+         jnp.sum(kept, axis=1, dtype=jnp.int32)], axis=1,
+    )
+    return tiles.reshape(-1), words.reshape(-1), counts.reshape(-1)
+
+
+def sorted_tile_add(
+    table: Array,
+    sorted_ids: Array,
+    deltas: Array,
+    *,
+    interpret: Optional[bool] = None,
+) -> Array:
+    """``table[r] += sum(deltas[lanes of r])`` for every row ``r`` some kept
+    lane names, a read-modify-write of each touched tile row (8 rows);
+    every other tile row is left as it is.
+
+    ``table``: (rows, W) float32, whole tiles (:func:`tile_refusal`).
+    ``sorted_ids``: (n,) int32 ASCENDING, lanes to drop at the end with an
+    id >= the row count (:func:`sort_by_row`).  ``deltas``: (n, W) in that
+    order; a dropped lane's may be anything.  In place when the enclosing
+    jit donates the table; an eager call copies it first.  Off the TPU the
+    kernel is interpreted.
+    """
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    rows, width = table.shape
+    n = sorted_ids.shape[0]
+    why = tile_refusal(table.shape, table.dtype) or _too_many(n)
+    if why is not None and not interpret:
+        raise ValueError(f"sorted_tile_add: {why}")
+    block = BLOCK
+    sorted_ids = sorted_ids.astype(jnp.int32)
+    deltas = deltas.astype(jnp.float32)
+    pad = -n % block
+    if pad:
+        sorted_ids = jnp.concatenate(
+            [sorted_ids, jnp.full((pad,), _INT32_MAX, jnp.int32)]
+        )
+        deltas = jnp.pad(deltas, ((0, pad), (0, 0)))
+    tiles, words, counts = _tile_plan(sorted_ids, rows, block)
+    if not isinstance(table, jax.core.Tracer):
+        table = jnp.copy(table)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=((n + pad) // block,),
+        in_specs=[
+            pl.BlockSpec((block, width), lambda b, *_: (b, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),  # the table stays in HBM
+        ],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        scratch_shapes=[
+            pltpu.VMEM((block, 8, width), jnp.float32),
+            pltpu.SemaphoreType.DMA((2,)),
+        ],
+    )
+    return pl.pallas_call(
+        functools.partial(_tile_kernel, block=block),
+        out_shape=jax.ShapeDtypeStruct(table.shape, table.dtype),
+        grid_spec=grid_spec,
+        input_output_aliases={4: 0},  # (tiles, words, counts, deltas, table)
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_TILE_VMEM_BYTES,
+        ),
+        interpret=interpret,
+        name="sorted_row_update_tiles",
+    )(tiles, words, counts, deltas, table)
+
+
+def scatter_add(
+    table: Array,
+    ids: Array,
+    deltas: Array,
+    *,
+    interpret: Optional[bool] = None,
+) -> Array:
+    """``table.at[ids].add(deltas, mode="drop")`` through the tile kernel:
+    sort the ids, bring the deltas into that order (a batch over
+    ``MAX_LANES`` lanes stretch by stretch, one call each), and one
+    read-modify-write per touched tile row.  Lanes of one row are added in
+    the order the batch holds them (the sort is stable).  An eager call is
+    one jitted program (one copy of the table, not one a kernel call).
+    """
+    if not isinstance(table, jax.core.Tracer):
+        return _scatter_add_jitted(table, ids, deltas, interpret=interpret)
+    sid, order = sort_by_row(ids.reshape(-1), None, table.shape[0])
+    for _, s, o in _calls(sid, order):
+        table = sorted_tile_add(
+            table, s, jnp.take(deltas, o, axis=0, mode="clip"),
+            interpret=interpret,
+        )
+    return table
+
+
+_scatter_add_jitted = jax.jit(scatter_add, static_argnames=("interpret",))
 
 
 __all__ = [
     "BLOCK", "MAX_LANES", "note_refusal", "preload", "refusal",
-    "refusal_count", "row_add", "sort_by_row", "sorted_row_update",
+    "refusal_count", "row_add", "scatter_add", "sort_by_row",
+    "sorted_row_update", "sorted_tile_add", "tile_refusal",
 ]

@@ -107,7 +107,8 @@ MODULE_SYMBOLS = {
         "pack_table", "unpack_table", "packed_pull", "lane_shift_deltas",
         "packed_phys_ids"],
     "flink_parameter_server_tpu.ops.row_update": [
-        "row_add", "sorted_row_update", "refusal", "refusal_count"],
+        "row_add", "sorted_row_update", "refusal", "refusal_count",
+        "scatter_add", "sorted_tile_add", "tile_refusal"],
     "flink_parameter_server_tpu.ops.hashing": [
         "hash_params", "bucket_hash", "sign_hash", "pair_key", "permute_ids"],
     "flink_parameter_server_tpu.ops.dedup": [

@@ -1,6 +1,7 @@
-"""ops/row_update: one row write per unique id of a sorted batch, against a
-plain numpy scatter-add (kernel interpreted on the CPU), and the MF step
-that uses it against the XLA arm in stream order."""
+"""ops/row_update: one row write per unique id of a sorted batch, and one
+read-modify-write per touched tile of eight wide rows, against a plain numpy
+scatter-add (kernels interpreted on the CPU); the MF step that uses the
+first against the XLA arm in stream order; who takes which arm."""
 import json
 import os
 import warnings
@@ -137,24 +138,128 @@ def test_a_non_finite_delta_in_a_kept_lane_stays_in_its_row(bad):
     np.testing.assert_allclose(got, want, rtol=0, atol=2e-4)
 
 
-@pytest.mark.parametrize("width,dtype,lanes,refused", [
-    (128, jnp.float32, 256, False), (256, jnp.float32, 256, False),
-    (128, jnp.float32, row_update.MAX_LANES, False),
-    (64, jnp.float32, 256, True), (128, jnp.bfloat16, 256, True),
-    (128, jnp.float32, row_update.MAX_LANES + 256, True),
+@pytest.mark.parametrize("row,dtype,lanes,refused", [
+    ((128,), jnp.float32, 256, False),
+    ((128,), jnp.float32, row_update.MAX_LANES, False),
+    # a 2-D state of wider rows lies eight rows to a tile: Mosaic takes no
+    # DMA of one row of it (tests/test_tpu_compile.py compiles the proof)
+    ((256,), jnp.float32, 256, True), ((1, 256), jnp.float32, 256, True),
+    ((64,), jnp.float32, 256, True), ((128,), jnp.bfloat16, 256, True),
+    # one CALL holds MAX_LANES lanes; row_add splits a larger batch
+    ((128,), jnp.float32, row_update.MAX_LANES + 256, True),
 ])
-def test_refusal_names_what_the_kernel_cannot_take(
-        width, dtype, lanes, refused):
-    why = row_update.refusal(width, dtype, lanes)
-    assert (why is not None) == refused
-    if refused:
-        shape = jax.ShapeDtypeStruct  # traced only: nothing this size is made
+def test_refusal_names_what_the_kernel_cannot_take(row, dtype, lanes, refused):
+    why = row_update.refusal(row, dtype)
+    assert (why is not None) == (refused and lanes <= row_update.MAX_LANES)
+    shape = jax.ShapeDtypeStruct  # traced only: nothing this size is made
+    width = row[-1]
+    args = (
+        shape((8,) + row, dtype), shape((lanes,), jnp.int32),
+        shape((lanes, width), dtype), shape((lanes, width), dtype),
+    )
+    if refused and len(row) == 1:
         with pytest.raises(ValueError, match="sorted_row_update"):
             jax.eval_shape(
                 lambda *a: row_update.sorted_row_update(*a, interpret=False),
-                shape((8, width), dtype), shape((lanes,), jnp.int32),
-                shape((lanes, width), dtype), shape((lanes, width), dtype),
+                *args)
+    if lanes > row_update.MAX_LANES:
+        # the same batch through row_add: two calls, no refusal
+        out = jax.eval_shape(
+            lambda st, i, o, d: row_update.row_add(st, i, o, d,
+                                                   interpret=False), *args)
+        assert out.shape == (8,) + row
+
+
+@pytest.mark.parametrize("shape,dtype,refused", [
+    ((64, 256), jnp.float32, False), ((64, 640), jnp.float32, False),
+    ((64, 128), jnp.float32, False),
+    ((64, 600), jnp.float32, True), ((64, 2, 384), jnp.float32, True),
+    ((60, 256), jnp.float32, True), ((64, 256), jnp.bfloat16, True),
+])
+def test_tile_refusal_names_what_the_tile_kernel_cannot_take(
+        shape, dtype, refused):
+    why = row_update.tile_refusal(shape, dtype)
+    assert (why is not None) == refused
+    if refused and len(shape) == 2:
+        with pytest.raises(ValueError, match="sorted_tile_add"):
+            jax.eval_shape(
+                lambda *a: row_update.sorted_tile_add(*a, interpret=False),
+                jax.ShapeDtypeStruct(shape, dtype),
+                jax.ShapeDtypeStruct((256,), jnp.int32),
+                jax.ShapeDtypeStruct((256, shape[1]), dtype),
             )
+
+
+# -- a batch over one call's lanes -------------------------------------------
+@pytest.mark.parametrize("limit", [256, 512, 1024])
+def test_row_add_over_the_lane_limit_equals_one_call_and_xla(
+        limit, monkeypatch):
+    """``hit_1_2_1000_times`` has a run of a thousand lanes: at a limit of
+    256 lanes a call it lies across five calls, each of which writes the
+    row; the later ones read it again from the state."""
+    rows, ids, mask, _ = _case("hit_1_2_1000_times")
+    rng = np.random.default_rng(7)
+    state = rng.normal(size=(rows, WIDTH)).astype(np.float32)
+    deltas = rng.normal(size=(ids.shape[0], WIDTH)).astype(np.float32)
+    run = jax.jit(lambda s, i, d: row_update.row_add(
+        s, i, jnp.take(s, i, axis=0), d, interpret=True))
+    one = np.asarray(run(state, ids, deltas))
+    monkeypatch.setattr(row_update, "MAX_LANES", limit)
+    assert len(row_update._calls(ids, ids)) == -(-ids.shape[0] // limit)
+    many = np.asarray(jax.jit(lambda s, i, d: row_update.row_add(
+        s, i, jnp.take(s, i, axis=0), d, interpret=True))(state, ids, deltas))
+    xla = np.asarray(jnp.asarray(state).at[ids].add(deltas))
+    np.testing.assert_allclose(many, one, rtol=0, atol=2e-4)
+    np.testing.assert_allclose(many, xla, rtol=0, atol=2e-4)
+    np.testing.assert_allclose(
+        many, numpy_scatter_add(state, ids, deltas, mask), rtol=0, atol=2e-4)
+
+
+# -- the tile kernel ----------------------------------------------------------
+@pytest.mark.parametrize("limit", [None, 512])
+@pytest.mark.parametrize("width", [256, 640])
+@pytest.mark.parametrize("name", CASES)
+def test_scatter_add_matches_numpy_scatter_add(
+        name, width, limit, monkeypatch):
+    """``table.at[ids].add(deltas, mode="drop")`` through the tile kernel,
+    in one call and in calls of 512 lanes (a run of a thousand lanes and a
+    tile of eight rows then lie across calls)."""
+    if limit:
+        monkeypatch.setattr(row_update, "MAX_LANES", limit)
+    rows, ids, mask, poison = _case(name)
+    rows = -(-rows // 8) * 8  # whole tiles, as a store's table is
+    if mask is not None:  # the store zeroes masked lanes; so does this
+        poison = poison & mask
+    rng = np.random.default_rng(5)
+    table = rng.normal(size=(rows, width)).astype(np.float32)
+    deltas = rng.normal(size=(ids.shape[0], width)).astype(np.float32)
+    if mask is not None:
+        deltas[~mask] = 0.0
+    deltas[poison] = np.nan  # out of range only: those lanes are dropped
+    got = np.asarray(jax.jit(
+        lambda t, i, d: row_update.scatter_add(t, i, d, interpret=True)
+    )(table, ids, deltas))
+    want = numpy_scatter_add(table, ids, deltas, None)
+    assert np.isfinite(got).all()
+    # one float32 rounding a lane: 1,536 of them on one row, against float64
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=2e-4)
+    touched = np.unique(ids[(ids >= 0) & (ids < rows)])
+    untouched = np.setdiff1d(np.arange(rows), touched)
+    assert np.array_equal(got[untouched], table[untouched])  # bit for bit
+    xla = np.asarray(jnp.asarray(table).at[
+        jnp.where(ids < 0, rows, ids)].add(
+            jnp.where(np.isnan(deltas), 0.0, deltas), mode="drop"))
+    # a row's lanes are added one by one in the order of the batch, as XLA
+    # adds them: the same roundings, bit for bit
+    np.testing.assert_array_equal(got, xla)
+
+
+def test_eager_scatter_add_leaves_the_callers_table_alone():
+    table = jnp.ones((16, 256), jnp.float32)
+    out = row_update.scatter_add(
+        table, jnp.array([3, 3, 5]), jnp.ones((3, 256)), interpret=True)
+    assert float(table[3, 0]) == 1.0 and float(out[3, 0]) == 3.0
+    assert float(out[5, 0]) == 2.0 and float(out[4, 0]) == 1.0
 
 
 # -- the MF step --------------------------------------------------------------
@@ -221,17 +326,16 @@ def test_mf_step_sorted_rows_matches_xla_in_stream_order():
         assert (a["error"][-17:] == 0).all()
 
 
-def _arm(monkeypatch, backend, dim, dtype=jnp.float32, mesh=None, lanes=256,
-         **kw):
+def _arm(monkeypatch, backend, dim, dtype=jnp.float32, mesh=None, **kw):
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
     logic = mfm.OnlineMatrixFactorization(64, dim, dtype=dtype, mesh=mesh, **kw)
     return logic, logic.state_update_arm(
-        jax.ShapeDtypeStruct((64, dim), dtype), lanes)
+        jax.ShapeDtypeStruct((64, dim), dtype))
 
 
 @pytest.mark.parametrize("backend,dim,dtype,pinned,want", [
     ("tpu", 128, jnp.float32, None, "sorted_rows"),
-    ("tpu", 256, jnp.float32, None, "sorted_rows"),
+    ("cpu", 256, jnp.float32, None, "xla"),
     ("cpu", 128, jnp.float32, None, "xla"),
     ("tpu", 128, jnp.float32, "xla", "xla"),
     ("cpu", 64, jnp.float32, "sorted_rows", "sorted_rows"),
@@ -246,22 +350,23 @@ def test_state_update_arm_is_read_from_what_the_step_sees(
     assert row_update.refusal_count() == n0
 
 
-@pytest.mark.parametrize("dim,dtype,lanes,reason", [
-    (64, jnp.float32, 256, "multiple of 128"),
-    (128, jnp.bfloat16, 256, "bfloat16"),
-    (128, jnp.float32, 131_072, "131072 lanes"),
+@pytest.mark.parametrize("dim,dtype,reason", [
+    (64, jnp.float32, "(64,)"),
+    (128, jnp.bfloat16, "bfloat16"),
+    # it chose the kernel before PR 33, which Mosaic refuses at 256 lanes
+    (256, jnp.float32, "(256,)"),
 ])
 def test_refused_state_shape_on_tpu_warns_once_and_counts(
-        monkeypatch, dim, dtype, lanes, reason):
+        monkeypatch, dim, dtype, reason):
     n0 = row_update.refusal_count()
     with pytest.warns(RuntimeWarning, match="falling back") as caught:
-        logic, arm = _arm(monkeypatch, "tpu", dim, dtype, lanes=lanes)
+        logic, arm = _arm(monkeypatch, "tpu", dim, dtype)
     assert arm == "xla" and reason in str(caught[0].message)
     assert row_update.refusal_count() == n0 + 1
     with warnings.catch_warnings():
         warnings.simplefilter("error")  # the second trace is silent
         assert logic.state_update_arm(
-            jax.ShapeDtypeStruct((64, dim), dtype), lanes) == "xla"
+            jax.ShapeDtypeStruct((64, dim), dtype)) == "xla"
     assert row_update.refusal_count() == n0 + 1
 
 
@@ -288,6 +393,82 @@ def test_a_logic_that_will_trace_the_kernel_starts_the_pallas_import(
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
     mfm.OnlineMatrixFactorization(64, dim, state_scatter=pinned)
     assert len(calls) == started
+
+
+# -- the store's arm -----------------------------------------------------------
+def _spec(shape, dtype=jnp.float32, update="add", mesh=None, layout="auto"):
+    from flink_parameter_server_tpu.core import store as store_mod
+
+    return store_mod.StoreSpec(
+        capacity=61, value_shape=shape, dtype=dtype, update=update, mesh=mesh,
+        layout=store_mod._resolve_layout(layout, update, shape),
+    )
+
+
+@pytest.mark.parametrize("backend,meshed,shape,update,want", [
+    ("tpu", False, (640,), "add", True),
+    ("tpu", False, (2, 300), "add", True),  # held flat in 640 lanes
+    ("tpu", False, (600,), "add", True),
+    ("tpu", False, (256,), "add", True),
+    ("tpu", False, (128,), "add", False),  # one register: XLA's 13-22 ns
+    ("tpu", False, (100,), "add", False),
+    ("tpu", False, (17,), "add", False),  # seven to a 128-lane row
+    ("tpu", False, (), "add", False),
+    ("cpu", False, (640,), "add", False),
+    ("tpu", True, (640,), "add", False),
+    ("tpu", False, (640,), lambda cur, new: new, False),
+])
+def test_push_takes_the_tile_kernel_from_what_the_spec_holds(
+        monkeypatch, backend, meshed, shape, update, want):
+    from flink_parameter_server_tpu.core import store as store_mod
+    from flink_parameter_server_tpu.parallel.mesh import make_mesh
+
+    mesh = make_mesh(worker_parallelism=2, ps_parallelism=2,
+                     devices=jax.devices()[:4]) if meshed else None
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    n0 = row_update.refusal_count()
+    with warnings.catch_warnings():
+        warnings.simplefilter("error")
+        assert store_mod._tile_kernel_takes(
+            _spec(shape, update=update, mesh=mesh)) == want
+    assert row_update.refusal_count() == n0
+
+
+@pytest.mark.parametrize("shape,dtype,layout,reason", [
+    ((640,), jnp.bfloat16, "auto", "bfloat16"),
+    ((2, 384), jnp.float32, "dense", "(2, 384)"),
+    ((600,), jnp.float32, "dense", "(600,)"),
+])
+def test_a_wide_row_store_the_kernel_refuses_warns_once_and_counts(
+        monkeypatch, shape, dtype, layout, reason):
+    from flink_parameter_server_tpu.core import store as store_mod
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(store_mod, "_REFUSALS_NOTED", set())
+    spec = _spec(shape, dtype, layout=layout)
+    n0 = row_update.refusal_count()
+    with pytest.warns(RuntimeWarning, match="falling back") as caught:
+        assert not store_mod._tile_kernel_takes(spec)
+    assert reason in str(caught[0].message)
+    assert row_update.refusal_count() == n0 + 1
+    with warnings.catch_warnings():
+        warnings.simplefilter("error")  # every later trace is silent
+        assert not store_mod._tile_kernel_takes(spec)
+    assert row_update.refusal_count() == n0 + 1
+
+
+@pytest.mark.parametrize("backend,shape,started", [
+    ("tpu", (2, 300), 1), ("tpu", (128,), 0), ("cpu", (2, 300), 0),
+])
+def test_a_store_whose_pushes_will_trace_the_kernel_starts_the_pallas_import(
+        monkeypatch, backend, shape, started):
+    calls = []
+    monkeypatch.setattr(row_update, "preload", lambda: calls.append(1))
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    ShardedParamStore.create(16, shape, layout="auto")
+    assert len(calls) == started
+    ShardedParamStore.from_values(jnp.zeros((16,) + shape), layout="auto")
+    assert len(calls) == 2 * started
 
 
 def test_preload_imports_pallas_off_the_calling_thread():

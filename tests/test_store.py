@@ -274,9 +274,9 @@ _push = jax.jit(lambda store, ids, deltas, mask: store.push(ids, deltas, mask))
 _pull = jax.jit(lambda store, ids: store.pull(ids))
 
 
-def _check_push_pull(store, values, ids, deltas, mask, ulps=64.0):
+def _check_push_pull(store, values, ids, deltas, mask, ulps=64.0, push=None):
     eps = float(jnp.finfo(store.spec.dtype).eps)
-    pushed = _push(
+    pushed = (push or _push)(
         store, jnp.asarray(ids), jnp.asarray(deltas),
         None if mask is None else jnp.asarray(mask),
     )
@@ -310,6 +310,69 @@ def test_push_pull_case_table(layout, width, traffic):
     if layout == "packed":
         assert store.table.shape[1] % 128 == 0
     _check_push_pull(store, values, *_traffic(traffic, rng, CAP, shape))
+
+
+# Rows of several 128-lane registers: on a TPU (no mesh, float32) ``push``
+# takes ``ops/row_update``'s tile kernel for them, everywhere else XLA's
+# scatter-add.  Off the TPU the chooser is steered in the test and the kernel
+# is interpreted; both arms are held to the same reference.
+WIDE_ROWS = [(256,), (640,), (2, 300), (600,)]
+WIDE_TRAFFIC = TRAFFIC + ["run_over_a_block_and_a_call", "tile_of_8_over_a_call"]
+
+
+def _wide_traffic(kind, rng, cap, shape):
+    if kind == "run_over_a_block_and_a_call":
+        n = 1100  # no multiple of the kernel's block
+        ids = rng.integers(0, cap, n)
+        ids[100:900] = 3  # 800 lanes in a row: blocks of 256, calls of 512
+        ids = rng.permutation(ids)
+    elif kind == "tile_of_8_over_a_call":
+        # rows 8..15 are one tile of the table; their lanes lie round the
+        # 512th sorted lane, so two calls read and write that tile
+        ids = np.concatenate([
+            np.zeros(500, np.int64), np.repeat(np.arange(8, 16), 3),
+            rng.integers(16, cap, 600),
+        ])
+        ids = rng.permutation(ids)
+    else:
+        return _traffic(kind, rng, cap, shape)
+    ids = np.asarray(ids, np.int32)
+    deltas = rng.normal(0, 1, ids.shape + shape).astype(np.float32)
+    return ids, deltas, rng.random(ids.shape) > 0.1
+
+
+@pytest.mark.parametrize("traffic", WIDE_TRAFFIC)
+@pytest.mark.parametrize("shape", WIDE_ROWS, ids=str)
+@pytest.mark.parametrize("arm", ["xla", "tile_kernel"])
+def test_push_pull_case_table_wide_rows(arm, shape, traffic, monkeypatch):
+    from flink_parameter_server_tpu.core import store as store_mod
+    from flink_parameter_server_tpu.ops import row_update
+
+    rng = np.random.default_rng([len(shape), shape[-1],
+                                 WIDE_TRAFFIC.index(traffic)])
+    values = _init_values(CAP, shape)
+    store = ShardedParamStore.from_values(jnp.asarray(values), layout="auto")
+    # one axis of whole registers stays as it is; every other row is held
+    # flat, padded to whole registers
+    whole = len(shape) == 1 and shape[0] % 128 == 0
+    assert store.spec.layout == ("dense" if whole else "packed")
+    assert store.table.shape == (64, -(-int(np.prod(shape)) // 128) * 128)
+    push = _push
+    if arm == "tile_kernel":
+        assert not store_mod._tile_kernel_takes(store.spec)  # this is a CPU
+        monkeypatch.setattr(store_mod, "_tile_kernel_takes", lambda spec: True)
+        monkeypatch.setattr(row_update, "MAX_LANES", 512)
+        calls = []
+        real = row_update.sorted_tile_add
+        monkeypatch.setattr(
+            row_update, "sorted_tile_add",
+            lambda *a, **kw: calls.append(a[1].shape[0]) or real(*a, **kw))
+        # not `_push`: a program traced for the other arm would be reused
+        push = jax.jit(lambda st, i, d, m: st.push(i, d, m))
+    _check_push_pull(
+        store, values, *_wide_traffic(traffic, rng, CAP, shape), push=push)
+    if arm == "tile_kernel":
+        assert calls and max(calls) <= 512, calls
 
 
 @pytest.mark.parametrize("layout", ["dense", "packed"])

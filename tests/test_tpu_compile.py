@@ -29,6 +29,9 @@ FM_SHARD_PHYS_ROWS = 6_705_984
 # fm-criteo: cell 2's table, on one chip (7 rows of 17 lanes to a 128-lane
 # physical row: 7,018,048 x 128 f32, 3.59 GB)
 FM1_ROWS, FM1_PHYS_ROWS = 49_126_310, 7_018_048
+# the released GoogleNews word2vec table: 3,000,000 words x (2, 300) f32, and a
+# batch of 16,384 pairs with 5 negatives = 114,688 keys
+W2V_VOCAB, W2V_DIM, W2V_BATCH, W2V_NEG = 3_000_000, 300, 16_384, 5
 GB = 1e9
 # an HLO line that APPLIES a collective (a use of its result is `%all-reduce,`)
 COLLECTIVE_OP = re.compile(
@@ -142,6 +145,54 @@ def test_row_update_kernel_compiles_at_the_mf_cells_shapes(
     assert mem.temp_size_in_bytes < 256 * 2 ** 20
 
 
+@pytest.mark.parametrize("state", [(4096, 256), (4096, 640)])
+def test_mosaic_takes_no_write_of_one_row_wider_than_a_register(
+        one_chip, no_compile_cache, monkeypatch, state):
+    """Why ``refusal`` lets only 128-lane rows through: a 2-D float32 array
+    is tiled (8, 128), a wider row is eight to a tile, and a DMA of one row
+    of it is no slice Mosaic takes.  (ISSUE 33 read the kernel as
+    width-general; nothing had compiled it over 128 lanes.)"""
+    monkeypatch.setattr(row_update, "refusal", lambda row, dtype: None)
+    with pytest.raises(Exception, match="aligned to tiling"):
+        jax.jit(
+            lambda st, ids, old, dl: row_update.sorted_row_update(
+                st, ids, old, dl, interpret=False),
+            donate_argnums=(0,),
+        ).lower(
+            _shape(one_chip, state, jnp.float32),
+            _shape(one_chip, (512,), jnp.int32),
+            _shape(one_chip, (512, state[1]), jnp.float32),
+            _shape(one_chip, (512, state[1]), jnp.float32),
+        ).compile()
+
+
+@pytest.mark.parametrize("width,lanes,calls", [
+    (640, W2V_BATCH * (W2V_NEG + 2), 2), (256, W2V_BATCH * (W2V_NEG + 2), 2),
+    (640, row_update.MAX_LANES, 1),
+])
+def test_tile_kernel_compiles_at_the_w2v_cells_shapes(
+        one_chip, no_compile_cache, width, lanes, calls):
+    """``scatter_add`` of 114,688 lanes into 3,000,000 rows of 640 (and
+    256) f32 lanes: two calls of the tile kernel (57,344 lanes each, their
+    scalars in SMEM, a block's 256 tile rows of 8 x 640 f32 in VMEM), the
+    table aliased through both, no table-sized temporary; and one call of
+    as many lanes as one call takes."""
+    compiled = jax.jit(
+        lambda t, ids, dl: row_update.scatter_add(t, ids, dl, interpret=False),
+        donate_argnums=(0,),
+    ).lower(
+        _shape(one_chip, (W2V_VOCAB, width), jnp.float32),
+        _shape(one_chip, (lanes,), jnp.int32),
+        _shape(one_chip, (lanes, width), jnp.float32),
+    ).compile()
+    text = compiled.as_text()
+    found = re.findall(r" custom-call\([^\n]*sorted_row_update_tiles", text)
+    assert len(found) == calls, len(found)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= W2V_VOCAB * width * 4
+    assert mem.temp_size_in_bytes < 512 * 2 ** 20  # the sorted deltas
+
+
 def _compiled_mf_step(one_chip, monkeypatch, batch_size):
     """The step the MF cells run (``OnlineMatrixFactorization`` as
     ``chipbench/families/mf.py`` builds it: no ``state_scatter``), compiled
@@ -168,16 +219,20 @@ def _compiled_mf_step(one_chip, monkeypatch, batch_size):
     ).compile()
 
 
-def test_mf_step_with_a_batch_over_the_kernels_lanes_keeps_the_xla_arm(
+def test_mf_step_with_a_batch_over_one_calls_lanes_takes_two_calls(
         one_chip, no_compile_cache, monkeypatch):
-    """131,072 row ids do not fit the kernel's SMEM (Mosaic: RESOURCE_
-    EXHAUSTED): the default step says so once, counts, and compiles with
-    the XLA scatter as the parent's did."""
+    """131,072 row ids do not fit one call's SMEM (Mosaic: RESOURCE_
+    EXHAUSTED; the step kept the XLA arm for them until PR 33):
+    ``row_add`` gives them to two calls of 65,536, nothing is refused and
+    the 2.56 GB state is still updated in place."""
     n0 = row_update.refusal_count()
-    with pytest.warns(RuntimeWarning, match="falling back.*131072 lanes"):
-        compiled = _compiled_mf_step(one_chip, monkeypatch, 131_072)
-    assert row_update.refusal_count() == n0 + 1
-    assert "sorted_row_update" not in compiled.as_text()
+    compiled = _compiled_mf_step(one_chip, monkeypatch, 131_072)
+    assert row_update.refusal_count() == n0
+    text = compiled.as_text()
+    calls = re.findall(r" custom-call\([^\n]*sorted_row_update", text)
+    assert len(calls) == 2, len(calls)
+    assert not re.findall(rf"= f32\[{USERS},{DIM}\][^ ]* copy\(", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 512 * 2 ** 20
 
 
 def test_mf_step_default_arm_on_tpu_is_the_row_kernel(
@@ -468,9 +523,6 @@ def test_creating_fm_s_table_fits_the_chip_chunk_by_chunk(
         ).lower().compile()
 
 
-# the released GoogleNews word2vec table: 3,000,000 words x (2, 300) f32, and a
-# batch of 16,384 pairs with 5 negatives = 114,688 keys
-W2V_VOCAB, W2V_DIM, W2V_BATCH, W2V_NEG = 3_000_000, 300, 16_384, 5
 
 
 @pytest.fixture(scope="module")
@@ -533,6 +585,80 @@ def test_w2v_step_holds_its_table_once_and_copies_no_table(
         text[text.index("ENTRY"):],
     )
     assert len(copies) >= 2, copies
+
+
+def test_w2v_step_on_a_tpu_pushes_through_the_tile_kernel(
+        one_chip, w2v, no_compile_cache, monkeypatch):
+    """What cell 5 runs on the chip: asked for the backend, ``push`` takes
+    ``ops/row_update``'s tile kernel for the 640-lane rows (no refusal is
+    counted), two calls of it under ``ps.push`` are the only ops that yield
+    a table, no XLA scatter is left on the table, nothing copies it, and
+    the step's temporaries stay within 0.3 GB of the XLA arm's 0.65."""
+    _, _, w2vm = w2v
+    # code that asks for the backend still sees the CPU here: steer it
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    n0 = row_update.refusal_count()
+    spec = jax.eval_shape(
+        lambda: w2vm.make_store(W2V_VOCAB, W2V_DIM, dtype=jnp.float32)
+    ).spec
+    assert store_mod._tile_kernel_takes(spec)
+    logic = w2vm.SkipGramNS(0.025, dedup_scale=True, vocab_size=W2V_VOCAB)
+    compiled = _w2v_step(one_chip, spec, logic).compile()
+    assert row_update.refusal_count() == n0
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 7.68 * GB  # in place
+    assert mem.temp_size_in_bytes < 0.95 * GB  # 0.78 GB here
+    text = compiled.as_text()
+    entry = text[text.index("ENTRY"):]
+    tables = [
+        line.strip() for line in entry.splitlines()
+        if re.search(rf" = f32\[{W2V_VOCAB},", line)
+    ]
+    assert len(tables) == 3 and " parameter(" in tables[0], tables
+    for call in tables[1:]:
+        assert call.startswith("%sorted_row_update_tiles"), call
+        assert "custom-call(" in call and "ps.push" in call, call
+    assert " copy(" not in "".join(tables)
+    assert "ps.push/scatter-add" not in text
+
+
+def _step_text_sha(step, *args):
+    import hashlib
+
+    text = jax.jit(step, donate_argnums=(0, 1)).lower(*args).as_text()
+    return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+
+def test_the_mf_and_fm_cells_step_text_is_the_parents(fm1):
+    """The lowered text of a step carries no locations, so a change that
+    traces the same ops gives the same text: cells 1 and 3 (MF, dense 128
+    lanes) and cell 2 (FM, seven 17-lane rows to a 128-lane row) run the
+    step PR 30 to PR 32 ran (PERF.md section 6 records both hashes).  A
+    change that means to move them brings its own."""
+    shape = jax.ShapeDtypeStruct
+    logic = mfm.OnlineMatrixFactorization(
+        USERS, DIM, updater=mfm.SGDUpdater(2e-4))
+    spec = jax.eval_shape(
+        lambda: ShardedParamStore.create(ITEMS, (DIM,), dtype=jnp.float32)
+    ).spec
+    batch = {
+        "user": shape((BATCH,), jnp.int32), "item": shape((BATCH,), jnp.int32),
+        "rating": shape((BATCH,), jnp.float32),
+        "mask": shape((BATCH,), jnp.bool_),
+    }
+    assert _step_text_sha(
+        make_train_step(logic, spec),
+        shape((spec.padded_capacity, DIM), jnp.float32),
+        shape((USERS, DIM), jnp.float32), batch,
+    ) == "467449ddc73eac39"
+    spec, logic = fm1
+    batch = {
+        k: shape(v.shape, v.dtype) for k, v in _fm_batch(None).items()
+    }
+    assert _step_text_sha(
+        make_train_step(logic, spec),
+        shape(spec.table_shape(), jnp.float32), (), batch,
+    ) == "3913e9e902cfade8"
 
 
 def test_w2v_table_is_initialised_in_place_from_a_seed_argument(
