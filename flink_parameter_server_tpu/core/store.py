@@ -27,7 +27,9 @@ duplicates with a segment-sum is exactly equivalent.  For *non-commutative*
 custom ``update`` functions, intra-batch duplicate deltas are summed first
 and ``update`` is then applied once per touched id — the documented
 semantic delta vs. the reference (bounded staleness ≤ one microbatch;
-SURVEY.md §7 "Guiding translation").
+SURVEY.md §7 "Guiding translation").  That arm costs what the batch does
+and nothing table-sized: a sort of the batch's ids, the sums of each run,
+one read and one write of every distinct row (:func:`_push_rule`).
 """
 from __future__ import annotations
 
@@ -53,7 +55,12 @@ def _resolve_layout(
     ``"auto"`` reads the row's shape and the update rule: dense for an
     add-store whose rows are ONE axis of a whole number of 128 lanes (a
     row is then a whole number of vector registers as it is), packed for
-    every other add-store.  A narrow dense row is a column of scalars
+    every other add-store, dense for every store whose ``update`` is a
+    rule (its push writes whole logical rows back, and a row write into
+    a packed table would lose a physical row's other touched rows; for
+    FTRL's 3-lane row the TPU pads a dense row to FOUR sublanes, 3.00 GB
+    at 187.8 M rows, and packed 42 to a physical row the step asks 13.5
+    GB for its 42 static lane slices: PERF.md section 6, PR 34).  A narrow dense row is a column of scalars
     across the table's tiles on the TPU, and its gather and scatter-add
     walk that column (36 and 121 ns a row for FM's 17 lanes on the v5e,
     against 10 and 22 for the 128-lane physical row that holds seven of
@@ -82,8 +89,8 @@ def _resolve_layout(
         whole = len(value_shape) == 1 and width % 128 == 0
         return "packed" if update == "add" and not whole else "dense"
     if layout == "packed" and update != "add":
-        # the generic update path applies `update` per logical row on a
-        # dense combined table — packing it would need an unpack per push
+        # a rule's push writes whole logical rows back (`_push_rule`): two
+        # of them may share a physical row, and a row write would lose one
         raise ValueError(
             "layout='packed' requires update='add' (custom update "
             "functions take the dense per-row path)"
@@ -98,9 +105,11 @@ class StoreSpec:
     capacity: int
     value_shape: Tuple[int, ...] = ()
     dtype: Any = jnp.float32
-    # "add" uses the fast scatter-add path; any other callable takes the
-    # generic dense-update path (see module docstring; intra-batch
-    # duplicate deltas are always summed before `update` is applied).
+    # "add" is one scatter-add of the batch; any other callable is a
+    # row-wise rule `(current, combined) -> new`, vectorised over a leading
+    # axis, applied once to every row the batch touches (intra-batch
+    # duplicate deltas are summed first): a sort of the batch, a read and
+    # a write of its distinct rows, nothing table-sized (`_push_rule`).
     update: Union[str, UpdateFn] = "add"
     mesh: Optional[Mesh] = None
     ps_axis: str = "ps"
@@ -312,7 +321,27 @@ def push(
     and are added one by one, XLA's roundings bit for bit) and every
     touched tile of eight rows read, added to and written back once a
     block of lanes (PERF.md section 6, PR 33).
+
+    A store whose ``update`` is a rule goes through :func:`_push_rule`
+    (:func:`push_counted` also hands out what that arm counted).
     """
+    return push_counted(spec, table, ids, deltas, mask)[0]
+
+
+def push_counted(
+    spec: StoreSpec,
+    table: Array,
+    ids: Array,
+    deltas: Array,
+    mask: Optional[Array] = None,
+) -> Tuple[Array, Optional[dict]]:
+    """:func:`push`, and beside the table what a rule store's push counted
+    on the device (``None`` for ``update="add"``, which counts nothing):
+    ``ps_rule_keys``, the live lanes of the batch, and ``ps_rule_rows``, the
+    distinct rows the rule rewrote.  ``make_train_step`` puts both among the
+    step's outputs, where whoever fetches outputs finds them, if the logic's
+    outputs are a dict (every logic of ``models/``); outputs of another
+    type leave the step as they are, without the counts."""
     vr = len(spec.value_shape)
     lead = tuple(deltas.shape[: deltas.ndim - vr])
     if (vr and tuple(deltas.shape[deltas.ndim - vr:]) != spec.value_shape) or (
@@ -353,27 +382,82 @@ def push(
         if _tile_kernel_takes(spec):
             from ..ops.row_update import scatter_add
 
-            return scatter_add(table, s_ids, s_deltas.astype(table.dtype))
-        return table.at[s_ids].add(s_deltas.astype(table.dtype), mode="drop")
+            return scatter_add(table, s_ids, s_deltas.astype(table.dtype)), None
+        return (
+            table.at[s_ids].add(s_deltas.astype(table.dtype), mode="drop"),
+            None,
+        )
 
-    # Generic path: combine duplicates densely, then apply `update` once per
-    # touched row.  O(capacity) per step — documented slow path; the add
-    # fast path is the perf path.
-    combined = jnp.zeros_like(table).at[flat_ids].add(
-        flat_deltas.astype(table.dtype), mode="drop"
-    )
-    ones = jnp.ones(flat_ids.shape, jnp.int32)
-    if mask is not None:
-        ones = jnp.where(flat_mask, ones, 0)
-    counts = (
-        jnp.zeros((spec.padded_capacity,), jnp.int32)
-        .at[flat_ids]
-        .add(ones, mode="drop")
-    )
+    live = flat_mask if mask is not None else None
+    return _push_rule(spec, table, flat_ids, flat_deltas, live)
+
+
+# Lanes a step of `_push_rule`'s loop over the batch's distinct rows.
+_RULE_CHUNK = 32_768
+
+
+def _push_rule(
+    spec: StoreSpec,
+    table: Array,
+    flat_ids: Array,
+    flat_deltas: Array,
+    live: Optional[Array],
+) -> Tuple[Array, dict]:
+    """The push of a store whose ``update`` is a rule and not ``"add"``:
+    ``(table, counted)``, as :func:`push_counted` hands them out.
+
+    Work and memory go with the batch, never with the table.  Under
+    ``ps.combine`` the batch's ids are sorted with their deltas, every run
+    of one id summed and the distinct ids moved to the front
+    (:func:`..ops.dedup.combine_runs`; masked, negative and out-of-range
+    lanes sort last and are dropped).  Then ``_RULE_CHUNK`` lanes a step of
+    a loop that ends with the last distinct id (on the TPU a dropped lane
+    of a gather or a scatter costs what a kept one does, and a batch of
+    Criteo records names a row 3.6 times on average): under ``ps.rule``
+    the CURRENT rows of the chunk's ids are read (:func:`pull`) and
+    ``update(current, combined)`` run on those alone; what is left under
+    ``ps.push`` writes the new rows back, each distinct id once."""
+    from ..ops.dedup import combine_runs
+
+    n = flat_ids.shape[0]
+    sentinel = spec.padded_capacity
     update_fn: UpdateFn = spec.update  # type: ignore[assignment]
-    updated = update_fn(table, combined)
-    touched = (counts > 0).reshape((-1,) + (1,) * len(spec.value_shape))
-    return jnp.where(touched, updated, table)
+    if n == 0:  # an empty batch rewrites nothing
+        zero = jnp.zeros((), jnp.int32)
+        return table, {"ps_rule_keys": zero, "ps_rule_rows": zero}
+    chunk = min(n, _RULE_CHUNK)
+    with jax.named_scope("ps.combine"):
+        dead = flat_ids >= sentinel
+        if live is not None:
+            dead = dead | ~live
+        row_ids, combined = combine_runs(
+            jnp.where(dead, sentinel, flat_ids),
+            flat_deltas.reshape(n, -1).astype(table.dtype), sentinel,
+        )
+        counted = {
+            "ps_rule_keys": n - jnp.sum(dead, dtype=jnp.int32),
+            "ps_rule_rows": jnp.sum(row_ids < sentinel, dtype=jnp.int32),
+        }
+        # whole chunks: a chunk that started early would run the rule on
+        # rows the chunk before it has already rewritten
+        pad = -n % chunk
+        row_ids = jnp.pad(row_ids, (0, pad), constant_values=sentinel)
+        combined = jnp.pad(combined, ((0, pad), (0, 0)))
+
+    def rewrite(i, table):
+        ids = jax.lax.dynamic_slice(row_ids, (i * chunk,), (chunk,))
+        sums = jax.lax.dynamic_slice(
+            combined, (i * chunk, 0), (chunk, combined.shape[1])
+        )
+        with jax.named_scope("ps.rule"):
+            new = update_fn(
+                pull(spec, table, ids),
+                sums.reshape((chunk,) + spec.value_shape),
+            ).astype(table.dtype)
+        return table.at[ids].set(new, mode="drop")
+
+    chunks = -(-counted["ps_rule_rows"] // chunk)
+    return jax.lax.fori_loop(0, chunks, rewrite, table), counted
 
 
 # Physical row widths, in 128-lane registers, from which `push` goes through
@@ -845,5 +929,6 @@ __all__ = [
     "create_table",
     "pull",
     "push",
+    "push_counted",
     "zeros_init",
 ]

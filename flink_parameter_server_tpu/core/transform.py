@@ -313,7 +313,13 @@ def make_train_step(logic: BatchedWorkerLogic, spec) -> Callable:
         with scope("ps.compute"):
             state, req, out = logic.step(state, batch, pulled)
         with scope("ps.push"):
-            table = store_mod.push(spec, table, req.ids, req.deltas, req.mask)
+            table, counted = store_mod.push_counted(
+                spec, table, req.ids, req.deltas, req.mask
+            )
+        if counted is not None and isinstance(out, dict):
+            # a rule store's push counts its live keys and distinct rows on
+            # the device; they leave the step with the logic's outputs
+            out = {**out, **counted}
         return table, state, out
 
     return step

@@ -164,15 +164,15 @@ def transform_multiclass(
     return transform_batched(data, logic, store, mesh=mesh, **kwargs)
 
 
-class PABinaryWorkerLogic(WorkerLogic):
-    """Event-API binary PA — the reference's per-example multi-pull with a
-    countdown until all feature answers arrive (SURVEY.md §3.4), for
-    semantics-parity tests."""
+class MultiPullWorkerLogic(WorkerLogic):
+    """Event-API worker for sparse examples ``(ids, values, label)`` — the
+    reference's per-example multi-pull with a countdown until all feature
+    answers arrive (SURVEY.md §3.4).  ``complete(ids, x, params, label,
+    ps)`` runs once an example's answers are all in."""
 
-    def __init__(self, rule: PARule = PARule()):
+    def __init__(self):
         import collections
 
-        self.rule = rule
         self.pending: Dict[int, dict] = {}
         # param_id -> FIFO of pending-example keys awaiting that answer:
         # O(1) per pull answer instead of a linear scan over all pending
@@ -219,19 +219,39 @@ class PABinaryWorkerLogic(WorkerLogic):
             del self._waiting[param_id]
         for key in done:
             ex = self.pending.pop(key)
-            x = np.array([ex["values"][i] for i in ex["ids"]], np.float32)
-            w = np.array([ex["weights"][i] for i in ex["ids"]], np.float32)
-            y = float(ex["label"])
-            margin = float(w @ x)
-            loss = max(0.0, 1.0 - y * margin)
-            tau = float(self.rule.tau(jnp.asarray(loss), jnp.asarray(float(x @ x))))
-            for fid, xi in zip(ex["ids"], x):
-                ps.push(fid, tau * y * float(xi))
-            ps.output((ex["label"], np.sign(margin), margin))
+            self.complete(
+                ex["ids"],
+                np.array([ex["values"][i] for i in ex["ids"]], np.float32),
+                np.array([ex["weights"][i] for i in ex["ids"]], np.float32),
+                ex["label"], ps,
+            )
+
+    def complete(self, ids, x, params, label, ps):
+        raise NotImplementedError
+
+
+class PABinaryWorkerLogic(MultiPullWorkerLogic):
+    """Event-API binary PA, for semantics-parity tests."""
+
+    def __init__(self, rule: PARule = PARule()):
+        super().__init__()
+        self.rule = rule
+
+    def complete(self, ids, x, w, label, ps):
+        import numpy as np
+
+        y = float(label)
+        margin = float(w @ x)
+        loss = max(0.0, 1.0 - y * margin)
+        tau = float(self.rule.tau(jnp.asarray(loss), jnp.asarray(float(x @ x))))
+        for fid, xi in zip(ids, x):
+            ps.push(fid, tau * y * float(xi))
+        ps.output((label, np.sign(margin), margin))
 
 
 __all__ = [
     "PARule",
+    "MultiPullWorkerLogic",
     "PassiveAggressiveBinary",
     "PassiveAggressiveMulticlass",
     "PABinaryWorkerLogic",

@@ -51,6 +51,52 @@ def occurrence_scale(
     return 1.0 / occurrence_counts(ids, capacity, mask)
 
 
+# Rows up to this many lanes wide ride through a sort as operands of their
+# own; wider ones are permuted after it by one gather of whole rows.  Alone
+# on the v5e, 1,277,952 lanes (PERF.md section 6, PR 34): carried, rows of
+# 1 / 3 / 4 lanes sort in 2.8 / 3.5 / 4.0 ms against 11.0 / 7.4 / 7.4
+# permuted; at 8 lanes it is 6.8 against 6.2, and the carrying sort takes
+# 96 s to compile against 13 (40 s at 3 lanes).  5 to 7 were not measured.
+_SORT_CARRIES_LANES = 4
+
+
+def _sorted_by_id(ids: Array, rows: Array) -> Tuple[Array, Array]:
+    """``ids`` ascending and the ``(n, w)`` ``rows`` in that order."""
+    n, w = rows.shape
+    if w <= _SORT_CARRIES_LANES:
+        out = jax.lax.sort((ids,) + tuple(rows.T), num_keys=1)
+        return out[0], jnp.stack(out[1:], axis=1)
+    ids, order = jax.lax.sort(
+        (ids, jnp.arange(n, dtype=jnp.int32)), num_keys=1
+    )
+    return ids, jnp.take(rows, order, axis=0)
+
+
+def combine_runs(ids: Array, vals: Array, sentinel: int) -> Tuple[Array, Array]:
+    """Sum the rows of ``vals`` (n, w) that share an id: ``(row_ids, sums)``,
+    both of the batch's static length.  The distinct ids come first, in
+    ascending order, each with its run's total; the rest of ``row_ids`` is
+    ``sentinel`` (an id no row has, and larger than any: lanes to drop carry
+    it coming in).  A run of any length costs what the batch does: one sort
+    that carries the values, ``log2 n`` shifted adds (a segmented prefix sum
+    by doubling, which sums each run as a balanced tree, the batch's lanes
+    along the minor axis), and a second sort that moves the lanes ending a
+    run to the front."""
+    n, w = vals.shape
+    ids, rows = _sorted_by_id(ids.astype(jnp.int32), vals)
+    cols = rows.T
+    d = 1
+    while d < n:
+        same = jnp.concatenate([jnp.zeros((d,), bool), ids[d:] == ids[:-d]])
+        before = jnp.concatenate(
+            [jnp.zeros((w, d), cols.dtype), cols[:, :-d]], axis=1
+        )
+        cols = cols + jnp.where(same[None], before, jnp.zeros_like(before))
+        d *= 2
+    ends = jnp.concatenate([ids[1:] != ids[:-1], jnp.ones((1,), bool)])
+    return _sorted_by_id(jnp.where(ends, ids, sentinel), cols.T)
+
+
 # -- host-side coalescing (the cluster client's request combiner) -----------
 # The wire-protocol analogue of the combination senders: before a
 # microbatch's pulls/pushes go to the network, duplicate ids collapse to

@@ -331,6 +331,29 @@ class StreamingDriver:
         return True
 
     # -- the loop ----------------------------------------------------------
+    def _publish_rule_counts(self, outs) -> None:
+        """What a rule store's push counted in the dispatch ``outs`` came
+        from (``core/store.push_counted``: its live keys and the distinct
+        rows its rule rewrote), as the gauges ``store_rule_keys`` and
+        ``store_rule_rows``.  A fetch of two scalars, made only where the
+        outputs are fetched anyway: at the metrics cadence, which syncs the
+        step, and once after the loop has ended."""
+        if self.registry is None or not isinstance(outs, dict):
+            return
+        if "ps_rule_rows" not in outs:
+            return
+
+        def total(x) -> float:  # a scanned dispatch stacks its steps' counts
+            return float(np.sum(np.asarray(x)))
+
+        # literal names: tools/fpsanalyze matches them to the docs' catalog
+        self.registry.gauge("store_rule_keys", component="train").set(
+            total(outs["ps_rule_keys"])
+        )
+        self.registry.gauge("store_rule_rows", component="train").set(
+            total(outs["ps_rule_rows"])
+        )
+
     def run(
         self,
         data: Iterable,
@@ -424,6 +447,7 @@ class StreamingDriver:
 
         def group_callback(first_idx, n_steps, table, state, outs):
             live[:] = table, state
+            last_outs[0] = outs
             # One invocation per jitted DISPATCH (n_steps == 1 when
             # steps_per_call == 1 — then this is exactly the old
             # per-step state_callback; n_steps == K for scanned groups,
@@ -510,6 +534,7 @@ class StreamingDriver:
                         step=global_step,
                     )
             if crossed(cfg.metrics_every):
+                self._publish_rule_counts(outs)
                 self.metrics.emit(self.metrics_sink)
                 if self._serving is not None:
                     self._serving.metrics.emit(self.metrics_sink)
@@ -579,6 +604,7 @@ class StreamingDriver:
         handed, self.store = self.store, ShardedParamStore(spec, None)
         handed_state, self._state = self._state, None
         live = [handed.table, handed_state]
+        last_outs = [None]  # the newest dispatch's outputs, never synced here
 
         try:
             result = transform_batched(
@@ -624,6 +650,7 @@ class StreamingDriver:
 
         self.store = result.store
         self._state = result.worker_state
+        self._publish_rule_counts(last_outs[0])
         if self._serving is not None:
             # close-time publish: post-run queries answer from the FINAL
             # table (the serve-path analogue of the §3.5 model flush)

@@ -376,6 +376,9 @@ def test_values_of_a_packed_sharded_table_come_out_sharded(dp, mesh_devices):
     store = ShardedParamStore.from_spec_values(spec, _on_mesh(want, mesh))
     ids = jnp.asarray(rng.integers(0, rows, 300).astype(np.int32))
     deltas = rng.normal(size=(300, d)).astype(np.float32)
+    # a copy: the CPU's device_put may alias `want`, and the pack above
+    # reads it asynchronously
+    want = want.copy()
     np.add.at(want, np.asarray(ids), deltas)
     values = store.push(ids, jnp.asarray(deltas)).values()
     np.testing.assert_allclose(np.asarray(values), want, rtol=1e-5, atol=1e-6)

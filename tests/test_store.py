@@ -582,3 +582,142 @@ def test_auto_leaves_a_128_lane_store_and_its_step_as_they_were():
         ).as_text())
         assert store.spec.layout == "dense"
     assert texts[0] == texts[1]
+
+
+# -- the rule arm (``update`` not "add"): work with the batch, not the table --
+
+
+def _capacity_arm(spec, table, ids, deltas, mask):
+    """The arm ``core/store.push`` had for a custom ``update`` until PR 34,
+    kept here as what the batch-sized arm is held to: duplicates combined
+    into a zeroed table, ``update`` over the WHOLE table, a table-sized
+    ``where``.  O(capacity) a step and three table-sized temporaries."""
+    flat_ids = ids.reshape(-1).astype(jnp.int32)
+    flat_ids = jnp.where(flat_ids < 0, spec.padded_capacity, flat_ids)
+    flat_deltas = deltas.reshape((-1,) + spec.value_shape)
+    ones = jnp.ones(flat_ids.shape, jnp.int32)
+    if mask is not None:
+        keep = mask.reshape((-1,) + (1,) * len(spec.value_shape))
+        flat_deltas = jnp.where(keep, flat_deltas, 0)
+        ones = jnp.where(mask.reshape(-1), ones, 0)
+    combined = jnp.zeros_like(table).at[flat_ids].add(
+        flat_deltas.astype(table.dtype), mode="drop"
+    )
+    counts = jnp.zeros((spec.padded_capacity,), jnp.int32).at[flat_ids].add(
+        ones, mode="drop"
+    )
+    touched = (counts > 0).reshape((-1,) + (1,) * len(spec.value_shape))
+    return jnp.where(touched, spec.update(table, combined), table)
+
+
+def _ema(current, combined):
+    return 0.5 * current + 0.5 * combined
+
+
+def _ftrl():
+    from flink_parameter_server_tpu.models.logistic_ftrl import FTRLProximal
+
+    return FTRLProximal()
+
+
+@pytest.mark.parametrize("sharded", [False, True], ids=["one_device", "ps_mesh"])
+@pytest.mark.parametrize("rule, shape, lanes, masked", [
+    ("ema", (), 64, False),
+    ("ema", (2,), 200, True),
+    ("ema", (17,), 300, True),       # wider than the sort carries: permuted
+    ("ema", (2, 3), 150, True),
+    ("ftrl", (3,), 400, True),
+    ("ftrl", (3,), 40_000, False),   # more than one chunk of the rule's loop
+])
+def test_the_rule_arm_is_the_capacity_arm(rule, shape, lanes, masked, sharded, mesh):
+    from flink_parameter_server_tpu.core import store as store_mod
+
+    rng = np.random.default_rng(lanes)
+    update = _ema if rule == "ema" else _ftrl()
+    capacity = 50 if lanes < 1000 else 30_000
+    values = rng.normal(size=(capacity,) + shape).astype(np.float32)
+    if rule == "ftrl":
+        values[:, 2] = np.abs(values[:, 2]) * 30
+    store = ShardedParamStore.from_values(
+        jnp.asarray(values), update=update, mesh=mesh if sharded else None
+    )
+    ids = rng.integers(-3, capacity + 4, lanes).astype(np.int32)
+    ids[: lanes // 4] = 5                          # one hot row
+    deltas = rng.normal(size=(lanes,) + shape).astype(np.float32)
+    if rule == "ftrl":
+        deltas[:, 2] = deltas[:, 0] ** 2
+    mask = jnp.asarray(rng.random(lanes) < 0.8) if masked else None
+    got = np.asarray(store.push(jnp.asarray(ids), jnp.asarray(deltas), mask).values())
+    want = np.asarray(_capacity_arm(
+        store.spec, jnp.asarray(np.array(store.table)), jnp.asarray(ids),
+        jnp.asarray(deltas), mask,
+    ))[:capacity]
+    live = np.ones(lanes, bool) if mask is None else np.asarray(mask)
+    hit = np.zeros(capacity, bool)
+    hit[ids[live & (ids >= 0) & (ids < capacity)]] = True
+    assert hit.any()
+    # untouched rows bit for bit; touched ones to the order of a run's sum
+    assert np.array_equal(got[~hit], values[~hit])
+    assert np.allclose(got[hit], want[hit], rtol=2e-5, atol=2e-5)
+    assert not np.array_equal(got[hit], values[hit])
+    table, counted = store_mod.push_counted(
+        store.spec, store.table, jnp.asarray(ids), jnp.asarray(deltas), mask
+    )
+    # padding rows are addressable rows too: an id counts up to there
+    kept = live & (ids >= 0) & (ids < store.spec.padded_capacity)
+    assert int(counted["ps_rule_rows"]) == len(np.unique(ids[kept]))
+    assert int(counted["ps_rule_keys"]) == kept.sum()
+
+
+@pytest.mark.parametrize("lanes", [3, 0], ids=["all_masked", "empty"])
+def test_a_push_of_no_live_lane_to_a_rule_store_changes_nothing(lanes):
+    from flink_parameter_server_tpu.core import store as store_mod
+
+    store = ShardedParamStore.create(9, (2,), init_fn=zeros((2,)), update=_ema)
+    store = store.push(jnp.array([1, 1, 4]), jnp.ones((3, 2)))
+    before = np.asarray(store.values())
+    push = (
+        jnp.array([1, 2, 3][:lanes], jnp.int32), jnp.ones((lanes, 2)),
+        jnp.zeros(lanes, bool),
+    )
+    assert np.array_equal(np.asarray(store.push(*push).values()), before)
+    _, counted = store_mod.push_counted(store.spec, store.table, *push)
+    assert int(counted["ps_rule_rows"]) == int(counted["ps_rule_keys"]) == 0
+    assert before[1, 0] == 1.0 and before[4, 0] == 0.5  # 0.5 * (1 + 1), 0.5 * 1
+
+
+@pytest.mark.parametrize("width", [1, 3, 8, 9, 17])
+def test_combine_runs_sums_each_id_and_moves_the_distinct_first(width):
+    from flink_parameter_server_tpu.ops.dedup import combine_runs
+
+    rng = np.random.default_rng(width)
+    n, sentinel = 777, 1000
+    ids = rng.integers(0, 40, n).astype(np.int32)
+    ids[rng.random(n) < 0.1] = sentinel            # lanes to drop
+    ids[:200] = 7                                   # a long run
+    vals = rng.normal(size=(n, width)).astype(np.float32)
+    row_ids, sums = jax.jit(combine_runs, static_argnums=2)(ids, vals, sentinel)
+    row_ids, sums = np.asarray(row_ids), np.asarray(sums)
+    distinct = np.unique(ids[ids < sentinel])
+    assert row_ids.shape == (n,) and sums.shape == (n, width)
+    assert np.array_equal(row_ids[: len(distinct)], distinct)
+    assert (row_ids[len(distinct):] == sentinel).all()
+    want = np.zeros((1001, width))
+    np.add.at(want, ids, vals.astype(np.float64))
+    assert np.allclose(sums[: len(distinct)], want[distinct], rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("update, shape, want", [
+    ("add", (17,), "packed"), ("add", (128,), "dense"), ("add", (2, 300), "packed"),
+    ("rule", (3,), "dense"), ("rule", (), "dense"), ("rule", (17,), "dense"),
+    ("rule", (128,), "dense"), ("rule", (2, 300), "dense"),
+])
+def test_auto_layout_reads_row_shape_and_update_rule(update, shape, want):
+    from flink_parameter_server_tpu.core.store import _resolve_layout
+
+    rule = "add" if update == "add" else _ema
+    assert _resolve_layout("auto", rule, shape) == want
+    if update == "rule":
+        # a rule writes whole logical rows back: it cannot be pinned packed
+        with pytest.raises(ValueError, match="requires update='add'"):
+            _resolve_layout("packed", rule, shape)

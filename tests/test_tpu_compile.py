@@ -673,3 +673,76 @@ def test_w2v_table_is_initialised_in_place_from_a_seed_argument(
     mem = compiled.memory_analysis()
     assert 7.68 * GB <= mem.output_size_in_bytes < 7.69 * GB
     assert mem.temp_size_in_bytes < 1.0 * GB  # 0.67 GB here: one block
+
+
+# lr-ftrl-criteo-40m (chipbench/configs): cell 6's table, (w, z, n) f32 rows
+# under a rule that is not "add", left dense: 3 lanes padded to FOUR sublanes,
+# f32[187767416,3]{0,1:T(4,128)}, 3.00 GB
+LR_ROWS = 187_767_412
+
+
+@pytest.fixture(scope="module")
+def lr():
+    from flink_parameter_server_tpu.models import logistic_ftrl as lf
+
+    spec = jax.eval_shape(lambda: lf.make_store(LR_ROWS)).spec
+    assert spec.layout == "dense" and spec.table_shape() == (LR_ROWS + 4, 3)
+    return spec, lf.LogisticFTRL()
+
+
+def test_lr_step_holds_nothing_table_sized_beside_its_table(
+        lr, one_chip, no_compile_cache):
+    """Cell 6's step at 187,767,412 rows for a described v5e: the donated
+    table is rewritten in place through the rule arm's loop, and what the
+    step holds beside it goes with the batch (the sorted ids and sums, a
+    chunk of gathered rows): 0.04 GB, where the arm before PR 34 held three
+    tables."""
+    spec, logic = lr
+    compiled = jax.jit(
+        make_train_step(logic, spec), donate_argnums=(0, 1)
+    ).lower(
+        _shape(one_chip, spec.table_shape(), jnp.float32), (),
+        _fm_batch(one_chip),
+    ).compile()
+    mem = compiled.memory_analysis()
+    assert 2.9 * GB < mem.alias_size_in_bytes < 3.1 * GB  # in place, 4 sublanes
+    assert mem.temp_size_in_bytes < 0.2 * GB
+    text = compiled.as_text()
+    table = r"f32\[%d,3\]" % (LR_ROWS + 4)
+    # no copy of the table, and only the write-back scatters into it
+    assert not re.search(table + r"\S* copy\(", text)
+    assert len(re.findall(r" while\(", text)) == 1
+    for scope in ("ps.pull", "ps.push/ps.combine",
+                  "ps.push/while/body/ps.rule"):
+        assert scope in text, scope
+
+
+def test_the_capacity_arm_holds_tables_beside_cell_6_s_table(
+        lr, one_chip, no_compile_cache):
+    """What ``push`` ran for a custom ``update`` until PR 34: duplicates
+    combined into a zeroed TABLE, the rule over the whole table, a
+    table-sized ``where``.  At cell 6's size that is table-sized
+    temporaries beside the table (and a walk of 187.8 M rows to change
+    355 k), where the batch-sized arm holds 0.04 GB."""
+    spec, _ = lr
+
+    def capacity_arm(table, ids, deltas):
+        combined = jnp.zeros_like(table).at[ids].add(deltas, mode="drop")
+        counts = jnp.zeros((table.shape[0],), jnp.int32).at[ids].add(
+            1, mode="drop"
+        )
+        return jnp.where(
+            (counts > 0)[:, None], spec.update(table, combined), table
+        )
+
+    lanes = FM_BATCH * FM_FIELDS
+    try:
+        mem = jax.jit(capacity_arm, donate_argnums=(0,)).lower(
+            _shape(one_chip, spec.table_shape(), jnp.float32),
+            _shape(one_chip, (lanes,), jnp.int32),
+            _shape(one_chip, (lanes, 3), jnp.float32),
+        ).compile().memory_analysis()
+    except Exception as e:  # refused outright
+        assert "RESOURCE_EXHAUSTED" in str(e)
+    else:
+        assert mem.temp_size_in_bytes > 3.0 * GB  # a table, or more
