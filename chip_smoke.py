@@ -642,6 +642,24 @@ class Smoke:
             close(table_w.at[ids_w].add(deltas_w), 1e-6),
         )
 
+        # a rule's narrow rows at cell 6's row (three lanes held at four,
+        # the table rows-minor): every touched tile of 128 rows read, set
+        # and written back, the bits of XLA's row set
+        rows_s = 128 * (4_000 if not self.dry_run else 8)
+        ids_s = jnp.sort(jnp.asarray(
+            rng.choice(rows_s, n - 40, replace=False), jnp.int32))
+        ids_s = jnp.concatenate([ids_s, jnp.full((40,), rows_s, jnp.int32)])
+        table_s = jnp.pad(normal((rows_s, 3)), ((0, 0), (0, 1)))
+        new_s = normal((n, 3))
+        self._kernel_case(
+            "row_set_tiles_d3_f32",
+            lambda t, i, r: row_update.sorted_tile_set(
+                t, i, r, interpret=interpret)[0],
+            (table_s, ids_s, new_s),
+            close(table_s.at[ids_s].set(
+                jnp.pad(new_s, ((0, 0), (0, 1))), mode="drop"), 0.0),
+        )
+
         # the MF step's DEFAULT arm at that shape class, against the XLA
         # arm: table, state and both per-record outputs in stream order
         # (the dry run pins the arm: off the chip the default is XLA's)

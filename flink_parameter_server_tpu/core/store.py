@@ -60,7 +60,14 @@ def _resolve_layout(
     a packed table would lose a physical row's other touched rows; for
     FTRL's 3-lane row the TPU pads a dense row to FOUR sublanes, 3.00 GB
     at 187.8 M rows, and packed 42 to a physical row the step asks 13.5
-    GB for its 42 static lane slices: PERF.md section 6, PR 34).  A narrow dense row is a column of scalars
+    GB for its 42 static lane slices: PERF.md section 6, PR 34).  Such a
+    store's PHYSICAL row, where it is float32, one axis of 1 to 8 lanes
+    and in one place, is that sublane tile, 1, 2, 4 or 8 lanes, the rest
+    zeros, and its table whole tiles of 128 rows (``StoreSpec.tile_lanes``:
+    ``(w, z, n)`` lies as ``f32[187767424,4]{0,1:T(4,128)}``): the chip's
+    bytes as they were, and a table whose tiles a Pallas kernel can move
+    (a 3-lane table has the same tiles and Mosaic refuses a slice of
+    them: PERF.md section 6, PR 35).  A narrow dense row is a column of scalars
     across the table's tiles on the TPU, and its gather and scatter-add
     walk that column (36 and 121 ns a row for FM's 17 lanes on the v5e,
     against 10 and 22 for the 128-lane physical row that holds seven of
@@ -151,14 +158,42 @@ class StoreSpec:
         return pack_k(self.row_width)
 
     @property
+    def narrow_rule(self) -> bool:
+        """A dense table in one place whose ``update`` is a rule and whose
+        rows hold at most 8 elements: the stores whose write-back the set
+        kernel is for (:func:`_set_kernel_takes`)."""
+        return (self.update != "add" and self.layout == "dense"
+                and self.mesh is None and self.row_width <= 8)
+
+    @property
+    def tile_lanes(self) -> int:
+        """Lanes of a PHYSICAL row where the table is that of a narrow rule
+        store the set kernel can move, and 0 for every other store: a
+        ``narrow_rule`` store of float32 rows of one axis holds them at 1,
+        2, 4 or 8 lanes, the sublane tile the TPU
+        pads a table of rows that narrow to anyway (FTRL's ``(w, z, n)`` at
+        four: 3.00 GB at 187.8 M rows either way).  The table then lies
+        rows-minor on the chip, a tile of it is 128 whole rows, and the
+        rule's write-back can move whole tiles
+        (``ops/row_update.sorted_tile_set``; :func:`_set_kernel_takes`).
+        The lanes past ``row_width`` are zero, the rule never reads them,
+        and ``pull`` and ``values()`` strip them."""
+        if (not self.narrow_rule or len(self.value_shape) != 1
+                or jnp.dtype(self.dtype) != jnp.float32):
+            return 0
+        return 1 << (self.row_width - 1).bit_length()
+
+    @property
     def rows_per_shard(self) -> int:
         """Per-shard PHYSICAL row count, aligned to 8 rows: the sublane
         tile of a float32 table on the TPU, so every shard's block starts
-        and ends on a tile."""
+        and ends on a tile.  A narrow rule store's (``tile_lanes``) is
+        aligned to the 128 rows of ITS tiles."""
         n = self.num_shards
         logical = (self.capacity + self.pack - 1) // self.pack
         per = (logical + n - 1) // n
-        return ((per + 7) // 8) * 8
+        align = 128 if self.tile_lanes else 8
+        return -(-per // align) * align
 
     @property
     def padded_capacity(self) -> int:
@@ -174,6 +209,8 @@ class StoreSpec:
                 self.rows_per_shard * self.num_shards,
                 phys_width(self.row_width),
             )
+        if self.tile_lanes:
+            return (self.padded_capacity, self.tile_lanes)
         return (self.padded_capacity,) + self.value_shape
 
     def sharding(self) -> Optional[NamedSharding]:
@@ -208,7 +245,7 @@ def create_table(spec: StoreSpec, init_fn: Optional[InitFn] = None) -> Array:
     out_sharding = spec.sharding()
 
     def build(ids):
-        return init_fn(ids)
+        return _physical_rows(spec, init_fn(ids))
 
     if out_sharding is not None:
         build = jax.jit(build, out_shardings=out_sharding)
@@ -275,7 +312,19 @@ def pull(spec: StoreSpec, table: Array, ids: Array) -> Array:
         else:
             vals = packed_pull(table, ids.reshape(-1), spec.row_width)
         return vals.reshape(ids.shape + spec.value_shape)
+    if spec.tile_lanes > spec.row_width:
+        return _narrow_pull(table, ids, spec.row_width)
     return jnp.take(table, ids, axis=0)
+
+
+@functools.partial(jax.jit, static_argnums=(2,))
+def _narrow_pull(table: Array, ids: Array, width: int) -> Array:
+    """Rows ``ids`` of a narrow rule store's table without the zero lanes
+    that end them: the slice is the gather's own window (``slice_sizes``
+    ``(1, width)``), no op of its own.  Jitted so that an eager ``pull``
+    is that gather too: op by op the slice comes first and copies the
+    table (3.0 GB at cell 6's size: PERF.md section 6, PR 35)."""
+    return jnp.take(table[:, :width], ids, axis=0)
 
 
 def _phys_scatter_args(
@@ -337,9 +386,11 @@ def push_counted(
 ) -> Tuple[Array, Optional[dict]]:
     """:func:`push`, and beside the table what a rule store's push counted
     on the device (``None`` for ``update="add"``, which counts nothing):
-    ``ps_rule_keys``, the live lanes of the batch, and ``ps_rule_rows``, the
-    distinct rows the rule rewrote.  ``make_train_step`` puts both among the
-    step's outputs, where whoever fetches outputs finds them, if the logic's
+    ``ps_rule_keys``, the live lanes of the batch, ``ps_rule_rows``, the
+    distinct rows the rule rewrote, and ``ps_rule_tiles``, the tiles of 128
+    rows the write-back read and wrote to do so (0 where XLA's scatter
+    wrote the rows: :func:`_set_kernel_takes`).  ``make_train_step`` puts
+    them among the step's outputs, where whoever fetches outputs finds them, if the logic's
     outputs are a dict (every logic of ``models/``); outputs of another
     type leave the step as they are, without the counts."""
     vr = len(spec.value_shape)
@@ -416,15 +467,25 @@ def _push_rule(
     Criteo records names a row 3.6 times on average): under ``ps.rule``
     the CURRENT rows of the chunk's ids are read (:func:`pull`) and
     ``update(current, combined)`` run on those alone; what is left under
-    ``ps.push`` writes the new rows back, each distinct id once."""
+    ``ps.push`` writes the new rows back, each distinct id once, in the arm
+    :func:`_set_kernel_takes` reads from the spec: XLA's row ``set`` (on
+    the v5e one serial write a row, 83 ns for FTRL's three lanes), or, on
+    a TPU, for a narrow row held at its sublane tile
+    (``StoreSpec.tile_lanes``), ``ops/row_update.sorted_tile_set``: every
+    touched tile of 128 rows read, set and written back once, the same
+    bits (PERF.md section 6, PR 35)."""
     from ..ops.dedup import combine_runs
+    from ..ops.row_update import sorted_tile_set
 
     n = flat_ids.shape[0]
     sentinel = spec.padded_capacity
     update_fn: UpdateFn = spec.update  # type: ignore[assignment]
     if n == 0:  # an empty batch rewrites nothing
         zero = jnp.zeros((), jnp.int32)
-        return table, {"ps_rule_keys": zero, "ps_rule_rows": zero}
+        return table, {
+            "ps_rule_keys": zero, "ps_rule_rows": zero, "ps_rule_tiles": zero,
+        }
+    tiles_arm = _set_kernel_takes(spec)
     chunk = min(n, _RULE_CHUNK)
     with jax.named_scope("ps.combine"):
         dead = flat_ids >= sentinel
@@ -444,7 +505,8 @@ def _push_rule(
         row_ids = jnp.pad(row_ids, (0, pad), constant_values=sentinel)
         combined = jnp.pad(combined, ((0, pad), (0, 0)))
 
-    def rewrite(i, table):
+    def rewrite(i, carry):
+        table, tiles = carry
         ids = jax.lax.dynamic_slice(row_ids, (i * chunk,), (chunk,))
         sums = jax.lax.dynamic_slice(
             combined, (i * chunk, 0), (chunk, combined.shape[1])
@@ -454,10 +516,24 @@ def _push_rule(
                 pull(spec, table, ids),
                 sums.reshape((chunk,) + spec.value_shape),
             ).astype(table.dtype)
-        return table.at[ids].set(new, mode="drop")
+        if tiles_arm:
+            table, moved = sorted_tile_set(table, ids, new)
+            return table, tiles + moved
+        return table.at[ids].set(_physical_rows(spec, new), mode="drop"), tiles
 
     chunks = -(-counted["ps_rule_rows"] // chunk)
-    return jax.lax.fori_loop(0, chunks, rewrite, table), counted
+    table, counted["ps_rule_tiles"] = jax.lax.fori_loop(
+        0, chunks, rewrite, (table, jnp.zeros((), jnp.int32))
+    )
+    return table, counted
+
+
+def _physical_rows(spec: StoreSpec, rows: Array) -> Array:
+    """Logical rows ``(n, *value_shape)`` as rows of a dense table: a narrow
+    rule store's get the zero lanes that fill its sublane tile
+    (``StoreSpec.tile_lanes``), every other store's are as they are."""
+    pad = spec.tile_lanes - spec.row_width
+    return jnp.pad(rows, ((0, 0), (0, pad))) if pad > 0 else rows
 
 
 # Physical row widths, in 128-lane registers, from which `push` goes through
@@ -494,21 +570,53 @@ def _tile_kernel_takes(spec: StoreSpec) -> bool:
             or jax.default_backend() != "tpu"
             or width < _TILE_KERNEL_MIN_REGISTERS * 128):
         return False
-    why = row_update.tile_refusal(shape, spec.dtype)
+    return _taken_or_noted(
+        spec, "push into a table of wide rows",
+        row_update.tile_refusal(shape, spec.dtype),
+    )
+
+
+def _taken_or_noted(spec: StoreSpec, what: str, why: Optional[str]) -> bool:
+    """True for no refusal; a refusal is counted and warned of once a row
+    shape, dtype and arm."""
     if why is None:
         return True
-    key = (shape[1:], jnp.dtype(spec.dtype).name)
+    key = (what, spec.table_shape()[1:], jnp.dtype(spec.dtype).name)
     if key not in _REFUSALS_NOTED:
         _REFUSALS_NOTED.add(key)
-        row_update.note_refusal("push into a table of wide rows", why)
+        from ..ops.row_update import note_refusal
+
+        note_refusal(what, why)
+    return False
+
+
+def _set_kernel_takes(spec: StoreSpec) -> bool:
+    """Whether a rule store's write-back (:func:`_push_rule`) goes through
+    ``ops/row_update.sorted_tile_set`` instead of XLA's row ``set``, read
+    from what the spec holds, as :func:`_tile_kernel_takes` reads the add
+    arm's: a TPU, and a table held at whole sublane tiles for it
+    (``StoreSpec.tile_lanes``).  Static per compiled step.  A narrow rule
+    store that is NOT held so (bfloat16, rows of rank 0 or 2) keeps the XLA
+    arm, counted and warned of once."""
+    if jax.default_backend() != "tpu":
+        return False
+    if spec.tile_lanes:
+        return True
+    if spec.narrow_rule:
+        from ..ops import row_update
+
+        _taken_or_noted(
+            spec, "write-back of a rule's narrow rows",
+            row_update.set_refusal(spec.table_shape(), spec.dtype),
+        )
     return False
 
 
 def _preload_tile_kernel(spec: StoreSpec) -> None:
-    """Where a store is made whose pushes will trace the tile kernel: have
+    """Where a store is made whose pushes will trace a tile kernel: have
     Pallas imported by then, beside the table's staging (the import is ~1 s
     that the first trace of the step else pays)."""
-    if _tile_kernel_takes(spec):
+    if _tile_kernel_takes(spec) or _set_kernel_takes(spec):
         from ..ops.row_update import preload
 
         preload()
@@ -795,6 +903,13 @@ def _lives_on_mesh(spec: StoreSpec, values: Any) -> bool:
     )
 
 
+@functools.partial(jax.jit, static_argnums=(1, 2))
+def _pad_to_tiles(values: Array, rows: int, lanes: int) -> Array:
+    """``values`` (n, w) as a ``(rows, lanes)`` table, zeros past them."""
+    n, w = values.shape
+    return jnp.pad(values, ((0, rows - n), (0, lanes - w)))
+
+
 @jax.tree_util.register_pytree_node_class
 class ShardedParamStore:
     """Functional bundle of (spec, table).  All mutators return new stores.
@@ -875,6 +990,9 @@ class ShardedParamStore:
             ):
                 return _pack_rows_on_mesh(spec)(values)
             values = _pack_rows(spec)(values)
+        elif spec.tile_lanes and values.shape != spec.table_shape():
+            # rows and zero lanes in one pass, the table its only output
+            values = _pad_to_tiles(values, *spec.table_shape())
         else:
             pad = spec.padded_capacity - values.shape[0]
             if pad and _lives_on_mesh(spec, values):
@@ -912,6 +1030,8 @@ class ShardedParamStore:
             ):
                 return _unpack_rows_on_mesh(spec, self.table)
             return _unpack_rows(spec, self.table)
+        if spec.tile_lanes > spec.row_width:
+            return self.table[: spec.capacity, : spec.row_width]
         return self.table[: spec.capacity]
 
     # -- pytree plumbing ---------------------------------------------------

@@ -325,6 +325,40 @@ def make_train_step(logic: BatchedWorkerLogic, spec) -> Callable:
     return step
 
 
+def jit_train_steps(
+    logic: BatchedWorkerLogic, spec, steps_per_call: int = 1
+) -> Tuple[Callable, Optional[Callable]]:
+    """The jitted, donating programs ``transform_batched`` dispatches: the
+    single step and, where ``steps_per_call > 1``, the scanned one (else
+    ``None``).  ``transform_batched`` builds a pair a call; a caller that
+    loops more than once over one logic and spec (the StreamingDriver)
+    builds the pair once and hands it to each call as ``steps``, so that a
+    second loop finds its step traced, lowered and loaded."""
+    step = jax.jit(make_train_step(logic, spec), donate_argnums=(0, 1))
+    scan_step = None
+    if steps_per_call > 1:
+        scan_step = jax.jit(
+            make_scan_train_step(logic, spec), donate_argnums=(0, 1)
+        )
+    return step, scan_step
+
+
+def _committed_where(table):
+    """``table`` committed to the devices it lies on (no copy: the array
+    keeps its buffers), and a function that does the same for every leaf of
+    the worker state that lies on those same devices.  A leaf anywhere else
+    (a state built on the default device beside a table on a mesh) is left
+    for ``jit`` to place, as it always was."""
+    devices = table.sharding.device_set
+
+    def commit(x):
+        if isinstance(x, jax.Array) and x.sharding.device_set == devices:
+            return jax.device_put(x, x.sharding)
+        return x
+
+    return commit(table), commit
+
+
 def scan_group_sharding(batch_sharding):
     """Sharding for (K, batch, ...)-stacked scan inputs: the scan axis
     prepends as unsharded, the per-batch spec shifts right.  ``None``
@@ -411,6 +445,7 @@ def transform_batched(
     steps_per_call: int = 1,
     tracer: SpanTracer = NULL_TRACER,
     owns_inputs: bool = False,
+    steps: Optional[Tuple[Callable, Optional[Callable]]] = None,
 ) -> TransformResult:
     """Run the compiled PS loop over an iterable of microbatches.
 
@@ -461,6 +496,10 @@ def transform_batched(
     granularity, since between scanned steps there is no host-visible
     table at all.
 
+    ``steps`` is the pair :func:`jit_train_steps` built for this logic,
+    this store's spec and this ``steps_per_call``, kept by a caller that
+    loops more than once; by default the call builds its own.
+
     ``tracer`` (the StreamingDriver hands its own; the default records
     nothing) gets two spans a dispatch on the calling thread, never
     overlapping: ``train.batch_wait`` while this loop is blocked on
@@ -482,12 +521,11 @@ def transform_batched(
             "access)"
         )
 
-    step = jax.jit(make_train_step(worker_logic, spec), donate_argnums=(0, 1))
-    scan_step = None
-    if steps_per_call > 1:
-        scan_step = jax.jit(
-            make_scan_train_step(worker_logic, spec), donate_argnums=(0, 1)
-        )
+    step, scan_step = (
+        steps
+        if steps is not None
+        else jit_train_steps(worker_logic, spec, steps_per_call)
+    )
     # The jitted step donates (table, state); unless the caller handed
     # them over (`owns_inputs`), start from copies so the caller's store
     # (and any restored state they still hold) stays valid — the same
@@ -510,7 +548,12 @@ def transform_batched(
         scan_group_sharding(batch_sharding) if steps_per_call > 1 else None
     )
 
-    table = keep(store.table)
+    # committed from the first dispatch on, as the step's own outputs are
+    # (a batch staged on a device commits them): an uncommitted table or
+    # state makes the first dispatch a program of its own, lowered and
+    # loaded once more (no copy: the array keeps its buffers)
+    table, commit = _committed_where(keep(store.table))
+    state = jax.tree.map(commit, state)
     worker_outputs: List[Any] = []
     step_idx = 0
 
@@ -687,7 +730,11 @@ def transform_with_model_load(
         table = ps_logic.table
         ids = np.array([int(i) for i, _ in model])
         vals = jnp.asarray(np.stack([np.asarray(v) for _, v in model]))
-        table = table.at[ids].set(vals.astype(table.dtype))
+        from .store import _physical_rows
+
+        table = table.at[ids].set(
+            _physical_rows(ps_logic.spec, vals.astype(table.dtype))
+        )
         seeded = ShardedParamStore(ps_logic.spec, table)
         return transform(data, worker_logic, seeded, **kwargs)
 

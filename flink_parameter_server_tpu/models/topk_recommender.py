@@ -41,6 +41,8 @@ def _logical_table(spec, table: Array) -> Array:
         from ..ops.packed import unpack_table
 
         return unpack_table(table, spec.padded_capacity, spec.row_width)
+    if spec.tile_lanes:  # a narrow rule row's zero lanes
+        return table[:, : spec.row_width]
     return table
 
 

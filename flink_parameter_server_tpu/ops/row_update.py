@@ -1,5 +1,6 @@
 """Pallas TPU kernels: one pipelined row WRITE per unique id of a sorted batch,
-and, for rows wider than a register, one read-modify-write per touched TILE.
+and, for rows wider than a register or far narrower, one read-modify-write
+per touched TILE.
 
 Reference parity (SURVEY.md §2 #7, §7 "Hard parts"): the reference's MF
 worker keeps its user vectors in a JVM hash map and updates one vector per
@@ -57,6 +58,21 @@ order of the batch (the sort is stable), and writes the tile rows back: a
 read-modify-write per touched tile row at HBM speed instead of XLA's serial
 one per lane (124 ns a 640-lane row on the v5e), with XLA's roundings, bit
 for bit.  It reads the table itself, so it takes no old rows.
+
+**Narrow rows under a rule** (``core/store._push_rule``'s write-back of
+FTRL's ``(w, z, n)``: :func:`sorted_tile_set`) go through the third.  A
+float32 table of rows of 1, 2, 4 or 8 lanes lies rows-minor on the TPU,
+``{0,1:T(L,128)}``: byte for byte the row-major ``(L, rows)``, a TILE of it
+128 consecutive rows, ``L x 512`` contiguous bytes, and what XLA's row
+``set`` writes at 83 ns a row is one column of such a tile.  The kernel
+reads every tile the sorted distinct ids touch into VMEM, sets each row's
+column by selects on iotas (its values scalars from SMEM) and writes the
+tiles back.  A tile belongs to the block of lanes that holds its first
+lane, whose sets reach into the next block's lanes, so no two grid steps
+touch one tile: the next block's reads are in flight under this block's
+sets and the block before's writes, three tile buffers.  Its time is the
+issue of two DMA descriptors a tile (17 ns each on the v5e) and 7 ns a lane
+of sets (PERF.md section 6, PR 35).
 """
 from __future__ import annotations
 
@@ -624,6 +640,297 @@ def sorted_tile_add(
     )(tiles, words, counts, deltas, table)
 
 
+# -- narrow rows under a rule: a read-modify-write per touched tile of 128 rows
+# lanes of a narrow physical row the set kernel takes: the sublane tiles the
+# TPU gives a float32 table of rows that narrow anyway
+SET_ROW_LANES = (1, 2, 4, 8)
+_TILE_ROWS = 128  # rows to a tile of the transposed (lanes, rows) table
+# a call of the set kernel prefetches, a lane, its tile, its word and the
+# row's values into SMEM: as many words as MAX_LANES lanes of the other two
+# kernels take
+_SET_SMEM_WORDS = 2 * MAX_LANES
+
+
+def _each(count, body) -> None:
+    """``body(i)`` for ``i`` in ``[0, count)``, eight to a trip of the loop
+    and the rest one by one: Mosaic unrolls a loop wholly or not at all,
+    and a trip's scalar work packs the better the more of it there is (on
+    the v5e a DMA descriptor issued one a trip is 22 ns, eight a trip 17;
+    a lane set 9.4 ns and 6.8: PERF.md section 6, PR 35).  The eight are a
+    loop of their own, unrolled where the kernel is lowered: ``body`` is
+    traced twice, not nine times, and the arithmetic here and in the
+    kernel is written in ``lax`` (``count // 8`` is a dozen equations and
+    a nested function to lower where a shift is one): every process that
+    runs the step traces and lowers the kernel, warm cache or not, and the
+    first form of this one cost cell 6 four seconds of set-up."""
+    whole = jax.lax.shift_right_logical(count, 3)
+
+    def group(g, _):
+        jax.lax.fori_loop(
+            0, 8, lambda k, c: (body(g * 8 + k), c)[1], 0, unroll=True
+        )
+        return 0
+
+    def single(i, _):
+        body(i)
+        return 0
+
+    jax.lax.fori_loop(0, whole, group, 0)
+    jax.lax.fori_loop(whole * 8, count, single, 0)
+
+
+def set_refusal(table_shape: Tuple[int, ...], dtype) -> Optional[str]:
+    """Why :func:`sorted_tile_set` cannot take this table (None: it can)."""
+    if jnp.dtype(dtype) != jnp.float32:
+        return f"rows are {jnp.dtype(dtype).name}, the kernel moves float32"
+    if len(table_shape) != 2 or table_shape[1] not in SET_ROW_LANES:
+        return (
+            f"rows of shape {tuple(table_shape[1:])}, the kernel takes flat "
+            f"rows of {SET_ROW_LANES} lanes (a whole sublane tile)"
+        )
+    if table_shape[0] % _TILE_ROWS != 0:
+        return f"{table_shape[0]} rows are not whole tiles of {_TILE_ROWS}"
+    return None
+
+
+def _tile_set_kernel(tiles_ref, words_ref, counts_ref, vals_ref, table_ref,
+                     out_ref, tile_buf, sem, *, block: int, width: int,
+                     lanes: int):
+    """One grid step = the tiles that ``block`` sorted lanes OPEN, and every
+    lane of those tiles (the last may reach into the next block's lanes, so
+    no two steps touch one tile and nothing orders their copies).
+
+    tiles_ref: (N,) int32 SMEM (scalar prefetch) — at the head of each
+      block's stretch, the tiles (row // 128) whose first lane lies in the
+      block, ascending.
+    words_ref: (N,) int32 SMEM — per kept lane, its tile's place in its
+      OWNER block's list (bits 0-15) and its row's lane in the tile (16-22).
+    counts_ref: (3 N / block,) int32 SMEM — per block, how many tiles it
+      opens, the first lane it owns and how many lanes it owns.
+    vals_ref: (width x N,) float32 SMEM — the new rows, lane-major.
+    table_ref / out_ref: the aliased (L, rows) view of the table in HBM.
+    tile_buf: (3, block, L, 128) f32 VMEM — three blocks' tiles: one being
+      read, one being set, one being written back.
+    sem: (2, 3) DMA semaphores — reads and writes of each slot.
+    """
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    del table_ref  # aliased to out_ref
+    b, blocks = pl.program_id(0), pl.num_programs(0)
+    reads, writes = 0, 1
+    shape = tile_buf.shape[2:]  # (L, 128)
+
+    def tile(blk, j):
+        first = pl.multiple_of(tiles_ref[blk * block + j] * _TILE_ROWS,
+                               _TILE_ROWS)
+        return out_ref.at[:, pl.ds(first, _TILE_ROWS)]
+
+    def start_reads(blk):
+        slot = jax.lax.rem(blk, 3)
+
+        def read(j):
+            pltpu.make_async_copy(
+                tile(blk, j), tile_buf.at[slot, j], sem.at[reads, slot]
+            ).start()
+
+        _each(counts_ref[3 * blk], read)
+
+    def await_copies(blk, which):
+        # a DMA semaphore counts bytes: a block's copies are answered by a
+        # wait the size of sixteen tiles for every sixteen of them and one
+        # the size of a tile for each of the rest (a wait a tile is 6.5 ns
+        # on the v5e, 2.3 ms a step of cell 6)
+        slot, count = jax.lax.rem(blk, 3), counts_ref[3 * blk]
+
+        def wait_for(tiles):
+            def wait(j, _):
+                part = tile_buf.at[0, pl.ds(0, tiles)]
+                pltpu.make_async_copy(part, part, sem.at[which, slot]).wait()
+                return 0
+
+            return wait
+
+        jax.lax.fori_loop(
+            0, jax.lax.shift_right_logical(count, 4), wait_for(16), 0)
+        jax.lax.fori_loop(0, count & 15, wait_for(1), 0)
+
+    # reads run a block ahead: block 0's and block 1's start in step 0.  The
+    # slot a block reads into is the one the third block before wrote from
+    def ahead(i, _):
+        blk = b + i
+
+        @pl.when(blk >= 3)
+        def _free_slot():
+            await_copies(blk - 3, writes)
+
+        start_reads(blk)
+        return 0
+
+    jax.lax.fori_loop(
+        (b != 0).astype(jnp.int32), 1 + (b + 1 < blocks).astype(jnp.int32),
+        ahead, 0,
+    )
+    await_copies(b, reads)
+    slot = jax.lax.rem(b, 3)
+    sublane = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    column = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    first = counts_ref[3 * b + 1]
+
+    def set_row(k):
+        lane = first + k
+        word = words_ref[lane]
+        at = (slot, word & 0xFFFF)
+        # the row as a column of the tile: its values down the sublanes,
+        # zeros on the pad ones
+        new = jnp.zeros(shape, jnp.float32)
+        for s in reversed(range(width)):
+            value = jax.lax.broadcast(vals_ref[s * lanes + lane], shape)
+            new = jax.lax.select(sublane == s, value, new)
+        tile_buf[at] = jax.lax.select(
+            column == (word >> 16), new, tile_buf[at])
+
+    _each(counts_ref[3 * b + 2], set_row)
+
+    def write(j):
+        pltpu.make_async_copy(
+            tile_buf.at[slot, j], tile(b, j), sem.at[writes, slot]
+        ).start()
+
+    _each(counts_ref[3 * b], write)
+
+    @pl.when(b == blocks - 1)
+    def _drain():  # the last three blocks' writes
+        jax.lax.fori_loop(
+            jax.lax.max(blocks - 3, 0), blocks,
+            lambda blk, _: (await_copies(blk, writes), 0)[1], 0,
+        )
+
+
+def _tile_set_plan(sorted_ids: Array, rows: int, block: int):
+    """The set kernel's scalars from the sorted DISTINCT ids:
+    ``(tiles, words, counts, opened)``, ``opened`` the tiles touched."""
+    ids = sorted_ids.reshape(-1, block)
+    kept = ids < rows  # the dropped lanes sort to the end
+    tile = ids // _TILE_ROWS
+    # a kept lane opens a tile unless the lane before it, in its block or
+    # the one before, lies in the same (ids ascend)
+    flat = tile.reshape(-1)
+    before = jnp.concatenate([jnp.full((1,), -1, flat.dtype), flat[:-1]])
+    opens = kept & (tile != before.reshape(tile.shape))
+    opened = jnp.sum(opens, axis=1, dtype=jnp.int32)
+    kept_n = jnp.sum(kept, axis=1, dtype=jnp.int32)
+    # the lanes at a block's head that lie in the last tile of the block
+    # before belong to that block: ids are distinct, so a tile holds at most
+    # 128 lanes and a block of more owns its own or its neighbour's
+    spill = jnp.where(
+        opened > 0, jnp.argmax(opens, axis=1).astype(jnp.int32), kept_n
+    )
+    nxt = jnp.concatenate([spill[1:], jnp.zeros((1,), jnp.int32)])
+    first = jnp.arange(ids.shape[0], dtype=jnp.int32) * block + spill
+    seen = jnp.cumsum(opens, axis=1, dtype=jnp.int32)
+    last_of_prev = jnp.concatenate([jnp.zeros((1,), jnp.int32), opened[:-1]])
+    place = jnp.maximum(
+        jnp.where(seen > 0, seen, last_of_prev[:, None]) - 1, 0
+    )
+    words = place | ((ids % _TILE_ROWS) << 16)
+    # the opened tiles to the front of their block, as `_tile_plan` moves its
+    local = jax.lax.broadcasted_iota(jnp.int32, ids.shape, 1)
+    tiles = jax.lax.sort(
+        (jnp.where(opens, local, local + block), tile), dimension=1,
+        num_keys=1,
+    )[1]
+    counts = jnp.stack([opened, first, kept_n - spill + nxt], axis=1)
+    return (tiles.reshape(-1), words.reshape(-1), counts.reshape(-1),
+            jnp.sum(opened))
+
+
+def sorted_tile_set(
+    table: Array,
+    sorted_ids: Array,
+    new: Array,
+    *,
+    interpret: Optional[bool] = None,
+) -> Tuple[Array, Array]:
+    """``table[r, :w] = new[k]`` (and zeros on the row's other lanes) for
+    every row ``r`` a kept lane ``k`` names, a read-modify-write of each
+    touched tile of 128 rows; every other tile is left as it is.  Returns
+    the table and how many tiles were moved.
+
+    ``table``: (rows, L) float32 with L one of ``SET_ROW_LANES`` and whole
+    tiles of 128 rows (:func:`set_refusal`): on the TPU such a table lies
+    rows-minor, ``{0,1:T(L,128)}``, which is byte for byte the row-major
+    ``(L, rows)`` the kernel addresses, 128 consecutive rows to a
+    contiguous tile, so both transposes here are bitcasts.
+    ``sorted_ids``: (n,) int32 ASCENDING and DISTINCT, lanes to drop at the
+    end with an id >= the row count.  ``new``: (n, w) in that order, ``w <=
+    L``; a dropped lane's may be anything.  In place when the enclosing jit
+    donates the table; an eager call copies it first.  Off the TPU the
+    kernel is interpreted.
+    """
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    why = set_refusal(table.shape, table.dtype)
+    if why is None and new.shape[1] > table.shape[1]:
+        why = f"new rows of {new.shape[1]} lanes, the table's have fewer"
+    if why is not None:
+        raise ValueError(f"sorted_tile_set: {why}")
+    rows, lanes_row = table.shape
+    n, width = new.shape
+    block = BLOCK
+    opened = jnp.zeros((), jnp.int32)
+    if n == 0:
+        return table, opened
+    # as few calls as hold their scalars in SMEM, of equal size in blocks
+    most = _SET_SMEM_WORDS // (2 + width) // block * block
+    calls = -(-n // most)
+    size = -(-n // (calls * block)) * block
+    pad = calls * size - n
+    sorted_ids = jnp.concatenate([
+        sorted_ids.astype(jnp.int32), jnp.full((pad,), _INT32_MAX, jnp.int32)
+    ])
+    new = jnp.pad(new.astype(jnp.float32), ((0, pad), (0, 0)))
+    if not isinstance(table, jax.core.Tracer):
+        table = jnp.copy(table)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(size // block,),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],  # the table stays in HBM
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        scratch_shapes=[
+            pltpu.VMEM((3, block, lanes_row, _TILE_ROWS), jnp.float32),
+            pltpu.SemaphoreType.DMA((2, 3)),
+        ],
+    )
+    call = pl.pallas_call(
+        functools.partial(
+            _tile_set_kernel, block=block, width=width, lanes=size
+        ),
+        out_shape=jax.ShapeDtypeStruct((lanes_row, rows), table.dtype),
+        grid_spec=grid_spec,
+        input_output_aliases={4: 0},  # (tiles, words, counts, vals, table)
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)
+        ),
+        interpret=interpret,
+        name="sorted_row_set_tiles",
+    )
+    view = table.T
+    for lo in range(0, calls * size, size):
+        tiles, words, counts, moved = _tile_set_plan(
+            sorted_ids[lo:lo + size], rows, block
+        )
+        view = call(
+            tiles, words, counts, new[lo:lo + size].T.reshape(-1), view
+        )
+        opened = opened + moved
+    return view.T, opened
+
+
 def scatter_add(
     table: Array,
     ids: Array,
@@ -654,6 +961,6 @@ _scatter_add_jitted = jax.jit(scatter_add, static_argnames=("interpret",))
 
 __all__ = [
     "BLOCK", "MAX_LANES", "note_refusal", "preload", "refusal",
-    "refusal_count", "row_add", "scatter_add", "sort_by_row",
-    "sorted_row_update", "sorted_tile_add", "tile_refusal",
+    "refusal_count", "row_add", "scatter_add", "set_refusal", "sort_by_row",
+    "sorted_row_update", "sorted_tile_add", "sorted_tile_set", "tile_refusal",
 ]

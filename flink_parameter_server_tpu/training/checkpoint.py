@@ -31,10 +31,13 @@ def _ocp():
 def _make_payload(store, worker_state, step, extra):
     # The payload table is in LOGICAL row order: dense stores pass the
     # padded table straight through (zero-copy per-shard save — restore
-    # slices to `capacity`); packed stores unpack first (the physical
-    # 128-lane layout is an on-device detail, not a portable format).
+    # slices to `capacity`); packed stores unpack first, and a narrow rule
+    # store strips its rows' zero lanes (the physical layout is an
+    # on-device detail, not a portable format).
+    spec = store.spec
     table = (
-        store.values() if store.spec.layout == "packed" else store.table
+        store.values() if spec.layout == "packed" or spec.tile_lanes
+        else store.table
     )
     return {
         "table": table,

@@ -24,7 +24,11 @@ import numpy as np
 
 from ..core.batched import BatchedWorkerLogic
 from ..core.store import ShardedParamStore
-from ..core.transform import TransformResult, transform_batched
+from ..core.transform import (
+    TransformResult,
+    jit_train_steps,
+    transform_batched,
+)
 from ..data.streams import prefetch as prefetch_iter
 from ..telemetry.registry import get_registry
 from ..telemetry.spans import NULL_TRACER, get_tracer
@@ -189,6 +193,9 @@ class StreamingDriver:
         self.tracer.annotate_with(jax.profiler.TraceAnnotation)
         self.step_idx = 0
         self._state = None
+        # the jitted programs of (logic, spec), built by the first `run`
+        # and kept: a second run finds its step traced, lowered and loaded
+        self._steps = None
         self._pending_skip = 0
         self._stop_requested = False
         self._serving = None
@@ -331,11 +338,23 @@ class StreamingDriver:
         return True
 
     # -- the loop ----------------------------------------------------------
+    def _jitted_steps(self, spec):
+        """This driver's jitted step (and scanned step), one pair for its
+        logic and ``spec``: a fresh ``jax.jit`` a ``run`` would trace and
+        lower the step again on every run after the first."""
+        if self._steps is None or self._steps[0] != spec:
+            self._steps = (
+                spec,
+                jit_train_steps(self.logic, spec, self.config.steps_per_call),
+            )
+        return self._steps[1]
+
     def _publish_rule_counts(self, outs) -> None:
         """What a rule store's push counted in the dispatch ``outs`` came
-        from (``core/store.push_counted``: its live keys and the distinct
-        rows its rule rewrote), as the gauges ``store_rule_keys`` and
-        ``store_rule_rows``.  A fetch of two scalars, made only where the
+        from (``core/store.push_counted``: its live keys, the distinct rows
+        its rule rewrote and the tiles of 128 rows its write-back moved to
+        do so), as the gauges ``store_rule_keys``, ``store_rule_rows`` and
+        ``store_rule_tiles``.  A fetch of three scalars, made only where the
         outputs are fetched anyway: at the metrics cadence, which syncs the
         step, and once after the loop has ended."""
         if self.registry is None or not isinstance(outs, dict):
@@ -352,6 +371,9 @@ class StreamingDriver:
         )
         self.registry.gauge("store_rule_rows", component="train").set(
             total(outs["ps_rule_rows"])
+        )
+        self.registry.gauge("store_rule_tiles", component="train").set(
+            total(outs["ps_rule_tiles"])
         )
 
     def run(
@@ -620,6 +642,7 @@ class StreamingDriver:
                 steps_per_call=cfg.steps_per_call,
                 tracer=tracer,
                 owns_inputs=True,
+                steps=self._jitted_steps(spec),
             )
         except BaseException:
             # Leave the driver usable: take back what the last dispatch

@@ -301,3 +301,78 @@ def test_with_serving_publishing_snapshots_stay_readable():
         assert len(np.asarray(answer.item_ids)) == 5
     finally:
         service.stop()
+
+
+class _CountingLogic(OnlineMatrixFactorization):
+    """Counts how often its step is TRACED."""
+
+    traced = 0
+
+    def step(self, state, batch, pulled):
+        type(self).traced += 1
+        return super().step(state, batch, pulled)
+
+
+def test_a_drivers_second_run_finds_its_step_traced_and_lowered():
+    """A driver keeps the jitted step it built for its logic and spec, and
+    the loop commits the table before the first dispatch: a driver that
+    runs twice (a benchmark's checked batches, then its window) traces and
+    lowers its step once, not twice and three times.  A direct
+    ``transform_batched`` builds a step of its own each call, as before."""
+    _CountingLogic.traced = 0
+    logic = _CountingLogic(64, 4, updater=SGDUpdater(0.05))
+    store = ShardedParamStore.create(
+        96, (4,), init_fn=ranged_random_factor(0, (4,))
+    )
+    batches = [
+        {k: jax.device_put(v, jax.devices()[0]) for k, v in b.items()}
+        for b in _stream(6)
+    ]  # staged on a device, as a pool is: they commit the step's outputs
+    driver = StreamingDriver(logic, store, config=DriverConfig(dump_model=False))
+    driver.run(iter(batches[:2]))
+    step, scan_step = driver._steps[1]
+    assert scan_step is None and step._cache_size() == 1
+    after_first = np.array(driver.store.values())
+    driver.run(iter(batches[2:]))
+    assert driver._steps[1][0] is step
+    assert _CountingLogic.traced == 1
+    assert step._cache_size() == 1  # one program, not three
+    assert not np.array_equal(np.array(driver.store.values()), after_first)
+    # a logic changed between two direct calls is traced as it then stands
+    for _ in range(2):
+        transform_batched(iter(batches[:1]), logic, driver.store, dump_model=False)
+    assert _CountingLogic.traced == 3
+
+
+def test_a_state_off_the_tables_mesh_is_left_for_jit_to_place():
+    """Under a mesh with no ``dp`` axis to shard it by, MF's user state is
+    an uncommitted array on the default device beside a table committed to
+    the mesh: the loop commits what lies where the table lies and leaves
+    the rest, so the step runs (committing that state to its one device
+    would make ``jit`` refuse the pair), in a direct call and in a driver
+    that runs twice."""
+    from jax.sharding import Mesh
+
+    mesh = Mesh(np.array(jax.devices()[:4]), ("ps",))
+    data = list(_stream(4))
+
+    def parts():
+        store = ShardedParamStore.create(
+            96, (4,), init_fn=ranged_random_factor(0, (4,)), mesh=mesh
+        )
+        return OnlineMatrixFactorization(64, 4, updater=SGDUpdater(0.05)), store
+
+    logic, store = parts()
+    state = logic.init_state(jax.random.PRNGKey(0))
+    assert state.sharding.device_set != store.table.sharding.device_set
+    direct = transform_batched(iter(data), logic, store, dump_model=False)
+    logic, store = parts()
+    driver = StreamingDriver(logic, store, config=DriverConfig(dump_model=False))
+    driver.run(iter(data[:2]))
+    driver.run(iter(data[2:]))
+    np.testing.assert_array_equal(
+        np.array(driver.store.values()), np.array(direct.store.values())
+    )
+    np.testing.assert_array_equal(
+        np.array(driver._state), np.array(direct.worker_state)
+    )

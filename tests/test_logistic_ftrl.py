@@ -172,7 +172,7 @@ def test_logic_and_store_against_the_plain_reference(seed, features, batch, fiel
     # to) and the push drops them
     batches[1]["ids"][::7, -1] = -3
     batches[1]["values"][::7, -1] = 0
-    batches[2]["ids"][::5, -2] = features + 11
+    batches[2]["ids"][::5, -2] = features + 211  # past the padding rows too
     batches[2]["values"][::5, -2] = 0
     store = ShardedParamStore.from_values(jnp.asarray(rows), update=RULE)
     after, outs = _run_batches(store, batches)
@@ -276,6 +276,7 @@ def test_the_driver_publishes_what_the_push_counted():
     gauges = registry.snapshot()
     assert gauges["store_rule_keys"][0]["value"] == live.sum()
     assert gauges["store_rule_rows"][0]["value"] == len(np.unique(last["ids"][live]))
+    assert gauges["store_rule_tiles"][0]["value"] == 0  # XLA wrote the rows
     assert np.isfinite(np.asarray(result.store.values())).all()
     # an add store's step counts nothing and its outputs gain no key
     from flink_parameter_server_tpu.models import factorization_machine as fmm
@@ -285,6 +286,54 @@ def test_the_driver_publishes_what_the_push_counted():
         fmm.FactorizationMachine(fm), fmm.make_store(fm).spec
     ))(fmm.make_store(fm).table, (), batches[0])
     assert set(out) == {"prediction", "loss"}
+
+
+@pytest.mark.parametrize("arm", ["xla", "set_kernel"])
+def test_the_store_holds_w_z_n_at_four_lanes_and_the_step_is_the_same(
+        arm, monkeypatch):
+    """``make_store``'s physical row is four lanes (the fourth zero, never
+    read by the rule, stripped by ``pull`` and ``values()``); a checkpoint
+    of it holds logical rows and restores onto the same table; the step
+    through the kernel's arm (steered, interpreted) leaves the bits XLA's
+    row ``set`` leaves and counts the tiles it moved."""
+    from flink_parameter_server_tpu.core import store as store_mod
+    from flink_parameter_server_tpu.training import checkpoint
+
+    rng = np.random.default_rng(6)
+    features = 700
+    rows = _warm_rows(rng, features)
+    store = ShardedParamStore.from_values(jnp.asarray(rows), update=RULE)
+    assert store.table.shape == (768, 4) and store.spec.tile_lanes == 4
+    assert lf.make_store(features).table.shape == (768, 4)
+    batches = _batches(rng, features, 256, 9, 2)
+    want, want_outs = _run_batches(store, batches)
+    assert all(int(o["ps_rule_tiles"]) == 0 for o in want_outs)
+    if arm == "set_kernel":
+        monkeypatch.setattr(store_mod, "_set_kernel_takes", lambda spec: True)
+        got, outs = _run_batches(store, batches)
+        np.testing.assert_array_equal(
+            np.asarray(got.table), np.asarray(want.table))
+        for o, b in zip(outs, batches):
+            live = b["feat_mask"] & b["mask"][:, None]
+            assert int(o["ps_rule_tiles"]) == len(
+                np.unique(b["ids"][live] // 128))
+            assert int(o["ps_rule_rows"]) == len(np.unique(b["ids"][live]))
+    else:
+        got = want
+    table = np.asarray(got.table)
+    assert not table[:, 3].any() and not table[features:].any()
+    values = np.asarray(got.values())
+    assert values.shape == (features, 3)
+    assert np.array_equal(values, table[:features, :3])
+    assert not np.array_equal(values, rows)
+    ids = np.array([0, 5, features - 1], np.int32)
+    assert np.array_equal(np.asarray(got.pull(jnp.asarray(ids))), values[ids])
+    payload = checkpoint._make_payload(got, (), 3, None)
+    assert payload["table"].shape == (features, 3)
+    back, _, _ = checkpoint._payload_to_state(
+        {**payload, "table": np.asarray(payload["table"])}, got.spec
+    )
+    np.testing.assert_array_equal(np.asarray(back.table), table)
 
 
 def test_a_scanned_dispatch_sums_its_steps_counts():

@@ -471,6 +471,190 @@ def test_a_store_whose_pushes_will_trace_the_kernel_starts_the_pallas_import(
     assert len(calls) == 2 * started
 
 
+# -- a rule's narrow rows: the set kernel --------------------------------------
+SET_ROWS = 128 * 24  # 24 tiles
+SET_CASES = [
+    "sparse", "dense", "a_tile_two_blocks_share", "first_and_last_tile",
+    "every_lane_of_a_tile", "all_dropped", "empty", "several_calls",
+]
+
+
+def _set_case(name, rng):
+    """The kept ids of one named case (ascending, distinct) and how many
+    dropped lanes follow them."""
+    rows = SET_ROWS
+    if name == "sparse":  # a lane or two a tile
+        return np.sort(rng.choice(rows, 40, replace=False)), 7
+    if name == "dense":  # 700 of 3,072 rows: three blocks of lanes
+        return np.sort(rng.choice(rows, 700, replace=False)), 30
+    if name == "a_tile_two_blocks_share":
+        # lanes 0-255 are rows 64-319: the tile of rows 256-383 begins in
+        # the first block of lanes and ends in the second
+        return np.arange(64, 64 + 600), 0
+    if name == "first_and_last_tile":
+        return np.array([0, 5, 127, rows - 128, rows - 2, rows - 1]), 3
+    if name == "every_lane_of_a_tile":
+        return np.concatenate([[3], np.arange(256, 384), [900, 901]]), 1
+    if name == "all_dropped":
+        return np.zeros((0,), np.int64), 300
+    if name == "empty":
+        return np.zeros((0,), np.int64), 0
+    if name == "several_calls":  # and a tile that two calls share
+        return np.arange(100, 100 + 1500), 36
+    raise KeyError(name)
+
+
+@pytest.mark.parametrize("lanes,width,name", [
+    (4, 3, name) for name in SET_CASES
+] + [
+    (lanes, width, name)
+    for lanes, width in [(1, 1), (2, 2), (4, 4), (8, 5), (8, 8)]
+    for name in ("dense", "a_tile_two_blocks_share")
+])
+def test_sorted_tile_set_is_xlas_row_set_bit_for_bit(
+        lanes, width, name, monkeypatch):
+    """``table.at[ids].set(new, mode="drop")`` with the pad lanes zeroed,
+    every bit of the table, and the tiles it moved."""
+    rng = np.random.default_rng([lanes, width, SET_CASES.index(name)])
+    kept, dropped = _set_case(name, rng)
+    ids = np.concatenate([kept, np.full(dropped, SET_ROWS)]).astype(np.int32)
+    table = rng.normal(size=(SET_ROWS, lanes)).astype(np.float32)
+    table[:, width:] = 0
+    new = rng.normal(size=(len(ids), width)).astype(np.float32)
+    new[len(kept):] = np.nan  # a dropped lane's values are never read
+    calls = []
+    if name == "several_calls":
+        import jax.experimental.pallas as pl
+
+        # 512 lanes' scalars a call: three calls of two blocks
+        monkeypatch.setattr(
+            row_update, "_SET_SMEM_WORDS", 512 * (2 + width))
+        real = pl.pallas_call
+        monkeypatch.setattr(
+            pl, "pallas_call",
+            lambda *a, **kw: calls.append(kw["grid_spec"].grid) or real(*a, **kw),
+        )
+    got, tiles = row_update.sorted_tile_set(
+        jnp.asarray(table), jnp.asarray(ids), jnp.asarray(new)
+    )
+    want = jnp.asarray(table).at[ids].set(
+        jnp.pad(jnp.asarray(new), ((0, 0), (0, lanes - width))), mode="drop"
+    )
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    if name == "several_calls":
+        # 1,536 lanes: one kernel of two blocks, called on three stretches
+        assert calls == [(2,)], calls
+        size = 512
+        assert int(tiles) == sum(
+            len(np.unique(kept[lo:lo + size] // 128))
+            for lo in range(0, len(kept), size)
+        )
+    else:
+        assert int(tiles) == len(np.unique(kept // 128))
+
+
+def test_eager_tile_set_leaves_the_callers_table_alone():
+    table = jnp.ones((256, 4), jnp.float32)
+    out, _ = row_update.sorted_tile_set(
+        table, jnp.array([3, 200], jnp.int32), jnp.full((2, 3), 7.0)
+    )
+    assert float(table.sum()) == 1024.0
+    assert np.asarray(out)[3].tolist() == [7.0, 7.0, 7.0, 0.0]
+
+
+@pytest.mark.parametrize("shape,dtype,refused", [
+    ((1024, 4), jnp.float32, None), ((1024, 1), jnp.float32, None),
+    ((1024, 8), jnp.float32, None), ((1024, 4), jnp.bfloat16, "bfloat16"),
+    ((1024, 3), jnp.float32, "(3,)"), ((1024, 16), jnp.float32, "(16,)"),
+    ((1024, 2, 2), jnp.float32, "(2, 2)"), ((1024,), jnp.float32, "()"),
+    ((1000, 4), jnp.float32, "whole tiles"),
+])
+def test_set_refusal_names_what_the_set_kernel_cannot_take(
+        shape, dtype, refused):
+    why = row_update.set_refusal(shape, dtype)
+    assert (why is None) if refused is None else (refused in why)
+    if refused is not None:
+        with pytest.raises(ValueError, match="sorted_tile_set"):
+            row_update.sorted_tile_set(
+                jnp.zeros(shape, dtype), jnp.zeros((4,), jnp.int32),
+                jnp.zeros((4,) + shape[1:], dtype).reshape(4, -1),
+            )
+
+
+def _rule(current, combined):
+    return 0.5 * current + combined
+
+
+@pytest.mark.parametrize("backend,meshed,shape,update,dtype,want", [
+    ("tpu", False, (3,), _rule, jnp.float32, True),  # FTRL's (w, z, n)
+    ("tpu", False, (1,), _rule, jnp.float32, True),
+    ("tpu", False, (8,), _rule, jnp.float32, True),
+    ("tpu", False, (5,), _rule, jnp.float32, True),
+    ("tpu", False, (9,), _rule, jnp.float32, False),
+    ("tpu", False, (128,), _rule, jnp.float32, False),
+    ("tpu", False, (3,), "add", jnp.float32, False),
+    ("cpu", False, (3,), _rule, jnp.float32, False),
+    ("tpu", True, (3,), _rule, jnp.float32, False),
+])
+def test_the_write_back_takes_the_set_kernel_from_what_the_spec_holds(
+        monkeypatch, backend, meshed, shape, update, dtype, want):
+    from flink_parameter_server_tpu.core import store as store_mod
+    from flink_parameter_server_tpu.parallel.mesh import make_mesh
+
+    mesh = make_mesh(worker_parallelism=2, ps_parallelism=2,
+                     devices=jax.devices()[:4]) if meshed else None
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    n0 = row_update.refusal_count()
+    spec = _spec(shape, dtype, update=update, mesh=mesh)
+    with warnings.catch_warnings():
+        warnings.simplefilter("error")
+        assert store_mod._set_kernel_takes(spec) == want
+    assert row_update.refusal_count() == n0
+    if want:  # the physical row is the sublane tile, whole tiles of rows
+        lanes = {1: 1, 3: 4, 5: 8, 8: 8}[shape[0]]
+        assert spec.tile_lanes == lanes and spec.table_shape() == (128, lanes)
+    elif backend == "cpu":  # the physical row is the spec's, not the backend's
+        assert spec.tile_lanes == 4
+
+
+@pytest.mark.parametrize("shape,dtype,reason", [
+    ((3,), jnp.bfloat16, "bfloat16"),
+    ((2, 2), jnp.float32, "(2, 2)"),
+    ((), jnp.float32, "()"),
+])
+def test_a_narrow_rule_store_the_kernel_refuses_warns_once_and_counts(
+        monkeypatch, shape, dtype, reason):
+    from flink_parameter_server_tpu.core import store as store_mod
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(store_mod, "_REFUSALS_NOTED", set())
+    spec = _spec(shape, dtype, update=_rule)
+    assert spec.tile_lanes == 0  # held as it is, XLA's row set
+    n0 = row_update.refusal_count()
+    with pytest.warns(RuntimeWarning, match="falling back") as caught:
+        assert not store_mod._set_kernel_takes(spec)
+    assert reason in str(caught[0].message)
+    assert row_update.refusal_count() == n0 + 1
+    with warnings.catch_warnings():
+        warnings.simplefilter("error")  # every later trace is silent
+        assert not store_mod._set_kernel_takes(spec)
+    assert row_update.refusal_count() == n0 + 1
+
+
+@pytest.mark.parametrize("backend,shape,started", [
+    ("tpu", (3,), 1), ("tpu", (9,), 0), ("cpu", (3,), 0),
+])
+def test_a_rule_store_whose_pushes_will_trace_the_kernel_starts_the_pallas_import(
+        monkeypatch, backend, shape, started):
+    calls = []
+    monkeypatch.setattr(row_update, "preload", lambda: calls.append(1))
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    ShardedParamStore.create(16, shape, update=_rule)
+    assert len(calls) == started
+    ShardedParamStore.from_values(jnp.zeros((16,) + shape), update=_rule)
+    assert len(calls) == 2 * started
+
+
 def test_preload_imports_pallas_off_the_calling_thread():
     import sys
     import threading

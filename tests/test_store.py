@@ -648,8 +648,12 @@ def test_the_rule_arm_is_the_capacity_arm(rule, shape, lanes, masked, sharded, m
         deltas[:, 2] = deltas[:, 0] ** 2
     mask = jnp.asarray(rng.random(lanes) < 0.8) if masked else None
     got = np.asarray(store.push(jnp.asarray(ids), jnp.asarray(deltas), mask).values())
+    # the table as the capacity arm knew it: logical rows, padding rows too
+    rows = np.array(store.table)
+    if store.spec.tile_lanes:
+        rows = rows[:, : store.spec.row_width]
     want = np.asarray(_capacity_arm(
-        store.spec, jnp.asarray(np.array(store.table)), jnp.asarray(ids),
+        store.spec, jnp.asarray(rows), jnp.asarray(ids),
         jnp.asarray(deltas), mask,
     ))[:capacity]
     live = np.ones(lanes, bool) if mask is None else np.asarray(mask)
@@ -683,7 +687,95 @@ def test_a_push_of_no_live_lane_to_a_rule_store_changes_nothing(lanes):
     assert np.array_equal(np.asarray(store.push(*push).values()), before)
     _, counted = store_mod.push_counted(store.spec, store.table, *push)
     assert int(counted["ps_rule_rows"]) == int(counted["ps_rule_keys"]) == 0
+    assert int(counted["ps_rule_tiles"]) == 0
     assert before[1, 0] == 1.0 and before[4, 0] == 0.5  # 0.5 * (1 + 1), 0.5 * 1
+
+
+@pytest.mark.parametrize("width, lanes", [
+    (1, 1), (2, 2), (3, 4), (4, 4), (5, 8), (7, 8), (8, 8), (9, 0), (17, 0),
+])
+def test_a_narrow_rule_row_is_held_at_its_sublane_tile(width, lanes):
+    """``from_values`` -> ``pull`` / ``values()`` round trip of a rule store
+    whose physical row carries zero lanes, and ``create`` the same."""
+    rng = np.random.default_rng(width)
+    capacity = 300
+    values = rng.normal(size=(capacity, width)).astype(np.float32)
+    store = ShardedParamStore.from_values(jnp.asarray(values), update=_ema)
+    spec = store.spec
+    assert spec.tile_lanes == lanes
+    if lanes:  # whole tiles of 128 rows, the row's lanes then zeros
+        assert spec.padded_capacity == 384
+        assert store.table.shape == (384, lanes)
+        table = np.asarray(store.table)
+        assert np.array_equal(table[:capacity, :width], values)
+        assert not table[capacity:].any() and not table[:, width:].any()
+    else:  # as every dense table: the row as it is, tiles of 8 rows
+        assert store.table.shape == (304, width)
+    assert np.array_equal(np.asarray(store.values()), values)
+    ids = np.array([[0, 299, 7], [7, 150, 298]], np.int32)
+    assert np.array_equal(np.asarray(store.pull(jnp.asarray(ids))), values[ids])
+    made = ShardedParamStore.create(
+        capacity, (width,), init_fn=lambda i: jnp.asarray(values)[i % capacity],
+        update=_ema,
+    )
+    assert made.table.shape == store.table.shape
+    assert np.array_equal(np.asarray(made.values()), values)
+    pushed = made.push(jnp.asarray(ids), jnp.ones(ids.shape + (width,)))
+    got = np.asarray(pushed.values())
+    assert np.array_equal(got[7], 0.5 * values[7] + 1.0)  # two deltas summed
+    assert np.array_equal(got[150], 0.5 * values[150] + 0.5)
+    if lanes:
+        assert not np.asarray(pushed.table)[:, width:].any()
+    # a store under a mesh, or of another dtype, holds its rows as they are
+    assert ShardedParamStore.from_values(
+        jnp.asarray(values, jnp.bfloat16), update=_ema
+    ).spec.tile_lanes == 0
+    assert ShardedParamStore.from_values(
+        jnp.asarray(values)
+    ).spec.tile_lanes == 0
+
+
+@pytest.mark.parametrize("rule, width, lanes, masked", [
+    ("ema", 1, 300, True), ("ema", 2, 900, False), ("ftrl", 3, 400, True),
+    ("ftrl", 3, 40_000, False),  # more than one chunk of the rule's loop
+    ("ema", 4, 700, True), ("ema", 5, 1200, True), ("ema", 8, 600, False),
+])
+def test_the_set_kernel_arm_is_the_xla_arm_bit_for_bit(
+        rule, width, lanes, masked, monkeypatch):
+    """The rule store's push with the write-back steered through
+    ``ops/row_update.sorted_tile_set`` (interpreted: this is a CPU) against
+    the same push with XLA's row ``set``: every bit of the table, the same
+    counts, and the tiles the kernel moved."""
+    from flink_parameter_server_tpu.core import store as store_mod
+
+    rng = np.random.default_rng([width, lanes])
+    update = _ema if rule == "ema" else _ftrl()
+    capacity = 900 if lanes < 1000 else 30_000
+    values = rng.normal(size=(capacity, width)).astype(np.float32)
+    if rule == "ftrl":
+        values[:, 2] = np.abs(values[:, 2]) * 30
+    store = ShardedParamStore.from_values(jnp.asarray(values), update=update)
+    ids = rng.integers(-3, capacity + 200, lanes).astype(np.int32)
+    ids[: lanes // 4] = 5                          # one hot row
+    deltas = rng.normal(size=(lanes, width)).astype(np.float32)
+    mask = jnp.asarray(rng.random(lanes) < 0.8) if masked else None
+    args = (jnp.asarray(ids), jnp.asarray(deltas), mask)
+    assert not store_mod._set_kernel_takes(store.spec)  # this is a CPU
+    want, counted = store_mod.push_counted(store.spec, store.table, *args)
+    assert int(counted["ps_rule_tiles"]) == 0
+    monkeypatch.setattr(store_mod, "_set_kernel_takes", lambda spec: True)
+    got, kernel_counted = store_mod.push_counted(store.spec, store.table, *args)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    for name in ("ps_rule_keys", "ps_rule_rows"):
+        assert int(kernel_counted[name]) == int(counted[name])
+    live = np.ones(lanes, bool) if mask is None else np.asarray(mask)
+    kept = np.unique(ids[live & (ids >= 0) & (ids < store.spec.padded_capacity)])
+    chunk = store_mod._RULE_CHUNK  # a tile two chunks share is moved twice
+    assert int(kernel_counted["ps_rule_tiles"]) == sum(
+        len(np.unique(kept[lo:lo + chunk] // 128))
+        for lo in range(0, len(kept), chunk)
+    )
+    assert int(kernel_counted["ps_rule_tiles"]) > 0
 
 
 @pytest.mark.parametrize("width", [1, 3, 8, 9, 17])
