@@ -910,6 +910,21 @@ def _pad_to_tiles(values: Array, rows: int, lanes: int) -> Array:
     return jnp.pad(values, ((0, rows - n), (0, lanes - w)))
 
 
+def _placing():
+    """A store's construction on the device as ``setup.store_place`` on the
+    process's ledger (host time inside the call: the jitted placements are
+    enqueued, not waited for), its seconds kept in
+    ``setup_store_place_seconds_total`` (``telemetry/compile_ledger.setup_span``;
+    the benchmark's ``setup.store_place_s``).  A ``create`` under
+    ``jax.eval_shape`` or ``jax.jit`` counts the time it took to trace."""
+    from ..telemetry.compile_ledger import setup_span
+    from ..telemetry.registry import get_registry
+
+    return setup_span("store_place", get_registry().counter(
+        "setup_store_place_seconds_total", component="setup"
+    ))
+
+
 @jax.tree_util.register_pytree_node_class
 class ShardedParamStore:
     """Functional bundle of (spec, table).  All mutators return new stores.
@@ -945,7 +960,8 @@ class ShardedParamStore:
             ps_axis=ps_axis,
             layout=_resolve_layout(layout, update, tuple(value_shape)),
         )
-        return cls(spec, create_table(spec, init_fn))
+        with _placing():
+            return cls(spec, create_table(spec, init_fn))
 
     @classmethod
     def from_values(
@@ -969,7 +985,8 @@ class ShardedParamStore:
             ps_axis=ps_axis,
             layout=_resolve_layout(layout, update, tuple(values.shape[1:])),
         )
-        return cls(spec, cls._place(spec, values))
+        with _placing():
+            return cls(spec, cls._place(spec, values))
 
     @classmethod
     def from_spec_values(
@@ -979,7 +996,8 @@ class ShardedParamStore:
         mesh, layout) from an unpadded ``(capacity, ...)``
         value array — the checkpoint-restore path, which must not drop
         spec fields the way a shape-inferred rebuild would."""
-        return cls(spec, cls._place(spec, values.astype(spec.dtype)))
+        with _placing():
+            return cls(spec, cls._place(spec, values.astype(spec.dtype)))
 
     @staticmethod
     def _place(spec: StoreSpec, values: Array) -> Array:

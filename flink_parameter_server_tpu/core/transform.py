@@ -42,6 +42,7 @@ from .batched import BatchedWorkerLogic
 from .entities import Pull, PullAnswer, Push, PSToWorker, WorkerToPS
 from .store import ShardedParamStore
 from ..parallel.mesh import DP_AXIS
+from ..telemetry.compile_ledger import setup_span
 from ..telemetry.spans import NULL_TRACER, SpanTracer
 from ..training.tracing import scope
 
@@ -551,9 +552,11 @@ def transform_batched(
     # committed from the first dispatch on, as the step's own outputs are
     # (a batch staged on a device commits them): an uncommitted table or
     # state makes the first dispatch a program of its own, lowered and
-    # loaded once more (no copy: the array keeps its buffers)
-    table, commit = _committed_where(keep(store.table))
-    state = jax.tree.map(commit, state)
+    # loaded once more (no copy: the array keeps its buffers; the ledger's
+    # `setup.commit` holds that claim)
+    with setup_span("commit"):
+        table, commit = _committed_where(keep(store.table))
+        state = jax.tree.map(commit, state)
     worker_outputs: List[Any] = []
     step_idx = 0
 

@@ -159,17 +159,51 @@ def note_refusal(what: str, why: str) -> None:
     )
 
 
+_PRELOAD = threading.Lock()  # held from the first `preload` on
+_PALLAS = None  # (pl, pltpu) once a kernel has asked for them
+
+
+def _import_pallas() -> None:
+    from ..telemetry.compile_ledger import setup_span
+
+    with setup_span("kernel_import"):
+        importlib.import_module("jax.experimental.pallas.tpu")
+
+
 def preload() -> None:
     """Import Pallas on a thread, for a caller that knows a step of its is
     going to trace the kernel.  The import is about a second (most of it
     Mosaic GPU, which JAX loads for its own interpreter) that would else
     fall into that first trace; begun where a job builds its logic it runs
     beside the staging of tables and batches (0.6 s of a warm set-up on
-    the v5e's machine: PERF.md section 6, PR 27)."""
-    threading.Thread(
-        target=importlib.import_module, args=("jax.experimental.pallas.tpu",),
-        name="pallas-import", daemon=True,
-    ).start()
+    the v5e's machine: PERF.md section 6, PR 27).  The first call starts
+    the thread, later ones do nothing; the import is the ledger's event
+    ``setup.kernel_import`` (a span for an export: on a thread beside the
+    staging its length is no cost of set-up's)."""
+    if _PRELOAD.acquire(blocking=False):
+        threading.Thread(
+            target=_import_pallas, name="pallas-import", daemon=True
+        ).start()
+
+
+def _pallas():
+    """``(pl, pltpu)``.  The first kernel to ask waits here for the import
+    :func:`preload` began, or pays for all of it: ``setup.kernel_import_wait``,
+    and the seconds of ``setup_kernel_import_seconds_total``, which is what
+    of the import lands in set-up's time (trace time only; a dispatch never
+    comes here)."""
+    global _PALLAS
+    if _PALLAS is None:
+        from ..telemetry.compile_ledger import setup_span
+        from ..telemetry.registry import get_registry
+
+        with setup_span("kernel_import_wait", get_registry().counter(
+            "setup_kernel_import_seconds_total", component="setup"
+        )):
+            import jax.experimental.pallas as pl
+            from jax.experimental.pallas import tpu as pltpu
+        _PALLAS = (pl, pltpu)
+    return _PALLAS
 
 
 def sort_by_row(ids: Array, keep: Optional[Array], rows: int):
@@ -209,8 +243,7 @@ def _kernel(tgt_ref, src_ref, writes_ref, aux_ref, old_ref, dl_ref, state_ref,
       block's writes stay in flight while the next block fills the other.
     carry_ref: (1, W) f32 VMEM — prefix sum of the run open at a block's end.
     """
-    import jax.experimental.pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
+    pl, pltpu = _pallas()
 
     del state_ref  # aliased to out_ref: untouched rows keep their values
     b = pl.program_id(0)
@@ -345,8 +378,7 @@ def sorted_row_update(
     eager call copies it first.  Off the TPU the kernel is interpreted
     (``interpret=None``: by the default backend).
     """
-    import jax.experimental.pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
+    pl, pltpu = _pallas()
 
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
@@ -482,8 +514,7 @@ def _tile_kernel(tiles_ref, words_ref, counts_ref, dl_ref, table_ref, out_ref,
     table_ref / out_ref: the aliased (rows, W) table in HBM.
     tile_buf: (block, 8, W) f32 VMEM — the block's tile rows.
     """
-    import jax.experimental.pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
+    pl, pltpu = _pallas()
 
     del table_ref  # aliased to out_ref
     b = pl.program_id(0)
@@ -590,8 +621,7 @@ def sorted_tile_add(
     jit donates the table; an eager call copies it first.  Off the TPU the
     kernel is interpreted.
     """
-    import jax.experimental.pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
+    pl, pltpu = _pallas()
 
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
@@ -713,8 +743,7 @@ def _tile_set_kernel(tiles_ref, words_ref, counts_ref, vals_ref, table_ref,
       read, one being set, one being written back.
     sem: (2, 3) DMA semaphores — reads and writes of each slot.
     """
-    import jax.experimental.pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
+    pl, pltpu = _pallas()
 
     del table_ref  # aliased to out_ref
     b, blocks = pl.program_id(0), pl.num_programs(0)
@@ -868,8 +897,7 @@ def sorted_tile_set(
     donates the table; an eager call copies it first.  Off the TPU the
     kernel is interpreted.
     """
-    import jax.experimental.pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
+    pl, pltpu = _pallas()
 
     if interpret is None:
         interpret = jax.default_backend() != "tpu"

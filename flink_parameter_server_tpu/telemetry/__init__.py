@@ -10,6 +10,10 @@ through, and the seam every later perf PR is judged through:
   * :mod:`.spans` — nestable wall-clock spans, ring-buffered, Chrome
     trace-event export; the HOST-side complement of
     ``training/tracing.py``'s device-side ``jax.named_scope``.
+  * :mod:`.compile_ledger` — set-up on the program's books: JAX's own
+    monitoring events (trace, lowering, compile or cache load) counted
+    and recorded by program, a recompile inside a warm run named, and
+    ``setup_span`` for set-up's own work.
   * :mod:`.exporter` — Prometheus-text rendering + the TCP
     ``/metrics`` / ``/healthz`` endpoint (live during training).
   * :mod:`.report` — ``results/<platform>/run_report.{md,json}``.

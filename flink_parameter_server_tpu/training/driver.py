@@ -15,6 +15,7 @@ loop implementation, hooked — not duplicated):
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from typing import Iterable, Optional
 
@@ -30,6 +31,7 @@ from ..core.transform import (
     transform_batched,
 )
 from ..data.streams import prefetch as prefetch_iter
+from ..telemetry import compile_ledger
 from ..telemetry.registry import get_registry
 from ..telemetry.spans import NULL_TRACER, get_tracer
 from . import checkpoint as ckpt
@@ -191,6 +193,11 @@ class StreamingDriver:
             ).set(store.spec.layout == "packed")
         # spans open on the profiler's clock too: this code owns a device
         self.tracer.annotate_with(jax.profiler.TraceAnnotation)
+        if self.config.telemetry:
+            # what this job traces, lowers, compiles and loads is counted by
+            # program from here at the latest (a caller that enabled the
+            # compile cache is counting already: this does nothing then)
+            compile_ledger.install()
         self.step_idx = 0
         self._state = None
         # the jitted programs of (logic, spec), built by the first `run`
@@ -494,6 +501,13 @@ class StreamingDriver:
                 # inter-run idle time into the latency window) — count,
                 # don't time
                 first_step_of_run[0] = False
+                if cfg.telemetry:
+                    # from here on a step built again stalls a warm
+                    # stream: the ledger counts and names it
+                    # (compiles_in_run_total)
+                    books.enter_context(compile_ledger.get_ledger().warm_run(
+                        s.__name__ for s in steps if s is not None
+                    ))
                 self.metrics.count_untimed(n_steps, events)
                 self.metrics.step_start()
             else:
@@ -627,6 +641,14 @@ class StreamingDriver:
         handed_state, self._state = self._state, None
         live = [handed.table, handed_state]
         last_outs = [None]  # the newest dispatch's outputs, never synced here
+        steps = self._jitted_steps(spec)
+        books = contextlib.ExitStack()
+        if cfg.telemetry:
+            # what is traced, lowered, compiled or loaded while this run
+            # lasts is a record on its tracer too (compile.*, setup.*)
+            books.enter_context(
+                compile_ledger.get_ledger().spans_to(self.tracer)
+            )
 
         try:
             result = transform_batched(
@@ -642,7 +664,7 @@ class StreamingDriver:
                 steps_per_call=cfg.steps_per_call,
                 tracer=tracer,
                 owns_inputs=True,
-                steps=self._jitted_steps(spec),
+                steps=steps,
             )
         except BaseException:
             # Leave the driver usable: take back what the last dispatch
@@ -657,6 +679,7 @@ class StreamingDriver:
                 self.resume()
             raise
         finally:
+            books.close()
             if prev_handlers:
                 import signal as _signal
 

@@ -8,6 +8,10 @@ it must not move between runs: it is either where
 ``JAX_COMPILATION_CACHE_DIR`` says (JAX reads that variable itself —
 nothing here touches the setting then) or ``<checkout>/.jax_cache``,
 computed from this file's location.
+
+Being every entry point's first call, it is also where the program's
+compile ledger starts listening (``telemetry/compile_ledger.py``): every
+trace, lowering, compile and cache load from here on is counted by program.
 """
 from __future__ import annotations
 
@@ -27,6 +31,9 @@ DEFAULT_CACHE_DIR = os.path.join(
 
 def enable_compile_cache() -> str:
     """Turn the persistent compilation cache on and return its directory."""
+    from ..telemetry import compile_ledger
+
+    compile_ledger.install()
     if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         jax.config.update("jax_compilation_cache_dir", DEFAULT_CACHE_DIR)
     # keep small programs too: the defaults skip anything that compiled

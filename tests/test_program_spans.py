@@ -287,9 +287,12 @@ def test_every_program_span_opened_its_annotation(served_run):
     assert entered == exited
     recorded = collections.Counter(
         f"fps.{s['component']}.{s['name']}" for s in served_run["spans"]
-        if s["name"] != "queue_wait"  # record(): host clock only
+        # record(): host clock only (the waits; the compile ledger's books)
+        if s["name"] != "queue_wait"
+        and s["component"] not in ("compile", "setup")
     )
     assert entered == recorded
+    assert not [n for n in entered if n.startswith(("fps.compile.", "fps.setup."))]
     assert {
         "fps.train.batch_wait", "fps.train.pull_compute_push",
         "fps.train.hooks", "fps.train.publish", "fps.train.publish_enqueue",

@@ -101,7 +101,7 @@ KNOWN_COMPONENTS = frozenset(
      "serving_dispatch", "elastic", "slo", "profiler", "net",
      "replication", "nemesis", "hotcache", "loadgen", "compression",
      "workloads", "shmem", "meshstore", "timeline", "adaptive",
-     "tierstore"}
+     "tierstore", "compile", "setup"}
 )
 
 
