@@ -12,8 +12,10 @@ TPU-first: one store row per word holds ``(2, dim)`` — slot 0 the input
 gather fetches everything a pair needs.  A microbatch of B pairs with N
 negatives pulls ``(B, N+2)`` rows, computes the loss/gradients as fused
 batched matvecs, and pushes one ``(B, N+2, 2, dim)`` scatter-add (zeros in
-the untouched slot).  Negative sampling happens host-side in the data
-stream (unigram^0.75), or on-device via ``sample_negatives``.
+the untouched slot: the step selects each key's one live slot against
+zeros as it writes the row, it fills no zeroed block).  Negative sampling
+happens host-side in the data stream (unigram^0.75), or on-device via
+``sample_negatives``.
 
 How the rows lie on the chip is the store's to decide
 (``core/store._resolve_layout``, which ``make_store`` asks by default): a
@@ -100,17 +102,31 @@ class SkipGramNS(BatchedWorkerLogic):
             lane_mask = jnp.broadcast_to(mask[:, None], (B, N + 2))
 
         with scope("ps.delta_build"):
-            # one (2, d) delta a pulled row: zeros in the slot it leaves
-            deltas = jnp.zeros((B, N + 2, 2, d), v.dtype)
-            deltas = deltas.at[:, 0, IN].set(-lr * d_v)
-            deltas = deltas.at[:, 1, OUT].set(-lr * d_upos)
-            deltas = deltas.at[:, 2:, OUT].set(-lr * d_uneg)
+            # one (2, d) delta a pulled row, written once.  A key has one
+            # live slot (IN the centre's, OUT every other's), so the
+            # gradients lie side by side in one compact (B, N+2, d) array,
+            # take the combiner's scale there, and only then meet their
+            # zeros.  (A zeroed block with the gradients set into it is
+            # the same values in four strided passes on the TPU.)
+            grads = jnp.concatenate(
+                [(-lr * d_v)[:, None], (-lr * d_upos)[:, None], -lr * d_uneg],
+                axis=1,
+            )
             if self.dedup_scale:
                 from ..ops.dedup import occurrence_scale
 
                 keys = self.keys(batch)
                 scale = occurrence_scale(keys, self.vocab_size, lane_mask)
-                deltas = deltas * scale[..., None, None]
+                grads = grads * scale[..., None]
+            is_centre = (jnp.arange(N + 2) == 0)[None, :, None]
+            zeros = jnp.zeros_like(grads)
+            deltas = jnp.concatenate(
+                [
+                    jnp.where(is_centre, grads, zeros),  # IN
+                    jnp.where(is_centre, zeros, grads),  # OUT
+                ],
+                axis=-1,
+            ).reshape(B, N + 2, 2, d)
 
         loss = -(
             jax.nn.log_sigmoid(pos_logit)

@@ -251,6 +251,72 @@ def test_sgns_step_on_the_stores_own_layout_is_the_plain_arithmetic(dim, lanes):
         assert (np.asarray(table)[:, -k_pad:] == 0).all()  # the padding lanes
 
 
+def _sgns_plain_deltas(logic, batch, pulled):
+    """``SkipGramNS.step``'s gradients, then the deltas assembled the plain
+    way: a zeroed block, the three groups set into their slots, the whole
+    block times the combiner's scale."""
+    from flink_parameter_server_tpu.models.word2vec import OUT
+    from flink_parameter_server_tpu.ops.dedup import occurrence_scale
+
+    lr = logic.learning_rate
+    v, u_pos, u_neg = pulled[:, 0, IN], pulled[:, 1, OUT], pulled[:, 2:, OUT]
+    g_pos = jax.nn.sigmoid(jnp.sum(v * u_pos, axis=-1)) - 1.0
+    g_neg = jax.nn.sigmoid(jnp.einsum("bd,bnd->bn", v, u_neg))
+    d_v = g_pos[:, None] * u_pos + jnp.einsum("bn,bnd->bd", g_neg, u_neg)
+    d_upos = g_pos[:, None] * v
+    d_uneg = g_neg[..., None] * v[:, None, :]
+    deltas = jnp.zeros(pulled.shape, v.dtype)
+    deltas = deltas.at[:, 0, IN].set(-lr * d_v)
+    deltas = deltas.at[:, 1, OUT].set(-lr * d_upos)
+    deltas = deltas.at[:, 2:, OUT].set(-lr * d_uneg)
+    if logic.dedup_scale:
+        mask = batch.get("mask")
+        keys = logic.keys(batch)
+        if mask is not None:
+            mask = jnp.broadcast_to(mask[:, None], keys.shape)
+        deltas = deltas * occurrence_scale(keys, logic.vocab_size, mask)[..., None, None]
+    return deltas
+
+
+@pytest.mark.parametrize("dim", [300, 8])
+@pytest.mark.parametrize("with_mask", [True, False])
+@pytest.mark.parametrize("dedup_scale", [True, False])
+def test_sgns_step_assembles_the_plain_deltas_bit_for_bit(
+        dedup_scale, with_mask, dim):
+    """The deltas a step pushes are the plain assembly's to the bit, the
+    signs of the zeros in the slot a key leaves included: duplicate keys in
+    the batch (so the mean combiner's scale is not 1), a masked pair, a
+    gradient that is -0.0 (a centre whose pulled output rows are +0.0)."""
+    from flink_parameter_server_tpu.models.word2vec import OUT, SkipGramNS
+
+    vocab, batch, negs = 64, 96, 5
+    rng = np.random.default_rng(dim + 2 * with_mask + dedup_scale)
+    ids = (rng.zipf(1.3, (batch, negs + 2)) % vocab).astype(np.int32)
+    b = {"center": ids[:, 0], "context": ids[:, 1], "negatives": ids[:, 2:]}
+    if with_mask:
+        b["mask"] = np.arange(batch) != 7
+    pulled = rng.normal(0.0, 0.3, (batch, negs + 2, 2, dim)).astype(np.float32)
+    pulled[3, 1:] = 0.0  # d_v of pair 3 is (-0.5) * 0 + 0.5 * 0: -lr * it is -0.0
+    b = {k: jnp.asarray(x) for k, x in b.items()}
+    logic = SkipGramNS(0.025, dedup_scale=dedup_scale, vocab_size=vocab)
+    _, req, _ = jax.jit(lambda b, p: logic.step((), b, p))(b, pulled)
+    want = jax.jit(lambda b, p: _sgns_plain_deltas(logic, b, p))(b, pulled)
+    assert req.deltas.shape == (batch, negs + 2, 2, dim)
+    assert req.deltas.dtype == jnp.float32 and req.ids.shape == (batch, negs + 2)
+    assert (req.mask is None) == (not with_mask)
+    got, want = np.asarray(req.deltas), np.asarray(want)
+    assert np.signbit(got[3, 0, IN]).all() and not np.signbit(got[3, 0, OUT]).any()
+    if dedup_scale:  # the scale did scale: a word named twice and more
+        assert len(np.unique(ids)) < ids.size
+    np.testing.assert_array_equal(got.view(np.uint32), want.view(np.uint32))
+    # and called eagerly, op by op
+    _, req, _ = logic.step((), b, jnp.asarray(pulled))
+    np.testing.assert_array_equal(
+        np.asarray(req.deltas).view(np.uint32),
+        np.asarray(_sgns_plain_deltas(logic, b, jnp.asarray(pulled))).view(np.uint32),
+    )
+
+
 def test_make_store_takes_a_dtype_and_a_traced_seed():
     from flink_parameter_server_tpu.models.word2vec import OUT, make_store
 
