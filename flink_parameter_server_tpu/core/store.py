@@ -297,7 +297,10 @@ def _create_packed(spec: StoreSpec, init_fn: InitFn) -> Callable[[], Array]:
 def pull(spec: StoreSpec, table: Array, ids: Array) -> Array:
     """Batched pull: ``values[i] = table[ids[i]]`` (sharded gather).
 
-    Out-of-range ids are clipped (callers use a validity mask alongside).
+    Out-of-range ids are clipped (callers use a validity mask alongside):
+    a DEAD lane, which by convention carries id -1 (the padding of a ragged
+    key bag: ``models/fasttext.py``), reads row 0, and its logic masks what
+    it reads.
     Packed layout: one gather of whole 128-lane physical rows, then the
     lane slice as ``k`` static slices chosen by a ``select`` on
     ``id % k`` (no per-element gather — see ops/packed.py); under a mesh
@@ -360,7 +363,13 @@ def push(
     ``mask`` (same leading shape as ``ids``) zeroes out padding lanes — the
     jit-friendly replacement for the reference's variable-length message
     batches (SURVEY.md §7 "Dynamic shapes").  Out-of-range ids are dropped
-    (``mode="drop"``).  ``update="add"`` is one scatter-add of the batch,
+    (``mode="drop"``), and that is what a DEAD lane is: by convention it
+    carries id -1, every negative id is routed to the sentinel one past the
+    table, and both arms drop it there whatever its delta holds (XLA's
+    ``mode="drop"``; the tile kernel sorts it to the end of the batch and
+    neither reads its delta nor opens a tile for it), so it moves nothing
+    and a rule store's counts pass it by.  A masked lane that keeps a live
+    id is added as a zero.  ``update="add"`` is one scatter-add of the batch,
     duplicates and all, in the arm :func:`_tile_kernel_takes` reads from the
     spec: ONE XLA scatter-add, which sums the deltas of a row in the order
     the batch holds them (part of what the benchmark's reference checks;

@@ -42,6 +42,39 @@ Array = jax.Array
 IN, OUT = 0, 1  # slots in the (2, dim) store row
 
 
+def sgns_gradients(v: Array, u_pos: Array, u_neg: Array):
+    """The SGNS arithmetic of a microbatch, shared by every logic whose
+    pairs meet one hidden vector with one positive and ``N`` negative
+    output vectors (``SkipGramNS``: the centre's input vector;
+    ``models/fasttext.FastTextSkipGram``: the average of a bag's).
+
+    ``v`` (B, d), ``u_pos`` (B, d), ``u_neg`` (B, N, d) ->
+    ``(pos_logit, neg_logit, d_v, d_upos, d_uneg)``: the logits and the
+    loss's gradients by ``v`` and by each output vector (maximise
+    ``log sigmoid(pos) + sum log sigmoid(-neg)``)."""
+    pos_logit = jnp.sum(v * u_pos, axis=-1)  # (B,)
+    neg_logit = jnp.einsum("bd,bnd->bn", v, u_neg)  # (B, N)
+    # SGNS: maximize log σ(pos) + Σ log σ(-neg)
+    g_pos = jax.nn.sigmoid(pos_logit) - 1.0  # dL/d(pos_logit)
+    g_neg = jax.nn.sigmoid(neg_logit)  # dL/d(neg_logit)
+
+    d_v = g_pos[:, None] * u_pos + jnp.einsum("bn,bnd->bd", g_neg, u_neg)
+    d_upos = g_pos[:, None] * v
+    d_uneg = g_neg[..., None] * v[:, None, :]  # (B, N, d)
+    return pos_logit, neg_logit, d_v, d_upos, d_uneg
+
+
+def sgns_loss(pos_logit: Array, neg_logit: Array, mask=None) -> Array:
+    """Per-pair SGNS loss (B,), zero where ``mask`` is false."""
+    loss = -(
+        jax.nn.log_sigmoid(pos_logit)
+        + jnp.sum(jax.nn.log_sigmoid(-neg_logit), axis=-1)
+    )
+    if mask is not None:
+        loss = loss * mask
+    return loss
+
+
 class SkipGramNS(BatchedWorkerLogic):
     """Batch: ``center`` (B,), ``context`` (B,), ``negatives`` (B, N),
     ``mask`` (B,) — produces per-pair SGNS loss and sparse pushes.
@@ -83,16 +116,9 @@ class SkipGramNS(BatchedWorkerLogic):
         v = pulled[:, 0, IN]  # (B, d) center input embedding
         u_pos = pulled[:, 1, OUT]  # (B, d) context output embedding
         u_neg = pulled[:, 2:, OUT]  # (B, N, d)
-
-        pos_logit = jnp.sum(v * u_pos, axis=-1)  # (B,)
-        neg_logit = jnp.einsum("bd,bnd->bn", v, u_neg)  # (B, N)
-        # SGNS: maximize log σ(pos) + Σ log σ(-neg)
-        g_pos = jax.nn.sigmoid(pos_logit) - 1.0  # dL/d(pos_logit)
-        g_neg = jax.nn.sigmoid(neg_logit)  # dL/d(neg_logit)
-
-        d_v = g_pos[:, None] * u_pos + jnp.einsum("bn,bnd->bd", g_neg, u_neg)
-        d_upos = g_pos[:, None] * v
-        d_uneg = g_neg[..., None] * v[:, None, :]  # (B, N, d)
+        pos_logit, neg_logit, d_v, d_upos, d_uneg = sgns_gradients(
+            v, u_pos, u_neg
+        )
 
         B, d = v.shape
         N = u_neg.shape[1]
@@ -128,13 +154,7 @@ class SkipGramNS(BatchedWorkerLogic):
                 axis=-1,
             ).reshape(B, N + 2, 2, d)
 
-        loss = -(
-            jax.nn.log_sigmoid(pos_logit)
-            + jnp.sum(jax.nn.log_sigmoid(-neg_logit), axis=-1)
-        )
-        if mask is not None:
-            loss = loss * mask
-        out = {"loss": loss}
+        out = {"loss": sgns_loss(pos_logit, neg_logit, mask)}
         return state, PushRequest(self.keys(batch), deltas, lane_mask), out
 
 
@@ -201,4 +221,7 @@ def train_skipgram(
     )
 
 
-__all__ = ["SkipGramNS", "make_store", "sample_negatives", "train_skipgram", "IN", "OUT"]
+__all__ = [
+    "SkipGramNS", "make_store", "sample_negatives", "sgns_gradients",
+    "sgns_loss", "train_skipgram", "IN", "OUT",
+]

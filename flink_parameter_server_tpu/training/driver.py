@@ -356,23 +356,34 @@ class StreamingDriver:
             )
         return self._steps[1]
 
-    def _publish_rule_counts(self, outs) -> None:
-        """What a rule store's push counted in the dispatch ``outs`` came
-        from (``core/store.push_counted``: its live keys, the distinct rows
-        its rule rewrote and the tiles of 128 rows its write-back moved to
-        do so), as the gauges ``store_rule_keys``, ``store_rule_rows`` and
-        ``store_rule_tiles``.  A fetch of three scalars, made only where the
+    def _publish_step_counts(self, outs) -> None:
+        """What the step counted on the device in the dispatch ``outs``
+        came from, as gauges: a rule store's push (``core/store.push_counted``:
+        its live keys, the distinct rows its rule rewrote and the tiles of
+        128 rows its write-back moved to do so: ``store_rule_keys``,
+        ``store_rule_rows``, ``store_rule_tiles``) and a logic of ragged key
+        bags (the live lanes of its keys and all of them: ``bag_live_keys``,
+        ``bag_padded_keys``).  A fetch of a few scalars, made only where the
         outputs are fetched anyway: at the metrics cadence, which syncs the
         step, and once after the loop has ended."""
         if self.registry is None or not isinstance(outs, dict):
-            return
-        if "ps_rule_rows" not in outs:
             return
 
         def total(x) -> float:  # a scanned dispatch stacks its steps' counts
             return float(np.sum(np.asarray(x)))
 
         # literal names: tools/fpsanalyze matches them to the docs' catalog
+        if "bag_live_keys" in outs:
+            # a logic of ragged key bags (models/fasttext.py) counts the
+            # live lanes of its keys and all of them, from its lane mask
+            self.registry.gauge("bag_live_keys", component="train").set(
+                total(outs["bag_live_keys"])
+            )
+            self.registry.gauge("bag_padded_keys", component="train").set(
+                total(outs["bag_padded_keys"])
+            )
+        if "ps_rule_rows" not in outs:
+            return
         self.registry.gauge("store_rule_keys", component="train").set(
             total(outs["ps_rule_keys"])
         )
@@ -570,7 +581,7 @@ class StreamingDriver:
                         step=global_step,
                     )
             if crossed(cfg.metrics_every):
-                self._publish_rule_counts(outs)
+                self._publish_step_counts(outs)
                 self.metrics.emit(self.metrics_sink)
                 if self._serving is not None:
                     self._serving.metrics.emit(self.metrics_sink)
@@ -696,7 +707,7 @@ class StreamingDriver:
 
         self.store = result.store
         self._state = result.worker_state
-        self._publish_rule_counts(last_outs[0])
+        self._publish_step_counts(last_outs[0])
         if self._serving is not None:
             # close-time publish: post-run queries answer from the FINAL
             # table (the serve-path analogue of the §3.5 model flush)

@@ -62,6 +62,11 @@ def _case(name):
         poison = ~mask
     elif name == "every_id_equal":
         ids[:] = 513
+    elif name == "most_lanes_dead":
+        # a ragged batch's padding: id -1 on most lanes, whole blocks and
+        # (at 512 lanes a call) whole calls of nothing but dropped lanes
+        ids[rng.random(n) < 0.6] = -1
+        poison = ids < 0
     elif name == "batch_not_a_multiple_of_block":
         ids = ids[:1000]
         poison = poison[:1000]
@@ -73,7 +78,7 @@ def _case(name):
 CASES = [
     "uniform_few_duplicates", "hit_1_2_1000_times", "masked_lanes_carry_nan",
     "ids_out_of_range_dropped", "rows_not_a_multiple_of_8", "all_masked",
-    "every_id_equal", "batch_not_a_multiple_of_block",
+    "every_id_equal", "batch_not_a_multiple_of_block", "most_lanes_dead",
 ]
 
 
@@ -217,7 +222,7 @@ def test_row_add_over_the_lane_limit_equals_one_call_and_xla(
 
 # -- the tile kernel ----------------------------------------------------------
 @pytest.mark.parametrize("limit", [None, 512])
-@pytest.mark.parametrize("width", [256, 640])
+@pytest.mark.parametrize("width", [256, 384, 640])
 @pytest.mark.parametrize("name", CASES)
 def test_scatter_add_matches_numpy_scatter_add(
         name, width, limit, monkeypatch):

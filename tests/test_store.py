@@ -213,7 +213,7 @@ CAP = 61  # no multiple of 8, of a pack factor or of a shard count
 WIDTHS = [1, 4, 17, 64, 100, 128]
 TRAFFIC = [
     "uniform", "zipf_hot", "one_row", "half_masked", "neg_and_oob",
-    "hot_run_over_512", "ids_2d_lane_mask",
+    "hot_run_over_512", "ids_2d_lane_mask", "dead_lanes_minus_one",
 ]
 
 
@@ -247,10 +247,19 @@ def _traffic(kind, rng, cap, shape):
     elif kind == "ids_2d_lane_mask":  # FM's and PA's (B, K) pulls
         ids = rng.integers(0, cap, (24, 4))
         mask = rng.random((24, 4)) > 0.3
+    elif kind == "dead_lanes_minus_one":
+        # fastText's ragged bags: a dead lane carries id -1 and NO mask
+        # covers it; the push drops it whatever its delta holds, and so it
+        # does an id past the table
+        ids = rng.integers(0, cap, (24, 8))
+        ids[rng.random((24, 8)) < 0.46] = -1
+        ids[3, :3] = [cap, cap + 100, 2 ** 31 - 1]
     else:
         raise AssertionError(kind)
     ids = np.asarray(ids, np.int32)
     deltas = rng.normal(0, 1, ids.shape + shape).astype(np.float32)
+    if kind == "dead_lanes_minus_one":
+        deltas[(ids < 0) | (ids >= cap)] = np.nan
     return ids, deltas, mask
 
 
@@ -316,7 +325,7 @@ def test_push_pull_case_table(layout, width, traffic):
 # takes ``ops/row_update``'s tile kernel for them, everywhere else XLA's
 # scatter-add.  Off the TPU the chooser is steered in the test and the kernel
 # is interpreted; both arms are held to the same reference.
-WIDE_ROWS = [(256,), (640,), (2, 300), (600,)]
+WIDE_ROWS = [(256,), (300,), (640,), (2, 300), (600,)]
 WIDE_TRAFFIC = TRAFFIC + ["run_over_a_block_and_a_call", "tile_of_8_over_a_call"]
 
 

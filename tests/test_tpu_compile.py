@@ -899,3 +899,57 @@ def test_the_capacity_arm_holds_tables_beside_cell_6_s_table(
         assert "RESOURCE_EXHAUSTED" in str(e)
     else:
         assert mem.temp_size_in_bytes > 3.0 * GB  # a table, or more
+
+
+# fastText's released wiki.en model (chipbench/configs/ft-wiki-en-300.json):
+# words, n-gram buckets and output vectors in one store of (300,) rows, and a
+# batch of 4,096 pairs x (bag of 51, context, 5 negatives) = 233,472 lanes
+FT_VOCAB, FT_BUCKETS, FT_DIM, FT_BATCH, FT_BAG = 2_519_370, 2_000_000, 300, 4_096, 51
+
+
+def test_ft_step_holds_cell_7_s_table_once_beside_three_tile_kernel_calls(
+        one_chip, no_compile_cache, monkeypatch):
+    """Cell 7's step as the chip runs it: the 300-lane row lies flat in
+    three registers (``f32[7038744,384]{1,0:T(8,128)}``, 10.81 GB, the
+    tightest table the system has held), the step updates it in place, its
+    push takes the tile kernel at three registers a row (no refusal) in
+    three calls for its 233,472 lanes, dead ones included, and nothing else
+    yields or copies a table; under 1 GB of temporaries beside it."""
+    from flink_parameter_server_tpu.models import fasttext as ftm
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    spec = jax.eval_shape(
+        lambda: ftm.make_store(FT_VOCAB, FT_BUCKETS, FT_DIM, dtype=jnp.float32)
+    ).spec
+    assert spec.layout == "packed" and spec.pack == 1
+    assert spec.table_shape() == (7_038_744, 384)
+    n0 = row_update.refusal_count()
+    assert store_mod._tile_kernel_takes(spec)
+    logic = ftm.FastTextSkipGram(0.05, FT_VOCAB, FT_BUCKETS, FT_BAG)
+    batch = {
+        "bag": _shape(one_chip, (FT_BATCH, FT_BAG), jnp.int32),
+        "context": _shape(one_chip, (FT_BATCH,), jnp.int32),
+        "negatives": _shape(one_chip, (FT_BATCH, 5), jnp.int32),
+        "mask": _shape(one_chip, (FT_BATCH,), jnp.bool_),
+    }
+    compiled = jax.jit(
+        make_train_step(logic, spec), donate_argnums=(0, 1)
+    ).lower(_shape(one_chip, spec.table_shape(), jnp.float32), (), batch).compile()
+    assert row_update.refusal_count() == n0
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 10.81 * GB  # in place
+    assert mem.temp_size_in_bytes < 1.0 * GB  # 0.77 GB here
+    text = compiled.as_text()
+    entry = text[text.index("ENTRY"):]
+    tables = [
+        line.strip() for line in entry.splitlines()
+        if re.search(r" = f32\[7038744,", line)
+    ]
+    assert len(tables) == 4 and " parameter(" in tables[0], tables
+    for call in tables[1:]:
+        assert call.startswith("%sorted_row_update_tiles"), call
+        assert "custom-call(" in call and "ps.push" in call, call
+        assert "f32[7038744,384]{1,0:T(8,128)}" in call
+    assert " copy(" not in "".join(tables)
+    assert "ps.push/scatter-add" not in text
+    assert "ps.compute/ps.bag_pool" in text and "ps.compute/ps.delta_build" in text
