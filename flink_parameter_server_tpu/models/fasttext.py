@@ -37,7 +37,9 @@ carries id -1 into the push, which drops it (``core/store.push``); its
 pulled row (``pull`` clips the id) is masked out of the average, and the
 mean combiner's counts (``ops/dedup.occurrence_scale``: a row that the
 batch's LIVE lanes name ``n`` times, as a word, a bucket, a context or a
-negative, takes the mean of its ``n`` deltas) skip it.
+negative, takes the mean of its ``n`` deltas) skip it: they are the run
+lengths of the batch's keys sorted, one key space for all three blocks,
+the dead lanes keyed past every row (no counter a row of the store).
 """
 from __future__ import annotations
 

@@ -13,6 +13,9 @@ batch, making its effective step ~count × lr and destabilising training at
 learning rates that are fine sequentially.  ``occurrence_scale`` gives the
 mean-combining alternative: scale each lane's delta by 1/count(id) so a
 hot id takes one averaged step per batch — bounded regardless of skew.
+The counts come from a sort of the batch's own keys (``occurrence_counts``),
+as the rule store's per-row sums do (``combine_runs``): on the device
+nothing here is as long as the table.
 """
 from __future__ import annotations
 
@@ -25,23 +28,59 @@ import numpy as np
 Array = jax.Array
 
 
+_INT32_MAX = np.int32(np.iinfo(np.int32).max)
+
+
 def occurrence_counts(
     ids: Array, capacity: int, mask: Optional[Array] = None
 ) -> Array:
     """Per-lane occurrence count of each lane's id within the batch.
 
-    ``ids``: any-shape int array; returns same-shape float32 counts
-    (≥ 1 for valid lanes).  O(capacity) scratch — intended for id spaces
-    that fit a dense counter (vocab/feature tables), not 2^30 hash spaces.
+    ``ids``: any-shape int array; returns same-shape float32 counts, each
+    lane's the number of COUNTING lanes that hold its id.  A lane counts if
+    its id names a row (``0 <= id < capacity``) and ``mask`` (same shape),
+    if given, is true there; every other lane (a dead lane's -1, an id past
+    the table, a masked lane whatever it holds) counts nothing and reads 1.
+
+    Work and memory go with the batch, never with ``capacity`` (2^30 hash
+    spaces are fine): one sort of (key, stream position) with the lanes
+    that do not count keyed last, the length of each run of one key from
+    the run's first and last index (two prefix scans over the run
+    boundaries), and a second sort on the carried positions that brings the
+    lengths back to stream order.  On the TPU a sort of a batch's keys is
+    about 1 ns a lane where one random access to a counter is 7-9, whatever
+    the counter's length (PERF.md section 6, PR 39).
     """
     flat = ids.reshape(-1).astype(jnp.int32)
-    flat = jnp.where(flat < 0, capacity, flat)  # OOB sentinel, drops
-    ones = jnp.ones(flat.shape, jnp.float32)
+    n = flat.shape[0]
+    if n == 0:
+        return jnp.ones(ids.shape, jnp.float32)
+    counts_lane = (flat >= 0) & (flat < capacity)
     if mask is not None:
-        ones = jnp.where(mask.reshape(-1), ones, 0.0)
-    table = jnp.zeros((capacity,), jnp.float32).at[flat].add(ones, mode="drop")
-    counts = jnp.take(table, jnp.clip(flat, 0, capacity - 1), axis=0)
-    return jnp.maximum(counts, 1.0).reshape(ids.shape)
+        counts_lane = counts_lane & mask.reshape(-1).astype(bool)
+    lane = jnp.arange(n, dtype=jnp.int32)
+    key, pos = jax.lax.sort(
+        (jnp.where(counts_lane, flat, _INT32_MAX), lane), num_keys=1
+    )
+    edge = key[1:] != key[:-1]
+    true = jnp.ones((1,), bool)
+    first = jnp.where(jnp.concatenate([true, edge]), lane, 0)
+    last = jnp.where(jnp.concatenate([edge, true]), lane, n - 1)
+    # prefix max of the run starts, suffix min of the run ends, as log2 n
+    # shifted max / min (alone on the v5e at 233,472 lanes 0.02 ms and a
+    # second to compile; ``lax.cummax`` / ``cummin`` lower to reduce-windows
+    # that take 0.11 ms and 47 s: PERF.md section 6, PR 39)
+    d = 1
+    while d < n:
+        first = jnp.maximum(first, jnp.pad(first[:-d], (d, 0)))
+        last = jnp.minimum(
+            last, jnp.pad(last[d:], (0, d), constant_values=n - 1)
+        )
+        d *= 2
+    length = jnp.where(key == _INT32_MAX, 1, last - first + 1)
+    # (the positions are distinct: nothing for a stable sort to keep)
+    _, counts = jax.lax.sort((pos, length), num_keys=1, is_stable=False)
+    return counts.astype(jnp.float32).reshape(ids.shape)
 
 
 def occurrence_scale(

@@ -88,16 +88,66 @@ def test_prefetch_preserves_order():
     assert got == list(range(50))
 
 
-def test_occurrence_counts_and_scale():
-    ids = jnp.array([[3, 3, 5], [3, 9, 9]])
-    counts = occurrence_counts(ids, 16)
-    np.testing.assert_allclose(
-        np.asarray(counts), [[3, 3, 1], [3, 2, 2]]
+def _bincount_counts(ids, capacity, mask):
+    """What ``occurrence_counts`` is held to, written with ``np.bincount``:
+    a lane counts if its id names a row and its mask is true; every lane
+    that counts reads how many such lanes hold its id, every other reads 1."""
+    ids = np.asarray(ids, np.int64)
+    counts = (ids >= 0) & (ids < capacity)
+    if mask is not None:
+        counts &= np.asarray(mask, bool)
+    distinct, inverse = np.unique(ids[counts], return_inverse=True)
+    out = np.ones(ids.shape, np.float32)
+    out[counts] = np.bincount(inverse, minlength=len(distinct))[inverse]
+    return out
+
+
+_rng = np.random.default_rng(39)
+_OCCURRENCE_CASES = {
+    # PR 39 kept this one: duplicates across the rows of a (B, K) batch
+    "duplicates": ([[3, 3, 5], [3, 9, 9]], 16, None),
+    "a_mask": ([[3, 3, 5], [3, 9, 9]], 16,
+               [[True, True, True], [True, False, False]]),
+    "ids_of_minus_one": ([7, -1, 7, -1, -1, 0, 7], 8, None),
+    "ids_past_the_table": ([15, 16, 15, 400, 16, 2**31 - 1, 0], 16, None),
+    "a_batch_of_bags": (
+        np.where(_rng.random((64, 57)) < 0.46, -1,
+                 _rng.zipf(1.2, (64, 57)) % 500), 500, None),
+    "a_batch_of_bags_masked": (
+        _rng.zipf(1.3, (96, 7)) % 64, 64,
+        np.broadcast_to((np.arange(96) % 5 != 0)[:, None], (96, 7))),
+    "one_lane": ([4], 5, None),
+    "one_dead_lane": ([-1], 5, None),
+    "every_lane_the_same_id": (np.full((33, 3), 11), 12, None),
+    "every_lane_distinct": (_rng.permutation(1000)[:257], 1000, None),
+    "a_hash_space_of_2_to_the_30": (
+        _rng.integers(2**30 - 40, 2**30 + 8, (50, 9)), 2**30, None),
+    "every_lane_masked": ([1, 1, 2], 4, [False, False, False]),
+    "an_empty_batch": (np.zeros((0, 3)), 4, None),
+}
+
+
+@pytest.mark.parametrize("case", list(_OCCURRENCE_CASES))
+@pytest.mark.parametrize("jitted", [False, True], ids=["eager", "jitted"])
+def test_occurrence_counts_and_scale(case, jitted):
+    """The counts are ``np.bincount``'s on every lane that counts and 1 on
+    every lane that does not, at any shape, for any capacity (nothing as
+    long as ``capacity`` is built: 2**30 would be 4 GB of counters)."""
+    ids, capacity, mask = _OCCURRENCE_CASES[case]
+    ids = np.asarray(ids, np.int32)
+    want = _bincount_counts(ids, capacity, mask)
+    args = (jnp.asarray(ids),) + (() if mask is None else (jnp.asarray(mask),))
+    count = lambda i, m=None: occurrence_counts(i, capacity, m)
+    scale = lambda i, m=None: occurrence_scale(i, capacity, m)
+    if jitted:
+        count, scale = jax.jit(count), jax.jit(scale)
+    got = count(*args)
+    assert got.shape == ids.shape and got.dtype == jnp.float32
+    np.testing.assert_array_equal(np.asarray(got), want)
+    np.testing.assert_array_equal(
+        np.asarray(scale(*args)), np.float32(1.0) / want
     )
-    scale = occurrence_scale(ids, 16)
-    np.testing.assert_allclose(np.asarray(scale), 1.0 / np.asarray(counts))
-    # masked lanes don't count: dropping row-1's two 9s leaves 3,3,5,3
-    mask = jnp.array([[True, True, True], [True, False, False]])
-    counts_m = occurrence_counts(ids, 16, mask)
-    np.testing.assert_allclose(np.asarray(counts_m)[0], [3, 3, 1])
-    np.testing.assert_allclose(np.asarray(counts_m)[1][0], 3)
+    if case == "duplicates":
+        np.testing.assert_array_equal(want, [[3, 3, 1], [3, 2, 2]])
+    if case == "a_mask":  # dropping row 1's two 9s leaves 3, 3, 5, 3
+        np.testing.assert_array_equal(want, [[3, 3, 1], [3, 1, 1]])

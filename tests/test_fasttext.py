@@ -188,6 +188,55 @@ def test_the_combiners_counts_are_over_live_lanes_of_all_three_key_spaces():
     assert moved["in"][np.searchsorted(ids["in"], V + 1)].max() > 0
 
 
+def test_the_step_scales_each_live_lane_by_the_bincount_of_its_row(monkeypatch):
+    """The deltas a step pushes on its live lanes are the unscaled ones
+    times ``1 / np.bincount`` of the batch's live keys, to the bit: a
+    bucket that hundreds of bags share, a context named by every third
+    pair, rows named once, dead lanes in every bag (most of the lanes) and
+    a masked pair whose lanes name the hot rows and count nothing."""
+    V, K, d, B, G, N = 600, 64, 16, 256, 24, 5
+    rng = np.random.default_rng(39)
+    out_base = V + K
+    bag = np.full((B, G), -1, np.int32)
+    bag[:, 0] = rng.permutation(V)[:B]  # each word once
+    size = rng.integers(1, G // 2, B)  # dead lanes in every bag, over half
+    for r in range(B):
+        bag[r, 1:size[r]] = V + rng.integers(0, K, size[r] - 1)
+    bag[size > 2, 2] = V + 5  # the hot bucket
+    context = (out_base + rng.integers(0, V, B)).astype(np.int32)
+    context[::3] = out_base + 9
+    negatives = (out_base + rng.integers(0, V, (B, N))).astype(np.int32)
+    mask = np.arange(B) != 11
+    bag[11, :3], context[11] = [bag[0, 0], V + 5, V + 5], out_base + 9
+    keys = np.concatenate([bag, context[:, None], negatives], axis=1)
+    live = (keys >= 0) & mask[:, None]
+    counts = np.bincount(keys[live], minlength=2 * V + K)
+    assert counts[V + 5] > 150 and counts[out_base + 9] > 80
+    assert (counts == 1).sum() > 200 and live.mean() < 0.5
+    pulled = rng.normal(0, 0.2, keys.shape + (d,)).astype(np.float32)
+    batch = {k: jnp.asarray(v) for k, v in {
+        "bag": bag, "context": context, "negatives": negatives, "mask": mask,
+    }.items()}
+    logic = ftm.FastTextSkipGram(0.05, V, K, G)
+
+    def pushed():
+        _, req, _ = jax.jit(lambda b, p: logic.step((), b, p))(batch, pulled)
+        np.testing.assert_array_equal(np.asarray(req.mask), live)
+        np.testing.assert_array_equal(
+            np.asarray(req.ids), np.where(live, keys, -1))
+        return np.asarray(req.deltas)
+
+    got = pushed()
+    monkeypatch.setattr(
+        ftm, "occurrence_scale", lambda keys, capacity: jnp.ones(keys.shape))
+    scale = np.float32(1.0) / counts[np.clip(keys, 0, None)].astype(np.float32)
+    want = pushed() * scale[..., None]
+    assert (want[live] != 0).any(axis=1).all()
+    np.testing.assert_array_equal(
+        got[live].view(np.uint32), want[live].view(np.uint32))
+    assert (got[~live] == 0).all()
+
+
 def test_a_300_lane_row_lies_in_three_registers_and_round_trips():
     assert store_mod._resolve_layout("auto", "add", (300,)) == "packed"
     rng = np.random.default_rng(0)

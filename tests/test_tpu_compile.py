@@ -628,6 +628,28 @@ def test_w2v_step_on_a_tpu_pushes_through_the_tile_kernel(
     assert "ps.push/scatter-add" not in text
 
 
+def _ops_built_under(text, scope):
+    """``(op, body)`` for every op of the compiled ENTRY whose innermost
+    ``ps.*`` scope is ``scope``: the line without its metadata, the op's
+    name (``.../scatter-add``) kept, and the text of the computation a
+    fusion calls (what the fusion does: a scatter, a gather, a pad...)."""
+    bodies = dict(re.findall(
+        r"^(%[\w.\-]+) [^\n]*\{\n(.*?)^\}", text, re.M | re.S
+    ))
+    found = []
+    for line in text[text.index("ENTRY"):].splitlines():
+        name = re.search(r'op_name="([^"]*)"', line)
+        scopes = re.findall(r"ps\.[a-z_]+", name.group(1)) if name else []
+        if not scopes or scopes[-1] != scope:
+            continue
+        called = re.search(r"calls=(%[\w.\-]+)", line)
+        found.append((
+            line.strip().split(" metadata=")[0] + " " + name.group(1),
+            bodies.get(called.group(1), "") if called else "",
+        ))
+    return found
+
+
 def test_w2v_step_assembles_its_deltas_without_a_zeroed_block(
         one_chip, w2v, no_compile_cache, monkeypatch):
     """Cell 5's deltas are written once: under ``ps.delta_build`` and
@@ -643,13 +665,10 @@ def test_w2v_step_assembles_its_deltas_without_a_zeroed_block(
     compiled = _w2v_cell_step(one_chip, w2vm, monkeypatch)
     assert compiled.memory_analysis().temp_size_in_bytes < 0.70 * GB
     text = compiled.as_text()
-    by_scope = {"ps.delta_build": [], "ps.push": []}
-    for line in text[text.index("ENTRY"):].splitlines():
-        name = re.search(r'op_name="([^"]*)"', line)
-        scopes = re.findall(r"ps\.[a-z_]+", name.group(1)) if name else []
-        if scopes and scopes[-1] in by_scope:  # the innermost scope
-            by_scope[scopes[-1]].append(line.strip().split(" metadata=")[0])
-    build, push = by_scope["ps.delta_build"], by_scope["ps.push"]
+    build, push = (
+        [op for op, _ in _ops_built_under(text, scope)]
+        for scope in ("ps.delta_build", "ps.push")
+    )
     assert len(build) >= 4 and len(push) >= 8  # the filter found both
     keys = W2V_BATCH * (W2V_NEG + 2)
     block = f"f32[{W2V_BATCH},{W2V_NEG + 2},2,{W2V_DIM}]"
@@ -664,6 +683,37 @@ def test_w2v_step_assembles_its_deltas_without_a_zeroed_block(
 
     assert not relaid(build + push, 2 * W2V_DIM)
     assert len(relaid(build, W2V_DIM)) == 1 and not relaid(push, W2V_DIM)
+
+
+@pytest.mark.parametrize("cell", [5, 7])
+def test_the_combiners_counts_hold_no_counter_and_touch_no_table(
+        cell, one_chip, w2v, no_compile_cache, monkeypatch):
+    """The mean combiner's counts come from a sort of the batch's keys
+    (``ops/dedup.occurrence_counts``, PR 39): in cell 5's and cell 7's step
+    as the chip runs them, no op under ``ps.delta_build`` yields a buffer
+    as long as the vocabulary (the parent zeroed a ``f32[3000000]`` /
+    ``f32[7038740]`` counter every step), none scatters or gathers (it
+    scatter-added a one a lane into the counter and gathered the counter
+    back: 7-9 ns a lane, 1.6 and 3.7 ms a step on the v5e), the two sorts
+    are there, and the step's temporaries stay inside the bounds the
+    parent's step was held to (0.649 / 0.767 GB here, as the parent's)."""
+    if cell == 5:
+        _, _, w2vm = w2v
+        compiled = _w2v_cell_step(one_chip, w2vm, monkeypatch)
+        rows, lanes, temp = W2V_VOCAB, W2V_BATCH * (W2V_NEG + 2), 0.70 * GB
+    else:
+        compiled = _ft_cell_step(one_chip, monkeypatch)
+        rows, lanes = 2 * FT_VOCAB + FT_BUCKETS, FT_BATCH * (FT_BAG + 6)
+        temp = 1.0 * GB
+    assert compiled.memory_analysis().temp_size_in_bytes < temp
+    build = _ops_built_under(compiled.as_text(), "ps.delta_build")
+    assert len(build) >= 10  # the filter found the scope
+    sorts = [op for op, _ in build if re.search(r"\) sort\(", op)]
+    assert len(sorts) == 2 and all(f"s32[{lanes}]" in op for op in sorts)
+    for op, body in build:
+        assert f"[{rows}]" not in op and f"[{rows}]" not in body, op
+        assert not re.search(r" (scatter|gather)\(", op + body), op
+        assert not re.search(r"/(scatter(-add)?|gather)$", op), op
 
 
 def _step_text_sha(step, *args):
@@ -907,14 +957,9 @@ def test_the_capacity_arm_holds_tables_beside_cell_6_s_table(
 FT_VOCAB, FT_BUCKETS, FT_DIM, FT_BATCH, FT_BAG = 2_519_370, 2_000_000, 300, 4_096, 51
 
 
-def test_ft_step_holds_cell_7_s_table_once_beside_three_tile_kernel_calls(
-        one_chip, no_compile_cache, monkeypatch):
-    """Cell 7's step as the chip runs it: the 300-lane row lies flat in
-    three registers (``f32[7038744,384]{1,0:T(8,128)}``, 10.81 GB, the
-    tightest table the system has held), the step updates it in place, its
-    push takes the tile kernel at three registers a row (no refusal) in
-    three calls for its 233,472 lanes, dead ones included, and nothing else
-    yields or copies a table; under 1 GB of temporaries beside it."""
+def _ft_cell_step(one_chip, monkeypatch):
+    """Cell 7's step as the chip runs it (the store's own layout, the
+    push's arm chosen as on a TPU), compiled."""
     from flink_parameter_server_tpu.models import fasttext as ftm
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -923,7 +968,6 @@ def test_ft_step_holds_cell_7_s_table_once_beside_three_tile_kernel_calls(
     ).spec
     assert spec.layout == "packed" and spec.pack == 1
     assert spec.table_shape() == (7_038_744, 384)
-    n0 = row_update.refusal_count()
     assert store_mod._tile_kernel_takes(spec)
     logic = ftm.FastTextSkipGram(0.05, FT_VOCAB, FT_BUCKETS, FT_BAG)
     batch = {
@@ -932,9 +976,21 @@ def test_ft_step_holds_cell_7_s_table_once_beside_three_tile_kernel_calls(
         "negatives": _shape(one_chip, (FT_BATCH, 5), jnp.int32),
         "mask": _shape(one_chip, (FT_BATCH,), jnp.bool_),
     }
-    compiled = jax.jit(
+    return jax.jit(
         make_train_step(logic, spec), donate_argnums=(0, 1)
     ).lower(_shape(one_chip, spec.table_shape(), jnp.float32), (), batch).compile()
+
+
+def test_ft_step_holds_cell_7_s_table_once_beside_three_tile_kernel_calls(
+        one_chip, no_compile_cache, monkeypatch):
+    """Cell 7's step as the chip runs it: the 300-lane row lies flat in
+    three registers (``f32[7038744,384]{1,0:T(8,128)}``, 10.81 GB, the
+    tightest table the system has held), the step updates it in place, its
+    push takes the tile kernel at three registers a row (no refusal) in
+    three calls for its 233,472 lanes, dead ones included, and nothing else
+    yields or copies a table; under 1 GB of temporaries beside it."""
+    n0 = row_update.refusal_count()
+    compiled = _ft_cell_step(one_chip, monkeypatch)
     assert row_update.refusal_count() == n0
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= 10.81 * GB  # in place

@@ -317,6 +317,44 @@ def test_sgns_step_assembles_the_plain_deltas_bit_for_bit(
     )
 
 
+def test_sgns_step_scales_each_live_lane_by_the_bincount_of_its_word():
+    """The mean combiner's scale is ``1 / np.bincount`` of the batch's
+    unmasked keys, to the bit, on every lane the push keeps: a word the
+    batch names hundreds of times (as a negative, a context and a centre),
+    words named once, and a masked pair that names the hot word too and
+    counts nothing (``ops/dedup.occurrence_counts`` counts from a sort of
+    the keys; the integers are ``np.bincount``'s, so ``1 / count`` and the
+    scaled deltas are the same float32)."""
+    from flink_parameter_server_tpu.models.word2vec import SkipGramNS
+
+    vocab, batch, negs, dim, hot = 4096, 512, 5, 24, 17
+    rng = np.random.default_rng(39)
+    ids = rng.integers(0, vocab, (batch, negs + 2)).astype(np.int32)
+    ids[rng.random(ids.shape) < 0.2] = hot
+    ids[7] = hot  # the masked pair names it seven times more
+    mask = np.arange(batch) != 7
+    counts = np.bincount(ids[mask].ravel(), minlength=vocab)
+    assert counts[hot] > 500 and (counts == 1).sum() > 100
+    pulled = rng.normal(0.0, 0.3, (batch, negs + 2, 2, dim)).astype(np.float32)
+    b = {"center": ids[:, 0], "context": ids[:, 1], "negatives": ids[:, 2:],
+         "mask": mask}
+    b = {k: jnp.asarray(x) for k, x in b.items()}
+
+    def pushed(dedup_scale):
+        logic = SkipGramNS(0.025, dedup_scale=dedup_scale, vocab_size=vocab)
+        _, req, _ = jax.jit(lambda b, p: logic.step((), b, p))(b, pulled)
+        np.testing.assert_array_equal(np.asarray(req.ids), ids)
+        return np.asarray(req.deltas)
+
+    scale = np.float32(1.0) / counts[ids].astype(np.float32)
+    want = pushed(False) * scale[..., None, None]
+    got = pushed(True)
+    assert (want[mask] != 0).any(axis=(1, 2, 3)).all()
+    np.testing.assert_array_equal(
+        got[mask].view(np.uint32), want[mask].view(np.uint32)
+    )
+
+
 def test_make_store_takes_a_dtype_and_a_traced_seed():
     from flink_parameter_server_tpu.models.word2vec import OUT, make_store
 
