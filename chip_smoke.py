@@ -184,14 +184,27 @@ class Smoke:
 
     def _stream(self, n_batches: int):
         """The first ``n_batches`` of one logical stream (re-fed from the
-        start after a resume: the driver's cursor skips what it consumed)."""
+        start after a resume: the driver's cursor skips what it consumed).
+        Under the mesh the user factors lie with keyed workers, and the
+        stream is keyed HERE (the driver's own router then hands it on
+        untouched), so that a dispatch is a batch of this stream whatever a
+        router holds back: one epoch more goes in than comes out."""
+        from flink_parameter_server_tpu.data.keyed import KeyedRouter
         from flink_parameter_server_tpu.data.streams import microbatches
+        from flink_parameter_server_tpu.models.matrix_factorization import (
+            worker_block_rows,
+        )
 
         s = self.sizes
         epochs = -(-n_batches // s.distinct_batches)
-        return itertools.islice(
-            microbatches(self._ratings, s.batch, epochs=epochs), n_batches
-        )
+        if self.mesh is None:
+            stream = microbatches(self._ratings, s.batch, epochs=epochs)
+        else:
+            dp = self.mesh.shape["dp"]
+            stream = KeyedRouter(
+                dp, worker_block_rows(s.num_users, dp)
+            ).route(microbatches(self._ratings, s.batch, epochs=epochs + 1))
+        return itertools.islice(stream, n_batches)
 
     def _assert_placement(self, table, state, spec) -> dict:
         """Table rows split over ``ps`` on four devices, worker state over
@@ -492,20 +505,21 @@ class Smoke:
 
         s = self.sizes
 
+        # one device is given the mesh's keyed microbatches
+        keyed = list(self._stream(s.parity_steps))
+
         def train(mesh):
             logic, store = self._mf_parts(jnp.float32, mesh)
             driver = StreamingDriver(
                 logic, store, config=DriverConfig(nan_check_every=1)
             )
-            res = driver.run(
-                self._stream(s.parity_steps), collect_outputs=True
-            )
+            res = driver.run(iter(keyed), collect_outputs=True)
             preds = np.stack([
                 np.asarray(o["prediction"]) for o in res.worker_outputs[:-1]
             ])
             return (
                 np.asarray(driver.store.values()),
-                np.asarray(res.worker_state), preds,
+                np.asarray(res.worker_state)[: s.num_users], preds,
             )
 
         t0 = time.perf_counter()
@@ -627,6 +641,37 @@ class Smoke:
             (table_u, ids_u, deltas),
             close(table_u.at[ids_u].add(deltas), 1e-3),
         )
+
+        # the same kernel under a mesh, as the keyed MF workers run it: every
+        # chip's own block of the rows, its own lane block of ids (local to
+        # the block), inside a shard_map; against np.add.at on the whole
+        if self.mesh is not None:
+            from jax.sharding import PartitionSpec as P
+
+            w = len(jax.devices()) if not self.dry_run else 4
+            workers = make_mesh(w, 1, devices=jax.devices()[:w])
+            block = rows_u // w // 8 * 8
+            ids_k = np.array(rng.integers(0, block, n // w * w), np.int32)
+            ids_k[: n // 16] = ids_k[0]
+            table_k, deltas_k = normal((w * block, d)), normal((len(ids_k), d))
+            want_k = np.array(table_k)
+            np.add.at(
+                want_k,
+                ids_k + np.repeat(np.arange(w) * block, len(ids_k) // w),
+                np.asarray(deltas_k),
+            )
+            self._kernel_case(
+                f"row_update_d128_f32_shard_map_dp{w}",
+                jax.shard_map(
+                    lambda t, i, dl: row_update.row_add(
+                        t, i, jnp.take(t, i, axis=0), dl,
+                        interpret=interpret),
+                    mesh=workers,
+                    in_specs=(P("dp", None), P("dp"), P("dp", None)),
+                    out_specs=P("dp", None), check_vma=False,
+                ),
+                (table_k, jnp.asarray(ids_k), deltas_k), close(want_k, 1e-3),
+            )
 
         # ops/row_update's tile kernel at the shape class of cell 5's push:
         # f32 rows of 640 lanes (five registers), Zipf ids with long runs;

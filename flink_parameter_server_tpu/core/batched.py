@@ -59,6 +59,15 @@ class BatchedWorkerLogic(abc.ABC, Generic[State, Batch, Out]):
     ) -> Tuple[State, PushRequest, Out]:
         """One compiled training step over the microbatch."""
 
+    def key_router(self, *, registry=None, tracer=None):  # noqa: B027
+        """The keyed shuffle this logic's step needs in front of it (an
+        object with ``route(batches) -> batches``:
+        :class:`~..data.keyed.KeyedRouter`), or ``None`` where the step takes
+        a microbatch as the stream delivered it.  ``transform_batched`` and
+        the StreamingDriver route their input through it; a batch that is
+        keyed already passes through untouched."""
+        return None
+
     def finish(self, state: State) -> Any:  # noqa: B027
         """Optional close-time worker output (e.g. dump local user
         vectors) — counterpart of ``WorkerLogic.close``."""

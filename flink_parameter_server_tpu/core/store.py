@@ -41,6 +41,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..parallel.mesh import DP_AXIS, worker_count
+
 Array = jax.Array
 InitFn = Callable[[Array], Array]  # ids (n,) int32 -> values (n, *value_shape)
 UpdateFn = Callable[[Array, Array], Array]  # (current, combined_delta) -> new
@@ -392,6 +394,8 @@ def push_counted(
     ids: Array,
     deltas: Array,
     mask: Optional[Array] = None,
+    *,
+    lanes_over_workers: bool = False,
 ) -> Tuple[Array, Optional[dict]]:
     """:func:`push`, and beside the table what a rule store's push counted
     on the device (``None`` for ``update="add"``, which counts nothing):
@@ -401,7 +405,14 @@ def push_counted(
     wrote the rows: :func:`_set_kernel_takes`).  ``make_train_step`` puts
     them among the step's outputs, where whoever fetches outputs finds them, if the logic's
     outputs are a dict (every logic of ``models/``); outputs of another
-    type leave the step as they are, without the counts."""
+    type leave the step as they are, without the counts.
+
+    ``lanes_over_workers`` is what the caller knows of where the batch lies
+    and a bare ``push`` cannot see: its lanes are split over the mesh's
+    ``dp`` workers (``make_train_step`` under such a mesh says so).  An
+    ``add`` batch may then be summed worker by worker
+    (:func:`_worker_reduce_takes`); a bare ``push`` keeps ONE scatter-add in
+    the batch's order, whatever the mesh."""
     vr = len(spec.value_shape)
     lead = tuple(deltas.shape[: deltas.ndim - vr])
     if (vr and tuple(deltas.shape[deltas.ndim - vr:]) != spec.value_shape) or (
@@ -443,6 +454,10 @@ def push_counted(
             from ..ops.row_update import scatter_add
 
             return scatter_add(table, s_ids, s_deltas.astype(table.dtype)), None
+        if lanes_over_workers and _worker_reduce_takes(spec, s_ids.shape[0]):
+            return _push_add_over_workers(
+                spec, table, s_ids, s_deltas.astype(table.dtype)
+            ), None
         return (
             table.at[s_ids].add(s_deltas.astype(table.dtype), mode="drop"),
             None,
@@ -619,6 +634,59 @@ def _set_kernel_takes(spec: StoreSpec) -> bool:
             row_update.set_refusal(spec.table_shape(), spec.dtype),
         )
     return False
+
+
+def _worker_reduce_takes(spec: StoreSpec, lanes: int) -> bool:
+    """Whether an ``add`` batch whose lanes lie split over ``dp`` workers (the
+    caller says so: ``push_counted(lanes_over_workers=True)``) is summed
+    worker by worker (:func:`_push_add_over_workers`), read from what the
+    spec and the batch hold: a mesh with more than one worker, lanes
+    that split evenly over them, and a shard of the table with no more rows
+    than the batch has lanes, so that the per-worker sums the reduce moves
+    are no larger than the deltas it would move else.  Static per compiled
+    step.  Every other store under a mesh leaves the scatter to GSPMD."""
+    workers = worker_count(spec.mesh)
+    return (workers > 1 and lanes % workers == 0
+            and spec.rows_per_shard <= lanes)
+
+
+def _push_add_over_workers(
+    spec: StoreSpec, table: Array, ids: Array, deltas: Array
+) -> Array:
+    """``table.at[ids].add(deltas, mode="drop")`` (physical ids, flat) for a
+    batch whose lanes lie split over the mesh's ``dp`` workers: every
+    worker scatter-adds ITS lanes, in their order, into a zeroed copy of
+    the shard of the table its chip holds, and the workers' sums are added
+    up across ``dp`` (scope ``ps.delta_reduce``: the step's one collective
+    for the push) and onto the table.  Bulk-synchronous over the whole
+    microbatch, as one scatter-add is; what differs is the order in which a
+    row's deltas are summed (worker by worker, then across workers).  Left to
+    GSPMD the scatter's operand is a table that every worker holds whole,
+    and the partitioner is free to gather the batch onto every chip and
+    have each walk all of it.  The sum is written outside the ``shard_map``
+    for the partitioner to name and place as an ``all-reduce``
+    (:func:`_packed_pull_on_shards` says why)."""
+    mesh, ps = spec.mesh, spec.ps_axis
+    rows = spec.rows_per_shard
+
+    def on_worker(ids: Array, deltas: Array) -> Array:
+        rel = ids - jax.lax.axis_index(ps) * rows
+        # other shards' rows, and the sentinel of a dropped lane, fall out
+        rel = jnp.where((rel >= 0) & (rel < rows), rel, rows)
+        zeros = jnp.zeros((rows,) + deltas.shape[1:], deltas.dtype)
+        return zeros.at[rel].add(deltas, mode="drop")[None]
+
+    wide = (None,) * (deltas.ndim - 1)
+    sums = jax.shard_map(
+        on_worker,
+        mesh=mesh,
+        in_specs=(P(DP_AXIS), P(DP_AXIS, *wide)),
+        out_specs=P(DP_AXIS, ps, *wide),
+        check_vma=False,
+    )(ids, deltas)
+    with jax.named_scope("ps.delta_reduce"):
+        total = sums.sum(axis=0)
+    return table + total
 
 
 def _preload_tile_kernel(spec: StoreSpec) -> None:

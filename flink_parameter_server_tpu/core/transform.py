@@ -41,7 +41,7 @@ from .api import (
 from .batched import BatchedWorkerLogic
 from .entities import Pull, PullAnswer, Push, PSToWorker, WorkerToPS
 from .store import ShardedParamStore
-from ..parallel.mesh import DP_AXIS
+from ..parallel.mesh import DP_AXIS, worker_count
 from ..telemetry.compile_ledger import setup_span
 from ..telemetry.spans import NULL_TRACER, SpanTracer
 from ..training.tracing import scope
@@ -304,7 +304,22 @@ def make_train_step(logic: BatchedWorkerLogic, spec) -> Callable:
     """
     from . import store as store_mod
 
+    workers = worker_count(spec.mesh)
+    lanes = None
+    if workers > 1:
+        lanes = NamedSharding(spec.mesh, PartitionSpec(DP_AXIS))
+
+    def over_workers(x):
+        # a leaf whose lanes split over the workers lies split over them,
+        # however it came: a batch staged on every chip is sliced where it
+        # lies, inside this program, not by a program of its own a leaf
+        if x.ndim and x.shape[0] % workers == 0:
+            return jax.lax.with_sharding_constraint(x, lanes)
+        return x
+
     def step(table, state, batch):
+        if lanes is not None:
+            batch = jax.tree.map(over_workers, batch)
         ids = logic.keys(batch)
         # ps.* scopes are metadata on the ops' names (docs/observability.md):
         # a trace reduction finds pull, compute and push by them whatever
@@ -315,7 +330,8 @@ def make_train_step(logic: BatchedWorkerLogic, spec) -> Callable:
             state, req, out = logic.step(state, batch, pulled)
         with scope("ps.push"):
             table, counted = store_mod.push_counted(
-                spec, table, req.ids, req.deltas, req.mask
+                spec, table, req.ids, req.deltas, req.mask,
+                lanes_over_workers=lanes is not None,
             )
         if counted is not None and isinstance(out, dict):
             # a rule store's push counts its live keys and distinct rows on
@@ -539,9 +555,10 @@ def transform_batched(
         else worker_logic.init_state(rng)
     )
 
-    batch_sharding = None
-    if mesh is not None and dp_axis in mesh.axis_names and mesh.shape[dp_axis] > 1:
+    batch_sharding = on_mesh = None
+    if worker_count(mesh, dp_axis) > 1:
         batch_sharding = NamedSharding(mesh, PartitionSpec(dp_axis))
+        on_mesh = set(mesh.devices.flat)
 
     # the scanned program consumes (K, batch, ...) leaves: the dp shard
     # moves to axis 1 (axis 0 is scan time, resident on every device)
@@ -560,12 +577,18 @@ def transform_batched(
     worker_outputs: List[Any] = []
     step_idx = 0
 
+    def to_workers(x):
+        # host arrays go to the workers a lane block each; what already
+        # lies on the mesh's chips (a staged pool) is taken where it lies,
+        # and the step slices it itself (`make_train_step`)
+        if isinstance(x, jax.Array) and x.sharding.device_set == on_mesh:
+            return x
+        return jax.device_put(x, batch_sharding)
+
     def _run_one(table, state, batch, step_idx):
         with tracer.span("pull_compute_push", component="train"):
             if batch_sharding is not None:
-                batch = jax.tree.map(
-                    lambda x: jax.device_put(x, batch_sharding), batch
-                )
+                batch = jax.tree.map(to_workers, batch)
             table, state, out = step(table, state, batch)
         if on_step is not None:
             on_step(step_idx, out)
@@ -596,6 +619,11 @@ def transform_batched(
 
     group: List[Any] = []
     batches = iter(data)
+    router = worker_logic.key_router(tracer=tracer)
+    if router is not None:
+        # keyed workers: every record to the lane block of the worker that
+        # owns its key (what arrives keyed passes through as it is)
+        batches = router.route(batches)
     while True:
         with tracer.span("batch_wait", component="train"):
             batch = next(batches, _END)

@@ -32,7 +32,7 @@ from ..core.api import WorkerLogic
 from ..core.batched import BatchedWorkerLogic, PushRequest
 from ..core.store import ShardedParamStore
 from ..ops import row_update
-from ..parallel.mesh import DP_AXIS
+from ..parallel.mesh import DP_AXIS, worker_count
 from ..training.tracing import scope
 from ..utils.initializers import ranged_random_factor
 
@@ -40,6 +40,16 @@ Array = jax.Array
 
 # the arms of the worker-state update (OnlineMatrixFactorization.step)
 STATE_ARMS = ("sorted_rows", "xla")
+
+
+def worker_block_rows(num_users: int, workers: int) -> int:
+    """Rows of one keyed worker's block of the user factors: ``num_users``
+    over the workers, aligned up to 8 (a float32 sublane tile, so every
+    block starts on one).  Worker ``w`` owns users ``[w * rows, (w + 1) *
+    rows)``; one worker holds exactly ``num_users`` rows."""
+    if workers == 1:
+        return num_users
+    return -(-num_users // (8 * workers)) * 8
 
 
 @dataclasses.dataclass(frozen=True)
@@ -66,7 +76,21 @@ class SGDUpdater:
 class OnlineMatrixFactorization(BatchedWorkerLogic):
     """Batched MF worker logic: user factors = worker state, item factors =
     PS store.  Batches are dicts with keys ``user``, ``item``, ``rating``,
-    ``mask`` (see :func:`..data.streams.microbatches`)."""
+    ``mask`` (see :func:`..data.streams.microbatches`).
+
+    Under a mesh whose ``dp`` axis is larger than one the user factors are
+    partitioned by user id over ``dp`` KEYED WORKERS, as the reference
+    partitions them over its worker subtasks: worker ``w`` holds the
+    contiguous block of ``rows_per_worker`` rows that starts at ``w *
+    rows_per_worker`` (the state is ``P(dp, None)`` over ``state_rows`` =
+    ``workers * rows_per_worker`` rows; the rows past ``num_users`` are
+    padding that the per-id initialiser fills and no record names), and the
+    stream reaches each worker keyed: lane block ``w`` of a microbatch holds
+    worker ``w``'s users (:meth:`key_router`, which the loop and the driver
+    put in front of the step).  ``step`` then runs each worker's own records
+    against its own block inside a ``shard_map``; a live record whose user is
+    not its block's is counted (``keyed_misrouted``) and dropped, never
+    applied to another row."""
 
     def __init__(
         self,
@@ -118,12 +142,37 @@ class OnlineMatrixFactorization(BatchedWorkerLogic):
             )
         self.state_scatter = state_scatter
         self._fallback_noted = False
-        if (state_scatter is None and mesh is None
+        if (state_scatter is None and (mesh is None or self.workers > 1)
                 and jax.default_backend() == "tpu"
                 and row_update.refusal((dim,), dtype) is None):
             # the step of this logic is going to trace the kernel: have
             # Pallas imported by then
             row_update.preload()
+
+    # -- keyed workers -----------------------------------------------------
+    @property
+    def workers(self) -> int:
+        """Keyed workers the user factors are partitioned over: the size of
+        the mesh's ``dp`` axis (1 without a mesh or without that axis)."""
+        return worker_count(self.mesh, self.dp_axis)
+
+    @property
+    def rows_per_worker(self) -> int:
+        return worker_block_rows(self.num_users, self.workers)
+
+    @property
+    def state_rows(self) -> int:
+        return self.workers * self.rows_per_worker
+
+    def key_router(self, *, registry=None, tracer=None):
+        if self.workers == 1:
+            return None
+        from ..data.keyed import KeyedRouter
+
+        return KeyedRouter(
+            self.workers, self.rows_per_worker, key="user",
+            registry=registry, tracer=tracer,
+        )
 
     # -- BatchedWorkerLogic ------------------------------------------------
     def init_state(self, rng: Array) -> Array:
@@ -131,6 +180,18 @@ class OnlineMatrixFactorization(BatchedWorkerLogic):
             self.seed, (self.dim,), low=self.init_low, high=self.init_high,
             dtype=self.dtype,
         )
+        if self.workers > 1:
+            # every worker fills its own block from its own row ids
+            dp, rows = self.dp_axis, self.rows_per_worker
+
+            def block():
+                first = jax.lax.axis_index(dp) * rows
+                return init(first + jnp.arange(rows, dtype=jnp.int32))
+
+            return jax.jit(jax.shard_map(
+                block, mesh=self.mesh, in_specs=(), out_specs=P(dp, None),
+                check_vma=False,
+            ))()
         ids = jnp.arange(self.num_users, dtype=jnp.int32)
         if self.mesh is not None and self.dp_axis in self.mesh.axis_names:
             sharding = NamedSharding(self.mesh, P(self.dp_axis, None))
@@ -147,9 +208,12 @@ class OnlineMatrixFactorization(BatchedWorkerLogic):
         warned of once (``ops/row_update.refusal_count``)."""
         if self.state_scatter is not None:
             return self.state_scatter
-        # under a mesh the state is P(dp, None) and GSPMD partitions the
-        # XLA scatter; off the TPU the kernel would be interpreted
-        if self.mesh is not None or jax.default_backend() != "tpu":
+        # keyed workers update their own block inside a shard_map, where
+        # the kernel sees a plain array; a mesh with one worker leaves the
+        # XLA scatter to GSPMD; off the TPU the kernel would be interpreted
+        if (self.mesh is not None and self.workers == 1) or (
+            jax.default_backend() != "tpu"
+        ):
             return "xla"
         why = row_update.refusal(state.shape[1:], state.dtype)
         if why is None:
@@ -166,7 +230,65 @@ class OnlineMatrixFactorization(BatchedWorkerLogic):
         if mask is None:
             mask = jnp.ones(users.shape, bool)
         arm = self.state_update_arm(state)
+        i_scale = None
+        if self.dedup_scale and self.workers > 1:
+            # an item's records lie with every worker: counted over the
+            # whole microbatch, outside the workers' own step
+            from ..ops.dedup import occurrence_scale
 
+            i_scale = occurrence_scale(
+                batch["item"].astype(jnp.int32), self.num_items, mask
+            )
+        if self.workers == 1:
+            state, item_delta, out = self._worker_step(
+                arm, state, users, batch["item"], ratings, mask, pulled
+            )
+            return state, PushRequest(batch["item"], item_delta, mask), out
+
+        dp, rows = self.dp_axis, self.rows_per_worker
+
+        scales = () if i_scale is None else (i_scale,)
+
+        def on_worker(state, users, items, ratings, mask, pulled, *scales):
+            # this worker's block of the state and its lane block of the
+            # microbatch: a user's row is its id less the block's first
+            local = users - jax.lax.axis_index(dp) * rows
+            mine = (local >= 0) & (local < rows)
+            live = mask & mine
+            state, item_delta, out = self._worker_step(
+                arm, state, jnp.clip(local, 0, rows - 1), items, ratings,
+                live, pulled, *scales,
+            )
+            out["keyed_misrouted"] = jnp.sum(
+                mask & ~mine, dtype=jnp.int32
+            )[None]
+            return state, item_delta, live, out
+
+        lanes, block = P(dp), P(dp, None)
+        state, item_delta, live, out = jax.shard_map(
+            on_worker,
+            mesh=self.mesh,
+            in_specs=(block, lanes, lanes, lanes, lanes, block)
+            + (lanes,) * len(scales),
+            out_specs=(
+                block, block, lanes,
+                {"prediction": lanes, "error": lanes,
+                 "keyed_misrouted": lanes},
+            ),
+            check_vma=False,
+        )(state, users, batch["item"], ratings, mask, pulled, *scales)
+        # a misrouted record's item delta came of another user's row: the
+        # push drops it with the lanes the stream masked
+        return state, PushRequest(batch["item"], item_delta, live), out
+
+    def _worker_step(
+        self, arm: str, state: Array, users: Array, items: Array,
+        ratings: Array, mask: Array, pulled: Array,
+        i_scale: Optional[Array] = None,
+    ):
+        """One worker's records against its own rows of ``state`` (all of it
+        where there is one worker): the state's gather, the SGD deltas, the
+        state's update.  ``users`` are rows of THIS ``state``."""
         with scope("ps.state_pull"):
             user_vecs = jnp.take(state, users, axis=0)
         user_delta, item_delta, pred = self.updater.delta(
@@ -175,10 +297,12 @@ class OnlineMatrixFactorization(BatchedWorkerLogic):
         if self.dedup_scale:
             from ..ops.dedup import occurrence_scale
 
-            u_scale = occurrence_scale(users, self.num_users, mask)
-            i_scale = occurrence_scale(
-                batch["item"].astype(jnp.int32), self.num_items, mask
-            )
+            # a user's records all lie with its worker
+            u_scale = occurrence_scale(users, state.shape[0], mask)
+            if i_scale is None:
+                i_scale = occurrence_scale(
+                    items.astype(jnp.int32), self.num_items, mask
+                )
             user_delta = user_delta * u_scale[..., None].astype(self.dtype)
             item_delta = item_delta * i_scale[..., None].astype(self.dtype)
         with scope("ps.state_push"):
@@ -194,7 +318,7 @@ class OnlineMatrixFactorization(BatchedWorkerLogic):
                 user_delta = user_delta * mask[..., None].astype(self.dtype)
                 state = state.at[users].add(user_delta, mode="drop")
         out = {"prediction": pred, "error": (ratings - pred) * mask}
-        return state, PushRequest(batch["item"], item_delta, mask), out
+        return state, item_delta, out
 
     def finish(self, state: Array):
         # close()-time worker dump: the final user factors (the reference's
@@ -256,109 +380,6 @@ def ps_online_mf(
     )
 
 
-def make_locality_mf_step(
-    logic: OnlineMatrixFactorization,
-    spec,
-    mesh: Mesh,
-    *,
-    dp_axis: str = DP_AXIS,
-    ps_axis: str = "ps",
-):
-    """The whole MF step fused into ONE ``shard_map`` over (dp × ps) —
-    the explicit-collective alternative to the jit-auto path.
-
-    Contract: batches must be partition-aligned by user
-    (:func:`..data.streams.partitioned_microbatches` with ``key="user"``,
-    ``capacity=num_users``) and ``num_users`` divisible by the dp size;
-    the user table is then dp-block-sharded and its gather/scatter is
-    purely local.  The only collectives per step are the pull's ``psum``
-    over ``ps`` and one ``all_gather`` of (ids, deltas) over ``dp`` for
-    the push — the reference's entire message plane as two ICI ops
-    (SURVEY.md §2 "TPU-native equivalent").  Out-of-partition users are
-    masked out defensively (a violation of the alignment contract drops
-    those updates rather than corrupting other shards' rows).
-
-    Use: ``step = jax.jit(make_locality_mf_step(logic, store.spec, mesh))``
-    then ``table, state, out = step(store.table, state, batch)``.
-    """
-    dp = mesh.shape[dp_axis]
-    ps = mesh.shape[ps_axis]
-    assert spec.padded_capacity % ps == 0, (
-        f"store padded capacity {spec.padded_capacity} not divisible by the "
-        f"mesh ps size {ps} — build the store with this mesh"
-    )
-    rows = spec.padded_capacity // ps
-    assert logic.num_users % dp == 0, (logic.num_users, dp)
-    users_per_shard = logic.num_users // dp
-    updater = logic.updater
-    dtype = logic.dtype
-
-    def body(local_table, local_state, batch):
-        # batches MUST carry a "mask" key (shard_map's in_specs are a
-        # fixed pytree); partitioned_microbatches always emits one
-        users = batch["user"].astype(jnp.int32)
-        items = batch["item"].astype(jnp.int32)
-        ratings = batch["rating"].astype(dtype)
-        mask = batch["mask"]
-
-        # -- pull: each ps shard answers its rows, one psum assembles ----
-        ps_idx = jax.lax.axis_index(ps_axis)
-        lo = ps_idx * rows
-        rel = items - lo
-        hit = (rel >= 0) & (rel < rows)
-        vals = jnp.take(local_table, jnp.clip(rel, 0, rows - 1), axis=0)
-        vals = jnp.where(hit[:, None], vals, jnp.zeros_like(vals))
-        pulled = jax.lax.psum(vals, ps_axis)
-
-        # -- local user state (alignment contract: users live here) ------
-        dp_idx = jax.lax.axis_index(dp_axis)
-        ulo = dp_idx * users_per_shard
-        urel = users - ulo
-        uvalid = (urel >= 0) & (urel < users_per_shard) & mask
-        urel = jnp.clip(urel, 0, users_per_shard - 1)
-        user_vecs = jnp.take(local_state, urel, axis=0)
-
-        user_delta, item_delta, pred = updater.delta(ratings, user_vecs, pulled)
-        um = uvalid[:, None].astype(dtype)
-        local_state = local_state.at[urel].add(user_delta * um)
-
-        # -- push: all_gather the microbatch over dp, local scatter ------
-        # gate on uvalid, not mask: an out-of-partition user's item delta
-        # was computed from the wrong (clipped) user row and must be
-        # dropped, matching the docstring's contract-violation semantics
-        g_items = jax.lax.all_gather(items, dp_axis, tiled=True)
-        g_deltas = jax.lax.all_gather(
-            item_delta * uvalid[:, None].astype(dtype), dp_axis, tiled=True
-        )
-        rel2 = g_items - lo
-        hit2 = (rel2 >= 0) & (rel2 < rows)
-        g_deltas = jnp.where(hit2[:, None], g_deltas, jnp.zeros_like(g_deltas))
-        local_table = local_table.at[jnp.clip(rel2, 0, rows - 1)].add(
-            g_deltas.astype(local_table.dtype)
-        )
-
-        out = {"prediction": pred, "error": (ratings - pred) * uvalid}
-        return local_table, local_state, out
-
-    batch_spec = {
-        "user": P(dp_axis),
-        "item": P(dp_axis),
-        "rating": P(dp_axis),
-        "mask": P(dp_axis),
-    }
-    return jax.shard_map(
-        body,
-        mesh=mesh,
-        in_specs=(P(ps_axis, None), P(dp_axis, None), batch_spec),
-        out_specs=(
-            P(ps_axis, None),
-            P(dp_axis, None),
-            {"prediction": P(dp_axis), "error": P(dp_axis)},
-        ),
-        check_vma=False,
-    )
-
-
 class MFWorkerLogic(WorkerLogic):
     """Event-API MF worker — the literal reference programming model
     (SURVEY.md §3.2): buffer the rating, pull the item vector, on answer run
@@ -412,6 +433,6 @@ __all__ = [
     "SGDUpdater",
     "OnlineMatrixFactorization",
     "MFWorkerLogic",
-    "make_locality_mf_step",
+    "worker_block_rows",
     "ps_online_mf",
 ]

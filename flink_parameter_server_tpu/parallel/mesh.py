@@ -58,8 +58,18 @@ def make_mesh(
     return Mesh(arr, axis_names)
 
 
+def worker_count(mesh: Optional[Mesh], dp_axis: str = DP_AXIS) -> int:
+    """Workers a mesh holds: the size of its ``dp`` axis; 1 without a mesh
+    or without that axis."""
+    if mesh is None or dp_axis not in mesh.axis_names:
+        return 1
+    return mesh.shape[dp_axis]
+
+
 def single_device_mesh(axis_names: Tuple[str, str] = (DP_AXIS, PS_AXIS)) -> Mesh:
     return Mesh(np.array(jax.devices()[:1]).reshape(1, 1), axis_names)
 
 
-__all__ = ["DP_AXIS", "PS_AXIS", "make_mesh", "single_device_mesh"]
+__all__ = [
+    "DP_AXIS", "PS_AXIS", "make_mesh", "single_device_mesh", "worker_count",
+]

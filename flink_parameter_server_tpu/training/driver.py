@@ -363,7 +363,9 @@ class StreamingDriver:
         128 rows its write-back moved to do so: ``store_rule_keys``,
         ``store_rule_rows``, ``store_rule_tiles``) and a logic of ragged key
         bags (the live lanes of its keys and all of them: ``bag_live_keys``,
-        ``bag_padded_keys``).  A fetch of a few scalars, made only where the
+        ``bag_padded_keys``) and keyed workers (the live records the last
+        dispatch dropped because they reached the wrong worker:
+        ``keyed_misrouted``, 0 behind the router).  A fetch of a few scalars, made only where the
         outputs are fetched anyway: at the metrics cadence, which syncs the
         step, and once after the loop has ended."""
         if self.registry is None or not isinstance(outs, dict):
@@ -381,6 +383,12 @@ class StreamingDriver:
             )
             self.registry.gauge("bag_padded_keys", component="train").set(
                 total(outs["bag_padded_keys"])
+            )
+        if "keyed_misrouted" in outs:
+            # keyed workers (models/matrix_factorization.py) count the live
+            # records that reached a worker whose block lacks their row
+            self.registry.gauge("keyed_misrouted", component="train").set(
+                total(outs["keyed_misrouted"])
             )
         if "ps_rule_rows" not in outs:
             return
@@ -477,7 +485,13 @@ class StreamingDriver:
                 n += 1
                 yield b
 
-        it = counting(iter(data), skip)
+        source = iter(data)
+        router = self.logic.key_router(registry=self.registry, tracer=tracer)
+        if router is not None:
+            # the keyed shuffle, on the ingest thread: what is counted,
+            # logged ahead and dispatched below is the keyed microbatch
+            source = router.route(source)
+        it = counting(source, skip)
         if cfg.prefetch:
             it = prefetch_iter(it, cfg.prefetch)
 

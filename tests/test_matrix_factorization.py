@@ -41,10 +41,24 @@ def test_mf_converges_single_device():
 
 
 def test_mf_sharded_matches_convergence(mesh):
+    from flink_parameter_server_tpu.models.matrix_factorization import (
+        OnlineMatrixFactorization,
+    )
+
     data = synthetic_ratings(128, 256, 8_000, rank=4, noise=0.01, seed=2)
-    stream = microbatches(data, batch_size=256, epochs=6, shuffle_seed=0)
+    # under the mesh the user factors lie with two keyed workers and the
+    # loop routes the stream to them (data/keyed.py): a microbatch is then
+    # two lane blocks, one a worker.  One device is given those same
+    # microbatches, so that both runs apply the same records in the same
+    # bulk-synchronous steps
+    keyed = list(
+        OnlineMatrixFactorization(128, 8, mesh=mesh).key_router().route(
+            microbatches(data, batch_size=256, epochs=6, shuffle_seed=0)
+        )
+    )
+    assert sum(int(b["mask"].sum()) for b in keyed) == 6 * 8_000
     res = ps_online_mf(
-        stream,
+        iter(keyed),
         num_users=128,
         num_items=256,
         dim=8,
@@ -56,10 +70,10 @@ def test_mf_sharded_matches_convergence(mesh):
     base = float(np.sqrt(np.mean(data["rating"] ** 2)))
     assert rmse < 0.6 * base, (rmse, base)
     # sharded run must match the unsharded run bit-for-bit-ish: same math,
-    # same init (deterministic per-id), different device layout only.
-    stream2 = microbatches(data, batch_size=256, epochs=6, shuffle_seed=0)
+    # same init (deterministic per-id), same microbatches; what differs is
+    # the device layout and the order in which a row's deltas are summed
     res_single = ps_online_mf(
-        stream2,
+        iter(keyed),
         num_users=128,
         num_items=256,
         dim=8,
@@ -69,6 +83,11 @@ def test_mf_sharded_matches_convergence(mesh):
     np.testing.assert_allclose(
         np.asarray(res.store.values()),
         np.asarray(res_single.store.values()),
+        atol=1e-4,
+    )
+    np.testing.assert_allclose(
+        np.asarray(res.worker_state)[:128],
+        np.asarray(res_single.worker_state),
         atol=1e-4,
     )
 

@@ -94,54 +94,3 @@ def test_mf_bfloat16_path():
     assert np.isfinite(rmse) and rmse < 0.8 * base  # bf16: looser bar
 
 
-def test_locality_mf_step_matches_auto_path(mesh):
-    """The fused shard_map MF step must produce the same table/state as
-    the jit-auto path when fed partition-aligned batches."""
-    from flink_parameter_server_tpu.core.store import ShardedParamStore
-    from flink_parameter_server_tpu.core.transform import make_train_step
-    from flink_parameter_server_tpu.models.matrix_factorization import (
-        OnlineMatrixFactorization,
-        SGDUpdater,
-        make_locality_mf_step,
-    )
-    from flink_parameter_server_tpu.utils.initializers import (
-        ranged_random_factor,
-    )
-
-    num_users, num_items = 64, 96
-    data = synthetic_ratings(num_users, num_items, 4000, rank=3, seed=4)
-    logic = OnlineMatrixFactorization(
-        num_users, 8, updater=SGDUpdater(0.05), mesh=mesh
-    )
-    make_store = lambda: ShardedParamStore.create(
-        num_items, (8,), init_fn=ranged_random_factor(1, (8,)), mesh=mesh
-    )
-    batches = list(
-        partitioned_microbatches(
-            data, 128, mesh.shape["dp"], key="user", capacity=num_users,
-            epochs=1, shuffle_seed=0,
-        )
-    )
-
-    # auto path
-    store_a = make_store()
-    step_a = jax.jit(make_train_step(logic, store_a.spec))
-    state_a = logic.init_state(jax.random.PRNGKey(0))
-    table_a = store_a.table
-    for b in batches:
-        table_a, state_a, _ = step_a(table_a, state_a, b)
-
-    # locality shard_map path
-    store_b = make_store()
-    step_b = jax.jit(make_locality_mf_step(logic, store_b.spec, mesh))
-    state_b = logic.init_state(jax.random.PRNGKey(0))
-    table_b = store_b.table
-    for b in batches:
-        table_b, state_b, out = step_b(table_b, state_b, b)
-
-    np.testing.assert_allclose(
-        np.asarray(table_a), np.asarray(table_b), atol=2e-5
-    )
-    np.testing.assert_allclose(
-        np.asarray(state_a), np.asarray(state_b), atol=2e-5
-    )

@@ -85,7 +85,7 @@ MODULE_SYMBOLS = {
     "flink_parameter_server_tpu.training.driver": ["TrainingDiverged"],
     "flink_parameter_server_tpu.models.matrix_factorization": [
         "SGDUpdater", "OnlineMatrixFactorization", "MFWorkerLogic",
-        "ps_online_mf", "make_locality_mf_step"],
+        "ps_online_mf", "worker_block_rows"],
     "flink_parameter_server_tpu.models.topk_recommender": [
         "query_topk", "make_mf_topk_step"],
     "flink_parameter_server_tpu.models.passive_aggressive": [

@@ -375,16 +375,22 @@ def test_refused_state_shape_on_tpu_warns_once_and_counts(
     assert row_update.refusal_count() == n0 + 1
 
 
-def test_mesh_keeps_the_xla_arm_silently(monkeypatch):
+@pytest.mark.parametrize("shape,want", [
+    ((1, 4), "xla"),          # one worker: GSPMD partitions the XLA scatter
+    ((2, 2), "sorted_rows"),  # keyed workers: each its own block, the kernel
+    ((4, 1), "sorted_rows"),
+])
+def test_under_a_mesh_keyed_workers_take_the_kernel_one_worker_keeps_xla(
+        monkeypatch, shape, want):
     from flink_parameter_server_tpu.parallel.mesh import make_mesh
 
-    mesh = make_mesh(worker_parallelism=2, ps_parallelism=2,
+    mesh = make_mesh(worker_parallelism=shape[0], ps_parallelism=shape[1],
                      devices=jax.devices()[:4])
     n0 = row_update.refusal_count()
     with warnings.catch_warnings():
         warnings.simplefilter("error")
         _, arm = _arm(monkeypatch, "tpu", 128, mesh=mesh)
-    assert arm == "xla" and row_update.refusal_count() == n0
+    assert arm == want and row_update.refusal_count() == n0
 
 
 @pytest.mark.parametrize("backend,dim,pinned,started", [

@@ -255,6 +255,62 @@ def test_mf_step_default_arm_on_tpu_is_the_row_kernel(
     )
 
 
+@pytest.mark.parametrize("staged", ["a_block_a_worker", "whole_on_every_chip"])
+def test_keyed_mf_step_at_hugewiki_uncut_on_four_described_chips(
+        topo, no_compile_cache, monkeypatch, staged):
+    """``mf-hugewiki-k128-dp4`` (chipbench/configs): 50,082,603 x 128 f32
+    user factors over ``dp = 4`` keyed workers.  Every chip updates its own
+    12,520,656-row block (6.41 GB, aliased) through the row kernel and
+    walks its own 65,536 records, never the 262,144 of the microbatch; the
+    step's one collective is the all-reduce of the workers' item deltas,
+    which carries the scope its reader finds it by.  So too where the batch
+    lies whole on every chip, as the benchmark stages its pool: the step
+    slices it where it lies, and only its four columns are that long."""
+    from flink_parameter_server_tpu.parallel.mesh import make_mesh
+
+    users, lanes = 50_082_603, 262_144
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = make_mesh(4, 1, devices=topo.devices)
+    logic = mfm.OnlineMatrixFactorization(
+        users, DIM, updater=mfm.SGDUpdater(5e-5), mesh=mesh)
+    assert (logic.rows_per_worker, logic.state_rows) == (12_520_656, 50_082_624)
+    spec = jax.eval_shape(lambda: ShardedParamStore.create(
+        ITEMS, (DIM,), dtype=jnp.float32, mesh=mesh)).spec
+
+    def on(shape, dtype, *axes):
+        return _shape(NamedSharding(mesh, PartitionSpec(*axes)), shape, dtype)
+
+    by = ("dp",) if staged == "a_block_a_worker" else ()
+    n0 = row_update.refusal_count()
+    compiled = jax.jit(
+        make_train_step(logic, spec), donate_argnums=(0, 1)
+    ).lower(
+        on((spec.padded_capacity, DIM), jnp.float32, "ps", None),
+        on((logic.state_rows, DIM), jnp.float32, "dp", None),
+        {"user": on((lanes,), jnp.int32, *by),
+         "item": on((lanes,), jnp.int32, *by),
+         "rating": on((lanes,), jnp.float32, *by),
+         "mask": on((lanes,), jnp.bool_, *by)},
+    ).compile()
+    assert row_update.refusal_count() == n0
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    kernels = re.findall(r" = f32\[(\d+),128\][^ ]* custom-call\([^\n]*sorted_row_update", text)
+    assert kernels == ["12520656"], kernels
+    collectives = [
+        line for line in text.splitlines() if re.search(
+            r" (all-reduce|all-gather|all-to-all|reduce-scatter|"
+            r"collective-permute)(-start)?\(", line)
+    ]
+    assert len(collectives) == 1, collectives
+    assert "f32[39784,128]" in collectives[0] and "all-reduce" in collectives[0]
+    assert "ps.push/ps.delta_reduce" in collectives[0]
+    # no chip holds rows for the whole microbatch
+    assert not re.findall(rf"= \w+\[{lanes},", text)
+    assert bool(re.findall(rf"= \w+\[{lanes}\]", text)) == (not by)
+    assert 6.41 * GB < mem.alias_size_in_bytes < 6.45 * GB
+    assert mem.temp_size_in_bytes < 0.25 * GB
+
+
 def test_padding_a_sharded_table_larger_than_a_chip_stays_on_its_shards(
         ps4, no_compile_cache):
     """``ShardedParamStore._place`` of a table pinned ``"dense"`` (cell 4's
