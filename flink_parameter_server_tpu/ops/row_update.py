@@ -55,9 +55,28 @@ rows, ``8 x W x 4`` contiguous bytes.  Block by block of the sorted lanes,
 the kernel reads the tile rows its lanes touch into VMEM (one DMA each),
 adds every lane's delta to its row's sublane, one float32 add a lane in the
 order of the batch (the sort is stable), and writes the tile rows back: a
-read-modify-write per touched tile row at HBM speed instead of XLA's serial
-one per lane (124 ns a 640-lane row on the v5e), with XLA's roundings, bit
-for bit.  It reads the table itself, so it takes no old rows.
+read-modify-write per touched tile row instead of XLA's serial one per lane
+(124 ns a 640-lane row on the v5e), with XLA's roundings, bit for bit.  It
+reads the table itself, so it takes no old rows.  Since PR 41 it walks as
+the third kernel does: descriptors and adds eight to a loop trip
+(:func:`_each`), a block's copies answered sixteen tile rows a wait, and
+three tile buffers, so that the next block's reads are in flight under this
+block's adds and the block before's writes.  A hot row's run spans many
+blocks, so one tile row may be open in a long stretch of them; but ids
+ascend, so only a block's FIRST tile row can be the block before's LAST,
+and that one is carried from slot to slot in VMEM: not written by the block
+before, not read by this one, written by the block that closes it.  Every
+touched tile row is then read once and written once a call and no two
+copies meet.  Its time is what one scalar core issues, far more than the
+bytes: on the v5e a DMA descriptor is 17 ns eight to a trip (22 issued one
+a trip), two a tile row, ~20 with its share of the waits and of what the
+copies still expose; a wait is 6.5 ns (one a tile row until they were
+folded); an add is two loads, an add and a store a register of the row,
+~6 ns a lane at three registers and 11.6 at five (20.8 one a trip).  Cell
+5's 114,688 lanes on 32.2 k tile rows take 2.81 ms where they took 4.20
+(its cold call's 1.30 GB of tile rows 2.15 ms, 1.6 at HBM speed), cell 7's
+126.4 k live lanes on 56.4 k tile rows 3.17 where they took 5.03 (PERF.md
+section 6, PR 41).
 
 **Narrow rows under a rule** (``core/store._push_rule``'s write-back of
 FTRL's ``(w, z, n)``: :func:`sorted_tile_set`) go through the third.  A
@@ -92,10 +111,10 @@ BLOCK = 256  # sorted lanes per grid step
 # v5e with Mosaic's own share in it: 98,304 lanes compile there and 131,072
 # run out of it (tests/test_tpu_compile.py)
 MAX_LANES = 98_304
-# the tile kernel keeps a block's tile rows in VMEM (block x 8 x W x 4 bytes:
-# 5.2 MB at 640 lanes) beside its pipelined deltas: past 1,280 lanes that is
-# over the 16 MiB Mosaic allows a kernel by default, and well under the
-# v5e's 128
+# the tile kernel keeps three blocks' tile rows in VMEM (3 x block x 8 x W x 4
+# bytes: 15.7 MB at 640 lanes, 9.4 at 384) beside its pipelined deltas (two
+# blocks of them): at 640 lanes that is over the 16 MiB Mosaic allows a
+# kernel by default, and well under the v5e's 128
 _TILE_VMEM_BYTES = 64 * 2**20
 _INT32_MAX = jnp.iinfo(jnp.int32).max
 
@@ -124,6 +143,13 @@ def tile_refusal(table_shape: Tuple[int, ...], dtype) -> Optional[str]:
         )
     if table_shape[0] % 8 != 0:
         return f"{table_shape[0]} rows are not whole tiles of 8"
+    held = (3 * 8 + 2) * BLOCK * table_shape[1] * 4
+    if held > _TILE_VMEM_BYTES:
+        return (
+            f"rows of {table_shape[1]} lanes: three blocks' tile rows and "
+            f"two blocks' deltas are {held} bytes of VMEM, over the "
+            f"{_TILE_VMEM_BYTES} the kernel asks for"
+        )
     return None
 
 
@@ -501,78 +527,124 @@ def row_add(
 # -- rows of several registers: a read-modify-write per touched tile row ------
 def _tile_kernel(tiles_ref, words_ref, counts_ref, dl_ref, table_ref, out_ref,
                  tile_buf, sem, *, block: int):
-    """One grid step = ``block`` sorted lanes, the kept ones first.
+    """One grid step = the adds of ``block`` sorted lanes (the kept ones
+    first), under the reads of the next block's tile rows and the writes of
+    the block before's.  Step ``g`` awaits the writes of block ``g - 3``
+    (block ``g`` reads into their slot), starts the reads of block ``g``,
+    and then works block ``g - 1``: awaits its reads, adds its lanes,
+    starts its writes.  Every step is that and no more: the grid is three
+    steps longer than the blocks, and three blocks of zeros before the
+    first block's counts and three after the last's make the steps at
+    either end do nothing where there is no block.
+
+    Ids ASCEND, so of a block's tile rows only the FIRST can be one an
+    earlier block has open (a hot row's run spans many blocks: one tile row
+    is then first and last of each); every other is strictly greater than
+    all an earlier block reads or writes.  Such a shared first tile row is
+    CARRIED: the block before does not write it, this block does not read
+    it, it is copied from that block's slot to this one's in VMEM after
+    that block's adds, and written by the block that closes it.  So within
+    a call every touched tile row is read once and written once, no two
+    copies touch the same bytes, and nothing orders them but the slots.
 
     tiles_ref: (N,) int32 SMEM (scalar prefetch) — at the head of each
       block's stretch, the tile rows (row // 8) its kept lanes touch,
       ascending, each once.
     words_ref: (N,) int32 SMEM — per kept lane, its tile row's place in
       the block's list (bits 0-7) and its row's sublane in the tile (8-10).
-    counts_ref: (2 N / block,) int32 SMEM — per block, how many tile rows
-      and how many kept lanes.
-    dl_ref: (block, W) f32 VMEM — the deltas, sorted.
+    counts_ref: (3 N / block + 18,) int32 SMEM — per block, how many tile
+      rows, how many kept lanes, and 1 where its first tile row is carried
+      over from the block before; blocks -3 to -1 and the three after the
+      last are zeros.
+    dl_ref: (block, W) f32 VMEM — the deltas of block ``g - 1``, sorted.
     table_ref / out_ref: the aliased (rows, W) table in HBM.
-    tile_buf: (block, 8, W) f32 VMEM — the block's tile rows.
+    tile_buf: (3, block, 8, W) f32 VMEM — three blocks' tile rows: one
+      being read, one being added to, one being written back.
+    sem: (2, 3) DMA semaphores — reads and writes of each slot.
+
+    What its time is made of on the v5e (PERF.md section 6, PR 41), all of
+    it issued by the one scalar core, one thing after the other: a DMA
+    descriptor 17 ns eight a trip (22 one a trip), two a tile row; an add
+    ~6 ns a lane at three registers a row, 11.6 at five (20.8 one a trip);
+    a wait 6.5 ns, one for sixteen tile rows.  The copies themselves run
+    under the adds: the three slots hide their latency, not their issue.
+    The body is short on purpose (ten loops, one branch, every index a
+    ``lax`` equation or two and nothing computed twice): every process that
+    runs the step traces it and lowers it, each unrolled copy by itself,
+    warm cache or not.
     """
     pl, pltpu = _pallas()
+    lax = jax.lax  # not jnp: an operator on a tracer is a jitted call to trace
 
     del table_ref  # aliased to out_ref
-    b = pl.program_id(0)
-    base = b * block
-    opened, kept = counts_ref[2 * b], counts_ref[2 * b + 1]
-    reads, writes = 0, 1  # the two semaphores
+    g = pl.program_id(0)
+    reads, writes = 0, 1
+    counts = lax.mul(g, 3)  # where block g - 3's three counts lie
 
-    def tile_row(j):
-        first = pl.multiple_of(tiles_ref[base + j] * 8, 8)
-        return out_ref.at[pl.ds(first, 8)]
+    def count(offset):  # blocks g - 3 (offsets 0-2) to g (offsets 9-11)
+        return counts_ref[lax.add(counts, offset)]
 
-    def await_copies(count, which):
-        # a DMA semaphore counts bytes: one wait the size of a tile row for
-        # each tile row moved
-        def one(j, _):
-            pltpu.make_async_copy(
-                tile_buf.at[0], tile_buf.at[0], sem.at[which]
-            ).wait()
-            return 0
+    ahead = lax.rem(g, 3)  # the slot of block g, and of block g - 3
+    slot = lax.rem(lax.add(g, 2), 3)  # the slot of block g - 1
+    opened, kept = count(6), count(7)  # of block g - 1
+    skip = count(11)  # 1 where block g's first tile row is carried over
+    stretch = lax.mul(g, block)  # block g's stretch of tiles_ref
+    base = lax.sub(stretch, block)  # block g - 1's, and of words_ref
 
-        jax.lax.fori_loop(0, count, one, 0)
+    def tile_row(i):
+        row = pl.multiple_of(lax.mul(tiles_ref[i], 8), 8)
+        return out_ref.at[pl.ds(row, 8)]
 
-    # the block before has written its tile rows back: its last may be this
-    # block's first, and the buffer is about to be filled again
-    @pl.when(b > 0)
-    def _previous():
-        await_copies(counts_ref[2 * (b - 1)], writes)
+    def await_copies(copies, which, of):
+        # a DMA semaphore counts bytes: a block's copies are answered by a
+        # wait the size of sixteen tile rows for every sixteen of them and
+        # one the size of a tile row for each of the rest
+        def wait_for(tiles):
+            def wait(j, _):
+                part = tile_buf.at[0, pl.ds(0, tiles)]
+                pltpu.make_async_copy(part, part, sem.at[which, of]).wait()
+                return 0
 
-    def read(j, _):
+            return wait
+
+        lax.fori_loop(0, lax.shift_right_logical(copies, 4), wait_for(16), 0)
+        lax.fori_loop(0, lax.bitwise_and(copies, 15), wait_for(1), 0)
+
+    # block g reads into the slot the third block before wrote from: every
+    # tile row of that block but a last one that block g - 2 carried on
+    await_copies(lax.sub(count(0), count(5)), writes, ahead)
+    first = lax.add(stretch, skip)
+
+    def read(j):  # every tile row but a carried first one
         pltpu.make_async_copy(
-            tile_row(j), tile_buf.at[j], sem.at[reads]
+            tile_row(lax.add(first, j)), tile_buf.at[ahead, lax.add(skip, j)],
+            sem.at[reads, ahead],
         ).start()
-        return 0
 
-    jax.lax.fori_loop(0, opened, read, 0)
-    await_copies(opened, reads)
+    _each(lax.sub(count(9), skip), read)
+    await_copies(lax.sub(opened, count(8)), reads, slot)
 
-    def add(lane, _):
+    def add(lane):
         # one float32 add a lane, in the order of the batch: what XLA's
         # scatter-add and a plain ``np.add.at`` do, rounding for rounding
-        word = words_ref[base + lane]
-        at = (word & 255, pl.ds(word >> 8, 1), slice(None))
-        tile_buf[at] = tile_buf[at] + dl_ref[pl.ds(lane, 1), :]
-        return 0
+        word = words_ref[lax.add(base, lane)]
+        at = (slot, lax.bitwise_and(word, 255),
+              pl.ds(lax.shift_right_logical(word, 8), 1), slice(None))
+        tile_buf[at] = lax.add(tile_buf[at], dl_ref[pl.ds(lane, 1), :])
 
-    jax.lax.fori_loop(0, kept, add, 0)
+    _each(kept, add)
 
-    def write(j, _):
+    @pl.when(lax.gt(skip, 0))
+    def _carry():  # the open tile row, as the adds left it, to the next slot
+        tile_buf[ahead, 0] = tile_buf[slot, lax.sub(opened, 1)]
+
+    def write(j):  # every tile row but a last one that block g carries on
         pltpu.make_async_copy(
-            tile_buf.at[j], tile_row(j), sem.at[writes]
+            tile_buf.at[slot, j], tile_row(lax.add(base, j)),
+            sem.at[writes, slot],
         ).start()
-        return 0
 
-    jax.lax.fori_loop(0, opened, write, 0)
-
-    @pl.when(b == pl.num_programs(0) - 1)
-    def _own():
-        await_copies(opened, writes)
+    _each(lax.sub(opened, skip), write)
 
 
 def _tile_plan(sorted_ids: Array, rows: int, block: int):
@@ -596,10 +668,18 @@ def _tile_plan(sorted_ids: Array, rows: int, block: int):
         (jnp.where(opens, local, local + block), tile), dimension=1,
         num_keys=1,
     )[1]
+    # a block's first tile row is carried over where the last lane of the
+    # block before lies in it (kept, as every lane before a kept one is)
+    last = jnp.concatenate([jnp.full((1,), -1, tile.dtype), tile[:-1, -1]])
+    carried = kept[:, 0] & (tile[:, 0] == last)
     counts = jnp.stack(
         [jnp.sum(opens, axis=1, dtype=jnp.int32),
-         jnp.sum(kept, axis=1, dtype=jnp.int32)], axis=1,
+         jnp.sum(kept, axis=1, dtype=jnp.int32),
+         carried.astype(jnp.int32)], axis=1,
     )
+    # the grid's first and last three steps work blocks that are not there
+    edge = jnp.zeros((3, 3), jnp.int32)
+    counts = jnp.concatenate([edge, counts, edge])
     return tiles.reshape(-1), words.reshape(-1), counts.reshape(-1)
 
 
@@ -620,17 +700,33 @@ def sorted_tile_add(
     order; a dropped lane's may be anything.  In place when the enclosing
     jit donates the table; an eager call copies it first.  Off the TPU the
     kernel is interpreted.
-    """
-    pl, pltpu = _pallas()
 
+    The plan and the kernel are one jitted function, inlined where it is
+    called: the calls a batch takes have one shape, so a step traces the
+    kernel once and lowers it once (equal equations share a lowering), not
+    once a call, and its text stays what separate calls give.  Every
+    process that runs the step pays both, warm cache or not: traced a call,
+    this walk cost cell 5 a second of set-up.
+    """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    rows, width = table.shape
-    n = sorted_ids.shape[0]
-    why = tile_refusal(table.shape, table.dtype) or _too_many(n)
+    why = tile_refusal(table.shape, table.dtype) or _too_many(
+        sorted_ids.shape[0])
     if why is not None and not interpret:
         raise ValueError(f"sorted_tile_add: {why}")
-    block = BLOCK
+    return _tile_add(table, sorted_ids, deltas, block=BLOCK,
+                     interpret=interpret)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("block", "interpret"), inline=True)
+def _tile_add(table, sorted_ids, deltas, *, block: int, interpret: bool):
+    pl, pltpu = _pallas()
+
+    rows, width = table.shape
+    n = sorted_ids.shape[0]
+    if n == 0:
+        return table
     sorted_ids = sorted_ids.astype(jnp.int32)
     deltas = deltas.astype(jnp.float32)
     pad = -n % block
@@ -640,20 +736,23 @@ def sorted_tile_add(
         )
         deltas = jnp.pad(deltas, ((0, pad), (0, 0)))
     tiles, words, counts = _tile_plan(sorted_ids, rows, block)
-    if not isinstance(table, jax.core.Tracer):
-        table = jnp.copy(table)
 
+    blocks = (n + pad) // block
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=((n + pad) // block,),
+        grid=(blocks + 3,),  # step g works block g - 1: `_tile_kernel`
         in_specs=[
-            pl.BlockSpec((block, width), lambda b, *_: (b, 0)),
+            pl.BlockSpec(
+                (block, width),
+                lambda g, *_: (
+                    jax.lax.min(jax.lax.max(g - 1, 0), blocks - 1), 0),
+            ),
             pl.BlockSpec(memory_space=pl.ANY),  # the table stays in HBM
         ],
         out_specs=pl.BlockSpec(memory_space=pl.ANY),
         scratch_shapes=[
-            pltpu.VMEM((block, 8, width), jnp.float32),
-            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.VMEM((3, block, 8, width), jnp.float32),
+            pltpu.SemaphoreType.DMA((2, 3)),
         ],
     )
     return pl.pallas_call(

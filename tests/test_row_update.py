@@ -267,6 +267,107 @@ def test_eager_scatter_add_leaves_the_callers_table_alone():
     assert float(out[5, 0]) == 2.0 and float(out[4, 0]) == 1.0
 
 
+# The tile add kernel's walk: three blocks' tile rows in VMEM, reads a block
+# ahead, a tile row that consecutive blocks share carried from slot to slot.
+ADD_ROWS = 8 * 96  # 96 tile rows
+ADD_CASES = [
+    "a_hot_row_over_three_blocks", "a_tile_row_two_blocks_share",
+    "a_tile_row_four_blocks_share", "a_block_that_opens_no_tile_row",
+    "first_and_last_tile_row", "every_lane_dropped", "one_lane",
+    "several_calls",
+]
+
+
+def _add_case(name, rng):
+    """The kept ids of one named case in SORTED order (the batch holds them
+    shuffled) and how many dropped lanes the batch carries beside them."""
+    rows = ADD_ROWS
+    if name == "a_hot_row_over_three_blocks":
+        # sorted lanes 40-1039 are row 77: blocks 0-4 hold it, blocks 1, 2
+        # and 3 nothing else (they read no tile row and write none)
+        return np.concatenate([
+            np.arange(40), np.full(1000, 77), np.arange(300, 500)]), 9
+    if name == "a_tile_row_two_blocks_share":
+        # lanes 250-261 lie in tile row 40 (rows 320-327), six a side of
+        # the first block's end
+        return np.concatenate([
+            np.arange(250), np.repeat(np.arange(320, 326), 2),
+            np.arange(400, 500)]), 0
+    if name == "a_tile_row_four_blocks_share":
+        # 900 lanes over the eight rows of tile row 12, from sorted lane 48
+        # on: blocks 0, 1, 2 and 3
+        return np.concatenate([
+            np.arange(0, 400, 2), np.sort(rng.integers(96, 104, 900)),
+            np.arange(500, 620)]), 5
+    if name == "a_block_that_opens_no_tile_row":
+        # 300 kept lanes, then a block and a half of dropped ones
+        return np.sort(rng.choice(rows, 300, replace=False)), 468
+    if name == "first_and_last_tile_row":
+        return np.array([0, 0, 3, 7, rows - 8, rows - 1, rows - 1]), 2
+    if name == "every_lane_dropped":
+        return np.zeros((0,), np.int64), 300
+    if name == "one_lane":
+        return np.array([333]), 0
+    if name == "several_calls":
+        # 512 lanes a call: row 301's run and tile row 37 lie across the
+        # first boundary, tile row 60 across the second
+        return np.concatenate([
+            np.arange(0, 500), np.full(40, 301), np.arange(302, 480),
+            np.repeat(np.arange(480, 488), 60)]), 30
+    raise KeyError(name)
+
+
+@pytest.mark.parametrize("width,name", [
+    (256, name) for name in ADD_CASES
+] + [
+    (width, name)
+    for width in (384, 640)
+    for name in ("a_hot_row_over_three_blocks", "a_tile_row_four_blocks_share",
+                 "several_calls")
+])
+def test_sorted_tile_add_is_numpys_add_at_in_batch_order_bit_for_bit(
+        width, name, monkeypatch):
+    """One float32 add a lane onto its row, in the order the batch holds
+    the lanes: every bit of the table against ``np.add.at``."""
+    rng = np.random.default_rng([width, ADD_CASES.index(name)])
+    kept, dropped = _add_case(name, rng)
+    ids = rng.permutation(np.concatenate([
+        kept, np.full(dropped // 2, -1), np.full(dropped - dropped // 2,
+                                                 ADD_ROWS + 5),
+    ])).astype(np.int32)
+    table = rng.normal(size=(ADD_ROWS, width)).astype(np.float32)
+    deltas = rng.normal(size=(len(ids), width)).astype(np.float32)
+    live = (ids >= 0) & (ids < ADD_ROWS)
+    deltas[~live] = np.nan  # a dropped lane's delta is never read
+    if name == "several_calls":
+        monkeypatch.setattr(row_update, "MAX_LANES", 512)
+    sid, order = row_update.sort_by_row(jnp.asarray(ids), None, ADD_ROWS)
+    calls = row_update._calls(sid, order)
+    assert len(calls) == (3 if name == "several_calls" else 1)
+    got = jnp.asarray(table)
+    for _, s, o in calls:
+        got = row_update.sorted_tile_add(
+            got, s, jnp.asarray(deltas)[o], interpret=True)
+    want = table.copy()
+    np.add.at(want, ids[live], deltas[live])
+    np.testing.assert_array_equal(np.asarray(got), want)
+    if len(kept):  # the case is what its name says
+        flat = np.asarray(sid)
+        blocks = [
+            set(flat[lo:lo + 256][flat[lo:lo + 256] < ADD_ROWS] // 8)
+            for lo in range(0, len(flat), 256)
+        ]
+        shared = max(
+            sum(t in b for b in blocks) for t in set().union(*blocks)
+        )
+        assert shared >= {
+            "a_hot_row_over_three_blocks": 5, "a_tile_row_two_blocks_share": 2,
+            "a_tile_row_four_blocks_share": 4, "several_calls": 2,
+        }.get(name, 1)
+        if name == "a_block_that_opens_no_tile_row":
+            assert blocks[0] and not blocks[-1]
+
+
 # -- the MF step --------------------------------------------------------------
 def _mf_step_run(arm, cfg, batches):
     dry = cfg["dry_run"]

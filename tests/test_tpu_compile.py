@@ -174,7 +174,7 @@ def test_tile_kernel_compiles_at_the_w2v_cells_shapes(
         one_chip, no_compile_cache, width, lanes, calls):
     """``scatter_add`` of 114,688 lanes into 3,000,000 rows of 640 (and
     256) f32 lanes: two calls of the tile kernel (57,344 lanes each, their
-    scalars in SMEM, a block's 256 tile rows of 8 x 640 f32 in VMEM), the
+    scalars in SMEM, three blocks' 256 tile rows of 8 x 640 f32 in VMEM), the
     table aliased through both, no table-sized temporary; and one call of
     as many lanes as one call takes."""
     compiled = jax.jit(
@@ -191,6 +191,48 @@ def test_tile_kernel_compiles_at_the_w2v_cells_shapes(
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= W2V_VOCAB * width * 4
     assert mem.temp_size_in_bytes < 512 * 2 ** 20  # the sorted deltas
+
+
+@pytest.mark.parametrize("width,rows", [
+    (384, 7_038_744), (640, W2V_VOCAB), (2432, 1_000_000),
+])
+def test_tile_kernel_holds_three_blocks_of_tile_rows_at_the_lane_cap(
+        one_chip, no_compile_cache, width, rows):
+    """One call of ``MAX_LANES`` sorted lanes at cell 7's and cell 5's row
+    widths: three blocks' tile rows (``f32[3,256,8,W]``: 9.4 and 15.7 MB)
+    beside the pipelined deltas in VMEM, two int32 a lane and three a block
+    in SMEM; and at the widest row ``tile_refusal`` lets through (19
+    registers: 59.8 MB of tile rows, 64.7 MB with the deltas)."""
+    lanes = row_update.MAX_LANES
+    assert row_update.tile_refusal((rows, width), jnp.float32) is None
+    compiled = jax.jit(
+        lambda t, ids, dl: row_update.sorted_tile_add(
+            t, ids, dl, interpret=False),
+        donate_argnums=(0,),
+    ).lower(
+        _shape(one_chip, (rows, width), jnp.float32),
+        _shape(one_chip, (lanes,), jnp.int32),
+        _shape(one_chip, (lanes, width), jnp.float32),
+    ).compile()
+    found = re.findall(
+        r" custom-call\([^\n]*sorted_row_update_tiles", compiled.as_text())
+    assert len(found) == 1, len(found)
+
+
+def test_tile_refusal_names_a_width_whose_three_buffers_do_not_fit(one_chip):
+    """20 registers a row: three blocks' tile rows and two of deltas are
+    68.2 MB, over the 64 MiB the kernel asks Mosaic for; the refusal says
+    so, ``sorted_tile_add`` raises it, and a store of such rows keeps XLA's
+    scatter-add (``core/store._tile_kernel_takes`` reads the refusal)."""
+    why = row_update.tile_refusal((1024, 2560), jnp.float32)
+    assert why is not None and "VMEM" in why and "2560" in why
+    with pytest.raises(ValueError, match="VMEM"):
+        jax.jit(lambda t, ids, dl: row_update.sorted_tile_add(
+            t, ids, dl, interpret=False)).lower(
+            _shape(one_chip, (1024, 2560), jnp.float32),
+            _shape(one_chip, (256,), jnp.int32),
+            _shape(one_chip, (256, 2560), jnp.float32),
+        )
 
 
 def _compiled_mf_step(one_chip, monkeypatch, batch_size):
