@@ -753,6 +753,22 @@ class Smoke:
         if self.mesh is not None:
             store_case("store_push_packed_d64_dp2xps2", self.mesh)
 
+        # the packed pull's lane slice (ops/packed's one kernel) at FM's 17
+        # lanes, seven rows to a physical row: whole blocks and a ragged
+        # rest, against the select arm bit for bit
+        from flink_parameter_server_tpu.ops import packed
+
+        block_p = packed.SLICE_BLOCK if not self.dry_run else 256
+        rows_p = normal((2 * block_p + 300, 128))
+        ids_p = jnp.asarray(rng.integers(0, 10 ** 6, len(rows_p)), jnp.int32)
+        self._kernel_case(
+            "packed_lane_slice_d17_f32",
+            lambda r, i: packed.sub_row_slice_kernel(
+                r, i, 17, block=block_p, interpret=interpret),
+            (rows_p, ids_p),
+            close(packed._sub_row_slice(rows_p, ids_p, 17), 0.0),
+        )
+
         # splash flash attention: forward, gradient, and under shard_map
         B, T, H, D = 2, self.sizes.flash_seq, 4, 64
         q, k, v = (normal((B, T, H, D), 0.5, jnp.bfloat16) for _ in range(3))

@@ -304,18 +304,18 @@ def pull(spec: StoreSpec, table: Array, ids: Array) -> Array:
     key bag: ``models/fasttext.py``), reads row 0, and its logic masks what
     it reads.
     Packed layout: one gather of whole 128-lane physical rows, then the
-    lane slice as ``k`` static slices chosen by a ``select`` on
-    ``id % k`` (no per-element gather — see ops/packed.py); under a mesh
-    each shard slices what it gathered before the one all-reduce
-    (:func:`_packed_pull_on_shards`)."""
+    lane slice down to sub-row ``id % k``, by selects and never a gather of
+    elements (ops/packed.py; :func:`_slice_kernel_takes` reads its arm);
+    under a mesh each shard slices what it gathered before the one
+    all-reduce (:func:`_packed_pull_on_shards`)."""
     ids = jnp.clip(ids.astype(jnp.int32), 0, spec.padded_capacity - 1)
     if spec.layout == "packed":
         from ..ops.packed import packed_pull
-
+        flat, kernel = ids.reshape(-1), _slice_kernel_takes(spec, ids.size)
         if spec.num_shards > 1:
-            vals = _packed_pull_on_shards(spec, table, ids.reshape(-1))
+            vals = _packed_pull_on_shards(spec, table, flat, kernel)
         else:
-            vals = packed_pull(table, ids.reshape(-1), spec.row_width)
+            vals = packed_pull(table, flat, spec.row_width, kernel)
         return vals.reshape(ids.shape + spec.value_shape)
     if spec.tile_lanes > spec.row_width:
         return _narrow_pull(table, ids, spec.row_width)
@@ -689,18 +689,53 @@ def _push_add_over_workers(
     return table + total
 
 
+def _slice_kernel_takes(spec: StoreSpec, n: Optional[int] = None) -> bool:
+    """Whether the packed pull of ``n`` ids slices the rows it gathered
+    through ``ops/packed.sub_row_slice_kernel`` instead of XLA's selects,
+    read from what the spec and the batch hold, as
+    :func:`_tile_kernel_takes` reads the push's arm: a TPU, rows packed
+    several to a physical row, float32, no mesh or a table sharded over it
+    (each shard then slices what it gathered inside a ``shard_map``, where
+    the kernel sees a plain array; a mesh with one shard leaves the pull to
+    GSPMD), and a block or more of ids to a shard.  Static per compiled
+    step.  Such a pull that the kernel REFUSES (bfloat16; an eager ``pull``
+    of a few thousand rows, which then compiles no kernel) keeps the select
+    arm, counted and warned of once a row shape.  ``n`` None asks whether
+    ANY pull of the store may take the kernel
+    (:func:`_preload_tile_kernel`) and notes nothing."""
+    from ..ops import packed
+
+    if (jax.default_backend() != "tpu" or spec.pack == 1
+            or (spec.mesh is not None and spec.num_shards == 1)):
+        return False
+    if n is None:
+        return packed.slice_refusal(
+            packed.SLICE_BLOCK, spec.dtype, spec.row_width) is None
+    if spec.mesh is not None:
+        # a shard slices the lanes of its worker (`_packed_pull_on_shards`)
+        workers = spec.mesh.size // spec.num_shards
+        n = n // workers if n % workers == 0 else n
+    return _taken_or_noted(
+        spec, "the lane slice of a packed pull",
+        packed.slice_refusal(n, spec.dtype, spec.row_width),
+    )
+
+
 def _preload_tile_kernel(spec: StoreSpec) -> None:
-    """Where a store is made whose pushes will trace a tile kernel: have
+    """Where a store is made whose pushes or pulls will trace a kernel: have
     Pallas imported by then, beside the table's staging (the import is ~1 s
     that the first trace of the step else pays)."""
-    if _tile_kernel_takes(spec) or _set_kernel_takes(spec):
+    if (_tile_kernel_takes(spec) or _set_kernel_takes(spec)
+            or _slice_kernel_takes(spec)):
         from ..ops.row_update import preload
 
         preload()
 
 
-@functools.partial(jax.jit, static_argnums=(0,))
-def _packed_pull_on_shards(spec: StoreSpec, table: Array, ids: Array) -> Array:
+@functools.partial(jax.jit, static_argnums=(0, 3))
+def _packed_pull_on_shards(
+    spec: StoreSpec, table: Array, ids: Array, kernel: bool = False
+) -> Array:
     """The packed pull of ``ids`` (flat, pre-clipped) from a table sharded
     over ``ps``: each shard gathers physical rows of its own block, slices
     them down to the logical row and zeroes the rows it does not own; the
@@ -712,9 +747,10 @@ def _packed_pull_on_shards(spec: StoreSpec, table: Array, ids: Array) -> Array:
     to place: a ``psum`` inside it reduces the rows as the slice leaves
     them, row-major and padded to 128 lanes again on the TPU (16.7 ms a
     step against 5-8; PERF.md section 6, PR 31).  The ids stay split over
-    the mesh's other axes (``dp``) where the batch is.  Jitted for the
-    reason ``packed_pull`` is."""
-    from ..ops.packed import _sub_row_slice
+    the mesh's other axes (``dp``) where the batch is.  ``kernel``: the
+    slice's arm (:func:`_slice_kernel_takes`).  Jitted for the reason
+    ``packed_pull`` is."""
+    from ..ops.packed import sub_row_slice
 
     mesh, ps = spec.mesh, spec.ps_axis
     k, rows = spec.pack, spec.rows_per_shard
@@ -728,8 +764,9 @@ def _packed_pull_on_shards(spec: StoreSpec, table: Array, ids: Array) -> Array:
         # rows of other shards wrap round to rows spread over this block:
         # clipped, they would all be its first or its last row, and a
         # gather that keeps hitting one row takes twice as long a row
-        vals = _sub_row_slice(
-            jnp.take(block, rel, axis=0, mode="wrap"), ids, spec.row_width
+        vals = sub_row_slice(
+            jnp.take(block, rel, axis=0, mode="wrap"), ids, spec.row_width,
+            kernel,
         )
         return jnp.where(mine[:, None], vals, jnp.zeros_like(vals))[None]
 
@@ -738,6 +775,7 @@ def _packed_pull_on_shards(spec: StoreSpec, table: Array, ids: Array) -> Array:
         mesh=mesh,
         in_specs=(P(ps, None), P(others or None)),
         out_specs=P(ps, others or None, None),
+        check_vma=not kernel,  # a Pallas call states no varying axes
     )(table, ids).sum(axis=0)
 
 

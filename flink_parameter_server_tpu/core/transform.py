@@ -337,6 +337,10 @@ def make_train_step(logic: BatchedWorkerLogic, spec) -> Callable:
             # a rule store's push counts its live keys and distinct rows on
             # the device; they leave the step with the logic's outputs
             out = {**out, **counted}
+        if spec.pack > 1 and isinstance(out, dict):
+            # which arm sliced the pulled rows, as this trace read it
+            took = store_mod._slice_kernel_takes(spec, ids.size)
+            out = {**out, "ps_slice_kernel": jnp.asarray(took, jnp.int32)}
         return table, state, out
 
     return step

@@ -15,7 +15,10 @@ row.  Logical row ``r`` maps to physical row ``r // k``, lane offset
 
   * **pull** = one gather of whole physical rows + the lane slice down to
     the logical row: ``k`` static slices and a ``select`` on ``r % k``
-    (one pass over a batch-sized buffer, no per-element gather),
+    (one pass over a batch-sized buffer, no per-element gather), in XLA
+    (:func:`_sub_row_slice`) or, for a batch of a block or more on a TPU,
+    in a Pallas kernel that writes the rows feature-major
+    (:func:`sub_row_slice_kernel`),
   * **push** = lane-shift each delta row to its offset (``k`` static pads
     and the same ``select``), then scatter-add at PHYSICAL row
     granularity — which is exactly the shape the pallas kernels want
@@ -24,8 +27,24 @@ row.  Logical row ``r`` maps to physical row ``r // k``, lane offset
     so the add semantics are unchanged, and Zipf-hot neighbours now
     share windows (fewer HBM round trips, fuller DMAs).
 
-Everything here is pure XLA; the pallas kernel consumes the packed form
-unmodified.  ``ShardedParamStore(layout="packed")`` wires it in.
+The push side is pure XLA and the scatter kernels consume the packed form
+unmodified; the pull's lane slice has the one kernel of this module.
+``ShardedParamStore(layout="packed")`` wires it in.
+
+**The lane slice as a kernel** (``core/store._slice_kernel_takes``).  XLA
+compiles :func:`_sub_row_slice` row-major: ``k`` lane rotates and selects
+over every 128-lane register of the gathered rows, then a copy of the
+``(n, d)`` result to the feature-major form ``{0,1}`` that whatever reads
+it wants (FM at 1,277,952 ids of 17 lanes: 7.3 + 1.1 ms a step on the v5e
+for 654 MB read and 87 kept, and no ``jnp`` form of the slice steers it:
+PERF.md section 6, PR 42).  :func:`sub_row_slice_kernel` reads a block of
+gathered rows once, transposes it in VMEM to ``(128, block)``, where the
+``k`` windows are ``d`` SUBLANES each and a block of 128 ids is
+``ceil(d / 8)`` registers a window and not 16, selects among them by
+``ids % k`` broadcast along sublanes and writes a ``(d, block)`` block of a
+``(d, n)`` output, whose transpose, a bitcast, is the ``(n, d)`` result
+held feature-major.  Selects only, so the same bits as
+:func:`_sub_row_slice`, NaN, infinities and -0.0 included.
 """
 from __future__ import annotations
 
@@ -39,6 +58,11 @@ import numpy as np
 Array = jax.Array
 
 LANES = 128
+# Gathered rows a grid step of `sub_row_slice_kernel`: 2 MB in, a (d, block)
+# block out, each double-buffered by the pipeline.  On the v5e, 1,277,952
+# rows of 17 lanes: 1.57 / 1.22 / 1.10 / 1.08 / 1.07 ms at 512 / 1,024 /
+# 2,048 / 4,096 / 8,192 (PERF.md section 6, PR 42).
+SLICE_BLOCK = 4096
 
 
 def pack_k(row_width: int) -> int:
@@ -99,10 +123,81 @@ def _sub_row_slice(rows: Array, ids: Array, row_width: int) -> Array:
     return out
 
 
-@functools.partial(jax.jit, static_argnums=(2,))
-def packed_pull(packed: Array, ids: Array, row_width: int) -> Array:
+def slice_refusal(n: int, dtype, row_width: int) -> Optional[str]:
+    """Why :func:`sub_row_slice_kernel` cannot take ``n`` gathered rows of
+    this dtype and logical width (None: it can)."""
+    if jnp.dtype(dtype) != jnp.float32:
+        return f"rows are {jnp.dtype(dtype).name}, the kernel moves float32"
+    if pack_k(row_width) == 1:
+        return f"rows of {row_width} lanes lie one to a physical row"
+    if n < SLICE_BLOCK:
+        return f"{n} rows are under one block of {SLICE_BLOCK}"
+    return None
+
+
+def _slice_kernel(t_ref, rows_ref, out_ref, *, k: int, d: int):
+    # (block, 128) -> (128, block): a window is now d sublanes of every lane
+    by_lane = rows_ref[...].T
+    t = jnp.broadcast_to(t_ref[...], (d, t_ref.shape[1]))
+    out = by_lane[:d]
+    for j in range(1, k):
+        out = jnp.where(t == j, by_lane[j * d:(j + 1) * d], out)
+    out_ref[...] = out
+
+
+def sub_row_slice_kernel(
+    rows: Array, ids: Array, row_width: int,
+    *, block: Optional[int] = None, interpret: Optional[bool] = None,
+) -> Array:
+    """:func:`_sub_row_slice` as one Pallas kernel (the module docstring
+    says how), bit for bit; the ``(n, d)`` result is the transpose of the
+    kernel's ``(d, n)`` output, so on the TPU it is held feature-major with
+    no copy.  ``n`` need not be whole blocks: the pipeline cuts the last
+    one.  Off the TPU the kernel is interpreted (``interpret=None``: by the
+    default backend)."""
+    from .row_update import _pallas
+
+    pl, pltpu = _pallas()
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    n, d, k = rows.shape[0], row_width, pack_k(row_width)
+    block = SLICE_BLOCK if block is None else block
+    t = (ids.astype(jnp.int32) % k).reshape(1, n)
+    out = pl.pallas_call(
+        functools.partial(_slice_kernel, k=k, d=d),
+        out_shape=jax.ShapeDtypeStruct((d, n), rows.dtype),
+        grid=(pl.cdiv(n, block),),
+        in_specs=[
+            pl.BlockSpec((1, block), lambda i: (0, i)),
+            pl.BlockSpec((block, LANES), lambda i: (i, 0)),
+        ],
+        out_specs=pl.BlockSpec((d, block), lambda i: (0, i)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)
+        ),
+        interpret=interpret,
+        name="packed_lane_slice",
+    )(t, rows)
+    return out.T
+
+
+def sub_row_slice(
+    rows: Array, ids: Array, row_width: int, kernel: bool = False
+) -> Array:
+    """The lane slice of gathered physical rows in the arm the caller read
+    (``core/store._slice_kernel_takes``)."""
+    if kernel:
+        return sub_row_slice_kernel(rows, ids, row_width)
+    return _sub_row_slice(rows, ids, row_width)
+
+
+@functools.partial(jax.jit, static_argnums=(2, 3))
+def packed_pull(
+    packed: Array, ids: Array, row_width: int, kernel: bool = False
+) -> Array:
     """Gather logical rows ``ids`` (pre-clipped) from the packed table:
-    one gather of whole 128-lane physical rows, then the lane slice.
+    one gather of whole 128-lane physical rows, then the lane slice
+    (``kernel``: :func:`sub_row_slice`).
     Jitted, so that a pull outside a jitted step (``store.pull(ids)`` by
     hand, a checkpoint's spot check) is one program and not ``3 k`` eager
     ones, each compiled on its first use."""
@@ -111,7 +206,7 @@ def packed_pull(packed: Array, ids: Array, row_width: int) -> Array:
     phys_vals = jnp.take(
         packed, ids // pack_k(row_width), axis=0, mode="clip"
     )
-    return _sub_row_slice(phys_vals, ids, row_width)
+    return sub_row_slice(phys_vals, ids, row_width, kernel)
 
 
 def lane_shift_deltas(deltas: Array, ids: Array, row_width: int) -> Array:
@@ -155,6 +250,9 @@ __all__ = [
     "pack_table",
     "unpack_table",
     "packed_pull",
+    "slice_refusal",
+    "sub_row_slice",
+    "sub_row_slice_kernel",
     "lane_shift_deltas",
     "lane_unshift",
     "packed_phys_ids",

@@ -179,7 +179,7 @@ def note_refusal(what: str, why: str) -> None:
     global _REFUSALS
     _REFUSALS += 1
     warnings.warn(
-        f"ops/row_update falling back to XLA scatter: {what}: {why}",
+        f"ops/row_update falling back to XLA: {what}: {why}",
         RuntimeWarning,
         stacklevel=3,
     )

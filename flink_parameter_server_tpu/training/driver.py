@@ -365,7 +365,9 @@ class StreamingDriver:
         bags (the live lanes of its keys and all of them: ``bag_live_keys``,
         ``bag_padded_keys``) and keyed workers (the live records the last
         dispatch dropped because they reached the wrong worker:
-        ``keyed_misrouted``, 0 behind the router).  A fetch of a few scalars, made only where the
+        ``keyed_misrouted``, 0 behind the router) and a store packed several
+        rows to a physical row (whether the step's pull took the slice
+        kernel: ``store_packed_slice_kernel``).  A fetch of a few scalars, made only where the
         outputs are fetched anyway: at the metrics cadence, which syncs the
         step, and once after the loop has ended."""
         if self.registry is None or not isinstance(outs, dict):
@@ -390,6 +392,12 @@ class StreamingDriver:
             self.registry.gauge("keyed_misrouted", component="train").set(
                 total(outs["keyed_misrouted"])
             )
+        if "ps_slice_kernel" in outs:
+            # a store of several rows to a physical row: whether the step's
+            # pull sliced them in ops/packed's kernel (core/transform.py)
+            self.registry.gauge(
+                "store_packed_slice_kernel", component="train"
+            ).set(float(np.max(np.asarray(outs["ps_slice_kernel"]))))
         if "ps_rule_rows" not in outs:
             return
         self.registry.gauge("store_rule_keys", component="train").set(

@@ -16,13 +16,16 @@ import pytest
 
 from flink_parameter_server_tpu.core import store as store_mod
 from flink_parameter_server_tpu.core.store import ShardedParamStore, StoreSpec
+from flink_parameter_server_tpu.ops import packed as packed_mod
 from flink_parameter_server_tpu.ops.packed import (
+    _sub_row_slice,
     lane_shift_deltas,
     lane_unshift,
     pack_k,
     pack_table,
     packed_pull,
     phys_width,
+    sub_row_slice_kernel,
     unpack_table,
 )
 
@@ -113,6 +116,116 @@ def test_select_forms_equal_the_gather_forms_bit_for_bit(d):
             jnp.take(packed, ids // pack_k(d), axis=0), ids, d)))
     np.testing.assert_array_equal(
         bits(packed_pull(packed, ids, d)), bits(table[np.asarray(ids)]))
+
+
+def _gathered_rows_with_specials(rng, n, d):
+    """``(rows, ids)``: ``n`` gathered physical rows with NaN, +-inf and
+    -0.0 in every sub-row, so in the selected one and in its neighbours."""
+    rows = rng.normal(0, 1, (n, 128)).astype(np.float32)
+    for bad in (np.nan, np.inf, -np.inf, -0.0):
+        rows[rng.integers(0, n, n // 2), rng.integers(0, 128, n // 2)] = bad
+    return jnp.asarray(rows), jnp.asarray(
+        rng.integers(0, 10 ** 6, n).astype(np.int32))
+
+
+# n against a block of 256 ids: whole blocks, one block and a remainder (of
+# whole 128-lane groups; of a ragged 44 lanes), many blocks and a remainder
+@pytest.mark.parametrize("n", [256, 1024, 256 + 128, 256 + 44, 256 * 5 + 129])
+@pytest.mark.parametrize("d", [1, 4, 17, 64, 100])
+def test_the_slice_kernel_equals_the_select_arm_bit_for_bit(d, n):
+    """``sub_row_slice_kernel`` (interpreted here) against
+    ``_sub_row_slice``: the same bits at every width, NaN, infinities and
+    -0.0 included, in the selected sub-row and beside it, whatever ``n``
+    leaves of the last block."""
+    rows, ids = _gathered_rows_with_specials(np.random.default_rng([d, n]), n, d)
+    got = sub_row_slice_kernel(rows, ids, d, block=256)
+    want = _sub_row_slice(rows, ids, d)
+    assert got.shape == want.shape == (n, d) and got.dtype == want.dtype
+    np.testing.assert_array_equal(
+        np.asarray(got).view(np.uint32), np.asarray(want).view(np.uint32))
+    assert np.isnan(np.asarray(want)).any() or d == 1
+    np.testing.assert_array_equal(
+        np.asarray(want).view(np.uint32),
+        np.asarray(_slice_by_gather(rows, ids, d)).view(np.uint32))
+
+
+@pytest.mark.parametrize("n,kernel", [(255, False), (256, True), (300, True)])
+def test_a_pull_under_one_block_keeps_the_select_arm(n, kernel, monkeypatch):
+    """The arm is read from the backend, the dtype, ``k`` and ``n``
+    (``core/store._slice_kernel_takes``): on a TPU a float32 pull of a
+    block or more of 17-lane rows takes the kernel, a shorter one (an eager
+    read-back) traces none and is noted once; here the backend is steered
+    and the kernel's call recorded."""
+    from flink_parameter_server_tpu.ops import row_update
+
+    monkeypatch.setattr(packed_mod, "SLICE_BLOCK", 256)
+    monkeypatch.setattr(store_mod, "_REFUSALS_NOTED", set())
+    store = ShardedParamStore.create(
+        1000, (17,), init_fn=_rand_init(17), layout="packed")
+    ids = jnp.asarray(np.random.default_rng(n).integers(0, 1000, n), jnp.int32)
+    want = np.asarray(store.pull(ids))
+    assert not store_mod._slice_kernel_takes(store.spec, n)  # this is a CPU
+    calls = []
+    real = packed_mod.sub_row_slice_kernel
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    # the backend is steered, the kernel still has to be interpreted
+    monkeypatch.setattr(
+        packed_mod, "sub_row_slice_kernel",
+        lambda rows, ids, d: calls.append(rows.shape) or real(
+            rows, ids, d, interpret=True))
+    n0 = row_update.refusal_count()
+    if kernel:
+        got = store.pull(ids)
+        assert row_update.refusal_count() == n0
+    else:
+        with pytest.warns(RuntimeWarning, match="under one block"):
+            got = store.pull(ids)
+        store.pull(ids[:100])  # once a row shape
+        assert row_update.refusal_count() == n0 + 1
+    assert calls == ([(n, 128)] if kernel else [])
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
+@pytest.mark.parametrize("arm", ["select", "kernel"])
+def test_the_driver_says_which_arm_sliced_its_pulled_rows(arm, monkeypatch):
+    """Where an operator reads it: the gauge ``store_packed_slice_kernel``,
+    from the scalar the step of a packed store carries among its outputs
+    (``ps_slice_kernel``: what its trace read).  A dense store's driver has
+    no such gauge."""
+    from flink_parameter_server_tpu.models import factorization_machine as fmm
+    from flink_parameter_server_tpu.telemetry.registry import MetricsRegistry
+    from flink_parameter_server_tpu.training.driver import (
+        DriverConfig, StreamingDriver)
+
+    if arm == "kernel":  # steered: this is a CPU, the kernel is interpreted
+        monkeypatch.setattr(
+            store_mod, "_slice_kernel_takes", lambda spec, n=None: True)
+        monkeypatch.setattr(packed_mod, "SLICE_BLOCK", 64)
+        packed_mod.packed_pull.clear_cache()
+    cfg = fmm.FMConfig(num_features=500, dim=16)
+    rng = np.random.default_rng(3)
+    batches = [{
+        "ids": rng.integers(0, 500, (32, 5)).astype(np.int32),
+        "values": rng.random((32, 5)).astype(np.float32),
+        "feat_mask": rng.random((32, 5)) > 0.2,
+        "label": (rng.integers(0, 2, 32) * 2 - 1).astype(np.float32),
+        "mask": np.ones(32, bool),
+    } for _ in range(3)]
+    seen = {}
+    for layout in ("auto", "dense"):
+        reg = MetricsRegistry()
+        driver = StreamingDriver(
+            fmm.FactorizationMachine(cfg), fmm.make_store(cfg, layout=layout),
+            config=DriverConfig(dump_model=False), registry=reg)
+        driver.run(batches)
+        seen[layout] = {k: v[0]["value"] for k, v in reg.snapshot().items()
+                        if k.startswith("store_")}
+    packed_mod.packed_pull.clear_cache()
+    assert seen == {
+        "auto": {"store_layout_packed": 1.0,
+                 "store_packed_slice_kernel": float(arm == "kernel")},
+        "dense": {"store_layout_packed": 0.0},
+    }
 
 
 @pytest.mark.parametrize("origin", ["numpy", "uncommitted", "committed"])

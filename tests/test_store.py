@@ -384,6 +384,110 @@ def test_push_pull_case_table_wide_rows(arm, shape, traffic, monkeypatch):
         assert calls and max(calls) <= 512, calls
 
 
+# Rows packed several to a physical row: on a TPU a float32 ``pull`` of a
+# block or more slices the rows it gathered in ``ops/packed``'s kernel, which
+# hands them over feature-major; everywhere else in XLA's selects.  Off the
+# TPU the chooser is steered in the test and the kernel is interpreted, at a
+# block the case table's batches fill; both arms are held to the same rows.
+@pytest.mark.parametrize("traffic", TRAFFIC)
+@pytest.mark.parametrize("width", [1, 4, 17, 64])
+@pytest.mark.parametrize("shards", ["one_shard", "dp_x_ps"])
+def test_push_pull_case_table_slice_kernel_arm(
+        shards, width, traffic, mesh, monkeypatch):
+    from flink_parameter_server_tpu.core import store as store_mod
+    from flink_parameter_server_tpu.ops import packed
+
+    rng = np.random.default_rng([width, TRAFFIC.index(traffic)])
+    values = _init_values(CAP, (width,))
+    store = ShardedParamStore.from_values(
+        jnp.asarray(values), layout="packed",
+        mesh=mesh if shards == "dp_x_ps" else None)
+    assert store.spec.pack == 128 // width
+    assert not store_mod._slice_kernel_takes(store.spec, 4096)  # a CPU
+    monkeypatch.setattr(
+        store_mod, "_slice_kernel_takes", lambda spec, n=None: True)
+    monkeypatch.setattr(packed, "SLICE_BLOCK", 32)
+    calls = []
+    real = packed.sub_row_slice_kernel
+    monkeypatch.setattr(
+        packed, "sub_row_slice_kernel",
+        lambda rows, *a, **kw: calls.append(rows.shape) or real(rows, *a, **kw))
+    ids, deltas, mask = _traffic(traffic, rng, CAP, (width,))
+    pushed = _push(
+        store, jnp.asarray(ids), jnp.asarray(deltas),
+        None if mask is None else jnp.asarray(mask))
+    # not `_pull`: a program traced for the other arm would be reused; and
+    # the jitted pulls forget the case before, whose trace made its call
+    packed.packed_pull.clear_cache()
+    store_mod._packed_pull_on_shards.clear_cache()
+    pulled = np.asarray(jax.jit(lambda st, i: st.pull(i))(
+        pushed, jnp.asarray(ids)))
+    # every shard slices its worker's half of the lanes, all of them alone
+    lanes = ids.size // 2 if shards == "dp_x_ps" else ids.size
+    assert calls == [(lanes, 128)], calls
+    assert pulled.shape == ids.shape + (width,)
+    rows = np.asarray(pushed.values())[np.clip(ids, 0, CAP - 1)]
+    below = ids < CAP
+    np.testing.assert_array_equal(
+        pulled[below].view(np.uint32), rows[below].view(np.uint32))
+    # and the select arm reads the same bits, clipped lanes included
+    monkeypatch.undo()
+    np.testing.assert_array_equal(
+        pulled.view(np.uint32),
+        np.asarray(pushed.pull(jnp.asarray(ids))).view(np.uint32))
+
+
+@pytest.mark.parametrize("why,shape,dtype,mesh_shape,n,noted", [
+    ("off_the_tpu", (17,), jnp.float32, None, 4096, False),
+    ("bfloat16", (17,), jnp.bfloat16, None, 4096, True),
+    ("k_is_1", (100,), jnp.float32, None, 4096, False),
+    ("k_is_1_wide", (2, 300), jnp.float32, None, 4096, False),
+    ("one_shard_mesh", (17,), jnp.float32, (4, 1), 4096, False),
+    ("a_worker_s_lanes_under_a_block", (17,), jnp.float32, (2, 4), 4094, True),
+])
+def test_the_slice_arm_says_no(
+        why, shape, dtype, mesh_shape, n, noted, mesh_devices, monkeypatch):
+    """What keeps ``_sub_row_slice``: the CPU; bfloat16 rows (noted and
+    counted, as the other arms' refusals are); rows that lie one to a
+    physical row, which have nothing to select; a mesh that does not shard
+    the table (the pull is GSPMD's); fewer ids a shard than a block.
+    Everything else on a TPU takes the kernel."""
+    from flink_parameter_server_tpu.core import store as store_mod
+    from flink_parameter_server_tpu.ops import packed, row_update
+    from flink_parameter_server_tpu.parallel.mesh import make_mesh
+
+    mesh = None
+    if mesh_shape is not None:
+        mesh = make_mesh(*mesh_shape, devices=mesh_devices[
+            :mesh_shape[0] * mesh_shape[1]])
+    spec = jax.eval_shape(lambda: ShardedParamStore.create(
+        1000, shape, dtype=dtype, layout="packed", mesh=mesh)).spec
+    monkeypatch.setattr(store_mod, "_REFUSALS_NOTED", set())
+    monkeypatch.setattr(packed, "SLICE_BLOCK", 2048)
+    n0 = row_update.refusal_count()
+    if why != "off_the_tpu":
+        assert not store_mod._slice_kernel_takes(spec, n)
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    if noted:
+        with pytest.warns(RuntimeWarning, match="lane slice of a packed pull"):
+            assert not store_mod._slice_kernel_takes(spec, n)
+    assert not store_mod._slice_kernel_takes(spec, n)
+    assert row_update.refusal_count() == n0 + noted
+    # asking for the store as a whole (the preload) notes nothing
+    store_mod._slice_kernel_takes(spec)
+    assert row_update.refusal_count() == n0 + noted
+    if why == "off_the_tpu":
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        assert store_mod._slice_kernel_takes(spec, n)
+        assert store_mod._slice_kernel_takes(spec)
+        assert row_update.refusal_count() == n0
+    if why == "a_worker_s_lanes_under_a_block":
+        # 2 workers: 2047 lanes each of 4094; 2048 each of 4096; 4095 do not
+        # split and every shard slices them all
+        assert store_mod._slice_kernel_takes(spec, 4096)
+        assert store_mod._slice_kernel_takes(spec, 4095)
+
+
 @pytest.mark.parametrize("layout", ["dense", "packed"])
 def test_push_pull_case_table_int32_exact_past_2_24(layout):
     """Counts are summed as integers: a float32 detour drops increments
@@ -482,7 +586,11 @@ def test_train_step_outputs_are_in_stream_order(case):
     table_b, _, out_b = step(
         store.table, state, {k: v[perm] for k, v in batch.items()})
     assert set(out_a) >= {"prediction"}
-    for name in out_a:
+    # a record's outputs; the step's own scalars (`ps_*`) are the same
+    per_record = [name for name in out_a if not name.startswith("ps_")]
+    for name in set(out_a) - set(per_record):
+        assert out_a[name].shape == () and out_a[name] == out_b[name]
+    for name in per_record:
         assert out_a[name].shape[0] == n
         np.testing.assert_allclose(
             np.asarray(out_a[name])[perm], np.asarray(out_b[name]),

@@ -516,6 +516,63 @@ def test_fm_step_on_one_chip_moves_whole_128_lane_rows(
     ), gathers
 
 
+@pytest.mark.parametrize("cell", ["cell_2", "cell_4"])
+def test_fm_step_on_a_tpu_slices_its_pulled_rows_in_the_kernel(
+        cell, fm1, ps4, one_chip, no_compile_cache, monkeypatch):
+    """What cells 2 and 4 run on the chip: asked for the backend, ``pull``
+    hands the 1,277,952 gathered rows to ``ops/packed``'s kernel (on four
+    chips inside ``_packed_pull_on_shards``' ``shard_map``, a call a
+    shard), nothing is refused, and no op of the step yields a
+    ``f32[1277952,17]`` ROW-major any more: the parent's 7-way select fusion
+    did, and a ``copy`` relaid it (7.34 + 1.08 ms a step on the v5e, PERF.md
+    section 6, PR 42).  The kernel's ``f32[17,1277952]`` reaches the flatten
+    through a bitcast (cell 4: through the ownership mask's select, in that
+    form); the one all-reduce keeps its operand, the table is updated in
+    place and the temporaries stay where the select arm's were."""
+    # code that asks for the backend still sees the CPU here: steer it
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    if cell == "cell_2":
+        spec, logic = fm1
+        table = _shape(one_chip, spec.table_shape(), jnp.float32)
+        batch = _fm_batch(one_chip)
+    else:
+        mesh, spec, logic = ps4
+        table = _shape(spec.sharding(), spec.table_shape(), jnp.float32)
+        batch = _fm_batch(NamedSharding(mesh, PartitionSpec()))
+    n0 = row_update.refusal_count()
+    assert store_mod._slice_kernel_takes(spec, FM_BATCH * FM_FIELDS)
+    compiled = jax.jit(
+        make_train_step(logic, spec), donate_argnums=(0, 1)
+    ).lower(table, (), batch).compile()
+    assert row_update.refusal_count() == n0
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes > 3.43 * GB  # in place
+    assert mem.temp_size_in_bytes < 1.4 * GB  # 1.322 / 1.321 here
+    text = compiled.as_text()
+    entry = text[text.index("ENTRY"):]
+    n = FM_BATCH * FM_FIELDS
+    calls = [
+        line for line in entry.splitlines()
+        if "tpu_custom_call" in line and "packed_lane_slice" in line
+    ]
+    assert len(calls) == 1, calls
+    assert f" = f32[17,{n}]{{1,0:" in calls[0], calls[0]
+    assert 'op_name="jit(step)/ps.pull/' in calls[0]
+    # the gathered rows come to it as the gather's fusion leaves them
+    assert re.search(rf"custom-call\(%[\w.\-]+, %fusion(\.\d+)?\)", calls[0])
+    assert f"f32[{n},17]{{1,0" not in text  # row-major, anywhere
+    assert not re.findall(rf" = f32\[{n},17\][^ ]* copy\(", text)
+    collectives = [
+        line for line in text.splitlines() if COLLECTIVE_OP.search(line)
+    ]
+    if cell == "cell_2":
+        assert not collectives
+    else:
+        assert len(collectives) == 1, collectives
+        assert f"%all-reduce = f32[{FM_BATCH},{FM_FIELDS},17]" in collectives[0]
+        assert 'op_name="jit(step)/ps.pull/' in collectives[0]
+
+
 def test_packing_fm_s_table_fits_the_chip_chunk_by_chunk(
         fm1, one_chip, no_compile_cache):
     """``ShardedParamStore._place`` packs cell 2's 49.1 M x 17 rows (4.72 GB
@@ -826,7 +883,10 @@ def test_the_mf_and_fm_cells_step_text_is_the_parents(fm1):
     traces the same ops gives the same text: cells 1 and 3 (MF, dense 128
     lanes) and cell 2 (FM, seven 17-lane rows to a 128-lane row) run the
     step PR 30 to PR 32 ran (PERF.md section 6 records both hashes).  A
-    change that means to move them brings its own."""
+    change that means to move them brings its own: PR 42 gave FM's step the
+    scalar ``ps_slice_kernel`` (which arm sliced the pulled rows; lowered
+    here, off the TPU, the arm and every other op are the parent's:
+    ``3913e9e902cfade8`` until then)."""
     shape = jax.ShapeDtypeStruct
     logic = mfm.OnlineMatrixFactorization(
         USERS, DIM, updater=mfm.SGDUpdater(2e-4))
@@ -850,7 +910,7 @@ def test_the_mf_and_fm_cells_step_text_is_the_parents(fm1):
     assert _step_text_sha(
         make_train_step(logic, spec),
         shape(spec.table_shape(), jnp.float32), (), batch,
-    ) == "3913e9e902cfade8"
+    ) == "62cb492a1f6ca10e"
 
 
 def test_w2v_table_is_initialised_in_place_from_a_seed_argument(
