@@ -3,8 +3,8 @@
 
 Drives the main path once through the entry points a user calls — online
 matrix factorisation: ``OnlineMatrixFactorization`` +
-``ShardedParamStore.create`` + ``StreamingDriver.run`` — at the full width
-``bench.py`` uses on a TPU (100,000 users x 131,072 items, dim 64,
+``ShardedParamStore.create`` + ``StreamingDriver.run`` — at one fixed,
+chip-sized shape (100,000 users x 131,072 items, dim 64,
 bfloat16 table, batch 65,536, Zipf(1.2) items, seed 0), then compiles every
 Pallas kernel the public API can reach and compares each with its XLA
 reference.  With four or more devices stage 1 runs on a dp=2 x ps=2 mesh,

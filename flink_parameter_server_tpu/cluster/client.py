@@ -22,9 +22,8 @@ Three bandwidth levers from the reference's sender stack
 
 Shards are contacted concurrently (persistent fan-out pool workers —
 :class:`_FanoutPool`; nothing is spawned per batch): a pull's wall
-time is the SLOWEST shard's round trip, not the sum — which is what
-makes the 1→2→4-shard scaling benchmark
-(``benchmarks/cluster_scaling.py``) a real scaling measurement.
+time is the SLOWEST shard's round trip, not the sum (not measured on
+the chip; no cell).
 
 Binary framing (``wire_proto="auto"``, the default — docs/cluster.md
 "Binary framing"): each connection opens with the ``hello bin v=1``

@@ -3,7 +3,7 @@
 PR 12's soak capacity curve was flat-to-inverted in shard count
 because every in-process shard thread shares ONE interpreter lock with
 every worker thread: adding shards added lock convoy, not capacity
-(``results/cpu/soak_capacity.md``).  This module runs each
+(a host-CPU soak; no cell).  This module runs each
 :class:`~.shard.ShardServer` in its OWN spawned process — its own
 interpreter, its own GIL, its own selectors event loop — so shard-side
 scatter/parse work runs in real OS-level parallelism with the workers

@@ -144,8 +144,8 @@ def shard_opt_state_constraint(
     allreduce becomes reduce_scatter, each replica runs the optimizer
     math only for its 1/dp parameter slice, and the updates rejoin the
     params — same collective bytes as the plain allreduce, but Adam's
-    m/v (8 bytes/param fp32) stop being replicated.  Measured
-    (benchmarks/zero1_memory.py, 35M-param LM, dp=8): GSPMD propagates
+    m/v (8 bytes/param fp32) stop being replicated.  Counted in bytes
+    (tests/test_zero1_memory.py, a small LM, dp=8): GSPMD propagates
     the constraint through ``apply_updates`` to the params OUTPUT too,
     so post-step params come back dp-sharded — steady-state memory
     matches :func:`fsdp_place` (0.125x replicated), with the weight

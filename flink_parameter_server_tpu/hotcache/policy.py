@@ -86,8 +86,8 @@ class LeasePolicy:
         Prefers the source's jax-free ``candidates`` path
         (``HotKeyAggregator.candidates``) over ``top_k``: the refresh
         runs next to serving hot paths, and an eager jax dispatch
-        holds the GIL for milliseconds — measured as the on-arm p99
-        tail in benchmarks/hotcache_storm.py before this existed."""
+        holds the GIL for milliseconds — the cache-on arm's p99 tail
+        under a hot-key storm before this existed."""
         fetch = getattr(self.source, "candidates", None)
         if fetch is None:
             fetch = self.source.top_k

@@ -5,8 +5,8 @@ exactly two futures: degrade gracefully for everyone, or collapse for
 everyone — queues grow without bound, retries amplify the offered
 load, and p99 explodes for *every* request, not just the excess.  This
 module is the repo's graceful-degradation toolkit, four mechanisms
-that compose (each is independently attachable; the soak A/B in
-``benchmarks/soak_capacity.py`` measures what they buy together):
+that compose (each is independently attachable; :mod:`.soak` switches
+them per arm, which is how what they buy together is measured):
 
   * :class:`OverloadGuard` — **priority-aware load shedding at the
     shard edge**.  Attached to a :class:`~..cluster.shard.ShardServer`,

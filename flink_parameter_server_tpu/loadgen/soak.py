@@ -6,8 +6,8 @@ This is ROADMAP item 4 made runnable: a seeded user population
 the PR-10 nemesis mesh underneath (every shard front door is a
 :class:`~..nemesis.proxy.ChaosProxy`, byte-for-byte the same splice
 ``nemesis/runner.py`` uses) and the overload-control plane
-(:mod:`.overload`) switchable per arm, which is what makes the
-capacity A/B in ``benchmarks/soak_capacity.py`` an experiment instead
+(:mod:`.overload`) switchable per arm, which is what makes a
+capacity A/B over it an experiment instead
 of a demo.
 
 Execution model:
@@ -52,8 +52,8 @@ read-id mapping come from the registered workload, and the push path
 can run the q8 codec (``wire_format="q8"``, bypassed for increment
 workloads) and the aggregation tree (``push_aggregate=True`` — one
 combined uplink push per train drain round, exactly-once on the
-uplink).  docs/workloads.md; the arms are recorded in
-``results/cpu/soak_capacity.md`` and the workload battery.
+uplink).  docs/workloads.md; tests/test_workloads.py runs the arms
+at toy sizes (not measured on the chip; no cell).
 """
 from __future__ import annotations
 
@@ -533,7 +533,7 @@ class SoakRunner:
             if cfg.link_delay_ms > 0:
                 for proxy in driver.mesh.values():
                     # request leg only: one delay per request burst,
-                    # the LAN-RTT model hotcache_storm.py established
+                    # a LAN round trip, modelled on the way in
                     proxy.set_delay(cfg.link_delay_ms, 0.0, "c2s")
             for g in range(cfg.generators):
                 sc, cache = self._make_serve_client(

@@ -33,8 +33,8 @@ jitted push, so a rebuilt table is bitwise the logged one
 :func:`~..replication.failover.verify_against_log`).
 
 ZeRO-1 fold-in (arXiv 2004.13336 via :mod:`..core.dense`, evidence
-``results/cpu/zero1_memory.json``: 0.125× replicated memory, identical
-loss): with ``momentum > 0`` the store keeps a velocity buffer — the
+``tests/test_zero1_memory.py``: 1/dp of replicated memory, counted in
+bytes): with ``momentum > 0`` the store keeps a velocity buffer — the
 optimizer state of its dense momentum update — created with
 ``zeros_like(table)`` (so it inherits the table's row-block sharding)
 and pinned there every step via

@@ -360,8 +360,8 @@ def run_scenario(
     :class:`~..telemetry.timeline.TimelineRecorder` built over the
     SAME registry: it samples for the duration of the run and every
     executed nemesis op is ``mark()``-ed onto its time axis, so
-    detector firings can be cross-referenced against fault onset (the
-    detection A/B in benchmarks/timeline_detection_ab.py)."""
+    detector firings can be cross-referenced against fault onset
+    (tests/test_timeline.py)."""
     reg = registry if registry is not None else MetricsRegistry()
     t0 = time.perf_counter()
     workload = _make_workload(scenario)

@@ -29,8 +29,8 @@ promoted in O(lag) when the primary dies or goes silent.
 See docs/elastic.md ("Replica chains") for the chain topology, the
 ack/lag semantics, the promote algorithm, and the read-staleness
 contract; docs/cluster.md documents the ``repl``/``replstate`` wire
-verbs.  Failover time is benchmarked against a full WAL rebuild by
-``benchmarks/failover_time.py``.
+verbs.  Failover time against a full WAL rebuild: not measured on the
+chip; no cell.
 """
 from .chain import ChainManager, ReplicaChain
 from .driver import ReplicatedClusterConfig, ReplicatedClusterDriver

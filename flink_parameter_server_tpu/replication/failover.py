@@ -6,8 +6,8 @@ point: a replacement rebuilds a dead shard by replaying its ENTIRE WAL
 stalls meanwhile); a promotion flips an already-warm follower in, and
 the only sequential work is the *lag* — the records the follower had
 logged but not applied, plus whatever unshipped tail can be salvaged
-from the dead primary's surviving disk.  ``benchmarks/failover_time.py``
-measures both on the same log length.
+from the dead primary's surviving disk.  (Neither is measured on the
+chip; no cell.)
 
 The algorithm (all under the driver's resize lock, one membership
 publish at the end — the same single-flip discipline as every other

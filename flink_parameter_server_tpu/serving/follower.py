@@ -12,8 +12,8 @@ It is a thin façade over a read-routed
 each shard's chain, honor the follower staleness contract (a lagging
 follower's ``err lagging`` falls back to the primary inside the
 client), and survive a promotion as a membership refresh — latency,
-never an error.  The chaos failover e2e test and
-``benchmarks/failover_time.py`` drive their "zero serving errors
+never an error.  The chaos failover e2e test
+(tests/test_replication.py) drives its "zero serving errors
 during failover" window through this service.
 """
 from __future__ import annotations

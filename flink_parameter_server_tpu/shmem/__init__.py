@@ -8,8 +8,8 @@ else: per-(client, shard-proc) SPSC ring pairs in
 ``multiprocessing.shared_memory`` carrying the SAME versioned frame
 layout as ``utils/frames.py`` byte for byte, negotiated per
 connection (``hello shm v=1`` → binary TCP → lines) with automatic
-fallback for non-co-located peers.  See docs/shmem.md; the 3-way
-numbers live in results/cpu/transport_ab.md.
+fallback for non-co-located peers.  See docs/shmem.md; not measured
+on the chip (no cell).
 
 Layering: ``ring`` and ``doorbell`` are dependency-free substrate;
 ``pump`` is the server half (imported lazily by ``utils/net.py`` on

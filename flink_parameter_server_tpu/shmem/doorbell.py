@@ -2,7 +2,7 @@
 
 TCP's wakeup primitive is the kernel: a blocked ``recv`` costs two
 scheduler round trips per request/response — the very floor the shm
-transport exists to remove (``results/cpu/transport_ab.md``).  Shared
+transport exists to remove (docs/shmem.md).  Shared
 memory has no kernel to ring, so the doorbell replaces it with a
 two-phase wait:
 

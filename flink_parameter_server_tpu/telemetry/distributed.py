@@ -94,7 +94,7 @@ class TraceCollector:
         col.add(client_tracer, "client")
         for i, t in enumerate(shard_tracers):
             col.add(t, f"shard-{i}")
-        col.export("results/cpu/merged_trace.json")
+        col.export("merged_trace.json")
 
     Each added ring becomes one Chrome-trace process lane (synthetic
     lane pids 1..N — several rings usually share one OS pid on the

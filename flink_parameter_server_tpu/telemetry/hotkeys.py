@@ -26,9 +26,9 @@ the same partial-top-K-then-merge shape ROADMAP item 3's serving
 fan-out needs, exercised here on sketch counters first.
 
 Everything is host-side numpy on the hot path (one ``np.add.at`` per
-observed batch); the overhead A/B in
-``benchmarks/telemetry_overhead.py`` holds the whole plane (tracing +
-sketch + SLO) under the 3% bar.
+observed batch); what the whole plane (tracing + sketch + SLO) costs
+a cell when it is on is PERF.md section 6's (PR 26: 7 % of cell 3's
+rate).
 """
 from __future__ import annotations
 

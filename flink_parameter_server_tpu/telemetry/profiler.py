@@ -43,9 +43,9 @@ Two measurement styles, one seam:
 Both are attribution, not load: a disabled profiler's ``timer()`` is a
 shared no-op (two attribute reads), and the sampler costs one frame
 walk per interval (default 100 ms — see :class:`StackSampler` for the
-measured tax curve on a single-core host) — the overhead A/B
-(``benchmarks/telemetry_overhead.py``) runs with both ON and the bar
-stays ≤ 3%.
+measured tax curve on a single-core host).  What it costs on the
+chip: not measured; no cell turns it on (PERF.md section 6 has the
+tracing plane's).
 """
 from __future__ import annotations
 
@@ -63,7 +63,7 @@ from .registry import MetricsRegistry, get_registry, json_line
 # everything else is measured at its call site.  The BINARY transport
 # (utils/frames.py) reuses these names — frame encode IS
 # client_serialize, frame decode IS server_parse — which is what keeps
-# the line-vs-binary A/B (results/cpu/transport_ab.md) directly
+# a line-vs-binary A/B of the two framings directly
 # comparable.  The vocabulary is pinned in lockstep with
 # ``tools/check_metric_lines.KNOWN_BUDGET_PHASES`` (a tier-1 test
 # compares the two), so a renamed/added phase must update the lint,

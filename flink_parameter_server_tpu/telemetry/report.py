@@ -3,8 +3,8 @@
 One page per run, not a log to grep: steps/sec, pull→push latency
 percentiles, serving QPS/p99, snapshot staleness, ingest reconnects,
 recovery episodes — pulled from the unified registry and written to
-``results/<platform>/run_report.{md,json}``.  docs/perf_status.md's
-rule: future bench deltas cite ``run_report.json``, so every number
+``results/<platform>/run_report.{md,json}``.  The rule it serves:
+a delta between runs cites ``run_report.json``, so every number
 here carries enough context (run_id, platform, wall clock) to be
 compared across rounds without re-deriving provenance.
 """
@@ -193,8 +193,8 @@ def build_run_report(
 def _latency_budget_section() -> Dict[str, Any]:
     """Per-verb phase budgets from the process profiler
     (telemetry/profiler.py) — empty when no phases were observed.
-    This is the section docs/perf_status.md cites as the required
-    evidence for the ROADMAP item 2 transport rework: it names the
+    This is the section a transport rework has to bring as its
+    evidence (docs/observability.md): it names the
     top cost center of a round with its % of round time."""
     from .profiler import get_profiler
 

@@ -41,7 +41,7 @@ On top ride two consumers fed inline at sample time:
 Surfaces: the TelemetryServer ``timeline`` path serves
 :meth:`TimelineRecorder.payload` live (``psctl watch`` /
 ``psctl timeline``); ``run_scenario``/``SoakRunner`` record timelines
-into ``results/<platform>/soak_timeline.{md,json}`` (linted by
+whose payload a caller may write out (linted by
 ``tools/check_metric_lines.py --timeline``); the run report grows a
 timeline section.  ``docs/observability.md`` documents the plane.
 """

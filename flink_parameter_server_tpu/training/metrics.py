@@ -2,7 +2,7 @@
 
 Reference parity (SURVEY.md §5 "Metrics / logging"): the reference exposes
 only Flink's operator metrics (throughput, backpressure).  The rebuild's
-north-star metrics (BASELINE.md) are measured here: updates/sec/chip and
+north-star metrics (ROADMAP.md) are measured here: updates/sec/chip and
 pull→push latency percentiles, plus a JSON-lines emitter as the
 "accumulator" analogue.
 

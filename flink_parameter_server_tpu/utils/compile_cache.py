@@ -1,7 +1,7 @@
 """Where the persistent XLA compilation cache lives.
 
-Entry points (``chip_smoke.py``, ``bench.py``, ``__graft_entry__.py``,
-the ``benchmarks/`` and ``examples/`` scripts that jit) call
+Entry points (``chip_smoke.py``, ``__graft_entry__.py``, the
+benchmark's ``chipbench/run.py``, the ``examples/`` that jit) call
 :func:`enable_compile_cache` before their first compile; the package
 never calls it at import.  The directory is part of the cache key, so
 it must not move between runs: it is either where

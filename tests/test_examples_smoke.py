@@ -171,7 +171,7 @@ def test_serve_recommendations_example():
 
 def test_mf_example_socket_path_conflict_is_loud():
     """--socket with --path/--epochs must refuse, not silently ignore
-    the bounded-file options (ADVICE.md round-5)."""
+    the bounded-file options (advisor finding, round 5)."""
     r = _run(
         [
             os.path.join("examples", "online_mf_movielens.py"),

@@ -1,8 +1,8 @@
 """loadgen/: open-loop arrivals, Zipf populations, the overload-control
 plane (shed / retry budget / breaker / brownout), its wiring through
 the shard + serving edges and the cluster client, ``psctl slo``, the
-``--soak`` artifact lint, the elastic-controller flapping regression,
-and an end-to-end soak smoke (marker ``soak``)."""
+elastic-controller flapping regression, and an end-to-end soak smoke
+(marker ``soak``)."""
 import io
 import json
 import time
@@ -685,51 +685,6 @@ class TestPsctlSlo:
             assert doc["sheds"] == {"serving/submit": 1}
         finally:
             tel.stop()
-
-
-# ---------------------------------------------------------------------------
-# the --soak artifact lint
-# ---------------------------------------------------------------------------
-
-
-def _valid_soak_doc():
-    arm = {
-        "arrivals": 100, "ok": 60, "late": 10, "shed": 25, "error": 5,
-        "goodput_rps": 60.0, "latency_anchor": "arrival",
-        "p50_ms": 5.0, "p99_ms": 50.0,
-    }
-    return {
-        "ts": 1.0, "run_id": "r",
-        "soak": {
-            "arms": {"on": dict(arm), "off": dict(arm)},
-            "capacity_curve": [
-                {"shards": 2, "replicas": 1, "capacity_rps": 300.0},
-            ],
-            "autoscaler": {"score": 0.9},
-        },
-    }
-
-
-class TestSoakLint:
-    def test_valid_doc_clean(self):
-        from tools.check_metric_lines import check_soak
-
-        assert check_soak(_valid_soak_doc()) == []
-
-    def test_violations_flagged(self):
-        from tools.check_metric_lines import check_soak
-
-        doc = _valid_soak_doc()
-        doc["soak"]["arms"]["on"]["ok"] = 61  # ledger off by one
-        doc["soak"]["arms"]["off"]["latency_anchor"] = "send"
-        doc["soak"]["autoscaler"]["score"] = 1.7
-        problems = check_soak(doc)
-        assert any("ledger does not balance" in p for p in problems)
-        assert any("latency_anchor" in p for p in problems)
-        assert any("score" in p for p in problems)
-        assert check_soak({"ts": 1.0, "run_id": "r"}) == [
-            "missing/non-object 'soak'"
-        ]
 
 
 # ---------------------------------------------------------------------------

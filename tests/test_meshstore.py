@@ -25,9 +25,7 @@ What is pinned here, and why it is the right bar:
   * **ZeRO-1 fold-in** — optimizer state is sharded (per-device bytes
     = (table + opt state) / n_devices) and the momentum update
     matches a numpy oracle exactly on integer-valued inputs;
-  * **tooling** — meshstore instruments lint as a known component and
-    the ``--mesh-ab`` artifact lint rejects one-armed or verdict-free
-    A/Bs, including the COMMITTED results/cpu/mesh_backend_ab.json.
+  * **tooling** — meshstore instruments lint as a known component.
 """
 import threading
 import time
@@ -264,7 +262,7 @@ class TestMeshParamStore:
     def test_zero1_opt_state_is_sharded_not_replicated(
         self, mesh_devices, rng
     ):
-        """The ZeRO-1 bar (results/cpu/zero1_memory.json): per-device
+        """The ZeRO-1 bar (tests/test_zero1_memory.py): per-device
         bytes = (table + optimizer state) / n_devices — each device
         holds 1/n of the velocity buffer, never a replica."""
         store = MeshParamStore(256, (4,), momentum=0.5, registry=False)
@@ -628,72 +626,3 @@ class TestMeshTelemetry:
         )
         problems = lint.check_lines([bad])
         assert problems and "meshstor" in problems[0][1]
-
-
-def _good_mesh_ab_doc():
-    arm = {
-        "updates_per_sec": 1000.0,
-        "pull_p50_ms": 1.0, "pull_p99_ms": 2.0,
-        "push_p50_ms": 1.0, "push_p99_ms": 2.0,
-    }
-    return {
-        "ts": 1.0, "run_id": "r",
-        "mesh_ab": {
-            "arms": {"mesh": dict(arm), "socket": dict(arm)},
-            "parity": "allclose",
-        },
-    }
-
-
-class TestMeshAbLint:
-    def test_good_doc_is_clean(self):
-        from tools.check_metric_lines import check_mesh_ab
-
-        assert check_mesh_ab(_good_mesh_ab_doc()) == []
-
-    def test_one_armed_ab_fails(self):
-        from tools.check_metric_lines import check_mesh_ab
-
-        doc = _good_mesh_ab_doc()
-        del doc["mesh_ab"]["arms"]["socket"]
-        problems = check_mesh_ab(doc)
-        assert any("socket" in p for p in problems)
-
-    def test_missing_parity_and_fields_fail(self):
-        from tools.check_metric_lines import check_mesh_ab
-
-        doc = _good_mesh_ab_doc()
-        del doc["mesh_ab"]["parity"]
-        del doc["mesh_ab"]["arms"]["mesh"]["pull_p99_ms"]
-        doc["run_id"] = 7
-        problems = check_mesh_ab(doc)
-        assert any("parity" in p for p in problems)
-        assert any("pull_p99_ms" in p for p in problems)
-        assert any("run_id" in p for p in problems)
-
-    def test_committed_artifact_lints_clean(self):
-        """The committed A/B evidence must pass its own lint — and
-        carry a payloads list the perf ledger folds."""
-        import json
-        import os
-
-        from tools.bench_history import _entry
-        from tools.check_metric_lines import check_mesh_ab
-
-        path = os.path.join(
-            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-            "results", "cpu", "mesh_backend_ab.json",
-        )
-        assert os.path.exists(path), (
-            "results/cpu/mesh_backend_ab.json missing — run "
-            "benchmarks/mesh_backend_ab.py"
-        )
-        with open(path) as f:
-            doc = json.load(f)
-        assert check_mesh_ab(doc) == []
-        folded = [
-            _entry(p) for p in doc.get("payloads", [])
-        ]
-        assert folded and all(e is not None for e in folded), (
-            "payloads must be metric-shaped for tools/bench_history.py"
-        )

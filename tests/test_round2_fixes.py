@@ -1,6 +1,6 @@
 """Round-2 regression tests: the advisor/verdict findings stay fixed.
 
-Covers (ADVICE.md r1 + VERDICT.md r1 "weak"):
+Covers (the advisor's findings r1 + VERDICT r1 "weak"):
   * transform_batched must not consume the caller's store/state (donation
     contract now matches transform_dense).
   * JobCheckpointManager.save(force=True) replaces a step without a

@@ -1,4 +1,4 @@
-"""Round-3 regression tests: the advisor findings (ADVICE.md r2) stay fixed.
+"""Round-3 regression tests: the advisor findings (r2) stay fixed.
 
 Covers:
   * topk serving unpacks ANY packed store — including pack == 1 widths

@@ -1,4 +1,4 @@
-"""Round-4 regression tests: the advisor findings (ADVICE.md r3) stay
+"""Round-4 regression tests: the advisor findings (r3) stay
 fixed.
 
 Covers:

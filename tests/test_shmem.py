@@ -739,29 +739,6 @@ class TestHygiene:
 
 
 class TestTooling:
-    def test_bench_history_folds_shm_payloads(self, tmp_path):
-        from tools.bench_history import load_ledger
-
-        d = tmp_path / "results" / "cpu"
-        d.mkdir(parents=True)
-        (d / "transport_ab.json").write_text(json.dumps({
-            "payloads": [
-                {"metric": "transport pull frame p50 (shm)",
-                 "value": 0.2, "unit": "ms"},
-                {"metric": "transport shm wire+codec share",
-                 "value": 70.0, "unit": "% of pull round"},
-                {"metric": "transport shm pull speedup",
-                 "value": 1.0, "unit": "x (p50, vs binary TCP arm)"},
-                {"metric": "transport shm rows pulled",
-                 "value": 2.5e5, "unit": "rows/sec"},
-            ],
-        }))
-        ledger = load_ledger(str(tmp_path))
-        assert ledger["transport pull frame p50 (shm)"]["current"] == (
-            0.2, "ms"
-        )
-        assert "transport shm pull speedup" in ledger
-
     def test_psctl_conns_renders_wire_column(self, capsys):
         from tools.psctl import cmd_conns
 

@@ -113,7 +113,7 @@ def test_parse_reserved_mask_key_is_loud():
 def test_parse_reserved_mask_key_is_loud_on_any_row():
     """The reserved-name guard must fire per row, not just on rows[0]:
     a 'mask' appearing only mid-stream used to slip past the old
-    rows[0]-only check (ADVICE.md round-5)."""
+    rows[0]-only check (advisor finding, round 5)."""
 
     def parse(ln):
         if ln == "bad":
@@ -129,7 +129,7 @@ def test_inconsistent_row_keys_drop_not_crash():
     """A parse() that returns different dict keys across records must
     not kill the unbounded job with a KeyError at stack time: rows
     whose key set differs from the first valid row's are counted as
-    dropped (ADVICE.md round-5)."""
+    dropped (advisor finding, round 5)."""
 
     def parse(ln):
         if ln == "extra":

@@ -444,27 +444,6 @@ def test_run_report_writes_md_and_json(registry, tmp_path):
     assert "telemetry_overhead_pct" in md
 
 
-def test_overhead_guard_200_step_run(registry, tracer):
-    """Registry+spans on vs off on a 200-step CPU driver run.  The
-    acceptance bar is 3% measured as a median over interleaved reps on
-    a quiet machine (benchmarks/telemetry_overhead.py, recorded in
-    results/<platform>/run_report.md — within noise at merge time); here we
-    assert a looser 20% so a noisy shared CI box can't flake the suite
-    while a real regression (per-step locking, accidental sync) still
-    fails loudly."""
-    from benchmarks.telemetry_overhead import run_overhead_bench
-
-    r = run_overhead_bench(
-        steps=200, reps=3, batch=256, num_users=500, num_items=1_024,
-        dim=8,
-    )
-    assert r["overhead_ratio"] > 0.80, r
-    # bench hygiene restored the default registry it installed; put the
-    # test fixture's registry back as the default
-    tm.set_registry(registry)
-    tm.set_tracer(tracer)
-
-
 # ---------------------------------------------------------------------------
 # satellite: device_memory_stats uniform keys + gauges
 # ---------------------------------------------------------------------------

@@ -25,8 +25,7 @@ What is pinned here, and why it is the right bar:
     and the ``kill_promote_cold_tier`` schedule;
   * **surfaces** — the TelemetryServer ``tiers`` path and ``psctl
     tiers`` render live stores (including over a real tiered
-    cluster), and the COMMITTED ``results/cpu/tierstore_soak.json``
-    passes the ``--tier`` lint it was born under.
+    cluster).
 """
 import json
 import os
@@ -778,96 +777,12 @@ class TestSurfaces:
 
 
 # ---------------------------------------------------------------------------
-# tooling: the --tier lint + the committed soak artifact
+# tooling: the component lint
 # ---------------------------------------------------------------------------
 
 
-def _good_tier_doc():
-    return {
-        "ts": 1.0,
-        "run_id": "r",
-        "tier": {
-            "rss_bound_bytes": 100,
-            "tiered_peak_rss_bytes": 80,
-            "pull_p50_ratio": 1.5,
-            "pull_overhead_limit": 2.0,
-            "hit_rate": 0.9,
-            "ledger": {"hits": 9, "misses": 1, "references": 10},
-            "legs": {
-                "parity_bitwise": True, "kill_promote": True,
-                "wal_replay": True, "migration": True,
-            },
-        },
-    }
-
-
 class TestTooling:
-    def test_check_tier_accepts_good_doc(self):
-        from tools.check_metric_lines import check_tier
-
-        assert check_tier(_good_tier_doc()) == []
-
-    @pytest.mark.parametrize("mutate,needle", [
-        (lambda t: t.pop("rss_bound_bytes"), "rss_bound_bytes"),
-        (lambda t: t.__setitem__("tiered_peak_rss_bytes", 200),
-         "exceeds the recorded bound"),
-        (lambda t: t.__setitem__("pull_p50_ratio", 2.5),
-         "exceeds the recorded limit"),
-        (lambda t: t["ledger"].__setitem__("hits", 8),
-         "does not balance"),
-        (lambda t: t["legs"].__setitem__("wal_replay", False),
-         "wal_replay"),
-        (lambda t: t.__setitem__("hit_rate", 1.5), "hit_rate"),
-    ])
-    def test_check_tier_rejects(self, mutate, needle):
-        from tools.check_metric_lines import check_tier
-
-        doc = _good_tier_doc()
-        mutate(doc["tier"])
-        problems = check_tier(doc)
-        assert problems and any(needle in p for p in problems), problems
-
     def test_tierstore_is_a_known_component(self):
         from tools.check_metric_lines import KNOWN_COMPONENTS
 
         assert "tierstore" in KNOWN_COMPONENTS
-
-    def test_committed_soak_artifact_lints_and_folds(self):
-        """The artifact this PR commits must pass the lint it was
-        born under, carry green legs, and fold into the perf ledger
-        with the worse direction pointing UP."""
-        from tools.bench_history import _entry, higher_is_better
-        from tools.check_metric_lines import check_tier
-
-        path = os.path.join(
-            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-            "results", "cpu", "tierstore_soak.json",
-        )
-        assert os.path.exists(path), (
-            "results/cpu/tierstore_soak.json missing — run "
-            "benchmarks/tierstore_soak.py"
-        )
-        with open(path) as f:
-            doc = json.load(f)
-        assert check_tier(doc) == []
-        assert all(doc["tier"]["legs"].values())
-        assert doc["tier"]["tiered_peak_rss_bytes"] < (
-            doc["tier"]["dense_peak_rss_bytes"]
-        ), "the tier must actually shrink the resident set"
-        # the headline ratio is an `x slowdown` unit: bench_history
-        # must treat upward drift as a regression
-        assert not higher_is_better(doc["unit"])
-        folded = [_entry(p) for p in doc.get("payloads", [])]
-        assert folded and all(e is not None for e in folded)
-
-    def test_bench_tier_guard(self, monkeypatch, capsys):
-        """FPS_BENCH_TIER is a strict 0|1 gate on both bench.py code
-        paths: junk values die loudly, 0 emits nothing."""
-        import bench
-
-        monkeypatch.setenv("FPS_BENCH_TIER", "2")
-        with pytest.raises(SystemExit, match="FPS_BENCH_TIER"):
-            bench._emit_tier_metric("cpu")
-        monkeypatch.setenv("FPS_BENCH_TIER", "0")
-        bench._emit_tier_metric("cpu")
-        assert capsys.readouterr().out == ""

@@ -28,12 +28,9 @@ What is pinned here, and why it is the right oracle:
     advances so the same firing never pressures twice.
   * **psctl watch / timeline** — smoke over a live 2-shard cluster
     and a real TelemetryServer scrape, both render paths.
-  * **the committed artifact** — results/cpu/soak_timeline.json lints
-    clean and records a passing detection A/B.
 """
 import json
 import math
-import os
 import time
 
 import numpy as np
@@ -53,8 +50,6 @@ from flink_parameter_server_tpu.telemetry.timeline import (
 )
 
 pytestmark = pytest.mark.timeline
-
-REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _feed(det, xs, *, name="m", field="value", labels=None):
@@ -619,7 +614,7 @@ class TestSurfaces:
 
 
 # ---------------------------------------------------------------------------
-# tooling gates + the committed artifact
+# tooling gates
 # ---------------------------------------------------------------------------
 
 
@@ -649,24 +644,3 @@ class TestTooling:
         assert any("regress" in p for p in problems)
         assert any("ghost" in p for p in problems)
         assert check_timeline({"no": "payload"})  # nothing to lint is loud
-
-    def test_committed_detection_ab_artifact(self):
-        """The acceptance artifact: both arms recorded, lint-clean,
-        straggler named within 3 windows, zero oracle firings."""
-        from tools.check_metric_lines import check_timeline
-
-        path = os.path.join(REPO_ROOT, "results", "cpu",
-                            "soak_timeline.json")
-        with open(path) as f:
-            doc = json.load(f)
-        assert check_timeline(doc) == []
-        assert doc["passed"] is True
-        det = doc["detection"]
-        assert det["detected"] and det["shard"] == "0"
-        assert det["windows"] <= 3
-        assert doc["oracle_anomalies"] == 0
-        assert doc["oracle_skew_flags"] == 0
-        assert set(doc["arms"]) == {"fault", "oracle"}
-        for arm in doc["arms"].values():
-            assert arm["ok"]
-            assert arm["timeline"]["series"], "arm recorded no series"

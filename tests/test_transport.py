@@ -18,13 +18,11 @@ Covers ISSUE 13's tentpole end to end:
     directions, with the (pid, id) ledger auditing the replay;
   * shard worker processes — bitwise proc-vs-thread parity, WAL
     rebuild across a kill, and the spawn-grace dial window;
-  * the committed transport_ab / cluster_scaling artifacts + the
-    budget-phase lint lockstep.
+  * the budget-phase lint lockstep.
 """
 from __future__ import annotations
 
 import json
-import os
 import socket
 import threading
 import time
@@ -42,8 +40,6 @@ from flink_parameter_server_tpu.utils import frames as binf
 from flink_parameter_server_tpu.utils.net import PeerHalfClosed
 
 pytestmark = pytest.mark.cluster
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 # ---------------------------------------------------------------------------
@@ -789,7 +785,7 @@ class TestShardProcesses:
 
 
 # ---------------------------------------------------------------------------
-# tools + committed artifacts
+# tools
 # ---------------------------------------------------------------------------
 
 
@@ -812,60 +808,6 @@ class TestToolsAndArtifacts:
         }
         bad = check_budget(doc)
         assert any("warp_drive" in b for b in bad)
-
-    def test_bench_history_folds_payloads_list(self, tmp_path):
-        from tools.bench_history import load_ledger
-
-        d = tmp_path / "results" / "cpu"
-        d.mkdir(parents=True)
-        (d / "transport_ab.json").write_text(json.dumps({
-            "payloads": [
-                {"metric": "transport pull p50", "value": 0.3,
-                 "unit": "ms"},
-                {"metric": "transport speedup", "value": 4.0,
-                 "unit": "x"},
-            ],
-        }))
-        ledger = load_ledger(str(tmp_path))
-        assert ledger["transport pull p50"]["current"] == (0.3, "ms")
-        assert ledger["transport speedup"]["current"] == (4.0, "x")
-
-    def test_committed_transport_ab_artifact_bars(self):
-        path = os.path.join(REPO, "results", "cpu", "transport_ab.json")
-        with open(path) as f:
-            doc = json.load(f)
-        v = doc["verdict"]
-        assert v["ok"] and v["speedup_ok"] and v["codec_ok"]
-        assert v["coverage_ok"]
-        arms = doc["arms"]
-        # the codec share the rework is responsible for collapsed
-        assert arms["binary"]["codec_pct"] < 10.0
-        assert arms["binary"]["codec_pct"] < arms["line"]["codec_pct"]
-        # pull p50 at least 2x better over the binary framing
-        assert (
-            arms["line"]["budget_round_ms"]
-            >= 2.0 * arms["binary"]["budget_round_ms"]
-        )
-        # both arms' budgets still lint clean
-        from tools.check_metric_lines import check_budget
-
-        for arm in ("line", "binary"):
-            assert check_budget(arms[arm]["budget_artifact"]) == []
-
-    def test_committed_cluster_scaling_has_proc_arms(self):
-        path = os.path.join(
-            REPO, "results", "cpu", "cluster_scaling.json"
-        )
-        with open(path) as f:
-            doc = json.load(f)
-        extra = doc["payload"]["extra"]
-        assert extra["procs"] is not None
-        ratios = extra["proc_over_thread"]
-        # the GIL escape: proc shards beat thread shards at EVERY
-        # shard count (on multi-core hosts the proc curve also rises;
-        # this artifact records the host's cpu count)
-        assert all(r is not None and r > 1.0 for r in ratios)
-        assert extra["procs"]["cpus"] >= 1
 
     def test_psctl_conns_renders_proto_column(self, capsys):
         import argparse

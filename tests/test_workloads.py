@@ -3,7 +3,7 @@
 What is pinned here, and why it is the right oracle:
 
   * **registry** — any learner by name, the operational property every
-    other harness (nemesis, soak, bench, psctl) rides;
+    other harness (nemesis, soak, psctl) rides;
   * **PA bitwise parity** — a BSP cluster run (sockets, WAL, retries)
     equals the StreamingDriver oracle BIT FOR BIT: the on-device dense
     combine (DenseCombineLogic) leaves exactly one fp32 row per id per
@@ -562,56 +562,3 @@ class TestTooling:
         from tools.check_metric_lines import KNOWN_COMPONENTS
 
         assert "workloads" in KNOWN_COMPONENTS
-
-    def test_battery_artifact_shape(self):
-        """The committed acceptance artifact parses, both scenarios
-        pass, and the q8/aggregation soak arms are recorded (the
-        ISSUE's evidence bar)."""
-        import os
-
-        path = os.path.join(
-            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-            "results", "cpu", "workload_battery.json",
-        )
-        with open(path) as f:
-            doc = json.load(f)
-        assert doc["payload"]["value"] == 2
-        r = doc["workloads"]
-        assert {s["scenario"] for s in r["scenarios"]} == {
-            "pa_full_stack", "sketch_full_stack"
-        }
-        assert all(s["ok"] for s in r["scenarios"])
-        modes = {
-            s["workload"]: s["parity_mode"] for s in r["scenarios"]
-        }
-        assert modes == {"pa": "bitwise", "sketch": "exact_int"}
-        arms = r["soak_arms"]
-        assert arms["q8"]["invariants_ok"]
-        assert arms["q8_agg"]["invariants_ok"]
-        assert arms["q8"]["compression_bytes_saved"] > 0
-        assert arms["q8_agg"]["combined_pushes"] > 0
-        assert arms["q8"]["latency_anchor"] == "arrival"
-
-    def test_soak_capacity_artifact_carries_new_arms(self):
-        """The regenerated 60 s soak-capacity artifact records the
-        q8 and q8+aggregation arms next to the on/off headline."""
-        import os
-
-        path = os.path.join(
-            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-            "results", "cpu", "soak_capacity.json",
-        )
-        with open(path) as f:
-            doc = json.load(f)
-        arms = doc["soak"]["arms"]
-        assert {"off", "on", "on_q8", "on_q8_agg"} <= set(arms)
-        q8 = arms["on_q8"]["overload"]
-        assert q8["wire_format"] == "q8"
-        assert q8["compression_bytes_saved"] > 0
-        agg = arms["on_q8_agg"]["overload"]
-        assert agg["push_aggregate"] is True
-        assert agg["combined_pushes"] > 0
-        for arm in ("on_q8", "on_q8_agg"):
-            assert all(
-                v["ok"] for v in arms[arm]["verdicts"]
-            ), arm

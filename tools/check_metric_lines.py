@@ -25,18 +25,13 @@ Registry samples (``"kind": "registry"``) additionally have every
 typo'd component silently forks a dashboard's series, so it fails the
 lint instead.
 
-Eight further artifact shapes from the observability plane lint here
-too (docs/observability.md, docs/loadgen.md, docs/meshstore.md,
-docs/adaptive.md, docs/tierstore.md):
+Four further artifact shapes from the observability plane lint here
+too (docs/observability.md):
 
     python tools/check_metric_lines.py --trace merged_trace.json
     python tools/check_metric_lines.py --flightrec flightrec_stall.json
     python tools/check_metric_lines.py --budget budget.json
-    python tools/check_metric_lines.py --soak soak_capacity.json
-    python tools/check_metric_lines.py --mesh-ab mesh_backend_ab.json
-    python tools/check_metric_lines.py --timeline soak_timeline.json
-    python tools/check_metric_lines.py --straggler-ab straggler_ab.json
-    python tools/check_metric_lines.py --tier tierstore_soak.json
+    python tools/check_metric_lines.py --timeline timeline.json
 
 ``--trace`` checks a Chrome trace-event JSON array (the
 ``TraceCollector`` merge format): every ``X`` event carries ``pid``,
@@ -49,41 +44,14 @@ latency-budget artifact (telemetry/profiler.py
 ``write_budget_artifact``): ts/run_id stamped, every budget carries a
 non-empty phase list with numeric ``p50_ms``/``pct``, and for any
 verb with full coverage the phase percentages sum to 100 ± 10 — the
-additivity contract the profiler's decomposition promises.  ``--soak``
-checks a soak-capacity artifact (benchmarks/soak_capacity.py,
-docs/loadgen.md): ts/run_id stamped, every arm declares
-``latency_anchor: "arrival"`` (the coordinated-omission-free contract)
-with numeric arrival-anchored percentiles, the goodput ledger sums
-(``arrivals == ok + late + shed + error``), the capacity curve rows
-carry numeric rates, and the autoscaler score stays in [0, 1].
-``--mesh-ab`` checks a mesh-vs-socket backend A/B artifact
-(benchmarks/mesh_backend_ab.py, docs/meshstore.md): ts/run_id stamped,
-BOTH arms present (``mesh`` and ``socket`` — a one-armed "A/B" is the
-classic way to ship a flattering number) with numeric updates/sec and
-pull/push p50/p99, and a ``parity`` verdict field so the artifact
-records whether the two backends converged to the same model, not just
-which was faster.  ``--timeline`` checks a metric-timeline artifact
+additivity contract the profiler's decomposition promises.
+``--timeline`` checks a metric-timeline artifact
 (telemetry/timeline.py ``TimelineRecorder.payload()``, possibly nested
 under ``arms``/``timelines``): every series' timestamps are monotone
 non-decreasing, the sampling cadence holds (median inter-point gap
 within 3x the declared ``interval_s`` — a jittering sampler quietly
 voids rate math), and every anomaly record cross-references a metric
-the artifact actually carries a series for.  ``--straggler-ab``
-checks a straggler-adaptive A/B artifact
-(benchmarks/straggler_ab.py, docs/adaptive.md): ts/run_id stamped,
-every workload carries BOTH arms (``adaptive`` and ``fixed`` — same
-chaos, same deadline) with numeric goodput and final-table RMSE, the
-goodput ratio is recorded at workload level, the adaptive arm counts
-every mechanism's firings (a "win" with zero widenings/hedges/moves
-means the control loop never ran), and the bound-envelope invariant
-is green (effective bounds stayed inside [bound, ceiling]).
-``--tier`` checks a two-tier store soak artifact
-(benchmarks/tierstore_soak.py, docs/tierstore.md): ts/run_id stamped,
-the RSS bound is RECORDED and the tiered arm's peak RSS stayed under
-it, the pull-overhead ratio travels with its limit and honours it,
-``hit_rate`` is a number in [0, 1], the hit/miss ledger balances
-against references, and every correctness leg (bitwise parity,
-kill→promote, WAL replay, migration) is green.  A mode flag applies
+the artifact actually carries a series for.  A mode flag applies
 to the paths that follow it.
 """
 from __future__ import annotations
@@ -236,8 +204,8 @@ def check_flightrec(doc: Any) -> List[str]:
 # or adds a phase must update the lint AND the docs together.  The
 # binary transport (utils/frames.py) reuses these names — the phases
 # are transport-generic costs (frame encode IS client_serialize), and
-# one vocabulary is what keeps the line-vs-binary A/B directly
-# comparable (results/cpu/transport_ab.md).
+# one vocabulary is what keeps a line-vs-binary A/B directly
+# comparable.
 KNOWN_BUDGET_PHASES = frozenset({
     "client_serialize",
     "wire",
@@ -307,136 +275,6 @@ def check_budget(doc: Any) -> List[str]:
                     f"{round(total, 1)} (full coverage requires "
                     f"100 ± 10)"
                 )
-    return bad
-
-
-def check_soak(doc: Any) -> List[str]:
-    """Lint a soak-capacity artifact (benchmarks/soak_capacity.py
-    format, docs/loadgen.md "Artifact schema")."""
-    bad: List[str] = []
-    if not isinstance(doc, dict):
-        return [f"soak document is {type(doc).__name__}, expected a "
-                f"JSON object"]
-    if not isinstance(doc.get("ts"), (int, float)):
-        bad.append("missing/non-numeric 'ts'")
-    if not isinstance(doc.get("run_id"), str):
-        bad.append("missing/non-string 'run_id'")
-    soak = doc.get("soak")
-    if not isinstance(soak, dict):
-        bad.append("missing/non-object 'soak'")
-        return bad
-    arms = soak.get("arms")
-    if not isinstance(arms, dict) or not arms:
-        bad.append("missing/empty 'soak.arms'")
-    else:
-        for name, arm in arms.items():
-            if not isinstance(arm, dict):
-                bad.append(f"arm {name!r}: not an object")
-                continue
-            if arm.get("latency_anchor") != "arrival":
-                bad.append(
-                    f"arm {name!r}: latency_anchor must be 'arrival' "
-                    f"(open-loop honesty — got "
-                    f"{arm.get('latency_anchor')!r})"
-                )
-            for field in ("p50_ms", "p99_ms", "goodput_rps"):
-                if not isinstance(arm.get(field), (int, float)):
-                    bad.append(
-                        f"arm {name!r}: missing/non-numeric {field!r}"
-                    )
-            counts = [arm.get(o) for o in ("ok", "late", "shed", "error")]
-            arrivals = arm.get("arrivals")
-            if not all(isinstance(c, int) for c in counts) or not \
-                    isinstance(arrivals, int):
-                bad.append(
-                    f"arm {name!r}: ledger fields (arrivals/ok/late/"
-                    f"shed/error) must be integers"
-                )
-            elif sum(counts) != arrivals:
-                bad.append(
-                    f"arm {name!r}: goodput ledger does not balance — "
-                    f"arrivals={arrivals} but ok+late+shed+error="
-                    f"{sum(counts)}"
-                )
-    curve = soak.get("capacity_curve")
-    if not isinstance(curve, list) or not curve:
-        bad.append("missing/empty 'soak.capacity_curve'")
-    else:
-        for i, row in enumerate(curve):
-            if not isinstance(row, dict) or not isinstance(
-                row.get("capacity_rps"), (int, float)
-            ):
-                bad.append(
-                    f"capacity_curve[{i}]: missing/non-numeric "
-                    f"'capacity_rps'"
-                )
-    auto = soak.get("autoscaler")
-    if auto is not None:
-        score = auto.get("score") if isinstance(auto, dict) else None
-        if not isinstance(score, (int, float)) or not 0.0 <= score <= 1.0:
-            bad.append(
-                f"autoscaler.score must be a number in [0, 1] "
-                f"(got {score!r})"
-            )
-    return bad
-
-
-# the latency fields every mesh-A/B arm must report (both backends,
-# same workload, same worker count — or the comparison is theater)
-_MESH_AB_ARM_FIELDS = (
-    "updates_per_sec",
-    "pull_p50_ms", "pull_p99_ms",
-    "push_p50_ms", "push_p99_ms",
-)
-
-# what the parity field may claim; "diverged" is allowed — an honest
-# artifact that says the backends disagree still lints clean, a
-# missing/unknown verdict does not
-_MESH_AB_PARITY = frozenset({"bitwise", "allclose", "diverged"})
-
-
-def check_mesh_ab(doc: Any) -> List[str]:
-    """Lint a mesh-vs-socket backend A/B artifact
-    (benchmarks/mesh_backend_ab.py format, docs/meshstore.md)."""
-    bad: List[str] = []
-    if not isinstance(doc, dict):
-        return [f"mesh-ab document is {type(doc).__name__}, expected a "
-                f"JSON object"]
-    if not isinstance(doc.get("ts"), (int, float)):
-        bad.append("missing/non-numeric 'ts'")
-    if not isinstance(doc.get("run_id"), str):
-        bad.append("missing/non-string 'run_id'")
-    ab = doc.get("mesh_ab")
-    if not isinstance(ab, dict):
-        bad.append("missing/non-object 'mesh_ab'")
-        return bad
-    arms = ab.get("arms")
-    if not isinstance(arms, dict):
-        bad.append("missing/non-object 'mesh_ab.arms'")
-        return bad
-    for required in ("mesh", "socket"):
-        if required not in arms:
-            bad.append(
-                f"arm {required!r} missing — the A/B requires BOTH "
-                f"backends at equal worker count"
-            )
-    for name, arm in arms.items():
-        if not isinstance(arm, dict):
-            bad.append(f"arm {name!r}: not an object")
-            continue
-        for field in _MESH_AB_ARM_FIELDS:
-            if not isinstance(arm.get(field), (int, float)):
-                bad.append(
-                    f"arm {name!r}: missing/non-numeric {field!r}"
-                )
-    parity = ab.get("parity")
-    if parity not in _MESH_AB_PARITY:
-        bad.append(
-            f"'mesh_ab.parity' must be one of "
-            f"{sorted(_MESH_AB_PARITY)} (got {parity!r}) — the "
-            f"artifact must record whether the two backends agreed on "
-            f"the model, not just who was faster"
-        )
     return bad
 
 
@@ -551,184 +389,6 @@ def check_timeline(doc: Any) -> List[str]:
     return bad
 
 
-# every adaptive mechanism the A/B must account for — an arm that
-# "won" without a single widening, hedge or move proves only that the
-# chaos never bit, so the counts travel with the number
-_STRAGGLER_AB_MECHANISMS = (
-    "widenings", "narrowings", "hedged_pushes", "push_hedges_won",
-    "rebalances",
-)
-
-
-def check_straggler_ab(doc: Any) -> List[str]:
-    """Lint a straggler-adaptive A/B artifact
-    (benchmarks/straggler_ab.py format, docs/adaptive.md)."""
-    bad: List[str] = []
-    if not isinstance(doc, dict):
-        return [f"straggler-ab document is {type(doc).__name__}, "
-                f"expected a JSON object"]
-    if not isinstance(doc.get("ts"), (int, float)):
-        bad.append("missing/non-numeric 'ts'")
-    if not isinstance(doc.get("run_id"), str):
-        bad.append("missing/non-string 'run_id'")
-    ab = doc.get("straggler_ab")
-    if not isinstance(ab, dict):
-        bad.append("missing/non-object 'straggler_ab'")
-        return bad
-    workloads = ab.get("workloads")
-    if not isinstance(workloads, dict) or not workloads:
-        bad.append("missing/empty 'straggler_ab.workloads'")
-        return bad
-    for wname, wl in workloads.items():
-        if not isinstance(wl, dict):
-            bad.append(f"workload {wname!r}: not an object")
-            continue
-        arms = wl.get("arms")
-        if not isinstance(arms, dict):
-            bad.append(f"workload {wname!r}: missing/non-object 'arms'")
-            continue
-        for required in ("adaptive", "fixed"):
-            if required not in arms:
-                bad.append(
-                    f"workload {wname!r}: arm {required!r} missing — "
-                    f"the A/B requires BOTH arms under the same chaos "
-                    f"and deadline"
-                )
-        for aname, arm in arms.items():
-            if not isinstance(arm, dict):
-                bad.append(f"workload {wname!r} arm {aname!r}: not an "
-                           f"object")
-                continue
-            for field in ("goodput_eps", "rmse"):
-                if not isinstance(arm.get(field), (int, float)):
-                    bad.append(
-                        f"workload {wname!r} arm {aname!r}: "
-                        f"missing/non-numeric {field!r}"
-                    )
-        if not isinstance(wl.get("goodput_ratio"), (int, float)):
-            bad.append(
-                f"workload {wname!r}: missing/non-numeric "
-                f"'goodput_ratio' (adaptive/fixed — the headline "
-                f"number must be recorded, not recomputed downstream)"
-            )
-        adaptive = arms.get("adaptive") if isinstance(arms, dict) else None
-        if isinstance(adaptive, dict):
-            mech = adaptive.get("mechanisms")
-            if not isinstance(mech, dict):
-                bad.append(
-                    f"workload {wname!r}: adaptive arm missing "
-                    f"'mechanisms' — every mechanism's firings must "
-                    f"be counted"
-                )
-            else:
-                for m in _STRAGGLER_AB_MECHANISMS:
-                    v = mech.get(m)
-                    if not isinstance(v, int) or v < 0:
-                        bad.append(
-                            f"workload {wname!r}: mechanisms[{m!r}] "
-                            f"must be a non-negative integer (got "
-                            f"{v!r})"
-                        )
-            env = adaptive.get("bound_envelope")
-            if not isinstance(env, dict):
-                bad.append(
-                    f"workload {wname!r}: adaptive arm missing "
-                    f"'bound_envelope'"
-                )
-            elif env.get("ok") is not True:
-                bad.append(
-                    f"workload {wname!r}: bound_envelope.ok is not "
-                    f"true — the ceiling invariant must be green for "
-                    f"the goodput number to count"
-                )
-    return bad
-
-
-# the legs a tierstore artifact must prove green — the RSS number is
-# only meaningful if the bounded store also stayed CORRECT across
-# every recovery plane on the same commit
-_TIER_LEGS = (
-    "parity_bitwise", "kill_promote", "wal_replay", "migration",
-)
-
-
-def check_tier(doc: Any) -> List[str]:
-    """Lint a two-tier store soak artifact (benchmarks/tierstore_soak.py
-    format, docs/tierstore.md): the RSS bound is RECORDED and honoured
-    (peak ≤ bound — a soak that never wrote down its own bound proves
-    nothing), the pull-overhead bar travels with its limit, the
-    hit/miss ledger balances, and every correctness leg is green."""
-    bad: List[str] = []
-    if not isinstance(doc, dict):
-        return [f"tier document is {type(doc).__name__}, expected a "
-                f"JSON object"]
-    if not isinstance(doc.get("ts"), (int, float)):
-        bad.append("missing/non-numeric 'ts'")
-    if not isinstance(doc.get("run_id"), str):
-        bad.append("missing/non-string 'run_id'")
-    tier = doc.get("tier")
-    if not isinstance(tier, dict):
-        bad.append("missing/non-object 'tier'")
-        return bad
-    bound = tier.get("rss_bound_bytes")
-    peak = tier.get("tiered_peak_rss_bytes")
-    if not isinstance(bound, (int, float)) or bound <= 0:
-        bad.append("missing/non-positive 'tier.rss_bound_bytes' — the "
-                   "bounded-RSS claim must record its own bound")
-    if not isinstance(peak, (int, float)) or peak <= 0:
-        bad.append("missing/non-positive 'tier.tiered_peak_rss_bytes'")
-    if (isinstance(bound, (int, float)) and isinstance(peak, (int, float))
-            and peak > bound):
-        bad.append(
-            f"tiered peak RSS {int(peak)} exceeds the recorded bound "
-            f"{int(bound)} — the bounded-residency claim is violated"
-        )
-    ratio = tier.get("pull_p50_ratio")
-    limit = tier.get("pull_overhead_limit")
-    if not isinstance(ratio, (int, float)):
-        bad.append("missing/non-numeric 'tier.pull_p50_ratio'")
-    if not isinstance(limit, (int, float)) or limit <= 0:
-        bad.append("missing/non-positive 'tier.pull_overhead_limit' — "
-                   "the overhead bar travels with the number")
-    elif isinstance(ratio, (int, float)) and ratio > limit:
-        bad.append(
-            f"pull p50 overhead {ratio} exceeds the recorded limit "
-            f"{limit}"
-        )
-    hit_rate = tier.get("hit_rate")
-    if not isinstance(hit_rate, (int, float)) or not 0.0 <= hit_rate <= 1.0:
-        bad.append(f"'tier.hit_rate' must be a number in [0, 1] "
-                   f"(got {hit_rate!r})")
-    ledger = tier.get("ledger")
-    if not isinstance(ledger, dict):
-        bad.append("missing/non-object 'tier.ledger'")
-    else:
-        h, m, refs = (ledger.get(k) for k in
-                      ("hits", "misses", "references"))
-        if not all(isinstance(v, int) for v in (h, m, refs)):
-            bad.append("'tier.ledger' fields (hits/misses/references) "
-                       "must be integers")
-        elif h + m != refs:
-            bad.append(
-                f"tier ledger does not balance — references={refs} "
-                f"but hits+misses={h + m}"
-            )
-    legs = tier.get("legs")
-    if not isinstance(legs, dict) or not legs:
-        bad.append("missing/empty 'tier.legs' — the correctness legs "
-                   "must travel with the perf number")
-    else:
-        for leg in _TIER_LEGS:
-            if legs.get(leg) is not True:
-                bad.append(
-                    f"tier leg {leg!r} is not green (got "
-                    f"{legs.get(leg)!r}) — the RSS/latency numbers "
-                    f"only count on a commit whose recovery planes "
-                    f"pass"
-                )
-    return bad
-
-
 def _check_json_artifact(path: str, checker) -> List[str]:
     try:
         with open(path) as f:
@@ -751,16 +411,8 @@ def main(argv: List[str]) -> int:
             mode = "flightrec"
         elif a == "--budget":
             mode = "budget"
-        elif a == "--soak":
-            mode = "soak"
-        elif a == "--mesh-ab":
-            mode = "mesh_ab"
         elif a == "--timeline":
             mode = "timeline"
-        elif a == "--straggler-ab":
-            mode = "straggler_ab"
-        elif a == "--tier":
-            mode = "tier"
         elif a == "--lines":
             mode = "lines"
         elif a in ("-h", "--help"):
@@ -770,23 +422,18 @@ def main(argv: List[str]) -> int:
             jobs.append((mode, a))
     if not jobs:
         print("usage: check_metric_lines.py [--allow-missing-ids] "
-              "[--trace|--flightrec|--budget|--soak|--mesh-ab|"
-              "--timeline|--straggler-ab|--tier|--lines] <file|-> ...",
+              "[--trace|--flightrec|--budget|--timeline|--lines] "
+              "<file|-> ...",
               file=sys.stderr)
         return 2
     failed = False
     for mode, path in jobs:
-        if mode in ("trace", "flightrec", "budget", "soak", "mesh_ab",
-                    "timeline", "straggler_ab", "tier"):
+        if mode in ("trace", "flightrec", "budget", "timeline"):
             checker = {
                 "trace": check_trace_events,
                 "flightrec": check_flightrec,
                 "budget": check_budget,
-                "soak": check_soak,
-                "mesh_ab": check_mesh_ab,
                 "timeline": check_timeline,
-                "straggler_ab": check_straggler_ab,
-                "tier": check_tier,
             }[mode]
             problems = _check_json_artifact(path, checker)
             for reason in problems:

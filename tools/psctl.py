@@ -123,8 +123,8 @@ and why, without a log dive.  ``--json`` emits the raw payload.
 pushes, restarts, epoch, WAL depth, dedupe-window size) and renders one
 table row per shard.  ``conns`` renders each server's live connection
 ledger (peer, age, bytes/frames each way).  ``budget`` renders the
-per-phase latency budget (telemetry/profiler.py) — the table
-docs/perf_status.md cites; ``--json`` emits the raw artifact (lintable
+per-phase latency budget (telemetry/profiler.py, docs/observability.md);
+``--json`` emits the raw artifact (lintable
 via ``tools/check_metric_lines.py --budget`` after stamping, or use
 the run-report JSON).
 
