@@ -243,17 +243,17 @@ def create_table(spec: StoreSpec, init_fn: Optional[InitFn] = None) -> Array:
     _preload_tile_kernel(spec)
     if spec.layout == "packed":
         return _create_packed(spec, init_fn)()
+    if spec.mesh is None and spec.padded_capacity > _INIT_BLOCK:
+        # a long dense table in one place is initialised block by block, in
+        # place: no temporary as long as the table (PERF.md section 6, PR 45)
+        return _create_dense_in_blocks(spec, init_fn)()
     ids = jnp.arange(spec.padded_capacity, dtype=jnp.int32)
     out_sharding = spec.sharding()
 
     def build(ids):
         return _physical_rows(spec, init_fn(ids))
 
-    if out_sharding is not None:
-        build = jax.jit(build, out_shardings=out_sharding)
-    else:
-        build = jax.jit(build)
-    return build(ids)
+    return jax.jit(build, out_shardings=out_sharding)(ids)
 
 
 def _create_packed(spec: StoreSpec, init_fn: InitFn) -> Callable[[], Array]:
@@ -558,6 +558,38 @@ def _physical_rows(spec: StoreSpec, rows: Array) -> Array:
     (``StoreSpec.tile_lanes``), every other store's are as they are."""
     pad = spec.tile_lanes - spec.row_width
     return jnp.pad(rows, ((0, 0), (0, pad))) if pad > 0 else rows
+
+
+# Rows a step of the loop that initialises a long dense table in place.
+_INIT_BLOCK = 1 << 20
+
+
+def _create_dense_in_blocks(
+    spec: StoreSpec, init_fn: InitFn
+) -> Callable[[], Array]:
+    """``() -> table`` for a dense spec in one place, as one jitted program
+    that initialises ``_INIT_BLOCK`` rows a ``fori_loop`` step and writes
+    them into the table where it lies, as :func:`_create_packed` does for a
+    packed one: the chip holds its table and one block's temporaries.  All
+    rows at once, a seeded init of DiFacto's 49,126,310 x 36 float32 rows
+    asked 11.8 GB of temporaries beside the 7.86 GB table (compiled for a
+    v5e: PERF.md section 6, PR 45).  ``init_fn`` is deterministic per id,
+    so the rows are what one call over all ids gives."""
+    rows = spec.padded_capacity
+    origin = (0,) * (len(spec.table_shape()) - 1)
+
+    def init_block(i, table):
+        # the last block starts early and writes some rows a second time
+        at = jnp.minimum(i * _INIT_BLOCK, rows - _INIT_BLOCK)
+        ids = at + jnp.arange(_INIT_BLOCK, dtype=jnp.int32)
+        part = _physical_rows(spec, init_fn(ids)).astype(spec.dtype)
+        return jax.lax.dynamic_update_slice(table, part, (at,) + origin)
+
+    def build() -> Array:
+        table = jnp.zeros(spec.table_shape(), spec.dtype)
+        return jax.lax.fori_loop(0, -(-rows // _INIT_BLOCK), init_block, table)
+
+    return jax.jit(build)
 
 
 # Physical row widths, in 128-lane registers, from which `push` goes through

@@ -42,6 +42,26 @@ class FMConfig:
     loss: str = "logistic"  # or "squared"
 
 
+def forward_gradients(x: Array, w: Array, v: Array, loss_gradient, l2: float):
+    """The degree-2 FM on examples with active values ``x`` (B, K), weights
+    ``w`` (B, K) and latent vectors ``v`` (B, K, d): ``(y_hat, loss, dw,
+    dv)``, the prediction, ``loss_gradient(y_hat) -> (dL/dy_hat, loss)``'s
+    loss, and the loss's gradients a (example, feature) with ``l2`` times
+    the parameter added.  The forward pass and the gradient algebra of every
+    FM logic (``FactorizationMachine``; ``models/difacto.DiFacto``, which
+    hands in its gated ``v``)."""
+    linear = jnp.sum(w * x, axis=-1)  # (B,)
+    xv = x[..., None] * v  # (B, K, d)
+    s = jnp.sum(xv, axis=1)  # (B, d)  Σ x_i v_i
+    interaction = 0.5 * (jnp.sum(s * s, axis=-1) - jnp.sum(xv * xv, axis=(1, 2)))
+    y_hat = linear + interaction  # (B,)
+    g, loss = loss_gradient(y_hat)
+    # ∂ŷ/∂w_i = x_i ;  ∂ŷ/∂v_i = x_i (s − x_i v_i)
+    dw = g[:, None] * x + l2 * w
+    dv = g[:, None, None] * (x[..., None] * (s[:, None, :] - xv)) + l2 * v
+    return y_hat, loss, dw, dv
+
+
 class FactorizationMachine(BatchedWorkerLogic):
     """Batch: ``ids`` (B,K) int, ``values`` (B,K) float, ``feat_mask``
     (B,K) bool, ``label`` (B,) (±1 logistic / float squared), ``mask`` (B,).
@@ -59,27 +79,19 @@ class FactorizationMachine(BatchedWorkerLogic):
     def step(self, state, batch: Dict[str, Array], pulled: Array):
         cfg = self.config
         x = jnp.where(batch["feat_mask"], batch["values"].astype(jnp.float32), 0.0)
-        w = pulled[..., 0]  # (B, K)
-        v = pulled[..., 1:]  # (B, K, d)
 
-        linear = jnp.sum(w * x, axis=-1)  # (B,)
-        xv = x[..., None] * v  # (B, K, d)
-        s = jnp.sum(xv, axis=1)  # (B, d)  Σ x_i v_i
-        interaction = 0.5 * (jnp.sum(s * s, axis=-1) - jnp.sum(xv * xv, axis=(1, 2)))
-        y_hat = linear + interaction  # (B,)
-
-        label = batch["label"].astype(jnp.float32)
-        if cfg.loss == "logistic":
-            # dL/dy_hat for y ∈ {−1,+1}: −y σ(−y ŷ)
-            g = -label * jax.nn.sigmoid(-label * y_hat)
-            loss = jax.nn.softplus(-label * y_hat)
-        else:
+        def loss_gradient(y_hat):
+            label = batch["label"].astype(jnp.float32)
+            if cfg.loss == "logistic":
+                # dL/dy_hat for y ∈ {−1,+1}: −y σ(−y ŷ)
+                return (-label * jax.nn.sigmoid(-label * y_hat),
+                        jax.nn.softplus(-label * y_hat))
             g = y_hat - label
-            loss = 0.5 * g * g
+            return g, 0.5 * g * g
 
-        # ∂ŷ/∂w_i = x_i ;  ∂ŷ/∂v_i = x_i (s − x_i v_i)
-        dw = g[:, None] * x + cfg.l2 * w
-        dv = g[:, None, None] * (x[..., None] * (s[:, None, :] - xv)) + cfg.l2 * v
+        y_hat, loss, dw, dv = forward_gradients(
+            x, pulled[..., 0], pulled[..., 1:], loss_gradient, cfg.l2
+        )
         deltas = jnp.concatenate(
             [-cfg.learning_rate * dw[..., None], -cfg.learning_rate * dv], axis=-1
         )  # (B, K, 1+d)
@@ -130,4 +142,7 @@ def train_fm(data, config: FMConfig, *, seed: int = 0, mesh=None, **kwargs):
     )
 
 
-__all__ = ["FMConfig", "FactorizationMachine", "make_store", "train_fm"]
+__all__ = [
+    "FMConfig", "FactorizationMachine", "forward_gradients", "make_store",
+    "train_fm",
+]

@@ -91,24 +91,19 @@ def occurrence_scale(
 
 
 # Rows up to this many lanes wide ride through a sort as operands of their
-# own; wider ones are permuted after it by one gather of whole rows.  Alone
-# on the v5e, 1,277,952 lanes (PERF.md section 6, PR 34): carried, rows of
-# 1 / 3 / 4 lanes sort in 2.8 / 3.5 / 4.0 ms against 11.0 / 7.4 / 7.4
-# permuted; at 8 lanes it is 6.8 against 6.2, and the carrying sort takes
-# 96 s to compile against 13 (40 s at 3 lanes).  5 to 7 were not measured.
+# own, and their runs are summed along the sorted lanes.  Alone on the v5e,
+# 1,277,952 lanes (PERF.md section 6, PR 34): carried, rows of 1 / 3 / 4
+# lanes sort in 2.8 / 3.5 / 4.0 ms; at 8 lanes it is 6.8, and the carrying
+# sort takes 96 s to compile (40 s at 3 lanes).  Wider rows are neither
+# sorted nor permuted: `_wide_runs` scatter-adds them where they lie.
 _SORT_CARRIES_LANES = 4
 
 
 def _sorted_by_id(ids: Array, rows: Array) -> Tuple[Array, Array]:
-    """``ids`` ascending and the ``(n, w)`` ``rows`` in that order."""
-    n, w = rows.shape
-    if w <= _SORT_CARRIES_LANES:
-        out = jax.lax.sort((ids,) + tuple(rows.T), num_keys=1)
-        return out[0], jnp.stack(out[1:], axis=1)
-    ids, order = jax.lax.sort(
-        (ids, jnp.arange(n, dtype=jnp.int32)), num_keys=1
-    )
-    return ids, jnp.take(rows, order, axis=0)
+    """``ids`` ascending and the ``(n, w)`` ``rows`` in that order, the
+    rows' lanes carried through the sort as operands."""
+    out = jax.lax.sort((ids,) + tuple(rows.T), num_keys=1)
+    return out[0], jnp.stack(out[1:], axis=1)
 
 
 def combine_runs(ids: Array, vals: Array, sentinel: int) -> Tuple[Array, Array]:
@@ -116,12 +111,15 @@ def combine_runs(ids: Array, vals: Array, sentinel: int) -> Tuple[Array, Array]:
     both of the batch's static length.  The distinct ids come first, in
     ascending order, each with its run's total; the rest of ``row_ids`` is
     ``sentinel`` (an id no row has, and larger than any: lanes to drop carry
-    it coming in).  A run of any length costs what the batch does: one sort
-    that carries the values, ``log2 n`` shifted adds (a segmented prefix sum
-    by doubling, which sums each run as a balanced tree, the batch's lanes
-    along the minor axis), and a second sort that moves the lanes ending a
-    run to the front."""
+    it coming in).  A run of any length costs what the batch does.  Rows of
+    at most ``_SORT_CARRIES_LANES`` lanes: one sort that carries the values,
+    ``log2 n`` shifted adds (a segmented prefix sum by doubling, which sums
+    each run as a balanced tree, the batch's lanes along the minor axis),
+    and a second sort that moves the lanes ending a run to the front.
+    Wider rows: :func:`_wide_runs`."""
     n, w = vals.shape
+    if w > _SORT_CARRIES_LANES:
+        return _wide_runs(ids.astype(jnp.int32), vals, sentinel)
     ids, rows = _sorted_by_id(ids.astype(jnp.int32), vals)
     cols = rows.T
     d = 1
@@ -134,6 +132,43 @@ def combine_runs(ids: Array, vals: Array, sentinel: int) -> Tuple[Array, Array]:
         d *= 2
     ends = jnp.concatenate([ids[1:] != ids[:-1], jnp.ones((1,), bool)])
     return _sorted_by_id(jnp.where(ends, ids, sentinel), cols.T)
+
+
+def _wide_runs(ids: Array, vals: Array, sentinel: int) -> Tuple[Array, Array]:
+    """:func:`combine_runs` for rows wider than a sort carries: the rows are
+    never permuted.  One sort of (id, stream position); each sorted lane's
+    SLOT is the rank of its id among the distinct ids (a prefix sum of the
+    run starts, by doubling, on one int32 vector); a second sort on the
+    carried positions brings the slots back to stream order; ONE scatter-add
+    of the rows into a zeroed ``(n, w)`` block then sums every run in the
+    order of the stream, float32 addition by addition what ``np.add.at``
+    does; a third sort moves the distinct ids to the front, where their
+    slots are.
+
+    The first form here sorted the ids, permuted the rows by one gather,
+    summed the runs by the shifted adds above on ``(w, n)`` and permuted
+    them again.  Inside a 36-lane store's push on the v5e those adds read
+    a shifted operand wrongly across every block of 16,000 lanes the
+    compiler cut the fusion into: 23 of 352,388 rows of DiFacto's first
+    batch got another run's share (``correct`` false at 8,500 x the
+    allowance; alone, jitted by itself, the same function was right:
+    PERF.md section 6, PR 45)."""
+    n, w = vals.shape
+    lane = jnp.arange(n, dtype=jnp.int32)
+    sorted_ids, order = jax.lax.sort((ids, lane), num_keys=1)
+    starts = jnp.concatenate(
+        [jnp.ones((1,), bool), sorted_ids[1:] != sorted_ids[:-1]]
+    )
+    rank = starts.astype(jnp.int32)
+    d = 1
+    while d < n:
+        rank = rank + jnp.pad(rank[:-d], (d, 0))
+        d *= 2
+    # (the positions are distinct: nothing for a stable sort to keep)
+    _, slot = jax.lax.sort((order, rank - 1), num_keys=1, is_stable=False)
+    sums = jnp.zeros((n, w), vals.dtype).at[slot].add(vals)
+    row_ids = jax.lax.sort(jnp.where(starts, sorted_ids, sentinel))
+    return row_ids, sums
 
 
 # -- host-side coalescing (the cluster client's request combiner) -----------

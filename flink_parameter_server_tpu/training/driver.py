@@ -363,8 +363,10 @@ class StreamingDriver:
         128 rows its write-back moved to do so: ``store_rule_keys``,
         ``store_rule_rows``, ``store_rule_tiles``) and a logic of ragged key
         bags (the live lanes of its keys and all of them: ``bag_live_keys``,
-        ``bag_padded_keys``) and keyed workers (the live records the last
-        dispatch dropped because they reached the wrong worker:
+        ``bag_padded_keys``), a gated factorisation machine (the live lanes
+        of its keys and those whose embedding the gate let through:
+        ``fm_live_keys``, ``fm_v_live_keys``) and keyed workers (the live
+        records the last dispatch dropped because they reached the wrong worker:
         ``keyed_misrouted``, 0 behind the router) and a store packed several
         rows to a physical row (whether the step's pull took the slice
         kernel: ``store_packed_slice_kernel``).  A fetch of a few scalars, made only where the
@@ -385,6 +387,16 @@ class StreamingDriver:
             )
             self.registry.gauge("bag_padded_keys", component="train").set(
                 total(outs["bag_padded_keys"])
+            )
+        if "fm_live_keys" in outs:
+            # a gated factorisation machine (models/difacto.py) counts the
+            # live lanes of its keys and those whose embedding its gate let
+            # through, from its own masks
+            self.registry.gauge("fm_live_keys", component="train").set(
+                total(outs["fm_live_keys"])
+            )
+            self.registry.gauge("fm_v_live_keys", component="train").set(
+                total(outs["fm_v_live_keys"])
             )
         if "keyed_misrouted" in outs:
             # keyed workers (models/matrix_factorization.py) count the live

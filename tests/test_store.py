@@ -895,7 +895,7 @@ def test_the_set_kernel_arm_is_the_xla_arm_bit_for_bit(
     assert int(kernel_counted["ps_rule_tiles"]) > 0
 
 
-@pytest.mark.parametrize("width", [1, 3, 8, 9, 17])
+@pytest.mark.parametrize("width", [1, 3, 4, 5, 8, 9, 17, 36])
 def test_combine_runs_sums_each_id_and_moves_the_distinct_first(width):
     from flink_parameter_server_tpu.ops.dedup import combine_runs
 

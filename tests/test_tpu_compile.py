@@ -1167,3 +1167,70 @@ def test_ft_step_holds_cell_7_s_table_once_beside_three_tile_kernel_calls(
     assert " copy(" not in "".join(tables)
     assert "ps.push/scatter-add" not in text
     assert "ps.compute/ps.bag_pool" in text and "ps.compute/ps.delta_build" in text
+
+
+# difacto-criteo-10m (chipbench/configs): cell 9's rule store, 36 f32 lanes a
+# row (w, z, s, c, V[16], S[16]), dense: on the chip rows-minor, 40 sublanes
+DF_ROWS, DF_LANES = 49_126_310, 36
+
+
+@pytest.fixture(scope="module")
+def difacto():
+    """``difacto-criteo-10m`` as ``chipbench/families/difacto.py`` builds it:
+    the configuration's rule, ``make_store``'s own layout."""
+    from chipbench import spec as bench_spec
+    from chipbench.families import difacto as fam
+    from flink_parameter_server_tpu.models import difacto as dfm
+
+    cfg = bench_spec.load_json("chipbench/configs/difacto-criteo-10m.json")
+    rule = dfm.DiFactoUpdater(**{k: float(cfg[k]) for k in fam.RULE_KEYS})
+    model = dfm.DiFactoConfig(DF_ROWS, 16)
+    assert cfg["num_features"] == DF_ROWS and model.row_lanes == DF_LANES
+    return cfg, rule, model, fam, dfm
+
+
+def test_difacto_table_is_initialised_in_place_block_by_block(
+        difacto, one_chip, no_compile_cache):
+    """The seeded warm start of 49,126,310 x 36 f32 rows under a ``jit`` that
+    takes the key: the 7.86 GB table (36 lanes down 40 sublanes) is the
+    program's only output, written ``core/store._INIT_BLOCK`` rows a loop
+    step; all rows at once the same init asked 11.8 GB of temporaries."""
+    cfg, rule, model, fam, dfm = difacto
+    compiled = jax.jit(lambda key: dfm.make_store(
+        model, rule, init_fn=fam.warm_rows(cfg, rule, key), dtype=jnp.float32,
+    ).table).lower(_shape(one_chip, (2,), jnp.uint32)).compile()
+    mem = compiled.memory_analysis()
+    assert 7.85 * GB < mem.output_size_in_bytes < 7.87 * GB
+    assert mem.temp_size_in_bytes < 0.5 * GB
+    assert len(re.findall(r" while\(", compiled.as_text())) >= 1
+
+
+def test_difacto_step_holds_nothing_table_sized_beside_its_table(
+        difacto, one_chip, no_compile_cache):
+    """Cell 9's step at full size for a described v5e: a rule row of 36
+    lanes goes through the dense arm (no kernel takes it), the donated table
+    is rewritten in place, and what the step holds beside it goes with the
+    batch (the 36-lane gradient rows laid 128 lanes wide and their sorted
+    copies: 0.92 GB), with every scope the cell's metrics read."""
+    cfg, rule, model, fam, dfm = difacto
+    spec = jax.eval_shape(
+        lambda: dfm.make_store(model, rule, dtype=jnp.float32)
+    ).spec
+    assert spec.layout == "dense" and not spec.narrow_rule
+    assert spec.table_shape() == (49_126_312, DF_LANES)
+    assert not store_mod._set_kernel_takes(spec)
+    compiled = jax.jit(
+        make_train_step(dfm.DiFacto(model, rule), spec), donate_argnums=(0, 1)
+    ).lower(
+        _shape(one_chip, spec.table_shape(), jnp.float32), (),
+        _fm_batch(one_chip),
+    ).compile()
+    mem = compiled.memory_analysis()
+    assert 7.85 * GB < mem.alias_size_in_bytes < 7.87 * GB  # in place
+    assert mem.temp_size_in_bytes < 1.2 * GB
+    text = compiled.as_text()
+    assert not re.search(r"f32\[49126312,36\]\S* (copy|transpose)\(", text)
+    assert "sorted_row" not in text and "tpu_custom_call" not in text  # no kernel
+    for scope in ("ps.pull", "ps.compute/ps.gate", "ps.compute/ps.delta_build",
+                  "ps.push/ps.combine", "ps.push/while/body/ps.rule"):
+        assert scope in text, scope
