@@ -602,7 +602,7 @@ class Smoke:
             OnlineMatrixFactorization,
             SGDUpdater,
         )
-        from flink_parameter_server_tpu.ops import row_update
+        from flink_parameter_server_tpu.ops import dedup, row_update
         from flink_parameter_server_tpu.ops.flash_attention import (
             flash_mha,
             flash_mha_dp,
@@ -703,6 +703,19 @@ class Smoke:
             (table_s, ids_s, new_s),
             close(table_s.at[ids_s].set(
                 jnp.pad(new_s, ((0, 0), (0, 1))), mode="drop"), 0.0),
+        )
+
+        # a rule's wide rows at cell 9's width (36 lanes; Zipf ids with long
+        # runs): permuted once at 128 lanes and summed run by run by the row
+        # kernel, against the one scatter-add in stream order that every
+        # other backend keeps (the same ids, other roundings)
+        ids_c, rows_c = zipf_ids(rows_w), normal((n, 36))
+        self._kernel_case(
+            "combine_runs_d36_f32",
+            lambda i, r: dedup.combine_runs(
+                i, r, rows_w, kernel=True, interpret=interpret),
+            (ids_c, rows_c),
+            close(dedup.combine_runs(ids_c, rows_c, rows_w), 1e-3),
         )
 
         # the MF step's DEFAULT arm at that shape class, against the XLA

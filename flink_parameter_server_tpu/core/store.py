@@ -402,7 +402,11 @@ def push_counted(
     ``ps_rule_keys``, the live lanes of the batch, ``ps_rule_rows``, the
     distinct rows the rule rewrote, and ``ps_rule_tiles``, the tiles of 128
     rows the write-back read and wrote to do so (0 where XLA's scatter
-    wrote the rows: :func:`_set_kernel_takes`).  ``make_train_step`` puts
+    wrote the rows: :func:`_set_kernel_takes`); a rule store whose rows are
+    wider than a sort carries also ``ps_combine_kernel_lanes``, the lanes
+    whose rows the row kernel summed (the live lanes; 0 where XLA's
+    scatter-add summed them: :func:`_combine_kernel_takes`).
+    ``make_train_step`` puts
     them among the step's outputs, where whoever fetches outputs finds them, if the logic's
     outputs are a dict (every logic of ``models/``); outputs of another
     type leave the step as they are, without the counts.
@@ -485,7 +489,9 @@ def _push_rule(
     ``ps.combine`` the batch's ids are sorted with their deltas, every run
     of one id summed and the distinct ids moved to the front
     (:func:`..ops.dedup.combine_runs`; masked, negative and out-of-range
-    lanes sort last and are dropped).  Then ``_RULE_CHUNK`` lanes a step of
+    lanes sort last and are dropped; a wide row's runs are summed in the arm
+    :func:`_combine_kernel_takes` reads from the spec).  Then
+    ``_RULE_CHUNK`` lanes a step of
     a loop that ends with the last distinct id (on the TPU a dropped lane
     of a gather or a scatter costs what a kept one does, and a batch of
     Criteo records names a row 3.6 times on average): under ``ps.rule``
@@ -498,18 +504,23 @@ def _push_rule(
     (``StoreSpec.tile_lanes``), ``ops/row_update.sorted_tile_set``: every
     touched tile of 128 rows read, set and written back once, the same
     bits (PERF.md section 6, PR 35)."""
-    from ..ops.dedup import combine_runs
+    from ..ops.dedup import _SORT_CARRIES_LANES, combine_runs
     from ..ops.row_update import sorted_tile_set
 
     n = flat_ids.shape[0]
     sentinel = spec.padded_capacity
     update_fn: UpdateFn = spec.update  # type: ignore[assignment]
+    wide = spec.row_width > _SORT_CARRIES_LANES
     if n == 0:  # an empty batch rewrites nothing
         zero = jnp.zeros((), jnp.int32)
-        return table, {
+        counted = {
             "ps_rule_keys": zero, "ps_rule_rows": zero, "ps_rule_tiles": zero,
         }
+        if wide:
+            counted["ps_combine_kernel_lanes"] = zero
+        return table, counted
     tiles_arm = _set_kernel_takes(spec)
+    sums_arm = _combine_kernel_takes(spec)
     chunk = min(n, _RULE_CHUNK)
     with jax.named_scope("ps.combine"):
         dead = flat_ids >= sentinel
@@ -518,11 +529,17 @@ def _push_rule(
         row_ids, combined = combine_runs(
             jnp.where(dead, sentinel, flat_ids),
             flat_deltas.reshape(n, -1).astype(table.dtype), sentinel,
+            kernel=sums_arm,
         )
         counted = {
             "ps_rule_keys": n - jnp.sum(dead, dtype=jnp.int32),
             "ps_rule_rows": jnp.sum(row_ids < sentinel, dtype=jnp.int32),
         }
+        if wide:
+            counted["ps_combine_kernel_lanes"] = (
+                counted["ps_rule_keys"] if sums_arm
+                else jnp.zeros((), jnp.int32)
+            )
         # whole chunks: a chunk that started early would run the rule on
         # rows the chunk before it has already rewritten
         pad = -n % chunk
@@ -668,6 +685,32 @@ def _set_kernel_takes(spec: StoreSpec) -> bool:
     return False
 
 
+def _combine_kernel_takes(spec: StoreSpec) -> bool:
+    """Whether a rule store's combine (:func:`_push_rule`) sums the runs of
+    its batch along SORTED lanes through ``ops/row_update``'s row kernel
+    (``ops/dedup._kernel_sums``) instead of ONE XLA scatter-add in the order
+    of the stream, read from what the spec holds, as
+    :func:`_set_kernel_takes` reads the write-back's arm: a TPU, no mesh, a
+    rule, and rows wider than a sort carries (``ops/dedup.
+    _SORT_CARRIES_LANES``; a narrower row rides through the sort whatever
+    the backend) that fit one 128-lane register, float32.  Static per
+    compiled step.  Such a store that the kernel REFUSES (bfloat16, rows of
+    more than 128 lanes) keeps the scatter-add, counted and warned of once.
+    On the v5e the scatter-add is 146 ns a 36-lane row, serial; the permute
+    of whole-register rows is 8-10 ns a row and the kernel 9.5 a lane
+    (PERF.md section 6, PR 46)."""
+    from ..ops import dedup
+
+    if (spec.update == "add" or spec.mesh is not None
+            or jax.default_backend() != "tpu"
+            or spec.row_width <= dedup._SORT_CARRIES_LANES):
+        return False
+    return _taken_or_noted(
+        spec, "the sum of a rule's wide rows",
+        dedup.kernel_refusal(spec.row_width, spec.dtype),
+    )
+
+
 def _worker_reduce_takes(spec: StoreSpec, lanes: int) -> bool:
     """Whether an ``add`` batch whose lanes lie split over ``dp`` workers (the
     caller says so: ``push_counted(lanes_over_workers=True)``) is summed
@@ -758,7 +801,7 @@ def _preload_tile_kernel(spec: StoreSpec) -> None:
     Pallas imported by then, beside the table's staging (the import is ~1 s
     that the first trace of the step else pays)."""
     if (_tile_kernel_takes(spec) or _set_kernel_takes(spec)
-            or _slice_kernel_takes(spec)):
+            or _combine_kernel_takes(spec) or _slice_kernel_takes(spec)):
         from ..ops.row_update import preload
 
         preload()

@@ -106,20 +106,38 @@ def _sorted_by_id(ids: Array, rows: Array) -> Tuple[Array, Array]:
     return out[0], jnp.stack(out[1:], axis=1)
 
 
-def combine_runs(ids: Array, vals: Array, sentinel: int) -> Tuple[Array, Array]:
+def combine_runs(
+    ids: Array, vals: Array, sentinel: int, *, kernel: bool = False,
+    interpret: Optional[bool] = None,
+) -> Tuple[Array, Array]:
     """Sum the rows of ``vals`` (n, w) that share an id: ``(row_ids, sums)``,
     both of the batch's static length.  The distinct ids come first, in
     ascending order, each with its run's total; the rest of ``row_ids`` is
     ``sentinel`` (an id no row has, and larger than any: lanes to drop carry
-    it coming in).  A run of any length costs what the batch does.  Rows of
-    at most ``_SORT_CARRIES_LANES`` lanes: one sort that carries the values,
-    ``log2 n`` shifted adds (a segmented prefix sum by doubling, which sums
-    each run as a balanced tree, the batch's lanes along the minor axis),
-    and a second sort that moves the lanes ending a run to the front.
-    Wider rows: :func:`_wide_runs`."""
+    it coming in).  A run of any length costs what the batch does.  Which
+    form runs goes with the row's WIDTH, and for a wide row with what the
+    caller has read from where it runs (``core/store._combine_kernel_takes``):
+
+    - rows of at most ``_SORT_CARRIES_LANES`` lanes, everywhere: one sort
+      that carries the values, ``log2 n`` shifted adds (a segmented prefix
+      sum by doubling, which sums each run as a balanced tree, the batch's
+      lanes along the minor axis), and a second sort that moves the lanes
+      ending a run to the front;
+    - wider rows, ``kernel`` false (the CPU, a mesh, bfloat16, rows of more
+      than 128 lanes): :func:`_wide_runs`' ONE scatter-add in the order of
+      the stream, ``np.add.at``'s own additions;
+    - wider rows, ``kernel`` true (a TPU, float32, at most 128 lanes): the
+      rows permuted ONCE into sorted order at 128 lanes and their runs summed
+      by the row kernel of ``ops/row_update`` (:func:`_kernel_sums`): on
+      the v5e a serial scatter-add is 146 ns a 36-lane row, the permute of
+      a whole-register row 8-10 and the kernel 9.5 a lane (cell 9's
+      ``ps.combine`` 188.8 -> 29.9 ms: PERF.md section 6, PR 46).
+
+    ``interpret`` is the kernel's (None: by the default backend)."""
     n, w = vals.shape
     if w > _SORT_CARRIES_LANES:
-        return _wide_runs(ids.astype(jnp.int32), vals, sentinel)
+        return _wide_runs(
+            ids.astype(jnp.int32), vals, sentinel, kernel, interpret)
     ids, rows = _sorted_by_id(ids.astype(jnp.int32), vals)
     cols = rows.T
     d = 1
@@ -134,16 +152,35 @@ def combine_runs(ids: Array, vals: Array, sentinel: int) -> Tuple[Array, Array]:
     return _sorted_by_id(jnp.where(ends, ids, sentinel), cols.T)
 
 
-def _wide_runs(ids: Array, vals: Array, sentinel: int) -> Tuple[Array, Array]:
-    """:func:`combine_runs` for rows wider than a sort carries: the rows are
-    never permuted.  One sort of (id, stream position); each sorted lane's
-    SLOT is the rank of its id among the distinct ids (a prefix sum of the
-    run starts, by doubling, on one int32 vector); a second sort on the
-    carried positions brings the slots back to stream order; ONE scatter-add
-    of the rows into a zeroed ``(n, w)`` block then sums every run in the
-    order of the stream, float32 addition by addition what ``np.add.at``
-    does; a third sort moves the distinct ids to the front, where their
-    slots are.
+def kernel_refusal(width: int, dtype) -> Optional[str]:
+    """Why :func:`_kernel_sums` cannot sum rows of ``width`` lanes of this
+    dtype (None: it can)."""
+    if jnp.dtype(dtype) != jnp.float32:
+        return f"rows are {jnp.dtype(dtype).name}, the kernel sums float32"
+    if width > 128:
+        return (
+            f"rows of {width} lanes: only a row of one 128-lane register is "
+            f"gathered and written in one piece"
+        )
+    return None
+
+
+def _wide_runs(
+    ids: Array, vals: Array, sentinel: int, kernel: bool,
+    interpret: Optional[bool],
+) -> Tuple[Array, Array]:
+    """:func:`combine_runs` for rows wider than a sort carries.  One sort of
+    (id, stream position); each sorted lane's SLOT is the rank of its id
+    among the distinct ids (a prefix sum of the run starts, by doubling, on
+    one int32 vector); a sort of the run starts' ids moves the distinct ids
+    to the front, where their slots are.  Then the sums, in one of two forms.
+
+    ``kernel`` false: the rows are never permuted.  A second sort on the
+    carried positions brings the slots back to stream order, and ONE
+    scatter-add of the rows into a zeroed ``(n, w)`` block sums every run in
+    the order of the stream, float32 addition by addition what
+    ``np.add.at`` does.  ``kernel`` true: the rows go to the slots, not the
+    slots to the rows (:func:`_kernel_sums`).
 
     The first form here sorted the ids, permuted the rows by one gather,
     summed the runs by the shifted adds above on ``(w, n)`` and permuted
@@ -164,11 +201,73 @@ def _wide_runs(ids: Array, vals: Array, sentinel: int) -> Tuple[Array, Array]:
     while d < n:
         rank = rank + jnp.pad(rank[:-d], (d, 0))
         d *= 2
-    # (the positions are distinct: nothing for a stable sort to keep)
-    _, slot = jax.lax.sort((order, rank - 1), num_keys=1, is_stable=False)
-    sums = jnp.zeros((n, w), vals.dtype).at[slot].add(vals)
+    if kernel:
+        # the lanes to drop sort last: the kernel writes no row for them
+        slot = jnp.where(sorted_ids < sentinel, rank - 1, _INT32_MAX)
+        sums = _kernel_sums(order, slot, vals, interpret)
+    else:
+        # (the positions are distinct: nothing for a stable sort to keep)
+        _, slot = jax.lax.sort((order, rank - 1), num_keys=1, is_stable=False)
+        sums = jnp.zeros((n, w), vals.dtype).at[slot].add(vals)
     row_ids = jax.lax.sort(jnp.where(starts, sorted_ids, sentinel))
     return row_ids, sums
+
+
+def _kernel_sums(
+    order: Array, slot: Array, vals: Array, interpret: Optional[bool]
+) -> Array:
+    """``sums[slot[k]] += vals[order[k]]`` over the sorted lanes ``k``
+    (``vals`` float32, at most 128 lanes: :func:`kernel_refusal`; ``slot``
+    ascending, the lanes to drop last with a slot past ``n``), as a
+    segment sum through ``ops/row_update.sorted_row_update``, the MF cells'
+    row kernel called as it stands: a zeroed ``(n, 128)`` block is its state,
+    zeros are its old rows, a run's total is the one row it writes.
+
+    The ``(n, w)`` rows are padded to ``(n, 128)``, row-major: whole
+    registers, the only width at which a row gathers and DMAs in one piece
+    (a 36-lane row of a rows-minor array is a strided column: 44 ns a row
+    to permute on the v5e where a 128-lane row is 8-10).  Then, a stretch of
+    at most ``MAX_LANES`` sorted lanes a trip of ONE loop (the kernel's
+    scalars lie in SMEM; equal shapes, so the kernel is traced and lowered
+    once): the stretch's rows gathered in sorted order (the one permute),
+    the kernel's call into the block, which is carried and aliased from
+    trip to trip.  A run that lies across two stretches is written by both:
+    the second reads what the first wrote as ITS old row, as
+    ``row_update.row_add`` does it.
+
+    A run is summed block by block of 256 lanes on the MXU, from three
+    exact bfloat16 pieces accumulated in float32, a carry between blocks:
+    NOT in the order of the stream (``np.add.at``), and to float32's
+    rounding of a blocked sum.  A non-finite value stays in its row."""
+    from .row_update import (
+        BLOCK, MAX_LANES, _open_run_reread, sorted_row_update,
+    )
+
+    n, w = vals.shape
+    trips = -(-n // MAX_LANES)
+    size = -(-n // (trips * BLOCK)) * BLOCK
+    tail = trips * size - n  # lanes that drop, reading row 0
+    order = jnp.pad(order, (0, tail))
+    slot = jnp.pad(slot, (0, tail), constant_values=_INT32_MAX)
+    padded = jnp.pad(vals, ((0, 0), (0, 128 - w)))
+    zeros = jnp.zeros((size, 128), jnp.float32)
+
+    def stretch(i, block):
+        lo = i * size
+        slots = jax.lax.dynamic_slice_in_dim(slot, lo, size)
+        # (a permutation: nothing to clip, and no fill to select after)
+        rows = jnp.take(
+            padded, jax.lax.dynamic_slice_in_dim(order, lo, size), axis=0,
+            mode="clip",
+        )
+        return sorted_row_update(
+            block, slots, _open_run_reread(block, slots, zeros), rows,
+            interpret=interpret,
+        )
+
+    block = jax.lax.fori_loop(
+        0, trips, stretch, jnp.zeros((n, 128), jnp.float32))
+    return block[:, :w]
 
 
 # -- host-side coalescing (the cluster client's request combiner) -----------

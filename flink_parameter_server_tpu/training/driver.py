@@ -361,7 +361,9 @@ class StreamingDriver:
         came from, as gauges: a rule store's push (``core/store.push_counted``:
         its live keys, the distinct rows its rule rewrote and the tiles of
         128 rows its write-back moved to do so: ``store_rule_keys``,
-        ``store_rule_rows``, ``store_rule_tiles``) and a logic of ragged key
+        ``store_rule_rows``, ``store_rule_tiles``; for wide rows the lanes
+        the row kernel summed: ``store_combine_kernel_lanes``) and a logic
+        of ragged key
         bags (the live lanes of its keys and all of them: ``bag_live_keys``,
         ``bag_padded_keys``), a gated factorisation machine (the live lanes
         of its keys and those whose embedding the gate let through:
@@ -421,6 +423,12 @@ class StreamingDriver:
         self.registry.gauge("store_rule_tiles", component="train").set(
             total(outs["ps_rule_tiles"])
         )
+        if "ps_combine_kernel_lanes" in outs:
+            # a rule store of rows wider than a sort carries: the lanes the
+            # row kernel summed (0 where XLA's scatter-add summed them)
+            self.registry.gauge(
+                "store_combine_kernel_lanes", component="train"
+            ).set(total(outs["ps_combine_kernel_lanes"]))
 
     def run(
         self,

@@ -358,3 +358,90 @@ def test_wide_rows_are_summed_in_stream_order_bit_for_bit(width, n):
     want = np.zeros((sentinel + 1, width), np.float32)
     np.add.at(want, ids, vals)
     assert np.array_equal(np.asarray(sums)[: len(distinct)], want[distinct])
+
+
+# The other side of that choice (``core/store._combine_kernel_takes``: a TPU,
+# float32, rows of 5 to 128 lanes): the rows permuted once into sorted order
+# at 128 lanes and every run summed by ``ops/row_update``'s row kernel, block
+# by block on the MXU, NOT in the order of the stream.  Here the kernel is
+# interpreted and a call's lanes are cut to 512, so that a few hundred lanes
+# walk several blocks of 256 and several stretches.
+def _kernel_traffic(kind, rng, n, sentinel):
+    """``n`` ids in [0, 60), ``sentinel`` on the lanes to drop."""
+    ids = rng.integers(0, 60, n).astype(np.int32)
+    ids[rng.random(n) < 0.1] = sentinel
+    if kind == "mixed":  # a hot row on a third of the lanes, a tenth dropped
+        ids[: n // 3] = 11
+    elif kind == "run_over_a_block":  # 300 lanes > 256, inside one stretch
+        ids[:] = np.where(ids < 30, ids + 30, ids)
+        ids[100:400] = 2
+    elif kind == "run_over_three_stretches":  # sorted lanes ~100 to ~1300
+        ids[150:1350] = 7
+    elif kind == "no_lane_dropped":  # the run of dropped lanes is empty
+        ids[ids == sentinel] = 59
+    elif kind == "every_lane_dropped":
+        ids[:] = sentinel
+    elif kind == "every_lane_its_own_row":  # whole stretches, nothing padded
+        ids = rng.permutation(n).astype(np.int32)
+    elif kind != "nan_stays_in_its_row":
+        raise AssertionError(kind)
+    return rng.permutation(ids)
+
+
+@pytest.mark.parametrize("width, n, traffic", [
+    (5, 1000, "mixed"), (17, 1100, "mixed"), (36, 4096, "mixed"),
+    (36, 777, "mixed"), (127, 600, "mixed"), (128, 300, "mixed"),
+    (36, 700, "run_over_a_block"), (36, 1500, "run_over_three_stretches"),
+    (36, 900, "no_lane_dropped"), (36, 300, "every_lane_dropped"),
+    (36, 1024, "every_lane_its_own_row"), (36, 1100, "nan_stays_in_its_row"),
+])
+def test_wide_rows_are_summed_along_sorted_lanes_by_the_row_kernel(
+        width, n, traffic, monkeypatch):
+    """``combine_runs(kernel=True)``: the distinct ids exactly as the other
+    arm hands them out, and every run's total within 2 ulps of the sum of
+    its addends' magnitudes of the float64 sum (a blocked float32 sum: not
+    ``np.add.at``'s bits), nothing in the rows past the distinct ones."""
+    from flink_parameter_server_tpu.ops import row_update
+    from flink_parameter_server_tpu.ops.dedup import combine_runs
+
+    monkeypatch.setattr(row_update, "MAX_LANES", 512)
+    calls = []
+    real = row_update.sorted_row_update
+    monkeypatch.setattr(
+        row_update, "sorted_row_update",
+        lambda *a, **kw: calls.append(a[1].shape[0]) or real(*a, **kw))
+    rng = np.random.default_rng([width, n])
+    sentinel = 5000
+    ids = _kernel_traffic(traffic, rng, n, sentinel)
+    vals = rng.normal(size=(n, width)).astype(np.float32)
+    nan_at = None
+    if traffic == "nan_stays_in_its_row":
+        lane = int(np.flatnonzero(ids == 11)[3])
+        nan_at, vals[lane, 2] = (11, 2), np.nan
+        vals[ids == sentinel] = np.nan  # a dropped lane's reaches nothing
+    row_ids, sums = jax.jit(
+        lambda i, v: combine_runs(i, v, sentinel, kernel=True, interpret=True)
+    )(ids, vals)
+    # one traced kernel under a loop, whole blocks, no more lanes than a call holds
+    assert len(calls) == 1 and calls[0] <= 512 and calls[0] % 256 == 0
+    row_ids, sums = np.asarray(row_ids), np.array(sums)
+    assert row_ids.shape == (n,) and sums.shape == (n, width)
+    assert sums.dtype == np.float32
+    distinct = np.unique(ids[ids < sentinel])
+    assert np.array_equal(row_ids[: len(distinct)], distinct)
+    assert (row_ids[len(distinct):] == sentinel).all()
+    live = ids < sentinel
+    want = np.zeros((sentinel, width))
+    size = np.zeros((sentinel, width))
+    np.add.at(want, ids[live], vals[live].astype(np.float64))
+    np.add.at(size, ids[live], np.abs(vals[live]).astype(np.float64))
+    got, want, size = sums[: len(distinct)], want[distinct], size[distinct]
+    if nan_at is not None:
+        at = (int(np.searchsorted(distinct, nan_at[0])), nan_at[1])
+        assert np.isnan(got[at]) and np.isnan(want[at])
+        got[at] = want[at] = size[at] = 0.0
+    assert np.isfinite(got).all()
+    eps = float(np.finfo(np.float32).eps)
+    assert (np.abs(got - want) <= 2 * eps * size).all(), float(
+        (np.abs(got - want) / (eps * size + 1e-30)).max())
+    assert not sums[len(distinct):].any()

@@ -754,11 +754,19 @@ def test_a_narrow_rule_store_the_kernel_refuses_warns_once_and_counts(
 
 
 @pytest.mark.parametrize("backend,shape,started", [
-    ("tpu", (3,), 1), ("tpu", (9,), 0), ("cpu", (3,), 0),
+    ("tpu", (3,), 1), ("tpu", (9,), 1), ("tpu", (200,), 0), ("cpu", (3,), 0),
+    ("cpu", (9,), 0),
 ])
 def test_a_rule_store_whose_pushes_will_trace_the_kernel_starts_the_pallas_import(
         monkeypatch, backend, shape, started):
+    """Three lanes: the set kernel writes the rows back.  Nine: the row
+    kernel sums them (``core/store._combine_kernel_takes``, PR 46; no kernel
+    took such a store before).  Two hundred: neither, and a warning says so."""
+    from flink_parameter_server_tpu.core import store as store_mod
+
     calls = []
+    monkeypatch.setattr(store_mod, "_REFUSALS_NOTED", {
+        ("the sum of a rule's wide rows", (200,), "float32")})  # keep it quiet
     monkeypatch.setattr(row_update, "preload", lambda: calls.append(1))
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
     ShardedParamStore.create(16, shape, update=_rule)

@@ -384,6 +384,106 @@ def test_push_pull_case_table_wide_rows(arm, shape, traffic, monkeypatch):
         assert calls and max(calls) <= 512, calls
 
 
+# A RULE store's rows wider than a sort carries: on a TPU (no mesh, float32,
+# at most 128 lanes) ``_push_rule`` sums each row's deltas along the SORTED
+# lanes through ``ops/row_update``'s row kernel, everywhere else by one
+# scatter-add in the order of the stream.  With the rule ``current +
+# combined`` such a store is held to the reference every add store is held
+# to.  Off the TPU the chooser is steered in the test and the kernel is
+# interpreted, a call's lanes cut to 512 so that the long cases take several.
+WIDE_RULE_TRAFFIC = TRAFFIC + ["run_over_a_block_and_a_call"]
+
+
+def _add_rule(current, combined):
+    return current + combined
+
+
+@pytest.mark.parametrize("traffic", WIDE_RULE_TRAFFIC)
+@pytest.mark.parametrize("shape", [(5,), (36,), (128,), (2, 9)], ids=str)
+@pytest.mark.parametrize("arm", ["xla", "row_kernel"])
+def test_push_pull_case_table_wide_rule_rows(arm, shape, traffic, monkeypatch):
+    from flink_parameter_server_tpu.core import store as store_mod
+    from flink_parameter_server_tpu.ops import row_update
+
+    rng = np.random.default_rng([len(shape), shape[-1],
+                                 WIDE_RULE_TRAFFIC.index(traffic)])
+    values = _init_values(CAP, shape)
+    store = ShardedParamStore.from_values(
+        jnp.asarray(values), update=_add_rule)
+    # (a row of 5 lanes is held at 8, the set kernel's tile: its sums go
+    # through the wide arm all the same)
+    assert store.spec.layout == "dense"
+    assert not store_mod._combine_kernel_takes(store.spec)  # this is a CPU
+    ids, deltas, mask = _wide_traffic(traffic, rng, CAP, shape)
+    calls = []
+    if arm == "row_kernel":
+        monkeypatch.setattr(
+            store_mod, "_combine_kernel_takes", lambda spec: True)
+        monkeypatch.setattr(row_update, "MAX_LANES", 512)
+        real = row_update.sorted_row_update
+        monkeypatch.setattr(
+            row_update, "sorted_row_update",
+            lambda *a, **kw: calls.append(a[1].shape[0]) or real(*a, **kw))
+    # not `_push`: a program traced for the other arm would be reused
+    push = jax.jit(lambda st, i, d, m: st.push(i, d, m))
+    _check_push_pull(store, values, ids, deltas, mask, push=push)
+    assert len(calls) == (arm == "row_kernel"), calls
+    # what the push counted: the kernel's lanes are the batch's live lanes
+    table, counted = store_mod.push_counted(
+        store.spec, store.table, jnp.asarray(ids), jnp.asarray(deltas),
+        None if mask is None else jnp.asarray(mask))
+    live = (ids >= 0) & (ids < store.spec.padded_capacity)
+    if mask is not None:
+        live &= mask
+    assert int(counted["ps_rule_keys"]) == live.sum()
+    assert int(counted["ps_rule_rows"]) == len(np.unique(ids[live]))
+    assert int(counted["ps_combine_kernel_lanes"]) == (
+        live.sum() if arm == "row_kernel" else 0)
+
+
+@pytest.mark.parametrize("why,shape,dtype,meshed,noted", [
+    ("off_the_tpu", (36,), jnp.float32, False, False),
+    ("a_sort_carries_the_row", (3,), jnp.float32, False, False),
+    ("a_sort_carries_four_lanes", (4,), jnp.float32, False, False),
+    ("under_a_mesh", (36,), jnp.float32, True, False),
+    ("bfloat16", (36,), jnp.bfloat16, False, True),
+    ("wider_than_a_register", (200,), jnp.float32, False, True),
+    ("rank_2_over_a_register", (2, 100), jnp.float32, False, True),
+])
+def test_the_combine_kernel_arm_says_no(
+        why, shape, dtype, meshed, noted, mesh, monkeypatch):
+    """What keeps the scatter-add in stream order: the CPU; rows a sort
+    carries (they never reach the wide arm); a mesh (the sums are GSPMD's);
+    bfloat16 and rows over 128 lanes (noted and counted, as the other arms'
+    refusals are).  Every other rule store on a TPU takes the kernel, rows
+    of rank 2 flat; an add store has no combine."""
+    from flink_parameter_server_tpu.core import store as store_mod
+    from flink_parameter_server_tpu.ops import row_update
+
+    def spec_of(shape, dtype, update=_add_rule, on=None):
+        return jax.eval_shape(lambda: ShardedParamStore.create(
+            1000, shape, dtype=dtype, update=update, mesh=on)).spec
+
+    spec = spec_of(shape, dtype, on=mesh if meshed else None)
+    monkeypatch.setattr(store_mod, "_REFUSALS_NOTED", set())
+    n0 = row_update.refusal_count()
+    assert not store_mod._combine_kernel_takes(spec)  # this is a CPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    if why == "off_the_tpu":
+        for takes in (spec, spec_of((5,), dtype), spec_of((128,), dtype),
+                      spec_of((2, 9), dtype)):
+            assert store_mod._combine_kernel_takes(takes)
+        assert not store_mod._combine_kernel_takes(
+            spec_of(shape, dtype, update="add"))
+        assert row_update.refusal_count() == n0
+        return
+    if noted:
+        with pytest.warns(RuntimeWarning, match="sum of a rule's wide rows"):
+            assert not store_mod._combine_kernel_takes(spec)
+    assert not store_mod._combine_kernel_takes(spec)  # and warns once
+    assert row_update.refusal_count() == n0 + noted
+
+
 # Rows packed several to a physical row: on a TPU a float32 ``pull`` of a
 # block or more slices the rows it gathered in ``ops/packed``'s kernel, which
 # hands them over feature-major; everywhere else in XLA's selects.  Off the

@@ -878,7 +878,14 @@ def _step_text_sha(step, *args):
     return hashlib.sha256(text.encode()).hexdigest()[:16]
 
 
-def test_the_mf_and_fm_cells_step_text_is_the_parents(fm1):
+@pytest.mark.parametrize("cell, want", [
+    ("mf_cells_1_and_3", "467449ddc73eac39"),
+    ("fm_cell_2", "62cb492a1f6ca10e"),
+    ("fm_ps4_cell_4", "0d16cc6091cddad1"),
+    ("lr_cell_6", "aab60546ac40ed64"),
+    ("keyed_mf_cell_8", "47d256f2590f4bc0"),
+])
+def test_the_mf_and_fm_cells_step_text_is_the_parents(cell, want, request):
     """The lowered text of a step carries no locations, so a change that
     traces the same ops gives the same text: cells 1 and 3 (MF, dense 128
     lanes) and cell 2 (FM, seven 17-lane rows to a 128-lane row) run the
@@ -886,31 +893,53 @@ def test_the_mf_and_fm_cells_step_text_is_the_parents(fm1):
     change that means to move them brings its own: PR 42 gave FM's step the
     scalar ``ps_slice_kernel`` (which arm sliced the pulled rows; lowered
     here, off the TPU, the arm and every other op are the parent's:
-    ``3913e9e902cfade8`` until then)."""
+    ``3913e9e902cfade8`` until then).  Since PR 46 also cell 4 (FM packed
+    under ``ps = 4``), cell 6 (the narrow rule store: its combine, its
+    counts and its outputs are what they were, no new count among them) and
+    cell 8 (MF under four keyed workers), each as PR 46's parent lowers it
+    here: that PR changed the combine of WIDE rule rows, which none of the
+    five traces."""
     shape = jax.ShapeDtypeStruct
-    logic = mfm.OnlineMatrixFactorization(
-        USERS, DIM, updater=mfm.SGDUpdater(2e-4))
-    spec = jax.eval_shape(
-        lambda: ShardedParamStore.create(ITEMS, (DIM,), dtype=jnp.float32)
-    ).spec
-    batch = {
-        "user": shape((BATCH,), jnp.int32), "item": shape((BATCH,), jnp.int32),
-        "rating": shape((BATCH,), jnp.float32),
-        "mask": shape((BATCH,), jnp.bool_),
-    }
-    assert _step_text_sha(
-        make_train_step(logic, spec),
-        shape((spec.padded_capacity, DIM), jnp.float32),
-        shape((USERS, DIM), jnp.float32), batch,
-    ) == "467449ddc73eac39"
-    spec, logic = fm1
-    batch = {
-        k: shape(v.shape, v.dtype) for k, v in _fm_batch(None).items()
-    }
-    assert _step_text_sha(
-        make_train_step(logic, spec),
-        shape(spec.table_shape(), jnp.float32), (), batch,
-    ) == "62cb492a1f6ca10e"
+
+    def mf_batch(n, on=shape):
+        return {"user": on((n,), jnp.int32), "item": on((n,), jnp.int32),
+                "rating": on((n,), jnp.float32), "mask": on((n,), jnp.bool_)}
+
+    if cell == "mf_cells_1_and_3":
+        logic = mfm.OnlineMatrixFactorization(
+            USERS, DIM, updater=mfm.SGDUpdater(2e-4))
+        spec = jax.eval_shape(
+            lambda: ShardedParamStore.create(ITEMS, (DIM,), dtype=jnp.float32)
+        ).spec
+        args = (shape((spec.padded_capacity, DIM), jnp.float32),
+                shape((USERS, DIM), jnp.float32), mf_batch(BATCH))
+    elif cell == "fm_cell_2":
+        spec, logic = request.getfixturevalue("fm1")
+        batch = {k: shape(v.shape, v.dtype) for k, v in _fm_batch(None).items()}
+        args = (shape(spec.table_shape(), jnp.float32), (), batch)
+    elif cell == "fm_ps4_cell_4":
+        mesh, spec, logic = request.getfixturevalue("ps4")
+        args = (_shape(spec.sharding(), spec.table_shape(), jnp.float32), (),
+                _fm_batch(NamedSharding(mesh, PartitionSpec())))
+    elif cell == "lr_cell_6":
+        spec, logic = request.getfixturevalue("lr")
+        args = (shape(spec.table_shape(), jnp.float32), (), _fm_batch(None))
+    else:
+        from flink_parameter_server_tpu.parallel.mesh import make_mesh
+
+        mesh = make_mesh(4, 1, devices=request.getfixturevalue("topo").devices)
+        logic = mfm.OnlineMatrixFactorization(
+            50_082_603, DIM, updater=mfm.SGDUpdater(5e-5), mesh=mesh)
+        spec = jax.eval_shape(lambda: ShardedParamStore.create(
+            ITEMS, (DIM,), dtype=jnp.float32, mesh=mesh)).spec
+
+        def on(dims, dtype, *axes):
+            return _shape(NamedSharding(mesh, PartitionSpec(*axes)), dims, dtype)
+
+        args = (on((spec.padded_capacity, DIM), jnp.float32, "ps", None),
+                on((logic.state_rows, DIM), jnp.float32, "dp", None),
+                mf_batch(262_144, on))
+    assert _step_text_sha(make_train_step(logic, spec), *args) == want
 
 
 def test_w2v_table_is_initialised_in_place_from_a_seed_argument(
@@ -1205,19 +1234,38 @@ def test_difacto_table_is_initialised_in_place_block_by_block(
     assert len(re.findall(r" while\(", compiled.as_text())) >= 1
 
 
+@pytest.mark.parametrize("arm", ["row_kernel", "scatter_add"])
 def test_difacto_step_holds_nothing_table_sized_beside_its_table(
-        difacto, one_chip, no_compile_cache):
+        arm, difacto, one_chip, no_compile_cache, monkeypatch):
     """Cell 9's step at full size for a described v5e: a rule row of 36
-    lanes goes through the dense arm (no kernel takes it), the donated table
-    is rewritten in place, and what the step holds beside it goes with the
-    batch (the 36-lane gradient rows laid 128 lanes wide and their sorted
-    copies: 0.92 GB), with every scope the cell's metrics read."""
+    lanes goes through the dense arm (no kernel takes its pull, its rule or
+    its write-back), the donated table is rewritten in place and never
+    copied, with every scope the cell's metrics read.  As the chip runs it
+    (asked for the backend: ``row_kernel``) the combine sums the rows along
+    the sorted lanes: ONE ``sorted_row_update`` call under ``ps.push/
+    ps.combine``, inside the loop over the thirteen stretches of 98,304
+    lanes, fed by a gather of whole 128-lane rows out of the padded
+    ``f32[1277952,128]``, and no scatter of the batch's rows there.  What the
+    step holds beside the table goes with the batch: 1.33 GB, of which the
+    padded rows and the zeroed block the kernel writes into are 0.654 GB
+    each, row-major ``(n, 128)``; the rows-minor ``(n, 36)`` gradient rows
+    and sums (0.20 GB: 40 sublanes) and a stretch's permuted rows and old
+    rows (0.05 GB each) lie where those two are not yet or no longer.  Off
+    the TPU (``scatter_add``) the step is PR 45's: one scatter-add of the
+    rows in stream order, 0.89 GB, no kernel."""
     cfg, rule, model, fam, dfm = difacto
     spec = jax.eval_shape(
         lambda: dfm.make_store(model, rule, dtype=jnp.float32)
     ).spec
     assert spec.layout == "dense" and not spec.narrow_rule
     assert spec.table_shape() == (49_126_312, DF_LANES)
+    assert not store_mod._combine_kernel_takes(spec)  # this is a CPU
+    if arm == "row_kernel":
+        # code that asks for the backend still sees the CPU here: steer it
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        n0 = row_update.refusal_count()
+        assert store_mod._combine_kernel_takes(spec)
+        assert row_update.refusal_count() == n0
     assert not store_mod._set_kernel_takes(spec)
     compiled = jax.jit(
         make_train_step(dfm.DiFacto(model, rule), spec), donate_argnums=(0, 1)
@@ -1227,10 +1275,27 @@ def test_difacto_step_holds_nothing_table_sized_beside_its_table(
     ).compile()
     mem = compiled.memory_analysis()
     assert 7.85 * GB < mem.alias_size_in_bytes < 7.87 * GB  # in place
-    assert mem.temp_size_in_bytes < 1.2 * GB
     text = compiled.as_text()
     assert not re.search(r"f32\[49126312,36\]\S* (copy|transpose)\(", text)
-    assert "sorted_row" not in text and "tpu_custom_call" not in text  # no kernel
     for scope in ("ps.pull", "ps.compute/ps.gate", "ps.compute/ps.delta_build",
                   "ps.push/ps.combine", "ps.push/while/body/ps.rule"):
         assert scope in text, scope
+    combine = [line for line in text.splitlines() if "ps.push/ps.combine" in line]
+    kernels = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    if arm == "scatter_add":
+        assert mem.temp_size_in_bytes < 1.2 * GB
+        assert not kernels
+        assert any(re.search(r"f32\[1277952,36\]\S* scatter\(", c) for c in combine)
+        return
+    assert 1.2 * GB < mem.temp_size_in_bytes < 1.5 * GB
+    assert len(kernels) == 1, kernels
+    assert kernels[0].strip().startswith("%sorted_row_update")
+    assert " f32[1277952,128]{1,0" in kernels[0]
+    assert "ps.push/ps.combine/while/body" in kernels[0]
+    assert not any(re.search(r" scatter\(", c) for c in combine)
+    gathers = [c for c in combine if re.search(r" gather\(%param", c)
+               and " f32[" in c]
+    assert len(gathers) == 1 and "f32[98304,128]{1,0" in gathers[0], gathers
+    assert "slice_sizes={1,128}" in gathers[0]
+    # the rule's loop and the stretches' (the flattens' two are cell 2's)
+    assert len(re.findall(r" while\(", text)) == 4
