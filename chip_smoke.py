@@ -541,7 +541,9 @@ class Smoke:
         )
 
     # -- stage 3: kernels ---------------------------------------------------
-    def _kernel_case(self, name, fn, args, check, *, mosaic=True) -> None:
+    def _kernel_case(
+        self, name, fn, args, check, *, mosaic=True, stage="kernel",
+    ) -> None:
         """Compile ``fn`` once, require a Mosaic call in its lowering (on
         the chip), run it, and hand the result to ``check``.  The row
         kernel's refusal counter must not move: a pass on the XLA scatter
@@ -565,7 +567,7 @@ class Smoke:
             f"{name}: fell back to the XLA scatter",
         )
         self.report(
-            f"kernel:{name}", max_abs_err=float(f"{err:.3e}"),
+            f"{stage}:{name}", max_abs_err=float(f"{err:.3e}"),
             setup_s=round(setup_s, 3),
             mosaic=bool(mosaic and not self.dry_run),
         )
@@ -780,6 +782,65 @@ class Smoke:
                 r, i, 17, block=block_p, interpret=interpret),
             (rows_p, ids_p),
             close(packed._sub_row_slice(rows_p, ids_p, 17), 0.0),
+        )
+
+        # the same kernel at DiFacto's 36 lanes, three rows to a physical row
+        # (windows of 36 sublanes that start at 0, 36, 72: no multiple of 8)
+        self._kernel_case(
+            "packed_lane_slice_k3_d36",
+            lambda r, i: packed.sub_row_slice_kernel(
+                r, i, 36, block=block_p, interpret=interpret),
+            (rows_p, ids_p),
+            close(packed._sub_row_slice(rows_p, ids_p, 36), 0.0),
+        )
+
+        # a PACKED rule store at cell 9's row (36 lanes, three to a physical
+        # row) inside one jitted step, pull and push: the lane slice, the
+        # combine's row kernel and the write-back's row set on the chip,
+        # against numpy on the host, bit for bit (small whole numbers and a
+        # rule of halves and sums: exact in any order of a run's sum)
+        from flink_parameter_server_tpu.core import store as store_mod
+
+        def halve_and_add(current, combined):
+            return 0.5 * current + combined
+
+        cap_r = 30_000 if not self.dry_run else 300
+        init_r = rng.integers(-8, 9, (cap_r, 36)).astype(np.float32) * 2
+        init_r[8], init_r[7, ::2] = -0.0, np.inf  # untouched neighbours of 6
+        ids_r = np.array(rng.zipf(1.3, size=n) % cap_r, np.int32)
+        ids_r[np.isin(ids_r, (7, 8))] = 6
+        ids_r[:3] = [6, 9, 10]  # row 6 alone in its physical row, 9-10 two
+        deltas_r = rng.integers(-4, 5, (n, 36)).astype(np.float32)
+        store_r = ShardedParamStore.from_values(
+            jnp.asarray(init_r), update=halve_and_add, layout="auto")
+        require(store_r.spec.layout == "packed" and store_r.spec.pack == 3,
+                f"rule rows of 36 lanes lie {store_r.spec.layout}")
+        want_r = init_r.copy()
+        sums_r = np.zeros_like(init_r)
+        np.add.at(sums_r, ids_r, deltas_r)
+        hit_r = np.unique(ids_r)
+        want_r[hit_r] = 0.5 * init_r[hit_r] + sums_r[hit_r]
+
+        def rule_step(t, i, dl):
+            pulled = store_mod.pull(store_r.spec, t, i)
+            t, counted = store_mod.push_counted(store_r.spec, t, i, dl)
+            return (ShardedParamStore(store_r.spec, t).values(), pulled,
+                    counted["ps_rule_packed_rows"])
+
+        def check_rule(got):
+            values, pulled, wrote = (np.asarray(g) for g in got)
+            require(values.tobytes() == want_r.tobytes(),
+                    "packed rule push differs from numpy's bits")
+            require(pulled.tobytes() == init_r[ids_r].tobytes(),
+                    "packed rule pull differs from the rows")
+            require(int(wrote) == len(np.unique(hit_r // 3)),
+                    f"{int(wrote)} physical rows written")
+            return 0.0
+
+        self._kernel_case(
+            "packed_rule_push_d36", rule_step,
+            (store_r.table, jnp.asarray(ids_r), jnp.asarray(deltas_r)),
+            check_rule, stage="store",
         )
 
         # splash flash attention: forward, gradient, and under shard_map

@@ -54,29 +54,48 @@ def _resolve_layout(
 ) -> str:
     """Resolve the table layout, validating packed-layout constraints.
 
-    ``"auto"`` reads the row's shape and the update rule: dense for an
-    add-store whose rows are ONE axis of a whole number of 128 lanes (a
-    row is then a whole number of vector registers as it is), packed for
-    every other add-store, dense for every store whose ``update`` is a
-    rule (its push writes whole logical rows back, and a row write into
-    a packed table would lose a physical row's other touched rows; for
-    FTRL's 3-lane row the TPU pads a dense row to FOUR sublanes, 3.00 GB
-    at 187.8 M rows, and packed 42 to a physical row the step asks 13.5
-    GB for its 42 static lane slices: PERF.md section 6, PR 34).  Such a
-    store's PHYSICAL row, where it is float32, one axis of 1 to 8 lanes
-    and in one place, is that sublane tile, 1, 2, 4 or 8 lanes, the rest
-    zeros, and its table whole tiles of 128 rows (``StoreSpec.tile_lanes``:
-    ``(w, z, n)`` lies as ``f32[187767424,4]{0,1:T(4,128)}``): the chip's
-    bytes as they were, and a table whose tiles a Pallas kernel can move
-    (a 3-lane table has the same tiles and Mosaic refuses a slice of
-    them: PERF.md section 6, PR 35).  A narrow dense row is a column of scalars
-    across the table's tiles on the TPU, and its gather and scatter-add
-    walk that column (36 and 121 ns a row for FM's 17 lanes on the v5e,
-    against 10 and 22 for the 128-lane physical row that holds seven of
-    them; PERF.md section 6, PR 29).  A row of 128 lanes or more that has
-    two axes or is no multiple of 128 is packed ONE to a physical row,
-    flat and zero-padded to whole registers (``ops/packed.py``: ``pack_k``
-    1, ``phys_width`` the padded width): left as it is, the TPU holds
+    ``"auto"`` reads the row's shape and the update rule, nothing else:
+    dense for an add-store whose rows are ONE axis of a whole number of 128
+    lanes (a row is then a whole number of vector registers as it is),
+    packed for every other add-store.  A store whose ``update`` is a rule
+    lies by its row's width:
+
+    - one axis of 9 to 64 lanes: PACKED, ``k = 128 // width`` logical rows
+      to a 128-lane physical row (DiFacto's 36 lanes three to a row:
+      ``f32[16375440,128]{1,0:T(8,128)}``, 8.384 GB where the dense table
+      is 7.860).  Dense, such a table lies rows-minor on the TPU, a row a
+      strided column of scalars across its tiles, and every op that names
+      a row pays a serial price for it: on the v5e the pull's gather 44.3
+      ns a 36-lane row, the rule's read 48.0, XLA's row ``set`` 136
+      (PERF.md section 6, PR 46).  Packed, the pull gathers whole
+      registers and slices in the kernel an add-store's has, and the
+      rule's push reads and writes each touched PHYSICAL row once, its
+      logical rows merged by selects (:func:`_push_rule`; what the chip
+      measured: PERF.md section 6, PR 47).
+    - one axis of 1 to 8 lanes: dense, and where it is float32 and in one
+      place its PHYSICAL row is its sublane tile, 1, 2, 4 or 8 lanes, the
+      rest zeros, and its table whole tiles of 128 rows
+      (``StoreSpec.tile_lanes``: FTRL's ``(w, z, n)`` lies as
+      ``f32[187767424,4]{0,1:T(4,128)}``, 3.00 GB at 187.8 M rows, the
+      bytes the TPU pads a dense 3-lane row to anyway): a table whose
+      tiles a Pallas kernel can move (a 3-lane table has the same tiles
+      and Mosaic refuses a slice of them: PERF.md section 6, PR 35).
+      Packed 42 to a physical row, its step asked 13.5 GB for its 42
+      static lane slices (PERF.md section 6, PR 34).
+    - every other row (65 lanes or more, so ``k`` = 1; two axes; none):
+      dense, as it was: no cell stands there.
+
+    ``layout="packed"`` may be pinned for any rule store; the rule's push
+    then takes the packed arm at whatever ``k`` the width gives.
+
+    A narrow dense row of an add-store is the same column of scalars
+    across the table's tiles, and its gather and scatter-add walk that
+    column (36 and 121 ns a row for FM's 17 lanes on the v5e, against 10
+    and 22 for the 128-lane physical row that holds seven of them;
+    PERF.md section 6, PR 29).  A row of 128 lanes or more that has two
+    axes or is no multiple of 128 is packed ONE to a physical row, flat
+    and zero-padded to whole registers (``ops/packed.py``: ``pack_k`` 1,
+    ``phys_width`` the padded width): left as it is, the TPU holds
     ``(capacity, 2, 300)`` and ``(capacity, 600)`` capacity-minor (no
     padding) and the step copies the WHOLE table to a row-major one for
     its gather and back after its scatter-add, two table-sized passes and
@@ -91,20 +110,15 @@ def _resolve_layout(
         raise ValueError(
             f"layout must be 'dense', 'packed' or 'auto', got {layout!r}"
         )
+    if layout != "auto":
+        return layout
     width = 1
     for s in value_shape:
         width *= int(s)
-    if layout == "auto":
-        whole = len(value_shape) == 1 and width % 128 == 0
-        return "packed" if update == "add" and not whole else "dense"
-    if layout == "packed" and update != "add":
-        # a rule's push writes whole logical rows back (`_push_rule`): two
-        # of them may share a physical row, and a row write would lose one
-        raise ValueError(
-            "layout='packed' requires update='add' (custom update "
-            "functions take the dense per-row path)"
-        )
-    return layout
+    one_axis = len(value_shape) == 1
+    if update == "add":
+        return "dense" if one_axis and width % 128 == 0 else "packed"
+    return "packed" if one_axis and 8 < width <= 64 else "dense"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -128,7 +142,8 @@ class StoreSpec:
     #   (MF dim 64, FM dim 17): full vector lanes on every pull/push.  A
     #   row of 128 lanes or more lies alone (k = 1), flat and zero-padded
     #   to whole 128-lane registers (word2vec's (2, 300): 640 lanes).
-    #   Requires update="add".
+    #   A rule store's push then reads and writes whole physical rows, the
+    #   touched logical rows of each merged by selects (`_push_rule`).
     layout: str = "dense"
 
     def __post_init__(self) -> None:
@@ -405,7 +420,9 @@ def push_counted(
     wrote the rows: :func:`_set_kernel_takes`); a rule store whose rows are
     wider than a sort carries also ``ps_combine_kernel_lanes``, the lanes
     whose rows the row kernel summed (the live lanes; 0 where XLA's
-    scatter-add summed them: :func:`_combine_kernel_takes`).
+    scatter-add summed them: :func:`_combine_kernel_takes`); a PACKED rule
+    store carries ``ps_rule_packed_rows``, the physical rows its write-back
+    wrote (:func:`_rewrite_packed`; a dense rule store has no such count).
     ``make_train_step`` puts
     them among the step's outputs, where whoever fetches outputs finds them, if the logic's
     outputs are a dict (every logic of ``models/``); outputs of another
@@ -503,7 +520,9 @@ def _push_rule(
     a TPU, for a narrow row held at its sublane tile
     (``StoreSpec.tile_lanes``), ``ops/row_update.sorted_tile_set``: every
     touched tile of 128 rows read, set and written back once, the same
-    bits (PERF.md section 6, PR 35)."""
+    bits (PERF.md section 6, PR 35).  A PACKED store's chunk goes through
+    :func:`_rewrite_packed`, which reads and writes whole physical rows,
+    and ``counted`` then carries ``ps_rule_packed_rows``."""
     from ..ops.dedup import _SORT_CARRIES_LANES, combine_runs
     from ..ops.row_update import sorted_tile_set
 
@@ -511,6 +530,7 @@ def _push_rule(
     sentinel = spec.padded_capacity
     update_fn: UpdateFn = spec.update  # type: ignore[assignment]
     wide = spec.row_width > _SORT_CARRIES_LANES
+    packed = spec.layout == "packed"
     if n == 0:  # an empty batch rewrites nothing
         zero = jnp.zeros((), jnp.int32)
         counted = {
@@ -518,6 +538,8 @@ def _push_rule(
         }
         if wide:
             counted["ps_combine_kernel_lanes"] = zero
+        if packed:
+            counted["ps_rule_packed_rows"] = zero
         return table, counted
     tiles_arm = _set_kernel_takes(spec)
     sums_arm = _combine_kernel_takes(spec)
@@ -547,26 +569,96 @@ def _push_rule(
         combined = jnp.pad(combined, ((0, pad), (0, 0)))
 
     def rewrite(i, carry):
-        table, tiles = carry
+        table, moved = carry  # tiles, or a packed store's physical rows
         ids = jax.lax.dynamic_slice(row_ids, (i * chunk,), (chunk,))
         sums = jax.lax.dynamic_slice(
             combined, (i * chunk, 0), (chunk, combined.shape[1])
         )
+        if packed:
+            table, wrote = _rewrite_packed(spec, table, ids, sums, tiles_arm)
+            return table, moved + wrote
         with jax.named_scope("ps.rule"):
             new = update_fn(
                 pull(spec, table, ids),
                 sums.reshape((chunk,) + spec.value_shape),
             ).astype(table.dtype)
         if tiles_arm:
-            table, moved = sorted_tile_set(table, ids, new)
-            return table, tiles + moved
-        return table.at[ids].set(_physical_rows(spec, new), mode="drop"), tiles
+            table, opened = sorted_tile_set(table, ids, new)
+            return table, moved + opened
+        return table.at[ids].set(_physical_rows(spec, new), mode="drop"), moved
 
     chunks = -(-counted["ps_rule_rows"] // chunk)
-    table, counted["ps_rule_tiles"] = jax.lax.fori_loop(
-        0, chunks, rewrite, (table, jnp.zeros((), jnp.int32))
-    )
+    zero = jnp.zeros((), jnp.int32)
+    table, moved = jax.lax.fori_loop(0, chunks, rewrite, (table, zero))
+    counted["ps_rule_tiles"] = zero if packed else moved
+    if packed:
+        counted["ps_rule_packed_rows"] = moved
     return table, counted
+
+
+def _rewrite_packed(
+    spec: StoreSpec, table: Array, ids: Array, sums: Array,
+    kernel: bool = False,
+) -> Tuple[Array, Array]:
+    """One chunk of :func:`_push_rule` for a PACKED table: ``(table,
+    physical rows written)``.  ``ids`` are sorted and distinct, the
+    sentinel last; ``sums`` ``(chunk, row_width)``.
+
+    Under ``ps.rule`` ONE gather of the chunk's physical rows (``ids //
+    k``, whole 128-lane registers), the lane slice down to each id's
+    logical row (``ops/packed._sub_row_slice``) and the rule on those.
+    What follows (``ps.push``, the write-back) places each new row at its
+    window (``ops/packed.lane_shift_deltas``) and MERGES the logical rows
+    of one physical row: sorted and distinct, the ids that share one are
+    neighbours, at most ``k`` of them, so the merged row is the gathered
+    physical row with every touched window replaced, ``k - 1`` shifted
+    passes over the chunk, and the FIRST lane of each such run writes it,
+    the others go to the sentinel: ONE ``set`` of whole physical rows,
+    each touched physical row once, in the arm :func:`_set_kernel_takes`
+    read from the spec (``kernel``): XLA's row ``set`` (on the v5e 72 ns a
+    lane of the chunk, written or dropped, serial) or, on a TPU, for
+    float32 physical rows of one register, ``ops/row_update.
+    sorted_row_set``, the row kernel's walk with a copy for its body
+    (PERF.md section 6, PR 47).  Selects and copies only, never an add or a
+    masked sum: an untouched logical row inside a touched physical row,
+    the pad lanes and a row's NaN or -0.0 come back bit for bit.  A
+    physical row whose touched rows fall on two chunks is written by
+    both: the second reads what the first wrote."""
+    from ..ops.packed import _sub_row_slice, lane_shift_deltas
+    from ..ops.row_update import sorted_row_set
+
+    k, d = spec.pack, spec.row_width
+    chunk, (phys_rows, lanes) = ids.shape[0], table.shape
+    update_fn: UpdateFn = spec.update  # type: ignore[assignment]
+    phys, sub = ids // k, ids % k  # the sentinel: one past the last row
+    with jax.named_scope("ps.rule"):
+        rows = jnp.take(table, phys, axis=0, mode="clip")
+        new = update_fn(
+            _sub_row_slice(rows, ids, d).reshape(
+                (chunk,) + spec.value_shape),
+            sums.reshape((chunk,) + spec.value_shape),
+        ).astype(table.dtype).reshape(chunk, d)
+    placed = lane_shift_deltas(new, ids, d)
+    # the window a lane lies in (the pad lanes: one no id has)
+    window = (jnp.arange(lanes, dtype=jnp.int32) // d)[None]
+    merged = rows
+    for s in range(min(k, chunk)):
+        # the lane itself, then the lane `s` further on where it names the
+        # same physical row: each replaces its own window
+        near = (
+            jnp.pad(phys[s:], (0, s), constant_values=-1) == phys
+        )[:, None] & (window == jnp.pad(sub[s:], (0, s))[:, None])
+        merged = jnp.where(
+            near, jnp.pad(placed[s:], ((0, s), (0, 0))), merged)
+    writes = jnp.concatenate(
+        [jnp.ones((1,), bool), phys[1:] != phys[:-1]]
+    ) & (phys < phys_rows)
+    at = jnp.where(writes, phys, phys_rows)
+    if kernel:
+        table = sorted_row_set(table, at, merged)
+    else:
+        table = table.at[at].set(merged, mode="drop")
+    return table, jnp.sum(writes, dtype=jnp.int32)
 
 
 def _physical_rows(spec: StoreSpec, rows: Array) -> Array:
@@ -664,17 +756,27 @@ def _taken_or_noted(spec: StoreSpec, what: str, why: Optional[str]) -> bool:
 
 
 def _set_kernel_takes(spec: StoreSpec) -> bool:
-    """Whether a rule store's write-back (:func:`_push_rule`) goes through
-    ``ops/row_update.sorted_tile_set`` instead of XLA's row ``set``, read
-    from what the spec holds, as :func:`_tile_kernel_takes` reads the add
-    arm's: a TPU, and a table held at whole sublane tiles for it
-    (``StoreSpec.tile_lanes``).  Static per compiled step.  A narrow rule
-    store that is NOT held so (bfloat16, rows of rank 0 or 2) keeps the XLA
-    arm, counted and warned of once."""
+    """Whether a rule store's write-back (:func:`_push_rule`) goes through a
+    kernel of ``ops/row_update`` instead of XLA's row ``set``, read from
+    what the spec holds, as :func:`_tile_kernel_takes` reads the add arm's:
+    a TPU, and either a table held at whole sublane tiles
+    (``StoreSpec.tile_lanes``: ``sorted_tile_set``) or a PACKED table in one
+    place whose physical row is one float32 register (``sorted_row_set``:
+    :func:`_rewrite_packed`).  Static per compiled step.  A narrow rule
+    store that is NOT held at its tile (bfloat16, rows of rank 0 or 2) and
+    a packed one the row kernel refuses (bfloat16, physical rows of several
+    registers) keep the XLA arm, counted and warned of once."""
     if jax.default_backend() != "tpu":
         return False
     if spec.tile_lanes:
         return True
+    if spec.layout == "packed" and spec.update != "add":
+        from ..ops import row_update
+
+        return spec.mesh is None and _taken_or_noted(
+            spec, "write-back of a packed rule store's rows",
+            row_update.refusal(spec.table_shape()[1:], spec.dtype),
+        )
     if spec.narrow_rule:
         from ..ops import row_update
 

@@ -29,7 +29,12 @@ row.  Logical row ``r`` maps to physical row ``r // k``, lane offset
 
 The push side is pure XLA and the scatter kernels consume the packed form
 unmodified; the pull's lane slice has the one kernel of this module.
-``ShardedParamStore(layout="packed")`` wires it in.
+``ShardedParamStore(layout="packed")`` wires it in.  A store whose update
+is a RULE packs too (rule rows of 9 to 64 lanes by default, DiFacto's 36
+three to a row): its push reads whole physical rows, slices each touched
+logical row out, runs the rule and writes each touched physical row back
+once, the new rows shifted to their windows and merged by selects
+(``core/store._rewrite_packed``).
 
 **The lane slice as a kernel** (``core/store._slice_kernel_takes``).  XLA
 compiles :func:`_sub_row_slice` row-major: ``k`` lane rotates and selects

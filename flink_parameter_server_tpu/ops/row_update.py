@@ -92,6 +92,15 @@ touch one tile: the next block's reads are in flight under this block's
 sets and the block before's writes, three tile buffers.  Its time is the
 issue of two DMA descriptors a tile (17 ns each on the v5e) and 7 ns a lane
 of sets (PERF.md section 6, PR 35).
+
+**A packed rule store's write-back** (``core/store._rewrite_packed``: whole
+128-lane physical rows, distinct, sorted, lanes to drop between them:
+:func:`sorted_row_set`) is the first kernel's walk with a COPY for its body:
+a block's new rows are staged as they are and sent, one single-row DMA a
+lane, nothing summed, so every bit of a row arrives (-0.0, NaN payloads,
+infinities).  XLA's row ``set`` of 32,768 such rows is 72 ns a lane on the
+v5e, kept or dropped, and 764 once promised sorted; the walk is 13.7 ns a
+lane inside cell 9's step (PERF.md section 6, PR 47).
 """
 from __future__ import annotations
 
@@ -522,6 +531,125 @@ def row_add(
             state, s, old, jnp.take(deltas, o, axis=0), interpret=interpret
         )
     return state
+
+
+# -- the same walk with a SET for its body: a packed rule store's write-back ---
+def _row_set_kernel(tgt_ref, src_ref, writes_ref, new_ref, state_ref, out_ref,
+                    buf_ref, sem, *, block: int):
+    """:func:`_kernel`'s walk with nothing to sum: one grid step = ``block``
+    lanes whose new rows (``new_ref``, (block, W) VMEM) are staged as they
+    are and sent, one single-row DMA a lane, to the rows ``tgt_ref`` names
+    (``src_ref`` / ``writes_ref`` as there: a lane that writes nothing
+    repeats its block's first write).  A block's writes stay in flight
+    while the next is staged and are awaited one block later."""
+    pl, pltpu = _pallas()
+
+    del state_ref  # aliased to out_ref: untouched rows keep their values
+    b = pl.program_id(0)
+    slot = b % 2
+    base = b * block
+    buf_ref[slot] = new_ref[:]
+
+    @pl.when(writes_ref[b] > 0)
+    def _start():
+        # eight lanes a trip: Mosaic unrolls a loop wholly or not at all
+        def group(g, _):
+            for k in range(8):
+                lane = base + g * 8 + k
+                pltpu.make_async_copy(
+                    buf_ref.at[slot, pl.ds(src_ref[lane], 1)],
+                    out_ref.at[pl.ds(tgt_ref[lane], 1)],
+                    sem.at[slot],
+                ).start()
+            return 0
+
+        jax.lax.fori_loop(0, block // 8, group, 0)
+
+    def wait_for(blk, s):
+        # one wait the size of the staging slot answers a block's copies
+        @pl.when(writes_ref[blk] > 0)
+        def _():
+            pltpu.make_async_copy(
+                buf_ref.at[s], buf_ref.at[s], sem.at[s]
+            ).wait()
+
+    @pl.when(b > 0)
+    def _previous():
+        wait_for(b - 1, 1 - slot)
+
+    @pl.when(b == pl.num_programs(0) - 1)
+    def _own():
+        wait_for(b, slot)
+
+
+def sorted_row_set(
+    state: Array,
+    ids: Array,
+    new_rows: Array,
+    *,
+    interpret: Optional[bool] = None,
+) -> Array:
+    """``state[r] = new_rows[k]`` for every row ``r`` a kept lane ``k``
+    names, bit for bit (a copy, no arithmetic); every other row is left as
+    it is.  The row kernel's walk (:func:`sorted_row_update`) with a set for
+    its body: on the v5e XLA's ``set`` of 32,768 sorted distinct 128-lane
+    rows is 72 ns a lane, kept or dropped, serial; a single-row DMA out of
+    a staged block is what the row kernel issues at under 10 (PERF.md
+    section 6, PR 47).
+
+    ``state``: (rows, 128) float32 (:func:`refusal`).  ``ids``: (n,) int32,
+    the kept lanes' DISTINCT (two lanes may not name one row: their DMAs
+    would race); a lane to drop carries an id >= the row count and may lie
+    anywhere (a packed rule store's lie between the lanes that write:
+    ``core/store._rewrite_packed``).  ``new_rows``: (n, 128).  At most
+    ``MAX_LANES`` lanes a call.  In place when the enclosing jit donates the
+    state; an eager call copies it first.  Off the TPU the kernel is
+    interpreted."""
+    pl, pltpu = _pallas()
+
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    rows, width = state.shape
+    n = ids.shape[0]
+    why = refusal(state.shape[1:], state.dtype) or _too_many(n)
+    if why is not None and not interpret:
+        raise ValueError(f"sorted_row_set: {why}")
+    block = BLOCK
+    ids = ids.astype(jnp.int32)
+    new_rows = new_rows.astype(state.dtype)
+    pad = -n % block
+    if pad:
+        ids = jnp.concatenate([ids, jnp.full((pad,), _INT32_MAX, jnp.int32)])
+        new_rows = jnp.pad(new_rows, ((0, pad), (0, 0)))
+    # distinct ids: every kept lane is the last of its run, and writes
+    tgt, src, count, _ = _plan(ids, rows, block)
+    if not isinstance(state, jax.core.Tracer):
+        state = jnp.copy(state)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=((n + pad) // block,),
+        in_specs=[
+            pl.BlockSpec((block, width), lambda b, *_: (b, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),  # the state stays in HBM
+        ],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        scratch_shapes=[
+            pltpu.VMEM((2, block, width), state.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+        ],
+    )
+    return pl.pallas_call(
+        functools.partial(_row_set_kernel, block=block),
+        out_shape=jax.ShapeDtypeStruct(state.shape, state.dtype),
+        grid_spec=grid_spec,
+        input_output_aliases={4: 0},  # (tgt, src, count, new rows, state)
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)
+        ),
+        interpret=interpret,
+        name="sorted_row_set",
+    )(tgt, src, count, new_rows, state)
 
 
 # -- rows of several registers: a read-modify-write per touched tile row ------
@@ -1089,5 +1217,6 @@ _scatter_add_jitted = jax.jit(scatter_add, static_argnames=("interpret",))
 __all__ = [
     "BLOCK", "MAX_LANES", "note_refusal", "preload", "refusal",
     "refusal_count", "row_add", "scatter_add", "set_refusal", "sort_by_row",
-    "sorted_row_update", "sorted_tile_add", "sorted_tile_set", "tile_refusal",
+    "sorted_row_set", "sorted_row_update", "sorted_tile_add",
+    "sorted_tile_set", "tile_refusal",
 ]

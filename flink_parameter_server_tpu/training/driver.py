@@ -429,6 +429,11 @@ class StreamingDriver:
             self.registry.gauge(
                 "store_combine_kernel_lanes", component="train"
             ).set(total(outs["ps_combine_kernel_lanes"]))
+        if "ps_rule_packed_rows" in outs:
+            # a packed rule store: the physical rows its write-back wrote
+            self.registry.gauge(
+                "store_rule_packed_rows", component="train"
+            ).set(total(outs["ps_rule_packed_rows"]))
 
     def run(
         self,

@@ -529,10 +529,58 @@ def test_auto_layout_resolution():
     assert s.spec.layout == "packed"
     s = ShardedParamStore.create(10, (256,), layout="auto")
     assert s.spec.layout == "dense"
-    with pytest.raises(ValueError, match="packed"):
-        ShardedParamStore.create(
-            10, (17,), update=lambda c, d: c + 2 * d, layout="packed"
-        )
+    # a rule store of 9 to 64 lanes packs too, and either layout may be
+    # pinned for one (until PR 47 a rule could not be packed)
+    def rule(c, d):
+        return c + 2 * d
+
+    s = ShardedParamStore.create(10, (17,), update=rule, layout="auto")
+    assert s.spec.layout == "packed" and s.table.shape == (8, 128)
+    s = ShardedParamStore.create(10, (17,), update=rule, layout="dense")
+    assert s.spec.layout == "dense" and s.table.shape == (16, 17)
+    s = ShardedParamStore.create(10, (3,), update=rule, layout="auto")
+    assert s.spec.layout == "dense" and s.spec.tile_lanes == 4
+    s = ShardedParamStore.create(10, (100,), update=rule, layout="auto")
+    assert s.spec.layout == "dense"
+
+
+@pytest.mark.parametrize("shape, k", [
+    ((3,), 42), ((17,), 7), ((36,), 3), ((100,), 1), ((2, 150), 1),
+])
+def test_a_rule_store_pinned_packed_at_any_width_is_the_dense_one(shape, k):
+    """``layout="packed"`` pinned for a rule store whatever its row: ``k``
+    rows to a physical row, one flat padded row at ``k`` = 1, the push
+    through the packed arm; the same rows as the dense store, bit for bit,
+    and a round trip through ``values()``."""
+    from flink_parameter_server_tpu.core import store as store_mod
+
+    def rule(c, d):
+        return 0.5 * c - d + 2.0
+
+    rng = np.random.default_rng(k)
+    cap = 200
+    values = rng.normal(size=(cap,) + shape).astype(np.float32)
+    ids = rng.integers(-2, cap + 2, 150).astype(np.int32)
+    ids[:3] = [0, 1, 2]  # neighbours of one physical row where k >= 3
+    deltas = rng.normal(size=(150,) + shape).astype(np.float32)
+    packed = ShardedParamStore.from_values(
+        jnp.asarray(values), update=rule, layout="packed")
+    dense = ShardedParamStore.from_values(
+        jnp.asarray(values), update=rule, layout="dense")
+    assert packed.spec.layout == "packed" and packed.spec.pack == k
+    assert packed.table.shape[1] % 128 == 0
+    assert np.asarray(packed.values()).tobytes() == values.tobytes()
+    got, counted = store_mod.push_counted(
+        packed.spec, packed.table, jnp.asarray(ids), jnp.asarray(deltas))
+    want = dense.push(jnp.asarray(ids), jnp.asarray(deltas)).values()
+    pushed = ShardedParamStore(packed.spec, got)
+    assert np.asarray(pushed.values()).tobytes() == np.asarray(want).tobytes()
+    kept = np.unique(ids[(ids >= 0) & (ids < packed.spec.padded_capacity)])
+    assert int(counted["ps_rule_packed_rows"]) == len(np.unique(kept // k))
+    reloaded = ShardedParamStore.from_spec_values(packed.spec, pushed.values())
+    assert reloaded.table.shape == got.shape  # (the padding rows start over)
+    assert np.asarray(reloaded.values()).tobytes() == (
+        np.asarray(pushed.values()).tobytes())
 
 
 def test_fm_store_packs_on_one_shard_and_under_ps(mesh_devices, recwarn):

@@ -665,6 +665,63 @@ def test_sorted_tile_set_is_xlas_row_set_bit_for_bit(
         assert int(tiles) == len(np.unique(kept // 128))
 
 
+ROW_SET_CASES = [
+    "sorted_distinct", "dropped_between_the_kept", "every_lane_dropped",
+    "a_block_that_writes_nothing", "not_whole_blocks", "one_lane",
+    "nan_inf_and_minus_zero", "unsorted_distinct",
+]
+
+
+@pytest.mark.parametrize("name", ROW_SET_CASES)
+def test_sorted_row_set_is_xlas_row_set_bit_for_bit(name):
+    """``state.at[ids].set(rows, mode="drop")`` for distinct kept ids, the
+    lanes to drop wherever they lie: every bit of the state (the kernel
+    copies, it does no arithmetic)."""
+    rng = np.random.default_rng(ROW_SET_CASES.index(name))
+    rows_n, n = 1000, 700
+    ids = np.sort(rng.choice(rows_n, n, replace=False)).astype(np.int32)
+    if name == "dropped_between_the_kept":
+        ids[rng.random(n) < 0.3] = rows_n  # as a packed rule store's are
+    elif name == "every_lane_dropped":
+        ids[:] = rows_n + 5
+    elif name == "a_block_that_writes_nothing":
+        ids[256:512] = rows_n
+    elif name == "not_whole_blocks":
+        ids, n = ids[:300], 300
+    elif name == "one_lane":
+        ids, n = ids[:1], 1
+    elif name == "unsorted_distinct":
+        ids = rng.permutation(ids)
+    state = rng.normal(size=(rows_n, WIDTH)).astype(np.float32)
+    new = rng.normal(size=(n, WIDTH)).astype(np.float32)
+    if name == "nan_inf_and_minus_zero":
+        new[0], new[1, ::2], new[2, 5] = -0.0, np.inf, np.nan
+        new[3] = np.frombuffer(  # a signalling NaN's payload
+            np.uint32(0x7FA00001).tobytes() * WIDTH, np.float32)
+    new[ids >= rows_n] = np.nan  # a dropped lane's row is never written
+    got = row_update.sorted_row_set(
+        jnp.asarray(state), jnp.asarray(ids), jnp.asarray(new))
+    want = np.array(state)
+    want[ids[ids < rows_n]] = new[ids < rows_n]
+    assert np.asarray(got).tobytes() == want.tobytes()
+    jitted = jax.jit(row_update.sorted_row_set, donate_argnums=0)(
+        jnp.asarray(state), jnp.asarray(ids), jnp.asarray(new))
+    assert np.asarray(jitted).tobytes() == want.tobytes()
+
+
+def test_sorted_row_set_refuses_what_the_row_kernel_refuses():
+    state = jnp.zeros((64, 256), jnp.float32)
+    with pytest.raises(ValueError, match="sorted_row_set: rows of shape"):
+        row_update.sorted_row_set(
+            state, jnp.zeros((8,), jnp.int32), jnp.zeros((8, 256)),
+            interpret=False)
+    with pytest.raises(ValueError, match="lanes in one call"):
+        row_update.sorted_row_set(
+            jnp.zeros((64, 128), jnp.float32),
+            jnp.zeros((row_update.MAX_LANES + 256,), jnp.int32),
+            jnp.zeros((row_update.MAX_LANES + 256, 128)), interpret=False)
+
+
 def test_eager_tile_set_leaves_the_callers_table_alone():
     table = jnp.ones((256, 4), jnp.float32)
     out, _ = row_update.sorted_tile_set(
@@ -702,11 +759,16 @@ def _rule(current, combined):
     ("tpu", False, (1,), _rule, jnp.float32, True),
     ("tpu", False, (8,), _rule, jnp.float32, True),
     ("tpu", False, (5,), _rule, jnp.float32, True),
-    ("tpu", False, (9,), _rule, jnp.float32, False),
+    ("tpu", False, (9,), _rule, jnp.float32, True),   # packed: the row set
+    ("tpu", False, (36,), _rule, jnp.float32, True),  # DiFacto's row, k = 3
+    ("tpu", False, (64,), _rule, jnp.float32, True),
+    ("tpu", False, (65,), _rule, jnp.float32, False),  # dense, XLA's set
     ("tpu", False, (128,), _rule, jnp.float32, False),
     ("tpu", False, (3,), "add", jnp.float32, False),
     ("cpu", False, (3,), _rule, jnp.float32, False),
+    ("cpu", False, (36,), _rule, jnp.float32, False),
     ("tpu", True, (3,), _rule, jnp.float32, False),
+    ("tpu", True, (36,), _rule, jnp.float32, False),  # a mesh: GSPMD's set
 ])
 def test_the_write_back_takes_the_set_kernel_from_what_the_spec_holds(
         monkeypatch, backend, meshed, shape, update, dtype, want):
@@ -722,7 +784,11 @@ def test_the_write_back_takes_the_set_kernel_from_what_the_spec_holds(
         warnings.simplefilter("error")
         assert store_mod._set_kernel_takes(spec) == want
     assert row_update.refusal_count() == n0
-    if want:  # the physical row is the sublane tile, whole tiles of rows
+    if update != "add" and 8 < shape[0] <= 64:
+        # several rows to a 128-lane physical row, whatever the backend
+        assert spec.layout == "packed" and spec.tile_lanes == 0
+        assert spec.table_shape()[1] == 128 and spec.pack == 128 // shape[0]
+    elif want:  # the physical row is the sublane tile, whole tiles of rows
         lanes = {1: 1, 3: 4, 5: 8, 8: 8}[shape[0]]
         assert spec.tile_lanes == lanes and spec.table_shape() == (128, lanes)
     elif backend == "cpu":  # the physical row is the spec's, not the backend's
@@ -733,6 +799,7 @@ def test_the_write_back_takes_the_set_kernel_from_what_the_spec_holds(
     ((3,), jnp.bfloat16, "bfloat16"),
     ((2, 2), jnp.float32, "(2, 2)"),
     ((), jnp.float32, "()"),
+    ((36,), jnp.bfloat16, "bfloat16"),  # packed: the row set moves float32
 ])
 def test_a_narrow_rule_store_the_kernel_refuses_warns_once_and_counts(
         monkeypatch, shape, dtype, reason):

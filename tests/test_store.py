@@ -1018,15 +1018,193 @@ def test_combine_runs_sums_each_id_and_moves_the_distinct_first(width):
 
 @pytest.mark.parametrize("update, shape, want", [
     ("add", (17,), "packed"), ("add", (128,), "dense"), ("add", (2, 300), "packed"),
-    ("rule", (3,), "dense"), ("rule", (), "dense"), ("rule", (17,), "dense"),
+    ("rule", (3,), "dense"), ("rule", (), "dense"), ("rule", (17,), "packed"),
     ("rule", (128,), "dense"), ("rule", (2, 300), "dense"),
+    ("rule", (8,), "dense"), ("rule", (9,), "packed"), ("rule", (36,), "packed"),
+    ("rule", (64,), "packed"), ("rule", (65,), "dense"), ("rule", (127,), "dense"),
+    ("rule", (2, 9), "dense"), ("rule", (1,), "dense"),
 ])
 def test_auto_layout_reads_row_shape_and_update_rule(update, shape, want):
     from flink_parameter_server_tpu.core.store import _resolve_layout
 
     rule = "add" if update == "add" else _ema
     assert _resolve_layout("auto", rule, shape) == want
-    if update == "rule":
-        # a rule writes whole logical rows back: it cannot be pinned packed
-        with pytest.raises(ValueError, match="requires update='add'"):
-            _resolve_layout("packed", rule, shape)
+    # either layout may be pinned, for a rule as for an add-store
+    for pinned in ("dense", "packed"):
+        assert _resolve_layout(pinned, rule, shape) == pinned
+    with pytest.raises(ValueError, match="layout must be"):
+        _resolve_layout("tiled", rule, shape)
+
+
+# -- a PACKED rule store: k = 128 // width logical rows to a physical row -----
+# What `_resolve_layout("auto")` gives a rule row of 9 to 64 lanes.  Its push
+# reads and writes whole physical rows, the touched logical rows of one merged
+# by selects (`core/store._rewrite_packed`); it is held to the DENSE rule store
+# on the same ids and deltas, bit for bit, untouched rows and the untouched
+# neighbours inside a touched physical row included.
+
+PACKED_RULE_WIDTHS = [9, 17, 36, 64]
+PACKED_RULE_CASES = [
+    "uniform", "two_of_one_physical_row", "a_whole_physical_row",
+    "across_a_chunk_edge", "nan_inf_and_minus_zero_neighbours",
+    "dead_and_out_of_range_lanes", "empty_batch", "the_last_rows_and_padding",
+    "half_masked_hot_row",
+]
+
+
+def _sticky_rule(current, combined):
+    # not linear, and it moves a row even by a zero sum: a row the push
+    # rewrote shows, and one it should have left does too
+    return 0.5 * current + 0.25 * combined + 1.0
+
+
+def _packed_rule_traffic(case, rng, cap, width):
+    """``(ids, deltas, mask or None, rule chunk or None)`` of one push."""
+    k = 128 // width
+    n, mask, chunk = 300, None, None
+    ids = rng.integers(0, cap, n)
+    if case == "two_of_one_physical_row":
+        ids = np.array([k * 5, k * 5 + 1, k * 9 + (k - 1), k * 9, 3])
+    elif case == "a_whole_physical_row":
+        ids = np.concatenate([k * 7 + np.arange(k), k * 8 + np.arange(k)])
+    elif case == "across_a_chunk_edge":
+        # 16 sorted distinct ids a trip: physical rows lie across the edges
+        ids, chunk = np.arange(11, 11 + 50), 16
+        assert k == 1 or any((11 + e) % k for e in (16, 32, 48))
+    elif case == "nan_inf_and_minus_zero_neighbours":
+        ids = np.array([k * 4 + 1, k * 6, 2 * k + (k - 1)])
+    elif case == "dead_and_out_of_range_lanes":
+        ids = rng.integers(-5, cap + 40, n)
+        ids[:5] = [-1, cap, cap + 1000, -(2 ** 31), 2 ** 31 - 1]
+    elif case == "empty_batch":
+        ids = np.zeros((0,), np.int64)
+    elif case == "the_last_rows_and_padding":
+        # the store pads its rows to whole physical rows and tiles of them:
+        # the padding rows are addressable, rewritten and never handed out
+        ids = np.arange(cap - 5, cap + 6)
+    elif case == "half_masked_hot_row":
+        ids[: n // 3] = 7
+        mask = rng.random(n) < 0.5
+    elif case != "uniform":
+        raise AssertionError(case)
+    ids = np.asarray(ids, np.int32)
+    deltas = rng.normal(size=ids.shape + (width,)).astype(np.float32)
+    if case == "dead_and_out_of_range_lanes":
+        deltas[(ids < 0) | (ids >= cap)] = np.nan
+    return ids, deltas, mask, chunk
+
+
+@pytest.mark.parametrize("case", PACKED_RULE_CASES)
+@pytest.mark.parametrize("width", PACKED_RULE_WIDTHS + [8, 65])
+@pytest.mark.parametrize("arm", ["xla", "row_set_kernel"])
+def test_a_packed_rule_store_is_the_dense_one_bit_for_bit(
+        arm, width, case, monkeypatch):
+    from flink_parameter_server_tpu.core import store as store_mod
+
+    rng = np.random.default_rng([width, PACKED_RULE_CASES.index(case)])
+    cap = 61 * max(1, 128 // width) + 2  # no whole number of physical rows
+    values = rng.normal(size=(cap, width)).astype(np.float32)
+    if case == "nan_inf_and_minus_zero_neighbours":
+        k = max(1, 128 // width)
+        # the untouched rows beside the touched ones, in their physical rows
+        values[k * 4] = np.nan
+        values[k * 6 + 1] = -0.0
+        values[2 * k if k > 1 else 0] = np.inf
+        values[k * 6 + 1, ::2] = -np.inf
+    ids, deltas, mask, chunk = _packed_rule_traffic(case, rng, cap, width)
+    if chunk:
+        monkeypatch.setattr(store_mod, "_RULE_CHUNK", chunk)
+    auto = ShardedParamStore.from_values(
+        jnp.asarray(values), update=_sticky_rule, layout="auto")
+    dense = ShardedParamStore.from_values(
+        jnp.asarray(values), update=_sticky_rule, layout="dense")
+    packs = width in PACKED_RULE_WIDTHS
+    assert auto.spec.layout == ("packed" if packs else "dense")
+    assert dense.spec.layout == "dense"
+    if not packs:
+        assert auto.spec == dense.spec
+    else:
+        k = 128 // width
+        assert auto.spec.pack == k and auto.table.shape == (64, 128)
+        table = np.asarray(auto.table)
+        assert not table[:, k * width:].any()  # the pad lanes
+        assert table[:, : k * width].reshape(-1, width)[:cap].tobytes() == (
+            values.tobytes())
+    # `values()` / `from_values` round trip, bit for bit
+    assert np.asarray(auto.values()).tobytes() == values.tobytes()
+    assert not store_mod._set_kernel_takes(auto.spec)  # this is a CPU
+    args = (jnp.asarray(ids), jnp.asarray(deltas),
+            None if mask is None else jnp.asarray(mask))
+    want, _ = store_mod.push_counted(dense.spec, dense.table, *args)
+    if arm == "row_set_kernel" and packs:
+        # off the TPU the chooser is steered and the kernel interpreted
+        monkeypatch.setattr(store_mod, "_set_kernel_takes", lambda spec: True)
+    got, counted = store_mod.push_counted(auto.spec, auto.table, *args)
+    pushed = ShardedParamStore(auto.spec, got)
+    want = np.asarray(ShardedParamStore(dense.spec, want).values())
+    assert np.asarray(pushed.values()).tobytes() == want.tobytes()
+    live = (ids >= 0) & (ids < auto.spec.padded_capacity)
+    if mask is not None:
+        live &= mask
+    kept = np.unique(ids[live])
+    hit = np.zeros(cap, bool)
+    hit[kept[kept < cap]] = True
+    # every touched row moved, every other row is what it was
+    assert (want[hit] != values[hit]).any(axis=1).all()
+    assert want[~hit].tobytes() == values[~hit].tobytes()
+    assert int(counted["ps_rule_keys"]) == live.sum()
+    assert int(counted["ps_rule_rows"]) == len(kept)
+    pulled = np.asarray(pushed.pull(jnp.asarray(np.clip(ids, 0, cap - 1))))
+    assert pulled.tobytes() == want[np.clip(ids, 0, cap - 1)].tobytes()
+    if not packs:
+        assert "ps_rule_packed_rows" not in counted
+        return
+    # the physical rows written: one a run of neighbours in a trip's chunk
+    k, step = 128 // width, chunk or store_mod._RULE_CHUNK
+    assert int(counted["ps_rule_packed_rows"]) == sum(
+        len(np.unique(kept[lo:lo + step] // k))
+        for lo in range(0, len(kept), step))
+    assert int(counted["ps_rule_tiles"]) == 0
+    table = np.asarray(got)
+    assert table.shape == (64, 128) and not table[:, k * width:].any()
+    if case == "across_a_chunk_edge" and k > 1:
+        assert int(counted["ps_rule_packed_rows"]) > len(np.unique(kept // k))
+
+
+@pytest.mark.parametrize("width", PACKED_RULE_WIDTHS)
+def test_a_packed_rule_store_under_a_ps_mesh_is_the_one_device_store(
+        width, mesh):
+    """The XLA arm under GSPMD: a packed rule store sharded over ``ps``
+    (each shard its own packed block) against the same store in one place,
+    bit for bit, pull and counts too; no kernel is asked for a mesh."""
+    from flink_parameter_server_tpu.core import store as store_mod
+
+    rng = np.random.default_rng(width)
+    k, cap = 128 // width, 500
+    values = rng.normal(size=(cap, width)).astype(np.float32)
+    ids = rng.integers(-2, cap + 3, 400).astype(np.int32)
+    ids[:k] = k * 11 + np.arange(k)  # one physical row whole
+    deltas = rng.normal(size=(400, width)).astype(np.float32)
+    one = ShardedParamStore.from_values(
+        jnp.asarray(values), update=_sticky_rule, layout="auto")
+    sharded = ShardedParamStore.from_values(
+        jnp.asarray(values), update=_sticky_rule, mesh=mesh, layout="auto")
+    assert sharded.spec.layout == one.spec.layout == "packed"
+    assert sharded.table.sharding == sharded.spec.sharding()
+    assert np.asarray(sharded.values()).tobytes() == values.tobytes()
+    args = (jnp.asarray(ids), jnp.asarray(deltas))
+    want, counted_one = store_mod.push_counted(one.spec, one.table, *args)
+    got, counted = jax.jit(
+        lambda t, i, d: store_mod.push_counted(sharded.spec, t, i, d)
+    )(sharded.table, *args)
+    want = np.asarray(ShardedParamStore(one.spec, want).values())
+    pushed = ShardedParamStore(sharded.spec, got)
+    assert np.asarray(pushed.values()).tobytes() == want.tobytes()
+    for name in ("ps_rule_keys", "ps_rule_rows"):
+        assert int(counted[name]) == int(counted_one[name])
+    # (a shard's padding moves where an id's physical row lies, not how many)
+    kept = np.unique(ids[(ids >= 0) & (ids < cap)])
+    assert int(counted["ps_rule_packed_rows"]) >= len(np.unique(kept // k))
+    probe = jnp.asarray(np.clip(ids, 0, cap - 1))
+    assert np.asarray(pushed.pull(probe)).tobytes() == (
+        want[np.asarray(probe)].tobytes())

@@ -75,3 +75,63 @@ def test_pull_after_push_roundtrip(pairs):
     pulled = np.asarray(store.pull(in_range))
     table = np.asarray(store.values())
     np.testing.assert_allclose(pulled, table[np.asarray(in_range)], atol=1e-5)
+
+
+# -- a packed rule store (rows of 9 to 64 lanes under a rule) ----------------
+RULE_DIM = 36
+
+
+def _rule(current, combined):
+    return 0.5 * current + combined + 1.0
+
+
+def _rule_stores():
+    values = jnp.asarray(
+        (np.arange(CAP * RULE_DIM, dtype=np.float32) % 11 - 5.0
+         ).reshape(CAP, RULE_DIM))
+    return [
+        ShardedParamStore.from_values(values, update=_rule, layout=layout)
+        for layout in ("auto", "dense")
+    ]
+
+
+def _rule_batch(pairs):
+    ids = jnp.asarray([i for i, _ in pairs], jnp.int32)
+    col = np.array([d for _, d in pairs], np.float32)
+    lanes = np.arange(RULE_DIM, dtype=np.float32)[None, :]
+    return ids, jnp.asarray(col[:, None] * (1.0 + lanes))
+
+
+@settings(max_examples=25, deadline=None)
+@given(ids_deltas)
+def test_a_packed_rule_push_is_the_dense_rule_push(pairs):
+    """Three 36-lane rows to a physical row against one row a row: whatever
+    the batch, the same rows bit for bit, an untouched row left as it was
+    and a pull of what was pushed."""
+    packed, dense = _rule_stores()
+    assert packed.spec.layout == "packed" and dense.spec.layout == "dense"
+    ids, deltas = _rule_batch(pairs)
+    got = packed.push(ids, deltas)
+    want = np.asarray(dense.push(ids, deltas).values())
+    assert np.asarray(got.values()).tobytes() == want.tobytes()
+    touched = {i for i, _ in pairs if 0 <= i < CAP}
+    before = np.asarray(dense.values())
+    for row in range(CAP):
+        assert (row in touched) != (want[row].tobytes() == before[row].tobytes())
+    in_range = jnp.clip(ids, 0, CAP - 1)
+    assert np.asarray(got.pull(in_range)).tobytes() == (
+        want[np.asarray(in_range)].tobytes())
+
+
+@settings(max_examples=25, deadline=None)
+@given(ids_deltas, st.randoms(use_true_random=False))
+def test_a_packed_rule_push_names_each_row_once_whatever_the_order(pairs, rnd):
+    """The rule runs once a touched row on the SUM of its deltas: a shuffled
+    batch gives the same rows to the sum's rounding, the same rows touched."""
+    shuffled = list(pairs)
+    rnd.shuffle(shuffled)
+
+    def run(ps):
+        return np.asarray(_rule_stores()[0].push(*_rule_batch(ps)).values())
+
+    np.testing.assert_allclose(run(pairs), run(shuffled), rtol=1e-5, atol=1e-4)

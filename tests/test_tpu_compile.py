@@ -1199,8 +1199,9 @@ def test_ft_step_holds_cell_7_s_table_once_beside_three_tile_kernel_calls(
 
 
 # difacto-criteo-10m (chipbench/configs): cell 9's rule store, 36 f32 lanes a
-# row (w, z, s, c, V[16], S[16]), dense: on the chip rows-minor, 40 sublanes
-DF_ROWS, DF_LANES = 49_126_310, 36
+# row (w, z, s, c, V[16], S[16]); since PR 47 packed three to a 128-lane
+# physical row, 16,375,440 x 128 f32 = 8.384 GB (dense: rows-minor, 40 sublanes)
+DF_ROWS, DF_LANES, DF_PHYS_ROWS = 49_126_310, 36, 16_375_440
 
 
 @pytest.fixture(scope="module")
@@ -1218,55 +1219,70 @@ def difacto():
     return cfg, rule, model, fam, dfm
 
 
+@pytest.mark.parametrize("layout", ["auto", "dense"])
 def test_difacto_table_is_initialised_in_place_block_by_block(
-        difacto, one_chip, no_compile_cache):
+        layout, difacto, one_chip, no_compile_cache):
     """The seeded warm start of 49,126,310 x 36 f32 rows under a ``jit`` that
-    takes the key: the 7.86 GB table (36 lanes down 40 sublanes) is the
-    program's only output, written ``core/store._INIT_BLOCK`` rows a loop
-    step; all rows at once the same init asked 11.8 GB of temporaries."""
+    takes the key.  As ``make_store`` resolves it (``auto``: packed) the
+    8.384 GB table ``f32[16375440,128]`` is the program's only output,
+    initialised and packed ``core/store._PACK_CHUNK`` physical rows a loop
+    step, 0.20 GB of temporaries; pinned dense it is PR 45's 7.86 GB table
+    (36 lanes down 40 sublanes), ``core/store._INIT_BLOCK`` rows a step (all
+    rows at once the same init asked 11.8 GB of temporaries)."""
     cfg, rule, model, fam, dfm = difacto
     compiled = jax.jit(lambda key: dfm.make_store(
         model, rule, init_fn=fam.warm_rows(cfg, rule, key), dtype=jnp.float32,
+        layout=layout,
     ).table).lower(_shape(one_chip, (2,), jnp.uint32)).compile()
     mem = compiled.memory_analysis()
-    assert 7.85 * GB < mem.output_size_in_bytes < 7.87 * GB
+    if layout == "auto":
+        assert mem.output_size_in_bytes == DF_PHYS_ROWS * 128 * 4
+        assert 8.38 * GB < mem.output_size_in_bytes < 8.39 * GB
+    else:
+        assert 7.85 * GB < mem.output_size_in_bytes < 7.87 * GB
     assert mem.temp_size_in_bytes < 0.5 * GB
     assert len(re.findall(r" while\(", compiled.as_text())) >= 1
 
 
-@pytest.mark.parametrize("arm", ["row_kernel", "scatter_add"])
+@pytest.mark.parametrize("arm", ["kernels", "xla"])
 def test_difacto_step_holds_nothing_table_sized_beside_its_table(
         arm, difacto, one_chip, no_compile_cache, monkeypatch):
-    """Cell 9's step at full size for a described v5e: a rule row of 36
-    lanes goes through the dense arm (no kernel takes its pull, its rule or
-    its write-back), the donated table is rewritten in place and never
-    copied, with every scope the cell's metrics read.  As the chip runs it
-    (asked for the backend: ``row_kernel``) the combine sums the rows along
-    the sorted lanes: ONE ``sorted_row_update`` call under ``ps.push/
-    ps.combine``, inside the loop over the thirteen stretches of 98,304
-    lanes, fed by a gather of whole 128-lane rows out of the padded
-    ``f32[1277952,128]``, and no scatter of the batch's rows there.  What the
-    step holds beside the table goes with the batch: 1.33 GB, of which the
-    padded rows and the zeroed block the kernel writes into are 0.654 GB
-    each, row-major ``(n, 128)``; the rows-minor ``(n, 36)`` gradient rows
-    and sums (0.20 GB: 40 sublanes) and a stretch's permuted rows and old
-    rows (0.05 GB each) lie where those two are not yet or no longer.  Off
-    the TPU (``scatter_add``) the step is PR 45's: one scatter-add of the
-    rows in stream order, 0.89 GB, no kernel."""
+    """Cell 9's step at full size for a described v5e, the table packed
+    three 36-lane rows to a physical row (``f32[16375440,128]{1,0:T(8,128)}``,
+    8.384 GB): the donated table is rewritten in place and never copied or
+    transposed, with every scope the cell's metrics read, and NO gather or
+    scatter of 36-lane rows of the table anywhere.  As the chip runs it
+    (asked for the backend: ``kernels``): under ``ps.pull`` ONE gather of
+    whole physical rows (``slice_sizes={1,128}``) and ONE ``packed_lane_slice``
+    call that hands the logic ``f32[36,1277952]``; under ``ps.push/
+    ps.combine`` PR 46's ``sorted_row_update`` inside the loop over thirteen
+    stretches; in the rule's loop ONE gather ``f32[32768,128]`` of the
+    chunk's physical rows under ``ps.rule`` and ONE ``sorted_row_set`` call,
+    the write-back of whole physical rows.  What the step holds beside the
+    table goes with the batch: 1.33 GB (the padded rows and the zeroed block
+    of the combine, 0.654 GB each; the pulled physical rows, 0.654 GB, are
+    dead before them).  Off the TPU (``xla``) the same layout with XLA's
+    selects, one scatter-add for the sums and one row ``set`` of
+    ``f32[32768,128]`` for the write-back, no kernel."""
     cfg, rule, model, fam, dfm = difacto
     spec = jax.eval_shape(
         lambda: dfm.make_store(model, rule, dtype=jnp.float32)
     ).spec
-    assert spec.layout == "dense" and not spec.narrow_rule
-    assert spec.table_shape() == (49_126_312, DF_LANES)
-    assert not store_mod._combine_kernel_takes(spec)  # this is a CPU
-    if arm == "row_kernel":
+    assert spec.layout == "packed" and spec.pack == 3 and not spec.narrow_rule
+    assert spec.table_shape() == (DF_PHYS_ROWS, 128)
+    n = FM_BATCH * FM_FIELDS
+    for takes in (store_mod._combine_kernel_takes, store_mod._set_kernel_takes):
+        assert not takes(spec)  # this is a CPU
+    assert not store_mod._slice_kernel_takes(spec, n)
+    if arm == "kernels":
         # code that asks for the backend still sees the CPU here: steer it
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
         n0 = row_update.refusal_count()
         assert store_mod._combine_kernel_takes(spec)
+        assert store_mod._set_kernel_takes(spec)
+        assert store_mod._slice_kernel_takes(spec, n)
+        assert store_mod._slice_kernel_takes(spec)  # the import is preloaded
         assert row_update.refusal_count() == n0
-    assert not store_mod._set_kernel_takes(spec)
     compiled = jax.jit(
         make_train_step(dfm.DiFacto(model, rule), spec), donate_argnums=(0, 1)
     ).lower(
@@ -1274,28 +1290,48 @@ def test_difacto_step_holds_nothing_table_sized_beside_its_table(
         _fm_batch(one_chip),
     ).compile()
     mem = compiled.memory_analysis()
-    assert 7.85 * GB < mem.alias_size_in_bytes < 7.87 * GB  # in place
+    assert 8.38 * GB < mem.alias_size_in_bytes < 8.39 * GB  # in place
     text = compiled.as_text()
-    assert not re.search(r"f32\[49126312,36\]\S* (copy|transpose)\(", text)
+    assert not re.search(r"f32\[16375440,128\]\S* (copy|transpose)\(", text)
+    assert "f32[49126312,36]" not in text and "f32[16375440,36]" not in text
     for scope in ("ps.pull", "ps.compute/ps.gate", "ps.compute/ps.delta_build",
                   "ps.push/ps.combine", "ps.push/while/body/ps.rule"):
         assert scope in text, scope
-    combine = [line for line in text.splitlines() if "ps.push/ps.combine" in line]
-    kernels = [line for line in text.splitlines() if "tpu_custom_call" in line]
-    if arm == "scatter_add":
-        assert mem.temp_size_in_bytes < 1.2 * GB
+    lines = text.splitlines()
+    # every gather and scatter that names the table moves whole registers
+    pulls = [c for c in lines if re.search(r" gather\(", c)
+             and f" f32[{n},128]{{1,0" in c]
+    assert len(pulls) == 1 and "slice_sizes={1,128}" in pulls[0], pulls
+    assert 'op_name="jit(step)/ps.pull/' in pulls[0]
+    reads = [c for c in lines if re.search(r" gather\(", c)
+             and " f32[32768,128]{1,0" in c]
+    assert len(reads) == 1 and "slice_sizes={1,128}" in reads[0], reads
+    assert "ps.push/while/body/ps.rule" in reads[0]
+    assert not any(re.search(r"f32\[\d+,36\]\S* (gather|scatter)\(", c)
+                   and "16375440" in c for c in lines)
+    kernels = [line for line in lines if "tpu_custom_call" in line]
+    scatters = [line for line in lines if re.search(r" scatter\(", line)]
+    if arm == "xla":
+        assert mem.temp_size_in_bytes < 1.6 * GB
         assert not kernels
-        assert any(re.search(r"f32\[1277952,36\]\S* scatter\(", c) for c in combine)
+        sets = [c for c in scatters if "f32[16375440,128]" in c]
+        assert len(sets) == 1 and "ps.push/while/body" in sets[0], scatters
+        assert "ps.rule" not in sets[0] and "ps.combine" not in sets[0]
         return
-    assert 1.2 * GB < mem.temp_size_in_bytes < 1.5 * GB
-    assert len(kernels) == 1, kernels
-    assert kernels[0].strip().startswith("%sorted_row_update")
-    assert " f32[1277952,128]{1,0" in kernels[0]
-    assert "ps.push/ps.combine/while/body" in kernels[0]
-    assert not any(re.search(r" scatter\(", c) for c in combine)
-    gathers = [c for c in combine if re.search(r" gather\(%param", c)
-               and " f32[" in c]
-    assert len(gathers) == 1 and "f32[98304,128]{1,0" in gathers[0], gathers
-    assert "slice_sizes={1,128}" in gathers[0]
+    assert 1.2 * GB < mem.temp_size_in_bytes < 1.5 * GB  # 1.327 here
+    assert not scatters
+    names = sorted(k.strip().split(" ", 1)[0].rstrip(".0123456789") for k in kernels)
+    assert names == ["%packed_lane_slice", "%sorted_row_set", "%sorted_row_update"]
+    by_name = {k.strip().split(".", 1)[0]: k for k in kernels}
+    slice_call = by_name["%packed_lane_slice"]
+    assert f" = f32[36,{n}]{{1,0:" in slice_call
+    assert 'op_name="jit(step)/ps.pull/' in slice_call
+    set_call = by_name["%sorted_row_set"]
+    assert " f32[16375440,128]{1,0" in set_call
+    assert "ps.push/while/body" in set_call and "ps.rule" not in set_call
+    assert "ps.combine" not in set_call
+    update_call = by_name["%sorted_row_update"]
+    assert " f32[1277952,128]{1,0" in update_call
+    assert "ps.push/ps.combine/while/body" in update_call
     # the rule's loop and the stretches' (the flattens' two are cell 2's)
     assert len(re.findall(r" while\(", text)) == 4
