@@ -1,0 +1,263 @@
+"""DLRM: embedding tables on the parameter server, a dense net in the worker
+(Naumov et al., "Deep Learning Recommendation Model for Personalization and
+Recommendation Systems", 2019; ``facebookresearch/dlrm``, its PyTorch script).
+
+Reference parity: the job parameter servers are run for today.  The server
+holds the categorical fields' embedding rows (the reference's
+``SimplePSLogic`` with ``paramUpdate = +``, SURVEY.md §2 #3); the worker holds
+the two MLPs in its STATE, as the reference's worker keeps its model-side
+variables in the ``WorkerLogic`` instance (SURVEY.md §2 #2), and as MF's user
+factors are an array there: here the state is a dict of arrays.  Per example,
+float32 throughout:
+
+    z0 = MLP_bot(x)                       x the dense fields; ReLU after every layer
+    e_f = E[id_f]                         one row a categorical field, every field
+                                          its own rows of ONE store
+    T = [z0; e_1; ...; e_F]               (F + 1, dim)
+    Z = T T^t                             the entries i > j, no diagonal
+    r = [z0, Z_lower]
+    p = sigmoid(MLP_top(r))               ReLU between layers
+    loss = mean over the live examples of BCE(p, y)
+
+One ``step`` is the forward pass, the backward pass written out, plain SGD on
+every MLP leaf (once a minibatch, on the whole batch's gradient) and a push
+of ``-lr dL/de`` for every pulled row; the store's ``add`` sums the deltas of
+a row that several examples name, in stream order.  Under a ``dp`` mesh the
+MLPs lie replicated and the batch split (``core/transform.make_train_step``
+constrains it); the reduction of the dense gradients over the workers is the
+partitioner's, nothing here names it.
+
+The matmuls run at ``Precision.HIGHEST``: the model's arithmetic is float32,
+and the TPU's default (one bfloat16 pass) is 4e-3 of every product.
+"""
+from __future__ import annotations
+
+import dataclasses
+from typing import Dict, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ..core.batched import BatchedWorkerLogic, PushRequest
+from ..core.store import InitFn, ShardedParamStore
+from ..training.tracing import scope
+from ..utils.initializers import ranged_random_factor
+
+Array = jax.Array
+
+_PRECISION = jax.lax.Precision.HIGHEST
+
+
+@dataclasses.dataclass(frozen=True)
+class DLRMConfig:
+    """The source's ``--arch-*`` flags: ``field_cardinalities`` its
+    ``--arch-embedding-size``, ``dim`` its ``--arch-sparse-feature-size``,
+    ``bottom_mlp`` / ``top_mlp`` the layer widths after the input's (the
+    bottom MLP must end at ``dim``, the top at 1)."""
+
+    field_cardinalities: Tuple[int, ...]
+    dense_features: int = 13
+    dim: int = 64
+    bottom_mlp: Tuple[int, ...] = (512, 256, 64)
+    top_mlp: Tuple[int, ...] = (512, 512, 256, 1)
+    learning_rate: float = 0.1
+
+    def __post_init__(self) -> None:
+        if self.bottom_mlp[-1] != self.dim or self.top_mlp[-1] != 1:
+            raise ValueError(
+                f"bottom MLP {self.bottom_mlp} must end at dim={self.dim} "
+                f"and top MLP {self.top_mlp} at 1"
+            )
+
+    @property
+    def num_rows(self) -> int:
+        return int(sum(self.field_cardinalities))
+
+    @property
+    def fields(self) -> int:
+        return len(self.field_cardinalities)
+
+    @property
+    def interaction_terms(self) -> int:
+        """Entries of ``T T^t`` below its diagonal."""
+        return (self.fields + 1) * self.fields // 2
+
+    def layers(self) -> Dict[str, Tuple[int, int]]:
+        """``{leaf prefix: (inputs, outputs)}`` of every layer, in order."""
+        out = {}
+        for name, first, widths in (
+            ("bot", self.dense_features, self.bottom_mlp),
+            ("top", self.dim + self.interaction_terms, self.top_mlp),
+        ):
+            for i, (n, m) in enumerate(zip((first,) + widths, widths)):
+                out[f"{name}{i}"] = (int(n), int(m))
+        return out
+
+    @property
+    def dense_params(self) -> int:
+        return sum(n * m + m for n, m in self.layers().values())
+
+    @property
+    def macs_per_example(self) -> int:
+        """Multiply-adds of one example's FORWARD pass: the layers and the
+        whole ``T T^t`` (806,720 at the source's Criteo Terabyte sizes)."""
+        pairs = (self.fields + 1) ** 2 * self.dim
+        return sum(n * m for n, m in self.layers().values()) + pairs
+
+
+def _dot(a: Array, b: Array) -> Array:
+    return jnp.dot(a, b, precision=_PRECISION)
+
+
+def _mlp_forward(state, name: str, x: Array, depth: int, last_relu: bool):
+    """Activations ``[x, a_0, ...]`` of ``depth`` layers ``name0..``: ReLU
+    after every layer, after the last only where ``last_relu``."""
+    acts = [x]
+    for i in range(depth):
+        z = _dot(acts[-1], state[f"{name}{i}_w"]) + state[f"{name}{i}_b"]
+        acts.append(jnp.maximum(z, 0.0) if last_relu or i < depth - 1 else z)
+    return acts
+
+
+def _mlp_backward(state, name: str, acts, d: Array, last_relu: bool):
+    """``d`` is dL/d(output) of the MLP; returns ``(gradients by leaf,
+    dL/d(input))``.  A ReLU's output is positive exactly where its input
+    was, so the mask is read from the activation kept."""
+    grads, depth = {}, len(acts) - 1
+    for i in reversed(range(depth)):
+        if last_relu or i < depth - 1:
+            d = jnp.where(acts[i + 1] > 0, d, 0.0)
+        grads[f"{name}{i}_w"] = _dot(acts[i].T, d)
+        grads[f"{name}{i}_b"] = d.sum(axis=0)
+        d = _dot(d, state[f"{name}{i}_w"].T)
+    return grads, d
+
+
+class DLRM(BatchedWorkerLogic):
+    """Batch: ``dense`` (B, dense_features) float, ``ids`` (B, fields) int,
+    the row of each categorical field in the ONE store (its field's first row
+    added), ``label`` (B,) positive for a click, ``mask`` (B,) bool.
+    ``pulled`` is ``(B, fields, dim)``.  The state is a dict of float32
+    arrays, ``{bot|top}{layer}_{w|b}``, ``w`` as ``(inputs, outputs)``.
+    Beside ``prediction`` and ``loss`` the outputs carry two constants of
+    the logic for whoever reads outputs: ``dlrm_dense_params`` and
+    ``dlrm_dense_flops_per_step`` (model FLOPs: 2 a multiply-add, the
+    backward pass twice the forward)."""
+
+    def __init__(self, config: DLRMConfig, *, seed=0):
+        self.config = config
+        self.seed = seed
+
+    def init_state(self, rng: Array) -> Dict[str, Array]:
+        """The source's init: ``W ~ N(0, sqrt(2 / (m + n)))``, ``b ~ N(0,
+        sqrt(1 / m))`` for ``m`` outputs and ``n`` inputs, from ``rng`` and
+        the logic's seed."""
+        key = jax.random.fold_in(rng, self.seed)
+        state = {}
+        for i, (name, (n, m)) in enumerate(self.config.layers().items()):
+            kw, kb = jax.random.split(jax.random.fold_in(key, i))
+            state[f"{name}_w"] = np.sqrt(2.0 / (m + n)) * jax.random.normal(
+                kw, (n, m), jnp.float32
+            )
+            state[f"{name}_b"] = np.sqrt(1.0 / m) * jax.random.normal(
+                kb, (m,), jnp.float32
+            )
+        return state
+
+    def keys(self, batch: Dict[str, Array]) -> Array:
+        return batch["ids"]
+
+    def step(self, state, batch: Dict[str, Array], pulled: Array):
+        cfg = self.config
+        lr = cfg.learning_rate
+        live = batch["mask"]
+        x = batch["dense"].astype(jnp.float32)
+        # the entries below the diagonal, row by row: the source's li, lj
+        lower_i, lower_j = np.tril_indices(cfg.fields + 1, -1)
+        n_bot, n_top = len(cfg.bottom_mlp), len(cfg.top_mlp)
+
+        with scope("ps.dense_bottom"):
+            bot = _mlp_forward(state, "bot", x, n_bot, True)
+        with scope("ps.dense_interact"):
+            t = jnp.concatenate([bot[-1][:, None, :], pulled], axis=1)
+            z = jnp.einsum("bid,bjd->bij", t, t, precision=_PRECISION)
+            r = jnp.concatenate([bot[-1], z[:, lower_i, lower_j]], axis=1)
+        with scope("ps.dense_top"):
+            top = _mlp_forward(state, "top", r, n_top, False)
+            logit = top[-1][:, 0]
+            # p - y for y in {0, 1}, written -s / (1 + exp(s logit)), s the
+            # label's sign: the same number without the subtraction
+            # (models/logistic_ftrl.example_deltas)
+            sign = jnp.where(batch["label"] > 0, 1.0, -1.0)
+            examples = jnp.maximum(jnp.sum(live, dtype=jnp.float32), 1.0)
+            d_logit = jnp.where(
+                live, -sign / (1.0 + jnp.exp(sign * logit)), 0.0
+            ) / examples
+            grads, d_r = _mlp_backward(
+                state, "top", top, d_logit[:, None], False
+            )
+        with scope("ps.dense_interact"):
+            d_z = jnp.zeros_like(z).at[:, lower_i, lower_j].set(
+                d_r[:, cfg.dim:]
+            )
+            d_t = jnp.einsum(
+                "bij,bjd->bid", d_z + d_z.swapaxes(1, 2), t,
+                precision=_PRECISION,
+            )
+        with scope("ps.dense_bottom"):
+            bot_grads, _ = _mlp_backward(
+                state, "bot", bot, d_r[:, :cfg.dim] + d_t[:, 0], True
+            )
+            grads.update(bot_grads)
+        with scope("ps.dense_sgd"):
+            state = {k: v - lr * grads[k] for k, v in state.items()}
+        with scope("ps.delta_build"):
+            deltas = -lr * d_t[:, 1:]
+        out = {
+            "prediction": jax.nn.sigmoid(logit),
+            "loss": jax.nn.softplus(-sign * logit) * live,
+            "dlrm_dense_params": jnp.asarray(cfg.dense_params, jnp.int32),
+            "dlrm_dense_flops_per_step": jnp.asarray(
+                6.0 * cfg.macs_per_example * x.shape[0], jnp.float32
+            ),
+        }
+        mask = jnp.broadcast_to(live[:, None], batch["ids"].shape)
+        return state, PushRequest(batch["ids"], deltas, mask), out
+
+
+def uniform_rows(config: DLRMConfig, *, seed=0, dtype=jnp.float32) -> InitFn:
+    """The source's embedding init: a field's rows ``U(-sqrt(1 / C),
+    sqrt(1 / C))``, ``C`` the field's cardinality; every row from the seed
+    and its own id alone.  ``seed`` may be traced."""
+    unit = ranged_random_factor(
+        seed, (config.dim,), low=-1.0, high=1.0, dtype=dtype
+    )
+    firsts = np.concatenate([[0], np.cumsum(config.field_cardinalities)[:-1]])
+
+    def init(ids: Array) -> Array:
+        bound = jnp.ones(ids.shape, dtype)
+        for first, card in zip(firsts, config.field_cardinalities):
+            bound = jnp.where(ids >= int(first), np.sqrt(1.0 / card), bound)
+        return bound[:, None] * unit(ids)
+
+    return init
+
+
+def make_store(
+    config: DLRMConfig, *, seed=0, mesh=None, dtype=None, layout: str = "auto",
+) -> ShardedParamStore:
+    """``(num_rows, dim)`` add-store of every field's embedding rows, field
+    after field, initialised in place (:func:`uniform_rows`;
+    ``ShardedParamStore.create``).  The rows' place on the chip is
+    ``core/store._resolve_layout``'s to choose: 64 lanes lie two to a
+    128-lane physical row."""
+    dtype = dtype or jnp.float32
+    return ShardedParamStore.create(
+        config.num_rows, (config.dim,), dtype=dtype,
+        init_fn=uniform_rows(config, seed=seed, dtype=dtype), mesh=mesh,
+        layout=layout,
+    )
+
+
+__all__ = ["DLRM", "DLRMConfig", "make_store", "uniform_rows"]

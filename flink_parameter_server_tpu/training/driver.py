@@ -367,7 +367,9 @@ class StreamingDriver:
         bags (the live lanes of its keys and all of them: ``bag_live_keys``,
         ``bag_padded_keys``), a gated factorisation machine (the live lanes
         of its keys and those whose embedding the gate let through:
-        ``fm_live_keys``, ``fm_v_live_keys``) and keyed workers (the live
+        ``fm_live_keys``, ``fm_v_live_keys``), a logic with a dense net in its
+        state (its parameters and the model FLOPs of a step over them:
+        ``dlrm_dense_params``, ``dlrm_dense_flops_per_step``) and keyed workers (the live
         records the last dispatch dropped because they reached the wrong worker:
         ``keyed_misrouted``, 0 behind the router) and a store packed several
         rows to a physical row (whether the step's pull took the slice
@@ -400,6 +402,16 @@ class StreamingDriver:
             self.registry.gauge("fm_v_live_keys", component="train").set(
                 total(outs["fm_v_live_keys"])
             )
+        if "dlrm_dense_params" in outs:
+            # a logic with a dense net in its state (models/dlrm.py) says
+            # how many parameters it holds there and the model FLOPs of a
+            # step over them: constants of the logic, so the last step's
+            self.registry.gauge("dlrm_dense_params", component="train").set(
+                float(np.max(np.asarray(outs["dlrm_dense_params"])))
+            )
+            self.registry.gauge(
+                "dlrm_dense_flops_per_step", component="train"
+            ).set(float(np.max(np.asarray(outs["dlrm_dense_flops_per_step"]))))
         if "keyed_misrouted" in outs:
             # keyed workers (models/matrix_factorization.py) count the live
             # records that reached a worker whose block lacks their row

@@ -1,0 +1,325 @@
+"""``models/dlrm.py``: the step against the plain reference of the benchmark
+(``chipbench/references/dlrm.py``) and against autodiff, on one device and at
+``dp`` = 2; the dict state through the driver, its gauges and a checkpoint;
+and the store a 64-lane add row resolves to (two rows to a physical row)
+against a dense one, bit for bit."""
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from chipbench import run
+from chipbench.references import dlrm as reference
+from flink_parameter_server_tpu import (
+    DriverConfig,
+    ShardedParamStore,
+    StreamingDriver,
+)
+from flink_parameter_server_tpu.core.transform import (
+    make_train_step,
+    transform_batched,
+)
+from flink_parameter_server_tpu.models import dlrm
+from flink_parameter_server_tpu.parallel.mesh import make_mesh
+
+# a 3-row field (every batch repeats its rows), two fields no batch repeats
+# much, and a one-row field; widths a fraction of the published ones
+CARDS = (40, 3, 1, 500)
+CONFIG = dlrm.DLRMConfig(
+    CARDS, dense_features=5, dim=8, bottom_mlp=(16, 8), top_mlp=(24, 12, 1),
+    learning_rate=0.1,
+)
+# what the reference reads of a configuration file
+CFG = {
+    "learning_rate": 0.1, "dim": 8, "bottom_mlp": [16, 8],
+    "top_mlp": [24, 12, 1], "dense_fields": 5, "field_cardinalities": CARDS,
+    "reference": {"delta_rtol": 4e-5, "relu_ulps": 16},
+}
+CHECK = {"delta_rtol": 4e-5, "delta_atol": 1e-11, "row_ulps": 8}
+FIRSTS = np.concatenate([[0], np.cumsum(CARDS)[:-1]])
+
+
+def _batches(seed, n, batch=32, masked=(5,)):
+    rng = np.random.default_rng(seed)
+    out = []
+    for _ in range(n):
+        mask = np.ones(batch, bool)
+        mask[list(masked)] = False
+        out.append({
+            "dense": rng.random((batch, 5), np.float32),
+            "ids": (rng.integers(0, CARDS, (batch, 4)) + FIRSTS).astype(np.int32),
+            "label": rng.integers(0, 2, batch).astype(np.float32),
+            "mask": mask,
+        })
+    return out
+
+
+def _rows(store, state, ids):
+    """The compared rows as the benchmark's family hands them over: the
+    touched embedding rows, then the MLPs flat, ``dim`` lanes to a row."""
+    from chipbench.families import dlrm as family
+
+    return family.rows(store, state, ids)
+
+
+def _against_the_reference(logic, store, state, batches, after):
+    """``run._check_rows`` of what ``after(store, state, batches)`` leaves."""
+    ids = reference.touched(batches)
+    before = _rows(store, state, ids)
+    want = reference.apply(CFG, before, ids, batches)
+    got = _rows(*after(store, state, batches), ids)
+    return run._check_rows(CHECK, want, got, before), want, got, before
+
+
+def _stepped(logic):
+    def after(store, state, batches):
+        step = jax.jit(make_train_step(logic, store.spec))
+        table = store.table
+        for b in batches:
+            table, state, _ = step(table, state, b)
+        return ShardedParamStore(store.spec, table), state
+
+    return after
+
+
+def test_the_configuration_counts_the_sources_sizes():
+    full = dlrm.DLRMConfig((9980333, 36084, 17217, 7378, 20134, 3, 7112, 1442, 61,
+                            9758201, 1333352, 313829, 10, 2208, 11156, 122, 4, 970,
+                            14, 9994222, 7267859, 9946608, 415421, 12420, 101, 36))
+    assert full.num_rows == 49_126_297 and full.fields == 26
+    assert full.interaction_terms == 351
+    assert full.layers() == {
+        "bot0": (13, 512), "bot1": (512, 256), "bot2": (256, 64),
+        "top0": (415, 512), "top1": (512, 512), "top2": (512, 256),
+        "top3": (256, 1),
+    }
+    assert full.macs_per_example == 806_720 and full.dense_params == 762_177
+    with pytest.raises(ValueError, match="must end at"):
+        dlrm.DLRMConfig((3, 4), dim=8, bottom_mlp=(16,), top_mlp=(1,))
+
+
+def test_init_state_is_the_sources_init_from_rng_and_seed():
+    logic = dlrm.DLRM(dlrm.DLRMConfig((3, 4)), seed=7)
+    state = logic.init_state(jax.random.PRNGKey(0))
+    assert sorted(state) == sorted(
+        f"{n}{i}_{p}" for n, d in (("bot", 3), ("top", 4)) for i in range(d)
+        for p in "wb"
+    )
+    assert state["top0_w"].shape == (64 + 3, 512) and state["top3_b"].shape == (1,)
+    w, b = np.asarray(state["bot1_w"]), np.asarray(state["top1_b"])
+    assert abs(w.std() - np.sqrt(2 / (512 + 256))) < 2e-3 and abs(w.mean()) < 1e-3
+    assert abs(b.std() - np.sqrt(1 / 512)) < 6e-3
+    again = logic.init_state(jax.random.PRNGKey(0))
+    other = dlrm.DLRM(logic.config, seed=8).init_state(jax.random.PRNGKey(0))
+    assert np.array_equal(again["bot1_w"], w) and not np.array_equal(other["bot1_w"], w)
+
+
+def test_make_store_packs_two_rows_and_draws_every_field_in_its_own_range():
+    config = dlrm.DLRMConfig(CARDS, dim=64)
+    store = dlrm.make_store(config, seed=3)
+    assert (store.spec.layout, store.spec.pack, store.spec.update) == ("packed", 2, "add")
+    assert store.table.shape == (272, 128) and store.spec.capacity == 544
+    values = np.asarray(store.values())
+    for first, card in zip(FIRSTS, CARDS):
+        rows = values[first:first + card]
+        assert np.abs(rows).max() <= np.sqrt(1 / card)
+        assert np.abs(rows).max() > 0.8 * np.sqrt(1 / card)
+    traced = jax.jit(lambda s: dlrm.make_store(config, seed=s).table)(3)
+    assert np.array_equal(np.asarray(traced), np.asarray(store.table))
+    assert not np.array_equal(values, np.asarray(dlrm.make_store(config, seed=4).values()))
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2**31 + 5])
+def test_the_step_is_the_plain_reference(seed):
+    logic = dlrm.DLRM(CONFIG, seed=seed % 1000)
+    store = dlrm.make_store(CONFIG, seed=seed % 1000)
+    state = logic.init_state(jax.random.PRNGKey(0))
+    batches = _batches(seed, 2)
+    # the 3-row field repeats its rows, the one-row field holds every example
+    assert len(np.unique(batches[0]["ids"][:, 1])) <= 3
+    (failures, worst), (want, moved), got, before = _against_the_reference(
+        logic, store, state, batches, _stepped(logic)
+    )
+    assert failures == [] and 0 < worst["share"] < 0.5, worst
+    # the masked example moved nothing: a row only it names is bit for bit
+    ids = reference.touched(batches)["embedding"]
+    only_masked = np.setdiff1d(
+        batches[0]["ids"][5], np.concatenate(
+            [np.delete(b["ids"], 5, axis=0).reshape(-1) for b in batches]
+            + [batches[1]["ids"][5]]
+        ),
+    )
+    assert only_masked.size  # the 500-row field's, at these seeds
+    at = np.searchsorted(ids, only_masked)
+    assert np.array_equal(got["parameters"][at], before["parameters"][at])
+    assert not moved["parameters"][at].any()
+    # ... and the MLPs lie behind the embedding rows, whole
+    table, layers = reference.unpack(CFG, got["parameters"], ids.size)
+    assert table.shape == (ids.size, 8) and layers["top0"].shape == (8 + 10 + 1, 24)
+
+
+def test_the_gradients_are_autodiffs():
+    logic = dlrm.DLRM(CONFIG, seed=1)
+    store = dlrm.make_store(CONFIG, seed=1)
+    state = logic.init_state(jax.random.PRNGKey(0))
+    (batch,) = _batches(4, 1)
+    pulled = store.pull(jnp.asarray(batch["ids"]))
+
+    def loss(state, pulled):
+        a = batch["dense"]
+        for i in range(2):
+            a = jax.nn.relu(a @ state[f"bot{i}_w"] + state[f"bot{i}_b"])
+        t = jnp.concatenate([a[:, None], pulled], axis=1)
+        li, lj = np.tril_indices(5, -1)
+        r = jnp.concatenate([a, jnp.einsum("bid,bjd->bij", t, t)[:, li, lj]], axis=1)
+        for i in range(3):
+            r = r @ state[f"top{i}_w"] + state[f"top{i}_b"]
+            r = jax.nn.relu(r) if i < 2 else r
+        bce = jax.nn.softplus(r[:, 0]) - batch["label"] * r[:, 0]
+        return jnp.sum(bce * batch["mask"]) / batch["mask"].sum()
+
+    g_state, g_rows = jax.grad(loss, (0, 1))(state, pulled)
+    new, req, out = logic.step(state, batch, pulled)
+    for k in state:
+        np.testing.assert_allclose(
+            (state[k] - new[k]) / 0.1, g_state[k], rtol=1e-4, atol=2e-6
+        )
+    np.testing.assert_allclose(-req.deltas / 0.1, g_rows, rtol=1e-4, atol=1e-8)
+    assert np.array_equal(req.ids, batch["ids"]) and req.mask.shape == (32, 4)
+    assert not np.asarray(req.mask)[5].any() and np.asarray(req.mask)[4].all()
+    assert float(out["loss"].sum() / 31) == pytest.approx(float(loss(state, pulled)), rel=1e-5)
+    assert int(out["dlrm_dense_params"]) == CONFIG.dense_params
+    assert float(out["dlrm_dense_flops_per_step"]) == 6.0 * CONFIG.macs_per_example * 32
+
+
+def test_at_dp_2_the_step_is_the_plain_reference_and_the_mlps_stay_replicated(devices):
+    mesh = make_mesh(2, 1, devices=devices[:2])
+    logic = dlrm.DLRM(CONFIG, seed=2)
+    store = dlrm.make_store(CONFIG, seed=2, mesh=mesh)
+    state = logic.init_state(jax.random.PRNGKey(0))
+    batches = _batches(6, 2)
+
+    def after(store, state, batches):
+        result = transform_batched(
+            iter(batches), logic, store, initial_state=state, mesh=mesh,
+            dump_model=False, collect_outputs=False,
+        )
+        return result.store, result.worker_state
+
+    (failures, worst), _, _, _ = _against_the_reference(
+        logic, store, state, batches, after
+    )
+    assert failures == [] and 0 < worst["share"] < 0.5, worst
+    text = jax.jit(make_train_step(logic, store.spec)).lower(
+        store.table, state, batches[0]
+    ).compile().as_text()
+    assert "all-reduce" in text  # the partitioner's, nothing names it
+
+
+def test_the_dict_state_goes_through_the_driver_its_gauges_and_a_checkpoint(tmp_path):
+    from flink_parameter_server_tpu.telemetry.registry import MetricsRegistry
+
+    config = dlrm.DLRMConfig(CARDS, dense_features=5, dim=64, bottom_mlp=(16, 64),
+                             top_mlp=(24, 1))
+    logic = dlrm.DLRM(config, seed=3)
+    batches = _batches(8, 3)
+
+    def driver(registry=None):
+        return StreamingDriver(
+            logic, dlrm.make_store(config, seed=3), registry=registry,
+            config=DriverConfig(checkpoint_dir=str(tmp_path / "ckpt"), dump_model=False),
+        )
+
+    registry = MetricsRegistry()
+    first = driver(registry)
+    seen = []
+    first.add_group_hook(lambda step, n, table, state, outs: seen.append(sorted(state)))
+    first.run(iter(batches[:2]))  # saves on close
+    gauges = registry.snapshot()
+    assert gauges["dlrm_dense_params"][0]["value"] == config.dense_params
+    assert gauges["dlrm_dense_flops_per_step"][0]["value"] == pytest.approx(
+        6.0 * config.macs_per_example * 32
+    )
+    assert gauges["store_layout_packed"][0]["value"] == 1
+    assert len(seen) == 2 and seen[0] == sorted(first._state)
+    state = {k: np.asarray(v) for k, v in first._state.items()}
+    values = np.asarray(first.store.values())
+    second = driver()
+    assert second.resume() and second.step_idx == 2
+    assert second.store.spec.pack == 2
+    assert np.array_equal(np.asarray(second.store.values()), values)
+    assert sorted(second._state) == sorted(state)
+    for k in state:
+        assert np.array_equal(np.asarray(second._state[k]), state[k]), k
+    # the restored job and the first go on to the same third batch, bit for bit
+    got = second.run(iter(batches))  # skips the two it has
+    want = first.run(iter(batches[2:]), fast_forward=False)
+    assert second.step_idx == first.step_idx == 3
+    for k in state:
+        assert np.array_equal(
+            np.asarray(got.worker_state[k]), np.asarray(want.worker_state[k])
+        ), k
+        assert not np.array_equal(np.asarray(got.worker_state[k]), state[k]), k
+    assert np.array_equal(
+        np.asarray(got.store.values()), np.asarray(want.store.values())
+    )
+
+
+def test_the_scopes_are_whole_path_components_forward_and_backward():
+    from chipbench import program_trace
+
+    logic = dlrm.DLRM(CONFIG)
+    store = dlrm.make_store(CONFIG)
+    state = logic.init_state(jax.random.PRNGKey(0))
+    text = jax.jit(make_train_step(logic, store.spec)).lower(
+        store.table, state, _batches(0, 1)[0]
+    ).as_text(debug_info=True)
+    for scope in ("dense_bottom", "dense_interact", "dense_top", "dense_sgd",
+                  "delta_build"):
+        assert f"jit(step)/ps.compute/ps.{scope}/" in text, scope
+    # nothing of the dense net lies under ps.compute alone or under a
+    # transform's name that the benchmark's reduction would pass by
+    assert "transpose(jvp(" not in text
+    names = set(__import__("re").findall(r'"(jit\(step\)/ps\.compute/[^"]*)"', text))
+    dots = [n for n in names if n.endswith("dot_general")]
+    assert len(dots) == 4  # a layer's three products share one name
+    for n in dots:
+        assert program_trace.SCOPE.findall(n)[-1] in (
+            "ps.dense_bottom", "ps.dense_interact", "ps.dense_top"), n
+
+
+@pytest.mark.parametrize("capacity", [1000, 1001, 7])
+def test_a_store_of_two_rows_to_a_physical_row_is_a_dense_one_bit_for_bit(capacity):
+    rng = np.random.default_rng(capacity)
+    init = lambda ids: jnp.sin(ids[:, None] * 64.0 + jnp.arange(64.0))  # noqa: E731
+    packed = ShardedParamStore.create(capacity, (64,), init_fn=init, layout="auto")
+    dense = ShardedParamStore.create(capacity, (64,), init_fn=init, layout="dense")
+    assert (packed.spec.layout, packed.spec.pack) == ("packed", 2)
+    assert packed.table.shape == (-(-capacity // 16) * 8, 128)
+    assert dense.table.shape[1] == 64
+    assert np.array_equal(np.asarray(packed.values()), np.asarray(dense.values()))
+    assert packed.values().shape == (capacity, 64)
+    ids = rng.integers(0, capacity, (50, 3)).astype(np.int32)
+    ids[0, 0], ids[1, 1] = capacity - 1, capacity - 1  # the odd table's last row
+    ids[2] = -1  # a dead lane
+    deltas = rng.normal(size=(50, 3, 64)).astype(np.float32)
+    deltas[3, 0] = [np.nan, np.inf, -0.0] + [1.0] * 61
+    mask = rng.random((50, 3)) < 0.8
+    assert np.array_equal(
+        np.asarray(packed.pull(jnp.asarray(ids))), np.asarray(dense.pull(jnp.asarray(ids))),
+        equal_nan=True,
+    )
+    for _ in range(2):
+        packed = packed.push(jnp.asarray(ids), jnp.asarray(deltas), jnp.asarray(mask))
+        dense = dense.push(jnp.asarray(ids), jnp.asarray(deltas), jnp.asarray(mask))
+    got, want = np.asarray(packed.values()), np.asarray(dense.values())
+    assert np.array_equal(got, want, equal_nan=True)
+    assert np.array_equal(np.signbit(got), np.signbit(want))
+    assert np.array_equal(
+        np.asarray(packed.pull(jnp.asarray(ids))), np.asarray(dense.pull(jnp.asarray(ids))),
+        equal_nan=True,
+    )
+    # a reload packs the same rows (the rows past the capacity are zeros then)
+    again = ShardedParamStore.from_spec_values(packed.spec, packed.values())
+    assert again.table.shape == packed.table.shape
+    assert np.array_equal(np.asarray(again.values()), got, equal_nan=True)

@@ -1335,3 +1335,92 @@ def test_difacto_step_holds_nothing_table_sized_beside_its_table(
     assert "ps.push/ps.combine/while/body" in update_call
     # the rule's loop and the stretches' (the flattens' two are cell 2's)
     assert len(re.findall(r" while\(", text)) == 4
+
+
+# dlrm-criteo-10m (chipbench/configs): cell 10's add-store, 49,126,297 rows of 64
+# f32 lanes packed two to a 128-lane physical row: 24,563,152 x 128 f32, 12.58 GB
+# of a 16 GB chip; the MLPs (762,177 f32) in the worker's state
+DLRM_ROWS, DLRM_PHYS_ROWS, DLRM_FIELDS = 49_126_297, 24_563_152, 26
+
+
+@pytest.fixture(scope="module")
+def dlrm_cell():
+    """``dlrm-criteo-10m`` as ``chipbench/families/dlrm.py`` builds it."""
+    from chipbench import spec as bench_spec
+    from flink_parameter_server_tpu.models import dlrm
+
+    cfg = bench_spec.load_json("chipbench/configs/dlrm-criteo-10m.json")
+    model = dlrm.DLRMConfig(tuple(cfg["field_cardinalities"]))
+    assert model.num_rows == DLRM_ROWS and cfg["dim"] == model.dim == 64
+    return cfg, model, dlrm
+
+
+def test_dlrm_table_is_initialised_in_place_two_rows_to_a_physical_row(
+        dlrm_cell, one_chip, no_compile_cache):
+    """The seeded init of 49,126,297 x 64 f32 rows under a ``jit`` that takes
+    the seed: the 12.58 GB table ``f32[24563152,128]`` is the program's only
+    output, initialised and packed ``core/store._PACK_CHUNK`` physical rows a
+    loop step, a quarter of a GB of temporaries (a second table does not fit
+    the chip, nor do the logical rows laid 128 lanes wide: 25 GB)."""
+    cfg, model, dlrm = dlrm_cell
+    compiled = jax.jit(
+        lambda seed: dlrm.make_store(model, seed=seed, dtype=jnp.float32).table
+    ).lower(_shape(one_chip, (), jnp.uint32)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes == DLRM_PHYS_ROWS * 128 * 4 == 12_576_333_824
+    assert mem.temp_size_in_bytes < 0.5 * GB
+    assert len(re.findall(r" while\(", compiled.as_text())) >= 1
+
+
+def test_dlrm_step_holds_nothing_table_sized_beside_its_12_58_gb_table(
+        dlrm_cell, one_chip, no_compile_cache, monkeypatch):
+    """Cell 10's step at full size for a described v5e, as the chip runs it
+    (asked for the backend): the donated table is rewritten in place by ONE
+    scatter-add of whole 128-lane rows and never copied; under ``ps.pull``
+    ONE gather of whole physical rows and the lane slice kernel at two rows
+    a register, handing the logic ``f32[64,851968]``; the dense net's scopes
+    on its products; 1.8 GB of temporaries, so table, step and the pool stay
+    under the chip's 16 GB."""
+    cfg, model, dlrm = dlrm_cell
+    spec = jax.eval_shape(lambda: dlrm.make_store(model, dtype=jnp.float32)).spec
+    assert (spec.layout, spec.pack, spec.update) == ("packed", 2, "add")
+    assert spec.table_shape() == (DLRM_PHYS_ROWS, 128)
+    n = FM_BATCH * DLRM_FIELDS
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert store_mod._slice_kernel_takes(spec, n)
+    assert not store_mod._tile_kernel_takes(spec)  # one register a row: XLA's
+    logic = dlrm.DLRM(model)
+    state = {
+        k: _shape(one_chip, v.shape, v.dtype) for k, v in jax.eval_shape(
+            lambda: logic.init_state(jax.random.PRNGKey(0))).items()
+    }
+    batch = {
+        "dense": _shape(one_chip, (FM_BATCH, 13), jnp.float32),
+        "ids": _shape(one_chip, (FM_BATCH, DLRM_FIELDS), jnp.int32),
+        "label": _shape(one_chip, (FM_BATCH,), jnp.float32),
+        "mask": _shape(one_chip, (FM_BATCH,), jnp.bool_),
+    }
+    compiled = jax.jit(
+        make_train_step(logic, spec), donate_argnums=(0, 1)
+    ).lower(_shape(one_chip, spec.table_shape(), jnp.float32), state, batch).compile()
+    mem = compiled.memory_analysis()
+    assert 12.57 * GB < mem.alias_size_in_bytes < 12.59 * GB  # in place, state too
+    assert mem.temp_size_in_bytes < 2.0 * GB  # 1.82 here
+    assert mem.alias_size_in_bytes + mem.temp_size_in_bytes < 14.5 * GB
+    text = compiled.as_text()
+    assert not re.search(r"f32\[24563152,128\]\S* (copy|transpose)\(", text)
+    assert "f32[49126297,64]" not in text and "f32[49126304,64]" not in text
+    lines = text.splitlines()
+    pulls = [c for c in lines if re.search(r" gather\(", c)
+             and f" f32[{n},128]{{1,0" in c]
+    assert len(pulls) == 1 and "slice_sizes={1,128}" in pulls[0], pulls
+    assert 'op_name="jit(step)/ps.pull/' in pulls[0]
+    kernels = [line for line in lines if "tpu_custom_call" in line]
+    assert len(kernels) == 1 and "packed_lane_slice" in kernels[0]
+    assert f" = f32[64,{n}]{{1,0:" in kernels[0]
+    scatters = [c for c in lines if re.search(r" scatter\(", c)]
+    assert len(scatters) == 1 and "f32[24563152,128]" in scatters[0]
+    assert 'op_name="jit(step)/ps.push/scatter-add"' in scatters[0]
+    for scope in ("dense_bottom", "dense_interact", "dense_top", "delta_build"):
+        assert f"jit(step)/ps.compute/ps.{scope}/" in text, scope
+    assert "transpose(jvp(" not in text
