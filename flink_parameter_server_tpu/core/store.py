@@ -388,14 +388,18 @@ def push(
     and a rule store's counts pass it by.  A masked lane that keeps a live
     id is added as a zero.  ``update="add"`` is one scatter-add of the batch,
     duplicates and all, in the arm :func:`_tile_kernel_takes` reads from the
-    spec: ONE XLA scatter-add, which sums the deltas of a row in the order
-    the batch holds them (part of what the benchmark's reference checks;
-    PERF.md section 6, PR 27 and PR 30), or, on a TPU, for physical rows of
-    several 128-lane registers, ``ops/row_update.scatter_add``: the batch
-    sorted by row (stably: a row's deltas stay in the order of the batch
-    and are added one by one, XLA's roundings bit for bit) and every
-    touched tile of eight rows read, added to and written back once a
-    block of lanes (PERF.md section 6, PR 33).
+    spec and the batch's length: ONE XLA scatter-add, which sums the deltas
+    of a row in the order the batch holds them (part of what the benchmark's
+    reference checks; PERF.md section 6, PR 27 and PR 30), or, on a TPU,
+    ``ops/row_update.scatter_add``: the batch sorted by row (stably: a row's
+    deltas stay in the order of the batch and are added one by one, XLA's
+    roundings bit for bit) and every touched tile of eight rows read, added
+    to and written back once a block of lanes (PERF.md section 6, PR 33).
+    The kernel takes physical rows of several 128-lane registers always, and
+    rows of ONE register where the batch has no more than an eighth as many
+    lanes as the table has rows: there the TPU compiler leaves its
+    scatter-add serial, 74.7 ns a lane, and above it sorts the batch itself
+    and pays 13-22 (PERF.md section 6, PR 49).
 
     A store whose ``update`` is a rule goes through :func:`_push_rule`
     (:func:`push_counted` also hands out what that arm counted).
@@ -412,8 +416,13 @@ def push_counted(
     *,
     lanes_over_workers: bool = False,
 ) -> Tuple[Array, Optional[dict]]:
-    """:func:`push`, and beside the table what a rule store's push counted
-    on the device (``None`` for ``update="add"``, which counts nothing):
+    """:func:`push`, and beside the table what the push counted on the
+    device (``None`` for an ``update="add"`` batch that XLA's scatter-add
+    took, which counts nothing).  An ``add`` batch the tile kernel took
+    (:func:`_tile_kernel_takes`): ``ps_push_kernel_lanes``, the lanes it
+    kept, and ``ps_push_tile_rows``, the tile rows of eight rows it read
+    and wrote for them, summed over the kernel's calls (what its time is
+    made of: two DMA descriptors a tile row).  A rule store:
     ``ps_rule_keys``, the live lanes of the batch, ``ps_rule_rows``, the
     distinct rows the rule rewrote, and ``ps_rule_tiles``, the tiles of 128
     rows the write-back read and wrote to do so (0 where XLA's scatter
@@ -471,10 +480,14 @@ def push_counted(
         s_ids, s_deltas = _phys_scatter_args(
             spec, table, flat_ids, flat_deltas
         )
-        if _tile_kernel_takes(spec):
-            from ..ops.row_update import scatter_add
+        if _tile_kernel_takes(spec, s_ids.shape[0]):
+            from ..ops.row_update import scatter_add_counted
 
-            return scatter_add(table, s_ids, s_deltas.astype(table.dtype)), None
+            table, lanes, tile_rows = scatter_add_counted(
+                table, s_ids, s_deltas.astype(table.dtype))
+            return table, {
+                "ps_push_kernel_lanes": lanes, "ps_push_tile_rows": tile_rows,
+            }
         if lanes_over_workers and _worker_reduce_takes(spec, s_ids.shape[0]):
             return _push_add_over_workers(
                 spec, table, s_ids, s_deltas.astype(table.dtype)
@@ -702,29 +715,53 @@ def _create_dense_in_blocks(
 
 
 # Physical row widths, in 128-lane registers, from which `push` goes through
-# ops/row_update's tile kernel.  XLA's TPU scatter-add is one serial
-# read-modify-write a lane (~65 ns + ~12 ns a 128-lane piece of the row); the
-# kernel pays a sort, a permute of the deltas, ~20 ns of adds a lane and a
-# read and a write of every touched tile of 8 rows at HBM speed.  On cell 5's
-# ids (114,688 lanes, 48,841 rows) at 2 / 3 / 5 registers a row: XLA 9.95 /
-# 11.71 / 14.28 ms, the kernel path 4.46 / 5.18 / 6.28 (PERF.md section 6,
-# PR 33).  At one register XLA takes 13-22 ns a lane and a tile is eight
-# times a row's bytes: every cell that has such rows keeps XLA.
+# ops/row_update's tile kernel WHATEVER the batch.  XLA's TPU scatter-add is
+# one serial read-modify-write a lane (~65 ns + ~12 ns a 128-lane piece of the
+# row); the kernel pays a sort, a permute of the deltas, ~20 ns of adds a lane
+# and a read and a write of every touched tile of 8 rows at HBM speed.  On
+# cell 5's ids (114,688 lanes, 48,841 rows) at 2 / 3 / 5 registers a row: XLA
+# 9.95 / 11.71 / 14.28 ms, the kernel path 4.46 / 5.18 / 6.28 (PERF.md section
+# 6, PR 33).
 _TILE_KERNEL_MIN_REGISTERS = 2
+# A row of ONE register goes where the TPU compiler's own form of the
+# scatter-add sends it.  The compiler sorts the batch inside the scatter
+# (`indices_are_sorted=true`: 13-22 ns a lane, cells 1 and 2) exactly when
+# the batch has MORE than an eighth as many lanes as the operand has rows, and
+# leaves the serial form (74.7 ns a lane: cell 10, MF's user state before PR
+# 27) at or under it; `tests/test_tpu_compile.py` pins that cut on the plain
+# op for a described v5e.  So the kernel takes a one-register push whose lanes
+# times this do not exceed the table's rows (the ordinary state of a parameter
+# server: a table far longer than a minibatch), and XLA keeps every other.
+_SERIAL_SCATTER_ROWS_A_LANE = 8
+# ... and whose lanes are no fewer than this: under it the kernel's fixed
+# costs (its sorts, its plan, a grid of at least four steps) lose to 75 ns a
+# lane, and an eager push of a few rows would trace and lower a kernel for
+# them, 0.4 s.  On the v5e, distinct rows into 24.6 M, XLA against the kernel
+# path: 64 lanes 5.2 / 12.6 us, 256 lanes 18.8 / 20.8, 1,024 lanes 77.4 /
+# 51.5, 4,096 lanes 308 / 172 (PERF.md section 6, PR 49: the sweep).
+_ONE_REGISTER_MIN_LANES = 1024
 # table row shapes already warned of (`_tile_kernel_takes`)
 _REFUSALS_NOTED: set = set()
 
 
-def _tile_kernel_takes(spec: StoreSpec) -> bool:
-    """Whether ``push`` applies an ``add`` batch through
-    ``ops/row_update.scatter_add`` instead of XLA's scatter-add, read from
-    what the spec holds: a TPU, no mesh (under one GSPMD partitions the XLA
-    scatter), ``update="add"`` and a physical row of
-    ``_TILE_KERNEL_MIN_REGISTERS`` registers or more, of a shape and dtype
-    the kernel takes.  Static per compiled step.  Such a store that the
-    kernel REFUSES (rows of rank 2 or no multiple of 128 lanes under a
-    pinned ``"dense"`` layout; bfloat16) keeps the XLA arm, is counted and
-    warned of once a row shape (``ops/row_update.refusal_count``)."""
+def _tile_kernel_takes(spec: StoreSpec, lanes: Optional[int] = None) -> bool:
+    """Whether ``push`` applies an ``add`` batch of ``lanes`` physical lanes
+    through ``ops/row_update.scatter_add`` instead of XLA's scatter-add, read
+    from what the spec and the batch hold, as :func:`_slice_kernel_takes`
+    reads the pull's arm: a TPU, no mesh (under one GSPMD partitions the XLA
+    scatter), ``update="add"``, a shape and dtype the kernel takes, and
+    either a physical row of ``_TILE_KERNEL_MIN_REGISTERS`` registers or
+    more, or a row of one register and a batch of at least
+    ``_ONE_REGISTER_MIN_LANES`` lanes that is no longer than the table's
+    rows over ``_SERIAL_SCATTER_ROWS_A_LANE``: where the TPU compiler leaves
+    its scatter-add serial (74.7 ns a lane on the v5e; above that cut it
+    sorts the batch itself and takes 13-22, which the kernel's sort, permute
+    and tile walk do not beat).  Both sizes are shapes: static per compiled
+    step.  ``lanes`` None asks whether ANY push of the store may take the
+    kernel (:func:`_preload_tile_kernel`).  Such a store that the kernel
+    REFUSES (rows of rank 2 or no multiple of 128 lanes under a pinned
+    ``"dense"`` layout; bfloat16) keeps the XLA arm, is counted and warned of
+    once a row shape (``ops/row_update.refusal_count``)."""
     from ..ops import row_update
 
     shape = spec.table_shape()
@@ -732,11 +769,18 @@ def _tile_kernel_takes(spec: StoreSpec) -> bool:
     for s in shape[1:]:
         width *= int(s)
     if (spec.update != "add" or spec.mesh is not None
-            or jax.default_backend() != "tpu"
-            or width < _TILE_KERNEL_MIN_REGISTERS * 128):
+            or jax.default_backend() != "tpu"):
         return False
+    what = "wide rows"
+    if width < _TILE_KERNEL_MIN_REGISTERS * 128:
+        if lanes is None:
+            lanes = _ONE_REGISTER_MIN_LANES
+        if (width != 128 or lanes < _ONE_REGISTER_MIN_LANES
+                or lanes * _SERIAL_SCATTER_ROWS_A_LANE > shape[0]):
+            return False
+        what = "one-register rows eight batches long"
     return _taken_or_noted(
-        spec, "push into a table of wide rows",
+        spec, f"push into a table of {what}",
         row_update.tile_refusal(shape, spec.dtype),
     )
 

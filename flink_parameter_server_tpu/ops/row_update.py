@@ -76,7 +76,12 @@ folded); an add is two loads, an add and a store a register of the row,
 5's 114,688 lanes on 32.2 k tile rows take 2.81 ms where they took 4.20
 (its cold call's 1.30 GB of tile rows 2.15 ms, 1.6 at HBM speed), cell 7's
 126.4 k live lanes on 56.4 k tile rows 3.17 where they took 5.03 (PERF.md
-section 6, PR 41).
+section 6, PR 41).  Since PR 49 the same walk takes rows of ONE register
+where the table is eight batches long or longer (the TPU compiler leaves
+XLA's scatter-add serial there, 74.7 ns a lane: ``core/store.
+_tile_kernel_takes``): cell 10's 851,968 lanes on 228 k tile rows of
+``f32[24563152,128]`` are nine calls, 14.2 ms, 9.4 ns of adds a lane and
+~22-27 a tile row.
 
 **Narrow rows under a rule** (``core/store._push_rule``'s write-back of
 FTRL's ``(w, z, n)``: :func:`sorted_tile_set`) go through the third.  A
@@ -836,6 +841,12 @@ def sorted_tile_add(
     process that runs the step pays both, warm cache or not: traced a call,
     this walk cost cell 5 a second of set-up.
     """
+    return _sorted_tile_add_counted(table, sorted_ids, deltas, interpret)[0]
+
+
+def _sorted_tile_add_counted(table, sorted_ids, deltas, interpret):
+    """:func:`sorted_tile_add`'s ``(table, kept lanes, tile rows read and
+    written)``, the last two from the plan's own counts."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     why = tile_refusal(table.shape, table.dtype) or _too_many(
@@ -854,7 +865,8 @@ def _tile_add(table, sorted_ids, deltas, *, block: int, interpret: bool):
     rows, width = table.shape
     n = sorted_ids.shape[0]
     if n == 0:
-        return table
+        zero = jnp.zeros((), jnp.int32)
+        return table, zero, zero
     sorted_ids = sorted_ids.astype(jnp.int32)
     deltas = deltas.astype(jnp.float32)
     pad = -n % block
@@ -883,7 +895,7 @@ def _tile_add(table, sorted_ids, deltas, *, block: int, interpret: bool):
             pltpu.SemaphoreType.DMA((2, 3)),
         ],
     )
-    return pl.pallas_call(
+    table = pl.pallas_call(
         functools.partial(_tile_kernel, block=block),
         out_shape=jax.ShapeDtypeStruct(table.shape, table.dtype),
         grid_spec=grid_spec,
@@ -895,6 +907,10 @@ def _tile_add(table, sorted_ids, deltas, *, block: int, interpret: bool):
         interpret=interpret,
         name="sorted_row_update_tiles",
     )(tiles, words, counts, deltas, table)
+    # a block's (opened, kept, carried): a carried tile row is opened by
+    # both blocks that share it and moved once
+    opened, kept, carried = jnp.sum(counts.reshape(-1, 3), axis=0)
+    return table, kept, opened - carried
 
 
 # -- narrow rows under a rule: a read-modify-write per touched tile of 128 rows
@@ -1186,6 +1202,46 @@ def sorted_tile_set(
     return view.T, opened
 
 
+def scatter_add_counted(
+    table: Array,
+    ids: Array,
+    deltas: Array,
+    *,
+    interpret: Optional[bool] = None,
+) -> Tuple[Array, Array, Array]:
+    """``table.at[ids].add(deltas, mode="drop")`` through the tile kernel:
+    sort the ids, bring the deltas into that order (a batch over
+    ``MAX_LANES`` lanes stretch by stretch, one call each), and one
+    read-modify-write per touched tile row.  Lanes of one row are added in
+    the order the batch holds them (the sort is stable).  An eager call is
+    one jitted program (one copy of the table, not one a kernel call).
+    Beside the table, as int32 scalars on the device: the lanes the kernel
+    kept and the tile rows it read and wrote for them, summed over its calls
+    (a tile row open across two calls is moved by both).
+    """
+    if not isinstance(table, jax.core.Tracer):
+        return _scatter_add_jitted(table, ids, deltas, interpret=interpret)
+    sid, order = sort_by_row(ids.reshape(-1), None, table.shape[0])
+    calls = -(-sid.shape[0] // MAX_LANES)
+    pad = -sid.shape[0] % (calls * BLOCK) if calls > 1 else 0
+    if pad:
+        # calls of ONE shape: every process that runs the step traces and
+        # lowers the kernel once a shape, warm cache or not (cell 10's
+        # 851,968 lanes were eight calls of 94,720 and one of 94,208)
+        sid = jnp.concatenate([sid, jnp.full((pad,), _INT32_MAX, jnp.int32)])
+        order = jnp.concatenate([order, jnp.zeros((pad,), jnp.int32)])
+    lanes = tile_rows = jnp.zeros((), jnp.int32)
+    for _, s, o in _calls(sid, order):
+        table, kept, moved = _sorted_tile_add_counted(
+            table, s, jnp.take(deltas, o, axis=0, mode="clip"), interpret)
+        lanes, tile_rows = lanes + kept, tile_rows + moved
+    return table, lanes, tile_rows
+
+
+_scatter_add_jitted = jax.jit(
+    scatter_add_counted, static_argnames=("interpret",))
+
+
 def scatter_add(
     table: Array,
     ids: Array,
@@ -1193,30 +1249,14 @@ def scatter_add(
     *,
     interpret: Optional[bool] = None,
 ) -> Array:
-    """``table.at[ids].add(deltas, mode="drop")`` through the tile kernel:
-    sort the ids, bring the deltas into that order (a batch over
-    ``MAX_LANES`` lanes stretch by stretch, one call each), and one
-    read-modify-write per touched tile row.  Lanes of one row are added in
-    the order the batch holds them (the sort is stable).  An eager call is
-    one jitted program (one copy of the table, not one a kernel call).
-    """
-    if not isinstance(table, jax.core.Tracer):
-        return _scatter_add_jitted(table, ids, deltas, interpret=interpret)
-    sid, order = sort_by_row(ids.reshape(-1), None, table.shape[0])
-    for _, s, o in _calls(sid, order):
-        table = sorted_tile_add(
-            table, s, jnp.take(deltas, o, axis=0, mode="clip"),
-            interpret=interpret,
-        )
-    return table
-
-
-_scatter_add_jitted = jax.jit(scatter_add, static_argnames=("interpret",))
+    """:func:`scatter_add_counted`'s table."""
+    return scatter_add_counted(table, ids, deltas, interpret=interpret)[0]
 
 
 __all__ = [
     "BLOCK", "MAX_LANES", "note_refusal", "preload", "refusal",
-    "refusal_count", "row_add", "scatter_add", "set_refusal", "sort_by_row",
+    "refusal_count", "row_add", "scatter_add", "scatter_add_counted",
+    "set_refusal", "sort_by_row",
     "sorted_row_set", "sorted_row_update", "sorted_tile_add",
     "sorted_tile_set", "tile_refusal",
 ]

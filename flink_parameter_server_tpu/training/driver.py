@@ -358,8 +358,11 @@ class StreamingDriver:
 
     def _publish_step_counts(self, outs) -> None:
         """What the step counted on the device in the dispatch ``outs``
-        came from, as gauges: a rule store's push (``core/store.push_counted``:
-        its live keys, the distinct rows its rule rewrote and the tiles of
+        came from, as gauges: an add store's push through the tile kernel
+        (``core/store.push_counted``: the lanes it kept and the tile rows it
+        read and wrote: ``store_push_kernel_lanes``, ``store_push_tile_rows``),
+        a rule store's push (its live keys, the distinct rows its rule
+        rewrote and the tiles of
         128 rows its write-back moved to do so: ``store_rule_keys``,
         ``store_rule_rows``, ``store_rule_tiles``; for wide rows the lanes
         the row kernel summed: ``store_combine_kernel_lanes``) and a logic
@@ -424,6 +427,15 @@ class StreamingDriver:
             self.registry.gauge(
                 "store_packed_slice_kernel", component="train"
             ).set(float(np.max(np.asarray(outs["ps_slice_kernel"]))))
+        if "ps_push_tile_rows" in outs:
+            # an add store whose push the tile kernel took: the lanes it
+            # kept and the tile rows it read and wrote for them
+            self.registry.gauge(
+                "store_push_kernel_lanes", component="train"
+            ).set(total(outs["ps_push_kernel_lanes"]))
+            self.registry.gauge(
+                "store_push_tile_rows", component="train"
+            ).set(total(outs["ps_push_tile_rows"]))
         if "ps_rule_rows" not in outs:
             return
         self.registry.gauge("store_rule_keys", component="train").set(

@@ -265,6 +265,51 @@ def test_the_dict_state_goes_through_the_driver_its_gauges_and_a_checkpoint(tmp_
     )
 
 
+@pytest.mark.parametrize("dim, pack", [(64, 2), (128, 1)])
+def test_the_step_through_the_one_register_tile_kernel_is_xlas_and_the_driver_says_so(
+        dim, pack, monkeypatch):
+    """Cell 10's arm since PR 49 (``core/store._tile_kernel_takes(spec,
+    lanes)``: a TPU, a table eight batches long or more), steered here and
+    interpreted: the driver's run leaves the table and the MLPs XLA's arm
+    leaves, bit for bit, and sets ``store_push_kernel_lanes`` /
+    ``store_push_tile_rows`` from the last dispatch (what
+    ``store.push_tile_rows_share`` reads); XLA's arm sets neither."""
+    from flink_parameter_server_tpu.core import store as store_mod
+    from flink_parameter_server_tpu.telemetry.registry import MetricsRegistry
+
+    config = dlrm.DLRMConfig(CARDS, dense_features=5, dim=dim,
+                             bottom_mlp=(16, dim), top_mlp=(24, 1))
+    batches = _batches(11, 3, masked=(5, 9))
+
+    def ran():
+        registry = MetricsRegistry()
+        store = dlrm.make_store(config, seed=3)
+        assert store.spec.pack == pack and store.table.shape[1] == 128
+        driver = StreamingDriver(
+            dlrm.DLRM(config, seed=3), store, registry=registry,
+            config=DriverConfig(dump_model=False),
+        )
+        result = driver.run(iter(batches))
+        return result, registry.snapshot()
+
+    want, gauges = ran()
+    assert "store_push_tile_rows" not in gauges
+    assert "store_push_kernel_lanes" not in gauges
+    monkeypatch.setattr(
+        store_mod, "_tile_kernel_takes", lambda spec, lanes=None: True)
+    got, gauges = ran()
+    assert np.array_equal(
+        np.asarray(got.store.table).view(np.uint32),
+        np.asarray(want.store.table).view(np.uint32))
+    for k, v in want.worker_state.items():
+        assert np.array_equal(np.asarray(got.worker_state[k]), np.asarray(v)), k
+    # a masked example's lanes keep their ids and add zeros: kept, all of them
+    ids = batches[-1]["ids"].reshape(-1)
+    assert gauges["store_push_kernel_lanes"][0]["value"] == ids.size
+    assert gauges["store_push_tile_rows"][0]["value"] == len(
+        np.unique(ids // pack // 8))
+
+
 def test_the_scopes_are_whole_path_components_forward_and_backward():
     from chipbench import program_trace
 

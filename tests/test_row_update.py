@@ -508,30 +508,58 @@ def test_a_logic_that_will_trace_the_kernel_starts_the_pallas_import(
 
 
 # -- the store's arm -----------------------------------------------------------
-def _spec(shape, dtype=jnp.float32, update="add", mesh=None, layout="auto"):
+def _spec(shape, dtype=jnp.float32, update="add", mesh=None, layout="auto",
+          capacity=61):
     from flink_parameter_server_tpu.core import store as store_mod
 
     return store_mod.StoreSpec(
-        capacity=61, value_shape=shape, dtype=dtype, update=update, mesh=mesh,
-        layout=store_mod._resolve_layout(layout, update, shape),
+        capacity=capacity, value_shape=shape, dtype=dtype, update=update,
+        mesh=mesh, layout=store_mod._resolve_layout(layout, update, shape),
     )
 
 
-@pytest.mark.parametrize("backend,meshed,shape,update,want", [
-    ("tpu", False, (640,), "add", True),
-    ("tpu", False, (2, 300), "add", True),  # held flat in 640 lanes
-    ("tpu", False, (600,), "add", True),
-    ("tpu", False, (256,), "add", True),
-    ("tpu", False, (128,), "add", False),  # one register: XLA's 13-22 ns
-    ("tpu", False, (100,), "add", False),
-    ("tpu", False, (17,), "add", False),  # seven to a 128-lane row
-    ("tpu", False, (), "add", False),
-    ("cpu", False, (640,), "add", False),
-    ("tpu", True, (640,), "add", False),
-    ("tpu", False, (640,), lambda cur, new: new, False),
+# a one-register table of 40,000 physical rows: 5,000 lanes times 8 are its
+# rows, the TPU compiler's cut (``core/store._SERIAL_SCATTER_ROWS_A_LANE``)
+LONG = 40_000
+
+
+@pytest.mark.parametrize("backend,meshed,shape,update,capacity,lanes,want", [
+    ("tpu", False, (640,), "add", 61, None, True),
+    ("tpu", False, (2, 300), "add", 61, None, True),  # held flat in 640 lanes
+    ("tpu", False, (600,), "add", 61, None, True),
+    ("tpu", False, (256,), "add", 61, None, True),
+    ("tpu", False, (256,), "add", 61, 7, True),  # wide rows: whatever the batch
+    ("tpu", False, (256,), "add", 61, 10 ** 6, True),
+    # one register: the kernel where the TPU compiler's scatter-add is serial
+    ("tpu", False, (128,), "add", 61, None, False),  # no batch is under the cut
+    ("tpu", False, (128,), "add", 61, 96, False),
+    ("tpu", False, (128,), "add", LONG, None, True),  # some batch may be
+    ("tpu", False, (128,), "add", LONG, 5000, True),  # lanes x 8 = rows
+    ("tpu", False, (128,), "add", LONG, 4999, True),
+    ("tpu", False, (128,), "add", LONG, 5001, False),  # XLA sorts: 13-22 ns
+    ("tpu", False, (128,), "add", LONG, 65_536, False),
+    ("tpu", False, (128,), "add", 2 * LONG, 1024, True),  # the floor in lanes
+    ("tpu", False, (128,), "add", 2 * LONG, 1023, False),
+    ("tpu", False, (128,), "add", 2 * LONG, 8, False),  # an eager push of a few
+    ("tpu", False, (64,), "add", 2 * LONG, 5000, True),  # two to a register
+    ("tpu", False, (64,), "add", 2 * LONG - 1, 5000, True),  # odd capacity
+    ("tpu", False, (64,), "add", 2 * LONG, 5001, False),
+    ("tpu", False, (17,), "add", 7 * LONG, 5000, True),  # seven to a register
+    ("tpu", False, (17,), "add", 7 * LONG, 5001, False),
+    ("cpu", False, (128,), "add", LONG, 5000, False),
+    ("tpu", True, (128,), "add", LONG, 5000, False),  # GSPMD's scatter
+    ("tpu", False, (128,), lambda cur, new: new, LONG, 5000, False),
+    ("tpu", False, (100,), "add", 61, None, False),
+    ("tpu", False, (17,), "add", 61, None, False),  # seven to a 128-lane row
+    ("tpu", False, (), "add", 61, None, False),
+    ("tpu", False, (), "add", 128 * LONG, 5000, True),  # 128 scalars to one
+    ("tpu", False, (), "add", 128 * LONG, 5001, False),
+    ("cpu", False, (640,), "add", 61, None, False),
+    ("tpu", True, (640,), "add", 61, None, False),
+    ("tpu", False, (640,), lambda cur, new: new, 61, None, False),
 ])
-def test_push_takes_the_tile_kernel_from_what_the_spec_holds(
-        monkeypatch, backend, meshed, shape, update, want):
+def test_push_takes_the_tile_kernel_from_what_the_spec_and_the_batch_hold(
+        monkeypatch, backend, meshed, shape, update, capacity, lanes, want):
     from flink_parameter_server_tpu.core import store as store_mod
     from flink_parameter_server_tpu.parallel.mesh import make_mesh
 
@@ -539,47 +567,56 @@ def test_push_takes_the_tile_kernel_from_what_the_spec_holds(
                      devices=jax.devices()[:4]) if meshed else None
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
     n0 = row_update.refusal_count()
+    spec = _spec(shape, update=update, mesh=mesh, capacity=capacity)
     with warnings.catch_warnings():
         warnings.simplefilter("error")
-        assert store_mod._tile_kernel_takes(
-            _spec(shape, update=update, mesh=mesh)) == want
+        assert store_mod._tile_kernel_takes(spec, lanes) == want
     assert row_update.refusal_count() == n0
 
 
-@pytest.mark.parametrize("shape,dtype,layout,reason", [
-    ((640,), jnp.bfloat16, "auto", "bfloat16"),
-    ((2, 384), jnp.float32, "dense", "(2, 384)"),
-    ((600,), jnp.float32, "dense", "(600,)"),
+@pytest.mark.parametrize("shape,dtype,layout,capacity,lanes,reason", [
+    ((640,), jnp.bfloat16, "auto", 61, None, "bfloat16"),
+    ((2, 384), jnp.float32, "dense", 61, None, "(2, 384)"),
+    ((600,), jnp.float32, "dense", 61, None, "(600,)"),
+    # one register under the compiler's cut, which the kernel would take
+    ((128,), jnp.bfloat16, "auto", LONG, 5000, "bfloat16"),
+    ((64,), jnp.bfloat16, "auto", 2 * LONG, 5000, "bfloat16"),
 ])
-def test_a_wide_row_store_the_kernel_refuses_warns_once_and_counts(
-        monkeypatch, shape, dtype, layout, reason):
+def test_a_store_the_tile_kernel_refuses_warns_once_and_counts(
+        monkeypatch, shape, dtype, layout, capacity, lanes, reason):
     from flink_parameter_server_tpu.core import store as store_mod
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(store_mod, "_REFUSALS_NOTED", set())
-    spec = _spec(shape, dtype, layout=layout)
+    spec = _spec(shape, dtype, layout=layout, capacity=capacity)
     n0 = row_update.refusal_count()
     with pytest.warns(RuntimeWarning, match="falling back") as caught:
-        assert not store_mod._tile_kernel_takes(spec)
+        assert not store_mod._tile_kernel_takes(spec, lanes)
     assert reason in str(caught[0].message)
     assert row_update.refusal_count() == n0 + 1
     with warnings.catch_warnings():
         warnings.simplefilter("error")  # every later trace is silent
-        assert not store_mod._tile_kernel_takes(spec)
+        assert not store_mod._tile_kernel_takes(spec, lanes)
+        if lanes is not None:
+            # above the cut XLA's own sorted form takes it: nothing refused
+            assert not store_mod._tile_kernel_takes(spec, lanes + 1)
     assert row_update.refusal_count() == n0 + 1
 
 
-@pytest.mark.parametrize("backend,shape,started", [
-    ("tpu", (2, 300), 1), ("tpu", (128,), 0), ("cpu", (2, 300), 0),
+@pytest.mark.parametrize("backend,shape,rows,started", [
+    ("tpu", (2, 300), 16, 1), ("tpu", (128,), 16, 0), ("cpu", (2, 300), 16, 0),
+    # a one-register table long enough for some batch to lie under the cut
+    ("tpu", (128,), 8 * 1024, 1), ("tpu", (128,), 8 * 1024 - 8, 0),
+    ("cpu", (128,), 8 * 1024, 0),
 ])
 def test_a_store_whose_pushes_will_trace_the_kernel_starts_the_pallas_import(
-        monkeypatch, backend, shape, started):
+        monkeypatch, backend, shape, rows, started):
     calls = []
     monkeypatch.setattr(row_update, "preload", lambda: calls.append(1))
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
-    ShardedParamStore.create(16, shape, layout="auto")
+    ShardedParamStore.create(rows, shape, layout="auto")
     assert len(calls) == started
-    ShardedParamStore.from_values(jnp.zeros((16,) + shape), layout="auto")
+    ShardedParamStore.from_values(jnp.zeros((rows,) + shape), layout="auto")
     assert len(calls) == 2 * started
 
 

@@ -325,7 +325,12 @@ def test_push_pull_case_table(layout, width, traffic):
 # takes ``ops/row_update``'s tile kernel for them, everywhere else XLA's
 # scatter-add.  Off the TPU the chooser is steered in the test and the kernel
 # is interpreted; both arms are held to the same reference.
-WIDE_ROWS = [(256,), (300,), (640,), (2, 300), (600,)]
+# Rows of ONE register (a dense 128-lane row, 64-lane rows two to a physical
+# row) take the same arm where the batch is short against the table
+# (``_tile_kernel_takes(spec, lanes)``: the rule's edges are
+# ``tests/test_row_update.py``'s); steered here, they are two more rows of the
+# case table.
+WIDE_ROWS = [(256,), (300,), (640,), (2, 300), (600,), (128,), (64,)]
 WIDE_TRAFFIC = TRAFFIC + ["run_over_a_block_and_a_call", "tile_of_8_over_a_call"]
 
 
@@ -365,23 +370,116 @@ def test_push_pull_case_table_wide_rows(arm, shape, traffic, monkeypatch):
     # flat, padded to whole registers
     whole = len(shape) == 1 and shape[0] % 128 == 0
     assert store.spec.layout == ("dense" if whole else "packed")
-    assert store.table.shape == (64, -(-int(np.prod(shape)) // 128) * 128)
+    assert store.table.shape == (
+        64 // store.spec.pack, -(-int(np.prod(shape)) // 128) * 128)
     push = _push
     if arm == "tile_kernel":
         assert not store_mod._tile_kernel_takes(store.spec)  # this is a CPU
-        monkeypatch.setattr(store_mod, "_tile_kernel_takes", lambda spec: True)
+        monkeypatch.setattr(
+            store_mod, "_tile_kernel_takes", lambda spec, lanes=None: True)
         monkeypatch.setattr(row_update, "MAX_LANES", 512)
         calls = []
-        real = row_update.sorted_tile_add
+        real = row_update._sorted_tile_add_counted
         monkeypatch.setattr(
-            row_update, "sorted_tile_add",
-            lambda *a, **kw: calls.append(a[1].shape[0]) or real(*a, **kw))
+            row_update, "_sorted_tile_add_counted",
+            lambda *a: calls.append(a[1].shape[0]) or real(*a))
         # not `_push`: a program traced for the other arm would be reused
         push = jax.jit(lambda st, i, d, m: st.push(i, d, m))
     _check_push_pull(
         store, values, *_wide_traffic(traffic, rng, CAP, shape), push=push)
     if arm == "tile_kernel":
         assert calls and max(calls) <= 512, calls
+
+
+# The one-register arm against XLA's, BIT FOR BIT: what ``correct`` rests on
+# in cell 10 (the benchmark's reference adds a row's deltas one by one, in the
+# order of the batch) is that the kernel's sort is stable and its adds single.
+ONE_REGISTER_CAP = 1001  # odd: the last physical row of a pack-2 store is half
+ONE_REGISTER_TRAFFIC = [
+    "hot_row_thousands", "both_halves_of_a_physical_row", "dead_and_masked",
+    "nan_inf_negative_zero", "over_max_lanes_a_tile_row_across_two_calls",
+]
+
+
+def _one_register_traffic(kind, rng, cap, width):
+    n, mask = 700, None
+    ids = rng.integers(0, cap, n)
+    special = None
+    if kind == "hot_row_thousands":
+        n = 4000
+        ids = rng.integers(0, cap, n)
+        ids[rng.permutation(n)[:3000]] = 501  # 3,000 deltas on one row
+    elif kind == "both_halves_of_a_physical_row":
+        # logical rows 2r and 2r + 1 share physical row r at two to a row;
+        # at one to a row they are neighbours in one tile row
+        ids = rng.choice(np.arange(40, 56), n)
+    elif kind == "dead_and_masked":
+        ids[rng.random(n) < 0.3] = -1
+        ids[:3] = [cap + 20, cap + 1000, 2 ** 31 - 1]  # past the padding too
+        mask = rng.random(n) > 0.25
+    elif kind == "nan_inf_negative_zero":
+        special = np.array([np.nan, np.inf, -np.inf, -0.0, 0.0], np.float32)
+    elif kind == "over_max_lanes_a_tile_row_across_two_calls":
+        # calls of 512 sorted lanes: rows 16-31 (one tile row dense, one or
+        # two packed) lie round the 512th sorted lane; a hot row spans calls
+        ids = np.concatenate([
+            np.zeros(490, np.int64), np.repeat(np.arange(16, 32), 4),
+            np.full(700, 700), rng.integers(32, cap, 500),
+        ])
+        ids = rng.permutation(ids)
+        n = ids.size
+    else:
+        raise AssertionError(kind)
+    ids = np.asarray(ids, np.int32)
+    deltas = rng.normal(0, 1, (ids.size, width)).astype(np.float32)
+    if special is not None:
+        at = rng.random(deltas.shape) < 0.02
+        deltas[at] = rng.choice(special, int(at.sum()))
+    if kind == "dead_and_masked":
+        deltas[(ids < 0) | (ids >= cap)] = np.nan  # dropped unread
+    return ids, deltas, mask
+
+
+@pytest.mark.parametrize("traffic", ONE_REGISTER_TRAFFIC)
+@pytest.mark.parametrize("width", [128, 64])
+def test_one_register_push_through_the_tile_kernel_is_xlas_bit_for_bit(
+        width, traffic, monkeypatch):
+    from flink_parameter_server_tpu.core import store as store_mod
+    from flink_parameter_server_tpu.ops import row_update
+
+    cap = ONE_REGISTER_CAP
+    rng = np.random.default_rng([width, ONE_REGISTER_TRAFFIC.index(traffic)])
+    values = _init_values(cap, (width,))
+    values[::7] = -0.0  # a masked lane adds +0.0 to it on both arms
+    store = ShardedParamStore.from_values(jnp.asarray(values), layout="auto")
+    k = 128 // width
+    assert store.spec.pack == k and store.table.shape == (-(-cap // k // 8) * 8, 128)
+    ids, deltas, mask = _one_register_traffic(traffic, rng, cap, width)
+    args = (jnp.asarray(ids), jnp.asarray(deltas),
+            None if mask is None else jnp.asarray(mask))
+    want, counted = store_mod.push_counted(store.spec, store.table, *args)
+    assert counted is None  # XLA's arm counts nothing
+    monkeypatch.setattr(
+        store_mod, "_tile_kernel_takes", lambda spec, lanes=None: True)
+    monkeypatch.setattr(row_update, "MAX_LANES", 512)
+    got, counted = store_mod.push_counted(store.spec, store.table, *args)
+    np.testing.assert_array_equal(
+        np.asarray(got).view(np.uint32), np.asarray(want).view(np.uint32))
+    # what the plan counted: the kept lanes, and the tile rows of each call
+    kept = ids[(ids >= 0) & (ids < cap)]
+    assert int(counted["ps_push_kernel_lanes"]) == kept.size
+    dropped = 2 ** 30  # sorts last, opens nothing
+    phys = np.sort(np.where((ids >= 0) & (ids < cap), ids // k, dropped))
+    calls = -(-ids.size // 512)
+    size = -(-ids.size // (calls * 256)) * 256
+    tile_rows = sum(
+        np.unique(part[part < dropped] // 8).size
+        for part in (phys[lo:lo + size] for lo in range(0, ids.size, size)))
+    assert int(counted["ps_push_tile_rows"]) == tile_rows
+    if traffic == "over_max_lanes_a_tile_row_across_two_calls":
+        assert calls == 4 and tile_rows > np.unique(kept // k // 8).size
+    if traffic == "dead_and_masked":
+        assert kept.size < ids.size and np.isfinite(np.asarray(got)).all()
 
 
 # A RULE store's rows wider than a sort carries: on a TPU (no mesh, float32,

@@ -1337,6 +1337,42 @@ def test_difacto_step_holds_nothing_table_sized_beside_its_table(
     assert len(re.findall(r" while\(", text)) == 4
 
 
+@pytest.mark.parametrize("rows, lanes, sorted_form", [
+    (FM1_PHYS_ROWS, 1_277_952, True),  # cell 2: XLA keeps it, 21.8 ns a lane
+    (FM1_PHYS_ROWS, 877_257, True),  # 8 lanes over the table's rows
+    (FM1_PHYS_ROWS, 877_256, False),  # lanes x 8 = rows
+    (FM1_PHYS_ROWS, 851_968, False),
+    (6_815_744, 851_968, False),  # lanes x 8 = rows
+    (24_563_152, 851_968, False),  # cell 10: the kernel, XLA's is 74.7 ns
+    (24_563_152, 3_100_000, True),
+])
+def test_the_compilers_cut_that_SERIAL_SCATTER_ROWS_A_LANE_stands_on(
+        one_chip, no_compile_cache, rows, lanes, sorted_form):
+    """The COMPILER's cut that ``core/store._SERIAL_SCATTER_ROWS_A_LANE``
+    stands on, on the plain op for a described v5e: the TPU compiler gives
+    ``table.at[ids].add(deltas, mode="drop")`` (float32, 128 lanes) its
+    sorted form, a ``sort`` of the ids and ``indices_are_sorted=true`` on
+    the scatter (13-22 ns a lane on the chip), exactly when the batch has
+    more than an eighth as many lanes as the operand has rows, and leaves it
+    serial (74.7 ns a lane) at or under that.  ``_tile_kernel_takes`` sends
+    a one-register push to the tile kernel on the serial side alone.  A
+    libtpu that moves the cut fails HERE: then measure both arms on the new
+    side (PERF.md section 6, PR 49) and move the constant."""
+    assert sorted_form == (
+        lanes * store_mod._SERIAL_SCATTER_ROWS_A_LANE > rows)
+    text = jax.jit(
+        lambda t, i, d: t.at[i].add(d, mode="drop"), donate_argnums=(0,)
+    ).lower(
+        _shape(one_chip, (rows, 128), jnp.float32),
+        _shape(one_chip, (lanes,), jnp.int32),
+        _shape(one_chip, (lanes, 128), jnp.float32),
+    ).compile().as_text()
+    scatters = [c for c in text.splitlines() if re.search(r" scatter\(", c)]
+    assert len(scatters) == 1 and f"f32[{rows},128]" in scatters[0]
+    assert ("indices_are_sorted=true" in scatters[0]) == sorted_form
+    assert bool(re.search(r" sort\(", text)) == sorted_form
+
+
 # dlrm-criteo-10m (chipbench/configs): cell 10's add-store, 49,126,297 rows of 64
 # f32 lanes packed two to a 128-lane physical row: 24,563,152 x 128 f32, 12.58 GB
 # of a 16 GB chip; the MLPs (762,177 f32) in the worker's state
@@ -1375,12 +1411,14 @@ def test_dlrm_table_is_initialised_in_place_two_rows_to_a_physical_row(
 def test_dlrm_step_holds_nothing_table_sized_beside_its_12_58_gb_table(
         dlrm_cell, one_chip, no_compile_cache, monkeypatch):
     """Cell 10's step at full size for a described v5e, as the chip runs it
-    (asked for the backend): the donated table is rewritten in place by ONE
-    scatter-add of whole 128-lane rows and never copied; under ``ps.pull``
-    ONE gather of whole physical rows and the lane slice kernel at two rows
-    a register, handing the logic ``f32[64,851968]``; the dense net's scopes
-    on its products; 1.8 GB of temporaries, so table, step and the pool stay
-    under the chip's 16 GB."""
+    (asked for the backend): the donated table is rewritten in place by the
+    tile kernel, nine calls of one shape (851,968 lanes times 8 are under
+    the table's 24,563,152 rows, where the TPU compiler would leave its own
+    scatter-add serial: ``_tile_kernel_takes(spec, n)``), no XLA scatter, and
+    never copied; under ``ps.pull`` ONE gather of whole physical rows and the
+    lane slice kernel at two rows a register, handing the logic
+    ``f32[64,851968]``; the dense net's scopes on its products; 1.8 GB of
+    temporaries, so table, step and the pool stay under the chip's 16 GB."""
     cfg, model, dlrm = dlrm_cell
     spec = jax.eval_shape(lambda: dlrm.make_store(model, dtype=jnp.float32)).spec
     assert (spec.layout, spec.pack, spec.update) == ("packed", 2, "add")
@@ -1388,7 +1426,8 @@ def test_dlrm_step_holds_nothing_table_sized_beside_its_12_58_gb_table(
     n = FM_BATCH * DLRM_FIELDS
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     assert store_mod._slice_kernel_takes(spec, n)
-    assert not store_mod._tile_kernel_takes(spec)  # one register a row: XLA's
+    assert store_mod._tile_kernel_takes(spec, n)  # under the compiler's cut
+    assert n * store_mod._SERIAL_SCATTER_ROWS_A_LANE < DLRM_PHYS_ROWS
     logic = dlrm.DLRM(model)
     state = {
         k: _shape(one_chip, v.shape, v.dtype) for k, v in jax.eval_shape(
@@ -1416,11 +1455,21 @@ def test_dlrm_step_holds_nothing_table_sized_beside_its_12_58_gb_table(
     assert len(pulls) == 1 and "slice_sizes={1,128}" in pulls[0], pulls
     assert 'op_name="jit(step)/ps.pull/' in pulls[0]
     kernels = [line for line in lines if "tpu_custom_call" in line]
-    assert len(kernels) == 1 and "packed_lane_slice" in kernels[0]
-    assert f" = f32[64,{n}]{{1,0:" in kernels[0]
-    scatters = [c for c in lines if re.search(r" scatter\(", c)]
-    assert len(scatters) == 1 and "f32[24563152,128]" in scatters[0]
-    assert 'op_name="jit(step)/ps.push/scatter-add"' in scatters[0]
+    slices = [k for k in kernels if "packed_lane_slice" in k]
+    assert len(slices) == 1 and f" = f32[64,{n}]{{1,0:" in slices[0]
+    adds = [k for k in kernels if "sorted_row_update_tiles" in k]
+    assert len(adds) == 9 == -(-n // row_update.MAX_LANES) and len(kernels) == 10
+    for call in adds:
+        assert " = f32[24563152,128]{1,0" in call and "ps.push/" in call
+    # calls of ONE shape (the sorted batch padded to nine times 370 blocks):
+    # a process traces and lowers the kernel once
+    assert "f32[94720,128]" in text and "f32[94208,128]" not in text
+    assert not re.search(r" scatter\(", text)
+    # the step's outputs carry what the plan counted
+    outs = jax.eval_shape(
+        make_train_step(logic, spec),
+        jax.ShapeDtypeStruct(spec.table_shape(), jnp.float32), state, batch)[2]
+    assert {"ps_push_kernel_lanes", "ps_push_tile_rows", "ps_slice_kernel"} <= set(outs)
     for scope in ("dense_bottom", "dense_interact", "dense_top", "delta_build"):
         assert f"jit(step)/ps.compute/ps.{scope}/" in text, scope
     assert "transpose(jvp(" not in text
