@@ -44,6 +44,7 @@ from .store import ShardedParamStore
 from ..parallel.mesh import DP_AXIS, worker_count
 from ..telemetry.compile_ledger import setup_span
 from ..telemetry.spans import NULL_TRACER, SpanTracer
+from ..training.metrics import InFlight
 from ..training.tracing import scope
 
 T = TypeVar("T")
@@ -467,6 +468,7 @@ def transform_batched(
     tracer: SpanTracer = NULL_TRACER,
     owns_inputs: bool = False,
     steps: Optional[Tuple[Callable, Optional[Callable]]] = None,
+    inflight: Optional[InFlight] = None,
 ) -> TransformResult:
     """Run the compiled PS loop over an iterable of microbatches.
 
@@ -527,7 +529,15 @@ def transform_batched(
     ``data`` for the next batch, and ``train.pull_compute_push`` round
     the batch's ``device_put`` and the jitted call — the host INSIDE the
     dispatch, which includes the time the runtime's cap on programs in
-    flight holds it.
+    flight holds it.  With an enabled tracer that second span also carries
+    the pipeline's depth as its ``args``, ``{"inflight": n, "ready_age_s":
+    a}``: the dispatches whose outputs are not yet known to be ready, this
+    one included, and how old the newest one seen ready was when it was
+    (``training/metrics.InFlight``, polled right after the jitted call
+    returns; no span is added for it).  ``inflight`` is the caller's own
+    books where it wants to read them too (the StreamingDriver's gauges);
+    by default the call keeps its own.  Under ``NULL_TRACER`` none of this
+    runs.
     """
     rng = rng if rng is not None else jax.random.PRNGKey(0)
     spec = store.spec
@@ -580,6 +590,10 @@ def transform_batched(
         state = jax.tree.map(commit, state)
     worker_outputs: List[Any] = []
     step_idx = 0
+    if not tracer.enabled:
+        inflight = None
+    elif inflight is None:
+        inflight = InFlight()
 
     def to_workers(x):
         # host arrays go to the workers a lane block each; what already
@@ -590,10 +604,12 @@ def transform_batched(
         return jax.device_put(x, batch_sharding)
 
     def _run_one(table, state, batch, step_idx):
-        with tracer.span("pull_compute_push", component="train"):
+        with tracer.span("pull_compute_push", component="train") as span:
             if batch_sharding is not None:
                 batch = jax.tree.map(to_workers, batch)
             table, state, out = step(table, state, batch)
+            if inflight is not None:
+                span.args = inflight.dispatched(out)
         if on_step is not None:
             on_step(step_idx, out)
         if state_callback is not None:
@@ -605,9 +621,11 @@ def transform_batched(
         return table, state
 
     def _run_group(table, state, group, first_idx):
-        with tracer.span("pull_compute_push", component="train"):
+        with tracer.span("pull_compute_push", component="train") as span:
             stacked = stack_group(group, scan_sharding)
             table, state, outs = scan_step(table, state, stacked)
+            if inflight is not None:
+                span.args = inflight.dispatched(outs)
         if on_step is not None or collect_outputs:
             for i in range(len(group)):
                 out_i = jax.tree.map(lambda x: x[i], outs)
@@ -652,6 +670,9 @@ def transform_batched(
         table, state = _run_one(table, state, batch, step_idx)
         step_idx += 1
 
+    if inflight is not None:
+        # the loop has ended: its books hold no output past it
+        inflight.pending.clear()
     final_store = ShardedParamStore(spec, table)
     server_outputs: List[Any] = []
     if dump_model:

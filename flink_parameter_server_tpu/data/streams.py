@@ -182,7 +182,7 @@ def prefetch(it: Iterator[Any], size: int = 2) -> Iterator[Any]:
             return
         q.put(sentinel)
 
-    t = threading.Thread(target=worker, daemon=True)
+    t = threading.Thread(target=worker, name="fps-prefetch", daemon=True)
     t.start()
     while True:
         item = q.get()

@@ -1,6 +1,6 @@
 """End-of-run report builder.
 
-One page per run, not a log to grep: steps/sec, pull→push latency
+One page per run, not a log to grep: steps/sec, the dispatch interval
 percentiles, serving QPS/p99, snapshot staleness, ingest reconnects,
 recovery episodes — pulled from the unified registry and written to
 ``results/<platform>/run_report.{md,json}``.  The rule it serves:
@@ -91,7 +91,9 @@ def build_run_report(
             "events": int(events),
             "steps_per_sec": round(steps / wall, 2),
             "updates_per_sec": round(events / wall, 1),
-            "pull_push": _hist_percentiles(reg, "pull_push_latency_seconds"),
+            "dispatch_interval": _hist_percentiles(
+                reg, "dispatch_interval_seconds"
+            ),
             "checkpoints": int(_sum_counter(snap, "checkpoints_total")),
         },
         "serving": {
@@ -379,7 +381,7 @@ def render_markdown(report: Dict[str, Any]) -> str:
     t, s = report["train"], report["serving"]
     i, r = report["ingest"], report["recovery"]
     e = report.get("elastic", {})
-    pp, sl = t["pull_push"], s["latency"]
+    pp, sl = t["dispatch_interval"], s["latency"]
 
     def fmt(v, unit=""):
         return "—" if v is None else f"{v}{unit}"
@@ -395,7 +397,7 @@ def render_markdown(report: Dict[str, Any]) -> str:
         f"| train steps | {t['steps']} |",
         f"| steps/sec | {t['steps_per_sec']} |",
         f"| updates/sec | {t['updates_per_sec']} |",
-        f"| pull→push p50 / p99 | {fmt(pp['p50_ms'], ' ms')} / "
+        f"| dispatch interval p50 / p99 | {fmt(pp['p50_ms'], ' ms')} / "
         f"{fmt(pp['p99_ms'], ' ms')} |",
         f"| checkpoints | {t['checkpoints']} |",
         f"| serving requests (rejected) | {s['requests']} "

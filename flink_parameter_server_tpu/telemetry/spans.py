@@ -32,6 +32,18 @@ program span then lies in the ``.xplane.pb`` beside the device's ops
 whenever a profiler session runs, and costs one inactive ``TraceMe``
 when none does.  ``record()`` stays host-clock only.
 
+``args``: a span or a record may carry ONE small dict of numbers beside
+its stamps (``with tracer.span(...) as sp: sp.args = {...}``,
+``record(..., args=...)``): a count taken at the boundary the span
+already marks rides on that span instead of costing the ring a second
+one.  ``spans()`` hands it back under ``"args"`` and the Chrome export
+merges it into the event's ``args``; ``None`` where none was set.
+
+What the ring drops it counts: ``dropped`` is how many spans fell off
+the old end since the last ``clear()``.  A reader that takes a window's
+spans after the run asks it first: above 0 the window's head may be
+gone, and every median over "the window's spans" reads its tail.
+
 Stack bookkeeping: per-thread span stacks live in a dict keyed by
 thread ident, with dead-thread entries evicted whenever a NEW thread
 first spans and the table has grown past a small bound — a
@@ -42,6 +54,8 @@ must not keep a stack list per thread that ever existed
 """
 from __future__ import annotations
 
+import contextlib
+import gc
 import json
 import os
 import threading
@@ -51,6 +65,10 @@ from typing import Any, Dict, List, Optional
 
 # prune dead-thread stacks once the table outgrows this many entries
 _STACK_TABLE_SOFT_CAP = 32
+# a collection of a younger generation is recorded when it lasts longer
+# (a full one always is): most take tens of microseconds and would fill
+# the ring with nothing anyone reads
+_GC_RECORD_MIN_S = 1e-4
 
 
 def gen_id(nbytes: int = 8) -> str:
@@ -78,7 +96,7 @@ _NULL_SPAN = _NullSpan()
 class _Span:
     __slots__ = (
         "tracer", "name", "component", "t0",
-        "trace_id", "span_id", "parent_id", "note",
+        "trace_id", "span_id", "parent_id", "note", "args",
     )
 
     def __init__(
@@ -97,6 +115,7 @@ class _Span:
         self.parent_id = parent_id
         self.span_id = span_id
         self.note = None
+        self.args = None  # set inside the block: recorded with the span
 
     def __enter__(self):
         stack = self.tracer._stack()
@@ -126,7 +145,7 @@ class _Span:
         stack.pop()
         self.tracer._record(
             self.name, self.component, self.t0, t1, depth,
-            self.trace_id, self.span_id, self.parent_id,
+            self.trace_id, self.span_id, self.parent_id, self.args,
         )
         return False
 
@@ -162,6 +181,13 @@ class SpanTracer:
         self.process = process
         self._lock = threading.Lock()
         self._spans: deque = deque(maxlen=self.capacity)
+        # spans the ring dropped off its old end since the last clear()
+        self.dropped = 0
+        # collections the gc callback has timed and no thread has put in
+        # the ring yet (gc_spans): the callback takes no lock
+        self._gc_pending: deque = deque()
+        self._gc_open = None  # (t0, annotation) of the collection running
+        self._gc_users = 0
         self._stacks: Dict[int, list] = {}
         self._stacks_lock = threading.Lock()
         # perf_counter has an arbitrary epoch; anchor it to wall time
@@ -202,12 +228,30 @@ class SpanTracer:
     def _record(
         self, name: str, component: str, t0: float, t1: float, depth: int,
         trace_id: Optional[str] = None, span_id: Optional[str] = None,
-        parent_id: Optional[str] = None,
+        parent_id: Optional[str] = None, args: Optional[dict] = None,
     ) -> None:
         with self._lock:
-            self._spans.append((
+            if self._gc_pending:
+                self._flush_gc()
+            self._append((
                 name, component, t0, t1, depth, threading.get_ident(),
-                trace_id, span_id, parent_id,
+                trace_id, span_id, parent_id, args,
+            ))
+
+    def _append(self, entry: tuple) -> None:
+        # under self._lock
+        if len(self._spans) == self.capacity:
+            self.dropped += 1
+        self._spans.append(entry)
+
+    def _flush_gc(self) -> None:
+        # under self._lock; the callback may append while this pops
+        pending = self._gc_pending
+        while pending:
+            t0, t1, generation, tid = pending.popleft()
+            self._append((
+                "gc", "host", t0, t1, 0, tid, None, None, None,
+                {"generation": generation},
             ))
 
     def span(
@@ -233,6 +277,7 @@ class SpanTracer:
         trace_id: Optional[str] = None,
         span_id: Optional[str] = None,
         parent_id: Optional[str] = None,
+        args: Optional[dict] = None,
     ) -> None:
         """Retroactive span from already-taken ``time.perf_counter()``
         stamps — for intervals whose boundaries live in someone else's
@@ -242,17 +287,74 @@ class SpanTracer:
             return
         self._record(
             name, component, float(t0), float(t1), 0,
-            trace_id, span_id, parent_id,
+            trace_id, span_id, parent_id, args,
         )
 
     def clear(self) -> None:
         with self._lock:
             self._spans.clear()
+            self._gc_pending.clear()
+            self.dropped = 0
+
+    # -- garbage collections -------------------------------------------------
+    @contextlib.contextmanager
+    def gc_spans(self):
+        """For the length of the block (a telemetry-on
+        ``StreamingDriver.run``) the interpreter's garbage collections are
+        on this tracer's books as ``host.gc``, on the thread that ran
+        them: a full collection (generation 2) always, and as an
+        ``fps.host.gc`` annotation on the profiler's clock too; a younger
+        one as a host-clock record when it lasts over 0.1 ms.  ``args``
+        carries the generation.  The callback takes no lock (a collection
+        can start inside ``_record``): it queues its stamps, and the next
+        span recorded, or the next read, puts them in the ring."""
+        if not self.enabled:
+            yield
+            return
+        with self._lock:
+            self._gc_users += 1
+            if self._gc_users == 1:
+                gc.callbacks.append(self._on_gc)
+        try:
+            yield
+        finally:
+            with self._lock:
+                self._gc_users -= 1
+                if self._gc_users == 0:
+                    gc.callbacks.remove(self._on_gc)
+                    self._gc_open = None
+
+    def _on_gc(self, phase: str, info: dict) -> None:
+        # collections never nest and run under the GIL: one open slot
+        if phase == "start":
+            note = None
+            if info["generation"] == 2 and self._annotation is not None:
+                note = self._annotation("fps.host.gc")
+                note.__enter__()
+            self._gc_open = (time.perf_counter(), note)
+            return
+        opened, self._gc_open = self._gc_open, None
+        if opened is None:  # installed while this collection ran
+            return
+        t1 = time.perf_counter()
+        t0, note = opened
+        if note is not None:
+            note.__exit__(None, None, None)
+        if info["generation"] == 2 or t1 - t0 > _GC_RECORD_MIN_S:
+            self._gc_pending.append(
+                (t0, t1, info["generation"], threading.get_ident())
+            )
 
     # -- reads -------------------------------------------------------------
     def __len__(self) -> int:
         with self._lock:
+            self._flush_gc()
             return len(self._spans)
+
+    def _raw(self) -> list:
+        with self._lock:
+            self._flush_gc()
+            return list(self._spans)
 
     def wall_clock_anchor(self) -> tuple:
         """``(epoch_wall, epoch_perf)`` — the wall-time anchoring of
@@ -260,19 +362,26 @@ class SpanTracer:
         material for cross-process clock alignment)."""
         return self._epoch_wall, self._epoch_perf
 
-    def spans(self) -> List[Dict[str, Any]]:
+    def spans(
+        self, overlapping: Optional[tuple] = None
+    ) -> List[Dict[str, Any]]:
         """Recorded spans, oldest first: name/component/start/dur/depth/
         tid (seconds, perf_counter timebase) plus trace_id/span_id/
-        parent_id (None for untraced spans)."""
-        with self._lock:
-            raw = list(self._spans)
+        parent_id (None for untraced spans) and ``args`` (the span's
+        dict, or None).  ``overlapping=(t0, t1)`` keeps those that lie at
+        least partly inside that interval."""
+        raw = self._raw()
+        if overlapping is not None:
+            lo, hi = overlapping
+            raw = [r for r in raw if r[3] > lo and r[2] < hi]
         return [
             {
                 "name": n, "component": c, "start": t0,
                 "dur": t1 - t0, "depth": d, "tid": tid,
                 "trace_id": tr, "span_id": sp, "parent_id": pa,
+                "args": args,
             }
-            for (n, c, t0, t1, d, tid, tr, sp, pa) in raw
+            for (n, c, t0, t1, d, tid, tr, sp, pa, args) in raw
         ]
 
     def export_chrome_trace(self, path: Optional[str] = None) -> str:
@@ -286,10 +395,10 @@ class SpanTracer:
                 "name": "process_name", "ph": "M", "pid": self.pid,
                 "tid": 0, "args": {"name": self.process},
             })
-        with self._lock:
-            raw = list(self._spans)
-        for (name, component, t0, t1, depth, tid, tr, sp, pa) in raw:
+        for (name, component, t0, t1, depth, tid, tr, sp, pa, own) in self._raw():
             args: Dict[str, Any] = {"depth": depth}
+            if own:
+                args.update(own)
             if tr is not None:
                 args["trace_id"] = tr
                 args["span_id"] = sp

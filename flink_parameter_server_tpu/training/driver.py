@@ -7,7 +7,8 @@ checkpointing (such as it is) and shutdown are runtime concerns
 framework, layered on :func:`..core.transform.transform_batched` (one
 loop implementation, hooked — not duplicated):
 
-  * step metrics (updates/sec, pull→push latency percentiles),
+  * step metrics (updates/sec, the dispatch cadence, the dispatches in
+    flight, and what held the host in a long gap between two of them),
   * periodic orbax checkpoints + resume (PS-aware, which Flink iterative
     jobs never had — SURVEY.md §5), with cursor fast-forward,
   * optional profiler tracing of steady-state steps,
@@ -35,7 +36,7 @@ from ..telemetry import compile_ledger
 from ..telemetry.registry import get_registry
 from ..telemetry.spans import NULL_TRACER, get_tracer
 from . import checkpoint as ckpt
-from .metrics import StepMetrics
+from .metrics import InFlight, StepMetrics
 from .tracing import profile_trace
 
 
@@ -104,8 +105,8 @@ class DriverConfig:
     # there is no
     # host-visible table: checkpoint/nan/metrics cadences round UP to
     # the next group boundary (a cadence of 10 with K=4 fires at steps
-    # 12, 20, 24, ...), metrics latency percentiles time dispatches (K
-    # steps each), and the profile window covers whole dispatches.
+    # 12, 20, 24, ...), the metrics' interval percentiles time dispatches
+    # (K steps each), and the profile window covers whole dispatches.
     steps_per_call: int = 1
     # Preemption-safe shutdown (the reference's stop-with-savepoint
     # analogue; Flink jobs drain + savepoint on SIGTERM): on any of
@@ -126,7 +127,7 @@ class DriverConfig:
     wal_fsync_every: int = 1  # records between fsyncs; 0 = never
     wal_max_bytes: Optional[int] = None  # soft budget (warns when over)
     # Unified telemetry plane (telemetry/): step/event counters, the
-    # pull→push latency histogram and live gauges publish to the
+    # dispatch-interval histogram and live gauges publish to the
     # process-wide MetricsRegistry (scrapeable via TelemetryServer
     # while the run is live), and the host-side phases — ingest wait,
     # WAL append, the pull/compute/push dispatch, snapshot publish,
@@ -185,12 +186,22 @@ class StreamingDriver:
         else:
             self.registry = get_registry() if self.config.telemetry else None
         self.tracer = get_tracer() if self.config.telemetry else NULL_TRACER
+        # the dispatches in flight: the loop writes the books where it
+        # dispatches, StepMetrics' gauges read them (training/metrics.py)
+        self._inflight = InFlight() if self.tracer.enabled else None
         if self.registry is not None:
             # which layout the store resolved to (fixed when it was built:
             # a stored value, so the registry holds no driver and no table)
             self.registry.gauge(
                 "store_layout_packed", component="train"
             ).set(store.spec.layout == "packed")
+            # what the ring has dropped since its last clear(): above 0 a
+            # reader of "the run's spans" is reading their tail
+            tracer = self.tracer
+            self.registry.gauge(
+                "tracer_spans_dropped", component="train",
+                fn=lambda: tracer.dropped,
+            )
         # spans open on the profiler's clock too: this code owns a device
         self.tracer.annotate_with(jax.profiler.TraceAnnotation)
         if self.config.telemetry:
@@ -576,6 +587,8 @@ class StreamingDriver:
                 self.metrics = StepMetrics(
                     events_per_step=events // max(1, n_steps),
                     registry=self.registry,
+                    tracer=tracer,
+                    inflight=self._inflight,
                 )
             if first_step_of_run[0]:
                 # this run's first dispatch start was never timestamped
@@ -593,10 +606,10 @@ class StreamingDriver:
                 self.metrics.count_untimed(n_steps, events)
                 self.metrics.step_start()
             else:
-                # latency percentiles time DISPATCHES (n_steps steps
-                # each); totals still count steps and events exactly
+                # the interval percentiles time DISPATCHES (n_steps steps
+                # each); totals still count steps and events exactly.  A
+                # long gap is explained here, at the dispatch that ends it
                 self.metrics.step_end(events, n_steps=n_steps)
-                self.metrics.step_start()
             self.step_idx = global_step
             if self.health is not None:
                 self.health.beat("train")
@@ -731,6 +744,9 @@ class StreamingDriver:
             books.enter_context(
                 compile_ledger.get_ledger().spans_to(self.tracer)
             )
+            # ... and so is a garbage collection (host.gc): a gap between
+            # two dispatches can then name it
+            books.enter_context(self.tracer.gc_spans())
 
         try:
             result = transform_batched(
@@ -747,6 +763,7 @@ class StreamingDriver:
                 tracer=tracer,
                 owns_inputs=True,
                 steps=steps,
+                inflight=self._inflight,
             )
         except BaseException:
             # Leave the driver usable: take back what the last dispatch

@@ -70,7 +70,7 @@ def test_step_metrics():
     snap = m.snapshot()
     assert snap["steps"] == 5 and snap["events"] == 500
     assert snap["updates_per_sec"] > 0
-    assert snap["pull_push_p50_ms"] >= 0
+    assert snap["dispatch_interval_p50_ms"] >= 0
     line = m.emit()
     assert '"updates_per_sec"' in line
 

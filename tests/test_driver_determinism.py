@@ -48,7 +48,7 @@ def test_driver_runs_with_metrics(tmp_path):
     res = d.run(_stream())
     assert d.metrics.total_steps == 20
     snap = d.metrics.snapshot()
-    assert snap["updates_per_sec"] > 0 and snap["pull_push_p50_ms"] > 0
+    assert snap["updates_per_sec"] > 0 and snap["dispatch_interval_p50_ms"] > 0
     ids, vals = res.server_outputs[0]
     assert vals.shape == (96, 4)
 

@@ -369,7 +369,7 @@ def test_metrics_endpoint_live_mid_training(registry, tracer):
     txt = mid_scrapes[0]
     # live counter value: exactly the 20 dispatches completed so far
     assert 'fps_train_steps_total{component="train"} 20' in txt
-    assert "fps_pull_push_latency_seconds_bucket" in txt
+    assert "fps_dispatch_interval_seconds_bucket" in txt
     assert 'fps_serving_requests_total{component="serving"} 1' in txt
     assert "fps_snapshot_staleness_steps" in txt
     assert "fps_ingest_batches_total" in txt
