@@ -794,6 +794,20 @@ class Smoke:
             close(packed._sub_row_slice(rows_p, ids_p, 36), 0.0),
         )
 
+        # the packed push's lane shift, the slice's mirror: 17-lane rows fed
+        # feature-major, a masked lane in every few, against the select arm
+        # over deltas zeroed first, bit for bit
+        deltas_p = rows_p[:, :17]
+        mask_p = jnp.asarray(rng.random(len(rows_p)) < 0.9)
+        self._kernel_case(
+            "packed_lane_shift_d17_f32",
+            lambda dl, i, m: packed.lane_shift_kernel(
+                dl, i, 17, m, block=block_p, interpret=interpret),
+            (deltas_p.T, ids_p, mask_p),
+            close(packed.lane_shift_deltas(
+                jnp.where(mask_p[:, None], deltas_p, 0), ids_p, 17), 0.0),
+        )
+
         # a PACKED rule store at cell 9's row (36 lanes, three to a physical
         # row) inside one jitted step, pull and push: the lane slice, the
         # combine's row kernel and the write-back's row set on the chip,

@@ -348,24 +348,52 @@ def _narrow_pull(table: Array, ids: Array, width: int) -> Array:
 
 
 def _phys_scatter_args(
-    spec: StoreSpec, table: Array, flat_ids: Array, flat_deltas: Array
+    spec: StoreSpec, table: Array, flat_ids: Array, flat_deltas: Array,
+    flat_mask: Optional[Array] = None,
 ):
-    """(ids, deltas) at PHYSICAL granularity for the scatter-add.
+    """(ids, deltas) at PHYSICAL granularity for the scatter-add, the
+    masked lanes' deltas zeros.
 
     Dense: passthrough.  Packed: lane-shift each delta row to its
     sub-row offset and divide ids down to physical rows (the sentinel
     ``padded_capacity`` divides to the out-of-range physical row, so
-    ``mode="drop"`` semantics are preserved)."""
+    ``mode="drop"`` semantics are preserved).  The shift is ``k`` pads
+    under a select or, where :func:`_shift_kernel_takes` says so, ONE
+    Pallas call that reads the deltas feature-major and leaves a masked
+    lane out itself (``ops/packed.lane_shift_kernel``; under a mesh
+    :func:`_packed_shift_on_mesh`): the same rows in the same order, bit
+    for bit."""
+    kernel = _shift_kernel_takes(spec, flat_ids.shape[0])  # packed alone
+    if not kernel:
+        flat_deltas = _zero_masked(flat_deltas, flat_mask)
     if spec.layout != "packed":
         return flat_ids, flat_deltas
-    from ..ops.packed import lane_shift_deltas, packed_phys_ids
+    from ..ops.packed import (
+        lane_shift_deltas, lane_shift_kernel, packed_phys_ids)
 
-    shifted = lane_shift_deltas(
-        flat_deltas.reshape(-1, spec.row_width).astype(table.dtype),
-        flat_ids,
-        spec.row_width,
+    d = spec.row_width
+    deltas = flat_deltas.reshape(-1, d).astype(table.dtype)
+    if not kernel:
+        shifted = lane_shift_deltas(deltas, flat_ids, d)
+    elif spec.mesh is not None:
+        shifted = _packed_shift_on_mesh(spec, deltas.T, flat_ids, flat_mask)
+    else:
+        # feature-major, which is how XLA holds a step's narrow rows on
+        # the TPU: the transpose is a bitcast there
+        shifted = lane_shift_kernel(deltas.T, flat_ids, d, flat_mask)
+    return packed_phys_ids(flat_ids, d), shifted
+
+
+def _zero_masked(flat_deltas: Array, flat_mask: Optional[Array]) -> Array:
+    """Masked-out lanes keep their id but carry a zero delta: for the add
+    path zero deltas are a no-op; a rule's count is masked besides."""
+    if flat_mask is None:
+        return flat_deltas
+    return jnp.where(
+        flat_mask.reshape((-1,) + (1,) * (flat_deltas.ndim - 1)),
+        flat_deltas,
+        jnp.zeros_like(flat_deltas),
     )
-    return packed_phys_ids(flat_ids, spec.row_width), shifted
 
 
 def push(
@@ -465,20 +493,11 @@ def push_counted(
     # route them to an always-out-of-bounds sentinel so they drop too.
     flat_ids = jnp.where(flat_ids < 0, spec.padded_capacity, flat_ids)
     flat_deltas = deltas.reshape((-1,) + spec.value_shape)
-    if mask is not None:
-        flat_mask = mask.reshape(-1)
-        # Masked-out lanes keep their id but carry a zero delta: for the
-        # fast add path zero deltas are a no-op; for the generic path the
-        # count is also masked.
-        flat_deltas = jnp.where(
-            flat_mask.reshape((-1,) + (1,) * len(spec.value_shape)),
-            flat_deltas,
-            jnp.zeros_like(flat_deltas),
-        )
+    flat_mask = None if mask is None else mask.reshape(-1)
 
     if spec.update == "add":
         s_ids, s_deltas = _phys_scatter_args(
-            spec, table, flat_ids, flat_deltas
+            spec, table, flat_ids, flat_deltas, flat_mask
         )
         if _tile_kernel_takes(spec, s_ids.shape[0]):
             from ..ops.row_update import scatter_add_counted
@@ -497,8 +516,9 @@ def push_counted(
             None,
         )
 
-    live = flat_mask if mask is not None else None
-    return _push_rule(spec, table, flat_ids, flat_deltas, live)
+    return _push_rule(
+        spec, table, flat_ids, _zero_masked(flat_deltas, flat_mask), flat_mask
+    )
 
 
 # Lanes a step of `_push_rule`'s loop over the batch's distinct rows.
@@ -924,28 +944,47 @@ def _slice_kernel_takes(spec: StoreSpec, n: Optional[int] = None) -> bool:
     arm, counted and warned of once a row shape.  ``n`` None asks whether
     ANY pull of the store may take the kernel
     (:func:`_preload_tile_kernel`) and notes nothing."""
+    if n is not None and spec.mesh is not None:
+        # a shard slices the lanes of its worker (`_packed_pull_on_shards`)
+        workers = spec.mesh.size // spec.num_shards
+        n = n // workers if n % workers == 0 else n
+    return _lane_kernel_takes(spec, n, "the lane slice of a packed pull")
+
+
+def _lane_kernel_takes(spec: StoreSpec, n: Optional[int], what: str) -> bool:
+    """What :func:`_slice_kernel_takes` and :func:`_shift_kernel_takes`
+    both ask of the spec and of the ``n`` lanes a chip moves:
+    ``ops/packed``'s two kernels move the same rows, one way each."""
     from ..ops import packed
 
     if (jax.default_backend() != "tpu" or spec.pack == 1
             or (spec.mesh is not None and spec.num_shards == 1)):
         return False
-    if n is None:
-        return packed.slice_refusal(
-            packed.SLICE_BLOCK, spec.dtype, spec.row_width) is None
-    if spec.mesh is not None:
-        # a shard slices the lanes of its worker (`_packed_pull_on_shards`)
-        workers = spec.mesh.size // spec.num_shards
-        n = n // workers if n % workers == 0 else n
-    return _taken_or_noted(
-        spec, "the lane slice of a packed pull",
-        packed.slice_refusal(n, spec.dtype, spec.row_width),
-    )
+    why = packed.slice_refusal(
+        packed.SLICE_BLOCK if n is None else n, spec.dtype, spec.row_width)
+    return why is None if n is None else _taken_or_noted(spec, what, why)
+
+
+def _shift_kernel_takes(spec: StoreSpec, n: Optional[int] = None) -> bool:
+    """Whether the packed push of ``n`` lanes shifts its deltas to their
+    windows through ``ops/packed.lane_shift_kernel`` instead of XLA's pads
+    and selects, read from what the spec and the batch hold: an ``add``
+    store (a rule store's write-back shifts 32,768 rows a chunk and merges
+    them there: :func:`_rewrite_packed`) where the pull's slice, the
+    kernel's mirror, would take as many ids (:func:`_slice_kernel_takes`: a
+    TPU, rows packed several to a physical row, float32, no mesh or a table
+    sharded over it) and the batch has a block or more of lanes.  Static
+    per compiled step; a refusal (bfloat16, a short eager push) keeps the
+    selects, counted and warned of once a row shape."""
+    return spec.update == "add" and _lane_kernel_takes(
+        spec, n, "the lane shift of a packed push")
 
 
 def _preload_tile_kernel(spec: StoreSpec) -> None:
     """Where a store is made whose pushes or pulls will trace a kernel: have
     Pallas imported by then, beside the table's staging (the import is ~1 s
-    that the first trace of the step else pays)."""
+    that the first trace of the step else pays).  The push's shift kernel
+    is taken only where the pull's slice is."""
     if (_tile_kernel_takes(spec) or _set_kernel_takes(spec)
             or _combine_kernel_takes(spec) or _slice_kernel_takes(spec)):
         from ..ops.row_update import preload
@@ -998,6 +1037,28 @@ def _packed_pull_on_shards(
         out_specs=P(ps, others or None, None),
         check_vma=not kernel,  # a Pallas call states no varying axes
     )(table, ids).sum(axis=0)
+
+
+def _packed_shift_on_mesh(
+    spec: StoreSpec, by_lane: Array, ids: Array, live: Optional[Array]
+) -> Array:
+    """``ops/packed.lane_shift_kernel`` of a batch pushed into a table
+    sharded over ``ps``: Mosaic's call cannot be partitioned, so it runs
+    inside a ``shard_map`` over the whole mesh with lanes, ids and mask
+    replicated, every chip shifting ALL the lanes, as GSPMD has each chip do
+    with the selects.  The scatter-add that takes the rows stays the
+    partitioner's, on the operand it had: rows split over ``dp`` here would
+    have it sum a row's deltas worker by worker, in another order."""
+    from ..ops.packed import lane_shift_kernel
+
+    masks = () if live is None else (live,)
+    return jax.shard_map(
+        lambda d, i, *m: lane_shift_kernel(d, i, spec.row_width, *m),
+        mesh=spec.mesh,
+        in_specs=(P(),) * (2 + len(masks)),
+        out_specs=P(),
+        check_vma=False,  # a Pallas call states no varying axes
+    )(by_lane, ids, *masks)
 
 
 def _pad_rows(spec: StoreSpec, pad: int) -> Callable[[Array], Array]:

@@ -342,6 +342,10 @@ def make_train_step(logic: BatchedWorkerLogic, spec) -> Callable:
             # which arm sliced the pulled rows, as this trace read it
             took = store_mod._slice_kernel_takes(spec, ids.size)
             out = {**out, "ps_slice_kernel": jnp.asarray(took, jnp.int32)}
+            if spec.update == "add":
+                # ... and which arm shifted the pushed deltas to their lanes
+                took = store_mod._shift_kernel_takes(spec, req.ids.size)
+                out = {**out, "ps_shift_kernel": jnp.asarray(took, jnp.int32)}
         return table, state, out
 
     return step

@@ -20,21 +20,23 @@ row.  Logical row ``r`` maps to physical row ``r // k``, lane offset
     in a Pallas kernel that writes the rows feature-major
     (:func:`sub_row_slice_kernel`),
   * **push** = lane-shift each delta row to its offset (``k`` static pads
-    and the same ``select``), then scatter-add at PHYSICAL row
-    granularity — which is exactly the shape the pallas kernels want
-    (width 128).
+    and the same ``select``: :func:`lane_shift_deltas`; for a batch of a
+    block or more on a TPU the slice's mirror kernel, which reads the
+    deltas feature-major: :func:`lane_shift_kernel`), then scatter-add at
+    PHYSICAL row granularity — which is exactly the shape the pallas
+    kernels want (width 128).
     Two logical rows sharing a physical row collide in different lanes,
     so the add semantics are unchanged, and Zipf-hot neighbours now
     share windows (fewer HBM round trips, fuller DMAs).
 
-The push side is pure XLA and the scatter kernels consume the packed form
-unmodified; the pull's lane slice has the one kernel of this module.
-``ShardedParamStore(layout="packed")`` wires it in.  A store whose update
-is a RULE packs too (rule rows of 9 to 64 lanes by default, DiFacto's 36
-three to a row): its push reads whole physical rows, slices each touched
-logical row out, runs the rule and writes each touched physical row back
-once, the new rows shifted to their windows and merged by selects
-(``core/store._rewrite_packed``).
+The scatter kernels consume the packed form unmodified; this module's two
+kernels are the pull's lane slice and the push's lane shift, each the
+other's mirror.  ``ShardedParamStore(layout="packed")`` wires it in.  A
+store whose update is a RULE packs too (rule rows of 9 to 64 lanes by
+default, DiFacto's 36 three to a row): its push reads whole physical rows,
+slices each touched logical row out, runs the rule and writes each touched
+physical row back once, the new rows shifted to their windows and merged by
+selects (``core/store._rewrite_packed``).
 
 **The lane slice as a kernel** (``core/store._slice_kernel_takes``).  XLA
 compiles :func:`_sub_row_slice` row-major: ``k`` lane rotates and selects
@@ -50,6 +52,18 @@ gathered rows once, transposes it in VMEM to ``(128, block)``, where the
 ``(d, n)`` output, whose transpose, a bitcast, is the ``(n, d)`` result
 held feature-major.  Selects only, so the same bits as
 :func:`_sub_row_slice`, NaN, infinities and -0.0 included.
+
+**The lane shift as a kernel** (``core/store._shift_kernel_takes``).  XLA
+compiles :func:`lane_shift_deltas` column-major over the ``(n, 128)``
+buffer and then relays it to the row-major rows the scatter-add reads (FM:
+2.23 + 1.99 ms a step on the v5e for 87 MB in and 654 MB out, and 0.38 for
+the mask in front; PERF.md section 6, PR 51).  :func:`lane_shift_kernel` is
+the slice run backwards: it reads a ``(d, block)`` block of the deltas
+FEATURE-major, where the step's logic leaves them, lays ``k`` copies of it
+down the 128 sublanes (pad sublanes zeros), keeps in every lane the one
+window ``ids % k`` names (none for a masked lane, which rides in as -1),
+transposes in VMEM and writes a ``(block, 128)`` block of the row-major
+``(n, 128)`` rows.  Selects against zeros only, never a 0/1 product.
 """
 from __future__ import annotations
 
@@ -236,6 +250,61 @@ def lane_shift_deltas(deltas: Array, ids: Array, row_width: int) -> Array:
     return out
 
 
+def _shift_kernel(t_ref, deltas_ref, out_ref, *, k: int, d: int):
+    by_lane = deltas_ref[...]  # (d, block): a row's lanes are d sublanes
+    block = by_lane.shape[1]
+    # every window holds the row, the pad lanes zeros ...
+    pad = [jnp.zeros((LANES - k * d, block), by_lane.dtype)] * (k * d < LANES)
+    windows = jnp.concatenate([by_lane] * k + pad, axis=0)
+    # ... and a lane keeps the window its id names: sublanes [t d, (t + 1) d),
+    # none for t = -1
+    first = t_ref[...] * d
+    sublane = jax.lax.broadcasted_iota(jnp.int32, (LANES, block), 0)
+    keep = (sublane >= first) & (sublane < first + d)
+    out_ref[...] = jnp.where(keep, windows, jnp.zeros_like(windows)).T
+
+
+def lane_shift_kernel(
+    by_lane: Array, ids: Array, row_width: int, mask: Optional[Array] = None,
+    *, block: Optional[int] = None, interpret: Optional[bool] = None,
+) -> Array:
+    """:func:`lane_shift_deltas` as one Pallas kernel (the module docstring
+    says how), bit for bit, for rows packed several to a physical row.  It
+    takes the deltas FEATURE-major, ``by_lane`` ``(d, n)``: the transpose
+    of the ``(n, d)`` deltas, a bitcast where XLA holds them so.  A lane
+    that ``mask`` (``(n,)`` bool) leaves out comes back a row of +0.0: the
+    bits of ``lane_shift_deltas(jnp.where(mask[:, None], deltas, 0), ...)``,
+    with no pass over the deltas for it.  ``n`` need not be whole blocks;
+    off the TPU the kernel is interpreted, as :func:`sub_row_slice_kernel`
+    is."""
+    from .row_update import _pallas
+
+    pl, pltpu = _pallas()
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    (d, n), k = by_lane.shape, pack_k(row_width)
+    assert d == row_width and k > 1, (d, row_width)
+    block = SLICE_BLOCK if block is None else block
+    t = ids.astype(jnp.int32) % k
+    if mask is not None:
+        t = jnp.where(mask, t, -1)
+    return pl.pallas_call(
+        functools.partial(_shift_kernel, k=k, d=d),
+        out_shape=jax.ShapeDtypeStruct((n, LANES), by_lane.dtype),
+        grid=(pl.cdiv(n, block),),
+        in_specs=[
+            pl.BlockSpec((1, block), lambda i: (0, i)),
+            pl.BlockSpec((d, block), lambda i: (0, i)),
+        ],
+        out_specs=pl.BlockSpec((block, LANES), lambda i: (i, 0)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)
+        ),
+        interpret=interpret,
+        name="packed_lane_shift",
+    )(t.reshape(1, n), by_lane)
+
+
 def lane_unshift(rows: Array, ids: Array, row_width: int) -> Array:
     """Inverse of :func:`lane_shift_deltas`: slice each (phys_width,)
     row back down to the (row_width,) slice at its id's lane offset."""
@@ -259,6 +328,7 @@ __all__ = [
     "sub_row_slice",
     "sub_row_slice_kernel",
     "lane_shift_deltas",
+    "lane_shift_kernel",
     "lane_unshift",
     "packed_phys_ids",
 ]

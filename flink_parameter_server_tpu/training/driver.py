@@ -387,7 +387,8 @@ class StreamingDriver:
         records the last dispatch dropped because they reached the wrong worker:
         ``keyed_misrouted``, 0 behind the router) and a store packed several
         rows to a physical row (whether the step's pull took the slice
-        kernel: ``store_packed_slice_kernel``).  A fetch of a few scalars, made only where the
+        kernel, and an ``add`` push the shift kernel:
+        ``store_packed_slice_kernel``, ``store_packed_shift_kernel``).  A fetch of a few scalars, made only where the
         outputs are fetched anyway: at the metrics cadence, which syncs the
         step, and once after the loop has ended."""
         if self.registry is None or not isinstance(outs, dict):
@@ -438,6 +439,12 @@ class StreamingDriver:
             self.registry.gauge(
                 "store_packed_slice_kernel", component="train"
             ).set(float(np.max(np.asarray(outs["ps_slice_kernel"]))))
+        if "ps_shift_kernel" in outs:
+            # such a store whose update is "add": whether the step's push
+            # shifted its deltas to their lanes in ops/packed's other kernel
+            self.registry.gauge(
+                "store_packed_shift_kernel", component="train"
+            ).set(float(np.max(np.asarray(outs["ps_shift_kernel"]))))
         if "ps_push_tile_rows" in outs:
             # an add store whose push the tile kernel took: the lanes it
             # kept and the tile rows it read and wrote for them

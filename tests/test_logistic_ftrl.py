@@ -279,16 +279,17 @@ def test_the_driver_publishes_what_the_push_counted():
     assert gauges["store_rule_tiles"][0]["value"] == 0  # XLA wrote the rows
     assert np.isfinite(np.asarray(result.store.values())).all()
     # an add store's step counts nothing: its outputs gain no `ps_rule_*`
-    # key (a packed one's names the arm that sliced its pulled rows: here,
-    # off the TPU, XLA's selects)
+    # key (a packed one's names the arms that sliced its pulled rows and
+    # shifted its pushed ones: here, off the TPU, XLA's selects)
     from flink_parameter_server_tpu.models import factorization_machine as fmm
 
     fm = fmm.FMConfig(num_features=200, dim=4)
     table, _, out = jax.jit(make_train_step(
         fmm.FactorizationMachine(fm), fmm.make_store(fm).spec
     ))(fmm.make_store(fm).table, (), batches[0])
-    assert set(out) == {"prediction", "loss", "ps_slice_kernel"}
-    assert int(out["ps_slice_kernel"]) == 0
+    assert set(out) == {
+        "prediction", "loss", "ps_slice_kernel", "ps_shift_kernel"}
+    assert int(out["ps_slice_kernel"]) == int(out["ps_shift_kernel"]) == 0
 
 
 @pytest.mark.parametrize("arm", ["xla", "set_kernel"])

@@ -20,6 +20,7 @@ from flink_parameter_server_tpu.ops import packed as packed_mod
 from flink_parameter_server_tpu.ops.packed import (
     _sub_row_slice,
     lane_shift_deltas,
+    lane_shift_kernel,
     lane_unshift,
     pack_k,
     pack_table,
@@ -149,6 +150,43 @@ def test_the_slice_kernel_equals_the_select_arm_bit_for_bit(d, n):
         np.asarray(_slice_by_gather(rows, ids, d)).view(np.uint32))
 
 
+# n against a block of 256 lanes: whole blocks, and a last block that is cut
+# (to whole 128-lane groups; to a ragged 44 lanes; after several blocks)
+@pytest.mark.parametrize("masked", [False, True], ids=["all_live", "masked"])
+@pytest.mark.parametrize("n", [256, 256 + 128, 256 + 44, 256 * 5 + 129])
+@pytest.mark.parametrize("d", [1, 16, 17, 36, 63, 64])
+def test_the_shift_kernel_equals_the_select_arm_bit_for_bit(d, n, masked):
+    """``lane_shift_kernel`` (interpreted here, fed the deltas' transpose)
+    against ``lane_shift_deltas``: the same bits at every width packed
+    several rows to a physical row, NaN, infinities and -0.0 included,
+    zeros (+0.0) in every lane outside the id's window, the pad lanes among
+    them, whatever ``n`` leaves of the last block; a masked lane is a row of +0.0, the bits
+    of the selects over deltas zeroed first, whatever it held; and
+    ``lane_unshift`` gives the deltas back."""
+    rng = np.random.default_rng([d, n])
+    ids = jnp.asarray(rng.integers(0, 10 ** 6, n).astype(np.int32))
+    deltas = rng.normal(0, 1, (n, d)).astype(np.float32)
+    for bad in (np.nan, np.inf, -np.inf, -0.0):
+        deltas[rng.integers(0, n, n // 4), rng.integers(0, d, n // 4)] = bad
+    deltas = jnp.asarray(deltas)
+    mask = jnp.asarray(rng.random(n) < 0.7) if masked else None
+
+    def bits(x):
+        return np.asarray(x).view(np.uint32)
+
+    got = lane_shift_kernel(deltas.T, ids, d, mask, block=256)
+    kept = deltas if mask is None else jnp.where(mask[:, None], deltas, 0)
+    want = lane_shift_deltas(kept, ids, d)
+    assert got.shape == want.shape == (n, 128) and got.dtype == want.dtype
+    np.testing.assert_array_equal(bits(got), bits(want))
+    assert np.isnan(np.asarray(want)).any()
+    np.testing.assert_array_equal(
+        bits(want), bits(_shift_by_gather(kept, ids, d)))
+    np.testing.assert_array_equal(bits(lane_unshift(got, ids, d)), bits(kept))
+    if masked:  # rows of +0.0, not of what the lane held times zero
+        assert not bits(got)[~np.asarray(mask)].any()
+
+
 @pytest.mark.parametrize("n,kernel", [(255, False), (256, True), (300, True)])
 def test_a_pull_under_one_block_keeps_the_select_arm(n, kernel, monkeypatch):
     """The arm is read from the backend, the dtype, ``k`` and ``n``
@@ -190,16 +228,19 @@ def test_a_pull_under_one_block_keeps_the_select_arm(n, kernel, monkeypatch):
 def test_the_driver_says_which_arm_sliced_its_pulled_rows(arm, monkeypatch):
     """Where an operator reads it: the gauge ``store_packed_slice_kernel``,
     from the scalar the step of a packed store carries among its outputs
-    (``ps_slice_kernel``: what its trace read).  A dense store's driver has
-    no such gauge."""
+    (``ps_slice_kernel``: what its trace read), and beside it, for an
+    ``add`` store, ``store_packed_shift_kernel`` (``ps_shift_kernel``: the
+    push's arm).  A dense store's driver has neither gauge."""
     from flink_parameter_server_tpu.models import factorization_machine as fmm
     from flink_parameter_server_tpu.telemetry.registry import MetricsRegistry
     from flink_parameter_server_tpu.training.driver import (
         DriverConfig, StreamingDriver)
 
-    if arm == "kernel":  # steered: this is a CPU, the kernel is interpreted
+    if arm == "kernel":  # steered: this is a CPU, the kernels are interpreted
         monkeypatch.setattr(
             store_mod, "_slice_kernel_takes", lambda spec, n=None: True)
+        monkeypatch.setattr(
+            store_mod, "_shift_kernel_takes", lambda spec, n=None: True)
         monkeypatch.setattr(packed_mod, "SLICE_BLOCK", 64)
         packed_mod.packed_pull.clear_cache()
     cfg = fmm.FMConfig(num_features=500, dim=16)
@@ -223,7 +264,8 @@ def test_the_driver_says_which_arm_sliced_its_pulled_rows(arm, monkeypatch):
     packed_mod.packed_pull.clear_cache()
     assert seen == {
         "auto": {"store_layout_packed": 1.0,
-                 "store_packed_slice_kernel": float(arm == "kernel")},
+                 "store_packed_slice_kernel": float(arm == "kernel"),
+                 "store_packed_shift_kernel": float(arm == "kernel")},
         "dense": {"store_layout_packed": 0.0},
     }
 

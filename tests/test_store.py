@@ -686,6 +686,132 @@ def test_the_slice_arm_says_no(
         assert store_mod._slice_kernel_takes(spec, 4095)
 
 
+# The push's mirror of that arm: on a TPU a float32 ``add`` push of a block or
+# more of lanes into a store packed several rows to a physical row shifts its
+# deltas to their windows in ``ops/packed``'s other kernel, which reads them
+# feature-major and leaves a masked lane out itself; everywhere else in XLA's
+# pads and selects.  Steered and interpreted here, at a block the case table's
+# batches fill: the same table as the select arm's, bit for bit.
+@pytest.mark.parametrize("traffic", TRAFFIC)
+@pytest.mark.parametrize("width", [1, 4, 17, 64])
+@pytest.mark.parametrize("shards", ["one_shard", "dp_x_ps"])
+def test_push_pull_case_table_shift_kernel_arm(
+        shards, width, traffic, mesh, monkeypatch):
+    from flink_parameter_server_tpu.core import store as store_mod
+    from flink_parameter_server_tpu.ops import packed
+
+    rng = np.random.default_rng([width, TRAFFIC.index(traffic)])
+    values = _init_values(CAP, (width,))
+    store = ShardedParamStore.from_values(
+        jnp.asarray(values), layout="packed",
+        mesh=mesh if shards == "dp_x_ps" else None)
+    ids, deltas, mask = _traffic(traffic, rng, CAP, (width,))
+    args = (jnp.asarray(ids), jnp.asarray(deltas),
+            None if mask is None else jnp.asarray(mask))
+    # not `_push`: a program traced for one arm would be reused by the other
+    selects = jax.jit(lambda st, i, d, m: st.push(i, d, m))(store, *args)
+    assert not store_mod._shift_kernel_takes(store.spec, 4096)  # a CPU
+    monkeypatch.setattr(
+        store_mod, "_shift_kernel_takes", lambda spec, n=None: True)
+    monkeypatch.setattr(packed, "SLICE_BLOCK", 32)
+    calls = []
+    real = packed.lane_shift_kernel
+    monkeypatch.setattr(
+        packed, "lane_shift_kernel",
+        lambda by_lane, ids, d, mask=None: calls.append(
+            (by_lane.shape, mask is not None)) or real(by_lane, ids, d, mask))
+    push = jax.jit(lambda st, i, d, m: st.push(i, d, m))
+    _check_push_pull(store, values, ids, deltas, mask, push=push)
+    # every chip shifts all the lanes, whatever the mesh; the mask rides
+    # into the kernel where there is one
+    assert calls == [((width, ids.size), mask is not None)], calls
+    np.testing.assert_array_equal(
+        np.asarray(push(store, *args).table).view(np.uint32),
+        np.asarray(selects.table).view(np.uint32))
+
+
+@pytest.mark.parametrize("why,update,shape,dtype,mesh_shape,n,noted", [
+    ("off_the_tpu", "add", (17,), jnp.float32, None, 4096, False),
+    ("bfloat16", "add", (17,), jnp.bfloat16, None, 4096, True),
+    ("pack_is_1", "add", (100,), jnp.float32, None, 4096, False),
+    ("a_rule_store", "rule", (36,), jnp.float32, None, 4096, False),
+    ("under_a_block", "add", (17,), jnp.float32, None, 2047, True),
+    ("one_shard_mesh", "add", (17,), jnp.float32, (4, 1), 4096, False),
+])
+def test_the_shift_arm_says_no(
+        why, update, shape, dtype, mesh_shape, n, noted, mesh_devices,
+        monkeypatch):
+    """What keeps ``lane_shift_deltas``' selects: the CPU; bfloat16 rows and
+    a push of under a block of lanes (noted and counted, as the slice's
+    refusals are); rows that lie one to a physical row, which have nothing
+    to shift; a store whose update is a rule (its write-back shifts and
+    merges a chunk at a time: ``_rewrite_packed``), with no note; a mesh
+    that does not shard the table."""
+    from flink_parameter_server_tpu.core import store as store_mod
+    from flink_parameter_server_tpu.ops import packed, row_update
+    from flink_parameter_server_tpu.parallel.mesh import make_mesh
+
+    mesh = None
+    if mesh_shape is not None:
+        mesh = make_mesh(*mesh_shape, devices=mesh_devices[
+            :mesh_shape[0] * mesh_shape[1]])
+    rule = "add" if update == "add" else (lambda row, delta: row + delta)
+    spec = jax.eval_shape(lambda: ShardedParamStore.create(
+        1000, shape, dtype=dtype, layout="packed", mesh=mesh,
+        update=rule)).spec
+    monkeypatch.setattr(store_mod, "_REFUSALS_NOTED", set())
+    monkeypatch.setattr(packed, "SLICE_BLOCK", 2048)
+    n0 = row_update.refusal_count()
+    if why != "off_the_tpu":
+        assert not store_mod._shift_kernel_takes(spec, n)
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    if noted:
+        with pytest.warns(RuntimeWarning, match="lane shift of a packed push"):
+            assert not store_mod._shift_kernel_takes(spec, n)
+    assert not store_mod._shift_kernel_takes(spec, n)
+    assert row_update.refusal_count() == n0 + noted
+    if why == "off_the_tpu":
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        assert store_mod._shift_kernel_takes(spec, n)
+        assert row_update.refusal_count() == n0
+    if why == "a_rule_store":  # its PULL takes the slice kernel all the same
+        assert store_mod._slice_kernel_takes(spec, n)
+
+
+@pytest.mark.parametrize("cell", ["cell_2", "cell_4", "cell_10"])
+def test_the_shift_arm_takes_the_criteo_add_cells(
+        cell, mesh_devices, monkeypatch):
+    """The three benchmark cells whose store is an ``add`` store packed
+    several rows to a physical row, at their own sizes (nothing allocated):
+    on a TPU each pushes its whole batch through the kernel, and a step of
+    theirs says so among its outputs; off it none does."""
+    from flink_parameter_server_tpu.core import store as store_mod
+    from flink_parameter_server_tpu.models import dlrm
+    from flink_parameter_server_tpu.models import factorization_machine as fmm
+    from flink_parameter_server_tpu.parallel.mesh import make_mesh
+
+    if cell == "cell_10":
+        from chipbench import spec as bench_spec
+
+        cfg = bench_spec.load_json("chipbench/configs/dlrm-criteo-10m.json")
+        model = dlrm.DLRMConfig(tuple(cfg["field_cardinalities"]))
+        spec = jax.eval_shape(
+            lambda: dlrm.make_store(model, dtype=jnp.float32)).spec
+        lanes, pack = 32_768 * 26, 2
+    else:
+        rows, mesh = 49_126_310, None
+        if cell == "cell_4":
+            rows, mesh = 187_767_412, make_mesh(1, 4, devices=mesh_devices[:4])
+        spec = jax.eval_shape(lambda: fmm.make_store(
+            fmm.FMConfig(num_features=rows, dim=16), mesh=mesh,
+            dtype=jnp.float32)).spec
+        lanes, pack = 32_768 * 39, 7
+    assert (spec.layout, spec.pack, spec.update) == ("packed", pack, "add")
+    assert not store_mod._shift_kernel_takes(spec, lanes)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert store_mod._shift_kernel_takes(spec, lanes)
+
+
 @pytest.mark.parametrize("layout", ["dense", "packed"])
 def test_push_pull_case_table_int32_exact_past_2_24(layout):
     """Counts are summed as integers: a float32 detour drops increments

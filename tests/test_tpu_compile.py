@@ -528,7 +528,22 @@ def test_fm_step_on_a_tpu_slices_its_pulled_rows_in_the_kernel(
     section 6, PR 42).  The kernel's ``f32[17,1277952]`` reaches the flatten
     through a bitcast (cell 4: through the ownership mask's select, in that
     form); the one all-reduce keeps its operand, the table is updated in
-    place and the temporaries stay where the select arm's were."""
+    place and the temporaries stay where the select arm's were.
+
+    Since PR 51 ``push`` hands the deltas to the kernel's mirror
+    (``ops/packed.lane_shift_kernel``; on four chips inside
+    ``_packed_shift_on_mesh``'s ``shard_map``): exactly one
+    ``packed_lane_shift`` call under ``ps.push``, its ``f32[17,1277952]``
+    operand two bitcasts of the flatten loop's ``f32[1,17,1277952]`` and no
+    other op (no new ``copy``, no new loop; XLA wraps the two in a fusion
+    that is one pass over 87 MB on the chip, 0.38 ms, what the mask's
+    select over the same buffer was), its ``f32[1277952,128]`` result the
+    scatter-add fusion's own operand, and no select fusion or ``copy`` of
+    ``f32[1277952,128]`` left: the parent's ``select_select_fusion`` and
+    ``copy.34`` (2.23 + 1.99 ms a step), and the mask's
+    ``broadcast_select_fusion f32[1277952,17]`` with them: the mask rides
+    in as a select on ``s32[1,1277952]``.  The shifted rows and their
+    relayout were the step's two largest temporaries."""
     # code that asks for the backend still sees the CPU here: steer it
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     if cell == "cell_2":
@@ -541,16 +556,48 @@ def test_fm_step_on_a_tpu_slices_its_pulled_rows_in_the_kernel(
         batch = _fm_batch(NamedSharding(mesh, PartitionSpec()))
     n0 = row_update.refusal_count()
     assert store_mod._slice_kernel_takes(spec, FM_BATCH * FM_FIELDS)
+    assert store_mod._shift_kernel_takes(spec, FM_BATCH * FM_FIELDS)
     compiled = jax.jit(
         make_train_step(logic, spec), donate_argnums=(0, 1)
     ).lower(table, (), batch).compile()
     assert row_update.refusal_count() == n0
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes > 3.43 * GB  # in place
-    assert mem.temp_size_in_bytes < 1.4 * GB  # 1.322 / 1.321 here
+    # 0.778 / 0.789 here (the parent's 1.322 / 1.321 under its 1.4 bound)
+    assert mem.temp_size_in_bytes < 0.9 * GB
     text = compiled.as_text()
     entry = text[text.index("ENTRY"):]
     n = FM_BATCH * FM_FIELDS
+    kernels = [
+        line for line in text.splitlines() if "tpu_custom_call" in line
+    ]
+    assert len(kernels) == 2, kernels
+    shifts = [line for line in kernels if "packed_lane_shift" in line]
+    assert len(shifts) == 1 and shifts[0] in entry, shifts
+    assert f" = f32[{n},128]{{1,0:" in shifts[0], shifts[0]
+    assert 'op_name="jit(step)/ps.push/' in shifts[0]
+    # its deltas: the flatten loop's result through bitcasts alone
+    fed = re.search(r"custom-call\(%[\w.\-]+, %([\w.\-]+)\)", shifts[0])[1]
+    feeder = next(
+        line for line in entry.splitlines() if f" %{fed} = " in line)
+    assert f" = f32[17,{n}]{{1,0:" in feeder, feeder
+    if " bitcast(" not in feeder:
+        body = text[text.index(
+            "%" + re.search(r"calls=%([\w.\-]+)", feeder)[1] + " ("):]
+        body = body[:body.index("\n}")].splitlines()[1:]
+        assert all(
+            " parameter(" in op or " bitcast(" in op for op in body), body
+    # its rows: the scatter-add's own operand, no relayout between
+    name = re.search(r"%(packed_lane_shift[\w.\-]*) = ", shifts[0])[1]
+    users = [line for line in entry.splitlines()
+             if re.search(rf"%{re.escape(name)}[,)]", line)]
+    assert len(users) == 1 and "/ps.push/scatter-add" in users[0], users
+    assert f" = f32[{spec.rows_per_shard},128]" in users[0]
+    wide = [line for line in entry.splitlines()
+            if re.search(rf" = f32\[{n},128\]\S* (copy|fusion)\(", line)]
+    # the pull's gather of physical rows is the one fusion that wide
+    assert len(wide) == 1 and "/ps.pull/" in wide[0], wide
+    assert not re.findall(rf" = f32\[{n},17\]\S* fusion\(", entry)
     calls = [
         line for line in entry.splitlines()
         if "tpu_custom_call" in line and "packed_lane_slice" in line
@@ -880,8 +927,8 @@ def _step_text_sha(step, *args):
 
 @pytest.mark.parametrize("cell, want", [
     ("mf_cells_1_and_3", "467449ddc73eac39"),
-    ("fm_cell_2", "62cb492a1f6ca10e"),
-    ("fm_ps4_cell_4", "0d16cc6091cddad1"),
+    ("fm_cell_2", "bc06381bf02bcde5"),
+    ("fm_ps4_cell_4", "db02bf3de22f8a3e"),
     ("lr_cell_6", "aab60546ac40ed64"),
     ("keyed_mf_cell_8", "47d256f2590f4bc0"),
 ])
@@ -898,7 +945,10 @@ def test_the_mf_and_fm_cells_step_text_is_the_parents(cell, want, request):
     counts and its outputs are what they were, no new count among them) and
     cell 8 (MF under four keyed workers), each as PR 46's parent lowers it
     here: that PR changed the combine of WIDE rule rows, which none of the
-    five traces."""
+    five traces.  PR 51 gave the two FM steps one scalar more,
+    ``ps_shift_kernel`` (which arm shifted the pushed deltas: here, off the
+    TPU, a constant 0 and the parent's ops to the letter; ``62cb492a1f6ca10e``
+    and ``0d16cc6091cddad1`` until then); the other three did not move."""
     shape = jax.ShapeDtypeStruct
 
     def mf_batch(n, on=shape):
@@ -1418,7 +1468,14 @@ def test_dlrm_step_holds_nothing_table_sized_beside_its_12_58_gb_table(
     never copied; under ``ps.pull`` ONE gather of whole physical rows and the
     lane slice kernel at two rows a register, handing the logic
     ``f32[64,851968]``; the dense net's scopes on its products; 1.8 GB of
-    temporaries, so table, step and the pool stay under the chip's 16 GB."""
+    temporaries, so table, step and the pool stay under the chip's 16 GB.
+    Since PR 51 the push's lane shift is the slice's mirror kernel here
+    too, ONE ``packed_lane_shift`` call whose rows the nine permutes read
+    as they lie: the logic builds its deltas ROW-major (``f32[32768,26,64]``),
+    so the flatten to ``f32[851968,64]`` stays and one ``copy`` turns them
+    feature-major for the kernel, in place of the mask's select over them
+    and the pads under a select (1.33 + 1.92 ms a step on the v5e for
+    0.95 + 1.01: PERF.md section 6, PR 51)."""
     cfg, model, dlrm = dlrm_cell
     spec = jax.eval_shape(lambda: dlrm.make_store(model, dtype=jnp.float32)).spec
     assert (spec.layout, spec.pack, spec.update) == ("packed", 2, "add")
@@ -1427,6 +1484,7 @@ def test_dlrm_step_holds_nothing_table_sized_beside_its_12_58_gb_table(
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     assert store_mod._slice_kernel_takes(spec, n)
     assert store_mod._tile_kernel_takes(spec, n)  # under the compiler's cut
+    assert store_mod._shift_kernel_takes(spec, n)
     assert n * store_mod._SERIAL_SCATTER_ROWS_A_LANE < DLRM_PHYS_ROWS
     logic = dlrm.DLRM(model)
     state = {
@@ -1458,9 +1516,21 @@ def test_dlrm_step_holds_nothing_table_sized_beside_its_12_58_gb_table(
     slices = [k for k in kernels if "packed_lane_slice" in k]
     assert len(slices) == 1 and f" = f32[64,{n}]{{1,0:" in slices[0]
     adds = [k for k in kernels if "sorted_row_update_tiles" in k]
-    assert len(adds) == 9 == -(-n // row_update.MAX_LANES) and len(kernels) == 10
+    assert len(adds) == 9 == -(-n // row_update.MAX_LANES) and len(kernels) == 11
     for call in adds:
         assert " = f32[24563152,128]{1,0" in call and "ps.push/" in call
+    shifts = [k for k in kernels if "packed_lane_shift" in k]
+    assert len(shifts) == 1 and f" = f32[{n},128]{{1,0:" in shifts[0]
+    assert 'op_name="jit(step)/ps.push/' in shifts[0]
+    name = re.search(r"%(packed_lane_shift[\w.\-]*) = ", shifts[0])[1]
+    users = [c for c in lines if re.search(rf"%{re.escape(name)}[,)]", c)]
+    assert len(users) == 9 and all(  # the permutes, no relayout between
+        " = f32[94720,128]" in c and "ps.push/jit(_take)/gather" in c
+        for c in users), users
+    # the gather's fusion and the nine permutes' are the fusions that wide
+    assert not re.search(rf" = f32\[{n},128\]\S* (copy|pad)\(", text)
+    assert "pad_select_fusion" not in text
+    assert not re.search(rf" = f32\[{n},64\]\S* fusion\(", text)  # the mask
     # calls of ONE shape (the sorted batch padded to nine times 370 blocks):
     # a process traces and lowers the kernel once
     assert "f32[94720,128]" in text and "f32[94208,128]" not in text
@@ -1469,7 +1539,8 @@ def test_dlrm_step_holds_nothing_table_sized_beside_its_12_58_gb_table(
     outs = jax.eval_shape(
         make_train_step(logic, spec),
         jax.ShapeDtypeStruct(spec.table_shape(), jnp.float32), state, batch)[2]
-    assert {"ps_push_kernel_lanes", "ps_push_tile_rows", "ps_slice_kernel"} <= set(outs)
+    assert {"ps_push_kernel_lanes", "ps_push_tile_rows", "ps_slice_kernel",
+            "ps_shift_kernel"} <= set(outs)
     for scope in ("dense_bottom", "dense_interact", "dense_top", "delta_build"):
         assert f"jit(step)/ps.compute/ps.{scope}/" in text, scope
     assert "transpose(jvp(" not in text
