@@ -29,7 +29,9 @@ and ``update`` is then applied once per touched id — the documented
 semantic delta vs. the reference (bounded staleness ≤ one microbatch;
 SURVEY.md §7 "Guiding translation").  That arm costs what the batch does
 and nothing table-sized: a sort of the batch's ids, the sums of each run,
-one read and one write of every distinct row (:func:`_push_rule`).
+one read and one write of every distinct row (:func:`_push_rule`).  Under a
+mesh with one worker it runs where the reference runs ``paramUpdate``: on
+the server shard that owns the row (:func:`_push_rule_on_shards`).
 """
 from __future__ import annotations
 
@@ -430,7 +432,9 @@ def push(
     and pays 13-22 (PERF.md section 6, PR 49).
 
     A store whose ``update`` is a rule goes through :func:`_push_rule`
-    (:func:`push_counted` also hands out what that arm counted).
+    (:func:`push_counted` also hands out what that arm counted); sharded
+    over ``ps`` under one worker, every shard runs it on the rows it owns
+    (:func:`_push_rule_on_shards`).
     """
     return push_counted(spec, table, ids, deltas, mask)[0]
 
@@ -460,6 +464,13 @@ def push_counted(
     scatter-add summed them: :func:`_combine_kernel_takes`); a PACKED rule
     store carries ``ps_rule_packed_rows``, the physical rows its write-back
     wrote (:func:`_rewrite_packed`; a dense rule store has no such count).
+    Where the push ran on the shards of a mesh (:func:`_rule_on_shards_takes`)
+    each of these is the SUM over the shards of what each counted on its own
+    block (ownership is disjoint, so keys, rows and kernel lanes are the
+    one-place push's numbers; tiles and physical rows are counted block by
+    block), and two more say how even the partition is:
+    ``ps_rule_keys_max_shard`` and ``ps_rule_rows_max_shard``, the live keys
+    and the distinct rows of the FULLEST shard, the one a step waits for.
     ``make_train_step`` puts
     them among the step's outputs, where whoever fetches outputs finds them, if the logic's
     outputs are a dict (every logic of ``models/``); outputs of another
@@ -516,7 +527,8 @@ def push_counted(
             None,
         )
 
-    return _push_rule(
+    on_shards = _rule_on_shards_takes(spec)
+    return (_push_rule_on_shards if on_shards else _push_rule)(
         spec, table, flat_ids, _zero_masked(flat_deltas, flat_mask), flat_mask
     )
 
@@ -531,9 +543,15 @@ def _push_rule(
     flat_ids: Array,
     flat_deltas: Array,
     live: Optional[Array],
+    block: Optional[int] = None,
 ) -> Tuple[Array, dict]:
     """The push of a store whose ``update`` is a rule and not ``"add"``:
-    ``(table, counted)``, as :func:`push_counted` hands them out.
+    ``(table, counted)``, as :func:`push_counted` hands them out.  ``table``
+    is the whole table in one place (or GSPMD's to partition), or, with
+    ``block`` its count of LOGICAL rows, the block of one shard with the
+    ids relative to its first row and every lane the shard does not own
+    carrying ``block``, dead as a dropped lane is
+    (:func:`_push_rule_on_shards`).
 
     Work and memory go with the batch, never with the table.  Under
     ``ps.combine`` the batch's ids are sorted with their deltas, every run
@@ -560,7 +578,7 @@ def _push_rule(
     from ..ops.row_update import sorted_tile_set
 
     n = flat_ids.shape[0]
-    sentinel = spec.padded_capacity
+    sentinel = spec.padded_capacity if block is None else block
     update_fn: UpdateFn = spec.update  # type: ignore[assignment]
     wide = spec.row_width > _SORT_CARRIES_LANES
     packed = spec.layout == "packed"
@@ -611,9 +629,11 @@ def _push_rule(
             table, wrote = _rewrite_packed(spec, table, ids, sums, tiles_arm)
             return table, moved + wrote
         with jax.named_scope("ps.rule"):
+            # a shard's block is a plain dense array (no tile under a mesh)
+            current = pull(spec, table, ids) if block is None else jnp.take(
+                table, ids, axis=0, mode="clip")
             new = update_fn(
-                pull(spec, table, ids),
-                sums.reshape((chunk,) + spec.value_shape),
+                current, sums.reshape((chunk,) + spec.value_shape),
             ).astype(table.dtype)
         if tiles_arm:
             table, opened = sorted_tile_set(table, ids, new)
@@ -824,9 +844,12 @@ def _set_kernel_takes(spec: StoreSpec) -> bool:
     kernel of ``ops/row_update`` instead of XLA's row ``set``, read from
     what the spec holds, as :func:`_tile_kernel_takes` reads the add arm's:
     a TPU, and either a table held at whole sublane tiles
-    (``StoreSpec.tile_lanes``: ``sorted_tile_set``) or a PACKED table in one
-    place whose physical row is one float32 register (``sorted_row_set``:
-    :func:`_rewrite_packed`).  Static per compiled step.  A narrow rule
+    (``StoreSpec.tile_lanes``: ``sorted_tile_set``; in one place only) or a
+    PACKED table whose physical row is one float32 register, in one place
+    or sharded over ``ps`` under one worker, where every shard's rule runs
+    on its own block (``sorted_row_set``: :func:`_rewrite_packed`;
+    :func:`_push_rule_on_shards`).  A mesh with ``dp`` > 1 keeps XLA's row
+    ``set``, GSPMD's to partition.  Static per compiled step.  A narrow rule
     store that is NOT held at its tile (bfloat16, rows of rank 0 or 2) and
     a packed one the row kernel refuses (bfloat16, physical rows of several
     registers) keep the XLA arm, counted and warned of once."""
@@ -837,7 +860,7 @@ def _set_kernel_takes(spec: StoreSpec) -> bool:
     if spec.layout == "packed" and spec.update != "add":
         from ..ops import row_update
 
-        return spec.mesh is None and _taken_or_noted(
+        return _rule_sees_one_block(spec) and _taken_or_noted(
             spec, "write-back of a packed rule store's rows",
             row_update.refusal(spec.table_shape()[1:], spec.dtype),
         )
@@ -856,7 +879,10 @@ def _combine_kernel_takes(spec: StoreSpec) -> bool:
     its batch along SORTED lanes through ``ops/row_update``'s row kernel
     (``ops/dedup._kernel_sums``) instead of ONE XLA scatter-add in the order
     of the stream, read from what the spec holds, as
-    :func:`_set_kernel_takes` reads the write-back's arm: a TPU, no mesh, a
+    :func:`_set_kernel_takes` reads the write-back's arm: a TPU, a table in
+    one place or sharded over ``ps`` under one worker (every shard then sums
+    the keys it owns inside :func:`_push_rule_on_shards`' ``shard_map``,
+    where the kernel sees a plain array; ``dp`` > 1 keeps GSPMD's), a
     rule, and rows wider than a sort carries (``ops/dedup.
     _SORT_CARRIES_LANES``; a narrower row rides through the sort whatever
     the backend) that fit one 128-lane register, float32.  Static per
@@ -867,7 +893,7 @@ def _combine_kernel_takes(spec: StoreSpec) -> bool:
     (PERF.md section 6, PR 46)."""
     from ..ops import dedup
 
-    if (spec.update == "add" or spec.mesh is not None
+    if (spec.update == "add" or not _rule_sees_one_block(spec)
             or jax.default_backend() != "tpu"
             or spec.row_width <= dedup._SORT_CARRIES_LANES):
         return False
@@ -875,6 +901,87 @@ def _combine_kernel_takes(spec: StoreSpec) -> bool:
         spec, "the sum of a rule's wide rows",
         dedup.kernel_refusal(spec.row_width, spec.dtype),
     )
+
+
+def _rule_sees_one_block(spec: StoreSpec) -> bool:
+    """Whether a rule store's push runs where a kernel sees a plain array:
+    in one place, or on the shards of a mesh with one worker
+    (:func:`_rule_on_shards_takes`, which also notes the mesh it refuses)."""
+    return spec.mesh is None or worker_count(spec.mesh) == 1
+
+
+def _rule_on_shards_takes(spec: StoreSpec) -> bool:
+    """Whether a rule store's push runs ON its shards
+    (:func:`_push_rule_on_shards`) instead of as one program for GSPMD to
+    partition, read from what the spec holds: a mesh with ONE worker, so
+    that every chip already holds the whole batch and each shard can take
+    the keys it owns with no exchange.  A mesh with ``dp`` > 1 holds the
+    batch's lanes split over its workers: a shard would first have to be
+    sent the other workers' keys, which no code here does, so such a store
+    keeps the one-place push under GSPMD (XLA's scatter-add and row
+    ``set``), counted and warned of once as every declined arm is."""
+    if spec.mesh is None:
+        return False
+    return _taken_or_noted(
+        spec, "a rule store's push on the shards that own its rows",
+        None if _rule_sees_one_block(spec) else "the batch lies split over "
+        f"dp = {worker_count(spec.mesh)} workers",
+    )
+
+
+def _push_rule_on_shards(
+    spec: StoreSpec,
+    table: Array,
+    flat_ids: Array,
+    flat_deltas: Array,
+    live: Optional[Array],
+) -> Tuple[Array, dict]:
+    """:func:`_push_rule` for a table sharded over ``ps`` under one worker:
+    the reference's servers, each running ``paramUpdate`` on the rows it
+    owns.  ONE ``shard_map`` over the mesh, the table ``P(ps, ...)``, the
+    batch's ids, deltas and mask replicated (every chip holds them: ``dp``
+    = 1).  A shard takes the ids that fall in its block, relative to its
+    first row; every other lane is dead to it, as a masked or dropped lane
+    is to the one-place push, and it runs that push on its own block: its
+    own keys sorted and summed, its own distinct rows read, ruled and
+    written, in the arms a store of that block in one place would get
+    (:func:`_combine_kernel_takes`, :func:`_set_kernel_takes`: Mosaic's
+    calls cannot be partitioned, and inside the ``shard_map`` they see a
+    plain array).  No key is sent anywhere and no row leaves its chip; every
+    chip still walks all the batch's lanes in its sort (routing a key to
+    its owner alone: ROADMAP S9 (2)).
+
+    Ownership is disjoint, so the counts summed over ``ps`` are the
+    one-place push's; ``ps_rule_keys_max_shard`` and
+    ``ps_rule_rows_max_shard`` are the fullest shard's (how far the
+    partition is from even: a step waits for that shard).  One gather of a
+    few scalars across ``ps`` carries them, the push's only collective."""
+    mesh, ps = spec.mesh, spec.ps_axis
+    block = spec.rows_per_shard * spec.pack  # a shard's LOGICAL rows
+    masks = () if live is None else (live,)
+
+    def on_shard(table: Array, ids: Array, deltas: Array, *mask: Array):
+        rel = ids - jax.lax.axis_index(ps) * block
+        # another shard's row, or the sentinel of a dropped lane
+        rel = jnp.where((rel >= 0) & (rel < block), rel, block)
+        table, counted = _push_rule(
+            spec, table, rel, deltas, mask[0] if mask else None, block)
+        names = sorted(counted)
+        by_shard = jax.lax.all_gather(
+            jnp.stack([counted[k] for k in names]), ps)
+        total = dict(zip(names, by_shard.sum(axis=0)))
+        for k in ("ps_rule_keys", "ps_rule_rows"):
+            total[k + "_max_shard"] = by_shard[:, names.index(k)].max()
+        return table, total
+
+    rows = spec.sharding().spec
+    return jax.shard_map(
+        on_shard,
+        mesh=mesh,
+        in_specs=(rows,) + (P(),) * (2 + len(masks)),
+        out_specs=(rows, P()),
+        check_vma=False,  # a Pallas call states no varying axes
+    )(table, flat_ids, flat_deltas, *masks)
 
 
 def _worker_reduce_takes(spec: StoreSpec, lanes: int) -> bool:

@@ -376,7 +376,10 @@ class StreamingDriver:
         rewrote and the tiles of
         128 rows its write-back moved to do so: ``store_rule_keys``,
         ``store_rule_rows``, ``store_rule_tiles``; for wide rows the lanes
-        the row kernel summed: ``store_combine_kernel_lanes``) and a logic
+        the row kernel summed: ``store_combine_kernel_lanes``; where the
+        push ran on the shards that own the rows, the fullest shard's keys
+        and rows: ``store_rule_keys_max_shard``,
+        ``store_rule_rows_max_shard``) and a logic
         of ragged key
         bags (the live lanes of its keys and all of them: ``bag_live_keys``,
         ``bag_padded_keys``), a gated factorisation machine (the live lanes
@@ -465,6 +468,15 @@ class StreamingDriver:
         self.registry.gauge("store_rule_tiles", component="train").set(
             total(outs["ps_rule_tiles"])
         )
+        if "ps_rule_rows_max_shard" in outs:
+            # a rule store whose push ran on its shards (one worker over
+            # `ps`): the live keys and the distinct rows of the fullest shard
+            self.registry.gauge(
+                "store_rule_keys_max_shard", component="train"
+            ).set(total(outs["ps_rule_keys_max_shard"]))
+            self.registry.gauge(
+                "store_rule_rows_max_shard", component="train"
+            ).set(total(outs["ps_rule_rows_max_shard"]))
         if "ps_combine_kernel_lanes" in outs:
             # a rule store of rows wider than a sort carries: the lanes the
             # row kernel summed (0 where XLA's scatter-add summed them)

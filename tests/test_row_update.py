@@ -805,15 +805,25 @@ def _rule(current, combined):
     ("cpu", False, (3,), _rule, jnp.float32, False),
     ("cpu", False, (36,), _rule, jnp.float32, False),
     ("tpu", True, (3,), _rule, jnp.float32, False),
-    ("tpu", True, (36,), _rule, jnp.float32, False),  # a mesh: GSPMD's set
+    ("tpu", True, (36,), _rule, jnp.float32, False),  # dp > 1: GSPMD's set
+    # one worker over ps = 4: the push runs on the shards, where a packed
+    # store's block gets the row set; a narrow row is not tiled under a mesh
+    ("tpu", "ps4", (36,), _rule, jnp.float32, True),
+    ("tpu", "ps4", (9,), _rule, jnp.float32, True),
+    ("tpu", "ps4", (3,), _rule, jnp.float32, False),
+    ("tpu", "ps4", (65,), _rule, jnp.float32, False),
+    ("cpu", "ps4", (36,), _rule, jnp.float32, False),
 ])
 def test_the_write_back_takes_the_set_kernel_from_what_the_spec_holds(
         monkeypatch, backend, meshed, shape, update, dtype, want):
     from flink_parameter_server_tpu.core import store as store_mod
     from flink_parameter_server_tpu.parallel.mesh import make_mesh
 
-    mesh = make_mesh(worker_parallelism=2, ps_parallelism=2,
-                     devices=jax.devices()[:4]) if meshed else None
+    mesh = None
+    if meshed:
+        dp = 1 if meshed == "ps4" else 2
+        mesh = make_mesh(worker_parallelism=dp, ps_parallelism=4 // dp,
+                         devices=jax.devices()[:4])
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
     n0 = row_update.refusal_count()
     spec = _spec(shape, dtype, update=update, mesh=mesh)

@@ -4,6 +4,8 @@ Mirrors the reference's server-side semantics (SimplePSLogic:
 getOrElseUpdate + user update fn — SURVEY.md §2 #3) at microbatch
 granularity.
 """
+import warnings
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -1432,3 +1434,232 @@ def test_a_packed_rule_store_under_a_ps_mesh_is_the_one_device_store(
     probe = jnp.asarray(np.clip(ids, 0, cap - 1))
     assert np.asarray(pushed.pull(probe)).tobytes() == (
         want[np.asarray(probe)].tobytes())
+
+
+# A RULE store whose table is sharded over ``ps`` under ONE worker runs its
+# push ON the shards (``core/store._push_rule_on_shards``): every shard takes
+# the keys that fall in its block and runs the one-place push on it.  On the
+# CPU (no kernel) that is the one-place store bit for bit; the kernels are
+# steered and interpreted inside the ``shard_map``.
+@pytest.fixture(scope="module")
+def ps_mesh(mesh_devices):
+    from flink_parameter_server_tpu.parallel.mesh import make_mesh
+
+    return make_mesh(1, 4, devices=mesh_devices[:4])
+
+
+SHARD_RULE_CASES = [
+    "uniform", "half_masked_hot_row", "ids_out_of_range",
+    "a_whole_physical_row", "a_physical_row_on_a_chunks_edge",
+    "an_empty_shard", "empty_batch",
+]
+
+
+def _shard_rule_traffic(case, rng, cap, width, block):
+    """``(ids, deltas, mask or None, rule chunk or None)`` of one push into a
+    store whose shards own ``block`` logical rows each."""
+    k = max(1, 128 // width)
+    n, mask, chunk = 400, None, None
+    ids = rng.integers(0, cap, n)
+    if case == "half_masked_hot_row":
+        ids[: n // 3] = block + 1  # shard 1's
+        mask = rng.random(n) < 0.5
+    elif case == "ids_out_of_range":
+        ids = rng.integers(-5, cap + 40, n)
+        # (past BOTH stores' padding rows, which differ and are addressable)
+        ids = np.where(ids >= cap, ids + 4 * block, ids)
+        ids[:6] = [-1, -(2 ** 31), 2 ** 31 - 1, cap + 4000, 4 * block, 4 * block + 1]
+    elif case == "a_whole_physical_row":
+        # every logical row of one physical row, in two shards
+        ids = np.concatenate([k * 7 + np.arange(k), block + k * 3 + np.arange(k)])
+    elif case == "a_physical_row_on_a_chunks_edge":
+        # 16 sorted distinct ids a trip of each shard's loop
+        ids, chunk = np.arange(5, 5 + 4 * 50) % cap, 16
+    elif case == "an_empty_shard":
+        ids = ids[(ids < 2 * block) | (ids >= 3 * block)]
+        assert ids.size > 100
+    elif case == "empty_batch":
+        ids = np.zeros((0,), np.int64)
+    elif case != "uniform":
+        raise AssertionError(case)
+    ids = np.asarray(ids, np.int32)
+    deltas = rng.normal(size=ids.shape + (width,)).astype(np.float32)
+    return ids, deltas, mask, chunk
+
+
+@pytest.mark.parametrize("case", SHARD_RULE_CASES)
+@pytest.mark.parametrize("width,arm", [
+    (3, "xla"), (9, "xla"), (17, "xla"), (36, "xla"), (64, "xla"),
+    (100, "xla"), (17, "row_set_kernel"), (36, "row_set_kernel"),
+])
+def test_a_rule_store_on_its_shards_is_the_one_place_store_and_the_shares_add_up(
+        arm, width, case, ps_mesh, monkeypatch):
+    from flink_parameter_server_tpu.core import store as store_mod
+
+    rng = np.random.default_rng([width, SHARD_RULE_CASES.index(case)])
+    cap = 500
+    values = rng.normal(size=(cap, width)).astype(np.float32)
+    one = ShardedParamStore.from_values(
+        jnp.asarray(values), update=_sticky_rule, layout="auto")
+    sharded = ShardedParamStore.from_values(
+        jnp.asarray(values), update=_sticky_rule, mesh=ps_mesh, layout="auto")
+    spec = sharded.spec
+    packs = width in PACKED_RULE_WIDTHS
+    assert spec.layout == one.spec.layout == ("packed" if packs else "dense")
+    # a narrow rule row is NOT held at its sublane tile under a mesh
+    assert spec.tile_lanes == 0 and (width != 3 or one.spec.tile_lanes == 4)
+    assert store_mod._rule_on_shards_takes(spec)
+    block = spec.rows_per_shard * spec.pack
+    ids, deltas, mask, chunk = _shard_rule_traffic(case, rng, cap, width, block)
+    if chunk:
+        monkeypatch.setattr(store_mod, "_RULE_CHUNK", chunk)
+    args = (jnp.asarray(ids), jnp.asarray(deltas),
+            None if mask is None else jnp.asarray(mask))
+    want, counted_one = store_mod.push_counted(one.spec, one.table, *args)
+    if arm == "row_set_kernel":
+        # off the TPU the chooser is steered and the kernel interpreted,
+        # here inside every shard's part of the shard_map
+        monkeypatch.setattr(store_mod, "_set_kernel_takes", lambda spec: True)
+    got, counted = jax.jit(
+        lambda t, i, d, m: store_mod.push_counted(spec, t, i, d, m)
+    )(sharded.table, *args)
+    assert got.sharding == spec.sharding()
+    want = np.asarray(ShardedParamStore(one.spec, want).values())
+    pushed = ShardedParamStore(spec, got)
+    assert np.asarray(pushed.values()).tobytes() == want.tobytes()
+    # the shares add up: ownership is disjoint, so the counts summed over the
+    # shards are the one-place push's, and the fullest shard's are numpy's
+    live = (ids >= 0) & (ids < min(spec.padded_capacity, one.spec.padded_capacity))
+    if mask is not None:
+        live &= mask
+    owner = ids[live] // block
+    keys = np.bincount(owner, minlength=4)
+    rows = np.array([np.unique(ids[live][owner == s]).size for s in range(4)])
+    if case == "an_empty_shard":
+        assert keys[2] == 0 and rows[2] == 0 and keys.sum() > 100
+    for name in ("ps_rule_keys", "ps_rule_rows", "ps_rule_tiles"):
+        assert int(counted[name]) == int(counted_one[name]), name
+    assert int(counted["ps_rule_keys"]) == keys.sum() == live.sum()
+    assert int(counted["ps_rule_rows"]) == rows.sum()
+    assert int(counted["ps_rule_keys_max_shard"]) == keys.max()
+    assert int(counted["ps_rule_rows_max_shard"]) == rows.max()
+    assert "ps_rule_rows_max_shard" not in counted_one
+    # the rows each shard rewrote lie in its own block, are disjoint, and
+    # their union is what the one-place store rewrote
+    moved = np.flatnonzero((want != values).any(axis=1))
+    assert np.array_equal(moved, np.unique(ids[live][ids[live] < cap]))
+    blocks = np.asarray(got).reshape(4, spec.rows_per_shard, -1)
+    before = np.asarray(sharded.table).reshape(blocks.shape)
+    touched_phys = [(blocks[s] != before[s]).any(axis=1).sum() for s in range(4)]
+    k = spec.pack
+    assert touched_phys == [
+        np.unique(ids[live][owner == s] // k).size for s in range(4)]
+    if packs:
+        assert int(counted["ps_combine_kernel_lanes"]) == 0  # a CPU
+        assert int(counted["ps_rule_packed_rows"]) >= sum(touched_phys)
+        if chunk is None:
+            assert int(counted["ps_rule_packed_rows"]) == sum(touched_phys)
+    probe = jnp.asarray(np.clip(ids, 0, cap - 1))
+    assert np.asarray(pushed.pull(probe)).tobytes() == (
+        want[np.asarray(probe)].tobytes())
+
+
+@pytest.mark.parametrize("width", [17, 36])
+def test_the_row_kernels_inside_the_shard_map_sum_and_write_what_xla_does(
+        width, ps_mesh, monkeypatch):
+    """Both kernels steered on and interpreted inside the ``shard_map``: the
+    combine sums a run along sorted lanes (a blocked float32 sum, not the
+    stream's order), so the rows are the XLA arm's within float32 rounding,
+    a row named once bit for bit, and the counts are the XLA arm's."""
+    from flink_parameter_server_tpu.core import store as store_mod
+    from flink_parameter_server_tpu.ops import row_update
+
+    rng = np.random.default_rng(width)
+    cap = 500
+    values = rng.normal(size=(cap, width)).astype(np.float32)
+    ids = rng.integers(-3, cap + 5, 700).astype(np.int32)
+    ids[:200] = 123  # a hot row, a run over a kernel block
+    deltas = rng.normal(size=(700, width)).astype(np.float32)
+    mask = rng.random(700) < 0.9
+    sharded = ShardedParamStore.from_values(
+        jnp.asarray(values), update=_sticky_rule, mesh=ps_mesh, layout="auto")
+    spec = sharded.spec
+    args = (jnp.asarray(ids), jnp.asarray(deltas), jnp.asarray(mask))
+    want, counted_xla = jax.jit(
+        lambda t, i, d, m: store_mod.push_counted(spec, t, i, d, m)
+    )(sharded.table, *args)
+    calls = []
+    monkeypatch.setattr(store_mod, "_set_kernel_takes", lambda spec: True)
+    monkeypatch.setattr(store_mod, "_combine_kernel_takes", lambda spec: True)
+    monkeypatch.setattr(row_update, "MAX_LANES", 512)
+    for name in ("sorted_row_update", "sorted_row_set"):
+        real = getattr(row_update, name)
+        monkeypatch.setattr(
+            row_update, name,
+            lambda *a, _real=real, _name=name, **kw: (
+                calls.append(_name) or _real(*a, **kw)))
+    got, counted = jax.jit(
+        lambda t, i, d, m: store_mod.push_counted(spec, t, i, d, m)
+    )(sharded.table, *args)
+    assert {"sorted_row_update", "sorted_row_set"} <= set(calls)
+    want = np.asarray(ShardedParamStore(spec, want).values())
+    got = np.asarray(ShardedParamStore(spec, got).values())
+    np.testing.assert_allclose(got, want, rtol=2e-6, atol=2e-6)
+    live = (ids >= 0) & (ids < spec.padded_capacity) & mask
+    named, times = np.unique(ids[live], return_counts=True)
+    once = named[(times == 1) & (named < cap)]
+    assert once.size > 50 and got[once].tobytes() == want[once].tobytes()
+    for name in counted_xla:
+        if name != "ps_combine_kernel_lanes":
+            assert int(counted[name]) == int(counted_xla[name]), name
+    assert int(counted_xla["ps_combine_kernel_lanes"]) == 0
+    assert int(counted["ps_combine_kernel_lanes"]) == live.sum()
+
+
+@pytest.mark.parametrize("shape,dp,backend,combine,write_back,on_shards", [
+    ((36,), 1, "tpu", True, True, True),    # cell 12: DiFacto over ps = 4
+    ((17,), 1, "tpu", True, True, True),
+    ((9,), 1, "tpu", True, True, True),
+    ((3,), 1, "tpu", False, False, True),   # dense under a mesh: XLA's set
+    ((100,), 1, "tpu", True, False, True),  # dense, one register: the sums
+    ((36,), 1, "cpu", False, False, True),
+    ((36,), 2, "tpu", False, False, False),  # dp > 1: GSPMD's, as it was
+    ((3,), 2, "tpu", False, False, False),
+])
+def test_a_rule_store_s_arms_under_a_mesh_are_read_from_its_workers(
+        shape, dp, backend, combine, write_back, on_shards, mesh_devices,
+        monkeypatch):
+    from flink_parameter_server_tpu.core import store as store_mod
+    from flink_parameter_server_tpu.ops import row_update
+    from flink_parameter_server_tpu.parallel.mesh import make_mesh
+
+    mesh = make_mesh(dp, 4 // dp, devices=mesh_devices[:4])
+    spec = jax.eval_shape(lambda: ShardedParamStore.create(
+        1000, shape, update=_sticky_rule, mesh=mesh, layout="auto")).spec
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    monkeypatch.setattr(store_mod, "_REFUSALS_NOTED", set())
+    n0 = row_update.refusal_count()
+    with warnings.catch_warnings():
+        warnings.simplefilter("error")  # the arms themselves note nothing
+        assert store_mod._combine_kernel_takes(spec) == combine
+        assert store_mod._set_kernel_takes(spec) == write_back
+    assert row_update.refusal_count() == n0
+    if on_shards:
+        with warnings.catch_warnings():
+            warnings.simplefilter("error")
+            assert store_mod._rule_on_shards_takes(spec)
+        assert row_update.refusal_count() == n0
+        return
+    # a batch split over dp > 1 workers keeps the one-place push under
+    # GSPMD, and says so once as every declined arm does
+    with pytest.warns(RuntimeWarning, match="split over dp = 2 workers"):
+        assert not store_mod._rule_on_shards_takes(spec)
+    with warnings.catch_warnings():
+        warnings.simplefilter("error")
+        assert not store_mod._rule_on_shards_takes(spec)
+    assert row_update.refusal_count() == n0 + 1
+    # neither a one-place store nor an add store under any mesh is asked
+    one = jax.eval_shape(lambda: ShardedParamStore.create(
+        1000, shape, update=_sticky_rule, layout="auto")).spec
+    assert not store_mod._rule_on_shards_takes(one)
+    assert row_update.refusal_count() == n0 + 1

@@ -1387,6 +1387,76 @@ def test_difacto_step_holds_nothing_table_sized_beside_its_table(
     assert len(re.findall(r" while\(", text)) == 4
 
 
+@pytest.mark.parametrize("arm", ["kernels", "xla"])
+def test_difacto_step_on_four_chips_runs_its_rule_on_the_shard_that_owns_the_row(
+        arm, difacto, topo, no_compile_cache, monkeypatch):
+    """Cell 12's step at full size for four described chips: cell 9's rows
+    at cell 4's record, 187,767,412 x 36 f32 packed three to a physical row,
+    ``f32[15647288,128]`` (8.011 GB) a chip.  The push is ONE ``shard_map``
+    (``core/store._push_rule_on_shards``): every chip's block rewritten in
+    place by the calls a one-place packed store gets (``sorted_row_update``
+    under ``ps.combine``, ``sorted_row_set`` in the rule's loop; off the TPU
+    XLA's scatter-add and row ``set`` ON THE BLOCK, nothing partitioned by
+    GSPMD), 1.33 GB of temporaries a chip, and the step's only collectives
+    are the pull's all-reduce of ``f32[32768,39,36]`` and the 80 bytes of
+    the push's counts: no row of the table and no key crosses chips."""
+    from flink_parameter_server_tpu.parallel.mesh import make_mesh
+
+    cfg, rule, model, fam, dfm = difacto
+    mesh = make_mesh(1, 4, devices=topo.devices)
+    model = dfm.DiFactoConfig(FM_ROWS, 16)  # the 40 M record's rows
+    spec = jax.eval_shape(
+        lambda: dfm.make_store(model, rule, mesh=mesh, dtype=jnp.float32)
+    ).spec
+    assert spec.layout == "packed" and spec.pack == 3
+    assert spec.table_shape() == (4 * 15_647_288, 128)
+    assert store_mod._rule_on_shards_takes(spec)
+    if arm == "kernels":
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        n0 = row_update.refusal_count()
+        assert store_mod._combine_kernel_takes(spec)
+        assert store_mod._set_kernel_takes(spec)
+        assert row_update.refusal_count() == n0
+    everywhere = NamedSharding(mesh, PartitionSpec())
+    compiled = jax.jit(
+        make_train_step(dfm.DiFacto(model, rule), spec), donate_argnums=(0, 1)
+    ).lower(
+        _shape(spec.sharding(), spec.table_shape(), jnp.float32), (),
+        _fm_batch(everywhere),
+    ).compile()
+    mem = compiled.memory_analysis()
+    assert 8.01 * GB < mem.alias_size_in_bytes < 8.02 * GB  # in place, a chip
+    assert mem.temp_size_in_bytes < 1.6 * GB  # 1.327 with the kernels
+    text = compiled.as_text()
+    lines = text.splitlines()
+    assert not re.search(r"f32\[15647288,128\]\S* (copy|transpose)\(", text)
+    assert "f32[62589152,128]" not in text  # no chip ever sees the whole table
+    collectives = [c for c in lines if re.search(
+        r" (all-reduce|all-gather|all-to-all|reduce-scatter|collective-permute)"
+        r"(-start)?\(", c)]
+    shapes = sorted(c.strip().split(" = ", 1)[1].split("{")[0] for c in collectives)
+    assert shapes == ["f32[32768,39,36]", "s32[20]"], collectives
+    for scope in ("ps.pull", "ps.push/shard_map/ps.combine",
+                  "ps.push/shard_map/while/body/ps.rule"):
+        assert scope in text, scope
+    kernels = [line for line in lines if "tpu_custom_call" in line]
+    scatters = [line for line in lines if re.search(r" scatter\(", line)]
+    if arm == "xla":
+        # the one-place arms on each shard's own block
+        assert all(" f32[15647288,128]" in c or " f32[1277952,36]" in c
+                   for c in scatters) and len(scatters) == 2, scatters
+        assert not kernels
+        return
+    assert not scatters
+    names = sorted(k.strip().split(" ", 1)[0].rstrip(".0123456789") for k in kernels)
+    assert names == ["%packed_lane_slice", "%sorted_row_set", "%sorted_row_update"]
+    by_name = {k.strip().split(".", 1)[0]: k for k in kernels}
+    assert " f32[15647288,128]{1,0" in by_name["%sorted_row_set"]
+    assert "ps.push/shard_map/while/body" in by_name["%sorted_row_set"]
+    assert " f32[1277952,128]{1,0" in by_name["%sorted_row_update"]
+    assert "ps.push/shard_map/ps.combine/while/body" in by_name["%sorted_row_update"]
+
+
 @pytest.mark.parametrize("rows, lanes, sorted_form", [
     (FM1_PHYS_ROWS, 1_277_952, True),  # cell 2: XLA keeps it, 21.8 ns a lane
     (FM1_PHYS_ROWS, 877_257, True),  # 8 lanes over the table's rows
