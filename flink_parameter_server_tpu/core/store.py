@@ -461,14 +461,19 @@ def push_counted(
     wrote the rows: :func:`_set_kernel_takes`); a rule store whose rows are
     wider than a sort carries also ``ps_combine_kernel_lanes``, the lanes
     whose rows the row kernel summed (the live lanes; 0 where XLA's
-    scatter-add summed them: :func:`_combine_kernel_takes`); a PACKED rule
+    scatter-add summed them: :func:`_combine_kernel_takes`), and
+    ``ps_combine_kernel_writes``, the single-row DMAs the kernel issued to
+    do so over the stretches it walked (``ops/row_update.descriptors``:
+    writes over lanes is the share of the walk that writes, ~28 % on Criteo
+    records); a PACKED rule
     store carries ``ps_rule_packed_rows``, the physical rows its write-back
     wrote (:func:`_rewrite_packed`; a dense rule store has no such count).
     Where the push ran on the shards of a mesh (:func:`_rule_on_shards_takes`)
     each of these is the SUM over the shards of what each counted on its own
     block (ownership is disjoint, so keys, rows and kernel lanes are the
-    one-place push's numbers; tiles and physical rows are counted block by
-    block), and two more say how even the partition is:
+    one-place push's numbers; tiles, physical rows and the kernel's writes,
+    which every block of 256 sorted lanes rounds up to a trip of eight, are
+    counted block by block), and two more say how even the partition is:
     ``ps_rule_keys_max_shard`` and ``ps_rule_rows_max_shard``, the live keys
     and the distinct rows of the FULLEST shard, the one a step waits for.
     ``make_train_step`` puts
@@ -558,7 +563,11 @@ def _push_rule(
     of one id summed and the distinct ids moved to the front
     (:func:`..ops.dedup.combine_runs`; masked, negative and out-of-range
     lanes sort last and are dropped; a wide row's runs are summed in the arm
-    :func:`_combine_kernel_takes` reads from the spec).  Then
+    :func:`_combine_kernel_takes` reads from the spec, and the kernel arm's
+    walk pays by what it writes: a DMA a distinct row, and no stretch of
+    sorted lanes past the last live one, so a shard of cell 12 that owns
+    3.7 % of the keys walks one stretch of thirteen: PERF.md section 6, PR
+    54).  Then
     ``_RULE_CHUNK`` lanes a step of
     a loop that ends with the last distinct id (on the TPU a dropped lane
     of a gather or a scatter costs what a kept one does, and a batch of
@@ -589,6 +598,7 @@ def _push_rule(
         }
         if wide:
             counted["ps_combine_kernel_lanes"] = zero
+            counted["ps_combine_kernel_writes"] = zero
         if packed:
             counted["ps_rule_packed_rows"] = zero
         return table, counted
@@ -599,7 +609,7 @@ def _push_rule(
         dead = flat_ids >= sentinel
         if live is not None:
             dead = dead | ~live
-        row_ids, combined = combine_runs(
+        row_ids, combined, issued = combine_runs(
             jnp.where(dead, sentinel, flat_ids),
             flat_deltas.reshape(n, -1).astype(table.dtype), sentinel,
             kernel=sums_arm,
@@ -613,6 +623,7 @@ def _push_rule(
                 counted["ps_rule_keys"] if sums_arm
                 else jnp.zeros((), jnp.int32)
             )
+            counted["ps_combine_kernel_writes"] = issued
         # whole chunks: a chunk that started early would run the rule on
         # rows the chunk before it has already rewritten
         pad = -n % chunk
@@ -889,8 +900,8 @@ def _combine_kernel_takes(spec: StoreSpec) -> bool:
     compiled step.  Such a store that the kernel REFUSES (bfloat16, rows of
     more than 128 lanes) keeps the scatter-add, counted and warned of once.
     On the v5e the scatter-add is 146 ns a 36-lane row, serial; the permute
-    of whole-register rows is 8-10 ns a row and the kernel 9.5 a lane
-    (PERF.md section 6, PR 46)."""
+    of whole-register rows is 8-10 ns a row and the kernel ~0.6 us a block
+    of 256 lanes + 10-13 ns a row it writes (PERF.md section 6, PRs 46, 54)."""
     from ..ops import dedup
 
     if (spec.update == "add" or not _rule_sees_one_block(spec)

@@ -109,9 +109,10 @@ def _sorted_by_id(ids: Array, rows: Array) -> Tuple[Array, Array]:
 def combine_runs(
     ids: Array, vals: Array, sentinel: int, *, kernel: bool = False,
     interpret: Optional[bool] = None,
-) -> Tuple[Array, Array]:
-    """Sum the rows of ``vals`` (n, w) that share an id: ``(row_ids, sums)``,
-    both of the batch's static length.  The distinct ids come first, in
+) -> Tuple[Array, Array, Optional[Array]]:
+    """Sum the rows of ``vals`` (n, w) that share an id: ``(row_ids, sums,
+    writes)``, the first two of the batch's static length.  The distinct
+    ids come first, in
     ascending order, each with its run's total; the rest of ``row_ids`` is
     ``sentinel`` (an id no row has, and larger than any: lanes to drop carry
     it coming in).  A run of any length costs what the batch does.  Which
@@ -130,10 +131,16 @@ def combine_runs(
       rows permuted ONCE into sorted order at 128 lanes and their runs summed
       by the row kernel of ``ops/row_update`` (:func:`_kernel_sums`): on
       the v5e a serial scatter-add is 146 ns a 36-lane row, the permute of
-      a whole-register row 8-10 and the kernel 9.5 a lane (cell 9's
-      ``ps.combine`` 188.8 -> 29.9 ms: PERF.md section 6, PR 46).
+      a whole-register row 8-10 and the kernel ~0.6 us a block of 256
+      lanes + 10-13 ns a row it writes (cell 9's ``ps.combine`` 188.8 ->
+      29.9 ms: PERF.md section 6, PR 46; 25.2 since the walk pays by the
+      row it writes and not by the lane: PR 54).
 
-    ``interpret`` is the kernel's (None: by the default backend)."""
+    The third value, for rows wider than a sort carries: the single-row
+    DMAs the row kernel issued over the stretches it walked, an int32
+    scalar on the device (0 where the scatter-add summed the rows); ``None``
+    for a narrow row, which has no such arm.  ``interpret`` is the kernel's
+    (None: by the default backend)."""
     n, w = vals.shape
     if w > _SORT_CARRIES_LANES:
         return _wide_runs(
@@ -149,7 +156,7 @@ def combine_runs(
         cols = cols + jnp.where(same[None], before, jnp.zeros_like(before))
         d *= 2
     ends = jnp.concatenate([ids[1:] != ids[:-1], jnp.ones((1,), bool)])
-    return _sorted_by_id(jnp.where(ends, ids, sentinel), cols.T)
+    return _sorted_by_id(jnp.where(ends, ids, sentinel), cols.T) + (None,)
 
 
 def kernel_refusal(width: int, dtype) -> Optional[str]:
@@ -168,7 +175,7 @@ def kernel_refusal(width: int, dtype) -> Optional[str]:
 def _wide_runs(
     ids: Array, vals: Array, sentinel: int, kernel: bool,
     interpret: Optional[bool],
-) -> Tuple[Array, Array]:
+) -> Tuple[Array, Array, Array]:
     """:func:`combine_runs` for rows wider than a sort carries.  One sort of
     (id, stream position); each sorted lane's SLOT is the rank of its id
     among the distinct ids (a prefix sum of the run starts, by doubling, on
@@ -204,24 +211,26 @@ def _wide_runs(
     if kernel:
         # the lanes to drop sort last: the kernel writes no row for them
         slot = jnp.where(sorted_ids < sentinel, rank - 1, _INT32_MAX)
-        sums = _kernel_sums(order, slot, vals, interpret)
+        sums, issued = _kernel_sums(order, slot, vals, interpret)
     else:
         # (the positions are distinct: nothing for a stable sort to keep)
         _, slot = jax.lax.sort((order, rank - 1), num_keys=1, is_stable=False)
         sums = jnp.zeros((n, w), vals.dtype).at[slot].add(vals)
+        issued = jnp.zeros((), jnp.int32)
     row_ids = jax.lax.sort(jnp.where(starts, sorted_ids, sentinel))
-    return row_ids, sums
+    return row_ids, sums, issued
 
 
 def _kernel_sums(
     order: Array, slot: Array, vals: Array, interpret: Optional[bool]
-) -> Array:
+) -> Tuple[Array, Array]:
     """``sums[slot[k]] += vals[order[k]]`` over the sorted lanes ``k``
     (``vals`` float32, at most 128 lanes: :func:`kernel_refusal`; ``slot``
     ascending, the lanes to drop last with a slot past ``n``), as a
     segment sum through ``ops/row_update.sorted_row_update``, the MF cells'
-    row kernel called as it stands: a zeroed ``(n, 128)`` block is its state,
-    zeros are its old rows, a run's total is the one row it writes.
+    row kernel under the plan for ids that repeat: a zeroed ``(n, 128)``
+    block is its state, zeros are its old rows, a run's total is the one row
+    it writes.  Beside the sums, the single-row DMAs the calls issued.
 
     The ``(n, w)`` rows are padded to ``(n, 128)``, row-major: whole
     registers, the only width at which a row gathers and DMAs in one piece
@@ -235,12 +244,23 @@ def _kernel_sums(
     the second reads what the first wrote as ITS old row, as
     ``row_update.row_add`` does it.
 
+    The walk pays by what it WRITES (PERF.md section 6, PR 54).  A combine
+    exists because ids repeat (a Criteo record names a row 3.6 times: 72 %
+    of cell 9's lanes are not the last of their run), so the kernel takes
+    the compact plan and issues a DMA a run, not a lane; and the loop ends
+    with the stretch that holds the last LIVE lane, as ``core/store.
+    _push_rule``'s ends with the last distinct id: the dead lanes sort
+    last, the block starts zeroed and a dead lane writes nothing, so the
+    stretches left out change no bit (a shard of cell 12 that owns 3.7 % of
+    the keys walks one stretch of thirteen).  A stretch that is partly dead
+    is walked whole.
+
     A run is summed block by block of 256 lanes on the MXU, from three
     exact bfloat16 pieces accumulated in float32, a carry between blocks:
     NOT in the order of the stream (``np.add.at``), and to float32's
     rounding of a blocked sum.  A non-finite value stays in its row."""
     from .row_update import (
-        BLOCK, MAX_LANES, _open_run_reread, sorted_row_update,
+        BLOCK, MAX_LANES, _open_run_reread, sorted_row_update_counted,
     )
 
     n, w = vals.shape
@@ -252,7 +272,8 @@ def _kernel_sums(
     padded = jnp.pad(vals, ((0, 0), (0, 128 - w)))
     zeros = jnp.zeros((size, 128), jnp.float32)
 
-    def stretch(i, block):
+    def stretch(i, carry):
+        block, issued = carry
         lo = i * size
         slots = jax.lax.dynamic_slice_in_dim(slot, lo, size)
         # (a permutation: nothing to clip, and no fill to select after)
@@ -260,14 +281,21 @@ def _kernel_sums(
             padded, jax.lax.dynamic_slice_in_dim(order, lo, size), axis=0,
             mode="clip",
         )
-        return sorted_row_update(
+        # a combine is handed ids that repeat: the walk pays by the row
+        block, sent = sorted_row_update_counted(
             block, slots, _open_run_reread(block, slots, zeros), rows,
-            interpret=interpret,
+            compact=True, interpret=interpret,
         )
+        return block, issued + sent
 
-    block = jax.lax.fori_loop(
-        0, trips, stretch, jnp.zeros((n, 128), jnp.float32))
-    return block[:, :w]
+    # the lanes to drop sort last: the live ones are a prefix, and the loop
+    # ends with the stretch that holds the last of them
+    live = jnp.sum(slot < _INT32_MAX, dtype=jnp.int32)
+    block, issued = jax.lax.fori_loop(
+        0, (live + size - 1) // size, stretch,
+        (jnp.zeros((n, 128), jnp.float32), jnp.zeros((), jnp.int32)),
+    )
+    return block[:, :w], issued
 
 
 # -- host-side coalescing (the cluster client's request combiner) -----------

@@ -25,9 +25,29 @@ sharing a row are adjacent, and this kernel
   3. writes, for the LAST lane of every run, ``old + prefix`` (= the row's
      new value) to its row with a single-row DMA.  Rows written are unique,
      so no DMA waits for another: a whole block's writes are in flight
-     while the next block is summed, and are awaited, all at once, one
-     block later.  Issuing the descriptors is what the kernel's time is:
-     13 ns a lane on the v5e, 16 ns a lane all told (PERF.md section 6).
+     while the next block is summed, and are awaited one block later.
+     Issuing the descriptors is what the kernel's time is: on the v5e a
+     block costs ~0.6 us whatever it holds (its two pipelined input blocks,
+     the four mask products) and 10-13 ns for every descriptor it issues
+     on the one scalar core (PERF.md section 6, PRs 27, 52 and 54).
+
+What a block issues is the PLAN's (:func:`_plan`), and the plan is the
+call site's, from what it knows of its ids by construction.  A keyed stream
+(MF's users: 99.3 % of the lanes write) keeps every writing lane where it
+lies: a lane that writes nothing repeats its block's first write, so a
+block that writes issues exactly ``block`` DMAs in a loop of static length
+with no branch a lane, and one wait the size of the staging slot answers
+them.  A batch handed over BECAUSE its ids repeat (a rule store's combine:
+``ops/dedup._kernel_sums``, 72 % of whose lanes in cell 9 end no run) and a
+write-back that dropped lanes itself (:func:`sorted_row_set`) take the
+COMPACT plan: one more sort of the block-shaped scalars brings a block's
+writes to the front of its stretch, and the walk issues a descriptor a row
+it WRITES, eight a trip, at most seven over (cell 9's thirteen calls 12.20
+-> 7.89 ms a step for 360.9 k descriptors where there were 852 k, its
+write-back's eleven 4.93 -> 4.22: PERF.md section 6, PR 54).  Same rows,
+same bytes, fewer copies of them.  At MF's shape the compact plan and its
+waits cost 0.023 ms a call more than they save (1.107 -> 1.130 ms at 65,536
+lanes): that caller keeps its plan.
 
 The state array stays in HBM and is aliased to the output; rows no lane
 names are never touched.  Lanes to drop carry an id >= the row count (they
@@ -104,8 +124,10 @@ of sets (PERF.md section 6, PR 35).
 a block's new rows are staged as they are and sent, one single-row DMA a
 lane, nothing summed, so every bit of a row arrives (-0.0, NaN payloads,
 infinities).  XLA's row ``set`` of 32,768 such rows is 72 ns a lane on the
-v5e, kept or dropped, and 764 once promised sorted; the walk is 13.7 ns a
-lane inside cell 9's step (PERF.md section 6, PR 47).
+v5e, kept or dropped, and 764 once promised sorted; the walk was 13.7 ns a
+lane inside cell 9's step (PERF.md section 6, PR 47) and pays by the row
+written since PR 54 (the compact plan: the store's dropped lanes, 18-20 %
+of a chunk, lie BETWEEN its writes).
 """
 from __future__ import annotations
 
@@ -263,17 +285,20 @@ def sort_by_row(ids: Array, keep: Optional[Array], rows: int):
 
 
 def _kernel(tgt_ref, src_ref, writes_ref, aux_ref, old_ref, dl_ref, state_ref,
-            out_ref, buf_ref, carry_ref, sem, *, block: int):
+            out_ref, buf_ref, carry_ref, sem, *, block: int, compact: bool):
     """One grid step = ``block`` sorted lanes.
 
-    tgt_ref / src_ref: (N,) int32 SMEM (scalar prefetch) — per lane, the
-      row its DMA writes and the block-local lane whose new row it sends.
-      A lane that is the last of its run (and kept) sends its own; every
-      other lane REPEATS its block's first such write, same source and
-      same row, so a block issues exactly ``block`` DMAs with no branch a
-      lane, and identical bytes landing twice on one row harm nothing.
+    tgt_ref / src_ref: (N,) int32 SMEM (scalar prefetch) — per DMA of a
+      block's stretch, the row it writes and the block-local lane whose new
+      row it sends (:func:`_plan`).  A lane that is the last of its run
+      (and kept) sends its own; every other entry REPEATS its block's
+      first such write, same source and same row: identical bytes landing
+      twice on one row harm nothing, and the loop has no branch a lane.
     writes_ref: (N / block,) int32 SMEM — how many lanes of a block write;
-      a block where none does issues and awaits nothing.
+      a block where none does issues and awaits nothing.  What a block that
+      writes issues is :func:`_send_and_await`'s: all ``block`` entries, or
+      (``compact``: the plan has moved the writes to the front) as many
+      trips of eight as hold them.
     aux_ref: (8, block) int32 VMEM — row 0: per lane, the block-local index
       of the last lane of its run (clipped to the block); row 1: whether
       the block's first lane continues the previous block's run.
@@ -326,29 +351,79 @@ def _kernel(tgt_ref, src_ref, writes_ref, aux_ref, old_ref, dl_ref, state_ref,
     carry_ref[:] = prefix[block - 1:block, :]
     buf_ref[slot] = old_ref[:] + prefix
 
-    @pl.when(writes_ref[b] > 0)
-    def _start():
-        # eight lanes a trip: Mosaic unrolls a loop wholly or not at all
-        def group(g, _):
-            for k in range(8):
-                lane = base + g * 8 + k
-                pltpu.make_async_copy(
-                    buf_ref.at[slot, pl.ds(src_ref[lane], 1)],
-                    out_ref.at[pl.ds(tgt_ref[lane], 1)],
-                    sem.at[slot],
-                ).start()
-            return 0
+    _send_and_await(tgt_ref, src_ref, writes_ref, buf_ref, out_ref, sem,
+                    slot, base, block=block, compact=compact)
 
-        jax.lax.fori_loop(0, block // 8, group, 0)
+
+def _send_and_await(tgt_ref, src_ref, writes_ref, buf_ref, out_ref, sem, slot,
+                    base, *, block: int, compact: bool):
+    """The walk both row kernels end a grid step with: send block ``b``'s
+    staged rows (``buf_ref[slot]``, ``slot = b % 2``), one single-row DMA an
+    entry of its stretch of ``tgt_ref`` / ``src_ref`` (which begins at
+    ``base = b x block``), and await the block before's, the last block its
+    own too: a block's writes are in flight while the next is staged.
+
+    What a block issues is the plan's (:func:`_plan`).  ``compact`` false
+    (a keyed stream: nearly every lane writes): all ``block`` entries,
+    eight a trip of a loop of static length, answered by ONE wait the size
+    of the staging slot (a DMA semaphore counts bytes, and exactly
+    ``block`` rows were sent).  ``compact`` true (a batch handed over
+    BECAUSE its ids repeat, a write-back that dropped lanes itself): the
+    block's ``writes_ref[b]`` writing entries lie first, and the loop runs
+    ``ceil(writes / 8)`` trips, the last trip's spare entries repeating the
+    block's first write as every entry past the writes does; the waits
+    answer exactly those bytes, four trips (32 rows) a wait and the rest a
+    trip a wait (a wait for more bytes than were sent hangs the chip, and
+    the interpreter cannot see it).  On the v5e the one scalar core issues
+    a descriptor in ~10 ns and a wait in 6.5; sixteen or thirty-two entries
+    a trip, and waits folded by two, eight or not at all, read within 0.1
+    ms of this form over cell 9's thirteen calls, 11.70-11.99 ms (PERF.md
+    section 6, PR 54)."""
+    pl, pltpu = _pallas()
+    lax = jax.lax
+
+    b = pl.program_id(0)
+
+    def trips(blk):  # compact: the trips of eight that hold a block's writes
+        return lax.shift_right_logical(lax.add(writes_ref[blk], 7), 3)
+
+    # eight entries a trip: Mosaic unrolls a loop wholly or not at all
+    def group(g, _):
+        for k in range(8):
+            at = base + g * 8 + k
+            pltpu.make_async_copy(
+                buf_ref.at[slot, pl.ds(src_ref[at], 1)],
+                out_ref.at[pl.ds(tgt_ref[at], 1)],
+                sem.at[slot],
+            ).start()
+        return 0
+
+    if compact:
+        lax.fori_loop(0, trips(b), group, 0)
+    else:
+        @pl.when(writes_ref[b] > 0)
+        def _start():
+            lax.fori_loop(0, block // 8, group, 0)
 
     def wait_for(blk, s):
-        # a DMA semaphore counts bytes: one wait the size of the staging
-        # slot answers the block's ``block`` single-row copies together
-        @pl.when(writes_ref[blk] > 0)
-        def _():
-            pltpu.make_async_copy(
-                buf_ref.at[s], buf_ref.at[s], sem.at[s]
-            ).wait()
+        def rows(count):  # a wait that answers ``count`` single-row copies
+            def wait(j, _):
+                part = buf_ref.at[s, pl.ds(0, count)]
+                pltpu.make_async_copy(part, part, sem.at[s]).wait()
+                return 0
+
+            return wait
+
+        if compact:
+            sent = trips(blk)
+            lax.fori_loop(0, lax.shift_right_logical(sent, 2), rows(32), 0)
+            lax.fori_loop(0, lax.bitwise_and(sent, 3), rows(8), 0)
+        else:
+            @pl.when(writes_ref[blk] > 0)
+            def _():
+                pltpu.make_async_copy(
+                    buf_ref.at[s], buf_ref.at[s], sem.at[s]
+                ).wait()
 
     @pl.when(b > 0)
     def _previous():
@@ -359,8 +434,28 @@ def _kernel(tgt_ref, src_ref, writes_ref, aux_ref, old_ref, dl_ref, state_ref,
         wait_for(b, slot)
 
 
-def _plan(sorted_ids: Array, rows: int, block: int):
-    """The kernel's per-lane scalars and vectors from the sorted ids."""
+def _plan(sorted_ids: Array, rows: int, block: int, compact: bool = False):
+    """The kernel's per-lane scalars and vectors from the sorted ids:
+    ``(tgt, src, count, aux)`` as :func:`_kernel` reads them.  A lane WRITES
+    if it is the last of its run and its id names a row; ``count`` is a
+    block's writing lanes.
+
+    ``compact`` false: a writing lane's entry of ``tgt`` / ``src`` lies at
+    the lane, and every other entry repeats its block's first write: a
+    block that writes issues all ``block`` of them.  Nothing is moved, which
+    is what a keyed stream wants (MF's users: 99.3 % of the lanes write).
+
+    ``compact`` true: a block's writing entries lie FIRST in its stretch, in
+    lane order (single-row DMAs to distinct rows may go in any order), the
+    rest repeat the first: a block issues ``ceil(count / 8)`` trips of
+    eight (:func:`descriptors`), at most seven entries over what it writes.
+    One sort along the block axis of ``(n / block, block)`` carries the
+    rows along, as :func:`_tile_plan` moves its tile rows (a
+    ``take_along_axis`` would be a gather of scalars, 10 ns each on the
+    TPU).  That sort is what a caller pays to drop its repeats: on the v5e
+    10.5 us a call of 98,304 lanes (cell 9's thirteen ``sort s32[384,256]``
+    0.137 ms a step, its write-back's eleven ``s32[128,256]`` 0.041:
+    PERF.md section 6, PR 54)."""
     n = sorted_ids.shape[0]
     iota = jnp.arange(n, dtype=jnp.int32)
     differs = sorted_ids[1:] != sorted_ids[:-1]
@@ -384,6 +479,18 @@ def _plan(sorted_ids: Array, rows: int, block: int):
          jnp.zeros((n // block, 6, block), jnp.int32)], axis=1,
     )
     writes = by_block(is_last & (sorted_ids < rows))
+    if compact:
+        key, tgt = jax.lax.sort(
+            (jnp.where(writes, local, local + block), by_block(sorted_ids)),
+            dimension=1, num_keys=1,
+        )
+        count = jnp.sum(writes, axis=1, dtype=jnp.int32)
+        spare = local >= count[:, None]
+        # (a block that writes nothing issues nothing: its entries only
+        # have to be in range)
+        src = jnp.where(spare, key[:, :1], key) % block
+        tgt = jnp.where(spare, jnp.minimum(tgt[:, :1], rows - 1), tgt)
+        return tgt.reshape(-1), src.reshape(-1), count, aux
     first_write = jnp.argmax(writes, axis=1).astype(jnp.int32)[:, None]
     src = jnp.where(writes, local, first_write)
     # (a block-sized take_along_axis by ``src`` would be a gather of
@@ -397,12 +504,25 @@ def _plan(sorted_ids: Array, rows: int, block: int):
     return tgt.reshape(-1), src.reshape(-1), count, aux
 
 
+def descriptors(count: Array, compact: bool, block: int = BLOCK) -> Array:
+    """The single-row DMAs the walk issues for blocks whose writing lanes
+    number ``count`` (int32, a block an entry), summed: a block that writes
+    sends all ``block`` entries of its stretch, under the compact plan the
+    trips of eight that hold its writes (:func:`_send_and_await`)."""
+    if compact:
+        sent = jax.lax.shift_right_logical(count + 7, 3) * 8
+    else:
+        sent = jnp.where(count > 0, block, 0)
+    return jnp.sum(sent, dtype=jnp.int32)
+
+
 def sorted_row_update(
     state: Array,
     sorted_ids: Array,
     old_rows: Array,
     deltas: Array,
     *,
+    compact: bool = False,
     interpret: Optional[bool] = None,
 ) -> Array:
     """``state[r] = old_rows[k] + sum(deltas[lanes of r])`` for every row
@@ -414,10 +534,34 @@ def sorted_row_update(
     row carry equal values).  ``deltas``: (n, W).  A dropped lane's old row
     and delta may be anything, NaN included: its run writes nothing.
 
+    ``compact`` is what the CALLER knows of its ids by construction, and
+    chooses the plan (:func:`_plan`): false for a keyed stream, whose lanes
+    nearly all write (the walk then issues a DMA a lane and the plan moves
+    nothing); true for a batch whose ids repeat, which pays one more sort of
+    its block-shaped scalars and issues a DMA a row it WRITES.  The same
+    rows get the same bytes either way.
+
     The state is updated in place when the enclosing jit donates it; an
     eager call copies it first.  Off the TPU the kernel is interpreted
     (``interpret=None``: by the default backend).
     """
+    return sorted_row_update_counted(
+        state, sorted_ids, old_rows, deltas, compact=compact,
+        interpret=interpret,
+    )[0]
+
+
+def sorted_row_update_counted(
+    state: Array,
+    sorted_ids: Array,
+    old_rows: Array,
+    deltas: Array,
+    *,
+    compact: bool = False,
+    interpret: Optional[bool] = None,
+) -> Tuple[Array, Array]:
+    """:func:`sorted_row_update`'s state and, as an int32 scalar on the
+    device, the single-row DMAs the call issued (:func:`descriptors`)."""
     pl, pltpu = _pallas()
 
     if interpret is None:
@@ -438,7 +582,7 @@ def sorted_row_update(
         )
         deltas = jnp.pad(deltas, ((0, pad), (0, 0)))
         old_rows = jnp.pad(old_rows, ((0, pad), (0, 0)))
-    tgt, src, count, aux = _plan(sorted_ids, rows, block)
+    tgt, src, count, aux = _plan(sorted_ids, rows, block, compact)
     if not isinstance(state, jax.core.Tracer):
         state = jnp.copy(state)
 
@@ -458,8 +602,8 @@ def sorted_row_update(
             pltpu.SemaphoreType.DMA((2,)),
         ],
     )
-    return pl.pallas_call(
-        functools.partial(_kernel, block=block),
+    state = pl.pallas_call(
+        functools.partial(_kernel, block=block, compact=compact),
         out_shape=jax.ShapeDtypeStruct(state.shape, state.dtype),
         grid_spec=grid_spec,
         input_output_aliases={6: 0},  # (tgt, src, count, aux, old, deltas, state)
@@ -469,6 +613,7 @@ def sorted_row_update(
         interpret=interpret,
         name="sorted_row_update",
     )(tgt, src, count, aux, old_rows, deltas, state)
+    return state, descriptors(count, compact, block)
 
 
 def _calls(sorted_ids: Array, order: Array):
@@ -543,48 +688,19 @@ def _row_set_kernel(tgt_ref, src_ref, writes_ref, new_ref, state_ref, out_ref,
                     buf_ref, sem, *, block: int):
     """:func:`_kernel`'s walk with nothing to sum: one grid step = ``block``
     lanes whose new rows (``new_ref``, (block, W) VMEM) are staged as they
-    are and sent, one single-row DMA a lane, to the rows ``tgt_ref`` names
-    (``src_ref`` / ``writes_ref`` as there: a lane that writes nothing
-    repeats its block's first write).  A block's writes stay in flight
-    while the next is staged and are awaited one block later."""
-    pl, pltpu = _pallas()
+    are and sent, one single-row DMA a lane that WRITES, to the rows
+    ``tgt_ref`` names (``src_ref`` / ``writes_ref`` as there, the compact
+    plan's: the dropped lanes lie between the writes, and the block issues
+    as many trips of eight as hold its writes).  A block's writes stay in
+    flight while the next is staged and are awaited one block later."""
+    pl, _ = _pallas()
 
     del state_ref  # aliased to out_ref: untouched rows keep their values
     b = pl.program_id(0)
     slot = b % 2
-    base = b * block
     buf_ref[slot] = new_ref[:]
-
-    @pl.when(writes_ref[b] > 0)
-    def _start():
-        # eight lanes a trip: Mosaic unrolls a loop wholly or not at all
-        def group(g, _):
-            for k in range(8):
-                lane = base + g * 8 + k
-                pltpu.make_async_copy(
-                    buf_ref.at[slot, pl.ds(src_ref[lane], 1)],
-                    out_ref.at[pl.ds(tgt_ref[lane], 1)],
-                    sem.at[slot],
-                ).start()
-            return 0
-
-        jax.lax.fori_loop(0, block // 8, group, 0)
-
-    def wait_for(blk, s):
-        # one wait the size of the staging slot answers a block's copies
-        @pl.when(writes_ref[blk] > 0)
-        def _():
-            pltpu.make_async_copy(
-                buf_ref.at[s], buf_ref.at[s], sem.at[s]
-            ).wait()
-
-    @pl.when(b > 0)
-    def _previous():
-        wait_for(b - 1, 1 - slot)
-
-    @pl.when(b == pl.num_programs(0) - 1)
-    def _own():
-        wait_for(b, slot)
+    _send_and_await(tgt_ref, src_ref, writes_ref, buf_ref, out_ref, sem,
+                    slot, b * block, block=block, compact=True)
 
 
 def sorted_row_set(
@@ -597,10 +713,11 @@ def sorted_row_set(
     """``state[r] = new_rows[k]`` for every row ``r`` a kept lane ``k``
     names, bit for bit (a copy, no arithmetic); every other row is left as
     it is.  The row kernel's walk (:func:`sorted_row_update`) with a set for
-    its body: on the v5e XLA's ``set`` of 32,768 sorted distinct 128-lane
-    rows is 72 ns a lane, kept or dropped, serial; a single-row DMA out of
-    a staged block is what the row kernel issues at under 10 (PERF.md
-    section 6, PR 47).
+    its body, under the compact plan (a DMA a row WRITTEN: the dropped lanes
+    cost their block's base and no descriptor): on the v5e XLA's ``set`` of
+    32,768 sorted distinct 128-lane rows is 72 ns a lane, kept or dropped,
+    serial; a single-row DMA out of a staged block is what the row kernel
+    issues at 10-13 (PERF.md section 6, PRs 47 and 54).
 
     ``state``: (rows, 128) float32 (:func:`refusal`).  ``ids``: (n,) int32,
     the kept lanes' DISTINCT (two lanes may not name one row: their DMAs
@@ -626,8 +743,9 @@ def sorted_row_set(
     if pad:
         ids = jnp.concatenate([ids, jnp.full((pad,), _INT32_MAX, jnp.int32)])
         new_rows = jnp.pad(new_rows, ((0, pad), (0, 0)))
-    # distinct ids: every kept lane is the last of its run, and writes
-    tgt, src, count, _ = _plan(ids, rows, block)
+    # distinct ids: every kept lane is the last of its run, and writes; the
+    # caller's dropped lanes lie between them, so the plan compacts
+    tgt, src, count, _ = _plan(ids, rows, block, compact=True)
     if not isinstance(state, jax.core.Tracer):
         state = jnp.copy(state)
 
@@ -1254,9 +1372,9 @@ def scatter_add(
 
 
 __all__ = [
-    "BLOCK", "MAX_LANES", "note_refusal", "preload", "refusal",
+    "BLOCK", "MAX_LANES", "descriptors", "note_refusal", "preload", "refusal",
     "refusal_count", "row_add", "scatter_add", "scatter_add_counted",
-    "set_refusal", "sort_by_row",
-    "sorted_row_set", "sorted_row_update", "sorted_tile_add",
-    "sorted_tile_set", "tile_refusal",
+    "set_refusal", "sort_by_row", "sorted_row_set", "sorted_row_update",
+    "sorted_row_update_counted", "sorted_tile_add", "sorted_tile_set",
+    "tile_refusal",
 ]

@@ -376,7 +376,8 @@ class StreamingDriver:
         rewrote and the tiles of
         128 rows its write-back moved to do so: ``store_rule_keys``,
         ``store_rule_rows``, ``store_rule_tiles``; for wide rows the lanes
-        the row kernel summed: ``store_combine_kernel_lanes``; where the
+        the row kernel summed and the single-row DMAs it issued for them:
+        ``store_combine_kernel_lanes``, ``store_combine_kernel_writes``; where the
         push ran on the shards that own the rows, the fullest shard's keys
         and rows: ``store_rule_keys_max_shard``,
         ``store_rule_rows_max_shard``) and a logic
@@ -483,6 +484,11 @@ class StreamingDriver:
             self.registry.gauge(
                 "store_combine_kernel_lanes", component="train"
             ).set(total(outs["ps_combine_kernel_lanes"]))
+            # ... and the single-row DMAs the kernel issued to sum them:
+            # over the lanes, the share of the walk that writes
+            self.registry.gauge(
+                "store_combine_kernel_writes", component="train"
+            ).set(total(outs["ps_combine_kernel_writes"]))
         if "ps_rule_packed_rows" in outs:
             # a packed rule store: the physical rows its write-back wrote
             self.registry.gauge(

@@ -434,6 +434,42 @@ def test_the_driver_sets_the_gate_s_gauges_after_the_loop():
     assert np.isfinite(np.asarray(result.store.table)).all()
 
 
+@pytest.mark.parametrize("arm", ["xla", "row_kernel"])
+def test_the_driver_publishes_the_descriptors_the_combine_issued(
+        arm, monkeypatch):
+    """``store_combine_kernel_writes`` beside ``store_combine_kernel_lanes``:
+    with the row kernel steered on (interpreted here) a DMA a distinct row
+    of the last dispatch, up to a trip of eight a block, where the lanes are
+    its live keys; both 0 where XLA's scatter-add summed the rows."""
+    from flink_parameter_server_tpu.core import store as store_mod
+    from flink_parameter_server_tpu.telemetry.registry import MetricsRegistry
+
+    if arm == "row_kernel":
+        monkeypatch.setattr(
+            store_mod, "_combine_kernel_takes", lambda spec: True)
+    rng = np.random.default_rng(54)
+    ids = rng.integers(0, 100, (3, 128, 4))
+    ids[:, :, 0] = 7  # an integer field's row: every example names it
+    batches = [_batch(i, rng) for i in ids]
+    registry = MetricsRegistry()
+    driver = StreamingDriver(
+        df.DiFacto(df.DiFactoConfig(100, DIM)), _store(_rows(9, n=100)),
+        config=DriverConfig(steps_per_call=1, dump_model=False),
+        registry=registry,
+    )
+    driver.run(iter(batches))
+    gauges = registry.snapshot()
+    lanes = gauges["store_combine_kernel_lanes"][0]["value"]
+    writes = gauges["store_combine_kernel_writes"][0]["value"]
+    if arm == "xla":
+        assert lanes == 0 == writes
+        return
+    rows = gauges["store_rule_rows"][0]["value"]
+    assert lanes == gauges["store_rule_keys"][0]["value"] == 512
+    assert rows == len(np.unique(ids[-1])) <= writes <= rows + 7 * 2
+    assert writes < lanes / 3  # a row is named five times on average
+
+
 def test_the_logics_scopes_are_in_the_lowered_step():
     store = _store(_rows(1, n=20))
     logic = df.DiFacto(df.DiFactoConfig(20, DIM))
@@ -479,7 +515,7 @@ def test_wide_rows_are_summed_in_stream_order_bit_for_bit(width, n):
     ids[: n // 3] = 11  # one hot row
     ids[rng.random(n) < 0.1] = sentinel  # lanes to drop
     vals = rng.normal(size=(n, width)).astype(np.float32)
-    row_ids, sums = jax.jit(combine_runs, static_argnums=2)(ids, vals, sentinel)
+    row_ids, sums, _ = jax.jit(combine_runs, static_argnums=2)(ids, vals, sentinel)
     distinct = np.unique(ids[ids < sentinel])
     assert np.array_equal(np.asarray(row_ids)[: len(distinct)], distinct)
     assert (np.asarray(row_ids)[len(distinct):] == sentinel).all()
@@ -534,9 +570,9 @@ def test_wide_rows_are_summed_along_sorted_lanes_by_the_row_kernel(
 
     monkeypatch.setattr(row_update, "MAX_LANES", 512)
     calls = []
-    real = row_update.sorted_row_update
+    real = row_update.sorted_row_update_counted
     monkeypatch.setattr(
-        row_update, "sorted_row_update",
+        row_update, "sorted_row_update_counted",
         lambda *a, **kw: calls.append(a[1].shape[0]) or real(*a, **kw))
     rng = np.random.default_rng([width, n])
     sentinel = 5000
@@ -547,11 +583,17 @@ def test_wide_rows_are_summed_along_sorted_lanes_by_the_row_kernel(
         lane = int(np.flatnonzero(ids == 11)[3])
         nan_at, vals[lane, 2] = (11, 2), np.nan
         vals[ids == sentinel] = np.nan  # a dropped lane's reaches nothing
-    row_ids, sums = jax.jit(
+    row_ids, sums, sent = jax.jit(
         lambda i, v: combine_runs(i, v, sentinel, kernel=True, interpret=True)
     )(ids, vals)
     # one traced kernel under a loop, whole blocks, no more lanes than a call holds
     assert len(calls) == 1 and calls[0] <= 512 and calls[0] % 256 == 0
+    # the walk pays by what it writes: the stretches that hold a live lane, a
+    # DMA a run (a run across two stretches is written by both) and at most
+    # seven spare a block of 256
+    walked = -(-int((ids < sentinel).sum()) // calls[0])
+    runs = len(np.unique(ids[ids < sentinel]))
+    assert runs <= int(sent) <= runs + walked + 7 * walked * (calls[0] // 256)
     row_ids, sums = np.asarray(row_ids), np.array(sums)
     assert row_ids.shape == (n,) and sums.shape == (n, width)
     assert sums.dtype == np.float32

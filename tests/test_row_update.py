@@ -70,6 +70,22 @@ def _case(name):
     elif name == "batch_not_a_multiple_of_block":
         ids = ids[:1000]
         poison = poison[:1000]
+    elif name == "every_lane_distinct":
+        rows = 2048
+        ids = rng.permutation(rows)[:n].astype(np.int32)
+    elif name == "blocks_that_write_1_7_8_9_and_256":
+        # sorted, six blocks of 256 lanes: one row; 7, 8 and 9 rows (the
+        # last of each filling its block); a row a lane; nothing but
+        # dropped lanes
+        ids = np.concatenate([
+            np.zeros(256), 10 + np.minimum(np.arange(256), 6),
+            20 + np.minimum(np.arange(256), 7),
+            30 + np.minimum(np.arange(256), 8), 100 + np.arange(256),
+            np.full(256, -1),
+        ]).astype(np.int32)
+        poison = ids < 0
+        keep = rng.permutation(n)
+        ids, poison = ids[keep], poison[keep]
     elif name != "uniform_few_duplicates":
         raise AssertionError(name)
     return rows, ids, mask, poison
@@ -80,13 +96,66 @@ CASES = [
     "ids_out_of_range_dropped", "rows_not_a_multiple_of_8", "all_masked",
     "every_id_equal", "batch_not_a_multiple_of_block", "most_lanes_dead",
 ]
+# the cases PR 54 brought with the compacting plan (the row kernel's tests
+# run them under both plans; the tile kernel's keep the list above)
+ROW_CASES = CASES + [
+    "every_lane_distinct", "blocks_that_write_1_7_8_9_and_256",
+]
+
+
+def _compact_plan_is_numpys(ids, rows, block):
+    """One call's compacting plan against numpy: a block's writing lanes
+    (the last of each run of a row's id) lie first in its stretch, in lane
+    order, every other entry repeats the first, and the descriptors it
+    issues are ``ceil(count / 8) x 8`` a block.  Returns them."""
+    ids = np.asarray(ids, np.int32)
+    ids = np.concatenate(
+        [ids, np.full(-len(ids) % block, np.iinfo(np.int32).max, np.int32)])
+    tgt, src, count, _ = (np.asarray(x) for x in row_update._plan(
+        jnp.asarray(ids), rows, block, compact=True))
+    last = np.concatenate([ids[1:] != ids[:-1], [True]]) & (ids < rows)
+    sent = 0
+    for b in range(len(ids) // block):
+        at = slice(b * block, (b + 1) * block)
+        lanes = np.flatnonzero(last[at])
+        c = len(lanes)
+        assert count[b] == c
+        assert np.array_equal(src[at][:c], lanes)
+        assert np.array_equal(tgt[at][:c], ids[at][lanes])
+        if c:
+            assert (src[at][c:] == lanes[0]).all()
+            assert (tgt[at][c:] == ids[at][lanes[0]]).all()
+        assert ((src[at] >= 0) & (src[at] < block)).all()
+        assert ((tgt[at] >= 0) & (tgt[at] < rows)).all()
+        sent += -(-c // 8) * 8
+    assert int(row_update.descriptors(jnp.asarray(count), True, block)) == sent
+    return sent
+
+
+def _row_add_under_the_compact_plan(monkeypatch):
+    """``row_add`` with its kernel under the plan for ids that repeat;
+    ``issued`` collects the descriptors each call counted."""
+    issued = []
+
+    def update(*args, **kw):
+        state, sent = row_update.sorted_row_update_counted(
+            *args, compact=True, **kw)
+        issued.append(sent)
+        return state
+
+    monkeypatch.setattr(row_update, "sorted_row_update", update)
+    return issued
 
 
 @pytest.mark.parametrize("block", [128, 256])
-@pytest.mark.parametrize("name", CASES)
-def test_row_add_matches_numpy_scatter_add(name, block, monkeypatch):
+@pytest.mark.parametrize("compact", [False, True])
+@pytest.mark.parametrize("name", ROW_CASES)
+def test_row_add_matches_numpy_scatter_add(name, compact, block, monkeypatch):
     """The caller hands over the rows it has gathered, as the MF step does;
-    a dropped lane's row and delta are garbage."""
+    a dropped lane's row and delta are garbage.  Under the compacting plan
+    (a combine's: ``ops/dedup._kernel_sums``) the same table bit for bit,
+    and a DMA descriptor a row written, the spare lanes of a trip of eight
+    aside."""
     monkeypatch.setattr(row_update, "BLOCK", block)
     rows, ids, mask, poison = _case(name)
     rng = np.random.default_rng(5)
@@ -95,9 +164,31 @@ def test_row_add_matches_numpy_scatter_add(name, block, monkeypatch):
     deltas[poison] = np.nan
     old = state[np.clip(ids, 0, rows - 1)]
     old[poison] = np.nan
-    got = np.asarray(jax.jit(
-        lambda s, i, o, d, m: row_update.row_add(s, i, o, d, m, interpret=True)
-    )(state, ids, old, deltas, mask))
+
+    def run(issued=()):
+        def add(s, i, o, d, m):
+            table = row_update.row_add(s, i, o, d, m, interpret=True)
+            return table, sum(issued, jnp.zeros((), jnp.int32))
+
+        return jax.jit(add)(state, ids, old, deltas, mask)
+
+    got = np.asarray(run()[0])
+    if compact:
+        table, sent = run(_row_add_under_the_compact_plan(monkeypatch))
+        keyed, got = got, np.asarray(table)
+        assert got.tobytes() == keyed.tobytes()
+        sid, _ = row_update.sort_by_row(
+            jnp.asarray(ids), None if mask is None else jnp.asarray(mask),
+            rows)
+        want_sent = _compact_plan_is_numpys(sid, rows, block)
+        assert int(sent) == want_sent
+        if name == "blocks_that_write_1_7_8_9_and_256" and block == 256:
+            assert want_sent == 8 + 8 + 8 + 16 + 256
+        live = np.ones(ids.shape, bool) if mask is None else mask
+        touched = len(np.unique(ids[live & (ids >= 0) & (ids < rows)]))
+        # a row whose run crosses a block is written once a call all the
+        # same: only the LAST lane of a run writes
+        assert touched <= want_sent <= touched + 7 * -(-len(ids) // block)
     want = numpy_scatter_add(state, ids, deltas, mask)
     assert np.isfinite(got).all()
     # float32 sums of up to 1,000 deltas against float64
@@ -118,12 +209,17 @@ def test_eager_call_leaves_the_callers_state_alone():
     assert float(out[5, 0]) == 2.0
 
 
+@pytest.mark.parametrize("compact", [False, True])
 @pytest.mark.parametrize("bad", [np.nan, np.inf, -np.inf])
-def test_a_non_finite_delta_in_a_kept_lane_stays_in_its_row(bad):
+def test_a_non_finite_delta_in_a_kept_lane_stays_in_its_row(
+        bad, compact, monkeypatch):
     """The XLA scatter confines a bad record to its row; so does the mask
     matmul (0 x NaN would poison the block): the row's element reads
     non-finite, its other elements and every other row are summed as ever,
-    across a block boundary too (row 7 fills lanes of two blocks)."""
+    across a block boundary too (row 7 fills lanes of two blocks).  Under
+    either plan."""
+    if compact:
+        _row_add_under_the_compact_plan(monkeypatch)
     rng = np.random.default_rng(3)
     rows, n = 64, 512
     ids = np.sort(rng.integers(0, rows, n)).astype(np.int32)
@@ -196,12 +292,15 @@ def test_tile_refusal_names_what_the_tile_kernel_cannot_take(
 
 
 # -- a batch over one call's lanes -------------------------------------------
+@pytest.mark.parametrize("compact", [False, True])
 @pytest.mark.parametrize("limit", [256, 512, 1024])
 def test_row_add_over_the_lane_limit_equals_one_call_and_xla(
-        limit, monkeypatch):
+        limit, compact, monkeypatch):
     """``hit_1_2_1000_times`` has a run of a thousand lanes: at a limit of
     256 lanes a call it lies across five calls, each of which writes the
-    row; the later ones read it again from the state."""
+    row; the later ones read it again from the state.  Under either plan."""
+    if compact:
+        _row_add_under_the_compact_plan(monkeypatch)
     rows, ids, mask, _ = _case("hit_1_2_1000_times")
     rng = np.random.default_rng(7)
     state = rng.normal(size=(rows, WIDTH)).astype(np.float32)
@@ -706,6 +805,7 @@ ROW_SET_CASES = [
     "sorted_distinct", "dropped_between_the_kept", "every_lane_dropped",
     "a_block_that_writes_nothing", "not_whole_blocks", "one_lane",
     "nan_inf_and_minus_zero", "unsorted_distinct",
+    "dropped_at_the_end", "blocks_that_write_1_7_8_9_and_256",
 ]
 
 
@@ -729,6 +829,16 @@ def test_sorted_row_set_is_xlas_row_set_bit_for_bit(name):
         ids, n = ids[:1], 1
     elif name == "unsorted_distinct":
         ids = rng.permutation(ids)
+    elif name == "dropped_at_the_end":
+        ids[500:] = rows_n
+    elif name == "blocks_that_write_1_7_8_9_and_256":
+        # five blocks of 256 lanes, the kept ones between dropped ones
+        rows_n, n = 2000, 1280
+        ids = np.sort(rng.choice(rows_n, n, replace=False)).astype(np.int32)
+        kept = np.zeros(n, bool)
+        for b, c in enumerate((1, 7, 8, 9, 256)):
+            kept[b * 256 + rng.choice(256, c, replace=False)] = True
+        ids[~kept] = rows_n
     state = rng.normal(size=(rows_n, WIDTH)).astype(np.float32)
     new = rng.normal(size=(n, WIDTH)).astype(np.float32)
     if name == "nan_inf_and_minus_zero":
@@ -744,6 +854,12 @@ def test_sorted_row_set_is_xlas_row_set_bit_for_bit(name):
     jitted = jax.jit(row_update.sorted_row_set, donate_argnums=0)(
         jnp.asarray(state), jnp.asarray(ids), jnp.asarray(new))
     assert np.asarray(jitted).tobytes() == want.tobytes()
+    # a descriptor a row written, the spare lanes of a trip of eight aside
+    sent = _compact_plan_is_numpys(ids, rows_n, row_update.BLOCK)
+    written = int((ids < rows_n).sum())
+    assert written <= sent <= written + 7 * -(-n // row_update.BLOCK)
+    if name == "blocks_that_write_1_7_8_9_and_256":
+        assert sent == 8 + 8 + 8 + 16 + 256
 
 
 def test_sorted_row_set_refuses_what_the_row_kernel_refuses():

@@ -520,9 +520,9 @@ def test_push_pull_case_table_wide_rule_rows(arm, shape, traffic, monkeypatch):
         monkeypatch.setattr(
             store_mod, "_combine_kernel_takes", lambda spec: True)
         monkeypatch.setattr(row_update, "MAX_LANES", 512)
-        real = row_update.sorted_row_update
+        real = row_update.sorted_row_update_counted
         monkeypatch.setattr(
-            row_update, "sorted_row_update",
+            row_update, "sorted_row_update_counted",
             lambda *a, **kw: calls.append(a[1].shape[0]) or real(*a, **kw))
     # not `_push`: a program traced for the other arm would be reused
     push = jax.jit(lambda st, i, d, m: st.push(i, d, m))
@@ -1231,7 +1231,10 @@ def test_combine_runs_sums_each_id_and_moves_the_distinct_first(width):
     ids[rng.random(n) < 0.1] = sentinel            # lanes to drop
     ids[:200] = 7                                   # a long run
     vals = rng.normal(size=(n, width)).astype(np.float32)
-    row_ids, sums = jax.jit(combine_runs, static_argnums=2)(ids, vals, sentinel)
+    row_ids, sums, writes = jax.jit(combine_runs, static_argnums=2)(
+        ids, vals, sentinel)
+    # a narrow row rides through the sort; off the TPU nothing issues a DMA
+    assert writes is None if width <= 4 else int(writes) == 0
     row_ids, sums = np.asarray(row_ids), np.asarray(sums)
     distinct = np.unique(ids[ids < sentinel])
     assert row_ids.shape == (n,) and sums.shape == (n, width)
@@ -1592,7 +1595,7 @@ def test_the_row_kernels_inside_the_shard_map_sum_and_write_what_xla_does(
     monkeypatch.setattr(store_mod, "_set_kernel_takes", lambda spec: True)
     monkeypatch.setattr(store_mod, "_combine_kernel_takes", lambda spec: True)
     monkeypatch.setattr(row_update, "MAX_LANES", 512)
-    for name in ("sorted_row_update", "sorted_row_set"):
+    for name in ("sorted_row_update_counted", "sorted_row_set"):
         real = getattr(row_update, name)
         monkeypatch.setattr(
             row_update, name,
@@ -1601,7 +1604,7 @@ def test_the_row_kernels_inside_the_shard_map_sum_and_write_what_xla_does(
     got, counted = jax.jit(
         lambda t, i, d, m: store_mod.push_counted(spec, t, i, d, m)
     )(sharded.table, *args)
-    assert {"sorted_row_update", "sorted_row_set"} <= set(calls)
+    assert {"sorted_row_update_counted", "sorted_row_set"} <= set(calls)
     want = np.asarray(ShardedParamStore(spec, want).values())
     got = np.asarray(ShardedParamStore(spec, got).values())
     np.testing.assert_allclose(got, want, rtol=2e-6, atol=2e-6)
@@ -1610,10 +1613,163 @@ def test_the_row_kernels_inside_the_shard_map_sum_and_write_what_xla_does(
     once = named[(times == 1) & (named < cap)]
     assert once.size > 50 and got[once].tobytes() == want[once].tobytes()
     for name in counted_xla:
-        if name != "ps_combine_kernel_lanes":
+        if not name.startswith("ps_combine_kernel_"):
             assert int(counted[name]) == int(counted_xla[name]), name
     assert int(counted_xla["ps_combine_kernel_lanes"]) == 0
     assert int(counted["ps_combine_kernel_lanes"]) == live.sum()
+    # a DMA a distinct row of a shard, the spare lanes of a trip aside
+    assert int(counted_xla["ps_combine_kernel_writes"]) == 0
+    assert int(counted["ps_rule_rows"]) <= int(
+        counted["ps_combine_kernel_writes"]) < live.sum()
+
+
+def _combine_descriptors(ids, lanes, size, block=256):
+    """numpy: the single-row DMAs ``ops/dedup._kernel_sums`` issues for a
+    batch of ``lanes`` lanes whose LIVE ids are ``ids``, a stretch of
+    ``size`` sorted lanes a call: the stretches that hold a live lane are
+    walked, and a block of a walked stretch sends ``ceil(count / 8)`` trips
+    of eight for its ``count`` lanes that end a run."""
+    dead = np.iinfo(np.int32).max
+    calls = -(-lanes // size)
+    slot = np.full(calls * size, dead, np.int64)
+    slot[:len(ids)] = np.unique(ids, return_inverse=True)[1]  # sorted ranks
+    slot[:len(ids)].sort()
+    sent = 0
+    for lo in range(0, -(-len(ids) // size) * size, size):
+        call = slot[lo:lo + size]
+        last = np.concatenate([call[1:] != call[:-1], [True]]) & (call < dead)
+        sent += int((-(-last.reshape(-1, block).sum(axis=1) // 8) * 8).sum())
+    return sent
+
+
+SHARD_OWNED = ["no_key", "one_key", "every_key", "spread"]
+
+
+@pytest.mark.parametrize("placed", ["one_place", "ps4"])
+@pytest.mark.parametrize("owned", SHARD_OWNED)
+def test_the_walk_pays_by_what_it_writes_and_the_push_is_the_parents(
+        owned, placed, ps_mesh, monkeypatch):
+    """A packed rule store's push with both kernels steered on and
+    interpreted, in one place and on the shards of a ``ps`` = 4 mesh: the
+    table is, bit for bit, what the parent's walk gives (the row kernel
+    under the plan that sends a DMA a LANE of a block that writes; the
+    write-back's rows set by XLA, which ``sorted_row_set`` is bit for bit:
+    tests/test_row_update.py), and ``ps_combine_kernel_writes`` says what
+    the walk paid: nothing for a shard that owns no key, one trip of eight
+    for one key, every stretch for every key (shard 2's, under the mesh)."""
+    from flink_parameter_server_tpu.core import store as store_mod
+    from flink_parameter_server_tpu.ops import row_update
+
+    width, cap, n, size = 36, 4800, 2048, 512
+    rng = np.random.default_rng([SHARD_OWNED.index(owned), placed == "ps4"])
+    values = rng.normal(size=(cap, width)).astype(np.float32)
+    store = ShardedParamStore.from_values(
+        jnp.asarray(values), update=_sticky_rule, layout="auto",
+        mesh=ps_mesh if placed == "ps4" else None)
+    spec = store.spec
+    assert spec.layout == "packed" and spec.pack == 3
+    block = spec.rows_per_shard * spec.pack if placed == "ps4" else cap
+    lo = 2 * block if placed == "ps4" else 0  # shard 2's rows
+    other = np.concatenate([np.arange(0, lo), np.arange(lo + block, cap)])
+    if owned == "spread" or (placed == "one_place" and owned == "every_key"):
+        ids = rng.integers(0, cap, n)
+        ids[:300] = lo + 7  # a run over a kernel block
+    elif owned == "every_key":
+        ids = lo + rng.integers(0, block, n)
+        ids[:300] = lo + 7
+    elif placed == "one_place":  # the whole batch: no key, one key
+        ids = np.full(n, -1)
+        ids[1234:1234 + (owned == "one_key")] = 77
+    else:  # shard 2 owns none or one of the batch's keys
+        ids = rng.choice(other, n)
+        ids[1234:1234 + (owned == "one_key")] = lo + 77
+    ids = ids.astype(np.int32)
+    deltas = rng.normal(size=(n, width)).astype(np.float32)
+    monkeypatch.setattr(store_mod, "_set_kernel_takes", lambda spec: True)
+    monkeypatch.setattr(store_mod, "_combine_kernel_takes", lambda spec: True)
+    monkeypatch.setattr(row_update, "MAX_LANES", size)
+
+    def push():
+        table, counted = jax.jit(
+            lambda t, i, d: store_mod.push_counted(spec, t, i, d)
+        )(store.table, jnp.asarray(ids), jnp.asarray(deltas))
+        return np.asarray(table), {k: int(v) for k, v in counted.items()}
+
+    got, counted = push()
+    real = row_update.sorted_row_update_counted
+    monkeypatch.setattr(
+        row_update, "sorted_row_update_counted",
+        lambda *a, compact, **kw: real(*a, compact=False, **kw))
+    monkeypatch.setattr(
+        row_update, "sorted_row_set",
+        lambda state, at, new, **kw: state.at[at].set(new, mode="drop"))
+    parents, counted_parents = push()
+    assert got.tobytes() == parents.tobytes()
+    writes = counted.pop("ps_combine_kernel_writes")
+    lanes_sent = counted_parents.pop("ps_combine_kernel_writes")
+    assert counted == counted_parents
+    live = ids[ids >= 0]
+    shards = [live] if placed == "one_place" else [
+        live[live // block == s] for s in range(4)]
+    assert writes == sum(_combine_descriptors(k, n, size) for k in shards)
+    assert counted["ps_combine_kernel_lanes"] == len(live)
+    distinct = sum(len(np.unique(k)) for k in shards)
+    assert counted["ps_rule_rows"] == distinct <= writes <= lanes_sent
+    # shard 2's keys (the whole batch's in one place): the stretches walked
+    mine = shards[-1] if placed == "one_place" else shards[2]
+    walked = -(-len(mine) // size)
+    before = np.asarray(store.table).reshape(got.shape)
+    rows = spec.rows_per_shard if placed == "ps4" else got.shape[0]
+    at = slice(2 * rows, 3 * rows) if placed == "ps4" else slice(None)
+    touched = int((got[at] != before[at]).any(axis=1).sum())
+    if owned == "no_key":
+        assert walked == 0 == touched == _combine_descriptors(mine, n, size)
+    elif owned == "one_key":
+        assert walked == 1 == touched and _combine_descriptors(mine, n, size) == 8
+    else:
+        assert touched == len(np.unique(mine // spec.pack))
+        if owned == "every_key":
+            assert walked == n // size == 4
+            # every key in one place: the shards' sum IS the one-place count
+            assert writes == _combine_descriptors(live, n, size)
+    if placed == "one_place":
+        assert writes == _combine_descriptors(live, n, size)
+
+
+@pytest.mark.parametrize("width,meshed,names", [
+    (3, False, {"ps_rule_keys", "ps_rule_rows", "ps_rule_tiles"}),  # cell 6
+    (4, False, {"ps_rule_keys", "ps_rule_rows", "ps_rule_tiles"}),
+    (100, False, {"ps_rule_keys", "ps_rule_rows", "ps_rule_tiles",
+                  "ps_combine_kernel_lanes", "ps_combine_kernel_writes"}),
+    (36, False, {"ps_rule_keys", "ps_rule_rows", "ps_rule_tiles",
+                 "ps_combine_kernel_lanes", "ps_combine_kernel_writes",
+                 "ps_rule_packed_rows"}),  # cell 9
+    (36, True, {"ps_rule_keys", "ps_rule_rows", "ps_rule_tiles",
+                "ps_combine_kernel_lanes", "ps_combine_kernel_writes",
+                "ps_rule_packed_rows", "ps_rule_keys_max_shard",
+                "ps_rule_rows_max_shard"}),  # cell 12
+])
+@pytest.mark.parametrize("lanes", [0, 64])
+def test_what_a_rule_store_counts_goes_with_its_row(
+        width, meshed, names, lanes, ps_mesh):
+    """A narrow rule store's step gains no output from the combine's kernel
+    (its row rides through the sort: cell 6's lowered text is pinned on
+    that); a wide one counts the kernel's lanes and its descriptors, both 0
+    off the TPU; an ``add`` store XLA's scatter-add took counts nothing."""
+    from flink_parameter_server_tpu.core import store as store_mod
+
+    values = jnp.zeros((600, width), jnp.float32)
+    store = ShardedParamStore.from_values(
+        values, update=_sticky_rule, layout="auto",
+        mesh=ps_mesh if meshed else None)
+    ids = jnp.arange(lanes, dtype=jnp.int32) % 50
+    deltas = jnp.ones((lanes, width), jnp.float32)
+    _, counted = store_mod.push_counted(store.spec, store.table, ids, deltas)
+    assert set(counted) == names
+    for name in names & {"ps_combine_kernel_lanes", "ps_combine_kernel_writes"}:
+        assert int(counted[name]) == 0
+    add = ShardedParamStore.from_values(values)
+    assert store_mod.push_counted(add.spec, add.table, ids, deltas)[1] is None
 
 
 @pytest.mark.parametrize("shape,dp,backend,combine,write_back,on_shards", [

@@ -121,15 +121,19 @@ def _shape(sharding, shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
+@pytest.mark.parametrize("compact", [False, True])
 @pytest.mark.parametrize("lanes", [BATCH, row_update.MAX_LANES])
 def test_row_update_kernel_compiles_at_the_mf_cells_shapes(
-        one_chip, no_compile_cache, lanes):
+        one_chip, no_compile_cache, lanes, compact):
     """Single-row dynamic-offset DMAs into 5,008,260 rows (no multiple of
     8) of 128 f32 lanes, 65,536 sorted lanes: Mosaic takes it, and as many
-    lanes as ``refusal`` lets through (their row ids fit SMEM)."""
+    lanes as ``refusal`` lets through (their row ids fit SMEM).  Under the
+    compacting plan too (PR 54: a loop of as many trips as a block's writes
+    fill, waits that answer those bytes; SMEM still holds two int32 a lane
+    and one a block)."""
     compiled = jax.jit(
         lambda st, ids, old, dl: row_update.sorted_row_update(
-            st, ids, old, dl, interpret=False),
+            st, ids, old, dl, compact=compact, interpret=False),
         donate_argnums=(0,),
     ).lower(
         _shape(one_chip, (USERS, DIM), jnp.float32),
@@ -142,6 +146,27 @@ def test_row_update_kernel_compiles_at_the_mf_cells_shapes(
     mem = compiled.memory_analysis()
     # in place: the 2.56 GB state is aliased, not copied
     assert mem.alias_size_in_bytes >= USERS * DIM * 4
+    assert mem.temp_size_in_bytes < 256 * 2 ** 20
+
+
+@pytest.mark.parametrize("lanes", [32_768, row_update.MAX_LANES])
+def test_row_set_kernel_compiles_at_cell_9_s_table(
+        one_chip, no_compile_cache, lanes):
+    """``sorted_row_set`` under the compacting plan (its only one) into
+    cell 9's 16,375,440 physical rows, at a chunk of the rule's loop and at
+    as many lanes as a call takes: in place, nothing table-sized beside it."""
+    compiled = jax.jit(
+        lambda st, ids, new: row_update.sorted_row_set(
+            st, ids, new, interpret=False),
+        donate_argnums=(0,),
+    ).lower(
+        _shape(one_chip, (16_375_440, DIM), jnp.float32),
+        _shape(one_chip, (lanes,), jnp.int32),
+        _shape(one_chip, (lanes, DIM), jnp.float32),
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 16_375_440 * DIM * 4
     assert mem.temp_size_in_bytes < 256 * 2 ** 20
 
 
@@ -275,6 +300,40 @@ def test_mf_step_with_a_batch_over_one_calls_lanes_takes_two_calls(
     assert len(calls) == 2, len(calls)
     assert not re.findall(rf"= f32\[{USERS},{DIM}\][^ ]* copy\(", text)
     assert compiled.memory_analysis().temp_size_in_bytes < 512 * 2 ** 20
+
+
+def test_the_keyed_streams_row_kernel_is_the_parents_op_for_op(one_chip):
+    """``row_add`` (MF's user state: cells 1, 3, 8, 11) keeps the plan that
+    issues a DMA a lane and a loop of static length: its Mosaic body,
+    printed without locations, is what PR 54's parent lowered (that PR gave
+    the combine and the write-back a compacting plan and left this caller
+    alone).  A change that means to move the MF cells' kernel brings its own
+    hash."""
+    import base64
+    import hashlib
+
+    from jax._src.interpreters import mlir as jax_mlir
+    from jax._src.lib.mlir import ir
+
+    text = jax.jit(
+        lambda st, ids, old, dl, m: row_update.row_add(
+            st, ids, old, dl, m, interpret=False),
+        donate_argnums=(0,),
+    ).lower(
+        _shape(one_chip, (USERS, DIM), jnp.float32),
+        _shape(one_chip, (BATCH,), jnp.int32),
+        _shape(one_chip, (BATCH, DIM), jnp.float32),
+        _shape(one_chip, (BATCH, DIM), jnp.float32),
+        _shape(one_chip, (BATCH,), jnp.bool_),
+    ).as_text()
+    bodies = re.findall(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22', text)
+    assert len(bodies) == 1
+    context = jax_mlir.make_ir_context()
+    context.allow_unregistered_dialects = True
+    with context:
+        asm = ir.Module.parse(base64.b64decode(bodies[0])).operation.get_asm(
+            enable_debug_info=False)
+    assert hashlib.sha256(asm.encode()).hexdigest()[:16] == "3f54df8566796c53"
 
 
 def test_mf_step_default_arm_on_tpu_is_the_row_kernel(
@@ -1385,6 +1444,19 @@ def test_difacto_step_holds_nothing_table_sized_beside_its_table(
     assert "ps.push/ps.combine/while/body" in update_call
     # the rule's loop and the stretches' (the flattens' two are cell 2's)
     assert len(re.findall(r" while\(", text)) == 4
+    # both end with what is live: the rule's with the last distinct row,
+    # since PR 54 the stretches' with the last live lane.  A `fori_loop` of
+    # a constant trip count is traced as a `scan`; the combine's is a `while`
+    from flink_parameter_server_tpu.ops import dedup
+
+    jaxpr = jax.make_jaxpr(lambda i, v: dedup.combine_runs(
+        i, v, spec.padded_capacity, kernel=True, interpret=False)
+    )(jax.ShapeDtypeStruct((FM_BATCH * FM_FIELDS,), jnp.int32),
+      jax.ShapeDtypeStruct((FM_BATCH * FM_FIELDS, 36), jnp.float32))
+    loops = [e.primitive.name for e in jaxpr.eqns
+             if e.primitive.name in ("scan", "while")
+             and "sorted_row_update" in str(e.params)]
+    assert loops == ["while"]
 
 
 @pytest.mark.parametrize("arm", ["kernels", "xla"])
@@ -1398,7 +1470,7 @@ def test_difacto_step_on_four_chips_runs_its_rule_on_the_shard_that_owns_the_row
     under ``ps.combine``, ``sorted_row_set`` in the rule's loop; off the TPU
     XLA's scatter-add and row ``set`` ON THE BLOCK, nothing partitioned by
     GSPMD), 1.33 GB of temporaries a chip, and the step's only collectives
-    are the pull's all-reduce of ``f32[32768,39,36]`` and the 80 bytes of
+    are the pull's all-reduce of ``f32[32768,39,36]`` and the 96 bytes of
     the push's counts: no row of the table and no key crosses chips."""
     from flink_parameter_server_tpu.parallel.mesh import make_mesh
 
@@ -1435,7 +1507,8 @@ def test_difacto_step_on_four_chips_runs_its_rule_on_the_shard_that_owns_the_row
         r" (all-reduce|all-gather|all-to-all|reduce-scatter|collective-permute)"
         r"(-start)?\(", c)]
     shapes = sorted(c.strip().split(" = ", 1)[1].split("{")[0] for c in collectives)
-    assert shapes == ["f32[32768,39,36]", "s32[20]"], collectives
+    # (six counts a shard since PR 54's `ps_combine_kernel_writes`)
+    assert shapes == ["f32[32768,39,36]", "s32[24]"], collectives
     for scope in ("ps.pull", "ps.push/shard_map/ps.combine",
                   "ps.push/shard_map/while/body/ps.rule"):
         assert scope in text, scope
