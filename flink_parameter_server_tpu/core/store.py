@@ -84,8 +84,17 @@ def _resolve_layout(
       and Mosaic refuses a slice of them: PERF.md section 6, PR 35).
       Packed 42 to a physical row, its step asked 13.5 GB for its 42
       static lane slices (PERF.md section 6, PR 34).
-    - every other row (65 lanes or more, so ``k`` = 1; two axes; none):
-      dense, as it was: no cell stands there.
+    - one axis of more than 128 lanes: PACKED with ``k`` = 1, the flat
+      whole-register row an add-store of that width has (GloVe's 602 lanes
+      of weights, bias and AdaGrad's accumulators in five registers:
+      ``f32[4392040,640]``, 11.24 GB).  Dense, the TPU holds ``(capacity,
+      602)`` capacity-minor and every step copies the whole table for its
+      gather and back (below: PR 32); flat, the pull and the rule's read
+      gather whole registers, and the push sums, rewrites and writes back
+      whole physical rows (:func:`_push_rule`; what the chip measured:
+      PERF.md section 6, PR 55).
+    - every other row (65 to 128 lanes, where ``k`` would be 1 and the row
+      one register; two axes; none): dense, as it was: no cell stands there.
 
     ``layout="packed"`` may be pinned for any rule store; the rule's push
     then takes the packed arm at whatever ``k`` the width gives.
@@ -120,7 +129,7 @@ def _resolve_layout(
     one_axis = len(value_shape) == 1
     if update == "add":
         return "dense" if one_axis and width % 128 == 0 else "packed"
-    return "packed" if one_axis and 8 < width <= 64 else "dense"
+    return "packed" if one_axis and (8 < width <= 64 or width > 128) else "dense"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -582,7 +591,15 @@ def _push_rule(
     touched tile of 128 rows read, set and written back once, the same
     bits (PERF.md section 6, PR 35).  A PACKED store's chunk goes through
     :func:`_rewrite_packed`, which reads and writes whole physical rows,
-    and ``counted`` then carries ``ps_rule_packed_rows``."""
+    and ``counted`` then carries ``ps_rule_packed_rows``.  A rule row wider
+    than a register lies flat in several (:func:`_flat_wide_rule`, packed at
+    ``k`` = 1): its deltas are padded to the physical width in front of the
+    combine, which on a TPU sums them through the tile kernel in the order
+    of the stream (``ops/dedup._tile_sums``), and its write-back's kernel
+    arm is ``ops/row_update.sorted_tile_assign``, every touched tile of
+    eight rows read, set and written once (``ps_rule_tiles`` counts those
+    tile rows, ``ps_combine_kernel_writes`` the combine's; PERF.md section
+    6, PR 55)."""
     from ..ops.dedup import _SORT_CARRIES_LANES, combine_runs
     from ..ops.row_update import sorted_tile_set
 
@@ -605,13 +622,18 @@ def _push_rule(
     tiles_arm = _set_kernel_takes(spec)
     sums_arm = _combine_kernel_takes(spec)
     chunk = min(n, _RULE_CHUNK)
+    flat_wide = _flat_wide_rule(spec)
     with jax.named_scope("ps.combine"):
         dead = flat_ids >= sentinel
         if live is not None:
             dead = dead | ~live
+        vals = flat_deltas.reshape(n, -1).astype(table.dtype)
+        if flat_wide:
+            # whole registers from here on, as the table holds the row: the
+            # sums, the rule's read and the write-back move physical rows
+            vals = jnp.pad(vals, ((0, 0), (0, table.shape[1] - vals.shape[1])))
         row_ids, combined, issued = combine_runs(
-            jnp.where(dead, sentinel, flat_ids),
-            flat_deltas.reshape(n, -1).astype(table.dtype), sentinel,
+            jnp.where(dead, sentinel, flat_ids), vals, sentinel,
             kernel=sums_arm,
         )
         counted = {
@@ -654,9 +676,13 @@ def _push_rule(
     chunks = -(-counted["ps_rule_rows"] // chunk)
     zero = jnp.zeros((), jnp.int32)
     table, moved = jax.lax.fori_loop(0, chunks, rewrite, (table, zero))
-    counted["ps_rule_tiles"] = zero if packed else moved
+    # a flat wide row's write-back counts its tile rows where the tile
+    # kernel wrote them; its physical rows are its distinct rows (k = 1)
+    wide_tiles = flat_wide and tiles_arm
+    counted["ps_rule_tiles"] = moved if wide_tiles or not packed else zero
     if packed:
-        counted["ps_rule_packed_rows"] = moved
+        counted["ps_rule_packed_rows"] = (
+            counted["ps_rule_rows"] if wide_tiles else moved)
     return table, counted
 
 
@@ -665,8 +691,10 @@ def _rewrite_packed(
     kernel: bool = False,
 ) -> Tuple[Array, Array]:
     """One chunk of :func:`_push_rule` for a PACKED table: ``(table,
-    physical rows written)``.  ``ids`` are sorted and distinct, the
-    sentinel last; ``sums`` ``(chunk, row_width)``.
+    physical rows written)``, or, where the tile kernel wrote a flat wide
+    row's (below), ``(table, tile rows read and written)``.  ``ids`` are
+    sorted and distinct, the sentinel last; ``sums`` ``(chunk, row_width)``
+    (a flat wide row's: ``(chunk, physical lanes)``, zeros past its width).
 
     Under ``ps.rule`` ONE gather of the chunk's physical rows (``ids //
     k``, whole 128-lane registers), the lane slice down to each id's
@@ -683,13 +711,18 @@ def _rewrite_packed(
     lane of the chunk, written or dropped, serial) or, on a TPU, for
     float32 physical rows of one register, ``ops/row_update.
     sorted_row_set``, the row kernel's walk with a copy for its body
-    (PERF.md section 6, PR 47).  Selects and copies only, never an add or a
+    (PERF.md section 6, PR 47), and for float32 physical rows of SEVERAL
+    registers (``k`` = 1, a flat wide row: GloVe's 640 lanes)
+    ``ops/row_update.sorted_tile_assign``, the wide add push's tile walk
+    with a store for its body: a Pallas DMA cannot write one such row
+    alone, so every touched tile of eight rows is read, set and written
+    once (PERF.md section 6, PR 55).  Selects and copies only, never an add or a
     masked sum: an untouched logical row inside a touched physical row,
     the pad lanes and a row's NaN or -0.0 come back bit for bit.  A
     physical row whose touched rows fall on two chunks is written by
     both: the second reads what the first wrote."""
     from ..ops.packed import _sub_row_slice, lane_shift_deltas
-    from ..ops.row_update import sorted_row_set
+    from ..ops.row_update import sorted_row_set, sorted_tile_assign
 
     k, d = spec.pack, spec.row_width
     chunk, (phys_rows, lanes) = ids.shape[0], table.shape
@@ -697,6 +730,8 @@ def _rewrite_packed(
     phys, sub = ids // k, ids % k  # the sentinel: one past the last row
     with jax.named_scope("ps.rule"):
         rows = jnp.take(table, phys, axis=0, mode="clip")
+        if sums.shape[1] != d:  # a flat wide row's, in whole registers
+            sums = sums[:, :d]
         new = update_fn(
             _sub_row_slice(rows, ids, d).reshape(
                 (chunk,) + spec.value_shape),
@@ -718,6 +753,9 @@ def _rewrite_packed(
         [jnp.ones((1,), bool), phys[1:] != phys[:-1]]
     ) & (phys < phys_rows)
     at = jnp.where(writes, phys, phys_rows)
+    if kernel and lanes > 128:
+        # k = 1: every live lane writes, the sentinels close the chunk
+        return sorted_tile_assign(table, at, merged)
     if kernel:
         table = sorted_row_set(table, at, merged)
     else:
@@ -856,14 +894,17 @@ def _set_kernel_takes(spec: StoreSpec) -> bool:
     what the spec holds, as :func:`_tile_kernel_takes` reads the add arm's:
     a TPU, and either a table held at whole sublane tiles
     (``StoreSpec.tile_lanes``: ``sorted_tile_set``; in one place only) or a
-    PACKED table whose physical row is one float32 register, in one place
-    or sharded over ``ps`` under one worker, where every shard's rule runs
-    on its own block (``sorted_row_set``: :func:`_rewrite_packed`;
-    :func:`_push_rule_on_shards`).  A mesh with ``dp`` > 1 keeps XLA's row
-    ``set``, GSPMD's to partition.  Static per compiled step.  A narrow rule
-    store that is NOT held at its tile (bfloat16, rows of rank 0 or 2) and
-    a packed one the row kernel refuses (bfloat16, physical rows of several
-    registers) keep the XLA arm, counted and warned of once."""
+    PACKED float32 table, in one place or sharded over ``ps`` under one
+    worker, where every shard's rule runs on its own block
+    (:func:`_rewrite_packed`; :func:`_push_rule_on_shards`): a physical row
+    of one register through ``sorted_row_set``, a flat wide row of several
+    (:func:`_flat_wide_rule`) through ``sorted_tile_assign``, tile by tile
+    of eight rows.  A mesh with ``dp`` > 1 keeps XLA's row ``set``, GSPMD's
+    to partition.  Static per compiled step.  A narrow rule store that is
+    NOT held at its tile (bfloat16, rows of rank 0 or 2) and a packed one
+    its kernel refuses (bfloat16; a pinned ``"packed"`` row of 65 to 127
+    lanes is one register and taken) keep the XLA arm, counted and warned
+    of once."""
     if jax.default_backend() != "tpu":
         return False
     if spec.tile_lanes:
@@ -871,9 +912,11 @@ def _set_kernel_takes(spec: StoreSpec) -> bool:
     if spec.layout == "packed" and spec.update != "add":
         from ..ops import row_update
 
+        shape = spec.table_shape()
         return _rule_sees_one_block(spec) and _taken_or_noted(
             spec, "write-back of a packed rule store's rows",
-            row_update.refusal(spec.table_shape()[1:], spec.dtype),
+            row_update.tile_refusal(shape, spec.dtype) if _flat_wide_rule(spec)
+            else row_update.refusal(shape[1:], spec.dtype),
         )
     if spec.narrow_rule:
         from ..ops import row_update
@@ -896,8 +939,11 @@ def _combine_kernel_takes(spec: StoreSpec) -> bool:
     where the kernel sees a plain array; ``dp`` > 1 keeps GSPMD's), a
     rule, and rows wider than a sort carries (``ops/dedup.
     _SORT_CARRIES_LANES``; a narrower row rides through the sort whatever
-    the backend) that fit one 128-lane register, float32.  Static per
-    compiled step.  Such a store that the kernel REFUSES (bfloat16, rows of
+    the backend), float32, that fit one 128-lane register or lie flat in
+    several (:func:`_flat_wide_rule`: the combine then sees the row padded
+    to its whole registers and sums it through the TILE kernel, in the
+    order of the stream: ``ops/dedup._tile_sums``).  Static per compiled
+    step.  Such a store that the kernel REFUSES (bfloat16; a DENSE row of
     more than 128 lanes) keeps the scatter-add, counted and warned of once.
     On the v5e the scatter-add is 146 ns a 36-lane row, serial; the permute
     of whole-register rows is 8-10 ns a row and the kernel ~0.6 us a block
@@ -908,10 +954,24 @@ def _combine_kernel_takes(spec: StoreSpec) -> bool:
             or jax.default_backend() != "tpu"
             or spec.row_width <= dedup._SORT_CARRIES_LANES):
         return False
+    width = spec.table_shape()[1] if _flat_wide_rule(spec) else spec.row_width
     return _taken_or_noted(
         spec, "the sum of a rule's wide rows",
-        dedup.kernel_refusal(spec.row_width, spec.dtype),
+        dedup.kernel_refusal(width, spec.dtype),
     )
+
+
+def _flat_wide_rule(spec: StoreSpec) -> bool:
+    """A rule store whose row lies FLAT in several whole 128-lane registers,
+    one logical row to a physical row (``layout="packed"`` at ``k`` = 1 and
+    more than 128 lanes: what ``"auto"`` gives a rule row of one axis over
+    128 lanes, GloVe's 602 in 640).  Its push pads the batch's deltas to the
+    physical width before the combine, so that the sums, the rule's read
+    and the write-back all move whole registers, and its two kernel arms
+    are the TILE kernel's (eight rows to a tile: a row that wide cannot be
+    written alone)."""
+    return (spec.update != "add" and spec.layout == "packed"
+            and spec.pack == 1 and spec.table_shape()[1] > 128)
 
 
 def _rule_sees_one_block(spec: StoreSpec) -> bool:

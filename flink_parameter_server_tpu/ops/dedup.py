@@ -124,9 +124,15 @@ def combine_runs(
       sum by doubling, which sums each run as a balanced tree, the batch's
       lanes along the minor axis), and a second sort that moves the lanes
       ending a run to the front;
-    - wider rows, ``kernel`` false (the CPU, a mesh, bfloat16, rows of more
-      than 128 lanes): :func:`_wide_runs`' ONE scatter-add in the order of
+    - wider rows, ``kernel`` false (the CPU, a mesh, bfloat16, rows over
+      128 lanes that are no whole registers): :func:`_wide_runs`' ONE
+      scatter-add in the order of
       the stream, ``np.add.at``'s own additions;
+    - wider rows, ``kernel`` true, several whole registers (a TPU,
+      float32, a multiple of 128 lanes over 128: a rule store's flat wide
+      row, GloVe's 640): the rows permuted once into sorted order and added
+      into a zeroed block by the TILE kernel, in the order of the stream
+      (:func:`_tile_sums`; PERF.md section 6, PR 55);
     - wider rows, ``kernel`` true (a TPU, float32, at most 128 lanes): the
       rows permuted ONCE into sorted order at 128 lanes and their runs summed
       by the row kernel of ``ops/row_update`` (:func:`_kernel_sums`): on
@@ -165,10 +171,10 @@ def kernel_refusal(width: int, dtype) -> Optional[str]:
     if jnp.dtype(dtype) != jnp.float32:
         return f"rows are {jnp.dtype(dtype).name}, the kernel sums float32"
     if width > 128:
-        return (
-            f"rows of {width} lanes: only a row of one 128-lane register is "
-            f"gathered and written in one piece"
-        )
+        # rows of whole registers are summed tile by tile (`_tile_sums`)
+        from .row_update import tile_refusal
+
+        return tile_refusal((8, width), dtype)
     return None
 
 
@@ -211,7 +217,8 @@ def _wide_runs(
     if kernel:
         # the lanes to drop sort last: the kernel writes no row for them
         slot = jnp.where(sorted_ids < sentinel, rank - 1, _INT32_MAX)
-        sums, issued = _kernel_sums(order, slot, vals, interpret)
+        sums, issued = (_kernel_sums if w <= 128 else _tile_sums)(
+            order, slot, vals, interpret)
     else:
         # (the positions are distinct: nothing for a stable sort to keep)
         _, slot = jax.lax.sort((order, rank - 1), num_keys=1, is_stable=False)
@@ -296,6 +303,39 @@ def _kernel_sums(
         (jnp.zeros((n, 128), jnp.float32), jnp.zeros((), jnp.int32)),
     )
     return block[:, :w], issued
+
+
+def _tile_sums(
+    order: Array, slot: Array, vals: Array, interpret: Optional[bool]
+) -> Tuple[Array, Array]:
+    """:func:`_kernel_sums` for rows of SEVERAL whole 128-lane registers
+    (``vals`` float32 ``(n, w)``, ``w`` a multiple of 128:
+    :func:`kernel_refusal`), which the row kernel cannot write alone: the
+    rows permuted once into sorted order and added into a zeroed ``(n, w)``
+    block by ``ops/row_update``'s TILE kernel (``sorted_tile_add``, the wide
+    add push's: every touched tile of eight slots read, added to lane by
+    lane and written once).  The slots are the ranks ``0 .. distinct - 1``,
+    so the tiles are full and the walk opens an eighth as many as there are
+    distinct rows.  A run's lanes are added one by one in the order of the
+    stream (the sort that ranked them is on (id, position)): float32
+    addition by addition what ``_wide_runs``' scatter-add and ``np.add.at``
+    do, bit for bit.  Beside the sums, the tile rows the calls read and
+    wrote (the kernel's DMA descriptors: two a tile row)."""
+    from .row_update import _calls, _pad_for_calls, _sorted_tile_add_counted
+
+    n, w = vals.shape
+    pad = _pad_for_calls(n)
+    if pad:
+        order = jnp.pad(order, (0, pad))
+        slot = jnp.pad(slot, (0, pad), constant_values=_INT32_MAX)
+    block = jnp.zeros((-(-n // 8) * 8, w), jnp.float32)
+    opened = jnp.zeros((), jnp.int32)
+    for _, slots, lanes in _calls(slot, order):
+        block, _, moved = _sorted_tile_add_counted(
+            block, slots, jnp.take(vals, lanes, axis=0, mode="clip"),
+            interpret)
+        opened = opened + moved
+    return block[:n], opened
 
 
 # -- host-side coalescing (the cluster client's request combiner) -----------

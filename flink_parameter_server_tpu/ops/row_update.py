@@ -632,6 +632,15 @@ def _calls(sorted_ids: Array, order: Array):
     ]
 
 
+def _pad_for_calls(lanes: int) -> int:
+    """Lanes to append so that the calls :func:`_calls` cuts a sorted batch
+    into have ONE shape: every process that runs the step traces and lowers
+    the kernel once a shape, warm cache or not (cell 10's 851,968 lanes were
+    eight calls of 94,720 and one of 94,208).  Nothing for one call."""
+    calls = -(-lanes // MAX_LANES)
+    return -lanes % (calls * BLOCK) if calls > 1 else 0
+
+
 def _open_run_reread(state: Array, sorted_ids: Array, old: Array) -> Array:
     """``old`` for a call that is not a batch's first: the run at its head
     may have begun in the call before, which then wrote that row (its old
@@ -777,7 +786,7 @@ def sorted_row_set(
 
 # -- rows of several registers: a read-modify-write per touched tile row ------
 def _tile_kernel(tiles_ref, words_ref, counts_ref, dl_ref, table_ref, out_ref,
-                 tile_buf, sem, *, block: int):
+                 tile_buf, sem, *, block: int, assign: bool = False):
     """One grid step = the adds of ``block`` sorted lanes (the kept ones
     first), under the reads of the next block's tile rows and the writes of
     the block before's.  Step ``g`` awaits the writes of block ``g - 3``
@@ -823,6 +832,11 @@ def _tile_kernel(tiles_ref, words_ref, counts_ref, dl_ref, table_ref, out_ref,
     ``lax`` equation or two and nothing computed twice): every process that
     runs the step traces it and lowers it, each unrolled copy by itself,
     warm cache or not.
+
+    ``assign``: a kept lane's row REPLACES its row of the tile instead of
+    being added to it (:func:`sorted_tile_assign`, a rule store's wide
+    write-back): the same walk, the same copies, one store a lane and no
+    load; the tile's other rows go back as they were read.
     """
     pl, pltpu = _pallas()
     lax = jax.lax  # not jnp: an operator on a tracer is a jitted call to trace
@@ -881,7 +895,10 @@ def _tile_kernel(tiles_ref, words_ref, counts_ref, dl_ref, table_ref, out_ref,
         word = words_ref[lax.add(base, lane)]
         at = (slot, lax.bitwise_and(word, 255),
               pl.ds(lax.shift_right_logical(word, 8), 1), slice(None))
-        tile_buf[at] = lax.add(tile_buf[at], dl_ref[pl.ds(lane, 1), :])
+        if assign:
+            tile_buf[at] = dl_ref[pl.ds(lane, 1), :]
+        else:
+            tile_buf[at] = lax.add(tile_buf[at], dl_ref[pl.ds(lane, 1), :])
 
     _each(kept, add)
 
@@ -962,22 +979,57 @@ def sorted_tile_add(
     return _sorted_tile_add_counted(table, sorted_ids, deltas, interpret)[0]
 
 
-def _sorted_tile_add_counted(table, sorted_ids, deltas, interpret):
+def _sorted_tile_add_counted(table, sorted_ids, deltas, interpret,
+                             assign=False):
     """:func:`sorted_tile_add`'s ``(table, kept lanes, tile rows read and
-    written)``, the last two from the plan's own counts."""
+    written)``, the last two from the plan's own counts (``assign``:
+    :func:`sorted_tile_assign`'s)."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     why = tile_refusal(table.shape, table.dtype) or _too_many(
         sorted_ids.shape[0])
     if why is not None and not interpret:
-        raise ValueError(f"sorted_tile_add: {why}")
+        name = "sorted_tile_assign" if assign else "sorted_tile_add"
+        raise ValueError(f"{name}: {why}")
     return _tile_add(table, sorted_ids, deltas, block=BLOCK,
-                     interpret=interpret)
+                     interpret=interpret, assign=assign)
+
+
+def sorted_tile_assign(
+    table: Array,
+    sorted_ids: Array,
+    new_rows: Array,
+    *,
+    interpret: Optional[bool] = None,
+) -> Tuple[Array, Array]:
+    """``table.at[sorted_ids].set(new_rows, mode="drop")`` for DISTINCT
+    ascending ids and rows of several 128-lane registers, and the tile rows
+    it read and wrote: :func:`sorted_tile_add`'s walk with a store for its
+    body (a Pallas DMA cannot write ONE row wider than 128 lanes: eight rows
+    to a tile).  Every touched tile row of 8 rows is read, the kept lanes'
+    rows replaced, and the tile row written back once; its other rows, NaN
+    and -0.0 included, come back bit for bit.  ``sorted_ids``: (n,) int32
+    ascending and distinct, lanes to drop at the end with an id >= the row
+    count; a batch over ``MAX_LANES`` lanes goes in several calls of equal
+    size.  The write-back of a rule store whose row is wider than a
+    register (``core/store._rewrite_packed``)."""
+    pad = _pad_for_calls(sorted_ids.shape[0])
+    if pad:
+        sorted_ids = jnp.concatenate(
+            [sorted_ids, jnp.full((pad,), _INT32_MAX, jnp.int32)])
+        new_rows = jnp.pad(new_rows, ((0, pad), (0, 0)))
+    opened = jnp.zeros((), jnp.int32)
+    for lo, ids, _ in _calls(sorted_ids, sorted_ids):
+        table, _, moved = _sorted_tile_add_counted(
+            table, ids, new_rows[lo:lo + ids.shape[0]], interpret, True)
+        opened = opened + moved
+    return table, opened
 
 
 @functools.partial(
-    jax.jit, static_argnames=("block", "interpret"), inline=True)
-def _tile_add(table, sorted_ids, deltas, *, block: int, interpret: bool):
+    jax.jit, static_argnames=("block", "interpret", "assign"), inline=True)
+def _tile_add(table, sorted_ids, deltas, *, block: int, interpret: bool,
+              assign: bool = False):
     pl, pltpu = _pallas()
 
     rows, width = table.shape
@@ -1014,7 +1066,7 @@ def _tile_add(table, sorted_ids, deltas, *, block: int, interpret: bool):
         ],
     )
     table = pl.pallas_call(
-        functools.partial(_tile_kernel, block=block),
+        functools.partial(_tile_kernel, block=block, assign=assign),
         out_shape=jax.ShapeDtypeStruct(table.shape, table.dtype),
         grid_spec=grid_spec,
         input_output_aliases={4: 0},  # (tiles, words, counts, deltas, table)
@@ -1023,7 +1075,7 @@ def _tile_add(table, sorted_ids, deltas, *, block: int, interpret: bool):
             vmem_limit_bytes=_TILE_VMEM_BYTES,
         ),
         interpret=interpret,
-        name="sorted_row_update_tiles",
+        name="sorted_row_assign_tiles" if assign else "sorted_row_update_tiles",
     )(tiles, words, counts, deltas, table)
     # a block's (opened, kept, carried): a carried tile row is opened by
     # both blocks that share it and moved once
@@ -1340,12 +1392,8 @@ def scatter_add_counted(
     if not isinstance(table, jax.core.Tracer):
         return _scatter_add_jitted(table, ids, deltas, interpret=interpret)
     sid, order = sort_by_row(ids.reshape(-1), None, table.shape[0])
-    calls = -(-sid.shape[0] // MAX_LANES)
-    pad = -sid.shape[0] % (calls * BLOCK) if calls > 1 else 0
+    pad = _pad_for_calls(sid.shape[0])
     if pad:
-        # calls of ONE shape: every process that runs the step traces and
-        # lowers the kernel once a shape, warm cache or not (cell 10's
-        # 851,968 lanes were eight calls of 94,720 and one of 94,208)
         sid = jnp.concatenate([sid, jnp.full((pad,), _INT32_MAX, jnp.int32)])
         order = jnp.concatenate([order, jnp.zeros((pad,), jnp.int32)])
     lanes = tile_rows = jnp.zeros((), jnp.int32)
@@ -1375,6 +1423,7 @@ __all__ = [
     "BLOCK", "MAX_LANES", "descriptors", "note_refusal", "preload", "refusal",
     "refusal_count", "row_add", "scatter_add", "scatter_add_counted",
     "set_refusal", "sort_by_row", "sorted_row_set", "sorted_row_update",
-    "sorted_row_update_counted", "sorted_tile_add", "sorted_tile_set",
+    "sorted_row_update_counted", "sorted_tile_add", "sorted_tile_assign",
+    "sorted_tile_set",
     "tile_refusal",
 ]

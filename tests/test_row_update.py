@@ -991,7 +991,10 @@ def test_a_rule_store_whose_pushes_will_trace_the_kernel_starts_the_pallas_impor
         monkeypatch, backend, shape, started):
     """Three lanes: the set kernel writes the rows back.  Nine: the row
     kernel sums them (``core/store._combine_kernel_takes``, PR 46; no kernel
-    took such a store before).  Two hundred: neither, and a warning says so."""
+    took such a store before).  Two hundred, held DENSE as ``create`` holds a
+    store by default: neither, and a warning says so (``layout="auto"`` lays
+    such a row flat in two registers since PR 55, and the tile kernel takes
+    both: tests/test_store.py)."""
     from flink_parameter_server_tpu.core import store as store_mod
 
     calls = []
@@ -1019,3 +1022,104 @@ def test_preload_imports_pallas_off_the_calling_thread():
 def test_unknown_state_scatter_is_refused():
     with pytest.raises(ValueError, match="state_scatter"):
         mfm.OnlineMatrixFactorization(8, 8, state_scatter="pallas")
+
+
+# -- rows of several registers under a rule: the tile walk with a store -------
+TILE_ASSIGN_CASES = [
+    "sorted_distinct", "neighbours_in_one_tile", "every_lane_dropped",
+    "dropped_at_the_end", "one_lane", "nan_inf_and_minus_zero",
+    "a_whole_tile", "several_calls",
+]
+
+
+@pytest.mark.parametrize("name", TILE_ASSIGN_CASES)
+@pytest.mark.parametrize("width", [256, 640])
+def test_sorted_tile_assign_is_xlas_row_set_bit_for_bit(name, width, monkeypatch):
+    """``table.at[ids].set(rows, mode="drop")`` for distinct ascending ids
+    and rows of several registers: every bit of the table, the rows that
+    share a touched tile with a written one included (the kernel copies, it
+    does no arithmetic)."""
+    rng = np.random.default_rng(TILE_ASSIGN_CASES.index(name))
+    rows_n, n = 1000, 300
+    ids = np.sort(rng.choice(rows_n, n, replace=False)).astype(np.int32)
+    if name == "neighbours_in_one_tile":
+        ids = np.arange(40, 40 + n).astype(np.int32)
+    elif name == "every_lane_dropped":
+        ids[:] = rows_n
+    elif name == "dropped_at_the_end":
+        ids[200:] = rows_n + 3
+    elif name == "one_lane":
+        ids, n = ids[:1], 1
+    elif name == "a_whole_tile":
+        ids, n = np.arange(16, 24).astype(np.int32), 8
+    elif name == "several_calls":
+        monkeypatch.setattr(row_update, "MAX_LANES", 256)
+        rows_n, n = 2000, 700
+        ids = np.sort(rng.choice(rows_n, n, replace=False)).astype(np.int32)
+    table = rng.normal(size=(rows_n, width)).astype(np.float32)
+    new = rng.normal(size=(n, width)).astype(np.float32)
+    if name == "nan_inf_and_minus_zero":
+        ids = (2 * np.arange(n)).astype(np.int32)  # the odd rows stay
+        new[0], new[1, ::2], new[2, 5] = -0.0, np.inf, np.nan
+        table[7] = np.nan  # untouched rows of touched tiles
+        table[9, ::3] = -0.0
+    new[ids >= rows_n] = np.nan  # a dropped lane's row is never written
+    got, opened = row_update.sorted_tile_assign(
+        jnp.asarray(table), jnp.asarray(ids), jnp.asarray(new))
+    want = np.asarray(
+        jnp.asarray(table).at[jnp.asarray(ids)].set(jnp.asarray(new), mode="drop"))
+    assert np.asarray(got).tobytes() == want.tobytes()
+    jitted, _ = jax.jit(row_update.sorted_tile_assign, donate_argnums=0)(
+        jnp.asarray(table), jnp.asarray(ids), jnp.asarray(new))
+    assert np.asarray(jitted).tobytes() == want.tobytes()
+    kept = ids[ids < rows_n]
+    if name == "several_calls":
+        size = 256  # three calls of 256 lanes: a tile open across two is moved by both
+        assert int(opened) == sum(
+            len(np.unique(kept[lo:lo + size] // 8)) for lo in range(0, 768, size))
+    else:
+        assert int(opened) == len(np.unique(kept // 8))
+
+
+def test_sorted_tile_assign_refuses_what_the_tile_kernel_refuses():
+    with pytest.raises(ValueError, match="sorted_tile_assign: .*whole tiles"):
+        row_update.sorted_tile_assign(
+            jnp.zeros((63, 256), jnp.float32), jnp.zeros((8,), jnp.int32),
+            jnp.zeros((8, 256)), interpret=False)
+    with pytest.raises(ValueError, match="sorted_tile_assign: rows are bfloat16"):
+        row_update.sorted_tile_assign(
+            jnp.zeros((64, 256), jnp.bfloat16), jnp.zeros((8,), jnp.int32),
+            jnp.zeros((8, 256)), interpret=False)
+
+
+@pytest.mark.parametrize("width, n", [(256, 300), (640, 1000)])
+def test_wide_rows_are_summed_by_the_tile_kernel_in_stream_order_bit_for_bit(
+        width, n):
+    """``ops/dedup.combine_runs`` for rows of several whole registers, kernel
+    arm: the same float32 additions in the same order as the scatter-add arm
+    (and ``np.add.at``), the descriptors counted by tile rows."""
+    from flink_parameter_server_tpu.ops import dedup
+
+    rng = np.random.default_rng(width)
+    sentinel = 500
+    ids = rng.integers(0, 60, n).astype(np.int32)
+    ids[rng.random(n) < 0.1] = sentinel
+    ids[: n // 4] = 7
+    vals = (rng.normal(size=(n, width)) * 10.0 ** rng.integers(-3, 4, (n, 1))
+            ).astype(np.float32)
+    want_ids, want, zero = jax.jit(dedup.combine_runs, static_argnums=2)(
+        ids, vals, sentinel)
+    got_ids, got, opened = jax.jit(
+        lambda i, v: dedup.combine_runs(i, v, sentinel, kernel=True, interpret=True)
+    )(ids, vals)
+    assert np.array_equal(np.asarray(got_ids), np.asarray(want_ids))
+    distinct = len(np.unique(ids[ids < sentinel]))
+    # (past the distinct ids the scatter-add arm holds the dropped lanes' sum,
+    # which no caller reads: their ids are the sentinel)
+    assert np.asarray(got)[:distinct].tobytes() == (
+        np.asarray(want)[:distinct].tobytes())
+    assert not np.asarray(got)[distinct:].any()
+    assert int(zero) == 0 and int(opened) == -(-distinct // 8)
+    assert dedup.kernel_refusal(width, jnp.float32) is None
+    assert "whole 128-lane" in dedup.kernel_refusal(width + 1, jnp.float32)
+    assert "bfloat16" in dedup.kernel_refusal(width, jnp.bfloat16)

@@ -1819,3 +1819,175 @@ def test_a_rule_store_s_arms_under_a_mesh_are_read_from_its_workers(
         1000, shape, update=_sticky_rule, layout="auto")).spec
     assert not store_mod._rule_on_shards_takes(one)
     assert row_update.refusal_count() == n0 + 1
+
+
+# -- a FLAT WIDE rule store: one axis of more than 128 lanes, k = 1 -----------
+# What `_resolve_layout("auto")` gives a rule row wider than a register
+# (GloVe's 602 lanes of weights, bias and accumulators in five): the flat
+# whole-register layout an add store of that width has.  Its push pads the
+# deltas to the physical width, sums them, and reads, rewrites and writes
+# whole physical rows; it is held to the DENSE rule store, bit for bit.
+
+FLAT_WIDE_WIDTHS = [129, 200, 256, 602]
+FLAT_WIDE_CASES = [
+    "uniform", "a_hot_row", "across_a_chunk_edge", "dead_and_out_of_range_lanes",
+    "nan_inf_and_minus_zero_neighbours", "the_last_rows_and_padding",
+    "empty_batch", "half_masked",
+]
+
+
+def _flat_wide_traffic(case, rng, cap, width):
+    n, mask, chunk = 200, None, None
+    ids = rng.integers(0, cap, n)
+    if case == "a_hot_row":
+        ids[: n // 2] = 11
+    elif case == "across_a_chunk_edge":
+        ids, chunk = np.arange(3, 3 + 50), 16
+    elif case == "dead_and_out_of_range_lanes":
+        ids = rng.integers(-5, cap + 40, n)
+        ids[:5] = [-1, cap, cap + 1000, -(2 ** 31), 2 ** 31 - 1]
+    elif case == "nan_inf_and_minus_zero_neighbours":
+        ids = np.array([9, 17, 18, 40])  # rows beside them share their tiles
+    elif case == "the_last_rows_and_padding":
+        ids = np.arange(cap - 5, cap + 3)
+    elif case == "empty_batch":
+        ids = np.zeros((0,), np.int64)
+    elif case == "half_masked":
+        ids[: n // 3] = 7
+        mask = rng.random(n) < 0.5
+    ids = np.asarray(ids, np.int32)
+    deltas = rng.normal(size=ids.shape + (width,)).astype(np.float32)
+    if case == "dead_and_out_of_range_lanes":
+        deltas[(ids < 0) | (ids >= cap)] = np.nan
+    return ids, deltas, mask, chunk
+
+
+@pytest.mark.parametrize("case", FLAT_WIDE_CASES)
+@pytest.mark.parametrize("width", FLAT_WIDE_WIDTHS)
+@pytest.mark.parametrize("arm", ["xla", "tile_kernels"])
+def test_a_flat_wide_rule_store_is_the_dense_one_bit_for_bit(
+        arm, width, case, monkeypatch):
+    from flink_parameter_server_tpu.core import store as store_mod
+
+    rng = np.random.default_rng([width, FLAT_WIDE_CASES.index(case)])
+    cap = 77  # no whole number of tiles of eight rows
+    values = rng.normal(size=(cap, width)).astype(np.float32)
+    if case == "nan_inf_and_minus_zero_neighbours":
+        values[8], values[16], values[19] = np.nan, -0.0, np.inf
+        values[41, ::2] = -np.inf
+    ids, deltas, mask, chunk = _flat_wide_traffic(case, rng, cap, width)
+    if chunk:
+        monkeypatch.setattr(store_mod, "_RULE_CHUNK", chunk)
+    auto = ShardedParamStore.from_values(
+        jnp.asarray(values), update=_sticky_rule, layout="auto")
+    dense = ShardedParamStore.from_values(
+        jnp.asarray(values), update=_sticky_rule, layout="dense")
+    lanes = -(-width // 128) * 128
+    assert auto.spec.layout == "packed" and auto.spec.pack == 1
+    assert store_mod._flat_wide_rule(auto.spec)
+    assert not store_mod._flat_wide_rule(dense.spec)
+    assert auto.table.shape == (80, lanes) and dense.spec.layout == "dense"
+    table = np.asarray(auto.table)
+    assert not table[:, width:].any() and (
+        table[:cap, :width].tobytes() == values.tobytes())
+    # `values()` / `from_values` round trip, bit for bit; `create` likewise
+    assert np.asarray(auto.values()).tobytes() == values.tobytes()
+    made = ShardedParamStore.create(
+        cap, (width,), init_fn=lambda i: jnp.asarray(values)[i],
+        update=_sticky_rule, layout="auto")
+    assert made.spec == auto.spec
+    assert np.asarray(made.table)[:cap].tobytes() == table[:cap].tobytes()
+    for takes in (store_mod._set_kernel_takes, store_mod._combine_kernel_takes):
+        assert not takes(auto.spec)  # this is a CPU
+    args = (jnp.asarray(ids), jnp.asarray(deltas),
+            None if mask is None else jnp.asarray(mask))
+    want, _ = store_mod.push_counted(dense.spec, dense.table, *args)
+    if arm == "tile_kernels":
+        # off the TPU the choosers are steered and the kernels interpreted
+        monkeypatch.setattr(store_mod, "_set_kernel_takes", lambda spec: True)
+        monkeypatch.setattr(store_mod, "_combine_kernel_takes", lambda spec: True)
+    got, counted = store_mod.push_counted(auto.spec, auto.table, *args)
+    pushed = ShardedParamStore(auto.spec, got)
+    want = np.asarray(ShardedParamStore(dense.spec, want).values())
+    assert np.asarray(pushed.values()).tobytes() == want.tobytes()
+    assert not np.asarray(got)[:, width:].any()  # the pad lanes stay zero
+    live = (ids >= 0) & (ids < auto.spec.padded_capacity)
+    if mask is not None:
+        live &= mask
+    kept = np.unique(ids[live])
+    hit = np.zeros(cap, bool)
+    hit[kept[kept < cap]] = True
+    assert (want[hit] != values[hit]).any(axis=1).all()
+    assert want[~hit].tobytes() == values[~hit].tobytes()
+    assert int(counted["ps_rule_keys"]) == live.sum()
+    assert int(counted["ps_rule_rows"]) == len(kept)
+    assert int(counted["ps_rule_packed_rows"]) == len(kept)
+    pulled = np.asarray(pushed.pull(jnp.asarray(np.clip(ids, 0, cap - 1))))
+    assert pulled.tobytes() == want[np.clip(ids, 0, cap - 1)].tobytes()
+    step = chunk or store_mod._RULE_CHUNK
+    if arm == "xla":
+        assert int(counted["ps_rule_tiles"]) == 0
+        assert int(counted["ps_combine_kernel_lanes"]) == 0
+        assert int(counted["ps_combine_kernel_writes"]) == 0
+        return
+    # the tile rows of eight the write-back read and wrote, chunk by chunk,
+    # and those of the combine's block: the slots are the ranks
+    assert int(counted["ps_rule_tiles"]) == sum(
+        len(np.unique(kept[lo:lo + step] // 8))
+        for lo in range(0, len(kept), step))
+    assert int(counted["ps_combine_kernel_lanes"]) == live.sum()
+    assert int(counted["ps_combine_kernel_writes"]) == -(-len(kept) // 8)
+
+
+@pytest.mark.parametrize("backend, shape, dtype, layout, combine, write_back, noted", [
+    ("tpu", (602,), jnp.float32, "auto", True, True, 0),   # GloVe's row
+    ("tpu", (129,), jnp.float32, "auto", True, True, 0),
+    ("tpu", (256,), jnp.float32, "auto", True, True, 0),
+    ("cpu", (602,), jnp.float32, "auto", False, False, 0),
+    ("tpu", (602,), jnp.bfloat16, "auto", False, False, 2),
+    # pinned dense, a wide row keeps XLA's arms: the combine says so
+    ("tpu", (602,), jnp.float32, "dense", False, False, 1),
+    # as before: a packed row of several to a register, a narrow row's tile
+    ("tpu", (36,), jnp.float32, "auto", True, True, 0),
+    ("tpu", (3,), jnp.float32, "auto", False, True, 0),
+    ("tpu", (128,), jnp.float32, "auto", True, False, 0),
+])
+def test_a_flat_wide_rule_store_takes_the_tile_kernels_from_its_spec(
+        backend, shape, dtype, layout, combine, write_back, noted, monkeypatch):
+    from flink_parameter_server_tpu.core import store as store_mod
+    from flink_parameter_server_tpu.ops import row_update
+
+    spec = jax.eval_shape(lambda: ShardedParamStore.create(
+        1000, shape, dtype=dtype, update=_sticky_rule, layout=layout)).spec
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    monkeypatch.setattr(store_mod, "_REFUSALS_NOTED", set())
+    n0 = row_update.refusal_count()
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore")
+        assert store_mod._combine_kernel_takes(spec) == combine
+        assert store_mod._set_kernel_takes(spec) == write_back
+    assert row_update.refusal_count() == n0 + noted
+    with warnings.catch_warnings():
+        warnings.simplefilter("error")  # a refusal is noted once
+        store_mod._combine_kernel_takes(spec)
+        store_mod._set_kernel_takes(spec)
+    assert row_update.refusal_count() == n0 + noted
+
+
+@pytest.mark.parametrize("shape, want, lanes", [
+    ((602,), "packed", 640), ((129,), "packed", 256), ((256,), "packed", 256),
+    ((36,), "packed", 128), ((3,), "dense", 4), ((128,), "dense", 128),
+    ((65,), "dense", 65), ((2, 300), "dense", None),
+])
+def test_a_rule_row_resolves_by_its_width_and_its_reload_to_the_same(
+        shape, want, lanes):
+    from flink_parameter_server_tpu.core.store import _resolve_layout
+
+    assert _resolve_layout("auto", _ema, shape) == want
+    store = ShardedParamStore.create(40, shape, update=_ema, layout="auto")
+    again = ShardedParamStore.from_values(
+        store.values(), update=_ema, layout="auto")
+    assert store.spec == again.spec and store.spec.layout == want
+    if lanes is not None:
+        assert store.table.shape[1] == lanes
+    assert np.asarray(again.table).tobytes() == np.asarray(store.table).tobytes()

@@ -1687,3 +1687,103 @@ def test_dlrm_step_holds_nothing_table_sized_beside_its_12_58_gb_table(
     for scope in ("dense_bottom", "dense_interact", "dense_top", "delta_build"):
         assert f"jit(step)/ps.compute/ps.{scope}/" in text, scope
     assert "transpose(jvp(" not in text
+
+
+# glove-840b-300 (chipbench/configs): cell 13's table and batch
+GLOVE_VOCAB, GLOVE_DIM, GLOVE_BATCH = 2_196_017, 300, 32_768
+GLOVE_PHYS_ROWS = 4_392_040
+
+
+@pytest.fixture(scope="module")
+def glove():
+    from flink_parameter_server_tpu.models import glove as gl
+
+    model = gl.GloVeConfig(GLOVE_VOCAB, GLOVE_DIM)
+    assert model.num_rows == 4_392_034 and model.row_lanes == 602
+    return model, gl
+
+
+def test_glove_table_is_initialised_in_place_from_a_seed_argument(
+        glove, one_chip, no_compile_cache):
+    """4,392,034 x 602 f32 rule rows under a ``jit`` that takes the seed: the
+    11.24 GB table ``f32[4392040,640]`` (a row flat in five registers) is the
+    program's only output, initialised ``core/store._PACK_CHUNK`` rows a loop
+    step: 0.67 GB of temporaries, where a second copy would not fit."""
+    model, gl = glove
+    compiled = jax.jit(lambda s: gl.make_store(model, seed=s).table).lower(
+        _shape(one_chip, (), jnp.uint32)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes == GLOVE_PHYS_ROWS * 640 * 4 == 11_243_622_400
+    assert mem.temp_size_in_bytes < 1.0 * GB
+    assert len(re.findall(r" while\(", compiled.as_text())) >= 1
+
+
+@pytest.mark.parametrize("arm", ["kernels", "xla"])
+def test_glove_step_holds_nothing_table_sized_beside_its_table(
+        arm, glove, one_chip, no_compile_cache, monkeypatch):
+    """Cell 13's step at full size for a described v5e: the donated 11.24 GB
+    table is rewritten in place and never copied or transposed, with every
+    scope the cell's metrics read.  As the chip runs it (``kernels``): under
+    ``ps.pull`` ONE gather of whole physical rows ``f32[65536,640]``; under
+    ``ps.push/ps.combine`` the permute of the batch's gradient rows and ONE
+    ``sorted_row_update_tiles`` call into a zeroed ``f32[65536,640]`` block;
+    in the rule's loop ONE gather ``f32[32768,640]`` under ``ps.rule`` and
+    ONE ``sorted_row_assign_tiles`` call on the table, the write-back.  What
+    the step holds beside the table goes with the batch: 0.67 GB.  Off the
+    TPU (``xla``) the same layout with one scatter-add for the sums and one
+    row ``set`` of ``f32[32768,640]`` for the write-back, no kernel."""
+    model, gl = glove
+    spec = jax.eval_shape(lambda: gl.make_store(model)).spec
+    assert spec.layout == "packed" and spec.pack == 1
+    assert spec.table_shape() == (GLOVE_PHYS_ROWS, 640)
+    assert store_mod._flat_wide_rule(spec)
+    for takes in (store_mod._combine_kernel_takes, store_mod._set_kernel_takes):
+        assert not takes(spec)  # this is a CPU
+    if arm == "kernels":
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        n0 = row_update.refusal_count()
+        assert store_mod._combine_kernel_takes(spec)
+        assert store_mod._set_kernel_takes(spec)
+        assert row_update.refusal_count() == n0
+    batch = {
+        "word": _shape(one_chip, (GLOVE_BATCH,), jnp.int32),
+        "context": _shape(one_chip, (GLOVE_BATCH,), jnp.int32),
+        "count": _shape(one_chip, (GLOVE_BATCH,), jnp.float32),
+        "mask": _shape(one_chip, (GLOVE_BATCH,), jnp.bool_),
+    }
+    compiled = jax.jit(
+        make_train_step(gl.GloVe(model), spec), donate_argnums=(0, 1)
+    ).lower(_shape(one_chip, spec.table_shape(), jnp.float32), (), batch).compile()
+    mem = compiled.memory_analysis()
+    assert 11.24 * GB < mem.alias_size_in_bytes < 11.25 * GB  # in place
+    assert mem.temp_size_in_bytes < 1.0 * GB
+    text = compiled.as_text()
+    assert not re.search(r"f32\[4392040,640\]\S* (copy|transpose)\(", text)
+    assert "f32[4392034,602]" not in text and "f32[4392040,602]" not in text
+    for scope in ("ps.pull", "ps.compute/ps.cooc_grad_rows", "ps.push/ps.combine",
+                  "ps.push/while/body/ps.rule"):
+        assert scope in text, scope
+    lines = text.splitlines()
+    pulls = [c for c in lines if re.search(r" gather\(", c)
+             and " f32[65536,640]{1,0" in c and "ps.pull" in c]
+    assert len(pulls) == 1 and "slice_sizes={1,640}" in pulls[0], pulls
+    reads = [c for c in lines if re.search(r" gather\(", c)
+             and " f32[32768,640]{1,0" in c]
+    assert len(reads) == 1 and "ps.push/while/body/ps.rule" in reads[0], reads
+    kernels = [line for line in lines if "tpu_custom_call" in line]
+    scatters = [line for line in lines if re.search(r" scatter\(", line)]
+    if arm == "xla":
+        assert not kernels
+        sets = [c for c in scatters if " f32[4392040,640]" in c]
+        assert len(sets) == 1 and "ps.push/while/body" in sets[0], scatters
+        sums = [c for c in scatters if " f32[65536,640]" in c]
+        assert len(sums) == 1 and "ps.push/ps.combine" in sums[0], scatters
+        return
+    assert not scatters
+    names = sorted(k.strip().split(" ", 1)[0].rstrip(".0123456789") for k in kernels)
+    assert names == ["%sorted_row_assign_tiles", "%sorted_row_update_tiles"], names
+    by_name = {k.strip().split(".", 1)[0]: k for k in kernels}
+    assert " f32[4392040,640]{1,0" in by_name["%sorted_row_assign_tiles"]
+    assert "ps.push/while/body" in by_name["%sorted_row_assign_tiles"]
+    assert " f32[65536,640]{1,0" in by_name["%sorted_row_update_tiles"]
+    assert "ps.push/ps.combine" in by_name["%sorted_row_update_tiles"]
