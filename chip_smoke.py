@@ -689,6 +689,16 @@ class Smoke:
             close(table_w.at[ids_w].add(deltas_w), 1e-6),
         )
 
+        # ... and handed rows narrower than the table's (cell 5's 600 lanes
+        # of 640): the kernel adds into lanes [0, 600) and pads nothing
+        self._kernel_case(
+            "row_update_tiles_d640_w600_f32",
+            lambda t, i, dl: row_update.scatter_add(
+                t, i, dl, interpret=interpret),
+            (table_w, ids_w, deltas_w[:, :600]),
+            close(table_w.at[ids_w, :600].add(deltas_w[:, :600]), 1e-6),
+        )
+
         # a rule's narrow rows at cell 6's row (three lanes held at four,
         # the table rows-minor): every touched tile of 128 rows read, set
         # and written back, the bits of XLA's row set

@@ -360,7 +360,7 @@ def _narrow_pull(table: Array, ids: Array, width: int) -> Array:
 
 def _phys_scatter_args(
     spec: StoreSpec, table: Array, flat_ids: Array, flat_deltas: Array,
-    flat_mask: Optional[Array] = None,
+    flat_mask: Optional[Array] = None, tiles: bool = False,
 ):
     """(ids, deltas) at PHYSICAL granularity for the scatter-add, the
     masked lanes' deltas zeros.
@@ -373,7 +373,13 @@ def _phys_scatter_args(
     Pallas call that reads the deltas feature-major and leaves a masked
     lane out itself (``ops/packed.lane_shift_kernel``; under a mesh
     :func:`_packed_shift_on_mesh`): the same rows in the same order, bit
-    for bit."""
+    for bit.  A row that lies ONE to a physical row (``k`` = 1: 600 lanes in
+    640) has nothing to shift, only its pad to whole registers: XLA's
+    scatter-add needs it, the tile kernel (``tiles``: the caller's reading
+    of :func:`_tile_kernel_takes`) adds a row of ``w`` <= ``W`` lanes into
+    lanes ``[0, w)`` and is handed the deltas as they are (a pad of the
+    batch's rows in HBM in front of it was 0.45 ms a call in cell 5: PERF.md
+    section 6, PR 57)."""
     kernel = _shift_kernel_takes(spec, flat_ids.shape[0])  # packed alone
     if not kernel:
         flat_deltas = _zero_masked(flat_deltas, flat_mask)
@@ -384,7 +390,9 @@ def _phys_scatter_args(
 
     d = spec.row_width
     deltas = flat_deltas.reshape(-1, d).astype(table.dtype)
-    if not kernel:
+    if tiles and spec.pack == 1:
+        shifted = deltas  # the tile kernel takes a row at its own width
+    elif not kernel:
         shifted = lane_shift_deltas(deltas, flat_ids, d)
     elif spec.mesh is not None:
         shifted = _packed_shift_on_mesh(spec, deltas.T, flat_ids, flat_mask)
@@ -397,7 +405,8 @@ def _phys_scatter_args(
 
 def _zero_masked(flat_deltas: Array, flat_mask: Optional[Array]) -> Array:
     """Masked-out lanes keep their id but carry a zero delta: for the add
-    path zero deltas are a no-op; a rule's count is masked besides."""
+    path zero deltas are a no-op (a rule store's push sends a masked lane to
+    the sentinel instead and zeroes nothing: :func:`_push_rule`)."""
     if flat_mask is None:
         return flat_deltas
     return jnp.where(
@@ -434,6 +443,10 @@ def push(
     deltas stay in the order of the batch and are added one by one, XLA's
     roundings bit for bit) and every touched tile of eight rows read, added
     to and written back once a block of lanes (PERF.md section 6, PR 33).
+    The kernel is handed the deltas at their OWN width ``w`` <= the table's
+    ``W`` (600 lanes for rows of 640: it adds into lanes ``[0, w)`` and
+    leaves the table's pad lanes as they are); only XLA's arm pads the
+    batch to whole registers (:func:`_phys_scatter_args`).
     The kernel takes physical rows of several 128-lane registers always, and
     rows of ONE register where the batch has no more than an eighth as many
     lanes as the table has rows: there the TPU compiler leaves its
@@ -521,10 +534,11 @@ def push_counted(
     flat_mask = None if mask is None else mask.reshape(-1)
 
     if spec.update == "add":
+        tiles = _tile_kernel_takes(spec, flat_ids.shape[0])
         s_ids, s_deltas = _phys_scatter_args(
-            spec, table, flat_ids, flat_deltas, flat_mask
+            spec, table, flat_ids, flat_deltas, flat_mask, tiles
         )
-        if _tile_kernel_takes(spec, s_ids.shape[0]):
+        if tiles:
             from ..ops.row_update import scatter_add_counted
 
             table, lanes, tile_rows = scatter_add_counted(
@@ -541,9 +555,12 @@ def push_counted(
             None,
         )
 
+    # (a masked lane's delta goes as it is: `_push_rule` sends the lane to
+    # the sentinel, and no combine arm lets a dropped lane's value reach a
+    # kept row)
     on_shards = _rule_on_shards_takes(spec)
     return (_push_rule_on_shards if on_shards else _push_rule)(
-        spec, table, flat_ids, _zero_masked(flat_deltas, flat_mask), flat_mask
+        spec, table, flat_ids, flat_deltas, flat_mask
     )
 
 
@@ -593,13 +610,22 @@ def _push_rule(
     :func:`_rewrite_packed`, which reads and writes whole physical rows,
     and ``counted`` then carries ``ps_rule_packed_rows``.  A rule row wider
     than a register lies flat in several (:func:`_flat_wide_rule`, packed at
-    ``k`` = 1): its deltas are padded to the physical width in front of the
-    combine, which on a TPU sums them through the tile kernel in the order
-    of the stream (``ops/dedup._tile_sums``), and its write-back's kernel
+    ``k`` = 1): nothing here pads its deltas.  On a TPU the combine sums
+    them, ``w`` lanes wide as they come, through the tile kernel into a
+    zeroed block of whole registers, in the order of the stream
+    (``ops/dedup._tile_sums``: the sums come out ``W`` wide, zeros past
+    ``w``; XLA's scatter-add arm sums at ``w``), and its write-back's kernel
     arm is ``ops/row_update.sorted_tile_assign``, every touched tile of
     eight rows read, set and written once (``ps_rule_tiles`` counts those
     tile rows, ``ps_combine_kernel_writes`` the combine's; PERF.md section
-    6, PR 55)."""
+    6, PRs 55 and 57).
+
+    ``flat_deltas`` come as the logic made them, a masked lane's too
+    (``live`` false): every such lane, like every id past the table, is
+    sent to the sentinel here, each combine arm drops a sentinel's lanes,
+    and what they hold (NaN, Inf) reaches no kept row
+    (``tests/test_store.py`` holds the four arms to that), so no pass
+    zeroes them first."""
     from ..ops.dedup import _SORT_CARRIES_LANES, combine_runs
     from ..ops.row_update import sorted_tile_set
 
@@ -628,10 +654,6 @@ def _push_rule(
         if live is not None:
             dead = dead | ~live
         vals = flat_deltas.reshape(n, -1).astype(table.dtype)
-        if flat_wide:
-            # whole registers from here on, as the table holds the row: the
-            # sums, the rule's read and the write-back move physical rows
-            vals = jnp.pad(vals, ((0, 0), (0, table.shape[1] - vals.shape[1])))
         row_ids, combined, issued = combine_runs(
             jnp.where(dead, sentinel, flat_ids), vals, sentinel,
             kernel=sums_arm,
@@ -694,7 +716,8 @@ def _rewrite_packed(
     physical rows written)``, or, where the tile kernel wrote a flat wide
     row's (below), ``(table, tile rows read and written)``.  ``ids`` are
     sorted and distinct, the sentinel last; ``sums`` ``(chunk, row_width)``
-    (a flat wide row's: ``(chunk, physical lanes)``, zeros past its width).
+    (a flat wide row's from the tile kernel's sums: ``(chunk, physical
+    lanes)``, zeros past its width).
 
     Under ``ps.rule`` ONE gather of the chunk's physical rows (``ids //
     k``, whole 128-lane registers), the lane slice down to each id's
@@ -716,7 +739,12 @@ def _rewrite_packed(
     ``ops/row_update.sorted_tile_assign``, the wide add push's tile walk
     with a store for its body: a Pallas DMA cannot write one such row
     alone, so every touched tile of eight rows is read, set and written
-    once (PERF.md section 6, PR 55).  Selects and copies only, never an add or a
+    once (PERF.md section 6, PR 55).  That arm is handed the NEW rows as the
+    rule made them, ``(chunk, row_width)``: at ``k`` = 1 a row has one
+    window and no neighbour, and the kernel stores lanes ``[0, row_width)``
+    of a row and keeps its pad lanes as it read them, so nothing shifts,
+    pads or merges on its way (PR 57; the other two arms write whole
+    physical rows and merge first).  Selects and copies only, never an add or a
     masked sum: an untouched logical row inside a touched physical row,
     the pad lanes and a row's NaN or -0.0 come back bit for bit.  A
     physical row whose touched rows fall on two chunks is written by
@@ -737,6 +765,15 @@ def _rewrite_packed(
                 (chunk,) + spec.value_shape),
             sums.reshape((chunk,) + spec.value_shape),
         ).astype(table.dtype).reshape(chunk, d)
+    writes = jnp.concatenate(
+        [jnp.ones((1,), bool), phys[1:] != phys[:-1]]
+    ) & (phys < phys_rows)
+    at = jnp.where(writes, phys, phys_rows)
+    if kernel and lanes > 128:
+        # k = 1: every live lane writes, the sentinels close the chunk; a
+        # new row has one window and no neighbour to merge with, and the
+        # kernel keeps the pad lanes of the rows it sets as it read them
+        return sorted_tile_assign(table, at, new)
     placed = lane_shift_deltas(new, ids, d)
     # the window a lane lies in (the pad lanes: one no id has)
     window = (jnp.arange(lanes, dtype=jnp.int32) // d)[None]
@@ -749,13 +786,6 @@ def _rewrite_packed(
         )[:, None] & (window == jnp.pad(sub[s:], (0, s))[:, None])
         merged = jnp.where(
             near, jnp.pad(placed[s:], ((0, s), (0, 0))), merged)
-    writes = jnp.concatenate(
-        [jnp.ones((1,), bool), phys[1:] != phys[:-1]]
-    ) & (phys < phys_rows)
-    at = jnp.where(writes, phys, phys_rows)
-    if kernel and lanes > 128:
-        # k = 1: every live lane writes, the sentinels close the chunk
-        return sorted_tile_assign(table, at, merged)
     if kernel:
         table = sorted_row_set(table, at, merged)
     else:
@@ -940,8 +970,8 @@ def _combine_kernel_takes(spec: StoreSpec) -> bool:
     rule, and rows wider than a sort carries (``ops/dedup.
     _SORT_CARRIES_LANES``; a narrower row rides through the sort whatever
     the backend), float32, that fit one 128-lane register or lie flat in
-    several (:func:`_flat_wide_rule`: the combine then sees the row padded
-    to its whole registers and sums it through the TILE kernel, in the
+    several (:func:`_flat_wide_rule`: the combine then sums the row, at
+    its own width, into whole registers through the TILE kernel, in the
     order of the stream: ``ops/dedup._tile_sums``).  Static per compiled
     step.  Such a store that the kernel REFUSES (bfloat16; a DENSE row of
     more than 128 lanes) keeps the scatter-add, counted and warned of once.
@@ -965,11 +995,11 @@ def _flat_wide_rule(spec: StoreSpec) -> bool:
     """A rule store whose row lies FLAT in several whole 128-lane registers,
     one logical row to a physical row (``layout="packed"`` at ``k`` = 1 and
     more than 128 lanes: what ``"auto"`` gives a rule row of one axis over
-    128 lanes, GloVe's 602 in 640).  Its push pads the batch's deltas to the
-    physical width before the combine, so that the sums, the rule's read
-    and the write-back all move whole registers, and its two kernel arms
-    are the TILE kernel's (eight rows to a tile: a row that wide cannot be
-    written alone)."""
+    128 lanes, GloVe's 602 in 640).  Its two kernel arms are the TILE
+    kernel's (eight rows to a tile: a row that wide cannot be written
+    alone), which takes the batch's rows at their own 602 lanes: the sums
+    land in a zeroed block of whole registers, the rule reads whole
+    physical rows, and the write-back stores a new row's own lanes."""
     return (spec.update != "add" and spec.layout == "packed"
             and spec.pack == 1 and spec.table_shape()[1] > 128)
 

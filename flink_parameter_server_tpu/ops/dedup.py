@@ -124,15 +124,19 @@ def combine_runs(
       sum by doubling, which sums each run as a balanced tree, the batch's
       lanes along the minor axis), and a second sort that moves the lanes
       ending a run to the front;
-    - wider rows, ``kernel`` false (the CPU, a mesh, bfloat16, rows over
-      128 lanes that are no whole registers): :func:`_wide_runs`' ONE
-      scatter-add in the order of
-      the stream, ``np.add.at``'s own additions;
-    - wider rows, ``kernel`` true, several whole registers (a TPU,
-      float32, a multiple of 128 lanes over 128: a rule store's flat wide
-      row, GloVe's 640): the rows permuted once into sorted order and added
-      into a zeroed block by the TILE kernel, in the order of the stream
-      (:func:`_tile_sums`; PERF.md section 6, PR 55);
+    - wider rows, ``kernel`` false (the CPU, a mesh, bfloat16): :func:`
+      _wide_runs`' ONE scatter-add in the order of the stream,
+      ``np.add.at``'s own additions, into a zeroed ``(n, w)`` block: the
+      sums come back ``w`` lanes wide;
+    - wider rows, ``kernel`` true, more than 128 lanes (a TPU, float32: a
+      rule store's flat wide row, at the width the push holds it, GloVe's
+      602): the rows permuted once into sorted order and added, ``w`` lanes
+      a row, into a zeroed block of whole registers by the TILE kernel, in
+      the order of the stream (:func:`_tile_sums`; PERF.md section 6, PRs
+      55 and 57).  The sums come back ``(n, W)``, ``W`` = ``w`` rounded up
+      to 128 lanes (640), zeros past ``w``: :func:`kernel_refusal` is
+      asked about ``W``, and ``core/store._rewrite_packed`` slices these
+      sums down to the row's own ``w`` lanes (the arm above hands it ``w``);
     - wider rows, ``kernel`` true (a TPU, float32, at most 128 lanes): the
       rows permuted ONCE into sorted order at 128 lanes and their runs summed
       by the row kernel of ``ops/row_update`` (:func:`_kernel_sums`): on
@@ -308,11 +312,13 @@ def _kernel_sums(
 def _tile_sums(
     order: Array, slot: Array, vals: Array, interpret: Optional[bool]
 ) -> Tuple[Array, Array]:
-    """:func:`_kernel_sums` for rows of SEVERAL whole 128-lane registers
-    (``vals`` float32 ``(n, w)``, ``w`` a multiple of 128:
-    :func:`kernel_refusal`), which the row kernel cannot write alone: the
-    rows permuted once into sorted order and added into a zeroed ``(n, w)``
-    block by ``ops/row_update``'s TILE kernel (``sorted_tile_add``, the wide
+    """:func:`_kernel_sums` for rows wider than a register (``vals``
+    float32 ``(n, w)``, as the push holds them: GloVe's 602 lanes), which
+    the row kernel cannot write alone: the rows permuted once into sorted
+    order and added into a zeroed ``(n, W)`` block of whole registers
+    (``W`` = ``w`` rounded up to 128: :func:`kernel_refusal` is asked about
+    ``W``; the sums' lanes past ``w`` are zeros)
+    by ``ops/row_update``'s TILE kernel (``sorted_tile_add``, the wide
     add push's: every touched tile of eight slots read, added to lane by
     lane and written once).  The slots are the ranks ``0 .. distinct - 1``,
     so the tiles are full and the walk opens an eighth as many as there are
@@ -321,20 +327,11 @@ def _tile_sums(
     addition by addition what ``_wide_runs``' scatter-add and ``np.add.at``
     do, bit for bit.  Beside the sums, the tile rows the calls read and
     wrote (the kernel's DMA descriptors: two a tile row)."""
-    from .row_update import _calls, _pad_for_calls, _sorted_tile_add_counted
+    from .row_update import _tile_add_calls
 
     n, w = vals.shape
-    pad = _pad_for_calls(n)
-    if pad:
-        order = jnp.pad(order, (0, pad))
-        slot = jnp.pad(slot, (0, pad), constant_values=_INT32_MAX)
-    block = jnp.zeros((-(-n // 8) * 8, w), jnp.float32)
-    opened = jnp.zeros((), jnp.int32)
-    for _, slots, lanes in _calls(slot, order):
-        block, _, moved = _sorted_tile_add_counted(
-            block, slots, jnp.take(vals, lanes, axis=0, mode="clip"),
-            interpret)
-        opened = opened + moved
+    block = jnp.zeros((-(-n // 8) * 8, -(-w // 128) * 128), jnp.float32)
+    block, _, opened = _tile_add_calls(block, slot, order, vals, interpret)
     return block[:n], opened
 
 

@@ -235,6 +235,14 @@ def lane_shift_deltas(deltas: Array, ids: Array, row_width: int) -> Array:
     and zeros elsewhere — ready to scatter-add at physical-row granularity.
     ``k`` static pads chosen by a ``select`` on the sub-row index (see
     :func:`_sub_row_slice` for what it must not be).
+
+    At ``k`` = 1 (a row of ``w`` lanes flat in ``W``: 600 in 640) there is
+    nothing to shift and this is the pad to whole registers alone, a pass
+    over the batch's rows in HBM that only a consumer of ``W``-lane rows
+    needs: XLA's ``table.at[].add`` / ``set``.  ``ops/row_update``'s tile
+    kernel takes rows of ``w`` <= ``W`` lanes as they are, and the callers
+    that go to it skip this function (``core/store._phys_scatter_args``,
+    ``core/store._rewrite_packed``).
     """
     n, d = deltas.shape
     assert d == row_width, (d, row_width)

@@ -77,7 +77,11 @@ adds every lane's delta to its row's sublane, one float32 add a lane in the
 order of the batch (the sort is stable), and writes the tile rows back: a
 read-modify-write per touched tile row instead of XLA's serial one per lane
 (124 ns a 640-lane row on the v5e), with XLA's roundings, bit for bit.  It
-reads the table itself, so it takes no old rows.  Since PR 41 it walks as
+reads the table itself, so it takes no old rows, and it takes the deltas at
+the width ``w`` <= ``W`` their caller holds them (word2vec's 600 lanes,
+fastText's 300 in 384, GloVe's 602): a lane's add touches lanes ``[0, w)``
+of its row of the tile, so nothing pads a batch to ``W`` in HBM in front of
+it (PR 57).  Since PR 41 it walks as
 the third kernel does: descriptors and adds eight to a loop trip
 (:func:`_each`), a block's copies answered sixteen tile rows a wait, and
 three tile buffers, so that the next block's reads are in flight under this
@@ -816,7 +820,10 @@ def _tile_kernel(tiles_ref, words_ref, counts_ref, dl_ref, table_ref, out_ref,
       rows, how many kept lanes, and 1 where its first tile row is carried
       over from the block before; blocks -3 to -1 and the three after the
       last are zeros.
-    dl_ref: (block, W) f32 VMEM — the deltas of block ``g - 1``, sorted.
+    dl_ref: (block, w) f32 VMEM — the deltas of block ``g - 1``, sorted, at
+      the width ``w`` <= ``W`` their caller holds them (a logical row of 600
+      lanes in a table of 640: nothing pads it to ``W`` in HBM for the
+      kernel's sake).
     table_ref / out_ref: the aliased (rows, W) table in HBM.
     tile_buf: (3, block, 8, W) f32 VMEM — three blocks' tile rows: one
       being read, one being added to, one being written back.
@@ -837,9 +844,16 @@ def _tile_kernel(tiles_ref, words_ref, counts_ref, dl_ref, table_ref, out_ref,
     being added to it (:func:`sorted_tile_assign`, a rule store's wide
     write-back): the same walk, the same copies, one store a lane and no
     load; the tile's other rows go back as they were read.
+
+    Either body touches lanes ``[0, w)`` of a lane's row of the tile and
+    no other: lanes ``[w, W)`` of every row, an assigned one's too, are
+    written back as they were read, bit for bit (the table's pad lanes,
+    whatever they hold).  At ``w`` = ``W`` the slice is the whole row.
     """
     pl, pltpu = _pallas()
     lax = jax.lax  # not jnp: an operator on a tracer is a jitted call to trace
+    w = dl_ref.shape[1]
+    lanes = slice(None) if w == tile_buf.shape[3] else pl.ds(0, w)
 
     del table_ref  # aliased to out_ref
     g = pl.program_id(0)
@@ -894,7 +908,7 @@ def _tile_kernel(tiles_ref, words_ref, counts_ref, dl_ref, table_ref, out_ref,
         # scatter-add and a plain ``np.add.at`` do, rounding for rounding
         word = words_ref[lax.add(base, lane)]
         at = (slot, lax.bitwise_and(word, 255),
-              pl.ds(lax.shift_right_logical(word, 8), 1), slice(None))
+              pl.ds(lax.shift_right_logical(word, 8), 1), lanes)
         if assign:
             tile_buf[at] = dl_ref[pl.ds(lane, 1), :]
         else:
@@ -964,8 +978,12 @@ def sorted_tile_add(
 
     ``table``: (rows, W) float32, whole tiles (:func:`tile_refusal`).
     ``sorted_ids``: (n,) int32 ASCENDING, lanes to drop at the end with an
-    id >= the row count (:func:`sort_by_row`).  ``deltas``: (n, W) in that
-    order; a dropped lane's may be anything.  In place when the enclosing
+    id >= the row count (:func:`sort_by_row`).  ``deltas``: (n, w) in that
+    order, ``w`` <= ``W``: a lane is added into lanes ``[0, w)`` of its row
+    and lanes ``[w, W)`` of every row are left as they are, so no caller
+    pads a batch's rows to ``W`` for the kernel (a pad of 57,344 x 640 f32
+    in HBM was 0.45 ms on the v5e: PERF.md section 6, PR 57); a dropped
+    lane's row may be anything.  In place when the enclosing
     jit donates the table; an eager call copies it first.  Off the TPU the
     kernel is interpreted.
 
@@ -980,18 +998,22 @@ def sorted_tile_add(
 
 
 def _sorted_tile_add_counted(table, sorted_ids, deltas, interpret,
-                             assign=False):
+                             assign=False, first=None):
     """:func:`sorted_tile_add`'s ``(table, kept lanes, tile rows read and
     written)``, the last two from the plan's own counts (``assign``:
-    :func:`sorted_tile_assign`'s)."""
+    :func:`sorted_tile_assign`'s; ``first``: :func:`_tile_add`'s)."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
+    name = "sorted_tile_assign" if assign else "sorted_tile_add"
+    if deltas.ndim != 2 or deltas.shape[1] > table.shape[-1]:
+        raise ValueError(
+            f"{name}: rows of shape {tuple(deltas.shape[1:])} for a table "
+            f"of rows of shape {tuple(table.shape[1:])}")
     why = tile_refusal(table.shape, table.dtype) or _too_many(
         sorted_ids.shape[0])
     if why is not None and not interpret:
-        name = "sorted_tile_assign" if assign else "sorted_tile_add"
         raise ValueError(f"{name}: {why}")
-    return _tile_add(table, sorted_ids, deltas, block=BLOCK,
+    return _tile_add(table, sorted_ids, deltas, first, block=BLOCK,
                      interpret=interpret, assign=assign)
 
 
@@ -1008,7 +1030,11 @@ def sorted_tile_assign(
     body (a Pallas DMA cannot write ONE row wider than 128 lanes: eight rows
     to a tile).  Every touched tile row of 8 rows is read, the kept lanes'
     rows replaced, and the tile row written back once; its other rows, NaN
-    and -0.0 included, come back bit for bit.  ``sorted_ids``: (n,) int32
+    and -0.0 included, come back bit for bit.  ``new_rows``: (n, w), ``w``
+    <= the table's ``W``: lanes ``[0, w)`` of an assigned row are replaced
+    and its lanes ``[w, W)`` (the table's pad lanes, whatever they hold)
+    come back as they were read, so the caller neither pads the new rows
+    nor merges them into the gathered ones.  ``sorted_ids``: (n,) int32
     ascending and distinct, lanes to drop at the end with an id >= the row
     count; a batch over ``MAX_LANES`` lanes goes in several calls of equal
     size.  The write-back of a rule store whose row is wider than a
@@ -1028,8 +1054,13 @@ def sorted_tile_assign(
 
 @functools.partial(
     jax.jit, static_argnames=("block", "interpret", "assign"), inline=True)
-def _tile_add(table, sorted_ids, deltas, *, block: int, interpret: bool,
-              assign: bool = False):
+def _tile_add(table, sorted_ids, deltas, first=None, *, block: int,
+              interpret: bool, assign: bool = False):
+    """One call of the tile kernel.  ``deltas``: the ``n`` sorted lanes'
+    rows, or, with ``first`` (an int32 scalar), the rows of a longer sorted
+    batch of which this call's lanes begin at block ``first``: the kernel's
+    pipeline reads its blocks from there, nothing is sliced
+    (:func:`_tile_add_calls`)."""
     pl, pltpu = _pallas()
 
     rows, width = table.shape
@@ -1044,19 +1075,27 @@ def _tile_add(table, sorted_ids, deltas, *, block: int, interpret: bool,
         sorted_ids = jnp.concatenate(
             [sorted_ids, jnp.full((pad,), _INT32_MAX, jnp.int32)]
         )
+        assert first is None, "a stretch of a longer batch is whole blocks"
         deltas = jnp.pad(deltas, ((0, pad), (0, 0)))
     tiles, words, counts = _tile_plan(sorted_ids, rows, block)
 
     blocks = (n + pad) // block
+    if first is not None:
+        # one scalar more at the end of the counts, which no step reads
+        counts = jnp.concatenate([counts, first.reshape(1)])
+
+    def delta_block(g, tiles_ref, words_ref, counts_ref):
+        at = jax.lax.min(jax.lax.max(g - 1, 0), blocks - 1)
+        if first is None:
+            return at, 0
+        return at + counts_ref[3 * blocks + 18], 0
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(blocks + 3,),  # step g works block g - 1: `_tile_kernel`
         in_specs=[
-            pl.BlockSpec(
-                (block, width),
-                lambda g, *_: (
-                    jax.lax.min(jax.lax.max(g - 1, 0), blocks - 1), 0),
-            ),
+            # the deltas' own width: w <= W
+            pl.BlockSpec((block, deltas.shape[1]), delta_block),
             pl.BlockSpec(memory_space=pl.ANY),  # the table stays in HBM
         ],
         out_specs=pl.BlockSpec(memory_space=pl.ANY),
@@ -1079,7 +1118,8 @@ def _tile_add(table, sorted_ids, deltas, *, block: int, interpret: bool,
     )(tiles, words, counts, deltas, table)
     # a block's (opened, kept, carried): a carried tile row is opened by
     # both blocks that share it and moved once
-    opened, kept, carried = jnp.sum(counts.reshape(-1, 3), axis=0)
+    opened, kept, carried = jnp.sum(
+        counts[:3 * blocks + 18].reshape(-1, 3), axis=0)
     return table, kept, opened - carried
 
 
@@ -1379,9 +1419,10 @@ def scatter_add_counted(
     *,
     interpret: Optional[bool] = None,
 ) -> Tuple[Array, Array, Array]:
-    """``table.at[ids].add(deltas, mode="drop")`` through the tile kernel:
-    sort the ids, bring the deltas into that order (a batch over
-    ``MAX_LANES`` lanes stretch by stretch, one call each), and one
+    """``table.at[ids].add(deltas, mode="drop")`` through the tile kernel
+    (``deltas`` ``(n, w)``, ``w`` <= the table's ``W``: lanes ``[0, w)`` of
+    the rows): sort the ids, bring the deltas into that order (a batch over
+    ``MAX_LANES`` lanes in several calls: :func:`_tile_add_calls`), and one
     read-modify-write per touched tile row.  Lanes of one row are added in
     the order the batch holds them (the sort is stable).  An eager call is
     one jitted program (one copy of the table, not one a kernel call).
@@ -1392,14 +1433,53 @@ def scatter_add_counted(
     if not isinstance(table, jax.core.Tracer):
         return _scatter_add_jitted(table, ids, deltas, interpret=interpret)
     sid, order = sort_by_row(ids.reshape(-1), None, table.shape[0])
-    pad = _pad_for_calls(sid.shape[0])
+    return _tile_add_calls(table, sid, order, deltas, interpret)
+
+
+def _tile_add_calls(table, sorted_ids, order, deltas, interpret):
+    """``(table, kept lanes, tile rows read and written)`` of the tile
+    kernel's calls over a sorted batch (``sorted_ids`` ascending, the lanes
+    to drop last; ``order[k]`` the row of ``deltas`` that sorted lane ``k``
+    adds): as few calls as hold ``MAX_LANES`` lanes each, of ONE shape (the
+    ids and ``order`` are padded to it, never the rows).
+
+    ``deltas`` ``(n, w)`` with ``w`` <= the table's ``W``.  Rows narrower
+    than the table's are permuted ONCE for all the calls, each of which
+    reads its blocks out of that one buffer (``_tile_add``'s ``first``).
+    Such a buffer has pad lanes in its ``(8, 128)`` tiles, and where one
+    lies idle across a kernel call the TPU compiler, once it counts the
+    step's memory as tight, "compresses" it (``remat_compressed``: a copy
+    to ``{0,1}`` before the call and a copy back after, the pad this width
+    was rid of come back twice); a permute a call leaves the later calls'
+    stretches, which nothing orders behind the earlier calls, lying across
+    them.  Compiled both ways for a described v5e (PERF.md section 6, PR
+    57): a permute a call draws four such copies round cell 7's three calls
+    (300 lanes, a 10.81 GB table; two from 6.1 GB on) and none at cell 5's
+    7.68 GB (600 lanes) but two from 8.45 GB on, a table a tenth larger;
+    how tight is tight is the compiler's and no shape of ours tells it.
+    The one buffer is an operand of every call, has no stretch of idleness
+    and drew no copy at any size tried (to 12.8 GB).  Rows of whole
+    registers (``w`` = ``W``) have no pad lane to shed: every call permutes
+    its own stretch, as it did before the kernel took narrower rows (cell
+    10: nine calls on a 12.58 GB table, no such copy, and its step stays
+    what it was)."""
+    pad = _pad_for_calls(sorted_ids.shape[0])
     if pad:
-        sid = jnp.concatenate([sid, jnp.full((pad,), _INT32_MAX, jnp.int32)])
+        sorted_ids = jnp.concatenate(
+            [sorted_ids, jnp.full((pad,), _INT32_MAX, jnp.int32)])
         order = jnp.concatenate([order, jnp.zeros((pad,), jnp.int32)])
+    calls = _calls(sorted_ids, order)
+    whole = None
+    if len(calls) > 1 and deltas.shape[1] < table.shape[1]:
+        whole = jnp.take(deltas, order, axis=0, mode="clip")
     lanes = tile_rows = jnp.zeros((), jnp.int32)
-    for _, s, o in _calls(sid, order):
+    for lo, s, o in calls:
+        if whole is None:
+            rows, first = jnp.take(deltas, o, axis=0, mode="clip"), None
+        else:
+            rows, first = whole, jnp.asarray(lo // BLOCK, jnp.int32)
         table, kept, moved = _sorted_tile_add_counted(
-            table, s, jnp.take(deltas, o, axis=0, mode="clip"), interpret)
+            table, s, rows, interpret, False, first)
         lanes, tile_rows = lanes + kept, tile_rows + moved
     return table, lanes, tile_rows
 

@@ -87,13 +87,23 @@ def test_the_step_is_the_reference_on_parameters_and_accumulators(seed, layout):
     assert float(jnp.sum(outs["cost"])) > 0
 
 
-def test_a_masked_record_changes_nothing():
+@pytest.mark.parametrize("arm", ["xla", "tile_kernels"])
+def test_a_masked_record_changes_nothing(arm, monkeypatch):
+    """... whatever its gradient holds: the push hands a masked lane's delta
+    on as it is (no pass zeroes the pushed block since PR 57), so a masked
+    record of count 0 (``ln 0``: a row of -inf and NaN gradients) must reach
+    no kept row, in the scatter-add's sums and in the tile kernel's."""
+    if arm == "tile_kernels":
+        monkeypatch.setattr(store_mod, "_set_kernel_takes", lambda spec: True)
+        monkeypatch.setattr(store_mod, "_combine_kernel_takes", lambda spec: True)
     store = gl.make_store(MODEL, seed=3)
     (b,) = _batches(3, n=1)
     step = jax.jit(make_train_step(gl.GloVe(MODEL), store.spec))
     kept = {**b, "mask": b["mask"] & (np.arange(64) % 3 != 0)}
     dropped = {k: v[kept["mask"]] for k, v in kept.items()}
+    kept["count"] = np.where(kept["mask"], b["count"], 0.0).astype(np.float32)
     got, _, _ = step(store.table, (), kept)
+    assert np.isfinite(np.asarray(got)).all()
     want, _, _ = jax.jit(make_train_step(gl.GloVe(MODEL), store.spec))(
         store.table, (), dropped)
     assert np.asarray(got).tobytes() == np.asarray(want).tobytes()

@@ -416,18 +416,27 @@ def _add_case(name, rng):
     raise KeyError(name)
 
 
-@pytest.mark.parametrize("width,name", [
-    (256, name) for name in ADD_CASES
+@pytest.mark.parametrize("width,w,name", [
+    (256, 256, name) for name in ADD_CASES
 ] + [
-    (width, name)
+    (width, width, name)
     for width in (384, 640)
     for name in ("a_hot_row_over_three_blocks", "a_tile_row_four_blocks_share",
                  "several_calls")
+] + [
+    # rows NARROWER than the table's (cells 5, 7 and 13: PR 57)
+    (width, w, name)
+    for width, w in ((640, 600), (384, 300), (640, 602))
+    for name in ("a_hot_row_over_three_blocks", "a_tile_row_two_blocks_share",
+                 "several_calls")
 ])
 def test_sorted_tile_add_is_numpys_add_at_in_batch_order_bit_for_bit(
-        width, name, monkeypatch):
+        width, w, name, monkeypatch):
     """One float32 add a lane onto its row, in the order the batch holds
-    the lanes: every bit of the table against ``np.add.at``."""
+    the lanes: every bit of the table against ``np.add.at``.  Rows of ``w``
+    < ``width`` lanes are added into lanes ``[0, w)``; the table's lanes
+    ``[w, width)`` hold NaN and -0.0, in touched rows and untouched, and
+    come back as they were."""
     rng = np.random.default_rng([width, ADD_CASES.index(name)])
     kept, dropped = _add_case(name, rng)
     ids = rng.permutation(np.concatenate([
@@ -435,7 +444,9 @@ def test_sorted_tile_add_is_numpys_add_at_in_batch_order_bit_for_bit(
                                                  ADD_ROWS + 5),
     ])).astype(np.int32)
     table = rng.normal(size=(ADD_ROWS, width)).astype(np.float32)
-    deltas = rng.normal(size=(len(ids), width)).astype(np.float32)
+    table[:, w:] = np.nan  # the pad lanes, whatever they hold
+    table[::3, w + 1:] = -0.0
+    deltas = rng.normal(size=(len(ids), w)).astype(np.float32)
     live = (ids >= 0) & (ids < ADD_ROWS)
     deltas[~live] = np.nan  # a dropped lane's delta is never read
     if name == "several_calls":
@@ -448,8 +459,8 @@ def test_sorted_tile_add_is_numpys_add_at_in_batch_order_bit_for_bit(
         got = row_update.sorted_tile_add(
             got, s, jnp.asarray(deltas)[o], interpret=True)
     want = table.copy()
-    np.add.at(want, ids[live], deltas[live])
-    np.testing.assert_array_equal(np.asarray(got), want)
+    np.add.at(want[:, :w], ids[live], deltas[live])
+    assert np.asarray(got).tobytes() == want.tobytes()
     if len(kept):  # the case is what its name says
         flat = np.asarray(sid)
         blocks = [
@@ -465,6 +476,59 @@ def test_sorted_tile_add_is_numpys_add_at_in_batch_order_bit_for_bit(
         }.get(name, 1)
         if name == "a_block_that_opens_no_tile_row":
             assert blocks[0] and not blocks[-1]
+
+
+# An add push NARROWER than its table (word2vec's 600 lanes in 640, fastText's
+# 300 in 384, GloVe's 602 in 640) that takes several kernel calls permutes its
+# rows once, and every call reads its blocks out of that one buffer.
+LOGICAL_WIDTHS = [(640, 600), (384, 300), (640, 602), (256, 256), (128, 128)]
+
+
+@pytest.mark.parametrize("W,w", LOGICAL_WIDTHS)
+def test_scatter_add_takes_rows_at_their_own_width_over_several_calls(
+        W, w, monkeypatch):
+    """``scatter_add`` against ``np.add.at`` in the order of the stream,
+    every bit of the table: lanes ``[0, w)`` of the rows a kept lane names,
+    nothing else.  NaN and -0.0 planted in the table's lanes ``[w, W)`` (of
+    touched rows too) and in untouched rows of touched tiles come back as
+    they were, a dropped lane's row holds NaN and is never read, the batch
+    takes three calls (``MAX_LANES`` cut to 512: below ``W`` the rows are
+    permuted once and every call reads its blocks out of that buffer), row
+    301's run lies across two blocks and across two calls."""
+    monkeypatch.setattr(row_update, "MAX_LANES", 512)
+    rng = np.random.default_rng([W, w])
+    rows = ADD_ROWS
+    table = rng.normal(size=(rows, W)).astype(np.float32)
+    table[:, w:] = np.nan  # the pad lanes, whatever they hold
+    table[::5, w + 1:] = -0.0
+    table[7], table[9, ::3] = np.nan, -0.0  # rows no lane names
+    kept = np.concatenate([
+        np.arange(0, 500, 2), np.full(600, 301), np.arange(302, 480),
+        np.repeat(np.arange(480, 488), 30)])
+    kept = kept[(kept != 7) & (kept != 9)]
+    ids = rng.permutation(np.concatenate([
+        kept, np.full(17, -1), np.full(20, rows + 5)])).astype(np.int32)
+    deltas = rng.normal(size=(len(ids), w)).astype(np.float32)
+    live = (ids >= 0) & (ids < rows)
+    deltas[~live] = np.nan
+    assert -(-len(ids) // 512) == 3
+    got = np.asarray(jax.jit(
+        lambda t, i, d: row_update.scatter_add(t, i, d, interpret=True)
+    )(table, ids, deltas))
+    want = table.copy()
+    np.add.at(want[:, :w], ids[live], deltas[live])
+    flat = np.sort(ids[live])
+    assert flat[255] == flat[256] == 301 == flat[511] == flat[512]
+    assert got.tobytes() == want.tobytes()
+    # the pad lanes of every row, written or not, are the table's own
+    assert got[:, w:].tobytes() == table[:, w:].tobytes()
+
+
+def test_the_tile_kernel_refuses_rows_wider_than_the_table():
+    for fn in (row_update.sorted_tile_add, row_update.sorted_tile_assign):
+        with pytest.raises(ValueError, match=r"rows of shape \(384,\) for a table"):
+            fn(jnp.zeros((64, 256), jnp.float32), jnp.zeros((8,), jnp.int32),
+               jnp.zeros((8, 384), jnp.float32), interpret=True)
 
 
 # -- the MF step --------------------------------------------------------------
@@ -1032,13 +1096,24 @@ TILE_ASSIGN_CASES = [
 ]
 
 
-@pytest.mark.parametrize("name", TILE_ASSIGN_CASES)
-@pytest.mark.parametrize("width", [256, 640])
-def test_sorted_tile_assign_is_xlas_row_set_bit_for_bit(name, width, monkeypatch):
+@pytest.mark.parametrize("width,w,name", [
+    (width, width, name) for width in (256, 640) for name in TILE_ASSIGN_CASES
+] + [
+    # rows NARROWER than the table's (cell 13's write-back: PR 57)
+    (width, w, name)
+    for width, w in ((640, 600), (384, 300), (640, 602))
+    for name in ("sorted_distinct", "dropped_at_the_end",
+                 "nan_inf_and_minus_zero", "several_calls")
+])
+def test_sorted_tile_assign_is_xlas_row_set_bit_for_bit(
+        width, w, name, monkeypatch):
     """``table.at[ids].set(rows, mode="drop")`` for distinct ascending ids
     and rows of several registers: every bit of the table, the rows that
     share a touched tile with a written one included (the kernel copies, it
-    does no arithmetic)."""
+    does no arithmetic).  New rows of ``w`` < ``width`` lanes replace lanes
+    ``[0, w)`` (XLA's ``set`` of the rows padded with what the table holds
+    past them): the table's lanes ``[w, width)``, NaN and -0.0 in written
+    rows and unwritten, come back as they were."""
     rng = np.random.default_rng(TILE_ASSIGN_CASES.index(name))
     rows_n, n = 1000, 300
     ids = np.sort(rng.choice(rows_n, n, replace=False)).astype(np.int32)
@@ -1057,7 +1132,9 @@ def test_sorted_tile_assign_is_xlas_row_set_bit_for_bit(name, width, monkeypatch
         rows_n, n = 2000, 700
         ids = np.sort(rng.choice(rows_n, n, replace=False)).astype(np.int32)
     table = rng.normal(size=(rows_n, width)).astype(np.float32)
-    new = rng.normal(size=(n, width)).astype(np.float32)
+    table[:, w:] = np.nan  # the pad lanes, whatever they hold
+    table[::3, w + 1:] = -0.0
+    new = rng.normal(size=(n, w)).astype(np.float32)
     if name == "nan_inf_and_minus_zero":
         ids = (2 * np.arange(n)).astype(np.int32)  # the odd rows stay
         new[0], new[1, ::2], new[2, 5] = -0.0, np.inf, np.nan
@@ -1066,9 +1143,12 @@ def test_sorted_tile_assign_is_xlas_row_set_bit_for_bit(name, width, monkeypatch
     new[ids >= rows_n] = np.nan  # a dropped lane's row is never written
     got, opened = row_update.sorted_tile_assign(
         jnp.asarray(table), jnp.asarray(ids), jnp.asarray(new))
+    padded = np.concatenate(  # the new rows, then what their rows hold
+        [new, table[np.minimum(ids, rows_n - 1), w:]], axis=1)
     want = np.asarray(
-        jnp.asarray(table).at[jnp.asarray(ids)].set(jnp.asarray(new), mode="drop"))
+        jnp.asarray(table).at[jnp.asarray(ids)].set(jnp.asarray(padded), mode="drop"))
     assert np.asarray(got).tobytes() == want.tobytes()
+    assert want[:, w:].tobytes() == table[:, w:].tobytes()
     jitted, _ = jax.jit(row_update.sorted_tile_assign, donate_argnums=0)(
         jnp.asarray(table), jnp.asarray(ids), jnp.asarray(new))
     assert np.asarray(jitted).tobytes() == want.tobytes()
@@ -1092,12 +1172,16 @@ def test_sorted_tile_assign_refuses_what_the_tile_kernel_refuses():
             jnp.zeros((8, 256)), interpret=False)
 
 
-@pytest.mark.parametrize("width, n", [(256, 300), (640, 1000)])
+@pytest.mark.parametrize("width, n", [
+    (256, 300), (640, 1000),
+    (602, 1000), (300, 300), (600, 700),  # no whole registers: summed as they come
+])
 def test_wide_rows_are_summed_by_the_tile_kernel_in_stream_order_bit_for_bit(
         width, n):
-    """``ops/dedup.combine_runs`` for rows of several whole registers, kernel
+    """``ops/dedup.combine_runs`` for rows wider than a register, kernel
     arm: the same float32 additions in the same order as the scatter-add arm
-    (and ``np.add.at``), the descriptors counted by tile rows."""
+    (and ``np.add.at``), the descriptors counted by tile rows.  The kernel's
+    sums come out in whole registers, zeros past the rows' own width."""
     from flink_parameter_server_tpu.ops import dedup
 
     rng = np.random.default_rng(width)
@@ -1116,10 +1200,14 @@ def test_wide_rows_are_summed_by_the_tile_kernel_in_stream_order_bit_for_bit(
     distinct = len(np.unique(ids[ids < sentinel]))
     # (past the distinct ids the scatter-add arm holds the dropped lanes' sum,
     # which no caller reads: their ids are the sentinel)
-    assert np.asarray(got)[:distinct].tobytes() == (
+    whole = -(-width // 128) * 128
+    assert got.shape == (n, whole) and want.shape == (n, width)
+    assert np.asarray(got)[:distinct, :width].tobytes() == (
         np.asarray(want)[:distinct].tobytes())
     assert not np.asarray(got)[distinct:].any()
+    assert not np.asarray(got)[:, width:].any()
     assert int(zero) == 0 and int(opened) == -(-distinct // 8)
-    assert dedup.kernel_refusal(width, jnp.float32) is None
-    assert "whole 128-lane" in dedup.kernel_refusal(width + 1, jnp.float32)
-    assert "bfloat16" in dedup.kernel_refusal(width, jnp.bfloat16)
+    # the refusal is asked about the block the sums land in
+    assert dedup.kernel_refusal(whole, jnp.float32) is None
+    assert "whole 128-lane" in dedup.kernel_refusal(whole + 1, jnp.float32)
+    assert "bfloat16" in dedup.kernel_refusal(whole, jnp.bfloat16)

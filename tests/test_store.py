@@ -380,17 +380,26 @@ def test_push_pull_case_table_wide_rows(arm, shape, traffic, monkeypatch):
         monkeypatch.setattr(
             store_mod, "_tile_kernel_takes", lambda spec, lanes=None: True)
         monkeypatch.setattr(row_update, "MAX_LANES", 512)
-        calls = []
+        calls, handed = [], set()
         real = row_update._sorted_tile_add_counted
-        monkeypatch.setattr(
-            row_update, "_sorted_tile_add_counted",
-            lambda *a: calls.append(a[1].shape[0]) or real(*a))
+
+        def noted(*a):  # (table, sorted ids, rows, ...)
+            calls.append(a[1].shape[0])
+            handed.add(a[2].shape[1])
+            return real(*a)
+
+        monkeypatch.setattr(row_update, "_sorted_tile_add_counted", noted)
         # not `_push`: a program traced for the other arm would be reused
         push = jax.jit(lambda st, i, d, m: st.push(i, d, m))
     _check_push_pull(
         store, values, *_wide_traffic(traffic, rng, CAP, shape), push=push)
     if arm == "tile_kernel":
         assert calls and max(calls) <= 512, calls
+        # a row that lies one to a physical row (cell 5's (2, 300) and 600 in
+        # 640, cell 7's 300 in 384) reaches the kernel at its OWN width: no
+        # pass pads the batch to whole registers for it
+        flat = int(np.prod(shape))
+        assert handed == {flat if store.spec.pack == 1 else 128}, handed
 
 
 # The one-register arm against XLA's, BIT FOR BIT: what ``correct`` rests on
@@ -1824,9 +1833,11 @@ def test_a_rule_store_s_arms_under_a_mesh_are_read_from_its_workers(
 # -- a FLAT WIDE rule store: one axis of more than 128 lanes, k = 1 -----------
 # What `_resolve_layout("auto")` gives a rule row wider than a register
 # (GloVe's 602 lanes of weights, bias and accumulators in five): the flat
-# whole-register layout an add store of that width has.  Its push pads the
-# deltas to the physical width, sums them, and reads, rewrites and writes
-# whole physical rows; it is held to the DENSE rule store, bit for bit.
+# whole-register layout an add store of that width has.  Its push sums the
+# deltas at their own width into whole registers, reads whole physical rows,
+# and writes the new rows' own lanes into them (the tile kernels take a row of
+# `w` <= `W` lanes: nothing pads the batch); it is held to the DENSE rule
+# store, bit for bit.
 
 FLAT_WIDE_WIDTHS = [129, 200, 256, 602]
 FLAT_WIDE_CASES = [
@@ -1902,15 +1913,34 @@ def test_a_flat_wide_rule_store_is_the_dense_one_bit_for_bit(
     args = (jnp.asarray(ids), jnp.asarray(deltas),
             None if mask is None else jnp.asarray(mask))
     want, _ = store_mod.push_counted(dense.spec, dense.table, *args)
+    handed = set()
     if arm == "tile_kernels":
+        from flink_parameter_server_tpu.ops import row_update
+
         # off the TPU the choosers are steered and the kernels interpreted
         monkeypatch.setattr(store_mod, "_set_kernel_takes", lambda spec: True)
         monkeypatch.setattr(store_mod, "_combine_kernel_takes", lambda spec: True)
+        real = row_update._sorted_tile_add_counted
+        monkeypatch.setattr(
+            row_update, "_sorted_tile_add_counted",
+            lambda *a: handed.add(a[2].shape[1]) or real(*a))
     got, counted = store_mod.push_counted(auto.spec, auto.table, *args)
     pushed = ShardedParamStore(auto.spec, got)
     want = np.asarray(ShardedParamStore(dense.spec, want).values())
     assert np.asarray(pushed.values()).tobytes() == want.tobytes()
     assert not np.asarray(got)[:, width:].any()  # the pad lanes stay zero
+    # the sums' and the write-back's kernels both took rows of `width` lanes
+    assert handed == ({width} if arm == "tile_kernels" and len(ids) else set())
+    if case == "nan_inf_and_minus_zero_neighbours" and width % 128:
+        # ... and whatever the table's pad lanes hold comes back bit for bit,
+        # in the rows the push rewrote too
+        junk = np.asarray(auto.table).copy()
+        junk[:, width:] = np.nan
+        junk[::3, width:] = -0.0
+        again, _ = store_mod.push_counted(auto.spec, jnp.asarray(junk), *args)
+        again = np.asarray(again)
+        assert again[:, :width].tobytes() == np.asarray(got)[:, :width].tobytes()
+        assert again[:, width:].tobytes() == junk[:, width:].tobytes()
     live = (ids >= 0) & (ids < auto.spec.padded_capacity)
     if mask is not None:
         live &= mask
@@ -1937,6 +1967,111 @@ def test_a_flat_wide_rule_store_is_the_dense_one_bit_for_bit(
         for lo in range(0, len(kept), step))
     assert int(counted["ps_combine_kernel_lanes"]) == live.sum()
     assert int(counted["ps_combine_kernel_writes"]) == -(-len(kept) // 8)
+
+
+# A rule store's push hands a masked lane's delta on AS IT IS (until PR 57 a
+# select over the whole pushed block zeroed it first): `_push_rule` sends the
+# lane to the sentinel, and whatever it holds must reach no kept row in any of
+# the combine's four arms.
+MASKED_ARMS = [
+    (3, "the_sort_carries_it"), (36, "wide_runs_scatter_add"),
+    (36, "kernel_sums"), (602, "wide_runs_scatter_add"), (602, "tile_sums"),
+]
+
+
+@pytest.mark.parametrize("bad", [np.nan, np.inf, -np.inf])
+@pytest.mark.parametrize("width, arm", MASKED_ARMS)
+def test_a_masked_lanes_nan_reaches_no_kept_row_in_any_combine_arm(
+        width, arm, bad, monkeypatch):
+    """The table after a push whose masked lanes hold NaN or Inf is, bit for
+    bit, the table after the same push with zeros there (what the parent's
+    `_zero_masked` made of them).  The masked lanes keep LIVE ids, ids that
+    live lanes of the same batch name too, and lie between live lanes: every
+    block of 256 sorted lanes of the row kernel's MXU sums, and every tile of
+    the tile kernel's, holds both."""
+    from flink_parameter_server_tpu.core import store as store_mod
+
+    rng = np.random.default_rng([width, MASKED_ARMS.index((width, arm))])
+    cap, n = 77, 700
+    values = rng.normal(size=(cap, width)).astype(np.float32)
+    store = ShardedParamStore.from_values(
+        jnp.asarray(values), update=_sticky_rule, layout="auto")
+    kernels = arm in ("kernel_sums", "tile_sums")
+    monkeypatch.setattr(store_mod, "_combine_kernel_takes", lambda spec: kernels)
+    if arm == "tile_sums":
+        monkeypatch.setattr(store_mod, "_set_kernel_takes", lambda spec: True)
+    ids = rng.integers(0, cap, n).astype(np.int32)
+    ids[: n // 4] = 11  # a hot row, a third of its lanes masked
+    mask = rng.random(n) > 0.3
+    # the same rows, live elsewhere (all but a row or two that no live lane
+    # names, which must then stay as it is)
+    assert len(set(ids[~mask]) & set(ids[mask])) > 60
+    deltas = rng.normal(size=(n, width)).astype(np.float32)
+    zeroed = np.where(mask[:, None], deltas, 0).astype(np.float32)
+    planted = np.where(mask[:, None], deltas, bad).astype(np.float32)
+    push = jax.jit(
+        lambda table, d: store_mod.push_counted(
+            store.spec, table, jnp.asarray(ids), d, jnp.asarray(mask)))
+    want, counted_want = push(store.table, zeroed)
+    got, counted = push(store.table, planted)
+    assert np.isfinite(np.asarray(got)).all()
+    assert np.asarray(got).tobytes() == np.asarray(want).tobytes()
+    assert {k: int(v) for k, v in counted.items()} == {
+        k: int(v) for k, v in counted_want.items()}
+    assert int(counted["ps_rule_keys"]) == mask.sum()
+    if width > 4:  # the arm is the one the case names
+        assert (int(counted["ps_combine_kernel_lanes"]) > 0) == kernels
+
+
+# The case table's flat wide stores on the arm that hands the tile kernel
+# their rows UNPADDED (PR 57) against the arm that pads them to whole
+# registers first (XLA's, the parent's path for both arms): the same table,
+# every bit, the pad lanes too.
+@pytest.mark.parametrize("traffic", [
+    "half_masked", "zipf_hot", "neg_and_oob", "dead_lanes_minus_one",
+    "run_over_a_block_and_a_call"])
+@pytest.mark.parametrize("kind, shape", [
+    ("add", (600,)), ("add", (2, 300)), ("add", (300,)), ("rule", (602,))],
+    ids=str)
+def test_push_pull_case_table_flat_wide_rows_unpadded_are_the_padded_bit_for_bit(
+        kind, shape, traffic, monkeypatch):
+    from flink_parameter_server_tpu.core import store as store_mod
+    from flink_parameter_server_tpu.ops import row_update
+
+    rng = np.random.default_rng(
+        [len(shape), shape[-1], WIDE_TRAFFIC.index(traffic)])
+    values = _init_values(CAP, shape)
+    values[::5] = -0.0  # a masked lane that keeps a live id adds +0.0 to it
+    store = ShardedParamStore.from_values(
+        jnp.asarray(values), layout="auto",
+        **({"update": _sticky_rule} if kind == "rule" else {}))
+    width, lanes = int(np.prod(shape)), store.table.shape[1]
+    assert store.spec.pack == 1 and width < lanes and lanes % 128 == 0
+    ids, deltas, mask = _wide_traffic(traffic, rng, CAP, shape)
+    if mask is not None:  # masked lanes keep ids that live lanes name too
+        flat, on = ids.reshape(-1), mask.reshape(-1)
+        assert set(flat[~on]) & set(flat[on])
+    args = (jnp.asarray(ids), jnp.asarray(deltas),
+            None if mask is None else jnp.asarray(mask))
+    want, _ = store_mod.push_counted(store.spec, store.table, *args)
+    handed = set()
+    real = row_update._sorted_tile_add_counted
+    monkeypatch.setattr(
+        row_update, "_sorted_tile_add_counted",
+        lambda *a: handed.add(a[2].shape[1]) or real(*a))
+    monkeypatch.setattr(row_update, "MAX_LANES", 512)
+    if kind == "add":
+        monkeypatch.setattr(
+            store_mod, "_tile_kernel_takes", lambda spec, lanes=None: True)
+    else:
+        monkeypatch.setattr(store_mod, "_set_kernel_takes", lambda spec: True)
+        monkeypatch.setattr(store_mod, "_combine_kernel_takes", lambda spec: True)
+    # (a fresh program: an eager push would reuse one traced for another case)
+    got, _ = jax.jit(
+        lambda table, *a: store_mod.push_counted(store.spec, table, *a)
+    )(store.table, *args)
+    assert handed == {width}
+    assert np.asarray(got).tobytes() == np.asarray(want).tobytes()
 
 
 @pytest.mark.parametrize("backend, shape, dtype, layout, combine, write_back, noted", [

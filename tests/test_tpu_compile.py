@@ -260,6 +260,61 @@ def test_tile_refusal_names_a_width_whose_three_buffers_do_not_fit(one_chip):
         )
 
 
+def _mosaic_body_sha(lowered_text):
+    """sha256 of the one Mosaic body in a lowered text, printed without
+    locations (file paths and lines are in the serialized kernel)."""
+    import base64
+    import hashlib
+
+    from jax._src.interpreters import mlir as jax_mlir
+    from jax._src.lib.mlir import ir
+
+    bodies = re.findall(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22', lowered_text)
+    assert len(bodies) == 1
+    context = jax_mlir.make_ir_context()
+    context.allow_unregistered_dialects = True
+    with context:
+        asm = ir.Module.parse(base64.b64decode(bodies[0])).operation.get_asm(
+            enable_debug_info=False)
+    return hashlib.sha256(asm.encode()).hexdigest()[:16]
+
+
+@pytest.mark.parametrize("rows,width,w,lanes,assign", [
+    (W2V_VOCAB, 640, 600, 57_344, False),   # cell 5: (2, 300) flat in 640
+    (7_038_744, 384, 300, 77_824, False),   # cell 7
+    (65_536, 640, 602, 65_536, False),      # cell 13's sums, a zeroed block
+    (W2V_VOCAB, 256, 256, 57_344, False),
+    (24_563_152, 128, 128, 94_720, False),  # cell 10: w = W
+    (4_392_040, 640, 602, 32_768, True),    # cell 13's write-back
+])
+def test_mosaic_takes_the_tile_kernel_with_rows_at_their_own_width(
+        one_chip, no_compile_cache, rows, width, w, lanes, assign):
+    """Mosaic takes the tile kernel with a block of the deltas ``(256, w)``
+    for ``w`` <= ``W``, a lane's add (or store) on lanes ``[0, w)`` of its
+    row of the tile, at every width a cell pushes: 600 and 602 in 640 (four
+    whole registers and one of 88 / 90 lanes under a mask), 300 in 384, and
+    ``w`` = ``W``; in place, no pad of the rows in front of it.  At ``w`` =
+    ``W`` = 128, cell 10's shape, the Mosaic body is the one PR 57's parent
+    lowered, op for op (a change that means to move that kernel brings its
+    own hash)."""
+    fn = row_update.sorted_tile_assign if assign else row_update.sorted_tile_add
+    lowered = jax.jit(
+        lambda t, ids, dl: fn(t, ids, dl, interpret=False), donate_argnums=(0,),
+    ).lower(
+        _shape(one_chip, (rows, width), jnp.float32),
+        _shape(one_chip, (lanes,), jnp.int32),
+        _shape(one_chip, (lanes, w), jnp.float32),
+    )
+    if (width, w) == (128, 128):
+        assert _mosaic_body_sha(lowered.as_text()) == "8f20c8758b8c3858"
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    name = "sorted_row_assign_tiles" if assign else "sorted_row_update_tiles"
+    assert len(re.findall(rf" custom-call\([^\n]*{name}", text)) == 1
+    assert not re.search(rf"= f32\[{lanes},{width}\]\S* pad\(", text)
+    assert compiled.memory_analysis().alias_size_in_bytes >= rows * width * 4
+
+
 def _compiled_mf_step(one_chip, monkeypatch, batch_size):
     """The step the MF cells run (``OnlineMatrixFactorization`` as
     ``chipbench/families/mf.py`` builds it: no ``state_scatter``), compiled
@@ -309,12 +364,6 @@ def test_the_keyed_streams_row_kernel_is_the_parents_op_for_op(one_chip):
     the combine and the write-back a compacting plan and left this caller
     alone).  A change that means to move the MF cells' kernel brings its own
     hash."""
-    import base64
-    import hashlib
-
-    from jax._src.interpreters import mlir as jax_mlir
-    from jax._src.lib.mlir import ir
-
     text = jax.jit(
         lambda st, ids, old, dl, m: row_update.row_add(
             st, ids, old, dl, m, interpret=False),
@@ -326,14 +375,7 @@ def test_the_keyed_streams_row_kernel_is_the_parents_op_for_op(one_chip):
         _shape(one_chip, (BATCH, DIM), jnp.float32),
         _shape(one_chip, (BATCH,), jnp.bool_),
     ).as_text()
-    bodies = re.findall(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22', text)
-    assert len(bodies) == 1
-    context = jax_mlir.make_ir_context()
-    context.allow_unregistered_dialects = True
-    with context:
-        asm = ir.Module.parse(base64.b64decode(bodies[0])).operation.get_asm(
-            enable_debug_info=False)
-    assert hashlib.sha256(asm.encode()).hexdigest()[:16] == "3f54df8566796c53"
+    assert _mosaic_body_sha(text) == "3f54df8566796c53"
 
 
 def test_mf_step_default_arm_on_tpu_is_the_row_kernel(
@@ -921,10 +963,20 @@ def test_w2v_step_assembles_its_deltas_without_a_zeroed_block(
     bytes (one ``reshape`` to ``f32[114688,300]``), and the 600-lane rows
     first exist in the push's own mask fusion: nothing relays a
     ``f32[114688,600]`` (the parent's ``reshape`` under ``ps.push``).  The
-    temporaries are 0.65 GB where the block made them 0.78."""
+    temporaries were 0.65 GB where the block made them 0.78; since PR 57 the
+    push permutes its 600-lane rows ONCE for both kernel calls, one 294 MB
+    buffer where two of 147 MB packed into the heap's holes: the same live
+    bytes take 0.117 GB more of heap (the buffer assignment's total 8.564 GB
+    for 8.447) and ``temp_size_in_bytes`` reads 0.883 GB.  A permute a call
+    without the pad compiles to 0.649 GB at this table's 3,000,000 rows and
+    draws two ``remat_compressed`` copies of ``f32[57344,600]`` round the
+    first call from 3,300,000 rows on (8.45 GB; compiled at 3.01, 3.025,
+    3.04, 3.3, 3.6, 3.9, 4.2 and 5.0 M rows: PERF.md section 6, PR 57),
+    the one permute none to 5.0 M: the bound is that form's reading.  The
+    ops below are what holds the block off."""
     _, _, w2vm = w2v
     compiled = _w2v_cell_step(one_chip, w2vm, monkeypatch)
-    assert compiled.memory_analysis().temp_size_in_bytes < 0.70 * GB
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.90 * GB
     text = compiled.as_text()
     build, push = (
         [op for op, _ in _ops_built_under(text, scope)]
@@ -957,11 +1009,12 @@ def test_the_combiners_counts_hold_no_counter_and_touch_no_table(
     scatter-added a one a lane into the counter and gathered the counter
     back: 7-9 ns a lane, 1.6 and 3.7 ms a step on the v5e), the two sorts
     are there, and the step's temporaries stay inside the bounds the
-    parent's step was held to (0.649 / 0.767 GB here, as the parent's)."""
+    parent's step was held to (0.883 / 0.767 GB here; cell 5's were 0.649
+    until PR 57 permuted its push's rows once: the test above says why)."""
     if cell == 5:
         _, _, w2vm = w2v
         compiled = _w2v_cell_step(one_chip, w2vm, monkeypatch)
-        rows, lanes, temp = W2V_VOCAB, W2V_BATCH * (W2V_NEG + 2), 0.70 * GB
+        rows, lanes, temp = W2V_VOCAB, W2V_BATCH * (W2V_NEG + 2), 0.90 * GB
     else:
         compiled = _ft_cell_step(one_chip, monkeypatch)
         rows, lanes = 2 * FT_VOCAB + FT_BUCKETS, FT_BATCH * (FT_BAG + 6)
@@ -988,7 +1041,7 @@ def _step_text_sha(step, *args):
     ("mf_cells_1_and_3", "467449ddc73eac39"),
     ("fm_cell_2", "bc06381bf02bcde5"),
     ("fm_ps4_cell_4", "db02bf3de22f8a3e"),
-    ("lr_cell_6", "aab60546ac40ed64"),
+    ("lr_cell_6", "f669bf2e1dddf416"),
     ("keyed_mf_cell_8", "47d256f2590f4bc0"),
 ])
 def test_the_mf_and_fm_cells_step_text_is_the_parents(cell, want, request):
@@ -1007,7 +1060,12 @@ def test_the_mf_and_fm_cells_step_text_is_the_parents(cell, want, request):
     five traces.  PR 51 gave the two FM steps one scalar more,
     ``ps_shift_kernel`` (which arm shifted the pushed deltas: here, off the
     TPU, a constant 0 and the parent's ops to the letter; ``62cb492a1f6ca10e``
-    and ``0d16cc6091cddad1`` until then); the other three did not move."""
+    and ``0d16cc6091cddad1`` until then); the other three did not move.
+    PR 57 took ``_zero_masked`` out of a RULE store's push (a masked lane goes
+    to the sentinel in ``_push_rule``; its delta reaches no kept row): cell
+    6's text lost the mask's ``reshape`` to ``(1277952, 1)``, a zero
+    ``broadcast_in_dim`` and the ``_where`` over ``f32[1277952,3]``, nothing
+    else (``aab60546ac40ed64`` until then); the four add stores kept theirs."""
     shape = jax.ShapeDtypeStruct
 
     def mf_batch(n, on=shape):
@@ -1718,20 +1776,9 @@ def test_glove_table_is_initialised_in_place_from_a_seed_argument(
     assert len(re.findall(r" while\(", compiled.as_text())) >= 1
 
 
-@pytest.mark.parametrize("arm", ["kernels", "xla"])
-def test_glove_step_holds_nothing_table_sized_beside_its_table(
-        arm, glove, one_chip, no_compile_cache, monkeypatch):
-    """Cell 13's step at full size for a described v5e: the donated 11.24 GB
-    table is rewritten in place and never copied or transposed, with every
-    scope the cell's metrics read.  As the chip runs it (``kernels``): under
-    ``ps.pull`` ONE gather of whole physical rows ``f32[65536,640]``; under
-    ``ps.push/ps.combine`` the permute of the batch's gradient rows and ONE
-    ``sorted_row_update_tiles`` call into a zeroed ``f32[65536,640]`` block;
-    in the rule's loop ONE gather ``f32[32768,640]`` under ``ps.rule`` and
-    ONE ``sorted_row_assign_tiles`` call on the table, the write-back.  What
-    the step holds beside the table goes with the batch: 0.67 GB.  Off the
-    TPU (``xla``) the same layout with one scatter-add for the sums and one
-    row ``set`` of ``f32[32768,640]`` for the write-back, no kernel."""
+def _glove_cell_step(one_chip, glove, monkeypatch, arm="kernels"):
+    """Cell 13's step, compiled: as the chip runs it (``kernels``: the arms
+    chosen as on a TPU) or off it (``xla``)."""
     model, gl = glove
     spec = jax.eval_shape(lambda: gl.make_store(model)).spec
     assert spec.layout == "packed" and spec.pack == 1
@@ -1751,9 +1798,26 @@ def test_glove_step_holds_nothing_table_sized_beside_its_table(
         "count": _shape(one_chip, (GLOVE_BATCH,), jnp.float32),
         "mask": _shape(one_chip, (GLOVE_BATCH,), jnp.bool_),
     }
-    compiled = jax.jit(
+    return jax.jit(
         make_train_step(gl.GloVe(model), spec), donate_argnums=(0, 1)
     ).lower(_shape(one_chip, spec.table_shape(), jnp.float32), (), batch).compile()
+
+
+@pytest.mark.parametrize("arm", ["kernels", "xla"])
+def test_glove_step_holds_nothing_table_sized_beside_its_table(
+        arm, glove, one_chip, no_compile_cache, monkeypatch):
+    """Cell 13's step at full size for a described v5e: the donated 11.24 GB
+    table is rewritten in place and never copied or transposed, with every
+    scope the cell's metrics read.  As the chip runs it (``kernels``): under
+    ``ps.pull`` ONE gather of whole physical rows ``f32[65536,640]``; under
+    ``ps.push/ps.combine`` the permute of the batch's gradient rows and ONE
+    ``sorted_row_update_tiles`` call into a zeroed ``f32[65536,640]`` block;
+    in the rule's loop ONE gather ``f32[32768,640]`` under ``ps.rule`` and
+    ONE ``sorted_row_assign_tiles`` call on the table, the write-back.  What
+    the step holds beside the table goes with the batch: 0.67 GB.  Off the
+    TPU (``xla``) the same layout with one scatter-add for the sums and one
+    row ``set`` of ``f32[32768,640]`` for the write-back, no kernel."""
+    compiled = _glove_cell_step(one_chip, glove, monkeypatch, arm)
     mem = compiled.memory_analysis()
     assert 11.24 * GB < mem.alias_size_in_bytes < 11.25 * GB  # in place
     assert mem.temp_size_in_bytes < 1.0 * GB
@@ -1776,7 +1840,8 @@ def test_glove_step_holds_nothing_table_sized_beside_its_table(
         assert not kernels
         sets = [c for c in scatters if " f32[4392040,640]" in c]
         assert len(sets) == 1 and "ps.push/while/body" in sets[0], scatters
-        sums = [c for c in scatters if " f32[65536,640]" in c]
+        # (the sums at the rows' own 602 lanes since PR 57: no pad in front)
+        sums = [c for c in scatters if " f32[65536,602]" in c]
         assert len(sums) == 1 and "ps.push/ps.combine" in sums[0], scatters
         return
     assert not scatters
@@ -1787,3 +1852,66 @@ def test_glove_step_holds_nothing_table_sized_beside_its_table(
     assert "ps.push/while/body" in by_name["%sorted_row_assign_tiles"]
     assert " f32[65536,640]{1,0" in by_name["%sorted_row_update_tiles"]
     assert "ps.push/ps.combine" in by_name["%sorted_row_update_tiles"]
+
+
+@pytest.mark.parametrize("cell", [5, 7, 13])
+def test_no_pass_over_the_pushed_rows_stands_in_front_of_the_tile_kernel(
+        cell, one_chip, w2v, glove, no_compile_cache, monkeypatch):
+    """The tile kernel takes a wide row at the width the logic left it (PR
+    57): in the compiled steps of cells 5, 7 and 13 every tile-kernel call
+    reads its rows from the op that made them, at their LOGICAL width
+    (``f32[114688,600]``, ``f32[233472,300]``: ONE permute for all the calls
+    of an add push; ``f32[65536,602]``: the combine's permute;
+    ``f32[32768,602]``: the rule's own output), and nothing between them
+    pads, copies or "compresses" the batch's rows: no ``pad`` to 640 / 384
+    lanes (the parent's ``pad.71/.73``, ``pad.75/.77/.79``, ``pad.38``, 0.37 to
+    0.51 ms each on the v5e), no select over the pushed block in front of a
+    rule's combine (``_zero_masked``), no merge of the new rows into the
+    gathered ones (``fusion.53``), and no ``remat_compressed`` /
+    ``remat_uncompressed`` pair, which is what the compiler makes of three
+    permutes of ``f32[77824,300]`` that lie across cell 7's calls (a copy to
+    ``{0,1}`` and back for two of them, and 0.952 GB of temporaries; of
+    cell 5's ``f32[57344,600]`` too once its table has a tenth more rows:
+    both compiled, PERF.md section 6, PR 57; why an add push narrower than
+    its table permutes once).  The temporaries:
+    cell 7's the parent's 0.767 GB, cell 13's 0.437 for its 0.673; cell 5's
+    0.883 for its 0.649 (one 294 MB buffer where two of 147 packed better
+    into the heap; the live bytes are the parent's)."""
+    if cell == 5:
+        compiled = _w2v_cell_step(one_chip, w2v[2], monkeypatch)
+        lanes, w, width = W2V_BATCH * (W2V_NEG + 2), 2 * W2V_DIM, 640
+        rows_in, calls, temp = [(lanes, w)] * 2, 2, 0.90 * GB
+    elif cell == 7:
+        compiled = _ft_cell_step(one_chip, monkeypatch)
+        lanes, w, width = FT_BATCH * (FT_BAG + 6), FT_DIM, 384
+        rows_in, calls, temp = [(lanes, w)] * 3, 3, 0.77 * GB
+    else:
+        compiled = _glove_cell_step(one_chip, glove, monkeypatch)
+        lanes, w, width = 2 * GLOVE_BATCH, 602, 640
+        rows_in, calls, temp = [(store_mod._RULE_CHUNK, w), (lanes, w)], 2, 0.45 * GB
+    assert compiled.memory_analysis().temp_size_in_bytes < temp
+    text = compiled.as_text()
+    assert "remat" not in text
+    made = dict(re.findall(r"^\s+(%[\w.\-]+) = (\S+) ", text, re.M))
+    kernels = re.findall(
+        r"^\s+%sorted_row_(?:update|assign)_tiles[\w.\-]* = \S+ custom-call\("
+        r"([^)]*)\)", text, re.M)
+    assert len(kernels) == calls, kernels
+    handed = sorted(
+        made[call.split(", ")[3]].split("{")[0] for call in kernels)
+    assert handed == sorted(f"f32[{n},{lanes_}]" for n, lanes_ in rows_in), handed
+    # no pass of its own over the batch's rows (ops of the entry and of the
+    # rule's loop; what a fusion does inside is the op that makes the rows):
+    # at the physical width only the pull's gather is left, and in cell 13
+    # the rule's read, the combine's zeroed block and its sums
+    ops = "\n".join(
+        body for head, body in re.findall(
+            r"^(?:ENTRY )?(%[\w.\-]+) [^\n]*\{\n(.*?)^\}", text, re.M | re.S)
+        if not head.startswith(("%fused_computation", "%region")))
+    assert "custom-call(" in ops and " fusion(" in ops  # the filter kept them
+    for n in {n for n, _ in rows_in} | {lanes // calls}:
+        assert not re.search(
+            rf"= f32\[{n},({w}|{width})\]\S* (pad|copy)\(", ops), (cell, n)
+    if cell == 13:  # `_zero_masked`'s select over the pushed block, the merge
+        assert not re.search(
+            rf"= f32\[\d+,({w}|{width})\]\S* fusion\([^\n]*jit\(_where\)", ops)
