@@ -725,9 +725,10 @@ class Smoke:
         self._kernel_case(
             "combine_runs_d36_f32",
             lambda i, r: dedup.combine_runs(
-                i, r, rows_w, kernel=True, interpret=interpret)[:2],
+                i, r, rows_w, "row_kernel", interpret=interpret)[:2],
             (ids_c, rows_c),
-            close(dedup.combine_runs(ids_c, rows_c, rows_w)[:2], 1e-3),
+            close(dedup.combine_runs(
+                ids_c, rows_c, rows_w, "scatter_add")[:2], 1e-3),
         )
 
         # the MF step's DEFAULT arm at that shape class, against the XLA

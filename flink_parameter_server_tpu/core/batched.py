@@ -68,6 +68,19 @@ class BatchedWorkerLogic(abc.ABC, Generic[State, Batch, Out]):
         keyed already passes through untouched."""
         return None
 
+    def publish_counts(  # noqa: B027
+        self, outs: dict, registry: Any, total, peak
+    ) -> None:
+        """What this logic's step counted among its outputs, as gauges of
+        ``registry``: the logic that makes a count names its gauge, in a
+        literal ``registry.gauge("<name>", component="train")`` call
+        (docs/observability.md is the catalog).  ``outs`` are a dispatch's
+        outputs; ``total`` and ``peak`` read one of them over the steps the
+        dispatch stacked (a count's sum, a constant's maximum).  Whoever
+        fetches the outputs calls this where it fetches them anyway (the
+        StreamingDriver: at the metrics cadence and once after its loop),
+        beside ``core/store.publish_counts``.  Default: nothing."""
+
     def finish(self, state: State) -> Any:  # noqa: B027
         """Optional close-time worker output (e.g. dump local user
         vectors) — counterpart of ``WorkerLogic.close``."""

@@ -56,7 +56,8 @@ def _resolve_layout(
 ) -> str:
     """Resolve the table layout, validating packed-layout constraints.
 
-    ``"auto"`` reads the row's shape and the update rule, nothing else:
+    ``"auto"`` reads the row's shape and the update rule, nothing else (which
+    FORM a pull or a push of the layout then takes is :func:`arms`' to say):
     dense for an add-store whose rows are ONE axis of a whole number of 128
     lanes (a row is then a whole number of vector registers as it is),
     packed for every other add-store.  A store whose ``update`` is a rule
@@ -69,11 +70,7 @@ def _resolve_layout(
       strided column of scalars across its tiles, and every op that names
       a row pays a serial price for it: on the v5e the pull's gather 44.3
       ns a 36-lane row, the rule's read 48.0, XLA's row ``set`` 136
-      (PERF.md section 6, PR 46).  Packed, the pull gathers whole
-      registers and slices in the kernel an add-store's has, and the
-      rule's push reads and writes each touched PHYSICAL row once, its
-      logical rows merged by selects (:func:`_push_rule`; what the chip
-      measured: PERF.md section 6, PR 47).
+      (PERF.md section 6, PRs 46, 47).
     - one axis of 1 to 8 lanes: dense, and where it is float32 and in one
       place its PHYSICAL row is its sublane tile, 1, 2, 4 or 8 lanes, the
       rest zeros, and its table whole tiles of 128 rows
@@ -83,18 +80,15 @@ def _resolve_layout(
       tiles a Pallas kernel can move (a 3-lane table has the same tiles
       and Mosaic refuses a slice of them: PERF.md section 6, PR 35).
       Packed 42 to a physical row, its step asked 13.5 GB for its 42
-      static lane slices (PERF.md section 6, PR 34).
+      static lane slices (PR 34).
     - one axis of more than 128 lanes: PACKED with ``k`` = 1, the flat
       whole-register row an add-store of that width has (GloVe's 602 lanes
       of weights, bias and AdaGrad's accumulators in five registers:
-      ``f32[4392040,640]``, 11.24 GB).  Dense, the TPU holds ``(capacity,
-      602)`` capacity-minor and every step copies the whole table for its
-      gather and back (below: PR 32); flat, the pull and the rule's read
-      gather whole registers, and the push sums, rewrites and writes back
-      whole physical rows (:func:`_push_rule`; what the chip measured:
-      PERF.md section 6, PR 55).
+      ``f32[4392040,640]``, 11.24 GB; PERF.md section 6, PR 55).  Dense,
+      the TPU holds ``(capacity, 602)`` capacity-minor and every step
+      copies the whole table for its gather and back (below).
     - every other row (65 to 128 lanes, where ``k`` would be 1 and the row
-      one register; two axes; none): dense, as it was: no cell stands there.
+      one register; two axes; none): dense: no cell stands there.
 
     ``layout="packed"`` may be pinned for any rule store; the rule's push
     then takes the packed arm at whatever ``k`` the width gives.
@@ -109,13 +103,10 @@ def _resolve_layout(
     ``phys_width`` the padded width): left as it is, the TPU holds
     ``(capacity, 2, 300)`` and ``(capacity, 600)`` capacity-minor (no
     padding) and the step copies the WHOLE table to a row-major one for
-    its gather and back after its scatter-add, two table-sized passes and
-    a table-sized temporary a step (compiled for a v5e at 3,000,000 rows:
-    7.7-9.2 GB of temporaries; padded flat, 0.65 GB and neither copy;
-    PERF.md section 6, PR 32).  The shard count is not asked: under a
-    mesh every shard holds its own packed block (``create_table``
-    initialises and packs each shard's block on its shard, ``_place``
-    packs values that lie on the mesh shard by shard), so a store and its
+    its gather and back after its scatter-add (compiled for a v5e at
+    3,000,000 rows: 7.7-9.2 GB of temporaries; padded flat, 0.65 GB and
+    neither copy; PERF.md section 6, PR 32).  The shard count is not asked:
+    under a mesh every shard holds its own packed block, so a store and its
     reload from a checkpoint (``from_values``) resolve to one layout."""
     if layout not in ("dense", "packed", "auto"):
         raise ValueError(
@@ -189,7 +180,7 @@ class StoreSpec:
     def narrow_rule(self) -> bool:
         """A dense table in one place whose ``update`` is a rule and whose
         rows hold at most 8 elements: the stores whose write-back the set
-        kernel is for (:func:`_set_kernel_takes`)."""
+        kernel is for (:func:`arms`)."""
         return (self.update != "add" and self.layout == "dense"
                 and self.mesh is None and self.row_width <= 8)
 
@@ -203,7 +194,7 @@ class StoreSpec:
         four: 3.00 GB at 187.8 M rows either way).  The table then lies
         rows-minor on the chip, a tile of it is 128 whole rows, and the
         rule's write-back can move whole tiles
-        (``ops/row_update.sorted_tile_set``; :func:`_set_kernel_takes`).
+        (``ops/row_update.sorted_tile_set``; :func:`arms`).
         The lanes past ``row_width`` are zero, the rule never reads them,
         and ``pull`` and ``values()`` strip them."""
         if (not self.narrow_rule or len(self.value_shape) != 1
@@ -331,21 +322,22 @@ def pull(spec: StoreSpec, table: Array, ids: Array) -> Array:
     it reads.
     Packed layout: one gather of whole 128-lane physical rows, then the
     lane slice down to sub-row ``id % k``, by selects and never a gather of
-    elements (ops/packed.py; :func:`_slice_kernel_takes` reads its arm);
-    under a mesh each shard slices what it gathered before the one
-    all-reduce (:func:`_packed_pull_on_shards`)."""
+    elements (ops/packed.py); under a mesh each shard slices what it
+    gathered before the one all-reduce (:func:`_packed_pull_on_shards`).
+    Which form: :func:`arms`."""
     ids = jnp.clip(ids.astype(jnp.int32), 0, spec.padded_capacity - 1)
-    if spec.layout == "packed":
-        from ..ops.packed import packed_pull
-        flat, kernel = ids.reshape(-1), _slice_kernel_takes(spec, ids.size)
-        if spec.num_shards > 1:
-            vals = _packed_pull_on_shards(spec, table, flat, kernel)
-        else:
-            vals = packed_pull(table, flat, spec.row_width, kernel)
-        return vals.reshape(ids.shape + spec.value_shape)
-    if spec.tile_lanes > spec.row_width:
+    arm = arms(spec, pull_lanes=ids.size).pull
+    if arm == "take":
+        return jnp.take(table, ids, axis=0)
+    if arm == "narrow":
         return _narrow_pull(table, ids, spec.row_width)
-    return jnp.take(table, ids, axis=0)
+    from ..ops.packed import packed_pull
+    flat, kernel = ids.reshape(-1), arm == "packed_kernel"
+    if spec.num_shards > 1:
+        vals = _packed_pull_on_shards(spec, table, flat, kernel)
+    else:
+        vals = packed_pull(table, flat, spec.row_width, kernel)
+    return vals.reshape(ids.shape + spec.value_shape)
 
 
 @functools.partial(jax.jit, static_argnums=(2,))
@@ -360,7 +352,7 @@ def _narrow_pull(table: Array, ids: Array, width: int) -> Array:
 
 def _phys_scatter_args(
     spec: StoreSpec, table: Array, flat_ids: Array, flat_deltas: Array,
-    flat_mask: Optional[Array] = None, tiles: bool = False,
+    flat_mask: Optional[Array], arm: Arms,
 ):
     """(ids, deltas) at PHYSICAL granularity for the scatter-add, the
     masked lanes' deltas zeros.
@@ -368,19 +360,17 @@ def _phys_scatter_args(
     Dense: passthrough.  Packed: lane-shift each delta row to its
     sub-row offset and divide ids down to physical rows (the sentinel
     ``padded_capacity`` divides to the out-of-range physical row, so
-    ``mode="drop"`` semantics are preserved).  The shift is ``k`` pads
-    under a select or, where :func:`_shift_kernel_takes` says so, ONE
+    ``mode="drop"`` semantics are preserved).  The shift (``arm.shift``) is
+    ``k`` pads under a select or ONE
     Pallas call that reads the deltas feature-major and leaves a masked
     lane out itself (``ops/packed.lane_shift_kernel``; under a mesh
     :func:`_packed_shift_on_mesh`): the same rows in the same order, bit
     for bit.  A row that lies ONE to a physical row (``k`` = 1: 600 lanes in
     640) has nothing to shift, only its pad to whole registers: XLA's
-    scatter-add needs it, the tile kernel (``tiles``: the caller's reading
-    of :func:`_tile_kernel_takes`) adds a row of ``w`` <= ``W`` lanes into
-    lanes ``[0, w)`` and is handed the deltas as they are (a pad of the
-    batch's rows in HBM in front of it was 0.45 ms a call in cell 5: PERF.md
-    section 6, PR 57)."""
-    kernel = _shift_kernel_takes(spec, flat_ids.shape[0])  # packed alone
+    scatter-add needs it, the tile kernel (``arm.push``) adds a row of
+    ``w`` <= ``W`` lanes into lanes ``[0, w)`` and is handed the deltas as
+    they are."""
+    kernel, tiles = arm.shift == "kernel", arm.push == "tile_add"
     if not kernel:
         flat_deltas = _zero_masked(flat_deltas, flat_mask)
     if spec.layout != "packed":
@@ -435,28 +425,17 @@ def push(
     neither reads its delta nor opens a tile for it), so it moves nothing
     and a rule store's counts pass it by.  A masked lane that keeps a live
     id is added as a zero.  ``update="add"`` is one scatter-add of the batch,
-    duplicates and all, in the arm :func:`_tile_kernel_takes` reads from the
-    spec and the batch's length: ONE XLA scatter-add, which sums the deltas
-    of a row in the order the batch holds them (part of what the benchmark's
-    reference checks; PERF.md section 6, PR 27 and PR 30), or, on a TPU,
-    ``ops/row_update.scatter_add``: the batch sorted by row (stably: a row's
-    deltas stay in the order of the batch and are added one by one, XLA's
-    roundings bit for bit) and every touched tile of eight rows read, added
-    to and written back once a block of lanes (PERF.md section 6, PR 33).
-    The kernel is handed the deltas at their OWN width ``w`` <= the table's
-    ``W`` (600 lanes for rows of 640: it adds into lanes ``[0, w)`` and
-    leaves the table's pad lanes as they are); only XLA's arm pads the
-    batch to whole registers (:func:`_phys_scatter_args`).
-    The kernel takes physical rows of several 128-lane registers always, and
-    rows of ONE register where the batch has no more than an eighth as many
-    lanes as the table has rows: there the TPU compiler leaves its
-    scatter-add serial, 74.7 ns a lane, and above it sorts the batch itself
-    and pays 13-22 (PERF.md section 6, PR 49).
-
-    A store whose ``update`` is a rule goes through :func:`_push_rule`
-    (:func:`push_counted` also hands out what that arm counted); sharded
-    over ``ps`` under one worker, every shard runs it on the rows it owns
-    (:func:`_push_rule_on_shards`).
+    duplicates and all: ONE XLA scatter-add, which sums the deltas of a row
+    in the order the batch holds them (part of what the benchmark's
+    reference checks), or ``ops/row_update.scatter_add``: the batch sorted
+    by row (stably: a row's deltas stay in the order of the batch and are
+    added one by one, XLA's roundings bit for bit) and every touched tile of
+    eight rows read, added to and written back once a block of lanes.  A
+    store whose ``update`` is a rule goes through :func:`_push_rule`, under
+    a mesh with one worker on the shards that own the rows
+    (:func:`_push_rule_on_shards`).  Which form, and what each cost on the
+    chip: :func:`arms`; :func:`push_counted` also hands out what the push
+    counted.
     """
     return push_counted(spec, table, ids, deltas, mask)[0]
 
@@ -472,25 +451,25 @@ def push_counted(
 ) -> Tuple[Array, Optional[dict]]:
     """:func:`push`, and beside the table what the push counted on the
     device (``None`` for an ``update="add"`` batch that XLA's scatter-add
-    took, which counts nothing).  An ``add`` batch the tile kernel took
-    (:func:`_tile_kernel_takes`): ``ps_push_kernel_lanes``, the lanes it
+    took, which counts nothing), by the arm :func:`arms` read.
+    ``tile_add``: ``ps_push_kernel_lanes``, the lanes the kernel
     kept, and ``ps_push_tile_rows``, the tile rows of eight rows it read
     and wrote for them, summed over the kernel's calls (what its time is
-    made of: two DMA descriptors a tile row).  A rule store:
+    made of: two DMA descriptors a tile row).  ``rule``:
     ``ps_rule_keys``, the live lanes of the batch, ``ps_rule_rows``, the
     distinct rows the rule rewrote, and ``ps_rule_tiles``, the tiles of 128
-    rows the write-back read and wrote to do so (0 where XLA's scatter
-    wrote the rows: :func:`_set_kernel_takes`); a rule store whose rows are
-    wider than a sort carries also ``ps_combine_kernel_lanes``, the lanes
+    rows the write-back read and wrote to do so (0 where XLA's ``set``
+    wrote the rows); where ``combine`` is not ``sort`` also
+    ``ps_combine_kernel_lanes``, the lanes
     whose rows the row kernel summed (the live lanes; 0 where XLA's
-    scatter-add summed them: :func:`_combine_kernel_takes`), and
+    scatter-add summed them), and
     ``ps_combine_kernel_writes``, the single-row DMAs the kernel issued to
     do so over the stretches it walked (``ops/row_update.descriptors``:
     writes over lanes is the share of the walk that writes, ~28 % on Criteo
     records); a PACKED rule
     store carries ``ps_rule_packed_rows``, the physical rows its write-back
     wrote (:func:`_rewrite_packed`; a dense rule store has no such count).
-    Where the push ran on the shards of a mesh (:func:`_rule_on_shards_takes`)
+    ``on_shards``:
     each of these is the SUM over the shards of what each counted on its own
     block (ownership is disjoint, so keys, rows and kernel lanes are the
     one-place push's numbers; tiles, physical rows and the kernel's writes,
@@ -499,7 +478,8 @@ def push_counted(
     ``ps_rule_keys_max_shard`` and ``ps_rule_rows_max_shard``, the live keys
     and the distinct rows of the FULLEST shard, the one a step waits for.
     ``make_train_step`` puts
-    them among the step's outputs, where whoever fetches outputs finds them, if the logic's
+    them among the step's outputs, where whoever fetches outputs finds them
+    (:func:`publish_counts`), if the logic's
     outputs are a dict (every logic of ``models/``); outputs of another
     type leave the step as they are, without the counts.
 
@@ -507,7 +487,7 @@ def push_counted(
     and a bare ``push`` cannot see: its lanes are split over the mesh's
     ``dp`` workers (``make_train_step`` under such a mesh says so).  An
     ``add`` batch may then be summed worker by worker
-    (:func:`_worker_reduce_takes`); a bare ``push`` keeps ONE scatter-add in
+    (``worker_reduce``); a bare ``push`` keeps ONE scatter-add in
     the batch's order, whatever the mesh."""
     vr = len(spec.value_shape)
     lead = tuple(deltas.shape[: deltas.ndim - vr])
@@ -533,34 +513,33 @@ def push_counted(
     flat_deltas = deltas.reshape((-1,) + spec.value_shape)
     flat_mask = None if mask is None else mask.reshape(-1)
 
-    if spec.update == "add":
-        tiles = _tile_kernel_takes(spec, flat_ids.shape[0])
-        s_ids, s_deltas = _phys_scatter_args(
-            spec, table, flat_ids, flat_deltas, flat_mask, tiles
+    arm = arms(spec, push_lanes=flat_ids.shape[0],
+               lanes_over_workers=lanes_over_workers)
+    if arm.push == "rule":
+        # (a masked lane's delta goes as it is: `_push_rule` sends the lane
+        # to the sentinel, and no combine arm lets a dropped lane's value
+        # reach a kept row)
+        return (_push_rule_on_shards if arm.on_shards else _push_rule)(
+            spec, table, flat_ids, flat_deltas, flat_mask, arm
         )
-        if tiles:
-            from ..ops.row_update import scatter_add_counted
+    s_ids, s_deltas = _phys_scatter_args(
+        spec, table, flat_ids, flat_deltas, flat_mask, arm
+    )
+    if arm.push == "tile_add":
+        from ..ops.row_update import scatter_add_counted
 
-            table, lanes, tile_rows = scatter_add_counted(
-                table, s_ids, s_deltas.astype(table.dtype))
-            return table, {
-                "ps_push_kernel_lanes": lanes, "ps_push_tile_rows": tile_rows,
-            }
-        if lanes_over_workers and _worker_reduce_takes(spec, s_ids.shape[0]):
-            return _push_add_over_workers(
-                spec, table, s_ids, s_deltas.astype(table.dtype)
-            ), None
-        return (
-            table.at[s_ids].add(s_deltas.astype(table.dtype), mode="drop"),
-            None,
-        )
-
-    # (a masked lane's delta goes as it is: `_push_rule` sends the lane to
-    # the sentinel, and no combine arm lets a dropped lane's value reach a
-    # kept row)
-    on_shards = _rule_on_shards_takes(spec)
-    return (_push_rule_on_shards if on_shards else _push_rule)(
-        spec, table, flat_ids, flat_deltas, flat_mask
+        table, lanes, tile_rows = scatter_add_counted(
+            table, s_ids, s_deltas.astype(table.dtype))
+        return table, {
+            "ps_push_kernel_lanes": lanes, "ps_push_tile_rows": tile_rows,
+        }
+    if arm.push == "worker_reduce":
+        return _push_add_over_workers(
+            spec, table, s_ids, s_deltas.astype(table.dtype)
+        ), None
+    return (
+        table.at[s_ids].add(s_deltas.astype(table.dtype), mode="drop"),
+        None,
     )
 
 
@@ -574,10 +553,12 @@ def _push_rule(
     flat_ids: Array,
     flat_deltas: Array,
     live: Optional[Array],
+    arm: Arms,
     block: Optional[int] = None,
 ) -> Tuple[Array, dict]:
     """The push of a store whose ``update`` is a rule and not ``"add"``:
-    ``(table, counted)``, as :func:`push_counted` hands them out.  ``table``
+    ``(table, counted)``, as :func:`push_counted` hands them out, in the
+    forms ``arm`` names (:func:`arms`).  ``table``
     is the whole table in one place (or GSPMD's to partition), or, with
     ``block`` its count of LOGICAL rows, the block of one shard with the
     ids relative to its first row and every lane the shard does not own
@@ -587,38 +568,32 @@ def _push_rule(
     Work and memory go with the batch, never with the table.  Under
     ``ps.combine`` the batch's ids are sorted with their deltas, every run
     of one id summed and the distinct ids moved to the front
-    (:func:`..ops.dedup.combine_runs`; masked, negative and out-of-range
-    lanes sort last and are dropped; a wide row's runs are summed in the arm
-    :func:`_combine_kernel_takes` reads from the spec, and the kernel arm's
-    walk pays by what it writes: a DMA a distinct row, and no stretch of
-    sorted lanes past the last live one, so a shard of cell 12 that owns
-    3.7 % of the keys walks one stretch of thirteen: PERF.md section 6, PR
-    54).  Then
+    (:func:`..ops.dedup.combine_runs`, ``arm.combine``; masked, negative and
+    out-of-range lanes sort last and are dropped).  Then
     ``_RULE_CHUNK`` lanes a step of
     a loop that ends with the last distinct id (on the TPU a dropped lane
     of a gather or a scatter costs what a kept one does, and a batch of
     Criteo records names a row 3.6 times on average): under ``ps.rule``
     the CURRENT rows of the chunk's ids are read (:func:`pull`) and
     ``update(current, combined)`` run on those alone; what is left under
-    ``ps.push`` writes the new rows back, each distinct id once, in the arm
-    :func:`_set_kernel_takes` reads from the spec: XLA's row ``set`` (on
-    the v5e one serial write a row, 83 ns for FTRL's three lanes), or, on
-    a TPU, for a narrow row held at its sublane tile
+    ``ps.push`` writes the new rows back, each distinct id once
+    (``arm.write_back``): XLA's row ``set``, or, for a narrow row held at
+    its sublane tile
     (``StoreSpec.tile_lanes``), ``ops/row_update.sorted_tile_set``: every
     touched tile of 128 rows read, set and written back once, the same
-    bits (PERF.md section 6, PR 35).  A PACKED store's chunk goes through
+    bits.  A PACKED store's chunk goes through
     :func:`_rewrite_packed`, which reads and writes whole physical rows,
     and ``counted`` then carries ``ps_rule_packed_rows``.  A rule row wider
-    than a register lies flat in several (:func:`_flat_wide_rule`, packed at
-    ``k`` = 1): nothing here pads its deltas.  On a TPU the combine sums
-    them, ``w`` lanes wide as they come, through the tile kernel into a
+    than a register lies flat in several (packed at
+    ``k`` = 1): nothing here pads its deltas.  The ``tile_kernel`` combine
+    sums
+    them, ``w`` lanes wide as they come, into a
     zeroed block of whole registers, in the order of the stream
     (``ops/dedup._tile_sums``: the sums come out ``W`` wide, zeros past
-    ``w``; XLA's scatter-add arm sums at ``w``), and its write-back's kernel
-    arm is ``ops/row_update.sorted_tile_assign``, every touched tile of
-    eight rows read, set and written once (``ps_rule_tiles`` counts those
-    tile rows, ``ps_combine_kernel_writes`` the combine's; PERF.md section
-    6, PRs 55 and 57).
+    ``w``; the ``scatter_add`` arm sums at ``w``), and its ``tile_assign``
+    write-back reads, sets and writes every touched tile of
+    eight rows once (``ps_rule_tiles`` counts those
+    tile rows, ``ps_combine_kernel_writes`` the combine's).
 
     ``flat_deltas`` come as the logic made them, a masked lane's too
     (``live`` false): every such lane, like every id past the table, is
@@ -626,46 +601,38 @@ def _push_rule(
     and what they hold (NaN, Inf) reaches no kept row
     (``tests/test_store.py`` holds the four arms to that), so no pass
     zeroes them first."""
-    from ..ops.dedup import _SORT_CARRIES_LANES, combine_runs
+    from ..ops.dedup import combine_runs
     from ..ops.row_update import sorted_tile_set
 
     n = flat_ids.shape[0]
     sentinel = spec.padded_capacity if block is None else block
     update_fn: UpdateFn = spec.update  # type: ignore[assignment]
-    wide = spec.row_width > _SORT_CARRIES_LANES
     packed = spec.layout == "packed"
+    # what the push hands out, an empty batch's too: by the arms alone
+    names = ["ps_rule_keys", "ps_rule_rows", "ps_rule_tiles"]
+    if arm.combine != "sort":
+        names += ["ps_combine_kernel_lanes", "ps_combine_kernel_writes"]
+    if packed:
+        names.append("ps_rule_packed_rows")
     if n == 0:  # an empty batch rewrites nothing
-        zero = jnp.zeros((), jnp.int32)
-        counted = {
-            "ps_rule_keys": zero, "ps_rule_rows": zero, "ps_rule_tiles": zero,
-        }
-        if wide:
-            counted["ps_combine_kernel_lanes"] = zero
-            counted["ps_combine_kernel_writes"] = zero
-        if packed:
-            counted["ps_rule_packed_rows"] = zero
-        return table, counted
-    tiles_arm = _set_kernel_takes(spec)
-    sums_arm = _combine_kernel_takes(spec)
+        return table, dict.fromkeys(names, jnp.zeros((), jnp.int32))
     chunk = min(n, _RULE_CHUNK)
-    flat_wide = _flat_wide_rule(spec)
     with jax.named_scope("ps.combine"):
         dead = flat_ids >= sentinel
         if live is not None:
             dead = dead | ~live
         vals = flat_deltas.reshape(n, -1).astype(table.dtype)
         row_ids, combined, issued = combine_runs(
-            jnp.where(dead, sentinel, flat_ids), vals, sentinel,
-            kernel=sums_arm,
+            jnp.where(dead, sentinel, flat_ids), vals, sentinel, arm.combine,
         )
         counted = {
             "ps_rule_keys": n - jnp.sum(dead, dtype=jnp.int32),
             "ps_rule_rows": jnp.sum(row_ids < sentinel, dtype=jnp.int32),
         }
-        if wide:
+        if arm.combine != "sort":
             counted["ps_combine_kernel_lanes"] = (
-                counted["ps_rule_keys"] if sums_arm
-                else jnp.zeros((), jnp.int32)
+                jnp.zeros((), jnp.int32) if arm.combine == "scatter_add"
+                else counted["ps_rule_keys"]
             )
             counted["ps_combine_kernel_writes"] = issued
         # whole chunks: a chunk that started early would run the rule on
@@ -681,7 +648,8 @@ def _push_rule(
             combined, (i * chunk, 0), (chunk, combined.shape[1])
         )
         if packed:
-            table, wrote = _rewrite_packed(spec, table, ids, sums, tiles_arm)
+            table, wrote = _rewrite_packed(
+                spec, table, ids, sums, arm.write_back)
             return table, moved + wrote
         with jax.named_scope("ps.rule"):
             # a shard's block is a plain dense array (no tile under a mesh)
@@ -690,7 +658,7 @@ def _push_rule(
             new = update_fn(
                 current, sums.reshape((chunk,) + spec.value_shape),
             ).astype(table.dtype)
-        if tiles_arm:
+        if arm.write_back == "tile_set":
             table, opened = sorted_tile_set(table, ids, new)
             return table, moved + opened
         return table.at[ids].set(_physical_rows(spec, new), mode="drop"), moved
@@ -700,17 +668,16 @@ def _push_rule(
     table, moved = jax.lax.fori_loop(0, chunks, rewrite, (table, zero))
     # a flat wide row's write-back counts its tile rows where the tile
     # kernel wrote them; its physical rows are its distinct rows (k = 1)
-    wide_tiles = flat_wide and tiles_arm
+    wide_tiles = arm.write_back == "tile_assign"
     counted["ps_rule_tiles"] = moved if wide_tiles or not packed else zero
     if packed:
         counted["ps_rule_packed_rows"] = (
             counted["ps_rule_rows"] if wide_tiles else moved)
-    return table, counted
+    return table, {name: counted[name] for name in names}
 
 
 def _rewrite_packed(
-    spec: StoreSpec, table: Array, ids: Array, sums: Array,
-    kernel: bool = False,
+    spec: StoreSpec, table: Array, ids: Array, sums: Array, write_back: str,
 ) -> Tuple[Array, Array]:
     """One chunk of :func:`_push_rule` for a PACKED table: ``(table,
     physical rows written)``, or, where the tile kernel wrote a flat wide
@@ -729,21 +696,20 @@ def _rewrite_packed(
     physical row with every touched window replaced, ``k - 1`` shifted
     passes over the chunk, and the FIRST lane of each such run writes it,
     the others go to the sentinel: ONE ``set`` of whole physical rows,
-    each touched physical row once, in the arm :func:`_set_kernel_takes`
-    read from the spec (``kernel``): XLA's row ``set`` (on the v5e 72 ns a
-    lane of the chunk, written or dropped, serial) or, on a TPU, for
+    each touched physical row once, in the form ``write_back`` names
+    (:func:`arms`): ``xla_set``, XLA's row ``set``; ``row_set``, for
     float32 physical rows of one register, ``ops/row_update.
-    sorted_row_set``, the row kernel's walk with a copy for its body
-    (PERF.md section 6, PR 47), and for float32 physical rows of SEVERAL
-    registers (``k`` = 1, a flat wide row: GloVe's 640 lanes)
+    sorted_row_set``, the row kernel's walk with a copy for its body; and
+    ``tile_assign``, for float32 physical rows of SEVERAL
+    registers (``k`` = 1, a flat wide row: GloVe's 640 lanes),
     ``ops/row_update.sorted_tile_assign``, the wide add push's tile walk
     with a store for its body: a Pallas DMA cannot write one such row
     alone, so every touched tile of eight rows is read, set and written
-    once (PERF.md section 6, PR 55).  That arm is handed the NEW rows as the
+    once.  That arm is handed the NEW rows as the
     rule made them, ``(chunk, row_width)``: at ``k`` = 1 a row has one
     window and no neighbour, and the kernel stores lanes ``[0, row_width)``
     of a row and keeps its pad lanes as it read them, so nothing shifts,
-    pads or merges on its way (PR 57; the other two arms write whole
+    pads or merges on its way (the other two arms write whole
     physical rows and merge first).  Selects and copies only, never an add or a
     masked sum: an untouched logical row inside a touched physical row,
     the pad lanes and a row's NaN or -0.0 come back bit for bit.  A
@@ -769,7 +735,7 @@ def _rewrite_packed(
         [jnp.ones((1,), bool), phys[1:] != phys[:-1]]
     ) & (phys < phys_rows)
     at = jnp.where(writes, phys, phys_rows)
-    if kernel and lanes > 128:
+    if write_back == "tile_assign":
         # k = 1: every live lane writes, the sentinels close the chunk; a
         # new row has one window and no neighbour to merge with, and the
         # kernel keeps the pad lanes of the rows it sets as it read them
@@ -786,7 +752,7 @@ def _rewrite_packed(
         )[:, None] & (window == jnp.pad(sub[s:], (0, s))[:, None])
         merged = jnp.where(
             near, jnp.pad(placed[s:], ((0, s), (0, 0))), merged)
-    if kernel:
+    if write_back == "row_set":
         table = sorted_row_set(table, at, merged)
     else:
         table = table.at[at].set(merged, mode="drop")
@@ -833,201 +799,288 @@ def _create_dense_in_blocks(
     return jax.jit(build)
 
 
-# Physical row widths, in 128-lane registers, from which `push` goes through
-# ops/row_update's tile kernel WHATEVER the batch.  XLA's TPU scatter-add is
-# one serial read-modify-write a lane (~65 ns + ~12 ns a 128-lane piece of the
-# row); the kernel pays a sort, a permute of the deltas, ~20 ns of adds a lane
-# and a read and a write of every touched tile of 8 rows at HBM speed.  On
-# cell 5's ids (114,688 lanes, 48,841 rows) at 2 / 3 / 5 registers a row: XLA
-# 9.95 / 11.71 / 14.28 ms, the kernel path 4.46 / 5.18 / 6.28 (PERF.md section
-# 6, PR 33).
+# The three constants below mirror the TPU compiler, as measured on the v5e.
+# Physical row widths, in 128-lane registers, from which an add push goes
+# through ops/row_update's tile kernel WHATEVER the batch: XLA's scatter-add
+# is one serial read-modify-write a lane (~65 ns + ~12 ns a 128-lane piece of
+# the row); the kernel pays a sort, a permute of the deltas, ~20 ns of adds a
+# lane and a read and a write of every touched tile of 8 rows at HBM speed
+# (cell 5's ids at 2 / 3 / 5 registers a row: XLA 9.95 / 11.71 / 14.28 ms, the
+# kernel path 4.46 / 5.18 / 6.28; PERF.md section 6, PR 33).
 _TILE_KERNEL_MIN_REGISTERS = 2
-# A row of ONE register goes where the TPU compiler's own form of the
-# scatter-add sends it.  The compiler sorts the batch inside the scatter
-# (`indices_are_sorted=true`: 13-22 ns a lane, cells 1 and 2) exactly when
-# the batch has MORE than an eighth as many lanes as the operand has rows, and
-# leaves the serial form (74.7 ns a lane: cell 10, MF's user state before PR
-# 27) at or under it; `tests/test_tpu_compile.py` pins that cut on the plain
-# op for a described v5e.  So the kernel takes a one-register push whose lanes
-# times this do not exceed the table's rows (the ordinary state of a parameter
-# server: a table far longer than a minibatch), and XLA keeps every other.
+# A row of ONE register goes where the compiler's own form of the scatter-add
+# sends it: it sorts the batch inside the scatter (13-22 ns a lane, cells 1
+# and 2) exactly when the batch has MORE than an eighth as many lanes as the
+# operand has rows, and leaves the serial form (74.7 ns a lane) at or under it
+# (`tests/test_tpu_compile.py` pins that cut on the plain op for a described
+# v5e).  The kernel takes the push whose lanes times this do not exceed the
+# table's rows, XLA keeps every other.
 _SERIAL_SCATTER_ROWS_A_LANE = 8
 # ... and whose lanes are no fewer than this: under it the kernel's fixed
 # costs (its sorts, its plan, a grid of at least four steps) lose to 75 ns a
 # lane, and an eager push of a few rows would trace and lower a kernel for
-# them, 0.4 s.  On the v5e, distinct rows into 24.6 M, XLA against the kernel
-# path: 64 lanes 5.2 / 12.6 us, 256 lanes 18.8 / 20.8, 1,024 lanes 77.4 /
-# 51.5, 4,096 lanes 308 / 172 (PERF.md section 6, PR 49: the sweep).
+# them, 0.4 s (the sweep: PERF.md section 6, PR 49).
 _ONE_REGISTER_MIN_LANES = 1024
-# table row shapes already warned of (`_tile_kernel_takes`)
+# (arm, physical row shape, dtype) already warned of (`arms`)
 _REFUSALS_NOTED: set = set()
 
 
-def _tile_kernel_takes(spec: StoreSpec, lanes: Optional[int] = None) -> bool:
-    """Whether ``push`` applies an ``add`` batch of ``lanes`` physical lanes
-    through ``ops/row_update.scatter_add`` instead of XLA's scatter-add, read
-    from what the spec and the batch hold, as :func:`_slice_kernel_takes`
-    reads the pull's arm: a TPU, no mesh (under one GSPMD partitions the XLA
-    scatter), ``update="add"``, a shape and dtype the kernel takes, and
-    either a physical row of ``_TILE_KERNEL_MIN_REGISTERS`` registers or
-    more, or a row of one register and a batch of at least
-    ``_ONE_REGISTER_MIN_LANES`` lanes that is no longer than the table's
-    rows over ``_SERIAL_SCATTER_ROWS_A_LANE``: where the TPU compiler leaves
-    its scatter-add serial (74.7 ns a lane on the v5e; above that cut it
-    sorts the batch itself and takes 13-22, which the kernel's sort, permute
-    and tile walk do not beat).  Both sizes are shapes: static per compiled
-    step.  ``lanes`` None asks whether ANY push of the store may take the
-    kernel (:func:`_preload_tile_kernel`).  Such a store that the kernel
-    REFUSES (rows of rank 2 or no multiple of 128 lanes under a pinned
-    ``"dense"`` layout; bfloat16) keeps the XLA arm, is counted and warned of
-    once a row shape (``ops/row_update.refusal_count``)."""
-    from ..ops import row_update
+@dataclasses.dataclass(frozen=True)
+class Arms:
+    """The forms a store's pull and push take, as :func:`arms` read them."""
 
-    shape = spec.table_shape()
-    width = 1
+    pull: str  # "take" | "narrow" | "packed_selects" | "packed_kernel"
+    push: str  # "xla_add" | "tile_add" | "worker_reduce" | "rule"
+    shift: str  # a packed add push's lane shift: "" | "selects" | "kernel"
+    # a rule push: "" | "sort" | "scatter_add" | "row_kernel" | "tile_kernel"
+    combine: str
+    # a rule push: "" | "xla_set" | "tile_set" | "row_set" | "tile_assign"
+    write_back: str
+    on_shards: bool  # a rule push runs in a shard_map, on the owning shard
+
+
+def arms(
+    spec: StoreSpec, *, pull_lanes: Optional[int] = None,
+    push_lanes: Optional[int] = None, lanes_over_workers: bool = False,
+) -> Arms:
+    """THE one reader of which form a pull of ``pull_lanes`` ids and a push
+    of ``push_lanes`` lanes take, from what the spec and the batch hold: the
+    backend, the mesh and its workers, the update, the layout, the physical
+    row, the dtype, the lanes against the rows, and the kernels' own
+    refusals.  All of it is shapes: static per compiled step.  A lane count
+    of None asks whether ANY pull / push of the store may take a kernel
+    (:func:`_preload_tile_kernel`).  ``lanes_over_workers``: the caller
+    knows the batch's lanes lie split over ``dp`` (:func:`push_counted`).  A
+    store a kernel REFUSES (bfloat16, a pinned layout Mosaic cannot tile, a
+    batch under one block) keeps XLA's arm and is counted and warned of once
+    a physical row shape, dtype and arm (``ops/row_update.refusal_count``).
+
+    THE CASE TABLE, on a TPU, float32 (``-`` is ``""``; the PRs are PERF.md
+    section 6's, where the arm was priced;
+    ``tests/test_store.py::test_the_arms_table`` holds a case a row).  Off a
+    TPU a pull reads ``take`` / ``narrow`` / ``packed_selects``, an add push
+    ``xla_add`` (``worker_reduce`` as below) and ``selects``, a rule push
+    ``sort`` / ``scatter_add`` and ``xla_set``, ``on_shards`` as below.
+
+    An ``add`` store (``combine`` and ``write_back`` ``""``):
+
+    ================================  ==============  =============  =======  ======  ========
+    spec and batch                    pull            push           shift    cell    PR
+    ================================  ==============  =============  =======  ======  ========
+    dense 1 reg, lanes x 8 > rows     take            xla_add        -        1 3 11  27 30
+    dense 1 reg, 1,024+ <= rows / 8   take            tile_add       -        none    49
+    dense 1 reg, dp 4, shard <= lanes  take           worker_reduce  -        8       40
+    packed k 7, lanes x 8 > rows      packed_kernel   xla_add        kernel   2       29 42 51
+    the same over ps 4, dp 1          packed_kernel   xla_add        kernel   4       31 42 51
+    packed k 2, 1,024+ <= rows / 8    packed_kernel   tile_add       kernel   10      49 51
+    packed k 7, under a block of ids  packed_selects  xla_add        selects  none    42
+    packed k 1, 5 regs (3: cell 7)    packed_selects  tile_add       selects  5 7     32 33 57
+    5 regs under a mesh               take            xla_add        -        none    33
+    ================================  ==============  =============  =======  ======  ========
+
+    A store whose ``update`` is a rule (``push`` ``"rule"``, ``shift``
+    ``""``):
+
+    ================================  ==============  ===========  ===========  =========  ====  ========
+    spec                              pull            combine      write_back   on_shards  cell  PR
+    ================================  ==============  ===========  ===========  =========  ====  ========
+    3 lanes, held at its tile of 4    narrow          sort         tile_set     no         6     34 35
+    6 lanes, held at its tile of 8    narrow          row_kernel   tile_set     no         none  35 46
+    (2, 2) lanes: rank 2, no tile     take            sort         xla_set      no         none  35
+    packed k 3 (36 lanes)             packed_kernel   row_kernel   row_set      no         9     46 47 54
+    the same over ps 4, dp 1          packed_kernel   row_kernel   row_set      yes        12    52
+    the same over ps 2, dp 2          packed_kernel   scatter_add  xla_set      no         none  52
+    packed k 1, 1 reg (pinned, 100)   packed_selects  row_kernel   row_set      no         none  47
+    packed k 1, 5 regs (602 lanes)    packed_selects  tile_kernel  tile_assign  no         13    55 57
+    dense 1 reg (100 lanes)           take            row_kernel   xla_set      no         none  46
+    ================================  ==============  ===========  ===========  =========  ====  ========
+
+    Reasons the code does not show.  A mesh keeps an add push XLA's because
+    GSPMD partitions the scatter and cannot partition Mosaic's call.  The
+    lane kernels and a rule's kernels run under a mesh inside a
+    ``shard_map``, where they see a plain array; a rule's need ONE worker
+    for that: ``dp`` > 1 holds the batch's lanes split, a shard would first
+    have to be sent the other workers' keys, and no code here does, so such
+    a store keeps the one-place push under GSPMD, noted.  A mesh with one
+    shard leaves the packed pull to GSPMD.  ``worker_reduce`` wants a shard
+    no longer than the batch, so that the per-worker sums it moves are no
+    larger than the deltas.  A rule's write-back shifts a chunk at a time
+    (:func:`_rewrite_packed`), so only an add push has a ``shift``.  A rule
+    row no wider than a sort carries rides through the sort whatever the
+    backend.  A narrow rule row NOT held at its tile (bfloat16, rank 0 or
+    2) keeps XLA's ``set``, noted."""
+    from ..ops import dedup, packed, row_update
+
+    tpu = jax.default_backend() == "tpu"
+    shape, rule = spec.table_shape(), spec.update != "add"
+    phys = 1  # lanes of a physical row
     for s in shape[1:]:
-        width *= int(s)
-    if (spec.update != "add" or spec.mesh is not None
-            or jax.default_backend() != "tpu"):
-        return False
-    what = "wide rows"
-    if width < _TILE_KERNEL_MIN_REGISTERS * 128:
-        if lanes is None:
-            lanes = _ONE_REGISTER_MIN_LANES
-        if (width != 128 or lanes < _ONE_REGISTER_MIN_LANES
-                or lanes * _SERIAL_SCATTER_ROWS_A_LANE > shape[0]):
+        phys *= int(s)
+    workers = worker_count(spec.mesh)
+    one_block = workers == 1  # a rule's push sees a plain array
+
+    def taken(what: str, why: Optional[str]) -> bool:
+        if why is not None:
+            key = (what, shape[1:], jnp.dtype(spec.dtype).name)
+            if key not in _REFUSALS_NOTED:
+                _REFUSALS_NOTED.add(key)
+                row_update.note_refusal(what, why)
+        return why is None
+
+    def lane_kernel(n: Optional[int], what: str) -> bool:
+        # ops/packed's two kernels move the same rows, one way each
+        if not tpu or spec.pack == 1 or (
+                spec.mesh is not None and spec.num_shards == 1):
             return False
-        what = "one-register rows eight batches long"
-    return _taken_or_noted(
-        spec, f"push into a table of {what}",
-        row_update.tile_refusal(shape, spec.dtype),
-    )
+        why = packed.slice_refusal(
+            packed.SLICE_BLOCK if n is None else n, spec.dtype, spec.row_width)
+        return why is None if n is None else taken(what, why)
+
+    if spec.layout != "packed":
+        pull = "narrow" if spec.tile_lanes > spec.row_width else "take"
+    else:
+        n = pull_lanes
+        if n is not None and spec.mesh is not None:
+            # a shard slices the lanes of its worker
+            over = spec.mesh.size // spec.num_shards
+            n = n // over if n % over == 0 else n
+        kernel = lane_kernel(n, "the lane slice of a packed pull")
+        pull = "packed_kernel" if kernel else "packed_selects"
+
+    if not rule:
+        tiles, what = spec.mesh is None and tpu, "wide rows"
+        if tiles and phys < _TILE_KERNEL_MIN_REGISTERS * 128:
+            n = _ONE_REGISTER_MIN_LANES if push_lanes is None else push_lanes
+            tiles = (phys == 128 and n >= _ONE_REGISTER_MIN_LANES
+                     and n * _SERIAL_SCATTER_ROWS_A_LANE <= shape[0])
+            what = "one-register rows eight batches long"
+        if tiles and taken(f"push into a table of {what}",
+                           row_update.tile_refusal(shape, spec.dtype)):
+            push = "tile_add"
+        elif (lanes_over_workers and push_lanes is not None and workers > 1
+              and push_lanes % workers == 0
+              and spec.rows_per_shard <= push_lanes):
+            push = "worker_reduce"
+        else:
+            push = "xla_add"
+        shift = ""
+        if spec.layout == "packed":
+            kernel = lane_kernel(push_lanes, "the lane shift of a packed push")
+            shift = "kernel" if kernel else "selects"
+        return Arms(pull, push, shift, "", "", False)
+
+    on_shards = spec.mesh is not None and taken(
+        "a rule store's push on the shards that own its rows",
+        None if one_block else
+        f"the batch lies split over dp = {workers} workers")
+    flat_wide = spec.layout == "packed" and spec.pack == 1 and phys > 128
+    write_back = "xla_set"
+    if tpu and spec.tile_lanes:
+        write_back = "tile_set"
+    elif tpu and spec.layout == "packed":
+        if one_block and taken(
+                "write-back of a packed rule store's rows",
+                row_update.tile_refusal(shape, spec.dtype) if flat_wide
+                else row_update.refusal(shape[1:], spec.dtype)):
+            write_back = "tile_assign" if flat_wide else "row_set"
+    elif tpu and spec.narrow_rule:
+        taken("write-back of a rule's narrow rows",
+              row_update.set_refusal(shape, spec.dtype))
+    if spec.row_width <= dedup.SORT_CARRIES_LANES:
+        combine = "sort"
+    elif one_block and tpu and taken(
+            "the sum of a rule's wide rows", dedup.kernel_refusal(
+                phys if flat_wide else spec.row_width, spec.dtype)):
+        combine = "tile_kernel" if flat_wide else "row_kernel"
+    else:
+        combine = "scatter_add"
+    return Arms(pull, "rule", "", combine, write_back, on_shards)
 
 
-def _taken_or_noted(spec: StoreSpec, what: str, why: Optional[str]) -> bool:
-    """True for no refusal; a refusal is counted and warned of once a row
-    shape, dtype and arm."""
-    if why is None:
-        return True
-    key = (what, spec.table_shape()[1:], jnp.dtype(spec.dtype).name)
-    if key not in _REFUSALS_NOTED:
-        _REFUSALS_NOTED.add(key)
-        from ..ops.row_update import note_refusal
+def _preload_tile_kernel(spec: StoreSpec) -> None:
+    """Where a store is made whose pushes or pulls will trace a kernel: have
+    Pallas imported by then, beside the table's staging (the import is ~1 s
+    that the first trace of the step else pays)."""
+    a = arms(spec)
+    if (a.pull == "packed_kernel" or a.push == "tile_add"
+            or a.combine.endswith("_kernel")
+            or a.write_back not in ("", "xla_set")):
+        from ..ops.row_update import preload
 
-        note_refusal(what, why)
-    return False
-
-
-def _set_kernel_takes(spec: StoreSpec) -> bool:
-    """Whether a rule store's write-back (:func:`_push_rule`) goes through a
-    kernel of ``ops/row_update`` instead of XLA's row ``set``, read from
-    what the spec holds, as :func:`_tile_kernel_takes` reads the add arm's:
-    a TPU, and either a table held at whole sublane tiles
-    (``StoreSpec.tile_lanes``: ``sorted_tile_set``; in one place only) or a
-    PACKED float32 table, in one place or sharded over ``ps`` under one
-    worker, where every shard's rule runs on its own block
-    (:func:`_rewrite_packed`; :func:`_push_rule_on_shards`): a physical row
-    of one register through ``sorted_row_set``, a flat wide row of several
-    (:func:`_flat_wide_rule`) through ``sorted_tile_assign``, tile by tile
-    of eight rows.  A mesh with ``dp`` > 1 keeps XLA's row ``set``, GSPMD's
-    to partition.  Static per compiled step.  A narrow rule store that is
-    NOT held at its tile (bfloat16, rows of rank 0 or 2) and a packed one
-    its kernel refuses (bfloat16; a pinned ``"packed"`` row of 65 to 127
-    lanes is one register and taken) keep the XLA arm, counted and warned
-    of once."""
-    if jax.default_backend() != "tpu":
-        return False
-    if spec.tile_lanes:
-        return True
-    if spec.layout == "packed" and spec.update != "add":
-        from ..ops import row_update
-
-        shape = spec.table_shape()
-        return _rule_sees_one_block(spec) and _taken_or_noted(
-            spec, "write-back of a packed rule store's rows",
-            row_update.tile_refusal(shape, spec.dtype) if _flat_wide_rule(spec)
-            else row_update.refusal(shape[1:], spec.dtype),
-        )
-    if spec.narrow_rule:
-        from ..ops import row_update
-
-        _taken_or_noted(
-            spec, "write-back of a rule's narrow rows",
-            row_update.set_refusal(spec.table_shape(), spec.dtype),
-        )
-    return False
+        preload()
 
 
-def _combine_kernel_takes(spec: StoreSpec) -> bool:
-    """Whether a rule store's combine (:func:`_push_rule`) sums the runs of
-    its batch along SORTED lanes through ``ops/row_update``'s row kernel
-    (``ops/dedup._kernel_sums``) instead of ONE XLA scatter-add in the order
-    of the stream, read from what the spec holds, as
-    :func:`_set_kernel_takes` reads the write-back's arm: a TPU, a table in
-    one place or sharded over ``ps`` under one worker (every shard then sums
-    the keys it owns inside :func:`_push_rule_on_shards`' ``shard_map``,
-    where the kernel sees a plain array; ``dp`` > 1 keeps GSPMD's), a
-    rule, and rows wider than a sort carries (``ops/dedup.
-    _SORT_CARRIES_LANES``; a narrower row rides through the sort whatever
-    the backend), float32, that fit one 128-lane register or lie flat in
-    several (:func:`_flat_wide_rule`: the combine then sums the row, at
-    its own width, into whole registers through the TILE kernel, in the
-    order of the stream: ``ops/dedup._tile_sums``).  Static per compiled
-    step.  Such a store that the kernel REFUSES (bfloat16; a DENSE row of
-    more than 128 lanes) keeps the scatter-add, counted and warned of once.
-    On the v5e the scatter-add is 146 ns a 36-lane row, serial; the permute
-    of whole-register rows is 8-10 ns a row and the kernel ~0.6 us a block
-    of 256 lanes + 10-13 ns a row it writes (PERF.md section 6, PRs 46, 54)."""
-    from ..ops import dedup
-
-    if (spec.update == "add" or not _rule_sees_one_block(spec)
-            or jax.default_backend() != "tpu"
-            or spec.row_width <= dedup._SORT_CARRIES_LANES):
-        return False
-    width = spec.table_shape()[1] if _flat_wide_rule(spec) else spec.row_width
-    return _taken_or_noted(
-        spec, "the sum of a rule's wide rows",
-        dedup.kernel_refusal(width, spec.dtype),
-    )
+def step_counts(
+    spec: StoreSpec, counted: Optional[dict], *, pull_lanes: int,
+    push_lanes: int,
+) -> dict:
+    """What a step hands out of its pull and its push beside the logic's
+    outputs: what :func:`push_counted` counted and, for a store packed
+    several rows to a physical row, which arm sliced the pulled rows
+    (``ps_slice_kernel``) and, where ``update`` is ``"add"``, which shifted
+    the pushed deltas (``ps_shift_kernel``), 1 for the kernel, as this
+    trace read them (:func:`arms`)."""
+    out = dict(counted or {})
+    if spec.pack > 1:
+        arm = arms(spec, pull_lanes=pull_lanes, push_lanes=push_lanes)
+        out["ps_slice_kernel"] = jnp.asarray(
+            arm.pull == "packed_kernel", jnp.int32)
+        if arm.shift:
+            out["ps_shift_kernel"] = jnp.asarray(
+                arm.shift == "kernel", jnp.int32)
+    return out
 
 
-def _flat_wide_rule(spec: StoreSpec) -> bool:
-    """A rule store whose row lies FLAT in several whole 128-lane registers,
-    one logical row to a physical row (``layout="packed"`` at ``k`` = 1 and
-    more than 128 lanes: what ``"auto"`` gives a rule row of one axis over
-    128 lanes, GloVe's 602 in 640).  Its two kernel arms are the TILE
-    kernel's (eight rows to a tile: a row that wide cannot be written
-    alone), which takes the batch's rows at their own 602 lanes: the sums
-    land in a zeroed block of whole registers, the rule reads whole
-    physical rows, and the write-back stores a new row's own lanes."""
-    return (spec.update != "add" and spec.layout == "packed"
-            and spec.pack == 1 and spec.table_shape()[1] > 128)
-
-
-def _rule_sees_one_block(spec: StoreSpec) -> bool:
-    """Whether a rule store's push runs where a kernel sees a plain array:
-    in one place, or on the shards of a mesh with one worker
-    (:func:`_rule_on_shards_takes`, which also notes the mesh it refuses)."""
-    return spec.mesh is None or worker_count(spec.mesh) == 1
-
-
-def _rule_on_shards_takes(spec: StoreSpec) -> bool:
-    """Whether a rule store's push runs ON its shards
-    (:func:`_push_rule_on_shards`) instead of as one program for GSPMD to
-    partition, read from what the spec holds: a mesh with ONE worker, so
-    that every chip already holds the whole batch and each shard can take
-    the keys it owns with no exchange.  A mesh with ``dp`` > 1 holds the
-    batch's lanes split over its workers: a shard would first have to be
-    sent the other workers' keys, which no code here does, so such a store
-    keeps the one-place push under GSPMD (XLA's scatter-add and row
-    ``set``), counted and warned of once as every declined arm is."""
-    if spec.mesh is None:
-        return False
-    return _taken_or_noted(
-        spec, "a rule store's push on the shards that own its rows",
-        None if _rule_sees_one_block(spec) else "the batch lies split over "
-        f"dp = {worker_count(spec.mesh)} workers",
-    )
+def publish_counts(outs: dict, registry: Any, total, peak) -> None:
+    """The store's own outputs of a dispatch (:func:`step_counts`: every
+    ``ps_*`` key of ``outs``) as its ``store_*`` gauges, ``component=train``
+    (docs/observability.md is their catalog).  ``total`` and ``peak`` are
+    the caller's readings of one output over the steps a dispatch stacked:
+    a count's sum, a constant's maximum.  Whoever fetches a step's outputs
+    calls this where it fetches them anyway
+    (``StreamingDriver._publish_step_counts``).  The names are literal:
+    ``tools/fpsanalyze`` matches them to the catalog."""
+    if "ps_slice_kernel" in outs:
+        registry.gauge(
+            "store_packed_slice_kernel", component="train"
+        ).set(peak(outs["ps_slice_kernel"]))
+    if "ps_shift_kernel" in outs:
+        registry.gauge(
+            "store_packed_shift_kernel", component="train"
+        ).set(peak(outs["ps_shift_kernel"]))
+    if "ps_push_tile_rows" in outs:
+        registry.gauge(
+            "store_push_kernel_lanes", component="train"
+        ).set(total(outs["ps_push_kernel_lanes"]))
+        registry.gauge(
+            "store_push_tile_rows", component="train"
+        ).set(total(outs["ps_push_tile_rows"]))
+    if "ps_rule_rows" not in outs:
+        return
+    registry.gauge("store_rule_keys", component="train").set(
+        total(outs["ps_rule_keys"]))
+    registry.gauge("store_rule_rows", component="train").set(
+        total(outs["ps_rule_rows"]))
+    registry.gauge("store_rule_tiles", component="train").set(
+        total(outs["ps_rule_tiles"]))
+    if "ps_rule_rows_max_shard" in outs:
+        registry.gauge(
+            "store_rule_keys_max_shard", component="train"
+        ).set(total(outs["ps_rule_keys_max_shard"]))
+        registry.gauge(
+            "store_rule_rows_max_shard", component="train"
+        ).set(total(outs["ps_rule_rows_max_shard"]))
+    if "ps_combine_kernel_lanes" in outs:
+        registry.gauge(
+            "store_combine_kernel_lanes", component="train"
+        ).set(total(outs["ps_combine_kernel_lanes"]))
+        registry.gauge(
+            "store_combine_kernel_writes", component="train"
+        ).set(total(outs["ps_combine_kernel_writes"]))
+    if "ps_rule_packed_rows" in outs:
+        registry.gauge(
+            "store_rule_packed_rows", component="train"
+        ).set(total(outs["ps_rule_packed_rows"]))
 
 
 def _push_rule_on_shards(
@@ -1036,6 +1089,7 @@ def _push_rule_on_shards(
     flat_ids: Array,
     flat_deltas: Array,
     live: Optional[Array],
+    arm: Arms,
 ) -> Tuple[Array, dict]:
     """:func:`_push_rule` for a table sharded over ``ps`` under one worker:
     the reference's servers, each running ``paramUpdate`` on the rows it
@@ -1046,9 +1100,8 @@ def _push_rule_on_shards(
     is to the one-place push, and it runs that push on its own block: its
     own keys sorted and summed, its own distinct rows read, ruled and
     written, in the arms a store of that block in one place would get
-    (:func:`_combine_kernel_takes`, :func:`_set_kernel_takes`: Mosaic's
-    calls cannot be partitioned, and inside the ``shard_map`` they see a
-    plain array).  No key is sent anywhere and no row leaves its chip; every
+    (``arm``: Mosaic's calls cannot be partitioned, and inside the
+    ``shard_map`` they see a plain array).  No key is sent anywhere and no row leaves its chip; every
     chip still walks all the batch's lanes in its sort (routing a key to
     its owner alone: ROADMAP S9 (2)).
 
@@ -1066,7 +1119,7 @@ def _push_rule_on_shards(
         # another shard's row, or the sentinel of a dropped lane
         rel = jnp.where((rel >= 0) & (rel < block), rel, block)
         table, counted = _push_rule(
-            spec, table, rel, deltas, mask[0] if mask else None, block)
+            spec, table, rel, deltas, mask[0] if mask else None, arm, block)
         names = sorted(counted)
         by_shard = jax.lax.all_gather(
             jnp.stack([counted[k] for k in names]), ps)
@@ -1083,20 +1136,6 @@ def _push_rule_on_shards(
         out_specs=(rows, P()),
         check_vma=False,  # a Pallas call states no varying axes
     )(table, flat_ids, flat_deltas, *masks)
-
-
-def _worker_reduce_takes(spec: StoreSpec, lanes: int) -> bool:
-    """Whether an ``add`` batch whose lanes lie split over ``dp`` workers (the
-    caller says so: ``push_counted(lanes_over_workers=True)``) is summed
-    worker by worker (:func:`_push_add_over_workers`), read from what the
-    spec and the batch hold: a mesh with more than one worker, lanes
-    that split evenly over them, and a shard of the table with no more rows
-    than the batch has lanes, so that the per-worker sums the reduce moves
-    are no larger than the deltas it would move else.  Static per compiled
-    step.  Every other store under a mesh leaves the scatter to GSPMD."""
-    workers = worker_count(spec.mesh)
-    return (workers > 1 and lanes % workers == 0
-            and spec.rows_per_shard <= lanes)
 
 
 def _push_add_over_workers(
@@ -1138,68 +1177,6 @@ def _push_add_over_workers(
     return table + total
 
 
-def _slice_kernel_takes(spec: StoreSpec, n: Optional[int] = None) -> bool:
-    """Whether the packed pull of ``n`` ids slices the rows it gathered
-    through ``ops/packed.sub_row_slice_kernel`` instead of XLA's selects,
-    read from what the spec and the batch hold, as
-    :func:`_tile_kernel_takes` reads the push's arm: a TPU, rows packed
-    several to a physical row, float32, no mesh or a table sharded over it
-    (each shard then slices what it gathered inside a ``shard_map``, where
-    the kernel sees a plain array; a mesh with one shard leaves the pull to
-    GSPMD), and a block or more of ids to a shard.  Static per compiled
-    step.  Such a pull that the kernel REFUSES (bfloat16; an eager ``pull``
-    of a few thousand rows, which then compiles no kernel) keeps the select
-    arm, counted and warned of once a row shape.  ``n`` None asks whether
-    ANY pull of the store may take the kernel
-    (:func:`_preload_tile_kernel`) and notes nothing."""
-    if n is not None and spec.mesh is not None:
-        # a shard slices the lanes of its worker (`_packed_pull_on_shards`)
-        workers = spec.mesh.size // spec.num_shards
-        n = n // workers if n % workers == 0 else n
-    return _lane_kernel_takes(spec, n, "the lane slice of a packed pull")
-
-
-def _lane_kernel_takes(spec: StoreSpec, n: Optional[int], what: str) -> bool:
-    """What :func:`_slice_kernel_takes` and :func:`_shift_kernel_takes`
-    both ask of the spec and of the ``n`` lanes a chip moves:
-    ``ops/packed``'s two kernels move the same rows, one way each."""
-    from ..ops import packed
-
-    if (jax.default_backend() != "tpu" or spec.pack == 1
-            or (spec.mesh is not None and spec.num_shards == 1)):
-        return False
-    why = packed.slice_refusal(
-        packed.SLICE_BLOCK if n is None else n, spec.dtype, spec.row_width)
-    return why is None if n is None else _taken_or_noted(spec, what, why)
-
-
-def _shift_kernel_takes(spec: StoreSpec, n: Optional[int] = None) -> bool:
-    """Whether the packed push of ``n`` lanes shifts its deltas to their
-    windows through ``ops/packed.lane_shift_kernel`` instead of XLA's pads
-    and selects, read from what the spec and the batch hold: an ``add``
-    store (a rule store's write-back shifts 32,768 rows a chunk and merges
-    them there: :func:`_rewrite_packed`) where the pull's slice, the
-    kernel's mirror, would take as many ids (:func:`_slice_kernel_takes`: a
-    TPU, rows packed several to a physical row, float32, no mesh or a table
-    sharded over it) and the batch has a block or more of lanes.  Static
-    per compiled step; a refusal (bfloat16, a short eager push) keeps the
-    selects, counted and warned of once a row shape."""
-    return spec.update == "add" and _lane_kernel_takes(
-        spec, n, "the lane shift of a packed push")
-
-
-def _preload_tile_kernel(spec: StoreSpec) -> None:
-    """Where a store is made whose pushes or pulls will trace a kernel: have
-    Pallas imported by then, beside the table's staging (the import is ~1 s
-    that the first trace of the step else pays).  The push's shift kernel
-    is taken only where the pull's slice is."""
-    if (_tile_kernel_takes(spec) or _set_kernel_takes(spec)
-            or _combine_kernel_takes(spec) or _slice_kernel_takes(spec)):
-        from ..ops.row_update import preload
-
-        preload()
-
-
 @functools.partial(jax.jit, static_argnums=(0, 3))
 def _packed_pull_on_shards(
     spec: StoreSpec, table: Array, ids: Array, kernel: bool = False
@@ -1216,7 +1193,7 @@ def _packed_pull_on_shards(
     them, row-major and padded to 128 lanes again on the TPU (16.7 ms a
     step against 5-8; PERF.md section 6, PR 31).  The ids stay split over
     the mesh's other axes (``dp``) where the batch is.  ``kernel``: the
-    slice's arm (:func:`_slice_kernel_takes`).  Jitted for the reason
+    slice's arm (:func:`arms`).  Jitted for the reason
     ``packed_pull`` is."""
     from ..ops.packed import sub_row_slice
 
@@ -1673,5 +1650,9 @@ __all__ = [
     "pull",
     "push",
     "push_counted",
+    "Arms",
+    "arms",
+    "step_counts",
+    "publish_counts",
     "zeros_init",
 ]

@@ -334,18 +334,11 @@ def make_train_step(logic: BatchedWorkerLogic, spec) -> Callable:
                 spec, table, req.ids, req.deltas, req.mask,
                 lanes_over_workers=lanes is not None,
             )
-        if counted is not None and isinstance(out, dict):
-            # a rule store's push counts its live keys and distinct rows on
-            # the device; they leave the step with the logic's outputs
-            out = {**out, **counted}
-        if spec.pack > 1 and isinstance(out, dict):
-            # which arm sliced the pulled rows, as this trace read it
-            took = store_mod._slice_kernel_takes(spec, ids.size)
-            out = {**out, "ps_slice_kernel": jnp.asarray(took, jnp.int32)}
-            if spec.update == "add":
-                # ... and which arm shifted the pushed deltas to their lanes
-                took = store_mod._shift_kernel_takes(spec, req.ids.size)
-                out = {**out, "ps_shift_kernel": jnp.asarray(took, jnp.int32)}
+        if isinstance(out, dict):
+            # what the store counted on the device and which arms this
+            # trace read leave the step with the logic's outputs
+            out = {**out, **store_mod.step_counts(
+                spec, counted, pull_lanes=ids.size, push_lanes=req.ids.size)}
         return table, state, out
 
     return step

@@ -200,6 +200,14 @@ class DiFacto(BatchedWorkerLogic):
         }
         return state, PushRequest(batch["ids"], deltas, mask), out
 
+    def publish_counts(self, outs, registry, total, peak) -> None:
+        # the live lanes of the dispatch's keys, and those whose embedding
+        # the gate let through
+        registry.gauge("fm_live_keys", component="train").set(
+            total(outs["fm_live_keys"]))
+        registry.gauge("fm_v_live_keys", component="train").set(
+            total(outs["fm_v_live_keys"]))
+
 
 def fresh_rows(
     config: DiFactoConfig, updater: DiFactoUpdater = DiFactoUpdater(), *,

@@ -225,6 +225,13 @@ class DLRM(BatchedWorkerLogic):
         mask = jnp.broadcast_to(live[:, None], batch["ids"].shape)
         return state, PushRequest(batch["ids"], deltas, mask), out
 
+    def publish_counts(self, outs, registry, total, peak) -> None:
+        # constants of the logic, so the newest step's
+        registry.gauge("dlrm_dense_params", component="train").set(
+            peak(outs["dlrm_dense_params"]))
+        registry.gauge("dlrm_dense_flops_per_step", component="train").set(
+            peak(outs["dlrm_dense_flops_per_step"]))
+
 
 def uniform_rows(config: DLRMConfig, *, seed=0, dtype=jnp.float32) -> InitFn:
     """The source's embedding init: a field's rows ``U(-sqrt(1 / C),

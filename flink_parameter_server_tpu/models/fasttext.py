@@ -145,6 +145,13 @@ class FastTextSkipGram(BatchedWorkerLogic):
         }
         return state, PushRequest(keys, deltas, live), out
 
+    def publish_counts(self, outs, registry, total, peak) -> None:
+        # the live lanes of the dispatch's keys and all of them
+        registry.gauge("bag_live_keys", component="train").set(
+            total(outs["bag_live_keys"]))
+        registry.gauge("bag_padded_keys", component="train").set(
+            total(outs["bag_padded_keys"]))
+
 
 def make_store(
     vocab_size: int,

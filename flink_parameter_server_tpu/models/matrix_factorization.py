@@ -174,6 +174,13 @@ class OnlineMatrixFactorization(BatchedWorkerLogic):
             registry=registry, tracer=tracer,
         )
 
+    def publish_counts(self, outs, registry, total, peak) -> None:
+        if "keyed_misrouted" in outs:
+            # keyed workers: the live records that reached a worker whose
+            # block lacks their row (0 behind the router)
+            registry.gauge("keyed_misrouted", component="train").set(
+                total(outs["keyed_misrouted"]))
+
     # -- BatchedWorkerLogic ------------------------------------------------
     def init_state(self, rng: Array) -> Array:
         init = ranged_random_factor(

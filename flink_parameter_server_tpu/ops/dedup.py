@@ -91,12 +91,13 @@ def occurrence_scale(
 
 
 # Rows up to this many lanes wide ride through a sort as operands of their
-# own, and their runs are summed along the sorted lanes.  Alone on the v5e,
+# own, and their runs are summed along the sorted lanes (`combine_runs`' arm
+# "sort"; `core/store.arms` reads this, nothing here does).  Alone on the v5e,
 # 1,277,952 lanes (PERF.md section 6, PR 34): carried, rows of 1 / 3 / 4
 # lanes sort in 2.8 / 3.5 / 4.0 ms; at 8 lanes it is 6.8, and the carrying
 # sort takes 96 s to compile (40 s at 3 lanes).  Wider rows are neither
 # sorted nor permuted: `_wide_runs` scatter-adds them where they lie.
-_SORT_CARRIES_LANES = 4
+SORT_CARRIES_LANES = 4
 
 
 def _sorted_by_id(ids: Array, rows: Array) -> Tuple[Array, Array]:
@@ -107,28 +108,29 @@ def _sorted_by_id(ids: Array, rows: Array) -> Tuple[Array, Array]:
 
 
 def combine_runs(
-    ids: Array, vals: Array, sentinel: int, *, kernel: bool = False,
-    interpret: Optional[bool] = None,
+    ids: Array, vals: Array, sentinel: int, arm: str,
+    *, interpret: Optional[bool] = None,
 ) -> Tuple[Array, Array, Optional[Array]]:
     """Sum the rows of ``vals`` (n, w) that share an id: ``(row_ids, sums,
     writes)``, the first two of the batch's static length.  The distinct
     ids come first, in
     ascending order, each with its run's total; the rest of ``row_ids`` is
     ``sentinel`` (an id no row has, and larger than any: lanes to drop carry
-    it coming in).  A run of any length costs what the batch does.  Which
-    form runs goes with the row's WIDTH, and for a wide row with what the
-    caller has read from where it runs (``core/store._combine_kernel_takes``):
+    it coming in).  A run of any length costs what the batch does.  ``arm``
+    names the form that runs; which one a store's push gets is
+    ``core/store.arms``' to say (its ``combine`` field), nothing here tests
+    a width:
 
-    - rows of at most ``_SORT_CARRIES_LANES`` lanes, everywhere: one sort
+    - ``"sort"`` (rows a sort carries): one sort
       that carries the values, ``log2 n`` shifted adds (a segmented prefix
       sum by doubling, which sums each run as a balanced tree, the batch's
       lanes along the minor axis), and a second sort that moves the lanes
       ending a run to the front;
-    - wider rows, ``kernel`` false (the CPU, a mesh, bfloat16): :func:`
-      _wide_runs`' ONE scatter-add in the order of the stream,
+    - ``"scatter_add"`` (wider rows off a TPU, under ``dp`` > 1, bfloat16):
+      :func:`_wide_runs`' ONE scatter-add in the order of the stream,
       ``np.add.at``'s own additions, into a zeroed ``(n, w)`` block: the
       sums come back ``w`` lanes wide;
-    - wider rows, ``kernel`` true, more than 128 lanes (a TPU, float32: a
+    - ``"tile_kernel"`` (float32 rows of more than 128 lanes: a
       rule store's flat wide row, at the width the push holds it, GloVe's
       602): the rows permuted once into sorted order and added, ``w`` lanes
       a row, into a zeroed block of whole registers by the TILE kernel, in
@@ -137,7 +139,7 @@ def combine_runs(
       to 128 lanes (640), zeros past ``w``: :func:`kernel_refusal` is
       asked about ``W``, and ``core/store._rewrite_packed`` slices these
       sums down to the row's own ``w`` lanes (the arm above hands it ``w``);
-    - wider rows, ``kernel`` true (a TPU, float32, at most 128 lanes): the
+    - ``"row_kernel"`` (float32 rows of at most 128 lanes): the
       rows permuted ONCE into sorted order at 128 lanes and their runs summed
       by the row kernel of ``ops/row_update`` (:func:`_kernel_sums`): on
       the v5e a serial scatter-add is 146 ns a 36-lane row, the permute of
@@ -146,15 +148,17 @@ def combine_runs(
       29.9 ms: PERF.md section 6, PR 46; 25.2 since the walk pays by the
       row it writes and not by the lane: PR 54).
 
-    The third value, for rows wider than a sort carries: the single-row
+    The third value, for every arm but ``"sort"``: the single-row
     DMAs the row kernel issued over the stretches it walked, an int32
     scalar on the device (0 where the scatter-add summed the rows); ``None``
-    for a narrow row, which has no such arm.  ``interpret`` is the kernel's
+    for ``"sort"``, which has no such arm.  ``interpret`` is the kernel's
     (None: by the default backend)."""
     n, w = vals.shape
-    if w > _SORT_CARRIES_LANES:
+    if arm != "sort":
+        sums = {"scatter_add": None, "row_kernel": _kernel_sums,
+                "tile_kernel": _tile_sums}[arm]
         return _wide_runs(
-            ids.astype(jnp.int32), vals, sentinel, kernel, interpret)
+            ids.astype(jnp.int32), vals, sentinel, sums, interpret)
     ids, rows = _sorted_by_id(ids.astype(jnp.int32), vals)
     cols = rows.T
     d = 1
@@ -183,7 +187,7 @@ def kernel_refusal(width: int, dtype) -> Optional[str]:
 
 
 def _wide_runs(
-    ids: Array, vals: Array, sentinel: int, kernel: bool,
+    ids: Array, vals: Array, sentinel: int, kernel_sums,
     interpret: Optional[bool],
 ) -> Tuple[Array, Array, Array]:
     """:func:`combine_runs` for rows wider than a sort carries.  One sort of
@@ -192,12 +196,12 @@ def _wide_runs(
     one int32 vector); a sort of the run starts' ids moves the distinct ids
     to the front, where their slots are.  Then the sums, in one of two forms.
 
-    ``kernel`` false: the rows are never permuted.  A second sort on the
+    ``kernel_sums`` None: the rows are never permuted.  A second sort on the
     carried positions brings the slots back to stream order, and ONE
     scatter-add of the rows into a zeroed ``(n, w)`` block sums every run in
     the order of the stream, float32 addition by addition what
-    ``np.add.at`` does.  ``kernel`` true: the rows go to the slots, not the
-    slots to the rows (:func:`_kernel_sums`).
+    ``np.add.at`` does.  Else the rows go to the slots, not the slots to the
+    rows (``kernel_sums``: :func:`_kernel_sums` or :func:`_tile_sums`).
 
     The first form here sorted the ids, permuted the rows by one gather,
     summed the runs by the shifted adds above on ``(w, n)`` and permuted
@@ -218,11 +222,10 @@ def _wide_runs(
     while d < n:
         rank = rank + jnp.pad(rank[:-d], (d, 0))
         d *= 2
-    if kernel:
+    if kernel_sums is not None:
         # the lanes to drop sort last: the kernel writes no row for them
         slot = jnp.where(sorted_ids < sentinel, rank - 1, _INT32_MAX)
-        sums, issued = (_kernel_sums if w <= 128 else _tile_sums)(
-            order, slot, vals, interpret)
+        sums, issued = kernel_sums(order, slot, vals, interpret)
     else:
         # (the positions are distinct: nothing for a stable sort to keep)
         _, slot = jax.lax.sort((order, rank - 1), num_keys=1, is_stable=False)

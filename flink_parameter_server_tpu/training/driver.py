@@ -25,7 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core.batched import BatchedWorkerLogic
-from ..core.store import ShardedParamStore
+from ..core.store import ShardedParamStore, publish_counts
 from ..core.transform import (
     TransformResult,
     jit_train_steps,
@@ -74,6 +74,16 @@ def _all_finite(*trees) -> jax.Array:
             ):
                 ok = jnp.logical_and(ok, jnp.isfinite(leaf).all())
     return ok
+
+
+# One output of a dispatch, read over the steps a scanned dispatch stacked:
+# a count is their sum, a constant of the step their maximum.
+def _total(x) -> float:
+    return float(np.sum(np.asarray(x)))
+
+
+def _peak(x) -> float:
+    return float(np.max(np.asarray(x)))
 
 
 @dataclasses.dataclass
@@ -369,131 +379,16 @@ class StreamingDriver:
 
     def _publish_step_counts(self, outs) -> None:
         """What the step counted on the device in the dispatch ``outs``
-        came from, as gauges: an add store's push through the tile kernel
-        (``core/store.push_counted``: the lanes it kept and the tile rows it
-        read and wrote: ``store_push_kernel_lanes``, ``store_push_tile_rows``),
-        a rule store's push (its live keys, the distinct rows its rule
-        rewrote and the tiles of
-        128 rows its write-back moved to do so: ``store_rule_keys``,
-        ``store_rule_rows``, ``store_rule_tiles``; for wide rows the lanes
-        the row kernel summed and the single-row DMAs it issued for them:
-        ``store_combine_kernel_lanes``, ``store_combine_kernel_writes``; where the
-        push ran on the shards that own the rows, the fullest shard's keys
-        and rows: ``store_rule_keys_max_shard``,
-        ``store_rule_rows_max_shard``) and a logic
-        of ragged key
-        bags (the live lanes of its keys and all of them: ``bag_live_keys``,
-        ``bag_padded_keys``), a gated factorisation machine (the live lanes
-        of its keys and those whose embedding the gate let through:
-        ``fm_live_keys``, ``fm_v_live_keys``), a logic with a dense net in its
-        state (its parameters and the model FLOPs of a step over them:
-        ``dlrm_dense_params``, ``dlrm_dense_flops_per_step``) and keyed workers (the live
-        records the last dispatch dropped because they reached the wrong worker:
-        ``keyed_misrouted``, 0 behind the router) and a store packed several
-        rows to a physical row (whether the step's pull took the slice
-        kernel, and an ``add`` push the shift kernel:
-        ``store_packed_slice_kernel``, ``store_packed_shift_kernel``).  A fetch of a few scalars, made only where the
-        outputs are fetched anyway: at the metrics cadence, which syncs the
-        step, and once after the loop has ended."""
+        came from, as gauges: each count is published by whoever made it,
+        the store its ``ps_*`` outputs (``core/store.publish_counts``), the
+        logic its own (``BatchedWorkerLogic.publish_counts``).  A fetch of
+        a few scalars, made only where the outputs are fetched anyway: at
+        the metrics cadence, which syncs the step, and once after the loop
+        has ended."""
         if self.registry is None or not isinstance(outs, dict):
             return
-
-        def total(x) -> float:  # a scanned dispatch stacks its steps' counts
-            return float(np.sum(np.asarray(x)))
-
-        # literal names: tools/fpsanalyze matches them to the docs' catalog
-        if "bag_live_keys" in outs:
-            # a logic of ragged key bags (models/fasttext.py) counts the
-            # live lanes of its keys and all of them, from its lane mask
-            self.registry.gauge("bag_live_keys", component="train").set(
-                total(outs["bag_live_keys"])
-            )
-            self.registry.gauge("bag_padded_keys", component="train").set(
-                total(outs["bag_padded_keys"])
-            )
-        if "fm_live_keys" in outs:
-            # a gated factorisation machine (models/difacto.py) counts the
-            # live lanes of its keys and those whose embedding its gate let
-            # through, from its own masks
-            self.registry.gauge("fm_live_keys", component="train").set(
-                total(outs["fm_live_keys"])
-            )
-            self.registry.gauge("fm_v_live_keys", component="train").set(
-                total(outs["fm_v_live_keys"])
-            )
-        if "dlrm_dense_params" in outs:
-            # a logic with a dense net in its state (models/dlrm.py) says
-            # how many parameters it holds there and the model FLOPs of a
-            # step over them: constants of the logic, so the last step's
-            self.registry.gauge("dlrm_dense_params", component="train").set(
-                float(np.max(np.asarray(outs["dlrm_dense_params"])))
-            )
-            self.registry.gauge(
-                "dlrm_dense_flops_per_step", component="train"
-            ).set(float(np.max(np.asarray(outs["dlrm_dense_flops_per_step"]))))
-        if "keyed_misrouted" in outs:
-            # keyed workers (models/matrix_factorization.py) count the live
-            # records that reached a worker whose block lacks their row
-            self.registry.gauge("keyed_misrouted", component="train").set(
-                total(outs["keyed_misrouted"])
-            )
-        if "ps_slice_kernel" in outs:
-            # a store of several rows to a physical row: whether the step's
-            # pull sliced them in ops/packed's kernel (core/transform.py)
-            self.registry.gauge(
-                "store_packed_slice_kernel", component="train"
-            ).set(float(np.max(np.asarray(outs["ps_slice_kernel"]))))
-        if "ps_shift_kernel" in outs:
-            # such a store whose update is "add": whether the step's push
-            # shifted its deltas to their lanes in ops/packed's other kernel
-            self.registry.gauge(
-                "store_packed_shift_kernel", component="train"
-            ).set(float(np.max(np.asarray(outs["ps_shift_kernel"]))))
-        if "ps_push_tile_rows" in outs:
-            # an add store whose push the tile kernel took: the lanes it
-            # kept and the tile rows it read and wrote for them
-            self.registry.gauge(
-                "store_push_kernel_lanes", component="train"
-            ).set(total(outs["ps_push_kernel_lanes"]))
-            self.registry.gauge(
-                "store_push_tile_rows", component="train"
-            ).set(total(outs["ps_push_tile_rows"]))
-        if "ps_rule_rows" not in outs:
-            return
-        self.registry.gauge("store_rule_keys", component="train").set(
-            total(outs["ps_rule_keys"])
-        )
-        self.registry.gauge("store_rule_rows", component="train").set(
-            total(outs["ps_rule_rows"])
-        )
-        self.registry.gauge("store_rule_tiles", component="train").set(
-            total(outs["ps_rule_tiles"])
-        )
-        if "ps_rule_rows_max_shard" in outs:
-            # a rule store whose push ran on its shards (one worker over
-            # `ps`): the live keys and the distinct rows of the fullest shard
-            self.registry.gauge(
-                "store_rule_keys_max_shard", component="train"
-            ).set(total(outs["ps_rule_keys_max_shard"]))
-            self.registry.gauge(
-                "store_rule_rows_max_shard", component="train"
-            ).set(total(outs["ps_rule_rows_max_shard"]))
-        if "ps_combine_kernel_lanes" in outs:
-            # a rule store of rows wider than a sort carries: the lanes the
-            # row kernel summed (0 where XLA's scatter-add summed them)
-            self.registry.gauge(
-                "store_combine_kernel_lanes", component="train"
-            ).set(total(outs["ps_combine_kernel_lanes"]))
-            # ... and the single-row DMAs the kernel issued to sum them:
-            # over the lanes, the share of the walk that writes
-            self.registry.gauge(
-                "store_combine_kernel_writes", component="train"
-            ).set(total(outs["ps_combine_kernel_writes"]))
-        if "ps_rule_packed_rows" in outs:
-            # a packed rule store: the physical rows its write-back wrote
-            self.registry.gauge(
-                "store_rule_packed_rows", component="train"
-            ).set(total(outs["ps_rule_packed_rows"]))
+        publish_counts(outs, self.registry, _total, _peak)
+        self.logic.publish_counts(outs, self.registry, _total, _peak)
 
     def run(
         self,

@@ -68,6 +68,40 @@ def rng():
     return np.random.default_rng(0)
 
 
+@pytest.fixture()
+def steer_arms(monkeypatch):
+    """The ONE way a test reaches an arm its backend would not get:
+    ``steer_arms(push="tile_add", write_back="row_set")`` replaces those
+    fields of the record the REAL ``core/store.arms`` reads from the spec
+    and the batch, for every store until the test ends (a kernel arm then
+    runs interpreted on the CPU).  An arm the store does not have stays as
+    read: an add store's ``combine`` and ``write_back``, a rule store's
+    ``push`` and ``shift``, a dense store's ``shift``, a row a sort carries
+    (``combine`` ``"sort"``: no other form takes such a row), a pull that is
+    not packed.  A value may be a function of the spec.  Calls stack."""
+    import dataclasses
+
+    from flink_parameter_server_tpu.core import store as store_mod
+
+    def steer(**fields):
+        real = store_mod.arms
+
+        def steered(spec, **lanes):
+            arm = real(spec, **lanes)
+            new = {}
+            for name, value in fields.items():
+                was = getattr(arm, name)
+                if isinstance(was, str) and (
+                        was in ("", "rule", "sort", "take", "narrow")):
+                    continue
+                new[name] = value(spec) if callable(value) else value
+            return dataclasses.replace(arm, **new)
+
+        monkeypatch.setattr(store_mod, "arms", steered)
+
+    return steer
+
+
 def pytest_collection_modifyitems(config, items):
     """Environment-gated marker skips.
 

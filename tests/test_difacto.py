@@ -436,7 +436,7 @@ def test_the_driver_sets_the_gate_s_gauges_after_the_loop():
 
 @pytest.mark.parametrize("arm", ["xla", "row_kernel"])
 def test_the_driver_publishes_the_descriptors_the_combine_issued(
-        arm, monkeypatch):
+        arm, monkeypatch, steer_arms):
     """``store_combine_kernel_writes`` beside ``store_combine_kernel_lanes``:
     with the row kernel steered on (interpreted here) a DMA a distinct row
     of the last dispatch, up to a trip of eight a block, where the lanes are
@@ -445,8 +445,7 @@ def test_the_driver_publishes_the_descriptors_the_combine_issued(
     from flink_parameter_server_tpu.telemetry.registry import MetricsRegistry
 
     if arm == "row_kernel":
-        monkeypatch.setattr(
-            store_mod, "_combine_kernel_takes", lambda spec: True)
+        steer_arms(combine="row_kernel")
     rng = np.random.default_rng(54)
     ids = rng.integers(0, 100, (3, 128, 4))
     ids[:, :, 0] = 7  # an integer field's row: every example names it
@@ -515,7 +514,8 @@ def test_wide_rows_are_summed_in_stream_order_bit_for_bit(width, n):
     ids[: n // 3] = 11  # one hot row
     ids[rng.random(n) < 0.1] = sentinel  # lanes to drop
     vals = rng.normal(size=(n, width)).astype(np.float32)
-    row_ids, sums, _ = jax.jit(combine_runs, static_argnums=2)(ids, vals, sentinel)
+    row_ids, sums, _ = jax.jit(combine_runs, static_argnums=(2, 3))(
+        ids, vals, sentinel, "scatter_add")
     distinct = np.unique(ids[ids < sentinel])
     assert np.array_equal(np.asarray(row_ids)[: len(distinct)], distinct)
     assert (np.asarray(row_ids)[len(distinct):] == sentinel).all()
@@ -524,7 +524,7 @@ def test_wide_rows_are_summed_in_stream_order_bit_for_bit(width, n):
     assert np.array_equal(np.asarray(sums)[: len(distinct)], want[distinct])
 
 
-# The other side of that choice (``core/store._combine_kernel_takes``: a TPU,
+# The other side of that choice (``core/store.arms``' ``combine``: a TPU,
 # float32, rows of 5 to 128 lanes): the rows permuted once into sorted order
 # at 128 lanes and every run summed by ``ops/row_update``'s row kernel, block
 # by block on the MXU, NOT in the order of the stream.  Here the kernel is
@@ -561,7 +561,7 @@ def _kernel_traffic(kind, rng, n, sentinel):
 ])
 def test_wide_rows_are_summed_along_sorted_lanes_by_the_row_kernel(
         width, n, traffic, monkeypatch):
-    """``combine_runs(kernel=True)``: the distinct ids exactly as the other
+    """``combine_runs(..., "row_kernel")``: the distinct ids exactly as the other
     arm hands them out, and every run's total within 2 ulps of the sum of
     its addends' magnitudes of the float64 sum (a blocked float32 sum: not
     ``np.add.at``'s bits), nothing in the rows past the distinct ones."""
@@ -584,7 +584,7 @@ def test_wide_rows_are_summed_along_sorted_lanes_by_the_row_kernel(
         nan_at, vals[lane, 2] = (11, 2), np.nan
         vals[ids == sentinel] = np.nan  # a dropped lane's reaches nothing
     row_ids, sums, sent = jax.jit(
-        lambda i, v: combine_runs(i, v, sentinel, kernel=True, interpret=True)
+        lambda i, v: combine_runs(i, v, sentinel, "row_kernel", interpret=True)
     )(ids, vals)
     # one traced kernel under a loop, whole blocks, no more lanes than a call holds
     assert len(calls) == 1 and calls[0] <= 512 and calls[0] % 256 == 0

@@ -267,9 +267,9 @@ def test_the_dict_state_goes_through_the_driver_its_gauges_and_a_checkpoint(tmp_
 
 @pytest.mark.parametrize("dim, pack", [(64, 2), (128, 1)])
 def test_the_step_through_the_one_register_tile_kernel_is_xlas_and_the_driver_says_so(
-        dim, pack, monkeypatch):
-    """Cell 10's arm since PR 49 (``core/store._tile_kernel_takes(spec,
-    lanes)``: a TPU, a table eight batches long or more), steered here and
+        dim, pack, monkeypatch, steer_arms):
+    """Cell 10's arm since PR 49 (``core/store.arms``' ``push``
+    ``"tile_add"``: a TPU, a table eight batches long or more), steered here and
     interpreted: the driver's run leaves the table and the MLPs XLA's arm
     leaves, bit for bit, and sets ``store_push_kernel_lanes`` /
     ``store_push_tile_rows`` from the last dispatch (what
@@ -295,8 +295,7 @@ def test_the_step_through_the_one_register_tile_kernel_is_xlas_and_the_driver_sa
     want, gauges = ran()
     assert "store_push_tile_rows" not in gauges
     assert "store_push_kernel_lanes" not in gauges
-    monkeypatch.setattr(
-        store_mod, "_tile_kernel_takes", lambda spec, lanes=None: True)
+    steer_arms(push="tile_add")
     got, gauges = ran()
     assert np.array_equal(
         np.asarray(got.store.table).view(np.uint32),

@@ -88,14 +88,13 @@ def test_the_step_is_the_reference_on_parameters_and_accumulators(seed, layout):
 
 
 @pytest.mark.parametrize("arm", ["xla", "tile_kernels"])
-def test_a_masked_record_changes_nothing(arm, monkeypatch):
+def test_a_masked_record_changes_nothing(arm, monkeypatch, steer_arms):
     """... whatever its gradient holds: the push hands a masked lane's delta
     on as it is (no pass zeroes the pushed block since PR 57), so a masked
     record of count 0 (``ln 0``: a row of -inf and NaN gradients) must reach
     no kept row, in the scatter-add's sums and in the tile kernel's."""
     if arm == "tile_kernels":
-        monkeypatch.setattr(store_mod, "_set_kernel_takes", lambda spec: True)
-        monkeypatch.setattr(store_mod, "_combine_kernel_takes", lambda spec: True)
+        steer_arms(combine="tile_kernel", write_back="tile_assign")
     store = gl.make_store(MODEL, seed=3)
     (b,) = _batches(3, n=1)
     step = jax.jit(make_train_step(gl.GloVe(MODEL), store.spec))
@@ -169,7 +168,7 @@ def test_make_store_is_a_rule_store_flat_in_whole_registers():
     store = jax.jit(lambda s: gl.make_store(MODEL, seed=s))(np.uint32(9))
     assert store.spec.layout == "packed" and store.spec.pack == 1
     assert store.table.shape == (2 * VOCAB, 384)  # 302 lanes in three registers
-    assert store_mod._flat_wide_rule(store.spec)
+    assert store_mod.arms(store.spec).push == "rule"
     values = np.asarray(store.values())
     p = MODEL.params
     assert values.shape == (2 * VOCAB, 2 * p)

@@ -358,7 +358,10 @@ def test_push_over_workers_matches_a_plain_scatter_add(mesh_devices, shape, layo
     ids[:40] = 5
     deltas = rng.normal(size=(256, dim)).astype(np.float32)
     mask = rng.random(256) < 0.9
-    assert store_mod._worker_reduce_takes(store.spec, 256)
+    assert store_mod.arms(
+        store.spec, push_lanes=256, lanes_over_workers=True
+    ).push == "worker_reduce"
+    assert store_mod.arms(store.spec, push_lanes=256).push == "xla_add"
     got = jax.jit(lambda t, i, d, m: store_mod.push_counted(
         store.spec, t, i, d, m, lanes_over_workers=True)[0])(
         store.table, ids, deltas, mask
@@ -388,4 +391,5 @@ def test_the_worker_reduce_is_read_from_mesh_table_and_batch(
         mesh_devices, shape, capacity, lanes, want):
     mesh = shape and make_mesh(*shape, devices=mesh_devices[: shape[0] * shape[1]])
     spec = store_mod.StoreSpec(capacity, (DIM,), mesh=mesh)
-    assert store_mod._worker_reduce_takes(spec, lanes) == want
+    arm = store_mod.arms(spec, push_lanes=lanes, lanes_over_workers=True)
+    assert arm.push == ("worker_reduce" if want else "xla_add")

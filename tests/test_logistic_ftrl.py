@@ -294,7 +294,7 @@ def test_the_driver_publishes_what_the_push_counted():
 
 @pytest.mark.parametrize("arm", ["xla", "set_kernel"])
 def test_the_store_holds_w_z_n_at_four_lanes_and_the_step_is_the_same(
-        arm, monkeypatch):
+        arm, monkeypatch, steer_arms):
     """``make_store``'s physical row is four lanes (the fourth zero, never
     read by the rule, stripped by ``pull`` and ``values()``); a checkpoint
     of it holds logical rows and restores onto the same table; the step
@@ -313,7 +313,7 @@ def test_the_store_holds_w_z_n_at_four_lanes_and_the_step_is_the_same(
     want, want_outs = _run_batches(store, batches)
     assert all(int(o["ps_rule_tiles"]) == 0 for o in want_outs)
     if arm == "set_kernel":
-        monkeypatch.setattr(store_mod, "_set_kernel_takes", lambda spec: True)
+        steer_arms(write_back="tile_set")
         got, outs = _run_batches(store, batches)
         np.testing.assert_array_equal(
             np.asarray(got.table), np.asarray(want.table))

@@ -190,7 +190,7 @@ def test_the_shift_kernel_equals_the_select_arm_bit_for_bit(d, n, masked):
 @pytest.mark.parametrize("n,kernel", [(255, False), (256, True), (300, True)])
 def test_a_pull_under_one_block_keeps_the_select_arm(n, kernel, monkeypatch):
     """The arm is read from the backend, the dtype, ``k`` and ``n``
-    (``core/store._slice_kernel_takes``): on a TPU a float32 pull of a
+    (``core/store.arms``' ``pull``): on a TPU a float32 pull of a
     block or more of 17-lane rows takes the kernel, a shorter one (an eager
     read-back) traces none and is noted once; here the backend is steered
     and the kernel's call recorded."""
@@ -202,7 +202,8 @@ def test_a_pull_under_one_block_keeps_the_select_arm(n, kernel, monkeypatch):
         1000, (17,), init_fn=_rand_init(17), layout="packed")
     ids = jnp.asarray(np.random.default_rng(n).integers(0, 1000, n), jnp.int32)
     want = np.asarray(store.pull(ids))
-    assert not store_mod._slice_kernel_takes(store.spec, n)  # this is a CPU
+    assert store_mod.arms(
+        store.spec, pull_lanes=n).pull == "packed_selects"  # this is a CPU
     calls = []
     real = packed_mod.sub_row_slice_kernel
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -225,7 +226,8 @@ def test_a_pull_under_one_block_keeps_the_select_arm(n, kernel, monkeypatch):
 
 
 @pytest.mark.parametrize("arm", ["select", "kernel"])
-def test_the_driver_says_which_arm_sliced_its_pulled_rows(arm, monkeypatch):
+def test_the_driver_says_which_arm_sliced_its_pulled_rows(arm, monkeypatch,
+        steer_arms):
     """Where an operator reads it: the gauge ``store_packed_slice_kernel``,
     from the scalar the step of a packed store carries among its outputs
     (``ps_slice_kernel``: what its trace read), and beside it, for an
@@ -237,10 +239,7 @@ def test_the_driver_says_which_arm_sliced_its_pulled_rows(arm, monkeypatch):
         DriverConfig, StreamingDriver)
 
     if arm == "kernel":  # steered: this is a CPU, the kernels are interpreted
-        monkeypatch.setattr(
-            store_mod, "_slice_kernel_takes", lambda spec, n=None: True)
-        monkeypatch.setattr(
-            store_mod, "_shift_kernel_takes", lambda spec, n=None: True)
+        steer_arms(pull="packed_kernel", shift="kernel")
         monkeypatch.setattr(packed_mod, "SLICE_BLOCK", 64)
         packed_mod.packed_pull.clear_cache()
     cfg = fmm.FMConfig(num_features=500, dim=16)

@@ -25,6 +25,9 @@ TOP_LEVEL = [
 ]
 
 MODULE_SYMBOLS = {
+    "flink_parameter_server_tpu.core.store": [
+        "StoreSpec", "ShardedParamStore", "pull", "push", "push_counted",
+        "Arms", "arms", "step_counts", "publish_counts"],
     "flink_parameter_server_tpu.core.senders": ["SenderPolicy"],
     "flink_parameter_server_tpu.parallel.collectives": [
         "shard_pull", "shard_push_add"],
@@ -188,3 +191,88 @@ def test_module_symbols(module):
     mod = importlib.import_module(module)
     missing = [n for n in MODULE_SYMBOLS[module] if not hasattr(mod, n)]
     assert not missing, (module, missing)
+
+
+# -- the seam between the store and the two layers above it -------------------
+# Which arm a pull or a push takes is `core/store.arms`' to read, and a count
+# is published by whoever made it (`core/store.publish_counts`, a logic's
+# `publish_counts`): the step and the driver name neither.
+COUNTER_PREFIXES = ("ps_", "bag_", "fm_", "dlrm_", "keyed_")
+
+
+@pytest.mark.parametrize("module", ["training/driver.py", "core/transform.py"])
+def test_the_driver_and_the_step_name_no_counter_and_no_private_of_the_store(
+        module):
+    import ast
+    import os
+
+    import flink_parameter_server_tpu as fps
+
+    path = os.path.join(os.path.dirname(fps.__file__), module)
+    with open(path, encoding="utf-8") as f:
+        tree = ast.parse(f.read())
+    named = sorted({
+        node.value for node in ast.walk(tree)
+        if isinstance(node, ast.Constant) and isinstance(node.value, str)
+        and node.value.startswith(COUNTER_PREFIXES)
+        # (the reference's knob, an argument of `transform`: no counter)
+        and node.value != "ps_parallelism"})
+    assert not named, named
+    private = sorted({
+        node.attr for node in ast.walk(tree)
+        if isinstance(node, ast.Attribute) and node.attr.startswith("_")
+        and not node.attr.startswith("__")
+        and isinstance(node.value, ast.Name)
+        and node.value.id in ("store_mod", "store")})
+    assert not private, private
+    takes = sorted({
+        node.attr if isinstance(node, ast.Attribute) else node.id
+        for node in ast.walk(tree)
+        if isinstance(node, (ast.Attribute, ast.Name))
+        and (node.attr if isinstance(node, ast.Attribute) else node.id
+             ).endswith("_takes")})
+    assert not takes, takes
+
+
+@pytest.mark.parametrize("steps_per_call", [1, 2])
+def test_a_logic_defined_here_publishes_its_own_count_through_the_driver(
+        steps_per_call):
+    """A new family's counter costs its own file: this logic counts one
+    thing in its step, names its gauge in ``publish_counts``, and the
+    StreamingDriver sets it (the total over a scanned dispatch) with no
+    edit anywhere else."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from flink_parameter_server_tpu import (
+        BatchedWorkerLogic, DriverConfig, PushRequest, ShardedParamStore,
+        StreamingDriver)
+    from flink_parameter_server_tpu.telemetry.registry import MetricsRegistry
+
+    class Toy(BatchedWorkerLogic):
+        def init_state(self, rng):
+            return ()
+
+        def keys(self, batch):
+            return batch["ids"]
+
+        def step(self, state, batch, pulled):
+            out = {"toy_even_keys": jnp.sum(
+                batch["ids"] % 2 == 0, dtype=jnp.int32)}
+            return state, PushRequest(batch["ids"], jnp.ones_like(pulled)), out
+
+        def publish_counts(self, outs, registry, total, peak):
+            registry.gauge("toy_even_keys", component="train").set(
+                total(outs["toy_even_keys"]))
+
+    registry = MetricsRegistry()
+    batches = [{"ids": np.arange(i, i + 8, dtype=np.int32)} for i in (0, 3, 4, 9)]
+    driver = StreamingDriver(
+        Toy(), ShardedParamStore.create(64, (4,)), registry=registry,
+        config=DriverConfig(dump_model=False, steps_per_call=steps_per_call))
+    result = driver.run(iter(batches))
+    gauges = registry.snapshot()
+    # the newest dispatch: the last batch, or the last two of a scanned one
+    assert gauges["toy_even_keys"][0]["value"] == 4 * steps_per_call, gauges
+    table = np.asarray(result.store.values())
+    assert table[:17].sum() == 4 * 8 * 4 and not table[17:].any()

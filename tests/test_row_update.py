@@ -733,7 +733,9 @@ def test_push_takes_the_tile_kernel_from_what_the_spec_and_the_batch_hold(
     spec = _spec(shape, update=update, mesh=mesh, capacity=capacity)
     with warnings.catch_warnings():
         warnings.simplefilter("error")
-        assert store_mod._tile_kernel_takes(spec, lanes) == want
+        arm = store_mod.arms(spec, push_lanes=lanes)
+    assert (arm.push == "tile_add") == want
+    assert arm.push == ("rule" if update != "add" else arm.push)
     assert row_update.refusal_count() == n0
 
 
@@ -752,18 +754,23 @@ def test_a_store_the_tile_kernel_refuses_warns_once_and_counts(
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(store_mod, "_REFUSALS_NOTED", set())
     spec = _spec(shape, dtype, layout=layout, capacity=capacity)
+    # (the record is read whole: two bfloat16 rows to a register are also
+    # refused the lane shift of their push, noted once as the tile add is)
+    noted = 1 + (spec.pack > 1)
     n0 = row_update.refusal_count()
     with pytest.warns(RuntimeWarning, match="falling back") as caught:
-        assert not store_mod._tile_kernel_takes(spec, lanes)
-    assert reason in str(caught[0].message)
-    assert row_update.refusal_count() == n0 + 1
+        assert store_mod.arms(spec, push_lanes=lanes).push == "xla_add"
+    assert len(caught) == noted and reason in str(caught[0].message)
+    assert "push into a table of" in str(caught[0].message)
+    assert row_update.refusal_count() == n0 + noted
     with warnings.catch_warnings():
         warnings.simplefilter("error")  # every later trace is silent
-        assert not store_mod._tile_kernel_takes(spec, lanes)
+        assert store_mod.arms(spec, push_lanes=lanes).push == "xla_add"
         if lanes is not None:
             # above the cut XLA's own sorted form takes it: nothing refused
-            assert not store_mod._tile_kernel_takes(spec, lanes + 1)
-    assert row_update.refusal_count() == n0 + 1
+            assert store_mod.arms(
+                spec, push_lanes=lanes + 1).push == "xla_add"
+    assert row_update.refusal_count() == n0 + noted
 
 
 @pytest.mark.parametrize("backend,shape,rows,started", [
@@ -1005,12 +1012,22 @@ def test_the_write_back_takes_the_set_kernel_from_what_the_spec_holds(
         mesh = make_mesh(worker_parallelism=dp, ps_parallelism=4 // dp,
                          devices=jax.devices()[:4])
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    monkeypatch.setattr(store_mod, "_REFUSALS_NOTED", set())
     n0 = row_update.refusal_count()
     spec = _spec(shape, dtype, update=update, mesh=mesh)
-    with warnings.catch_warnings():
-        warnings.simplefilter("error")
-        assert store_mod._set_kernel_takes(spec) == want
-    assert row_update.refusal_count() == n0
+    # (the record is read whole: under dp = 2 a rule store is refused the
+    # push on its shards, noted once; the write-back itself notes nothing)
+    noted = int(meshed is True and update != "add")
+    with warnings.catch_warnings(record=True) as caught:
+        warnings.simplefilter("always")
+        arm = store_mod.arms(spec)
+    assert (arm.write_back not in ("", "xla_set")) == want
+    if want:
+        assert arm.write_back == (
+            "tile_set" if shape[0] <= 8 else "row_set")
+    assert len(caught) == noted and all(
+        "dp = 2 workers" in str(w.message) for w in caught)
+    assert row_update.refusal_count() == n0 + noted
     if update != "add" and 8 < shape[0] <= 64:
         # several rows to a 128-lane physical row, whatever the backend
         assert spec.layout == "packed" and spec.tile_lanes == 0
@@ -1036,15 +1053,19 @@ def test_a_narrow_rule_store_the_kernel_refuses_warns_once_and_counts(
     monkeypatch.setattr(store_mod, "_REFUSALS_NOTED", set())
     spec = _spec(shape, dtype, update=_rule)
     assert spec.tile_lanes == 0  # held as it is, XLA's row set
+    # (the record is read whole: bfloat16 rows too wide for a sort are also
+    # refused the row kernel's sums, noted once as the write-back is)
+    noted = 1 + (shape == (36,))
     n0 = row_update.refusal_count()
     with pytest.warns(RuntimeWarning, match="falling back") as caught:
-        assert not store_mod._set_kernel_takes(spec)
-    assert reason in str(caught[0].message)
-    assert row_update.refusal_count() == n0 + 1
+        assert store_mod.arms(spec).write_back == "xla_set"
+    assert len(caught) == noted and reason in str(caught[0].message)
+    assert "write-back of a" in str(caught[0].message)
+    assert row_update.refusal_count() == n0 + noted
     with warnings.catch_warnings():
         warnings.simplefilter("error")  # every later trace is silent
-        assert not store_mod._set_kernel_takes(spec)
-    assert row_update.refusal_count() == n0 + 1
+        assert store_mod.arms(spec).write_back == "xla_set"
+    assert row_update.refusal_count() == n0 + noted
 
 
 @pytest.mark.parametrize("backend,shape,started", [
@@ -1054,7 +1075,7 @@ def test_a_narrow_rule_store_the_kernel_refuses_warns_once_and_counts(
 def test_a_rule_store_whose_pushes_will_trace_the_kernel_starts_the_pallas_import(
         monkeypatch, backend, shape, started):
     """Three lanes: the set kernel writes the rows back.  Nine: the row
-    kernel sums them (``core/store._combine_kernel_takes``, PR 46; no kernel
+    kernel sums them (``core/store.arms``' ``combine``, PR 46; no kernel
     took such a store before).  Two hundred, held DENSE as ``create`` holds a
     store by default: neither, and a warning says so (``layout="auto"`` lays
     such a row flat in two registers since PR 55, and the tile kernel takes
@@ -1191,10 +1212,11 @@ def test_wide_rows_are_summed_by_the_tile_kernel_in_stream_order_bit_for_bit(
     ids[: n // 4] = 7
     vals = (rng.normal(size=(n, width)) * 10.0 ** rng.integers(-3, 4, (n, 1))
             ).astype(np.float32)
-    want_ids, want, zero = jax.jit(dedup.combine_runs, static_argnums=2)(
-        ids, vals, sentinel)
+    want_ids, want, zero = jax.jit(dedup.combine_runs, static_argnums=(2, 3))(
+        ids, vals, sentinel, "scatter_add")
     got_ids, got, opened = jax.jit(
-        lambda i, v: dedup.combine_runs(i, v, sentinel, kernel=True, interpret=True)
+        lambda i, v: dedup.combine_runs(
+            i, v, sentinel, "tile_kernel", interpret=True)
     )(ids, vals)
     assert np.array_equal(np.asarray(got_ids), np.asarray(want_ids))
     distinct = len(np.unique(ids[ids < sentinel]))

@@ -329,7 +329,7 @@ def test_push_pull_case_table(layout, width, traffic):
 # is interpreted; both arms are held to the same reference.
 # Rows of ONE register (a dense 128-lane row, 64-lane rows two to a physical
 # row) take the same arm where the batch is short against the table
-# (``_tile_kernel_takes(spec, lanes)``: the rule's edges are
+# (``core/store.arms``' ``push``: the rule's edges are
 # ``tests/test_row_update.py``'s); steered here, they are two more rows of the
 # case table.
 WIDE_ROWS = [(256,), (300,), (640,), (2, 300), (600,), (128,), (64,)]
@@ -360,7 +360,8 @@ def _wide_traffic(kind, rng, cap, shape):
 @pytest.mark.parametrize("traffic", WIDE_TRAFFIC)
 @pytest.mark.parametrize("shape", WIDE_ROWS, ids=str)
 @pytest.mark.parametrize("arm", ["xla", "tile_kernel"])
-def test_push_pull_case_table_wide_rows(arm, shape, traffic, monkeypatch):
+def test_push_pull_case_table_wide_rows(arm, shape, traffic, monkeypatch,
+        steer_arms):
     from flink_parameter_server_tpu.core import store as store_mod
     from flink_parameter_server_tpu.ops import row_update
 
@@ -376,9 +377,8 @@ def test_push_pull_case_table_wide_rows(arm, shape, traffic, monkeypatch):
         64 // store.spec.pack, -(-int(np.prod(shape)) // 128) * 128)
     push = _push
     if arm == "tile_kernel":
-        assert not store_mod._tile_kernel_takes(store.spec)  # this is a CPU
-        monkeypatch.setattr(
-            store_mod, "_tile_kernel_takes", lambda spec, lanes=None: True)
+        assert store_mod.arms(store.spec).push == "xla_add"  # this is a CPU
+        steer_arms(push="tile_add")
         monkeypatch.setattr(row_update, "MAX_LANES", 512)
         calls, handed = [], set()
         real = row_update._sorted_tile_add_counted
@@ -454,7 +454,7 @@ def _one_register_traffic(kind, rng, cap, width):
 @pytest.mark.parametrize("traffic", ONE_REGISTER_TRAFFIC)
 @pytest.mark.parametrize("width", [128, 64])
 def test_one_register_push_through_the_tile_kernel_is_xlas_bit_for_bit(
-        width, traffic, monkeypatch):
+        width, traffic, monkeypatch, steer_arms):
     from flink_parameter_server_tpu.core import store as store_mod
     from flink_parameter_server_tpu.ops import row_update
 
@@ -470,8 +470,7 @@ def test_one_register_push_through_the_tile_kernel_is_xlas_bit_for_bit(
             None if mask is None else jnp.asarray(mask))
     want, counted = store_mod.push_counted(store.spec, store.table, *args)
     assert counted is None  # XLA's arm counts nothing
-    monkeypatch.setattr(
-        store_mod, "_tile_kernel_takes", lambda spec, lanes=None: True)
+    steer_arms(push="tile_add")
     monkeypatch.setattr(row_update, "MAX_LANES", 512)
     got, counted = store_mod.push_counted(store.spec, store.table, *args)
     np.testing.assert_array_equal(
@@ -507,10 +506,19 @@ def _add_rule(current, combined):
     return current + combined
 
 
+def _rule_arms(spec):
+    """``(combine, write_back)`` of the record ``core/store.arms`` reads."""
+    from flink_parameter_server_tpu.core import store as store_mod
+
+    arm = store_mod.arms(spec)
+    return arm.combine, arm.write_back
+
+
 @pytest.mark.parametrize("traffic", WIDE_RULE_TRAFFIC)
 @pytest.mark.parametrize("shape", [(5,), (36,), (128,), (2, 9)], ids=str)
 @pytest.mark.parametrize("arm", ["xla", "row_kernel"])
-def test_push_pull_case_table_wide_rule_rows(arm, shape, traffic, monkeypatch):
+def test_push_pull_case_table_wide_rule_rows(arm, shape, traffic, monkeypatch,
+        steer_arms):
     from flink_parameter_server_tpu.core import store as store_mod
     from flink_parameter_server_tpu.ops import row_update
 
@@ -522,12 +530,11 @@ def test_push_pull_case_table_wide_rule_rows(arm, shape, traffic, monkeypatch):
     # (a row of 5 lanes is held at 8, the set kernel's tile: its sums go
     # through the wide arm all the same)
     assert store.spec.layout == "dense"
-    assert not store_mod._combine_kernel_takes(store.spec)  # this is a CPU
+    assert store_mod.arms(store.spec).combine == "scatter_add"  # a CPU
     ids, deltas, mask = _wide_traffic(traffic, rng, CAP, shape)
     calls = []
     if arm == "row_kernel":
-        monkeypatch.setattr(
-            store_mod, "_combine_kernel_takes", lambda spec: True)
+        steer_arms(combine="row_kernel")
         monkeypatch.setattr(row_update, "MAX_LANES", 512)
         real = row_update.sorted_row_update_counted
         monkeypatch.setattr(
@@ -576,21 +583,29 @@ def test_the_combine_kernel_arm_says_no(
     spec = spec_of(shape, dtype, on=mesh if meshed else None)
     monkeypatch.setattr(store_mod, "_REFUSALS_NOTED", set())
     n0 = row_update.refusal_count()
-    assert not store_mod._combine_kernel_takes(spec)  # this is a CPU
+    kept = "sort" if int(np.prod(shape)) <= 4 else "scatter_add"
+    with warnings.catch_warnings():
+        # (the record is read whole: a mesh of two workers is refused the
+        # push on its shards, on any backend, noted once as every refusal)
+        warnings.simplefilter("ignore")
+        assert store_mod.arms(spec).combine == kept  # this is a CPU
+    assert row_update.refusal_count() == n0 + meshed
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     if why == "off_the_tpu":
         for takes in (spec, spec_of((5,), dtype), spec_of((128,), dtype),
                       spec_of((2, 9), dtype)):
-            assert store_mod._combine_kernel_takes(takes)
-        assert not store_mod._combine_kernel_takes(
-            spec_of(shape, dtype, update="add"))
+            assert store_mod.arms(takes).combine == "row_kernel"
+        assert store_mod.arms(
+            spec_of(shape, dtype, update="add")).combine == ""
         assert row_update.refusal_count() == n0
         return
     if noted:
         with pytest.warns(RuntimeWarning, match="sum of a rule's wide rows"):
-            assert not store_mod._combine_kernel_takes(spec)
-    assert not store_mod._combine_kernel_takes(spec)  # and warns once
-    assert row_update.refusal_count() == n0 + noted
+            assert store_mod.arms(spec).combine == kept
+    with warnings.catch_warnings():
+        warnings.simplefilter("error")  # and warns once
+        assert store_mod.arms(spec).combine == kept
+    assert row_update.refusal_count() == n0 + noted + meshed
 
 
 # Rows packed several to a physical row: on a TPU a float32 ``pull`` of a
@@ -602,7 +617,7 @@ def test_the_combine_kernel_arm_says_no(
 @pytest.mark.parametrize("width", [1, 4, 17, 64])
 @pytest.mark.parametrize("shards", ["one_shard", "dp_x_ps"])
 def test_push_pull_case_table_slice_kernel_arm(
-        shards, width, traffic, mesh, monkeypatch):
+        shards, width, traffic, mesh, monkeypatch, steer_arms):
     from flink_parameter_server_tpu.core import store as store_mod
     from flink_parameter_server_tpu.ops import packed
 
@@ -612,9 +627,9 @@ def test_push_pull_case_table_slice_kernel_arm(
         jnp.asarray(values), layout="packed",
         mesh=mesh if shards == "dp_x_ps" else None)
     assert store.spec.pack == 128 // width
-    assert not store_mod._slice_kernel_takes(store.spec, 4096)  # a CPU
-    monkeypatch.setattr(
-        store_mod, "_slice_kernel_takes", lambda spec, n=None: True)
+    assert store_mod.arms(
+        store.spec, pull_lanes=4096).pull == "packed_selects"  # a CPU
+    steer_arms(pull="packed_kernel")
     monkeypatch.setattr(packed, "SLICE_BLOCK", 32)
     calls = []
     real = packed.sub_row_slice_kernel
@@ -674,27 +689,31 @@ def test_the_slice_arm_says_no(
     monkeypatch.setattr(store_mod, "_REFUSALS_NOTED", set())
     monkeypatch.setattr(packed, "SLICE_BLOCK", 2048)
     n0 = row_update.refusal_count()
+
+    def sliced(n=None):
+        return store_mod.arms(spec, pull_lanes=n).pull == "packed_kernel"
+
     if why != "off_the_tpu":
-        assert not store_mod._slice_kernel_takes(spec, n)
+        assert not sliced(n)
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     if noted:
         with pytest.warns(RuntimeWarning, match="lane slice of a packed pull"):
-            assert not store_mod._slice_kernel_takes(spec, n)
-    assert not store_mod._slice_kernel_takes(spec, n)
+            assert not sliced(n)
+    assert not sliced(n)
     assert row_update.refusal_count() == n0 + noted
     # asking for the store as a whole (the preload) notes nothing
-    store_mod._slice_kernel_takes(spec)
+    sliced()
     assert row_update.refusal_count() == n0 + noted
     if why == "off_the_tpu":
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-        assert store_mod._slice_kernel_takes(spec, n)
-        assert store_mod._slice_kernel_takes(spec)
+        assert sliced(n)
+        assert sliced()
         assert row_update.refusal_count() == n0
     if why == "a_worker_s_lanes_under_a_block":
         # 2 workers: 2047 lanes each of 4094; 2048 each of 4096; 4095 do not
         # split and every shard slices them all
-        assert store_mod._slice_kernel_takes(spec, 4096)
-        assert store_mod._slice_kernel_takes(spec, 4095)
+        assert sliced(4096)
+        assert sliced(4095)
 
 
 # The push's mirror of that arm: on a TPU a float32 ``add`` push of a block or
@@ -707,7 +726,7 @@ def test_the_slice_arm_says_no(
 @pytest.mark.parametrize("width", [1, 4, 17, 64])
 @pytest.mark.parametrize("shards", ["one_shard", "dp_x_ps"])
 def test_push_pull_case_table_shift_kernel_arm(
-        shards, width, traffic, mesh, monkeypatch):
+        shards, width, traffic, mesh, monkeypatch, steer_arms):
     from flink_parameter_server_tpu.core import store as store_mod
     from flink_parameter_server_tpu.ops import packed
 
@@ -721,9 +740,9 @@ def test_push_pull_case_table_shift_kernel_arm(
             None if mask is None else jnp.asarray(mask))
     # not `_push`: a program traced for one arm would be reused by the other
     selects = jax.jit(lambda st, i, d, m: st.push(i, d, m))(store, *args)
-    assert not store_mod._shift_kernel_takes(store.spec, 4096)  # a CPU
-    monkeypatch.setattr(
-        store_mod, "_shift_kernel_takes", lambda spec, n=None: True)
+    assert store_mod.arms(
+        store.spec, push_lanes=4096).shift == "selects"  # a CPU
+    steer_arms(shift="kernel")
     monkeypatch.setattr(packed, "SLICE_BLOCK", 32)
     calls = []
     real = packed.lane_shift_kernel
@@ -773,20 +792,25 @@ def test_the_shift_arm_says_no(
     monkeypatch.setattr(store_mod, "_REFUSALS_NOTED", set())
     monkeypatch.setattr(packed, "SLICE_BLOCK", 2048)
     n0 = row_update.refusal_count()
+
+    def shifted(n):
+        return store_mod.arms(spec, push_lanes=n).shift == "kernel"
+
     if why != "off_the_tpu":
-        assert not store_mod._shift_kernel_takes(spec, n)
+        assert not shifted(n)
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     if noted:
         with pytest.warns(RuntimeWarning, match="lane shift of a packed push"):
-            assert not store_mod._shift_kernel_takes(spec, n)
-    assert not store_mod._shift_kernel_takes(spec, n)
+            assert not shifted(n)
+    assert not shifted(n)
     assert row_update.refusal_count() == n0 + noted
     if why == "off_the_tpu":
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-        assert store_mod._shift_kernel_takes(spec, n)
+        assert shifted(n)
         assert row_update.refusal_count() == n0
     if why == "a_rule_store":  # its PULL takes the slice kernel all the same
-        assert store_mod._slice_kernel_takes(spec, n)
+        assert store_mod.arms(spec, push_lanes=n).shift == ""
+        assert store_mod.arms(spec, pull_lanes=n).pull == "packed_kernel"
 
 
 @pytest.mark.parametrize("cell", ["cell_2", "cell_4", "cell_10"])
@@ -818,9 +842,9 @@ def test_the_shift_arm_takes_the_criteo_add_cells(
             dtype=jnp.float32)).spec
         lanes, pack = 32_768 * 39, 7
     assert (spec.layout, spec.pack, spec.update) == ("packed", pack, "add")
-    assert not store_mod._shift_kernel_takes(spec, lanes)
+    assert store_mod.arms(spec, push_lanes=lanes).shift == "selects"
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    assert store_mod._shift_kernel_takes(spec, lanes)
+    assert store_mod.arms(spec, push_lanes=lanes).shift == "kernel"
 
 
 @pytest.mark.parametrize("layout", ["dense", "packed"])
@@ -1193,7 +1217,7 @@ def test_a_narrow_rule_row_is_held_at_its_sublane_tile(width, lanes):
     ("ema", 4, 700, True), ("ema", 5, 1200, True), ("ema", 8, 600, False),
 ])
 def test_the_set_kernel_arm_is_the_xla_arm_bit_for_bit(
-        rule, width, lanes, masked, monkeypatch):
+        rule, width, lanes, masked, monkeypatch, steer_arms):
     """The rule store's push with the write-back steered through
     ``ops/row_update.sorted_tile_set`` (interpreted: this is a CPU) against
     the same push with XLA's row ``set``: every bit of the table, the same
@@ -1212,10 +1236,10 @@ def test_the_set_kernel_arm_is_the_xla_arm_bit_for_bit(
     deltas = rng.normal(size=(lanes, width)).astype(np.float32)
     mask = jnp.asarray(rng.random(lanes) < 0.8) if masked else None
     args = (jnp.asarray(ids), jnp.asarray(deltas), mask)
-    assert not store_mod._set_kernel_takes(store.spec)  # this is a CPU
+    assert store_mod.arms(store.spec).write_back == "xla_set"  # a CPU
     want, counted = store_mod.push_counted(store.spec, store.table, *args)
     assert int(counted["ps_rule_tiles"]) == 0
-    monkeypatch.setattr(store_mod, "_set_kernel_takes", lambda spec: True)
+    steer_arms(write_back="tile_set")
     got, kernel_counted = store_mod.push_counted(store.spec, store.table, *args)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
     for name in ("ps_rule_keys", "ps_rule_rows"):
@@ -1240,8 +1264,9 @@ def test_combine_runs_sums_each_id_and_moves_the_distinct_first(width):
     ids[rng.random(n) < 0.1] = sentinel            # lanes to drop
     ids[:200] = 7                                   # a long run
     vals = rng.normal(size=(n, width)).astype(np.float32)
-    row_ids, sums, writes = jax.jit(combine_runs, static_argnums=2)(
-        ids, vals, sentinel)
+    arm = "sort" if width <= 4 else "scatter_add"  # what `arms` reads here
+    row_ids, sums, writes = jax.jit(combine_runs, static_argnums=(2, 3))(
+        ids, vals, sentinel, arm)
     # a narrow row rides through the sort; off the TPU nothing issues a DMA
     assert writes is None if width <= 4 else int(writes) == 0
     row_ids, sums = np.asarray(row_ids), np.asarray(sums)
@@ -1336,7 +1361,7 @@ def _packed_rule_traffic(case, rng, cap, width):
 @pytest.mark.parametrize("width", PACKED_RULE_WIDTHS + [8, 65])
 @pytest.mark.parametrize("arm", ["xla", "row_set_kernel"])
 def test_a_packed_rule_store_is_the_dense_one_bit_for_bit(
-        arm, width, case, monkeypatch):
+        arm, width, case, monkeypatch, steer_arms):
     from flink_parameter_server_tpu.core import store as store_mod
 
     rng = np.random.default_rng([width, PACKED_RULE_CASES.index(case)])
@@ -1370,13 +1395,13 @@ def test_a_packed_rule_store_is_the_dense_one_bit_for_bit(
             values.tobytes())
     # `values()` / `from_values` round trip, bit for bit
     assert np.asarray(auto.values()).tobytes() == values.tobytes()
-    assert not store_mod._set_kernel_takes(auto.spec)  # this is a CPU
+    assert store_mod.arms(auto.spec).write_back == "xla_set"  # a CPU
     args = (jnp.asarray(ids), jnp.asarray(deltas),
             None if mask is None else jnp.asarray(mask))
     want, _ = store_mod.push_counted(dense.spec, dense.table, *args)
     if arm == "row_set_kernel" and packs:
-        # off the TPU the chooser is steered and the kernel interpreted
-        monkeypatch.setattr(store_mod, "_set_kernel_takes", lambda spec: True)
+        # off the TPU the arm is steered and the kernel interpreted
+        steer_arms(write_back="row_set")
     got, counted = store_mod.push_counted(auto.spec, auto.table, *args)
     pushed = ShardedParamStore(auto.spec, got)
     want = np.asarray(ShardedParamStore(dense.spec, want).values())
@@ -1505,7 +1530,7 @@ def _shard_rule_traffic(case, rng, cap, width, block):
     (100, "xla"), (17, "row_set_kernel"), (36, "row_set_kernel"),
 ])
 def test_a_rule_store_on_its_shards_is_the_one_place_store_and_the_shares_add_up(
-        arm, width, case, ps_mesh, monkeypatch):
+        arm, width, case, ps_mesh, monkeypatch, steer_arms):
     from flink_parameter_server_tpu.core import store as store_mod
 
     rng = np.random.default_rng([width, SHARD_RULE_CASES.index(case)])
@@ -1520,7 +1545,7 @@ def test_a_rule_store_on_its_shards_is_the_one_place_store_and_the_shares_add_up
     assert spec.layout == one.spec.layout == ("packed" if packs else "dense")
     # a narrow rule row is NOT held at its sublane tile under a mesh
     assert spec.tile_lanes == 0 and (width != 3 or one.spec.tile_lanes == 4)
-    assert store_mod._rule_on_shards_takes(spec)
+    assert store_mod.arms(spec).on_shards
     block = spec.rows_per_shard * spec.pack
     ids, deltas, mask, chunk = _shard_rule_traffic(case, rng, cap, width, block)
     if chunk:
@@ -1529,9 +1554,9 @@ def test_a_rule_store_on_its_shards_is_the_one_place_store_and_the_shares_add_up
             None if mask is None else jnp.asarray(mask))
     want, counted_one = store_mod.push_counted(one.spec, one.table, *args)
     if arm == "row_set_kernel":
-        # off the TPU the chooser is steered and the kernel interpreted,
+        # off the TPU the arm is steered and the kernel interpreted,
         # here inside every shard's part of the shard_map
-        monkeypatch.setattr(store_mod, "_set_kernel_takes", lambda spec: True)
+        steer_arms(write_back="row_set")
     got, counted = jax.jit(
         lambda t, i, d, m: store_mod.push_counted(spec, t, i, d, m)
     )(sharded.table, *args)
@@ -1578,7 +1603,7 @@ def test_a_rule_store_on_its_shards_is_the_one_place_store_and_the_shares_add_up
 
 @pytest.mark.parametrize("width", [17, 36])
 def test_the_row_kernels_inside_the_shard_map_sum_and_write_what_xla_does(
-        width, ps_mesh, monkeypatch):
+        width, ps_mesh, monkeypatch, steer_arms):
     """Both kernels steered on and interpreted inside the ``shard_map``: the
     combine sums a run along sorted lanes (a blocked float32 sum, not the
     stream's order), so the rows are the XLA arm's within float32 rounding,
@@ -1601,8 +1626,7 @@ def test_the_row_kernels_inside_the_shard_map_sum_and_write_what_xla_does(
         lambda t, i, d, m: store_mod.push_counted(spec, t, i, d, m)
     )(sharded.table, *args)
     calls = []
-    monkeypatch.setattr(store_mod, "_set_kernel_takes", lambda spec: True)
-    monkeypatch.setattr(store_mod, "_combine_kernel_takes", lambda spec: True)
+    steer_arms(write_back="row_set", combine="row_kernel")
     monkeypatch.setattr(row_update, "MAX_LANES", 512)
     for name in ("sorted_row_update_counted", "sorted_row_set"):
         real = getattr(row_update, name)
@@ -1657,7 +1681,7 @@ SHARD_OWNED = ["no_key", "one_key", "every_key", "spread"]
 @pytest.mark.parametrize("placed", ["one_place", "ps4"])
 @pytest.mark.parametrize("owned", SHARD_OWNED)
 def test_the_walk_pays_by_what_it_writes_and_the_push_is_the_parents(
-        owned, placed, ps_mesh, monkeypatch):
+        owned, placed, ps_mesh, monkeypatch, steer_arms):
     """A packed rule store's push with both kernels steered on and
     interpreted, in one place and on the shards of a ``ps`` = 4 mesh: the
     table is, bit for bit, what the parent's walk gives (the row kernel
@@ -1694,8 +1718,7 @@ def test_the_walk_pays_by_what_it_writes_and_the_push_is_the_parents(
         ids[1234:1234 + (owned == "one_key")] = lo + 77
     ids = ids.astype(np.int32)
     deltas = rng.normal(size=(n, width)).astype(np.float32)
-    monkeypatch.setattr(store_mod, "_set_kernel_takes", lambda spec: True)
-    monkeypatch.setattr(store_mod, "_combine_kernel_takes", lambda spec: True)
+    steer_arms(write_back="row_set", combine="row_kernel")
     monkeypatch.setattr(row_update, "MAX_LANES", size)
 
     def push():
@@ -1804,29 +1827,35 @@ def test_a_rule_store_s_arms_under_a_mesh_are_read_from_its_workers(
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
     monkeypatch.setattr(store_mod, "_REFUSALS_NOTED", set())
     n0 = row_update.refusal_count()
-    with warnings.catch_warnings():
-        warnings.simplefilter("error")  # the arms themselves note nothing
-        assert store_mod._combine_kernel_takes(spec) == combine
-        assert store_mod._set_kernel_takes(spec) == write_back
-    assert row_update.refusal_count() == n0
+    want = (
+        "row_kernel" if combine else "sort" if shape[0] <= 4 else "scatter_add",
+        "row_set" if write_back else "xla_set", on_shards)
+
+    def read(spec):
+        arm = store_mod.arms(spec)
+        return arm.combine, arm.write_back, arm.on_shards
+
     if on_shards:
         with warnings.catch_warnings():
-            warnings.simplefilter("error")
-            assert store_mod._rule_on_shards_takes(spec)
+            warnings.simplefilter("error")  # the arms themselves note nothing
+            assert read(spec) == want
         assert row_update.refusal_count() == n0
         return
     # a batch split over dp > 1 workers keeps the one-place push under
     # GSPMD, and says so once as every declined arm does
-    with pytest.warns(RuntimeWarning, match="split over dp = 2 workers"):
-        assert not store_mod._rule_on_shards_takes(spec)
+    with pytest.warns(RuntimeWarning, match="split over dp = 2 workers") as w:
+        assert read(spec) == want
+    assert len(w) == 1  # the combine and the write-back note nothing
     with warnings.catch_warnings():
         warnings.simplefilter("error")
-        assert not store_mod._rule_on_shards_takes(spec)
+        assert read(spec) == want
     assert row_update.refusal_count() == n0 + 1
     # neither a one-place store nor an add store under any mesh is asked
     one = jax.eval_shape(lambda: ShardedParamStore.create(
         1000, shape, update=_sticky_rule, layout="auto")).spec
-    assert not store_mod._rule_on_shards_takes(one)
+    assert not store_mod.arms(one).on_shards
+    assert not store_mod.arms(jax.eval_shape(lambda: ShardedParamStore.create(
+        1000, shape, mesh=mesh, layout="auto")).spec).on_shards
     assert row_update.refusal_count() == n0 + 1
 
 
@@ -1877,7 +1906,7 @@ def _flat_wide_traffic(case, rng, cap, width):
 @pytest.mark.parametrize("width", FLAT_WIDE_WIDTHS)
 @pytest.mark.parametrize("arm", ["xla", "tile_kernels"])
 def test_a_flat_wide_rule_store_is_the_dense_one_bit_for_bit(
-        arm, width, case, monkeypatch):
+        arm, width, case, monkeypatch, steer_arms):
     from flink_parameter_server_tpu.core import store as store_mod
 
     rng = np.random.default_rng([width, FLAT_WIDE_CASES.index(case)])
@@ -1895,8 +1924,6 @@ def test_a_flat_wide_rule_store_is_the_dense_one_bit_for_bit(
         jnp.asarray(values), update=_sticky_rule, layout="dense")
     lanes = -(-width // 128) * 128
     assert auto.spec.layout == "packed" and auto.spec.pack == 1
-    assert store_mod._flat_wide_rule(auto.spec)
-    assert not store_mod._flat_wide_rule(dense.spec)
     assert auto.table.shape == (80, lanes) and dense.spec.layout == "dense"
     table = np.asarray(auto.table)
     assert not table[:, width:].any() and (
@@ -1908,8 +1935,8 @@ def test_a_flat_wide_rule_store_is_the_dense_one_bit_for_bit(
         update=_sticky_rule, layout="auto")
     assert made.spec == auto.spec
     assert np.asarray(made.table)[:cap].tobytes() == table[:cap].tobytes()
-    for takes in (store_mod._set_kernel_takes, store_mod._combine_kernel_takes):
-        assert not takes(auto.spec)  # this is a CPU
+    assert _rule_arms(auto.spec) == (
+        "scatter_add", "xla_set")  # this is a CPU
     args = (jnp.asarray(ids), jnp.asarray(deltas),
             None if mask is None else jnp.asarray(mask))
     want, _ = store_mod.push_counted(dense.spec, dense.table, *args)
@@ -1917,9 +1944,8 @@ def test_a_flat_wide_rule_store_is_the_dense_one_bit_for_bit(
     if arm == "tile_kernels":
         from flink_parameter_server_tpu.ops import row_update
 
-        # off the TPU the choosers are steered and the kernels interpreted
-        monkeypatch.setattr(store_mod, "_set_kernel_takes", lambda spec: True)
-        monkeypatch.setattr(store_mod, "_combine_kernel_takes", lambda spec: True)
+        # off the TPU the arms are steered and the kernels interpreted
+        steer_arms(combine="tile_kernel", write_back="tile_assign")
         real = row_update._sorted_tile_add_counted
         monkeypatch.setattr(
             row_update, "_sorted_tile_add_counted",
@@ -1982,7 +2008,7 @@ MASKED_ARMS = [
 @pytest.mark.parametrize("bad", [np.nan, np.inf, -np.inf])
 @pytest.mark.parametrize("width, arm", MASKED_ARMS)
 def test_a_masked_lanes_nan_reaches_no_kept_row_in_any_combine_arm(
-        width, arm, bad, monkeypatch):
+        width, arm, bad, monkeypatch, steer_arms):
     """The table after a push whose masked lanes hold NaN or Inf is, bit for
     bit, the table after the same push with zeros there (what the parent's
     `_zero_masked` made of them).  The masked lanes keep LIVE ids, ids that
@@ -1997,9 +2023,11 @@ def test_a_masked_lanes_nan_reaches_no_kept_row_in_any_combine_arm(
     store = ShardedParamStore.from_values(
         jnp.asarray(values), update=_sticky_rule, layout="auto")
     kernels = arm in ("kernel_sums", "tile_sums")
-    monkeypatch.setattr(store_mod, "_combine_kernel_takes", lambda spec: kernels)
+    steer_arms(combine={
+        "kernel_sums": "row_kernel", "tile_sums": "tile_kernel",
+    }.get(arm, "scatter_add"))  # (a row the sort carries keeps the sort)
     if arm == "tile_sums":
-        monkeypatch.setattr(store_mod, "_set_kernel_takes", lambda spec: True)
+        steer_arms(write_back="tile_assign")
     ids = rng.integers(0, cap, n).astype(np.int32)
     ids[: n // 4] = 11  # a hot row, a third of its lanes masked
     mask = rng.random(n) > 0.3
@@ -2034,7 +2062,7 @@ def test_a_masked_lanes_nan_reaches_no_kept_row_in_any_combine_arm(
     ("add", (600,)), ("add", (2, 300)), ("add", (300,)), ("rule", (602,))],
     ids=str)
 def test_push_pull_case_table_flat_wide_rows_unpadded_are_the_padded_bit_for_bit(
-        kind, shape, traffic, monkeypatch):
+        kind, shape, traffic, monkeypatch, steer_arms):
     from flink_parameter_server_tpu.core import store as store_mod
     from flink_parameter_server_tpu.ops import row_update
 
@@ -2060,12 +2088,8 @@ def test_push_pull_case_table_flat_wide_rows_unpadded_are_the_padded_bit_for_bit
         row_update, "_sorted_tile_add_counted",
         lambda *a: handed.add(a[2].shape[1]) or real(*a))
     monkeypatch.setattr(row_update, "MAX_LANES", 512)
-    if kind == "add":
-        monkeypatch.setattr(
-            store_mod, "_tile_kernel_takes", lambda spec, lanes=None: True)
-    else:
-        monkeypatch.setattr(store_mod, "_set_kernel_takes", lambda spec: True)
-        monkeypatch.setattr(store_mod, "_combine_kernel_takes", lambda spec: True)
+    steer_arms(
+        push="tile_add", combine="tile_kernel", write_back="tile_assign")
     # (a fresh program: an eager push would reuse one traced for another case)
     got, _ = jax.jit(
         lambda table, *a: store_mod.push_counted(store.spec, table, *a)
@@ -2097,15 +2121,19 @@ def test_a_flat_wide_rule_store_takes_the_tile_kernels_from_its_spec(
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
     monkeypatch.setattr(store_mod, "_REFUSALS_NOTED", set())
     n0 = row_update.refusal_count()
+    width, flat = shape[0], spec.table_shape()[1] > 128
+    want = (
+        "sort" if width <= 4 else "scatter_add" if not combine
+        else "tile_kernel" if flat else "row_kernel",
+        "xla_set" if not write_back else "tile_set" if width <= 8
+        else "tile_assign" if flat else "row_set")
     with warnings.catch_warnings():
         warnings.simplefilter("ignore")
-        assert store_mod._combine_kernel_takes(spec) == combine
-        assert store_mod._set_kernel_takes(spec) == write_back
+        assert _rule_arms(spec) == want
     assert row_update.refusal_count() == n0 + noted
     with warnings.catch_warnings():
         warnings.simplefilter("error")  # a refusal is noted once
-        store_mod._combine_kernel_takes(spec)
-        store_mod._set_kernel_takes(spec)
+        assert _rule_arms(spec) == want
     assert row_update.refusal_count() == n0 + noted
 
 
@@ -2126,3 +2154,144 @@ def test_a_rule_row_resolves_by_its_width_and_its_reload_to_the_same(
     if lanes is not None:
         assert store.table.shape[1] == lanes
     assert np.asarray(again.table).tobytes() == np.asarray(store.table).tobytes()
+
+
+# -- THE CASE TABLE of `core/store.arms` --------------------------------------
+# One case a row of the two tables in its docstring, in their order, the
+# backend steered to the TPU the tables describe; then the same specs off it.
+# (what, value_shape, update, layout, mesh (dp, ps), capacity, pull lanes,
+#  push lanes, lanes over workers, the record, refusals noted on the way)
+_RULE = "rule"
+ARMS_ON_A_TPU = [
+    ("dense 1 reg, lanes x 8 > rows", (128,), "add", "auto", None, 40_000,
+     65_536, 65_536, False, ("take", "xla_add", "", "", "", False), 0),
+    ("dense 1 reg, 1,024+ <= rows / 8", (128,), "add", "auto", None, 40_000,
+     5_000, 5_000, False, ("take", "tile_add", "", "", "", False), 0),
+    ("dense 1 reg, dp 4, shard <= lanes", (128,), "add", "auto", (4, 1), 96,
+     256, 256, True, ("take", "worker_reduce", "", "", "", False), 0),
+    ("packed k 7, lanes x 8 > rows", (17,), "add", "auto", None, 7_000,
+     8_192, 8_192, False,
+     ("packed_kernel", "xla_add", "kernel", "", "", False), 0),
+    ("the same over ps 4, dp 1", (17,), "add", "auto", (1, 4), 7_000,
+     8_192, 8_192, False,
+     ("packed_kernel", "xla_add", "kernel", "", "", False), 0),
+    ("packed k 2, 1,024+ <= rows / 8", (64,), "add", "auto", None, 80_000,
+     4_096, 4_096, False,
+     ("packed_kernel", "tile_add", "kernel", "", "", False), 0),
+    ("packed k 7, under a block of ids", (17,), "add", "auto", None, 7_000,
+     312, 312, False, ("packed_selects", "xla_add", "selects", "", "", False),
+     2),  # the slice and the shift: noted
+    ("packed k 1, 5 regs", (2, 300), "add", "auto", None, 61,
+     8_192, 8_192, False,
+     ("packed_selects", "tile_add", "selects", "", "", False), 0),
+    ("5 regs under a mesh", (640,), "add", "auto", (1, 4), 61,
+     8_192, 8_192, False, ("take", "xla_add", "", "", "", False), 0),
+    ("3 lanes, held at its tile of 4", (3,), _RULE, "auto", None, 1_000,
+     8_192, 8_192, False, ("narrow", "rule", "", "sort", "tile_set", False), 0),
+    ("6 lanes, held at its tile of 8", (6,), _RULE, "auto", None, 1_000,
+     8_192, 8_192, False,
+     ("narrow", "rule", "", "row_kernel", "tile_set", False), 0),
+    ("(2, 2) lanes: rank 2, no tile", (2, 2), _RULE, "auto", None, 1_000,
+     8_192, 8_192, False, ("take", "rule", "", "sort", "xla_set", False),
+     1),  # the write-back of a narrow row not held at its tile: noted
+    ("packed k 3 (36 lanes)", (36,), _RULE, "auto", None, 3_000,
+     8_192, 8_192, False,
+     ("packed_kernel", "rule", "", "row_kernel", "row_set", False), 0),
+    ("the same over ps 4, dp 1", (36,), _RULE, "auto", (1, 4), 3_000,
+     8_192, 8_192, False,
+     ("packed_kernel", "rule", "", "row_kernel", "row_set", True), 0),
+    ("the same over ps 2, dp 2", (36,), _RULE, "auto", (2, 2), 3_000,
+     8_192, 8_192, False,
+     ("packed_kernel", "rule", "", "scatter_add", "xla_set", False),
+     1),  # the push on the shards: the batch lies split over dp, noted
+    ("packed k 1, 1 reg (pinned, 100)", (100,), _RULE, "packed", None, 1_000,
+     8_192, 8_192, False,
+     ("packed_selects", "rule", "", "row_kernel", "row_set", False), 0),
+    ("packed k 1, 5 regs (602 lanes)", (602,), _RULE, "auto", None, 1_000,
+     8_192, 8_192, False,
+     ("packed_selects", "rule", "", "tile_kernel", "tile_assign", False), 0),
+    ("dense 1 reg (100 lanes)", (100,), _RULE, "auto", None, 1_000,
+     8_192, 8_192, False,
+     ("take", "rule", "", "row_kernel", "xla_set", False), 0),
+]
+# off a TPU: XLA's forms; where the push runs is read from the mesh alone
+ARMS_OFF_IT = {
+    "dense 1 reg, 1,024+ <= rows / 8": ("take", "xla_add", "", "", "", False),
+    "dense 1 reg, dp 4, shard <= lanes": (
+        "take", "worker_reduce", "", "", "", False),
+    "packed k 2, 1,024+ <= rows / 8": (
+        "packed_selects", "xla_add", "selects", "", "", False),
+    "packed k 1, 5 regs": ("packed_selects", "xla_add", "selects", "", "", False),
+    "3 lanes, held at its tile of 4": (
+        "narrow", "rule", "", "sort", "xla_set", False),
+    "packed k 3 (36 lanes)": (
+        "packed_selects", "rule", "", "scatter_add", "xla_set", False),
+    "packed k 1, 5 regs (602 lanes)": (
+        "packed_selects", "rule", "", "scatter_add", "xla_set", False),
+}
+
+
+@pytest.mark.parametrize("backend, row", [
+    ("tpu", i) for i in range(len(ARMS_ON_A_TPU))
+] + [
+    ("cpu", i) for i, c in enumerate(ARMS_ON_A_TPU) if c[0] in ARMS_OFF_IT
+], ids=lambda v: v if isinstance(v, str) else ARMS_ON_A_TPU[v][0])
+def test_the_arms_table(backend, row, mesh_devices, monkeypatch):
+    """``core/store.arms`` is the one reader of which form a pull and a push
+    take; its docstring's case table is held here row by row."""
+    import dataclasses
+
+    from flink_parameter_server_tpu.core import store as store_mod
+    from flink_parameter_server_tpu.ops import row_update
+    from flink_parameter_server_tpu.parallel.mesh import make_mesh
+
+    (what, shape, update, layout, mesh_shape, capacity, pull_lanes,
+     push_lanes, over_workers, want, noted) = ARMS_ON_A_TPU[row]
+    if backend == "cpu":
+        want = ARMS_OFF_IT[what]
+        noted = 0
+    mesh = mesh_shape and make_mesh(
+        *mesh_shape, devices=mesh_devices[:mesh_shape[0] * mesh_shape[1]])
+    rule = _sticky_rule if update == _RULE else "add"
+    spec = store_mod.StoreSpec(
+        capacity, shape, update=rule, mesh=mesh or None,
+        layout=store_mod._resolve_layout(layout, rule, shape))
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    monkeypatch.setattr(store_mod, "_REFUSALS_NOTED", set())
+    n0 = row_update.refusal_count()
+
+    def read():
+        return dataclasses.astuple(store_mod.arms(
+            spec, pull_lanes=pull_lanes, push_lanes=push_lanes,
+            lanes_over_workers=over_workers))
+
+    with warnings.catch_warnings(record=True) as caught:
+        warnings.simplefilter("always")
+        assert read() == want
+    assert len(caught) == noted == row_update.refusal_count() - n0
+    with warnings.catch_warnings():
+        warnings.simplefilter("error")  # a refusal is noted once
+        assert read() == want
+    assert row_update.refusal_count() == n0 + noted
+
+
+def test_the_arms_table_has_a_case_a_row_of_the_docstring():
+    """The docstring of ``arms`` IS the case table: its data rows, in order,
+    are the cases above (first column against first column)."""
+    from flink_parameter_server_tpu.core import store as store_mod
+
+    # (a row ends with the PRs that priced it; the headers end with "PR")
+    rows = [
+        line.strip() for line in store_mod.arms.__doc__.splitlines()
+        if "  " in line.strip() and line.split()[-1].isdigit()]
+    assert len(rows) == len(ARMS_ON_A_TPU)
+    for row, case in zip(rows, ARMS_ON_A_TPU):
+        assert row.startswith(case[0]), (row, case[0])
+        want = case[9]
+        cells = row[len(case[0]):].split()
+        got = [c for c in cells if c in {
+            "take", "narrow", "packed_selects", "packed_kernel", "xla_add",
+            "tile_add", "worker_reduce", "selects", "kernel", "sort",
+            "scatter_add", "row_kernel", "tile_kernel", "xla_set", "tile_set",
+            "row_set", "tile_assign"}]
+        assert got == [w for w in want if w not in ("", "rule", True, False)]

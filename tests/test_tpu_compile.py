@@ -248,7 +248,7 @@ def test_tile_refusal_names_a_width_whose_three_buffers_do_not_fit(one_chip):
     """20 registers a row: three blocks' tile rows and two of deltas are
     68.2 MB, over the 64 MiB the kernel asks Mosaic for; the refusal says
     so, ``sorted_tile_add`` raises it, and a store of such rows keeps XLA's
-    scatter-add (``core/store._tile_kernel_takes`` reads the refusal)."""
+    scatter-add (``core/store.arms`` reads the refusal)."""
     why = row_update.tile_refusal((1024, 2560), jnp.float32)
     assert why is not None and "VMEM" in why and "2560" in why
     with pytest.raises(ValueError, match="VMEM"):
@@ -656,8 +656,9 @@ def test_fm_step_on_a_tpu_slices_its_pulled_rows_in_the_kernel(
         table = _shape(spec.sharding(), spec.table_shape(), jnp.float32)
         batch = _fm_batch(NamedSharding(mesh, PartitionSpec()))
     n0 = row_update.refusal_count()
-    assert store_mod._slice_kernel_takes(spec, FM_BATCH * FM_FIELDS)
-    assert store_mod._shift_kernel_takes(spec, FM_BATCH * FM_FIELDS)
+    lanes = FM_BATCH * FM_FIELDS
+    assert store_mod.arms(spec, pull_lanes=lanes, push_lanes=lanes) == (
+        store_mod.Arms("packed_kernel", "xla_add", "kernel", "", "", False))
     compiled = jax.jit(
         make_train_step(logic, spec), donate_argnums=(0, 1)
     ).lower(table, (), batch).compile()
@@ -898,7 +899,7 @@ def _w2v_cell_step(one_chip, w2vm, monkeypatch):
     spec = jax.eval_shape(
         lambda: w2vm.make_store(W2V_VOCAB, W2V_DIM, dtype=jnp.float32)
     ).spec
-    assert store_mod._tile_kernel_takes(spec)
+    assert store_mod.arms(spec).push == "tile_add"
     logic = w2vm.SkipGramNS(0.025, dedup_scale=True, vocab_size=W2V_VOCAB)
     return _w2v_step(one_chip, spec, logic).compile()
 
@@ -1164,7 +1165,8 @@ def test_lr_step_holds_nothing_table_sized_beside_its_table(
     # code that asks for the backend still sees the CPU here: steer it
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     n0 = row_update.refusal_count()
-    assert store_mod._set_kernel_takes(spec)
+    assert store_mod.arms(spec) == store_mod.Arms(
+        "narrow", "rule", "", "sort", "tile_set", False)
     compiled = _lr_step(lr, one_chip)
     assert row_update.refusal_count() == n0
     mem = compiled.memory_analysis()
@@ -1202,7 +1204,7 @@ def test_lr_step_off_the_tpu_keeps_xlas_row_set_in_place(
     not take: XLA's row ``set`` of the padded rows into the same 4-lane
     table, in place, nothing table-sized beside it."""
     spec, _ = lr
-    assert not store_mod._set_kernel_takes(spec)  # this is a CPU
+    assert store_mod.arms(spec).write_back == "xla_set"  # this is a CPU
     compiled = _lr_step(lr, one_chip)
     mem = compiled.memory_analysis()
     assert 2.9 * GB < mem.alias_size_in_bytes < 3.1 * GB
@@ -1322,7 +1324,7 @@ def _ft_cell_step(one_chip, monkeypatch):
     ).spec
     assert spec.layout == "packed" and spec.pack == 1
     assert spec.table_shape() == (7_038_744, 384)
-    assert store_mod._tile_kernel_takes(spec)
+    assert store_mod.arms(spec).push == "tile_add"
     logic = ftm.FastTextSkipGram(0.05, FT_VOCAB, FT_BUCKETS, FT_BAG)
     batch = {
         "bag": _shape(one_chip, (FT_BATCH, FT_BAG), jnp.int32),
@@ -1438,17 +1440,16 @@ def test_difacto_step_holds_nothing_table_sized_beside_its_table(
     assert spec.layout == "packed" and spec.pack == 3 and not spec.narrow_rule
     assert spec.table_shape() == (DF_PHYS_ROWS, 128)
     n = FM_BATCH * FM_FIELDS
-    for takes in (store_mod._combine_kernel_takes, store_mod._set_kernel_takes):
-        assert not takes(spec)  # this is a CPU
-    assert not store_mod._slice_kernel_takes(spec, n)
+    assert store_mod.arms(spec, pull_lanes=n) == store_mod.Arms(
+        "packed_selects", "rule", "", "scatter_add", "xla_set", False)  # a CPU
     if arm == "kernels":
         # code that asks for the backend still sees the CPU here: steer it
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
         n0 = row_update.refusal_count()
-        assert store_mod._combine_kernel_takes(spec)
-        assert store_mod._set_kernel_takes(spec)
-        assert store_mod._slice_kernel_takes(spec, n)
-        assert store_mod._slice_kernel_takes(spec)  # the import is preloaded
+        kernels = store_mod.Arms(
+            "packed_kernel", "rule", "", "row_kernel", "row_set", False)
+        assert store_mod.arms(spec, pull_lanes=n) == kernels
+        assert store_mod.arms(spec) == kernels  # the import is preloaded
         assert row_update.refusal_count() == n0
     compiled = jax.jit(
         make_train_step(dfm.DiFacto(model, rule), spec), donate_argnums=(0, 1)
@@ -1508,7 +1509,7 @@ def test_difacto_step_holds_nothing_table_sized_beside_its_table(
     from flink_parameter_server_tpu.ops import dedup
 
     jaxpr = jax.make_jaxpr(lambda i, v: dedup.combine_runs(
-        i, v, spec.padded_capacity, kernel=True, interpret=False)
+        i, v, spec.padded_capacity, "row_kernel", interpret=False)
     )(jax.ShapeDtypeStruct((FM_BATCH * FM_FIELDS,), jnp.int32),
       jax.ShapeDtypeStruct((FM_BATCH * FM_FIELDS, 36), jnp.float32))
     loops = [e.primitive.name for e in jaxpr.eqns
@@ -1540,12 +1541,12 @@ def test_difacto_step_on_four_chips_runs_its_rule_on_the_shard_that_owns_the_row
     ).spec
     assert spec.layout == "packed" and spec.pack == 3
     assert spec.table_shape() == (4 * 15_647_288, 128)
-    assert store_mod._rule_on_shards_takes(spec)
+    assert store_mod.arms(spec).on_shards
     if arm == "kernels":
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
         n0 = row_update.refusal_count()
-        assert store_mod._combine_kernel_takes(spec)
-        assert store_mod._set_kernel_takes(spec)
+        assert store_mod.arms(spec) == store_mod.Arms(
+            "packed_kernel", "rule", "", "row_kernel", "row_set", True)
         assert row_update.refusal_count() == n0
     everywhere = NamedSharding(mesh, PartitionSpec())
     compiled = jax.jit(
@@ -1605,7 +1606,7 @@ def test_the_compilers_cut_that_SERIAL_SCATTER_ROWS_A_LANE_stands_on(
     sorted form, a ``sort`` of the ids and ``indices_are_sorted=true`` on
     the scatter (13-22 ns a lane on the chip), exactly when the batch has
     more than an eighth as many lanes as the operand has rows, and leaves it
-    serial (74.7 ns a lane) at or under that.  ``_tile_kernel_takes`` sends
+    serial (74.7 ns a lane) at or under that.  ``core/store.arms`` sends
     a one-register push to the tile kernel on the serial side alone.  A
     libtpu that moves the cut fails HERE: then measure both arms on the new
     side (PERF.md section 6, PR 49) and move the constant."""
@@ -1665,7 +1666,7 @@ def test_dlrm_step_holds_nothing_table_sized_beside_its_12_58_gb_table(
     (asked for the backend): the donated table is rewritten in place by the
     tile kernel, nine calls of one shape (851,968 lanes times 8 are under
     the table's 24,563,152 rows, where the TPU compiler would leave its own
-    scatter-add serial: ``_tile_kernel_takes(spec, n)``), no XLA scatter, and
+    scatter-add serial: ``arms(spec, push_lanes=n)``), no XLA scatter, and
     never copied; under ``ps.pull`` ONE gather of whole physical rows and the
     lane slice kernel at two rows a register, handing the logic
     ``f32[64,851968]``; the dense net's scopes on its products; 1.8 GB of
@@ -1683,9 +1684,9 @@ def test_dlrm_step_holds_nothing_table_sized_beside_its_12_58_gb_table(
     assert spec.table_shape() == (DLRM_PHYS_ROWS, 128)
     n = FM_BATCH * DLRM_FIELDS
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    assert store_mod._slice_kernel_takes(spec, n)
-    assert store_mod._tile_kernel_takes(spec, n)  # under the compiler's cut
-    assert store_mod._shift_kernel_takes(spec, n)
+    # (the tile kernel: the batch is under the compiler's cut)
+    assert store_mod.arms(spec, pull_lanes=n, push_lanes=n) == store_mod.Arms(
+        "packed_kernel", "tile_add", "kernel", "", "", False)
     assert n * store_mod._SERIAL_SCATTER_ROWS_A_LANE < DLRM_PHYS_ROWS
     logic = dlrm.DLRM(model)
     state = {
@@ -1783,14 +1784,13 @@ def _glove_cell_step(one_chip, glove, monkeypatch, arm="kernels"):
     spec = jax.eval_shape(lambda: gl.make_store(model)).spec
     assert spec.layout == "packed" and spec.pack == 1
     assert spec.table_shape() == (GLOVE_PHYS_ROWS, 640)
-    assert store_mod._flat_wide_rule(spec)
-    for takes in (store_mod._combine_kernel_takes, store_mod._set_kernel_takes):
-        assert not takes(spec)  # this is a CPU
+    assert store_mod.arms(spec) == store_mod.Arms(
+        "packed_selects", "rule", "", "scatter_add", "xla_set", False)  # a CPU
     if arm == "kernels":
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
         n0 = row_update.refusal_count()
-        assert store_mod._combine_kernel_takes(spec)
-        assert store_mod._set_kernel_takes(spec)
+        assert store_mod.arms(spec) == store_mod.Arms(
+            "packed_selects", "rule", "", "tile_kernel", "tile_assign", False)
         assert row_update.refusal_count() == n0
     batch = {
         "word": _shape(one_chip, (GLOVE_BATCH,), jnp.int32),
