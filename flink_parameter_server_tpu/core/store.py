@@ -147,11 +147,31 @@ class StoreSpec:
     #   A rule store's push then reads and writes whole physical rows, the
     #   touched logical rows of each merged by selects (`_push_rule`).
     layout: str = "dense"
+    # The WORKER'S PART of a rule store's row: how many leading lanes of
+    # its flat row a worker reads and its gradients touch, the rest being
+    # the server's own (an optimiser's accumulators: DiFacto's `S`, GloVe's
+    # `gradsq`).  A step then pulls these lanes alone and pushes deltas
+    # that wide (`make_train_step`, `pull(worker_part=True)`,
+    # `push_counted`); the rule is still `update(whole current row,
+    # combined)`, `combined` that wide, and writes whole rows.  None: the
+    # whole row crosses, as every add store's does.  The MODEL's fact, set
+    # by whoever lays the row out (`models/difacto.make_store`: 20 of 36,
+    # `models/glove.make_store`: 301 of 602); nothing tunes it.
+    worker_width: Optional[int] = None
 
     def __post_init__(self) -> None:
         if self.layout not in ("dense", "packed"):
             raise ValueError(
                 f"layout={self.layout!r} is not one of ('dense', 'packed')"
+            )
+        if self.worker_width is not None and (
+                self.update == "add" or len(self.value_shape) != 1
+                or not 0 < self.worker_width <= self.row_width):
+            raise ValueError(
+                f"worker_width={self.worker_width!r}: the worker's part is "
+                f"1 to {self.row_width} leading lanes of a RULE store's "
+                f"one-axis row (update={self.update!r}, value shape "
+                f"{self.value_shape})"
             )
 
     @property
@@ -313,7 +333,9 @@ def _create_packed(spec: StoreSpec, init_fn: InitFn) -> Callable[[], Array]:
     ), out_shardings=spec.sharding())
 
 
-def pull(spec: StoreSpec, table: Array, ids: Array) -> Array:
+def pull(
+    spec: StoreSpec, table: Array, ids: Array, *, worker_part: bool = False
+) -> Array:
     """Batched pull: ``values[i] = table[ids[i]]`` (sharded gather).
 
     Out-of-range ids are clipped (callers use a validity mask alongside):
@@ -324,20 +346,30 @@ def pull(spec: StoreSpec, table: Array, ids: Array) -> Array:
     lane slice down to sub-row ``id % k``, by selects and never a gather of
     elements (ops/packed.py); under a mesh each shard slices what it
     gathered before the one all-reduce (:func:`_packed_pull_on_shards`).
-    Which form: :func:`arms`."""
+    Which form: :func:`arms`.
+
+    ``worker_part`` (a step's pull: ``make_train_step``): of a store whose
+    spec names a worker's part (``StoreSpec.worker_width``) those leading
+    lanes of each row alone, ``ids.shape + (worker_width,)``, cut where the
+    arm cuts anyway and by no pass of its own (the gather's window; the
+    lane slice of a packed row, under a mesh in front of the all-reduce);
+    of every other store, and by default, whole rows."""
     ids = jnp.clip(ids.astype(jnp.int32), 0, spec.padded_capacity - 1)
     arm = arms(spec, pull_lanes=ids.size).pull
+    part = spec.worker_width if worker_part else None
     if arm == "take":
-        return jnp.take(table, ids, axis=0)
+        rows = jnp.take(table, ids, axis=0)
+        return rows if part is None else rows[..., :part]
     if arm == "narrow":
-        return _narrow_pull(table, ids, spec.row_width)
+        return _narrow_pull(table, ids, part or spec.row_width)
     from ..ops.packed import packed_pull
     flat, kernel = ids.reshape(-1), arm == "packed_kernel"
     if spec.num_shards > 1:
-        vals = _packed_pull_on_shards(spec, table, flat, kernel)
+        vals = _packed_pull_on_shards(spec, table, flat, kernel, part)
     else:
-        vals = packed_pull(table, flat, spec.row_width, kernel)
-    return vals.reshape(ids.shape + spec.value_shape)
+        vals = packed_pull(table, flat, spec.row_width, kernel, part)
+    return vals.reshape(
+        ids.shape + (spec.value_shape if part is None else (part,)))
 
 
 @functools.partial(jax.jit, static_argnums=(2,))
@@ -488,16 +520,29 @@ def push_counted(
     ``dp`` workers (``make_train_step`` under such a mesh says so).  An
     ``add`` batch may then be summed worker by worker
     (``worker_reduce``); a bare ``push`` keeps ONE scatter-add in
-    the batch's order, whatever the mesh."""
+    the batch's order, whatever the mesh.
+
+    A store whose spec names a worker's part (``StoreSpec.worker_width``)
+    takes deltas of that trailing width too, which is what its step
+    pushes: lanes ``[0, worker_width)`` of each row's sum, the server's own
+    lanes carrying nothing (they only ever carried ``+0.0`` that no rule
+    reads).  The combine then runs at that width (:func:`arms`'
+    ``push_width``) and the rule is handed ``combined`` that wide; whole-row
+    deltas stay legal, any other width raises.  ``ps_push_row_lanes``, for
+    such a store alone, is the width the push was handed."""
     vr = len(spec.value_shape)
     lead = tuple(deltas.shape[: deltas.ndim - vr])
-    if (vr and tuple(deltas.shape[deltas.ndim - vr:]) != spec.value_shape) or (
+    row = tuple(deltas.shape[deltas.ndim - vr:])
+    part = row == (spec.worker_width,)  # (no row is `(None,)` wide)
+    if (vr and row != spec.value_shape and not part) or (
         lead != tuple(ids.shape)
     ):
         raise ValueError(
             f"push deltas shape {tuple(deltas.shape)} does not match ids "
             f"shape {tuple(ids.shape)} + store value shape "
-            f"{spec.value_shape}"
+            f"{spec.value_shape}" + (
+                "" if spec.worker_width is None else
+                f" (or its worker's part, ({spec.worker_width},))")
         )
     if mask is not None and tuple(mask.shape) != tuple(ids.shape):
         # a length-1 mask would silently broadcast across every lane
@@ -510,18 +555,22 @@ def push_counted(
     # Negative ids would wrap (numpy semantics) before mode="drop" applies;
     # route them to an always-out-of-bounds sentinel so they drop too.
     flat_ids = jnp.where(flat_ids < 0, spec.padded_capacity, flat_ids)
-    flat_deltas = deltas.reshape((-1,) + spec.value_shape)
+    flat_deltas = deltas.reshape((-1,) + row)
     flat_mask = None if mask is None else mask.reshape(-1)
 
+    width = row[0] if part else spec.row_width  # lanes of a row handed in
     arm = arms(spec, push_lanes=flat_ids.shape[0],
-               lanes_over_workers=lanes_over_workers)
+               lanes_over_workers=lanes_over_workers, push_width=width)
     if arm.push == "rule":
         # (a masked lane's delta goes as it is: `_push_rule` sends the lane
         # to the sentinel, and no combine arm lets a dropped lane's value
         # reach a kept row)
-        return (_push_rule_on_shards if arm.on_shards else _push_rule)(
-            spec, table, flat_ids, flat_deltas, flat_mask, arm
-        )
+        table, counted = (
+            _push_rule_on_shards if arm.on_shards else _push_rule
+        )(spec, table, flat_ids, flat_deltas, flat_mask, arm)
+        if spec.worker_width is not None:
+            counted["ps_push_row_lanes"] = jnp.asarray(width, jnp.int32)
+        return table, counted
     s_ids, s_deltas = _phys_scatter_args(
         spec, table, flat_ids, flat_deltas, flat_mask, arm
     )
@@ -595,6 +644,16 @@ def _push_rule(
     eight rows once (``ps_rule_tiles`` counts those
     tile rows, ``ps_combine_kernel_writes`` the combine's).
 
+    ``flat_deltas`` are ``(n, row...)`` whole rows or ``(n, worker_width)``,
+    the worker's part of them (:func:`push_counted`): the combine sums at
+    the width it is handed (``arm.combine`` was read for that width: 20 of
+    DiFacto's 36 lanes through the row kernel as 36 are, 301 of GloVe's 602
+    through the tile kernel into a zeroed block of three registers, not
+    five), every chunk of sums is cut out that wide, and the rule is called
+    as ``update(whole current rows, combined)`` with ``combined`` that wide
+    (a rule reads it by lane number from the front); the write-back writes
+    whole rows either way.
+
     ``flat_deltas`` come as the logic made them, a masked lane's too
     (``live`` false): every such lane, like every id past the table, is
     sent to the sentinel here, each combine arm drops a sentinel's lanes,
@@ -622,6 +681,7 @@ def _push_rule(
         if live is not None:
             dead = dead | ~live
         vals = flat_deltas.reshape(n, -1).astype(table.dtype)
+        width = vals.shape[1]  # the whole row's lanes, or the worker's part
         row_ids, combined, issued = combine_runs(
             jnp.where(dead, sentinel, flat_ids), vals, sentinel, arm.combine,
         )
@@ -644,8 +704,10 @@ def _push_rule(
     def rewrite(i, carry):
         table, moved = carry  # tiles, or a packed store's physical rows
         ids = jax.lax.dynamic_slice(row_ids, (i * chunk,), (chunk,))
+        # (the tile kernel's sums come in whole registers, zeros past
+        # `width`: the chunk is cut out at the width the rule is handed)
         sums = jax.lax.dynamic_slice(
-            combined, (i * chunk, 0), (chunk, combined.shape[1])
+            combined, (i * chunk, 0), (chunk, width)
         )
         if packed:
             table, wrote = _rewrite_packed(
@@ -656,7 +718,7 @@ def _push_rule(
             current = pull(spec, table, ids) if block is None else jnp.take(
                 table, ids, axis=0, mode="clip")
             new = update_fn(
-                current, sums.reshape((chunk,) + spec.value_shape),
+                current, sums.reshape((chunk,) + _rule_takes(spec, width)),
             ).astype(table.dtype)
         if arm.write_back == "tile_set":
             table, opened = sorted_tile_set(table, ids, new)
@@ -682,9 +744,10 @@ def _rewrite_packed(
     """One chunk of :func:`_push_rule` for a PACKED table: ``(table,
     physical rows written)``, or, where the tile kernel wrote a flat wide
     row's (below), ``(table, tile rows read and written)``.  ``ids`` are
-    sorted and distinct, the sentinel last; ``sums`` ``(chunk, row_width)``
-    (a flat wide row's from the tile kernel's sums: ``(chunk, physical
-    lanes)``, zeros past its width).
+    sorted and distinct, the sentinel last; ``sums`` ``(chunk, row_width)``,
+    or ``(chunk, worker_width)`` where the push carried the worker's part
+    of the rows (:func:`_push_rule` cuts a chunk out at the width pushed,
+    whatever registers the combine summed it in).
 
     Under ``ps.rule`` ONE gather of the chunk's physical rows (``ids //
     k``, whole 128-lane registers), the lane slice down to each id's
@@ -724,12 +787,10 @@ def _rewrite_packed(
     phys, sub = ids // k, ids % k  # the sentinel: one past the last row
     with jax.named_scope("ps.rule"):
         rows = jnp.take(table, phys, axis=0, mode="clip")
-        if sums.shape[1] != d:  # a flat wide row's, in whole registers
-            sums = sums[:, :d]
         new = update_fn(
             _sub_row_slice(rows, ids, d).reshape(
                 (chunk,) + spec.value_shape),
-            sums.reshape((chunk,) + spec.value_shape),
+            sums.reshape((chunk,) + _rule_takes(spec, sums.shape[1])),
         ).astype(table.dtype).reshape(chunk, d)
     writes = jnp.concatenate(
         [jnp.ones((1,), bool), phys[1:] != phys[:-1]]
@@ -757,6 +818,13 @@ def _rewrite_packed(
     else:
         table = table.at[at].set(merged, mode="drop")
     return table, jnp.sum(writes, dtype=jnp.int32)
+
+
+def _rule_takes(spec: StoreSpec, width: int) -> Tuple[int, ...]:
+    """The trailing shape of the ``combined`` a rule is handed for sums
+    ``width`` lanes wide: the row's own shape, or, for the sums of a push
+    that carried the worker's part alone, that many lanes."""
+    return spec.value_shape if width == spec.row_width else (width,)
 
 
 def _physical_rows(spec: StoreSpec, rows: Array) -> Array:
@@ -842,6 +910,7 @@ class Arms:
 def arms(
     spec: StoreSpec, *, pull_lanes: Optional[int] = None,
     push_lanes: Optional[int] = None, lanes_over_workers: bool = False,
+    push_width: Optional[int] = None,
 ) -> Arms:
     """THE one reader of which form a pull of ``pull_lanes`` ids and a push
     of ``push_lanes`` lanes take, from what the spec and the batch hold: the
@@ -850,7 +919,11 @@ def arms(
     refusals.  All of it is shapes: static per compiled step.  A lane count
     of None asks whether ANY pull / push of the store may take a kernel
     (:func:`_preload_tile_kernel`).  ``lanes_over_workers``: the caller
-    knows the batch's lanes lie split over ``dp`` (:func:`push_counted`).  A
+    knows the batch's lanes lie split over ``dp`` (:func:`push_counted`).
+    ``push_width``: the lanes of a row the push is handed, None for what a
+    STEP pushes (the worker's part where the spec names one,
+    ``StoreSpec.worker_width``, else the whole row); a rule's sums are made
+    at that width, so it chooses the ``combine``.  A
     store a kernel REFUSES (bfloat16, a pinned layout Mosaic cannot tile, a
     batch under one block) keeps XLA's arm and is counted and warned of once
     a physical row shape, dtype and arm (``ops/row_update.refusal_count``).
@@ -861,6 +934,9 @@ def arms(
     TPU a pull reads ``take`` / ``narrow`` / ``packed_selects``, an add push
     ``xla_add`` (``worker_reduce`` as below) and ``selects``, a rule push
     ``sort`` / ``scatter_add`` and ``xla_set``, ``on_shards`` as below.
+    The worker's part of a row (the last rows of the second table) changes
+    no pull arm, only the width the arm cuts (:func:`pull`), and no
+    write-back: the rule writes whole rows.
 
     An ``add`` store (``combine`` and ``write_back`` ``""``):
 
@@ -887,12 +963,17 @@ def arms(
     3 lanes, held at its tile of 4    narrow          sort         tile_set     no         6     34 35
     6 lanes, held at its tile of 8    narrow          row_kernel   tile_set     no         none  35 46
     (2, 2) lanes: rank 2, no tile     take            sort         xla_set      no         none  35
-    packed k 3 (36 lanes)             packed_kernel   row_kernel   row_set      no         9     46 47 54
-    the same over ps 4, dp 1          packed_kernel   row_kernel   row_set      yes        12    52
+    packed k 3 (36 lanes)             packed_kernel   row_kernel   row_set      no         none  46 47 54
+    the same over ps 4, dp 1          packed_kernel   row_kernel   row_set      yes        none  52
     the same over ps 2, dp 2          packed_kernel   scatter_add  xla_set      no         none  52
     packed k 1, 1 reg (pinned, 100)   packed_selects  row_kernel   row_set      no         none  47
-    packed k 1, 5 regs (602 lanes)    packed_selects  tile_kernel  tile_assign  no         13    55 57
+    packed k 1, 5 regs (602 lanes)    packed_selects  tile_kernel  tile_assign  no         none  55 57
     dense 1 reg (100 lanes)           take            row_kernel   xla_set      no         none  46
+    packed k 3, the worker's 20 / 36  packed_kernel   row_kernel   row_set      no         9     59
+    the worker's 20 / 36 over ps 4    packed_kernel   row_kernel   row_set      yes        12    59
+    5 regs, the worker's 301 / 602    packed_selects  tile_kernel  tile_assign  no         13    59
+    5 regs, the worker's 100 / 602    packed_selects  row_kernel   tile_assign  no         none  59
+    5 regs, the worker's 3 / 602      packed_selects  sort         tile_assign  no         none  59
     ================================  ==============  ===========  ===========  =========  ====  ========
 
     Reasons the code does not show.  A mesh keeps an add push XLA's because
@@ -986,12 +1067,17 @@ def arms(
     elif tpu and spec.narrow_rule:
         taken("write-back of a rule's narrow rows",
               row_update.set_refusal(shape, spec.dtype))
-    if spec.row_width <= dedup.SORT_CARRIES_LANES:
+    # the sums are as wide as the rows the push is handed: the whole row
+    # (as it lies: flat in several registers or not), or the worker's part,
+    # which is several registers wide where it is over 128 lanes
+    width = push_width or spec.worker_width or spec.row_width
+    wide = flat_wide if width == spec.row_width else width > 128
+    if width <= dedup.SORT_CARRIES_LANES:
         combine = "sort"
     elif one_block and tpu and taken(
             "the sum of a rule's wide rows", dedup.kernel_refusal(
-                phys if flat_wide else spec.row_width, spec.dtype)):
-        combine = "tile_kernel" if flat_wide else "row_kernel"
+                packed.phys_width(width) if wide else width, spec.dtype)):
+        combine = "tile_kernel" if wide else "row_kernel"
     else:
         combine = "scatter_add"
     return Arms(pull, "rule", "", combine, write_back, on_shards)
@@ -1019,8 +1105,14 @@ def step_counts(
     several rows to a physical row, which arm sliced the pulled rows
     (``ps_slice_kernel``) and, where ``update`` is ``"add"``, which shifted
     the pushed deltas (``ps_shift_kernel``), 1 for the kernel, as this
-    trace read them (:func:`arms`)."""
+    trace read them (:func:`arms`).  A store whose spec names a worker's
+    part (``StoreSpec.worker_width``) says how many lanes of a row crossed,
+    a key: ``ps_pull_row_lanes`` here (a step pulls the worker's part) beside
+    :func:`push_counted`'s ``ps_push_row_lanes``; every other store's whole
+    row crosses and its step hands out neither."""
     out = dict(counted or {})
+    if spec.worker_width is not None:
+        out["ps_pull_row_lanes"] = jnp.asarray(spec.worker_width, jnp.int32)
     if spec.pack > 1:
         arm = arms(spec, pull_lanes=pull_lanes, push_lanes=push_lanes)
         out["ps_slice_kernel"] = jnp.asarray(
@@ -1057,6 +1149,11 @@ def publish_counts(outs: dict, registry: Any, total, peak) -> None:
         ).set(total(outs["ps_push_tile_rows"]))
     if "ps_rule_rows" not in outs:
         return
+    if "ps_pull_row_lanes" in outs:
+        registry.gauge("store_pull_row_lanes", component="train").set(
+            peak(outs["ps_pull_row_lanes"]))
+        registry.gauge("store_push_row_lanes", component="train").set(
+            peak(outs["ps_push_row_lanes"]))
     registry.gauge("store_rule_keys", component="train").set(
         total(outs["ps_rule_keys"]))
     registry.gauge("store_rule_rows", component="train").set(
@@ -1177,9 +1274,10 @@ def _push_add_over_workers(
     return table + total
 
 
-@functools.partial(jax.jit, static_argnums=(0, 3))
+@functools.partial(jax.jit, static_argnums=(0, 3, 4))
 def _packed_pull_on_shards(
-    spec: StoreSpec, table: Array, ids: Array, kernel: bool = False
+    spec: StoreSpec, table: Array, ids: Array, kernel: bool = False,
+    width: Optional[int] = None,
 ) -> Array:
     """The packed pull of ``ids`` (flat, pre-clipped) from a table sharded
     over ``ps``: each shard gathers physical rows of its own block, slices
@@ -1193,7 +1291,10 @@ def _packed_pull_on_shards(
     them, row-major and padded to 128 lanes again on the TPU (16.7 ms a
     step against 5-8; PERF.md section 6, PR 31).  The ids stay split over
     the mesh's other axes (``dp``) where the batch is.  ``kernel``: the
-    slice's arm (:func:`arms`).  Jitted for the reason
+    slice's arm (:func:`arms`).  ``width``: a row's first ``width`` lanes
+    alone (the worker's part, :func:`pull`), cut by that slice, so the
+    all-reduce moves no lane a worker does not read (DiFacto on four
+    chips: ``f32[32768,39,20]`` for ``[..., 36]``).  Jitted for the reason
     ``packed_pull`` is."""
     from ..ops.packed import sub_row_slice
 
@@ -1211,7 +1312,7 @@ def _packed_pull_on_shards(
         # gather that keeps hitting one row takes twice as long a row
         vals = sub_row_slice(
             jnp.take(block, rel, axis=0, mode="wrap"), ids, spec.row_width,
-            kernel,
+            kernel, width,
         )
         return jnp.where(mine[:, None], vals, jnp.zeros_like(vals))[None]
 
@@ -1532,6 +1633,7 @@ class ShardedParamStore:
         mesh: Optional[Mesh] = None,
         ps_axis: str = "ps",
         layout: str = "dense",
+        worker_width: Optional[int] = None,
     ) -> "ShardedParamStore":
         spec = StoreSpec(
             capacity=capacity,
@@ -1541,6 +1643,7 @@ class ShardedParamStore:
             mesh=mesh,
             ps_axis=ps_axis,
             layout=_resolve_layout(layout, update, tuple(value_shape)),
+            worker_width=worker_width,
         )
         with _placing():
             return cls(spec, create_table(spec, init_fn))

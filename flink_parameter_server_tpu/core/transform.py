@@ -326,7 +326,8 @@ def make_train_step(logic: BatchedWorkerLogic, spec) -> Callable:
         # a trace reduction finds pull, compute and push by them whatever
         # XLA numbers its fusions
         with scope("ps.pull"):
-            pulled = store_mod.pull(spec, table, ids)
+            # (of a rule store whose row has a worker's part, that part)
+            pulled = store_mod.pull(spec, table, ids, worker_part=True)
         with scope("ps.compute"):
             state, req, out = logic.step(state, batch, pulled)
         with scope("ps.push"):

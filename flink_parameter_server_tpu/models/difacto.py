@@ -17,13 +17,16 @@ squared gradients, as the source stores them.  A feature's embedding is LIVE
 once its count passes ``V_threshold`` and (``l1_shrk``) its weight is not
 zero: ``a_i = (c_i > V_threshold) and (w_i != 0)``.
 
-Workers pull rows, use ``V_i`` where ``a_i`` and 0 elsewhere in the degree-2
-FM of ``models/factorization_machine.forward_gradients``, and push RAW
-gradients, no rate, in the row's shape (the lanes of ``z, s, c, S`` zero):
-``gw = g x_i``, ``gV = a_i g x_i (sum_j a_j V_j x_j - V_i x_i)``, ``g = p -
-y``.  The store sums a minibatch's pushes per feature to ``(Gw, GV)`` and runs
-the rule once a touched row (``core/store.push``), on the row as it stood at
-the start of the step:
+Workers pull the WORKER'S PART of a row, its first ``4 + k`` lanes ``(w, z,
+s, c, V)`` (they read ``w``, ``c`` and ``V``; a prefix keeps the row's layout
+as it is, and ``S``, the half of the row that is AdaGrad's, never leaves the
+server: ``make_store`` sets ``StoreSpec.worker_width``), use ``V_i`` where
+``a_i`` and 0 elsewhere in the degree-2 FM of
+``models/factorization_machine.forward_gradients``, and push RAW gradients,
+no rate, that wide, ``(gw, 0, 0, 0, gV)``: ``gw = g x_i``, ``gV = a_i g x_i
+(sum_j a_j V_j x_j - V_i x_i)``, ``g = p - y``.  The store sums a minibatch's
+pushes per feature to ``(Gw, GV)`` and runs the rule once a touched row
+(``core/store.push``), on the WHOLE row as it stood at the start of the step:
 
     UpdateW   gw = Gw + l2 w;   s' = sqrt(s^2 + gw^2)
               z' = z - gw + (s' - s) / lr * w
@@ -77,7 +80,9 @@ def embedding_live(w: Array, c: Array, threshold: float) -> Array:
 class DiFactoUpdater:
     """The rule, with its hyper-parameters as data (``dmlc/difacto``'s
     defaults): ``rule(current, combined)`` is a ``StoreSpec.update`` over
-    rows ``(..., 4 + 2 dim)``, vectorised over the leading axes."""
+    rows ``(..., 4 + 2 dim)``, vectorised over the leading axes.
+    ``combined`` is read by lane number, ``W`` and ``[4, 4 + dim)``: the
+    sums of whole-row pushes or of the worker's part, ``(..., 4 + dim)``."""
 
     lr: float = 0.01
     lr_beta: float = 1.0
@@ -127,8 +132,12 @@ class DiFactoUpdater:
 class DiFacto(BatchedWorkerLogic):
     """Batch keys as ``FactorizationMachine``'s: ``ids`` (B,K) int (-1 in a
     dead lane), ``values`` (B,K) float, ``feat_mask`` (B,K) bool, ``label``
-    (B,) ±1, ``mask`` (B,) bool.  ``pulled`` is ``(B, K, 4 + 2 dim)``; the
-    worker is stateless (weights, embeddings and both optimisers' state live
+    (B,) ±1, ``mask`` (B,) bool.  ``pulled`` is ``(B, K, 4 + dim)``, the
+    worker's part of the rows, and the pushed gradients are that wide (of a
+    store whose spec names no worker's part, one reloaded by
+    ``ShardedParamStore.from_values``, whole rows come and whole rows go,
+    the lanes past ``V`` zeros: the step answers at the width it was
+    handed); the worker is stateless (weights, embeddings and both optimisers' state live
     on the server).  Beside ``prediction`` and ``loss`` the outputs carry two
     counts of the step's lanes, made on the device from the logic's own
     masks: ``fm_live_keys`` (the live lanes of the step's keys) and
@@ -181,15 +190,13 @@ class DiFacto(BatchedWorkerLogic):
         with scope("ps.gate"):
             gv = jnp.where(v_live[..., None], gv, 0.0)
         with scope("ps.delta_build"):
-            # raw gradients in the row's shape: (gw, 0, 0, 0, gV, 0 x dim)
+            # raw gradients at the width the rows came: (gw, 0, 0, 0, gV),
+            # the worker's part; whole rows get zeros for the lanes of S
             lead = gw.shape
+            past = pulled.shape[-1] - (v_at + dim)
             deltas = jnp.concatenate(
-                [
-                    gw[..., None],
-                    jnp.zeros(lead + (STATE_LANES - 1,), gw.dtype),
-                    gv,
-                    jnp.zeros(lead + (dim,), gw.dtype),
-                ],
+                [gw[..., None], jnp.zeros(lead + (v_at - 1,), gw.dtype), gv]
+                + [jnp.zeros(lead + (past,), gw.dtype)] * (past > 0),
                 axis=-1,
             )
         out = {
@@ -241,12 +248,15 @@ def make_store(
     (``ShardedParamStore.create``).  ``seed`` may be traced
     (``jax.jit(lambda seed: make_store(..., seed=seed))``: one program
     whatever the seed).  The rows' place on the chip is
-    ``core/store._resolve_layout``'s to choose."""
+    ``core/store._resolve_layout``'s to choose.  The worker's part of a row
+    is ``(w, z, s, c, V)``, ``4 + dim`` lanes (``StoreSpec.worker_width``):
+    a step pulls and pushes those, ``S`` stays on the server."""
     dtype = dtype or jnp.float32
     return ShardedParamStore.create(
         config.num_features, (config.row_lanes,), dtype=dtype,
         init_fn=init_fn or fresh_rows(config, updater, seed=seed, dtype=dtype),
         update=updater, mesh=mesh, layout=layout,
+        worker_width=STATE_LANES + config.dim,
     )
 
 

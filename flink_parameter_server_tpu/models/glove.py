@@ -19,8 +19,12 @@ step (paper section 3, equations 8 and 9):
     d = w_i . w~_j + b_i + b~_j - ln X
     f = min(1, (X / x_max)^alpha)
     s = f d                                  (cost 1/2 f d^2)
-    push to row i:      (s w~_j, s, 0 ... 0)
-    push to row V + j:  (s w_i,  s, 0 ... 0)
+    push to row i:      (s w~_j, s)
+    push to row V + j:  (s w_i,  s)
+
+The worker reads and writes the WORKER'S PART of a row, its first ``dim + 1``
+lanes (``make_store`` sets ``StoreSpec.worker_width``): the accumulators, the
+other half of the row, never leave the server.
 
 and the server's rule, once a distinct row a step, ``G`` the sum of the
 batch's pushes to the row (:class:`GloVeAdaGrad`; ``glove.c`` multiplies the
@@ -79,7 +83,9 @@ class GloVeAdaGrad:
     ``StoreSpec.update`` (rows ``(..., 2 p)``, vectorised over the leading
     axes): the first ``p`` lanes the parameters, the last ``p`` their
     accumulated squared gradients, read BEFORE this step's square is
-    added, as ``glove.c`` reads ``gradsq``."""
+    added, as ``glove.c`` reads ``gradsq``.  ``combined`` is read over its
+    first ``p`` lanes: the sums of whole-row pushes, or of the worker's
+    part, ``(..., p)``."""
 
     eta: float = 0.05
 
@@ -109,7 +115,10 @@ class GloVe(BatchedWorkerLogic):
     """Batch keys: ``word`` (B,) and ``context`` (B,) int word ids, ``count``
     (B,) float ``X > 0``, ``mask`` (B,) bool.  The step's keys are ``(B,
     2)``: row ``word`` and row ``vocab_size + context``; ``pulled`` is
-    ``(B, 2, 2 (dim + 1))``.  The worker is stateless (vectors, biases and
+    ``(B, 2, dim + 1)``, the worker's part of the rows, and the pushed
+    gradients are that wide (handed whole rows, by a store whose spec names
+    no worker's part, the step answers with whole rows, zeros for the
+    accumulators' lanes).  The worker is stateless (vectors, biases and
     accumulators live on the server)."""
 
     def __init__(self, config: GloVeConfig):
@@ -137,16 +146,17 @@ class GloVe(BatchedWorkerLogic):
         weight = jnp.minimum(1.0, (x / cfg.x_max) ** cfg.alpha)
         s = weight * diff
         with scope("ps.cooc_grad_rows"):
-            # raw gradients in the row's shape: each side takes the OTHER
-            # side's vector times s, s for its bias, zeros for the
-            # accumulators' lanes
+            # raw gradients at the width the rows came: each side takes the
+            # OTHER side's vector times s and s for its bias, the worker's
+            # part; whole rows get zeros for the accumulators' lanes
             other = vec[:, ::-1]
+            past = pulled.shape[-1] - cfg.params
             deltas = jnp.concatenate(
                 [
                     s[:, None, None] * other,
                     jnp.broadcast_to(s[:, None, None], other.shape[:2] + (1,)),
-                    jnp.zeros(other.shape[:2] + (cfg.params,), other.dtype),
-                ],
+                ] + [jnp.zeros(other.shape[:2] + (past,), other.dtype)]
+                * (past > 0),
                 axis=-1,
             )
         mask = batch["mask"]
@@ -187,12 +197,14 @@ def make_store(
     may be traced (``jax.jit(lambda seed: make_store(..., seed=seed))``: one
     program whatever the seed).  The rows' place on the chip is
     ``core/store._resolve_layout``'s to choose: flat in whole registers
-    where a row is wider than one."""
+    where a row is wider than one.  The worker's part of a row is its
+    vector and bias, ``dim + 1`` lanes (``StoreSpec.worker_width``): a step
+    pulls and pushes those, the accumulators stay on the server."""
     dtype = dtype or jnp.float32
     return ShardedParamStore.create(
         config.num_rows, (config.row_lanes,), dtype=dtype,
         init_fn=init_fn or fresh_rows(config, seed=seed, dtype=dtype),
-        update=rule, mesh=mesh, layout=layout,
+        update=rule, mesh=mesh, layout=layout, worker_width=config.params,
     )
 
 

@@ -38,7 +38,7 @@ slices each touched logical row out, runs the rule and writes each touched
 physical row back once, the new rows shifted to their windows and merged by
 selects (``core/store._rewrite_packed``).
 
-**The lane slice as a kernel** (``core/store._slice_kernel_takes``).  XLA
+**The lane slice as a kernel** (``core/store.arms``' ``pull``).  XLA
 compiles :func:`_sub_row_slice` row-major: ``k`` lane rotates and selects
 over every 128-lane register of the gathered rows, then a copy of the
 ``(n, d)`` result to the feature-major form ``{0,1}`` that whatever reads
@@ -53,7 +53,7 @@ gathered rows once, transposes it in VMEM to ``(128, block)``, where the
 held feature-major.  Selects only, so the same bits as
 :func:`_sub_row_slice`, NaN, infinities and -0.0 included.
 
-**The lane shift as a kernel** (``core/store._shift_kernel_takes``).  XLA
+**The lane shift as a kernel** (``core/store.arms``' ``shift``).  XLA
 compiles :func:`lane_shift_deltas` column-major over the ``(n, 128)``
 buffer and then relays it to the row-major rows the scatter-add reads (FM:
 2.23 + 1.99 ms a step on the v5e for 87 MB in and 654 MB out, and 0.38 for
@@ -125,20 +125,25 @@ def unpack_table(packed: Array, capacity: int, row_width: int) -> Array:
     return v[:capacity]
 
 
-def _sub_row_slice(rows: Array, ids: Array, row_width: int) -> Array:
+def _sub_row_slice(
+    rows: Array, ids: Array, row_width: int, width: Optional[int] = None
+) -> Array:
     """``rows[i, t*d:(t+1)*d]`` with ``t = ids[i] % k``: ``k`` STATIC lane
     slices and a ``select`` on the sub-row index, one pass over the
     batch-sized buffer.  Never a ``take_along_axis``: that is a gather of
     scalars (~10 ns an element on the TPU, PERF.md section 6, PR 29), and
     never a 0/1 matmul or a masked sum: ``0 * NaN`` would spread one
-    non-finite element over its physical row."""
+    non-finite element over its physical row.  ``width``: only the first
+    ``width`` lanes of each logical row (the worker's part of a rule
+    store's row, ``StoreSpec.worker_width``), cut by the same slices."""
     k, d = pack_k(row_width), row_width
+    w = d if width is None else width
     if k == 1:
-        return rows[:, :d]
+        return rows[:, :w]
     t = (ids.astype(jnp.int32) % k)[:, None]
-    out = rows[:, :d]
+    out = rows[:, :w]
     for j in range(1, k):
-        out = jnp.where(t == j, rows[:, j * d:(j + 1) * d], out)
+        out = jnp.where(t == j, rows[:, j * d:j * d + w], out)
     return out
 
 
@@ -154,24 +159,27 @@ def slice_refusal(n: int, dtype, row_width: int) -> Optional[str]:
     return None
 
 
-def _slice_kernel(t_ref, rows_ref, out_ref, *, k: int, d: int):
-    # (block, 128) -> (128, block): a window is now d sublanes of every lane
+def _slice_kernel(t_ref, rows_ref, out_ref, *, k: int, d: int, w: int):
+    # (block, 128) -> (128, block): a window is now d sublanes of every lane,
+    # of which the first w are wanted
     by_lane = rows_ref[...].T
-    t = jnp.broadcast_to(t_ref[...], (d, t_ref.shape[1]))
-    out = by_lane[:d]
+    t = jnp.broadcast_to(t_ref[...], (w, t_ref.shape[1]))
+    out = by_lane[:w]
     for j in range(1, k):
-        out = jnp.where(t == j, by_lane[j * d:(j + 1) * d], out)
+        out = jnp.where(t == j, by_lane[j * d:j * d + w], out)
     out_ref[...] = out
 
 
 def sub_row_slice_kernel(
-    rows: Array, ids: Array, row_width: int,
+    rows: Array, ids: Array, row_width: int, width: Optional[int] = None,
     *, block: Optional[int] = None, interpret: Optional[bool] = None,
 ) -> Array:
     """:func:`_sub_row_slice` as one Pallas kernel (the module docstring
     says how), bit for bit; the ``(n, d)`` result is the transpose of the
     kernel's ``(d, n)`` output, so on the TPU it is held feature-major with
-    no copy.  ``n`` need not be whole blocks: the pipeline cuts the last
+    no copy (``(n, width)`` of ``(width, n)`` where only a row's first
+    ``width`` lanes are asked for: the kernel writes no other).  ``n`` need
+    not be whole blocks: the pipeline cuts the last
     one.  Off the TPU the kernel is interpreted (``interpret=None``: by the
     default backend)."""
     from .row_update import _pallas
@@ -180,17 +188,18 @@ def sub_row_slice_kernel(
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     n, d, k = rows.shape[0], row_width, pack_k(row_width)
+    w = d if width is None else width
     block = SLICE_BLOCK if block is None else block
     t = (ids.astype(jnp.int32) % k).reshape(1, n)
     out = pl.pallas_call(
-        functools.partial(_slice_kernel, k=k, d=d),
-        out_shape=jax.ShapeDtypeStruct((d, n), rows.dtype),
+        functools.partial(_slice_kernel, k=k, d=d, w=w),
+        out_shape=jax.ShapeDtypeStruct((w, n), rows.dtype),
         grid=(pl.cdiv(n, block),),
         in_specs=[
             pl.BlockSpec((1, block), lambda i: (0, i)),
             pl.BlockSpec((block, LANES), lambda i: (i, 0)),
         ],
-        out_specs=pl.BlockSpec((d, block), lambda i: (0, i)),
+        out_specs=pl.BlockSpec((w, block), lambda i: (0, i)),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)
         ),
@@ -201,22 +210,32 @@ def sub_row_slice_kernel(
 
 
 def sub_row_slice(
-    rows: Array, ids: Array, row_width: int, kernel: bool = False
+    rows: Array, ids: Array, row_width: int, kernel: bool = False,
+    width: Optional[int] = None,
 ) -> Array:
     """The lane slice of gathered physical rows in the arm the caller read
-    (``core/store._slice_kernel_takes``)."""
+    (``core/store.arms``' ``pull``), down to a row's first ``width`` lanes
+    where the caller wants no more."""
     if kernel:
-        return sub_row_slice_kernel(rows, ids, row_width)
-    return _sub_row_slice(rows, ids, row_width)
+        return sub_row_slice_kernel(rows, ids, row_width, width)
+    return _sub_row_slice(rows, ids, row_width, width)
 
 
-@functools.partial(jax.jit, static_argnums=(2, 3))
+@functools.partial(jax.jit, static_argnums=(2, 3, 4))
 def packed_pull(
-    packed: Array, ids: Array, row_width: int, kernel: bool = False
+    packed: Array, ids: Array, row_width: int, kernel: bool = False,
+    width: Optional[int] = None,
 ) -> Array:
     """Gather logical rows ``ids`` (pre-clipped) from the packed table:
     one gather of whole 128-lane physical rows, then the lane slice
-    (``kernel``: :func:`sub_row_slice`).
+    (``kernel``: :func:`sub_row_slice`), cut to ``width`` lanes of each row
+    where given.  The gather moves whole physical rows whatever ``width``:
+    the TPU's gather takes whole rows of its operand, a slice of the table
+    in front of it (the leading three of GloVe's five registers) is a COPY
+    of those registers (6.7 GB beside cell 13's 11.24 GB table), and a
+    gather window narrower than the row (``slice_sizes={1,384}``) is
+    expanded to a loop over the ids (both compiled for a v5e: PERF.md
+    section 6, PR 59).
     Jitted, so that a pull outside a jitted step (``store.pull(ids)`` by
     hand, a checkpoint's spot check) is one program and not ``3 k`` eager
     ones, each compiled on its first use."""
@@ -225,7 +244,7 @@ def packed_pull(
     phys_vals = jnp.take(
         packed, ids // pack_k(row_width), axis=0, mode="clip"
     )
-    return sub_row_slice(phys_vals, ids, row_width, kernel)
+    return sub_row_slice(phys_vals, ids, row_width, kernel, width)
 
 
 def lane_shift_deltas(deltas: Array, ids: Array, row_width: int) -> Array:

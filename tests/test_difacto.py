@@ -434,6 +434,49 @@ def test_the_driver_sets_the_gate_s_gauges_after_the_loop():
     assert np.isfinite(np.asarray(result.store.table)).all()
 
 
+@pytest.mark.parametrize("store", ["make_store", "reloaded", "fm"])
+def test_the_driver_publishes_the_lanes_of_a_row_that_crossed(store):
+    """``store_pull_row_lanes`` / ``store_push_row_lanes``: ``make_store``'s
+    rows have a worker's part, ``(w, z, s, c, V)``, and a step pulls and
+    pushes those ``4 + dim`` lanes; a store that names no part (a reload by
+    ``from_values``; an FM's add store) moves whole rows and its step hands
+    out no such count: the gauges stay unset, the whole row's width is the
+    spec's to say."""
+    from flink_parameter_server_tpu.telemetry.registry import MetricsRegistry
+
+    rng = np.random.default_rng(59)
+    batches = [_batch(rng.integers(0, 100, (16, 4)), rng) for _ in range(2)]
+    cfg = df.DiFactoConfig(100, DIM)
+    logic = df.DiFacto(cfg)
+    if store == "make_store":
+        made = df.make_store(cfg, seed=3)
+        assert made.spec.worker_width == df.STATE_LANES + DIM == 8
+    elif store == "reloaded":
+        made = _store(_rows(9, n=100))
+        assert made.spec.worker_width is None
+    else:
+        fm_cfg = fmm.FMConfig(num_features=100, dim=DIM)
+        logic, made = fmm.FactorizationMachine(fm_cfg), fmm.make_store(fm_cfg)
+        assert made.spec.worker_width is None
+    width = made.spec.row_width
+    registry = MetricsRegistry()
+    driver = StreamingDriver(
+        logic, made, registry=registry,
+        config=DriverConfig(steps_per_call=1, dump_model=False))
+    result = driver.run(iter(batches), collect_outputs=True)
+    gauges = registry.snapshot()
+    last = result.worker_outputs[-1]
+    if store == "make_store":
+        assert gauges["store_pull_row_lanes"][0]["value"] == 8 < width == 12
+        assert gauges["store_push_row_lanes"][0]["value"] == 8
+        assert int(last["ps_pull_row_lanes"]) == 8
+    else:
+        assert "store_pull_row_lanes" not in gauges
+        assert "store_push_row_lanes" not in gauges
+        assert "ps_pull_row_lanes" not in last
+    assert np.isfinite(np.asarray(result.store.table)).all()
+
+
 @pytest.mark.parametrize("arm", ["xla", "row_kernel"])
 def test_the_driver_publishes_the_descriptors_the_combine_issued(
         arm, monkeypatch, steer_arms):

@@ -212,3 +212,6 @@ def test_the_driver_sets_the_rule_s_gauges_after_the_loop():
     assert gauges["store_rule_rows"][0]["value"] == len(np.unique(keys))
     assert gauges["store_rule_packed_rows"][0]["value"] == len(np.unique(keys))
     assert gauges["store_combine_kernel_lanes"][0]["value"] == 0  # a CPU
+    # the worker's part of a row crossed, the vector and its bias
+    assert gauges["store_pull_row_lanes"][0]["value"] == MODEL.params
+    assert gauges["store_push_row_lanes"][0]["value"] == MODEL.params

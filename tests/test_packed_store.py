@@ -210,8 +210,8 @@ def test_a_pull_under_one_block_keeps_the_select_arm(n, kernel, monkeypatch):
     # the backend is steered, the kernel still has to be interpreted
     monkeypatch.setattr(
         packed_mod, "sub_row_slice_kernel",
-        lambda rows, ids, d: calls.append(rows.shape) or real(
-            rows, ids, d, interpret=True))
+        lambda rows, ids, d, width=None: calls.append(rows.shape) or real(
+            rows, ids, d, width, interpret=True))
     n0 = row_update.refusal_count()
     if kernel:
         got = store.pull(ids)

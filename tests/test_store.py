@@ -11,6 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from flink_parameter_server_tpu.core.batched import PushRequest
 from flink_parameter_server_tpu.core.store import ShardedParamStore
 from flink_parameter_server_tpu.core.transform import make_train_step
 from flink_parameter_server_tpu.parallel.collectives import (
@@ -2213,7 +2214,29 @@ ARMS_ON_A_TPU = [
     ("dense 1 reg (100 lanes)", (100,), _RULE, "auto", None, 1_000,
      8_192, 8_192, False,
      ("take", "rule", "", "row_kernel", "xla_set", False), 0),
+    # the worker's part of a row (`WORKER_WIDTHS`): the pull arms and the
+    # write-backs of the whole row, the combine by the width that is pushed
+    ("packed k 3, the worker's 20 / 36", (36,), _RULE, "auto", None, 3_000,
+     8_192, 8_192, False,
+     ("packed_kernel", "rule", "", "row_kernel", "row_set", False), 0),
+    ("the worker's 20 / 36 over ps 4", (36,), _RULE, "auto", (1, 4), 3_000,
+     8_192, 8_192, False,
+     ("packed_kernel", "rule", "", "row_kernel", "row_set", True), 0),
+    ("5 regs, the worker's 301 / 602", (602,), _RULE, "auto", None, 1_000,
+     8_192, 8_192, False,
+     ("packed_selects", "rule", "", "tile_kernel", "tile_assign", False), 0),
+    ("5 regs, the worker's 100 / 602", (602,), _RULE, "auto", None, 1_000,
+     8_192, 8_192, False,
+     ("packed_selects", "rule", "", "row_kernel", "tile_assign", False), 0),
+    ("5 regs, the worker's 3 / 602", (602,), _RULE, "auto", None, 1_000,
+     8_192, 8_192, False,
+     ("packed_selects", "rule", "", "sort", "tile_assign", False), 0),
 ]
+WORKER_WIDTHS = {
+    "packed k 3, the worker's 20 / 36": 20, "the worker's 20 / 36 over ps 4": 20,
+    "5 regs, the worker's 301 / 602": 301, "5 regs, the worker's 100 / 602": 100,
+    "5 regs, the worker's 3 / 602": 3,
+}
 # off a TPU: XLA's forms; where the push runs is read from the mesh alone
 ARMS_OFF_IT = {
     "dense 1 reg, 1,024+ <= rows / 8": ("take", "xla_add", "", "", "", False),
@@ -2228,6 +2251,10 @@ ARMS_OFF_IT = {
         "packed_selects", "rule", "", "scatter_add", "xla_set", False),
     "packed k 1, 5 regs (602 lanes)": (
         "packed_selects", "rule", "", "scatter_add", "xla_set", False),
+    "5 regs, the worker's 301 / 602": (
+        "packed_selects", "rule", "", "scatter_add", "xla_set", False),
+    "5 regs, the worker's 3 / 602": (
+        "packed_selects", "rule", "", "sort", "xla_set", False),
 }
 
 
@@ -2253,17 +2280,19 @@ def test_the_arms_table(backend, row, mesh_devices, monkeypatch):
     mesh = mesh_shape and make_mesh(
         *mesh_shape, devices=mesh_devices[:mesh_shape[0] * mesh_shape[1]])
     rule = _sticky_rule if update == _RULE else "add"
+    part = WORKER_WIDTHS.get(what)
     spec = store_mod.StoreSpec(
         capacity, shape, update=rule, mesh=mesh or None,
-        layout=store_mod._resolve_layout(layout, rule, shape))
+        layout=store_mod._resolve_layout(layout, rule, shape),
+        worker_width=part)
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
     monkeypatch.setattr(store_mod, "_REFUSALS_NOTED", set())
     n0 = row_update.refusal_count()
 
-    def read():
+    def read(**width):
         return dataclasses.astuple(store_mod.arms(
             spec, pull_lanes=pull_lanes, push_lanes=push_lanes,
-            lanes_over_workers=over_workers))
+            lanes_over_workers=over_workers, **width))
 
     with warnings.catch_warnings(record=True) as caught:
         warnings.simplefilter("always")
@@ -2272,6 +2301,14 @@ def test_the_arms_table(backend, row, mesh_devices, monkeypatch):
     with warnings.catch_warnings():
         warnings.simplefilter("error")  # a refusal is noted once
         assert read() == want
+        if part is not None:
+            # a step's push is the worker's part; whole-row deltas (a bare
+            # `push`) get the combine of the store that names no part
+            assert read(push_width=part) == want
+            whole = dataclasses.astuple(store_mod.arms(
+                dataclasses.replace(spec, worker_width=None),
+                pull_lanes=pull_lanes, push_lanes=push_lanes))
+            assert read(push_width=spec.row_width) == whole
     assert row_update.refusal_count() == n0 + noted
 
 
@@ -2295,3 +2332,204 @@ def test_the_arms_table_has_a_case_a_row_of_the_docstring():
             "scatter_add", "row_kernel", "tile_kernel", "xla_set", "tile_set",
             "row_set", "tile_assign"}]
         assert got == [w for w in want if w not in ("", "rule", True, False)]
+
+
+# -- the worker's part of a rule store's row ---------------------------------
+# ``StoreSpec.worker_width``: a step pulls and pushes the leading lanes a
+# worker reads and writes; the rule still reads and writes whole rows.  The
+# lanes left out only ever carried +0.0 that no rule reads, so the table is
+# the whole-row step's, bit for bit.
+PART_ROWS = [  # (what, row width, the worker's part, layout, physical lanes)
+    ("dense", 100, 37, "dense", 100),
+    ("packed_k3", 36, 20, "auto", 128),
+    ("flat_wide_k1", 260, 131, "auto", 384),
+]
+PART = {what: part for what, _, part, _, _ in PART_ROWS}
+
+
+def _part_rule(part):
+    """A rule over a row whose first ``part`` lanes are the worker's: it
+    reads ``combined`` by lane number over those alone, moves every lane of
+    a touched row, and each new lane is ONE float32 operation (two programs
+    that fuse it differently still round it alike)."""
+
+    def rule(current, combined):
+        g = combined[..., :part]
+        return jnp.concatenate(
+            [current[..., :part] - g, current[..., part:] + g[..., :1]],
+            axis=-1)
+
+    return rule
+
+
+class _PartLogic:
+    """Pushes gradients at the width the rows came: the worker's part, or
+    whole rows with zeros for the server's lanes (``models/difacto.py``)."""
+
+    def __init__(self, part):
+        self.part = part
+
+    def init_state(self, rng):
+        return ()
+
+    def keys(self, batch):
+        return batch["ids"]
+
+    def step(self, state, batch, pulled):
+        g = pulled[..., :self.part] * batch["x"][..., None]
+        # what a dropped lane carries reaches no row, whatever it is
+        g = jnp.where(batch["bad"][..., None], batch["poison"], g)
+        past = pulled.shape[-1] - self.part
+        if past:
+            g = jnp.concatenate(
+                [g, jnp.zeros(g.shape[:-1] + (past,), g.dtype)], axis=-1)
+        out = {"lanes": jnp.asarray(pulled.shape[-1], jnp.int32)}
+        return state, PushRequest(batch["ids"], g, batch["mask"]), out
+
+
+def _part_store(what, cap, mesh, rng, part="own"):
+    _, width, own, layout, lanes = next(r for r in PART_ROWS if r[0] == what)
+    values = rng.normal(size=(cap, width)).astype(np.float32)
+    part = own if part == "own" else part
+    store = ShardedParamStore.create(
+        cap, (width,), init_fn=lambda ids: jnp.asarray(values)[ids],
+        update=_part_rule(own), mesh=mesh, layout=layout, worker_width=part)
+    assert store.table.shape[1] == lanes and store.spec.worker_width == part
+    return store, values
+
+
+def _part_batch(rng, cap, poison):
+    ids = rng.integers(0, cap, (32, 6)).astype(np.int32)
+    ids[:, 0] = 7  # a hot row
+    ids[3, 1:4] = [-1, cap + 50, 2 ** 31 - 1]  # dead lanes
+    mask = rng.random(ids.shape) > 0.2
+    bad = ~mask | (ids < 0) | (ids >= cap)
+    return {
+        "ids": ids, "x": rng.normal(size=ids.shape).astype(np.float32),
+        "mask": mask, "bad": bad, "poison": np.float32(poison)}
+
+
+@pytest.mark.parametrize("poison", [0.0, np.nan, np.inf])
+@pytest.mark.parametrize("place", ["one_place", "ps4", "one_place_kernels"])
+@pytest.mark.parametrize("what", [r[0] for r in PART_ROWS])
+def test_a_step_with_the_workers_part_is_the_whole_row_step_bit_for_bit(
+        what, place, poison, ps_mesh, steer_arms):
+    from flink_parameter_server_tpu.core import store as store_mod
+    from flink_parameter_server_tpu.core.transform import make_train_step
+
+    seed = [sorted(PART).index(what), place == "ps4"]
+    cap = 500
+    mesh = ps_mesh if place == "ps4" else None
+    part_store, values = _part_store(
+        what, cap, mesh, np.random.default_rng(seed))
+    whole_store, _ = _part_store(
+        what, cap, mesh, np.random.default_rng(seed), part=None)
+    assert np.asarray(whole_store.values()).tobytes() == values.tobytes()
+    if place == "one_place_kernels":  # interpreted off the TPU
+        steer_arms(
+            combine=lambda s: "tile_kernel" if (
+                s.worker_width or s.row_width) > 128 else "row_kernel",
+            write_back=lambda s: "xla_set" if s.layout == "dense" else (
+                "tile_assign" if s.row_width > 128 else "row_set"))
+    batch = _part_batch(np.random.default_rng(seed + [1]), cap, poison)
+    logic = _PartLogic(PART[what])
+    tables, lanes = [], []
+    for store in (part_store, whole_store):
+        table = store.table
+        step = jax.jit(make_train_step(logic, store.spec))
+        for _ in range(2):  # the second step reads what the first wrote
+            table, _, out = step(table, (), batch)
+        tables.append(np.asarray(
+            ShardedParamStore(store.spec, table).values()))
+        lanes.append(int(out["lanes"]))
+        counted = {k: int(v) for k, v in out.items() if k.startswith("ps_")}
+        if store is part_store:
+            assert counted["ps_pull_row_lanes"] == PART[what]
+            assert counted["ps_push_row_lanes"] == PART[what]
+        else:
+            assert "ps_pull_row_lanes" not in counted
+            assert "ps_push_row_lanes" not in counted
+    width = values.shape[1]
+    assert lanes == [PART[what], width]
+    assert tables[0].tobytes() == tables[1].tobytes()
+    assert np.isfinite(tables[0]).all()
+    live = ~batch["bad"]
+    hit = np.zeros(cap, bool)
+    hit[np.unique(batch["ids"][live])] = True
+    assert (tables[0][hit] != values[hit]).all(axis=1).all()  # every lane
+    assert tables[0][~hit].tobytes() == values[~hit].tobytes()
+    # the public pull keeps whole rows; the step's hands out the part
+    probe = jnp.asarray(batch["ids"][:4])
+    after = ShardedParamStore(part_store.spec, part_store.table)
+    rows = np.asarray(after.pull(probe))
+    assert rows.shape == (4, 6, width)
+    got = np.asarray(store_mod.pull(
+        after.spec, after.table, probe, worker_part=True))
+    assert got.tobytes() == rows[..., :PART[what]].tobytes()
+    # ... and of a store that names no part, whole rows either way
+    assert store_mod.pull(
+        whole_store.spec, whole_store.table, probe, worker_part=True
+    ).shape == (4, 6, width)
+
+
+@pytest.mark.parametrize("traffic", TRAFFIC)
+@pytest.mark.parametrize("what", [r[0] for r in PART_ROWS])
+def test_push_pull_case_table_the_workers_part(what, traffic):
+    """The case table's traffic pushed at the worker's width into a rule
+    store (``current + combined`` over the part, the server's lanes left as
+    they are) against the float64 ``np.add.at``, and against the same
+    deltas pushed as whole rows with zero lanes."""
+    from flink_parameter_server_tpu.core import store as store_mod
+
+    _, width, part, layout, _ = next(r for r in PART_ROWS if r[0] == what)
+    rng = np.random.default_rng([width, TRAFFIC.index(traffic)])
+    values = _init_values(CAP, (width,))
+
+    def rule(current, combined):
+        return jnp.concatenate(
+            [current[..., :part] + combined[..., :part], current[..., part:]],
+            axis=-1)
+
+    store = ShardedParamStore.create(
+        CAP, (width,), init_fn=lambda ids: jnp.asarray(values)[ids],
+        update=rule, layout=layout, worker_width=part)
+    ids, deltas, mask = _traffic(traffic, rng, CAP, (part,))
+    args = (jnp.asarray(ids), None if mask is None else jnp.asarray(mask))
+    pushed = store.push(args[0], jnp.asarray(deltas), args[1])
+    got = np.asarray(pushed.values()).astype(np.float64)
+    want, mag = _reference(values[:, :part], ids, deltas, mask)
+    off = np.abs(got[:, :part] - want)
+    assert (off <= 64 * np.finfo(np.float32).eps * mag + 1e-30).all()
+    assert got[:, part:].tobytes() == values[:, part:].astype(
+        np.float64).tobytes()
+    whole = np.zeros(ids.shape + (width,), np.float32)
+    whole[..., :part] = deltas
+    twin = store.push(args[0], jnp.asarray(whole), args[1])
+    assert np.asarray(twin.values()).tobytes() == np.asarray(
+        pushed.values()).tobytes()
+    pulled = np.asarray(store_mod.pull(
+        pushed.spec, pushed.table, jnp.asarray(ids), worker_part=True))
+    assert pulled.shape == ids.shape + (part,)
+    below = (ids >= 0) & (ids < CAP)
+    assert pulled[below].tobytes() == np.asarray(
+        pushed.values())[ids[below], :part].tobytes()
+
+
+def test_a_push_at_neither_width_and_a_part_no_row_has_raise():
+    store, _ = _part_store("packed_k3", 50, None, np.random.default_rng(0))
+    ids = jnp.arange(4)
+    for width in (20, 36):  # the worker's part, the whole row
+        store.push(ids, jnp.ones((4, width)))
+    for width in (19, 21, 35, 1):
+        with pytest.raises(ValueError, match=r"worker's part, \(20,\)"):
+            store.push(ids, jnp.ones((4, width)))
+    whole, _ = _part_store(
+        "packed_k3", 50, None, np.random.default_rng(0), part=None)
+    with pytest.raises(ValueError, match=r"does not match ids"):
+        whole.push(ids, jnp.ones((4, 20)))  # the spec names no part
+    import dataclasses
+
+    for bad in (dict(update="add"), dict(value_shape=(2, 18)),
+                dict(worker_width=0), dict(worker_width=37)):
+        with pytest.raises(ValueError, match="worker_width"):
+            dataclasses.replace(store.spec, **bad)

@@ -1423,7 +1423,10 @@ def test_difacto_step_holds_nothing_table_sized_beside_its_table(
     scatter of 36-lane rows of the table anywhere.  As the chip runs it
     (asked for the backend: ``kernels``): under ``ps.pull`` ONE gather of
     whole physical rows (``slice_sizes={1,128}``) and ONE ``packed_lane_slice``
-    call that hands the logic ``f32[36,1277952]``; under ``ps.push/
+    call that hands the logic ``f32[20,1277952]``, the WORKER'S PART of the
+    rows (``StoreSpec.worker_width``, PR 59: the 36-lane rows of ``S`` never
+    leave the rule's loop; the logic's gradient rows are ``f32[1277952,20]``
+    and no array of the step outside that loop is 36 lanes wide); under ``ps.push/
     ps.combine`` PR 46's ``sorted_row_update`` inside the loop over thirteen
     stretches; in the rule's loop ONE gather ``f32[32768,128]`` of the
     chunk's physical rows under ``ps.rule`` and ONE ``sorted_row_set`` call,
@@ -1492,8 +1495,13 @@ def test_difacto_step_holds_nothing_table_sized_beside_its_table(
     assert names == ["%packed_lane_slice", "%sorted_row_set", "%sorted_row_update"]
     by_name = {k.strip().split(".", 1)[0]: k for k in kernels}
     slice_call = by_name["%packed_lane_slice"]
-    assert f" = f32[36,{n}]{{1,0:" in slice_call
+    assert f" = f32[20,{n}]{{1,0:" in slice_call
     assert 'op_name="jit(step)/ps.pull/' in slice_call
+    # the worker's part alone crosses: outside the rule's loop (its chunk
+    # of 32,768 whole rows) nothing is 36 lanes wide
+    assert not re.search(rf"f32\[(36,{n}|{n},36|36,{FM_BATCH},{FM_FIELDS}"
+                         rf"|{FM_BATCH},{FM_FIELDS},36)\]", text)
+    assert f"f32[{n},20]" in text
     set_call = by_name["%sorted_row_set"]
     assert " f32[16375440,128]{1,0" in set_call
     assert "ps.push/while/body" in set_call and "ps.rule" not in set_call
@@ -1529,8 +1537,10 @@ def test_difacto_step_on_four_chips_runs_its_rule_on_the_shard_that_owns_the_row
     under ``ps.combine``, ``sorted_row_set`` in the rule's loop; off the TPU
     XLA's scatter-add and row ``set`` ON THE BLOCK, nothing partitioned by
     GSPMD), 1.33 GB of temporaries a chip, and the step's only collectives
-    are the pull's all-reduce of ``f32[32768,39,36]`` and the 96 bytes of
-    the push's counts: no row of the table and no key crosses chips."""
+    are the pull's all-reduce of ``f32[32768,39,20]``, the worker's part of
+    the rows (PR 59: 102 MB a step where whole rows were 184), and the 96
+    bytes of the push's counts: no row of the table and no key crosses
+    chips."""
     from flink_parameter_server_tpu.parallel.mesh import make_mesh
 
     cfg, rule, model, fam, dfm = difacto
@@ -1567,7 +1577,7 @@ def test_difacto_step_on_four_chips_runs_its_rule_on_the_shard_that_owns_the_row
         r"(-start)?\(", c)]
     shapes = sorted(c.strip().split(" = ", 1)[1].split("{")[0] for c in collectives)
     # (six counts a shard since PR 54's `ps_combine_kernel_writes`)
-    assert shapes == ["f32[32768,39,36]", "s32[24]"], collectives
+    assert shapes == ["f32[32768,39,20]", "s32[24]"], collectives
     for scope in ("ps.pull", "ps.push/shard_map/ps.combine",
                   "ps.push/shard_map/while/body/ps.rule"):
         assert scope in text, scope
@@ -1575,7 +1585,7 @@ def test_difacto_step_on_four_chips_runs_its_rule_on_the_shard_that_owns_the_row
     scatters = [line for line in lines if re.search(r" scatter\(", line)]
     if arm == "xla":
         # the one-place arms on each shard's own block
-        assert all(" f32[15647288,128]" in c or " f32[1277952,36]" in c
+        assert all(" f32[15647288,128]" in c or " f32[1277952,20]" in c
                    for c in scatters) and len(scatters) == 2, scatters
         assert not kernels
         return
@@ -1809,9 +1819,14 @@ def test_glove_step_holds_nothing_table_sized_beside_its_table(
     """Cell 13's step at full size for a described v5e: the donated 11.24 GB
     table is rewritten in place and never copied or transposed, with every
     scope the cell's metrics read.  As the chip runs it (``kernels``): under
-    ``ps.pull`` ONE gather of whole physical rows ``f32[65536,640]``; under
-    ``ps.push/ps.combine`` the permute of the batch's gradient rows and ONE
-    ``sorted_row_update_tiles`` call into a zeroed ``f32[65536,640]`` block;
+    ``ps.pull`` ONE gather of whole physical rows ``f32[65536,640]`` (the
+    TPU's gather moves whole rows: a window of the three registers that
+    hold the worker's 301 lanes compiles to a loop over the ids, a slice of
+    the table in front of it to a copy of 6.7 GB, PR 59) cut to the worker's
+    part, ``f32[65536,301]``; under
+    ``ps.push/ps.combine`` the permute of the batch's gradient rows, 301
+    lanes wide, and ONE ``sorted_row_update_tiles`` call into a zeroed
+    ``f32[65536,384]`` block of THREE registers;
     in the rule's loop ONE gather ``f32[32768,640]`` under ``ps.rule`` and
     ONE ``sorted_row_assign_tiles`` call on the table, the write-back.  What
     the step holds beside the table goes with the batch: 0.67 GB.  Off the
@@ -1840,8 +1855,9 @@ def test_glove_step_holds_nothing_table_sized_beside_its_table(
         assert not kernels
         sets = [c for c in scatters if " f32[4392040,640]" in c]
         assert len(sets) == 1 and "ps.push/while/body" in sets[0], scatters
-        # (the sums at the rows' own 602 lanes since PR 57: no pad in front)
-        sums = [c for c in scatters if " f32[65536,602]" in c]
+        # (the sums at the width pushed, the worker's 301 lanes: no pad in
+        # front since PR 57, no accumulators' lanes since PR 59)
+        sums = [c for c in scatters if " f32[65536,301]" in c]
         assert len(sums) == 1 and "ps.push/ps.combine" in sums[0], scatters
         return
     assert not scatters
@@ -1850,8 +1866,11 @@ def test_glove_step_holds_nothing_table_sized_beside_its_table(
     by_name = {k.strip().split(".", 1)[0]: k for k in kernels}
     assert " f32[4392040,640]{1,0" in by_name["%sorted_row_assign_tiles"]
     assert "ps.push/while/body" in by_name["%sorted_row_assign_tiles"]
-    assert " f32[65536,640]{1,0" in by_name["%sorted_row_update_tiles"]
+    assert " f32[65536,384]{1,0" in by_name["%sorted_row_update_tiles"]
     assert "ps.push/ps.combine" in by_name["%sorted_row_update_tiles"]
+    # the worker's part alone crosses: the 602-lane rows are the rule's
+    assert not re.search(r"f32\[(65536|32768,2),60[02]\]", text)
+    assert "f32[65536,301]" in text and "f32[32768,602]" in text
 
 
 @pytest.mark.parametrize("cell", [5, 7, 13])
@@ -1861,8 +1880,9 @@ def test_no_pass_over_the_pushed_rows_stands_in_front_of_the_tile_kernel(
     57): in the compiled steps of cells 5, 7 and 13 every tile-kernel call
     reads its rows from the op that made them, at their LOGICAL width
     (``f32[114688,600]``, ``f32[233472,300]``: ONE permute for all the calls
-    of an add push; ``f32[65536,602]``: the combine's permute;
-    ``f32[32768,602]``: the rule's own output), and nothing between them
+    of an add push; ``f32[65536,301]``: the combine's permute of the worker's
+    part, PR 59 (602 until then); ``f32[32768,602]``: the rule's own output,
+    whole rows), and nothing between them
     pads, copies or "compresses" the batch's rows: no ``pad`` to 640 / 384
     lanes (the parent's ``pad.71/.73``, ``pad.75/.77/.79``, ``pad.38``, 0.37 to
     0.51 ms each on the v5e), no select over the pushed block in front of a
@@ -1874,7 +1894,8 @@ def test_no_pass_over_the_pushed_rows_stands_in_front_of_the_tile_kernel(
     cell 5's ``f32[57344,600]`` too once its table has a tenth more rows:
     both compiled, PERF.md section 6, PR 57; why an add push narrower than
     its table permutes once).  The temporaries:
-    cell 7's the parent's 0.767 GB, cell 13's 0.437 for its 0.673; cell 5's
+    cell 7's the parent's 0.767 GB, cell 13's 0.336 (0.437 until PR 59, 0.673
+    until PR 57); cell 5's
     0.883 for its 0.649 (one 294 MB buffer where two of 147 packed better
     into the heap; the live bytes are the parent's)."""
     if cell == 5:
@@ -1887,8 +1908,8 @@ def test_no_pass_over_the_pushed_rows_stands_in_front_of_the_tile_kernel(
         rows_in, calls, temp = [(lanes, w)] * 3, 3, 0.77 * GB
     else:
         compiled = _glove_cell_step(one_chip, glove, monkeypatch)
-        lanes, w, width = 2 * GLOVE_BATCH, 602, 640
-        rows_in, calls, temp = [(store_mod._RULE_CHUNK, w), (lanes, w)], 2, 0.45 * GB
+        lanes, w, width = 2 * GLOVE_BATCH, "602|301", "640|384"
+        rows_in, calls, temp = [(store_mod._RULE_CHUNK, 602), (lanes, 301)], 2, 0.45 * GB
     assert compiled.memory_analysis().temp_size_in_bytes < temp
     text = compiled.as_text()
     assert "remat" not in text
