@@ -87,8 +87,17 @@ def _resolve_layout(
       ``f32[4392040,640]``, 11.24 GB; PERF.md section 6, PR 55).  Dense,
       the TPU holds ``(capacity, 602)`` capacity-minor and every step
       copies the whole table for its gather and back (below).
-    - every other row (65 to 128 lanes, where ``k`` would be 1 and the row
-      one register; two axes; none): dense: no cell stands there.
+    - one axis of 65 to 127 lanes: PACKED with ``k`` = 1, the row alone in
+      ONE 128-lane register, zeros past its width (PBG's 101 lanes, an
+      embedding and row-wise AdaGrad's one accumulator: ``f32[15152096,128]
+      {1,0:T(8,128)}``, 7.76 GB).  Dense, the TPU pads such a row to 128
+      lanes all the same and hands the step its table ``(capacity, 101)``
+      capacity-minor: the step copies the WHOLE table to a row-major one for
+      its gathers and back after its row ``set`` (compiled for a v5e at
+      15,152,092 rows: 7.9 GB of temporaries beside the table, which do not
+      fit; packed, 0.44 GB and neither copy; PERF.md section 6, PR 61).
+    - every other row (exactly 128 lanes, a row that fills its register as
+      it is; two axes; none): dense: no cell stands there.
 
     ``layout="packed"`` may be pinned for any rule store; the rule's push
     then takes the packed arm at whatever ``k`` the width gives.
@@ -120,7 +129,7 @@ def _resolve_layout(
     one_axis = len(value_shape) == 1
     if update == "add":
         return "dense" if one_axis and width % 128 == 0 else "packed"
-    return "packed" if one_axis and (8 < width <= 64 or width > 128) else "dense"
+    return "packed" if one_axis and width > 8 and width != 128 else "dense"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -966,14 +975,16 @@ def arms(
     packed k 3 (36 lanes)             packed_kernel   row_kernel   row_set      no         none  46 47 54
     the same over ps 4, dp 1          packed_kernel   row_kernel   row_set      yes        none  52
     the same over ps 2, dp 2          packed_kernel   scatter_add  xla_set      no         none  52
-    packed k 1, 1 reg (pinned, 100)   packed_selects  row_kernel   row_set      no         none  47
+    packed k 1, 1 reg (100 lanes)     packed_selects  row_kernel   row_set      no         none  47 61
     packed k 1, 5 regs (602 lanes)    packed_selects  tile_kernel  tile_assign  no         none  55 57
-    dense 1 reg (100 lanes)           take            row_kernel   xla_set      no         none  46
+    dense 1 reg (pinned, 100)         take            row_kernel   xla_set      no         none  46 61
     packed k 3, the worker's 20 / 36  packed_kernel   row_kernel   row_set      no         9     59
     the worker's 20 / 36 over ps 4    packed_kernel   row_kernel   row_set      yes        12    59
     5 regs, the worker's 301 / 602    packed_selects  tile_kernel  tile_assign  no         13    59
     5 regs, the worker's 100 / 602    packed_selects  row_kernel   tile_assign  no         none  59
     5 regs, the worker's 3 / 602      packed_selects  sort         tile_assign  no         none  59
+    1 reg, the worker's 100 / 101     packed_selects  row_kernel   row_set      no         14    61
+    1 reg, 100 / 101 over ps 4        packed_selects  row_kernel   row_set      yes        none  61
     ================================  ==============  ===========  ===========  =========  ====  ========
 
     Reasons the code does not show.  A mesh keeps an add push XLA's because

@@ -236,13 +236,13 @@ def test_with_every_gate_open_the_forward_pass_and_gradients_are_fms():
 @pytest.mark.parametrize("dim, layout, table", [
     (16, "packed", (104, 128)),   # 36 lanes: three rows to a physical row
     (2, "dense", (384, 8)),       # 8 lanes: the narrow rule store's tile
-    (32, "dense", (304, 68)),     # 68 lanes: one to a register, as it was
+    (32, "packed", (304, 128)),   # 68 lanes: one to a register (PR 61)
 ])
 def test_make_store_is_a_rule_store_of_fresh_rows_packed_by_its_width(
         dim, layout, table):
     """``make_store`` leaves the layout to the store: DiFacto's 36 lanes (k =
-    16) lie three to a 128-lane physical row since PR 47; the widths that
-    stay dense are the dense store they were."""
+    16) lie three to a 128-lane physical row since PR 47, 68 one to a register since PR 61;
+    the widths that stay dense are the dense store they were."""
     cfg = df.DiFactoConfig(300, dim)
     lanes = 4 + 2 * dim
     store = df.make_store(cfg, seed=5)

@@ -581,7 +581,10 @@ def test_auto_layout_resolution():
     assert s.spec.layout == "dense" and s.table.shape == (16, 17)
     s = ShardedParamStore.create(10, (3,), update=rule, layout="auto")
     assert s.spec.layout == "dense" and s.spec.tile_lanes == 4
+    # ... and of 65 to 127 lanes one to a register (PR 61)
     s = ShardedParamStore.create(10, (100,), update=rule, layout="auto")
+    assert s.spec.layout == "packed" and s.table.shape == (16, 128)
+    s = ShardedParamStore.create(10, (128,), update=rule, layout="auto")
     assert s.spec.layout == "dense"
 
 

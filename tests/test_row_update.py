@@ -986,8 +986,9 @@ def _rule(current, combined):
     ("tpu", False, (9,), _rule, jnp.float32, True),   # packed: the row set
     ("tpu", False, (36,), _rule, jnp.float32, True),  # DiFacto's row, k = 3
     ("tpu", False, (64,), _rule, jnp.float32, True),
-    ("tpu", False, (65,), _rule, jnp.float32, False),  # dense, XLA's set
-    ("tpu", False, (128,), _rule, jnp.float32, False),
+    ("tpu", False, (65,), _rule, jnp.float32, True),  # one to a register
+    ("tpu", False, (101,), _rule, jnp.float32, True),  # (PR 61: PBG's row)
+    ("tpu", False, (128,), _rule, jnp.float32, False),  # dense, XLA's set
     ("tpu", False, (3,), "add", jnp.float32, False),
     ("cpu", False, (3,), _rule, jnp.float32, False),
     ("cpu", False, (36,), _rule, jnp.float32, False),
@@ -998,7 +999,8 @@ def _rule(current, combined):
     ("tpu", "ps4", (36,), _rule, jnp.float32, True),
     ("tpu", "ps4", (9,), _rule, jnp.float32, True),
     ("tpu", "ps4", (3,), _rule, jnp.float32, False),
-    ("tpu", "ps4", (65,), _rule, jnp.float32, False),
+    ("tpu", "ps4", (65,), _rule, jnp.float32, True),
+    ("tpu", "ps4", (128,), _rule, jnp.float32, False),
     ("cpu", "ps4", (36,), _rule, jnp.float32, False),
 ])
 def test_the_write_back_takes_the_set_kernel_from_what_the_spec_holds(
@@ -1028,8 +1030,9 @@ def test_the_write_back_takes_the_set_kernel_from_what_the_spec_holds(
     assert len(caught) == noted and all(
         "dp = 2 workers" in str(w.message) for w in caught)
     assert row_update.refusal_count() == n0 + noted
-    if update != "add" and 8 < shape[0] <= 64:
-        # several rows to a 128-lane physical row, whatever the backend
+    if update != "add" and 8 < shape[0] < 128:
+        # several rows to a 128-lane physical row (from 65 lanes one),
+        # whatever the backend
         assert spec.layout == "packed" and spec.tile_lanes == 0
         assert spec.table_shape()[1] == 128 and spec.pack == 128 // shape[0]
     elif want:  # the physical row is the sublane tile, whole tiles of rows

@@ -1285,8 +1285,9 @@ def test_combine_runs_sums_each_id_and_moves_the_distinct_first(width):
     ("rule", (3,), "dense"), ("rule", (), "dense"), ("rule", (17,), "packed"),
     ("rule", (128,), "dense"), ("rule", (2, 300), "dense"),
     ("rule", (8,), "dense"), ("rule", (9,), "packed"), ("rule", (36,), "packed"),
-    ("rule", (64,), "packed"), ("rule", (65,), "dense"), ("rule", (127,), "dense"),
-    ("rule", (2, 9), "dense"), ("rule", (1,), "dense"),
+    ("rule", (64,), "packed"), ("rule", (65,), "packed"),
+    ("rule", (101,), "packed"), ("rule", (127,), "packed"),
+    ("rule", (129,), "packed"), ("rule", (2, 9), "dense"), ("rule", (1,), "dense"),
 ])
 def test_auto_layout_reads_row_shape_and_update_rule(update, shape, want):
     from flink_parameter_server_tpu.core.store import _resolve_layout
@@ -1308,6 +1309,9 @@ def test_auto_layout_reads_row_shape_and_update_rule(update, shape, want):
 # neighbours inside a touched physical row included.
 
 PACKED_RULE_WIDTHS = [9, 17, 36, 64]
+# ... and of 65 to 127 lanes (PR 61): the row alone in ONE register, k = 1
+# (PBG's 101: an embedding and row-wise AdaGrad's one accumulator)
+ONE_REGISTER_RULE_WIDTHS = [65, 101, 127]
 PACKED_RULE_CASES = [
     "uniform", "two_of_one_physical_row", "a_whole_physical_row",
     "across_a_chunk_edge", "nan_inf_and_minus_zero_neighbours",
@@ -1359,7 +1363,8 @@ def _packed_rule_traffic(case, rng, cap, width):
 
 
 @pytest.mark.parametrize("case", PACKED_RULE_CASES)
-@pytest.mark.parametrize("width", PACKED_RULE_WIDTHS + [8, 65])
+@pytest.mark.parametrize(
+    "width", PACKED_RULE_WIDTHS + ONE_REGISTER_RULE_WIDTHS + [8, 128])
 @pytest.mark.parametrize("arm", ["xla", "row_set_kernel"])
 def test_a_packed_rule_store_is_the_dense_one_bit_for_bit(
         arm, width, case, monkeypatch, steer_arms):
@@ -1382,7 +1387,7 @@ def test_a_packed_rule_store_is_the_dense_one_bit_for_bit(
         jnp.asarray(values), update=_sticky_rule, layout="auto")
     dense = ShardedParamStore.from_values(
         jnp.asarray(values), update=_sticky_rule, layout="dense")
-    packs = width in PACKED_RULE_WIDTHS
+    packs = width in PACKED_RULE_WIDTHS + ONE_REGISTER_RULE_WIDTHS
     assert auto.spec.layout == ("packed" if packs else "dense")
     assert dense.spec.layout == "dense"
     if not packs:
@@ -1528,7 +1533,8 @@ def _shard_rule_traffic(case, rng, cap, width, block):
 @pytest.mark.parametrize("case", SHARD_RULE_CASES)
 @pytest.mark.parametrize("width,arm", [
     (3, "xla"), (9, "xla"), (17, "xla"), (36, "xla"), (64, "xla"),
-    (100, "xla"), (17, "row_set_kernel"), (36, "row_set_kernel"),
+    (100, "xla"), (128, "xla"), (17, "row_set_kernel"), (36, "row_set_kernel"),
+    (101, "row_set_kernel"),
 ])
 def test_a_rule_store_on_its_shards_is_the_one_place_store_and_the_shares_add_up(
         arm, width, case, ps_mesh, monkeypatch, steer_arms):
@@ -1542,7 +1548,7 @@ def test_a_rule_store_on_its_shards_is_the_one_place_store_and_the_shares_add_up
     sharded = ShardedParamStore.from_values(
         jnp.asarray(values), update=_sticky_rule, mesh=ps_mesh, layout="auto")
     spec = sharded.spec
-    packs = width in PACKED_RULE_WIDTHS
+    packs = width in PACKED_RULE_WIDTHS + [100, 101]
     assert spec.layout == one.spec.layout == ("packed" if packs else "dense")
     # a narrow rule row is NOT held at its sublane tile under a mesh
     assert spec.tile_lanes == 0 and (width != 3 or one.spec.tile_lanes == 4)
@@ -1772,8 +1778,11 @@ def test_the_walk_pays_by_what_it_writes_and_the_push_is_the_parents(
 @pytest.mark.parametrize("width,meshed,names", [
     (3, False, {"ps_rule_keys", "ps_rule_rows", "ps_rule_tiles"}),  # cell 6
     (4, False, {"ps_rule_keys", "ps_rule_rows", "ps_rule_tiles"}),
-    (100, False, {"ps_rule_keys", "ps_rule_rows", "ps_rule_tiles",
+    (128, False, {"ps_rule_keys", "ps_rule_rows", "ps_rule_tiles",
                   "ps_combine_kernel_lanes", "ps_combine_kernel_writes"}),
+    (101, False, {"ps_rule_keys", "ps_rule_rows", "ps_rule_tiles",
+                  "ps_combine_kernel_lanes", "ps_combine_kernel_writes",
+                  "ps_rule_packed_rows"}),  # cell 14: packed, one a register
     (36, False, {"ps_rule_keys", "ps_rule_rows", "ps_rule_tiles",
                  "ps_combine_kernel_lanes", "ps_combine_kernel_writes",
                  "ps_rule_packed_rows"}),  # cell 9
@@ -1810,7 +1819,7 @@ def test_what_a_rule_store_counts_goes_with_its_row(
     ((17,), 1, "tpu", True, True, True),
     ((9,), 1, "tpu", True, True, True),
     ((3,), 1, "tpu", False, False, True),   # dense under a mesh: XLA's set
-    ((100,), 1, "tpu", True, False, True),  # dense, one register: the sums
+    ((100,), 1, "tpu", True, True, True),   # one register, packed k = 1
     ((36,), 1, "cpu", False, False, True),
     ((36,), 2, "tpu", False, False, False),  # dp > 1: GSPMD's, as it was
     ((3,), 2, "tpu", False, False, False),
@@ -2141,7 +2150,8 @@ def test_a_flat_wide_rule_store_takes_the_tile_kernels_from_its_spec(
 @pytest.mark.parametrize("shape, want, lanes", [
     ((602,), "packed", 640), ((129,), "packed", 256), ((256,), "packed", 256),
     ((36,), "packed", 128), ((3,), "dense", 4), ((128,), "dense", 128),
-    ((65,), "dense", 65), ((2, 300), "dense", None),
+    ((65,), "packed", 128), ((101,), "packed", 128), ((127,), "packed", 128),
+    ((2, 300), "dense", None),
 ])
 def test_a_rule_row_resolves_by_its_width_and_its_reload_to_the_same(
         shape, want, lanes):
@@ -2205,13 +2215,13 @@ ARMS_ON_A_TPU = [
      8_192, 8_192, False,
      ("packed_kernel", "rule", "", "scatter_add", "xla_set", False),
      1),  # the push on the shards: the batch lies split over dp, noted
-    ("packed k 1, 1 reg (pinned, 100)", (100,), _RULE, "packed", None, 1_000,
+    ("packed k 1, 1 reg (100 lanes)", (100,), _RULE, "auto", None, 1_000,
      8_192, 8_192, False,
      ("packed_selects", "rule", "", "row_kernel", "row_set", False), 0),
     ("packed k 1, 5 regs (602 lanes)", (602,), _RULE, "auto", None, 1_000,
      8_192, 8_192, False,
      ("packed_selects", "rule", "", "tile_kernel", "tile_assign", False), 0),
-    ("dense 1 reg (100 lanes)", (100,), _RULE, "auto", None, 1_000,
+    ("dense 1 reg (pinned, 100)", (100,), _RULE, "dense", None, 1_000,
      8_192, 8_192, False,
      ("take", "rule", "", "row_kernel", "xla_set", False), 0),
     # the worker's part of a row (`WORKER_WIDTHS`): the pull arms and the
@@ -2231,11 +2241,18 @@ ARMS_ON_A_TPU = [
     ("5 regs, the worker's 3 / 602", (602,), _RULE, "auto", None, 1_000,
      8_192, 8_192, False,
      ("packed_selects", "rule", "", "sort", "tile_assign", False), 0),
+    ("1 reg, the worker's 100 / 101", (101,), _RULE, "auto", None, 1_000,
+     8_192, 8_192, False,
+     ("packed_selects", "rule", "", "row_kernel", "row_set", False), 0),
+    ("1 reg, 100 / 101 over ps 4", (101,), _RULE, "auto", (1, 4), 1_000,
+     8_192, 8_192, False,
+     ("packed_selects", "rule", "", "row_kernel", "row_set", True), 0),
 ]
 WORKER_WIDTHS = {
     "packed k 3, the worker's 20 / 36": 20, "the worker's 20 / 36 over ps 4": 20,
     "5 regs, the worker's 301 / 602": 301, "5 regs, the worker's 100 / 602": 100,
     "5 regs, the worker's 3 / 602": 3,
+    "1 reg, the worker's 100 / 101": 100, "1 reg, 100 / 101 over ps 4": 100,
 }
 # off a TPU: XLA's forms; where the push runs is read from the mesh alone
 ARMS_OFF_IT = {
@@ -2255,6 +2272,8 @@ ARMS_OFF_IT = {
         "packed_selects", "rule", "", "scatter_add", "xla_set", False),
     "5 regs, the worker's 3 / 602": (
         "packed_selects", "rule", "", "sort", "xla_set", False),
+    "1 reg, the worker's 100 / 101": (
+        "packed_selects", "rule", "", "scatter_add", "xla_set", False),
 }
 
 
@@ -2343,6 +2362,7 @@ PART_ROWS = [  # (what, row width, the worker's part, layout, physical lanes)
     ("dense", 100, 37, "dense", 100),
     ("packed_k3", 36, 20, "auto", 128),
     ("flat_wide_k1", 260, 131, "auto", 384),
+    ("one_register_k1", 101, 100, "auto", 128),  # PBG's row: cell 14
 ]
 PART = {what: part for what, _, part, _, _ in PART_ROWS}
 

@@ -1936,3 +1936,126 @@ def test_no_pass_over_the_pushed_rows_stands_in_front_of_the_tile_kernel(
     if cell == 13:  # `_zero_masked`'s select over the pushed block, the merge
         assert not re.search(
             rf"= f32\[\d+,({w}|{width})\]\S* fusion\([^\n]*jit\(_where\)", ops)
+
+
+# pbg-freebase-d100-p16 (chipbench/configs): cell 14's table and batch
+KGE_ROWS, KGE_RELATIONS, KGE_DIM = 15_152_092, 25_291, 100
+KGE_CHUNKS, KGE_CHUNK, KGE_UNIFORM = 800, 50, 50
+KGE_PHYS_ROWS, KGE_KEYS = 15_152_096, 160_000
+
+
+@pytest.fixture(scope="module")
+def kge():
+    from flink_parameter_server_tpu.models import kge as kg
+
+    model = kg.KGEConfig(KGE_ROWS, KGE_RELATIONS, KGE_DIM)
+    assert model.row_lanes == 101
+    return model, kg
+
+
+def test_kge_table_is_initialised_in_place_from_a_seed_argument(
+        kge, one_chip, no_compile_cache):
+    """15,152,092 x 101 f32 rule rows under a ``jit`` that takes the seed:
+    the 7.76 GB table ``f32[15152096,128]`` (a row alone in one register) is
+    the program's only output, initialised ``core/store._PACK_CHUNK`` rows a
+    loop step beside 0.07 GB of temporaries."""
+    model, kg = kge
+    compiled = jax.jit(lambda s: kg.make_store(model, seed=s).table).lower(
+        _shape(one_chip, (), jnp.uint32)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes == KGE_PHYS_ROWS * 128 * 4 == 7_757_873_152
+    assert mem.temp_size_in_bytes < 0.2 * GB
+    assert len(re.findall(r" while\(", compiled.as_text())) >= 1
+
+
+def _kge_cell_step(one_chip, kge, monkeypatch, layout):
+    """Cell 14's step, compiled with the arms chosen as on a TPU: as the
+    chip runs it (``auto``) or with the row left dense (pinned)."""
+    model, kg = kge
+    spec = jax.eval_shape(lambda: kg.make_store(model, layout=layout)).spec
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    n0 = row_update.refusal_count()
+    arm = store_mod.arms(spec, pull_lanes=KGE_KEYS, push_lanes=KGE_KEYS)
+    assert row_update.refusal_count() == n0
+    logic = kg.ComplExNegatives(model)
+    state = jax.tree.map(
+        lambda x: _shape(one_chip, x.shape, x.dtype),
+        jax.eval_shape(lambda: logic.init_state(jax.random.PRNGKey(0))))
+    batch = {
+        name: _shape(one_chip, (KGE_CHUNKS, n), jnp.int32)
+        for name, n in (
+            ("source", KGE_CHUNK), ("destination", KGE_CHUNK),
+            ("relation", KGE_CHUNK), ("source_negatives", KGE_UNIFORM),
+            ("destination_negatives", KGE_UNIFORM))
+    }
+    return spec, arm, jax.jit(
+        make_train_step(logic, spec), donate_argnums=(0, 1)
+    ).lower(_shape(one_chip, spec.table_shape(), jnp.float32), state, batch
+            ).compile()
+
+
+def test_kge_step_holds_its_one_register_table_once(
+        kge, one_chip, no_compile_cache, monkeypatch):
+    """Cell 14's step at full size for a described v5e, as ``"auto"`` lays a
+    rule row of 101 lanes (PR 61): PACKED, one row to one 128-lane register,
+    ``f32[15152096,128]{1,0}``.  The donated 7.76 GB table is rewritten in
+    place and never copied or transposed.  Under ``ps.pull`` ONE gather of
+    whole physical rows ``f32[160000,128]``, cut to the worker's 100 lanes;
+    under ``ps.push/ps.combine`` the row kernel's sums of the batch's
+    gradient rows, 100 lanes wide as they come; in the rule's loop ONE gather
+    ``f32[32768,128]`` under ``ps.rule`` and ONE ``sorted_row_set`` call on
+    the table, the write-back; no XLA scatter touches the table.  The
+    chunked scores are batched ``50 x 100 x 100`` products under the logic's
+    scopes, the written-out backward pass too.  Beside the table: 0.44 GB."""
+    spec, arm, compiled = _kge_cell_step(one_chip, kge, monkeypatch, "auto")
+    assert spec.layout == "packed" and spec.pack == 1
+    assert spec.table_shape() == (KGE_PHYS_ROWS, 128)
+    assert arm == store_mod.Arms(
+        "packed_selects", "rule", "", "row_kernel", "row_set", False)
+    mem = compiled.memory_analysis()
+    assert 7.75 * GB < mem.alias_size_in_bytes < 7.81 * GB  # in place
+    assert mem.temp_size_in_bytes < 0.6 * GB
+    text = compiled.as_text()
+    assert not re.search(r"f32\[15152096,128\]\S* (copy|transpose)\(", text)
+    assert "f32[15152096,101]" not in text and "f32[15152092," not in text
+    for scope in ("ps.pull", "ps.compute/ps.kge_operator/",
+                  "ps.compute/ps.kge_score/", "ps.compute/ps.kge_score_grad/",
+                  "ps.compute/ps.kge_operator_update/", "ps.push/ps.combine",
+                  "ps.push/while/body/ps.rule"):
+        assert scope in text, scope
+    assert "transpose(jvp(" not in text
+    lines = text.splitlines()
+    pulls = [c for c in lines if re.search(r" gather\(", c)
+             and " f32[160000,128]{1,0" in c and "ps.pull" in c]
+    assert len(pulls) == 1 and "slice_sizes={1,128}" in pulls[0], pulls
+    reads = [c for c in lines if re.search(r" gather\(", c)
+             and " f32[32768,128]{1,0" in c]
+    assert len(reads) == 1 and "ps.push/while/body/ps.rule" in reads[0], reads
+    kernels = [line for line in lines if "tpu_custom_call" in line]
+    names = sorted(k.strip().split(" ", 1)[0].rstrip(".0123456789") for k in kernels)
+    assert names == ["%sorted_row_set", "%sorted_row_update"], names
+    by_name = {k.strip().split(".", 1)[0]: k for k in kernels}
+    assert " f32[15152096,128]{1,0" in by_name["%sorted_row_set"]
+    assert "ps.push/while/body" in by_name["%sorted_row_set"]
+    assert "ps.push/ps.combine" in by_name["%sorted_row_update"]
+    # the operators' segment sum is the one scatter left, in the worker
+    scatters = [line for line in lines if re.search(r" scatter\(", line)]
+    assert len(scatters) == 1 and " f32[25291,2,100]" in scatters[0], scatters
+    assert "ps.kge_operator_update" in scatters[0]
+
+
+def test_kge_step_with_its_row_left_dense_copies_the_whole_table(
+        kge, one_chip, no_compile_cache, monkeypatch):
+    """Why ``"auto"`` packs a rule row of 65 to 127 lanes: pinned dense, the
+    TPU hands the step its ``f32[15152096,101]`` table capacity-minor
+    (``{0,1}``) and the step copies it WHOLE to a row-major one for its
+    gathers and back after XLA's row ``set``: 7.9 GB of temporaries beside
+    a table that the chip pads to 7.76 GB all the same; they do not fit."""
+    spec, arm, compiled = _kge_cell_step(one_chip, kge, monkeypatch, "dense")
+    assert spec.layout == "dense" and spec.table_shape() == (KGE_PHYS_ROWS, 101)
+    assert (arm.pull, arm.combine, arm.write_back) == (
+        "take", "row_kernel", "xla_set")
+    assert compiled.memory_analysis().temp_size_in_bytes > 6.0 * GB
+    text = compiled.as_text()
+    assert len(re.findall(r"f32\[15152096,101\]\S* copy\(", text)) >= 2
+    assert re.search(r"f32\[15152096,101\]\{0,1", text)
