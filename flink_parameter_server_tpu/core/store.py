@@ -978,12 +978,12 @@ def arms(
     packed k 1, 1 reg (100 lanes)     packed_selects  row_kernel   row_set      no         none  47 61
     packed k 1, 5 regs (602 lanes)    packed_selects  tile_kernel  tile_assign  no         none  55 57
     dense 1 reg (pinned, 100)         take            row_kernel   xla_set      no         none  46 61
-    packed k 3, the worker's 20 / 36  packed_kernel   row_kernel   row_set      no         9     59
-    the worker's 20 / 36 over ps 4    packed_kernel   row_kernel   row_set      yes        12    59
+    packed k 3, the worker's 20 / 36  packed_kernel   row_kernel   row_set      no         9     59 62
+    the worker's 20 / 36 over ps 4    packed_kernel   row_kernel   row_set      yes        12    59 62
     5 regs, the worker's 301 / 602    packed_selects  tile_kernel  tile_assign  no         13    59
     5 regs, the worker's 100 / 602    packed_selects  row_kernel   tile_assign  no         none  59
     5 regs, the worker's 3 / 602      packed_selects  sort         tile_assign  no         none  59
-    1 reg, the worker's 100 / 101     packed_selects  row_kernel   row_set      no         14    61
+    1 reg, the worker's 100 / 101     packed_selects  row_kernel   row_set      no         14    61 62
     1 reg, 100 / 101 over ps 4        packed_selects  row_kernel   row_set      yes        none  61
     ================================  ==============  ===========  ===========  =========  ====  ========
 
@@ -1000,7 +1000,14 @@ def arms(
     (:func:`_rewrite_packed`), so only an add push has a ``shift``.  A rule
     row no wider than a sort carries rides through the sort whatever the
     backend.  A narrow rule row NOT held at its tile (bfloat16, rank 0 or
-    2) keeps XLA's ``set``, noted."""
+    2) keeps XLA's ``set``, noted.  The ``row_kernel`` combine pays by the
+    BLOCK of 256 sorted lanes, whatever the batch's duplicates (its slots
+    are dense ranks, so a block's sums leave as ONE copy:
+    ``ops/row_update.sorted_run_sums``, PR 62; a descriptor a row written
+    until then), which is why ``arms`` need not know them: XLA's
+    scatter-add, which won cell 14's all-distinct batch by 1.15 ms against
+    the walk that paid by the row (PR 61), loses it by ~1.2 now, and lost
+    cell 9's (a row named 3.6 times) all along."""
     from ..ops import dedup, packed, row_update
 
     tpu = jax.default_backend() == "tpu"

@@ -144,13 +144,17 @@ def combine_runs(
       by the row kernel of ``ops/row_update`` (:func:`_kernel_sums`): on
       the v5e a serial scatter-add is 146 ns a 36-lane row, the permute of
       a whole-register row 8-10 and the kernel ~0.6 us a block of 256
-      lanes + 10-13 ns a row it writes (cell 9's ``ps.combine`` 188.8 ->
-      29.9 ms: PERF.md section 6, PR 46; 25.2 since the walk pays by the
-      row it writes and not by the lane: PR 54).
+      lanes, whatever the block holds: its slots are the dense ranks made
+      here, so a block's sums are neighbours and leave as ONE copy (cell
+      9's ``ps.combine`` 188.8 -> 29.9 ms: PERF.md section 6, PR 46; 25.2
+      once the walk paid by the row it wrote and not by the lane: PR 54;
+      by the block since PR 62).
 
-    The third value, for every arm but ``"sort"``: the single-row
-    DMAs the row kernel issued over the stretches it walked, an int32
-    scalar on the device (0 where the scatter-add summed the rows); ``None``
+    The third value, for every arm but ``"sort"``: the DMAs the kernel
+    started over the stretches it walked, an int32 scalar on the device
+    (``"row_kernel"``: a copy a block of 256 sorted lanes in which a run
+    ends; ``"tile_kernel"``: the tile rows it read and wrote; 0 where the
+    scatter-add summed the rows); ``None``
     for ``"sort"``, which has no such arm.  ``interpret`` is the kernel's
     (None: by the default backend)."""
     n, w = vals.shape
@@ -241,41 +245,47 @@ def _kernel_sums(
     """``sums[slot[k]] += vals[order[k]]`` over the sorted lanes ``k``
     (``vals`` float32, at most 128 lanes: :func:`kernel_refusal`; ``slot``
     ascending, the lanes to drop last with a slot past ``n``), as a
-    segment sum through ``ops/row_update.sorted_row_update``, the MF cells'
-    row kernel under the plan for ids that repeat: a zeroed ``(n, 128)``
-    block is its state, zeros are its old rows, a run's total is the one row
-    it writes.  Beside the sums, the single-row DMAs the calls issued.
+    segment sum through ``ops/row_update.sorted_run_sums``, the MF cells'
+    row kernel under the plan for ids that are DENSE RANKS: a zeroed block
+    of 128-lane rows is its state, a run's total is the one row it writes.
+    Beside the sums, the DMAs the calls started.
 
     The ``(n, w)`` rows are padded to ``(n, 128)``, row-major: whole
     registers, the only width at which a row gathers and DMAs in one piece
     (a 36-lane row of a rows-minor array is a strided column: 44 ns a row
     to permute on the v5e where a 128-lane row is 8-10).  Then, a stretch of
-    at most ``MAX_LANES`` sorted lanes a trip of ONE loop (the kernel's
-    scalars lie in SMEM; equal shapes, so the kernel is traced and lowered
-    once): the stretch's rows gathered in sorted order (the one permute),
-    the kernel's call into the block, which is carried and aliased from
-    trip to trip.  A run that lies across two stretches is written by both:
-    the second reads what the first wrote as ITS old row, as
+    at most ``MAX_LANES`` sorted lanes a trip of ONE loop (equal shapes, so
+    the kernel is traced and lowered once; a stretch is also what the one
+    permute holds beside the block): the stretch's rows gathered in sorted
+    order, the kernel's call into the block, which is carried and aliased
+    from trip to trip.  A run that lies across two stretches is written by
+    both: the second reads what the first wrote as ITS old row, as
     ``row_update.row_add`` does it.
 
-    The walk pays by what it WRITES (PERF.md section 6, PR 54).  A combine
-    exists because ids repeat (a Criteo record names a row 3.6 times: 72 %
-    of cell 9's lanes are not the last of their run), so the kernel takes
-    the compact plan and issues a DMA a run, not a lane; and the loop ends
-    with the stretch that holds the last LIVE lane, as ``core/store.
-    _push_rule``'s ends with the last distinct id: the dead lanes sort
-    last, the block starts zeroed and a dead lane writes nothing, so the
-    stretches left out change no bit (a shard of cell 12 that owns 3.7 % of
-    the keys walks one stretch of thirteen).  A stretch that is partly dead
-    is walked whole.
+    The walk pays by the BLOCK (PERF.md section 6, PR 62).  ``slot`` is
+    :func:`_wide_runs`' rank of each id among the distinct ones, so the
+    runs that end in a block of 256 sorted lanes write NEIGHBOURING rows:
+    the kernel sums each run straight into its place among them and sends
+    the block's rows as one copy, where it issued a DMA descriptor a lane
+    (PR 46) and then a row written (PR 54: 72 % of cell 9's lanes end no
+    run, but 99.5 % of cell 14's do, and a descriptor is 13 ns of the one
+    scalar core).  A copy is a whole kernel block of rows from the block's
+    first slot on, zeros behind its sums; a lane's slot is at most the
+    lane's place among the sorted lanes, so the zeroed block has a row a
+    lane of the padded stretches and no copy leaves it (cells 9 and 12: the
+    batch's own 1,277,952 rows, nothing sliced after).  The loop ends with the stretch that holds the last
+    LIVE lane, as ``core/store._push_rule``'s ends with the last distinct
+    id: the dead lanes sort last, the block starts zeroed and a dead lane
+    writes nothing, so the stretches left out change no bit (a shard of
+    cell 12 that owns 3.7 % of the keys walks one stretch of thirteen).  A
+    stretch that is partly dead is walked whole.
 
     A run is summed block by block of 256 lanes on the MXU, from three
     exact bfloat16 pieces accumulated in float32, a carry between blocks:
     NOT in the order of the stream (``np.add.at``), and to float32's
-    rounding of a blocked sum.  A non-finite value stays in its row."""
-    from .row_update import (
-        BLOCK, MAX_LANES, _open_run_reread, sorted_row_update_counted,
-    )
+    rounding of a blocked sum; bit for bit what the kernel summed under
+    its other plans.  A non-finite value stays in its row."""
+    from .row_update import BLOCK, MAX_LANES, sorted_run_sums
 
     n, w = vals.shape
     trips = -(-n // MAX_LANES)
@@ -284,21 +294,19 @@ def _kernel_sums(
     order = jnp.pad(order, (0, tail))
     slot = jnp.pad(slot, (0, tail), constant_values=_INT32_MAX)
     padded = jnp.pad(vals, ((0, 0), (0, 128 - w)))
-    zeros = jnp.zeros((size, 128), jnp.float32)
 
     def stretch(i, carry):
         block, issued = carry
         lo = i * size
-        slots = jax.lax.dynamic_slice_in_dim(slot, lo, size)
         # (a permutation: nothing to clip, and no fill to select after)
         rows = jnp.take(
             padded, jax.lax.dynamic_slice_in_dim(order, lo, size), axis=0,
             mode="clip",
         )
-        # a combine is handed ids that repeat: the walk pays by the row
-        block, sent = sorted_row_update_counted(
-            block, slots, _open_run_reread(block, slots, zeros), rows,
-            compact=True, interpret=interpret,
+        # the slots are dense ranks: a block's sums leave as neighbours
+        block, sent = sorted_run_sums(
+            block, jax.lax.dynamic_slice_in_dim(slot, lo, size), rows,
+            interpret=interpret,
         )
         return block, issued + sent
 
@@ -307,9 +315,12 @@ def _kernel_sums(
     live = jnp.sum(slot < _INT32_MAX, dtype=jnp.int32)
     block, issued = jax.lax.fori_loop(
         0, (live + size - 1) // size, stretch,
-        (jnp.zeros((n, 128), jnp.float32), jnp.zeros((), jnp.int32)),
+        # (a block's copy is a kernel block of rows from its first slot on,
+        # and a slot is at most its lane's place: a row a padded lane)
+        (jnp.zeros((trips * size, 128), jnp.float32),
+         jnp.zeros((), jnp.int32)),
     )
-    return block[:, :w], issued
+    return block[:n, :w], issued
 
 
 def _tile_sums(

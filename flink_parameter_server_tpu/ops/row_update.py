@@ -33,21 +33,27 @@ sharing a row are adjacent, and this kernel
 
 What a block issues is the PLAN's (:func:`_plan`), and the plan is the
 call site's, from what it knows of its ids by construction.  A keyed stream
-(MF's users: 99.3 % of the lanes write) keeps every writing lane where it
-lies: a lane that writes nothing repeats its block's first write, so a
-block that writes issues exactly ``block`` DMAs in a loop of static length
-with no branch a lane, and one wait the size of the staging slot answers
-them.  A batch handed over BECAUSE its ids repeat (a rule store's combine:
-``ops/dedup._kernel_sums``, 72 % of whose lanes in cell 9 end no run) and a
-write-back that dropped lanes itself (:func:`sorted_row_set`) take the
-COMPACT plan: one more sort of the block-shaped scalars brings a block's
+(MF's users: 99.3 % of the lanes write) takes ``"lane"``: every writing
+lane stays where it lies, a lane that writes nothing repeats its block's
+first write, so a block that writes issues exactly ``block`` DMAs in a loop
+of static length with no branch a lane, and one wait the size of the
+staging slot answers them.  A write-back that dropped lanes itself
+(:func:`sorted_row_set`: its targets are scattered table rows) takes
+``"compact"``: one more sort of the block-shaped scalars brings a block's
 writes to the front of its stretch, and the walk issues a descriptor a row
-it WRITES, eight a trip, at most seven over (cell 9's thirteen calls 12.20
--> 7.89 ms a step for 360.9 k descriptors where there were 852 k, its
-write-back's eleven 4.93 -> 4.22: PERF.md section 6, PR 54).  Same rows,
-same bytes, fewer copies of them.  At MF's shape the compact plan and its
-waits cost 0.023 ms a call more than they save (1.107 -> 1.130 ms at 65,536
-lanes): that caller keeps its plan.
+it WRITES, eight a trip, at most seven over (cell 9's write-back's eleven
+calls 4.93 -> 4.22 ms a step: PERF.md section 6, PR 54).  Same rows, same
+bytes, fewer copies of them.  At MF's shape the compact plan and its waits
+cost 0.023 ms a call more than they save (1.107 -> 1.130 ms at 65,536
+lanes): that caller keeps its plan.  A rule store's combine
+(``ops/dedup._kernel_sums``) hands over ids that are DENSE RANKS, so the
+rows a block writes are neighbours in its zeroed block, and takes
+``"dense"`` (:func:`sorted_run_sums`, a kernel body of its own): each run
+is summed straight into its place among the block's runs, by the same
+products, and the block's rows leave as ONE copy, no descriptor a row, no
+scalar a lane, whatever share of the lanes write (72 % of cell 9's end no
+run, 0.5 % of cell 14's: thirteen calls 7.89 -> 2.64 ms a step and two 2.61
+-> 0.44; PERF.md section 6, PR 62).
 
 The state array stays in HBM and is aliased to the output; rows no lane
 names are never touched.  Lanes to drop carry an id >= the row count (they
@@ -288,8 +294,32 @@ def sort_by_row(ids: Array, keep: Optional[Array], rows: int):
     return jax.lax.sort((key, iota), num_keys=1)
 
 
+def _exact_run_sums(mask, deltas):
+    """``mask @ deltas`` in float32 for a 0/1 bfloat16 ``mask``: three
+    bfloat16 pieces hold a float32 exactly and the mask is 0/1, so every
+    product is exact and the MXU's float32 accumulation is the only
+    rounding, three passes.  0 x NaN is NaN, so a non-finite delta is
+    summed as 0 and a fourth pass says which rows of the product it
+    reaches: those elements become NaN by select."""
+    finite = jnp.abs(deltas) <= jnp.finfo(jnp.float32).max
+    deltas = jnp.where(finite, deltas, 0.0)
+    hi = deltas.astype(jnp.bfloat16)
+    rest = deltas - hi.astype(jnp.float32)
+    mid = rest.astype(jnp.bfloat16)
+    lo = (rest - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+    bad = jnp.where(finite, 0.0, 1.0).astype(jnp.bfloat16)
+
+    def run_sum(*pieces):
+        return sum(
+            jnp.dot(mask, piece, preferred_element_type=jnp.float32)
+            for piece in pieces
+        )
+
+    return jnp.where(run_sum(bad) > 0, jnp.nan, run_sum(lo, mid, hi))
+
+
 def _kernel(tgt_ref, src_ref, writes_ref, aux_ref, old_ref, dl_ref, state_ref,
-            out_ref, buf_ref, carry_ref, sem, *, block: int, compact: bool):
+            out_ref, buf_ref, carry_ref, sem, *, block: int, plan: str):
     """One grid step = ``block`` sorted lanes.
 
     tgt_ref / src_ref: (N,) int32 SMEM (scalar prefetch) — per DMA of a
@@ -300,9 +330,9 @@ def _kernel(tgt_ref, src_ref, writes_ref, aux_ref, old_ref, dl_ref, state_ref,
       twice on one row harm nothing, and the loop has no branch a lane.
     writes_ref: (N / block,) int32 SMEM — how many lanes of a block write;
       a block where none does issues and awaits nothing.  What a block that
-      writes issues is :func:`_send_and_await`'s: all ``block`` entries, or
-      (``compact``: the plan has moved the writes to the front) as many
-      trips of eight as hold them.
+      writes issues is :func:`_send_and_await`'s: all ``block`` entries
+      (``plan`` ``"lane"``), or (``"compact"``: the plan has moved the
+      writes to the front) as many trips of eight as hold them.
     aux_ref: (8, block) int32 VMEM — row 0: per lane, the block-local index
       of the last lane of its run (clipped to the block); row 1: whether
       the block's first lane continues the previous block's run.
@@ -326,29 +356,10 @@ def _kernel(tgt_ref, src_ref, writes_ref, aux_ref, old_ref, dl_ref, state_ref,
     end = aux_ref[0:1, :]  # (1, block)
     ii = jax.lax.broadcasted_iota(jnp.int32, (block, block), 0)
     jj = jax.lax.broadcasted_iota(jnp.int32, (block, block), 1)
-    # lane j adds into lane i iff j <= i <= end[j]: runs are contiguous
+    # lane j adds into lane i iff j <= i <= end[j]: runs are contiguous; an
+    # inclusive segmented prefix sum in float32
     same_run = ((jj <= ii) & (ii <= end)).astype(jnp.bfloat16)
-    # three bfloat16 pieces hold a float32 exactly and the mask is 0/1, so
-    # every product is exact and the MXU's float32 accumulation is the only
-    # rounding: a float32 segmented prefix sum in three passes.  0 x NaN is
-    # NaN, so a non-finite delta is summed as 0 and a fourth pass says which
-    # elements of its run it reaches: those become NaN by select
-    deltas = dl_ref[:]
-    finite = jnp.abs(deltas) <= jnp.finfo(jnp.float32).max
-    deltas = jnp.where(finite, deltas, 0.0)
-    hi = deltas.astype(jnp.bfloat16)
-    rest = deltas - hi.astype(jnp.float32)
-    mid = rest.astype(jnp.bfloat16)
-    lo = (rest - mid.astype(jnp.float32)).astype(jnp.bfloat16)
-    bad = jnp.where(finite, 0.0, 1.0).astype(jnp.bfloat16)
-
-    def run_sum(*pieces):
-        return sum(
-            jnp.dot(same_run, piece, preferred_element_type=jnp.float32)
-            for piece in pieces
-        )
-
-    prefix = jnp.where(run_sum(bad) > 0, jnp.nan, run_sum(lo, mid, hi))
+    prefix = _exact_run_sums(same_run, dl_ref[:])
     col = jax.lax.broadcasted_iota(jnp.int32, (block, 1), 0)
     continues = (col <= aux_ref[0:1, 0:1]) & (aux_ref[1:2, 0:1] > 0)
     prefix = prefix + jnp.where(continues, carry_ref[:], 0.0)
@@ -356,23 +367,23 @@ def _kernel(tgt_ref, src_ref, writes_ref, aux_ref, old_ref, dl_ref, state_ref,
     buf_ref[slot] = old_ref[:] + prefix
 
     _send_and_await(tgt_ref, src_ref, writes_ref, buf_ref, out_ref, sem,
-                    slot, base, block=block, compact=compact)
+                    slot, base, block=block, plan=plan)
 
 
 def _send_and_await(tgt_ref, src_ref, writes_ref, buf_ref, out_ref, sem, slot,
-                    base, *, block: int, compact: bool):
+                    base, *, block: int, plan: str):
     """The walk both row kernels end a grid step with: send block ``b``'s
     staged rows (``buf_ref[slot]``, ``slot = b % 2``), one single-row DMA an
     entry of its stretch of ``tgt_ref`` / ``src_ref`` (which begins at
     ``base = b x block``), and await the block before's, the last block its
     own too: a block's writes are in flight while the next is staged.
 
-    What a block issues is the plan's (:func:`_plan`).  ``compact`` false
-    (a keyed stream: nearly every lane writes): all ``block`` entries,
+    What a block issues is the plan's (:func:`_plan`).  ``"lane"`` (a
+    keyed stream: nearly every lane writes): all ``block`` entries,
     eight a trip of a loop of static length, answered by ONE wait the size
     of the staging slot (a DMA semaphore counts bytes, and exactly
-    ``block`` rows were sent).  ``compact`` true (a batch handed over
-    BECAUSE its ids repeat, a write-back that dropped lanes itself): the
+    ``block`` rows were sent).  ``"compact"`` (a write-back that dropped
+    lanes itself; the combine's until it got a plan of its own): the
     block's ``writes_ref[b]`` writing entries lie first, and the loop runs
     ``ceil(writes / 8)`` trips, the last trip's spare entries repeating the
     block's first write as every entry past the writes does; the waits
@@ -386,6 +397,7 @@ def _send_and_await(tgt_ref, src_ref, writes_ref, buf_ref, out_ref, sem, slot,
     pl, pltpu = _pallas()
     lax = jax.lax
 
+    compact = plan == "compact"
     b = pl.program_id(0)
 
     def trips(blk):  # compact: the trips of eight that hold a block's writes
@@ -438,18 +450,19 @@ def _send_and_await(tgt_ref, src_ref, writes_ref, buf_ref, out_ref, sem, slot,
         wait_for(b, slot)
 
 
-def _plan(sorted_ids: Array, rows: int, block: int, compact: bool = False):
+def _plan(sorted_ids: Array, rows: int, block: int, plan: str = "lane"):
     """The kernel's per-lane scalars and vectors from the sorted ids:
     ``(tgt, src, count, aux)`` as :func:`_kernel` reads them.  A lane WRITES
     if it is the last of its run and its id names a row; ``count`` is a
-    block's writing lanes.
+    block's writing lanes.  ``plan`` is what the CALLER knows of its ids by
+    construction:
 
-    ``compact`` false: a writing lane's entry of ``tgt`` / ``src`` lies at
-    the lane, and every other entry repeats its block's first write: a
-    block that writes issues all ``block`` of them.  Nothing is moved, which
-    is what a keyed stream wants (MF's users: 99.3 % of the lanes write).
+    ``"lane"``: a writing lane's entry of ``tgt`` / ``src`` lies at the
+    lane, and every other entry repeats its block's first write: a block
+    that writes issues all ``block`` of them.  Nothing is moved, which is
+    what a keyed stream wants (MF's users: 99.3 % of the lanes write).
 
-    ``compact`` true: a block's writing entries lie FIRST in its stretch, in
+    ``"compact"``: a block's writing entries lie FIRST in its stretch, in
     lane order (single-row DMAs to distinct rows may go in any order), the
     rest repeat the first: a block issues ``ceil(count / 8)`` trips of
     eight (:func:`descriptors`), at most seven entries over what it writes.
@@ -457,33 +470,56 @@ def _plan(sorted_ids: Array, rows: int, block: int, compact: bool = False):
     rows along, as :func:`_tile_plan` moves its tile rows (a
     ``take_along_axis`` would be a gather of scalars, 10 ns each on the
     TPU).  That sort is what a caller pays to drop its repeats: on the v5e
-    10.5 us a call of 98,304 lanes (cell 9's thirteen ``sort s32[384,256]``
-    0.137 ms a step, its write-back's eleven ``s32[128,256]`` 0.041:
-    PERF.md section 6, PR 54)."""
+    10.5 us a call of 98,304 lanes (cell 9's write-back's eleven ``sort
+    s32[128,256]`` 0.041 ms a step: PERF.md section 6, PR 54).
+
+    ``"dense"`` (:func:`_dense_kernel` reads it): the kept ids are DENSE
+    RANKS, each the one before it or that plus one (a combine's slots), so
+    the rows a block writes are the ``count`` neighbours from its first
+    kept id on and no lane needs an entry: ``tgt`` is that first row A
+    BLOCK, ``src`` is None, and ``aux`` carries per lane the place of its
+    run among the block's (its id less the block's first; ``block`` for a
+    lane to drop: no place), whether the block's first lane continues the
+    block before's run (row 1, as above) and whether its first run is the
+    CALL's first (row 2).  Differences and two reductions a block: no
+    sort, no scan."""
     n = sorted_ids.shape[0]
     iota = jnp.arange(n, dtype=jnp.int32)
     differs = sorted_ids[1:] != sorted_ids[:-1]
     is_last = jnp.concatenate([differs, jnp.ones((1,), bool)])
     is_first = jnp.concatenate([jnp.ones((1,), bool), differs])
-    run_end = jax.lax.cummin(
-        jnp.where(is_last, iota, n - 1), axis=0, reverse=True
-    )
 
     def by_block(x):
         return x.reshape(n // block, block)
 
+    def aux_rows(*given):  # eight sublanes of int32 a block
+        given = [x[:, None] for x in given]
+        spare = jnp.zeros((n // block, 8 - len(given), block), jnp.int32)
+        return jnp.concatenate(given + [spare], axis=1)
+
+    if plan == "dense":
+        ids = by_block(sorted_ids)
+        place = jnp.where(ids < rows, ids - ids[:, :1], block)
+        flags = [
+            jnp.broadcast_to(flag.astype(jnp.int32)[:, :1], place.shape)
+            for flag in (by_block(~is_first), ids == sorted_ids[0])
+        ]
+        count = jnp.sum(
+            by_block(is_last & (sorted_ids < rows)), axis=1, dtype=jnp.int32)
+        tgt = jnp.minimum(ids[:, 0], rows - 1)
+        return tgt, None, count, aux_rows(place, *flags)
+    run_end = jax.lax.cummin(
+        jnp.where(is_last, iota, n - 1), axis=0, reverse=True
+    )
     local = by_block(iota % block)
     end_local = jnp.minimum(by_block(run_end - (iota // block) * block),
                             block - 1)
     continues = jnp.broadcast_to(
         by_block(~is_first).astype(jnp.int32)[:, :1], local.shape
     )
-    aux = jnp.concatenate(
-        [end_local[:, None], continues[:, None],
-         jnp.zeros((n // block, 6, block), jnp.int32)], axis=1,
-    )
+    aux = aux_rows(end_local, continues)
     writes = by_block(is_last & (sorted_ids < rows))
-    if compact:
+    if plan == "compact":
         key, tgt = jax.lax.sort(
             (jnp.where(writes, local, local + block), by_block(sorted_ids)),
             dimension=1, num_keys=1,
@@ -508,12 +544,16 @@ def _plan(sorted_ids: Array, rows: int, block: int, compact: bool = False):
     return tgt.reshape(-1), src.reshape(-1), count, aux
 
 
-def descriptors(count: Array, compact: bool, block: int = BLOCK) -> Array:
-    """The single-row DMAs the walk issues for blocks whose writing lanes
-    number ``count`` (int32, a block an entry), summed: a block that writes
-    sends all ``block`` entries of its stretch, under the compact plan the
-    trips of eight that hold its writes (:func:`_send_and_await`)."""
-    if compact:
+def descriptors(count: Array, plan: str, block: int = BLOCK) -> Array:
+    """The DMAs the walk starts for blocks whose writing lanes number
+    ``count`` (int32, a block an entry), summed: under ``"lane"`` a block
+    that writes sends all ``block`` single-row entries of its stretch,
+    under ``"compact"`` the trips of eight that hold its writes
+    (:func:`_send_and_await`), under ``"dense"`` ONE copy of its whole
+    staging slot (:func:`_dense_kernel`)."""
+    if plan == "dense":
+        sent = count > 0
+    elif plan == "compact":
         sent = jax.lax.shift_right_logical(count + 7, 3) * 8
     else:
         sent = jnp.where(count > 0, block, 0)
@@ -526,7 +566,7 @@ def sorted_row_update(
     old_rows: Array,
     deltas: Array,
     *,
-    compact: bool = False,
+    plan: str = "lane",
     interpret: Optional[bool] = None,
 ) -> Array:
     """``state[r] = old_rows[k] + sum(deltas[lanes of r])`` for every row
@@ -538,21 +578,32 @@ def sorted_row_update(
     row carry equal values).  ``deltas``: (n, W).  A dropped lane's old row
     and delta may be anything, NaN included: its run writes nothing.
 
-    ``compact`` is what the CALLER knows of its ids by construction, and
-    chooses the plan (:func:`_plan`): false for a keyed stream, whose lanes
-    nearly all write (the walk then issues a DMA a lane and the plan moves
-    nothing); true for a batch whose ids repeat, which pays one more sort of
+    ``plan`` is what the CALLER knows of its ids by construction
+    (:func:`_plan`): ``"lane"`` for a keyed stream, whose lanes nearly all
+    write (the walk then issues a DMA a lane and the plan moves nothing);
+    ``"compact"`` for a batch whose ids repeat, which pays one more sort of
     its block-shaped scalars and issues a DMA a row it WRITES.  The same
-    rows get the same bytes either way.
+    rows get the same bytes either way.  (Ids that are dense ranks go to
+    :func:`sorted_run_sums`, which sends a block's rows as neighbours.)
 
     The state is updated in place when the enclosing jit donates it; an
     eager call copies it first.  Off the TPU the kernel is interpreted
     (``interpret=None``: by the default backend).
     """
     return sorted_row_update_counted(
-        state, sorted_ids, old_rows, deltas, compact=compact,
-        interpret=interpret,
+        state, sorted_ids, old_rows, deltas, plan=plan, interpret=interpret,
     )[0]
+
+
+def _padded_to_blocks(sorted_ids: Array, *rows: Array):
+    """Ids and rows of lanes to drop appended up to whole blocks."""
+    pad = -sorted_ids.shape[0] % BLOCK
+    if not pad:
+        return (sorted_ids,) + rows
+    sorted_ids = jnp.concatenate(
+        [sorted_ids, jnp.full((pad,), _INT32_MAX, jnp.int32)]
+    )
+    return (sorted_ids,) + tuple(jnp.pad(r, ((0, pad), (0, 0))) for r in rows)
 
 
 def sorted_row_update_counted(
@@ -561,38 +612,34 @@ def sorted_row_update_counted(
     old_rows: Array,
     deltas: Array,
     *,
-    compact: bool = False,
+    plan: str = "lane",
     interpret: Optional[bool] = None,
 ) -> Tuple[Array, Array]:
     """:func:`sorted_row_update`'s state and, as an int32 scalar on the
     device, the single-row DMAs the call issued (:func:`descriptors`)."""
     pl, pltpu = _pallas()
 
+    if plan not in ("lane", "compact"):
+        raise ValueError(f"sorted_row_update: no plan {plan!r}")
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     rows, width = state.shape
-    n = sorted_ids.shape[0]
-    why = refusal(state.shape[1:], state.dtype) or _too_many(n)
+    why = refusal(state.shape[1:], state.dtype) or _too_many(
+        sorted_ids.shape[0])
     if why is not None and not interpret:
         raise ValueError(f"sorted_row_update: {why}")
     block = BLOCK
-    sorted_ids = sorted_ids.astype(jnp.int32)
-    deltas = deltas.astype(jnp.float32)
-    old_rows = old_rows.astype(jnp.float32)
-    pad = -n % block
-    if pad:
-        sorted_ids = jnp.concatenate(
-            [sorted_ids, jnp.full((pad,), _INT32_MAX, jnp.int32)]
-        )
-        deltas = jnp.pad(deltas, ((0, pad), (0, 0)))
-        old_rows = jnp.pad(old_rows, ((0, pad), (0, 0)))
-    tgt, src, count, aux = _plan(sorted_ids, rows, block, compact)
+    sorted_ids, deltas, old_rows = _padded_to_blocks(
+        sorted_ids.astype(jnp.int32), deltas.astype(jnp.float32),
+        old_rows.astype(jnp.float32),
+    )
+    tgt, src, count, aux = _plan(sorted_ids, rows, block, plan)
     if not isinstance(state, jax.core.Tracer):
         state = jnp.copy(state)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=((n + pad) // block,),
+        grid=(sorted_ids.shape[0] // block,),
         in_specs=[
             pl.BlockSpec((None, 8, block), lambda b, *_: (b, 0, 0)),
             pl.BlockSpec((block, width), lambda b, *_: (b, 0)),
@@ -607,7 +654,7 @@ def sorted_row_update_counted(
         ],
     )
     state = pl.pallas_call(
-        functools.partial(_kernel, block=block, compact=compact),
+        functools.partial(_kernel, block=block, plan=plan),
         out_shape=jax.ShapeDtypeStruct(state.shape, state.dtype),
         grid_spec=grid_spec,
         input_output_aliases={6: 0},  # (tgt, src, count, aux, old, deltas, state)
@@ -617,7 +664,170 @@ def sorted_row_update_counted(
         interpret=interpret,
         name="sorted_row_update",
     )(tgt, src, count, aux, old_rows, deltas, state)
-    return state, descriptors(count, compact, block)
+    return state, descriptors(count, plan, block)
+
+
+# -- the same sums for ids that are DENSE RANKS: a rule store's combine -------
+def _dense_kernel(tgt_ref, writes_ref, aux_ref, head_ref, dl_ref, state_ref,
+                  out_ref, buf_ref, carry_ref, sem, *, block: int):
+    """:func:`_kernel` for ids that are dense ranks (:func:`_plan`,
+    ``"dense"``): one grid step = ``block`` sorted lanes, whose runs'
+    totals are summed STRAIGHT INTO THE ORDER THEY ARE WRITTEN IN and sent
+    as neighbours.
+
+    tgt_ref / writes_ref: (N / block,) int32 SMEM (scalar prefetch) — the
+      first row a block writes and how many: rows ``[tgt, tgt + writes)``.
+    aux_ref: (8, block) int32 VMEM — row 0: per lane, its run's place among
+      the block's runs (``block``: a lane to drop, no place); row 1: whether
+      the block's first lane continues the block before's run; row 2:
+      whether the block's first run is the call's first.
+    head_ref: (1, W) f32 VMEM — the row the state holds for the call's
+      first run (what an earlier call summed of it; zeros else): its old
+      row.  Every other row a call writes is new.
+    dl_ref: (block, W) f32 VMEM — the deltas, sorted.
+    buf_ref / carry_ref / sem: as :func:`_kernel`'s.
+
+    Lane ``j`` adds into row ``place[j]`` of the product, so row ``r`` is
+    the sum of the block's ``r``-th run: the same 0/1 mask row, the same
+    three exact pieces and the same float32 accumulation as
+    :func:`_kernel`'s inclusive prefix sum reads at that run's LAST lane,
+    bit for bit, the carry of an open run and the non-finite rule with
+    them; it is only born where it is sent from, and no product is added
+    for it.  The ``writes`` rows then lie at the front of the staging slot,
+    zeros behind them, and the slot goes out WHOLE: one copy of ``block``
+    rows to ``tgt`` on, where :func:`_send_and_await` issues a descriptor a
+    row.  The zeros behind a block's writes land on rows the NEXT blocks
+    write (and, past the last slot, on rows that hold zeros), so a block
+    starts its copy only when the block before's has landed: awaited after
+    this block's sums are staged, so the copy flies under the next block's
+    matmuls, and the state needs ``block`` rows from ``tgt`` on.  On the
+    v5e that wait is never on the path (cell 9's 4,992 blocks a step, 72
+    writes each: 23.94 ms the whole combine alone, against 23.97 / 24.15 in
+    copies of 64 / 32 rows, 25.62 for two overlapping copies of a power of
+    two that need no order but nine branches a block, and 29.38 under the
+    compact plan; cell 14's 626 blocks of 254 writes 1.75 against 4.13:
+    PERF.md section 6, PR 62)."""
+    pl, pltpu = _pallas()
+
+    del state_ref  # aliased to out_ref: untouched rows keep their values
+    b = pl.program_id(0)
+    slot = b % 2
+
+    @pl.when(b == 0)
+    def _init():
+        carry_ref[:] = jnp.zeros_like(carry_ref)
+
+    place = aux_ref[0:1, :]  # (1, block)
+    ii = jax.lax.broadcasted_iota(jnp.int32, (block, block), 0)
+    sums = _exact_run_sums((ii == place).astype(jnp.bfloat16), dl_ref[:])
+    row = jax.lax.broadcasted_iota(jnp.int32, (block, 1), 0)
+    first = row == 0
+    sums = sums + jnp.where(
+        first & (aux_ref[1:2, 0:1] > 0), carry_ref[:], 0.0)
+    # the run open at the block's end is the last lane's (all but one row
+    # of the sum are zeros: it is exact)
+    carry_ref[:] = jnp.sum(
+        jnp.where(row == aux_ref[0:1, block - 1:block], sums, 0.0),
+        axis=0, keepdims=True,
+    )
+    head = jnp.where(first & (aux_ref[2:3, 0:1] > 0), head_ref[:], 0.0)
+    buf_ref[slot] = jnp.where(row < writes_ref[b], head + sums, 0.0)
+
+    def whole(blk, s):  # a block that writes sends its slot whole
+        return pltpu.make_async_copy(
+            buf_ref.at[s], out_ref.at[pl.ds(tgt_ref[blk], block)], sem.at[s]
+        )
+
+    before = jnp.maximum(b - 1, 0)
+
+    @pl.when((b > 0) & (writes_ref[before] > 0))
+    def _previous():
+        whole(before, 1 - slot).wait()
+
+    @pl.when(writes_ref[b] > 0)
+    def _start():
+        whole(b, slot).start()
+
+    @pl.when((b == pl.num_programs(0) - 1) & (writes_ref[b] > 0))
+    def _own():
+        whole(b, slot).wait()
+
+
+def sorted_run_sums(
+    state: Array,
+    slots: Array,
+    rows: Array,
+    *,
+    interpret: Optional[bool] = None,
+) -> Tuple[Array, Array]:
+    """``state[s] = state[s] + sum(rows[lanes of s])`` for every slot ``s``
+    some kept lane names, where the kept slots are DENSE RANKS — ascending,
+    each the one before it or that plus one — and ``state`` holds zeros
+    from the first slot's row on, that row itself aside (a run that began in
+    the call before: its old row is read here, as :func:`_open_run_reread`
+    reads it for :func:`row_add`): a rule store's combine
+    (``ops/dedup._kernel_sums``), one call a stretch of its sorted lanes.
+    Returns the state and the DMAs the call started
+    (:func:`descriptors`, ``"dense"``), an int32 scalar on the device.
+
+    ``state``: (n, 128) float32 (:func:`refusal`) with ``BLOCK`` rows from
+    the first slot of every block of ``BLOCK`` lanes on: a block's copy
+    writes zeros over those of them it has no sum for.  (Ranks from 0 are
+    at most their lane's place: a row a lane of the batch, padded to whole
+    blocks, is enough.)
+    ``slots``: (n,) int32, lanes to drop at the end with a slot >= the row
+    count.  ``rows``: (n, 128), a dropped lane's anything.  The sums are
+    :func:`sorted_row_update`'s bit for bit (:func:`_dense_kernel`); what
+    differs is how they leave the kernel: a block's totals are neighbours
+    in the state, so they go as ONE copy and the plan needs no scalar a
+    lane (two a BLOCK in SMEM).  At most ``MAX_LANES`` lanes a call all the
+    same: the caller's stretches are its one permute's too.  In place when
+    the enclosing jit donates the state; an eager call copies it first.
+    Off the TPU the kernel is interpreted."""
+    pl, pltpu = _pallas()
+
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    height, width = state.shape
+    why = refusal(state.shape[1:], state.dtype) or _too_many(slots.shape[0])
+    if why is not None and not interpret:
+        raise ValueError(f"sorted_run_sums: {why}")
+    block = BLOCK
+    slots, rows = _padded_to_blocks(
+        slots.astype(jnp.int32), rows.astype(jnp.float32))
+    tgt, _, count, aux = _plan(slots, height, block, "dense")
+    head = _open_run_row(state, slots[0])
+    if not isinstance(state, jax.core.Tracer):
+        state = jnp.copy(state)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(slots.shape[0] // block,),
+        in_specs=[
+            pl.BlockSpec((None, 8, block), lambda b, *_: (b, 0, 0)),
+            pl.BlockSpec((1, width), lambda b, *_: (0, 0)),
+            pl.BlockSpec((block, width), lambda b, *_: (b, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),  # the state stays in HBM
+        ],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        scratch_shapes=[
+            pltpu.VMEM((2, block, width), jnp.float32),
+            pltpu.VMEM((1, width), jnp.float32),
+            pltpu.SemaphoreType.DMA((2,)),
+        ],
+    )
+    state = pl.pallas_call(
+        functools.partial(_dense_kernel, block=block),
+        out_shape=jax.ShapeDtypeStruct(state.shape, state.dtype),
+        grid_spec=grid_spec,
+        input_output_aliases={5: 0},  # (tgt, count, aux, head, rows, state)
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)
+        ),
+        interpret=interpret,
+        name="sorted_run_sums",
+    )(tgt, count, aux, head, rows, state)
+    return state, descriptors(count, "dense", block)
 
 
 def _calls(sorted_ids: Array, order: Array):
@@ -645,6 +855,14 @@ def _pad_for_calls(lanes: int) -> int:
     return -lanes % (calls * BLOCK) if calls > 1 else 0
 
 
+def _open_run_row(state: Array, first: Array) -> Array:
+    """``(1, W)``: the state's row for ``first``, a call's first id (row 0's
+    for a lane to drop, which writes nothing)."""
+    return jax.lax.dynamic_slice_in_dim(
+        state, jnp.minimum(first, state.shape[0] - 1), 1, axis=0
+    )
+
+
 def _open_run_reread(state: Array, sorted_ids: Array, old: Array) -> Array:
     """``old`` for a call that is not a batch's first: the run at its head
     may have begun in the call before, which then wrote that row (its old
@@ -653,9 +871,7 @@ def _open_run_reread(state: Array, sorted_ids: Array, old: Array) -> Array:
     that begins here reads what it had."""
     first = sorted_ids[0]
     last = jnp.sum(sorted_ids == first, dtype=jnp.int32) - 1
-    row = jax.lax.dynamic_slice_in_dim(
-        state, jnp.minimum(first, state.shape[0] - 1), 1, axis=0
-    )
+    row = _open_run_row(state, first)
     return jax.lax.dynamic_update_slice_in_dim(
         old, row.astype(old.dtype), last, axis=0
     )
@@ -713,7 +929,7 @@ def _row_set_kernel(tgt_ref, src_ref, writes_ref, new_ref, state_ref, out_ref,
     slot = b % 2
     buf_ref[slot] = new_ref[:]
     _send_and_await(tgt_ref, src_ref, writes_ref, buf_ref, out_ref, sem,
-                    slot, b * block, block=block, compact=True)
+                    slot, b * block, block=block, plan="compact")
 
 
 def sorted_row_set(
@@ -750,21 +966,17 @@ def sorted_row_set(
     if why is not None and not interpret:
         raise ValueError(f"sorted_row_set: {why}")
     block = BLOCK
-    ids = ids.astype(jnp.int32)
-    new_rows = new_rows.astype(state.dtype)
-    pad = -n % block
-    if pad:
-        ids = jnp.concatenate([ids, jnp.full((pad,), _INT32_MAX, jnp.int32)])
-        new_rows = jnp.pad(new_rows, ((0, pad), (0, 0)))
+    ids, new_rows = _padded_to_blocks(
+        ids.astype(jnp.int32), new_rows.astype(state.dtype))
     # distinct ids: every kept lane is the last of its run, and writes; the
     # caller's dropped lanes lie between them, so the plan compacts
-    tgt, src, count, _ = _plan(ids, rows, block, compact=True)
+    tgt, src, count, _ = _plan(ids, rows, block, "compact")
     if not isinstance(state, jax.core.Tracer):
         state = jnp.copy(state)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=((n + pad) // block,),
+        grid=(ids.shape[0] // block,),
         in_specs=[
             pl.BlockSpec((block, width), lambda b, *_: (b, 0)),
             pl.BlockSpec(memory_space=pl.ANY),  # the state stays in HBM

@@ -481,9 +481,10 @@ def test_the_driver_publishes_the_lanes_of_a_row_that_crossed(store):
 def test_the_driver_publishes_the_descriptors_the_combine_issued(
         arm, monkeypatch, steer_arms):
     """``store_combine_kernel_writes`` beside ``store_combine_kernel_lanes``:
-    with the row kernel steered on (interpreted here) a DMA a distinct row
-    of the last dispatch, up to a trip of eight a block, where the lanes are
-    its live keys; both 0 where XLA's scatter-add summed the rows."""
+    with the row kernel steered on (interpreted here) ONE copy a block of
+    256 sorted lanes of the last dispatch (its sums are neighbours: the
+    dense plan), where the lanes are its live keys; both 0 where XLA's
+    scatter-add summed the rows."""
     from flink_parameter_server_tpu.core import store as store_mod
     from flink_parameter_server_tpu.telemetry.registry import MetricsRegistry
 
@@ -508,8 +509,8 @@ def test_the_driver_publishes_the_descriptors_the_combine_issued(
         return
     rows = gauges["store_rule_rows"][0]["value"]
     assert lanes == gauges["store_rule_keys"][0]["value"] == 512
-    assert rows == len(np.unique(ids[-1])) <= writes <= rows + 7 * 2
-    assert writes < lanes / 3  # a row is named five times on average
+    assert rows == len(np.unique(ids[-1])) > 50
+    assert writes == 2 == lanes // 256  # a copy a block, not a DMA a row
 
 
 def test_the_logics_scopes_are_in_the_lowered_step():
@@ -613,9 +614,9 @@ def test_wide_rows_are_summed_along_sorted_lanes_by_the_row_kernel(
 
     monkeypatch.setattr(row_update, "MAX_LANES", 512)
     calls = []
-    real = row_update.sorted_row_update_counted
+    real = row_update.sorted_run_sums
     monkeypatch.setattr(
-        row_update, "sorted_row_update_counted",
+        row_update, "sorted_run_sums",
         lambda *a, **kw: calls.append(a[1].shape[0]) or real(*a, **kw))
     rng = np.random.default_rng([width, n])
     sentinel = 5000
@@ -631,12 +632,11 @@ def test_wide_rows_are_summed_along_sorted_lanes_by_the_row_kernel(
     )(ids, vals)
     # one traced kernel under a loop, whole blocks, no more lanes than a call holds
     assert len(calls) == 1 and calls[0] <= 512 and calls[0] % 256 == 0
-    # the walk pays by what it writes: the stretches that hold a live lane, a
-    # DMA a run (a run across two stretches is written by both) and at most
-    # seven spare a block of 256
+    # the walk pays by the block: the stretches that hold a live lane, ONE
+    # copy a block of 256 lanes in which a run ends (its sums are neighbours)
     walked = -(-int((ids < sentinel).sum()) // calls[0])
     runs = len(np.unique(ids[ids < sentinel]))
-    assert runs <= int(sent) <= runs + walked + 7 * walked * (calls[0] // 256)
+    assert (runs > 0) <= int(sent) <= min(runs + walked, walked * (calls[0] // 256))
     row_ids, sums = np.asarray(row_ids), np.array(sums)
     assert row_ids.shape == (n,) and sums.shape == (n, width)
     assert sums.dtype == np.float32

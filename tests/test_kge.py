@@ -316,3 +316,54 @@ def test_the_driver_sets_the_rule_s_gauges_and_the_logic_s_after_the_loop():
     # the worker's part of a row crossed: the embedding, no accumulator
     assert gauges["store_pull_row_lanes"][0]["value"] == DIM
     assert gauges["store_push_row_lanes"][0]["value"] == DIM
+
+
+@pytest.mark.parametrize("arm", ["xla", "row_kernel"])
+def test_the_driver_publishes_the_copies_the_combine_started(arm, steer_arms):
+    """``store_combine_kernel_writes`` beside ``store_combine_kernel_lanes``
+    for the one-register rule row: with the row kernel steered on
+    (interpreted here) the sums of a batch whose keys are nearly all
+    distinct leave as ONE copy a block of 256 sorted lanes (the dense plan
+    of PR 62; a DMA a distinct row until then), and the step's table is the
+    scatter-add arm's within the rounding of a blocked sum; both gauges 0
+    where XLA's scatter-add summed the rows."""
+    from flink_parameter_server_tpu import DriverConfig, StreamingDriver
+    from flink_parameter_server_tpu.telemetry.registry import MetricsRegistry
+
+    if arm == "row_kernel":
+        steer_arms(combine="row_kernel")
+    rng = np.random.default_rng(62)
+    half, chunks, chunk, uniform = ENTITIES // 2, 8, 40, 24
+    batch = {
+        "source": rng.integers(0, half, (chunks, chunk)),
+        "destination": half + rng.integers(0, half, (chunks, chunk)),
+        "relation": rng.integers(0, RELATIONS, (chunks, chunk)),
+        "source_negatives": rng.integers(0, half, (chunks, uniform)),
+        "destination_negatives": half + rng.integers(0, half, (chunks, uniform)),
+    }
+    batch = {k: v.astype(np.int32) for k, v in batch.items()}
+    registry = MetricsRegistry()
+    driver = StreamingDriver(
+        kge.ComplExNegatives(MODEL), kge.make_store(MODEL, seed=3),
+        config=DriverConfig(steps_per_call=1, dump_model=False),
+        registry=registry,
+    )
+    result = driver.run(iter([batch]))
+    gauges = registry.snapshot()
+    lanes = gauges["store_combine_kernel_lanes"][0]["value"]
+    writes = gauges["store_combine_kernel_writes"][0]["value"]
+    if arm == "xla":
+        assert lanes == 0 == writes
+        return
+    keys = np.concatenate([batch[k].reshape(-1) for k in REF.KEYS])
+    rows = gauges["store_rule_rows"][0]["value"]
+    assert lanes == len(keys) == 1024 and rows == len(np.unique(keys)) > 300
+    assert writes == 4 == lanes // 256  # a copy a block, not a DMA a row
+    want = StreamingDriver(
+        kge.ComplExNegatives(MODEL), kge.make_store(MODEL, seed=3),
+        config=DriverConfig(steps_per_call=1, dump_model=False),
+    )
+    steer_arms(combine="scatter_add")
+    want = np.asarray(want.run(iter([batch])).store.table)
+    np.testing.assert_allclose(
+        np.asarray(result.store.table), want, rtol=2e-6, atol=1e-7)

@@ -112,7 +112,7 @@ def _compact_plan_is_numpys(ids, rows, block):
     ids = np.concatenate(
         [ids, np.full(-len(ids) % block, np.iinfo(np.int32).max, np.int32)])
     tgt, src, count, _ = (np.asarray(x) for x in row_update._plan(
-        jnp.asarray(ids), rows, block, compact=True))
+        jnp.asarray(ids), rows, block, "compact"))
     last = np.concatenate([ids[1:] != ids[:-1], [True]]) & (ids < rows)
     sent = 0
     for b in range(len(ids) // block):
@@ -128,7 +128,8 @@ def _compact_plan_is_numpys(ids, rows, block):
         assert ((src[at] >= 0) & (src[at] < block)).all()
         assert ((tgt[at] >= 0) & (tgt[at] < rows)).all()
         sent += -(-c // 8) * 8
-    assert int(row_update.descriptors(jnp.asarray(count), True, block)) == sent
+    assert int(
+        row_update.descriptors(jnp.asarray(count), "compact", block)) == sent
     return sent
 
 
@@ -139,7 +140,7 @@ def _row_add_under_the_compact_plan(monkeypatch):
 
     def update(*args, **kw):
         state, sent = row_update.sorted_row_update_counted(
-            *args, compact=True, **kw)
+            *args, plan="compact", **kw)
         issued.append(sent)
         return state
 
@@ -1236,3 +1237,237 @@ def test_wide_rows_are_summed_by_the_tile_kernel_in_stream_order_bit_for_bit(
     assert dedup.kernel_refusal(whole, jnp.float32) is None
     assert "whole 128-lane" in dedup.kernel_refusal(whole + 1, jnp.float32)
     assert "bfloat16" in dedup.kernel_refusal(whole, jnp.bfloat16)
+
+
+# -- the dense plan: a combine's sums leave the kernel as neighbours ----------
+DENSE_CASES = [
+    "uniform_few_duplicates", "every_lane_distinct", "one_run_over_the_batch",
+    "runs_across_blocks_and_calls", "dropped_lanes_at_the_end",
+    "whole_dead_blocks", "every_lane_dropped", "nan_inf_minus_zero_kept",
+    "nan_inf_in_dropped_lanes", "blocks_that_write_1_7_8_9_and_256",
+    "batch_not_a_multiple_of_block", "every_lane_distinct_no_whole_blocks",
+]
+DEAD = np.iinfo(np.int32).max
+
+
+def _dense_case(name):
+    """(slots, rows): one batch in sorted order, its slots the dense ranks
+    of its ids (ascending, each the one before or that plus one), the lanes
+    to drop last with the slot ``DEAD``."""
+    rng = np.random.default_rng(DENSE_CASES.index(name))
+    n = 1536
+    ids = np.sort(rng.integers(0, 700, n))
+    dead = np.zeros(n, bool)
+    if name == "every_lane_distinct":
+        ids = np.arange(n)
+    elif name == "one_run_over_the_batch":
+        ids[:] = 3
+    elif name == "runs_across_blocks_and_calls":
+        # runs of 700 and 300 lanes across the blocks of 256 and, at 512
+        # lanes a call, across calls; one ends with its block, one with
+        # its call
+        ids = np.sort(np.concatenate([
+            np.full(700, 5), np.full(68, 6), rng.integers(7, 90, 212),
+            np.full(300, 90), rng.integers(91, 200, n - 1280)]))
+    elif name == "dropped_lanes_at_the_end":
+        dead[1000:] = True
+    elif name == "whole_dead_blocks":
+        dead[300:] = True  # at 512 lanes a call, two calls of nothing else
+    elif name == "every_lane_dropped":
+        dead[:] = True
+    elif name == "blocks_that_write_1_7_8_9_and_256":
+        ids = np.concatenate([
+            np.zeros(256), 10 + np.minimum(np.arange(256), 6),
+            20 + np.minimum(np.arange(256), 7),
+            30 + np.minimum(np.arange(256), 8), 100 + np.arange(256),
+            np.full(256, 500),
+        ])
+        dead[1280:] = True
+    elif name == "batch_not_a_multiple_of_block":
+        ids, dead = ids[:1000], dead[:1000]
+    elif name == "every_lane_distinct_no_whole_blocks":
+        ids, dead = np.arange(1000), dead[:1000]  # the last copy ends the state
+    slots = np.unique(ids, return_inverse=True)[1].astype(np.int32)
+    slots[dead] = DEAD
+    n = len(slots)
+    rows = (rng.normal(size=(n, WIDTH)) * 10.0 ** rng.integers(-3, 4, (n, 1))
+            ).astype(np.float32)
+    rows[dead] = 7.0
+    if name == "nan_inf_minus_zero_kept":
+        lanes = np.flatnonzero(slots == slots[400])
+        rows[lanes[0], 1], rows[lanes[-1], 2] = np.nan, np.inf
+        rows[lanes[0], 3], rows[lanes[-1], 3] = np.inf, -np.inf
+        rows[700, 4] = -np.inf
+        rows[slots == slots[900], 5] = -0.0
+        rows[:, 6] = -0.0
+        # finite values whose float32 sum is not
+        rows[slots == slots[1200], 7] = 3e38
+    elif name == "nan_inf_in_dropped_lanes" or dead.any():
+        rows[dead, ::3] = np.nan
+        rows[dead, 1::3] = np.inf
+    if name == "nan_inf_in_dropped_lanes":
+        slots[1100:] = DEAD
+        rows[1100:, ::2], rows[1100:, 1::2] = np.nan, -np.inf
+    return slots, rows
+
+
+def _sums_under_the_compact_plan(slots, rows, size):
+    """The PARENT's combine (PR 54 to PR 61): a stretch of ``size`` sorted
+    lanes a call of the row kernel under the compact plan into a zeroed
+    block, a run across two calls read again by the second."""
+    n = len(slots)
+    pad = -n % size
+    slots = jnp.asarray(np.concatenate([slots, np.full(pad, DEAD, np.int32)]))
+    rows = jnp.asarray(np.concatenate([rows, np.zeros((pad, WIDTH), "f4")]))
+    zeros = jnp.zeros((size, WIDTH), jnp.float32)
+    block, sent = jnp.zeros((n, WIDTH), jnp.float32), 0
+    for lo in range(0, n + pad, size):
+        at = slots[lo:lo + size]
+        block, issued = row_update.sorted_row_update_counted(
+            block, at, row_update._open_run_reread(block, at, zeros),
+            rows[lo:lo + size], plan="compact", interpret=True)
+        sent += int(issued)
+    return np.asarray(block), sent
+
+
+def _dense_copies(slots, size, block):
+    """numpy: the copies the dense plan starts over calls of ``size``
+    lanes: one a block that writes (:func:`row_update.descriptors`)."""
+    slots = np.concatenate(
+        [slots, np.full(-len(slots) % size, DEAD, np.int32)])
+    sent = 0
+    for lo in range(0, len(slots), size):
+        call = slots[lo:lo + size]
+        call = np.concatenate([call, np.full(-len(call) % block, DEAD)])
+        last = np.concatenate([call[1:] != call[:-1], [True]]) & (call < DEAD)
+        sent += int((last.reshape(-1, block).sum(axis=1) > 0).sum())
+    return sent
+
+
+@pytest.mark.parametrize("block", [128, 256])
+@pytest.mark.parametrize("size", [512, 2048])
+@pytest.mark.parametrize("name", DENSE_CASES)
+def test_the_dense_plans_sums_are_the_compact_plans_bit_for_bit(
+        name, size, block, monkeypatch):
+    """``sorted_run_sums`` (a rule store's combine: its slots are dense
+    ranks) against the row kernel under the compact plan on the same
+    slots, one call and calls of 512 lanes: every kept row the same BITS
+    (the same mask rows, pieces and accumulation; a NaN or an Inf stays in
+    its run, -0.0 sums as it did, finite values that overflow read Inf in
+    both), zeros past the last distinct rank whatever the dropped lanes
+    hold, and ONE copy a block that writes where the compact plan issues a
+    descriptor a row."""
+    monkeypatch.setattr(row_update, "BLOCK", block)
+    slots, rows = _dense_case(name)
+    n = len(slots)
+    want, sent_rows = _sums_under_the_compact_plan(slots, rows, size)
+
+    def combine(slots, rows):
+        # (a copy is a whole block of rows from the block's first slot on,
+        # and a slot is at most its lane's place: a row a padded lane will
+        # do, with every lane distinct too)
+        state, sent = jnp.zeros((-(-n // block) * block, WIDTH), "f4"), 0
+        for lo in range(0, n, size):
+            state, issued = row_update.sorted_run_sums(
+                state, slots[lo:lo + size], rows[lo:lo + size],
+                interpret=True)
+            sent = sent + issued
+        return state, sent
+
+    got, sent = jax.jit(combine)(jnp.asarray(slots), jnp.asarray(rows))
+    got = np.asarray(got)
+    assert got[:n].tobytes() == want.tobytes()
+    distinct = int(slots[slots < DEAD].max()) + 1 if (slots < DEAD).any() else 0
+    assert not got[distinct:].any()
+    if name not in ("nan_inf_minus_zero_kept",):
+        clean = np.where(np.isfinite(rows), rows, 0.0).astype(np.float64)
+        ref = np.zeros(got.shape)
+        np.add.at(ref, slots[slots < DEAD], clean[slots < DEAD])
+        np.testing.assert_allclose(got, ref, rtol=0, atol=2e-3 * np.abs(
+            clean).max())
+    assert int(sent) == _dense_copies(slots, size, block) <= -(-n // block)
+    assert int(sent) * 8 <= sent_rows  # the compact plan's trips of eight
+    if name == "blocks_that_write_1_7_8_9_and_256" and (size, block) == (
+            2048, 256):
+        assert sent_rows == 8 + 8 + 8 + 16 + 256 and int(sent) == 5
+    if name == "nan_inf_minus_zero_kept":
+        at = slots[400]
+        assert np.isnan(got[at, 1]) and np.isnan(got[at, 3])
+        assert np.isnan(got[at, 2]) and np.isnan(got[slots[700], 4])
+        assert np.isinf(got[slots[1200], 7]) or (
+            slots == slots[1200]).sum() == 1
+        assert np.isfinite(np.delete(got, [at, slots[700]], axis=0)[:, :7]).all()
+
+
+def test_the_dense_plan_is_numpys():
+    """``_plan(..., "dense")``: a block's first row, its writes, and per lane
+    the place of its run among the block's runs."""
+    slots, _ = _dense_case("runs_across_blocks_and_calls")
+    slots[1400:] = DEAD
+    block, rows = 256, 1536
+    tgt, src, count, aux = row_update._plan(
+        jnp.asarray(slots), rows, block, "dense")
+    assert src is None
+    tgt, count, aux = np.asarray(tgt), np.asarray(count), np.asarray(aux)
+    last = np.concatenate([slots[1:] != slots[:-1], [True]]) & (slots < rows)
+    for b in range(len(slots) // block):
+        at = slice(b * block, (b + 1) * block)
+        mine = slots[at]
+        assert count[b] == last[at].sum()
+        assert tgt[b] == min(mine[0], rows - 1)
+        kept = mine < rows
+        assert np.array_equal(aux[b, 0][kept], mine[kept] - mine[0])
+        assert (aux[b, 0][~kept] == block).all()
+        # the rows a block writes are neighbours from its first on
+        assert np.array_equal(
+            mine[last[at]], tgt[b] + np.arange(count[b]))
+        assert (aux[b, 1] == (b > 0 and slots[b * block - 1] == mine[0])).all()
+        assert (aux[b, 2] == (mine[0] == slots[0])).all()
+        assert not aux[b, 3:].any()
+
+
+@pytest.mark.parametrize("head", ["zeros", "an_open_run", "nan_in_it"])
+def test_a_run_across_two_calls_reads_the_first_calls_row_as_its_old_row(
+        head):
+    """One call of ``sorted_run_sums`` whose first run began in a call
+    before: the state's row for it is its old row, added ONCE, where the run
+    ends (three blocks in), and no other row reads the state."""
+    rng = np.random.default_rng(2)
+    slots = np.sort(np.concatenate(
+        [np.full(600, 40), rng.integers(41, 300, 424)]))
+    slots = (40 + np.unique(slots, return_inverse=True)[1]).astype(np.int32)
+    rows = rng.normal(size=(1024, WIDTH)).astype(np.float32)
+    state = np.zeros((1100, WIDTH), np.float32)
+    state[:40] = rng.normal(size=(40, WIDTH))  # earlier calls' sums
+    if head != "zeros":
+        state[40] = rng.normal(size=WIDTH)
+    if head == "nan_in_it":
+        state[40, 3] = np.nan
+    got, _ = row_update.sorted_run_sums(
+        jnp.asarray(state), jnp.asarray(slots), jnp.asarray(rows),
+        interpret=True)
+    zeros = jnp.zeros((1024, WIDTH), jnp.float32)
+    want, _ = row_update.sorted_row_update_counted(
+        jnp.asarray(state), jnp.asarray(slots),
+        row_update._open_run_reread(
+            jnp.asarray(state), jnp.asarray(slots), zeros),
+        jnp.asarray(rows), plan="compact", interpret=True)
+    assert np.asarray(got).tobytes() == np.asarray(want).tobytes()
+    assert np.asarray(got)[:40].tobytes() == state[:40].tobytes()
+
+
+def test_sorted_run_sums_refuses_what_the_row_kernel_refuses():
+    with pytest.raises(ValueError, match="sorted_run_sums: rows of shape"):
+        row_update.sorted_run_sums(
+            jnp.zeros((64, 256), jnp.float32), jnp.zeros((8,), jnp.int32),
+            jnp.zeros((8, 256)), interpret=False)
+    with pytest.raises(ValueError, match="lanes in one call"):
+        jax.eval_shape(
+            lambda *a: row_update.sorted_run_sums(*a, interpret=False),
+            jax.ShapeDtypeStruct((64, 128), jnp.float32),
+            jax.ShapeDtypeStruct((row_update.MAX_LANES + 256,), jnp.int32),
+            jax.ShapeDtypeStruct((row_update.MAX_LANES + 256, 128), "f4"))
+    with pytest.raises(ValueError, match="no plan 'dense'"):
+        row_update.sorted_row_update(
+            jnp.zeros((64, 128), jnp.float32), jnp.zeros((8,), jnp.int32),
+            jnp.zeros((8, 128)), jnp.zeros((8, 128)), plan="dense")

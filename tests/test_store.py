@@ -537,9 +537,9 @@ def test_push_pull_case_table_wide_rule_rows(arm, shape, traffic, monkeypatch,
     if arm == "row_kernel":
         steer_arms(combine="row_kernel")
         monkeypatch.setattr(row_update, "MAX_LANES", 512)
-        real = row_update.sorted_row_update_counted
+        real = row_update.sorted_run_sums
         monkeypatch.setattr(
-            row_update, "sorted_row_update_counted",
+            row_update, "sorted_run_sums",
             lambda *a, **kw: calls.append(a[1].shape[0]) or real(*a, **kw))
     # not `_push`: a program traced for the other arm would be reused
     push = jax.jit(lambda st, i, d, m: st.push(i, d, m))
@@ -1635,7 +1635,7 @@ def test_the_row_kernels_inside_the_shard_map_sum_and_write_what_xla_does(
     calls = []
     steer_arms(write_back="row_set", combine="row_kernel")
     monkeypatch.setattr(row_update, "MAX_LANES", 512)
-    for name in ("sorted_row_update_counted", "sorted_row_set"):
+    for name in ("sorted_run_sums", "sorted_row_set"):
         real = getattr(row_update, name)
         monkeypatch.setattr(
             row_update, name,
@@ -1644,7 +1644,7 @@ def test_the_row_kernels_inside_the_shard_map_sum_and_write_what_xla_does(
     got, counted = jax.jit(
         lambda t, i, d, m: store_mod.push_counted(spec, t, i, d, m)
     )(sharded.table, *args)
-    assert {"sorted_row_update_counted", "sorted_row_set"} <= set(calls)
+    assert {"sorted_run_sums", "sorted_row_set"} <= set(calls)
     want = np.asarray(ShardedParamStore(spec, want).values())
     got = np.asarray(ShardedParamStore(spec, got).values())
     np.testing.assert_allclose(got, want, rtol=2e-6, atol=2e-6)
@@ -1657,18 +1657,18 @@ def test_the_row_kernels_inside_the_shard_map_sum_and_write_what_xla_does(
             assert int(counted[name]) == int(counted_xla[name]), name
     assert int(counted_xla["ps_combine_kernel_lanes"]) == 0
     assert int(counted["ps_combine_kernel_lanes"]) == live.sum()
-    # a DMA a distinct row of a shard, the spare lanes of a trip aside
+    # ONE copy a block of 256 sorted lanes that ends a run, on every shard
     assert int(counted_xla["ps_combine_kernel_writes"]) == 0
-    assert int(counted["ps_rule_rows"]) <= int(
-        counted["ps_combine_kernel_writes"]) < live.sum()
+    assert 4 <= int(counted["ps_combine_kernel_writes"]) <= 4 * -(-700 // 256)
 
 
 def _combine_descriptors(ids, lanes, size, block=256):
-    """numpy: the single-row DMAs ``ops/dedup._kernel_sums`` issues for a
-    batch of ``lanes`` lanes whose LIVE ids are ``ids``, a stretch of
-    ``size`` sorted lanes a call: the stretches that hold a live lane are
-    walked, and a block of a walked stretch sends ``ceil(count / 8)`` trips
-    of eight for its ``count`` lanes that end a run."""
+    """numpy: the DMAs ``ops/dedup._kernel_sums`` starts for a batch of
+    ``lanes`` lanes whose LIVE ids are ``ids``, a stretch of ``size``
+    sorted lanes a call: the stretches that hold a live lane are walked,
+    and a block of a walked stretch in which a run ends sends ONE copy, its
+    sums being neighbours (until PR 62 ``ceil(count / 8)`` trips of eight
+    single-row descriptors for its ``count`` lanes that end a run)."""
     dead = np.iinfo(np.int32).max
     calls = -(-lanes // size)
     slot = np.full(calls * size, dead, np.int64)
@@ -1678,7 +1678,7 @@ def _combine_descriptors(ids, lanes, size, block=256):
     for lo in range(0, -(-len(ids) // size) * size, size):
         call = slot[lo:lo + size]
         last = np.concatenate([call[1:] != call[:-1], [True]]) & (call < dead)
-        sent += int((-(-last.reshape(-1, block).sum(axis=1) // 8) * 8).sum())
+        sent += int((last.reshape(-1, block).sum(axis=1) > 0).sum())
     return sent
 
 
@@ -1691,12 +1691,13 @@ def test_the_walk_pays_by_what_it_writes_and_the_push_is_the_parents(
         owned, placed, ps_mesh, monkeypatch, steer_arms):
     """A packed rule store's push with both kernels steered on and
     interpreted, in one place and on the shards of a ``ps`` = 4 mesh: the
-    table is, bit for bit, what the parent's walk gives (the row kernel
-    under the plan that sends a DMA a LANE of a block that writes; the
+    table is, bit for bit, what the walk of PR 54's parent gives (the row
+    kernel under the plan that sends a DMA a LANE of a block that writes,
+    its old rows zeros but a run's that a call before began; the
     write-back's rows set by XLA, which ``sorted_row_set`` is bit for bit:
     tests/test_row_update.py), and ``ps_combine_kernel_writes`` says what
-    the walk paid: nothing for a shard that owns no key, one trip of eight
-    for one key, every stretch for every key (shard 2's, under the mesh)."""
+    the walk paid: nothing for a shard that owns no key, one copy for one
+    key, every stretch for every key (shard 2's, under the mesh)."""
     from flink_parameter_server_tpu.core import store as store_mod
     from flink_parameter_server_tpu.ops import row_update
 
@@ -1735,10 +1736,14 @@ def test_the_walk_pays_by_what_it_writes_and_the_push_is_the_parents(
         return np.asarray(table), {k: int(v) for k, v in counted.items()}
 
     got, counted = push()
-    real = row_update.sorted_row_update_counted
-    monkeypatch.setattr(
-        row_update, "sorted_row_update_counted",
-        lambda *a, compact, **kw: real(*a, compact=False, **kw))
+
+    def a_dma_a_lane(block, slots, rows, **kw):
+        old = row_update._open_run_reread(
+            block, slots, jnp.zeros(rows.shape, jnp.float32))
+        return row_update.sorted_row_update_counted(
+            block, slots, old, rows, plan="lane", **kw)
+
+    monkeypatch.setattr(row_update, "sorted_run_sums", a_dma_a_lane)
     monkeypatch.setattr(
         row_update, "sorted_row_set",
         lambda state, at, new, **kw: state.at[at].set(new, mode="drop"))
@@ -1753,7 +1758,10 @@ def test_the_walk_pays_by_what_it_writes_and_the_push_is_the_parents(
     assert writes == sum(_combine_descriptors(k, n, size) for k in shards)
     assert counted["ps_combine_kernel_lanes"] == len(live)
     distinct = sum(len(np.unique(k)) for k in shards)
-    assert counted["ps_rule_rows"] == distinct <= writes <= lanes_sent
+    # (a copy a block where the compact plan sent a descriptor a row and
+    # the parent's a lane)
+    assert counted["ps_rule_rows"] == distinct <= lanes_sent
+    assert writes <= min(distinct, lanes_sent // 256)
     # shard 2's keys (the whole batch's in one place): the stretches walked
     mine = shards[-1] if placed == "one_place" else shards[2]
     walked = -(-len(mine) // size)
@@ -1764,7 +1772,7 @@ def test_the_walk_pays_by_what_it_writes_and_the_push_is_the_parents(
     if owned == "no_key":
         assert walked == 0 == touched == _combine_descriptors(mine, n, size)
     elif owned == "one_key":
-        assert walked == 1 == touched and _combine_descriptors(mine, n, size) == 8
+        assert walked == 1 == touched == _combine_descriptors(mine, n, size)
     else:
         assert touched == len(np.unique(mine // spec.pack))
         if owned == "every_key":

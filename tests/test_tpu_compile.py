@@ -121,10 +121,10 @@ def _shape(sharding, shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-@pytest.mark.parametrize("compact", [False, True])
+@pytest.mark.parametrize("plan", ["lane", "compact"])
 @pytest.mark.parametrize("lanes", [BATCH, row_update.MAX_LANES])
 def test_row_update_kernel_compiles_at_the_mf_cells_shapes(
-        one_chip, no_compile_cache, lanes, compact):
+        one_chip, no_compile_cache, lanes, plan):
     """Single-row dynamic-offset DMAs into 5,008,260 rows (no multiple of
     8) of 128 f32 lanes, 65,536 sorted lanes: Mosaic takes it, and as many
     lanes as ``refusal`` lets through (their row ids fit SMEM).  Under the
@@ -133,7 +133,7 @@ def test_row_update_kernel_compiles_at_the_mf_cells_shapes(
     and one a block)."""
     compiled = jax.jit(
         lambda st, ids, old, dl: row_update.sorted_row_update(
-            st, ids, old, dl, compact=compact, interpret=False),
+            st, ids, old, dl, plan=plan, interpret=False),
         donate_argnums=(0,),
     ).lower(
         _shape(one_chip, (USERS, DIM), jnp.float32),
@@ -147,6 +147,35 @@ def test_row_update_kernel_compiles_at_the_mf_cells_shapes(
     # in place: the 2.56 GB state is aliased, not copied
     assert mem.alias_size_in_bytes >= USERS * DIM * 4
     assert mem.temp_size_in_bytes < 256 * 2 ** 20
+
+
+@pytest.mark.parametrize("rows, lanes", [
+    (1_277_952, row_update.MAX_LANES),  # cell 9 (and 12): 13 stretches
+    (160_256, 80_128),                  # cell 14: two
+    (1_024, 1_000),                     # no whole blocks
+])
+def test_the_dense_plans_kernel_compiles_at_the_combines_shapes(
+        one_chip, no_compile_cache, rows, lanes):
+    """``sorted_run_sums`` (PR 62) for a described v5e: a copy of a WHOLE
+    staging slot, 256 rows, to a dynamic row of the zeroed block that is no
+    multiple of 8, one a block of sorted lanes, where the compact plan sent a
+    row a descriptor; in place, nothing block-sized beside it, two int32 a
+    BLOCK in SMEM."""
+    compiled = jax.jit(
+        lambda st, slots, new: row_update.sorted_run_sums(
+            st, slots, new, interpret=False),
+        donate_argnums=(0,),
+    ).lower(
+        _shape(one_chip, (rows, DIM), jnp.float32),
+        _shape(one_chip, (lanes,), jnp.int32),
+        _shape(one_chip, (lanes, DIM), jnp.float32),
+    ).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r" custom-call\([^\n]*sorted_run_sums", text)) == 1
+    assert " sort(" not in text
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= rows * DIM * 4
+    assert mem.temp_size_in_bytes < 16 * 2 ** 20
 
 
 @pytest.mark.parametrize("lanes", [32_768, row_update.MAX_LANES])
@@ -1427,8 +1456,12 @@ def test_difacto_step_holds_nothing_table_sized_beside_its_table(
     rows (``StoreSpec.worker_width``, PR 59: the 36-lane rows of ``S`` never
     leave the rule's loop; the logic's gradient rows are ``f32[1277952,20]``
     and no array of the step outside that loop is 36 lanes wide); under ``ps.push/
-    ps.combine`` PR 46's ``sorted_row_update`` inside the loop over thirteen
-    stretches; in the rule's loop ONE gather ``f32[32768,128]`` of the
+    ps.combine`` ``sorted_run_sums`` inside the loop over thirteen stretches
+    (PR 62: the row kernel under the DENSE plan, 98,304 lanes a call into the
+    zeroed ``f32[1277952,128]`` block, a row a lane, which the rule's loop
+    reads in place: no slice of it; PR 46's ``sorted_row_update`` until
+    then, with a second pipelined input of zeros and, since PR 54, a ``sort
+    s32[384,256]`` a call that are gone); in the rule's loop ONE gather ``f32[32768,128]`` of the
     chunk's physical rows under ``ps.rule`` and ONE ``sorted_row_set`` call,
     the write-back of whole physical rows.  What the step holds beside the
     table goes with the batch: 1.33 GB (the padded rows and the zeroed block
@@ -1492,7 +1525,7 @@ def test_difacto_step_holds_nothing_table_sized_beside_its_table(
     assert 1.2 * GB < mem.temp_size_in_bytes < 1.5 * GB  # 1.327 here
     assert not scatters
     names = sorted(k.strip().split(" ", 1)[0].rstrip(".0123456789") for k in kernels)
-    assert names == ["%packed_lane_slice", "%sorted_row_set", "%sorted_row_update"]
+    assert names == ["%packed_lane_slice", "%sorted_row_set", "%sorted_run_sums"]
     by_name = {k.strip().split(".", 1)[0]: k for k in kernels}
     slice_call = by_name["%packed_lane_slice"]
     assert f" = f32[20,{n}]{{1,0:" in slice_call
@@ -1506,9 +1539,13 @@ def test_difacto_step_holds_nothing_table_sized_beside_its_table(
     assert " f32[16375440,128]{1,0" in set_call
     assert "ps.push/while/body" in set_call and "ps.rule" not in set_call
     assert "ps.combine" not in set_call
-    update_call = by_name["%sorted_row_update"]
-    assert " f32[1277952,128]{1,0" in update_call
-    assert "ps.push/ps.combine/while/body" in update_call
+    sums_call = by_name["%sorted_run_sums"]
+    assert " f32[1277952,128]{1,0" in sums_call
+    assert "ps.push/ps.combine/while/body" in sums_call
+    # the rule's loop cuts its chunks out of the block where it lies
+    assert not re.search(rf"f32\[{n},20\]\S* slice\(", text)
+    # the dense plan sorts nothing: the batch's two sorts are `_wide_runs`'
+    assert not re.search(r"s32\[384,256\]\S* sort\(", text)
     # the rule's loop and the stretches' (the flattens' two are cell 2's)
     assert len(re.findall(r" while\(", text)) == 4
     # both end with what is live: the rule's with the last distinct row,
@@ -1522,7 +1559,7 @@ def test_difacto_step_holds_nothing_table_sized_beside_its_table(
       jax.ShapeDtypeStruct((FM_BATCH * FM_FIELDS, 36), jnp.float32))
     loops = [e.primitive.name for e in jaxpr.eqns
              if e.primitive.name in ("scan", "while")
-             and "sorted_row_update" in str(e.params)]
+             and "sorted_run_sums" in str(e.params)]
     assert loops == ["while"]
 
 
@@ -1533,7 +1570,7 @@ def test_difacto_step_on_four_chips_runs_its_rule_on_the_shard_that_owns_the_row
     at cell 4's record, 187,767,412 x 36 f32 packed three to a physical row,
     ``f32[15647288,128]`` (8.011 GB) a chip.  The push is ONE ``shard_map``
     (``core/store._push_rule_on_shards``): every chip's block rewritten in
-    place by the calls a one-place packed store gets (``sorted_row_update``
+    place by the calls a one-place packed store gets (``sorted_run_sums``
     under ``ps.combine``, ``sorted_row_set`` in the rule's loop; off the TPU
     XLA's scatter-add and row ``set`` ON THE BLOCK, nothing partitioned by
     GSPMD), 1.33 GB of temporaries a chip, and the step's only collectives
@@ -1591,12 +1628,12 @@ def test_difacto_step_on_four_chips_runs_its_rule_on_the_shard_that_owns_the_row
         return
     assert not scatters
     names = sorted(k.strip().split(" ", 1)[0].rstrip(".0123456789") for k in kernels)
-    assert names == ["%packed_lane_slice", "%sorted_row_set", "%sorted_row_update"]
+    assert names == ["%packed_lane_slice", "%sorted_row_set", "%sorted_run_sums"]
     by_name = {k.strip().split(".", 1)[0]: k for k in kernels}
     assert " f32[15647288,128]{1,0" in by_name["%sorted_row_set"]
     assert "ps.push/shard_map/while/body" in by_name["%sorted_row_set"]
-    assert " f32[1277952,128]{1,0" in by_name["%sorted_row_update"]
-    assert "ps.push/shard_map/ps.combine/while/body" in by_name["%sorted_row_update"]
+    assert " f32[1277952,128]{1,0" in by_name["%sorted_run_sums"]
+    assert "ps.push/shard_map/ps.combine/while/body" in by_name["%sorted_run_sums"]
 
 
 @pytest.mark.parametrize("rows, lanes, sorted_form", [
@@ -2002,7 +2039,9 @@ def test_kge_step_holds_its_one_register_table_once(
     place and never copied or transposed.  Under ``ps.pull`` ONE gather of
     whole physical rows ``f32[160000,128]``, cut to the worker's 100 lanes;
     under ``ps.push/ps.combine`` the row kernel's sums of the batch's
-    gradient rows, 100 lanes wide as they come; in the rule's loop ONE gather
+    gradient rows, 100 lanes wide as they come (``sorted_run_sums``, the
+    dense plan of PR 62: 80,128 lanes a call into a zeroed
+    ``f32[160256,128]`` block); in the rule's loop ONE gather
     ``f32[32768,128]`` under ``ps.rule`` and ONE ``sorted_row_set`` call on
     the table, the write-back; no XLA scatter touches the table.  The
     chunked scores are batched ``50 x 100 x 100`` products under the logic's
@@ -2033,11 +2072,12 @@ def test_kge_step_holds_its_one_register_table_once(
     assert len(reads) == 1 and "ps.push/while/body/ps.rule" in reads[0], reads
     kernels = [line for line in lines if "tpu_custom_call" in line]
     names = sorted(k.strip().split(" ", 1)[0].rstrip(".0123456789") for k in kernels)
-    assert names == ["%sorted_row_set", "%sorted_row_update"], names
+    assert names == ["%sorted_row_set", "%sorted_run_sums"], names
     by_name = {k.strip().split(".", 1)[0]: k for k in kernels}
     assert " f32[15152096,128]{1,0" in by_name["%sorted_row_set"]
     assert "ps.push/while/body" in by_name["%sorted_row_set"]
-    assert "ps.push/ps.combine" in by_name["%sorted_row_update"]
+    assert " f32[160256,128]{1,0" in by_name["%sorted_run_sums"]
+    assert "ps.push/ps.combine/while/body" in by_name["%sorted_run_sums"]
     # the operators' segment sum is the one scatter left, in the worker
     scatters = [line for line in lines if re.search(r" scatter\(", line)]
     assert len(scatters) == 1 and " f32[25291,2,100]" in scatters[0], scatters
