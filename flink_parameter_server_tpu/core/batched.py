@@ -51,13 +51,35 @@ class BatchedWorkerLogic(abc.ABC, Generic[State, Batch, Out]):
     @abc.abstractmethod
     def keys(self, batch: Batch) -> Array:
         """Param ids this microbatch needs pulled (static shape; pad +
-        mask for variable counts)."""
+        mask for variable counts).  The key block in C order is the PULL's
+        lanes: lane ``n`` pulls row ``keys.reshape(-1)[n]``, in that order,
+        and ``step``'s ``pulled`` is ``keys.shape + row``, whoever calls
+        ``step``.  The push's lanes are its request's: ``PushRequest.ids``
+        in C order, deltas and mask lane for lane, and a row's deltas are
+        summed in lane order."""
 
     @abc.abstractmethod
     def step(
         self, state: State, batch: Batch, pulled: Array
     ) -> Tuple[State, PushRequest, Out]:
         """One compiled training step over the microbatch."""
+
+    def for_workers(self, workers: int) -> "BatchedWorkerLogic":
+        """The logic ``make_train_step`` traces, asked once with the worker
+        count of the store's mesh (a batch's leaves are split over the
+        workers on their leading axis).  Default: itself.  The one caller
+        that honours what the answer may declare is ``make_train_step``,
+        which reads both with ``getattr`` (a logic need not subclass this):
+        an answer whose ``pulls_turned`` is true is handed ``pulled`` with
+        the key block's two axes SWAPPED, for keys ``(B, K)`` ``(K, B) +
+        row``, ``pulled[f, b]`` the row of ``keys[b, f]``, and pushes a
+        block ``(K, B)`` (``core/store.pull`` and ``push_counted``'s
+        ``turned``).  Every other caller of ``step`` holds the logic it was
+        given, whose ``pulled`` is ``keys.shape + row``
+        (``cluster/driver.ClusterDriver``):
+        ``models/factorization_machine.FieldLanes`` answers a COPY that
+        computes field-major and stays example-major itself."""
+        return self
 
     def key_router(self, *, registry=None, tracer=None):  # noqa: B027
         """The keyed shuffle this logic's step needs in front of it (an

@@ -343,7 +343,8 @@ def _create_packed(spec: StoreSpec, init_fn: InitFn) -> Callable[[], Array]:
 
 
 def pull(
-    spec: StoreSpec, table: Array, ids: Array, *, worker_part: bool = False
+    spec: StoreSpec, table: Array, ids: Array, *, worker_part: bool = False,
+    turned: bool = False,
 ) -> Array:
     """Batched pull: ``values[i] = table[ids[i]]`` (sharded gather).
 
@@ -362,9 +363,27 @@ def pull(
     lanes of each row alone, ``ids.shape + (worker_width,)``, cut where the
     arm cuts anyway and by no pass of its own (the gather's window; the
     lane slice of a packed row, under a mesh in front of the all-reduce);
-    of every other store, and by default, whole rows."""
+    of every other store, and by default, whole rows.
+
+    ``turned`` (``make_train_step`` for a logic that ``pulls_turned``), of
+    a key block of two axes ``(B, K)``: the rows with the block's axes
+    swapped, ``values[f, b] = table[ids[b, f]]``, ``(K, B) + row``.  The
+    rows are GATHERED in the block's own order whatever the answer's (the
+    TPU's gather pays for neighbours that name one row:
+    ``ops/packed.turned_slice_kernel``).  The arm ``packed_kernel_by_field``
+    writes them turned (:func:`arms`' ``fields``); every other arm's answer
+    is turned as it stands (what a CPU runs; no cell)."""
+    if turned and ids.ndim != 2:
+        raise ValueError(
+            f"a turned pull takes a key block of two axes, (B, K): got "
+            f"{tuple(ids.shape)}")
     ids = jnp.clip(ids.astype(jnp.int32), 0, spec.padded_capacity - 1)
-    arm = arms(spec, pull_lanes=ids.size).pull
+    arm = arms(spec, pull_lanes=ids.size,
+               fields=ids.shape[1] if turned else None).pull
+    by_field = turned and arm == "packed_kernel_by_field"
+    if turned and not by_field:
+        return jnp.swapaxes(
+            pull(spec, table, ids, worker_part=worker_part), 0, 1)
     part = spec.worker_width if worker_part else None
     if arm == "take":
         rows = jnp.take(table, ids, axis=0)
@@ -372,13 +391,16 @@ def pull(
     if arm == "narrow":
         return _narrow_pull(table, ids, part or spec.row_width)
     from ..ops.packed import packed_pull
-    flat, kernel = ids.reshape(-1), arm == "packed_kernel"
+    block, kernel = ids if by_field else ids.reshape(-1), arm != "packed_selects"
     if spec.num_shards > 1:
-        vals = _packed_pull_on_shards(spec, table, flat, kernel, part)
+        vals = _packed_pull_on_shards(
+            spec, table, block, kernel, part, by_field)
     else:
-        vals = packed_pull(table, flat, spec.row_width, kernel, part)
+        vals = packed_pull(
+            table, block, spec.row_width, kernel, part, by_field)
     return vals.reshape(
-        ids.shape + (spec.value_shape if part is None else (part,)))
+        (ids.T if by_field else ids).shape
+        + (spec.value_shape if part is None else (part,)))
 
 
 @functools.partial(jax.jit, static_argnums=(2,))
@@ -393,7 +415,7 @@ def _narrow_pull(table: Array, ids: Array, width: int) -> Array:
 
 def _phys_scatter_args(
     spec: StoreSpec, table: Array, flat_ids: Array, flat_deltas: Array,
-    flat_mask: Optional[Array], arm: Arms,
+    flat_mask: Optional[Array], arm: Arms, lead: Tuple[int, ...] = (),
 ):
     """(ids, deltas) at PHYSICAL granularity for the scatter-add, the
     masked lanes' deltas zeros.
@@ -410,8 +432,11 @@ def _phys_scatter_args(
     640) has nothing to shift, only its pad to whole registers: XLA's
     scatter-add needs it, the tile kernel (``arm.push``) adds a row of
     ``w`` <= ``W`` lanes into lanes ``[0, w)`` and is handed the deltas as
-    they are."""
-    kernel, tiles = arm.shift == "kernel", arm.push == "tile_add"
+    they are.  ``lead`` is the batch's shape in front of a row: the shift
+    ``kernel_by_field`` (:func:`arms`' ``fields``) is handed the deltas of
+    a block ``(K, B)`` as ``(d, K, B)``, the same lanes in the same order,
+    read where XLA holds them on the TPU (``lane_shift_kernel``)."""
+    kernel, tiles = arm.shift.startswith("kernel"), arm.push == "tile_add"
     if not kernel:
         flat_deltas = _zero_masked(flat_deltas, flat_mask)
     if spec.layout != "packed":
@@ -425,12 +450,20 @@ def _phys_scatter_args(
         shifted = deltas  # the tile kernel takes a row at its own width
     elif not kernel:
         shifted = lane_shift_deltas(deltas, flat_ids, d)
-    elif spec.mesh is not None:
-        shifted = _packed_shift_on_mesh(spec, deltas.T, flat_ids, flat_mask)
     else:
         # feature-major, which is how XLA holds a step's narrow rows on
         # the TPU: the transpose is a bitcast there
-        shifted = lane_shift_kernel(deltas.T, flat_ids, d, flat_mask)
+        ids, mask, by_lane = flat_ids, flat_mask, deltas.T
+        if arm.shift == "kernel_by_field":
+            ids = ids.reshape(lead)
+            mask = None if mask is None else mask.reshape(lead)
+            # (the row axis moved in front of the block as it was handed
+            # in: XLA folds two reshapes, not a reshape round a transpose)
+            by_lane = jnp.moveaxis(deltas.reshape(lead + (d,)), -1, 0)
+        if spec.mesh is not None:
+            shifted = _packed_shift_on_mesh(spec, by_lane, ids, mask)
+        else:
+            shifted = lane_shift_kernel(by_lane, ids, d, mask)
     return packed_phys_ids(flat_ids, d), shifted
 
 
@@ -489,6 +522,7 @@ def push_counted(
     mask: Optional[Array] = None,
     *,
     lanes_over_workers: bool = False,
+    turned: bool = False,
 ) -> Tuple[Array, Optional[dict]]:
     """:func:`push`, and beside the table what the push counted on the
     device (``None`` for an ``update="add"`` batch that XLA's scatter-add
@@ -538,7 +572,14 @@ def push_counted(
     reads).  The combine then runs at that width (:func:`arms`'
     ``push_width``) and the rule is handed ``combined`` that wide; whole-row
     deltas stay legal, any other width raises.  ``ps_push_row_lanes``, for
-    such a store alone, is the width the push was handed."""
+    such a store alone, is the width the push was handed.
+
+    ``turned`` (``make_train_step`` for a logic that ``pulls_turned``, the
+    declaration :func:`pull` is handed): the request is a block of two axes
+    ``(K, B)``, the ``K`` keys of an example down its leading axis.  The
+    same lanes in the same order as its flattening, and the same table;
+    :func:`arms` is told the ``fields`` and may shift them a field at a
+    time."""
     vr = len(spec.value_shape)
     lead = tuple(deltas.shape[: deltas.ndim - vr])
     row = tuple(deltas.shape[deltas.ndim - vr:])
@@ -553,6 +594,10 @@ def push_counted(
                 "" if spec.worker_width is None else
                 f" (or its worker's part, ({spec.worker_width},))")
         )
+    if turned and len(lead) != 2:
+        raise ValueError(
+            f"a turned push takes a block of two axes, (K, B): got ids of "
+            f"shape {lead}")
     if mask is not None and tuple(mask.shape) != tuple(ids.shape):
         # a length-1 mask would silently broadcast across every lane
         raise ValueError(
@@ -569,7 +614,8 @@ def push_counted(
 
     width = row[0] if part else spec.row_width  # lanes of a row handed in
     arm = arms(spec, push_lanes=flat_ids.shape[0],
-               lanes_over_workers=lanes_over_workers, push_width=width)
+               lanes_over_workers=lanes_over_workers, push_width=width,
+               fields=lead[0] if turned else None)
     if arm.push == "rule":
         # (a masked lane's delta goes as it is: `_push_rule` sends the lane
         # to the sentinel, and no combine arm lets a dropped lane's value
@@ -581,7 +627,7 @@ def push_counted(
             counted["ps_push_row_lanes"] = jnp.asarray(width, jnp.int32)
         return table, counted
     s_ids, s_deltas = _phys_scatter_args(
-        spec, table, flat_ids, flat_deltas, flat_mask, arm
+        spec, table, flat_ids, flat_deltas, flat_mask, arm, lead
     )
     if arm.push == "tile_add":
         from ..ops.row_update import scatter_add_counted
@@ -906,9 +952,11 @@ _REFUSALS_NOTED: set = set()
 class Arms:
     """The forms a store's pull and push take, as :func:`arms` read them."""
 
-    pull: str  # "take" | "narrow" | "packed_selects" | "packed_kernel"
+    # "take" | "narrow" | "packed_selects" | "packed_kernel[_by_field]"
+    pull: str
     push: str  # "xla_add" | "tile_add" | "worker_reduce" | "rule"
-    shift: str  # a packed add push's lane shift: "" | "selects" | "kernel"
+    # a packed add push's lane shift: "" | "selects" | "kernel[_by_field]"
+    shift: str
     # a rule push: "" | "sort" | "scatter_add" | "row_kernel" | "tile_kernel"
     combine: str
     # a rule push: "" | "xla_set" | "tile_set" | "row_set" | "tile_assign"
@@ -919,7 +967,7 @@ class Arms:
 def arms(
     spec: StoreSpec, *, pull_lanes: Optional[int] = None,
     push_lanes: Optional[int] = None, lanes_over_workers: bool = False,
-    push_width: Optional[int] = None,
+    push_width: Optional[int] = None, fields: Optional[int] = None,
 ) -> Arms:
     """THE one reader of which form a pull of ``pull_lanes`` ids and a push
     of ``push_lanes`` lanes take, from what the spec and the batch hold: the
@@ -932,7 +980,15 @@ def arms(
     ``push_width``: the lanes of a row the push is handed, None for what a
     STEP pushes (the worker's part where the spec names one,
     ``StoreSpec.worker_width``, else the whole row); a rule's sums are made
-    at that width, so it chooses the ``combine``.  A
+    at that width, so it chooses the ``combine``.  ``fields``: the batch is
+    a block of two axes, ``fields`` keys an example, whose logic takes its
+    rows TURNED and pushes them so (:func:`pull` and :func:`push_counted`'s
+    ``turned``, ONE declaration, ``make_train_step``'s of a logic that
+    ``pulls_turned``); where the lane kernels take the batch and
+    ``ops/packed.by_field`` says its shape is one they were compiled for,
+    they move it a field at a time, ``packed_kernel_by_field`` and
+    ``kernel_by_field``: the same bits, XLA's own layout of the logic's
+    buffers at the other end.  A
     store a kernel REFUSES (bfloat16, a pinned layout Mosaic cannot tile, a
     batch under one block) keeps XLA's arm and is counted and warned of once
     a physical row shape, dtype and arm (``ops/row_update.refusal_count``).
@@ -949,43 +1005,48 @@ def arms(
 
     An ``add`` store (``combine`` and ``write_back`` ``""``):
 
-    ================================  ==============  =============  =======  ======  ========
-    spec and batch                    pull            push           shift    cell    PR
-    ================================  ==============  =============  =======  ======  ========
-    dense 1 reg, lanes x 8 > rows     take            xla_add        -        1 3 11  27 30
-    dense 1 reg, 1,024+ <= rows / 8   take            tile_add       -        none    49
-    dense 1 reg, dp 4, shard <= lanes  take           worker_reduce  -        8       40
-    packed k 7, lanes x 8 > rows      packed_kernel   xla_add        kernel   2       29 42 51
-    the same over ps 4, dp 1          packed_kernel   xla_add        kernel   4       31 42 51
-    packed k 2, 1,024+ <= rows / 8    packed_kernel   tile_add       kernel   10      49 51
-    packed k 7, under a block of ids  packed_selects  xla_add        selects  none    42
-    packed k 1, 5 regs (3: cell 7)    packed_selects  tile_add       selects  5 7     32 33 57
-    5 regs under a mesh               take            xla_add        -        none    33
-    ================================  ==============  =============  =======  ======  ========
+    =================================  ======================  =============  ===============  ======  ========
+    spec and batch                     pull                    push           shift            cell    PR
+    =================================  ======================  =============  ===============  ======  ========
+    dense 1 reg, lanes x 8 > rows      take                    xla_add        -                1 3 11  27 30
+    dense 1 reg, 1,024+ <= rows / 8    take                    tile_add       -                none    49
+    dense 1 reg, dp 4, shard <= lanes  take                    worker_reduce  -                8       40
+    packed k 7, lanes x 8 > rows       packed_kernel           xla_add        kernel           none    29 42 51
+    the same over ps 4, dp 1           packed_kernel           xla_add        kernel           none    31 42 51
+    packed k 7, fields 39              packed_kernel_by_field  xla_add        kernel_by_field  2       63
+    fields 39 over ps 4, dp 1          packed_kernel_by_field  xla_add        kernel_by_field  4       63
+    fields 39, the batch no blocks     packed_kernel           xla_add        kernel           none    63
+    packed k 2, 1,024+ <= rows / 8     packed_kernel           tile_add       kernel           10      49 51
+    packed k 7, under a block of ids   packed_selects          xla_add        selects          none    42
+    packed k 1, 5 regs (3: cell 7)     packed_selects          tile_add       selects          5 7     32 33 57
+    5 regs under a mesh                take                    xla_add        -                none    33
+    =================================  ======================  =============  ===============  ======  ========
 
     A store whose ``update`` is a rule (``push`` ``"rule"``, ``shift``
     ``""``):
 
-    ================================  ==============  ===========  ===========  =========  ====  ========
-    spec                              pull            combine      write_back   on_shards  cell  PR
-    ================================  ==============  ===========  ===========  =========  ====  ========
-    3 lanes, held at its tile of 4    narrow          sort         tile_set     no         6     34 35
-    6 lanes, held at its tile of 8    narrow          row_kernel   tile_set     no         none  35 46
-    (2, 2) lanes: rank 2, no tile     take            sort         xla_set      no         none  35
-    packed k 3 (36 lanes)             packed_kernel   row_kernel   row_set      no         none  46 47 54
-    the same over ps 4, dp 1          packed_kernel   row_kernel   row_set      yes        none  52
-    the same over ps 2, dp 2          packed_kernel   scatter_add  xla_set      no         none  52
-    packed k 1, 1 reg (100 lanes)     packed_selects  row_kernel   row_set      no         none  47 61
-    packed k 1, 5 regs (602 lanes)    packed_selects  tile_kernel  tile_assign  no         none  55 57
-    dense 1 reg (pinned, 100)         take            row_kernel   xla_set      no         none  46 61
-    packed k 3, the worker's 20 / 36  packed_kernel   row_kernel   row_set      no         9     59 62
-    the worker's 20 / 36 over ps 4    packed_kernel   row_kernel   row_set      yes        12    59 62
-    5 regs, the worker's 301 / 602    packed_selects  tile_kernel  tile_assign  no         13    59
-    5 regs, the worker's 100 / 602    packed_selects  row_kernel   tile_assign  no         none  59
-    5 regs, the worker's 3 / 602      packed_selects  sort         tile_assign  no         none  59
-    1 reg, the worker's 100 / 101     packed_selects  row_kernel   row_set      no         14    61 62
-    1 reg, 100 / 101 over ps 4        packed_selects  row_kernel   row_set      yes        none  61
-    ================================  ==============  ===========  ===========  =========  ====  ========
+    ================================  ======================  ===========  ===========  =========  ====  ========
+    spec                              pull                    combine      write_back   on_shards  cell  PR
+    ================================  ======================  ===========  ===========  =========  ====  ========
+    3 lanes, held at its tile of 4    narrow                  sort         tile_set     no         6     34 35
+    6 lanes, held at its tile of 8    narrow                  row_kernel   tile_set     no         none  35 46
+    (2, 2) lanes: rank 2, no tile     take                    sort         xla_set      no         none  35
+    packed k 3 (36 lanes)             packed_kernel           row_kernel   row_set      no         none  46 47 54
+    the same over ps 4, dp 1          packed_kernel           row_kernel   row_set      yes        none  52
+    the same over ps 2, dp 2          packed_kernel           scatter_add  xla_set      no         none  52
+    packed k 1, 1 reg (100 lanes)     packed_selects          row_kernel   row_set      no         none  47 61
+    packed k 1, 5 regs (602 lanes)    packed_selects          tile_kernel  tile_assign  no         none  55 57
+    dense 1 reg (pinned, 100)         take                    row_kernel   xla_set      no         none  46 61
+    packed k 3, the worker's 20 / 36  packed_kernel           row_kernel   row_set      no         none  59 62
+    the worker's 20 / 36 over ps 4    packed_kernel           row_kernel   row_set      yes        none  59 62
+    the worker's 20 / 36, fields 39   packed_kernel_by_field  row_kernel   row_set      no         9     63
+    20 / 36, fields 39, over ps 4     packed_kernel_by_field  row_kernel   row_set      yes        12    63
+    5 regs, the worker's 301 / 602    packed_selects          tile_kernel  tile_assign  no         13    59
+    5 regs, the worker's 100 / 602    packed_selects          row_kernel   tile_assign  no         none  59
+    5 regs, the worker's 3 / 602      packed_selects          sort         tile_assign  no         none  59
+    1 reg, the worker's 100 / 101     packed_selects          row_kernel   row_set      no         14    61 62
+    1 reg, 100 / 101 over ps 4        packed_selects          row_kernel   row_set      yes        none  61
+    ================================  ======================  ===========  ===========  =========  ====  ========
 
     Reasons the code does not show.  A mesh keeps an add push XLA's because
     GSPMD partitions the scatter and cannot partition Mosaic's call.  The
@@ -1035,6 +1096,11 @@ def arms(
             packed.SLICE_BLOCK if n is None else n, spec.dtype, spec.row_width)
         return why is None if n is None else taken(what, why)
 
+    def by_field(n: Optional[int]) -> str:
+        whole = fields and n is not None and n % fields == 0
+        return "_by_field" if whole and packed.by_field(
+            fields, n // fields) else ""
+
     if spec.layout != "packed":
         pull = "narrow" if spec.tile_lanes > spec.row_width else "take"
     else:
@@ -1044,7 +1110,7 @@ def arms(
             over = spec.mesh.size // spec.num_shards
             n = n // over if n % over == 0 else n
         kernel = lane_kernel(n, "the lane slice of a packed pull")
-        pull = "packed_kernel" if kernel else "packed_selects"
+        pull = "packed_kernel" + by_field(n) if kernel else "packed_selects"
 
     if not rule:
         tiles, what = spec.mesh is None and tpu, "wide rows"
@@ -1065,7 +1131,7 @@ def arms(
         shift = ""
         if spec.layout == "packed":
             kernel = lane_kernel(push_lanes, "the lane shift of a packed push")
-            shift = "kernel" if kernel else "selects"
+            shift = "kernel" + by_field(push_lanes) if kernel else "selects"
         return Arms(pull, push, shift, "", "", False)
 
     on_shards = spec.mesh is not None and taken(
@@ -1106,7 +1172,7 @@ def _preload_tile_kernel(spec: StoreSpec) -> None:
     Pallas imported by then, beside the table's staging (the import is ~1 s
     that the first trace of the step else pays)."""
     a = arms(spec)
-    if (a.pull == "packed_kernel" or a.push == "tile_add"
+    if (a.pull.startswith("packed_kernel") or a.push == "tile_add"
             or a.combine.endswith("_kernel")
             or a.write_back not in ("", "xla_set")):
         from ..ops.row_update import preload
@@ -1116,14 +1182,15 @@ def _preload_tile_kernel(spec: StoreSpec) -> None:
 
 def step_counts(
     spec: StoreSpec, counted: Optional[dict], *, pull_lanes: int,
-    push_lanes: int,
+    push_lanes: int, fields: Optional[int] = None,
 ) -> dict:
     """What a step hands out of its pull and its push beside the logic's
     outputs: what :func:`push_counted` counted and, for a store packed
     several rows to a physical row, which arm sliced the pulled rows
     (``ps_slice_kernel``) and, where ``update`` is ``"add"``, which shifted
-    the pushed deltas (``ps_shift_kernel``), 1 for the kernel, as this
-    trace read them (:func:`arms`).  A store whose spec names a worker's
+    the pushed deltas (``ps_shift_kernel``), 1 for the kernel in either of
+    its forms, as this trace read them (:func:`arms`, told the step's
+    ``fields``).  A store whose spec names a worker's
     part (``StoreSpec.worker_width``) says how many lanes of a row crossed,
     a key: ``ps_pull_row_lanes`` here (a step pulls the worker's part) beside
     :func:`push_counted`'s ``ps_push_row_lanes``; every other store's whole
@@ -1132,12 +1199,13 @@ def step_counts(
     if spec.worker_width is not None:
         out["ps_pull_row_lanes"] = jnp.asarray(spec.worker_width, jnp.int32)
     if spec.pack > 1:
-        arm = arms(spec, pull_lanes=pull_lanes, push_lanes=push_lanes)
+        arm = arms(spec, pull_lanes=pull_lanes, push_lanes=push_lanes,
+                   fields=fields)
         out["ps_slice_kernel"] = jnp.asarray(
-            arm.pull == "packed_kernel", jnp.int32)
+            arm.pull.startswith("packed_kernel"), jnp.int32)
         if arm.shift:
             out["ps_shift_kernel"] = jnp.asarray(
-                arm.shift == "kernel", jnp.int32)
+                arm.shift.startswith("kernel"), jnp.int32)
     return out
 
 
@@ -1292,12 +1360,14 @@ def _push_add_over_workers(
     return table + total
 
 
-@functools.partial(jax.jit, static_argnums=(0, 3, 4))
+@functools.partial(jax.jit, static_argnums=(0, 3, 4, 5))
 def _packed_pull_on_shards(
     spec: StoreSpec, table: Array, ids: Array, kernel: bool = False,
-    width: Optional[int] = None,
+    width: Optional[int] = None, turned: bool = False,
 ) -> Array:
-    """The packed pull of ``ids`` (flat, pre-clipped) from a table sharded
+    """The packed pull of ``ids`` (flat, or ``turned`` a key block of two
+    axes whose rows come back as ``ops/packed.packed_pull``'s do;
+    pre-clipped) from a table sharded
     over ``ps``: each shard gathers physical rows of its own block, slices
     them down to the logical row and zeroes the rows it does not own; the
     sum over the shards of those ``(n, row_width)`` answers is the step's
@@ -1321,6 +1391,12 @@ def _packed_pull_on_shards(
     others = tuple(a for a in mesh.axis_names if a != ps)
     if ids.shape[0] % (mesh.size // spec.num_shards):
         others = ()
+    # the axis the batch is split on: the block's leading one, which a
+    # turned answer has second
+    lanes = (None,) if turned else ()
+    out = (others or None,) + lanes
+    if turned:
+        out = out[::-1]
 
     def on_shard(block: Array, ids: Array) -> Array:
         rel = ids // k - jax.lax.axis_index(ps) * rows
@@ -1329,16 +1405,17 @@ def _packed_pull_on_shards(
         # clipped, they would all be its first or its last row, and a
         # gather that keeps hitting one row takes twice as long a row
         vals = sub_row_slice(
-            jnp.take(block, rel, axis=0, mode="wrap"), ids, spec.row_width,
-            kernel, width,
+            jnp.take(block, rel.reshape(-1), axis=0, mode="wrap"), ids,
+            spec.row_width, kernel, width, turned,
         )
-        return jnp.where(mine[:, None], vals, jnp.zeros_like(vals))[None]
+        mine = mine.T if turned else mine
+        return jnp.where(mine[..., None], vals, jnp.zeros_like(vals))[None]
 
     return jax.shard_map(
         on_shard,
         mesh=mesh,
-        in_specs=(P(ps, None), P(others or None)),
-        out_specs=P(ps, others or None, None),
+        in_specs=(P(ps, None), P(others or None, *lanes)),
+        out_specs=P(ps, *out, None),
         check_vma=not kernel,  # a Pallas call states no varying axes
     )(table, ids).sum(axis=0)
 

@@ -299,13 +299,25 @@ def make_train_step(logic: BatchedWorkerLogic, spec) -> Callable:
 
     One call = one microbatch of "events": the reference's per-message hot
     loop (SURVEY.md §3.1) collapsed into gather → math → scatter-add with
-    zero host round-trips.  The batch is taken as the stream delivered it:
-    worker outputs are in stream order, and the push sums a row's deltas
-    in that order.
+    zero host round-trips.  The batch is taken as the stream delivered it
+    and worker outputs are in stream order.  The pull's LANES are the
+    logic's key block in C order (``BatchedWorkerLogic.keys``), the push's
+    its request's ids in C order, and the push sums a row's deltas in lane
+    order.  For most logics both are stream order.  A batch's leaves are
+    split over the mesh's workers on their leading axis, and the logic is
+    asked what it is over that many (``for_workers``: this function alone
+    asks, and alone honours an answer that ``pulls_turned``).  The FM
+    family's answer in one place pulls in stream order, takes the rows
+    turned and pushes field-major lanes, ``f B + b`` for example ``b``'s
+    field ``f``: stream order within a field, and so within a row wherever
+    a field has its own rows (``models/factorization_machine.FieldLanes``).
     """
     from . import store as store_mod
 
     workers = worker_count(spec.mesh)
+    # (both read with defaults: a logic need not be a BatchedWorkerLogic)
+    logic = getattr(logic, "for_workers", lambda workers: logic)(workers)
+    turned = getattr(logic, "pulls_turned", False)
     lanes = None
     if workers > 1:
         lanes = NamedSharding(spec.mesh, PartitionSpec(DP_AXIS))
@@ -327,19 +339,21 @@ def make_train_step(logic: BatchedWorkerLogic, spec) -> Callable:
         # XLA numbers its fusions
         with scope("ps.pull"):
             # (of a rule store whose row has a worker's part, that part)
-            pulled = store_mod.pull(spec, table, ids, worker_part=True)
+            pulled = store_mod.pull(
+                spec, table, ids, worker_part=True, turned=turned)
         with scope("ps.compute"):
             state, req, out = logic.step(state, batch, pulled)
         with scope("ps.push"):
             table, counted = store_mod.push_counted(
                 spec, table, req.ids, req.deltas, req.mask,
-                lanes_over_workers=lanes is not None,
+                lanes_over_workers=lanes is not None, turned=turned,
             )
         if isinstance(out, dict):
             # what the store counted on the device and which arms this
             # trace read leave the step with the logic's outputs
             out = {**out, **store_mod.step_counts(
-                spec, counted, pull_lanes=ids.size, push_lanes=req.ids.size)}
+                spec, counted, pull_lanes=ids.size, push_lanes=req.ids.size,
+                fields=ids.shape[-1] if turned else None)}
         return table, state, out
 
     return step

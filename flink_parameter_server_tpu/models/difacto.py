@@ -51,7 +51,7 @@ from ..core.batched import BatchedWorkerLogic, PushRequest
 from ..core.store import InitFn, ShardedParamStore
 from ..training.tracing import scope
 from ..utils.initializers import normal_factor
-from .factorization_machine import forward_gradients
+from .factorization_machine import FieldLanes, forward_gradients
 
 Array = jax.Array
 
@@ -129,11 +129,14 @@ class DiFactoUpdater:
         ).astype(current.dtype)
 
 
-class DiFacto(BatchedWorkerLogic):
+class DiFacto(FieldLanes, BatchedWorkerLogic):
     """Batch keys as ``FactorizationMachine``'s: ``ids`` (B,K) int (-1 in a
     dead lane), ``values`` (B,K) float, ``feat_mask`` (B,K) bool, ``label``
     (B,) ±1, ``mask`` (B,) bool.  ``pulled`` is ``(B, K, 4 + dim)``, the
-    worker's part of the rows, and the pushed gradients are that wide (of a
+    worker's part of the rows, and the pushed ids, mask and gradients lie
+    so (``(K, B, .)``, the field axis leading, in the copy a step in one
+    place traces: ``FieldLanes``, the FM family's lane order); the gradients
+    are as wide as the rows came (of a
     store whose spec names no worker's part, one reloaded by
     ``ShardedParamStore.from_values``, whole rows come and whole rows go,
     the lanes past ``V`` zeros: the step answers at the width it was
@@ -160,16 +163,17 @@ class DiFacto(BatchedWorkerLogic):
     def step(self, state, batch: Dict[str, Array], pulled: Array):
         dim = self.config.dim
         v_at = STATE_LANES
-        mask = batch["feat_mask"] & batch["mask"][:, None]
+        feat_mask = self.lanes(batch["feat_mask"])
+        mask = feat_mask & self.by_field(batch["mask"])
         x = jnp.where(
-            batch["feat_mask"], batch["values"].astype(jnp.float32), 0.0
+            feat_mask, self.lanes(batch["values"]).astype(jnp.float32), 0.0
         )
         w = pulled[..., W]
         with scope("ps.gate"):
             # a feature's embedding counts only where its row is live
             v_live = embedding_live(
                 w, pulled[..., C], self.V_threshold
-            ) & batch["feat_mask"]
+            ) & feat_mask
             v = jnp.where(
                 v_live[..., None], pulled[..., v_at:v_at + dim], 0.0
             )
@@ -186,7 +190,9 @@ class DiFacto(BatchedWorkerLogic):
                 jax.nn.softplus(-sign * y_hat),
             )
 
-        y_hat, loss, gw, gv = forward_gradients(x, w, v, loss_gradient, 0.0)
+        y_hat, loss, gw, gv = forward_gradients(
+            x, w, v, loss_gradient, 0.0, self.field_axis
+        )
         with scope("ps.gate"):
             gv = jnp.where(v_live[..., None], gv, 0.0)
         with scope("ps.delta_build"):
@@ -205,7 +211,7 @@ class DiFacto(BatchedWorkerLogic):
             "fm_live_keys": jnp.sum(mask, dtype=jnp.int32),
             "fm_v_live_keys": jnp.sum(v_live & mask, dtype=jnp.int32),
         }
-        return state, PushRequest(batch["ids"], deltas, mask), out
+        return state, PushRequest(self.lanes(batch["ids"]), deltas, mask), out
 
     def publish_counts(self, outs, registry, total, peak) -> None:
         # the live lanes of the dispatch's keys, and those whose embedding

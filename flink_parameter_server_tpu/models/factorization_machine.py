@@ -19,6 +19,7 @@ convention) or squared loss SGD; the global bias is a reserved feature id
 """
 from __future__ import annotations
 
+import copy
 import dataclasses
 from typing import Dict
 
@@ -42,29 +43,110 @@ class FMConfig:
     loss: str = "logistic"  # or "squared"
 
 
-def forward_gradients(x: Array, w: Array, v: Array, loss_gradient, l2: float):
-    """The degree-2 FM on examples with active values ``x`` (B, K), weights
-    ``w`` (B, K) and latent vectors ``v`` (B, K, d): ``(y_hat, loss, dw,
-    dv)``, the prediction, ``loss_gradient(y_hat) -> (dL/dy_hat, loss)``'s
-    loss, and the loss's gradients a (example, feature) with ``l2`` times
-    the parameter added.  The forward pass and the gradient algebra of every
-    FM logic (``FactorizationMachine``; ``models/difacto.DiFacto``, which
-    hands in its gated ``v``)."""
-    linear = jnp.sum(w * x, axis=-1)  # (B,)
-    xv = x[..., None] * v  # (B, K, d)
-    s = jnp.sum(xv, axis=1)  # (B, d)  Σ x_i v_i
-    interaction = 0.5 * (jnp.sum(s * s, axis=-1) - jnp.sum(xv * xv, axis=(1, 2)))
+def forward_gradients(
+    x: Array, w: Array, v: Array, loss_gradient, l2: float,
+    field_axis: int = 1,
+):
+    """The degree-2 FM on examples with active values ``x``, weights ``w``
+    and latent vectors ``v`` (``v``'s last axis the ``d`` latent lanes):
+    ``(y_hat, loss, dw, dv)``, the prediction ``(B,)``,
+    ``loss_gradient(y_hat) -> (dL/dy_hat, loss)``'s loss, and the loss's
+    gradients a (example, feature), shaped as ``w`` and ``v``, with ``l2``
+    times the parameter added.  Generic in where the fields lie:
+    ``field_axis`` 1 takes ``x``, ``w`` ``(B, K)`` and ``v`` ``(B, K, d)``,
+    example-major; ``field_axis`` 0 takes them ``(K, B)`` and ``(K, B, d)``,
+    FIELD-major, the batch the minor axis of every buffer (lane-dense on a
+    TPU, where a minor axis of 39 fields fills 39 of 128 lanes) and every
+    sum over the fields a sum over a leading axis.  The forward pass and the
+    gradient algebra of every FM logic (``FactorizationMachine``;
+    ``models/difacto.DiFacto``, which hands in its gated ``v``)."""
+
+    def by_field(a: Array) -> Array:
+        # an example's number beside each of its fields
+        return jnp.expand_dims(a, field_axis)
+
+    linear = jnp.sum(w * x, axis=field_axis)  # (B,)
+    xv = x[..., None] * v  # x_i v_i
+    s = jnp.sum(xv, axis=field_axis)  # (B, d)  Σ x_i v_i
+    interaction = 0.5 * (
+        jnp.sum(s * s, axis=-1) - jnp.sum(xv * xv, axis=(field_axis, 2))
+    )
     y_hat = linear + interaction  # (B,)
     g, loss = loss_gradient(y_hat)
     # ∂ŷ/∂w_i = x_i ;  ∂ŷ/∂v_i = x_i (s − x_i v_i)
-    dw = g[:, None] * x + l2 * w
-    dv = g[:, None, None] * (x[..., None] * (s[:, None, :] - xv)) + l2 * v
+    dw = by_field(g) * x + l2 * w
+    dv = by_field(g)[..., None] * (x[..., None] * (by_field(s) - xv)) + l2 * v
     return y_hat, loss, dw, dv
 
 
-class FactorizationMachine(BatchedWorkerLogic):
+class FieldLanes:
+    """The lane order of the FM family's logics (``FactorizationMachine``,
+    ``models/difacto.DiFacto``), whose batch is ``(B, K)``: ``B`` examples
+    of ``K`` fields.
+
+    The logic itself is EXAMPLE-major, as every ``BatchedWorkerLogic`` is:
+    ``keys()`` gives the batch's ``ids`` ``(B, K)``, ``pulled`` is ``(B, K,
+    d)`` and the push's ids, mask and deltas leave ``(B, K[, d])``, whoever
+    calls ``step`` (``cluster/driver.ClusterDriver`` does, with rows it
+    pulled itself).
+
+    ``for_workers(1)``, what ``make_train_step`` traces in one place, is a
+    copy that computes FIELD-major, the field axis leading and the batch
+    axis minor.  Its ``keys()`` are the same ``(B, K)`` (the rows are
+    gathered in the stream's order), it says ``pulls_turned``, so
+    ``pulled`` comes ``(K, B, d)``; the deltas, the ids and the mask of the
+    push leave ``(K, B, d)`` and ``(K, B)``, so the push's lanes are
+    field-major, lane ``f B + b``.  The stream's batch is untouched: the
+    logic turns its three ``(B, K)`` arrays inside the step.  On a TPU a
+    packed store's kernels hand over and take a step's rows feature-major;
+    with the batch the minor axis the logic's buffers are ``f32[d, K, B]``
+    there, lane-dense, the pull's kernel writes exactly that and the push's
+    reads it (``core/store.arms``' ``fields``), where ``(B, K, d)`` costs a
+    loop of ``d`` trips through a flat buffer each way (6 ms of cell 2's
+    53: PERF.md section 6, PR 63).  The rows are still GATHERED
+    example-major, the key block's own order: field-major a field's few
+    rows are named 32,768 lanes on end, and the TPU's gather pays 3.5 ms a
+    step for that.  Where a row belongs to one field (a key space a field),
+    a row's deltas are summed in the order example-major lanes give them,
+    by example; where fields share rows the order differs, and with it the
+    sum's last bits.
+
+    Over several data-parallel workers the step keeps the logic as it is:
+    ``make_train_step`` splits a batch over its workers on the leading
+    axis, so a worker's lanes are its examples' only in that order."""
+
+    field_major = False
+
+    def for_workers(self, workers: int):
+        if self.field_major == (workers == 1):
+            return self
+        other = copy.copy(self)
+        other.field_major = workers == 1
+        return other
+
+    @property
+    def pulls_turned(self) -> bool:
+        return self.field_major
+
+    @property
+    def field_axis(self) -> int:
+        return 0 if self.field_major else 1
+
+    def lanes(self, per_key: Array) -> Array:
+        """A ``(B, K)`` array of the batch as the step computes with it."""
+        return per_key.T if self.field_major else per_key
+
+    def by_field(self, per_example: Array) -> Array:
+        """A ``(B,)`` array of the batch beside each of its fields."""
+        return jnp.expand_dims(per_example, self.field_axis)
+
+
+class FactorizationMachine(FieldLanes, BatchedWorkerLogic):
     """Batch: ``ids`` (B,K) int, ``values`` (B,K) float, ``feat_mask``
     (B,K) bool, ``label`` (B,) (±1 logistic / float squared), ``mask`` (B,).
+    ``pulled``, the push's ids, deltas and mask are as :class:`FieldLanes`
+    has them (``(B, K)`` leading; ``(K, B)`` in the copy a step in one place
+    traces); ``prediction`` and ``loss`` are ``(B,)``.
     """
 
     def __init__(self, config: FMConfig):
@@ -78,7 +160,10 @@ class FactorizationMachine(BatchedWorkerLogic):
 
     def step(self, state, batch: Dict[str, Array], pulled: Array):
         cfg = self.config
-        x = jnp.where(batch["feat_mask"], batch["values"].astype(jnp.float32), 0.0)
+        feat_mask = self.lanes(batch["feat_mask"])
+        x = jnp.where(
+            feat_mask, self.lanes(batch["values"]).astype(jnp.float32), 0.0
+        )
 
         def loss_gradient(y_hat):
             label = batch["label"].astype(jnp.float32)
@@ -90,18 +175,19 @@ class FactorizationMachine(BatchedWorkerLogic):
             return g, 0.5 * g * g
 
         y_hat, loss, dw, dv = forward_gradients(
-            x, pulled[..., 0], pulled[..., 1:], loss_gradient, cfg.l2
+            x, pulled[..., 0], pulled[..., 1:], loss_gradient, cfg.l2,
+            self.field_axis,
         )
         deltas = jnp.concatenate(
             [-cfg.learning_rate * dw[..., None], -cfg.learning_rate * dv], axis=-1
-        )  # (B, K, 1+d)
+        )  # (lanes, 1+d)
 
-        mask = batch["feat_mask"] & batch["mask"][:, None]
+        mask = feat_mask & self.by_field(batch["mask"])
         out = {
             "prediction": y_hat,
             "loss": loss * batch["mask"],
         }
-        return state, PushRequest(batch["ids"], deltas, mask), out
+        return state, PushRequest(self.lanes(batch["ids"]), deltas, mask), out
 
 
 def make_store(
@@ -143,6 +229,7 @@ def train_fm(data, config: FMConfig, *, seed: int = 0, mesh=None, **kwargs):
 
 
 __all__ = [
-    "FMConfig", "FactorizationMachine", "forward_gradients", "make_store",
+    "FMConfig", "FactorizationMachine", "FieldLanes", "forward_gradients",
+    "make_store",
     "train_fm",
 ]

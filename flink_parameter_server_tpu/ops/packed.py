@@ -64,6 +64,26 @@ down the 128 sublanes (pad sublanes zeros), keeps in every lane the one
 window ``ids % k`` names (none for a masked lane, which rides in as -1),
 transposes in VMEM and writes a ``(block, 128)`` block of the row-major
 ``(n, 128)`` rows.  Selects against zeros only, never a 0/1 product.
+
+**Both kernels a field at a time** (:func:`by_field`; PERF.md section 6, PR
+63).  A logic whose batch is ``(B, K)``, ``B`` examples of ``K`` keys, and
+that sums over the ``K`` wants ``B`` as the MINOR axis of its buffers: on the
+TPU XLA then holds ``(K, B, d)`` as ``f32[d, K, B]``, ``K`` on the sublanes of
+a tile, lane-dense.  That is no bitcast of the flat kernels' ``f32[d, K B]``
+(a row's LANES on the sublanes; with ``d`` > 8 no three-axis layout is those
+bytes), and the gather wants the rows in the batch's own order, example-major
+(neighbours that name one row cost it a quarter more).  So each kernel has a
+form whose grid step moves a block of examples of all ``K`` fields, the
+flat body once a field: :func:`turned_slice_kernel` takes field ``f``'s rows
+out of the example-major block with a strided load and writes sublane ``f``
+of a ``(d, K, B)`` output; :func:`lane_shift_kernel` handed ``(d, K, B)``
+reads sublane ``f`` and writes field ``f``'s ``(block, 128)`` rows.  The same
+bits as the flat forms.  Their loop over the fields is a ``fori_loop`` of
+``TURN_GROUP`` fields a trip (:func:`_each_field`): with 39 static bodies a
+warm set-up pays 1.4 s a kernel of tracing and lowering on the chip's host.
+At FM's size on the v5e the two relayouts between the flat kernels and the
+logic took 6.4 ms a step; in the step these take 0.87 ms each where the
+flat ones take 1.08 and 1.21.
 """
 from __future__ import annotations
 
@@ -82,6 +102,54 @@ LANES = 128
 # rows of 17 lanes: 1.57 / 1.22 / 1.10 / 1.08 / 1.07 ms at 512 / 1,024 /
 # 2,048 / 4,096 / 8,192 (PERF.md section 6, PR 42).
 SLICE_BLOCK = 4096
+# Examples a grid step of the two kernels that move a batch a field at a time
+# (`by_field`).  `turned_slice_kernel`: `TURN_BLOCK x K` gathered rows in, a
+# (d, K, TURN_BLOCK) block out; `lane_shift_kernel` of three axes the reverse,
+# at `FIELDED_BLOCK`.  Their loop over the fields is bound by a trip's latency,
+# not by bytes, so what pays is more work a trip: in cell 2's step the slice
+# takes 2.11 / 1.08 / 0.87 ms at 128 x 1 / 256 x 1 / 256 x 3 (examples x
+# fields a trip) and the shift 2.04 / 1.72 / 1.33, and 0.87 at 128 x 3, the
+# 39 static bodies' time; 13 fields a trip cost 0.2 s more of tracing
+# (PERF.md section 6, PR 63, calls H and J).
+TURN_BLOCK = 256
+FIELDED_BLOCK = 128
+# Fields a trip of the kernels' loop over the fields (`_each_field`).
+TURN_GROUP = 3
+# The most fields a block has: 40 fields of 256 examples are 5.2 MB of rows,
+# twice in VMEM (`tests/test_tpu_compile.py` compiles both kernels at 40 for a
+# described v5e; Criteo's 39 ran on the chip).
+TURN_FIELDS = 40
+
+
+def by_field(fields: int, batch: int) -> bool:
+    """Whether the two kernels take a batch of ``fields x batch`` lanes a
+    FIELD at a time (:func:`turned_slice_kernel`; :func:`lane_shift_kernel`
+    of three axes; ``core/store.arms`` asks, of a batch whose logic says it
+    is such a block): the batch in whole blocks of both kernels
+    (:data:`TURN_BLOCK`, :data:`FIELDED_BLOCK` lanes), and no more than
+    :data:`TURN_FIELDS` fields, whose rows a block holds."""
+    whole = batch % TURN_BLOCK == 0 and batch % FIELDED_BLOCK == 0
+    return whole and 0 < fields <= TURN_FIELDS
+
+
+def _each_field(fields: int, one_field) -> None:
+    """``one_field(f)`` for every field of a kernel's block, :data:`TURN_GROUP`
+    fields a trip of a ``fori_loop`` and the odd ones after it: a trip's
+    fields are independent chains the scheduler interleaves.  A loop, not
+    ``fields`` copies of the body: a warm set-up traces and lowers the kernel
+    anew, 1.4 s a kernel unrolled 39 times on the chip's host (cell 2's
+    ``setup_s`` 16.2 -> 19.0: PERF.md section 6, PR 63)."""
+    trips = fields // TURN_GROUP
+
+    def trip(g, carry):
+        for j in range(TURN_GROUP):
+            one_field(g * TURN_GROUP + j)
+        return carry
+
+    if trips:
+        jax.lax.fori_loop(0, trips, trip, 0)
+    for f in range(trips * TURN_GROUP, fields):
+        one_field(f)
 
 
 def pack_k(row_width: int) -> int:
@@ -209,27 +277,98 @@ def sub_row_slice_kernel(
     return out.T
 
 
+def _turned_slice_kernel(t_ref, rows_ref, out_ref, *, fields: int, **widths):
+    from .row_update import _pallas
+
+    pl, examples = _pallas()[0], t_ref.shape[1]
+
+    def one_field(f):
+        # every `fields`-th row from row f on is field f of the block's
+        # examples; its slice is sublane f of the (w, fields, examples) block
+        _slice_kernel(
+            t_ref.at[pl.ds(f, 1), :],
+            rows_ref.at[pl.ds(f, examples, stride=fields), :],
+            out_ref.at[:, f, :], **widths)
+
+    _each_field(fields, one_field)
+
+
+def turned_slice_kernel(
+    rows: Array, ids: Array, row_width: int, width: Optional[int] = None,
+    *, block: Optional[int] = None, interpret: Optional[bool] = None,
+) -> Array:
+    """The lane slice of the rows gathered for a TWO-axis key block ``ids``
+    ``(B, K)``, ``rows`` ``(B K, 128)`` in the block's C order, handed back
+    TURNED, ``(K, B, w)``: ``out[f, b]`` is the slice of row ``b K + f``.
+    :func:`sub_row_slice_kernel`'s body a field: a grid step reads the
+    gathered rows of ``block`` examples, takes field ``f``'s with a strided
+    load (every ``K``-th row), transposes, selects the window and writes
+    sublane ``f`` of a ``(w, K, block)`` block of a ``(w, K, B)`` output,
+    whose transpose to ``(K, B, w)`` is a bitcast on the TPU: the form XLA
+    gives a logic's buffers whose minor axis is the batch.  What it is for:
+    the gather runs in the ORDER of the key block, and the TPU's gather
+    pays for neighbours that name one row (cell 2's, example-major 12.98 ms
+    a step, field-major 16.49: a field's few rows 32,768 lanes on end;
+    PERF.md section 6, PR 63), while a logic that sums over its fields
+    wants them on a leading axis.  ``B`` in whole blocks of ``block``
+    (:data:`TURN_BLOCK`) examples; bit for bit
+    ``_sub_row_slice(rows, ids.reshape(-1), ...)`` turned."""
+    from .row_update import _pallas
+
+    pl, pltpu = _pallas()
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    (b, fields), d, k = ids.shape, row_width, pack_k(row_width)
+    w = d if width is None else width
+    block = TURN_BLOCK if block is None else block
+    assert rows.shape[0] == b * fields and b % block == 0, (rows.shape, b)
+    out = pl.pallas_call(
+        functools.partial(_turned_slice_kernel, k=k, d=d, w=w, fields=fields),
+        out_shape=jax.ShapeDtypeStruct((w, fields, b), rows.dtype),
+        grid=(b // block,),
+        in_specs=[
+            pl.BlockSpec((fields, block), lambda i: (0, i)),
+            pl.BlockSpec((block * fields, LANES), lambda i: (i, 0)),
+        ],
+        out_specs=pl.BlockSpec((w, fields, block), lambda i: (0, 0, i)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)
+        ),
+        interpret=interpret,
+        name="packed_lane_slice_turned",
+    )((ids.astype(jnp.int32) % k).T, rows)
+    return jnp.transpose(out, (1, 2, 0))
+
+
 def sub_row_slice(
     rows: Array, ids: Array, row_width: int, kernel: bool = False,
-    width: Optional[int] = None,
+    width: Optional[int] = None, turned: bool = False,
 ) -> Array:
     """The lane slice of gathered physical rows in the arm the caller read
     (``core/store.arms``' ``pull``), down to a row's first ``width`` lanes
-    where the caller wants no more."""
+    where the caller wants no more.  ``turned`` (the arm
+    ``packed_kernel_by_field``): ``ids`` a key block of two axes ``(B, K)``,
+    ``rows`` in its C order, the slices handed back ``(K, B, width)``
+    (:func:`turned_slice_kernel`)."""
+    if turned:
+        return turned_slice_kernel(rows, ids, row_width, width)
     if kernel:
         return sub_row_slice_kernel(rows, ids, row_width, width)
     return _sub_row_slice(rows, ids, row_width, width)
 
 
-@functools.partial(jax.jit, static_argnums=(2, 3, 4))
+@functools.partial(jax.jit, static_argnums=(2, 3, 4, 5))
 def packed_pull(
     packed: Array, ids: Array, row_width: int, kernel: bool = False,
-    width: Optional[int] = None,
+    width: Optional[int] = None, turned: bool = False,
 ) -> Array:
     """Gather logical rows ``ids`` (pre-clipped) from the packed table:
     one gather of whole 128-lane physical rows, then the lane slice
     (``kernel``: :func:`sub_row_slice`), cut to ``width`` lanes of each row
-    where given.  The gather moves whole physical rows whatever ``width``:
+    where given.  ``ids`` flat give ``(n, width)``; ``turned``, a key block
+    of two axes ``(B, K)`` is gathered in its C order and handed back ``(K,
+    B, width)`` (:func:`sub_row_slice`).  The gather moves whole physical
+    rows whatever ``width``:
     the TPU's gather takes whole rows of its operand, a slice of the table
     in front of it (the leading three of GloVe's five registers) is a COPY
     of those registers (6.7 GB beside cell 13's 11.24 GB table), and a
@@ -242,9 +381,9 @@ def packed_pull(
     ids = ids.astype(jnp.int32)
     # ids are in range, so no pass to fill rows that are not
     phys_vals = jnp.take(
-        packed, ids // pack_k(row_width), axis=0, mode="clip"
+        packed, ids.reshape(-1) // pack_k(row_width), axis=0, mode="clip"
     )
-    return sub_row_slice(phys_vals, ids, row_width, kernel, width)
+    return sub_row_slice(phys_vals, ids, row_width, kernel, width, turned)
 
 
 def lane_shift_deltas(deltas: Array, ids: Array, row_width: int) -> Array:
@@ -291,6 +430,20 @@ def _shift_kernel(t_ref, deltas_ref, out_ref, *, k: int, d: int):
     out_ref[...] = jnp.where(keep, windows, jnp.zeros_like(windows)).T
 
 
+def _fielded_shift_kernel(t_ref, deltas_ref, out_ref, *, k: int, d: int):
+    from .row_update import _pallas
+
+    pl = _pallas()[0]
+
+    def one_field(f):
+        # sublane f of every one of the d planes: field f's (d, block)
+        _shift_kernel(
+            t_ref.at[pl.ds(f, 1), :], deltas_ref.at[:, f, :], out_ref.at[f],
+            k=k, d=d)
+
+    _each_field(t_ref.shape[0], one_field)
+
+
 def lane_shift_kernel(
     by_lane: Array, ids: Array, row_width: int, mask: Optional[Array] = None,
     *, block: Optional[int] = None, interpret: Optional[bool] = None,
@@ -303,18 +456,48 @@ def lane_shift_kernel(
     bits of ``lane_shift_deltas(jnp.where(mask[:, None], deltas, 0), ...)``,
     with no pass over the deltas for it.  ``n`` need not be whole blocks;
     off the TPU the kernel is interpreted, as :func:`sub_row_slice_kernel`
-    is."""
+    is.
+
+    ``by_lane`` of THREE axes ``(d, K, B)`` (``ids`` and ``mask`` ``(K,
+    B)``) is the same batch as its flattening to ``(d, K B)``, and gives the
+    same ``(K B, 128)`` rows, read where a logic whose minor axis is the
+    batch leaves its deltas: on the TPU XLA holds ``(K, B, d)`` deltas as
+    ``f32[d, K, B]``, ``K`` on the sublanes, which no bitcast makes the
+    ``(d, K B)`` operand (that has a row's LANES on the sublanes: a pad and
+    a copy of the batch, 0.55 ms a step in cell 2; PERF.md section 6, PR
+    63).  The kernel's body a field, ``B`` in whole blocks of
+    :data:`FIELDED_BLOCK` lanes."""
     from .row_update import _pallas
 
     pl, pltpu = _pallas()
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    (d, n), k = by_lane.shape, pack_k(row_width)
+    d, k = by_lane.shape[0], pack_k(row_width)
     assert d == row_width and k > 1, (d, row_width)
-    block = SLICE_BLOCK if block is None else block
     t = ids.astype(jnp.int32) % k
     if mask is not None:
         t = jnp.where(mask, t, -1)
+    if by_lane.ndim == 3:
+        fields, b = ids.shape
+        block = FIELDED_BLOCK if block is None else block
+        assert b % block == 0, (b, block)
+        return pl.pallas_call(
+            functools.partial(_fielded_shift_kernel, k=k, d=d),
+            out_shape=jax.ShapeDtypeStruct((fields, b, LANES), by_lane.dtype),
+            grid=(b // block,),
+            in_specs=[
+                pl.BlockSpec((fields, block), lambda i: (0, i)),
+                pl.BlockSpec((d, fields, block), lambda i: (0, 0, i)),
+            ],
+            out_specs=pl.BlockSpec((fields, block, LANES), lambda i: (0, i, 0)),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel",)
+            ),
+            interpret=interpret,
+            name="packed_lane_shift_fielded",
+        )(t, by_lane).reshape(fields * b, LANES)
+    n = by_lane.shape[1]
+    block = SLICE_BLOCK if block is None else block
     return pl.pallas_call(
         functools.partial(_shift_kernel, k=k, d=d),
         out_shape=jax.ShapeDtypeStruct((n, LANES), by_lane.dtype),
@@ -354,6 +537,8 @@ __all__ = [
     "slice_refusal",
     "sub_row_slice",
     "sub_row_slice_kernel",
+    "turned_slice_kernel",
+    "by_field",
     "lane_shift_deltas",
     "lane_shift_kernel",
     "lane_unshift",

@@ -27,6 +27,7 @@ from flink_parameter_server_tpu.ops.packed import (
     packed_pull,
     phys_width,
     sub_row_slice_kernel,
+    turned_slice_kernel,
     unpack_table,
 )
 
@@ -185,6 +186,74 @@ def test_the_shift_kernel_equals_the_select_arm_bit_for_bit(d, n, masked):
     np.testing.assert_array_equal(bits(lane_unshift(got, ids, d)), bits(kept))
     if masked:  # rows of +0.0, not of what the lane held times zero
         assert not bits(got)[~np.asarray(mask)].any()
+
+
+# a key block (B, K): K fields on the leading axis of the answer
+@pytest.mark.parametrize("batch, fields", [(256, 1), (256, 5), (512, 39), (256, 40)])
+@pytest.mark.parametrize("d, width", [(4, None), (17, None), (36, 20), (64, 1)])
+def test_the_turned_slice_kernel_is_the_select_arm_turned_bit_for_bit(
+        d, width, batch, fields):
+    """``turned_slice_kernel`` (interpreted here) reads the rows gathered
+    for a two-axis key block in the block's C order and writes them TURNED:
+    the bits of ``_sub_row_slice`` on the flat ids, NaN, infinities and -0.0
+    included, with the block's axes swapped.  ``by_field`` says which block
+    the kernels take so: whole blocks of examples, as many fields as were
+    compiled for the chip."""
+    n = batch * fields
+    rows, ids = _gathered_rows_with_specials(
+        np.random.default_rng([d, batch, fields]), n, d)
+    block = ids.reshape(batch, fields)
+    want = jnp.swapaxes(_sub_row_slice(rows, ids, d, width).reshape(
+        batch, fields, -1), 0, 1)
+
+    def bits(x):
+        return np.asarray(x).view(np.uint32)
+
+    got = turned_slice_kernel(rows, block, d, width)
+    assert got.shape == want.shape == (fields, batch, width or d)
+    np.testing.assert_array_equal(bits(got), bits(want))
+    assert np.isnan(np.asarray(want)).any() or (width or d) == 1
+    assert packed_mod.by_field(fields, batch)
+    assert not packed_mod.by_field(fields, batch - 8)
+    assert not packed_mod.by_field(packed_mod.TURN_FIELDS + 1, batch)
+    assert not packed_mod.by_field(0, batch)
+
+
+@pytest.mark.parametrize("masked", [False, True], ids=["all_live", "masked"])
+@pytest.mark.parametrize("batch, fields", [(128, 1), (256, 5), (384, 39), (128, 40)])
+@pytest.mark.parametrize("d", [16, 17, 36, 64])
+def test_the_shift_kernel_takes_its_deltas_a_field_at_a_time_bit_for_bit(
+        d, batch, fields, masked):
+    """``lane_shift_kernel`` handed ``(d, K, B)`` deltas with ``(K, B)`` ids
+    and mask (interpreted here): the rows of the flat kernel on the same
+    batch flattened, ``(K B, 128)`` in the block's C order, and so
+    ``lane_shift_deltas``' bits, NaN, infinities and -0.0 included; a
+    masked lane a row of +0.0."""
+    rng = np.random.default_rng([d, batch, fields])
+    n = batch * fields
+    ids = jnp.asarray(rng.integers(0, 10 ** 6, (fields, batch)).astype(np.int32))
+    deltas = rng.normal(0, 1, (fields, batch, d)).astype(np.float32)
+    for bad in (np.nan, np.inf, -np.inf, -0.0):
+        deltas[rng.integers(0, fields, n // 4), rng.integers(0, batch, n // 4),
+               rng.integers(0, d, n // 4)] = bad
+    deltas = jnp.asarray(deltas)
+    mask = jnp.asarray(rng.random((fields, batch)) < 0.7) if masked else None
+
+    def bits(x):
+        return np.asarray(x).view(np.uint32)
+
+    got = lane_shift_kernel(jnp.moveaxis(deltas, -1, 0), ids, d, mask)
+    flat_mask = None if mask is None else mask.reshape(-1)
+    flat = lane_shift_kernel(
+        deltas.reshape(n, d).T, ids.reshape(-1), d, flat_mask, block=256)
+    kept = deltas if mask is None else jnp.where(mask[..., None], deltas, 0)
+    want = lane_shift_deltas(kept.reshape(n, d), ids.reshape(-1), d)
+    assert got.shape == want.shape == (n, 128) and got.dtype == want.dtype
+    np.testing.assert_array_equal(bits(got), bits(flat))
+    np.testing.assert_array_equal(bits(got), bits(want))
+    assert np.isnan(np.asarray(want)).any()
+    if masked:
+        assert not bits(got)[~np.asarray(flat_mask)].any()
 
 
 @pytest.mark.parametrize("n,kernel", [(255, False), (256, True), (300, True)])

@@ -761,6 +761,161 @@ def test_push_pull_case_table_shift_kernel_arm(
         np.asarray(selects.table).view(np.uint32))
 
 
+# A key block of two axes pulled TURNED (``pull(..., turned=True)``, what a
+# step asks for a logic that ``pulls_turned``): the pull's rows with the
+# block's axes swapped, in every arm; the arm ``packed_kernel_by_field`` writes
+# them so (``ops/packed.turned_slice_kernel``: steered and interpreted here),
+# the others turn what they gathered.  And the push's mirror, under the SAME
+# declaration (``push_counted(..., turned=True)``): the shift
+# ``kernel_by_field`` is handed the deltas ``(d, K, B)``.  Which batch takes
+# the by-field forms is ``arms``' to say (``test_the_arms_table``); here the
+# arm is steered to what ``arms`` reads on a TPU.
+TURNED_STORES = [
+    # what, row, update, layout, the arm a CPU reads, a kernel arm to steer to
+    ("dense_add", (128,), "add", "dense", "take", False),
+    ("narrow_rule", (3,), "rule", "auto", "narrow", False),
+    ("packed_selects", (17,), "add", "packed", "packed_selects", False),
+    ("packed_kernel", (17,), "add", "packed", "packed_selects", True),
+    ("packed_rule_part", (36,), "rule", "packed", "packed_selects", True),
+]
+
+
+def _by_field(placed, examples, fields):
+    """Whether a TPU's ``arms`` reads the by-field forms for a block of
+    ``examples x fields`` (under ``dp`` a worker's half of the examples)."""
+    from flink_parameter_server_tpu.ops import packed
+
+    mine = examples // 2 if placed == "dp_x_ps" else examples
+    return packed.by_field(fields, mine), (mine, fields)
+
+
+@pytest.mark.parametrize("block", [(256, 5), (512, 39), (100, 3)], ids=str)
+@pytest.mark.parametrize("placed", ["one_place", "ps_4", "dp_x_ps"])
+@pytest.mark.parametrize("what", [row[0] for row in TURNED_STORES])
+def test_a_turned_pull_is_the_pull_with_its_blocks_axes_swapped(
+        what, placed, block, mesh, mesh_devices, monkeypatch, steer_arms):
+    from flink_parameter_server_tpu.core import store as store_mod
+    from flink_parameter_server_tpu.ops import packed
+    from flink_parameter_server_tpu.parallel.mesh import make_mesh
+
+    _, row, update, layout, cpu_arm, steered = next(
+        r for r in TURNED_STORES if r[0] == what)
+    cap = 1000
+    on = {"one_place": None, "ps_4": make_mesh(1, 4, devices=mesh_devices[:4]),
+          "dp_x_ps": mesh}[placed]
+    rule = (lambda current, combined: current + combined[..., :1])
+    part = 20 if row == (36,) else None
+    store = ShardedParamStore.create(
+        cap, row, init_fn=lambda ids: jnp.asarray(
+            _init_values(cap, row))[ids],
+        update="add" if update == "add" else rule, layout=layout, mesh=on,
+        worker_width=part)
+    rng = np.random.default_rng([len(what), block[0]])
+    ids = rng.integers(-3, cap + 5, block).astype(np.int32)
+    if cpu_arm == "narrow" and on is not None:
+        cpu_arm = "take"  # (a narrow row keeps its tile in one place only)
+    # off the TPU the declaration changes no arm
+    assert store_mod.arms(
+        store.spec, pull_lanes=ids.size, fields=block[1]).pull == cpu_arm
+    calls = []
+    fielded, lanes = _by_field(placed, *block)
+    if steered:
+        steer_arms(
+            pull="packed_kernel_by_field" if fielded else "packed_kernel")
+        monkeypatch.setattr(packed, "SLICE_BLOCK", 32)
+        real = packed.turned_slice_kernel
+        monkeypatch.setattr(
+            packed, "turned_slice_kernel",
+            lambda rows, ids, *a, **kw: calls.append(ids.shape) or real(
+                rows, ids, *a, **kw))
+        packed.packed_pull.clear_cache()
+        store_mod._packed_pull_on_shards.clear_cache()
+
+    def pull(turned):
+        return np.asarray(jax.jit(lambda table, i: store_mod.pull(
+            store.spec, table, i, worker_part=True, turned=turned))(
+                store.table, jnp.asarray(ids)))
+
+    got = pull(True)
+    assert got.shape == block[::-1] + ((part,) if part else row)
+    # the by-field arm hands the kernel each shard's block; no other does
+    assert calls == ([lanes] if steered and fielded else []), calls
+    want = np.swapaxes(pull(False), 0, 1)
+    np.testing.assert_array_equal(got.view(np.uint32), want.view(np.uint32))
+    if steered:  # and the arm the CPU reads gives the same bits
+        monkeypatch.undo()
+        packed.packed_pull.clear_cache()
+        store_mod._packed_pull_on_shards.clear_cache()
+        np.testing.assert_array_equal(
+            got.view(np.uint32), pull(True).view(np.uint32))
+
+
+@pytest.mark.parametrize("masked", [False, True], ids=["all_live", "masked"])
+@pytest.mark.parametrize("block", [(5, 256), (39, 512), (3, 100)], ids=str)
+@pytest.mark.parametrize("placed", ["one_place", "ps_4", "dp_x_ps"])
+def test_a_turned_push_goes_to_the_shift_kernel_a_field_at_a_time(
+        placed, block, masked, mesh, mesh_devices, monkeypatch, steer_arms):
+    """The table after a push of ``(K, B)`` ids and ``(K, B, d)`` deltas is
+    the table after the same lanes pushed flat, bit for bit, in the select
+    arm and in both of the kernel's; ``kernel_by_field`` is handed ``(d, K,
+    B)``, ``kernel`` the flat ``(d, K B)``."""
+    from flink_parameter_server_tpu.core import store as store_mod
+    from flink_parameter_server_tpu.ops import packed
+    from flink_parameter_server_tpu.parallel.mesh import make_mesh
+
+    width, cap = 17, 1000
+    on = {"one_place": None, "ps_4": make_mesh(1, 4, devices=mesh_devices[:4]),
+          "dp_x_ps": mesh}[placed]
+    values = _init_values(cap, (width,))
+    store = ShardedParamStore.from_values(
+        jnp.asarray(values), layout="packed", mesh=on)
+    rng = np.random.default_rng([block[0], masked])
+    ids = jnp.asarray(rng.integers(-3, cap + 5, block).astype(np.int32))
+    deltas = jnp.asarray(rng.normal(size=block + (width,)).astype(np.float32))
+    mask = jnp.asarray(rng.random(block) < 0.7) if masked else None
+
+    def push(*args, turned=False):  # (a fresh function a call: no reuse)
+        return np.asarray(jax.jit(
+            lambda table, i, d, m: store_mod.push_counted(
+                store.spec, table, i, d, m, turned=turned)[0])(
+                    store.table, *args))
+
+    flat = (ids.reshape(-1), deltas.reshape(-1, width),
+            None if mask is None else mask.reshape(-1))
+    want = push(*flat)
+    np.testing.assert_array_equal(
+        push(ids, deltas, mask, turned=True).view(np.uint32),
+        want.view(np.uint32))
+    fielded = packed.by_field(*block)
+    steer_arms(shift="kernel_by_field" if fielded else "kernel")
+    monkeypatch.setattr(packed, "SLICE_BLOCK", 32)
+    calls = []
+    real = packed.lane_shift_kernel
+    monkeypatch.setattr(
+        packed, "lane_shift_kernel",
+        lambda by_lane, ids, d, mask=None: calls.append(
+            (by_lane.shape, ids.shape)) or real(by_lane, ids, d, mask))
+    got = push(ids, deltas, mask, turned=True)
+    assert calls == [
+        ((width,) + block, block) if fielded
+        else ((width, ids.size), (ids.size,))], calls
+    np.testing.assert_array_equal(got.view(np.uint32), want.view(np.uint32))
+
+
+def test_a_turned_pull_or_push_of_a_flat_batch_is_refused():
+    """``turned`` declares a block of two axes; a flat batch under it is a
+    caller's mistake and says so, on both sides."""
+    from flink_parameter_server_tpu.core import store as store_mod
+
+    store = ShardedParamStore.create(100, (17,), layout="packed")
+    ids = jnp.arange(8, dtype=jnp.int32)
+    with pytest.raises(ValueError, match=r"turned pull .* two axes"):
+        store_mod.pull(store.spec, store.table, ids, turned=True)
+    with pytest.raises(ValueError, match=r"turned push .* two axes"):
+        store_mod.push_counted(
+            store.spec, store.table, ids, jnp.zeros((8, 17)), turned=True)
+
+
 @pytest.mark.parametrize("why,update,shape,dtype,mesh_shape,n,noted", [
     ("off_the_tpu", "add", (17,), jnp.float32, None, 4096, False),
     ("bfloat16", "add", (17,), jnp.bfloat16, None, 4096, True),
@@ -2194,6 +2349,19 @@ ARMS_ON_A_TPU = [
     ("the same over ps 4, dp 1", (17,), "add", "auto", (1, 4), 7_000,
      8_192, 8_192, False,
      ("packed_kernel", "xla_add", "kernel", "", "", False), 0),
+    # a block of two axes whose logic takes it turned (`FIELDS`): both lane
+    # kernels a field at a time where the batch is whole blocks
+    ("packed k 7, fields 39", (17,), "add", "auto", None, 7_000,
+     39 * 256, 39 * 256, False,
+     ("packed_kernel_by_field", "xla_add", "kernel_by_field", "", "", False),
+     0),
+    ("fields 39 over ps 4, dp 1", (17,), "add", "auto", (1, 4), 7_000,
+     39 * 256, 39 * 256, False,
+     ("packed_kernel_by_field", "xla_add", "kernel_by_field", "", "", False),
+     0),
+    ("fields 39, the batch no blocks", (17,), "add", "auto", None, 7_000,
+     39 * 250, 39 * 250, False,
+     ("packed_kernel", "xla_add", "kernel", "", "", False), 0),
     ("packed k 2, 1,024+ <= rows / 8", (64,), "add", "auto", None, 80_000,
      4_096, 4_096, False,
      ("packed_kernel", "tile_add", "kernel", "", "", False), 0),
@@ -2240,6 +2408,13 @@ ARMS_ON_A_TPU = [
     ("the worker's 20 / 36 over ps 4", (36,), _RULE, "auto", (1, 4), 3_000,
      8_192, 8_192, False,
      ("packed_kernel", "rule", "", "row_kernel", "row_set", True), 0),
+    ("the worker's 20 / 36, fields 39", (36,), _RULE, "auto", None, 3_000,
+     39 * 256, 39 * 256, False,
+     ("packed_kernel_by_field", "rule", "", "row_kernel", "row_set", False),
+     0),
+    ("20 / 36, fields 39, over ps 4", (36,), _RULE, "auto", (1, 4), 3_000,
+     39 * 256, 39 * 256, False,
+     ("packed_kernel_by_field", "rule", "", "row_kernel", "row_set", True), 0),
     ("5 regs, the worker's 301 / 602", (602,), _RULE, "auto", None, 1_000,
      8_192, 8_192, False,
      ("packed_selects", "rule", "", "tile_kernel", "tile_assign", False), 0),
@@ -2261,6 +2436,13 @@ WORKER_WIDTHS = {
     "5 regs, the worker's 301 / 602": 301, "5 regs, the worker's 100 / 602": 100,
     "5 regs, the worker's 3 / 602": 3,
     "1 reg, the worker's 100 / 101": 100, "1 reg, 100 / 101 over ps 4": 100,
+    "the worker's 20 / 36, fields 39": 20, "20 / 36, fields 39, over ps 4": 20,
+}
+# the keys an example of a block of two axes that its logic takes TURNED
+FIELDS = {
+    "packed k 7, fields 39": 39, "fields 39 over ps 4, dp 1": 39,
+    "fields 39, the batch no blocks": 39,
+    "the worker's 20 / 36, fields 39": 39, "20 / 36, fields 39, over ps 4": 39,
 }
 # off a TPU: XLA's forms; where the push runs is read from the mesh alone
 ARMS_OFF_IT = {
@@ -2268,6 +2450,8 @@ ARMS_OFF_IT = {
     "dense 1 reg, dp 4, shard <= lanes": (
         "take", "worker_reduce", "", "", "", False),
     "packed k 2, 1,024+ <= rows / 8": (
+        "packed_selects", "xla_add", "selects", "", "", False),
+    "packed k 7, fields 39": (
         "packed_selects", "xla_add", "selects", "", "", False),
     "packed k 1, 5 regs": ("packed_selects", "xla_add", "selects", "", "", False),
     "3 lanes, held at its tile of 4": (
@@ -2319,7 +2503,8 @@ def test_the_arms_table(backend, row, mesh_devices, monkeypatch):
     def read(**width):
         return dataclasses.astuple(store_mod.arms(
             spec, pull_lanes=pull_lanes, push_lanes=push_lanes,
-            lanes_over_workers=over_workers, **width))
+            lanes_over_workers=over_workers, fields=FIELDS.get(what),
+            **width))
 
     with warnings.catch_warnings(record=True) as caught:
         warnings.simplefilter("always")
@@ -2334,7 +2519,8 @@ def test_the_arms_table(backend, row, mesh_devices, monkeypatch):
             assert read(push_width=part) == want
             whole = dataclasses.astuple(store_mod.arms(
                 dataclasses.replace(spec, worker_width=None),
-                pull_lanes=pull_lanes, push_lanes=push_lanes))
+                pull_lanes=pull_lanes, push_lanes=push_lanes,
+                fields=FIELDS.get(what)))
             assert read(push_width=spec.row_width) == whole
     assert row_update.refusal_count() == n0 + noted
 
@@ -2354,7 +2540,8 @@ def test_the_arms_table_has_a_case_a_row_of_the_docstring():
         want = case[9]
         cells = row[len(case[0]):].split()
         got = [c for c in cells if c in {
-            "take", "narrow", "packed_selects", "packed_kernel", "xla_add",
+            "take", "narrow", "packed_selects", "packed_kernel",
+            "packed_kernel_by_field", "kernel_by_field", "xla_add",
             "tile_add", "worker_reduce", "selects", "kernel", "sort",
             "scatter_add", "row_kernel", "tile_kernel", "xla_set", "tile_set",
             "row_set", "tile_assign"}]

@@ -582,7 +582,8 @@ def test_fm_step_on_four_chips_keeps_its_collective_under_ps_pull(
     donated 3.43 GB shard (``f32[6705984,128]``) is updated in place,
     every gather moves whole 128-lane rows, and the ONE collective is the
     all-reduce of the pulled rows AFTER their lane slice, 17 lanes wide
-    (left to GSPMD it is ``f32[1277952,128]``, 7.5 x the bytes), whose
+    (left to GSPMD it is ``f32[1277952,128]``, 7.5 x the bytes) and, since
+    PR 63, TURNED as the FM logic takes them, ``f32[39,32768,17]``, whose
     ``op_name`` carries ``ps.pull``: a device trace reads it under
     ``store.pull_device_ms`` (docs/observability.md).  The scatter-add is
     GSPMD's, into the chip's own block, with no collective."""
@@ -606,7 +607,7 @@ def test_fm_step_on_four_chips_keeps_its_collective_under_ps_pull(
     assert len(collectives) == 1, collectives
     # placed by the partitioner behind the pulled rows' relayout, as the
     # dense step's was: XLA's own name, which the benchmark's reader knows
-    assert f"%all-reduce = f32[{FM_BATCH},{FM_FIELDS},17]" in collectives[0]
+    assert f"%all-reduce = f32[{FM_FIELDS},{FM_BATCH},17]" in collectives[0]
     assert "all-reduce(" in collectives[0]
     assert 'op_name="jit(step)/ps.pull/' in collectives[0]
     assert f"f32[{FM_SHARD_PHYS_ROWS},128]" in text
@@ -646,6 +647,97 @@ def test_fm_step_on_one_chip_moves_whole_128_lane_rows(
     ), gathers
 
 
+def _ops(text):
+    """``{name: line}`` of a compiled module's ops."""
+    found = {}
+    for line in text.splitlines():
+        named = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = ", line)
+        if named:
+            found[named[1]] = line
+    return found
+
+
+def _is_a_view(line):
+    """An op that moves no byte: a bitcast or a tuple's element."""
+    return bool(re.search(r" (bitcast|get-tuple-element)\(", line))
+
+
+def _reaches_the_logic_through_bitcasts(text, call, width):
+    """Every op of the entry computation that reads the slice kernel's
+    result ``call``, directly or through views, is the logic's own compute
+    (or, under a mesh, the ownership mask's select in front of the
+    all-reduce): a fusion under ``ps.compute`` / ``ps.pull`` (XLA leaves a
+    fusion it cut out of one without a name: such a one yields a plane of
+    the batch, ``(K, B)``, never ``width`` of them), never a ``copy``, a
+    ``reshape``, a ``while`` or a fusion that only moves the bytes."""
+    entry = text[text.index("ENTRY"):]
+    ops = _ops(entry)
+    seen, front, readers = set(), [re.match(r"\s*%([\w.\-]+) = ", call)[1]], []
+    while front:
+        name = front.pop()
+        for other, line in ops.items():
+            if other in seen or not re.search(rf"%{re.escape(name)}[,)]", line):
+                continue
+            seen.add(other)
+            (front if _is_a_view(line) else readers).append(other)
+    assert readers
+    for name in readers:
+        line = ops[name]
+        assert re.search(r" (fusion|all-reduce)\(", line), line
+        assert not re.search(r" (copy|reshape|while|transpose)\(", line), line
+        if "op_name=" in line:
+            assert re.search(r'op_name="jit\(step\)/ps\.(compute|pull)', line), line
+        else:
+            yields = re.search(r" = f32\[([\d,]+)\]", line)[1].split(",")
+            assert sorted(int(d) for d in yields if d != "1") == [
+                FM_FIELDS, FM_BATCH], line
+    return [ops[name] for name in readers]
+
+
+def _no_block_of_the_batch_has_its_fields_minor(text):
+    """No array of the step holds the batch's 1,277,952 lanes with the 39
+    fields as its MINOR axis (39 of 128 lanes), whatever its rows' width:
+    ``f32[B,39,d]{1,0,2}`` / ``f32[d,B,39]{2,1,0}`` were the parent's."""
+    for shape, layout in re.findall(r"f32\[([\d,]+)\]\{([\d,]+)", text):
+        dims = [int(d) for d in shape.split(",")]
+        minor = dims[int(layout.split(",")[0])]
+        if len(dims) == 3 and sorted(dims)[1:] == [FM_FIELDS, FM_BATCH]:
+            assert minor == FM_BATCH, (shape, layout)
+
+
+@pytest.mark.parametrize("d, width", [(17, None), (36, 20), (64, None)], ids=str)
+def test_the_by_field_kernels_compile_at_the_most_fields_they_take(
+        one_chip, d, width):
+    """``ops/packed.by_field`` takes no more than ``TURN_FIELDS`` fields a
+    block: both kernels are lowered by Mosaic and compiled for a described
+    v5e at exactly that many (Criteo's 39 ran on the chip), at FM's and at
+    DiFacto's widths and at the widest row that packs (64 lanes, two to a
+    physical row): a block of ``TURN_BLOCK`` examples of every field, twice
+    in the 16 MB of scoped VMEM beside its ``(d, fields, block)`` mirror."""
+    from flink_parameter_server_tpu.ops import packed
+
+    fields, batch = packed.TURN_FIELDS, 8 * packed.TURN_BLOCK
+    assert packed.by_field(fields, batch)
+    assert not packed.by_field(fields + 1, batch)
+    sliced = jax.jit(lambda rows, ids: packed.turned_slice_kernel(
+        rows, ids, d, width, interpret=False)).lower(
+            _shape(one_chip, (batch * fields, 128), jnp.float32),
+            _shape(one_chip, (batch, fields), jnp.int32)).compile()
+    call, = [line for line in sliced.as_text().splitlines()
+             if "tpu_custom_call" in line]
+    assert "%packed_lane_slice_turned" in call
+    assert f" = f32[{width or d},{fields},{batch}]" in call, call
+    shifted = jax.jit(lambda deltas, ids, mask: packed.lane_shift_kernel(
+        jnp.moveaxis(deltas, -1, 0), ids, d, mask, interpret=False)).lower(
+            _shape(one_chip, (fields, batch, d), jnp.float32),
+            _shape(one_chip, (fields, batch), jnp.int32),
+            _shape(one_chip, (fields, batch), jnp.bool_)).compile()
+    call, = [line for line in shifted.as_text().splitlines()
+             if "tpu_custom_call" in line]
+    assert "%packed_lane_shift_fielded" in call
+    assert f" = f32[{fields},{batch},128]" in call, call
+
+
 @pytest.mark.parametrize("cell", ["cell_2", "cell_4"])
 def test_fm_step_on_a_tpu_slices_its_pulled_rows_in_the_kernel(
         cell, fm1, ps4, one_chip, no_compile_cache, monkeypatch):
@@ -662,18 +754,29 @@ def test_fm_step_on_a_tpu_slices_its_pulled_rows_in_the_kernel(
 
     Since PR 51 ``push`` hands the deltas to the kernel's mirror
     (``ops/packed.lane_shift_kernel``; on four chips inside
-    ``_packed_shift_on_mesh``'s ``shard_map``): exactly one
-    ``packed_lane_shift`` call under ``ps.push``, its ``f32[17,1277952]``
-    operand two bitcasts of the flatten loop's ``f32[1,17,1277952]`` and no
-    other op (no new ``copy``, no new loop; XLA wraps the two in a fusion
-    that is one pass over 87 MB on the chip, 0.38 ms, what the mask's
-    select over the same buffer was), its ``f32[1277952,128]`` result the
-    scatter-add fusion's own operand, and no select fusion or ``copy`` of
-    ``f32[1277952,128]`` left: the parent's ``select_select_fusion`` and
-    ``copy.34`` (2.23 + 1.99 ms a step), and the mask's
-    ``broadcast_select_fusion f32[1277952,17]`` with them: the mask rides
-    in as a select on ``s32[1,1277952]``.  The shifted rows and their
-    relayout were the step's two largest temporaries."""
+    ``_packed_shift_on_mesh``'s ``shard_map``): exactly one call under
+    ``ps.push``, its ``f32[1277952,128]`` rows the scatter-add fusion's own
+    operand, and no select fusion or ``copy`` of ``f32[1277952,128]`` left:
+    the parent's ``select_select_fusion`` and ``copy.34`` (2.23 + 1.99 ms a
+    step), and the mask's ``broadcast_select_fusion f32[1277952,17]`` with
+    them: the mask rides in as a select on the ids.  The shifted rows and
+    their relayout were the step's two largest temporaries.
+
+    Since PR 63 the logic computes FIELD-major
+    (``models/factorization_machine.FieldLanes``) and both kernels move the
+    batch a field at a time: ``packed_lane_slice_turned`` reads the rows as
+    they were gathered, example-major, and writes ``f32[17,39,32768]``, which
+    is XLA's own layout of the logic's ``(39, 32768, 17)`` buffers: its
+    result reaches the logic's fusions through BITCASTS alone (cell 4:
+    through the ownership mask's select and the all-reduce, in that form,
+    ``f32[39,32768,17]`` where the parent's was ``f32[32768,39,17]``: 89 MB
+    as tiled for 285); ``packed_lane_shift_fielded`` takes the
+    ``concatenate``'s ``f32[17,39,32768]`` as the logic's fusion leaves it,
+    no op between.  No ``while(`` is left in the step (the parent's two
+    flattens were loops of 17 trips through a flat ``f32[21725184]``, 6.0 ms
+    of cell 2's 52.9: PERF.md section 6, PR 63), no op yields
+    ``f32[17,32768,39]`` / ``f32[32768,39,.]``, and the temporaries fall to
+    0.66 GB (0.778 / 0.789 until then)."""
     # code that asks for the backend still sees the CPU here: steer it
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     if cell == "cell_2":
@@ -688,40 +791,52 @@ def test_fm_step_on_a_tpu_slices_its_pulled_rows_in_the_kernel(
     lanes = FM_BATCH * FM_FIELDS
     assert store_mod.arms(spec, pull_lanes=lanes, push_lanes=lanes) == (
         store_mod.Arms("packed_kernel", "xla_add", "kernel", "", "", False))
+    # ... and of the block the step's logic declares, a field at a time
+    assert store_mod.arms(
+        spec, pull_lanes=lanes, push_lanes=lanes, fields=FM_FIELDS
+    ) == store_mod.Arms(
+        "packed_kernel_by_field", "xla_add", "kernel_by_field", "", "", False)
     compiled = jax.jit(
         make_train_step(logic, spec), donate_argnums=(0, 1)
     ).lower(table, (), batch).compile()
     assert row_update.refusal_count() == n0
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes > 3.43 * GB  # in place
-    # 0.778 / 0.789 here (the parent's 1.322 / 1.321 under its 1.4 bound)
-    assert mem.temp_size_in_bytes < 0.9 * GB
+    # 0.660 / 0.660 here (0.778 / 0.789 with the flattens' loops)
+    assert mem.temp_size_in_bytes < 0.7 * GB
     text = compiled.as_text()
     entry = text[text.index("ENTRY"):]
-    n = FM_BATCH * FM_FIELDS
+    ops = _ops(entry)
+    n, lanes_3d = FM_BATCH * FM_FIELDS, f"{FM_FIELDS},{FM_BATCH}"
     kernels = [
         line for line in text.splitlines() if "tpu_custom_call" in line
     ]
     assert len(kernels) == 2, kernels
+    assert not re.findall(r" while\(", text)
+    _no_block_of_the_batch_has_its_fields_minor(text)
     shifts = [line for line in kernels if "packed_lane_shift" in line]
     assert len(shifts) == 1 and shifts[0] in entry, shifts
-    assert f" = f32[{n},128]{{1,0:" in shifts[0], shifts[0]
+    assert "%packed_lane_shift_fielded" in shifts[0]
+    assert f" = f32[{lanes_3d},128]{{2,1,0:" in shifts[0], shifts[0]
     assert 'op_name="jit(step)/ps.push/' in shifts[0]
-    # its deltas: the flatten loop's result through bitcasts alone
+    # its deltas: what the logic's last fusion wrote, and no op between
     fed = re.search(r"custom-call\(%[\w.\-]+, %([\w.\-]+)\)", shifts[0])[1]
-    feeder = next(
-        line for line in entry.splitlines() if f" %{fed} = " in line)
-    assert f" = f32[17,{n}]{{1,0:" in feeder, feeder
-    if " bitcast(" not in feeder:
-        body = text[text.index(
-            "%" + re.search(r"calls=%([\w.\-]+)", feeder)[1] + " ("):]
-        body = body[:body.index("\n}")].splitlines()[1:]
-        assert all(
-            " parameter(" in op or " bitcast(" in op for op in body), body
+    while _is_a_view(ops[fed]):
+        fed = re.search(r"\(%([\w.\-]+)", ops[fed])[1]
+    feeder = ops[fed]
+    assert f" = f32[17,{lanes_3d}]{{2,1,0:" in feeder, feeder
+    assert " fusion(" in feeder and "concatenate" in text[text.index(
+        "%" + re.search(r"calls=%([\w.\-]+)", feeder)[1] + " ("):][:4000]
+    fed_by = re.findall(r"%([\w.\-]+)[,)]", feeder.split(" fusion(")[1])
+    assert all("ps.compute" in ops[name] for name in fed_by if name in ops)
     # its rows: the scatter-add's own operand, no relayout between
     name = re.search(r"%(packed_lane_shift[\w.\-]*) = ", shifts[0])[1]
     users = [line for line in entry.splitlines()
              if re.search(rf"%{re.escape(name)}[,)]", line)]
+    while len(users) == 1 and _is_a_view(users[0]):
+        name = re.match(r"\s*%([\w.\-]+) = ", users[0])[1]
+        users = [line for line in entry.splitlines()
+                 if re.search(rf"%{re.escape(name)}[,)]", line)]
     assert len(users) == 1 and "/ps.push/scatter-add" in users[0], users
     assert f" = f32[{spec.rows_per_shard},128]" in users[0]
     wide = [line for line in entry.splitlines()
@@ -733,13 +848,19 @@ def test_fm_step_on_a_tpu_slices_its_pulled_rows_in_the_kernel(
         line for line in entry.splitlines()
         if "tpu_custom_call" in line and "packed_lane_slice" in line
     ]
-    assert len(calls) == 1, calls
-    assert f" = f32[17,{n}]{{1,0:" in calls[0], calls[0]
+    assert len(calls) == 1 and "%packed_lane_slice_turned" in calls[0], calls
+    assert f" = f32[17,{lanes_3d}]{{2,1,0:" in calls[0], calls[0]
     assert 'op_name="jit(step)/ps.pull/' in calls[0]
     # the gathered rows come to it as the gather's fusion leaves them
     assert re.search(rf"custom-call\(%[\w.\-]+, %fusion(\.\d+)?\)", calls[0])
+    # and its result reaches the logic through bitcasts alone
+    readers = _reaches_the_logic_through_bitcasts(text, calls[0], 17)
+    if cell == "cell_2":
+        assert all("ps.compute" in line for line in readers), readers
     assert f"f32[{n},17]{{1,0" not in text  # row-major, anywhere
     assert not re.findall(rf" = f32\[{n},17\][^ ]* copy\(", text)
+    assert not re.search(
+        rf"f32\[(17,{FM_BATCH},{FM_FIELDS}|{FM_BATCH},{FM_FIELDS},\d+)\]", text)
     collectives = [
         line for line in text.splitlines() if COLLECTIVE_OP.search(line)
     ]
@@ -747,7 +868,7 @@ def test_fm_step_on_a_tpu_slices_its_pulled_rows_in_the_kernel(
         assert not collectives
     else:
         assert len(collectives) == 1, collectives
-        assert f"%all-reduce = f32[{FM_BATCH},{FM_FIELDS},17]" in collectives[0]
+        assert f"%all-reduce = f32[{FM_FIELDS},{FM_BATCH},17]" in collectives[0]
         assert 'op_name="jit(step)/ps.pull/' in collectives[0]
 
 
@@ -1069,8 +1190,8 @@ def _step_text_sha(step, *args):
 
 @pytest.mark.parametrize("cell, want", [
     ("mf_cells_1_and_3", "467449ddc73eac39"),
-    ("fm_cell_2", "bc06381bf02bcde5"),
-    ("fm_ps4_cell_4", "db02bf3de22f8a3e"),
+    ("fm_cell_2", "1a01b65060f0886d"),
+    ("fm_ps4_cell_4", "d240e3e5008700fb"),
     ("lr_cell_6", "f669bf2e1dddf416"),
     ("keyed_mf_cell_8", "47d256f2590f4bc0"),
 ])
@@ -1095,7 +1216,16 @@ def test_the_mf_and_fm_cells_step_text_is_the_parents(cell, want, request):
     to the sentinel in ``_push_rule``; its delta reaches no kept row): cell
     6's text lost the mask's ``reshape`` to ``(1277952, 1)``, a zero
     ``broadcast_in_dim`` and the ``_where`` over ``f32[1277952,3]``, nothing
-    else (``aab60546ac40ed64`` until then); the four add stores kept theirs."""
+    else (``aab60546ac40ed64`` until then); the four add stores kept theirs.
+    PR 63 moved the two FM steps and meant to: the copy of their logic that
+    a step in one place traces computes field-major, takes its rows turned
+    (off the TPU, as here, the pull's answer with its axes swapped) and
+    pushes ``(K, B)`` lanes (``models/factorization_machine.FieldLanes``;
+    ``bc06381bf02bcde5`` and ``db02bf3de22f8a3e`` until then); the other
+    three did not move, nor did
+    the step of any cell that runs neither FM logic (every cell's lowered
+    step at full size for a described v5e, hashed on both trees: PERF.md
+    section 6, PR 63)."""
     shape = jax.ShapeDtypeStruct
 
     def mf_batch(n, on=shape):
@@ -1451,8 +1581,12 @@ def test_difacto_step_holds_nothing_table_sized_beside_its_table(
     transposed, with every scope the cell's metrics read, and NO gather or
     scatter of 36-lane rows of the table anywhere.  As the chip runs it
     (asked for the backend: ``kernels``): under ``ps.pull`` ONE gather of
-    whole physical rows (``slice_sizes={1,128}``) and ONE ``packed_lane_slice``
-    call that hands the logic ``f32[20,1277952]``, the WORKER'S PART of the
+    whole physical rows (``slice_sizes={1,128}``) and ONE
+    ``packed_lane_slice_turned`` call (PR 63: the logic takes its rows TURNED,
+    ``models/factorization_machine.FieldLanes``; ``packed_lane_slice`` and
+    ``f32[20,1277952]`` until then) that hands the logic
+    ``f32[20,39,32768]``, its own layout of ``(K, B, 20)``, through bitcasts
+    alone: the WORKER'S PART of the
     rows (``StoreSpec.worker_width``, PR 59: the 36-lane rows of ``S`` never
     leave the rule's loop; the logic's gradient rows are ``f32[1277952,20]``
     and no array of the step outside that loop is 36 lanes wide); under ``ps.push/
@@ -1486,6 +1620,10 @@ def test_difacto_step_holds_nothing_table_sized_beside_its_table(
             "packed_kernel", "rule", "", "row_kernel", "row_set", False)
         assert store_mod.arms(spec, pull_lanes=n) == kernels
         assert store_mod.arms(spec) == kernels  # the import is preloaded
+        # (the step's logic declares its block: the slice a field at a time)
+        assert store_mod.arms(
+            spec, pull_lanes=n, fields=FM_FIELDS
+        ).pull == "packed_kernel_by_field"
         assert row_update.refusal_count() == n0
     compiled = jax.jit(
         make_train_step(dfm.DiFacto(model, rule), spec), donate_argnums=(0, 1)
@@ -1525,11 +1663,13 @@ def test_difacto_step_holds_nothing_table_sized_beside_its_table(
     assert 1.2 * GB < mem.temp_size_in_bytes < 1.5 * GB  # 1.327 here
     assert not scatters
     names = sorted(k.strip().split(" ", 1)[0].rstrip(".0123456789") for k in kernels)
-    assert names == ["%packed_lane_slice", "%sorted_row_set", "%sorted_run_sums"]
+    assert names == [
+        "%packed_lane_slice_turned", "%sorted_row_set", "%sorted_run_sums"]
     by_name = {k.strip().split(".", 1)[0]: k for k in kernels}
-    slice_call = by_name["%packed_lane_slice"]
-    assert f" = f32[20,{n}]{{1,0:" in slice_call
+    slice_call = by_name["%packed_lane_slice_turned"]
+    assert f" = f32[20,{FM_FIELDS},{FM_BATCH}]{{2,1,0:" in slice_call
     assert 'op_name="jit(step)/ps.pull/' in slice_call
+    _reaches_the_logic_through_bitcasts(text, slice_call, 20)
     # the worker's part alone crosses: outside the rule's loop (its chunk
     # of 32,768 whole rows) nothing is 36 lanes wide
     assert not re.search(rf"f32\[(36,{n}|{n},36|36,{FM_BATCH},{FM_FIELDS}"
@@ -1546,8 +1686,9 @@ def test_difacto_step_holds_nothing_table_sized_beside_its_table(
     assert not re.search(rf"f32\[{n},20\]\S* slice\(", text)
     # the dense plan sorts nothing: the batch's two sorts are `_wide_runs`'
     assert not re.search(r"s32\[384,256\]\S* sort\(", text)
-    # the rule's loop and the stretches' (the flattens' two are cell 2's)
-    assert len(re.findall(r" while\(", text)) == 4
+    # the rule's loop and the stretches': no flatten is a loop since PR 63
+    assert len(re.findall(r" while\(", text)) == 2
+    _no_block_of_the_batch_has_its_fields_minor(text)
     # both end with what is live: the rule's with the last distinct row,
     # since PR 54 the stretches' with the last live lane.  A `fori_loop` of
     # a constant trip count is traced as a `scan`; the combine's is a `while`
@@ -1574,10 +1715,12 @@ def test_difacto_step_on_four_chips_runs_its_rule_on_the_shard_that_owns_the_row
     under ``ps.combine``, ``sorted_row_set`` in the rule's loop; off the TPU
     XLA's scatter-add and row ``set`` ON THE BLOCK, nothing partitioned by
     GSPMD), 1.33 GB of temporaries a chip, and the step's only collectives
-    are the pull's all-reduce of ``f32[32768,39,20]``, the worker's part of
-    the rows (PR 59: 102 MB a step where whole rows were 184), and the 96
-    bytes of the push's counts: no row of the table and no key crosses
-    chips."""
+    are the pull's all-reduce of ``f32[39,32768,20]``, the worker's part of
+    the rows (PR 59: 20 lanes of a row's 36) TURNED, the batch its minor
+    axis (PR 63: 105 MB as tiled where ``f32[32768,39,20]``, 39 fields padded
+    to 128 lanes, was 336), and the 96 bytes of the push's counts: no row of
+    the table and no key crosses chips.  The rule's loop and the stretches'
+    are the step's two ``while``: no flatten is a loop since PR 63."""
     from flink_parameter_server_tpu.parallel.mesh import make_mesh
 
     cfg, rule, model, fam, dfm = difacto
@@ -1594,6 +1737,9 @@ def test_difacto_step_on_four_chips_runs_its_rule_on_the_shard_that_owns_the_row
         n0 = row_update.refusal_count()
         assert store_mod.arms(spec) == store_mod.Arms(
             "packed_kernel", "rule", "", "row_kernel", "row_set", True)
+        assert store_mod.arms(
+            spec, pull_lanes=FM_BATCH * FM_FIELDS, fields=FM_FIELDS
+        ).pull == "packed_kernel_by_field"
         assert row_update.refusal_count() == n0
     everywhere = NamedSharding(mesh, PartitionSpec())
     compiled = jax.jit(
@@ -1614,7 +1760,8 @@ def test_difacto_step_on_four_chips_runs_its_rule_on_the_shard_that_owns_the_row
         r"(-start)?\(", c)]
     shapes = sorted(c.strip().split(" = ", 1)[1].split("{")[0] for c in collectives)
     # (six counts a shard since PR 54's `ps_combine_kernel_writes`)
-    assert shapes == ["f32[32768,39,20]", "s32[24]"], collectives
+    assert shapes == ["f32[39,32768,20]", "s32[24]"], collectives
+    assert len(re.findall(r" while\(", text)) == 2
     for scope in ("ps.pull", "ps.push/shard_map/ps.combine",
                   "ps.push/shard_map/while/body/ps.rule"):
         assert scope in text, scope
@@ -1628,7 +1775,10 @@ def test_difacto_step_on_four_chips_runs_its_rule_on_the_shard_that_owns_the_row
         return
     assert not scatters
     names = sorted(k.strip().split(" ", 1)[0].rstrip(".0123456789") for k in kernels)
-    assert names == ["%packed_lane_slice", "%sorted_row_set", "%sorted_run_sums"]
+    assert names == [
+        "%packed_lane_slice_turned", "%sorted_row_set", "%sorted_run_sums"]
+    # (the selects' arm turns what it sliced: `f32[20,32768,39]` is there)
+    _no_block_of_the_batch_has_its_fields_minor(text)
     by_name = {k.strip().split(".", 1)[0]: k for k in kernels}
     assert " f32[15647288,128]{1,0" in by_name["%sorted_row_set"]
     assert "ps.push/shard_map/while/body" in by_name["%sorted_row_set"]

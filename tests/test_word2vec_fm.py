@@ -152,6 +152,16 @@ def test_fm_squared_loss_gradient_check():
     want = -jax.grad(objective)(pulled)  # lr = 1, delta = -grad
     _, req, _ = logic.step((), batch, pulled)
     np.testing.assert_allclose(np.asarray(req.deltas), np.asarray(want), rtol=2e-4, atol=2e-5)
+    # the copy a step in one place traces takes its rows turned, fields
+    # leading, (K, B, d), and pushes so: the same numbers
+    turned = logic.for_workers(1)
+    assert turned.pulls_turned and not logic.pulls_turned
+    assert turned.keys(batch).shape == (2, 4) and logic.for_workers(2) is logic
+    _, req, out = turned.step((), batch, jnp.swapaxes(pulled, 0, 1))
+    assert out["prediction"].shape == out["loss"].shape == (2,)
+    np.testing.assert_allclose(
+        np.swapaxes(np.asarray(req.deltas), 0, 1), np.asarray(want),
+        rtol=2e-4, atol=2e-5)
 
 
 def test_sgns_dedup_scale_stabilizes_high_lr():
