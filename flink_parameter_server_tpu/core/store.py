@@ -1046,6 +1046,8 @@ def arms(
     5 regs, the worker's 3 / 602      packed_selects          sort         tile_assign  no         none  59
     1 reg, the worker's 100 / 101     packed_selects          row_kernel   row_set      no         14    61 62
     1 reg, 100 / 101 over ps 4        packed_selects          row_kernel   row_set      yes        none  61
+    2 regs, the worker's 128 / 256    packed_selects          row_kernel   tile_assign  no         15    64
+    2 regs, 128 / 256 over ps 4       packed_selects          row_kernel   tile_assign  yes        none  64
     ================================  ======================  ===========  ===========  =========  ====  ========
 
     Reasons the code does not show.  A mesh keeps an add push XLA's because

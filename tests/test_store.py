@@ -2430,6 +2430,15 @@ ARMS_ON_A_TPU = [
     ("1 reg, 100 / 101 over ps 4", (101,), _RULE, "auto", (1, 4), 1_000,
      8_192, 8_192, False,
      ("packed_selects", "rule", "", "row_kernel", "row_set", True), 0),
+    # a wide rule row whose worker's part is ONE WHOLE register (cell 15's
+    # (w[128], G[128])): the combine is the row kernel's, the write-back the
+    # tile kernel's, in one push
+    ("2 regs, the worker's 128 / 256", (256,), _RULE, "auto", None, 1_000,
+     8_192, 8_192, False,
+     ("packed_selects", "rule", "", "row_kernel", "tile_assign", False), 0),
+    ("2 regs, 128 / 256 over ps 4", (256,), _RULE, "auto", (1, 4), 1_000,
+     8_192, 8_192, False,
+     ("packed_selects", "rule", "", "row_kernel", "tile_assign", True), 0),
 ]
 WORKER_WIDTHS = {
     "packed k 3, the worker's 20 / 36": 20, "the worker's 20 / 36 over ps 4": 20,
@@ -2437,6 +2446,7 @@ WORKER_WIDTHS = {
     "5 regs, the worker's 3 / 602": 3,
     "1 reg, the worker's 100 / 101": 100, "1 reg, 100 / 101 over ps 4": 100,
     "the worker's 20 / 36, fields 39": 20, "20 / 36, fields 39, over ps 4": 20,
+    "2 regs, the worker's 128 / 256": 128, "2 regs, 128 / 256 over ps 4": 128,
 }
 # the keys an example of a block of two axes that its logic takes TURNED
 FIELDS = {
@@ -2465,6 +2475,8 @@ ARMS_OFF_IT = {
     "5 regs, the worker's 3 / 602": (
         "packed_selects", "rule", "", "sort", "xla_set", False),
     "1 reg, the worker's 100 / 101": (
+        "packed_selects", "rule", "", "scatter_add", "xla_set", False),
+    "2 regs, the worker's 128 / 256": (
         "packed_selects", "rule", "", "scatter_add", "xla_set", False),
 }
 

@@ -2249,3 +2249,109 @@ def test_kge_step_with_its_row_left_dense_copies_the_whole_table(
     text = compiled.as_text()
     assert len(re.findall(r"f32\[15152096,101\]\S* copy\(", text)) >= 2
     assert re.search(r"f32\[15152096,101\]\{0,1", text)
+
+
+# dlrm-dcnv2-mlperf-s32 (chipbench/configs): cell 15's tables and batch
+DCN_SIZES = (
+    40000000, 39060, 17295, 7424, 20265, 3, 7122, 1543, 63, 40000000, 3067956,
+    405282, 10, 2209, 11938, 155, 4, 976, 14, 40000000, 40000000, 40000000,
+    590152, 12973, 108, 36)
+DCN_BAGS = (3, 2, 1, 2, 6, 1, 1, 1, 1, 7, 3, 8, 1, 6, 9, 5, 1, 1, 1, 12, 100,
+            27, 10, 3, 1, 1)
+DCN_ROWS, DCN_PHYS_ROWS, DCN_BATCH, DCN_KEYS = 6_380_781, 6_380_784, 2_048, 438_272
+
+
+@pytest.fixture(scope="module")
+def dcn():
+    from flink_parameter_server_tpu.models import dlrm_dcnv2 as dc
+
+    model = dc.DCNv2Config(
+        tuple(-(-n // 32) for n in DCN_SIZES), DCN_BAGS, DCN_SIZES)
+    assert model.num_rows == DCN_ROWS and DCN_BATCH * model.lookups == DCN_KEYS
+    return model, dc
+
+
+def test_dcn_table_is_initialised_in_place_from_a_seed_argument(
+        dcn, one_chip, no_compile_cache):
+    """6,380,781 x 256 f32 rule rows under a ``jit`` that takes the seed: the
+    6.53 GB table ``f32[6380784,256]`` (a row flat in two whole registers,
+    its weights and then their zeroed accumulators) is the program's only
+    output, initialised a block of rows a loop step beside 0.14 GB of
+    temporaries: no second array holds the rows' optimiser state."""
+    model, dc = dcn
+    compiled = jax.jit(lambda s: dc.make_store(model, seed=s).table).lower(
+        _shape(one_chip, (), jnp.uint32)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes == DCN_PHYS_ROWS * 256 * 4 == 6_533_922_816
+    assert mem.temp_size_in_bytes < 0.2 * GB
+    assert len(re.findall(r" while\(", compiled.as_text())) >= 1
+
+
+def test_dcn_step_fits_beside_its_two_register_table(
+        dcn, one_chip, no_compile_cache, monkeypatch):
+    """Cell 15's step at full size for a described v5e, as ``"auto"`` lays a
+    rule row of 256 lanes: PACKED at ``k`` = 1, flat in two whole registers,
+    ``f32[6380784,256]{1,0}``.  The donated 6.53 GB table (and the dense
+    net's 128 MB of leaves and accumulators) is rewritten in place and never
+    copied or transposed; beside it 0.70 GB of temporaries.  Under
+    ``ps.pull`` ONE gather of whole rows ``f32[438272,256]`` cut to the
+    worker's 128 lanes (the accumulators' half is gathered and dropped:
+    PERF.md section 7); under ``ps.push/ps.combine`` the row kernel's sums of
+    the batch's gradient rows at 128 lanes (``sorted_run_sums``); in the
+    rule's loop ONE gather ``f32[32768,256]`` under ``ps.rule`` and ONE
+    ``sorted_row_assign_tiles`` call on the table, the write-back; no XLA
+    scatter touches the table.  The dense net's products are under the
+    logic's scopes, the written-out backward pass too."""
+    model, dc = dcn
+    spec = jax.eval_shape(lambda: dc.make_store(model)).spec
+    assert spec.layout == "packed" and spec.pack == 1
+    assert spec.table_shape() == (DCN_PHYS_ROWS, 256) and spec.worker_width == 128
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    n0 = row_update.refusal_count()
+    arm = store_mod.arms(spec, pull_lanes=DCN_KEYS, push_lanes=DCN_KEYS)
+    assert row_update.refusal_count() == n0
+    assert arm == store_mod.Arms(
+        "packed_selects", "rule", "", "row_kernel", "tile_assign", False)
+    logic = dc.DLRMDCNv2(model)
+    state = jax.tree.map(
+        lambda x: _shape(one_chip, x.shape, x.dtype),
+        jax.eval_shape(lambda: logic.init_state(jax.random.PRNGKey(0))))
+    assert sum(x.size for x in jax.tree.leaves(state)) == 2 * 16_044_545
+    batch = {
+        "dense": _shape(one_chip, (DCN_BATCH, 13), jnp.float32),
+        "ids": _shape(one_chip, (DCN_BATCH, 214), jnp.int32),
+        "label": _shape(one_chip, (DCN_BATCH,), jnp.float32),
+        "mask": _shape(one_chip, (DCN_BATCH,), jnp.bool_),
+    }
+    compiled = jax.jit(
+        make_train_step(logic, spec), donate_argnums=(0, 1)
+    ).lower(_shape(one_chip, spec.table_shape(), jnp.float32), state, batch
+            ).compile()
+    mem = compiled.memory_analysis()
+    assert 6.65 * GB < mem.alias_size_in_bytes < 6.68 * GB  # in place
+    assert mem.temp_size_in_bytes < 0.9 * GB
+    text = compiled.as_text()
+    assert not re.search(r"f32\[6380784,256\]\S* (copy|transpose)\(", text)
+    assert "f32[6380781," not in text
+    for scope in ("ps.pull", "ps.compute/ps.bag_pool/",
+                  "ps.compute/ps.dense_bottom/", "ps.compute/ps.dense_interact/",
+                  "ps.compute/ps.dense_top/", "ps.compute/ps.bag_grad_spread/",
+                  "ps.compute/ps.dense_adagrad/", "ps.push/ps.combine",
+                  "ps.push/while/body/ps.rule"):
+        assert scope in text, scope
+    assert "transpose(jvp(" not in text
+    lines = text.splitlines()
+    pulls = [c for c in lines if re.search(r" gather\(", c)
+             and " f32[438272,256]{1,0" in c and "ps.pull" in c]
+    assert len(pulls) == 1 and "slice_sizes={1,256}" in pulls[0], pulls
+    reads = [c for c in lines if re.search(r" gather\(", c)
+             and " f32[32768,256]{1,0" in c]
+    assert len(reads) == 1 and "ps.push/while/body/ps.rule" in reads[0], reads
+    kernels = [line for line in lines if "tpu_custom_call" in line]
+    names = sorted(k.strip().split(" ", 1)[0].rstrip(".0123456789") for k in kernels)
+    assert names == ["%sorted_row_assign_tiles", "%sorted_run_sums"], names
+    by_name = {k.strip().split(".", 1)[0]: k for k in kernels}
+    assert " f32[6380784,256]{1,0" in by_name["%sorted_row_assign_tiles"]
+    assert "ps.push/while/body" in by_name["%sorted_row_assign_tiles"]
+    assert "ps.push/ps.combine/while/body" in by_name["%sorted_run_sums"]
+    assert not [line for line in lines if re.search(r" scatter\(", line)]
