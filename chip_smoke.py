@@ -819,33 +819,36 @@ class Smoke:
                 jnp.where(mask_p[:, None], deltas_p, 0), ids_p, 17), 0.0),
         )
 
-        # both kernels a FIELD at a time (the FM family's step since PR 63):
-        # a key block of (B, 39) gathered example-major and sliced TURNED,
-        # (39, B, 17); deltas read (17, 39, B); against the flat arms' bits
-        fields_p, batch_p = 39, 2 * packed.TURN_BLOCK
-        rows_f = normal((batch_p * fields_p, 128))
-        ids_f = jnp.asarray(
-            rng.integers(0, 10 ** 6, (batch_p, fields_p)), jnp.int32)
-        self._kernel_case(
-            "packed_lane_slice_turned_d17_f32",
-            lambda r, i: packed.turned_slice_kernel(
-                r, i, 17, interpret=interpret),
-            (rows_f, ids_f),
-            close(jnp.swapaxes(packed._sub_row_slice(
-                rows_f, ids_f.reshape(-1), 17
-            ).reshape(batch_p, fields_p, 17), 0, 1), 0.0),
-        )
-        deltas_f = normal((fields_p, batch_p, 17))
-        mask_f = jnp.asarray(rng.random((fields_p, batch_p)) < 0.9)
-        self._kernel_case(
-            "packed_lane_shift_fielded_d17_f32",
-            lambda dl, i, m: packed.lane_shift_kernel(
-                jnp.moveaxis(dl, -1, 0), i, 17, m, interpret=interpret),
-            (deltas_f, ids_f.T, mask_f),
-            close(packed.lane_shift_deltas(
-                jnp.where(mask_f[..., None], deltas_f, 0).reshape(-1, 17),
-                ids_f.T.reshape(-1), 17), 0.0),
-        )
+        # both kernels a FIELD at a time (the FM family's step since PR 63,
+        # DLRM's since PR 65): a key block of (B, K) gathered example-major
+        # and sliced TURNED, (K, B, d); deltas read (d, K, B); against the
+        # flat arms' bits.  39 fields of 17 lanes, seven rows to a physical
+        # row, and 26 of 64, two to one (8 trips of 3 fields and 2 odd ones)
+        for fields_p, d_p in ((39, 17), (26, 64)):
+            batch_p = 2 * packed.TURN_BLOCK
+            rows_f = normal((batch_p * fields_p, 128))
+            ids_f = jnp.asarray(
+                rng.integers(0, 10 ** 6, (batch_p, fields_p)), jnp.int32)
+            self._kernel_case(
+                f"packed_lane_slice_turned_d{d_p}_f32",
+                lambda r, i, d_p=d_p: packed.turned_slice_kernel(
+                    r, i, d_p, interpret=interpret),
+                (rows_f, ids_f),
+                close(jnp.swapaxes(packed._sub_row_slice(
+                    rows_f, ids_f.reshape(-1), d_p
+                ).reshape(batch_p, fields_p, d_p), 0, 1), 0.0),
+            )
+            deltas_f = normal((fields_p, batch_p, d_p))
+            mask_f = jnp.asarray(rng.random((fields_p, batch_p)) < 0.9)
+            self._kernel_case(
+                f"packed_lane_shift_fielded_d{d_p}_f32",
+                lambda dl, i, m, d_p=d_p: packed.lane_shift_kernel(
+                    jnp.moveaxis(dl, -1, 0), i, d_p, m, interpret=interpret),
+                (deltas_f, ids_f.T, mask_f),
+                close(packed.lane_shift_deltas(
+                    jnp.where(mask_f[..., None], deltas_f, 0).reshape(-1, d_p),
+                    ids_f.T.reshape(-1), d_p), 0.0),
+            )
 
         # a PACKED rule store at cell 9's row (36 lanes, three to a physical
         # row) inside one jitted step, pull and push: the lane slice, the

@@ -1016,7 +1016,8 @@ def arms(
     packed k 7, fields 39              packed_kernel_by_field  xla_add        kernel_by_field  2       63
     fields 39 over ps 4, dp 1          packed_kernel_by_field  xla_add        kernel_by_field  4       63
     fields 39, the batch no blocks     packed_kernel           xla_add        kernel           none    63
-    packed k 2, 1,024+ <= rows / 8     packed_kernel           tile_add       kernel           10      49 51
+    packed k 2, 1,024+ <= rows / 8     packed_kernel           tile_add       kernel           none    49 51
+    k 2, fields 26, 1,024+ <= rows/8   packed_kernel_by_field  tile_add       kernel_by_field  10      65
     packed k 7, under a block of ids   packed_selects          xla_add        selects          none    42
     packed k 1, 5 regs (3: cell 7)     packed_selects          tile_add       selects          5 7     32 33 57
     5 regs under a mesh                take                    xla_add        -                none    33
@@ -1192,7 +1193,11 @@ def step_counts(
     (``ps_slice_kernel``) and, where ``update`` is ``"add"``, which shifted
     the pushed deltas (``ps_shift_kernel``), 1 for the kernel in either of
     its forms, as this trace read them (:func:`arms`, told the step's
-    ``fields``).  A store whose spec names a worker's
+    ``fields``); and ``ps_lanes_by_field``, 1 where every lane kernel the
+    step has (the pull's and, for an ``add`` store, the shift's) moves the
+    batch a FIELD at a time, the logic's buffers batch-minor at the other
+    end (``packed_kernel_by_field`` / ``kernel_by_field``), else 0.  A store
+    whose spec names a worker's
     part (``StoreSpec.worker_width``) says how many lanes of a row crossed,
     a key: ``ps_pull_row_lanes`` here (a step pulls the worker's part) beside
     :func:`push_counted`'s ``ps_push_row_lanes``; every other store's whole
@@ -1208,6 +1213,9 @@ def step_counts(
         if arm.shift:
             out["ps_shift_kernel"] = jnp.asarray(
                 arm.shift.startswith("kernel"), jnp.int32)
+        out["ps_lanes_by_field"] = jnp.asarray(
+            arm.pull.endswith("_by_field")
+            and arm.shift in ("", "kernel_by_field"), jnp.int32)
     return out
 
 
@@ -1228,6 +1236,10 @@ def publish_counts(outs: dict, registry: Any, total, peak) -> None:
         registry.gauge(
             "store_packed_shift_kernel", component="train"
         ).set(peak(outs["ps_shift_kernel"]))
+    if "ps_lanes_by_field" in outs:
+        registry.gauge(
+            "store_packed_by_field", component="train"
+        ).set(peak(outs["ps_lanes_by_field"]))
     if "ps_push_tile_rows" in outs:
         registry.gauge(
             "store_push_kernel_lanes", component="train"

@@ -27,6 +27,37 @@ MLPs lie replicated and the batch split (``core/transform.make_train_step``
 constrains it); the reduction of the dense gradients over the workers is the
 partitioner's, nothing here names it.
 
+The order of the step's lanes is ``FieldLanes``' (the mixin of the logics
+whose batch is examples of fields, ``models/factorization_machine``): the
+logic is example-major for whoever calls ``step``, ``pulled`` ``(B, F,
+dim)``; the copy a step in one place traces (``for_workers(1)``) takes its
+rows TURNED, ``(F, B, dim)``, and pushes ids, mask and deltas ``(F, B[,
+dim])``, the batch the minor axis from the pull's lane kernel to the push's.
+On a TPU the two lane kernels of a packed store then hand over and take XLA's
+own layout of those blocks (``f32[dim, F, B]``; ``core/store.arms``'
+``fields``), and nothing stands between them and the interaction's ``T``
+and ``dT`` but the ONE transposing copy each way that XLA's batched products
+ask for: the axis swap behind the pull is a bitcast, the one in front of the
+push that copy.  Example-major, the flat kernels' ``f32[dim, B F]`` reached ``(B,
+F, dim)`` and left it through a flatten and a copy each way, four passes
+that only turned rows round (4.97 ms of cell 10's 50.8: PERF.md section 6,
+PR 65).  The interaction itself is written ONCE, on ``(B, F + 1, dim)``:
+which axis of ``T`` and ``Z`` is minor on the chip is XLA's to assign, and
+it assigns the same whichever way the products are written.  Every field
+owns its rows, so a row's deltas are summed in the order of the examples
+either way.
+
+The triangle of ``Z`` is taken by a static gather and PUT BACK by a product
+with a constant 0/1 matrix: ``dZ + dZ^t = d_pairs @ both``
+(:func:`pair_tables`; 0.68 ms a step at cell 10's size).  A scatter
+of the 351 pairs into a zeroed ``dZ`` compiles on the TPU to a loop of 351
+column updates (2.28 ms a step); a static GATHER of them (``d_pairs[:,
+sym]``), the same bits, compiles and HANGS the v5e in one program with
+``ops/row_update``'s tile kernel (PERF.md section 6, PR 65: every form ran
+on the chip).  A non-finite pair gradient spreads over its
+example's ``dZ`` (0 x inf), where the scatter kept it to two entries; such a
+step has lost its MLPs already.
+
 The matmuls run at ``Precision.HIGHEST``: the model's arithmetic is float32,
 and the TPU's default (one bfloat16 pass) is 4e-3 of every product.
 """
@@ -43,6 +74,7 @@ from ..core.batched import BatchedWorkerLogic, PushRequest
 from ..core.store import InitFn, ShardedParamStore
 from ..training.tracing import scope
 from ..utils.initializers import ranged_random_factor
+from .factorization_machine import FieldLanes
 
 Array = jax.Array
 
@@ -134,11 +166,30 @@ def _mlp_backward(state, name: str, acts, d: Array, last_relu: bool):
     return grads, d
 
 
-class DLRM(BatchedWorkerLogic):
+def pair_tables(vectors: int):
+    """``(lower_i, lower_j, both)`` for the pairs of ``vectors`` vectors: the
+    entries of ``T T^t`` below its diagonal, row by row (the source's ``li``,
+    ``lj``), pair ``p`` at ``(lower_i[p], lower_j[p])``; and ``both``
+    ``(pairs, vectors * vectors)`` float32, row ``p`` a one at ``(i, j)`` AND
+    at ``(j, i)`` of the flattened square and zeros elsewhere, the diagonal's
+    columns all zeros: ``d_pairs @ both`` is ``dZ + dZ^t`` of the ``dZ`` that
+    holds ``d_pairs`` below its diagonal and zeros elsewhere, every element
+    ONE product by one (exact at ``Precision.HIGHEST``: the three bfloat16
+    pieces of a float32 add up to it) beside products by zero."""
+    lower_i, lower_j = np.tril_indices(vectors, -1)
+    pair = np.arange(lower_i.size)
+    both = np.zeros((lower_i.size, vectors, vectors), np.float32)
+    both[pair, lower_i, lower_j] = both[pair, lower_j, lower_i] = 1.0
+    return lower_i, lower_j, both.reshape(lower_i.size, -1)
+
+
+class DLRM(FieldLanes, BatchedWorkerLogic):
     """Batch: ``dense`` (B, dense_features) float, ``ids`` (B, fields) int,
     the row of each categorical field in the ONE store (its field's first row
     added), ``label`` (B,) positive for a click, ``mask`` (B,) bool.
-    ``pulled`` is ``(B, fields, dim)``.  The state is a dict of float32
+    ``pulled``, the push's ids, deltas and mask are as :class:`FieldLanes`
+    has them: ``(B, fields[, dim])``, and ``(fields, B[, dim])`` in the copy
+    a step in one place traces.  The state is a dict of float32
     arrays, ``{bot|top}{layer}_{w|b}``, ``w`` as ``(inputs, outputs)``.
     Beside ``prediction`` and ``loss`` the outputs carry two constants of
     the logic for whoever reads outputs: ``dlrm_dense_params`` and
@@ -173,14 +224,19 @@ class DLRM(BatchedWorkerLogic):
         lr = cfg.learning_rate
         live = batch["mask"]
         x = batch["dense"].astype(jnp.float32)
-        # the entries below the diagonal, row by row: the source's li, lj
-        lower_i, lower_j = np.tril_indices(cfg.fields + 1, -1)
+        lower_i, lower_j, both = pair_tables(cfg.fields + 1)
         n_bot, n_top = len(cfg.bottom_mlp), len(cfg.top_mlp)
+
+        def by_example(rows):
+            # the rows of the copy that `pulls_turned`, `(fields, B, dim)`,
+            # as `(B, fields, dim)` and back: no pass on the TPU, where the
+            # batch stays the minor axis of the buffer
+            return jnp.swapaxes(rows, 0, 1) if self.field_major else rows
 
         with scope("ps.dense_bottom"):
             bot = _mlp_forward(state, "bot", x, n_bot, True)
         with scope("ps.dense_interact"):
-            t = jnp.concatenate([bot[-1][:, None, :], pulled], axis=1)
+            t = jnp.concatenate([bot[-1][:, None, :], by_example(pulled)], axis=1)
             z = jnp.einsum("bid,bjd->bij", t, t, precision=_PRECISION)
             r = jnp.concatenate([bot[-1], z[:, lower_i, lower_j]], axis=1)
         with scope("ps.dense_top"):
@@ -198,22 +254,25 @@ class DLRM(BatchedWorkerLogic):
                 state, "top", top, d_logit[:, None], False
             )
         with scope("ps.dense_interact"):
-            d_z = jnp.zeros_like(z).at[:, lower_i, lower_j].set(
-                d_r[:, cfg.dim:]
-            )
-            d_t = jnp.einsum(
-                "bij,bjd->bid", d_z + d_z.swapaxes(1, 2), t,
-                precision=_PRECISION,
-            )
+            # dZ + dZ^t as ONE product with a 0/1 matrix: pair (i, j)'s
+            # gradient at (i, j) and at (j, i), zeros on the diagonal
+            d_z = _dot(d_r[:, cfg.dim:], both).reshape(z.shape)
+            # (turned whole, THEN cut: the rows' gradients leave by one slice
+            # of the leading axis that the delta build's multiply takes in;
+            # cut first, `d_t[:, 1:]` is a pass of its own, 1.6 ms in cell 10)
+            d_t = by_example(
+                jnp.einsum("bij,bjd->bid", d_z, t, precision=_PRECISION))
+            d_z0, d_rows = jnp.split(d_t, [1], axis=self.field_axis)
         with scope("ps.dense_bottom"):
             bot_grads, _ = _mlp_backward(
-                state, "bot", bot, d_r[:, :cfg.dim] + d_t[:, 0], True
+                state, "bot", bot,
+                d_r[:, :cfg.dim] + jnp.squeeze(d_z0, self.field_axis), True
             )
             grads.update(bot_grads)
         with scope("ps.dense_sgd"):
             state = {k: v - lr * grads[k] for k, v in state.items()}
         with scope("ps.delta_build"):
-            deltas = -lr * d_t[:, 1:]
+            deltas = -lr * d_rows
         out = {
             "prediction": jax.nn.sigmoid(logit),
             "loss": jax.nn.softplus(-sign * logit) * live,
@@ -222,8 +281,9 @@ class DLRM(BatchedWorkerLogic):
                 6.0 * cfg.macs_per_example * x.shape[0], jnp.float32
             ),
         }
-        mask = jnp.broadcast_to(live[:, None], batch["ids"].shape)
-        return state, PushRequest(batch["ids"], deltas, mask), out
+        ids = self.lanes(batch["ids"])
+        mask = jnp.broadcast_to(self.by_field(live), ids.shape)
+        return state, PushRequest(ids, deltas, mask), out
 
     def publish_counts(self, outs, registry, total, peak) -> None:
         # constants of the logic, so the newest step's
@@ -267,4 +327,4 @@ def make_store(
     )
 
 
-__all__ = ["DLRM", "DLRMConfig", "make_store", "uniform_rows"]
+__all__ = ["DLRM", "DLRMConfig", "make_store", "pair_tables", "uniform_rows"]

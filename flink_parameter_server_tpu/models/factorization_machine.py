@@ -80,9 +80,10 @@ def forward_gradients(
 
 
 class FieldLanes:
-    """The lane order of the FM family's logics (``FactorizationMachine``,
-    ``models/difacto.DiFacto``), whose batch is ``(B, K)``: ``B`` examples
-    of ``K`` fields.
+    """The lane order of the logics whose batch is ``(B, K)``, ``B`` examples
+    of ``K`` fields: the FM family's (``FactorizationMachine``,
+    ``models/difacto.DiFacto``) and ``models/dlrm.DLRM``, whose fields are
+    its categorical ones and whose interaction sums over them.
 
     The logic itself is EXAMPLE-major, as every ``BatchedWorkerLogic`` is:
     ``keys()`` gives the batch's ``ids`` ``(B, K)``, ``pulled`` is ``(B, K,
@@ -103,7 +104,9 @@ class FieldLanes:
     there, lane-dense, the pull's kernel writes exactly that and the push's
     reads it (``core/store.arms``' ``fields``), where ``(B, K, d)`` costs a
     loop of ``d`` trips through a flat buffer each way (6 ms of cell 2's
-    53: PERF.md section 6, PR 63).  The rows are still GATHERED
+    53: PERF.md section 6, PR 63; at DLRM's 64 lanes two copies and two
+    flattens of the batch's rows, 5 ms of cell 10's 51: PR 65).  The rows
+    are still GATHERED
     example-major, the key block's own order: field-major a field's few
     rows are named 32,768 lanes on end, and the TPU's gather pays 3.5 ms a
     step for that.  Where a row belongs to one field (a key space a field),

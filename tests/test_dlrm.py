@@ -1,8 +1,11 @@
 """``models/dlrm.py``: the step against the plain reference of the benchmark
 (``chipbench/references/dlrm.py``) and against autodiff, on one device and at
-``dp`` = 2; the dict state through the driver, its gauges and a checkpoint;
-and the store a 64-lane add row resolves to (two rows to a physical row)
-against a dense one, bit for bit."""
+``dp`` = 2; the copy a step in one place traces (``FieldLanes``: the batch
+the minor axis) and the triangle put back by a 0/1 product against the
+example-major step with its scattered triangle that PR 65's parent ran, kept
+here, bit for bit; the dict state through the driver, its gauges and a checkpoint; and
+the store a 64-lane add row resolves to (two rows to a physical row) against
+a dense one, bit for bit."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -158,7 +161,8 @@ def test_the_step_is_the_plain_reference(seed):
     assert table.shape == (ids.size, 8) and layers["top0"].shape == (8 + 10 + 1, 24)
 
 
-def test_the_gradients_are_autodiffs():
+@pytest.mark.parametrize("form", ["example_major", "field_major"])
+def test_the_gradients_are_autodiffs(form):
     logic = dlrm.DLRM(CONFIG, seed=1)
     store = dlrm.make_store(CONFIG, seed=1)
     state = logic.init_state(jax.random.PRNGKey(0))
@@ -179,7 +183,17 @@ def test_the_gradients_are_autodiffs():
         return jnp.sum(bce * batch["mask"]) / batch["mask"].sum()
 
     g_state, g_rows = jax.grad(loss, (0, 1))(state, pulled)
-    new, req, out = logic.step(state, batch, pulled)
+    if form == "field_major":
+        # the copy a step in one place traces: rows, ids, deltas and mask
+        # with the fields in front of the batch
+        logic = logic.for_workers(1)
+        assert logic.pulls_turned
+        new, req, out = logic.step(state, batch, jnp.swapaxes(pulled, 0, 1))
+        assert req.deltas.shape == (4, 32, 8)
+        req = type(req)(req.ids.T, jnp.swapaxes(req.deltas, 0, 1), req.mask.T)
+    else:
+        assert not logic.pulls_turned and logic.for_workers(2) is logic
+        new, req, out = logic.step(state, batch, pulled)
     for k in state:
         np.testing.assert_allclose(
             (state[k] - new[k]) / 0.1, g_state[k], rtol=1e-4, atol=2e-6
@@ -190,6 +204,143 @@ def test_the_gradients_are_autodiffs():
     assert float(out["loss"].sum() / 31) == pytest.approx(float(loss(state, pulled)), rel=1e-5)
     assert int(out["dlrm_dense_params"]) == CONFIG.dense_params
     assert float(out["dlrm_dense_flops_per_step"]) == 6.0 * CONFIG.macs_per_example * 32
+
+
+class _ParentsDLRM(dlrm.DLRM):
+    """The logic as PR 65's parent had it: example-major whoever traces it,
+    ``T`` ``(B, F + 1, dim)``, the triangle of ``dZ`` put back by a scatter
+    (``at[...].set``) and ``dZ + dZ^t`` an add.  Kept to hold the logic to
+    its bits."""
+
+    def for_workers(self, workers):
+        return self
+
+    def step(self, state, batch, pulled):
+        from flink_parameter_server_tpu.core.batched import PushRequest
+
+        cfg, p = self.config, dlrm._PRECISION
+        lr, live = cfg.learning_rate, batch["mask"]
+        x = batch["dense"].astype(jnp.float32)
+        lower_i, lower_j = np.tril_indices(cfg.fields + 1, -1)
+        n_bot, n_top = len(cfg.bottom_mlp), len(cfg.top_mlp)
+        bot = dlrm._mlp_forward(state, "bot", x, n_bot, True)
+        t = jnp.concatenate([bot[-1][:, None, :], pulled], axis=1)
+        z = jnp.einsum("bid,bjd->bij", t, t, precision=p)
+        r = jnp.concatenate([bot[-1], z[:, lower_i, lower_j]], axis=1)
+        top = dlrm._mlp_forward(state, "top", r, n_top, False)
+        logit = top[-1][:, 0]
+        sign = jnp.where(batch["label"] > 0, 1.0, -1.0)
+        examples = jnp.maximum(jnp.sum(live, dtype=jnp.float32), 1.0)
+        d_logit = jnp.where(
+            live, -sign / (1.0 + jnp.exp(sign * logit)), 0.0) / examples
+        grads, d_r = dlrm._mlp_backward(
+            state, "top", top, d_logit[:, None], False)
+        d_z = jnp.zeros_like(z).at[:, lower_i, lower_j].set(d_r[:, cfg.dim:])
+        d_t = jnp.einsum(
+            "bij,bjd->bid", d_z + d_z.swapaxes(1, 2), t, precision=p)
+        bot_grads, _ = dlrm._mlp_backward(
+            state, "bot", bot, d_r[:, :cfg.dim] + d_t[:, 0], True)
+        grads.update(bot_grads)
+        state = {k: v - lr * grads[k] for k, v in state.items()}
+        out = {"prediction": jax.nn.sigmoid(logit),
+               "loss": jax.nn.softplus(-sign * logit) * live}
+        mask = jnp.broadcast_to(live[:, None], batch["ids"].shape)
+        return state, PushRequest(batch["ids"], -lr * d_t[:, 1:], mask), out
+
+
+def _bits(x):
+    x = np.asarray(x)
+    return x.view(np.uint32) if x.dtype == np.float32 else x
+
+
+def _same_bits(got, want):
+    """Every leaf of two trees of arrays, bit for bit."""
+    got, want = jax.tree.leaves(got), jax.tree.leaves(want)
+    assert len(got) == len(want)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(_bits(g), _bits(w))
+
+
+@pytest.mark.parametrize("dim", [8, 64])
+def test_the_step_in_one_place_is_the_parents_example_major_step_bit_for_bit(dim):
+    """What ``make_train_step`` traces in one place is the copy that
+    ``pulls_turned`` and keeps the batch the minor axis; three steps of it
+    leave the table, the MLPs and the outputs that the parent's example-major
+    step leaves, masked examples included (every field owns its rows, so a
+    row's deltas are summed in the order of the examples either way)."""
+    config = dlrm.DLRMConfig(CARDS, dense_features=5, dim=dim,
+                             bottom_mlp=(16, dim), top_mlp=(24, 12, 1))
+    logic, parents = dlrm.DLRM(config, seed=1), _ParentsDLRM(config, seed=1)
+    traced = logic.for_workers(1)
+    assert traced is not logic and traced.pulls_turned and traced.field_major
+    assert traced.for_workers(1) is traced and not logic.pulls_turned
+    store = dlrm.make_store(config, seed=1)
+    state = logic.init_state(jax.random.PRNGKey(0))
+    got = want = (store.table, state)
+    step = jax.jit(make_train_step(logic, store.spec))
+    parents_step = jax.jit(make_train_step(parents, store.spec))
+    for batch in _batches(12, 3, masked=(5, 9)):
+        *got, out = step(*got, batch)
+        *want, parents_out = parents_step(*want, batch)
+        _same_bits(got, want)
+        _same_bits([out[k] for k in ("prediction", "loss")],
+                   [parents_out[k] for k in ("prediction", "loss")])
+    assert not np.array_equal(np.asarray(got[0]), np.asarray(store.table))
+
+
+def test_example_major_stays_the_logics_own_form_for_every_other_caller(devices):
+    """A caller that hands ``step`` example-major ``pulled`` (rows it pulled
+    itself: ``cluster/driver.ClusterDriver``) gets the parent's request and
+    state, ``(B, fields[, dim])``, bit for bit; and so does the step over
+    ``dp`` = 2 workers, which keeps the logic as it is."""
+    logic, parents = dlrm.DLRM(CONFIG, seed=2), _ParentsDLRM(CONFIG, seed=2)
+    store = dlrm.make_store(CONFIG, seed=2)
+    state = logic.init_state(jax.random.PRNGKey(0))
+    batch = _batches(13, 1, masked=(5, 9))[0]
+    pulled = store.pull(jnp.asarray(batch["ids"]))
+    assert pulled.shape == (32, 4, 8) and not logic.pulls_turned
+    got, want = logic.step(state, batch, pulled), parents.step(state, batch, pulled)
+    assert got[1].ids.shape == (32, 4) and got[1].deltas.shape == (32, 4, 8)
+    _same_bits((got[0], vars(got[1])), (want[0], vars(want[1])))
+    mesh = make_mesh(2, 1, devices=devices[:2])
+    assert logic.for_workers(2) is logic
+    store = dlrm.make_store(CONFIG, seed=2, mesh=mesh)
+    got = jax.jit(make_train_step(logic, store.spec))(store.table, state, batch)
+    want = jax.jit(make_train_step(parents, store.spec))(store.table, state, batch)
+    _same_bits(got[:2], want[:2])
+
+
+@pytest.mark.parametrize("vectors", [2, 5, 27])
+def test_the_triangle_put_back_by_a_product_is_the_scattered_triangle(vectors):
+    """``pair_tables``: the pairs below the diagonal row by row (the source's
+    ``li``, ``lj``) and ``both``, the 0/1 matrix by which ONE product of the
+    pairs' gradients is ``dZ + dZ^t`` of the ``dZ`` that ``at[...].set``
+    scatters them into, bit for bit; forward, the gather of the pairs is the
+    one whose transpose that scatter is."""
+    lower_i, lower_j, both = dlrm.pair_tables(vectors)
+    pairs = vectors * (vectors - 1) // 2
+    assert np.array_equal((lower_i, lower_j), np.tril_indices(vectors, -1))
+    square = both.reshape(pairs, vectors, vectors)
+    assert both.dtype == np.float32 and set(np.unique(both)) <= {0.0, 1.0}
+    assert np.array_equal(square, square.swapaxes(1, 2))
+    assert (square.sum(axis=(1, 2)) == 2).all()  # (i, j) and (j, i), no other
+    assert np.array_equal(square[np.arange(pairs), lower_i, lower_j], np.ones(pairs))
+    assert not square[:, np.arange(vectors), np.arange(vectors)].any()
+    rng = np.random.default_rng(vectors)
+    z = jnp.asarray(rng.normal(size=(16, vectors, vectors)).astype(np.float32))
+    d_pairs = rng.normal(size=(16, pairs)).astype(np.float32)
+    d_pairs[3, 0], d_pairs[4, 0], d_pairs[5, 0] = 0.0, 3e38, 1e-30
+    d_pairs = jnp.asarray(d_pairs)
+    d_z = jnp.zeros_like(z).at[:, lower_i, lower_j].set(d_pairs)
+    # (the values: a diagonal entry is a sum of products by zero, -0.0 where
+    # every pair's gradient is negative, which adds nothing to what it meets)
+    put_back = np.asarray(dlrm._dot(d_pairs, both).reshape(z.shape))
+    np.testing.assert_array_equal(put_back, np.asarray(d_z + d_z.swapaxes(1, 2)))
+    _same_bits(put_back[:, lower_i, lower_j], d_pairs)
+    _same_bits(put_back[:, lower_j, lower_i], d_pairs)
+    _same_bits(z[:, lower_i, lower_j], np.asarray(z)[:, lower_i, lower_j])
+    grad = jax.grad(lambda z: jnp.sum(z[:, lower_i, lower_j] * d_pairs))(z)
+    _same_bits(grad, d_z)
 
 
 def test_at_dp_2_the_step_is_the_plain_reference_and_the_mlps_stay_replicated(devices):
@@ -326,7 +477,9 @@ def test_the_scopes_are_whole_path_components_forward_and_backward():
     assert "transpose(jvp(" not in text
     names = set(__import__("re").findall(r'"(jit\(step\)/ps\.compute/[^"]*)"', text))
     dots = [n for n in names if n.endswith("dot_general")]
-    assert len(dots) == 4  # a layer's three products share one name
+    # (a layer's three products share one name; the interaction has its two
+    # batched products and the 0/1 product that puts the triangle back)
+    assert len(dots) == 5
     for n in dots:
         assert program_trace.SCOPE.findall(n)[-1] in (
             "ps.dense_bottom", "ps.dense_interact", "ps.dense_top"), n

@@ -288,8 +288,10 @@ def test_the_driver_publishes_what_the_push_counted():
         fmm.FactorizationMachine(fm), fmm.make_store(fm).spec
     ))(fmm.make_store(fm).table, (), batches[0])
     assert set(out) == {
-        "prediction", "loss", "ps_slice_kernel", "ps_shift_kernel"}
+        "prediction", "loss", "ps_slice_kernel", "ps_shift_kernel",
+        "ps_lanes_by_field"}
     assert int(out["ps_slice_kernel"]) == int(out["ps_shift_kernel"]) == 0
+    assert int(out["ps_lanes_by_field"]) == 0
 
 
 @pytest.mark.parametrize("arm", ["xla", "set_kernel"])

@@ -189,16 +189,19 @@ def test_the_shift_kernel_equals_the_select_arm_bit_for_bit(d, n, masked):
 
 
 # a key block (B, K): K fields on the leading axis of the answer
-@pytest.mark.parametrize("batch, fields", [(256, 1), (256, 5), (512, 39), (256, 40)])
-@pytest.mark.parametrize("d, width", [(4, None), (17, None), (36, 20), (64, 1)])
+@pytest.mark.parametrize("batch, fields", [
+    (256, 1), (256, 5), (512, 39), (256, 40), (256, 26)])
+@pytest.mark.parametrize("d, width", [
+    (4, None), (17, None), (36, 20), (64, 1), (64, None)])
 def test_the_turned_slice_kernel_is_the_select_arm_turned_bit_for_bit(
         d, width, batch, fields):
     """``turned_slice_kernel`` (interpreted here) reads the rows gathered
     for a two-axis key block in the block's C order and writes them TURNED:
     the bits of ``_sub_row_slice`` on the flat ids, NaN, infinities and -0.0
-    included, with the block's axes swapped.  ``by_field`` says which block
-    the kernels take so: whole blocks of examples, as many fields as were
-    compiled for the chip."""
+    included, with the block's axes swapped, and so the flat kernel's.
+    ``by_field`` says which block the kernels take so: whole blocks of
+    examples, as many fields as were compiled for the chip.  (64 lanes whole,
+    two rows to a register, 26 fields: DLRM's, cell 10 since PR 65.)"""
     n = batch * fields
     rows, ids = _gathered_rows_with_specials(
         np.random.default_rng([d, batch, fields]), n, d)
@@ -212,6 +215,10 @@ def test_the_turned_slice_kernel_is_the_select_arm_turned_bit_for_bit(
     got = turned_slice_kernel(rows, block, d, width)
     assert got.shape == want.shape == (fields, batch, width or d)
     np.testing.assert_array_equal(bits(got), bits(want))
+    if pack_k(d) > 1:
+        flat = sub_row_slice_kernel(rows, ids, d, width, block=256)
+        np.testing.assert_array_equal(bits(got), bits(jnp.swapaxes(
+            flat.reshape(batch, fields, -1), 0, 1)))
     assert np.isnan(np.asarray(want)).any() or (width or d) == 1
     assert packed_mod.by_field(fields, batch)
     assert not packed_mod.by_field(fields, batch - 8)
@@ -220,7 +227,8 @@ def test_the_turned_slice_kernel_is_the_select_arm_turned_bit_for_bit(
 
 
 @pytest.mark.parametrize("masked", [False, True], ids=["all_live", "masked"])
-@pytest.mark.parametrize("batch, fields", [(128, 1), (256, 5), (384, 39), (128, 40)])
+@pytest.mark.parametrize("batch, fields", [
+    (128, 1), (256, 5), (384, 39), (128, 40), (256, 26)])
 @pytest.mark.parametrize("d", [16, 17, 36, 64])
 def test_the_shift_kernel_takes_its_deltas_a_field_at_a_time_bit_for_bit(
         d, batch, fields, masked):
@@ -294,31 +302,39 @@ def test_a_pull_under_one_block_keeps_the_select_arm(n, kernel, monkeypatch):
     np.testing.assert_array_equal(np.asarray(got), want)
 
 
-@pytest.mark.parametrize("arm", ["select", "kernel"])
+@pytest.mark.parametrize("arm", ["select", "kernel", "kernel_by_field"])
 def test_the_driver_says_which_arm_sliced_its_pulled_rows(arm, monkeypatch,
         steer_arms):
     """Where an operator reads it: the gauge ``store_packed_slice_kernel``,
     from the scalar the step of a packed store carries among its outputs
     (``ps_slice_kernel``: what its trace read), and beside it, for an
     ``add`` store, ``store_packed_shift_kernel`` (``ps_shift_kernel``: the
-    push's arm).  A dense store's driver has neither gauge."""
+    push's arm).  ``store_packed_by_field`` (``ps_lanes_by_field``) says
+    whether those kernels moved the batch a field at a time, the logic's
+    buffers batch-minor at the other end: 1 only where both did.  A dense
+    store's driver has none of the three."""
     from flink_parameter_server_tpu.models import factorization_machine as fmm
     from flink_parameter_server_tpu.telemetry.registry import MetricsRegistry
     from flink_parameter_server_tpu.training.driver import (
         DriverConfig, StreamingDriver)
 
-    if arm == "kernel":  # steered: this is a CPU, the kernels are interpreted
-        steer_arms(pull="packed_kernel", shift="kernel")
+    examples = 32
+    if arm != "select":  # steered: this is a CPU, the kernels are interpreted
+        fielded = "_by_field" if arm == "kernel_by_field" else ""
+        steer_arms(pull="packed_kernel" + fielded, shift="kernel" + fielded)
         monkeypatch.setattr(packed_mod, "SLICE_BLOCK", 64)
         packed_mod.packed_pull.clear_cache()
+        if fielded:  # whole blocks of examples, as `arms` asks of a TPU's
+            examples = 256
+            assert packed_mod.by_field(5, examples)
     cfg = fmm.FMConfig(num_features=500, dim=16)
     rng = np.random.default_rng(3)
     batches = [{
-        "ids": rng.integers(0, 500, (32, 5)).astype(np.int32),
-        "values": rng.random((32, 5)).astype(np.float32),
-        "feat_mask": rng.random((32, 5)) > 0.2,
-        "label": (rng.integers(0, 2, 32) * 2 - 1).astype(np.float32),
-        "mask": np.ones(32, bool),
+        "ids": rng.integers(0, 500, (examples, 5)).astype(np.int32),
+        "values": rng.random((examples, 5)).astype(np.float32),
+        "feat_mask": rng.random((examples, 5)) > 0.2,
+        "label": (rng.integers(0, 2, examples) * 2 - 1).astype(np.float32),
+        "mask": np.ones(examples, bool),
     } for _ in range(3)]
     seen = {}
     for layout in ("auto", "dense"):
@@ -332,10 +348,43 @@ def test_the_driver_says_which_arm_sliced_its_pulled_rows(arm, monkeypatch,
     packed_mod.packed_pull.clear_cache()
     assert seen == {
         "auto": {"store_layout_packed": 1.0,
-                 "store_packed_slice_kernel": float(arm == "kernel"),
-                 "store_packed_shift_kernel": float(arm == "kernel")},
+                 "store_packed_slice_kernel": float(arm != "select"),
+                 "store_packed_shift_kernel": float(arm != "select"),
+                 "store_packed_by_field": float(arm == "kernel_by_field")},
         "dense": {"store_layout_packed": 0.0},
     }
+
+
+def test_a_step_is_by_field_only_where_every_lane_kernel_it_has_is(steer_arms):
+    """``core/store.step_counts``' ``ps_lanes_by_field``: the pull's arm and,
+    for an ``add`` store, the shift's; a rule store has no shift to ask; a
+    store of one row to a physical row hands out none of the three."""
+    from flink_parameter_server_tpu.core import store as store_mod
+
+    def read(store, **arms):
+        steer_arms(**arms)  # (calls stack: an arm stays as last steered)
+        out = store_mod.step_counts(
+            store.spec, None, pull_lanes=1280, push_lanes=1280, fields=5)
+        return {k: int(v) for k, v in out.items() if k in (
+            "ps_slice_kernel", "ps_shift_kernel", "ps_lanes_by_field")}
+
+    add = ShardedParamStore.create(500, (17,), layout="packed")
+    assert read(add) == {
+        "ps_slice_kernel": 0, "ps_shift_kernel": 0, "ps_lanes_by_field": 0}
+    assert read(add, pull="packed_kernel_by_field", shift="kernel") == {
+        "ps_slice_kernel": 1, "ps_shift_kernel": 1, "ps_lanes_by_field": 0}
+    assert read(add, shift="kernel_by_field") == {
+        "ps_slice_kernel": 1, "ps_shift_kernel": 1, "ps_lanes_by_field": 1}
+    rule = ShardedParamStore.create(
+        500, (36,), update=lambda current, combined: current + combined,
+        layout="auto")
+    assert rule.spec.pack == 3
+    assert read(rule, pull="packed_kernel", shift="selects") == {
+        "ps_slice_kernel": 1, "ps_lanes_by_field": 0}
+    assert read(rule, pull="packed_kernel_by_field") == {
+        "ps_slice_kernel": 1, "ps_lanes_by_field": 1}
+    wide = ShardedParamStore.create(61, (300,), layout="packed")
+    assert wide.spec.pack == 1 and read(wide) == {}
 
 
 @pytest.mark.parametrize("origin", ["numpy", "uncommitted", "committed"])

@@ -2330,6 +2330,63 @@ def test_a_rule_row_resolves_by_its_width_and_its_reload_to_the_same(
     assert np.asarray(again.table).tobytes() == np.asarray(store.table).tobytes()
 
 
+@pytest.mark.parametrize("masked", [False, True], ids=["all_live", "masked"])
+def test_a_turned_push_through_the_tile_kernel_is_the_flat_arms_push(
+        masked, monkeypatch, steer_arms):
+    """Cell 10's push since PR 65, a combination no cell ran until then: the
+    shift ``kernel_by_field`` (handed ``(d, K, B)``) in front of ``tile_add``
+    (``ops/row_update``'s tile kernel).  Two 64-lane rows to a physical row,
+    26 fields of 256 examples, a field its own rows and one field of three
+    rows that every example repeats: the table XLA's flat arms leave (pads
+    under a select, ``table.at[].add``), bit for bit, and the kernel's
+    counts."""
+    from flink_parameter_server_tpu.core import store as store_mod
+    from flink_parameter_server_tpu.ops import packed
+
+    width, block = 64, (26, 256)
+    cards = np.array([3] + [400] * 25)
+    firsts = np.concatenate([[0], np.cumsum(cards)[:-1]])
+    cap = int(cards.sum())
+    store = ShardedParamStore.from_values(
+        jnp.asarray(_init_values(cap, (width,))), layout="auto")
+    assert (store.spec.layout, store.spec.pack) == ("packed", 2)
+    rng = np.random.default_rng([7, masked])
+    ids = (rng.integers(0, cards[:, None], block) + firsts[:, None]).astype(np.int32)
+    # dead lanes, dropped in every arm: negative, or past the padded table
+    end = store.spec.padded_capacity
+    ids[4, :3] = [-1, end, end + 9]
+    ids = jnp.asarray(ids)
+    deltas = jnp.asarray(rng.normal(size=block + (width,)).astype(np.float32))
+    # (an example's mask beside each of its fields, as DLRM's is)
+    mask = jnp.asarray(np.broadcast_to(rng.random(256) < 0.7, block)) if masked else None
+
+    def push(*args, turned=False):
+        return jax.jit(lambda table, i, d, m: store_mod.push_counted(
+            store.spec, table, i, d, m, turned=turned))(store.table, *args)
+
+    want, counted = push(ids.reshape(-1), deltas.reshape(-1, width),
+                         None if mask is None else mask.reshape(-1))
+    assert counted is None
+    assert packed.by_field(*block)
+    steer_arms(push="tile_add", shift="kernel_by_field")
+    calls = []
+    real = packed.lane_shift_kernel
+    monkeypatch.setattr(
+        packed, "lane_shift_kernel",
+        lambda by_lane, ids, d, mask=None: calls.append(
+            (by_lane.shape, ids.shape)) or real(by_lane, ids, d, mask))
+    got, counted = push(ids, deltas, mask, turned=True)
+    assert calls == [((width,) + block, block)], calls
+    np.testing.assert_array_equal(
+        np.asarray(got).view(np.uint32), np.asarray(want).view(np.uint32))
+    assert not np.array_equal(np.asarray(got), np.asarray(store.table))
+    # a masked lane keeps its id and adds a row of zeros; a dead one is dropped
+    live = np.asarray(ids).reshape(-1)
+    live = live[(live >= 0) & (live < end)]
+    assert int(counted["ps_push_kernel_lanes"]) == live.size == ids.size - 3
+    assert int(counted["ps_push_tile_rows"]) == len(np.unique(live // 2 // 8))
+
+
 # -- THE CASE TABLE of `core/store.arms` --------------------------------------
 # One case a row of the two tables in its docstring, in their order, the
 # backend steered to the TPU the tables describe; then the same specs off it.
@@ -2365,6 +2422,11 @@ ARMS_ON_A_TPU = [
     ("packed k 2, 1,024+ <= rows / 8", (64,), "add", "auto", None, 80_000,
      4_096, 4_096, False,
      ("packed_kernel", "tile_add", "kernel", "", "", False), 0),
+    # cell 10 since PR 65: the tile kernel behind the shift a field at a time
+    ("k 2, fields 26, 1,024+ <= rows/8", (64,), "add", "auto", None, 120_000,
+     26 * 256, 26 * 256, False,
+     ("packed_kernel_by_field", "tile_add", "kernel_by_field", "", "", False),
+     0),
     ("packed k 7, under a block of ids", (17,), "add", "auto", None, 7_000,
      312, 312, False, ("packed_selects", "xla_add", "selects", "", "", False),
      2),  # the slice and the shift: noted
@@ -2451,7 +2513,7 @@ WORKER_WIDTHS = {
 # the keys an example of a block of two axes that its logic takes TURNED
 FIELDS = {
     "packed k 7, fields 39": 39, "fields 39 over ps 4, dp 1": 39,
-    "fields 39, the batch no blocks": 39,
+    "fields 39, the batch no blocks": 39, "k 2, fields 26, 1,024+ <= rows/8": 26,
     "the worker's 20 / 36, fields 39": 39, "20 / 36, fields 39, over ps 4": 39,
 }
 # off a TPU: XLA's forms; where the push runs is read from the mesh alone
@@ -2462,6 +2524,8 @@ ARMS_OFF_IT = {
     "packed k 2, 1,024+ <= rows / 8": (
         "packed_selects", "xla_add", "selects", "", "", False),
     "packed k 7, fields 39": (
+        "packed_selects", "xla_add", "selects", "", "", False),
+    "k 2, fields 26, 1,024+ <= rows/8": (
         "packed_selects", "xla_add", "selects", "", "", False),
     "packed k 1, 5 regs": ("packed_selects", "xla_add", "selects", "", "", False),
     "3 lanes, held at its tile of 4": (

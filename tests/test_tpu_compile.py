@@ -705,20 +705,23 @@ def _no_block_of_the_batch_has_its_fields_minor(text):
             assert minor == FM_BATCH, (shape, layout)
 
 
-@pytest.mark.parametrize("d, width", [(17, None), (36, 20), (64, None)], ids=str)
+@pytest.mark.parametrize("d, width, fields", [
+    (17, None, 40), (36, 20, 40), (64, None, 40), (64, None, 26)], ids=str)
 def test_the_by_field_kernels_compile_at_the_most_fields_they_take(
-        one_chip, d, width):
+        one_chip, d, width, fields):
     """``ops/packed.by_field`` takes no more than ``TURN_FIELDS`` fields a
     block: both kernels are lowered by Mosaic and compiled for a described
     v5e at exactly that many (Criteo's 39 ran on the chip), at FM's and at
     DiFacto's widths and at the widest row that packs (64 lanes, two to a
     physical row): a block of ``TURN_BLOCK`` examples of every field, twice
-    in the 16 MB of scoped VMEM beside its ``(d, fields, block)`` mirror."""
+    in the 16 MB of scoped VMEM beside its ``(d, fields, block)`` mirror.
+    And at DLRM's own shape, 26 fields of 64 lanes (cell 10 since PR 65:
+    eight trips of ``TURN_GROUP`` fields and two odd ones)."""
     from flink_parameter_server_tpu.ops import packed
 
-    fields, batch = packed.TURN_FIELDS, 8 * packed.TURN_BLOCK
-    assert packed.by_field(fields, batch)
-    assert not packed.by_field(fields + 1, batch)
+    batch = 8 * packed.TURN_BLOCK
+    assert fields <= packed.TURN_FIELDS == 40 and packed.by_field(fields, batch)
+    assert not packed.by_field(packed.TURN_FIELDS + 1, batch)
     sliced = jax.jit(lambda rows, ids: packed.turned_slice_kernel(
         rows, ids, d, width, interpret=False)).lower(
             _shape(one_chip, (batch * fields, 128), jnp.float32),
@@ -1190,8 +1193,8 @@ def _step_text_sha(step, *args):
 
 @pytest.mark.parametrize("cell, want", [
     ("mf_cells_1_and_3", "467449ddc73eac39"),
-    ("fm_cell_2", "1a01b65060f0886d"),
-    ("fm_ps4_cell_4", "d240e3e5008700fb"),
+    ("fm_cell_2", "715a8a5e1325631a"),
+    ("fm_ps4_cell_4", "7c6eef68c7b9b162"),
     ("lr_cell_6", "f669bf2e1dddf416"),
     ("keyed_mf_cell_8", "47d256f2590f4bc0"),
 ])
@@ -1225,7 +1228,14 @@ def test_the_mf_and_fm_cells_step_text_is_the_parents(cell, want, request):
     three did not move, nor did
     the step of any cell that runs neither FM logic (every cell's lowered
     step at full size for a described v5e, hashed on both trees: PERF.md
-    section 6, PR 63)."""
+    section 6, PR 63).  PR 65 gave the step of every store packed several
+    rows to a physical row one scalar more, ``ps_lanes_by_field`` (whether
+    its lane kernels move the batch a field at a time: here, off the TPU, a
+    constant 0 behind the parent's ops to the letter; ``1a01b65060f0886d``
+    and ``d240e3e5008700fb`` until then; at full size for a described v5e
+    cells 2, 4, 9 and 12 hash equal to the parent's with that output left
+    out, every other cell but cell 10 as it stands: PERF.md section 6, PR
+    65); the other three did not move."""
     shape = jax.ShapeDtypeStruct
 
     def mf_batch(n, on=shape):
@@ -1865,16 +1875,32 @@ def test_dlrm_step_holds_nothing_table_sized_beside_its_12_58_gb_table(
     the table's 24,563,152 rows, where the TPU compiler would leave its own
     scatter-add serial: ``arms(spec, push_lanes=n)``), no XLA scatter, and
     never copied; under ``ps.pull`` ONE gather of whole physical rows and the
-    lane slice kernel at two rows a register, handing the logic
-    ``f32[64,851968]``; the dense net's scopes on its products; 1.8 GB of
-    temporaries, so table, step and the pool stay under the chip's 16 GB.
-    Since PR 51 the push's lane shift is the slice's mirror kernel here
-    too, ONE ``packed_lane_shift`` call whose rows the nine permutes read
-    as they lie: the logic builds its deltas ROW-major (``f32[32768,26,64]``),
-    so the flatten to ``f32[851968,64]`` stays and one ``copy`` turns them
-    feature-major for the kernel, in place of the mask's select over them
-    and the pads under a select (1.33 + 1.92 ms a step on the v5e for
-    0.95 + 1.01: PERF.md section 6, PR 51)."""
+    lane slice kernel at two rows a register; the dense net's scopes on its
+    products; 1.8 GB of temporaries, so table, step and the pool stay under
+    the chip's 16 GB.  Since PR 51 the push's lane shift is the slice's
+    mirror kernel here too, ONE call whose rows the nine permutes read as
+    they lie.
+
+    Since PR 65 the logic a step in one place traces keeps the batch the
+    MINOR axis between the two kernels (``models/dlrm.DLRM`` mixes in
+    ``FieldLanes``) and both move the batch a field at a time:
+    ``packed_lane_slice_turned`` hands ``f32[64,26,32768]``, XLA's own layout
+    of the logic's ``(26, 32768, 64)`` (and of its swap to ``(32768, 26,
+    64)``, a bitcast), and ``packed_lane_shift_fielded``
+    reads one fusion of that shape, the delta build fused into it.  Until
+    then the logic computed on row-major ``(B, 26, 64)`` between two
+    feature-major kernels (``packed_lane_slice`` handing ``f32[64,851968]``):
+    ``copy.319 f32[851968,64]`` + ``reshape.45 f32[32768,26,64]`` behind the
+    slice, ``reshape.47`` + ``copy.324 f32[851968,64]`` in front of the
+    shift, 4.97 ms a step on the v5e; and the backward pass put the triangle
+    of ``dZ`` back by a scatter, a ``while`` of 351 ``dynamic-update-slice``
+    of one ``f32[32768]`` column (2.28 ms), where one product with a 0/1
+    matrix does now (``dlrm.pair_tables``' ``both``; a static GATHER in its
+    place hangs the chip beside the tile kernel, which no compile shows:
+    PERF.md section 6, PR 65): no `` while(`` is left in the step.
+    What stands in the crossing's place is one transposing copy each way
+    round XLA's batched products, which want ``(B, 27, 64)`` row-major
+    (PERF.md section 6, PR 65)."""
     cfg, model, dlrm = dlrm_cell
     spec = jax.eval_shape(lambda: dlrm.make_store(model, dtype=jnp.float32)).spec
     assert (spec.layout, spec.pack, spec.update) == ("packed", 2, "add")
@@ -1884,8 +1910,13 @@ def test_dlrm_step_holds_nothing_table_sized_beside_its_12_58_gb_table(
     # (the tile kernel: the batch is under the compiler's cut)
     assert store_mod.arms(spec, pull_lanes=n, push_lanes=n) == store_mod.Arms(
         "packed_kernel", "tile_add", "kernel", "", "", False)
+    assert store_mod.arms(
+        spec, pull_lanes=n, push_lanes=n, fields=DLRM_FIELDS
+    ) == store_mod.Arms(
+        "packed_kernel_by_field", "tile_add", "kernel_by_field", "", "", False)
     assert n * store_mod._SERIAL_SCATTER_ROWS_A_LANE < DLRM_PHYS_ROWS
     logic = dlrm.DLRM(model)
+    assert logic.for_workers(1).pulls_turned and not logic.pulls_turned
     state = {
         k: _shape(one_chip, v.shape, v.dtype) for k, v in jax.eval_shape(
             lambda: logic.init_state(jax.random.PRNGKey(0))).items()
@@ -1912,20 +1943,46 @@ def test_dlrm_step_holds_nothing_table_sized_beside_its_12_58_gb_table(
     assert len(pulls) == 1 and "slice_sizes={1,128}" in pulls[0], pulls
     assert 'op_name="jit(step)/ps.pull/' in pulls[0]
     kernels = [line for line in lines if "tpu_custom_call" in line]
+    block = f"{DLRM_FIELDS},{FM_BATCH}"
     slices = [k for k in kernels if "packed_lane_slice" in k]
-    assert len(slices) == 1 and f" = f32[64,{n}]{{1,0:" in slices[0]
+    assert len(slices) == 1 and "%packed_lane_slice_turned" in slices[0]
+    assert f" = f32[64,{block}]{{2,1,0:" in slices[0]
     adds = [k for k in kernels if "sorted_row_update_tiles" in k]
     assert len(adds) == 9 == -(-n // row_update.MAX_LANES) and len(kernels) == 11
     for call in adds:
         assert " = f32[24563152,128]{1,0" in call and "ps.push/" in call
     shifts = [k for k in kernels if "packed_lane_shift" in k]
-    assert len(shifts) == 1 and f" = f32[{n},128]{{1,0:" in shifts[0]
+    assert len(shifts) == 1 and "%packed_lane_shift_fielded" in shifts[0]
+    assert f" = f32[{block},128]{{2,1,0:" in shifts[0]
     assert 'op_name="jit(step)/ps.push/' in shifts[0]
-    name = re.search(r"%(packed_lane_shift[\w.\-]*) = ", shifts[0])[1]
-    users = [c for c in lines if re.search(rf"%{re.escape(name)}[,)]", c)]
+
+    def users_of(call):
+        name = re.search(r"%([\w.\-]+) = ", call)[1]
+        return [c for c in lines if re.search(rf"%{re.escape(name)}[,)]", c)]
+
+    # the logic takes the slice kernel's rows through a bitcast ...
+    # (example-major to the logic's products, the batch still the buffer's
+    # minor axis)
+    turned, = users_of(slices[0])
+    assert f" = f32[{FM_BATCH},{DLRM_FIELDS},64]{{0,1,2:" in turned
+    assert " bitcast(" in turned
+    # ... the shift kernel reads ONE fusion in its own layout (the delta
+    # build in it), and the nine permutes its rows through a bitcast
+    fed, = re.findall(r"custom-call\(%[\w.\-]+, %([\w.\-]+)\)", shifts[0])
+    feeder, = [c for c in lines if re.search(rf"%{re.escape(fed)} = ", c)]
+    assert f" = f32[64,{block}]{{2,1,0:" in feeder and " fusion(" in feeder
+    flat, = users_of(shifts[0])
+    assert f" = f32[{n},128]{{1,0:" in flat and " bitcast(" in flat
+    users = users_of(flat)
     assert len(users) == 9 and all(  # the permutes, no relayout between
         " = f32[94720,128]" in c and "ps.push/jit(_take)/gather" in c
         for c in users), users
+    # nothing turns the batch's rows round between the kernels and the logic,
+    # and no loop puts the triangle back
+    assert not re.search(rf" = f32\[{n},64\]\S* (copy|reshape)\(", text)
+    assert not re.search(
+        rf" = f32\[{FM_BATCH},{DLRM_FIELDS},64\]\S* reshape\(", text)
+    assert not re.search(r" while\(", text)
     # the gather's fusion and the nine permutes' are the fusions that wide
     assert not re.search(rf" = f32\[{n},128\]\S* (copy|pad)\(", text)
     assert "pad_select_fusion" not in text
@@ -1939,7 +1996,9 @@ def test_dlrm_step_holds_nothing_table_sized_beside_its_12_58_gb_table(
         make_train_step(logic, spec),
         jax.ShapeDtypeStruct(spec.table_shape(), jnp.float32), state, batch)[2]
     assert {"ps_push_kernel_lanes", "ps_push_tile_rows", "ps_slice_kernel",
-            "ps_shift_kernel"} <= set(outs)
+            "ps_shift_kernel", "ps_lanes_by_field"} <= set(outs)
+    # (the delta build's multiply lies INSIDE the fusion that feeds the shift
+    # kernel, which a trace names by its root, under `ps.push`)
     for scope in ("dense_bottom", "dense_interact", "dense_top", "delta_build"):
         assert f"jit(step)/ps.compute/ps.{scope}/" in text, scope
     assert "transpose(jvp(" not in text
