@@ -505,11 +505,11 @@ def push(
     by row (stably: a row's deltas stay in the order of the batch and are
     added one by one, XLA's roundings bit for bit) and every touched tile of
     eight rows read, added to and written back once a block of lanes.  A
-    store whose ``update`` is a rule goes through :func:`_push_rule`, under
-    a mesh with one worker on the shards that own the rows
-    (:func:`_push_rule_on_shards`).  Which form, and what each cost on the
-    chip: :func:`arms`; :func:`push_counted` also hands out what the push
-    counted.
+    store whose ``update`` is a rule goes through :func:`_push_rule`.  Under
+    a mesh with one worker that push, and the tile kernel's, run on the
+    shards that own the rows (:func:`_push_rule_on_shards`,
+    :func:`_push_add_on_shards`).  Which form, and what each cost on the
+    chip: :func:`arms`; :func:`push_counted` hands out what the push counted.
     """
     return push_counted(spec, table, ids, deltas, mask)[0]
 
@@ -544,19 +544,17 @@ def push_counted(
     records); a PACKED rule
     store carries ``ps_rule_packed_rows``, the physical rows its write-back
     wrote (:func:`_rewrite_packed`; a dense rule store has no such count).
-    ``on_shards``:
-    each of these is the SUM over the shards of what each counted on its own
-    block (ownership is disjoint, so keys, rows and kernel lanes are the
-    one-place push's numbers; tiles, physical rows and the kernel's writes,
-    which every block of 256 sorted lanes rounds up to a trip of eight, are
-    counted block by block), and two more say how even the partition is:
-    ``ps_rule_keys_max_shard`` and ``ps_rule_rows_max_shard``, the live keys
-    and the distinct rows of the FULLEST shard, the one a step waits for.
-    ``make_train_step`` puts
-    them among the step's outputs, where whoever fetches outputs finds them
-    (:func:`publish_counts`), if the logic's
-    outputs are a dict (every logic of ``models/``); outputs of another
-    type leave the step as they are, without the counts.
+    ``on_shards`` (a rule's push or ``tile_add``): each of these is the SUM
+    over the shards of what each counted on its own block (ownership is
+    disjoint, so keys, rows and kernel lanes are the one-place push's
+    numbers; tiles, tile rows, physical rows and the kernel's writes are
+    counted block by block and call by call), and two more say how even the
+    partition is, the FULLEST shard's, the one a step waits for:
+    ``ps_rule_keys_max_shard`` and ``ps_rule_rows_max_shard``, of an add
+    store ``ps_push_lanes_max_shard`` and ``ps_push_tile_rows_max_shard``.
+    ``make_train_step`` puts them among the step's outputs
+    (:func:`publish_counts`) if the logic's outputs are a dict (every logic
+    of ``models/``); outputs of another type leave without the counts.
 
     ``lanes_over_workers`` is what the caller knows of where the batch lies
     and a bare ``push`` cannot see: its lanes are split over the mesh's
@@ -629,6 +627,8 @@ def push_counted(
     s_ids, s_deltas = _phys_scatter_args(
         spec, table, flat_ids, flat_deltas, flat_mask, arm, lead
     )
+    if arm.on_shards:
+        return _push_add_on_shards(spec, table, s_ids, s_deltas)
     if arm.push == "tile_add":
         from ..ops.row_update import scatter_add_counted
 
@@ -961,7 +961,8 @@ class Arms:
     combine: str
     # a rule push: "" | "xla_set" | "tile_set" | "row_set" | "tile_assign"
     write_back: str
-    on_shards: bool  # a rule push runs in a shard_map, on the owning shard
+    # a rule push or a `tile_add` runs in a shard_map, on the owning shard
+    on_shards: bool
 
 
 def arms(
@@ -997,31 +998,34 @@ def arms(
     section 6's, where the arm was priced;
     ``tests/test_store.py::test_the_arms_table`` holds a case a row).  Off a
     TPU a pull reads ``take`` / ``narrow`` / ``packed_selects``, an add push
-    ``xla_add`` (``worker_reduce`` as below) and ``selects``, a rule push
-    ``sort`` / ``scatter_add`` and ``xla_set``, ``on_shards`` as below.
+    ``xla_add`` (``worker_reduce`` as below) and ``selects`` (``on_shards``
+    follows ``tile_add``: no), a rule push ``sort`` / ``scatter_add`` and
+    ``xla_set``, ``on_shards`` as below.
     The worker's part of a row (the last rows of the second table) changes
     no pull arm, only the width the arm cuts (:func:`pull`), and no
     write-back: the rule writes whole rows.
 
     An ``add`` store (``combine`` and ``write_back`` ``""``):
 
-    =================================  ======================  =============  ===============  ======  ========
-    spec and batch                     pull                    push           shift            cell    PR
-    =================================  ======================  =============  ===============  ======  ========
-    dense 1 reg, lanes x 8 > rows      take                    xla_add        -                1 3 11  27 30
-    dense 1 reg, 1,024+ <= rows / 8    take                    tile_add       -                none    49
-    dense 1 reg, dp 4, shard <= lanes  take                    worker_reduce  -                8       40
-    packed k 7, lanes x 8 > rows       packed_kernel           xla_add        kernel           none    29 42 51
-    the same over ps 4, dp 1           packed_kernel           xla_add        kernel           none    31 42 51
-    packed k 7, fields 39              packed_kernel_by_field  xla_add        kernel_by_field  2       63
-    fields 39 over ps 4, dp 1          packed_kernel_by_field  xla_add        kernel_by_field  4       63
-    fields 39, the batch no blocks     packed_kernel           xla_add        kernel           none    63
-    packed k 2, 1,024+ <= rows / 8     packed_kernel           tile_add       kernel           none    49 51
-    k 2, fields 26, 1,024+ <= rows/8   packed_kernel_by_field  tile_add       kernel_by_field  10      65
-    packed k 7, under a block of ids   packed_selects          xla_add        selects          none    42
-    packed k 1, 5 regs (3: cell 7)     packed_selects          tile_add       selects          5 7     32 33 57
-    5 regs under a mesh                take                    xla_add        -                none    33
-    =================================  ======================  =============  ===============  ======  ========
+    =================================  ======================  =============  ===============  =========  ======  ========
+    spec and batch                     pull                    push           shift            on_shards  cell    PR
+    =================================  ======================  =============  ===============  =========  ======  ========
+    dense 1 reg, lanes x 8 > rows      take                    xla_add        -                no         1 3 11  27 30
+    dense 1 reg, 1,024+ <= rows / 8    take                    tile_add       -                no         none    49
+    1 reg over ps 4, shard's rows / 8  take                    tile_add       -                yes        16      49 67
+    dense 1 reg, dp 4, shard <= lanes  take                    worker_reduce  -                no         8       40
+    packed k 7, lanes x 8 > rows       packed_kernel           xla_add        kernel           no         none    29 42 51
+    the same over ps 4, dp 1           packed_kernel           xla_add        kernel           no         none    31 42 51
+    packed k 7, fields 39              packed_kernel_by_field  xla_add        kernel_by_field  no         2       63
+    fields 39 over ps 4, dp 1          packed_kernel_by_field  xla_add        kernel_by_field  no         4       63
+    fields 39, the batch no blocks     packed_kernel           xla_add        kernel           no         none    63
+    packed k 2, 1,024+ <= rows / 8     packed_kernel           tile_add       kernel           no         none    49 51
+    k 2, fields 26, 1,024+ <= rows/8   packed_kernel_by_field  tile_add       kernel_by_field  no         10      65
+    packed k 7, under a block of ids   packed_selects          xla_add        selects          no         none    42
+    packed k 1, 5 regs (3: cell 7)     packed_selects          tile_add       selects          no         5 7     32 33 57
+    5 regs over ps 4, dp 1             take                    tile_add       -                yes        none    33 67
+    5 regs over ps 2, dp 2             take                    xla_add        -                no         none    33 67
+    =================================  ======================  =============  ===============  =========  ======  ========
 
     A store whose ``update`` is a rule (``push`` ``"rule"``, ``shift``
     ``""``):
@@ -1051,16 +1055,21 @@ def arms(
     2 regs, 128 / 256 over ps 4       packed_selects          row_kernel   tile_assign  yes        none  64
     ================================  ======================  ===========  ===========  =========  ====  ========
 
-    Reasons the code does not show.  A mesh keeps an add push XLA's because
-    GSPMD partitions the scatter and cannot partition Mosaic's call.  The
-    lane kernels and a rule's kernels run under a mesh inside a
-    ``shard_map``, where they see a plain array; a rule's need ONE worker
-    for that: ``dp`` > 1 holds the batch's lanes split, a shard would first
-    have to be sent the other workers' keys, and no code here does, so such
-    a store keeps the one-place push under GSPMD, noted.  A mesh with one
-    shard leaves the packed pull to GSPMD.  ``worker_reduce`` wants a shard
-    no longer than the batch, so that the per-worker sums it moves are no
-    larger than the deltas.  A rule's write-back shifts a chunk at a time
+    Reasons the code does not show.  GSPMD partitions XLA's scatter-add and
+    cannot partition Mosaic's call: the lane kernels, a rule's kernels and
+    the add push's tile kernel run under a mesh inside a ``shard_map``,
+    where they see a plain array; a push's need ONE worker for that: ``dp``
+    > 1 holds the batch's lanes split, a shard would first have to be sent
+    the other workers' keys, and no code here does, so such a store keeps
+    the one-place push under GSPMD (an add store ``xla_add`` or
+    ``worker_reduce``), noted.  On the shards an add push is judged as a
+    store of ONE shard's block in one place is (its rows against the lanes:
+    every shard walks all the batch's lanes), and where that reads
+    ``xla_add`` the scatter stays GSPMD's, the program it was (cell 4: its
+    lanes x 8 exceed a shard's 6.7 M physical rows, the compiler's sorted
+    form).  A mesh with one shard leaves the packed pull to GSPMD.
+    ``worker_reduce`` wants a shard no longer than the batch, so that the
+    per-worker sums it moves are no larger than the deltas.  A rule's write-back shifts a chunk at a time
     (:func:`_rewrite_packed`), so only an add push has a ``shift``.  A rule
     row no wider than a sort carries rides through the sort whatever the
     backend.  A narrow rule row NOT held at its tile (bfloat16, rank 0 or
@@ -1116,14 +1125,21 @@ def arms(
         pull = "packed_kernel" + by_field(n) if kernel else "packed_selects"
 
     if not rule:
-        tiles, what = spec.mesh is None and tpu, "wide rows"
+        # the table the kernel sees: the whole one, or a shard's block
+        block = (spec.rows_per_shard,) + shape[1:]
+        tiles, what = tpu, "wide rows"
         if tiles and phys < _TILE_KERNEL_MIN_REGISTERS * 128:
             n = _ONE_REGISTER_MIN_LANES if push_lanes is None else push_lanes
             tiles = (phys == 128 and n >= _ONE_REGISTER_MIN_LANES
-                     and n * _SERIAL_SCATTER_ROWS_A_LANE <= shape[0])
+                     and n * _SERIAL_SCATTER_ROWS_A_LANE <= block[0])
             what = "one-register rows eight batches long"
+        if tiles and spec.mesh is not None and not one_block:
+            if push_lanes is not None:  # noted where a batch is judged
+                taken("an add store's push on the shards that own its rows",
+                      f"the batch lies split over dp = {workers} workers")
+            tiles = False
         if tiles and taken(f"push into a table of {what}",
-                           row_update.tile_refusal(shape, spec.dtype)):
+                           row_update.tile_refusal(block, spec.dtype)):
             push = "tile_add"
         elif (lanes_over_workers and push_lanes is not None and workers > 1
               and push_lanes % workers == 0
@@ -1135,7 +1151,8 @@ def arms(
         if spec.layout == "packed":
             kernel = lane_kernel(push_lanes, "the lane shift of a packed push")
             shift = "kernel" + by_field(push_lanes) if kernel else "selects"
-        return Arms(pull, push, shift, "", "", False)
+        on_shards = push == "tile_add" and spec.mesh is not None
+        return Arms(pull, push, shift, "", "", on_shards)
 
     on_shards = spec.mesh is not None and taken(
         "a rule store's push on the shards that own its rows",
@@ -1247,6 +1264,13 @@ def publish_counts(outs: dict, registry: Any, total, peak) -> None:
         registry.gauge(
             "store_push_tile_rows", component="train"
         ).set(total(outs["ps_push_tile_rows"]))
+    if "ps_push_lanes_max_shard" in outs:
+        registry.gauge(
+            "store_push_lanes_max_shard", component="train"
+        ).set(total(outs["ps_push_lanes_max_shard"]))
+        registry.gauge(
+            "store_push_tile_rows_max_shard", component="train"
+        ).set(total(outs["ps_push_tile_rows_max_shard"]))
     if "ps_rule_rows" not in outs:
         return
     if "ps_pull_row_lanes" in outs:
@@ -1333,6 +1357,58 @@ def _push_rule_on_shards(
         out_specs=(rows, P()),
         check_vma=False,  # a Pallas call states no varying axes
     )(table, flat_ids, flat_deltas, *masks)
+
+
+def _push_add_on_shards(
+    spec: StoreSpec, table: Array, ids: Array, deltas: Array
+) -> Tuple[Array, dict]:
+    """The ``tile_add`` push (physical ids, flat, and their rows as
+    :func:`_phys_scatter_args` leaves them) into a table sharded over ``ps``
+    under one worker, shaped as :func:`_push_rule_on_shards` is: ONE
+    ``shard_map`` over the mesh, the table ``P(ps, ...)``, ids and rows
+    replicated.  A shard takes the ids that fall in its block, relative to
+    its first row; every other lane is dead to it (it sorts to the end of
+    the shard's batch, and the kernel neither reads its row nor opens a
+    tile for it), and the shard runs the one-place push on its own block:
+    ``ops/row_update.scatter_add_counted``, which inside the ``shard_map``
+    sees a plain array.  No key is sent anywhere, no row leaves its chip,
+    and every chip still sorts and permutes all the batch's lanes.
+
+    The table is the one-place push's bit for bit: ownership is disjoint,
+    the sort is stable, so a row's deltas are added to it one by one in the
+    order of the batch, by the shard that owns it.  The counts are sums over
+    ``ps`` (the kept lanes are the one-place push's; a shard's tile rows are
+    counted over ITS sorted batch's calls, and blocks start on a tile row,
+    so the sum is the one-place push's wherever a batch is one call long);
+    ``ps_push_lanes_max_shard`` and ``ps_push_tile_rows_max_shard`` are the
+    fullest shard's.  One gather of two scalars across ``ps`` carries them,
+    the push's only collective."""
+    from ..ops.row_update import scatter_add_counted
+
+    ps, rows = spec.ps_axis, spec.rows_per_shard
+
+    def on_shard(table: Array, ids: Array, deltas: Array):
+        rel = ids - jax.lax.axis_index(ps) * rows
+        # another shard's row, or the sentinel of a dropped lane
+        rel = jnp.where((rel >= 0) & (rel < rows), rel, rows)
+        table, lanes, tile_rows = scatter_add_counted(
+            table, rel, deltas, rolled=True)
+        by_shard = jax.lax.all_gather(jnp.stack([lanes, tile_rows]), ps)
+        total, most = by_shard.sum(axis=0), by_shard.max(axis=0)
+        return table, {
+            "ps_push_kernel_lanes": total[0], "ps_push_tile_rows": total[1],
+            "ps_push_lanes_max_shard": most[0],
+            "ps_push_tile_rows_max_shard": most[1],
+        }
+
+    block = spec.sharding().spec
+    return jax.shard_map(
+        on_shard,
+        mesh=spec.mesh,
+        in_specs=(block, P(), P()),
+        out_specs=(block, P()),
+        check_vma=False,  # a Pallas call states no varying axes
+    )(table, ids, deltas.astype(table.dtype))
 
 
 def _push_add_over_workers(

@@ -377,17 +377,29 @@ def jit_train_steps(
     return step, scan_step
 
 
-def _committed_where(table):
+def _committed_where(table, mesh=None):
     """``table`` committed to the devices it lies on (no copy: the array
     keeps its buffers), and a function that does the same for every leaf of
-    the worker state that lies on those same devices.  A leaf anywhere else
-    (a state built on the default device beside a table on a mesh) is left
-    for ``jit`` to place, as it always was."""
+    the worker state that lies on those same devices.  An UNCOMMITTED leaf
+    anywhere else (a state built on the default device beside a table on
+    ``mesh``) is laid replicated over the mesh, where ``jit`` would place it
+    and where the step hands it back: left as it was, the first dispatch of
+    a driver's second ``run`` met other input shardings than the first's
+    and traced, lowered and loaded the step once more (DLRM's MLPs beside a
+    table over ``ps``: PERF.md section 6, PR 67).  A committed leaf
+    elsewhere is the caller's to answer for, as it always was."""
     devices = table.sharding.device_set
+    everywhere = None
+    if mesh is not None and set(mesh.devices.flat) == devices:
+        everywhere = NamedSharding(mesh, PartitionSpec())
 
     def commit(x):
-        if isinstance(x, jax.Array) and x.sharding.device_set == devices:
+        if not isinstance(x, jax.Array):
+            return x
+        if x.sharding.device_set == devices:
             return jax.device_put(x, x.sharding)
+        if everywhere is not None and not x.committed:
+            return jax.device_put(x, everywhere)
         return x
 
     return commit(table), commit
@@ -598,7 +610,7 @@ def transform_batched(
     # loaded once more (no copy: the array keeps its buffers; the ledger's
     # `setup.commit` holds that claim)
     with setup_span("commit"):
-        table, commit = _committed_where(keep(store.table))
+        table, commit = _committed_where(keep(store.table), mesh)
         state = jax.tree.map(commit, state)
     worker_outputs: List[Any] = []
     step_idx = 0

@@ -64,6 +64,7 @@ and the TPU's default (one bfloat16 pass) is 4e-3 of every product.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Dict, Tuple
 
 import jax
@@ -166,6 +167,26 @@ def _mlp_backward(state, name: str, acts, d: Array, last_relu: bool):
     return grads, d
 
 
+@functools.partial(jax.jit, static_argnames=("layers",))
+def _init_layers(rng: Array, seed, *, layers) -> Dict[str, Array]:
+    """:meth:`DLRM.init_state`'s leaves, the keys and the ops the eager
+    form had, layer by layer, and its BITS: the barrier keeps XLA from
+    folding a leaf's scale into ``normal``'s own last multiply, one
+    rounding where the eager form had two (up to 2 ulps of 26-65 % of the
+    elements on the CPU).  ``seed`` is an argument: baked in, every seed
+    would compile its own program."""
+    key = jax.random.fold_in(rng, seed)
+    unit, scale = {}, {}
+    for i, (name, (n, m)) in enumerate(layers):
+        kw, kb = jax.random.split(jax.random.fold_in(key, i))
+        unit[f"{name}_w"] = jax.random.normal(kw, (n, m), jnp.float32)
+        unit[f"{name}_b"] = jax.random.normal(kb, (m,), jnp.float32)
+        scale[f"{name}_w"] = np.sqrt(2.0 / (m + n))
+        scale[f"{name}_b"] = np.sqrt(1.0 / m)
+    unit = jax.lax.optimization_barrier(unit)
+    return {leaf: scale[leaf] * x for leaf, x in unit.items()}
+
+
 def pair_tables(vectors: int):
     """``(lower_i, lower_j, both)`` for the pairs of ``vectors`` vectors: the
     entries of ``T T^t`` below its diagonal, row by row (the source's ``li``,
@@ -203,18 +224,17 @@ class DLRM(FieldLanes, BatchedWorkerLogic):
     def init_state(self, rng: Array) -> Dict[str, Array]:
         """The source's init: ``W ~ N(0, sqrt(2 / (m + n)))``, ``b ~ N(0,
         sqrt(1 / m))`` for ``m`` outputs and ``n`` inputs, from ``rng`` and
-        the logic's seed."""
-        key = jax.random.fold_in(rng, self.seed)
-        state = {}
-        for i, (name, (n, m)) in enumerate(self.config.layers().items()):
-            kw, kb = jax.random.split(jax.random.fold_in(key, i))
-            state[f"{name}_w"] = np.sqrt(2.0 / (m + n)) * jax.random.normal(
-                kw, (n, m), jnp.float32
-            )
-            state[f"{name}_b"] = np.sqrt(1.0 / m) * jax.random.normal(
-                kb, (m,), jnp.float32
-            )
-        return state
+        the logic's seed.  ONE program for all the layers
+        (:func:`_init_layers`): op by op it was a ``fold_in``, a ``split``,
+        two ``normal``s and two multiplies a layer, every shape a program of
+        its own that each process traced, lowered and loaded, ~28 of them
+        (`setup.compiles` 35 -> 7 in cell 16; the seconds did not follow
+        on the chip's host: PERF.md section 6, PR 67)."""
+        seed = self.seed
+        if not isinstance(seed, jax.Array):  # a number: no program of its own
+            seed = np.asarray(seed).astype(np.uint32)
+        return _init_layers(
+            rng, seed, layers=tuple(self.config.layers().items()))
 
     def keys(self, batch: Dict[str, Array]) -> Array:
         return batch["ids"]

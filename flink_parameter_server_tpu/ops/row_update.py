@@ -143,6 +143,7 @@ from __future__ import annotations
 
 import functools
 import importlib
+import sys
 import threading
 import warnings
 from typing import Optional, Tuple
@@ -235,11 +236,33 @@ _PRELOAD = threading.Lock()  # held from the first `preload` on
 _PALLAS = None  # (pl, pltpu) once a kernel has asked for them
 
 
+# What of ``jax.experimental.pallas`` no TPU kernel runs: the interpreter of
+# Mosaic GPU kernels and, behind it, Mosaic GPU's own dialects, 0.7 s of the
+# import's 1.1.  JAX's own ``pallas_call`` module imports it under ``except
+# ImportError``.
+_GPU_INTERPRETER = "jax._src.pallas.mosaic_gpu.interpret.interpret_pallas_call"
+
+
 def _import_pallas() -> None:
+    """Import Pallas for the TPU.  On a TPU the import leaves the Mosaic GPU
+    interpreter out (a ``None`` in ``sys.modules`` makes ITS import raise
+    ``ImportError``, which Pallas takes as "not installed"; the entry is
+    taken away again, so a later import of Mosaic GPU itself finds it): a
+    process that runs TPU kernels interprets no GPU kernel, and every warm
+    set-up paid for loading it (PERF.md section 6, PR 67).  Where Pallas is
+    imported already, or JAX has moved the module, nothing is left out."""
     from ..telemetry.compile_ledger import setup_span
 
     with setup_span("kernel_import"):
-        importlib.import_module("jax.experimental.pallas.tpu")
+        leave_out = (jax.default_backend() == "tpu"
+                     and _GPU_INTERPRETER not in sys.modules)
+        if leave_out:
+            sys.modules[_GPU_INTERPRETER] = None
+        try:
+            importlib.import_module("jax.experimental.pallas.tpu")
+        finally:
+            if leave_out:
+                del sys.modules[_GPU_INTERPRETER]
 
 
 def preload() -> None:
@@ -1630,6 +1653,7 @@ def scatter_add_counted(
     deltas: Array,
     *,
     interpret: Optional[bool] = None,
+    rolled: bool = False,
 ) -> Tuple[Array, Array, Array]:
     """``table.at[ids].add(deltas, mode="drop")`` through the tile kernel
     (``deltas`` ``(n, w)``, ``w`` <= the table's ``W``: lanes ``[0, w)`` of
@@ -1640,15 +1664,20 @@ def scatter_add_counted(
     one jitted program (one copy of the table, not one a kernel call).
     Beside the table, as int32 scalars on the device: the lanes the kernel
     kept and the tile rows it read and wrote for them, summed over its calls
-    (a tile row open across two calls is moved by both).
+    (a tile row open across two calls is moved by both).  ``rolled``: the
+    calls of a batch over ``MAX_LANES`` lanes as ONE call in a loop that
+    ends with the last call that has a live lane (:func:`_tile_add_calls`);
+    the table and the counts are the same either way.
     """
     if not isinstance(table, jax.core.Tracer):
-        return _scatter_add_jitted(table, ids, deltas, interpret=interpret)
+        return _scatter_add_jitted(
+            table, ids, deltas, interpret=interpret, rolled=rolled)
     sid, order = sort_by_row(ids.reshape(-1), None, table.shape[0])
-    return _tile_add_calls(table, sid, order, deltas, interpret)
+    return _tile_add_calls(table, sid, order, deltas, interpret, rolled)
 
 
-def _tile_add_calls(table, sorted_ids, order, deltas, interpret):
+def _tile_add_calls(table, sorted_ids, order, deltas, interpret,
+                    rolled=False):
     """``(table, kept lanes, tile rows read and written)`` of the tile
     kernel's calls over a sorted batch (``sorted_ids`` ascending, the lanes
     to drop last; ``order[k]`` the row of ``deltas`` that sorted lane ``k``
@@ -1674,7 +1703,21 @@ def _tile_add_calls(table, sorted_ids, order, deltas, interpret):
     registers (``w`` = ``W``) have no pad lane to shed: every call permutes
     its own stretch, as it did before the kernel took narrower rows (cell
     10: nine calls on a 12.58 GB table, no such copy, and its step stays
-    what it was)."""
+    what it was).
+
+    ``rolled``: the calls, which have one shape, as ONE kernel call in the
+    body of a ``while`` that carries the table (aliased, as in each call)
+    and ends with the last call that holds a live lane: the lanes to drop
+    sort last, so a call whose first lane is dead has none to add, and
+    neither its rows are permuted nor its blocks walked.  The calls that
+    run are the unrolled form's, in its order, so the table and the counts
+    are its own bit for bit.  It is what a push on the shards of a mesh
+    takes (``core/store._push_add_on_shards``): every shard sorts ALL the
+    batch's lanes and owns a part of them (cell 16: 5.5-38.9 %, one to four
+    calls of nine), and every process that runs the step traces and lowers
+    one kernel call where it traced nine (PERF.md section 6, PR 67).  The
+    unrolled form stays what a store in one place traces, whose lanes are
+    nearly all live and whose step text is pinned (cells 5, 7, 10)."""
     pad = _pad_for_calls(sorted_ids.shape[0])
     if pad:
         sorted_ids = jnp.concatenate(
@@ -1685,6 +1728,30 @@ def _tile_add_calls(table, sorted_ids, order, deltas, interpret):
     if len(calls) > 1 and deltas.shape[1] < table.shape[1]:
         whole = jnp.take(deltas, order, axis=0, mode="clip")
     lanes = tile_rows = jnp.zeros((), jnp.int32)
+    if rolled and len(calls) > 1:
+        size, rows = calls[0][1].shape[0], table.shape[0]
+
+        def some_live(carry):
+            # (past the last call the index is clamped and the count says no)
+            first = jax.lax.dynamic_index_in_dim(
+                sorted_ids, carry[0] * size, keepdims=False)
+            return (carry[0] < len(calls)) & (first < rows)
+
+        def one_call(carry):
+            i, table, lanes, tile_rows = carry
+            s = jax.lax.dynamic_slice_in_dim(sorted_ids, i * size, size)
+            if whole is None:
+                o = jax.lax.dynamic_slice_in_dim(order, i * size, size)
+                stretch, first = jnp.take(deltas, o, axis=0, mode="clip"), None
+            else:
+                stretch, first = whole, i * (size // BLOCK)
+            table, kept, moved = _sorted_tile_add_counted(
+                table, s, stretch, interpret, False, first)
+            return i + 1, table, lanes + kept, tile_rows + moved
+
+        return jax.lax.while_loop(
+            some_live, one_call,
+            (jnp.zeros((), jnp.int32), table, lanes, tile_rows))[1:]
     for lo, s, o in calls:
         if whole is None:
             rows, first = jnp.take(deltas, o, axis=0, mode="clip"), None
@@ -1697,7 +1764,7 @@ def _tile_add_calls(table, sorted_ids, order, deltas, interpret):
 
 
 _scatter_add_jitted = jax.jit(
-    scatter_add_counted, static_argnames=("interpret",))
+    scatter_add_counted, static_argnames=("interpret", "rolled"))
 
 
 def scatter_add(

@@ -117,6 +117,120 @@ def test_init_state_is_the_sources_init_from_rng_and_seed():
     assert np.array_equal(again["bot1_w"], w) and not np.array_equal(other["bot1_w"], w)
 
 
+def _eager_init(config, seed, rng):
+    """``DLRM.init_state`` as it stood until PR 67: op by op, a ``fold_in``,
+    a ``split``, two ``normal``s and two multiplies a layer."""
+    key = jax.random.fold_in(rng, seed)
+    state = {}
+    for i, (name, (n, m)) in enumerate(config.layers().items()):
+        kw, kb = jax.random.split(jax.random.fold_in(key, i))
+        state[f"{name}_w"] = np.sqrt(2.0 / (m + n)) * jax.random.normal(
+            kw, (n, m), jnp.float32)
+        state[f"{name}_b"] = np.sqrt(1.0 / m) * jax.random.normal(
+            kb, (m,), jnp.float32)
+    return state
+
+
+# MLPerf's widths (cell 16) and the test's own; a seed over 31 bits
+INIT_CONFIGS = {
+    "mlperf": dlrm.DLRMConfig(
+        (7, 5) * 13, dim=128, bottom_mlp=(512, 256, 128),
+        top_mlp=(1024, 1024, 512, 256, 1)),
+    "small": CONFIG,
+}
+
+
+@pytest.mark.parametrize("seed", [0, 7, np.uint32(3_000_000_067)])
+@pytest.mark.parametrize("widths", list(INIT_CONFIGS))
+def test_init_state_as_one_program_is_the_eager_form_bit_for_bit(widths, seed):
+    """The same keys, shapes, scales and BITS as the op-by-op init: the
+    jitted form keeps each leaf's scale apart from ``normal``'s own last
+    multiply (an ``optimization_barrier``); folded into it, XLA's CPU
+    rounds once where the eager form rounded twice, and up to 65 % of a
+    leaf's elements differ by one or two ulps (measured while this was
+    written).  So a job that is started again draws the weights it drew."""
+    config = INIT_CONFIGS[widths]
+    rng = jax.random.PRNGKey(3)
+    got = dlrm.DLRM(config, seed=seed).init_state(rng)
+    want = _eager_init(config, seed, rng)
+    assert sorted(got) == sorted(want)
+    for leaf, x in want.items():
+        assert got[leaf].shape == x.shape and got[leaf].dtype == x.dtype
+        assert np.asarray(got[leaf]).tobytes() == np.asarray(x).tobytes(), leaf
+
+
+def test_init_state_compiles_one_program_where_it_compiled_a_score():
+    """What a warm set-up pays for the init: ONE program traced, lowered and
+    loaded, whatever the seed (an argument); the eager form was ~28 of them
+    (a ``fold_in``, a ``split``, a ``normal`` and a multiply for every new
+    shape: 35 compiles in cell 16's set-up on the ledger, PR 66)."""
+    from flink_parameter_server_tpu.telemetry import compile_ledger
+
+    compile_ledger.install()
+    config = dlrm.DLRMConfig(  # widths no other test compiles an init for
+        (3, 4, 5), dim=24, bottom_mlp=(40, 24), top_mlp=(56, 1))
+
+    def compiled(fn):
+        before = len(compile_ledger.events())
+        jax.block_until_ready(fn())
+        return [e["program"] for e in compile_ledger.events()[before:]
+                if e["stage"] == "backend"]
+
+    first = compiled(lambda: dlrm.DLRM(config, seed=1).init_state(
+        jax.random.PRNGKey(0)))
+    assert first == ["_init_layers"]
+    assert compiled(lambda: dlrm.DLRM(config, seed=2).init_state(
+        jax.random.PRNGKey(5))) == []
+    eager = compiled(lambda: _eager_init(config, 1, jax.random.PRNGKey(0)))
+    assert len(eager) >= 8  # (more in a process that has compiled nothing)
+
+
+def test_a_drivers_second_run_under_a_mesh_meets_the_step_it_traced(
+        mesh_devices):
+    """A state built on the default device beside a table over ``ps`` (the
+    MLPs beside cell 16's table): the loop lays it over the mesh before the
+    first dispatch, where the step hands it back, so the second ``run`` of
+    the driver (the benchmark's window after its checked batches) meets the
+    shardings the first traced for and traces, lowers and loads nothing.
+    Left on its device, the second run's first dispatch was a program of its
+    own: the step once more, ~0.5 s of cell 16's ``warmup`` (PR 67)."""
+    from flink_parameter_server_tpu.telemetry import compile_ledger
+
+    compile_ledger.install()
+    mesh = make_mesh(1, 4, devices=mesh_devices[:4])
+    logic = dlrm.DLRM(CONFIG, seed=3)
+    store = dlrm.make_store(CONFIG, seed=3, mesh=mesh)
+    one = dlrm.make_store(CONFIG, seed=3)
+    batches = _batches(11, 4)
+    driver = StreamingDriver(logic, store, config=DriverConfig())
+
+    def steps_built(run):
+        before = len(compile_ledger.events())
+        result = run()
+        return result, [
+            (e["stage"], e["program"])
+            for e in compile_ledger.events()[before:] if e["program"] == "step"]
+
+    first, built = steps_built(lambda: driver.run(iter(batches[:2])))
+    assert [stage for stage, _ in built] == ["trace", "lower", "backend"]
+    for leaf in jax.tree.leaves(first.worker_state):
+        assert leaf.sharding.is_fully_replicated
+        assert leaf.sharding.device_set == set(mesh.devices.flat)
+    second, built = steps_built(lambda: driver.run(iter(batches[2:])))
+    assert built == []
+    # ... and the placement changed no number: one device, the same batches
+    alone = StreamingDriver(logic, one, config=DriverConfig())
+    alone.run(iter(batches[:2]))
+    want = alone.run(iter(batches[2:]))
+    for leaf, x in want.worker_state.items():
+        np.testing.assert_allclose(
+            np.asarray(second.worker_state[leaf]), np.asarray(x),
+            rtol=2e-6, atol=1e-7)
+    np.testing.assert_allclose(
+        np.asarray(second.store.values())[:sum(CARDS)],
+        np.asarray(want.store.values())[:sum(CARDS)], rtol=2e-6, atol=1e-7)
+
+
 def test_make_store_packs_two_rows_and_draws_every_field_in_its_own_range():
     config = dlrm.DLRMConfig(CARDS, dim=64)
     store = dlrm.make_store(config, seed=3)

@@ -525,6 +525,73 @@ def test_scatter_add_takes_rows_at_their_own_width_over_several_calls(
     assert got[:, w:].tobytes() == table[:, w:].tobytes()
 
 
+ROLLED_LIVE = {  # what of the batch a table (a shard's block) owns
+    "every_lane": 1.0, "two_fifths": 0.389, "a_twentieth": 0.055,
+    "under_one_call": 0.2, "no_lane": 0.0,
+}
+
+
+@pytest.mark.parametrize("share", list(ROLLED_LIVE))
+@pytest.mark.parametrize("W,w", [(128, 128), (128, 64), (384, 300), (640, 640)])
+def test_the_rolled_calls_are_the_unrolled_calls_bit_for_bit(
+        W, w, share, monkeypatch):
+    """``scatter_add_counted(rolled=True)``: the calls of a batch over
+    ``MAX_LANES`` lanes as one kernel call in a ``while`` that ends with the
+    last call holding a live lane (PR 67: what a push on the shards of a
+    mesh takes, where a shard owns a part of the lanes it sorts).  Against
+    the unrolled calls: every bit of the table (a run and a tile row lie
+    across two calls, NaN rides in the dead lanes' rows and in the table's
+    pad lanes) and both counts, whatever share of the lanes is live; the
+    traced program holds ONE kernel call where the unrolled form holds one
+    a call."""
+    monkeypatch.setattr(row_update, "MAX_LANES", 512)
+    rng = np.random.default_rng([W, w, list(ROLLED_LIVE).index(share)])
+    rows, n = ADD_ROWS, 2_304  # five calls of 512 lanes (the last padded)
+    live_n = int(round(ROLLED_LIVE[share] * n))
+    table = rng.normal(size=(rows, W)).astype(np.float32)
+    table[:, w:] = np.nan
+    kept = rng.integers(0, rows, live_n)
+    kept[: live_n // 3] = 301  # a run across blocks, and across calls
+    dead = rng.choice([-1, rows, rows + 9, 2**31 - 1], n - live_n)
+    ids = rng.permutation(np.concatenate([kept, dead])).astype(np.int32)
+    deltas = rng.normal(size=(n, w)).astype(np.float32)
+    live = (ids >= 0) & (ids < rows)
+    deltas[~live] = np.nan
+    assert len(row_update._calls(jnp.zeros(n + 256, jnp.int32), ids)) == 5
+
+    def push(rolled):
+        return jax.jit(lambda t, i, d: row_update.scatter_add_counted(
+            t, i, d, interpret=True, rolled=rolled))
+
+    one, lanes, tile_rows = push(True)(table, ids, deltas)
+    want, want_lanes, want_tile_rows = push(False)(table, ids, deltas)
+    assert np.asarray(one).tobytes() == np.asarray(want).tobytes()
+    assert int(lanes) == int(want_lanes) == live.sum()
+    assert int(tile_rows) == int(want_tile_rows)
+    by_numpy = table.copy()
+    np.add.at(by_numpy[:, :w], ids[live], deltas[live])
+    assert np.asarray(one).tobytes() == by_numpy.tobytes()
+    if share == "no_lane":  # the loop's body never ran
+        assert int(tile_rows) == 0
+    text = {r: str(jax.make_jaxpr(lambda t, i, d: row_update.scatter_add_counted(
+        t, i, d, interpret=False, rolled=r))(table, ids, deltas))
+        for r in (True, False)}
+    assert text[True].count("pallas_call[") == 1
+    assert text[False].count("pallas_call[") == 5
+    # eager, the rolled form is one jitted program like the other
+    eager = row_update.scatter_add_counted(
+        jnp.asarray(table), ids, deltas, interpret=True, rolled=True)
+    assert np.asarray(eager[0]).tobytes() == by_numpy.tobytes()
+
+
+def test_a_batch_of_one_call_is_not_rolled():
+    args = (jnp.zeros((64, 128)), jnp.arange(40, dtype=jnp.int32),
+            jnp.ones((40, 128)))
+    text = [str(jax.make_jaxpr(lambda t, i, d: row_update.scatter_add_counted(
+        t, i, d, interpret=False, rolled=r))(*args)) for r in (True, False)]
+    assert text[0] == text[1] and text[0].count("pallas_call[") == 1
+
+
 def test_the_tile_kernel_refuses_rows_wider_than_the_table():
     for fn in (row_update.sorted_tile_add, row_update.sorted_tile_assign):
         with pytest.raises(ValueError, match=r"rows of shape \(384,\) for a table"):

@@ -1817,6 +1817,179 @@ def test_the_row_kernels_inside_the_shard_map_sum_and_write_what_xla_does(
     assert 4 <= int(counted["ps_combine_kernel_writes"]) <= 4 * -(-700 // 256)
 
 
+# An ADD store over ``ps`` under one worker (PR 67): where ``arms`` reads the
+# tile kernel for a store of ONE shard's block, the push runs in a
+# ``shard_map`` on the shard that owns the row
+# (``core/store._push_add_on_shards``).  Off the TPU the arm is steered and the
+# kernel interpreted inside every shard's part.
+ADD_SHARD_CASES = ONE_REGISTER_TRAFFIC + ["an_empty_shard"]
+ADD_SHARD_ROWS = [  # (row width, rows to a physical row, physical lanes)
+    (128, 1, 128),  # cell 16's: dense, a row a register
+    (64, 2, 128),  # cell 10's row, two to a register
+    (300, 1, 384),  # three registers, flat
+]
+
+
+@pytest.mark.parametrize("case", ADD_SHARD_CASES)
+@pytest.mark.parametrize("shards", [4, 2])
+@pytest.mark.parametrize("width, k, lanes", ADD_SHARD_ROWS)
+def test_an_add_push_on_its_shards_is_the_one_place_push_and_the_counts_add_up(
+        width, k, lanes, shards, case, mesh_devices, monkeypatch, steer_arms):
+    from flink_parameter_server_tpu.core import store as store_mod
+    from flink_parameter_server_tpu.ops import row_update
+    from flink_parameter_server_tpu.parallel.mesh import make_mesh
+
+    cap = ONE_REGISTER_CAP
+    rng = np.random.default_rng([width, shards, ADD_SHARD_CASES.index(case)])
+    values = _init_values(cap, (width,))
+    values[::7] = -0.0  # a masked lane adds +0.0 to it, wherever it is added
+    mesh = make_mesh(1, shards, devices=mesh_devices[:shards])
+    one = ShardedParamStore.from_values(jnp.asarray(values), layout="auto")
+    sharded = ShardedParamStore.from_values(
+        jnp.asarray(values), layout="auto", mesh=mesh)
+    spec = sharded.spec
+    assert spec.pack == k and spec.table_shape()[1] == lanes
+    assert spec.rows_per_shard % 8 == 0  # a tile row has one owner
+    block = spec.rows_per_shard * k  # a shard's LOGICAL rows
+    if case == "an_empty_shard":
+        mask = None
+        ids = rng.integers(0, cap, 900)
+        ids = ids[ids // block != shards - 2].astype(np.int32)
+        deltas = rng.normal(0, 1, (ids.size, width)).astype(np.float32)
+    else:
+        ids, deltas, mask = _one_register_traffic(case, rng, cap, width)
+    args = (jnp.asarray(ids), jnp.asarray(deltas),
+            None if mask is None else jnp.asarray(mask))
+    # off the TPU neither store has the kernel, and nothing runs on shards
+    assert not store_mod.arms(spec, push_lanes=ids.size).on_shards
+    want, counted = store_mod.push_counted(one.spec, one.table, *args)
+    assert counted is None
+    steer_arms(push="tile_add", on_shards=lambda spec: spec.mesh is not None)
+    if case == "over_max_lanes_a_tile_row_across_two_calls":
+        monkeypatch.setattr(row_update, "MAX_LANES", 512)
+    # (both under a jit of this test's own: an eager push is one cached
+    # program a shape, traced at whatever `MAX_LANES` an earlier test held)
+    _, counted_one = jax.jit(
+        lambda t, i, d, m: store_mod.push_counted(one.spec, t, i, d, m)
+    )(one.table, *args)
+    got, counted = jax.jit(
+        lambda t, i, d, m: store_mod.push_counted(spec, t, i, d, m)
+    )(sharded.table, *args)
+    assert got.sharding == spec.sharding()
+    want = np.asarray(ShardedParamStore(one.spec, want).values())
+    pushed = ShardedParamStore(spec, got)
+    assert np.asarray(pushed.values()).tobytes() == want.tobytes()
+    # a batch over a call: on the shards ONE kernel call in a loop that ends
+    # with a shard's last live call (PR 67), in one place a call a stretch
+    many = case == "over_max_lanes_a_tile_row_across_two_calls"
+    if many or case == "an_empty_shard":
+        traced = {
+            name: str(jax.make_jaxpr(
+                lambda t, i, d, m: store_mod.push_counted(sp, t, i, d, m)
+            )(tb, *args)).count("pallas_call[")
+            for name, sp, tb in (("one", one.spec, one.table),
+                                 ("sharded", spec, sharded.table))}
+        assert traced == {"one": 4 if many else 1, "sharded": 1}
+    # the counts: a shard keeps the lanes whose row it owns (a masked lane
+    # keeps its id and adds zeros), and opens the tile rows of each of ITS
+    # sorted batch's calls
+    # (a shard's block ends on a tile row, so the sharded table has padding
+    # rows the one-place table lacks: row `cap + 20` is a row of it)
+    live = (ids >= 0) & (ids < spec.padded_capacity)
+    live_one = (ids >= 0) & (ids < one.spec.padded_capacity)
+    if case == "dead_and_masked":
+        assert not live.all() and live.sum() - live_one.sum() == (
+            spec.padded_capacity > cap + 20 >= one.spec.padded_capacity)
+    owner = ids[live] // block
+    kept = np.bincount(owner, minlength=shards)
+    calls = -(-ids.size // row_update.MAX_LANES)
+    size = -(-ids.size // (calls * 256)) * 256
+    dropped = 2 ** 30
+    tile_rows = []
+    for s in range(shards):
+        rel = np.sort(np.where(
+            live & (ids // block == s), ids // k - s * spec.rows_per_shard,
+            dropped))
+        tile_rows.append(sum(
+            np.unique(part[part < dropped] // 8).size
+            for part in (rel[lo:lo + size] for lo in range(0, ids.size, size))))
+    assert int(counted["ps_push_kernel_lanes"]) == kept.sum() == live.sum()
+    assert int(counted["ps_push_lanes_max_shard"]) == kept.max()
+    assert int(counted["ps_push_tile_rows"]) == sum(tile_rows)
+    assert int(counted["ps_push_tile_rows_max_shard"]) == max(tile_rows)
+    assert set(counted_one) == {"ps_push_kernel_lanes", "ps_push_tile_rows"}
+    assert int(counted_one["ps_push_kernel_lanes"]) == live_one.sum()
+    if calls == 1:  # blocks start on a tile row: the sum is the one-place push's
+        assert int(counted_one["ps_push_tile_rows"]) == sum(tile_rows) - (
+            live.sum() - live_one.sum())
+    else:
+        assert calls == 4 and sum(tile_rows) != int(counted_one["ps_push_tile_rows"])
+    if case == "an_empty_shard":
+        assert kept[shards - 2] == 0 == tile_rows[shards - 2] and kept.sum() > 300
+        # ... and that shard's block came back as it went in, bit for bit
+        blocks = np.asarray(got).reshape(shards, spec.rows_per_shard, -1)
+        before = np.asarray(sharded.table).reshape(blocks.shape)
+        assert blocks[shards - 2].tobytes() == before[shards - 2].tobytes()
+    if case == "dead_and_masked":
+        assert np.isfinite(want).all()  # a dead lane's NaN unread
+    # (GSPMD's pull sums the shards' answers: a -0.0 comes back +0.0)
+    probe = jnp.asarray(np.clip(ids, 0, cap - 1))
+    np.testing.assert_array_equal(
+        np.asarray(pushed.pull(probe)), want[np.asarray(probe)])
+
+
+def test_an_add_push_under_dp_x_ps_keeps_the_one_place_push_under_gspmd(
+        mesh_devices, monkeypatch):
+    """``dp`` = 2 x ``ps`` = 2: the batch's lanes lie split over the workers,
+    no shard could take its keys without being sent the other worker's, so
+    where a TPU would have read the tile kernel the push stays XLA's under
+    GSPMD (or ``worker_reduce``), noted once; the table is the one-place
+    push's."""
+    import dataclasses
+
+    from flink_parameter_server_tpu.core import store as store_mod
+    from flink_parameter_server_tpu.ops import row_update
+    from flink_parameter_server_tpu.parallel.mesh import make_mesh
+
+    rng = np.random.default_rng(66)
+    cap, n = 40_000, 2_048
+    values = _init_values(cap, (128,))
+    mesh = make_mesh(2, 2, devices=mesh_devices[:4])
+    one = ShardedParamStore.from_values(jnp.asarray(values), layout="auto")
+    sharded = ShardedParamStore.from_values(
+        jnp.asarray(values), layout="auto", mesh=mesh)
+    ids = rng.integers(-2, cap + 2, n).astype(np.int32)
+    deltas = rng.normal(0, 1, (n, 128)).astype(np.float32)
+    args = (jnp.asarray(ids), jnp.asarray(deltas))
+    want, _ = store_mod.push_counted(one.spec, one.table, *args)
+    got, counted = jax.jit(
+        lambda t, i, d: store_mod.push_counted(sharded.spec, t, i, d)
+    )(sharded.table, *args)
+    assert counted is None
+    assert np.asarray(ShardedParamStore(sharded.spec, got).values()).tobytes() == (
+        np.asarray(ShardedParamStore(one.spec, want).values()).tobytes())
+    # on a TPU: 2,048 x 8 <= 20,000 rows a shard, the kernel's ground, refused
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(store_mod, "_REFUSALS_NOTED", set())
+    n0 = row_update.refusal_count()
+    with pytest.warns(RuntimeWarning, match="split over dp = 2 workers"):
+        arm = store_mod.arms(sharded.spec, push_lanes=n)
+    assert (arm.push, arm.on_shards) == ("xla_add", False)
+    with warnings.catch_warnings():
+        warnings.simplefilter("error")
+        assert store_mod.arms(
+            sharded.spec, push_lanes=n, lanes_over_workers=True
+        ).push == "xla_add"  # (a shard longer than the batch: no worker sums)
+    assert row_update.refusal_count() == n0 + 1
+    # ... and a shard shorter than eight batches never was the kernel's
+    assert store_mod.arms(sharded.spec, push_lanes=8 * n).push == "xla_add"
+    one_worker = dataclasses.replace(
+        sharded.spec, mesh=make_mesh(1, 4, devices=mesh_devices[:4]))
+    assert store_mod.arms(one_worker, push_lanes=n // 2).on_shards
+    assert not store_mod.arms(one_worker, push_lanes=n).on_shards  # 10,000 rows
+    assert row_update.refusal_count() == n0 + 1
+
+
 def _combine_descriptors(ids, lanes, size, block=256):
     """numpy: the DMAs ``ops/dedup._kernel_sums`` starts for a batch of
     ``lanes`` lanes whose LIVE ids are ``ids``, a stretch of ``size``
@@ -2398,6 +2571,10 @@ ARMS_ON_A_TPU = [
      65_536, 65_536, False, ("take", "xla_add", "", "", "", False), 0),
     ("dense 1 reg, 1,024+ <= rows / 8", (128,), "add", "auto", None, 40_000,
      5_000, 5_000, False, ("take", "tile_add", "", "", "", False), 0),
+    # cell 16 (PR 67): under ONE worker the kernel's push runs on the shards,
+    # read as a store of a shard's block is: 5,000 x 8 <= 160,000 / 4 rows
+    ("1 reg over ps 4, shard's rows / 8", (128,), "add", "auto", (1, 4),
+     160_000, 5_000, 5_000, False, ("take", "tile_add", "", "", "", True), 0),
     ("dense 1 reg, dp 4, shard <= lanes", (128,), "add", "auto", (4, 1), 96,
      256, 256, True, ("take", "worker_reduce", "", "", "", False), 0),
     ("packed k 7, lanes x 8 > rows", (17,), "add", "auto", None, 7_000,
@@ -2433,8 +2610,11 @@ ARMS_ON_A_TPU = [
     ("packed k 1, 5 regs", (2, 300), "add", "auto", None, 61,
      8_192, 8_192, False,
      ("packed_selects", "tile_add", "selects", "", "", False), 0),
-    ("5 regs under a mesh", (640,), "add", "auto", (1, 4), 61,
-     8_192, 8_192, False, ("take", "xla_add", "", "", "", False), 0),
+    ("5 regs over ps 4, dp 1", (640,), "add", "auto", (1, 4), 61,
+     8_192, 8_192, False, ("take", "tile_add", "", "", "", True), 0),
+    ("5 regs over ps 2, dp 2", (640,), "add", "auto", (2, 2), 61,
+     8_192, 8_192, False, ("take", "xla_add", "", "", "", False),
+     1),  # the push on the shards: the batch lies split over dp, noted
     ("3 lanes, held at its tile of 4", (3,), _RULE, "auto", None, 1_000,
      8_192, 8_192, False, ("narrow", "rule", "", "sort", "tile_set", False), 0),
     ("6 lanes, held at its tile of 8", (6,), _RULE, "auto", None, 1_000,
@@ -2519,6 +2699,9 @@ FIELDS = {
 # off a TPU: XLA's forms; where the push runs is read from the mesh alone
 ARMS_OFF_IT = {
     "dense 1 reg, 1,024+ <= rows / 8": ("take", "xla_add", "", "", "", False),
+    "1 reg over ps 4, shard's rows / 8": ("take", "xla_add", "", "", "", False),
+    "5 regs over ps 4, dp 1": ("take", "xla_add", "", "", "", False),
+    "5 regs over ps 2, dp 2": ("take", "xla_add", "", "", "", False),
     "dense 1 reg, dp 4, shard <= lanes": (
         "take", "worker_reduce", "", "", "", False),
     "packed k 2, 1,024+ <= rows / 8": (

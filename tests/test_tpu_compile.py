@@ -1235,7 +1235,11 @@ def test_the_mf_and_fm_cells_step_text_is_the_parents(cell, want, request):
     and ``d240e3e5008700fb`` until then; at full size for a described v5e
     cells 2, 4, 9 and 12 hash equal to the parent's with that output left
     out, every other cell but cell 10 as it stands: PERF.md section 6, PR
-    65); the other three did not move."""
+    65); the other three did not move.  PR 67 (an add store's push on the
+    shards that own its rows, the tile kernel's calls rolled into a loop
+    there, DLRM's init one program) moved none of the five, and at full
+    size for a described v5e every one of cells 1-15 hashes equal on its
+    parent and on its tree (the sixteen hashes: PERF.md section 6, PR 67)."""
     shape = jax.ShapeDtypeStruct
 
     def mf_batch(n, on=shape):
@@ -2002,6 +2006,128 @@ def test_dlrm_step_holds_nothing_table_sized_beside_its_12_58_gb_table(
     for scope in ("dense_bottom", "dense_interact", "dense_top", "delta_build"):
         assert f"jit(step)/ps.compute/ps.{scope}/" in text, scope
     assert "transpose(jvp(" not in text
+
+
+# dlrm-criteo-40m-ps4 (chipbench/configs): cell 16's table over four chips
+DLRM_PS4_ROWS, DLRM_PS4_SHARD_ROWS = 93_883_705, 23_470_928
+
+
+@pytest.fixture(scope="module")
+def dlrm_ps4(topo):
+    """``dlrm-criteo-40m-ps4`` as ``chipbench/families/dlrm.py`` builds it,
+    on the four described chips: its mesh, model, spec (nothing allocated)."""
+    from chipbench import spec as bench_spec
+    from flink_parameter_server_tpu.models import dlrm
+    from flink_parameter_server_tpu.parallel.mesh import make_mesh
+
+    cfg = bench_spec.load_json("chipbench/configs/dlrm-criteo-40m-ps4.json")
+    mesh = make_mesh(cfg["mesh"]["dp"], cfg["mesh"]["ps"], devices=topo.devices)
+    model = dlrm.DLRMConfig(
+        tuple(cfg["field_cardinalities"]), dim=cfg["dim"],
+        bottom_mlp=tuple(cfg["bottom_mlp"]), top_mlp=tuple(cfg["top_mlp"]),
+        learning_rate=cfg["learning_rate"])
+    assert model.num_rows == DLRM_PS4_ROWS and model.dim == 128
+    spec = jax.eval_shape(
+        lambda: dlrm.make_store(model, mesh=mesh, dtype=jnp.float32)).spec
+    assert (spec.layout, spec.pack, spec.update) == ("dense", 1, "add")
+    assert spec.rows_per_shard == DLRM_PS4_SHARD_ROWS
+    assert spec.table_shape() == (4 * DLRM_PS4_SHARD_ROWS, 128)
+    return mesh, model, spec, dlrm
+
+
+def test_dlrm_ps4_table_is_initialised_on_its_shards(dlrm_ps4, no_compile_cache):
+    """The seeded init of 93,883,705 x 128 f32 rows under a ``jit`` that takes
+    the seed, over four described chips: every chip's 12.02 GB block is the
+    program's only output there, 0.28 GB of temporaries beside it (the rows
+    are one register wide as they are drawn, so the init fuses into the
+    table's own write: no loop is needed where a packed table has one)."""
+    mesh, model, spec, dlrm = dlrm_ps4
+    compiled = jax.jit(
+        lambda seed: dlrm.make_store(
+            model, seed=seed, mesh=mesh, dtype=jnp.float32).table
+    ).lower(_shape(NamedSharding(mesh, PartitionSpec()), (), jnp.uint32)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes == DLRM_PS4_SHARD_ROWS * 128 * 4 == 12_017_115_136
+    assert mem.temp_size_in_bytes < 0.5 * GB
+    assert not COLLECTIVE_OP.search(compiled.as_text())
+
+
+def test_dlrm_ps4_step_adds_on_the_shard_that_owns_the_row(
+        dlrm_ps4, no_compile_cache, monkeypatch):
+    """Cell 16's step at full size for four described chips, as the chips run
+    it (asked for the backend): the push is ONE ``shard_map``
+    (``core/store._push_add_on_shards``), every chip's ``f32[23470928,128]``
+    block (12.02 GB) rewritten in place by the tile kernel, one call in a
+    loop over the batch's nine (851,968 lanes x 8 are under a SHARD's rows, where the TPU
+    compiler would leave the scatter-add it partitions serial: 74.7 ns a
+    lane), no XLA scatter, no copy of a block; the step's only collectives
+    are the pull's all-reduce of the gathered rows, ``f32[32768,26,128]``
+    (436 MB: GSPMD's, behind ``jnp.take`` of the row-sharded table), and the
+    32 bytes of the push's counts: no row of the table and no key crosses
+    chips for the push.  Table, MLPs and temporaries are 13.85 GB a chip,
+    under the 15.0 GB that decided one host of two against one of three
+    (``reduced_why``)."""
+    mesh, model, spec, dlrm = dlrm_ps4
+    n = FM_BATCH * DLRM_FIELDS
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    n0 = row_update.refusal_count()
+    assert store_mod.arms(
+        spec, pull_lanes=n, push_lanes=n, fields=DLRM_FIELDS
+    ) == store_mod.Arms("take", "tile_add", "", "", "", True)
+    assert row_update.refusal_count() == n0
+    assert n * store_mod._SERIAL_SCATTER_ROWS_A_LANE <= DLRM_PS4_SHARD_ROWS
+    everywhere = NamedSharding(mesh, PartitionSpec())
+    logic = dlrm.DLRM(model)
+    state = {
+        k: _shape(everywhere, v.shape, v.dtype) for k, v in jax.eval_shape(
+            lambda: logic.init_state(jax.random.PRNGKey(0))).items()
+    }
+    assert sum(v.size for v in state.values()) == 2_368_897
+    batch = {
+        "dense": _shape(everywhere, (FM_BATCH, 13), jnp.float32),
+        "ids": _shape(everywhere, (FM_BATCH, DLRM_FIELDS), jnp.int32),
+        "label": _shape(everywhere, (FM_BATCH,), jnp.float32),
+        "mask": _shape(everywhere, (FM_BATCH,), jnp.bool_),
+    }
+    compiled = jax.jit(
+        make_train_step(logic, spec), donate_argnums=(0, 1)
+    ).lower(
+        _shape(spec.sharding(), spec.table_shape(), jnp.float32), state, batch
+    ).compile()
+    mem = compiled.memory_analysis()
+    assert 12.02 * GB < mem.alias_size_in_bytes < 12.04 * GB  # in place, a chip
+    assert mem.temp_size_in_bytes < 2.0 * GB  # 1.83 here
+    assert mem.alias_size_in_bytes + mem.temp_size_in_bytes < 15.0 * GB
+    text = compiled.as_text()
+    lines = text.splitlines()
+    assert not re.search(r"f32\[23470928,128\]\S* (copy|transpose)\(", text)
+    assert "f32[93883712,128]" not in text  # no chip ever sees the whole table
+    assert not re.search(r" scatter\(", text)
+    collectives = [c for c in lines if COLLECTIVE_OP.search(c)]
+    shapes = sorted(
+        c.strip().split(" = ", 1)[1].split("{")[0] for c in collectives)
+    assert shapes == [f"f32[{FM_BATCH},{DLRM_FIELDS},128]", "s32[8]"], collectives
+    rows, = [c for c in collectives if " f32[" in c]
+    assert "ps.pull" in rows
+    # ONE kernel call in the step's text, in the body of the one `while`
+    # that walks the nine equal calls of the sorted batch and ends with the
+    # last that holds a lane of this shard's (PR 67: nine calls, unrolled,
+    # were nine bodies traced, lowered and loaded by every warm set-up)
+    assert -(-n // row_update.MAX_LANES) == 9
+    call, = [line for line in lines if "tpu_custom_call" in line]
+    assert "%sorted_row_update_tiles" in call
+    assert " = f32[23470928,128]{1,0" in call
+    assert "ps.push/shard_map/while/body/" in call
+    loop, = re.findall(r"^.* while\(.*$", text, re.M)
+    assert "f32[23470928,128]" in loop and "ps.push/shard_map/while" in loop
+    outs = jax.eval_shape(
+        make_train_step(logic, spec),
+        jax.ShapeDtypeStruct(spec.table_shape(), jnp.float32), state, batch)[2]
+    assert {"ps_push_kernel_lanes", "ps_push_tile_rows",
+            "ps_push_lanes_max_shard", "ps_push_tile_rows_max_shard"} <= set(outs)
+    assert "ps_slice_kernel" not in outs  # a dense store has no lane kernel
+    for scope in ("dense_bottom", "dense_interact", "dense_top", "delta_build"):
+        assert f"jit(step)/ps.compute/ps.{scope}/" in text, scope
 
 
 # glove-840b-300 (chipbench/configs): cell 13's table and batch
