@@ -19,9 +19,11 @@ from __future__ import annotations
 
 import abc
 import dataclasses
+import functools
 from typing import Any, Generic, Optional, Tuple, TypeVar
 
 import jax
+import jax.numpy as jnp
 
 Array = jax.Array
 State = TypeVar("State")
@@ -78,7 +80,17 @@ class BatchedWorkerLogic(abc.ABC, Generic[State, Batch, Out]):
         given, whose ``pulled`` is ``keys.shape + row``
         (``cluster/driver.ClusterDriver``):
         ``models/factorization_machine.FieldLanes`` answers a COPY that
-        computes field-major and stays example-major itself."""
+        computes field-major and stays example-major itself.  An answer
+        with ``example_blocks`` (a number; ``models/dlrm.DLRM``: a dense
+        net) says that its compute is a function of each example and of a
+        replicated state that only sums over the examples change, and that
+        it takes those sums over that many equal blocks of the minibatch
+        and adds the blocks' in their order (:func:`sums_by_blocks`):
+        under ONE worker group and ``ps`` > 1 servers
+        that divide the blocks ``make_train_step`` then splits the
+        minibatch's compute over the servers' own axis, whole blocks a chip,
+        and the result is the one-place step's bit for bit (its docstring
+        says how; absent, the whole minibatch is computed in every place)."""
         return self
 
     def key_router(self, *, registry=None, tracer=None):  # noqa: B027
@@ -109,4 +121,46 @@ class BatchedWorkerLogic(abc.ABC, Generic[State, Batch, Out]):
         return None
 
 
-__all__ = ["PushRequest", "BatchedWorkerLogic"]
+def sums_by_blocks(fn, blocks: int, *per_example):
+    """``fn(*per_example)``, a tree of sums over the examples (the leading
+    axis of every argument), taken over each of ``blocks`` equal blocks of
+    the examples ALONE and added block after block in the order of the
+    batch: an association that is written down, so whoever holds a block
+    computes the same bits.  In one place the blocks are slices.  Where
+    ``make_train_step`` has split the examples over a mesh's chips (it
+    traces the logic under that mesh's description then, and under none
+    otherwise) each chip runs ``fn`` on the whole blocks it holds, the
+    chips' sums are all-gathered, and every chip adds them itself: no sum
+    is a collective's, and the result is the one-place step's bit for bit.
+    ``blocks`` 1: ``fn`` as it is."""
+    if blocks == 1:
+        return fn(*per_example)
+    mesh = jax.sharding.get_abstract_mesh()
+
+    def by_block(*local, parts=blocks):
+        pieces = zip(*(jnp.split(x, parts) for x in local))
+        return [fn(*piece) for piece in pieces]
+
+    if mesh.empty:
+        parts = by_block(*per_example)
+    else:
+        over = tuple(name for name, size in mesh.shape.items() if size > 1)
+        held = blocks // mesh.size
+
+        def on_chip(*local):
+            return jax.tree.map(
+                lambda *sums: jnp.stack(sums), *by_block(*local, parts=held))
+
+        # (gathered by the partitioner, which names and places it)
+        split = jax.sharding.PartitionSpec(over)
+        gathered = jax.lax.with_sharding_constraint(
+            jax.shard_map(on_chip, in_specs=split, out_specs=split)(
+                *per_example),
+            jax.sharding.PartitionSpec())
+        parts = [jax.tree.map(lambda s, k=k: s[k], gathered)
+                 for k in range(blocks)]
+    return functools.reduce(
+        lambda done, part: jax.tree.map(jnp.add, done, part), parts)
+
+
+__all__ = ["PushRequest", "BatchedWorkerLogic", "sums_by_blocks"]

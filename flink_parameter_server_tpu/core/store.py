@@ -372,7 +372,11 @@ def pull(
     TPU's gather pays for neighbours that name one row:
     ``ops/packed.turned_slice_kernel``).  The arm ``packed_kernel_by_field``
     writes them turned (:func:`arms`' ``fields``); every other arm's answer
-    is turned as it stands (what a CPU runs; no cell)."""
+    is turned as it stands (what a CPU runs; no cell).
+
+    Whole rows of a dense table over ``ps`` > 1 servers under ONE worker
+    group (the arm ``take`` there: cell 16) are taken ON the shards and
+    summed (:func:`_take_on_shards`); GSPMD's gather keeps ``dp`` > 1."""
     if turned and ids.ndim != 2:
         raise ValueError(
             f"a turned pull takes a key block of two axes, (B, K): got "
@@ -386,7 +390,10 @@ def pull(
             pull(spec, table, ids, worker_part=worker_part), 0, 1)
     part = spec.worker_width if worker_part else None
     if arm == "take":
-        rows = jnp.take(table, ids, axis=0)
+        if spec.num_shards > 1 and worker_count(spec.mesh) == 1:
+            rows = _take_on_shards(spec, table, ids)
+        else:
+            rows = jnp.take(table, ids, axis=0)
         return rows if part is None else rows[..., :part]
     if arm == "narrow":
         return _narrow_pull(table, ids, part or spec.row_width)
@@ -1012,7 +1019,7 @@ def arms(
     =================================  ======================  =============  ===============  =========  ======  ========
     dense 1 reg, lanes x 8 > rows      take                    xla_add        -                no         1 3 11  27 30
     dense 1 reg, 1,024+ <= rows / 8    take                    tile_add       -                no         none    49
-    1 reg over ps 4, shard's rows / 8  take                    tile_add       -                yes        16      49 67
+    1 reg over ps 4, shard's rows / 8  take                    tile_add       -                yes        16      49 67 68
     dense 1 reg, dp 4, shard <= lanes  take                    worker_reduce  -                no         8       40
     packed k 7, lanes x 8 > rows       packed_kernel           xla_add        kernel           no         none    29 42 51
     the same over ps 4, dp 1           packed_kernel           xla_add        kernel           no         none    31 42 51
@@ -1067,7 +1074,10 @@ def arms(
     every shard walks all the batch's lanes), and where that reads
     ``xla_add`` the scatter stays GSPMD's, the program it was (cell 4: its
     lanes x 8 exceed a shard's 6.7 M physical rows, the compiler's sorted
-    form).  A mesh with one shard leaves the packed pull to GSPMD.
+    form).  A mesh with one shard leaves the packed pull to GSPMD.  ``take``
+    over ``ps`` under one worker group gathers on the shards too
+    (:func:`_take_on_shards`: cell 16 since PR 68); over ``dp`` > 1 it is
+    GSPMD's (a gather a shard and an all-reduce; no cell).
     ``worker_reduce`` wants a shard no longer than the batch, so that the
     per-worker sums it moves are no larger than the deltas.  A rule's write-back shifts a chunk at a time
     (:func:`_rewrite_packed`), so only an add push has a ``shift``.  A rule
@@ -1202,7 +1212,7 @@ def _preload_tile_kernel(spec: StoreSpec) -> None:
 
 def step_counts(
     spec: StoreSpec, counted: Optional[dict], *, pull_lanes: int,
-    push_lanes: int, fields: Optional[int] = None,
+    push_lanes: int, fields: Optional[int] = None, compute_parts: int = 1,
 ) -> dict:
     """What a step hands out of its pull and its push beside the logic's
     outputs: what :func:`push_counted` counted and, for a store packed
@@ -1218,8 +1228,14 @@ def step_counts(
     part (``StoreSpec.worker_width``) says how many lanes of a row crossed,
     a key: ``ps_pull_row_lanes`` here (a step pulls the worker's part) beside
     :func:`push_counted`'s ``ps_push_row_lanes``; every other store's whole
-    row crosses and its step hands out neither."""
+    row crosses and its step hands out neither.  ``compute_parts`` is the
+    caller's own reading (``make_train_step``): into how many parts over
+    ``ps`` it split the minibatch's compute, ``ps_compute_parts`` where that
+    is more than one (a step that computes the whole minibatch in every
+    place hands out no such output and keeps its text)."""
     out = dict(counted or {})
+    if compute_parts > 1:
+        out["ps_compute_parts"] = jnp.asarray(compute_parts, jnp.int32)
     if spec.worker_width is not None:
         out["ps_pull_row_lanes"] = jnp.asarray(spec.worker_width, jnp.int32)
     if spec.pack > 1:
@@ -1245,6 +1261,9 @@ def publish_counts(outs: dict, registry: Any, total, peak) -> None:
     calls this where it fetches them anyway
     (``StreamingDriver._publish_step_counts``).  The names are literal:
     ``tools/fpsanalyze`` matches them to the catalog."""
+    # (1 where the step says nothing: the whole minibatch in every place)
+    registry.gauge("store_compute_parts", component="train").set(
+        peak(outs["ps_compute_parts"]) if "ps_compute_parts" in outs else 1)
     if "ps_slice_kernel" in outs:
         registry.gauge(
             "store_packed_slice_kernel", component="train"
@@ -1476,8 +1495,7 @@ def _packed_pull_on_shards(
     ``packed_pull`` is."""
     from ..ops.packed import sub_row_slice
 
-    mesh, ps = spec.mesh, spec.ps_axis
-    k, rows = spec.pack, spec.rows_per_shard
+    mesh, ps, k = spec.mesh, spec.ps_axis, spec.pack
     others = tuple(a for a in mesh.axis_names if a != ps)
     if ids.shape[0] % (mesh.size // spec.num_shards):
         others = ()
@@ -1489,15 +1507,9 @@ def _packed_pull_on_shards(
         out = out[::-1]
 
     def on_shard(block: Array, ids: Array) -> Array:
-        rel = ids // k - jax.lax.axis_index(ps) * rows
-        mine = (rel >= 0) & (rel < rows)
-        # rows of other shards wrap round to rows spread over this block:
-        # clipped, they would all be its first or its last row, and a
-        # gather that keeps hitting one row takes twice as long a row
+        vals, mine = _own_rows(spec, block, ids // k)
         vals = sub_row_slice(
-            jnp.take(block, rel.reshape(-1), axis=0, mode="wrap"), ids,
-            spec.row_width, kernel, width, turned,
-        )
+            vals, ids, spec.row_width, kernel, width, turned)
         mine = mine.T if turned else mine
         return jnp.where(mine[..., None], vals, jnp.zeros_like(vals))[None]
 
@@ -1508,6 +1520,46 @@ def _packed_pull_on_shards(
         out_specs=P(ps, *out, None),
         check_vma=not kernel,  # a Pallas call states no varying axes
     )(table, ids).sum(axis=0)
+
+
+def _own_rows(spec: StoreSpec, block: Array, phys: Array):
+    """Inside a ``shard_map`` over ``ps``: ``(rows, mine)``, the rows of
+    this shard's ``block`` that the physical rows ``phys`` of the whole
+    table name, flat, and which of ``phys`` lie in it.  Rows of other
+    shards wrap round to rows spread over this block: clipped, they would
+    all be its first or its last row, and a gather that keeps hitting one
+    row takes longer a row (13.6 ns a 128-lane row against 9.9 at cell
+    16's size: PERF.md section 6, PR 68)."""
+    rows = spec.rows_per_shard
+    rel = phys - jax.lax.axis_index(spec.ps_axis) * rows
+    mine = (rel >= 0) & (rel < rows)
+    return jnp.take(block, rel.reshape(-1), axis=0, mode="wrap"), mine
+
+
+@functools.partial(jax.jit, static_argnums=(0,))
+def _take_on_shards(spec: StoreSpec, table: Array, ids: Array) -> Array:
+    """``jnp.take(table, ids, axis=0)`` (``ids`` pre-clipped, whole on every
+    chip) of a dense table sharded over ``ps`` under one worker group: each
+    shard gathers every lane from its own block and zeroes the rows it does
+    not own, as :func:`_packed_pull_on_shards` does, and the sum over the
+    shards is written OUTSIDE the ``shard_map`` and FLAT, ``(lanes, row)``,
+    for the partitioner to name and place: an all-reduce, or, where the
+    caller constrains the flat answer split over ``ps`` (``make_train_step``
+    for a logic whose compute it splits), a reduce-scatter that moves the
+    block as it lies.  Jitted for the reason ``packed_pull`` is."""
+
+    def on_shard(block: Array, lanes: Array) -> Array:
+        vals, mine = _own_rows(spec, block, lanes)
+        mine = mine.reshape(mine.shape + (1,) * (vals.ndim - 1))
+        return jnp.where(mine, vals, jnp.zeros_like(vals))[None]
+
+    flat = jax.shard_map(
+        on_shard,
+        mesh=spec.mesh,
+        in_specs=(spec.sharding().spec, P()),
+        out_specs=P(spec.ps_axis),
+    )(table, ids.reshape(-1)).sum(axis=0)
+    return flat.reshape(ids.shape + flat.shape[1:])
 
 
 def _packed_shift_on_mesh(

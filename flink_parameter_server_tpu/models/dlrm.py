@@ -25,7 +25,24 @@ of ``-lr dL/de`` for every pulled row; the store's ``add`` sums the deltas of
 a row that several examples name, in stream order.  Under a ``dp`` mesh the
 MLPs lie replicated and the batch split (``core/transform.make_train_step``
 constrains it); the reduction of the dense gradients over the workers is the
-partitioner's, nothing here names it.
+partitioner's, nothing here names it.  Under a mesh of ONE worker group and
+``ps`` > 1 servers (the embedding tables over the servers, the source's
+model-parallel half) the minibatch's compute lies split over the servers'
+own axis, the data-parallel half: the logic declares ``example_blocks``, and
+``make_train_step`` constrains ``pulled`` and the deltas to lie split over
+``ps`` on the examples' axis, so every chip runs this step's dense ops on its
+share of the examples.  Every ``a^t d`` and bias sum is taken over
+``example_blocks`` (4) equal blocks of the minibatch, each block's alone, and
+the blocks' sums are added in the order of the batch
+(``core/batched.sums_by_blocks``): in one place the blocks are slices, on the
+chips each holds whole blocks, their sums are gathered and every chip adds
+them itself.  So the SGD step is taken on the whole minibatch's gradient on
+every chip, and the MLPs do not depend on the number of servers: the
+``ps`` = 4 step is the one-place step bit for bit.  ``examples``, the loss's
+normaliser, is the count of the WHOLE minibatch's live examples: it is taken
+of the mask, which no constraint splits.  Cell 16 computed the whole dense
+net on each of its four chips until then (24.1 ms of a 63.5 ms step: PERF.md
+section 6, PR 68).
 
 The order of the step's lanes is ``FieldLanes``' (the mixin of the logics
 whose batch is examples of fields, ``models/factorization_machine``): the
@@ -71,7 +88,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..core.batched import BatchedWorkerLogic, PushRequest
+from ..core.batched import BatchedWorkerLogic, PushRequest, sums_by_blocks
 from ..core.store import InitFn, ShardedParamStore
 from ..training.tracing import scope
 from ..utils.initializers import ranged_random_factor
@@ -153,16 +170,35 @@ def _mlp_forward(state, name: str, x: Array, depth: int, last_relu: bool):
     return acts
 
 
-def _mlp_backward(state, name: str, acts, d: Array, last_relu: bool):
+def _over_examples(a: Array, d: Array):
+    """``(a^t d, d's column sums)``: a layer's two sums over the examples."""
+    return _dot(a.T, d), d.sum(axis=0)
+
+
+def _over_examples_to_one_output(a: Array, d: Array):
+    """:func:`_over_examples` of a layer with ONE output, ``a^t d`` written
+    as the weighted sum of ``a``'s rows that it is: a backend's
+    matrix-vector forms differ between a block that is a slice and a block
+    that is a chip's own (the CPU's by bits), a sum's do not."""
+    return (a * d).sum(axis=0)[:, None], d.sum(axis=0)
+
+
+def _mlp_backward(
+    state, name: str, acts, d: Array, last_relu: bool, blocks: int = 1,
+):
     """``d`` is dL/d(output) of the MLP; returns ``(gradients by leaf,
     dL/d(input))``.  A ReLU's output is positive exactly where its input
-    was, so the mask is read from the activation kept."""
+    was, so the mask is read from the activation kept.  ``blocks``: a
+    layer's sums over the examples are ``core/batched.sums_by_blocks``'."""
     grads, depth = {}, len(acts) - 1
     for i in reversed(range(depth)):
         if last_relu or i < depth - 1:
             d = jnp.where(acts[i + 1] > 0, d, 0.0)
-        grads[f"{name}{i}_w"] = _dot(acts[i].T, d)
-        grads[f"{name}{i}_b"] = d.sum(axis=0)
+        sums = _over_examples
+        if blocks > 1 and d.shape[-1] == 1:
+            sums = _over_examples_to_one_output
+        grads[f"{name}{i}_w"], grads[f"{name}{i}_b"] = sums_by_blocks(
+            sums, blocks, acts[i], d)
         d = _dot(d, state[f"{name}{i}_w"].T)
     return grads, d
 
@@ -217,6 +253,12 @@ class DLRM(FieldLanes, BatchedWorkerLogic):
     ``dlrm_dense_flops_per_step`` (model FLOPs: 2 a multiply-add, the
     backward pass twice the forward)."""
 
+    # every op of `step` is a function of one example and of the MLPs, which
+    # only sums over the examples change, and those are taken over this many
+    # equal blocks of the minibatch and added in the blocks' order
+    # (`sums_by_blocks`; `BatchedWorkerLogic.for_workers` says who reads it)
+    example_blocks = 4
+
     def __init__(self, config: DLRMConfig, *, seed=0):
         self.config = config
         self.seed = seed
@@ -246,6 +288,9 @@ class DLRM(FieldLanes, BatchedWorkerLogic):
         x = batch["dense"].astype(jnp.float32)
         lower_i, lower_j, both = pair_tables(cfg.fields + 1)
         n_bot, n_top = len(cfg.bottom_mlp), len(cfg.top_mlp)
+        blocks = self.example_blocks
+        if x.shape[0] % blocks:  # a ragged minibatch is one block
+            blocks = 1
 
         def by_example(rows):
             # the rows of the copy that `pulls_turned`, `(fields, B, dim)`,
@@ -271,7 +316,7 @@ class DLRM(FieldLanes, BatchedWorkerLogic):
                 live, -sign / (1.0 + jnp.exp(sign * logit)), 0.0
             ) / examples
             grads, d_r = _mlp_backward(
-                state, "top", top, d_logit[:, None], False
+                state, "top", top, d_logit[:, None], False, blocks
             )
         with scope("ps.dense_interact"):
             # dZ + dZ^t as ONE product with a 0/1 matrix: pair (i, j)'s
@@ -286,7 +331,8 @@ class DLRM(FieldLanes, BatchedWorkerLogic):
         with scope("ps.dense_bottom"):
             bot_grads, _ = _mlp_backward(
                 state, "bot", bot,
-                d_r[:, :cfg.dim] + jnp.squeeze(d_z0, self.field_axis), True
+                d_r[:, :cfg.dim] + jnp.squeeze(d_z0, self.field_axis), True,
+                blocks,
             )
             grads.update(bot_grads)
         with scope("ps.dense_sgd"):

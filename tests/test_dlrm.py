@@ -337,6 +337,8 @@ class _ParentsDLRM(dlrm.DLRM):
         x = batch["dense"].astype(jnp.float32)
         lower_i, lower_j = np.tril_indices(cfg.fields + 1, -1)
         n_bot, n_top = len(cfg.bottom_mlp), len(cfg.top_mlp)
+        # (the sums over the examples in the logic's blocks: PR 68)
+        blocks = 1 if x.shape[0] % self.example_blocks else self.example_blocks
         bot = dlrm._mlp_forward(state, "bot", x, n_bot, True)
         t = jnp.concatenate([bot[-1][:, None, :], pulled], axis=1)
         z = jnp.einsum("bid,bjd->bij", t, t, precision=p)
@@ -348,12 +350,12 @@ class _ParentsDLRM(dlrm.DLRM):
         d_logit = jnp.where(
             live, -sign / (1.0 + jnp.exp(sign * logit)), 0.0) / examples
         grads, d_r = dlrm._mlp_backward(
-            state, "top", top, d_logit[:, None], False)
+            state, "top", top, d_logit[:, None], False, blocks)
         d_z = jnp.zeros_like(z).at[:, lower_i, lower_j].set(d_r[:, cfg.dim:])
         d_t = jnp.einsum(
             "bij,bjd->bid", d_z + d_z.swapaxes(1, 2), t, precision=p)
         bot_grads, _ = dlrm._mlp_backward(
-            state, "bot", bot, d_r[:, :cfg.dim] + d_t[:, 0], True)
+            state, "bot", bot, d_r[:, :cfg.dim] + d_t[:, 0], True, blocks)
         grads.update(bot_grads)
         state = {k: v - lr * grads[k] for k, v in state.items()}
         out = {"prediction": jax.nn.sigmoid(logit),
@@ -591,8 +593,9 @@ def test_the_scopes_are_whole_path_components_forward_and_backward():
     assert "transpose(jvp(" not in text
     names = set(__import__("re").findall(r'"(jit\(step\)/ps\.compute/[^"]*)"', text))
     dots = [n for n in names if n.endswith("dot_general")]
-    # (a layer's three products share one name; the interaction has its two
-    # batched products and the 0/1 product that puts the triangle back)
+    # (a layer's three products share one name, whichever block of the
+    # examples an `a^t d` is taken over; the interaction has its two batched
+    # products and the 0/1 product that puts the triangle back)
     assert len(dots) == 5
     for n in dots:
         assert program_trace.SCOPE.findall(n)[-1] in (
@@ -634,3 +637,211 @@ def test_a_store_of_two_rows_to_a_physical_row_is_a_dense_one_bit_for_bit(capaci
     again = ShardedParamStore.from_spec_values(packed.spec, packed.values())
     assert again.table.shape == packed.table.shape
     assert np.array_equal(np.asarray(again.values()), got, equal_nan=True)
+
+
+# -- the compute split over the servers' own axis (PR 68) ---------------------
+# Under `make_mesh(1, ps)` a logic that declares `example_blocks` has its
+# minibatch's compute split over `ps`; every other logic, and every logic
+# without such a mesh, computes the whole minibatch in every place.
+
+
+def _split_config(dim):
+    return dlrm.DLRMConfig(
+        CARDS, dense_features=5, dim=dim, bottom_mlp=(16, dim),
+        top_mlp=(24, 12, 1), learning_rate=0.1)
+
+
+def _one_step(logic, store, state, batch):
+    table, state, out = jax.jit(make_train_step(logic, store.spec))(
+        store.table, state, batch)
+    return ShardedParamStore(store.spec, table), state, out
+
+
+@pytest.mark.parametrize("masked", [(5,), tuple(range(8, 16)) + (3, 30)],
+                         ids=["one_dead", "a_quarter_dead"])
+@pytest.mark.parametrize("dim, push, ps", [
+    (8, "xla", 4), (128, "xla", 4), (128, "tiles", 4), (128, "tiles", 2)])
+def test_at_ps_4_the_compute_is_split_and_the_step_is_the_one_place_steps(
+        dim, push, ps, masked, devices, steer_arms):
+    """``make_mesh(1, 4)``: every chip computes the dense net on ITS quarter
+    of the examples (``ps`` = 2: on its half, two blocks a chip).  TWO steps
+    leave the one-place step's table AND MLPs bit for bit: the per-example
+    arithmetic is untouched, the push takes the gathered deltas in the
+    batch's order (XLA's scatter-add at two widths, and cell 16's own push,
+    the tile kernel on the shards that own the rows, interpreted), and every
+    dense gradient is the sum of four blocks' own sums added in the batch's
+    order, wherever the blocks lie (``core/batched.sums_by_blocks``; with
+    ONE sum of a chip's share and an all-reduce the second step's rows
+    differed in their last bits); the MLPs are equal on the chips bit for
+    bit; and the loss is normalised by the WHOLE minibatch's live count
+    whichever quarter the dead examples lie in (examples 8-15 are one chip's
+    quarter: a local mean would divide that chip's gradients by 1 and the
+    others' by 7, 8 and 7)."""
+    if push == "tiles":
+        steer_arms(push="tile_add")
+    config = _split_config(dim)
+    logic = dlrm.DLRM(config, seed=2)
+    mesh = make_mesh(1, ps, devices=devices[:ps])
+    state = logic.init_state(jax.random.PRNGKey(0))
+    one = dlrm.make_store(config, seed=2)
+    got_store, want_store = dlrm.make_store(config, seed=2, mesh=mesh), one
+    got = want = state
+    rows = sum(CARDS)
+    for batch in _batches(6, 2, masked=masked):
+        got_store, got, out = _one_step(logic, got_store, got, batch)
+        want_store, want, want_out = _one_step(logic, want_store, want, batch)
+        assert int(out["ps_compute_parts"]) == ps
+        assert "ps_compute_parts" not in want_out
+        if push == "tiles":
+            assert int(out["ps_push_kernel_lanes"]) == batch["ids"].size
+        _same_bits(np.asarray(got_store.values())[:rows],
+                   np.asarray(want_store.values())[:rows])
+        for k in ("prediction", "loss"):
+            _same_bits(np.asarray(out[k]), np.asarray(want_out[k]))
+        for leaf, x in got.items():
+            assert x.sharding.is_fully_replicated, leaf
+            chips = [np.asarray(s.data) for s in x.addressable_shards]
+            assert len(chips) == ps
+            for chip in chips[1:]:
+                _same_bits(chip, chips[0])
+            _same_bits(np.asarray(x), np.asarray(want[leaf]))
+            assert not np.array_equal(np.asarray(x), np.asarray(state[leaf]))
+    assert not np.array_equal(
+        np.asarray(want_store.values())[:rows], np.asarray(one.values())[:rows])
+
+
+@pytest.mark.parametrize("blocks", [1, 2, 4])
+def test_sums_by_blocks_is_the_written_association(blocks, devices):
+    """``core/batched.sums_by_blocks``: each block's sums alone, then the
+    blocks' added one by one in the batch's order, whatever tree ``fn``
+    answers; in one place, and traced under a mesh's description with the
+    examples split over its chips (what ``make_train_step`` does), the same
+    bits; one block is ``fn`` as it is."""
+    from flink_parameter_server_tpu.core.batched import sums_by_blocks
+
+    rng = np.random.default_rng(blocks)
+    a = jnp.asarray(rng.normal(size=(64, 5)).astype(np.float32) * 1e3)
+    d = jnp.asarray(rng.normal(size=(64, 3)).astype(np.float32))
+
+    def fn(a, d):
+        return {"w": a.T @ d, "b": (d.sum(axis=0), a.sum())}
+
+    @jax.jit
+    def written_out(a, d):
+        done = None
+        for a_k, d_k in zip(jnp.split(a, blocks), jnp.split(d, blocks)):
+            part = fn(a_k, d_k)
+            done = part if done is None else jax.tree.map(jnp.add, done, part)
+        return done
+
+    want = written_out(a, d)
+    got = jax.jit(lambda a, d: sums_by_blocks(fn, blocks, a, d))(a, d)
+    _same_bits(got, want)
+    if blocks == 1:
+        _same_bits(got, jax.jit(fn)(a, d))
+        return
+    mesh = make_mesh(1, 2, devices=devices[:2])
+    split = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec("ps"))
+
+    def on_the_mesh(a, d):
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+            return sums_by_blocks(fn, blocks, a, d)
+
+    placed = jax.jit(on_the_mesh)(
+        jax.device_put(a, split), jax.device_put(d, split))
+    _same_bits(placed, want)
+    assert all(x.sharding.is_fully_replicated for x in jax.tree.leaves(placed))
+
+
+def test_at_ps_4_the_step_is_the_plain_reference(devices):
+    mesh = make_mesh(1, 4, devices=devices[:4])
+    logic = dlrm.DLRM(CONFIG, seed=2)
+    store = dlrm.make_store(CONFIG, seed=2, mesh=mesh)
+    state = logic.init_state(jax.random.PRNGKey(0))
+    batches = _batches(6, 2, masked=tuple(range(8, 16)))
+
+    def after(store, state, batches):
+        result = transform_batched(
+            iter(batches), logic, store, initial_state=state, mesh=mesh,
+            dump_model=False, collect_outputs=False,
+        )
+        return result.store, result.worker_state
+
+    (failures, worst), _, _, _ = _against_the_reference(
+        logic, store, state, batches, after
+    )
+    assert failures == [] and 0 < worst["share"] < 0.5, worst
+    text = jax.jit(make_train_step(logic, store.spec)).lower(
+        store.table, state, batches[0]).as_text()
+    # the two constraints `make_train_step` writes and the logic's own, a
+    # layer's two block sums held whole in every place: nothing else names
+    # a placement
+    assert text.count("sdy.sharding_constraint") + text.count(
+        "custom_call @Sharding") == 2 + 2 * len(CONFIG.layers()), text
+
+
+def _fm_family(model, mesh):
+    from flink_parameter_server_tpu.models import difacto as df
+    from flink_parameter_server_tpu.models import factorization_machine as fmm
+
+    rng = np.random.default_rng(4)
+    batch = {
+        "ids": rng.integers(0, 400, (32, 4)).astype(np.int32),
+        "values": rng.uniform(0.1, 1, (32, 4)).astype(np.float32),
+        "feat_mask": np.ones((32, 4), bool),
+        "label": rng.choice([-1.0, 1.0], 32).astype(np.float32),
+        "mask": np.ones(32, bool),
+    }
+    if model == "fm":
+        cfg = fmm.FMConfig(num_features=400, dim=8, learning_rate=0.05)
+        return fmm.FactorizationMachine(cfg), fmm.make_store(cfg, mesh=mesh), batch
+    cfg = df.DiFactoConfig(400, 8)
+    return df.DiFacto(cfg), df.make_store(cfg, mesh=mesh), batch
+
+
+@pytest.mark.parametrize("case", [
+    "dlrm_ps_4", "dlrm_ps_2", "dlrm_ps_4_ragged", "dlrm_dp_2_ps_2", "dlrm_dp_2",
+    "dlrm_one_place", "fm_ps_4", "difacto_ps_4", "fm_one_place",
+    "difacto_one_place"])
+def test_the_split_engages_by_the_mesh_and_the_logics_declaration_alone(
+        case, devices):
+    """The new count, among the step's outputs where it is more than 1 and
+    the gauge ``store_compute_parts`` from the driver everywhere: DLRM under
+    one worker group and ``ps`` servers reads ``ps`` (a batch that does not
+    divide over them, and any ``dp`` > 1, keep the whole minibatch in every
+    place); FM and DiFacto do not declare and read 1 under the same mesh,
+    their lowered step naming no constraint; every logic without a mesh
+    reads 1."""
+    from flink_parameter_server_tpu.telemetry.registry import MetricsRegistry
+
+    model, _, placed = case.partition("_")
+    shape = {"ps_4": (1, 4), "ps_2": (1, 2), "ps_4_ragged": (1, 4),
+             "dp_2_ps_2": (2, 2), "dp_2": (2, 1)}.get(placed)
+    mesh = None
+    if shape is not None:
+        mesh = make_mesh(*shape, devices=devices[:shape[0] * shape[1]])
+    if model == "dlrm":
+        logic = dlrm.DLRM(CONFIG, seed=1)
+        store = dlrm.make_store(CONFIG, seed=1, mesh=mesh)
+        batch = _batches(3, 1, batch=30 if placed == "ps_4_ragged" else 32)[0]
+        assert logic.example_blocks
+    else:
+        logic, store, batch = _fm_family(model, mesh)
+        assert not getattr(logic, "example_blocks", None)
+    want = {"dlrm_ps_4": 4, "dlrm_ps_2": 2}.get(case, 1)
+    state = logic.init_state(jax.random.PRNGKey(0))
+    step = jax.jit(make_train_step(logic, store.spec))
+    lowered = step.lower(store.table, state, batch).as_text()
+    constraints = lowered.count("sdy.sharding_constraint") + lowered.count(
+        "custom_call @Sharding")
+    if shape is None or shape[0] == 1:
+        # (`pulled`, the deltas, and a DLRM layer's two block sums)
+        assert constraints == (
+            2 + 2 * len(CONFIG.layers()) if want > 1 else 0)
+    out = step(store.table, state, batch)[2]
+    assert ("ps_compute_parts" in out) == (want > 1)
+    assert int(out.get("ps_compute_parts", 1)) == want
+    registry = MetricsRegistry()
+    StreamingDriver(logic, store, registry=registry,
+                    config=DriverConfig(dump_model=False)).run(iter([batch]))
+    assert registry.snapshot()["store_compute_parts"][0]["value"] == want

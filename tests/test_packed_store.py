@@ -346,12 +346,14 @@ def test_the_driver_says_which_arm_sliced_its_pulled_rows(arm, monkeypatch,
         seen[layout] = {k: v[0]["value"] for k, v in reg.snapshot().items()
                         if k.startswith("store_")}
     packed_mod.packed_pull.clear_cache()
+    # (`store_compute_parts`, PR 68: the whole minibatch computed in one
+    # place; every driver sets it)
     assert seen == {
-        "auto": {"store_layout_packed": 1.0,
+        "auto": {"store_layout_packed": 1.0, "store_compute_parts": 1.0,
                  "store_packed_slice_kernel": float(arm != "select"),
                  "store_packed_shift_kernel": float(arm != "select"),
                  "store_packed_by_field": float(arm == "kernel_by_field")},
-        "dense": {"store_layout_packed": 0.0},
+        "dense": {"store_layout_packed": 0.0, "store_compute_parts": 1.0},
     }
 
 

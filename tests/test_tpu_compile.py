@@ -10,6 +10,7 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
 
@@ -1239,7 +1240,16 @@ def test_the_mf_and_fm_cells_step_text_is_the_parents(cell, want, request):
     shards that own its rows, the tile kernel's calls rolled into a loop
     there, DLRM's init one program) moved none of the five, and at full
     size for a described v5e every one of cells 1-15 hashes equal on its
-    parent and on its tree (the sixteen hashes: PERF.md section 6, PR 67)."""
+    parent and on its tree (the sixteen hashes: PERF.md section 6, PR 67).
+    PR 68 (the minibatch's compute split over the servers' own axis where a
+    logic declares ``example_blocks`` under one worker group and ``ps`` > 1:
+    cell 16 alone) moved none of the five: a step that computes the whole
+    minibatch in every place hands out no new output and names no
+    constraint, and at full size for a described v5e every one of cells
+    1-15 BUT CELL 10 hashes equal on its parent and on its tree; cell 10's
+    text moved and was meant to (its dense gradients are summed in the four
+    blocks the chips of cell 16 hold, so that the MLPs do not depend on
+    ``ps``: PERF.md section 6, PR 68)."""
     shape = jax.ShapeDtypeStruct
 
     def mf_batch(n, on=shape):
@@ -2052,63 +2062,104 @@ def test_dlrm_ps4_table_is_initialised_on_its_shards(dlrm_ps4, no_compile_cache)
     assert not COLLECTIVE_OP.search(compiled.as_text())
 
 
-def test_dlrm_ps4_step_adds_on_the_shard_that_owns_the_row(
-        dlrm_ps4, no_compile_cache, monkeypatch):
-    """Cell 16's step at full size for four described chips, as the chips run
-    it (asked for the backend): the push is ONE ``shard_map``
-    (``core/store._push_add_on_shards``), every chip's ``f32[23470928,128]``
-    block (12.02 GB) rewritten in place by the tile kernel, one call in a
-    loop over the batch's nine (851,968 lanes x 8 are under a SHARD's rows, where the TPU
-    compiler would leave the scatter-add it partitions serial: 74.7 ns a
-    lane), no XLA scatter, no copy of a block; the step's only collectives
-    are the pull's all-reduce of the gathered rows, ``f32[32768,26,128]``
-    (436 MB: GSPMD's, behind ``jnp.take`` of the row-sharded table), and the
-    32 bytes of the push's counts: no row of the table and no key crosses
-    chips for the push.  Table, MLPs and temporaries are 13.85 GB a chip,
-    under the 15.0 GB that decided one host of two against one of three
-    (``reduced_why``)."""
+@pytest.fixture(scope="module")
+def dlrm_ps4_step(dlrm_ps4):
+    """Cell 16's step at full size compiled ONCE for four described chips,
+    as the chips run it (asked for the backend): its compiled text, its
+    memory analysis and the shapes of the step's outputs."""
+    from jax.experimental.compilation_cache import compilation_cache
+
     mesh, model, spec, dlrm = dlrm_ps4
     n = FM_BATCH * DLRM_FIELDS
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    n0 = row_update.refusal_count()
-    assert store_mod.arms(
-        spec, pull_lanes=n, push_lanes=n, fields=DLRM_FIELDS
-    ) == store_mod.Arms("take", "tile_add", "", "", "", True)
-    assert row_update.refusal_count() == n0
+    backend, cached = jax.default_backend, jax.config.jax_enable_compilation_cache
+    jax.default_backend = lambda: "tpu"
+    # a described device's executable cannot be read back from the cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        n0 = row_update.refusal_count()
+        assert store_mod.arms(
+            spec, pull_lanes=n, push_lanes=n, fields=DLRM_FIELDS
+        ) == store_mod.Arms("take", "tile_add", "", "", "", True)
+        assert row_update.refusal_count() == n0
+        everywhere = NamedSharding(mesh, PartitionSpec())
+        logic = dlrm.DLRM(model)
+        state = {
+            k: _shape(everywhere, v.shape, v.dtype) for k, v in jax.eval_shape(
+                lambda: logic.init_state(jax.random.PRNGKey(0))).items()
+        }
+        assert sum(v.size for v in state.values()) == 2_368_897
+        batch = {
+            "dense": _shape(everywhere, (FM_BATCH, 13), jnp.float32),
+            "ids": _shape(everywhere, (FM_BATCH, DLRM_FIELDS), jnp.int32),
+            "label": _shape(everywhere, (FM_BATCH,), jnp.float32),
+            "mask": _shape(everywhere, (FM_BATCH,), jnp.bool_),
+        }
+        compiled = jax.jit(
+            make_train_step(logic, spec), donate_argnums=(0, 1)
+        ).lower(
+            _shape(spec.sharding(), spec.table_shape(), jnp.float32), state,
+            batch,
+        ).compile()
+        outs = jax.eval_shape(
+            make_train_step(logic, spec),
+            jax.ShapeDtypeStruct(spec.table_shape(), jnp.float32), state,
+            batch)[2]
+    finally:
+        jax.default_backend = backend
+        jax.config.update("jax_enable_compilation_cache", cached)
+        compilation_cache.reset_cache()
+    return compiled.as_text(), compiled.memory_analysis(), outs
+
+
+def _collectives(text):
+    """``{channel id: (op, result shapes)}`` of a compiled step's
+    collectives (an async one is spelled several times, one channel)."""
+    found = {}
+    for line in text.splitlines():
+        op = COLLECTIVE_OP.search(line)
+        if op:
+            shapes = line.split(" = ", 1)[1].split(op.group(0))[0]
+            found[re.search(r"channel_id=(\d+)", line).group(1)] = (
+                op.group(1), tuple(re.findall(r"[a-z]+\d+\[[\d,]*\]", shapes)),
+                line)
+    return found
+
+
+def test_dlrm_ps4_step_adds_on_the_shard_that_owns_the_row(dlrm_ps4_step):
+    """Cell 16's step at full size for four described chips: the push is ONE
+    ``shard_map`` (``core/store._push_add_on_shards``), every chip's
+    ``f32[23470928,128]`` block (12.02 GB) rewritten in place by the tile
+    kernel, one call in a loop over the batch's nine (851,968 lanes x 8 are
+    under a SHARD's rows, where the TPU compiler would leave the scatter-add
+    it partitions serial: 74.7 ns a lane), no XLA scatter, no copy of a
+    block; no row of the table and no key crosses chips for the push: what
+    crosses in front of it is ONE all-gather of the delta block
+    ``f32[26,32768,128]``, each chip's quarter of the examples built where
+    its dense net ran (PR 68), and behind it the 32 bytes of its counts.
+    Table, MLPs and temporaries are 12.92 GB a chip (13.85 until PR 68),
+    under the 15.0 GB that decided one host of two against one of three
+    (``reduced_why``)."""
+    text, mem, outs = dlrm_ps4_step
+    n = FM_BATCH * DLRM_FIELDS
     assert n * store_mod._SERIAL_SCATTER_ROWS_A_LANE <= DLRM_PS4_SHARD_ROWS
-    everywhere = NamedSharding(mesh, PartitionSpec())
-    logic = dlrm.DLRM(model)
-    state = {
-        k: _shape(everywhere, v.shape, v.dtype) for k, v in jax.eval_shape(
-            lambda: logic.init_state(jax.random.PRNGKey(0))).items()
-    }
-    assert sum(v.size for v in state.values()) == 2_368_897
-    batch = {
-        "dense": _shape(everywhere, (FM_BATCH, 13), jnp.float32),
-        "ids": _shape(everywhere, (FM_BATCH, DLRM_FIELDS), jnp.int32),
-        "label": _shape(everywhere, (FM_BATCH,), jnp.float32),
-        "mask": _shape(everywhere, (FM_BATCH,), jnp.bool_),
-    }
-    compiled = jax.jit(
-        make_train_step(logic, spec), donate_argnums=(0, 1)
-    ).lower(
-        _shape(spec.sharding(), spec.table_shape(), jnp.float32), state, batch
-    ).compile()
-    mem = compiled.memory_analysis()
-    assert 12.02 * GB < mem.alias_size_in_bytes < 12.04 * GB  # in place, a chip
-    assert mem.temp_size_in_bytes < 2.0 * GB  # 1.83 here
-    assert mem.alias_size_in_bytes + mem.temp_size_in_bytes < 15.0 * GB
-    text = compiled.as_text()
     lines = text.splitlines()
+    assert 12.02 * GB < mem.alias_size_in_bytes < 12.04 * GB  # in place, a chip
+    # (no more than the parent's 1.82: 0.89 here, a quarter of the
+    # activations and one block of rows each side)
+    assert mem.temp_size_in_bytes < 1.0 * GB
+    assert mem.alias_size_in_bytes + mem.temp_size_in_bytes < 15.0 * GB
     assert not re.search(r"f32\[23470928,128\]\S* (copy|transpose)\(", text)
     assert "f32[93883712,128]" not in text  # no chip ever sees the whole table
     assert not re.search(r" scatter\(", text)
-    collectives = [c for c in lines if COLLECTIVE_OP.search(c)]
-    shapes = sorted(
-        c.strip().split(" = ", 1)[1].split("{")[0] for c in collectives)
-    assert shapes == [f"f32[{FM_BATCH},{DLRM_FIELDS},128]", "s32[8]"], collectives
-    rows, = [c for c in collectives if " f32[" in c]
-    assert "ps.pull" in rows
+    found = _collectives(text)
+    gathers = [c for c in found.values()
+               if c[0] == "all-gather" and "ps.compute" not in c[2]]
+    (_, shapes, line), = gathers
+    assert shapes == (f"f32[{DLRM_FIELDS},{FM_BATCH},128]",), gathers
+    assert "ps.push" in line
+    counts, = [c for c in found.values() if c[1] == ("s32[8]",)]
+    assert counts[0] == "all-reduce"
     # ONE kernel call in the step's text, in the body of the one `while`
     # that walks the nine equal calls of the sorted batch and ends with the
     # last that holds a lane of this shard's (PR 67: nine calls, unrolled,
@@ -2120,14 +2171,72 @@ def test_dlrm_ps4_step_adds_on_the_shard_that_owns_the_row(
     assert "ps.push/shard_map/while/body/" in call
     loop, = re.findall(r"^.* while\(.*$", text, re.M)
     assert "f32[23470928,128]" in loop and "ps.push/shard_map/while" in loop
-    outs = jax.eval_shape(
-        make_train_step(logic, spec),
-        jax.ShapeDtypeStruct(spec.table_shape(), jnp.float32), state, batch)[2]
     assert {"ps_push_kernel_lanes", "ps_push_tile_rows",
             "ps_push_lanes_max_shard", "ps_push_tile_rows_max_shard"} <= set(outs)
     assert "ps_slice_kernel" not in outs  # a dense store has no lane kernel
     for scope in ("dense_bottom", "dense_interact", "dense_top", "delta_build"):
         assert f"jit(step)/ps.compute/ps.{scope}/" in text, scope
+
+
+def test_dlrm_ps4_step_computes_its_dense_net_once(dlrm_ps4_step):
+    """The same compiled step (PR 68): the minibatch's compute split over
+    the servers' own axis.  The pull's all-reduce of ``f32[32768,26,128]``
+    (436 MB handed whole to every chip) is gone: every shard gathers all
+    851,968 lanes from its own block (``core/store._take_on_shards``) and
+    the sum over ``ps`` is the TPU's fused all-reduce-and-scatter on the
+    FLAT block, a chip left its quarter of the examples' rows (212,992 of
+    them; a halo of 1,056 rows goes to the neighbour by one permute, the
+    scatter's blocks being the padded block's quarters); every dense product
+    and every pass of the interaction runs on 8,192 examples; the dense
+    gradients cross as the 16 leaves' BLOCK sums, a chip's one block of four
+    gathered leaf by leaf (4 x 9,475,588 bytes held a chip), and every chip adds
+    the four in the batch's order (``models/dlrm._over_examples``): no sum
+    of theirs is a collective's, so the MLPs are the one-place step's bit
+    for bit; the rows' deltas are built on the quarter.  The step's outputs
+    say 4 parts."""
+    text, mem, outs = dlrm_ps4_step
+    quarter = FM_BATCH // 4
+    assert "ps_compute_parts" in outs
+    assert f"f32[{FM_BATCH},{DLRM_FIELDS},128]" not in text
+    found = _collectives(text)
+    assert sorted(c[0] for c in found.values()) == (
+        ["all-gather"] * 16 + ["all-reduce"] * 3 + ["collective-permute"]
+    ), found
+    # the pull: an all-reduce inside the fusion the TPU runs as a
+    # reduce-scatter, on the flat block, under `ps.pull`
+    scattered, = [
+        line for line in text.splitlines() if "calls=%all-reduce-scatter" in line]
+    assert re.search(r" = f32\[2133\d\d,128\]", scattered), scattered
+    assert "ps.pull" in scattered
+    inside, = [c for c in found.values()
+               if c[0] == "all-reduce" and c[1][0].startswith("f32[85")]
+    assert inside[1] == ("f32[853376,128]",)  # 4 x 213,344: no field padded
+    halo, = [c for c in found.values() if c[0] == "collective-permute"]
+    assert set(halo[1]) == {"f32[1056,128]", "u32[]"}
+    # the dense gradients: every leaf's four block sums gathered (the last
+    # bias' four numbers each into a vector of zeros that an all-reduce
+    # hands round: a sum of one number and three zeros), 4 x 2,368,897
+    # float32 a chip, and no all-reduce of a leaf's own sum
+    grads = [c for c in found.values()
+             if c[0] == "all-gather" and "ps.compute" in c[2]]
+    sizes = [
+        int(np.prod([int(d) for d in re.findall(r"\d+", c[1][-1].split("[")[1])]))
+        for c in grads]
+    small, = [c for c in found.values()
+              if c[0] == "all-reduce" and c[1][0].startswith("f32[")
+              and not c[1][0].startswith("f32[85")]
+    assert small[1] == ("f32[4]",)
+    assert len(sizes) == 15 and sum(sizes) + 4 == 4 * 2_368_897 == 9_475_588
+    # the dense net at a quarter of the examples, nowhere at all of them
+    dense = [line for line in text.splitlines() if "/ps.compute/ps.dense_" in line]
+    assert dense and not any(f"[{FM_BATCH}," in line.split(" = ", 1)[-1].split("(")[0]
+                             for line in dense)
+    for shape in (f"f32[{quarter},1024]", f"f32[{quarter},27,27]",
+                  f"f32[{quarter},27,128]", f"f32[{quarter},479]"):
+        assert shape in text, shape
+    build, = [line for line in text.splitlines()
+              if "ps.delta_build" in line and " fusion(" in line]
+    assert f" = f32[{DLRM_FIELDS},{quarter},128]" in build, build
 
 
 # glove-840b-300 (chipbench/configs): cell 13's table and batch
