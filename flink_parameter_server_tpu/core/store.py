@@ -37,7 +37,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Callable, Optional, Tuple, Union
+from typing import Any, Callable, NamedTuple, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -343,6 +343,19 @@ def _create_packed(spec: StoreSpec, init_fn: InitFn) -> Callable[[], Array]:
     ), out_shardings=spec.sharding())
 
 
+class PulledRows(NamedTuple):
+    """What a pull that read a batch's DISTINCT rows once leaves for the
+    push of the same keys (:func:`pull_counted`, the arm
+    ``narrow_distinct``): ``ids`` (n,), the distinct ids the pull read, in
+    ascending order, ``count`` of them, the rest the sentinel
+    ``padded_capacity``; ``rows``, their WHOLE rows in that order, padded
+    with zeros to whole chunks of :func:`_push_rule`'s loop."""
+
+    ids: Array
+    rows: Array
+    count: Array
+
+
 def pull(
     spec: StoreSpec, table: Array, ids: Array, *, worker_part: bool = False,
     turned: bool = False, kept: int = 1,
@@ -386,7 +399,39 @@ def pull(
     (:func:`_ids_over_workers`), and by ``kept``, the parts into which the
     caller will constrain it split over ``ps`` so that a chip keeps one
     (``make_train_step`` for a logic whose compute it splits: the all-reduce
-    becomes a reduce-scatter).  ``kept`` changes nothing else."""
+    becomes a reduce-scatter).  ``kept`` changes nothing else.
+
+    Who reads the table when.  This function reads a row once a LANE that
+    names it, whatever the arm, and a rule store's push then reads each
+    distinct row again for its rule (:func:`_push_rule`).  A STEP's pull is
+    :func:`pull_counted`, which for the arm ``narrow_distinct`` reads each
+    distinct row once, for the logic and the rule both; a pull that no push
+    follows (a check's touched rows, a query: keys that are most often
+    distinct already) has nobody to share with and stays the gather."""
+    return _pull(spec, table, ids, worker_part, turned, kept, False)[0]
+
+
+def pull_counted(
+    spec: StoreSpec, table: Array, ids: Array, *, worker_part: bool = False,
+    turned: bool = False, kept: int = 1,
+) -> Tuple[Array, Optional[PulledRows]]:
+    """The pull of a STEP, whose push follows (``make_train_step``):
+    :func:`pull`'s answer bit for bit, and beside it what the pull leaves
+    for the push of the SAME keys: ``None`` from every arm that gathered a
+    row a lane; from the arm ``narrow_distinct`` (a narrow rule store in one
+    place on a TPU, rows a sort carries: cell 6), which read each DISTINCT
+    row once (:func:`_distinct_pull`), those rows (:class:`PulledRows`).
+    ``make_train_step`` hands them to :func:`push_counted` where the
+    request's ids are the pulled keys, and to :func:`step_counts`
+    (``ps_pull_distinct_rows``)."""
+    return _pull(spec, table, ids, worker_part, turned, kept, True)
+
+
+def _pull(
+    spec: StoreSpec, table: Array, ids: Array, worker_part: bool,
+    turned: bool, kept: int, shared: bool,
+) -> Tuple[Array, Optional[PulledRows]]:
+    # `pull` and `pull_counted`; `shared`: a push of the same keys follows
     if turned and ids.ndim != 2:
         raise ValueError(
             f"a turned pull takes a key block of two axes, (B, K): got "
@@ -396,8 +441,9 @@ def pull(
                fields=ids.shape[1] if turned else None).pull
     by_field = turned and arm == "packed_kernel_by_field"
     if turned and not by_field:
-        return jnp.swapaxes(
-            pull(spec, table, ids, worker_part=worker_part, kept=kept), 0, 1)
+        rows, left = _pull(
+            spec, table, ids, worker_part, False, kept, shared)
+        return jnp.swapaxes(rows, 0, 1), left
     part = spec.worker_width if worker_part else None
     if arm == "take":
         if spec.num_shards > 1 and worker_count(spec.mesh) == 1:
@@ -405,9 +451,11 @@ def pull(
                 "pull_rows_sum", _take_on_shards(spec, table, ids), kept)
         else:
             rows = jnp.take(table, ids, axis=0)
-        return rows if part is None else rows[..., :part]
-    if arm == "narrow":
-        return _narrow_pull(table, ids, part or spec.row_width)
+        return (rows if part is None else rows[..., :part]), None
+    if arm == "narrow_distinct" and shared and ids.size:
+        return _distinct_pull(spec, table, ids, part or spec.row_width)
+    if arm.startswith("narrow"):
+        return _narrow_pull(table, ids, part or spec.row_width), None
     from ..ops.packed import packed_pull
     block, kernel = ids if by_field else ids.reshape(-1), arm != "packed_selects"
     if spec.num_shards > 1:
@@ -419,7 +467,49 @@ def pull(
             table, block, spec.row_width, kernel, part, by_field)
     return vals.reshape(
         (ids.T if by_field else ids).shape
-        + (spec.value_shape if part is None else (part,)))
+        + (spec.value_shape if part is None else (part,))), None
+
+
+def _distinct_pull(
+    spec: StoreSpec, table: Array, ids: Array, width: int
+) -> Tuple[Array, PulledRows]:
+    """The arm ``narrow_distinct`` of :func:`pull_counted`: the leading
+    ``width`` lanes of rows ``ids`` (already clipped) of a narrow rule
+    store's table, ``jnp.take``'s block bit for bit, read a DISTINCT row at
+    a time.  On the TPU a gather pays by the row it fetches, 13-14 ns,
+    whatever the row holds, and a batch of Criteo records names a row 3.6
+    times: so one sort of the keys finds the distinct ids
+    (``ops/dedup.sorted_runs``), their whole rows are gathered
+    ``_RULE_CHUNK`` lanes a step of a loop that ends with the last distinct
+    id (:func:`_push_rule`'s loop, and its read: the push of the same keys
+    runs its rule on these rows and reads nothing), and the rows go back to
+    the batch's lanes by shifted selects and one sort that carries them
+    (``ops/dedup.spread_runs``): no second gather.  Cell 6's step: the
+    pull 18.39 -> 10.18 ms and the rule's read 4.78 -> 0.13 (PERF.md
+    section 6, PR 70).  A batch of distinct keys pays three sorts and the
+    selects for nothing, ~5 ms on 1,277,952 lanes."""
+    from ..ops.dedup import sorted_runs, spread_runs
+
+    flat = ids.reshape(-1)
+    n = flat.shape[0]
+    chunk = min(n, _RULE_CHUNK)
+    sentinel = spec.padded_capacity
+    row_ids, count, place, behind = sorted_runs(flat, sentinel)
+    padded = jnp.pad(row_ids, (0, -n % chunk), constant_values=sentinel)
+
+    def fetch(i, rows):
+        # (the sentinels that end the last chunk read the last row, as a
+        # sentinel of the rule's own read does, and nobody reads them)
+        got = _narrow_pull(table, jnp.minimum(jax.lax.dynamic_slice(
+            padded, (i * chunk,), (chunk,)), sentinel - 1), spec.row_width)
+        return jax.lax.dynamic_update_slice(rows, got, (i * chunk, 0))
+
+    rows = jax.lax.fori_loop(
+        0, -(-count // chunk), fetch,
+        jnp.zeros((padded.shape[0], spec.row_width), table.dtype))
+    pulled = spread_runs(rows[:n, :width], place, behind)
+    return (pulled.reshape(ids.shape + (width,)),
+            PulledRows(row_ids, rows, count))
 
 
 @functools.partial(jax.jit, static_argnums=(2,))
@@ -542,6 +632,7 @@ def push_counted(
     *,
     lanes_over_workers: bool = False,
     turned: bool = False,
+    pulled: Optional[PulledRows] = None,
 ) -> Tuple[Array, Optional[dict]]:
     """:func:`push`, and beside the table what the push counted on the
     device (``None`` for an ``update="add"`` batch that XLA's scatter-add
@@ -596,7 +687,14 @@ def push_counted(
     ``(K, B)``, the ``K`` keys of an example down its leading axis.  The
     same lanes in the same order as its flattening, and the same table;
     :func:`arms` is told the ``fields`` and may shift them a field at a
-    time."""
+    time.
+
+    ``pulled`` is what :func:`pull_counted` left of a pull of THESE ids
+    from THIS table, nothing written in between (``make_train_step`` hands
+    it over where the request's ids are the very array it pulled), or
+    ``None``: the distinct rows that pull read, on which a rule's push in
+    one place runs its rule and so reads the table not at all
+    (:func:`_push_rule`); no other push looks at it."""
     vr = len(spec.value_shape)
     lead = tuple(deltas.shape[: deltas.ndim - vr])
     row = tuple(deltas.shape[deltas.ndim - vr:])
@@ -637,9 +735,13 @@ def push_counted(
         # (a masked lane's delta goes as it is: `_push_rule` sends the lane
         # to the sentinel, and no combine arm lets a dropped lane's value
         # reach a kept row)
-        table, counted = (
-            _push_rule_on_shards if arm.on_shards else _push_rule
-        )(spec, table, flat_ids, flat_deltas, flat_mask, arm)
+        if arm.on_shards:
+            table, counted = _push_rule_on_shards(
+                spec, table, flat_ids, flat_deltas, flat_mask, arm)
+        else:
+            table, counted = _push_rule(
+                spec, table, flat_ids, flat_deltas, flat_mask, arm,
+                pulled=pulled)
         if spec.worker_width is not None:
             counted["ps_push_row_lanes"] = jnp.asarray(width, jnp.int32)
         return table, counted
@@ -678,6 +780,7 @@ def _push_rule(
     live: Optional[Array],
     arm: Arms,
     block: Optional[int] = None,
+    pulled: Optional[PulledRows] = None,
 ) -> Tuple[Array, dict]:
     """The push of a store whose ``update`` is a rule and not ``"add"``:
     ``(table, counted)``, as :func:`push_counted` hands them out, in the
@@ -733,11 +836,28 @@ def _push_rule(
     sent to the sentinel here, each combine arm drops a sentinel's lanes,
     and what they hold (NaN, Inf) reaches no kept row
     (``tests/test_store.py`` holds the four arms to that), so no pass
-    zeroes them first."""
+    zeroes them first.
+
+    Who reads the table when.  The rule's ``current`` rows are read from
+    the table here, a chunk's before the chunk is rewritten, UNLESS the
+    step's pull left them (``pulled``, :func:`push_counted`: the arm
+    ``narrow_distinct`` read each distinct row of these very keys once, and
+    nothing has written the table since; every distinct row lies in one
+    chunk, so a row is as the pull read it until its own chunk rewrites
+    it).  Then chunk ``i`` of ``pulled.rows`` IS chunk ``i``'s ``current``,
+    bit for bit, and ``ps.rule`` slices it and gathers nothing, wherever the
+    push's distinct ids are the pull's, which is whenever every lane is
+    live and in range.  A masked lane, or an id the pull clipped and the
+    push drops, may leave the push FEWER distinct ids than the pull read
+    (never others): the two lists are compared once, on the device, and a
+    step on which they differ reads its rows from the table as it always
+    did (a branch of every chunk, the parent's gather: the same bits)."""
     from ..ops.dedup import combine_runs
     from ..ops.row_update import sorted_tile_set
 
     n = flat_ids.shape[0]
+    if pulled is not None and pulled.ids.shape[0] != n:
+        pulled = None  # (of another batch: not this push's rows)
     sentinel = spec.padded_capacity if block is None else block
     update_fn: UpdateFn = spec.update  # type: ignore[assignment]
     packed = spec.layout == "packed"
@@ -775,6 +895,17 @@ def _push_rule(
         row_ids = jnp.pad(row_ids, (0, pad), constant_values=sentinel)
         combined = jnp.pad(combined, ((0, pad), (0, 0)))
 
+    def read(table, ids):
+        # the rule's own read of a chunk's rows (a shard's block is a plain
+        # dense array: no tile under a mesh)
+        return pull(spec, table, ids) if block is None else jnp.take(
+            table, ids, axis=0, mode="clip")
+
+    if pulled is not None:
+        with jax.named_scope("ps.rule"):
+            # the pull's distinct ids are the push's: every lane live
+            shared = jnp.array_equal(row_ids[:n], pulled.ids)
+
     def rewrite(i, carry):
         table, moved = carry  # tiles, or a packed store's physical rows
         ids = jax.lax.dynamic_slice(row_ids, (i * chunk,), (chunk,))
@@ -788,9 +919,15 @@ def _push_rule(
                 spec, table, ids, sums, arm.write_back)
             return table, moved + wrote
         with jax.named_scope("ps.rule"):
-            # a shard's block is a plain dense array (no tile under a mesh)
-            current = pull(spec, table, ids) if block is None else jnp.take(
-                table, ids, axis=0, mode="clip")
+            if pulled is None:
+                current = read(table, ids)
+            else:
+                current = jax.lax.cond(
+                    shared,
+                    lambda: jax.lax.dynamic_slice(
+                        pulled.rows, (i * chunk, 0),
+                        (chunk, spec.row_width)),
+                    lambda: read(table, ids))
             new = update_fn(
                 current, sums.reshape((chunk,) + _rule_takes(spec, width)),
             ).astype(table.dtype)
@@ -971,7 +1108,8 @@ _REFUSALS_NOTED: set = set()
 class Arms:
     """The forms a store's pull and push take, as :func:`arms` read them."""
 
-    # "take" | "narrow" | "packed_selects" | "packed_kernel[_by_field]"
+    # "take" | "narrow" | "narrow_distinct" | "packed_selects"
+    # | "packed_kernel[_by_field]"
     pull: str
     push: str  # "xla_add" | "tile_add" | "worker_reduce" | "rule"
     # a packed add push's lane shift: "" | "selects" | "kernel[_by_field]"
@@ -1052,7 +1190,8 @@ def arms(
     ================================  ======================  ===========  ===========  =========  ====  ========
     spec                              pull                    combine      write_back   on_shards  cell  PR
     ================================  ======================  ===========  ===========  =========  ====  ========
-    3 lanes, held at its tile of 4    narrow                  sort         tile_set     no         6     34 35
+    3 lanes, held at its tile of 4    narrow_distinct         sort         tile_set     no         6     34 35 70
+    3 lanes over ps 4, dp 1           take                    sort         xla_set      yes        none  70
     6 lanes, held at its tile of 8    narrow                  row_kernel   tile_set     no         none  35 46
     (2, 2) lanes: rank 2, no tile     take                    sort         xla_set      no         none  35
     packed k 3 (36 lanes)             packed_kernel           row_kernel   row_set      no         none  46 47 54
@@ -1206,6 +1345,11 @@ def arms(
         combine = "tile_kernel" if wide else "row_kernel"
     else:
         combine = "scatter_add"
+    if (pull == "narrow" and spec.mesh is None and write_back == "tile_set"
+            and spec.row_width <= dedup.SORT_CARRIES_LANES):
+        # rows a sort carries, in one place on a TPU: the distinct rows are
+        # read once, for the logic and the rule both (`_distinct_pull`)
+        pull = "narrow_distinct"
     return Arms(pull, "rule", "", combine, write_back, on_shards)
 
 
@@ -1225,7 +1369,7 @@ def _preload_tile_kernel(spec: StoreSpec) -> None:
 def step_counts(
     spec: StoreSpec, counted: Optional[dict], *, pull_lanes: int,
     push_lanes: int, fields: Optional[int] = None, compute_parts: int = 1,
-    crossings: Optional[dict] = None,
+    crossings: Optional[dict] = None, pulled: Optional[PulledRows] = None,
 ) -> dict:
     """What a step hands out of its pull and its push beside the logic's
     outputs: what :func:`push_counted` counted and, for a store packed
@@ -1263,8 +1407,16 @@ def step_counts(
     ``tests/test_tpu_compile.py`` holds the difference).  A constant of the
     trace, and KiB because it is an int32 (a step may well cross 2 GiB a
     chip: cell 16 crosses 0.58 GB; 2 TiB it does not).  A step in one place
-    crosses nothing, hands out no such output and keeps its text."""
+    crosses nothing, hands out no such output and keeps its text.
+
+    ``pulled`` is what the step's pull left (:func:`pull_counted`): where
+    it read the batch's distinct rows once, ``ps_pull_distinct_rows`` is
+    how many it read (the gauge ``store_pull_distinct_rows``; under
+    ``store_rule_keys`` it says how many lanes shared a fetched row: 3.6 on
+    Criteo records, 1.0 where the arm sorted for nothing)."""
     out = dict(counted or {})
+    if pulled is not None:
+        out["ps_pull_distinct_rows"] = pulled.count
     if crossings:
         moved = sum(b for sizes in crossings.values() for b in sizes)
         out["ps_mesh_kib"] = jnp.asarray(-(-moved // 1024), jnp.int32)
@@ -1340,6 +1492,9 @@ def publish_counts(outs: dict, registry: Any, total, peak) -> None:
         total(outs["ps_rule_rows"]))
     registry.gauge("store_rule_tiles", component="train").set(
         total(outs["ps_rule_tiles"]))
+    if "ps_pull_distinct_rows" in outs:
+        registry.gauge("store_pull_distinct_rows", component="train").set(
+            total(outs["ps_pull_distinct_rows"]))
     if "ps_rule_rows_max_shard" in outs:
         registry.gauge(
             "store_rule_keys_max_shard", component="train"

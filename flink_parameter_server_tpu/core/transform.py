@@ -404,7 +404,8 @@ def make_train_step(logic: BatchedWorkerLogic, spec) -> Callable:
         # XLA numbers its fusions
         with scope("ps.pull"):
             # (of a rule store whose row has a worker's part, that part)
-            pulled = store_mod.pull(
+            # (and what the pull leaves for a push of the same keys)
+            pulled, left = store_mod.pull_counted(
                 spec, table, ids, worker_part=True, turned=turned, kept=parts)
             if parts > 1:
                 pulled = pulled_over_servers(pulled)
@@ -424,6 +425,9 @@ def make_train_step(logic: BatchedWorkerLogic, spec) -> Callable:
             table, counted = store_mod.push_counted(
                 spec, table, req.ids, deltas, req.mask,
                 lanes_over_workers=lanes is not None, turned=turned,
+                # the rows the pull read are the push's where its ids are
+                # the very keys pulled; nothing wrote the table in between
+                pulled=left if req.ids is ids else None,
             )
         if isinstance(out, dict):
             # what the store counted on the device and which arms this
@@ -431,7 +435,7 @@ def make_train_step(logic: BatchedWorkerLogic, spec) -> Callable:
             out = {**out, **store_mod.step_counts(
                 spec, counted, pull_lanes=ids.size, push_lanes=req.ids.size,
                 fields=ids.shape[-1] if turned else None,
-                compute_parts=parts, crossings=crossings)}
+                compute_parts=parts, crossings=crossings, pulled=left)}
         return table, state, out
 
     return step

@@ -107,6 +107,34 @@ def _sorted_by_id(ids: Array, rows: Array) -> Tuple[Array, Array]:
     return out[0], jnp.stack(out[1:], axis=1)
 
 
+def _shifted(cols: Array, d: int) -> Array:
+    """``cols`` (w, n) moved ``d`` lanes to the right, zeros coming in."""
+    return jnp.concatenate(
+        [jnp.zeros((cols.shape[0], d), cols.dtype), cols[:, :-d]], axis=1
+    )
+
+
+def _ranked(ids: Array, is_stable: bool):
+    """One sort of ``(ids, lane)`` and the runs it shows: ``(sorted_ids,
+    order, starts, rank)``, ``order`` each sorted lane's place in the
+    stream, ``starts`` true where a run of one id begins, ``rank`` the
+    prefix sum of the starts (by doubling, on one int32 vector): a lane's
+    run number, from 1."""
+    n = ids.shape[0]
+    lane = jnp.arange(n, dtype=jnp.int32)
+    sorted_ids, order = jax.lax.sort(
+        (ids, lane), num_keys=1, is_stable=is_stable)
+    starts = jnp.concatenate(
+        [jnp.ones((1,), bool), sorted_ids[1:] != sorted_ids[:-1]]
+    )
+    rank = starts.astype(jnp.int32)
+    d = 1
+    while d < n:
+        rank = rank + jnp.pad(rank[:-d], (d, 0))
+        d *= 2
+    return sorted_ids, order, starts, rank
+
+
 def combine_runs(
     ids: Array, vals: Array, sentinel: int, arm: str,
     *, interpret: Optional[bool] = None,
@@ -168,13 +196,68 @@ def combine_runs(
     d = 1
     while d < n:
         same = jnp.concatenate([jnp.zeros((d,), bool), ids[d:] == ids[:-d]])
-        before = jnp.concatenate(
-            [jnp.zeros((w, d), cols.dtype), cols[:, :-d]], axis=1
-        )
+        before = _shifted(cols, d)
         cols = cols + jnp.where(same[None], before, jnp.zeros_like(before))
         d *= 2
     ends = jnp.concatenate([ids[1:] != ids[:-1], jnp.ones((1,), bool)])
     return _sorted_by_id(jnp.where(ends, ids, sentinel), cols.T) + (None,)
+
+
+def sorted_runs(
+    ids: Array, sentinel: int
+) -> Tuple[Array, Array, Array, Array]:
+    """The runs of one id among ``ids`` (n,), for a pull that reads each
+    DISTINCT row once (``core/store._distinct_pull``): ``(row_ids, count,
+    place, behind)``.  ONE sort of ``(ids, lane)``: ``place`` is each sorted
+    lane's place in the stream.  ``row_ids`` are the distinct ids first, in
+    ascending order, ``count`` of them, the rest ``sentinel`` (an id larger
+    than any here): what :func:`combine_runs` makes of the same live ids,
+    by :func:`_wide_runs`' prefix sum of the run starts and its sort of the
+    starts' ids.  ``behind[k] = k - run(k)`` is how far to the LEFT of
+    sorted lane ``k`` its run's number lies (:func:`spread_runs` reads it);
+    it never falls and rises by at most one a lane."""
+    # (any order within a run will do: every lane of it gets the same row)
+    sorted_ids, place, starts, rank = _ranked(ids.astype(jnp.int32), False)
+    lane = jnp.arange(ids.shape[0], dtype=jnp.int32)
+    # (keys alone: nothing for a stable sort to keep, and it would carry an
+    # iota to keep it)
+    row_ids = jax.lax.sort(
+        jnp.where(starts, sorted_ids, sentinel), is_stable=False)
+    return row_ids, rank[-1], place, lane - (rank - 1)
+
+
+def spread_runs(rows: Array, place: Array, behind: Array) -> Array:
+    """The way back from a batch's distinct rows to its lanes: ``rows`` (n,
+    w), row ``j`` the ``j``-th distinct id's (what lies past the last
+    distinct id is never read), ``place`` and ``behind`` as
+    :func:`sorted_runs` made them; ``(n, w)`` in the order of the STREAM,
+    every lane its id's row, copied bit for bit.
+
+    In sorted order lane ``k`` wants ``rows[k - behind[k]]``, and
+    ``behind`` never falls and rises by at most one a lane: so the read is
+    ``log2 n`` shifted SELECTS, ``behind``'s highest bit first, each lane
+    deciding by its own ``behind`` alone (the hop for bit ``b`` lands on a
+    lane ``q`` with ``behind[q]`` in ``[u, u + 2^b)``, ``u`` what is left of
+    the lane's own from bit ``b`` up: the same bits from ``b`` up), which
+    places a run's row and spreads it along the run in ONE loop of the
+    shape :func:`combine_runs`' shifted adds have, the lanes along the
+    minor axis.  Then one sort on ``place`` that carries the ``w`` lanes
+    (``w`` <= ``SORT_CARRIES_LANES``).  No gather: alone on the v5e, at
+    1,277,952 lanes of three-lane rows, the selects take 0.63 ms and the
+    sort 2.75 (1.71 with one lane carried, which is what a step whose logic
+    reads one lane compiles to), where a gather of that many rows from the
+    table is 18.8 ms and XLA's gather of them from the compact block 5.6
+    alone and ~17 inside a larger program; placing the rows at their runs'
+    starts by a sort and filling the runs forward costs a second carrying
+    sort, 2.7 ms more (PERF.md section 6, PR 70)."""
+    n = rows.shape[0]
+    cols = rows.T
+    for b in reversed(range((n - 1).bit_length())):
+        hops = ((behind >> b) & 1) == 1
+        cols = jnp.where(hops[None], _shifted(cols, 1 << b), cols)
+    # (the places are distinct: nothing for a stable sort to keep)
+    out = jax.lax.sort((place,) + tuple(cols), num_keys=1, is_stable=False)
+    return jnp.stack(out[1:], axis=1)
 
 
 def kernel_refusal(width: int, dtype) -> Optional[str]:
@@ -216,16 +299,8 @@ def _wide_runs(
     allowance; alone, jitted by itself, the same function was right:
     PERF.md section 6, PR 45)."""
     n, w = vals.shape
-    lane = jnp.arange(n, dtype=jnp.int32)
-    sorted_ids, order = jax.lax.sort((ids, lane), num_keys=1)
-    starts = jnp.concatenate(
-        [jnp.ones((1,), bool), sorted_ids[1:] != sorted_ids[:-1]]
-    )
-    rank = starts.astype(jnp.int32)
-    d = 1
-    while d < n:
-        rank = rank + jnp.pad(rank[:-d], (d, 0))
-        d *= 2
+    # (stable: a run's lanes stay in the order of the stream)
+    sorted_ids, order, starts, rank = _ranked(ids, True)
     if kernel_sums is not None:
         # the lanes to drop sort last: the kernel writes no row for them
         slot = jnp.where(sorted_ids < sentinel, rank - 1, _INT32_MAX)

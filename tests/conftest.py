@@ -78,7 +78,9 @@ def steer_arms(monkeypatch):
     read: an add store's ``combine`` and ``write_back``, a rule store's
     ``push`` and ``shift``, a dense store's ``shift``, a row a sort carries
     (``combine`` ``"sort"``: no other form takes such a row), a pull that is
-    not packed.  A value may be a function of the spec.  Calls stack."""
+    not packed (but a narrow rule store's: ``pull="narrow_distinct"`` has it
+    read a batch's distinct rows once, ``pull="narrow"`` a row a lane).  A
+    value may be a function of the spec.  Calls stack."""
     import dataclasses
 
     from flink_parameter_server_tpu.core import store as store_mod
@@ -92,7 +94,11 @@ def steer_arms(monkeypatch):
             for name, value in fields.items():
                 was = getattr(arm, name)
                 if isinstance(was, str) and (
-                        was in ("", "rule", "sort", "take", "narrow")):
+                        was in ("", "rule", "sort", "take")):
+                    continue
+                # a narrow pull is steered between its own two forms alone
+                if str(was).startswith("narrow") and not (
+                        str(value).startswith("narrow")):
                     continue
                 new[name] = value(spec) if callable(value) else value
             return dataclasses.replace(arm, **new)

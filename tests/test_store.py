@@ -2615,8 +2615,14 @@ ARMS_ON_A_TPU = [
     ("5 regs over ps 2, dp 2", (640,), "add", "auto", (2, 2), 61,
      8_192, 8_192, False, ("take", "xla_add", "", "", "", False),
      1),  # the push on the shards: the batch lies split over dp, noted
+    # cell 6 since PR 70: rows a sort carries, in one place, are read a
+    # DISTINCT row at a time, for the logic and the rule both
     ("3 lanes, held at its tile of 4", (3,), _RULE, "auto", None, 1_000,
-     8_192, 8_192, False, ("narrow", "rule", "", "sort", "tile_set", False), 0),
+     8_192, 8_192, False,
+     ("narrow_distinct", "rule", "", "sort", "tile_set", False), 0),
+    # (a mesh's table has no tile, and its pull is GSPMD's or the shards')
+    ("3 lanes over ps 4, dp 1", (3,), _RULE, "auto", (1, 4), 1_000,
+     8_192, 8_192, False, ("take", "rule", "", "sort", "xla_set", True), 0),
     ("6 lanes, held at its tile of 8", (6,), _RULE, "auto", None, 1_000,
      8_192, 8_192, False,
      ("narrow", "rule", "", "row_kernel", "tile_set", False), 0),
@@ -2799,7 +2805,8 @@ def test_the_arms_table_has_a_case_a_row_of_the_docstring():
         want = case[9]
         cells = row[len(case[0]):].split()
         got = [c for c in cells if c in {
-            "take", "narrow", "packed_selects", "packed_kernel",
+            "take", "narrow", "narrow_distinct", "packed_selects",
+            "packed_kernel",
             "packed_kernel_by_field", "kernel_by_field", "xla_add",
             "tile_add", "worker_reduce", "selects", "kernel", "sort",
             "scatter_add", "row_kernel", "tile_kernel", "xla_set", "tile_set",
@@ -3155,3 +3162,272 @@ def test_the_tally_leaves_the_step_in_kib_and_the_gauge_reads_bytes(moved, kib):
     assert 0 <= gauges["store_mesh_bytes"] - total < 1024
     assert not [k for k in gauges if k.startswith("store_mesh_") and
                 k != "store_mesh_bytes"]
+
+
+# -- a narrow rule store pulls a batch's DISTINCT rows once (PR 70) ----------
+# `core/store.arms`' pull arm ``narrow_distinct`` (cell 6 on a TPU; steered in
+# here): one sort finds the distinct ids, their rows are gathered once, go back
+# to the batch's lanes by shifted selects and a sort (`ops/dedup.spread_runs`),
+# and the push of the same keys runs its rule on them.  The pulled block is
+# ``jnp.take``'s and the table the parent path's, bit for bit.
+def _criteo_like(rng, examples, rows):
+    # integer fields of one row each, small and large fields of their own
+    return np.stack([
+        np.full(examples, 0), np.full(examples, 1),
+        2 + rng.integers(0, 3, examples), 5 + rng.integers(0, 40, examples),
+        45 + rng.integers(0, rows - 45, examples)], axis=1)
+
+
+def _masked(rng, examples, rows):
+    ids = _criteo_like(rng, examples, rows)
+    live = rng.random(ids.shape) < 0.7
+    ids[5, 4], live[5, 4] = rows - 1, False  # a row no live lane names
+    return ids, live
+
+
+def _bad_ids(rng, examples, rows):
+    ids = _criteo_like(rng, examples, rows)
+    ids[rng.random(ids.shape) < 0.1] = -1
+    ids[rng.random(ids.shape) < 0.1] = rows + 1_000_000
+    ids[0, :2] = [-7, np.iinfo(np.int32).max]
+    return ids
+
+
+# (what, (examples, fields), rows, the loop's chunk or None for its own, ids)
+DISTINCT_BATCHES = {
+    "criteo_like_duplicates": (
+        (60, 5), 400, 64, lambda rng: _criteo_like(rng, 60, 400)),
+    "all_distinct": (
+        (60, 5), 400, 64,
+        lambda rng: rng.permutation(400)[:300].reshape(60, 5)),
+    "one_id": ((60, 5), 400, 64, lambda rng: np.full((60, 5), 17)),
+    "a_run_longer_than_a_chunk": (
+        (60, 5), 400, 64, lambda rng: np.where(
+            rng.random((60, 5)) < 0.6, 200, rng.integers(0, 400, (60, 5)))),
+    "masked_lanes_with_nan_deltas": (
+        (60, 5), 400, 64, lambda rng: _masked(rng, 60, 400)),
+    "negative_and_out_of_range_ids": (
+        (60, 5), 400, 64, lambda rng: _bad_ids(rng, 60, 400)),
+    "shorter_than_a_chunk": (
+        (9, 5), 400, None, lambda rng: _criteo_like(rng, 9, 400)),
+    "a_batch_of_one_lane": ((1, 1), 400, None, lambda rng: np.full((1, 1), 3)),
+}
+
+
+def _ftrl_that_shows_its_rows():
+    from flink_parameter_server_tpu.models import logistic_ftrl as lf
+
+    class Logic(lf.LogisticFTRL):
+        """FTRL's step, the pulled block among its outputs and a masked
+        lane's delta NaN: what a dropped lane holds reaches no row."""
+
+        def step(self, state, batch, pulled):
+            state, req, out = super().step(state, batch, pulled)
+            deltas = jnp.where(req.mask[..., None], req.deltas, jnp.nan)
+            return state, PushRequest(req.ids, deltas, req.mask), {
+                **out, "pulled": pulled}
+
+    return Logic()
+
+
+def _distinct_case(what, monkeypatch, steps):
+    """``(spec, table, batches, chunk)`` of a case: a warm FTRL table (both
+    branches of the rule's threshold hold rows) and ``steps`` batches of the
+    case's keys with fresh values and labels."""
+    from flink_parameter_server_tpu.core import store as store_mod
+    from flink_parameter_server_tpu.models import logistic_ftrl as lf
+
+    (examples, fields), rows, chunk, make = DISTINCT_BATCHES[what]
+    if chunk is not None:
+        monkeypatch.setattr(store_mod, "_RULE_CHUNK", chunk)
+    rng = np.random.default_rng(70)
+    made = make(rng)
+    ids, live = made if isinstance(made, tuple) else (made, None)
+    spec = lf.make_store(rows).spec
+    z = rng.normal(0, 2, spec.table_shape()[0]).astype(np.float32)
+    n = rng.uniform(0, 64, z.shape).astype(np.float32)
+    values = jnp.stack(
+        [lf.FTRLProximal().weights(jnp.asarray(z), jnp.asarray(n)), z, n], 1)
+    table = jnp.pad(values, ((0, 0), (0, spec.table_shape()[1] - 3)))
+    batches = [{
+        "ids": jnp.asarray(ids.astype(np.int32)),
+        "values": jnp.asarray(rng.random(ids.shape, np.float32) + 0.5),
+        "feat_mask": jnp.asarray(
+            np.ones(ids.shape, bool) if live is None else live),
+        "label": jnp.asarray(rng.choice([-1.0, 1.0], examples), jnp.float32),
+        # (whole examples dropped too, in the case that masks lanes)
+        "mask": jnp.asarray(
+            (rng.random(examples) < 0.9) | (live is None)),
+    } for _ in range(steps)]
+    return spec, table, batches
+
+
+def _bits(x):
+    return np.asarray(x).view(np.int32)
+
+
+@pytest.mark.parametrize("steps", [1, 3])
+@pytest.mark.parametrize("what", sorted(DISTINCT_BATCHES))
+def test_the_distinct_pull_hands_out_takes_block_and_leaves_the_parents_table(
+        what, steps, steer_arms, monkeypatch):
+    from flink_parameter_server_tpu.core import store as store_mod
+
+    spec, table, batches = _distinct_case(what, monkeypatch, steps)
+    assert store_mod.arms(spec).pull == "narrow"  # the parent path, off a TPU
+    step = jax.jit(make_train_step(_ftrl_that_shows_its_rows(), spec))
+    want, outs = table, []
+    for batch in batches:
+        want, _, out = step(want, (), batch)
+        outs.append(out)
+    steer_arms(pull="narrow_distinct")
+    assert store_mod.arms(spec).pull == "narrow_distinct"
+    step = jax.jit(make_train_step(_ftrl_that_shows_its_rows(), spec))
+    got = table
+    for batch, parents in zip(batches, outs):
+        clipped = jnp.clip(batch["ids"], 0, spec.padded_capacity - 1)
+        block = jnp.take(got[:, :3], clipped, axis=0)
+        got, _, out = step(got, (), batch)
+        assert np.array_equal(_bits(out["pulled"]), _bits(block))
+        assert int(out["ps_pull_distinct_rows"]) == len(
+            np.unique(np.asarray(clipped)))
+        assert set(out) == set(parents) | {"ps_pull_distinct_rows"}
+        for name, value in parents.items():
+            assert np.array_equal(_bits(out[name]), _bits(value)), name
+    assert np.array_equal(_bits(got), _bits(want))
+    assert not np.array_equal(_bits(got), _bits(table))  # something moved
+
+
+@pytest.mark.parametrize("what", sorted(DISTINCT_BATCHES))
+def test_the_gauge_of_the_distinct_pull_counts_what_numpy_unique_counts(
+        what, steer_arms, monkeypatch):
+    from flink_parameter_server_tpu.core import store as store_mod
+    from flink_parameter_server_tpu.telemetry.registry import MetricsRegistry
+
+    spec, table, (batch,) = _distinct_case(what, monkeypatch, 1)
+    steer_arms(pull="narrow_distinct")
+    step = jax.jit(make_train_step(_ftrl_that_shows_its_rows(), spec))
+    _, _, out = step(table, (), batch)
+    registry = MetricsRegistry()
+    store_mod.publish_counts(out, registry, int, int)
+    gauges = {i.name: i.value for i in registry.instruments()}
+    clipped = np.clip(np.asarray(batch["ids"]), 0, spec.padded_capacity - 1)
+    assert gauges["store_pull_distinct_rows"] == len(np.unique(clipped))
+    # ... a pull no push follows stays the gather, bit for bit the same
+    rows, left = store_mod.pull_counted(spec, table, batch["ids"])
+    assert int(left.count) == len(np.unique(clipped))
+    assert np.array_equal(
+        _bits(rows), _bits(jnp.take(table[:, :3], jnp.asarray(clipped), axis=0)))
+    assert np.array_equal(
+        _bits(rows), _bits(store_mod.pull(spec, table, batch["ids"])))
+    lowered = jax.jit(lambda t, i: store_mod.pull(spec, t, i)).lower(
+        table, batch["ids"]).as_text()
+    assert "stablehlo.sort" not in lowered and "stablehlo.gather" in lowered
+    # the parent path's step hands out no such count and sets no such gauge
+    monkeypatch.undo()
+    _, _, out = jax.jit(make_train_step(
+        _ftrl_that_shows_its_rows(), spec))(table, (), batch)
+    registry = MetricsRegistry()
+    store_mod.publish_counts(out, registry, int, int)
+    assert "ps_pull_distinct_rows" not in out
+    assert "store_pull_distinct_rows" not in {
+        i.name for i in registry.instruments()}
+
+
+@pytest.mark.parametrize("what, shared", [
+    ("criteo_like_duplicates", True), ("all_distinct", True),
+    ("a_run_longer_than_a_chunk", True), ("shorter_than_a_chunk", True),
+    ("masked_lanes_with_nan_deltas", False),
+    ("negative_and_out_of_range_ids", False),
+])
+def test_the_rule_runs_on_the_pulled_rows_where_the_pushs_ids_are_the_pulls(
+        what, shared, steer_arms, monkeypatch):
+    """Who reads the table when: handed rows that are NOT the table's (each
+    lane one more), a push whose distinct ids are the pull's runs its rule
+    on THEM and gathers nothing; a push that a masked or clipped lane left
+    fewer ids reads the table, as the parent does."""
+    from flink_parameter_server_tpu.core import store as store_mod
+    from flink_parameter_server_tpu.models import logistic_ftrl as lf
+
+    spec, table, (batch,) = _distinct_case(what, monkeypatch, 1)
+    steer_arms(pull="narrow_distinct")
+    ids = batch["ids"]
+    live = batch["feat_mask"] & batch["mask"][:, None]
+    rows, left = store_mod.pull_counted(spec, table, ids)
+    _, deltas = lf.example_deltas(
+        batch["values"], rows[..., lf.W], batch["label"])
+    other = left._replace(rows=left.rows + 1)
+
+    def pushed(pulled, on):
+        return np.asarray(jax.jit(lambda t: store_mod.push_counted(
+            spec, t, ids, deltas, live, pulled=pulled)[0])(on))
+
+    parents = pushed(None, table)
+    assert np.array_equal(_bits(pushed(left, table)), _bits(parents))
+    moved = jnp.where(
+        jnp.arange(4) < 3, table + 1, table)  # (the tile's pad lane is 0)
+    want = parents if not shared else pushed(None, moved)
+    touched = np.unique(np.asarray(ids)[np.asarray(live)])
+    touched = touched[(touched >= 0) & (touched < spec.padded_capacity)]
+    # (two programs may fuse the rule's arithmetic differently: to an ulp)
+    got = pushed(other, table)[touched]
+    assert np.allclose(got, want[touched], rtol=1e-5, atol=1e-6)
+    assert shared != np.allclose(got, parents[touched], rtol=1e-5, atol=1e-6)
+    # rows of another batch are not this push's: it reads the table
+    short = store_mod.pull_counted(spec, table, ids.reshape(-1)[:-1])[1]
+    assert np.array_equal(_bits(pushed(short, table)), _bits(parents))
+
+
+def test_a_request_of_other_ids_than_the_pulled_keys_keeps_its_own_read(
+        steer_arms, monkeypatch):
+    """``make_train_step`` hands the pull's rows to the push only where the
+    request's ids ARE the pulled keys, the same traced array: a logic that
+    pushes to other rows (here: every key's neighbour) must have its rule
+    read the rows it names."""
+    from flink_parameter_server_tpu.core import store as store_mod
+
+    spec, table, (batch,) = _distinct_case(
+        "criteo_like_duplicates", monkeypatch, 1)
+
+    class Neighbours(type(_ftrl_that_shows_its_rows())):
+        def step(self, state, batch, pulled):
+            state, req, out = super().step(state, batch, pulled)
+            return state, PushRequest(req.ids + 1, req.deltas, req.mask), out
+
+    want, _, _ = jax.jit(make_train_step(Neighbours(), spec))(table, (), batch)
+    steer_arms(pull="narrow_distinct")
+    handed = []
+    real = store_mod.push_counted
+    monkeypatch.setattr(store_mod, "push_counted", lambda *a, **kw: (
+        handed.append(kw["pulled"]), real(*a, **kw))[1])
+    got, _, out = jax.jit(make_train_step(Neighbours(), spec))(table, (), batch)
+    assert handed == [None] and "ps_pull_distinct_rows" in out
+    assert np.array_equal(_bits(got), _bits(want))
+
+
+@pytest.mark.parametrize("n, rows", [
+    (1, 5), (2, 1), (3, 2), (7, 3), (64, 9), (65, 64), (1000, 37), (1000, 5000),
+])
+def test_spread_runs_is_a_gather_by_run_number(n, rows):
+    """``ops/dedup.sorted_runs`` and ``spread_runs`` alone: the distinct ids
+    as ``numpy.unique`` has them, and every lane its id's row through the
+    shifted selects and the sort, NaN, -0.0 and all."""
+    from flink_parameter_server_tpu.ops.dedup import sorted_runs, spread_runs
+
+    rng = np.random.default_rng(n * 1009 + rows)
+    ids = rng.integers(0, rows, n).astype(np.int32)
+    row_ids, count, place, behind = jax.jit(
+        lambda i: sorted_runs(i, rows))(jnp.asarray(ids))
+    uniq = np.unique(ids)
+    assert int(count) == len(uniq)
+    assert np.array_equal(np.asarray(row_ids)[:len(uniq)], uniq)
+    assert (np.asarray(row_ids)[len(uniq):] == rows).all()
+    steps = np.diff(np.asarray(behind))
+    assert behind[0] == 0 and ((steps == 0) | (steps == 1)).all()
+    table = rng.normal(size=(rows, 3)).astype(np.float32)
+    table[rng.random(table.shape) < 0.1] = np.nan
+    table[rng.random(table.shape) < 0.1] = -0.0
+    compact = np.zeros((n, 3), np.float32)
+    compact[:len(uniq)] = table[uniq]
+    compact[len(uniq):] = 7.0  # never read
+    got = jax.jit(spread_runs)(jnp.asarray(compact), place, behind)
+    assert np.array_equal(_bits(got), _bits(table[ids]))

@@ -1456,7 +1456,16 @@ def test_the_mf_and_fm_cells_step_text_is_the_parents(cell, want, request):
     trace): with that output left out their text is the parent's to
     the letter (``7c6eef68c7b9b162`` and ``47d256f2590f4bc0``, held below:
     the names themselves are locations, which this text does not carry);
-    the three steps in one place did not move."""
+    the three steps in one place did not move.  PR 70 (a narrow rule
+    store pulls a batch's distinct rows once: cell 6 on a TPU, the arm
+    ``narrow_distinct``) moved none of the five, cell 6's included: the arm
+    is taken on a TPU alone, and lowered here, off it, the step calls
+    ``pull_counted`` for ``pull`` and traces the parent's ops to the letter;
+    the step as the chip runs it is held by
+    ``test_lr_step_reads_each_distinct_row_once_for_the_logic_and_the_rule``
+    (compiled for a described v5e), and every other cell's step hashes
+    equal on the parent and on the tree at full size for a described v5e
+    (PERF.md section 6, PR 70)."""
     shape = jax.ShapeDtypeStruct
 
     def mf_batch(n, on=shape):
@@ -1553,28 +1562,36 @@ def _lr_step(lr, one_chip):
     ).compile()
 
 
-def test_lr_step_holds_nothing_table_sized_beside_its_table(
-        lr, one_chip, no_compile_cache, monkeypatch):
+@pytest.fixture(scope="module")
+def lr_tpu_step(lr, one_chip):
     """Cell 6's step at 187,767,412 rows for a described v5e, as the chip
-    runs it (asked for the backend, the write-back takes
-    ``ops/row_update.sorted_tile_set``): the donated table is rewritten in
-    place through the rule arm's loop, the kernel under ``ps.push`` is the
-    only op that yields a table, both transposes round it are bitcasts,
-    nothing copies or transposes the table, no XLA scatter is left on it,
-    both gathers read a 3-lane window of the 4-lane row, and what the step
-    holds beside the table goes with the batch: 0.04 GB."""
+    runs it (code that asks for the backend still sees the CPU here: it is
+    steered), compiled ONCE for the tests that read it: ``(text, memory
+    analysis, refusals noted on the way)``."""
     spec, _ = lr
-    # code that asks for the backend still sees the CPU here: steer it
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    n0 = row_update.refusal_count()
-    assert store_mod.arms(spec) == store_mod.Arms(
-        "narrow", "rule", "", "sort", "tile_set", False)
-    compiled = _lr_step(lr, one_chip)
-    assert row_update.refusal_count() == n0
-    mem = compiled.memory_analysis()
+    with _compiling_for_described_chips("tpu"):
+        n0 = row_update.refusal_count()
+        assert store_mod.arms(spec) == store_mod.Arms(
+            "narrow_distinct", "rule", "", "sort", "tile_set", False)
+        compiled = _lr_step(lr, one_chip)
+        return (compiled.as_text(), compiled.memory_analysis(),
+                row_update.refusal_count() - n0)
+
+
+def test_lr_step_holds_nothing_table_sized_beside_its_table(lr_tpu_step):
+    """Cell 6's step as the chip runs it (asked for the backend, the
+    write-back takes ``ops/row_update.sorted_tile_set``): the donated table
+    is rewritten in place through the rule arm's loop, the kernel under
+    ``ps.push`` is the only op that yields a table, both transposes round it
+    are bitcasts, nothing copies or transposes the table, no XLA scatter is
+    left on it, both gathers read a 3-lane window of the 4-lane row, and
+    what the step holds beside the table goes with the batch: 0.04 GB
+    (0.105 since PR 70: the distinct rows the pull leaves for the rule and
+    the sorts' operands)."""
+    text, mem, noted = lr_tpu_step
+    assert noted == 0
     assert 2.9 * GB < mem.alias_size_in_bytes < 3.1 * GB  # in place, 4 sublanes
     assert mem.temp_size_in_bytes < 0.2 * GB
-    text = compiled.as_text()
     rows, lanes = LR_TABLE
     table = rf"f32\[({rows},{lanes}|{lanes},{rows})\]"
     yields = [
@@ -1590,14 +1607,86 @@ def test_lr_step_holds_nothing_table_sized_beside_its_table(
     assert "scatter" not in "".join(
         line for line in text.splitlines() if "ps.push/while" in line
     )
-    assert len(re.findall(r" while\(", text)) == 1
+    # the pull's loop over the distinct rows, and the rule's
+    assert len(re.findall(r" while\(", text)) == 2
     gathers = [line for line in text.splitlines() if " gather(" in line]
     assert len(gathers) == 2 and all(
-        "slice_sizes={1,3}" in g for g in gathers
+        "slice_sizes={1,3}" in g and " = f32[32768,3]" in g for g in gathers
     ), gathers
+
+
+def test_lr_step_reads_each_distinct_row_once_for_the_logic_and_the_rule(
+        lr_tpu_step):
+    """Since PR 70 the pull reads the batch's DISTINCT rows once
+    (``core/store._distinct_pull``): no gather yields ``f32[1277952,3]``;
+    the two gathers left are a chunk of 32,768 rows each, the pull's inside
+    its loop and the rule's own read below the BRANCH a step takes only
+    where a masked or clipped lane left the push fewer distinct ids than
+    the pull read: below the other branch, the one every step of cell 6
+    takes, nothing gathers, the rule's rows are a ``dynamic-slice`` of what
+    the pull left.  The sorts: three under ``ps.pull`` (the keys with their
+    places; the distinct ids, ONE operand: unstable, it carries no iota; the
+    way back, which carries ONE lane of the
+    three, the compiler having dropped the two FTRL's worker never reads),
+    the combine's two untouched, each with the three lanes of the deltas.
+    The scopes ``ps.pull``, ``ps.push/ps.combine`` and ``ps.rule`` stand
+    round what is left of each."""
+    text, _, _ = lr_tpu_step
     for scope in ("ps.pull", "ps.push/ps.combine",
                   "ps.push/while/body/ps.rule"):
         assert scope in text, scope
+    lanes = FM_BATCH * FM_FIELDS
+    sorts = sorted(
+        (re.search(r'op_name="([^"]*)"', line).group(1),
+         len(re.findall(rf"[sf]32\[{lanes}\]", line.split(" sort(")[0])))
+        for line in text.splitlines()
+        if re.search(rf" = \(?[sf]32\[{lanes}\]\S* .*sort\(", line))
+    assert sorts == [
+        ("jit(step)/ps.pull/sort", 1), ("jit(step)/ps.pull/sort", 2),
+        ("jit(step)/ps.pull/sort", 2),
+        ("jit(step)/ps.push/ps.combine/sort", 5),
+        ("jit(step)/ps.push/ps.combine/sort", 5)], sorts
+    assert not re.search(rf" = f32\[{lanes},\d\]\S* gather\(", text)
+    bodies, _ = _computations(text)
+
+    def below(name):
+        # `name` and every computation it calls, however deep
+        found, new = set(), {name}
+        while new:
+            found |= new
+            new = {
+                callee for n in new for line in bodies[n]
+                for callee in re.findall(r"%[\w.\-]+", line.split(" = ", 1)[1])
+                if callee in bodies} - found
+        return found
+
+    # (a gather inside the jitted `_narrow_pull` carries that name, not the
+    # scope's: where it runs is read from who calls its computation)
+    gathers = {name for name, lines in bodies.items()
+               if any(" gather(" in line for line in lines)}
+    assert len(gathers) == 2
+    conditional = [
+        line for line in text.splitlines() if " conditional(" in line]
+    assert len(conditional) == 1 and "ps.rule/cond" in conditional[0]
+    branches = re.search(
+        r"branch_computations=\{([^}]*)\}", conditional[0]
+    ).group(1).split(", ")
+    reads = [b for b in branches if below(b) & gathers]
+    slices = [b for b in branches if not below(b) & gathers]
+    assert len(reads) == 1 and len(slices) == 1, branches
+    assert any(
+        " dynamic-slice(" in line
+        for name in below(slices[0]) for line in bodies[name])
+    # the other gather is the pull's, a chunk a trip of ITS loop
+    loops = {
+        re.search(r"body=(%[\w.\-]+)", line).group(1):
+        re.search(r'op_name="([^"]*)"', line).group(1)
+        for line in text.splitlines() if " while(" in line}
+    assert sorted(loops.values()) == [
+        "jit(step)/ps.pull/while", "jit(step)/ps.push/while"]
+    pulls = [body for body, name in loops.items() if "ps.pull" in name]
+    assert below(pulls[0]) & gathers == gathers - below(reads[0])
+    assert len(below(pulls[0]) & gathers) == 1
 
 
 def test_lr_step_off_the_tpu_keeps_xlas_row_set_in_place(
