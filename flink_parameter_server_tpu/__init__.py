@@ -22,7 +22,7 @@ from .core.batched import BatchedWorkerLogic, PushRequest
 from .core.dense import DenseParameterServer, transform_dense
 from .core.entities import Pull, PullAnswer, Push, PSToWorker, WorkerToPS
 from .core.hybrid import transform_hybrid
-from .core.store import ShardedParamStore, StoreSpec
+from .core.store import GroupSpec, ShardedParamStore, StoreGroup, StoreSpec
 from .core.transform import (
     TransformResult,
     transform,
@@ -89,7 +89,9 @@ __all__ = [
     "WorkerToPS",
     "PSToWorker",
     "ShardedParamStore",
+    "StoreGroup",
     "StoreSpec",
+    "GroupSpec",
     "TransformResult",
     "transform",
     "transform_batched",

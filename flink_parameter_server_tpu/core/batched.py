@@ -38,9 +38,11 @@ Out = TypeVar("Out")
 class PushRequest:
     """A microbatch of pushes: fold ``deltas[i]`` into param ``ids[i]``.
 
-    ``mask`` marks valid lanes (padding-friendly static shapes)."""
+    ``mask`` marks valid lanes (padding-friendly static shapes).  In a
+    step over several stores (below) ``ids`` may be ``None``: the keys the
+    step pulled from that store, lane for lane."""
 
-    ids: Array
+    ids: Optional[Array]
     deltas: Array
     mask: Optional[Array] = None
 
@@ -66,7 +68,14 @@ class BatchedWorkerLogic(abc.ABC, Generic[State, Batch, Out]):
     def step(
         self, state: State, batch: Batch, pulled: Array
     ) -> Tuple[State, PushRequest, Out]:
-        """One compiled training step over the microbatch."""
+        """One compiled training step over the microbatch.
+
+        SEVERAL STORES in one step (``core/store.StoreGroup``; Wide & Deep's
+        cross weights under one rule beside its embeddings under another:
+        ``models/wide_deep.py``): ``keys`` answers ``{store: key block}``,
+        a block perhaps computed from the batch, ``pulled`` is ``{store:
+        rows}`` and the answer's second part ``{store: PushRequest}``
+        (``core/transform.make_train_step`` over a ``GroupSpec``)."""
 
     def for_workers(self, workers: int) -> "BatchedWorkerLogic":
         """The logic ``make_train_step`` traces, asked once with the worker

@@ -35,9 +35,13 @@ the server shard that owns the row (:func:`_push_rule_on_shards`).
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
-from typing import Any, Callable, NamedTuple, Optional, Tuple, Union
+from typing import (
+    Any, Callable, Dict, Iterator, List, Mapping, NamedTuple, Optional, Tuple,
+    Union,
+)
 
 import jax
 import jax.numpy as jnp
@@ -271,6 +275,81 @@ class StoreSpec:
             self.mesh, P(self.ps_axis, *([None] * len(self.value_shape)))
         )
 
+    # -- the seam a loop, a driver and a checkpoint rebuild a store through
+    # (`GroupSpec` answers the same three for several stores) --------------
+    def store(self, table: Optional[Array]) -> "ShardedParamStore":
+        """The store of this spec round ``table`` (its PHYSICAL table, or
+        ``None`` while a loop owns it)."""
+        return ShardedParamStore(self, table)
+
+    def named(self) -> List[Tuple[Dict[str, str], "StoreSpec"]]:
+        """``[(labels, spec)]``: this spec under no label."""
+        return [({}, self)]
+
+    def restored(self, saved: Any, capacity: Any = None) -> "ShardedParamStore":
+        """The store of this spec from a checkpoint's table (LOGICAL rows,
+        ``ShardedParamStore.portable``) saved at ``capacity`` rows: cut to
+        the rows both hold, zeros for the rest (a job restores onto another
+        capacity or shard count), then placed as this spec lays it."""
+        import numpy as np
+
+        held = self.capacity if capacity is None else int(capacity)
+        values = np.asarray(saved)[: min(held, self.capacity)]
+        if values.shape[0] < self.capacity:
+            values = np.concatenate([values, np.zeros(
+                (self.capacity - values.shape[0],) + values.shape[1:],
+                values.dtype)])
+        return ShardedParamStore.from_spec_values(
+            self, jnp.asarray(values, dtype=self.dtype))
+
+
+@dataclasses.dataclass(frozen=True)
+class GroupSpec:
+    """Static configuration of SEVERAL stores that ONE step trains
+    (``core/transform.make_train_step``): named :class:`StoreSpec`s, each
+    with its own key space, row width and update rule (Wide & Deep's
+    hashed-cross FTRL rows beside its AdaGrad embedding rows).  Hashable,
+    as a ``StoreSpec`` is.  It answers what a loop, a driver and a
+    checkpoint ask of a spec (``store``, ``named``, ``restored``, ``mesh``,
+    ``capacity``), so each carries several tables as it carries one."""
+
+    members: Tuple[Tuple[str, StoreSpec], ...]
+
+    def __post_init__(self) -> None:
+        names = [name for name, _ in self.members]
+        if not names or len(set(names)) != len(names) or not all(
+                isinstance(n, str) and n.isidentifier() for n in names):
+            raise ValueError(
+                f"a group's stores carry distinct identifiers: got {names}")
+        if len({spec.mesh for _, spec in self.members}) != 1:
+            raise ValueError("a group's stores lie on ONE mesh (or none)")
+
+    def __getitem__(self, name: str) -> StoreSpec:
+        return dict(self.members)[name]
+
+    def __iter__(self) -> Iterator[str]:
+        return (name for name, _ in self.members)
+
+    @property
+    def mesh(self) -> Optional[Mesh]:
+        return self.members[0][1].mesh
+
+    @property
+    def capacity(self) -> Dict[str, int]:
+        return {name: spec.capacity for name, spec in self.members}
+
+    def store(self, table: Optional[Mapping[str, Array]]) -> "StoreGroup":
+        return StoreGroup(self, table)
+
+    def named(self) -> List[Tuple[Dict[str, str], StoreSpec]]:
+        return [({"store": name}, spec) for name, spec in self.members]
+
+    def restored(self, saved: Any, capacity: Any = None) -> "StoreGroup":
+        return StoreGroup.of({
+            name: spec.restored(
+                saved[name], None if capacity is None else capacity[name])
+            for name, spec in self.members})
+
 
 def zeros_init(spec: StoreSpec) -> InitFn:
     def init(ids: Array) -> Array:
@@ -411,9 +490,19 @@ def pull(
     return _pull(spec, table, ids, worker_part, turned, kept, False)[0]
 
 
+def _store_scope(store: Optional[str]):
+    """``store.<name>`` on the ``op_name`` of every op of ONE store's pull
+    or push in a step over several (``GroupSpec``): a label beside the
+    ``ps.*`` phase the op stands in, which ``chipbench/store_trace.py``
+    reads; a step over one store opens none and keeps its text."""
+    if store is None:
+        return contextlib.nullcontext()
+    return jax.named_scope("store." + store)
+
+
 def pull_counted(
     spec: StoreSpec, table: Array, ids: Array, *, worker_part: bool = False,
-    turned: bool = False, kept: int = 1,
+    turned: bool = False, kept: int = 1, store: Optional[str] = None,
 ) -> Tuple[Array, Optional[PulledRows]]:
     """The pull of a STEP, whose push follows (``make_train_step``):
     :func:`pull`'s answer bit for bit, and beside it what the pull leaves
@@ -423,8 +512,10 @@ def pull_counted(
     row once (:func:`_distinct_pull`), those rows (:class:`PulledRows`).
     ``make_train_step`` hands them to :func:`push_counted` where the
     request's ids are the pulled keys, and to :func:`step_counts`
-    (``ps_pull_distinct_rows``)."""
-    return _pull(spec, table, ids, worker_part, turned, kept, True)
+    (``ps_pull_distinct_rows``).  ``store``: the store's name in a step
+    over several (:func:`_store_scope`)."""
+    with _store_scope(store):
+        return _pull(spec, table, ids, worker_part, turned, kept, True)
 
 
 def _pull(
@@ -633,6 +724,7 @@ def push_counted(
     lanes_over_workers: bool = False,
     turned: bool = False,
     pulled: Optional[PulledRows] = None,
+    store: Optional[str] = None,
 ) -> Tuple[Array, Optional[dict]]:
     """:func:`push`, and beside the table what the push counted on the
     device (``None`` for an ``update="add"`` batch that XLA's scatter-add
@@ -694,7 +786,16 @@ def push_counted(
     it over where the request's ids are the very array it pulled), or
     ``None``: the distinct rows that pull read, on which a rule's push in
     one place runs its rule and so reads the table not at all
-    (:func:`_push_rule`); no other push looks at it."""
+    (:func:`_push_rule`); no other push looks at it.
+
+    ``store``: the store's name in a step over several
+    (:func:`_store_scope`); the counts keep their names, and
+    :func:`step_counts` is told the store too."""
+    if store is not None:
+        with _store_scope(store):
+            return push_counted(
+                spec, table, ids, deltas, mask, turned=turned, pulled=pulled,
+                lanes_over_workers=lanes_over_workers)
     vr = len(spec.value_shape)
     lead = tuple(deltas.shape[: deltas.ndim - vr])
     row = tuple(deltas.shape[deltas.ndim - vr:])
@@ -1370,6 +1471,7 @@ def step_counts(
     spec: StoreSpec, counted: Optional[dict], *, pull_lanes: int,
     push_lanes: int, fields: Optional[int] = None, compute_parts: int = 1,
     crossings: Optional[dict] = None, pulled: Optional[PulledRows] = None,
+    store: Optional[str] = None,
 ) -> dict:
     """What a step hands out of its pull and its push beside the logic's
     outputs: what :func:`push_counted` counted and, for a store packed
@@ -1413,7 +1515,17 @@ def step_counts(
     it read the batch's distinct rows once, ``ps_pull_distinct_rows`` is
     how many it read (the gauge ``store_pull_distinct_rows``; under
     ``store_rule_keys`` it says how many lanes shared a fetched row: 3.6 on
-    Criteo records, 1.0 where the arm sorted for nothing)."""
+    Criteo records, 1.0 where the arm sorted for nothing).
+
+    ``store``: in a step over several stores (``GroupSpec``) every output
+    of one store leaves as ``<name>@<store>``, and :func:`publish_counts`
+    sets its gauges under the label ``store=<store>``."""
+    if store is not None:
+        own = step_counts(
+            spec, counted, pull_lanes=pull_lanes, push_lanes=push_lanes,
+            fields=fields, compute_parts=compute_parts, crossings=crossings,
+            pulled=pulled)
+        return {f"{name}@{store}": value for name, value in own.items()}
     out = dict(counted or {})
     if pulled is not None:
         out["ps_pull_distinct_rows"] = pulled.count
@@ -1438,6 +1550,16 @@ def step_counts(
     return out
 
 
+class _Labelled:
+    """A registry whose gauges carry ``store=<name>``."""
+
+    def __init__(self, registry: Any, store: str):
+        self._registry, self._store = registry, store
+
+    def gauge(self, name: str, **labels):
+        return self._registry.gauge(name, store=self._store, **labels)
+
+
 def publish_counts(outs: dict, registry: Any, total, peak) -> None:
     """The store's own outputs of a dispatch (:func:`step_counts`: every
     ``ps_*`` key of ``outs``) as its ``store_*`` gauges, ``component=train``
@@ -1446,7 +1568,18 @@ def publish_counts(outs: dict, registry: Any, total, peak) -> None:
     a count's sum, a constant's maximum.  Whoever fetches a step's outputs
     calls this where it fetches them anyway
     (``StreamingDriver._publish_step_counts``).  The names are literal:
-    ``tools/fpsanalyze`` matches them to the catalog."""
+    ``tools/fpsanalyze`` matches them to the catalog.  The outputs of a step
+    over several stores (``<name>@<store>``: :func:`step_counts`) are set
+    store by store, each gauge under the label ``store=<store>``."""
+    by_store: Dict[str, dict] = {}
+    for key, value in outs.items():
+        name, _, store = key.partition("@")
+        if store:
+            by_store.setdefault(store, {})[name] = value
+    if by_store:
+        for store, own in by_store.items():
+            publish_counts(own, _Labelled(registry, store), total, peak)
+        return
     # (1 where the step says nothing: the whole minibatch in every place)
     registry.gauge("store_compute_parts", component="train").set(
         peak(outs["ps_compute_parts"]) if "ps_compute_parts" in outs else 1)
@@ -2203,7 +2336,67 @@ class ShardedParamStore:
             return self.table[: spec.capacity, : spec.row_width]
         return self.table[: spec.capacity]
 
+    def portable(self) -> Array:
+        """The table a checkpoint keeps, in LOGICAL row order: a dense
+        store's padded table as it lies (zero-copy, shard by shard;
+        ``StoreSpec.restored`` cuts it to ``capacity``); a packed store's
+        rows unpacked and a narrow rule store's without their zero lanes
+        (the physical layout is the device's matter, no portable format)."""
+        spec = self.spec
+        if spec.layout == "packed" or spec.tile_lanes:
+            return self.values()
+        return self.table
+
+    def dump(self) -> Tuple[Any, Any]:
+        """``(ids, values)`` on the host: the close-time model flush."""
+        import numpy as np
+
+        return np.arange(self.spec.capacity), np.asarray(self.values())
+
     # -- pytree plumbing ---------------------------------------------------
+    def tree_flatten(self):
+        return (self.table,), self.spec
+
+    @classmethod
+    def tree_unflatten(cls, spec, leaves):
+        return cls(spec, leaves[0])
+
+
+@jax.tree_util.register_pytree_node_class
+class StoreGroup:
+    """Named :class:`ShardedParamStore`s that ONE step trains
+    (:class:`GroupSpec`): what ``StreamingDriver(logic, store)`` and
+    ``transform_batched`` take where a model's parameter groups differ in
+    key space, row width AND update rule.  Built as a store is, from a spec
+    and a table: ``StoreGroup(spec, table)`` with ``table`` the pytree
+    ``{name: table}`` the step donates (``None`` while a loop owns the
+    tables), or :meth:`of` from stores that stand.  ``group[name]`` is a
+    member, to pull from; ``values``, ``portable`` and ``dump`` answer name
+    by name what a member answers."""
+
+    def __init__(self, spec: GroupSpec, table: Optional[Mapping[str, Array]]):
+        self.spec = spec
+        self.table = None if table is None else {n: table[n] for n in spec}
+
+    @classmethod
+    def of(cls, stores: Mapping[str, ShardedParamStore]) -> "StoreGroup":
+        return cls(
+            GroupSpec(tuple((n, s.spec) for n, s in stores.items())),
+            {n: s.table for n, s in stores.items()})
+
+    def __getitem__(self, name: str) -> ShardedParamStore:
+        return ShardedParamStore(
+            self.spec[name], None if self.table is None else self.table[name])
+
+    def values(self) -> Dict[str, Array]:
+        return {name: self[name].values() for name in self.spec}
+
+    def portable(self) -> Dict[str, Array]:
+        return {name: self[name].portable() for name in self.spec}
+
+    def dump(self) -> Dict[str, Tuple[Any, Any]]:
+        return {name: self[name].dump() for name in self.spec}
+
     def tree_flatten(self):
         return (self.table,), self.spec
 
@@ -2214,7 +2407,9 @@ class ShardedParamStore:
 
 __all__ = [
     "StoreSpec",
+    "GroupSpec",
     "ShardedParamStore",
+    "StoreGroup",
     "create_table",
     "pull",
     "push",

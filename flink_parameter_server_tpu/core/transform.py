@@ -41,7 +41,7 @@ from .api import (
 )
 from .batched import BatchedWorkerLogic
 from .entities import Pull, PullAnswer, Push, PSToWorker, WorkerToPS
-from .store import ShardedParamStore
+from .store import ShardedParamStore, StoreGroup
 from ..parallel.mesh import DP_AXIS, worker_count
 from ..telemetry.compile_ledger import setup_span
 from ..telemetry.spans import NULL_TRACER, SpanTracer
@@ -345,9 +345,16 @@ def make_train_step(logic: BatchedWorkerLogic, spec) -> Callable:
     other logic computes the whole minibatch on every chip, which costs it
     nothing worth a scatter and a gather of its rows (FM, DiFacto: 0.16 /
     0.065 ms a step).
+
+    ``spec`` may be a ``core/store.GroupSpec``: SEVERAL named stores in the
+    one step, each with its own key space, row width and rule
+    (:func:`_make_group_train_step`; the step then takes and hands back
+    ``{name: table}``).  A single spec's step is the program it always was.
     """
     from . import store as store_mod
 
+    if isinstance(spec, store_mod.GroupSpec):
+        return _make_group_train_step(logic, spec)
     workers = worker_count(spec.mesh)
     # (both read with defaults: a logic need not be a BatchedWorkerLogic)
     logic = getattr(logic, "for_workers", lambda workers: logic)(workers)
@@ -441,6 +448,65 @@ def make_train_step(logic: BatchedWorkerLogic, spec) -> Callable:
     return step
 
 
+def _make_group_train_step(logic: BatchedWorkerLogic, spec) -> Callable:
+    """:func:`make_train_step` over the SEVERAL stores of a
+    ``core/store.GroupSpec``: ``step(tables, state, batch)`` with ``tables``
+    ``{name: table}``, every one donated with the state.  The logic's
+    ``keys`` answers ``{name: key block}``, a block perhaps COMPUTED from
+    the batch inside the step (a hashed cross); its ``step`` takes
+    ``pulled`` as ``{name: rows}`` and answers ``{name: PushRequest}``.
+    ONE pull and ONE push a store, in the group's order, each under its
+    phase's scope (``ps.pull`` / ``ps.push``: the pulls of all stores stand
+    before the compute, the pushes behind it) and the store's own label
+    (``core/store.pull_counted`` / ``push_counted``'s ``store``).  A request
+    whose ``ids`` is ``None`` pushes to the keys its store pulled, lane for
+    lane, the very array: what the pull left for the push of the same keys
+    (``PulledRows``) is then the push's, whether the keys were staged or
+    computed.  The stores' counts leave among the outputs as
+    ``<count>@<store>`` (``core/store.step_counts``).
+
+    In one place, or under a mesh of ONE worker group: every store takes
+    the arms it would take alone.  A turned pull, a split of the compute
+    over the servers and a batch split over ``dp`` workers are a single
+    store's step's: the logic is traced as it is given (``for_workers`` is
+    not asked), and ``dp`` > 1 raises."""
+    from . import store as store_mod
+
+    if worker_count(spec.mesh) > 1:
+        raise ValueError(
+            "a step over several stores runs in one place or under ONE "
+            f"worker group: this mesh holds {worker_count(spec.mesh)}")
+    names = tuple(spec)
+
+    def step(tables, state, batch):
+        keys = logic.keys(batch)
+        pulled, left = {}, {}
+        for name in names:
+            with scope("ps.pull"):
+                pulled[name], left[name] = store_mod.pull_counted(
+                    spec[name], tables[name], keys[name], worker_part=True,
+                    store=name)
+        with scope("ps.compute"):
+            state, reqs, out = logic.step(state, batch, pulled)
+        tables, counts = dict(tables), {}
+        for name in names:
+            req = reqs[name]
+            ids = keys[name] if req.ids is None else req.ids
+            with scope("ps.push"):
+                tables[name], counted = store_mod.push_counted(
+                    spec[name], tables[name], ids, req.deltas, req.mask,
+                    pulled=left[name] if ids is keys[name] else None,
+                    store=name)
+            counts.update(store_mod.step_counts(
+                spec[name], counted, pull_lanes=keys[name].size,
+                push_lanes=ids.size, pulled=left[name], store=name))
+        if isinstance(out, dict):
+            out = {**out, **counts}
+        return tables, state, out
+
+    return step
+
+
 def jit_train_steps(
     logic: BatchedWorkerLogic, spec, steps_per_call: int = 1
 ) -> Tuple[Callable, Optional[Callable]]:
@@ -470,7 +536,7 @@ def _committed_where(table, mesh=None):
     and traced, lowered and loaded the step once more (DLRM's MLPs beside a
     table over ``ps``: PERF.md section 6, PR 67).  A committed leaf
     elsewhere is the caller's to answer for, as it always was."""
-    devices = table.sharding.device_set
+    devices = jax.tree.leaves(table)[0].sharding.device_set
     everywhere = None
     if mesh is not None and set(mesh.devices.flat) == devices:
         everywhere = NamedSharding(mesh, PartitionSpec())
@@ -484,7 +550,8 @@ def _committed_where(table, mesh=None):
             return jax.device_put(x, everywhere)
         return x
 
-    return commit(table), commit
+    # (a group of stores hands its tables as a pytree: every one)
+    return jax.tree.map(commit, table), commit
 
 
 def scan_group_sharding(batch_sharding):
@@ -692,7 +759,8 @@ def transform_batched(
     # loaded once more (no copy: the array keeps its buffers; the ledger's
     # `setup.commit` holds that claim)
     with setup_span("commit"):
-        table, commit = _committed_where(keep(store.table), mesh)
+        table, commit = _committed_where(
+            jax.tree.map(keep, store.table), mesh)
         state = jax.tree.map(commit, state)
     worker_outputs: List[Any] = []
     step_idx = 0
@@ -779,13 +847,11 @@ def transform_batched(
     if inflight is not None:
         # the loop has ended: its books hold no output past it
         inflight.pending.clear()
-    final_store = ShardedParamStore(spec, table)
+    final_store = spec.store(table)
     server_outputs: List[Any] = []
     if dump_model:
         # close()-time model flush (reference §3.5): emit the final table.
-        server_outputs.append(
-            (np.arange(spec.capacity), np.asarray(final_store.values()))
-        )
+        server_outputs.append(final_store.dump())
     finish = worker_logic.finish(state)
     if finish is not None:
         worker_outputs.append(finish)
@@ -841,9 +907,10 @@ def transform(
     the microbatch itself is the combination buffer, so they are ignored.
     """
     if isinstance(worker_logic, BatchedWorkerLogic):
-        if not isinstance(ps_logic, ShardedParamStore):
+        if not isinstance(ps_logic, (ShardedParamStore, StoreGroup)):
             raise TypeError(
-                "batched worker logic requires a ShardedParamStore server"
+                "batched worker logic requires a ShardedParamStore server "
+                "(or a StoreGroup of them)"
             )
         return transform_batched(data, worker_logic, ps_logic, **batched_kwargs)
 

@@ -29,18 +29,11 @@ def _ocp():
 
 
 def _make_payload(store, worker_state, step, extra):
-    # The payload table is in LOGICAL row order: dense stores pass the
-    # padded table straight through (zero-copy per-shard save — restore
-    # slices to `capacity`); packed stores unpack first, and a narrow rule
-    # store strips its rows' zero lanes (the physical layout is an
-    # on-device detail, not a portable format).
-    spec = store.spec
-    table = (
-        store.values() if spec.layout == "packed" or spec.tile_lanes
-        else store.table
-    )
+    # The payload table is in LOGICAL row order, as the store itself hands
+    # it out (`ShardedParamStore.portable`; a `StoreGroup`'s is a table a
+    # name, and its `capacity` a count a name).
     return {
-        "table": table,
+        "table": store.portable(),
         "worker_state": worker_state if worker_state is not None else (),
         "meta": {
             "step": step,
@@ -101,17 +94,10 @@ def _payload_to_state(
 ) -> Tuple[ShardedParamStore, Any, Dict[str, Any]]:
     """Re-place a restored payload onto the target spec (elastic)."""
     meta = payload.get("meta", {})
-    capacity = int(meta.get("capacity", spec.capacity))
-    values = np.asarray(payload["table"])[: min(capacity, spec.capacity)]
-    if values.shape[0] < spec.capacity:
-        values = np.concatenate(
-            [values, np.zeros((spec.capacity - values.shape[0],) + values.shape[1:], values.dtype)]
-        )
-    # Rebuild on the *target* spec directly so nothing is dropped in the
-    # round-trip (update rule, mesh, layout).
-    store = ShardedParamStore.from_spec_values(
-        spec, jax.numpy.asarray(values, dtype=spec.dtype)
-    )
+    # Rebuilt on the *target* spec by the spec itself, so nothing is dropped
+    # in the round-trip (update rule, mesh, layout) and a group of stores
+    # comes back through the same call (`StoreSpec.restored`).
+    store = spec.restored(payload["table"], meta.get("capacity"))
     worker_state = payload.get("worker_state")
     if worker_state_shardings is not None and worker_state is not None:
         worker_state = jax.tree.map(

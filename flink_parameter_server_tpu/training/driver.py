@@ -202,9 +202,11 @@ class StreamingDriver:
         if self.registry is not None:
             # which layout the store resolved to (fixed when it was built:
             # a stored value, so the registry holds no driver and no table)
-            self.registry.gauge(
-                "store_layout_packed", component="train"
-            ).set(store.spec.layout == "packed")
+            # (a group of stores: one gauge a store, labelled by its name)
+            for labels, member in store.spec.named():
+                self.registry.gauge(
+                    "store_layout_packed", component="train", **labels
+                ).set(member.layout == "packed")
             # what the ring has dropped since its last clear(): above 0 a
             # reader of "the run's spans" is reading their tail
             tracer = self.tracer
@@ -600,8 +602,7 @@ class StreamingDriver:
                 if self._ckpt_mgr is not None:
                     with tracer.span("checkpoint", component="train"):
                         self._ckpt_mgr.save(
-                            global_step, ShardedParamStore(spec, table),
-                            state,
+                            global_step, spec.store(table), state,
                         )
                     if self.registry is not None:
                         self.registry.counter(
@@ -652,7 +653,7 @@ class StreamingDriver:
         # spec and NO table meanwhile, so it never holds a deleted array;
         # `live` names the buffers the last dispatch left, for the
         # handler below to take back if the run raises.
-        handed, self.store = self.store, ShardedParamStore(spec, None)
+        handed, self.store = self.store, spec.store(None)
         handed_state, self._state = self._state, None
         live = [handed.table, handed_state]
         last_outs = [None]  # the newest dispatch's outputs, never synced here
@@ -692,7 +693,7 @@ class StreamingDriver:
             # have consumed them, and then the store keeps no table),
             # then reload the last durable checkpoint, if any.
             if _is_live(*live):
-                self.store = ShardedParamStore(spec, live[0])
+                self.store = spec.store(live[0])
                 self._state = live[1]
             if self._ckpt_mgr is not None:
                 self.resume()

@@ -2962,3 +2962,127 @@ def test_dcn_step_fits_beside_its_two_register_table(
     assert "ps.push/while/body" in by_name["%sorted_row_assign_tiles"]
     assert "ps.push/ps.combine/while/body" in by_name["%sorted_run_sums"]
     assert not [line for line in lines if re.search(r" scatter\(", line)]
+
+
+# wdl-criteo-10m (chipbench/configs): cell 17's two tables, as they lie
+WDL_DEEP_TABLE, WDL_WIDE_TABLE = (24_563_152, 128), (27_262_976, 4)
+WDL_BATCH, WDL_FIELDS = 32_768, 26
+
+
+@pytest.fixture(scope="module")
+def wdl_tpu_step(one_chip):
+    """Cell 17's step over its TWO stores at full size for a described v5e,
+    as the chip runs it, compiled ONCE: ``(text, memory analysis)``."""
+    from chipbench import spec as bench_spec
+    from flink_parameter_server_tpu.models import wide_deep as wd
+
+    cfg = bench_spec.resolve(
+        bench_spec.load_benchmark(), "wdl-criteo-10m.train-fields-uniform",
+        dry_run=False)["cfg"]
+    model = wd.WideDeepConfig(
+        tuple(cfg["field_cardinalities"]), dim=cfg["dim"],
+        hidden=tuple(cfg["hidden"]), cross_buckets=cfg["cross_buckets"])
+    with _compiling_for_described_chips("tpu"):
+        spec = jax.eval_shape(lambda: wd.make_stores(model)).spec
+        assert spec["deep"].table_shape() == WDL_DEEP_TABLE
+        assert spec["wide"].table_shape() == WDL_WIDE_TABLE
+        lanes = WDL_BATCH * WDL_FIELDS
+        n0 = row_update.refusal_count()
+        assert store_mod.arms(
+            spec["wide"], pull_lanes=lanes, push_lanes=lanes
+        ) == store_mod.Arms(
+            "narrow_distinct", "rule", "", "sort", "tile_set", False)
+        assert store_mod.arms(
+            spec["deep"], pull_lanes=lanes, push_lanes=lanes
+        ) == store_mod.Arms(
+            "packed_kernel", "rule", "", "row_kernel", "row_set", False)
+        assert row_update.refusal_count() == n0
+        logic = wd.WideAndDeep(model)
+        tables = {
+            name: _shape(one_chip, spec[name].table_shape(), jnp.float32)
+            for name in spec}
+        state = jax.tree.map(
+            lambda x: _shape(one_chip, x.shape, x.dtype),
+            jax.eval_shape(lambda: logic.init_state(jax.random.PRNGKey(0))))
+        batch = {
+            "dense": _shape(one_chip, (WDL_BATCH, 13), jnp.float32),
+            "ids": _shape(one_chip, (WDL_BATCH, WDL_FIELDS), jnp.int32),
+            "label": _shape(one_chip, (WDL_BATCH,), jnp.float32),
+            "mask": _shape(one_chip, (WDL_BATCH,), jnp.bool_),
+        }
+        compiled = jax.jit(
+            make_train_step(logic, spec), donate_argnums=(0, 1)
+        ).lower(tables, state, batch).compile()
+        return compiled.as_text(), compiled.memory_analysis()
+
+
+def test_the_two_store_step_rewrites_both_tables_in_place(wdl_tpu_step):
+    """ONE program over both stores, both tables donated: 13.02 GB aliased
+    (12.58 + 0.44), under 1 GB of temporaries (they go with the batch), and
+    of each table exactly ONE op yields a table: its write-back's kernel in
+    its rule's loop, under its own store's label.  Nothing copies,
+    transposes or scatters either table."""
+    text, mem = wdl_tpu_step
+    assert 13.0 * GB < mem.alias_size_in_bytes < 13.05 * GB
+    assert mem.temp_size_in_bytes < 1.1 * GB
+    for shape, kernel, where in (
+            (r"24563152,128", "%sorted_row_set.", "ps.push/store.deep/while/body"),
+            (r"(27262976,4|4,27262976)", "%sorted_row_set_tiles.",
+             "ps.push/store.wide/while/body")):
+        table = rf"f32\[{shape}\]"
+        yields = [
+            line.strip() for line in text.splitlines()
+            if re.search(rf" = {table}\S* (?!parameter|get-tuple-element)",
+                         line)]
+        kernels = [y for y in yields if " custom-call(" in y]
+        assert len(kernels) == 1 and kernels[0].startswith(kernel), yields
+        assert where in kernels[0]
+        # what else yields a table only names it anew
+        assert all(" bitcast(" in y for y in yields if y not in kernels), yields
+        assert not re.search(table + r"\S* (copy|transpose|scatter)\(", text)
+    assert not [line for line in text.splitlines()
+                if re.search(r" scatter\(", line)]
+
+
+def test_the_two_store_step_holds_one_gather_and_one_write_back_a_store(
+        wdl_tpu_step):
+    """What reads a table: the deep pull's ONE gather of 851,968 whole
+    physical rows and its rule's chunk of 32,768; the wide pull's chunk of
+    32,768 distinct 3-lane rows in its loop and the rule's own under the
+    branch a masked step takes (cell 6's pair).  The Pallas calls are the
+    four the stores would take alone: the deep pull's lane slice, the deep
+    combine's row sums, the two write-backs.  Four loops: the wide pull's
+    and rule's, the deep combine's and rule's.  The hash stands under its
+    own scope in front of the pulls, the net under the logic's."""
+    text, _ = wdl_tpu_step
+    lines = text.splitlines()
+    gathers = [line for line in lines if re.search(r" gather\(", line)]
+    lanes = WDL_BATCH * WDL_FIELDS
+    deep_pull = [g for g in gathers if f" = f32[{lanes},128]" in g]
+    assert len(deep_pull) == 1 and "ps.pull/store.deep" in deep_pull[0]
+    deep_rule = [g for g in gathers if " = f32[32768,128]" in g]
+    assert len(deep_rule) == 1
+    assert "ps.push/store.deep/while/body/ps.rule" in deep_rule[0]
+    wide = [g for g in gathers if " = f32[32768,3]" in g]
+    assert len(wide) == 2 and all("slice_sizes={1,3}" in g for g in wide)
+    assert not [g for g in gathers if f" = f32[{lanes},3]" in g]
+    kernels = [line for line in lines if "tpu_custom_call" in line]
+    names = sorted(
+        k.strip().split(" ", 1)[0].rstrip(".0123456789") for k in kernels)
+    assert names == [
+        "%packed_lane_slice", "%sorted_row_set", "%sorted_row_set_tiles",
+        "%sorted_run_sums"], names
+    assert len(re.findall(r" while\(", text)) == 4
+    for scope in ("ps.cross_hash/", "ps.pull/store.wide", "ps.pull/store.deep",
+                  "ps.compute/ps.dense_top/", "ps.compute/ps.dense_adagrad/",
+                  "ps.compute/ps.delta_build/",
+                  "ps.push/store.wide/ps.combine",
+                  "ps.push/store.deep/ps.combine",
+                  "ps.push/store.wide/while/body/ps.rule",
+                  "ps.push/store.deep/while/body/ps.rule"):
+        assert scope in text, scope
+    # the backward product that ends at the pulled rows carries the net's
+    # scope, the cut to the rows' lanes fused into it
+    assert not [
+        line for line in lines if " convolution(" in line
+        and "ps.delta_build" in line]
