@@ -735,8 +735,14 @@ def push_counted(
     made of: two DMA descriptors a tile row).  ``rule``:
     ``ps_rule_keys``, the live lanes of the batch, ``ps_rule_rows``, the
     distinct rows the rule rewrote, and ``ps_rule_tiles``, the tiles of 128
-    rows the write-back read and wrote to do so (0 where XLA's ``set``
-    wrote the rows); where ``combine`` is not ``sort`` also
+    rows the write-back touched to do so (0 where XLA's ``set``
+    wrote the rows), and where the set kernel wrote them
+    (``write_back`` ``tile_set``) ``ps_rule_descriptors``, the DMA
+    descriptors it issued (one in and one out for every copy, of one tile
+    or of a span of side-by-side tiles: ``ops/row_update.set_span``), and
+    ``ps_rule_span_tiles``, the tiles those copies moved each way, the
+    untouched ones inside a span among them; where ``combine`` is not
+    ``sort`` also
     ``ps_combine_kernel_lanes``, the lanes
     whose rows the row kernel summed (the live lanes; 0 where XLA's
     scatter-add summed them), and
@@ -908,7 +914,10 @@ def _push_rule(
     its sublane tile
     (``StoreSpec.tile_lanes``), ``ops/row_update.sorted_tile_set``: every
     touched tile of 128 rows read, set and written back once, the same
-    bits.  A PACKED store's chunk goes through
+    bits, side-by-side tiles in one copy where this push's lanes lie close
+    on the table (``ops/row_update.set_span``, told the push's ``n``
+    lanes; ``ps_rule_descriptors`` / ``ps_rule_span_tiles`` count what it
+    issued and moved).  A PACKED store's chunk goes through
     :func:`_rewrite_packed`, which reads and writes whole physical rows,
     and ``counted`` then carries ``ps_rule_packed_rows``.  A rule row wider
     than a register lies flat in several (packed at
@@ -954,7 +963,7 @@ def _push_rule(
     step on which they differ reads its rows from the table as it always
     did (a branch of every chunk, the parent's gather: the same bits)."""
     from ..ops.dedup import combine_runs
-    from ..ops.row_update import sorted_tile_set
+    from ..ops.row_update import TileSetCounts, sorted_tile_set
 
     n = flat_ids.shape[0]
     if pulled is not None and pulled.ids.shape[0] != n:
@@ -964,6 +973,8 @@ def _push_rule(
     packed = spec.layout == "packed"
     # what the push hands out, an empty batch's too: by the arms alone
     names = ["ps_rule_keys", "ps_rule_rows", "ps_rule_tiles"]
+    if arm.write_back == "tile_set":
+        names += ["ps_rule_descriptors", "ps_rule_span_tiles"]
     if arm.combine != "sort":
         names += ["ps_combine_kernel_lanes", "ps_combine_kernel_writes"]
     if packed:
@@ -1008,7 +1019,9 @@ def _push_rule(
             shared = jnp.array_equal(row_ids[:n], pulled.ids)
 
     def rewrite(i, carry):
-        table, moved = carry  # tiles, or a packed store's physical rows
+        # tiles, or a packed store's physical rows (the set kernel's: its
+        # `TileSetCounts`)
+        table, moved = carry
         ids = jax.lax.dynamic_slice(row_ids, (i * chunk,), (chunk,))
         # (the tile kernel's sums come in whole registers, zeros past
         # `width`: the chunk is cut out at the width the rule is handed)
@@ -1033,13 +1046,19 @@ def _push_rule(
                 current, sums.reshape((chunk,) + _rule_takes(spec, width)),
             ).astype(table.dtype)
         if arm.write_back == "tile_set":
-            table, opened = sorted_tile_set(table, ids, new)
-            return table, moved + opened
+            table, counts = sorted_tile_set(table, ids, new, of=n)
+            return table, jax.tree.map(jnp.add, moved, counts)
         return table.at[ids].set(_physical_rows(spec, new), mode="drop"), moved
 
     chunks = -(-counted["ps_rule_rows"] // chunk)
     zero = jnp.zeros((), jnp.int32)
-    table, moved = jax.lax.fori_loop(0, chunks, rewrite, (table, zero))
+    tile_set = arm.write_back == "tile_set"
+    table, moved = jax.lax.fori_loop(0, chunks, rewrite, (
+        table, TileSetCounts(zero, zero, zero) if tile_set else zero))
+    if tile_set:  # a copy is a descriptor in and a descriptor out
+        counted["ps_rule_descriptors"] = 2 * moved.copies
+        counted["ps_rule_span_tiles"] = moved.moved
+        moved = moved.touched
     # a flat wide row's write-back counts its tile rows where the tile
     # kernel wrote them; its physical rows are its distinct rows (k = 1)
     wide_tiles = arm.write_back == "tile_assign"
@@ -1291,7 +1310,7 @@ def arms(
     ================================  ======================  ===========  ===========  =========  ====  ========
     spec                              pull                    combine      write_back   on_shards  cell  PR
     ================================  ======================  ===========  ===========  =========  ====  ========
-    3 lanes, held at its tile of 4    narrow_distinct         sort         tile_set     no         6     34 35 70
+    3 lanes, held at its tile of 4    narrow_distinct         sort         tile_set     no         6 17  34 35 70 72
     3 lanes over ps 4, dp 1           take                    sort         xla_set      yes        none  70
     6 lanes, held at its tile of 8    narrow                  row_kernel   tile_set     no         none  35 46
     (2, 2) lanes: rank 2, no tile     take                    sort         xla_set      no         none  35
@@ -1625,6 +1644,11 @@ def publish_counts(outs: dict, registry: Any, total, peak) -> None:
         total(outs["ps_rule_rows"]))
     registry.gauge("store_rule_tiles", component="train").set(
         total(outs["ps_rule_tiles"]))
+    if "ps_rule_descriptors" in outs:
+        registry.gauge("store_rule_descriptors", component="train").set(
+            total(outs["ps_rule_descriptors"]))
+        registry.gauge("store_rule_span_tiles", component="train").set(
+            total(outs["ps_rule_span_tiles"]))
     if "ps_pull_distinct_rows" in outs:
         registry.gauge("store_pull_distinct_rows", component="train").set(
             total(outs["ps_pull_distinct_rows"]))

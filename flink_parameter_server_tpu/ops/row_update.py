@@ -116,17 +116,27 @@ _tile_kernel_takes``): cell 10's 851,968 lanes on 228 k tile rows of
 **Narrow rows under a rule** (``core/store._push_rule``'s write-back of
 FTRL's ``(w, z, n)``: :func:`sorted_tile_set`) go through the third.  A
 float32 table of rows of 1, 2, 4 or 8 lanes lies rows-minor on the TPU,
-``{0,1:T(L,128)}``: byte for byte the row-major ``(L, rows)``, a TILE of it
-128 consecutive rows, ``L x 512`` contiguous bytes, and what XLA's row
-``set`` writes at 83 ns a row is one column of such a tile.  The kernel
-reads every tile the sorted distinct ids touch into VMEM, sets each row's
-column by selects on iotas (its values scalars from SMEM) and writes the
-tiles back.  A tile belongs to the block of lanes that holds its first
-lane, whose sets reach into the next block's lanes, so no two grid steps
-touch one tile: the next block's reads are in flight under this block's
-sets and the block before's writes, three tile buffers.  Its time is the
-issue of two DMA descriptors a tile (17 ns each on the v5e) and 7 ns a lane
-of sets (PERF.md section 6, PR 35).
+``{0,1:T(L,128)}``: byte for byte the row-major ``(rows / 128, L, 128)``, a
+TILE of it 128 consecutive rows, ``L x 512`` contiguous bytes, the tiles
+side by side, and what XLA's row ``set`` writes at 83 ns a row is one column
+of such a tile.  The kernel reads every tile the sorted distinct ids touch
+into VMEM, sets each row's column by selects on iotas (its values scalars
+from SMEM) and writes the tiles back.  A tile belongs to the block of lanes
+that holds its first lane, whose sets reach into the next blocks' lanes, so
+no two grid steps touch one tile: the next block's reads are in flight
+under this block's sets and the block before's writes, three tile buffers.
+Its time was the issue of two DMA descriptors a tile (17 ns each on the
+v5e) and 7 ns a lane of sets (PERF.md section 6, PR 35).  Since PR 72 a
+copy takes a SPAN where the touched tiles lie close: the tiles stand in
+aligned groups of ``W`` (:func:`set_span`: from the table's tiles and the
+push's lanes, 32 in cell 17, 8 in cell 6, 1 where a push is short against
+its table), and a whole group that the ids touch in two tiles or more is
+read and written by ONE descriptor each way, the untouched tiles inside it
+as they are; any other touched tile is copied alone (the table's last,
+ragged group; a lone tile).  Cell 17's 187.6 k touched tiles a step go in
+6.6 k copies and its write-back took 13.1 ms alone and takes 7.5 (11.8 ->
+6.4 in the step: the 685 k lane sets are what is left), cell 6's 180.6 k
+in 110.4 k, 9.7 -> 7.8 (PERF.md section 6, PR 72).
 
 **A packed rule store's write-back** (``core/store._rewrite_packed``: whole
 128-lane physical rows, distinct, sorted, lanes to drop between them:
@@ -143,10 +153,11 @@ from __future__ import annotations
 
 import functools
 import importlib
+import math
 import sys
 import threading
 import warnings
-from typing import Optional, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -1413,21 +1424,28 @@ def set_refusal(table_shape: Tuple[int, ...], dtype) -> Optional[str]:
 
 def _tile_set_kernel(tiles_ref, words_ref, counts_ref, vals_ref, table_ref,
                      out_ref, tile_buf, sem, *, block: int, width: int,
-                     lanes: int):
-    """One grid step = the tiles that ``block`` sorted lanes OPEN, and every
-    lane of those tiles (the last may reach into the next block's lanes, so
-    no two steps touch one tile and nothing orders their copies).
+                     lanes: int, span: int):
+    """One grid step = the copies that ``block`` sorted lanes OPEN, and every
+    lane of the tiles they move (the last may reach into later blocks'
+    lanes, so no two steps touch one tile and nothing orders their copies).
+    A copy moves ONE touched tile or, where ``span`` > 1, a whole span: the
+    ``span`` tiles of an aligned group that the ids touch in several places
+    (:func:`_tile_set_plan`), one descriptor each way for all of them.
 
     tiles_ref: (N,) int32 SMEM (scalar prefetch) — at the head of each
-      block's stretch, the tiles (row // 128) whose first lane lies in the
-      block, ascending.
+      block's stretch, the first tile (row // 128) of each span whose first
+      lane lies in the block, ascending, then the single tiles it opens.
     words_ref: (N,) int32 SMEM — per kept lane, its tile's place in its
-      OWNER block's list (bits 0-15) and its row's lane in the tile (16-22).
-    counts_ref: (3 N / block,) int32 SMEM — per block, how many tiles it
-      opens, the first lane it owns and how many lanes it owns.
+      OWNER block's buffer (bits 0-15) and its row's lane in the tile
+      (16-22).  The spans' tiles lie first there, side by side in the
+      list's order, then the single tiles.
+    counts_ref: (4 N / block,) int32 SMEM — per block, how many single
+      tiles and how many spans it opens, the first lane it owns and how
+      many lanes it owns.
     vals_ref: (width x N,) float32 SMEM — the new rows, lane-major.
-    table_ref / out_ref: the aliased (L, rows) view of the table in HBM.
-    tile_buf: (3, block, L, 128) f32 VMEM — three blocks' tiles: one being
+    table_ref / out_ref: the aliased (tiles, L, 128) view of the table in
+      HBM.
+    tile_buf: (3, P, L, 128) f32 VMEM — three blocks' tiles: one being
       read, one being set, one being written back.
     sem: (2, 3) DMA semaphores — reads and writes of each slot.
     """
@@ -1436,29 +1454,37 @@ def _tile_set_kernel(tiles_ref, words_ref, counts_ref, vals_ref, table_ref,
     del table_ref  # aliased to out_ref
     b, blocks = pl.program_id(0), pl.num_programs(0)
     reads, writes = 0, 1
-    shape = tile_buf.shape[2:]  # (L, 128)
 
-    def tile(blk, j):
-        first = pl.multiple_of(tiles_ref[blk * block + j] * _TILE_ROWS,
-                               _TILE_ROWS)
-        return out_ref.at[:, pl.ds(first, _TILE_ROWS)]
-
-    def start_reads(blk):
+    def start_copies(blk, which):
+        """Block ``blk``'s tiles from the table into its slot (``reads``)
+        or back (``writes``)."""
         slot = jax.lax.rem(blk, 3)
+        spans = counts_ref[4 * blk + 1] if span > 1 else 0
 
-        def read(j):
-            pltpu.make_async_copy(
-                tile(blk, j), tile_buf.at[slot, j], sem.at[reads, slot]
-            ).start()
+        def start(table, held):
+            pair = (table, held) if which == reads else (held, table)
+            pltpu.make_async_copy(*pair, sem.at[which, slot]).start()
 
-        _each(counts_ref[3 * blk], read)
+        def whole_span(j):
+            start(out_ref.at[pl.ds(tiles_ref[blk * block + j], span)],
+                  tile_buf.at[slot, pl.ds(j * span, span)])
+
+        def single(j):
+            start(out_ref.at[tiles_ref[blk * block + spans + j]],
+                  tile_buf.at[slot, spans * span + j])
+
+        if span > 1:
+            _each(spans, whole_span)
+        _each(counts_ref[4 * blk], single)
 
     def await_copies(blk, which):
         # a DMA semaphore counts bytes: a block's copies are answered by a
-        # wait the size of sixteen tiles for every sixteen of them and one
-        # the size of a tile for each of the rest (a wait a tile is 6.5 ns
-        # on the v5e, 2.3 ms a step of cell 6)
-        slot, count = jax.lax.rem(blk, 3), counts_ref[3 * blk]
+        # wait the size of sixteen tiles for every sixteen tiles of them and
+        # one the size of a tile for each of the rest (a wait a tile is 6.5
+        # ns on the v5e, 2.3 ms a step of cell 6)
+        slot, count = jax.lax.rem(blk, 3), counts_ref[4 * blk]
+        if span > 1:
+            count = count + span * counts_ref[4 * blk + 1]
 
         def wait_for(tiles):
             def wait(j, _):
@@ -1481,7 +1507,7 @@ def _tile_set_kernel(tiles_ref, words_ref, counts_ref, vals_ref, table_ref,
         def _free_slot():
             await_copies(blk - 3, writes)
 
-        start_reads(blk)
+        start_copies(blk, reads)
         return 0
 
     jax.lax.fori_loop(
@@ -1490,9 +1516,10 @@ def _tile_set_kernel(tiles_ref, words_ref, counts_ref, vals_ref, table_ref,
     )
     await_copies(b, reads)
     slot = jax.lax.rem(b, 3)
+    shape = tile_buf.shape[2:]  # (L, 128)
     sublane = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
     column = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
-    first = counts_ref[3 * b + 1]
+    first = counts_ref[4 * b + 2]
 
     def set_row(k):
         lane = first + k
@@ -1507,14 +1534,8 @@ def _tile_set_kernel(tiles_ref, words_ref, counts_ref, vals_ref, table_ref,
         tile_buf[at] = jax.lax.select(
             column == (word >> 16), new, tile_buf[at])
 
-    _each(counts_ref[3 * b + 2], set_row)
-
-    def write(j):
-        pltpu.make_async_copy(
-            tile_buf.at[slot, j], tile(b, j), sem.at[writes, slot]
-        ).start()
-
-    _each(counts_ref[3 * b], write)
+    _each(counts_ref[4 * b + 3], set_row)
+    start_copies(b, writes)
 
     @pl.when(b == blocks - 1)
     def _drain():  # the last three blocks' writes
@@ -1524,42 +1545,146 @@ def _tile_set_kernel(tiles_ref, words_ref, counts_ref, vals_ref, table_ref,
         )
 
 
-def _tile_set_plan(sorted_ids: Array, rows: int, block: int):
-    """The set kernel's scalars from the sorted DISTINCT ids:
-    ``(tiles, words, counts, opened)``, ``opened`` the tiles touched."""
-    ids = sorted_ids.reshape(-1, block)
+def _span_places(block: int, span: int) -> int:
+    """The tiles a block of lanes may hold in VMEM at once: every lane a
+    tile of its own, or, dearer, every two lanes a whole span (a span is
+    touched in two tiles or more; the last copy a block opens may own one
+    lane of it alone), and whole sixteens (a wait's size)."""
+    return block if span == 1 else span * (block // 2) + 16
+
+
+class TileSetCounts(NamedTuple):
+    """What :func:`sorted_tile_set` counted on the device, int32 scalars
+    summed over its calls: the tiles the ids ``touched``, the ``copies`` it
+    made of them each way (a DMA descriptor in and one out for each) and
+    the tiles those copies ``moved`` each way, the untouched tiles inside a
+    span among them.  With one-tile copies the three are one number."""
+
+    touched: Array
+    copies: Array
+    moved: Array
+
+
+def _running_max(x: Array, reverse: bool = False) -> Array:
+    """The running maximum of ``x`` (blocks, block) in the FLAT order of its
+    lanes, block after block (``reverse``: from the last lane back).  A scan
+    along each block and the blocks before it by a triangle of their maxima:
+    on the v5e a scan of 32,768 lanes in one row is 8.5 us and this is under
+    two, and a plan has three."""
+    local = jax.lax.cummax(x, axis=1, reverse=reverse)
+    blk = jnp.arange(x.shape[0])
+    earlier = (blk[None, :] > blk[:, None] if reverse
+               else blk[None, :] < blk[:, None])
+    before = jnp.max(
+        jnp.where(earlier, local[None, :, 0 if reverse else -1],
+                  jnp.iinfo(x.dtype).min), axis=1)
+    return jnp.maximum(local, before[:, None])
+
+
+def _tile_set_plan(sorted_ids: Array, rows: int, block: int, span: int = 1):
+    """The set kernel's scalars from the sorted DISTINCT ids: ``(tiles,
+    words, counts, counted)``, ``counted`` a :class:`TileSetCounts`.
+
+    The table's tiles stand in aligned groups of ``span``.  A group that is
+    whole (the table's last few tiles may not be) and in which the ids touch
+    two tiles or more is copied as ONE span, the tiles
+    between them read and written back as they are; every other touched
+    tile is copied alone.  A copy belongs to the block of lanes that holds
+    its first lane and owns every lane of its tiles, in whichever block they
+    lie.  Scans, sorts and selects of the call's lanes only (a gather of
+    scalars is 75 ns a lane on the TPU)."""
+    n = sorted_ids.shape[0]
+    by_block = (n // block, block)
+    ids = sorted_ids.reshape(by_block)
     kept = ids < rows  # the dropped lanes sort to the end
     tile = ids // _TILE_ROWS
-    # a kept lane opens a tile unless the lane before it, in its block or
-    # the one before, lies in the same (ids ascend)
-    flat = tile.reshape(-1)
-    before = jnp.concatenate([jnp.full((1,), -1, flat.dtype), flat[:-1]])
-    opens = kept & (tile != before.reshape(tile.shape))
-    opened = jnp.sum(opens, axis=1, dtype=jnp.int32)
-    kept_n = jnp.sum(kept, axis=1, dtype=jnp.int32)
-    # the lanes at a block's head that lie in the last tile of the block
-    # before belong to that block: ids are distinct, so a tile holds at most
-    # 128 lanes and a block of more owns its own or its neighbour's
-    spill = jnp.where(
-        opened > 0, jnp.argmax(opens, axis=1).astype(jnp.int32), kept_n
-    )
-    nxt = jnp.concatenate([spill[1:], jnp.zeros((1,), jnp.int32)])
-    first = jnp.arange(ids.shape[0], dtype=jnp.int32) * block + spill
-    seen = jnp.cumsum(opens, axis=1, dtype=jnp.int32)
-    last_of_prev = jnp.concatenate([jnp.zeros((1,), jnp.int32), opened[:-1]])
-    place = jnp.maximum(
-        jnp.where(seen > 0, seen, last_of_prev[:, None]) - 1, 0
-    )
+    local = jax.lax.broadcasted_iota(jnp.int32, by_block, 1)
+    blk = jax.lax.broadcasted_iota(jnp.int32, by_block, 0)
+
+    def shifted(x, fill, by=1):  # the lane before's (after's: by = -1)
+        pad, flat = jnp.full((1,), fill, x.dtype), x.reshape(-1)
+        parts = [pad, flat[:-1]] if by > 0 else [flat[1:], pad]
+        return jnp.concatenate(parts).reshape(by_block)
+
+    new_tile = kept & (tile != shifted(tile, -1))  # (ids ascend)
+    touched = jnp.sum(new_tile, dtype=jnp.int32)
+    # a copy's place in its block's buffer, the spans first and then the
+    # single tiles, is carried from its first lane to the lanes that follow
+    # it, over as many blocks as they run, as a running maximum
+    places = _span_places(block, span)
+    high = 1 << (places - 1).bit_length()
+    spanned = jnp.zeros(by_block, bool)
+    if span > 1:
+        group = tile // span
+        new_group = kept & (group != shifted(group, -1))
+        # the last tile its group's lanes touch, at every lane: the tile at
+        # the group's last lane, carried back (tiles ascend: a minimum)
+        ends = kept & ~(shifted(kept, False, -1) & ~shifted(new_group, True, -1))
+        last = -_running_max(-jnp.where(ends, tile, _INT32_MAX), reverse=True)
+        spanned = (new_group & (group < rows // (_TILE_ROWS * span))
+                   & (last != tile))
+        # every group's first lane says whether it is a span (bit 0) and
+        # where its tiles lie (above it), under the lane's own number, which
+        # makes the newest say the maximum (65,536 lanes x 2 x 8,192 places
+        # fill an int32)
+        base = span * (jnp.cumsum(spanned, axis=1, dtype=jnp.int32) - 1)
+        said = _running_max(jnp.where(
+            new_group, (blk * block + local) * (2 * high)
+            + jnp.where(spanned, 2 * base + 1, 0), -1))
+        wide = kept & (said & 1 == 1)
+        new_tile = new_tile & ~wide
+    spans = jnp.sum(spanned, axis=1, dtype=jnp.int32)
+    singles = jnp.sum(new_tile, axis=1, dtype=jnp.int32)
+    place = _running_max(jnp.where(
+        new_tile, blk * high + (span * spans)[:, None]
+        + jnp.cumsum(new_tile, axis=1, dtype=jnp.int32) - 1, -1)) & (high - 1)
+    if span > 1:
+        place = jnp.where(
+            wide, (said >> 1 & (high - 1)) + tile % span, place)
     words = place | ((ids % _TILE_ROWS) << 16)
-    # the opened tiles to the front of their block, as `_tile_plan` moves its
-    local = jax.lax.broadcasted_iota(jnp.int32, ids.shape, 1)
-    tiles = jax.lax.sort(
-        (jnp.where(opens, local, local + block), tile), dimension=1,
-        num_keys=1,
-    )[1]
-    counts = jnp.stack([opened, first, kept_n - spill + nxt], axis=1)
+    # a block owns the lanes from the first copy it opens to the next
+    # block's that opens one (or the last kept lane)
+    opens = new_tile | spanned
+    head = jnp.where(
+        singles + spans > 0,
+        blk[:, 0] * block + jnp.argmax(opens, axis=1).astype(jnp.int32), n)
+    later = blk[:, :1] < jnp.arange(by_block[0])[None, :]
+    end = jnp.min(jnp.where(later, head[None, :], n), axis=1)
+    owned = jnp.where(
+        head < n, jnp.minimum(end, jnp.sum(kept, dtype=jnp.int32)) - head, 0)
+    # the opened copies to the front of their block, the spans first (as
+    # `_tile_plan` moves its tiles); a span is named by its first tile
+    order = jnp.where(
+        spanned, local, jnp.where(new_tile, local + block, local + 2 * block))
+    first_tile = jnp.where(spanned, tile // span * span, tile)
+    tiles = jax.lax.sort((order, first_tile), dimension=1, num_keys=1)[1]
+    counts = jnp.stack([singles, spans, jnp.minimum(head, n - 1), owned],
+                       axis=1)
     return (tiles.reshape(-1), words.reshape(-1), counts.reshape(-1),
-            jnp.sum(opened))
+            TileSetCounts(touched, jnp.sum(singles + spans),
+                          jnp.sum(singles + span * spans)))
+
+
+# the widest span: 32 tiles a copy are 64 KB of a 4-lane table each way
+_SPAN_MOST_TILES = 32
+
+
+def set_span(tiles: int, lanes: int) -> int:
+    """How many side-by-side tiles one copy of :func:`sorted_tile_set` may
+    take, from what a trace can see: the table's ``tiles`` and the ``lanes``
+    of the push whose distinct rows are being written.  Eight times the
+    lanes to a tile, to the nearest power of two, at most
+    ``_SPAN_MOST_TILES``: where the touched tiles lie close a wide copy
+    saves descriptors and moves little it need not (cell 17, four lanes a
+    tile: 32), where they lie apart it bridges tiles for nothing (cell 6,
+    0.87 lanes a tile: 8), and under an eighth of a lane a tile a span is
+    the tile itself, the plan and kernel of one-tile copies."""
+    if lanes <= 0:
+        return 1
+    span = 1 << max(0, round(math.log2(8 * lanes / tiles)))
+    while span > min(tiles, _SPAN_MOST_TILES):  # (a table of a few tiles)
+        span //= 2
+    return span
 
 
 def sorted_tile_set(
@@ -1567,21 +1692,27 @@ def sorted_tile_set(
     sorted_ids: Array,
     new: Array,
     *,
+    of: Optional[int] = None,
     interpret: Optional[bool] = None,
-) -> Tuple[Array, Array]:
+) -> Tuple[Array, TileSetCounts]:
     """``table[r, :w] = new[k]`` (and zeros on the row's other lanes) for
     every row ``r`` a kept lane ``k`` names, a read-modify-write of each
-    touched tile of 128 rows; every other tile is left as it is.  Returns
-    the table and how many tiles were moved.
+    touched tile of 128 rows; every other tile is left as it is, or read
+    and written back as it is (inside a span: :func:`_tile_set_plan`).
+    Returns the table and what the calls counted (:class:`TileSetCounts`).
 
     ``table``: (rows, L) float32 with L one of ``SET_ROW_LANES`` and whole
     tiles of 128 rows (:func:`set_refusal`): on the TPU such a table lies
     rows-minor, ``{0,1:T(L,128)}``, which is byte for byte the row-major
-    ``(L, rows)`` the kernel addresses, 128 consecutive rows to a
-    contiguous tile, so both transposes here are bitcasts.
+    ``(rows / 128, L, 128)`` the kernel addresses, 128 consecutive rows to
+    a contiguous tile and the tiles side by side, so the reshapes and
+    transposes here are bitcasts.
     ``sorted_ids``: (n,) int32 ASCENDING and DISTINCT, lanes to drop at the
     end with an id >= the row count.  ``new``: (n, w) in that order, ``w <=
-    L``; a dropped lane's may be anything.  In place when the enclosing jit
+    L``; a dropped lane's may be anything.  ``of``: the lanes of the whole
+    push these ids are a chunk of (``n`` where it is not said), which with
+    the table's size says how close the touched tiles lie
+    (:func:`set_span`).  In place when the enclosing jit
     donates the table; an eager call copies it first.  Off the TPU the
     kernel is interpreted.
     """
@@ -1597,9 +1728,11 @@ def sorted_tile_set(
     rows, lanes_row = table.shape
     n, width = new.shape
     block = BLOCK
-    opened = jnp.zeros((), jnp.int32)
+    zero = jnp.zeros((), jnp.int32)
+    counted = TileSetCounts(zero, zero, zero)
     if n == 0:
-        return table, opened
+        return table, counted
+    span = set_span(rows // _TILE_ROWS, n if of is None else of)
     # as few calls as hold their scalars in SMEM, of equal size in blocks
     most = _SET_SMEM_WORDS // (2 + width) // block * block
     calls = -(-n // most)
@@ -1612,39 +1745,44 @@ def sorted_tile_set(
     if not isinstance(table, jax.core.Tracer):
         table = jnp.copy(table)
 
+    tile = (lanes_row, _TILE_ROWS)
+    held = (3, _span_places(block, span)) + tile
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(size // block,),
         in_specs=[pl.BlockSpec(memory_space=pl.ANY)],  # the table stays in HBM
         out_specs=pl.BlockSpec(memory_space=pl.ANY),
         scratch_shapes=[
-            pltpu.VMEM((3, block, lanes_row, _TILE_ROWS), jnp.float32),
+            pltpu.VMEM(held, jnp.float32),
             pltpu.SemaphoreType.DMA((2, 3)),
         ],
     )
     call = pl.pallas_call(
         functools.partial(
-            _tile_set_kernel, block=block, width=width, lanes=size
+            _tile_set_kernel, block=block, width=width, lanes=size, span=span
         ),
-        out_shape=jax.ShapeDtypeStruct((lanes_row, rows), table.dtype),
+        out_shape=jax.ShapeDtypeStruct(
+            (rows // _TILE_ROWS,) + tile, table.dtype),
         grid_spec=grid_spec,
         input_output_aliases={4: 0},  # (tiles, words, counts, vals, table)
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_TILE_VMEM_BYTES,
         ),
         interpret=interpret,
         name="sorted_row_set_tiles",
     )
-    view = table.T
+    # (tiles, L, 128): the tiles of the transposed table, one after another
+    view = table.reshape(-1, _TILE_ROWS, lanes_row).transpose(0, 2, 1)
     for lo in range(0, calls * size, size):
         tiles, words, counts, moved = _tile_set_plan(
-            sorted_ids[lo:lo + size], rows, block
+            sorted_ids[lo:lo + size], rows, block, span
         )
         view = call(
             tiles, words, counts, new[lo:lo + size].T.reshape(-1), view
         )
-        opened = opened + moved
-    return view.T, opened
+        counted = jax.tree.map(jnp.add, counted, moved)
+    return view.transpose(0, 2, 1).reshape(rows, lanes_row), counted
 
 
 def scatter_add_counted(

@@ -921,23 +921,163 @@ def test_sorted_tile_set_is_xlas_row_set_bit_for_bit(
             pl, "pallas_call",
             lambda *a, **kw: calls.append(kw["grid_spec"].grid) or real(*a, **kw),
         )
-    got, tiles = row_update.sorted_tile_set(
+    # (a span of one tile: the one-tile copies of PR 35)
+    monkeypatch.setattr(row_update, "set_span", lambda tiles, lanes: 1)
+    got, counted = row_update.sorted_tile_set(
         jnp.asarray(table), jnp.asarray(ids), jnp.asarray(new)
     )
     want = jnp.asarray(table).at[ids].set(
         jnp.pad(jnp.asarray(new), ((0, 0), (0, lanes - width))), mode="drop"
     )
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    tiles = int(counted.touched)
+    assert tiles == int(counted.copies) == int(counted.moved)
     if name == "several_calls":
         # 1,536 lanes: one kernel of two blocks, called on three stretches
         assert calls == [(2,)], calls
         size = 512
-        assert int(tiles) == sum(
+        assert tiles == sum(
             len(np.unique(kept[lo:lo + size] // 128))
             for lo in range(0, len(kept), size)
         )
     else:
-        assert int(tiles) == len(np.unique(kept // 128))
+        assert tiles == len(np.unique(kept // 128))
+
+
+# -- spans: side-by-side tiles in one copy (PR 72) ------------------------------
+SPAN_TILES = 37  # two whole groups of 16 and five tiles over: ragged at 4, 8, 16
+SPAN_CASES = [
+    "every_tile_touched", "every_row", "alternating_tiles", "one_tile",
+    "one_row", "the_last_ragged_tiles", "both_ends_of_a_span",
+    "padded_lanes", "a_span_many_blocks_share", "sparse",
+    "a_tile_apart_from_a_touched_group", "all_dropped", "several_calls",
+]
+
+
+def _span_case(name, span, rng):
+    """The kept ids of one named case (ascending, distinct) and how many
+    dropped lanes follow them."""
+    rows, group = SPAN_TILES * 128, span * 128
+    if name == "every_tile_touched":  # two rows a tile
+        return np.sort(np.concatenate(
+            [np.arange(SPAN_TILES) * 128 + r for r in (3, 77)])), 2
+    if name == "every_row":  # a group's lanes over many blocks of 256
+        return np.arange(rows), 0
+    if name == "alternating_tiles":  # a span bridges the tiles between
+        return np.sort(np.concatenate(
+            [np.arange(0, SPAN_TILES, 2) * 128 + r for r in (0, 64, 127)])), 5
+    if name == "one_tile":
+        return 128 * 9 + np.array([0, 1, 17, 126, 127]), 3
+    if name == "one_row":
+        return np.array([128 * 20 + 5]), 0
+    if name == "the_last_ragged_tiles":
+        # the last whole group and the tiles after it, which no span holds
+        whole = SPAN_TILES // span * span
+        return np.sort(rng.choice(
+            np.arange((whole - span) * 128, rows), 500, replace=False)), 12
+    if name == "both_ends_of_a_span":  # a group's first row and its last
+        return np.sort(np.concatenate(
+            [[g * group, (g + 1) * group - 1]
+             for g in range(SPAN_TILES // span)])), 1
+    if name == "padded_lanes":  # a few kept lanes in front of many dropped
+        return np.sort(rng.choice(rows, 90, replace=False)), 600
+    if name == "a_span_many_blocks_share":
+        return np.arange(100, 100 + group + group // 2), 36
+    if name == "sparse":
+        return np.sort(rng.choice(rows, 40, replace=False)), 7
+    if name == "a_tile_apart_from_a_touched_group":
+        # one touched tile in the first group, two in the second
+        return np.array([5, group + 1, group + 128 * (span - 1) + 9]), 0
+    if name == "all_dropped":
+        return np.zeros((0,), np.int64), 300
+    if name == "several_calls":  # and a group that two calls share
+        return np.arange(100, 100 + 1500), 36
+    raise KeyError(name)
+
+
+def _copies_of(kept, span, tiles):
+    """What the plan must count for one call's kept ids: the touched tiles,
+    the copies (a whole group the ids touch in two tiles or more is one, any
+    other touched tile one) and the tiles those move."""
+    touched = np.unique(kept // 128)
+    groups, counts = np.unique(touched // span, return_counts=True)
+    spanned = (counts >= 2) & (groups < tiles // span)
+    copies = int(spanned.sum() + counts[~spanned].sum())
+    return len(touched), copies, int(span * spanned.sum() + counts[~spanned].sum())
+
+
+@pytest.mark.parametrize("lanes,width,span,name", [
+    (4, 3, span, name) for span in (4, 8, 16) for name in SPAN_CASES
+] + [
+    (lanes, width, 8, name)
+    for lanes, width in [(1, 1), (2, 2), (8, 5), (8, 8)]
+    for name in ("every_row", "alternating_tiles")
+] + [(4, 3, 32, "every_row"), (4, 3, 2, "alternating_tiles")])
+def test_sorted_tile_set_with_spans_is_the_one_tile_plan_bit_for_bit(
+        lanes, width, span, name, monkeypatch):
+    """Aligned groups of ``span`` tiles copied whole where the ids touch two
+    of their tiles or more: every bit of the table is the one-tile plan's
+    and ``table.at[ids].set(new)``'s, the untouched tiles inside a span come
+    back as they were, and the counts are what the plan issued."""
+    rng = np.random.default_rng([lanes, width, span, SPAN_CASES.index(name)])
+    rows = SPAN_TILES * 128
+    kept, dropped = _span_case(name, span, rng)
+    ids = np.concatenate([kept, np.full(dropped, rows)]).astype(np.int32)
+    table = rng.normal(size=(rows, lanes)).astype(np.float32)
+    table[:, width:] = 0
+    table[7, 0], table[rows - 1, 0] = np.nan, -0.0  # carried as they are
+    new = rng.normal(size=(len(ids), width)).astype(np.float32)
+    new[len(kept):] = np.nan  # a dropped lane's values are never read
+    size = len(ids)
+    if name == "several_calls":  # 512 lanes' scalars a call
+        monkeypatch.setattr(row_update, "_SET_SMEM_WORDS", 512 * (2 + width))
+        size = 512
+    args = jnp.asarray(table), jnp.asarray(ids), jnp.asarray(new)
+    monkeypatch.setattr(row_update, "set_span", lambda tiles, lanes: span)
+    got, counted = row_update.sorted_tile_set(*args)
+    monkeypatch.setattr(row_update, "set_span", lambda tiles, lanes: 1)
+    one_tile, _ = row_update.sorted_tile_set(*args)
+    want = args[0].at[ids].set(
+        jnp.pad(args[2], ((0, 0), (0, lanes - width))), mode="drop")
+    assert np.asarray(got).tobytes() == np.asarray(one_tile).tobytes()
+    assert np.asarray(got).tobytes() == np.asarray(want).tobytes()
+    by_call = [_copies_of(kept[lo:lo + size], span, SPAN_TILES)
+               for lo in range(0, max(len(kept), 1), size)]
+    assert tuple(int(c) for c in counted) == tuple(
+        int(x) for x in np.sum(by_call, axis=0))
+    if name in ("every_tile_touched", "every_row"):
+        # one descriptor each way for a whole group, the ragged tiles alone
+        whole = SPAN_TILES // span
+        assert int(counted.copies) == whole + SPAN_TILES - whole * span
+    if name == "alternating_tiles":  # the tiles between are moved too
+        # (at a span of two no group holds two even tiles)
+        assert (int(counted.moved) > int(counted.touched)) == (span > 2)
+
+
+@pytest.mark.parametrize("tiles,lanes,want", [
+    (212_992, 851_968, 32),    # cell 17: four lanes a tile
+    (1_466_933, 1_277_952, 8),  # cell 6: 0.87
+    (1_466_933, 32_768, 1),    # a short push into a long table
+    (8, 100_000, 8),           # no wider than the table
+    (1000, 125, 1), (1000, 180, 2), (1000, 0, 1),
+])
+def test_the_span_is_read_from_the_tables_tiles_and_the_pushs_lanes(
+        tiles, lanes, want):
+    assert row_update.set_span(tiles, lanes) == want
+
+
+def test_a_call_says_how_many_lanes_its_push_has(monkeypatch):
+    """``of``: a chunk of a push is judged by the push's lanes, a bare call
+    by its own."""
+    seen = []
+    monkeypatch.setattr(
+        row_update, "set_span",
+        lambda tiles, lanes: seen.append((tiles, lanes)) or 1)
+    table = jnp.zeros((1024, 4), jnp.float32)
+    ids, new = jnp.arange(40, dtype=jnp.int32), jnp.ones((40, 3))
+    row_update.sorted_tile_set(table, ids, new)
+    row_update.sorted_tile_set(table, ids, new, of=5000)
+    assert seen == [(8, 40), (8, 5000)]
 
 
 ROW_SET_CASES = [
@@ -1018,7 +1158,7 @@ def test_eager_tile_set_leaves_the_callers_table_alone():
     table = jnp.ones((256, 4), jnp.float32)
     out, _ = row_update.sorted_tile_set(
         table, jnp.array([3, 200], jnp.int32), jnp.full((2, 3), 7.0)
-    )
+    )  # (two tiles of one group: a span)
     assert float(table.sum()) == 1024.0
     assert np.asarray(out)[3].tolist() == [7.0, 7.0, 7.0, 0.0]
 

@@ -1408,6 +1408,65 @@ def test_the_set_kernel_arm_is_the_xla_arm_bit_for_bit(
         for lo in range(0, len(kept), chunk)
     )
     assert int(kernel_counted["ps_rule_tiles"]) > 0
+    # a descriptor in and one out a copy, of a touched tile or of a span
+    # that holds several (the next test counts them)
+    assert 2 <= int(kernel_counted["ps_rule_descriptors"]) <= 2 * int(
+        kernel_counted["ps_rule_tiles"])
+    assert int(kernel_counted["ps_rule_span_tiles"]) >= int(
+        kernel_counted["ps_rule_tiles"])
+    assert "ps_rule_descriptors" not in counted  # XLA's set issues none
+
+
+@pytest.mark.parametrize("span", [1, 4, 16])
+def test_the_step_hands_out_the_descriptors_its_write_back_issued(
+        span, monkeypatch, steer_arms):
+    """``ps_rule_descriptors`` / ``ps_rule_span_tiles`` (gauges
+    ``store_rule_descriptors`` / ``store_rule_span_tiles``): what the set
+    kernel's plan issued, a descriptor in and one out for every copy, and
+    the tiles those copies moved, counted here from the ids alone.  The
+    span is judged by the PUSH's lanes, chunk by chunk of its distinct
+    rows; the table is XLA's arm's bit for bit at every span."""
+    from flink_parameter_server_tpu.core import store as store_mod
+    from flink_parameter_server_tpu.ops import row_update
+    from flink_parameter_server_tpu.telemetry.registry import MetricsRegistry
+
+    rng = np.random.default_rng(span)
+    capacity, lanes = 128 * 50, 3000
+    store = ShardedParamStore.from_values(
+        jnp.asarray(rng.normal(size=(capacity, 3)).astype(np.float32)),
+        update=_ema)
+    # half the table dense, the other half a row here and there
+    ids = np.concatenate([
+        rng.integers(0, capacity // 2, lanes - 40),
+        rng.integers(capacity // 2, capacity, 40)]).astype(np.int32)
+    deltas = rng.normal(size=(lanes, 3)).astype(np.float32)
+    want, _ = store_mod.push_counted(
+        store.spec, store.table, jnp.asarray(ids), jnp.asarray(deltas))
+    seen = []
+    monkeypatch.setattr(
+        row_update, "set_span",
+        lambda tiles, lanes: seen.append((tiles, lanes)) or span)
+    steer_arms(write_back="tile_set")
+    got, counted = jax.jit(
+        lambda t, i, d: store_mod.push_counted(store.spec, t, i, d)
+    )(store.table, jnp.asarray(ids), jnp.asarray(deltas))
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert seen == [(50, lanes)]
+    touched = np.unique(np.unique(ids) // 128)
+    groups, in_group = np.unique(touched // span, return_counts=True)
+    spanned = (in_group >= 2) & (groups < 50 // span)
+    copies = int(spanned.sum() + in_group[~spanned].sum())
+    assert int(counted["ps_rule_tiles"]) == len(touched)
+    assert int(counted["ps_rule_descriptors"]) == 2 * copies
+    assert int(counted["ps_rule_span_tiles"]) == int(
+        span * spanned.sum() + in_group[~spanned].sum())
+    if span > 1:
+        assert copies < len(touched)  # what the chip is spared
+    registry = MetricsRegistry()
+    store_mod.publish_counts(counted, registry, int, int)
+    gauges = {i.name: i.value for i in registry.instruments()}
+    assert gauges["store_rule_descriptors"] == 2 * copies
+    assert gauges["store_rule_span_tiles"] == int(counted["ps_rule_span_tiles"])
 
 
 @pytest.mark.parametrize("width", [1, 3, 4, 5, 8, 9, 17, 36])

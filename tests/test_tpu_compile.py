@@ -1541,6 +1541,7 @@ def test_w2v_table_is_initialised_in_place_from_a_seed_argument(
 # f32[187767424,4]{0,1:T(4,128)}, 3.00 GB
 LR_ROWS = 187_767_412
 LR_TABLE = (187_767_424, 4)
+LR_ROWS_HELD = LR_TABLE[0]
 
 
 @pytest.fixture(scope="module")
@@ -1593,7 +1594,8 @@ def test_lr_step_holds_nothing_table_sized_beside_its_table(lr_tpu_step):
     assert 2.9 * GB < mem.alias_size_in_bytes < 3.1 * GB  # in place, 4 sublanes
     assert mem.temp_size_in_bytes < 0.2 * GB
     rows, lanes = LR_TABLE
-    table = rf"f32\[({rows},{lanes}|{lanes},{rows})\]"
+    # (the kernel's view of it since PR 72: its tiles one after another)
+    table = rf"f32\[({rows},{lanes}|{rows // 128},{lanes},128)\]"
     yields = [
         line.strip() for line in text.splitlines()
         if re.search(rf" = {table}\S* (?!parameter|get-tuple-element)", line)
@@ -1601,6 +1603,9 @@ def test_lr_step_holds_nothing_table_sized_beside_its_table(lr_tpu_step):
     kernels = [y for y in yields if " custom-call(" in y]
     assert len(kernels) == 1 and kernels[0].startswith("%sorted_row_set_tiles")
     assert "ps.push/while/body" in kernels[0]
+    # (PR 72) the spans' buffers are the kernel's own VMEM: three of 8 x 128
+    # + 16 tiles of 2 KB, a span of eight tiles at 0.87 lanes a tile
+    assert f'"size":"{3 * (8 * 128 + 16) * 2048}"' in kernels[0]
     # what else yields a table only names it anew
     assert all(" bitcast(" in y for y in yields if y not in kernels), yields
     assert not re.search(table + r"\S* (copy|transpose|scatter)\(", text)
@@ -1719,19 +1724,30 @@ def test_placing_cell_6_s_values_pads_rows_and_lanes_in_one_pass(
     assert mem.temp_size_in_bytes < 0.01 * GB
 
 
-@pytest.mark.parametrize("lanes,width", [
-    (1, 1), (2, 2), (4, 3), (4, 4), (8, 5), (8, 8),
+@pytest.mark.parametrize("rows,lanes,width,pushed,span", [
+    (LR_ROWS_HELD, 1, 1, None, 1), (LR_ROWS_HELD, 2, 2, None, 1),
+    (LR_ROWS_HELD, 4, 3, None, 1), (LR_ROWS_HELD, 4, 4, None, 1),
+    (LR_ROWS_HELD, 8, 5, None, 1), (LR_ROWS_HELD, 8, 8, None, 1),
+    # as the cells' pushes call it (PR 72): cell 6's 1,277,952 lanes on
+    # 1,466,933 tiles, cell 17's 851,968 on 212,992; the widest span at the
+    # widest row
+    (LR_ROWS_HELD, 4, 3, 1_277_952, 8), (27_262_976, 4, 3, 851_968, 32),
+    (27_262_976, 8, 8, 851_968, 32),
 ])
 def test_set_kernel_compiles_at_cell_6_s_size_for_every_row_it_takes(
-        one_chip, no_compile_cache, lanes, width):
-    """A chunk of 32,768 sorted ids into 187,767,424 rows of 1 to 8 lanes:
-    the table ``{0,1:T(L,128)}`` is the kernel's ``(L, rows)`` by bitcasts,
-    aliased through its calls (two where a lane's scalars, 2 + 8 words, are
-    too many for one call's SMEM), no temporary worth the name."""
-    rows = LR_TABLE[0]
+        one_chip, no_compile_cache, rows, lanes, width, pushed, span):
+    """A chunk of 32,768 sorted ids into 187,767,424 rows of 1 to 8 lanes
+    (cell 17's 27,262,976 of 4): the table ``{0,1:T(L,128)}`` is the
+    kernel's ``(rows / 128, L, 128)`` by bitcasts, aliased through its calls
+    (two where a lane's scalars, 2 + 8 words, are too many for one call's
+    SMEM), no temporary worth the name; a chunk alone is a short push into a
+    long table and copies tile by tile, a chunk of a cell's push copies
+    spans of the width ``set_span`` reads, whose three buffers (25 MB at 32
+    tiles of 4 lanes, 50 at 8) Mosaic takes."""
+    assert row_update.set_span(rows // 128, pushed or 32_768) == span
     compiled = jax.jit(
         lambda t, ids, new: row_update.sorted_tile_set(
-            t, ids, new, interpret=False),
+            t, ids, new, of=pushed, interpret=False),
         donate_argnums=(0,),
     ).lower(
         _shape(one_chip, (rows, lanes), jnp.float32),
@@ -1741,7 +1757,8 @@ def test_set_kernel_compiles_at_cell_6_s_size_for_every_row_it_takes(
     text = compiled.as_text()
     found = re.findall(r" custom-call\([^\n]*sorted_row_set_tiles", text)
     assert len(found) == (2 if width > 4 else 1), len(found)
-    assert not re.search(r"f32\[\d{9},\d\]\S* (copy|transpose)\(", text)
+    assert not re.search(
+        r"f32\[(\d{8,9},\d|\d{6,7},\d,128)\]\S* (copy|transpose)\(", text)
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= rows * lanes * 4
     assert mem.temp_size_in_bytes < 16 * 2 ** 20
@@ -1749,22 +1766,31 @@ def test_set_kernel_compiles_at_cell_6_s_size_for_every_row_it_takes(
 
 def test_mosaic_takes_no_tile_of_a_three_lane_table(
         one_chip, no_compile_cache, monkeypatch):
-    """Why the physical row is four lanes and not three: the chip lays
-    ``f32[rows,3]`` ``{0,1:T(4,128)}`` too, but Mosaic then sees four
-    sublanes of which the array has three, and refuses the slice of a
-    tile.  (ISSUE 35's probe; with this the kernel exists exactly where
-    the store's row is the chip's sublane tile.)"""
+    """Why the physical row is four lanes and not three.  The chip lays
+    ``f32[rows,3]`` ``{0,1:T(4,128)}`` too, four sublanes of which the array
+    has three, and until PR 72 Mosaic refused the slice of a tile of the
+    ``(3, rows)`` view ("aligned to tiling": ISSUE 35's probe).  The view
+    ``(rows / 128, 3, 128)`` has no such slice (a copy takes whole tiles by
+    their number) and compiles, in place, by bitcasts; but it saves nothing:
+    the three-lane table holds the four-lane table's bytes, so the store's
+    row stays the chip's sublane tile and ``set_refusal`` still names three
+    lanes."""
+    assert "(3,)" in row_update.set_refusal((LR_TABLE[0], 3), jnp.float32)
     monkeypatch.setattr(row_update, "SET_ROW_LANES", (1, 2, 3, 4, 8))
-    with pytest.raises(Exception, match="aligned to tiling"):
-        jax.jit(
-            lambda t, ids, new: row_update.sorted_tile_set(
-                t, ids, new, interpret=False),
-            donate_argnums=(0,),
-        ).lower(
-            _shape(one_chip, (LR_TABLE[0], 3), jnp.float32),
-            _shape(one_chip, (32_768,), jnp.int32),
-            _shape(one_chip, (32_768, 3), jnp.float32),
-        ).compile()
+    compiled = jax.jit(
+        lambda t, ids, new: row_update.sorted_tile_set(
+            t, ids, new, interpret=False),
+        donate_argnums=(0,),
+    ).lower(
+        _shape(one_chip, (LR_TABLE[0], 3), jnp.float32),
+        _shape(one_chip, (32_768,), jnp.int32),
+        _shape(one_chip, (32_768, 3), jnp.float32),
+    ).compile()
+    text = compiled.as_text()
+    assert "f32[187767424,3]{0,1:T(4,128)}" in text
+    assert not re.search(r"f32\[\d{7,9},[\d,]+\]\S* (copy|transpose)\(", text)
+    assert compiled.memory_analysis().alias_size_in_bytes == (
+        LR_TABLE[0] * 4 * 4)
 
 
 def test_the_capacity_arm_holds_tables_beside_cell_6_s_table(
@@ -3024,10 +3050,13 @@ def test_the_two_store_step_rewrites_both_tables_in_place(wdl_tpu_step):
     transposes or scatters either table."""
     text, mem = wdl_tpu_step
     assert 13.0 * GB < mem.alias_size_in_bytes < 13.05 * GB
-    assert mem.temp_size_in_bytes < 1.1 * GB
+    # PR 71's step held 0.934 GB; the set kernel's spans (PR 72) live in
+    # VMEM (three buffers of 4,112 tiles, 25 MB) and its plan's scans are a
+    # chunk's lanes: nothing more in HBM
+    assert mem.temp_size_in_bytes < 0.94 * GB
     for shape, kernel, where in (
             (r"24563152,128", "%sorted_row_set.", "ps.push/store.deep/while/body"),
-            (r"(27262976,4|4,27262976)", "%sorted_row_set_tiles.",
+            (r"(27262976,4|212992,4,128)", "%sorted_row_set_tiles.",
              "ps.push/store.wide/while/body")):
         table = rf"f32\[{shape}\]"
         yields = [

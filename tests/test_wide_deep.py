@@ -162,6 +162,16 @@ PARENT_JAX = "0.9.0"
 PARENT_TEXT = {
     "lr": "3373b1a5464e62bcf607f99f021f748943b73b306c6b3ecdf162b98c80a4a7de",
     "dcn": "7a042098f1a40890e201b67955b5688bdcff4177b88d2f18b4befde0790c0396",
+    # PR 72 (the set kernel copies spans; `_push_rule` carries its counts):
+    # the stores whose write-back is NOT `tile_set`, at PR 72's parent
+    # (90cbcf4): a packed rule store of three rows to a register (DiFacto),
+    # a flat wide one (GloVe), a one-register one (PBG's ComplEx) and a
+    # packed add store (FM); "lr" and "dcn" above did not move either (off
+    # the TPU a narrow rule store's write-back is XLA's row set)
+    "difacto": "c579045f24c4f8b1fa76549a721deb641669a84f3e0856e934bb403585e9d731",
+    "glove": "bc4031dbf2f9494a63b47637fec09fd106ce751ee14aca266d1151fcba93ad95",
+    "kge": "c40ebcf65f60c7dd85bfb16b21bc1b0fe1cd01afd62c76ed141162b0a36151f9",
+    "fm": "c071e25106d07d67f60c68c8e51cf28d85d42b90feceb97f2ece726de34116f8",
 }
 
 
@@ -172,11 +182,45 @@ def _lowered(logic, store, batch, **how):
     ).lower(store.table, state, batch).as_text(**how)
 
 
-@pytest.mark.parametrize("which", ["lr", "dcn"])
+def _other_store(which):
+    """A small model of a cell whose write-back is not the set kernel's."""
+    from flink_parameter_server_tpu.models import difacto as df
+    from flink_parameter_server_tpu.models import factorization_machine as fmm
+    from flink_parameter_server_tpu.models import glove as gl
+    from flink_parameter_server_tpu.models import kge
+
+    ids = np.zeros((32, 6), np.int32)
+    clicks = {
+        "ids": ids, "values": np.ones(ids.shape, np.float32),
+        "feat_mask": np.ones(ids.shape, bool),
+        "label": np.ones(32, np.float32), "mask": np.ones(32, bool)}
+    if which == "difacto":
+        cfg = df.DiFactoConfig(200, 16)
+        return df.DiFacto(cfg), df.make_store(cfg), clicks
+    if which == "glove":
+        model = gl.GloVeConfig(96, 150)
+        return gl.GloVe(model), gl.make_store(model, seed=3), {
+            "word": np.zeros(64, np.int32), "context": np.zeros(64, np.int32),
+            "count": np.ones(64, np.float32), "mask": np.ones(64, bool)}
+    if which == "kge":
+        graph = kge.KGEConfig(600, 24, 100)
+        return kge.ComplExNegatives(graph), kge.make_store(graph, seed=3), {
+            k: np.zeros((4, n), np.int32) for k, n in (
+                ("source", 10), ("destination", 10), ("relation", 10),
+                ("source_negatives", 6), ("destination_negatives", 6))}
+    cfg = fmm.FMConfig(num_features=200, dim=16, learning_rate=0.05)
+    return fmm.FactorizationMachine(cfg), fmm.make_store(cfg), clicks
+
+
+@pytest.mark.parametrize(
+    "which", ["lr", "dcn", "difacto", "glove", "kge", "fm"])
 def test_a_single_store_s_lowered_step_is_the_parent_s_to_the_letter(which):
     if jax.__version__ != PARENT_JAX:
         pytest.skip(f"the pins are jax {PARENT_JAX}'s text")
-    if which == "lr":
+    if which in ("difacto", "glove", "kge", "fm"):
+        text = _lowered(*_other_store(which))
+        assert "ps_rule_descriptors" not in text
+    elif which == "lr":
         text = _lowered(lf.LogisticFTRL(), lf.make_store(1000), {
             "ids": np.zeros((64, 5), np.int32),
             "values": np.ones((64, 5), np.float32),
