@@ -12,8 +12,8 @@ row-sharded over a named mesh axis (``"ps"``).  The reference's message-level
 protocol maps onto array ops *inside* a jitted step:
 
   * ``pull(ids)``  → sharded gather (``jnp.take``); XLA lowers the
-    cross-shard reads to ICI collectives (or we do it explicitly with
-    ``shard_map`` — see :mod:`..parallel.collectives`).
+    cross-shard reads to ICI collectives (under one worker group each shard
+    answers from its own block in a ``shard_map``: :func:`_take_on_shards`).
   * ``push(ids, deltas)`` → sharded scatter-add (``table.at[ids].add``).
 
 "Lazy init on first pull" in the reference uses a *deterministic per-id*

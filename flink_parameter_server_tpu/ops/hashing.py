@@ -93,8 +93,8 @@ def pair_key(x: Array, y: Array, num_keys: int) -> Array:
 def permute_ids(ids: Array, capacity: int, seed: int = 0x5BD1) -> Array:
     """Bijective spreading of ids across [0, capacity): defeats
     block-sharding hotspots for Zipf-skewed ids (the rebuild's answer to
-    the reference's mod-hash routing under skew — see
-    parallel/collectives.py docstring).
+    the reference's mod-hash routing under skew; shard ``s`` owns rows
+    ``[s*R, (s+1)*R)``: core/store.py's pull and push on the shards).
 
     ``capacity`` must be a power of two (the padded table capacity
     usually is): an odd-multiplier affine map mod 2^k is a permutation,

@@ -11,6 +11,8 @@ this file runs, and jax captures ``JAX_PLATFORMS`` when it is imported.
 Set ``FPS_TPU_TESTS=1`` to run the suite on the backend jax finds instead.
 """
 import os
+import shutil
+import tempfile
 
 flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in flags:
@@ -19,13 +21,58 @@ if "xla_force_host_platform_device_count" not in flags:
     ).strip()
 os.environ.setdefault("JAX_ENABLE_X64", "0")
 
+# One compile a program a RUN.  The tests call the store eagerly, so every
+# primitive at every new shape is an XLA program of its own (~60 ms), and
+# under ``--dist load`` each xdist worker met the same ones and compiled them
+# again.  The first process of a run (the xdist controller, which imports
+# this file before it starts its workers; or the one process of a run
+# without xdist) makes an EMPTY directory and names it in the environment,
+# where its workers and every subprocess a test starts find it: a process
+# that finds one named uses it and removes nothing.  Whoever made it removes
+# it when the session ends: nothing outlives the run, so a second run is no
+# faster than the first and no executable is loaded on another machine than
+# compiled it.  ``tests/test_tpu_compile.py`` switches the cache off round
+# its compiles (a described chip's executable cannot be read back).
+_RUN_CACHE = None
+if "JAX_COMPILATION_CACHE_DIR" not in os.environ:
+    _RUN_CACHE = tempfile.mkdtemp(prefix="fps-tests-jax-cache-")
+    os.environ["JAX_COMPILATION_CACHE_DIR"] = _RUN_CACHE
+# (the defaults keep nothing that compiled in under a second: every entry here)
+os.environ["JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"] = "0"
+os.environ["JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES"] = "-1"
+
 import jax  # noqa: E402
 
 if os.environ.get("FPS_TPU_TESTS") != "1":
     jax.config.update("jax_platforms", "cpu")
+# (as above: jax may have read the environment before this file ran)
+jax.config.update(
+    "jax_compilation_cache_dir", os.environ["JAX_COMPILATION_CACHE_DIR"])
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
 
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402
+
+
+def pytest_unconfigure(config):
+    """The run's compile cache goes with the run (its maker removes it)."""
+    if _RUN_CACHE is not None:
+        shutil.rmtree(_RUN_CACHE, ignore_errors=True)
+
+
+@pytest.fixture()
+def no_compile_cache():
+    """For a test that must see the backend compile: no executable is read
+    from (or written to) the run's cache until the test ends."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
 
 
 @pytest.fixture(scope="session")

@@ -29,8 +29,6 @@ MODULE_SYMBOLS = {
         "StoreSpec", "ShardedParamStore", "pull", "push", "push_counted",
         "Arms", "arms", "step_counts", "publish_counts"],
     "flink_parameter_server_tpu.core.senders": ["SenderPolicy"],
-    "flink_parameter_server_tpu.parallel.collectives": [
-        "shard_pull", "shard_push_add"],
     "flink_parameter_server_tpu.parallel.ring_attention": [
         "ring_attention", "reference_attention"],
     "flink_parameter_server_tpu.parallel.pipeline": [

@@ -14,10 +14,6 @@ import pytest
 from flink_parameter_server_tpu.core.batched import PushRequest
 from flink_parameter_server_tpu.core.store import ShardedParamStore
 from flink_parameter_server_tpu.core.transform import make_train_step
-from flink_parameter_server_tpu.parallel.collectives import (
-    shard_pull,
-    shard_push_add,
-)
 from flink_parameter_server_tpu.utils.initializers import (
     ranged_random_factor,
     zeros,
@@ -132,43 +128,6 @@ def test_model_load_under_a_trace_pads_abstractly(mesh):
         lambda: ShardedParamStore.from_values(jnp.ones((12, 2)), mesh=mesh)
     )
     assert traced.table.shape == (32, 2)
-
-
-class TestExplicitCollectives:
-    """shard_map pull/push — the explicit ICI message plane."""
-
-    def test_shard_pull_matches_take(self, mesh):
-        table = jnp.arange(64 * 4, dtype=jnp.float32).reshape(64, 4)
-        store = ShardedParamStore.from_values(table, mesh=mesh)
-        # ids: leading dim sharded over dp (2 workers x 3 ids each)
-        ids = jnp.array([[0, 17, 63], [5, 5, 32]], dtype=jnp.int32)
-        got = shard_pull(store.table, ids, mesh=mesh)
-        want = jnp.take(table, ids.reshape(-1), axis=0).reshape(2, 3, 4)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want))
-
-    def test_shard_push_matches_scatter_add(self, mesh):
-        table = jnp.zeros((64, 4), jnp.float32)
-        store = ShardedParamStore.from_values(table, mesh=mesh)
-        ids = jnp.array([[1, 1, 40], [40, 2, 63]], dtype=jnp.int32)
-        deltas = jnp.ones((2, 3, 4), jnp.float32)
-        mask = jnp.array([[True, True, True], [True, True, False]])
-        got = shard_push_add(store.table, ids, deltas, mask, mesh=mesh)
-        want = np.zeros((64, 4))
-        for i, m in zip(np.asarray(ids).reshape(-1), np.asarray(mask).reshape(-1)):
-            if m:
-                want[i] += 1.0
-        np.testing.assert_allclose(np.asarray(got), want)
-
-    def test_pull_under_jit(self, mesh):
-        table = jnp.arange(64.0).reshape(64, 1)
-        store = ShardedParamStore.from_values(table, mesh=mesh)
-        ids = jnp.array([[3, 9], [60, 0]], dtype=jnp.int32)
-
-        f = jax.jit(lambda t, i: shard_pull(t, i, mesh=mesh))
-        got = f(store.table, ids)
-        np.testing.assert_allclose(
-            np.asarray(got).reshape(-1), [3.0, 9.0, 60.0, 0.0]
-        )
 
 
 def test_generic_update_fn_sharded(mesh):

@@ -3,11 +3,32 @@ real widths, with the TPU compiler installed here — no chip, nothing runs
 (the ``on-chip-measurement`` guide, section 2).  What Mosaic or the TPU
 compiler refuses costs a test failure instead of chip time.
 
-All such compiles live in THIS file: the worker that runs it loads the TPU
-library, inside a fixture, and keeps it until it exits."""
+The file's two rules, for the next PR's author:
+
+1. ALL compiles for a described chip live in THIS file: the worker that runs
+   it loads the TPU library, inside a fixture, and keeps it until it exits.
+2. A cell's FULL-SIZE step is lowered and compiled in a MODULE fixture, never
+   in a test body or in a helper a test calls: ``CellStep`` below, one a
+   (cell, arm), built under ``_compiling_for_described_chips`` (the run's
+   compile cache off: a described chip's executable cannot be read back).  A
+   test reads what the fixture hands out: the lowered text, the compiled
+   text, the memory analysis, the refusals noted, the outputs' shapes.  A new
+   reader of a cell's step is a new test that takes the cell's fixture, at
+   no new compile; keep it NEXT to the other readers of that fixture (under
+   the driver's ``--dist load`` a module fixture is built once a WORKER that
+   is handed one of its tests, and neighbours travel together).  A step is
+   compiled when a test first reads ``text`` or ``memory``, so a test that
+   reads the lowered text alone (``-k step_text``: every cell's hash) costs a
+   lowering.  The helpers that check a refusal or ONE kernel (1-17 s) compile
+   where they stand.
+
+``pytest tests/test_tpu_compile.py -k step_text`` is the per-PR proof that a
+change left every cell's traced program alone: run it on the parent's tree
+and on the change's."""
 import collections
 import contextlib
 import dataclasses
+import functools
 import re
 
 import jax
@@ -108,35 +129,22 @@ def _fm_batch(sharding):
     }
 
 
-@pytest.fixture()
-def no_compile_cache():
-    # a described device's executable cannot be read back from the cache
-    from jax.experimental.compilation_cache import compilation_cache
-
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    yield
-    jax.config.update("jax_enable_compilation_cache", was)
-    compilation_cache.reset_cache()
-
-
 def _shape(sharding, shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-# a step compiled for the four described chips: its compiled text, its
-# memory analysis, the shapes of its outputs and what the trace's `mesh.*`
-# sites said they move (`tracing.mesh_tally`: name -> bytes a transfer)
-FourChipStep = collections.namedtuple(
-    "FourChipStep", "text memory outs crossings")
+# the backend a step's arms are read for, by the name the tests give the arm:
+# as the chip runs it, or what a CPU reads
+BACKEND = {"kernels": "tpu", "xla": None}
 
 
 @contextlib.contextmanager
 def _compiling_for_described_chips(backend=None):
-    """What ``no_compile_cache`` and a ``monkeypatch`` of
-    ``jax.default_backend`` do for one test, for a fixture of the module's
-    scope (a cell's step is compiled ONCE for every test that reads it)."""
+    """What ``no_compile_cache`` (``conftest.py``: a described device's
+    executable cannot be read back from the run's cache) and a
+    ``monkeypatch`` of ``jax.default_backend`` do for one test, for a
+    fixture of the module's scope (a cell's step is compiled ONCE for every
+    test that reads it)."""
     from jax.experimental.compilation_cache import compilation_cache
 
     was, cached = jax.default_backend, jax.config.jax_enable_compilation_cache
@@ -152,13 +160,53 @@ def _compiling_for_described_chips(backend=None):
         compilation_cache.reset_cache()
 
 
-def _four_chip_step(step, *args):
-    with tracing.mesh_tally() as crossings:
-        compiled = jax.jit(step, donate_argnums=(0, 1)).lower(*args).compile()
-    outs = jax.eval_shape(step, *jax.tree.map(
-        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), args))[2]
-    return FourChipStep(
-        compiled.as_text(), compiled.memory_analysis(), outs, dict(crossings))
+class CellStep:
+    """A cell's step ``make_train_step`` gives, at full size for described
+    chips (``backend`` ``"tpu"``: as the chip runs it, code that asks for the
+    backend steered; ``None``: in the arms a CPU reads).  Lowered where it is
+    built: ``lowered`` (the text), ``noted`` (refusals counted on the way)
+    and ``crossings`` (what the trace's ``mesh.*`` sites said they move,
+    ``tracing.mesh_tally``: name -> bytes a transfer) are there at once.
+    Compiled ONCE, when a test first reads ``text`` (the compiled module's)
+    or ``memory`` (its memory analysis); ``outs`` (the shapes of the step's
+    outputs) is a second trace, made when first read.  No test sees the
+    ``Compiled`` object.  ``spec`` and ``arms`` are what the fixture read on
+    its way (the store's spec, its arms as the backend reads them), for the
+    tests that hold them too."""
+
+    def __init__(self, step, *args, backend=None, spec=None, arms=None):
+        self._step, self._args, self._backend = step, args, backend
+        self.spec, self.arms = spec, arms
+        with _compiling_for_described_chips(backend):
+            n0 = row_update.refusal_count()
+            with tracing.mesh_tally() as crossings:
+                self._lowered = jax.jit(
+                    step, donate_argnums=(0, 1)).lower(*args)
+            self.noted = row_update.refusal_count() - n0
+        self.crossings = dict(crossings)
+        self.lowered = self._lowered.as_text()
+
+    @functools.cached_property
+    def _compiled(self):
+        with _compiling_for_described_chips():
+            compiled = self._lowered.compile()
+        del self._lowered
+        return compiled.as_text(), compiled.memory_analysis()
+
+    @functools.cached_property
+    def outs(self):
+        with _compiling_for_described_chips(self._backend):
+            return jax.eval_shape(self._step, *jax.tree.map(
+                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
+                self._args))[2]
+
+    @property
+    def text(self):
+        return self._compiled[0]
+
+    @property
+    def memory(self):
+        return self._compiled[1]
 
 
 # -- the third naming rule, held where it can break: in the compiled step -----
@@ -302,15 +350,27 @@ def _every_transfer_carries_the_programs_name(cell, step):
 
 
 @pytest.fixture(scope="module")
-def fm_ps4_step(ps4):
-    """Cell 4's step at full size compiled ONCE for four described chips, on
-    the layout ``make_store`` resolves by itself, in the arms a CPU reads."""
-    mesh, spec, logic = ps4
-    with _compiling_for_described_chips():
-        return _four_chip_step(
-            make_train_step(logic, spec),
-            _shape(spec.sharding(), spec.table_shape(), jnp.float32), (),
-            _fm_batch(NamedSharding(mesh, PartitionSpec())))
+def fm_step(fm1, ps4, one_chip):
+    """``(cell, arm) -> CellStep``: cell 2's step for one described chip and
+    cell 4's for four, at full size on the layout ``make_store`` resolves by
+    itself, compiled once a cell and arm (``kernels``: as the chips run it,
+    asked for the backend; ``xla``: in the arms a CPU reads)."""
+
+    @functools.cache
+    def built(cell, arm):
+        if cell == "cell_2":
+            spec, logic = fm1
+            table = _shape(one_chip, spec.table_shape(), jnp.float32)
+            batch = _fm_batch(one_chip)
+        else:
+            mesh, spec, logic = ps4
+            table = _shape(spec.sharding(), spec.table_shape(), jnp.float32)
+            batch = _fm_batch(NamedSharding(mesh, PartitionSpec()))
+        return CellStep(
+            make_train_step(logic, spec), table, (), batch,
+            backend=BACKEND[arm])
+
+    return built
 
 
 @pytest.mark.parametrize("plan", ["lane", "compact"])
@@ -481,23 +541,30 @@ def test_tile_refusal_names_a_width_whose_three_buffers_do_not_fit(one_chip):
         )
 
 
-def _mosaic_body_sha(lowered_text):
-    """sha256 of the one Mosaic body in a lowered text, printed without
-    locations (file paths and lines are in the serialized kernel)."""
+MOSAIC_BODY = re.compile(r'(?<=\\22body\\22: \\22)[A-Za-z0-9+/=]+(?=\\22)')
+
+
+def _body_sha(body):
+    """sha256 of a serialized Mosaic body printed without locations (file
+    paths and lines are in the serialized kernel)."""
     import base64
     import hashlib
 
     from jax._src.interpreters import mlir as jax_mlir
     from jax._src.lib.mlir import ir
 
-    bodies = re.findall(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22', lowered_text)
-    assert len(bodies) == 1
     context = jax_mlir.make_ir_context()
     context.allow_unregistered_dialects = True
     with context:
-        asm = ir.Module.parse(base64.b64decode(bodies[0])).operation.get_asm(
+        asm = ir.Module.parse(base64.b64decode(body)).operation.get_asm(
             enable_debug_info=False)
     return hashlib.sha256(asm.encode()).hexdigest()[:16]
+
+
+def _mosaic_body_sha(lowered_text):
+    """... of the one Mosaic body in a lowered text."""
+    body, = MOSAIC_BODY.findall(lowered_text)
+    return _body_sha(body)
 
 
 @pytest.mark.parametrize("rows,width,w,lanes,assign", [
@@ -536,46 +603,49 @@ def test_mosaic_takes_the_tile_kernel_with_rows_at_their_own_width(
     assert compiled.memory_analysis().alias_size_in_bytes >= rows * width * 4
 
 
-def _compiled_mf_step(one_chip, monkeypatch, batch_size):
-    """The step the MF cells run (``OnlineMatrixFactorization`` as
-    ``chipbench/families/mf.py`` builds it: no ``state_scatter``), compiled
-    for the chip at the cells' tables and a batch of ``batch_size``."""
-    # code that asks for the backend still sees the CPU here: steer it
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    logic = mfm.OnlineMatrixFactorization(
-        USERS, DIM, updater=mfm.SGDUpdater(2e-4))
-    spec = jax.eval_shape(
-        lambda: ShardedParamStore.create(ITEMS, (DIM,), dtype=jnp.float32)
-    ).spec
-    batch = {
-        "user": _shape(one_chip, (batch_size,), jnp.int32),
-        "item": _shape(one_chip, (batch_size,), jnp.int32),
-        "rating": _shape(one_chip, (batch_size,), jnp.float32),
-        "mask": _shape(one_chip, (batch_size,), jnp.bool_),
-    }
-    return jax.jit(
-        make_train_step(logic, spec), donate_argnums=(0, 1)
-    ).lower(
-        _shape(one_chip, (spec.padded_capacity, DIM), jnp.float32),
-        _shape(one_chip, (USERS, DIM), jnp.float32),
-        batch,
-    ).compile()
+@pytest.fixture(scope="module")
+def mf_tpu_step(one_chip):
+    """``batch_size -> CellStep``: the step the MF cells run (cells 1, 3 and
+    11: ``OnlineMatrixFactorization`` as ``chipbench/families/mf.py`` builds
+    it, no ``state_scatter``) as the chip runs it (code that asks for the
+    backend still sees the CPU here: it is steered), at the cells' tables,
+    compiled once a batch size."""
+
+    @functools.cache
+    def built(batch_size):
+        logic = mfm.OnlineMatrixFactorization(
+            USERS, DIM, updater=mfm.SGDUpdater(2e-4))
+        spec = jax.eval_shape(
+            lambda: ShardedParamStore.create(ITEMS, (DIM,), dtype=jnp.float32)
+        ).spec
+        batch = {
+            "user": _shape(one_chip, (batch_size,), jnp.int32),
+            "item": _shape(one_chip, (batch_size,), jnp.int32),
+            "rating": _shape(one_chip, (batch_size,), jnp.float32),
+            "mask": _shape(one_chip, (batch_size,), jnp.bool_),
+        }
+        return CellStep(
+            make_train_step(logic, spec),
+            _shape(one_chip, (spec.padded_capacity, DIM), jnp.float32),
+            _shape(one_chip, (USERS, DIM), jnp.float32),
+            batch, backend="tpu")
+
+    return built
 
 
 def test_mf_step_with_a_batch_over_one_calls_lanes_takes_two_calls(
-        one_chip, no_compile_cache, monkeypatch):
+        mf_tpu_step):
     """131,072 row ids do not fit one call's SMEM (Mosaic: RESOURCE_
     EXHAUSTED; the step kept the XLA arm for them until PR 33):
     ``row_add`` gives them to two calls of 65,536, nothing is refused and
     the 2.56 GB state is still updated in place."""
-    n0 = row_update.refusal_count()
-    compiled = _compiled_mf_step(one_chip, monkeypatch, 131_072)
-    assert row_update.refusal_count() == n0
-    text = compiled.as_text()
+    step = mf_tpu_step(131_072)
+    assert step.noted == 0
+    text = step.text
     calls = re.findall(r" custom-call\([^\n]*sorted_row_update", text)
     assert len(calls) == 2, len(calls)
     assert not re.findall(rf"= f32\[{USERS},{DIM}\][^ ]* copy\(", text)
-    assert compiled.memory_analysis().temp_size_in_bytes < 512 * 2 ** 20
+    assert step.memory.temp_size_in_bytes < 512 * 2 ** 20
 
 
 def test_the_keyed_streams_row_kernel_is_the_parents_op_for_op(one_chip):
@@ -599,14 +669,12 @@ def test_the_keyed_streams_row_kernel_is_the_parents_op_for_op(one_chip):
     assert _mosaic_body_sha(text) == "3f54df8566796c53"
 
 
-def test_mf_step_default_arm_on_tpu_is_the_row_kernel(
-        one_chip, no_compile_cache, monkeypatch):
+def test_mf_step_default_arm_on_tpu_is_the_row_kernel(mf_tpu_step):
     """At the cells' batch the user state goes through the kernel, and no
     scatter over the state array is left under ``ps.state_push``."""
-    n0 = row_update.refusal_count()
-    compiled = _compiled_mf_step(one_chip, monkeypatch, BATCH)
-    assert row_update.refusal_count() == n0
-    text = compiled.as_text()
+    step = mf_tpu_step(BATCH)
+    assert step.noted == 0
+    text = step.text
     assert "tpu_custom_call" in text and "sorted_row_update" in text
     # what is left under ps.state_push that yields the whole state array:
     # the kernel's call and nothing else (no XLA scatter fusion)
@@ -621,17 +689,15 @@ def test_mf_step_default_arm_on_tpu_is_the_row_kernel(
 
 @pytest.fixture(scope="module")
 def mf_dp4_step(topo):
-    """``staged -> FourChipStep``: cell 8's step at full size for four
+    """``staged -> CellStep``: cell 8's step at full size for four
     described chips, compiled once a staging of the batch, as the chips run
     it (asked for the backend)."""
     from flink_parameter_server_tpu.parallel.mesh import make_mesh
 
     users, lanes = 50_082_603, 262_144
-    done = {}
 
-    def compiled(staged):
-        if staged in done:
-            return done[staged]
+    @functools.cache
+    def built(staged):
         mesh = make_mesh(4, 1, devices=topo.devices)
         with _compiling_for_described_chips("tpu"):
             logic = mfm.OnlineMatrixFactorization(
@@ -641,24 +707,23 @@ def mf_dp4_step(topo):
             spec = jax.eval_shape(lambda: ShardedParamStore.create(
                 ITEMS, (DIM,), dtype=jnp.float32, mesh=mesh)).spec
 
-            def on(shape, dtype, *axes):
-                return _shape(
-                    NamedSharding(mesh, PartitionSpec(*axes)), shape, dtype)
+        def on(shape, dtype, *axes):
+            return _shape(
+                NamedSharding(mesh, PartitionSpec(*axes)), shape, dtype)
 
-            by = ("dp",) if staged == "a_block_a_worker" else ()
-            n0 = row_update.refusal_count()
-            done[staged] = _four_chip_step(
-                make_train_step(logic, spec),
-                on((spec.padded_capacity, DIM), jnp.float32, "ps", None),
-                on((logic.state_rows, DIM), jnp.float32, "dp", None),
-                {"user": on((lanes,), jnp.int32, *by),
-                 "item": on((lanes,), jnp.int32, *by),
-                 "rating": on((lanes,), jnp.float32, *by),
-                 "mask": on((lanes,), jnp.bool_, *by)})
-            assert row_update.refusal_count() == n0
-        return done[staged]
+        by = ("dp",) if staged == "a_block_a_worker" else ()
+        step = CellStep(
+            make_train_step(logic, spec),
+            on((spec.padded_capacity, DIM), jnp.float32, "ps", None),
+            on((logic.state_rows, DIM), jnp.float32, "dp", None),
+            {"user": on((lanes,), jnp.int32, *by),
+             "item": on((lanes,), jnp.int32, *by),
+             "rating": on((lanes,), jnp.float32, *by),
+             "mask": on((lanes,), jnp.bool_, *by)}, backend="tpu")
+        assert step.noted == 0
+        return step
 
-    return compiled
+    return built
 
 
 @pytest.mark.parametrize("staged", ["a_block_a_worker", "whole_on_every_chip"])
@@ -786,7 +851,7 @@ def test_unpacking_cell_4_s_table_stays_on_its_shards(ps4, no_compile_cache):
         ).lower(table).compile()
 
 
-def test_fm_step_on_four_chips_keeps_its_collective_under_ps_pull(fm_ps4_step):
+def test_fm_step_on_four_chips_keeps_its_collective_under_ps_pull(fm_step):
     """Cell 4's step on the layout ``make_store`` resolves by itself: the
     donated 3.43 GB shard (``f32[6705984,128]``) is updated in place,
     every gather moves whole 128-lane rows, and the ONE collective is the
@@ -796,8 +861,9 @@ def test_fm_step_on_four_chips_keeps_its_collective_under_ps_pull(fm_ps4_step):
     ``op_name`` carries ``ps.pull``: a device trace reads it under
     ``store.pull_device_ms`` (docs/observability.md).  The scatter-add is
     GSPMD's, into the chip's own block, with no collective."""
-    text, mem, _, _ = fm_ps4_step
-    _every_transfer_carries_the_programs_name("cell_4", fm_ps4_step)
+    step = fm_step("cell_4", "xla")
+    text, mem = step.text, step.memory
+    _every_transfer_carries_the_programs_name("cell_4", step)
     assert mem.alias_size_in_bytes > 3.43 * GB  # in place
     # 1.350 GB here (the dense step's: under 1.0): two ``f32[1277952,128]``
     # of 654 MB live at once, the gathered physical rows (in the push the
@@ -822,26 +888,19 @@ def test_fm_step_on_four_chips_keeps_its_collective_under_ps_pull(fm_ps4_step):
     ), gathers
 
 
-def test_fm_step_on_one_chip_moves_whole_128_lane_rows(
-        fm1, one_chip, no_compile_cache):
+def test_fm_step_on_one_chip_moves_whole_128_lane_rows(fm_step):
     """Cell 2's step on the layout ``make_store`` resolves by itself: the
     donated 3.59 GB table is updated in place, and every gather under
     ``ps.pull`` / ``ps.push`` takes a whole 128-lane physical row.  A gather
     with 1-element slices there (``take_along_axis`` for the lane slice or
     the lane shift) is 10 ns an ELEMENT on the v5e: 460 ms and 3.4 s a
     step (my chip run, PR 29)."""
-    spec, logic = fm1
-    compiled = jax.jit(
-        make_train_step(logic, spec), donate_argnums=(0, 1)
-    ).lower(
-        _shape(one_chip, spec.table_shape(), jnp.float32), (),
-        _fm_batch(one_chip),
-    ).compile()
-    mem = compiled.memory_analysis()
+    step = fm_step("cell_2", "xla")
+    mem = step.memory
     assert mem.alias_size_in_bytes > 3.59 * GB  # in place
     assert mem.temp_size_in_bytes < 1.4 * GB  # 1.352: two f32[1277952,128]
     gathers = [
-        line for line in compiled.as_text().splitlines()
+        line for line in step.text.splitlines()
         if " gather(" in line and re.search(r"ps\.(pull|push)", line)
     ]
     assert gathers and all(
@@ -910,7 +969,7 @@ def _no_block_of_the_batch_has_its_fields_minor(text):
 @pytest.mark.parametrize("d, width, fields", [
     (17, None, 40), (36, 20, 40), (64, None, 40), (64, None, 26)], ids=str)
 def test_the_by_field_kernels_compile_at_the_most_fields_they_take(
-        one_chip, d, width, fields):
+        one_chip, no_compile_cache, d, width, fields):
     """``ops/packed.by_field`` takes no more than ``TURN_FIELDS`` fields a
     block: both kernels are lowered by Mosaic and compiled for a described
     v5e at exactly that many (Criteo's 39 ran on the chip), at FM's and at
@@ -945,7 +1004,7 @@ def test_the_by_field_kernels_compile_at_the_most_fields_they_take(
 
 @pytest.mark.parametrize("cell", ["cell_2", "cell_4"])
 def test_fm_step_on_a_tpu_slices_its_pulled_rows_in_the_kernel(
-        cell, fm1, ps4, one_chip, no_compile_cache, monkeypatch):
+        cell, fm1, ps4, fm_step, monkeypatch):
     """What cells 2 and 4 run on the chip: asked for the backend, ``pull``
     hands the 1,277,952 gathered rows to ``ops/packed``'s kernel (on four
     chips inside ``_packed_pull_on_shards``' ``shard_map``, a call a
@@ -984,15 +1043,7 @@ def test_fm_step_on_a_tpu_slices_its_pulled_rows_in_the_kernel(
     0.66 GB (0.778 / 0.789 until then)."""
     # code that asks for the backend still sees the CPU here: steer it
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    if cell == "cell_2":
-        spec, logic = fm1
-        table = _shape(one_chip, spec.table_shape(), jnp.float32)
-        batch = _fm_batch(one_chip)
-    else:
-        mesh, spec, logic = ps4
-        table = _shape(spec.sharding(), spec.table_shape(), jnp.float32)
-        batch = _fm_batch(NamedSharding(mesh, PartitionSpec()))
-    n0 = row_update.refusal_count()
+    spec = fm1[0] if cell == "cell_2" else ps4[1]
     lanes = FM_BATCH * FM_FIELDS
     assert store_mod.arms(spec, pull_lanes=lanes, push_lanes=lanes) == (
         store_mod.Arms("packed_kernel", "xla_add", "kernel", "", "", False))
@@ -1001,15 +1052,13 @@ def test_fm_step_on_a_tpu_slices_its_pulled_rows_in_the_kernel(
         spec, pull_lanes=lanes, push_lanes=lanes, fields=FM_FIELDS
     ) == store_mod.Arms(
         "packed_kernel_by_field", "xla_add", "kernel_by_field", "", "", False)
-    compiled = jax.jit(
-        make_train_step(logic, spec), donate_argnums=(0, 1)
-    ).lower(table, (), batch).compile()
-    assert row_update.refusal_count() == n0
-    mem = compiled.memory_analysis()
+    step = fm_step(cell, "kernels")
+    assert step.noted == 0
+    mem = step.memory
     assert mem.alias_size_in_bytes > 3.43 * GB  # in place
     # 0.660 / 0.660 here (0.778 / 0.789 with the flattens' loops)
     assert mem.temp_size_in_bytes < 0.7 * GB
-    text = compiled.as_text()
+    text = step.text
     entry = text[text.index("ENTRY"):]
     ops = _ops(entry)
     n, lanes_3d = FM_BATCH * FM_FIELDS, f"{FM_FIELDS},{FM_BATCH}"
@@ -1194,20 +1243,41 @@ def w2v():
     return spec, w2vm.SkipGramNS(0.025), w2vm
 
 
-def _w2v_step(one_chip, spec, logic):
+@pytest.fixture(scope="module")
+def w2v_step(w2v, one_chip):
+    """``arm -> CellStep``: cell 5's step at full size for a described v5e,
+    compiled once an arm.  ``kernels``: as the chip runs it (the mean
+    combiner on, the store's own layout, the push's arm chosen as on a TPU);
+    ``xla``: the summed logic on the store's own layout in the arms a CPU
+    reads; ``dense``: that with the table left ``(vocab, 2, 300)``."""
+    spec, summed, w2vm = w2v
     batch = {
         "center": _shape(one_chip, (W2V_BATCH,), jnp.int32),
         "context": _shape(one_chip, (W2V_BATCH,), jnp.int32),
         "negatives": _shape(one_chip, (W2V_BATCH, W2V_NEG), jnp.int32),
         "mask": _shape(one_chip, (W2V_BATCH,), jnp.bool_),
     }
-    return jax.jit(make_train_step(logic, spec), donate_argnums=(0, 1)).lower(
-        _shape(one_chip, spec.table_shape(), jnp.float32), (), batch
-    )
+
+    @functools.cache
+    def built(arm):
+        logic, laid, backend = summed, spec, None
+        if arm == "kernels":
+            logic = w2vm.SkipGramNS(
+                0.025, dedup_scale=True, vocab_size=W2V_VOCAB)
+            backend = "tpu"
+            with _compiling_for_described_chips(backend):
+                assert store_mod.arms(spec).push == "tile_add"
+        elif arm == "dense":
+            laid = dataclasses.replace(spec, layout="dense")
+        return CellStep(
+            make_train_step(logic, laid),
+            _shape(one_chip, laid.table_shape(), jnp.float32), (), batch,
+            backend=backend)
+
+    return built
 
 
-def test_w2v_step_holds_its_table_once_and_copies_no_table(
-        one_chip, w2v, no_compile_cache):
+def test_w2v_step_holds_its_table_once_and_copies_no_table(w2v, w2v_step):
     """The layout ``make_store`` resolves by itself holds a ``(2, 300)`` row
     flat in 640 lanes, row-major (``f32[3000000,640]{1,0:T(8,128)}``, 7.68
     GB): the step updates it in place, gathers and scatter-adds whole rows
@@ -1216,14 +1286,13 @@ def test_w2v_step_holds_its_table_once_and_copies_no_table(
     no padding) and the step copies all of it to a row-major table (9.2 GB,
     300 lanes padded to 384) for the gather and back after the scatter-add,
     9.6 GB of temporaries beside the table."""
-    spec, logic, _ = w2v
+    spec, _, _ = w2v
     assert spec.layout == "packed" and spec.pack == 1
     assert spec.table_shape() == (W2V_VOCAB, 640)
-    compiled = _w2v_step(one_chip, spec, logic).compile()
-    mem = compiled.memory_analysis()
+    mem = w2v_step("xla").memory
     assert mem.alias_size_in_bytes >= 7.68 * GB  # in place
     assert mem.temp_size_in_bytes < 1.0 * GB  # 0.65 GB here
-    text = compiled.as_text()
+    text = w2v_step("xla").text
     entry = text[text.index("ENTRY"):]
     tables = [
         line.strip() for line in entry.splitlines()
@@ -1234,11 +1303,9 @@ def test_w2v_step_holds_its_table_once_and_copies_no_table(
         f"f32[{W2V_VOCAB},640]{{1,0:T(8,128)}}" in t for t in tables
     ), tables
     assert " parameter(" in tables[0] and "ps.push/scatter-add" in tables[1]
-    dense = _w2v_step(
-        one_chip, dataclasses.replace(spec, layout="dense"), logic
-    ).compile()
-    assert dense.memory_analysis().temp_size_in_bytes > 9.0 * GB
-    text = dense.as_text()
+    dense = w2v_step("dense")
+    assert dense.memory.temp_size_in_bytes > 9.0 * GB
+    text = dense.text
     copies = re.findall(
         rf"= f32\[{W2V_VOCAB},2,300\]\{{[0-9,]+:T\(2,128\)\}} copy\(",
         text[text.index("ENTRY"):],
@@ -1246,34 +1313,18 @@ def test_w2v_step_holds_its_table_once_and_copies_no_table(
     assert len(copies) >= 2, copies
 
 
-def _w2v_cell_step(one_chip, w2vm, monkeypatch):
-    """Cell 5's step as the chip runs it (the mean combiner on, the store's
-    own layout, the push's arm chosen as on a TPU), compiled."""
-    # code that asks for the backend still sees the CPU here: steer it
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    spec = jax.eval_shape(
-        lambda: w2vm.make_store(W2V_VOCAB, W2V_DIM, dtype=jnp.float32)
-    ).spec
-    assert store_mod.arms(spec).push == "tile_add"
-    logic = w2vm.SkipGramNS(0.025, dedup_scale=True, vocab_size=W2V_VOCAB)
-    return _w2v_step(one_chip, spec, logic).compile()
-
-
-def test_w2v_step_on_a_tpu_pushes_through_the_tile_kernel(
-        one_chip, w2v, no_compile_cache, monkeypatch):
+def test_w2v_step_on_a_tpu_pushes_through_the_tile_kernel(w2v_step):
     """What cell 5 runs on the chip: asked for the backend, ``push`` takes
     ``ops/row_update``'s tile kernel for the 640-lane rows (no refusal is
     counted), two calls of it under ``ps.push`` are the only ops that yield
     a table, no XLA scatter is left on the table, nothing copies it, and
     the step's temporaries stay within 0.3 GB of the XLA arm's 0.65."""
-    _, _, w2vm = w2v
-    n0 = row_update.refusal_count()
-    compiled = _w2v_cell_step(one_chip, w2vm, monkeypatch)
-    assert row_update.refusal_count() == n0
-    mem = compiled.memory_analysis()
+    step = w2v_step("kernels")
+    assert step.noted == 0
+    mem = step.memory
     assert mem.alias_size_in_bytes >= 7.68 * GB  # in place
     assert mem.temp_size_in_bytes < 0.95 * GB  # 0.65 GB here
-    text = compiled.as_text()
+    text = step.text
     entry = text[text.index("ENTRY"):]
     tables = [
         line.strip() for line in entry.splitlines()
@@ -1309,8 +1360,7 @@ def _ops_built_under(text, scope):
     return found
 
 
-def test_w2v_step_assembles_its_deltas_without_a_zeroed_block(
-        one_chip, w2v, no_compile_cache, monkeypatch):
+def test_w2v_step_assembles_its_deltas_without_a_zeroed_block(w2v_step):
     """Cell 5's deltas are written once: under ``ps.delta_build`` and
     ``ps.push`` no op of the compiled step yields a ``(B, 7, 2, 300)`` block
     (the parent filled a zeroed one, ``{3,2,1,0:T(2,128)}``: a ``pad``, two
@@ -1330,10 +1380,9 @@ def test_w2v_step_assembles_its_deltas_without_a_zeroed_block(
     3.04, 3.3, 3.6, 3.9, 4.2 and 5.0 M rows: PERF.md section 6, PR 57),
     the one permute none to 5.0 M: the bound is that form's reading.  The
     ops below are what holds the block off."""
-    _, _, w2vm = w2v
-    compiled = _w2v_cell_step(one_chip, w2vm, monkeypatch)
-    assert compiled.memory_analysis().temp_size_in_bytes < 0.90 * GB
-    text = compiled.as_text()
+    step = w2v_step("kernels")
+    assert step.memory.temp_size_in_bytes < 0.90 * GB
+    text = step.text
     build, push = (
         [op for op, _ in _ops_built_under(text, scope)]
         for scope in ("ps.delta_build", "ps.push")
@@ -1354,9 +1403,110 @@ def test_w2v_step_assembles_its_deltas_without_a_zeroed_block(
     assert len(relaid(build, W2V_DIM)) == 1 and not relaid(push, W2V_DIM)
 
 
+# -- cells 5, 7 and 13 push wide rows through the tile kernel: the tests that
+# read their steps stand together (a worker that is dealt neighbours builds
+# each fixture once) ---------------------------------------------------------
+# fastText's released wiki.en model (chipbench/configs/ft-wiki-en-300.json):
+# words, n-gram buckets and output vectors in one store of (300,) rows, and a
+# batch of 4,096 pairs x (bag of 51, context, 5 negatives) = 233,472 lanes
+FT_VOCAB, FT_BUCKETS, FT_DIM, FT_BATCH, FT_BAG = 2_519_370, 2_000_000, 300, 4_096, 51
+
+
+@pytest.fixture(scope="module")
+def ft_step(one_chip):
+    """Cell 7's step at full size for a described v5e as the chip runs it
+    (the store's own layout, the push's arm chosen as on a TPU), compiled
+    ONCE for the tests that read it."""
+    from flink_parameter_server_tpu.models import fasttext as ftm
+
+    spec = jax.eval_shape(
+        lambda: ftm.make_store(FT_VOCAB, FT_BUCKETS, FT_DIM, dtype=jnp.float32)
+    ).spec
+    assert spec.layout == "packed" and spec.pack == 1
+    assert spec.table_shape() == (7_038_744, 384)
+    with _compiling_for_described_chips("tpu"):
+        assert store_mod.arms(spec).push == "tile_add"
+    logic = ftm.FastTextSkipGram(0.05, FT_VOCAB, FT_BUCKETS, FT_BAG)
+    batch = {
+        "bag": _shape(one_chip, (FT_BATCH, FT_BAG), jnp.int32),
+        "context": _shape(one_chip, (FT_BATCH,), jnp.int32),
+        "negatives": _shape(one_chip, (FT_BATCH, 5), jnp.int32),
+        "mask": _shape(one_chip, (FT_BATCH,), jnp.bool_),
+    }
+    return CellStep(
+        make_train_step(logic, spec),
+        _shape(one_chip, spec.table_shape(), jnp.float32), (), batch,
+        backend="tpu")
+
+
+# glove-840b-300 (chipbench/configs): cell 13's table and batch
+GLOVE_VOCAB, GLOVE_DIM, GLOVE_BATCH = 2_196_017, 300, 32_768
+GLOVE_PHYS_ROWS = 4_392_040
+
+
+@pytest.fixture(scope="module")
+def glove():
+    from flink_parameter_server_tpu.models import glove as gl
+
+    model = gl.GloVeConfig(GLOVE_VOCAB, GLOVE_DIM)
+    assert model.num_rows == 4_392_034 and model.row_lanes == 602
+    return model, gl
+
+
+def test_glove_table_is_initialised_in_place_from_a_seed_argument(
+        glove, one_chip, no_compile_cache):
+    """4,392,034 x 602 f32 rule rows under a ``jit`` that takes the seed: the
+    11.24 GB table ``f32[4392040,640]`` (a row flat in five registers) is the
+    program's only output, initialised ``core/store._PACK_CHUNK`` rows a loop
+    step: 0.67 GB of temporaries, where a second copy would not fit."""
+    model, gl = glove
+    compiled = jax.jit(lambda s: gl.make_store(model, seed=s).table).lower(
+        _shape(one_chip, (), jnp.uint32)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes == GLOVE_PHYS_ROWS * 640 * 4 == 11_243_622_400
+    assert mem.temp_size_in_bytes < 1.0 * GB
+    assert len(re.findall(r" while\(", compiled.as_text())) >= 1
+
+
+@pytest.fixture(scope="module")
+def glove_step(glove, one_chip):
+    """``arm -> CellStep``: cell 13's step at full size for a described v5e,
+    compiled once an arm: as the chip runs it (``kernels``: the arms chosen
+    as on a TPU) or off it (``xla``)."""
+    model, gl = glove
+    spec = jax.eval_shape(lambda: gl.make_store(model)).spec
+    assert spec.layout == "packed" and spec.pack == 1
+    assert spec.table_shape() == (GLOVE_PHYS_ROWS, 640)
+    assert store_mod.arms(spec) == store_mod.Arms(
+        "packed_selects", "rule", "", "scatter_add", "xla_set", False)  # a CPU
+    batch = {
+        "word": _shape(one_chip, (GLOVE_BATCH,), jnp.int32),
+        "context": _shape(one_chip, (GLOVE_BATCH,), jnp.int32),
+        "count": _shape(one_chip, (GLOVE_BATCH,), jnp.float32),
+        "mask": _shape(one_chip, (GLOVE_BATCH,), jnp.bool_),
+    }
+
+    @functools.cache
+    def built(arm):
+        backend = BACKEND[arm]
+        if arm == "kernels":
+            with _compiling_for_described_chips(backend):
+                n0 = row_update.refusal_count()
+                assert store_mod.arms(spec) == store_mod.Arms(
+                    "packed_selects", "rule", "", "tile_kernel",
+                    "tile_assign", False)
+                assert row_update.refusal_count() == n0
+        return CellStep(
+            make_train_step(gl.GloVe(model), spec),
+            _shape(one_chip, spec.table_shape(), jnp.float32), (), batch,
+            backend=backend)
+
+    return built
+
+
 @pytest.mark.parametrize("cell", [5, 7])
 def test_the_combiners_counts_hold_no_counter_and_touch_no_table(
-        cell, one_chip, w2v, no_compile_cache, monkeypatch):
+        cell, request):
     """The mean combiner's counts come from a sort of the batch's keys
     (``ops/dedup.occurrence_counts``, PR 39): in cell 5's and cell 7's step
     as the chip runs them, no op under ``ps.delta_build`` yields a buffer
@@ -1368,15 +1518,14 @@ def test_the_combiners_counts_hold_no_counter_and_touch_no_table(
     parent's step was held to (0.883 / 0.767 GB here; cell 5's were 0.649
     until PR 57 permuted its push's rows once: the test above says why)."""
     if cell == 5:
-        _, _, w2vm = w2v
-        compiled = _w2v_cell_step(one_chip, w2vm, monkeypatch)
+        step = request.getfixturevalue("w2v_step")("kernels")
         rows, lanes, temp = W2V_VOCAB, W2V_BATCH * (W2V_NEG + 2), 0.90 * GB
     else:
-        compiled = _ft_cell_step(one_chip, monkeypatch)
+        step = request.getfixturevalue("ft_step")
         rows, lanes = 2 * FT_VOCAB + FT_BUCKETS, FT_BATCH * (FT_BAG + 6)
         temp = 1.0 * GB
-    assert compiled.memory_analysis().temp_size_in_bytes < temp
-    build = _ops_built_under(compiled.as_text(), "ps.delta_build")
+    assert step.memory.temp_size_in_bytes < temp
+    build = _ops_built_under(step.text, "ps.delta_build")
     assert len(build) >= 10  # the filter found the scope
     sorts = [op for op, _ in build if re.search(r"\) sort\(", op)]
     assert len(sorts) == 2 and all(f"s32[{lanes}]" in op for op in sorts)
@@ -1386,139 +1535,157 @@ def test_the_combiners_counts_hold_no_counter_and_touch_no_table(
         assert not re.search(r"/(scatter(-add)?|gather)$", op), op
 
 
-def _step_text_sha(step, *args):
-    import hashlib
-
-    text = jax.jit(step, donate_argnums=(0, 1)).lower(*args).as_text()
-    return hashlib.sha256(text.encode()).hexdigest()[:16]
-
-
-@pytest.mark.parametrize("cell, want", [
-    ("mf_cells_1_and_3", "467449ddc73eac39"),
-    ("fm_cell_2", "715a8a5e1325631a"),
-    ("fm_ps4_cell_4", "b1244432ae0312eb"),
-    ("lr_cell_6", "f669bf2e1dddf416"),
-    ("keyed_mf_cell_8", "878ead5a803a932d"),
-])
-def test_the_mf_and_fm_cells_step_text_is_the_parents(cell, want, request):
-    """The lowered text of a step carries no locations, so a change that
-    traces the same ops gives the same text: cells 1 and 3 (MF, dense 128
-    lanes) and cell 2 (FM, seven 17-lane rows to a 128-lane row) run the
-    step PR 30 to PR 32 ran (PERF.md section 6 records both hashes).  A
-    change that means to move them brings its own: PR 42 gave FM's step the
-    scalar ``ps_slice_kernel`` (which arm sliced the pulled rows; lowered
-    here, off the TPU, the arm and every other op are the parent's:
-    ``3913e9e902cfade8`` until then).  Since PR 46 also cell 4 (FM packed
-    under ``ps = 4``), cell 6 (the narrow rule store: its combine, its
-    counts and its outputs are what they were, no new count among them) and
-    cell 8 (MF under four keyed workers), each as PR 46's parent lowers it
-    here: that PR changed the combine of WIDE rule rows, which none of the
-    five traces.  PR 51 gave the two FM steps one scalar more,
-    ``ps_shift_kernel`` (which arm shifted the pushed deltas: here, off the
-    TPU, a constant 0 and the parent's ops to the letter; ``62cb492a1f6ca10e``
-    and ``0d16cc6091cddad1`` until then); the other three did not move.
-    PR 57 took ``_zero_masked`` out of a RULE store's push (a masked lane goes
-    to the sentinel in ``_push_rule``; its delta reaches no kept row): cell
-    6's text lost the mask's ``reshape`` to ``(1277952, 1)``, a zero
-    ``broadcast_in_dim`` and the ``_where`` over ``f32[1277952,3]``, nothing
-    else (``aab60546ac40ed64`` until then); the four add stores kept theirs.
-    PR 63 moved the two FM steps and meant to: the copy of their logic that
-    a step in one place traces computes field-major, takes its rows turned
-    (off the TPU, as here, the pull's answer with its axes swapped) and
-    pushes ``(K, B)`` lanes (``models/factorization_machine.FieldLanes``;
-    ``bc06381bf02bcde5`` and ``db02bf3de22f8a3e`` until then); the other
-    three did not move, nor did
-    the step of any cell that runs neither FM logic (every cell's lowered
-    step at full size for a described v5e, hashed on both trees: PERF.md
-    section 6, PR 63).  PR 65 gave the step of every store packed several
-    rows to a physical row one scalar more, ``ps_lanes_by_field`` (whether
-    its lane kernels move the batch a field at a time: here, off the TPU, a
-    constant 0 behind the parent's ops to the letter; ``1a01b65060f0886d``
-    and ``d240e3e5008700fb`` until then; at full size for a described v5e
-    cells 2, 4, 9 and 12 hash equal to the parent's with that output left
-    out, every other cell but cell 10 as it stands: PERF.md section 6, PR
-    65); the other three did not move.  PR 67 (an add store's push on the
-    shards that own its rows, the tile kernel's calls rolled into a loop
-    there, DLRM's init one program) moved none of the five, and at full
-    size for a described v5e every one of cells 1-15 hashes equal on its
-    parent and on its tree (the sixteen hashes: PERF.md section 6, PR 67).
-    PR 68 (the minibatch's compute split over the servers' own axis where a
-    logic declares ``example_blocks`` under one worker group and ``ps`` > 1:
-    cell 16 alone) moved none of the five: a step that computes the whole
-    minibatch in every place hands out no new output and names no
-    constraint, and at full size for a described v5e every one of cells
-    1-15 BUT CELL 10 hashes equal on its parent and on its tree; cell 10's
-    text moved and was meant to (its dense gradients are summed in the four
-    blocks the chips of cell 16 hold, so that the MLPs do not depend on
-    ``ps``: PERF.md section 6, PR 68).  PR 69 gave the two steps under a
-    mesh one scalar more, ``ps_mesh_kib`` (what their ``mesh.*`` sites say
-    crosses between chips, a constant of the
-    trace): with that output left out their text is the parent's to
-    the letter (``7c6eef68c7b9b162`` and ``47d256f2590f4bc0``, held below:
-    the names themselves are locations, which this text does not carry);
-    the three steps in one place did not move.  PR 70 (a narrow rule
-    store pulls a batch's distinct rows once: cell 6 on a TPU, the arm
-    ``narrow_distinct``) moved none of the five, cell 6's included: the arm
-    is taken on a TPU alone, and lowered here, off it, the step calls
-    ``pull_counted`` for ``pull`` and traces the parent's ops to the letter;
-    the step as the chip runs it is held by
-    ``test_lr_step_reads_each_distinct_row_once_for_the_logic_and_the_rule``
-    (compiled for a described v5e), and every other cell's step hashes
-    equal on the parent and on the tree at full size for a described v5e
-    (PERF.md section 6, PR 70)."""
-    shape = jax.ShapeDtypeStruct
-
-    def mf_batch(n, on=shape):
-        return {"user": on((n,), jnp.int32), "item": on((n,), jnp.int32),
-                "rating": on((n,), jnp.float32), "mask": on((n,), jnp.bool_)}
-
-    if cell == "mf_cells_1_and_3":
-        logic = mfm.OnlineMatrixFactorization(
-            USERS, DIM, updater=mfm.SGDUpdater(2e-4))
-        spec = jax.eval_shape(
-            lambda: ShardedParamStore.create(ITEMS, (DIM,), dtype=jnp.float32)
-        ).spec
-        args = (shape((spec.padded_capacity, DIM), jnp.float32),
-                shape((USERS, DIM), jnp.float32), mf_batch(BATCH))
-    elif cell == "fm_cell_2":
-        spec, logic = request.getfixturevalue("fm1")
-        batch = {k: shape(v.shape, v.dtype) for k, v in _fm_batch(None).items()}
-        args = (shape(spec.table_shape(), jnp.float32), (), batch)
-    elif cell == "fm_ps4_cell_4":
-        mesh, spec, logic = request.getfixturevalue("ps4")
-        args = (_shape(spec.sharding(), spec.table_shape(), jnp.float32), (),
-                _fm_batch(NamedSharding(mesh, PartitionSpec())))
-    elif cell == "lr_cell_6":
-        spec, logic = request.getfixturevalue("lr")
-        args = (shape(spec.table_shape(), jnp.float32), (), _fm_batch(None))
+@pytest.mark.parametrize("cell", [5, 7, 13])
+def test_no_pass_over_the_pushed_rows_stands_in_front_of_the_tile_kernel(
+        cell, request):
+    """The tile kernel takes a wide row at the width the logic left it (PR
+    57): in the compiled steps of cells 5, 7 and 13 every tile-kernel call
+    reads its rows from the op that made them, at their LOGICAL width
+    (``f32[114688,600]``, ``f32[233472,300]``: ONE permute for all the calls
+    of an add push; ``f32[65536,301]``: the combine's permute of the worker's
+    part, PR 59 (602 until then); ``f32[32768,602]``: the rule's own output,
+    whole rows), and nothing between them
+    pads, copies or "compresses" the batch's rows: no ``pad`` to 640 / 384
+    lanes (the parent's ``pad.71/.73``, ``pad.75/.77/.79``, ``pad.38``, 0.37 to
+    0.51 ms each on the v5e), no select over the pushed block in front of a
+    rule's combine (``_zero_masked``), no merge of the new rows into the
+    gathered ones (``fusion.53``), and no ``remat_compressed`` /
+    ``remat_uncompressed`` pair, which is what the compiler makes of three
+    permutes of ``f32[77824,300]`` that lie across cell 7's calls (a copy to
+    ``{0,1}`` and back for two of them, and 0.952 GB of temporaries; of
+    cell 5's ``f32[57344,600]`` too once its table has a tenth more rows:
+    both compiled, PERF.md section 6, PR 57; why an add push narrower than
+    its table permutes once).  The temporaries:
+    cell 7's the parent's 0.767 GB, cell 13's 0.336 (0.437 until PR 59, 0.673
+    until PR 57); cell 5's
+    0.883 for its 0.649 (one 294 MB buffer where two of 147 packed better
+    into the heap; the live bytes are the parent's)."""
+    if cell == 5:
+        step = request.getfixturevalue("w2v_step")("kernels")
+        lanes, w, width = W2V_BATCH * (W2V_NEG + 2), 2 * W2V_DIM, 640
+        rows_in, calls, temp = [(lanes, w)] * 2, 2, 0.90 * GB
+    elif cell == 7:
+        step = request.getfixturevalue("ft_step")
+        lanes, w, width = FT_BATCH * (FT_BAG + 6), FT_DIM, 384
+        rows_in, calls, temp = [(lanes, w)] * 3, 3, 0.77 * GB
     else:
-        from flink_parameter_server_tpu.parallel.mesh import make_mesh
+        step = request.getfixturevalue("glove_step")("kernels")
+        lanes, w, width = 2 * GLOVE_BATCH, "602|301", "640|384"
+        rows_in, calls, temp = [(store_mod._RULE_CHUNK, 602), (lanes, 301)], 2, 0.45 * GB
+    assert step.memory.temp_size_in_bytes < temp
+    text = step.text
+    assert "remat" not in text
+    made = dict(re.findall(r"^\s+(%[\w.\-]+) = (\S+) ", text, re.M))
+    kernels = re.findall(
+        r"^\s+%sorted_row_(?:update|assign)_tiles[\w.\-]* = \S+ custom-call\("
+        r"([^)]*)\)", text, re.M)
+    assert len(kernels) == calls, kernels
+    handed = sorted(
+        made[call.split(", ")[3]].split("{")[0] for call in kernels)
+    assert handed == sorted(f"f32[{n},{lanes_}]" for n, lanes_ in rows_in), handed
+    # no pass of its own over the batch's rows (ops of the entry and of the
+    # rule's loop; what a fusion does inside is the op that makes the rows):
+    # at the physical width only the pull's gather is left, and in cell 13
+    # the rule's read, the combine's zeroed block and its sums
+    ops = "\n".join(
+        body for head, body in re.findall(
+            r"^(?:ENTRY )?(%[\w.\-]+) [^\n]*\{\n(.*?)^\}", text, re.M | re.S)
+        if not head.startswith(("%fused_computation", "%region")))
+    assert "custom-call(" in ops and " fusion(" in ops  # the filter kept them
+    for n in {n for n, _ in rows_in} | {lanes // calls}:
+        assert not re.search(
+            rf"= f32\[{n},({w}|{width})\]\S* (pad|copy)\(", ops), (cell, n)
+    if cell == 13:  # `_zero_masked`'s select over the pushed block, the merge
+        assert not re.search(
+            rf"= f32\[\d+,({w}|{width})\]\S* fusion\([^\n]*jit\(_where\)", ops)
 
-        mesh = make_mesh(4, 1, devices=request.getfixturevalue("topo").devices)
-        logic = mfm.OnlineMatrixFactorization(
-            50_082_603, DIM, updater=mfm.SGDUpdater(5e-5), mesh=mesh)
-        spec = jax.eval_shape(lambda: ShardedParamStore.create(
-            ITEMS, (DIM,), dtype=jnp.float32, mesh=mesh)).spec
 
-        def on(dims, dtype, *axes):
-            return _shape(NamedSharding(mesh, PartitionSpec(*axes)), dims, dtype)
+def test_ft_step_holds_cell_7_s_table_once_beside_three_tile_kernel_calls(
+        ft_step):
+    """Cell 7's step as the chip runs it: the 300-lane row lies flat in
+    three registers (``f32[7038744,384]{1,0:T(8,128)}``, 10.81 GB, the
+    tightest table the system has held), the step updates it in place, its
+    push takes the tile kernel at three registers a row (no refusal) in
+    three calls for its 233,472 lanes, dead ones included, and nothing else
+    yields or copies a table; under 1 GB of temporaries beside it."""
+    assert ft_step.noted == 0
+    mem = ft_step.memory
+    assert mem.alias_size_in_bytes >= 10.81 * GB  # in place
+    assert mem.temp_size_in_bytes < 1.0 * GB  # 0.77 GB here
+    text = ft_step.text
+    entry = text[text.index("ENTRY"):]
+    tables = [
+        line.strip() for line in entry.splitlines()
+        if re.search(r" = f32\[7038744,", line)
+    ]
+    assert len(tables) == 4 and " parameter(" in tables[0], tables
+    for call in tables[1:]:
+        assert call.startswith("%sorted_row_update_tiles"), call
+        assert "custom-call(" in call and "ps.push" in call, call
+        assert "f32[7038744,384]{1,0:T(8,128)}" in call
+    assert " copy(" not in "".join(tables)
+    assert "ps.push/scatter-add" not in text
+    assert "ps.compute/ps.bag_pool" in text and "ps.compute/ps.delta_build" in text
 
-        args = (on((spec.padded_capacity, DIM), jnp.float32, "ps", None),
-                on((logic.state_rows, DIM), jnp.float32, "dp", None),
-                mf_batch(262_144, on))
-    step = make_train_step(logic, spec)
-    assert _step_text_sha(step, *args) == want
-    before_the_mesh_names = {
-        "fm_ps4_cell_4": "7c6eef68c7b9b162", "keyed_mf_cell_8": "47d256f2590f4bc0"}
-    if cell in before_the_mesh_names:
 
-        def without(*a):
-            table, state, outs = step(*a)
-            return table, state, {
-                k: v for k, v in outs.items() if not k.startswith("ps_mesh_")}
-
-        without.__name__ = "step"  # (the module's name is in its text)
-        assert _step_text_sha(without, *args) == before_the_mesh_names[cell]
+@pytest.mark.parametrize("arm", ["kernels", "xla"])
+def test_glove_step_holds_nothing_table_sized_beside_its_table(
+        arm, glove_step):
+    """Cell 13's step at full size for a described v5e: the donated 11.24 GB
+    table is rewritten in place and never copied or transposed, with every
+    scope the cell's metrics read.  As the chip runs it (``kernels``): under
+    ``ps.pull`` ONE gather of whole physical rows ``f32[65536,640]`` (the
+    TPU's gather moves whole rows: a window of the three registers that
+    hold the worker's 301 lanes compiles to a loop over the ids, a slice of
+    the table in front of it to a copy of 6.7 GB, PR 59) cut to the worker's
+    part, ``f32[65536,301]``; under
+    ``ps.push/ps.combine`` the permute of the batch's gradient rows, 301
+    lanes wide, and ONE ``sorted_row_update_tiles`` call into a zeroed
+    ``f32[65536,384]`` block of THREE registers;
+    in the rule's loop ONE gather ``f32[32768,640]`` under ``ps.rule`` and
+    ONE ``sorted_row_assign_tiles`` call on the table, the write-back.  What
+    the step holds beside the table goes with the batch: 0.67 GB.  Off the
+    TPU (``xla``) the same layout with one scatter-add for the sums and one
+    row ``set`` of ``f32[32768,640]`` for the write-back, no kernel."""
+    step = glove_step(arm)
+    mem = step.memory
+    assert 11.24 * GB < mem.alias_size_in_bytes < 11.25 * GB  # in place
+    assert mem.temp_size_in_bytes < 1.0 * GB
+    text = step.text
+    assert not re.search(r"f32\[4392040,640\]\S* (copy|transpose)\(", text)
+    assert "f32[4392034,602]" not in text and "f32[4392040,602]" not in text
+    for scope in ("ps.pull", "ps.compute/ps.cooc_grad_rows", "ps.push/ps.combine",
+                  "ps.push/while/body/ps.rule"):
+        assert scope in text, scope
+    lines = text.splitlines()
+    pulls = [c for c in lines if re.search(r" gather\(", c)
+             and " f32[65536,640]{1,0" in c and "ps.pull" in c]
+    assert len(pulls) == 1 and "slice_sizes={1,640}" in pulls[0], pulls
+    reads = [c for c in lines if re.search(r" gather\(", c)
+             and " f32[32768,640]{1,0" in c]
+    assert len(reads) == 1 and "ps.push/while/body/ps.rule" in reads[0], reads
+    kernels = [line for line in lines if "tpu_custom_call" in line]
+    scatters = [line for line in lines if re.search(r" scatter\(", line)]
+    if arm == "xla":
+        assert not kernels
+        sets = [c for c in scatters if " f32[4392040,640]" in c]
+        assert len(sets) == 1 and "ps.push/while/body" in sets[0], scatters
+        # (the sums at the width pushed, the worker's 301 lanes: no pad in
+        # front since PR 57, no accumulators' lanes since PR 59)
+        sums = [c for c in scatters if " f32[65536,301]" in c]
+        assert len(sums) == 1 and "ps.push/ps.combine" in sums[0], scatters
+        return
+    assert not scatters
+    names = sorted(k.strip().split(" ", 1)[0].rstrip(".0123456789") for k in kernels)
+    assert names == ["%sorted_row_assign_tiles", "%sorted_row_update_tiles"], names
+    by_name = {k.strip().split(".", 1)[0]: k for k in kernels}
+    assert " f32[4392040,640]{1,0" in by_name["%sorted_row_assign_tiles"]
+    assert "ps.push/while/body" in by_name["%sorted_row_assign_tiles"]
+    assert " f32[65536,384]{1,0" in by_name["%sorted_row_update_tiles"]
+    assert "ps.push/ps.combine" in by_name["%sorted_row_update_tiles"]
+    # the worker's part alone crosses: the 602-lane rows are the rule's
+    assert not re.search(r"f32\[(65536|32768,2),60[02]\]", text)
+    assert "f32[65536,301]" in text and "f32[32768,602]" in text
 
 
 def test_w2v_table_is_initialised_in_place_from_a_seed_argument(
@@ -1553,33 +1720,30 @@ def lr():
     return spec, lf.LogisticFTRL()
 
 
-def _lr_step(lr, one_chip):
-    spec, logic = lr
-    return jax.jit(
-        make_train_step(logic, spec), donate_argnums=(0, 1)
-    ).lower(
-        _shape(one_chip, spec.table_shape(), jnp.float32), (),
-        _fm_batch(one_chip),
-    ).compile()
-
-
 @pytest.fixture(scope="module")
-def lr_tpu_step(lr, one_chip):
-    """Cell 6's step at 187,767,412 rows for a described v5e, as the chip
-    runs it (code that asks for the backend still sees the CPU here: it is
-    steered), compiled ONCE for the tests that read it: ``(text, memory
-    analysis, refusals noted on the way)``."""
-    spec, _ = lr
-    with _compiling_for_described_chips("tpu"):
-        n0 = row_update.refusal_count()
-        assert store_mod.arms(spec) == store_mod.Arms(
-            "narrow_distinct", "rule", "", "sort", "tile_set", False)
-        compiled = _lr_step(lr, one_chip)
-        return (compiled.as_text(), compiled.memory_analysis(),
-                row_update.refusal_count() - n0)
+def lr_step(lr, one_chip):
+    """``arm -> CellStep``: cell 6's step at 187,767,412 rows for a described
+    v5e, compiled ONCE an arm for the tests that read it: ``kernels``, as
+    the chip runs it (code that asks for the backend still sees the CPU
+    here: it is steered); ``xla``, the arms every other backend runs."""
+    spec, logic = lr
+
+    @functools.cache
+    def built(arm):
+        backend = BACKEND[arm]
+        if arm == "kernels":
+            with _compiling_for_described_chips(backend):
+                assert store_mod.arms(spec) == store_mod.Arms(
+                    "narrow_distinct", "rule", "", "sort", "tile_set", False)
+        return CellStep(
+            make_train_step(logic, spec),
+            _shape(one_chip, spec.table_shape(), jnp.float32), (),
+            _fm_batch(one_chip), backend=backend)
+
+    return built
 
 
-def test_lr_step_holds_nothing_table_sized_beside_its_table(lr_tpu_step):
+def test_lr_step_holds_nothing_table_sized_beside_its_table(lr_step):
     """Cell 6's step as the chip runs it (asked for the backend, the
     write-back takes ``ops/row_update.sorted_tile_set``): the donated table
     is rewritten in place through the rule arm's loop, the kernel under
@@ -1589,8 +1753,9 @@ def test_lr_step_holds_nothing_table_sized_beside_its_table(lr_tpu_step):
     what the step holds beside the table goes with the batch: 0.04 GB
     (0.105 since PR 70: the distinct rows the pull leaves for the rule and
     the sorts' operands)."""
-    text, mem, noted = lr_tpu_step
-    assert noted == 0
+    step = lr_step("kernels")
+    text, mem = step.text, step.memory
+    assert step.noted == 0
     assert 2.9 * GB < mem.alias_size_in_bytes < 3.1 * GB  # in place, 4 sublanes
     assert mem.temp_size_in_bytes < 0.2 * GB
     rows, lanes = LR_TABLE
@@ -1621,7 +1786,7 @@ def test_lr_step_holds_nothing_table_sized_beside_its_table(lr_tpu_step):
 
 
 def test_lr_step_reads_each_distinct_row_once_for_the_logic_and_the_rule(
-        lr_tpu_step):
+        lr_step):
     """Since PR 70 the pull reads the batch's DISTINCT rows once
     (``core/store._distinct_pull``): no gather yields ``f32[1277952,3]``;
     the two gathers left are a chunk of 32,768 rows each, the pull's inside
@@ -1636,7 +1801,7 @@ def test_lr_step_reads_each_distinct_row_once_for_the_logic_and_the_rule(
     the combine's two untouched, each with the three lanes of the deltas.
     The scopes ``ps.pull``, ``ps.push/ps.combine`` and ``ps.rule`` stand
     round what is left of each."""
-    text, _, _ = lr_tpu_step
+    text = lr_step("kernels").text
     for scope in ("ps.pull", "ps.push/ps.combine",
                   "ps.push/while/body/ps.rule"):
         assert scope in text, scope
@@ -1694,18 +1859,17 @@ def test_lr_step_reads_each_distinct_row_once_for_the_logic_and_the_rule(
     assert len(below(pulls[0]) & gathers) == 1
 
 
-def test_lr_step_off_the_tpu_keeps_xlas_row_set_in_place(
-        lr, one_chip, no_compile_cache):
+def test_lr_step_off_the_tpu_keeps_xlas_row_set_in_place(lr, lr_step):
     """The arm every other backend runs, and a rule store the kernel does
     not take: XLA's row ``set`` of the padded rows into the same 4-lane
     table, in place, nothing table-sized beside it."""
     spec, _ = lr
     assert store_mod.arms(spec).write_back == "xla_set"  # this is a CPU
-    compiled = _lr_step(lr, one_chip)
-    mem = compiled.memory_analysis()
+    step = lr_step("xla")
+    mem = step.memory
     assert 2.9 * GB < mem.alias_size_in_bytes < 3.1 * GB
     assert mem.temp_size_in_bytes < 0.2 * GB
-    text = compiled.as_text()
+    text = step.text
     assert "sorted_row_set_tiles" not in text
     assert not re.search(r"f32\[%d,%d\]\S* copy\(" % LR_TABLE, text)
 
@@ -1824,66 +1988,6 @@ def test_the_capacity_arm_holds_tables_beside_cell_6_s_table(
         assert mem.temp_size_in_bytes > 3.0 * GB  # a table, or more
 
 
-# fastText's released wiki.en model (chipbench/configs/ft-wiki-en-300.json):
-# words, n-gram buckets and output vectors in one store of (300,) rows, and a
-# batch of 4,096 pairs x (bag of 51, context, 5 negatives) = 233,472 lanes
-FT_VOCAB, FT_BUCKETS, FT_DIM, FT_BATCH, FT_BAG = 2_519_370, 2_000_000, 300, 4_096, 51
-
-
-def _ft_cell_step(one_chip, monkeypatch):
-    """Cell 7's step as the chip runs it (the store's own layout, the
-    push's arm chosen as on a TPU), compiled."""
-    from flink_parameter_server_tpu.models import fasttext as ftm
-
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    spec = jax.eval_shape(
-        lambda: ftm.make_store(FT_VOCAB, FT_BUCKETS, FT_DIM, dtype=jnp.float32)
-    ).spec
-    assert spec.layout == "packed" and spec.pack == 1
-    assert spec.table_shape() == (7_038_744, 384)
-    assert store_mod.arms(spec).push == "tile_add"
-    logic = ftm.FastTextSkipGram(0.05, FT_VOCAB, FT_BUCKETS, FT_BAG)
-    batch = {
-        "bag": _shape(one_chip, (FT_BATCH, FT_BAG), jnp.int32),
-        "context": _shape(one_chip, (FT_BATCH,), jnp.int32),
-        "negatives": _shape(one_chip, (FT_BATCH, 5), jnp.int32),
-        "mask": _shape(one_chip, (FT_BATCH,), jnp.bool_),
-    }
-    return jax.jit(
-        make_train_step(logic, spec), donate_argnums=(0, 1)
-    ).lower(_shape(one_chip, spec.table_shape(), jnp.float32), (), batch).compile()
-
-
-def test_ft_step_holds_cell_7_s_table_once_beside_three_tile_kernel_calls(
-        one_chip, no_compile_cache, monkeypatch):
-    """Cell 7's step as the chip runs it: the 300-lane row lies flat in
-    three registers (``f32[7038744,384]{1,0:T(8,128)}``, 10.81 GB, the
-    tightest table the system has held), the step updates it in place, its
-    push takes the tile kernel at three registers a row (no refusal) in
-    three calls for its 233,472 lanes, dead ones included, and nothing else
-    yields or copies a table; under 1 GB of temporaries beside it."""
-    n0 = row_update.refusal_count()
-    compiled = _ft_cell_step(one_chip, monkeypatch)
-    assert row_update.refusal_count() == n0
-    mem = compiled.memory_analysis()
-    assert mem.alias_size_in_bytes >= 10.81 * GB  # in place
-    assert mem.temp_size_in_bytes < 1.0 * GB  # 0.77 GB here
-    text = compiled.as_text()
-    entry = text[text.index("ENTRY"):]
-    tables = [
-        line.strip() for line in entry.splitlines()
-        if re.search(r" = f32\[7038744,", line)
-    ]
-    assert len(tables) == 4 and " parameter(" in tables[0], tables
-    for call in tables[1:]:
-        assert call.startswith("%sorted_row_update_tiles"), call
-        assert "custom-call(" in call and "ps.push" in call, call
-        assert "f32[7038744,384]{1,0:T(8,128)}" in call
-    assert " copy(" not in "".join(tables)
-    assert "ps.push/scatter-add" not in text
-    assert "ps.compute/ps.bag_pool" in text and "ps.compute/ps.delta_build" in text
-
-
 # difacto-criteo-10m (chipbench/configs): cell 9's rule store, 36 f32 lanes a
 # row (w, z, s, c, V[16], S[16]); since PR 47 packed three to a 128-lane
 # physical row, 16,375,440 x 128 f32 = 8.384 GB (dense: rows-minor, 40 sublanes)
@@ -1930,9 +2034,30 @@ def test_difacto_table_is_initialised_in_place_block_by_block(
     assert len(re.findall(r" while\(", compiled.as_text())) >= 1
 
 
+@pytest.fixture(scope="module")
+def difacto_step(difacto, one_chip):
+    """``arm -> CellStep``: cell 9's step at full size for a
+    described v5e, compiled once an arm (``kernels``: as the chip runs it,
+    asked for the backend; ``xla``: what a CPU reads)."""
+    _, rule, model, _, dfm = difacto
+    spec = jax.eval_shape(
+        lambda: dfm.make_store(model, rule, dtype=jnp.float32)
+    ).spec
+
+    @functools.cache
+    def built(arm):
+        return CellStep(
+            make_train_step(dfm.DiFacto(model, rule), spec),
+            _shape(one_chip, spec.table_shape(), jnp.float32), (),
+            _fm_batch(one_chip), backend=BACKEND[arm],
+            spec=spec)
+
+    return built
+
+
 @pytest.mark.parametrize("arm", ["kernels", "xla"])
 def test_difacto_step_holds_nothing_table_sized_beside_its_table(
-        arm, difacto, one_chip, no_compile_cache, monkeypatch):
+        arm, difacto_step, monkeypatch):
     """Cell 9's step at full size for a described v5e, the table packed
     three 36-lane rows to a physical row (``f32[16375440,128]{1,0:T(8,128)}``,
     8.384 GB): the donated table is rewritten in place and never copied or
@@ -1961,10 +2086,8 @@ def test_difacto_step_holds_nothing_table_sized_beside_its_table(
     dead before them).  Off the TPU (``xla``) the same layout with XLA's
     selects, one scatter-add for the sums and one row ``set`` of
     ``f32[32768,128]`` for the write-back, no kernel."""
-    cfg, rule, model, fam, dfm = difacto
-    spec = jax.eval_shape(
-        lambda: dfm.make_store(model, rule, dtype=jnp.float32)
-    ).spec
+    step = difacto_step(arm)
+    spec = step.spec
     assert spec.layout == "packed" and spec.pack == 3 and not spec.narrow_rule
     assert spec.table_shape() == (DF_PHYS_ROWS, 128)
     n = FM_BATCH * FM_FIELDS
@@ -1983,15 +2106,10 @@ def test_difacto_step_holds_nothing_table_sized_beside_its_table(
             spec, pull_lanes=n, fields=FM_FIELDS
         ).pull == "packed_kernel_by_field"
         assert row_update.refusal_count() == n0
-    compiled = jax.jit(
-        make_train_step(dfm.DiFacto(model, rule), spec), donate_argnums=(0, 1)
-    ).lower(
-        _shape(one_chip, spec.table_shape(), jnp.float32), (),
-        _fm_batch(one_chip),
-    ).compile()
-    mem = compiled.memory_analysis()
+    assert step.noted == 0
+    mem = step.memory
     assert 8.38 * GB < mem.alias_size_in_bytes < 8.39 * GB  # in place
-    text = compiled.as_text()
+    text = step.text
     assert not re.search(r"f32\[16375440,128\]\S* (copy|transpose)\(", text)
     assert "f32[49126312,36]" not in text and "f32[16375440,36]" not in text
     for scope in ("ps.pull", "ps.compute/ps.gate", "ps.compute/ps.delta_build",
@@ -2064,17 +2182,15 @@ def test_difacto_step_holds_nothing_table_sized_beside_its_table(
 
 @pytest.fixture(scope="module")
 def difacto_ps4_step(difacto, topo):
-    """``arm -> FourChipStep``: cell 12's step at full size for four
+    """``arm -> CellStep``: cell 12's step at full size for four
     described chips, compiled once an arm (``kernels``: as the chips run it,
     asked for the backend; ``xla``: what a CPU reads)."""
     from flink_parameter_server_tpu.parallel.mesh import make_mesh
 
     cfg, rule, _, fam, dfm = difacto
-    done = {}
 
-    def compiled(arm):
-        if arm in done:
-            return done[arm]
+    @functools.cache
+    def built(arm):
         mesh = make_mesh(1, 4, devices=topo.devices)
         model = dfm.DiFactoConfig(FM_ROWS, 16)  # the 40 M record's rows
         spec = jax.eval_shape(
@@ -2083,8 +2199,9 @@ def difacto_ps4_step(difacto, topo):
         assert spec.layout == "packed" and spec.pack == 3
         assert spec.table_shape() == (4 * 15_647_288, 128)
         assert store_mod.arms(spec).on_shards
-        with _compiling_for_described_chips("tpu" if arm == "kernels" else None):
-            if arm == "kernels":
+        backend = BACKEND[arm]
+        if arm == "kernels":
+            with _compiling_for_described_chips(backend):
                 n0 = row_update.refusal_count()
                 assert store_mod.arms(spec) == store_mod.Arms(
                     "packed_kernel", "rule", "", "row_kernel", "row_set", True)
@@ -2092,13 +2209,12 @@ def difacto_ps4_step(difacto, topo):
                     spec, pull_lanes=FM_BATCH * FM_FIELDS, fields=FM_FIELDS
                 ).pull == "packed_kernel_by_field"
                 assert row_update.refusal_count() == n0
-            done[arm] = _four_chip_step(
-                make_train_step(dfm.DiFacto(model, rule), spec),
-                _shape(spec.sharding(), spec.table_shape(), jnp.float32), (),
-                _fm_batch(NamedSharding(mesh, PartitionSpec())))
-        return done[arm]
+        return CellStep(
+            make_train_step(dfm.DiFacto(model, rule), spec),
+            _shape(spec.sharding(), spec.table_shape(), jnp.float32), (),
+            _fm_batch(NamedSharding(mesh, PartitionSpec())), backend=backend)
 
-    return compiled
+    return built
 
 
 @pytest.mark.parametrize("arm", ["kernels", "xla"])
@@ -2231,8 +2347,31 @@ def test_dlrm_table_is_initialised_in_place_two_rows_to_a_physical_row(
     assert len(re.findall(r" while\(", compiled.as_text())) >= 1
 
 
+@pytest.fixture(scope="module")
+def dlrm_step(dlrm_cell, one_chip):
+    """Cell 10's step at full size for a described v5e as the chip runs it
+    (asked for the backend), compiled ONCE for the tests that read it."""
+    _, model, dlrm = dlrm_cell
+    spec = jax.eval_shape(lambda: dlrm.make_store(model, dtype=jnp.float32)).spec
+    logic = dlrm.DLRM(model)
+    state = {
+        k: _shape(one_chip, v.shape, v.dtype) for k, v in jax.eval_shape(
+            lambda: logic.init_state(jax.random.PRNGKey(0))).items()
+    }
+    batch = {
+        "dense": _shape(one_chip, (FM_BATCH, 13), jnp.float32),
+        "ids": _shape(one_chip, (FM_BATCH, DLRM_FIELDS), jnp.int32),
+        "label": _shape(one_chip, (FM_BATCH,), jnp.float32),
+        "mask": _shape(one_chip, (FM_BATCH,), jnp.bool_),
+    }
+    return CellStep(
+        make_train_step(logic, spec),
+        _shape(one_chip, spec.table_shape(), jnp.float32), state, batch,
+        backend="tpu", spec=spec)
+
+
 def test_dlrm_step_holds_nothing_table_sized_beside_its_12_58_gb_table(
-        dlrm_cell, one_chip, no_compile_cache, monkeypatch):
+        dlrm_cell, dlrm_step, monkeypatch):
     """Cell 10's step at full size for a described v5e, as the chip runs it
     (asked for the backend): the donated table is rewritten in place by the
     tile kernel, nine calls of one shape (851,968 lanes times 8 are under
@@ -2265,8 +2404,7 @@ def test_dlrm_step_holds_nothing_table_sized_beside_its_12_58_gb_table(
     What stands in the crossing's place is one transposing copy each way
     round XLA's batched products, which want ``(B, 27, 64)`` row-major
     (PERF.md section 6, PR 65)."""
-    cfg, model, dlrm = dlrm_cell
-    spec = jax.eval_shape(lambda: dlrm.make_store(model, dtype=jnp.float32)).spec
+    (_, model, dlrm), step, spec = dlrm_cell, dlrm_step, dlrm_step.spec
     assert (spec.layout, spec.pack, spec.update) == ("packed", 2, "add")
     assert spec.table_shape() == (DLRM_PHYS_ROWS, 128)
     n = FM_BATCH * DLRM_FIELDS
@@ -2281,24 +2419,11 @@ def test_dlrm_step_holds_nothing_table_sized_beside_its_12_58_gb_table(
     assert n * store_mod._SERIAL_SCATTER_ROWS_A_LANE < DLRM_PHYS_ROWS
     logic = dlrm.DLRM(model)
     assert logic.for_workers(1).pulls_turned and not logic.pulls_turned
-    state = {
-        k: _shape(one_chip, v.shape, v.dtype) for k, v in jax.eval_shape(
-            lambda: logic.init_state(jax.random.PRNGKey(0))).items()
-    }
-    batch = {
-        "dense": _shape(one_chip, (FM_BATCH, 13), jnp.float32),
-        "ids": _shape(one_chip, (FM_BATCH, DLRM_FIELDS), jnp.int32),
-        "label": _shape(one_chip, (FM_BATCH,), jnp.float32),
-        "mask": _shape(one_chip, (FM_BATCH,), jnp.bool_),
-    }
-    compiled = jax.jit(
-        make_train_step(logic, spec), donate_argnums=(0, 1)
-    ).lower(_shape(one_chip, spec.table_shape(), jnp.float32), state, batch).compile()
-    mem = compiled.memory_analysis()
+    mem = step.memory
     assert 12.57 * GB < mem.alias_size_in_bytes < 12.59 * GB  # in place, state too
     assert mem.temp_size_in_bytes < 2.0 * GB  # 1.82 here
     assert mem.alias_size_in_bytes + mem.temp_size_in_bytes < 14.5 * GB
-    text = compiled.as_text()
+    text = step.text
     assert not re.search(r"f32\[24563152,128\]\S* (copy|transpose)\(", text)
     assert "f32[49126297,64]" not in text and "f32[49126304,64]" not in text
     lines = text.splitlines()
@@ -2356,11 +2481,8 @@ def test_dlrm_step_holds_nothing_table_sized_beside_its_12_58_gb_table(
     assert "f32[94720,128]" in text and "f32[94208,128]" not in text
     assert not re.search(r" scatter\(", text)
     # the step's outputs carry what the plan counted
-    outs = jax.eval_shape(
-        make_train_step(logic, spec),
-        jax.ShapeDtypeStruct(spec.table_shape(), jnp.float32), state, batch)[2]
     assert {"ps_push_kernel_lanes", "ps_push_tile_rows", "ps_slice_kernel",
-            "ps_shift_kernel", "ps_lanes_by_field"} <= set(outs)
+            "ps_shift_kernel", "ps_lanes_by_field"} <= set(step.outs)
     # (the delta build's multiply lies INSIDE the fusion that feeds the shift
     # kernel, which a trace names by its root, under `ps.push`)
     for scope in ("dense_bottom", "dense_interact", "dense_top", "delta_build"):
@@ -2437,10 +2559,10 @@ def dlrm_ps4_step(dlrm_ps4):
             "label": _shape(everywhere, (FM_BATCH,), jnp.float32),
             "mask": _shape(everywhere, (FM_BATCH,), jnp.bool_),
         }
-        return _four_chip_step(
+        return CellStep(
             make_train_step(logic, spec),
             _shape(spec.sharding(), spec.table_shape(), jnp.float32), state,
-            batch)
+            batch, backend="tpu")
 
 
 def _collectives(text):
@@ -2474,7 +2596,8 @@ def test_dlrm_ps4_step_adds_on_the_shard_that_owns_the_row(dlrm_ps4_step):
     Table, MLPs and temporaries are 12.92 GB a chip (13.85 until PR 68),
     under the 15.0 GB that decided one host of two against one of three
     (``reduced_why``)."""
-    text, mem, outs, _ = dlrm_ps4_step
+    text, mem, outs = (
+        dlrm_ps4_step.text, dlrm_ps4_step.memory, dlrm_ps4_step.outs)
     _every_transfer_carries_the_programs_name("cell_16", dlrm_ps4_step)
     n = FM_BATCH * DLRM_FIELDS
     assert n * store_mod._SERIAL_SCATTER_ROWS_A_LANE <= DLRM_PS4_SHARD_ROWS
@@ -2533,7 +2656,7 @@ def test_dlrm_ps4_step_computes_its_dense_net_once(dlrm_ps4_step):
     of theirs is a collective's, so the MLPs are the one-place step's bit
     for bit; the rows' deltas are built on the quarter.  The step's outputs
     say 4 parts."""
-    text, mem, outs, _ = dlrm_ps4_step
+    text, outs = dlrm_ps4_step.text, dlrm_ps4_step.outs
     quarter = FM_BATCH // 4
     assert "ps_compute_parts" in outs
     assert f"f32[{FM_BATCH},{DLRM_FIELDS},128]" not in text
@@ -2578,186 +2701,6 @@ def test_dlrm_ps4_step_computes_its_dense_net_once(dlrm_ps4_step):
     assert f" = f32[{DLRM_FIELDS},{quarter},128]" in build, build
 
 
-# glove-840b-300 (chipbench/configs): cell 13's table and batch
-GLOVE_VOCAB, GLOVE_DIM, GLOVE_BATCH = 2_196_017, 300, 32_768
-GLOVE_PHYS_ROWS = 4_392_040
-
-
-@pytest.fixture(scope="module")
-def glove():
-    from flink_parameter_server_tpu.models import glove as gl
-
-    model = gl.GloVeConfig(GLOVE_VOCAB, GLOVE_DIM)
-    assert model.num_rows == 4_392_034 and model.row_lanes == 602
-    return model, gl
-
-
-def test_glove_table_is_initialised_in_place_from_a_seed_argument(
-        glove, one_chip, no_compile_cache):
-    """4,392,034 x 602 f32 rule rows under a ``jit`` that takes the seed: the
-    11.24 GB table ``f32[4392040,640]`` (a row flat in five registers) is the
-    program's only output, initialised ``core/store._PACK_CHUNK`` rows a loop
-    step: 0.67 GB of temporaries, where a second copy would not fit."""
-    model, gl = glove
-    compiled = jax.jit(lambda s: gl.make_store(model, seed=s).table).lower(
-        _shape(one_chip, (), jnp.uint32)).compile()
-    mem = compiled.memory_analysis()
-    assert mem.output_size_in_bytes == GLOVE_PHYS_ROWS * 640 * 4 == 11_243_622_400
-    assert mem.temp_size_in_bytes < 1.0 * GB
-    assert len(re.findall(r" while\(", compiled.as_text())) >= 1
-
-
-def _glove_cell_step(one_chip, glove, monkeypatch, arm="kernels"):
-    """Cell 13's step, compiled: as the chip runs it (``kernels``: the arms
-    chosen as on a TPU) or off it (``xla``)."""
-    model, gl = glove
-    spec = jax.eval_shape(lambda: gl.make_store(model)).spec
-    assert spec.layout == "packed" and spec.pack == 1
-    assert spec.table_shape() == (GLOVE_PHYS_ROWS, 640)
-    assert store_mod.arms(spec) == store_mod.Arms(
-        "packed_selects", "rule", "", "scatter_add", "xla_set", False)  # a CPU
-    if arm == "kernels":
-        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-        n0 = row_update.refusal_count()
-        assert store_mod.arms(spec) == store_mod.Arms(
-            "packed_selects", "rule", "", "tile_kernel", "tile_assign", False)
-        assert row_update.refusal_count() == n0
-    batch = {
-        "word": _shape(one_chip, (GLOVE_BATCH,), jnp.int32),
-        "context": _shape(one_chip, (GLOVE_BATCH,), jnp.int32),
-        "count": _shape(one_chip, (GLOVE_BATCH,), jnp.float32),
-        "mask": _shape(one_chip, (GLOVE_BATCH,), jnp.bool_),
-    }
-    return jax.jit(
-        make_train_step(gl.GloVe(model), spec), donate_argnums=(0, 1)
-    ).lower(_shape(one_chip, spec.table_shape(), jnp.float32), (), batch).compile()
-
-
-@pytest.mark.parametrize("arm", ["kernels", "xla"])
-def test_glove_step_holds_nothing_table_sized_beside_its_table(
-        arm, glove, one_chip, no_compile_cache, monkeypatch):
-    """Cell 13's step at full size for a described v5e: the donated 11.24 GB
-    table is rewritten in place and never copied or transposed, with every
-    scope the cell's metrics read.  As the chip runs it (``kernels``): under
-    ``ps.pull`` ONE gather of whole physical rows ``f32[65536,640]`` (the
-    TPU's gather moves whole rows: a window of the three registers that
-    hold the worker's 301 lanes compiles to a loop over the ids, a slice of
-    the table in front of it to a copy of 6.7 GB, PR 59) cut to the worker's
-    part, ``f32[65536,301]``; under
-    ``ps.push/ps.combine`` the permute of the batch's gradient rows, 301
-    lanes wide, and ONE ``sorted_row_update_tiles`` call into a zeroed
-    ``f32[65536,384]`` block of THREE registers;
-    in the rule's loop ONE gather ``f32[32768,640]`` under ``ps.rule`` and
-    ONE ``sorted_row_assign_tiles`` call on the table, the write-back.  What
-    the step holds beside the table goes with the batch: 0.67 GB.  Off the
-    TPU (``xla``) the same layout with one scatter-add for the sums and one
-    row ``set`` of ``f32[32768,640]`` for the write-back, no kernel."""
-    compiled = _glove_cell_step(one_chip, glove, monkeypatch, arm)
-    mem = compiled.memory_analysis()
-    assert 11.24 * GB < mem.alias_size_in_bytes < 11.25 * GB  # in place
-    assert mem.temp_size_in_bytes < 1.0 * GB
-    text = compiled.as_text()
-    assert not re.search(r"f32\[4392040,640\]\S* (copy|transpose)\(", text)
-    assert "f32[4392034,602]" not in text and "f32[4392040,602]" not in text
-    for scope in ("ps.pull", "ps.compute/ps.cooc_grad_rows", "ps.push/ps.combine",
-                  "ps.push/while/body/ps.rule"):
-        assert scope in text, scope
-    lines = text.splitlines()
-    pulls = [c for c in lines if re.search(r" gather\(", c)
-             and " f32[65536,640]{1,0" in c and "ps.pull" in c]
-    assert len(pulls) == 1 and "slice_sizes={1,640}" in pulls[0], pulls
-    reads = [c for c in lines if re.search(r" gather\(", c)
-             and " f32[32768,640]{1,0" in c]
-    assert len(reads) == 1 and "ps.push/while/body/ps.rule" in reads[0], reads
-    kernels = [line for line in lines if "tpu_custom_call" in line]
-    scatters = [line for line in lines if re.search(r" scatter\(", line)]
-    if arm == "xla":
-        assert not kernels
-        sets = [c for c in scatters if " f32[4392040,640]" in c]
-        assert len(sets) == 1 and "ps.push/while/body" in sets[0], scatters
-        # (the sums at the width pushed, the worker's 301 lanes: no pad in
-        # front since PR 57, no accumulators' lanes since PR 59)
-        sums = [c for c in scatters if " f32[65536,301]" in c]
-        assert len(sums) == 1 and "ps.push/ps.combine" in sums[0], scatters
-        return
-    assert not scatters
-    names = sorted(k.strip().split(" ", 1)[0].rstrip(".0123456789") for k in kernels)
-    assert names == ["%sorted_row_assign_tiles", "%sorted_row_update_tiles"], names
-    by_name = {k.strip().split(".", 1)[0]: k for k in kernels}
-    assert " f32[4392040,640]{1,0" in by_name["%sorted_row_assign_tiles"]
-    assert "ps.push/while/body" in by_name["%sorted_row_assign_tiles"]
-    assert " f32[65536,384]{1,0" in by_name["%sorted_row_update_tiles"]
-    assert "ps.push/ps.combine" in by_name["%sorted_row_update_tiles"]
-    # the worker's part alone crosses: the 602-lane rows are the rule's
-    assert not re.search(r"f32\[(65536|32768,2),60[02]\]", text)
-    assert "f32[65536,301]" in text and "f32[32768,602]" in text
-
-
-@pytest.mark.parametrize("cell", [5, 7, 13])
-def test_no_pass_over_the_pushed_rows_stands_in_front_of_the_tile_kernel(
-        cell, one_chip, w2v, glove, no_compile_cache, monkeypatch):
-    """The tile kernel takes a wide row at the width the logic left it (PR
-    57): in the compiled steps of cells 5, 7 and 13 every tile-kernel call
-    reads its rows from the op that made them, at their LOGICAL width
-    (``f32[114688,600]``, ``f32[233472,300]``: ONE permute for all the calls
-    of an add push; ``f32[65536,301]``: the combine's permute of the worker's
-    part, PR 59 (602 until then); ``f32[32768,602]``: the rule's own output,
-    whole rows), and nothing between them
-    pads, copies or "compresses" the batch's rows: no ``pad`` to 640 / 384
-    lanes (the parent's ``pad.71/.73``, ``pad.75/.77/.79``, ``pad.38``, 0.37 to
-    0.51 ms each on the v5e), no select over the pushed block in front of a
-    rule's combine (``_zero_masked``), no merge of the new rows into the
-    gathered ones (``fusion.53``), and no ``remat_compressed`` /
-    ``remat_uncompressed`` pair, which is what the compiler makes of three
-    permutes of ``f32[77824,300]`` that lie across cell 7's calls (a copy to
-    ``{0,1}`` and back for two of them, and 0.952 GB of temporaries; of
-    cell 5's ``f32[57344,600]`` too once its table has a tenth more rows:
-    both compiled, PERF.md section 6, PR 57; why an add push narrower than
-    its table permutes once).  The temporaries:
-    cell 7's the parent's 0.767 GB, cell 13's 0.336 (0.437 until PR 59, 0.673
-    until PR 57); cell 5's
-    0.883 for its 0.649 (one 294 MB buffer where two of 147 packed better
-    into the heap; the live bytes are the parent's)."""
-    if cell == 5:
-        compiled = _w2v_cell_step(one_chip, w2v[2], monkeypatch)
-        lanes, w, width = W2V_BATCH * (W2V_NEG + 2), 2 * W2V_DIM, 640
-        rows_in, calls, temp = [(lanes, w)] * 2, 2, 0.90 * GB
-    elif cell == 7:
-        compiled = _ft_cell_step(one_chip, monkeypatch)
-        lanes, w, width = FT_BATCH * (FT_BAG + 6), FT_DIM, 384
-        rows_in, calls, temp = [(lanes, w)] * 3, 3, 0.77 * GB
-    else:
-        compiled = _glove_cell_step(one_chip, glove, monkeypatch)
-        lanes, w, width = 2 * GLOVE_BATCH, "602|301", "640|384"
-        rows_in, calls, temp = [(store_mod._RULE_CHUNK, 602), (lanes, 301)], 2, 0.45 * GB
-    assert compiled.memory_analysis().temp_size_in_bytes < temp
-    text = compiled.as_text()
-    assert "remat" not in text
-    made = dict(re.findall(r"^\s+(%[\w.\-]+) = (\S+) ", text, re.M))
-    kernels = re.findall(
-        r"^\s+%sorted_row_(?:update|assign)_tiles[\w.\-]* = \S+ custom-call\("
-        r"([^)]*)\)", text, re.M)
-    assert len(kernels) == calls, kernels
-    handed = sorted(
-        made[call.split(", ")[3]].split("{")[0] for call in kernels)
-    assert handed == sorted(f"f32[{n},{lanes_}]" for n, lanes_ in rows_in), handed
-    # no pass of its own over the batch's rows (ops of the entry and of the
-    # rule's loop; what a fusion does inside is the op that makes the rows):
-    # at the physical width only the pull's gather is left, and in cell 13
-    # the rule's read, the combine's zeroed block and its sums
-    ops = "\n".join(
-        body for head, body in re.findall(
-            r"^(?:ENTRY )?(%[\w.\-]+) [^\n]*\{\n(.*?)^\}", text, re.M | re.S)
-        if not head.startswith(("%fused_computation", "%region")))
-    assert "custom-call(" in ops and " fusion(" in ops  # the filter kept them
-    for n in {n for n, _ in rows_in} | {lanes // calls}:
-        assert not re.search(
-            rf"= f32\[{n},({w}|{width})\]\S* (pad|copy)\(", ops), (cell, n)
-    if cell == 13:  # `_zero_masked`'s select over the pushed block, the merge
-        assert not re.search(
-            rf"= f32\[\d+,({w}|{width})\]\S* fusion\([^\n]*jit\(_where\)", ops)
-
-
 # pbg-freebase-d100-p16 (chipbench/configs): cell 14's table and batch
 KGE_ROWS, KGE_RELATIONS, KGE_DIM = 15_152_092, 25_291, 100
 KGE_CHUNKS, KGE_CHUNK, KGE_UNIFORM = 800, 50, 50
@@ -2788,15 +2731,12 @@ def test_kge_table_is_initialised_in_place_from_a_seed_argument(
     assert len(re.findall(r" while\(", compiled.as_text())) >= 1
 
 
-def _kge_cell_step(one_chip, kge, monkeypatch, layout):
-    """Cell 14's step, compiled with the arms chosen as on a TPU: as the
+@pytest.fixture(scope="module")
+def kge_step(kge, one_chip):
+    """``layout -> CellStep``: cell 14's step at full size for a described
+    v5e with the arms chosen as on a TPU, compiled once a layout: as the
     chip runs it (``auto``) or with the row left dense (pinned)."""
     model, kg = kge
-    spec = jax.eval_shape(lambda: kg.make_store(model, layout=layout)).spec
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    n0 = row_update.refusal_count()
-    arm = store_mod.arms(spec, pull_lanes=KGE_KEYS, push_lanes=KGE_KEYS)
-    assert row_update.refusal_count() == n0
     logic = kg.ComplExNegatives(model)
     state = jax.tree.map(
         lambda x: _shape(one_chip, x.shape, x.dtype),
@@ -2808,14 +2748,25 @@ def _kge_cell_step(one_chip, kge, monkeypatch, layout):
             ("relation", KGE_CHUNK), ("source_negatives", KGE_UNIFORM),
             ("destination_negatives", KGE_UNIFORM))
     }
-    return spec, arm, jax.jit(
-        make_train_step(logic, spec), donate_argnums=(0, 1)
-    ).lower(_shape(one_chip, spec.table_shape(), jnp.float32), state, batch
-            ).compile()
+
+    @functools.cache
+    def built(layout):
+        spec = jax.eval_shape(
+            lambda: kg.make_store(model, layout=layout)).spec
+        with _compiling_for_described_chips("tpu"):
+            n0 = row_update.refusal_count()
+            arm = store_mod.arms(
+                spec, pull_lanes=KGE_KEYS, push_lanes=KGE_KEYS)
+            assert row_update.refusal_count() == n0
+        return CellStep(
+            make_train_step(logic, spec),
+            _shape(one_chip, spec.table_shape(), jnp.float32), state, batch,
+            backend="tpu", spec=spec, arms=arm)
+
+    return built
 
 
-def test_kge_step_holds_its_one_register_table_once(
-        kge, one_chip, no_compile_cache, monkeypatch):
+def test_kge_step_holds_its_one_register_table_once(kge_step):
     """Cell 14's step at full size for a described v5e, as ``"auto"`` lays a
     rule row of 101 lanes (PR 61): PACKED, one row to one 128-lane register,
     ``f32[15152096,128]{1,0}``.  The donated 7.76 GB table is rewritten in
@@ -2829,15 +2780,16 @@ def test_kge_step_holds_its_one_register_table_once(
     the table, the write-back; no XLA scatter touches the table.  The
     chunked scores are batched ``50 x 100 x 100`` products under the logic's
     scopes, the written-out backward pass too.  Beside the table: 0.44 GB."""
-    spec, arm, compiled = _kge_cell_step(one_chip, kge, monkeypatch, "auto")
+    step = kge_step("auto")
+    spec, arm = step.spec, step.arms
     assert spec.layout == "packed" and spec.pack == 1
     assert spec.table_shape() == (KGE_PHYS_ROWS, 128)
     assert arm == store_mod.Arms(
         "packed_selects", "rule", "", "row_kernel", "row_set", False)
-    mem = compiled.memory_analysis()
+    mem = step.memory
     assert 7.75 * GB < mem.alias_size_in_bytes < 7.81 * GB  # in place
     assert mem.temp_size_in_bytes < 0.6 * GB
-    text = compiled.as_text()
+    text = step.text
     assert not re.search(r"f32\[15152096,128\]\S* (copy|transpose)\(", text)
     assert "f32[15152096,101]" not in text and "f32[15152092," not in text
     for scope in ("ps.pull", "ps.compute/ps.kge_operator/",
@@ -2867,19 +2819,19 @@ def test_kge_step_holds_its_one_register_table_once(
     assert "ps.kge_operator_update" in scatters[0]
 
 
-def test_kge_step_with_its_row_left_dense_copies_the_whole_table(
-        kge, one_chip, no_compile_cache, monkeypatch):
+def test_kge_step_with_its_row_left_dense_copies_the_whole_table(kge_step):
     """Why ``"auto"`` packs a rule row of 65 to 127 lanes: pinned dense, the
     TPU hands the step its ``f32[15152096,101]`` table capacity-minor
     (``{0,1}``) and the step copies it WHOLE to a row-major one for its
     gathers and back after XLA's row ``set``: 7.9 GB of temporaries beside
     a table that the chip pads to 7.76 GB all the same; they do not fit."""
-    spec, arm, compiled = _kge_cell_step(one_chip, kge, monkeypatch, "dense")
+    step = kge_step("dense")
+    spec, arm = step.spec, step.arms
     assert spec.layout == "dense" and spec.table_shape() == (KGE_PHYS_ROWS, 101)
     assert (arm.pull, arm.combine, arm.write_back) == (
         "take", "row_kernel", "xla_set")
-    assert compiled.memory_analysis().temp_size_in_bytes > 6.0 * GB
-    text = compiled.as_text()
+    assert step.memory.temp_size_in_bytes > 6.0 * GB
+    text = step.text
     assert len(re.findall(r"f32\[15152096,101\]\S* copy\(", text)) >= 2
     assert re.search(r"f32\[15152096,101\]\{0,1", text)
 
@@ -2920,8 +2872,34 @@ def test_dcn_table_is_initialised_in_place_from_a_seed_argument(
     assert len(re.findall(r" while\(", compiled.as_text())) >= 1
 
 
-def test_dcn_step_fits_beside_its_two_register_table(
-        dcn, one_chip, no_compile_cache, monkeypatch):
+@pytest.fixture(scope="module")
+def dcn_step(dcn, one_chip):
+    """Cell 15's step at full size for a described v5e as the chip runs it
+    (asked for the backend), compiled ONCE for the tests that read it."""
+    model, dc = dcn
+    spec = jax.eval_shape(lambda: dc.make_store(model)).spec
+    with _compiling_for_described_chips("tpu"):
+        n0 = row_update.refusal_count()
+        arm = store_mod.arms(spec, pull_lanes=DCN_KEYS, push_lanes=DCN_KEYS)
+        assert row_update.refusal_count() == n0
+    logic = dc.DLRMDCNv2(model)
+    state = jax.tree.map(
+        lambda x: _shape(one_chip, x.shape, x.dtype),
+        jax.eval_shape(lambda: logic.init_state(jax.random.PRNGKey(0))))
+    assert sum(x.size for x in jax.tree.leaves(state)) == 2 * 16_044_545
+    batch = {
+        "dense": _shape(one_chip, (DCN_BATCH, 13), jnp.float32),
+        "ids": _shape(one_chip, (DCN_BATCH, 214), jnp.int32),
+        "label": _shape(one_chip, (DCN_BATCH,), jnp.float32),
+        "mask": _shape(one_chip, (DCN_BATCH,), jnp.bool_),
+    }
+    return CellStep(
+        make_train_step(logic, spec),
+        _shape(one_chip, spec.table_shape(), jnp.float32), state, batch,
+        backend="tpu", spec=spec, arms=arm)
+
+
+def test_dcn_step_fits_beside_its_two_register_table(dcn_step):
     """Cell 15's step at full size for a described v5e, as ``"auto"`` lays a
     rule row of 256 lanes: PACKED at ``k`` = 1, flat in two whole registers,
     ``f32[6380784,256]{1,0}``.  The donated 6.53 GB table (and the dense
@@ -2935,35 +2913,15 @@ def test_dcn_step_fits_beside_its_two_register_table(
     ``sorted_row_assign_tiles`` call on the table, the write-back; no XLA
     scatter touches the table.  The dense net's products are under the
     logic's scopes, the written-out backward pass too."""
-    model, dc = dcn
-    spec = jax.eval_shape(lambda: dc.make_store(model)).spec
+    step, spec, arm = dcn_step, dcn_step.spec, dcn_step.arms
     assert spec.layout == "packed" and spec.pack == 1
     assert spec.table_shape() == (DCN_PHYS_ROWS, 256) and spec.worker_width == 128
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    n0 = row_update.refusal_count()
-    arm = store_mod.arms(spec, pull_lanes=DCN_KEYS, push_lanes=DCN_KEYS)
-    assert row_update.refusal_count() == n0
     assert arm == store_mod.Arms(
         "packed_selects", "rule", "", "row_kernel", "tile_assign", False)
-    logic = dc.DLRMDCNv2(model)
-    state = jax.tree.map(
-        lambda x: _shape(one_chip, x.shape, x.dtype),
-        jax.eval_shape(lambda: logic.init_state(jax.random.PRNGKey(0))))
-    assert sum(x.size for x in jax.tree.leaves(state)) == 2 * 16_044_545
-    batch = {
-        "dense": _shape(one_chip, (DCN_BATCH, 13), jnp.float32),
-        "ids": _shape(one_chip, (DCN_BATCH, 214), jnp.int32),
-        "label": _shape(one_chip, (DCN_BATCH,), jnp.float32),
-        "mask": _shape(one_chip, (DCN_BATCH,), jnp.bool_),
-    }
-    compiled = jax.jit(
-        make_train_step(logic, spec), donate_argnums=(0, 1)
-    ).lower(_shape(one_chip, spec.table_shape(), jnp.float32), state, batch
-            ).compile()
-    mem = compiled.memory_analysis()
+    mem = step.memory
     assert 6.65 * GB < mem.alias_size_in_bytes < 6.68 * GB  # in place
     assert mem.temp_size_in_bytes < 0.9 * GB
-    text = compiled.as_text()
+    text = step.text
     assert not re.search(r"f32\[6380784,256\]\S* (copy|transpose)\(", text)
     assert "f32[6380781," not in text
     for scope in ("ps.pull", "ps.compute/ps.bag_pool/",
@@ -2998,7 +2956,7 @@ WDL_BATCH, WDL_FIELDS = 32_768, 26
 @pytest.fixture(scope="module")
 def wdl_tpu_step(one_chip):
     """Cell 17's step over its TWO stores at full size for a described v5e,
-    as the chip runs it, compiled ONCE: ``(text, memory analysis)``."""
+    as the chip runs it, compiled ONCE for the tests that read it."""
     from chipbench import spec as bench_spec
     from flink_parameter_server_tpu.models import wide_deep as wd
 
@@ -3036,10 +2994,8 @@ def wdl_tpu_step(one_chip):
             "label": _shape(one_chip, (WDL_BATCH,), jnp.float32),
             "mask": _shape(one_chip, (WDL_BATCH,), jnp.bool_),
         }
-        compiled = jax.jit(
-            make_train_step(logic, spec), donate_argnums=(0, 1)
-        ).lower(tables, state, batch).compile()
-        return compiled.as_text(), compiled.memory_analysis()
+        return CellStep(
+            make_train_step(logic, spec), tables, state, batch, backend="tpu")
 
 
 def test_the_two_store_step_rewrites_both_tables_in_place(wdl_tpu_step):
@@ -3048,7 +3004,7 @@ def test_the_two_store_step_rewrites_both_tables_in_place(wdl_tpu_step):
     of each table exactly ONE op yields a table: its write-back's kernel in
     its rule's loop, under its own store's label.  Nothing copies,
     transposes or scatters either table."""
-    text, mem = wdl_tpu_step
+    text, mem = wdl_tpu_step.text, wdl_tpu_step.memory
     assert 13.0 * GB < mem.alias_size_in_bytes < 13.05 * GB
     # PR 71's step held 0.934 GB; the set kernel's spans (PR 72) live in
     # VMEM (three buffers of 4,112 tiles, 25 MB) and its plan's scans are a
@@ -3083,7 +3039,7 @@ def test_the_two_store_step_holds_one_gather_and_one_write_back_a_store(
     combine's row sums, the two write-backs.  Four loops: the wide pull's
     and rule's, the deep combine's and rule's.  The hash stands under its
     own scope in front of the pulls, the net under the logic's."""
-    text, _ = wdl_tpu_step
+    text = wdl_tpu_step.text
     lines = text.splitlines()
     gathers = [line for line in lines if re.search(r" gather\(", line)]
     lanes = WDL_BATCH * WDL_FIELDS
@@ -3115,3 +3071,201 @@ def test_the_two_store_step_holds_one_gather_and_one_write_back_a_store(
     assert not [
         line for line in lines if " convolution(" in line
         and "ps.delta_build" in line]
+
+
+def _step_text_sha(lowered_text):
+    """sha256 of a step's lowered text, which carries no locations but in
+    its Mosaic bodies: each stands as the hash of its print without them, so
+    the text is the same from any checkout and a kernel that moves moves
+    it."""
+    import hashlib
+
+    text = MOSAIC_BODY.sub(lambda m: _body_sha(m.group(0)), lowered_text)
+    return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+
+def _lowered_off_the_tpu(step, *args):
+    return jax.jit(step, donate_argnums=(0, 1)).lower(*args).as_text()
+
+
+# every cell's step at full size AS THE CHIP RUNS IT (lowered for described
+# chips, asked for the backend, the kernels in it): name -> the fixture that
+# holds it, the key the fixture is asked for, and the hash of its text.  Read
+# from the fixtures' lowered text: no compile, and a lowering (0.2-2 s) only
+# where the worker has not built the fixture for a neighbour.
+ON_THE_CHIP = {
+    "mf_cells_1_3_11_on_a_tpu": (
+        "mf_tpu_step", (BATCH,), "d3dfc5c70ddec5b1"),
+    "fm_cell_2_on_a_tpu": (
+        "fm_step", ("cell_2", "kernels"), "54af7aba7d6f5d8a"),
+    "fm_ps4_cell_4_on_four_tpus": (
+        "fm_step", ("cell_4", "kernels"), "831429aecc471fca"),
+    "w2v_cell_5_on_a_tpu": (
+        "w2v_step", ("kernels",), "16f834160caf82d7"),
+    "lr_cell_6_on_a_tpu": (
+        "lr_step", ("kernels",), "4316e6f312ec73b7"),
+    "ft_cell_7_on_a_tpu": (
+        "ft_step", None, "714998ad7272db94"),
+    "keyed_mf_cell_8_on_four_tpus": (
+        "mf_dp4_step", ("whole_on_every_chip",), "4c18ed8a28ba52c5"),
+    "difacto_cell_9_on_a_tpu": (
+        "difacto_step", ("kernels",), "303f9a9ea4e1a64e"),
+    "dlrm_cell_10_on_a_tpu": (
+        "dlrm_step", None, "0ea613ea9d5a0e78"),
+    "difacto_ps4_cell_12_on_four_tpus": (
+        "difacto_ps4_step", ("kernels",), "cd80a9212976c0a2"),
+    "glove_cell_13_on_a_tpu": (
+        "glove_step", ("kernels",), "04a7f054e433a5bc"),
+    "kge_cell_14_on_a_tpu": (
+        "kge_step", ("auto",), "572a5cfb332f1ff5"),
+    "dcn_cell_15_on_a_tpu": (
+        "dcn_step", None, "e0bb481e6420de82"),
+    "dlrm_ps4_cell_16_on_four_tpus": (
+        "dlrm_ps4_step", None, "c1ca125a2e7b3fc2"),
+    "wdl_cell_17_on_a_tpu": (
+        "wdl_tpu_step", None, "6c8f66f09bc7e7b3"),
+}
+
+
+@pytest.mark.parametrize("cell, want", [
+    ("mf_cells_1_and_3", "467449ddc73eac39"),
+    ("fm_cell_2", "715a8a5e1325631a"),
+    ("fm_ps4_cell_4", "b1244432ae0312eb"),
+    ("lr_cell_6", "f669bf2e1dddf416"),
+    ("keyed_mf_cell_8", "878ead5a803a932d"),
+] + [(cell, held[2]) for cell, held in ON_THE_CHIP.items()])
+def test_every_cells_step_text_is_the_parents(cell, want, request):
+    """The lowered text of a step carries no locations, so a change that
+    traces the same ops gives the same text: cells 1 and 3 (MF, dense 128
+    lanes) and cell 2 (FM, seven 17-lane rows to a 128-lane row) run the
+    step PR 30 to PR 32 ran (PERF.md section 6 records both hashes).  A
+    change that means to move them brings its own: PR 42 gave FM's step the
+    scalar ``ps_slice_kernel`` (which arm sliced the pulled rows; lowered
+    here, off the TPU, the arm and every other op are the parent's:
+    ``3913e9e902cfade8`` until then).  Since PR 46 also cell 4 (FM packed
+    under ``ps = 4``), cell 6 (the narrow rule store: its combine, its
+    counts and its outputs are what they were, no new count among them) and
+    cell 8 (MF under four keyed workers), each as PR 46's parent lowers it
+    here: that PR changed the combine of WIDE rule rows, which none of the
+    five traces.  PR 51 gave the two FM steps one scalar more,
+    ``ps_shift_kernel`` (which arm shifted the pushed deltas: here, off the
+    TPU, a constant 0 and the parent's ops to the letter; ``62cb492a1f6ca10e``
+    and ``0d16cc6091cddad1`` until then); the other three did not move.
+    PR 57 took ``_zero_masked`` out of a RULE store's push (a masked lane goes
+    to the sentinel in ``_push_rule``; its delta reaches no kept row): cell
+    6's text lost the mask's ``reshape`` to ``(1277952, 1)``, a zero
+    ``broadcast_in_dim`` and the ``_where`` over ``f32[1277952,3]``, nothing
+    else (``aab60546ac40ed64`` until then); the four add stores kept theirs.
+    PR 63 moved the two FM steps and meant to: the copy of their logic that
+    a step in one place traces computes field-major, takes its rows turned
+    (off the TPU, as here, the pull's answer with its axes swapped) and
+    pushes ``(K, B)`` lanes (``models/factorization_machine.FieldLanes``;
+    ``bc06381bf02bcde5`` and ``db02bf3de22f8a3e`` until then); the other
+    three did not move, nor did
+    the step of any cell that runs neither FM logic (every cell's lowered
+    step at full size for a described v5e, hashed on both trees: PERF.md
+    section 6, PR 63).  PR 65 gave the step of every store packed several
+    rows to a physical row one scalar more, ``ps_lanes_by_field`` (whether
+    its lane kernels move the batch a field at a time: here, off the TPU, a
+    constant 0 behind the parent's ops to the letter; ``1a01b65060f0886d``
+    and ``d240e3e5008700fb`` until then; at full size for a described v5e
+    cells 2, 4, 9 and 12 hash equal to the parent's with that output left
+    out, every other cell but cell 10 as it stands: PERF.md section 6, PR
+    65); the other three did not move.  PR 67 (an add store's push on the
+    shards that own its rows, the tile kernel's calls rolled into a loop
+    there, DLRM's init one program) moved none of the five, and at full
+    size for a described v5e every one of cells 1-15 hashes equal on its
+    parent and on its tree (the sixteen hashes: PERF.md section 6, PR 67).
+    PR 68 (the minibatch's compute split over the servers' own axis where a
+    logic declares ``example_blocks`` under one worker group and ``ps`` > 1:
+    cell 16 alone) moved none of the five: a step that computes the whole
+    minibatch in every place hands out no new output and names no
+    constraint, and at full size for a described v5e every one of cells
+    1-15 BUT CELL 10 hashes equal on its parent and on its tree; cell 10's
+    text moved and was meant to (its dense gradients are summed in the four
+    blocks the chips of cell 16 hold, so that the MLPs do not depend on
+    ``ps``: PERF.md section 6, PR 68).  PR 69 gave the two steps under a
+    mesh one scalar more, ``ps_mesh_kib`` (what their ``mesh.*`` sites say
+    crosses between chips, a constant of the
+    trace): with that output left out their text is the parent's to
+    the letter (``7c6eef68c7b9b162`` and ``47d256f2590f4bc0``, held below:
+    the names themselves are locations, which this text does not carry);
+    the three steps in one place did not move.  PR 70 (a narrow rule
+    store pulls a batch's distinct rows once: cell 6 on a TPU, the arm
+    ``narrow_distinct``) moved none of the five, cell 6's included: the arm
+    is taken on a TPU alone, and lowered here, off it, the step calls
+    ``pull_counted`` for ``pull`` and traces the parent's ops to the letter;
+    the step as the chip runs it is held by
+    ``test_lr_step_reads_each_distinct_row_once_for_the_logic_and_the_rule``
+    (compiled for a described v5e), and every other cell's step hashes
+    equal on the parent and on the tree at full size for a described v5e
+    (PERF.md section 6, PR 70).
+    PR 73 (the tests' clock; the explicit-collectives module that no step
+    called, deleted) moved none of the five and renamed this test: it now
+    also holds EVERY cell's step at full size as the chip runs it (the
+    ``..._on_a_tpu`` / ``..._on_four_tpus`` cases, ``ON_THE_CHIP``: what
+    every PR since PR 63 hashed by hand in a scratch script), read from the
+    lowered text of the fixtures that compile those steps, a Mosaic body
+    standing as the hash of its print without locations; each as PR 73's
+    parent lowers it.  Cell 11 runs cells 1 and 3's step."""
+    if cell in ON_THE_CHIP:
+        fixture, key, _ = ON_THE_CHIP[cell]
+        held = request.getfixturevalue(fixture)
+        step = held(*key) if key is not None else held
+        assert step.noted == 0
+        assert _step_text_sha(step.lowered) == want
+        return
+    shape = jax.ShapeDtypeStruct
+
+    def mf_batch(n, on=shape):
+        return {"user": on((n,), jnp.int32), "item": on((n,), jnp.int32),
+                "rating": on((n,), jnp.float32), "mask": on((n,), jnp.bool_)}
+
+    if cell == "mf_cells_1_and_3":
+        logic = mfm.OnlineMatrixFactorization(
+            USERS, DIM, updater=mfm.SGDUpdater(2e-4))
+        spec = jax.eval_shape(
+            lambda: ShardedParamStore.create(ITEMS, (DIM,), dtype=jnp.float32)
+        ).spec
+        args = (shape((spec.padded_capacity, DIM), jnp.float32),
+                shape((USERS, DIM), jnp.float32), mf_batch(BATCH))
+    elif cell == "fm_cell_2":
+        spec, logic = request.getfixturevalue("fm1")
+        batch = {k: shape(v.shape, v.dtype) for k, v in _fm_batch(None).items()}
+        args = (shape(spec.table_shape(), jnp.float32), (), batch)
+    elif cell == "fm_ps4_cell_4":
+        mesh, spec, logic = request.getfixturevalue("ps4")
+        args = (_shape(spec.sharding(), spec.table_shape(), jnp.float32), (),
+                _fm_batch(NamedSharding(mesh, PartitionSpec())))
+    elif cell == "lr_cell_6":
+        spec, logic = request.getfixturevalue("lr")
+        args = (shape(spec.table_shape(), jnp.float32), (), _fm_batch(None))
+    else:
+        from flink_parameter_server_tpu.parallel.mesh import make_mesh
+
+        mesh = make_mesh(4, 1, devices=request.getfixturevalue("topo").devices)
+        logic = mfm.OnlineMatrixFactorization(
+            50_082_603, DIM, updater=mfm.SGDUpdater(5e-5), mesh=mesh)
+        spec = jax.eval_shape(lambda: ShardedParamStore.create(
+            ITEMS, (DIM,), dtype=jnp.float32, mesh=mesh)).spec
+
+        def on(dims, dtype, *axes):
+            return _shape(NamedSharding(mesh, PartitionSpec(*axes)), dims, dtype)
+
+        args = (on((spec.padded_capacity, DIM), jnp.float32, "ps", None),
+                on((logic.state_rows, DIM), jnp.float32, "dp", None),
+                mf_batch(262_144, on))
+    step = make_train_step(logic, spec)
+    assert _step_text_sha(_lowered_off_the_tpu(step, *args)) == want
+    before_the_mesh_names = {
+        "fm_ps4_cell_4": "7c6eef68c7b9b162", "keyed_mf_cell_8": "47d256f2590f4bc0"}
+    if cell in before_the_mesh_names:
+
+        def without(*a):
+            table, state, outs = step(*a)
+            return table, state, {
+                k: v for k, v in outs.items() if not k.startswith("ps_mesh_")}
+
+        without.__name__ = "step"  # (the module's name is in its text)
+        assert _step_text_sha(
+            _lowered_off_the_tpu(without, *args)) == before_the_mesh_names[cell]
