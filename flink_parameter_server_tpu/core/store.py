@@ -1618,12 +1618,19 @@ def publish_counts(outs: dict, registry: Any, total, peak) -> None:
             "store_packed_by_field", component="train"
         ).set(peak(outs["ps_lanes_by_field"]))
     if "ps_push_tile_rows" in outs:
+        lanes = total(outs["ps_push_kernel_lanes"])
+        tile_rows = total(outs["ps_push_tile_rows"])
         registry.gauge(
             "store_push_kernel_lanes", component="train"
-        ).set(total(outs["ps_push_kernel_lanes"]))
+        ).set(lanes)
         registry.gauge(
             "store_push_tile_rows", component="train"
-        ).set(total(outs["ps_push_tile_rows"]))
+        ).set(tile_rows)
+        # how many lanes share a tile row's copies (and its one load into
+        # registers where eight of them lie side by side)
+        registry.gauge(
+            "store_tile_lanes_per_row", component="train"
+        ).set(lanes / max(tile_rows, 1))
     if "ps_push_lanes_max_shard" in outs:
         registry.gauge(
             "store_push_lanes_max_shard", component="train"

@@ -175,6 +175,14 @@ MAX_LANES = 98_304
 # kernel by default, and well under the v5e's 128
 _TILE_VMEM_BYTES = 64 * 2**20
 _INT32_MAX = jnp.iinfo(jnp.int32).max
+# the tile ADD kernel holds a tile row in registers in the blocks where at
+# least one in so many trips of eight sorted lanes lies in one tile row
+_RUN_TRIPS_SHARE = 3
+# and at the rows' widths, in 128-lane registers, at which the v5e has priced
+# that and it won (`_tile_kernel`): one (cell 10) and five (cell 5).  At three
+# lane by lane is ~6 ns a lane and the held row lost (cell 13 -0.64 %, cell 7
+# nothing); no cell adds rows of another width
+_HELD_ROW_REGISTERS = (1, 5)
 
 
 def refusal(row: Tuple[int, ...], dtype) -> Optional[str]:
@@ -1062,9 +1070,11 @@ def _tile_kernel(tiles_ref, words_ref, counts_ref, dl_ref, table_ref, out_ref,
       ascending, each once.
     words_ref: (N,) int32 SMEM — per kept lane, its tile row's place in
       the block's list (bits 0-7) and its row's sublane in the tile (8-10).
-    counts_ref: (3 N / block + 18,) int32 SMEM — per block, how many tile
-      rows, how many kept lanes, and 1 where its first tile row is carried
-      over from the block before; blocks -3 to -1 and the three after the
+    counts_ref: (5 N / block + 30,) int32 SMEM — per block, how many tile
+      rows, how many kept lanes, 1 where its first tile row is carried over
+      from the block before, and two words of a bit a trip of eight lanes:
+      where the trip's eight lie in ONE tile row, and where the trip before
+      does too and in the same one; blocks -3 to -1 and the three after the
       last are zeros.
     dl_ref: (block, w) f32 VMEM — the deltas of block ``g - 1``, sorted, at
       the width ``w`` <= ``W`` their caller holds them (a logical row of 600
@@ -1075,21 +1085,61 @@ def _tile_kernel(tiles_ref, words_ref, counts_ref, dl_ref, table_ref, out_ref,
       being read, one being added to, one being written back.
     sem: (2, 3) DMA semaphores — reads and writes of each slot.
 
-    What its time is made of on the v5e (PERF.md section 6, PR 41), all of
-    it issued by the one scalar core, one thing after the other: a DMA
-    descriptor 17 ns eight a trip (22 one a trip), two a tile row; an add
-    ~6 ns a lane at three registers a row, 11.6 at five (20.8 one a trip);
-    a wait 6.5 ns, one for sixteen tile rows.  The copies themselves run
-    under the adds: the three slots hide their latency, not their issue.
-    The body is short on purpose (ten loops, one branch, every index a
-    ``lax`` equation or two and nothing computed twice): every process that
-    runs the step traces it and lowers it, each unrolled copy by itself,
-    warm cache or not.
+    The adds (PR 74), where rows are ADDED and are as wide as
+    :func:`_holds_tile_rows` takes (one register or five: any other width,
+    and ``assign``, keep the walk lane by lane of the last paragraph but
+    one).  Ids ascend, so the lanes of one tile row lie side by
+    side, and a block's lanes are walked eight at a time.  Where eight lie
+    in ONE tile row (the plan says which trips do: ``counts_ref``), that
+    tile row is LOADED once into registers, the eight lanes are added to
+    that value, each to its sublane by a select against a sublane iota (a
+    lane's delta, broadcast to the eight sublanes, is added where the iota
+    names the lane's row: the same float32 add on the same operands as
+    before, in the same order, so every row's sum keeps its bits), the
+    following trips too for as long as they lie in that tile row, and it is
+    STORED once.  Before, every lane loaded its row of the tile, added and
+    stored it, and the next lane's load, at an address the compiler cannot
+    tell from the store's, waited for that store: a hot row's run was one
+    chain of load, add and store from end to end.  Any other eight lanes
+    are added one by one as they were, and a block with no such trip, or
+    with fewer than one in ``_RUN_TRIPS_SHARE`` (the plan clears its bits),
+    is walked by the old loop alone and pays no branch a trip.
+
+    What its time is made of on the v5e (PERF.md section 6, PRs 41 and 74),
+    all of it issued by the one scalar core, one thing after the other: a
+    DMA descriptor 13-17 ns eight a trip (22 one a trip), two a tile row:
+    22-27 ns a tile row at one register, 65 at five (19 bundles of the
+    compiled kernel a descriptor, its bounds checks among them: the scalar
+    core's two slots a bundle are what is full); an add one lane at a time
+    9.1-9.4 ns a lane at one register a row (seven bundles, and as long
+    again waiting for the store before it), ~6 at three, 11.6 at five
+    (20.8 one a trip); **an add to a tile row held in registers 5.0 ns a
+    lane at one register** (39 bundles a trip of eight: 851,968 lanes in
+    runs of 64 read 4.62 ms where lane by lane read 8.12), ~6.7 at five
+    (cell 5's ids: 2.857 -> 2.548 ms for the 55 % of its lanes that lie in
+    such trips), and at three DEARER than lane by lane, which is cheapest
+    there (cell 7's ids, 22 % of the lanes: 3.16 -> 3.15-3.19 ms; cell 13,
+    whose combine adds dense ranks, nearly every lane: -0.64 % end to end),
+    so three registers keep the old loop; a wait 6.5 ns, one for sixteen
+    tile rows.
+    The copies themselves run under the adds: the three slots hide their
+    latency, not their issue.  The walk by TILE ROW that ISSUE 74 set out
+    (a loop a tile row over its lanes, the tile row held from its first
+    lane to its last) read 18.6-22.2 ms on cell 10's ids where this reads
+    11.78 and the old loop 14.10: five tile rows in six hold ONE lane, and
+    a loop a tile row is 40 bundles and two taken branches before its
+    first add.  The body is short on purpose (every index a ``lax``
+    equation or two and nothing computed twice, the eight lanes of a trip
+    a loop unrolled where the kernel is lowered): every process that runs
+    the step traces it and lowers it, each unrolled copy by itself, warm
+    cache or not (cell 10's nine calls: 0.18 -> 0.25 s in this sandbox).
 
     ``assign``: a kept lane's row REPLACES its row of the tile instead of
     being added to it (:func:`sorted_tile_assign`, a rule store's wide
-    write-back): the same walk, the same copies, one store a lane and no
-    load; the tile's other rows go back as they were read.
+    write-back): the same copies, the walk lane by lane, one store a lane
+    and no load (distinct ids: a lane a row, 1.05-1.2 a tile row in cells
+    13 and 15, nothing to hold); the tile's other rows go back as they
+    were read.
 
     Either body touches lanes ``[0, w)`` of a lane's row of the tile and
     no other: lanes ``[w, W)`` of every row, an assigned one's too, are
@@ -1104,15 +1154,15 @@ def _tile_kernel(tiles_ref, words_ref, counts_ref, dl_ref, table_ref, out_ref,
     del table_ref  # aliased to out_ref
     g = pl.program_id(0)
     reads, writes = 0, 1
-    counts = lax.mul(g, 3)  # where block g - 3's three counts lie
+    counts = lax.mul(g, 5)  # where block g - 3's five counts lie
 
-    def count(offset):  # blocks g - 3 (offsets 0-2) to g (offsets 9-11)
+    def count(offset):  # blocks g - 3 (offsets 0-4) to g (offsets 15-19)
         return counts_ref[lax.add(counts, offset)]
 
     ahead = lax.rem(g, 3)  # the slot of block g, and of block g - 3
     slot = lax.rem(lax.add(g, 2), 3)  # the slot of block g - 1
-    opened, kept = count(6), count(7)  # of block g - 1
-    skip = count(11)  # 1 where block g's first tile row is carried over
+    opened, kept = count(10), count(11)  # of block g - 1
+    skip = count(17)  # 1 where block g's first tile row is carried over
     stretch = lax.mul(g, block)  # block g's stretch of tiles_ref
     base = lax.sub(stretch, block)  # block g - 1's, and of words_ref
 
@@ -1137,7 +1187,7 @@ def _tile_kernel(tiles_ref, words_ref, counts_ref, dl_ref, table_ref, out_ref,
 
     # block g reads into the slot the third block before wrote from: every
     # tile row of that block but a last one that block g - 2 carried on
-    await_copies(lax.sub(count(0), count(5)), writes, ahead)
+    await_copies(lax.sub(count(0), count(7)), writes, ahead)
     first = lax.add(stretch, skip)
 
     def read(j):  # every tile row but a carried first one
@@ -1146,8 +1196,8 @@ def _tile_kernel(tiles_ref, words_ref, counts_ref, dl_ref, table_ref, out_ref,
             sem.at[reads, ahead],
         ).start()
 
-    _each(lax.sub(count(9), skip), read)
-    await_copies(lax.sub(opened, count(8)), reads, slot)
+    _each(lax.sub(count(15), skip), read)
+    await_copies(lax.sub(opened, count(12)), reads, slot)
 
     def add(lane):
         # one float32 add a lane, in the order of the batch: what XLA's
@@ -1160,7 +1210,71 @@ def _tile_kernel(tiles_ref, words_ref, counts_ref, dl_ref, table_ref, out_ref,
         else:
             tile_buf[at] = lax.add(tile_buf[at], dl_ref[pl.ds(lane, 1), :])
 
-    _each(kept, add)
+    holds = _holds_tile_rows(tile_buf.shape[3], assign)
+    sublanes = lax.broadcasted_iota(jnp.int32, (8, w), 0)
+    whole, goes_on = count(13), count(14)  # a bit a trip of eight lanes
+
+    def bit(word, trip):
+        return lax.bitwise_and(lax.shift_right_logical(word, trip), 1)
+
+    def eight(trip):
+        first = lax.mul(trip, 8)
+
+        def in_registers():
+            # all eight lie in ONE tile row: it is loaded once, the lanes
+            # are added to that VALUE, each to its sublane, trip after trip
+            # for as long as the trips lie in it, and it is stored once: no
+            # lane's load waits behind another lane's store
+            at = (slot, lax.bitwise_and(words_ref[lax.add(base, first)], 255),
+                  slice(None), lanes)
+
+            def add_eight(carry):
+                lane0 = lax.mul(carry[0], 8)
+
+                def add_to(k, held):
+                    lane = lax.add(lane0, k)
+                    delta = lax.broadcast_in_dim(
+                        dl_ref[pl.ds(lane, 1), :], (8, w), (0, 1))
+                    mine = lax.eq(sublanes, lax.shift_right_logical(
+                        words_ref[lax.add(base, lane)], 8))
+                    return lax.select(mine, lax.add(held, delta), held)
+
+                after = lax.add(carry[0], 1)
+                # (a shift by 32 is no shift: nothing follows the last trip)
+                more = lax.bitwise_and(
+                    lax.convert_element_type(lax.lt(after, 32), jnp.int32),
+                    bit(goes_on, after))
+                return after, lax.fori_loop(
+                    0, 8, add_to, carry[1], unroll=True), more
+
+            after, held, _ = lax.while_loop(
+                lambda carry: lax.ne(carry[2], 0), add_eight,
+                (trip, tile_buf[at], jnp.int32(1)))
+            tile_buf[at] = held
+            return after
+
+        def one_by_one():
+            lax.fori_loop(
+                0, 8, lambda k, c: (add(lax.add(first, k)), c)[1], 0,
+                unroll=True)
+            return lax.add(trip, 1)
+
+        return lax.cond(lax.ne(bit(whole, trip), 0), in_registers, one_by_one)
+
+    if not holds:  # lane by lane, as every block was walked until PR 74
+        _each(kept, add)
+    else:
+        @pl.when(lax.eq(whole, 0))
+        def _apart():  # no eight lanes of the block lie in one tile row
+            _each(kept, add)
+
+        @pl.when(lax.ne(whole, 0))
+        def _runs():
+            trips = lax.shift_right_logical(kept, 3)
+            lax.while_loop(
+                lambda trip: lax.lt(trip, trips), eight, jnp.int32(0))
+            lax.fori_loop(
+                lax.mul(trips, 8), kept, lambda i, c: (add(i), c)[1], 0)
 
     @pl.when(lax.gt(skip, 0))
     def _carry():  # the open tile row, as the adds left it, to the next slot
@@ -1175,8 +1289,28 @@ def _tile_kernel(tiles_ref, words_ref, counts_ref, dl_ref, table_ref, out_ref,
     _each(lax.sub(opened, skip), write)
 
 
-def _tile_plan(sorted_ids: Array, rows: int, block: int):
-    """The tile kernel's scalars from the sorted ids."""
+def _holds_tile_rows(width: int, assign: bool) -> bool:
+    """Whether the tile kernel adds the trips of eight lanes that lie in one
+    tile row to that tile row held in registers (``_tile_kernel``): an ADD
+    of rows as wide as the chip has priced it and it won."""
+    return not assign and width // 128 in _HELD_ROW_REGISTERS
+
+
+def _bits(flags: Array) -> Array:
+    """``(n, k)`` booleans, ``k`` <= 32, as ``(n,)`` int32: bit ``i`` of a
+    word is ``flags[:, i]``."""
+    weights = jnp.left_shift(
+        jnp.uint32(1), jnp.arange(flags.shape[1], dtype=jnp.uint32))
+    return jax.lax.bitcast_convert_type(
+        jnp.sum(jnp.where(flags, weights, jnp.uint32(0)), axis=1,
+                dtype=jnp.uint32), jnp.int32)
+
+
+def _tile_plan(sorted_ids: Array, rows: int, block: int, holds: bool = True):
+    """The tile kernel's scalars from the sorted ids (``holds``:
+    :func:`_holds_tile_rows`; a kernel that does not is told of no trip that
+    lies in one tile row)."""
+    assert block <= 256, "a bit a trip of eight lanes in one int32"
     ids = sorted_ids.reshape(-1, block)
     local = jax.lax.broadcasted_iota(jnp.int32, ids.shape, 1)
     kept = ids < rows  # the dropped lanes sort to the end
@@ -1200,13 +1334,28 @@ def _tile_plan(sorted_ids: Array, rows: int, block: int):
     # block before lies in it (kept, as every lane before a kept one is)
     last = jnp.concatenate([jnp.full((1,), -1, tile.dtype), tile[:-1, -1]])
     carried = kept[:, 0] & (tile[:, 0] == last)
+    # eight lanes side by side (a trip of the kernel's walk) lie in ONE tile
+    # row where the first and the last do: a bit a trip of the block, and a
+    # second where the trip before lies in the same tile row (a run goes on)
+    eights = place.reshape(ids.shape[0], -1, 8)
+    whole = kept.reshape(eights.shape)[:, :, 7] & (
+        eights[:, :, 0] == eights[:, :, 7])
+    # (a block with few such trips is walked lane by lane: the look at a
+    # trip's bit costs every OTHER trip of the block a branch)
+    whole &= _RUN_TRIPS_SHARE * jnp.sum(whole, axis=1, keepdims=True) >= (
+        jnp.sum(kept, axis=1, keepdims=True) // 8)
+    if not holds:
+        whole = jnp.zeros_like(whole)
+    goes_on = whole[:, 1:] & whole[:, :-1] & (
+        eights[:, 1:, 0] == eights[:, :-1, 0])
+    goes_on = jnp.concatenate([jnp.zeros_like(whole[:, :1]), goes_on], axis=1)
     counts = jnp.stack(
         [jnp.sum(opens, axis=1, dtype=jnp.int32),
          jnp.sum(kept, axis=1, dtype=jnp.int32),
-         carried.astype(jnp.int32)], axis=1,
+         carried.astype(jnp.int32), _bits(whole), _bits(goes_on)], axis=1,
     )
     # the grid's first and last three steps work blocks that are not there
-    edge = jnp.zeros((3, 3), jnp.int32)
+    edge = jnp.zeros((3, 5), jnp.int32)
     counts = jnp.concatenate([edge, counts, edge])
     return tiles.reshape(-1), words.reshape(-1), counts.reshape(-1)
 
@@ -1323,7 +1472,8 @@ def _tile_add(table, sorted_ids, deltas, first=None, *, block: int,
         )
         assert first is None, "a stretch of a longer batch is whole blocks"
         deltas = jnp.pad(deltas, ((0, pad), (0, 0)))
-    tiles, words, counts = _tile_plan(sorted_ids, rows, block)
+    tiles, words, counts = _tile_plan(
+        sorted_ids, rows, block, _holds_tile_rows(width, assign))
 
     blocks = (n + pad) // block
     if first is not None:
@@ -1334,7 +1484,7 @@ def _tile_add(table, sorted_ids, deltas, first=None, *, block: int,
         at = jax.lax.min(jax.lax.max(g - 1, 0), blocks - 1)
         if first is None:
             return at, 0
-        return at + counts_ref[3 * blocks + 18], 0
+        return at + counts_ref[5 * blocks + 30], 0
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
@@ -1365,7 +1515,7 @@ def _tile_add(table, sorted_ids, deltas, first=None, *, block: int,
     # a block's (opened, kept, carried): a carried tile row is opened by
     # both blocks that share it and moved once
     opened, kept, carried = jnp.sum(
-        counts[:3 * blocks + 18].reshape(-1, 3), axis=0)
+        counts[:5 * blocks + 30].reshape(-1, 5)[:, :3], axis=0)
     return table, kept, opened - carried
 
 
@@ -1385,7 +1535,10 @@ def _each(count, body) -> None:
     and the rest one by one: Mosaic unrolls a loop wholly or not at all,
     and a trip's scalar work packs the better the more of it there is (on
     the v5e a DMA descriptor issued one a trip is 22 ns, eight a trip 17;
-    a lane set 9.4 ns and 6.8: PERF.md section 6, PR 35).  The eight are a
+    a lane set 9.4 ns and 6.8: PERF.md section 6, PR 35; a lane's
+    load-add-store in the tile ADD kernel 9.1-9.4 ns eight a trip at one
+    register, and 5.0 where the eight are added to a tile row held in
+    registers: PR 74).  The eight are a
     loop of their own, unrolled where the kernel is lowered: ``body`` is
     traced twice, not nine times, and the arithmetic here and in the
     kernel is written in ``lax`` (``count // 8`` is a dozen equations and

@@ -562,6 +562,7 @@ def test_the_step_through_the_one_register_tile_kernel_is_xlas_and_the_driver_sa
     want, gauges = ran()
     assert "store_push_tile_rows" not in gauges
     assert "store_push_kernel_lanes" not in gauges
+    assert "store_tile_lanes_per_row" not in gauges
     steer_arms(push="tile_add")
     got, gauges = ran()
     assert np.array_equal(
@@ -573,6 +574,9 @@ def test_the_step_through_the_one_register_tile_kernel_is_xlas_and_the_driver_sa
     ids = batches[-1]["ids"].reshape(-1)
     assert gauges["store_push_kernel_lanes"][0]["value"] == ids.size
     assert gauges["store_push_tile_rows"][0]["value"] == len(
+        np.unique(ids // pack // 8))
+    # how many lanes share a tile row's one load and one store (PR 74)
+    assert gauges["store_tile_lanes_per_row"][0]["value"] == ids.size / len(
         np.unique(ids // pack // 8))
 
 

@@ -375,6 +375,10 @@ ADD_CASES = [
     "a_tile_row_four_blocks_share", "a_block_that_opens_no_tile_row",
     "first_and_last_tile_row", "every_lane_dropped", "one_lane",
     "several_calls",
+    # eight lanes side by side in one tile row are added to it as a value
+    # held in registers (PR 74)
+    "every_row_of_a_tile_row", "duplicates_inside_one_tile_row",
+    "a_tile_row_of_a_whole_block", "dropped_lanes_at_the_end",
 ]
 
 
@@ -414,6 +418,26 @@ def _add_case(name, rng):
         return np.concatenate([
             np.arange(0, 500), np.full(40, 301), np.arange(302, 480),
             np.repeat(np.arange(480, 488), 60)]), 30
+    if name == "every_row_of_a_tile_row":
+        # tile rows 5 and 6 with each of their eight rows once, tile row 9
+        # with each of its rows three times over
+        return np.concatenate([
+            np.arange(40, 56), np.repeat(np.arange(72, 80), 3)]), 0
+    if name == "duplicates_inside_one_tile_row":
+        # the batch holds rows 163, 160 and 165 of tile row 20 interleaved
+        # (the caller shuffles them): each row's adds in the batch's order
+        return np.concatenate([
+            np.arange(0, 100, 3), np.tile([163, 160, 165, 163, 163], 9),
+            np.arange(200, 260)]), 3
+    if name == "a_tile_row_of_a_whole_block":
+        # sorted lanes 256-511 (block 1, all of it) lie in tile row 50, and
+        # so do the two lanes either side: a run of a block's every lane
+        return np.concatenate([
+            np.arange(0, 254), np.sort(rng.integers(400, 408, 260)),
+            np.arange(500, 600)]), 0
+    if name == "dropped_lanes_at_the_end":
+        # 100 kept lanes and 1,180 dropped: blocks 1-4 hold nothing to add
+        return np.sort(rng.integers(0, rows, 100)), 1180
     raise KeyError(name)
 
 
@@ -430,6 +454,15 @@ def _add_case(name, rng):
     for width, w in ((640, 600), (384, 300), (640, 602))
     for name in ("a_hot_row_over_three_blocks", "a_tile_row_two_blocks_share",
                  "several_calls")
+] + [
+    # ONE register a row (cell 10), and the held tile row at five (PR 74)
+    (width, w, name)
+    for width, w in ((128, 128), (640, 600))
+    for name in ("a_hot_row_over_three_blocks", "every_row_of_a_tile_row",
+                 "duplicates_inside_one_tile_row",
+                 "a_tile_row_of_a_whole_block", "dropped_lanes_at_the_end",
+                 "one_lane")
+    if (w, name) != (600, "a_hot_row_over_three_blocks")  # held above
 ])
 def test_sorted_tile_add_is_numpys_add_at_in_batch_order_bit_for_bit(
         width, w, name, monkeypatch):
@@ -477,6 +510,52 @@ def test_sorted_tile_add_is_numpys_add_at_in_batch_order_bit_for_bit(
         }.get(name, 1)
         if name == "a_block_that_opens_no_tile_row":
             assert blocks[0] and not blocks[-1]
+
+
+@pytest.mark.parametrize("name", [
+    "a_hot_row_over_three_blocks", "every_row_of_a_tile_row",
+    "a_tile_row_of_a_whole_block", "first_and_last_tile_row",
+    "a_block_that_opens_no_tile_row", "every_lane_dropped",
+])
+def test_the_tile_plan_counts_the_trips_that_lie_in_one_tile_row(name):
+    """The plan's fourth and fifth word a block (PR 74), a bit a trip of
+    eight sorted lanes: where all eight are kept and lie in ONE tile row
+    (the trips whose lanes the kernel adds to a tile row held in registers;
+    a block that has none is walked lane by lane as before), and where the
+    trip before is such a trip too, in the same tile row (the held tile row
+    stays held)."""
+    rng = np.random.default_rng(ADD_CASES.index(name))
+    kept, dropped = _add_case(name, rng)
+    ids = np.sort(np.concatenate(
+        [kept, np.full(dropped, ADD_ROWS + 5)])).astype(np.int32)
+    ids = np.concatenate(
+        [ids, np.full(-len(ids) % 256, np.iinfo(np.int32).max, np.int32)])
+    counts = np.asarray(row_update._tile_plan(
+        jnp.asarray(ids), ADD_ROWS, 256)[2]).reshape(-1, 5)
+    assert not counts[:3].any() and not counts[-3:].any()  # the grid's ends
+    trips = ids.reshape(-1, 32, 8)
+    whole = (trips[:, :, 7] < ADD_ROWS) & (
+        trips[:, :, 0] // 8 == trips[:, :, 7] // 8)
+    # (in a block where at least one in `share` of the whole trips is one)
+    share = row_update._RUN_TRIPS_SHARE
+    whole &= share * whole.sum(axis=1, keepdims=True) >= (
+        (trips < ADD_ROWS).sum(axis=(1, 2)) // 8)[:, None]
+    goes_on = np.zeros_like(whole)
+    goes_on[:, 1:] = whole[:, 1:] & whole[:, :-1] & (
+        trips[:, 1:, 0] // 8 == trips[:, :-1, 0] // 8)
+
+    def bits(words):
+        return (words.astype(np.uint32)[:, None] >> np.arange(32)) & 1
+
+    assert np.array_equal(bits(counts[3:-3, 3]), whole)
+    assert np.array_equal(bits(counts[3:-3, 4]), goes_on)
+    assert counts[3:-3, 1].tolist() == (
+        ids.reshape(-1, 256) < ADD_ROWS).sum(axis=1).tolist()
+    if name in ("a_hot_row_over_three_blocks", "a_tile_row_of_a_whole_block"):
+        # a whole block of one tile row: every trip, the last (bit 31) too
+        assert whole.sum(axis=1).max() == 32 == goes_on.sum(axis=1).max() + 1
+    if name in ("first_and_last_tile_row", "a_block_that_opens_no_tile_row"):
+        assert not whole.any()  # the walk by lane alone
 
 
 # An add push NARROWER than its table (word2vec's 600 lanes in 640, fastText's
@@ -1324,7 +1403,7 @@ def test_unknown_state_scatter_is_refused():
 TILE_ASSIGN_CASES = [
     "sorted_distinct", "neighbours_in_one_tile", "every_lane_dropped",
     "dropped_at_the_end", "one_lane", "nan_inf_and_minus_zero",
-    "a_whole_tile", "several_calls",
+    "a_whole_tile", "several_calls", "a_tile_row_two_blocks_share",
 ]
 
 
@@ -1336,6 +1415,12 @@ TILE_ASSIGN_CASES = [
     for width, w in ((640, 600), (384, 300), (640, 602))
     for name in ("sorted_distinct", "dropped_at_the_end",
                  "nan_inf_and_minus_zero", "several_calls")
+] + [
+    # one register a row, and a held tile row under a store at five (PR 74)
+    (width, w, name)
+    for width, w in ((128, 128), (640, 600))
+    for name in ("neighbours_in_one_tile", "a_whole_tile", "one_lane",
+                 "a_tile_row_two_blocks_share")
 ])
 def test_sorted_tile_assign_is_xlas_row_set_bit_for_bit(
         width, w, name, monkeypatch):
@@ -1359,6 +1444,11 @@ def test_sorted_tile_assign_is_xlas_row_set_bit_for_bit(
         ids, n = ids[:1], 1
     elif name == "a_whole_tile":
         ids, n = np.arange(16, 24).astype(np.int32), 8
+    elif name == "a_tile_row_two_blocks_share":
+        # sorted lanes 253-258 are rows 400-405: three a side of block 0's
+        # end: the carried tile row, its lanes stored one by one
+        ids = np.concatenate(
+            [np.arange(0, 253), np.arange(400, 447)]).astype(np.int32)
     elif name == "several_calls":
         monkeypatch.setattr(row_update, "MAX_LANES", 256)
         rows_n, n = 2000, 700

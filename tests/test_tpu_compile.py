@@ -582,9 +582,10 @@ def test_mosaic_takes_the_tile_kernel_with_rows_at_their_own_width(
     row of the tile, at every width a cell pushes: 600 and 602 in 640 (four
     whole registers and one of 88 / 90 lanes under a mask), 300 in 384, and
     ``w`` = ``W``; in place, no pad of the rows in front of it.  At ``w`` =
-    ``W`` = 128, cell 10's shape, the Mosaic body is the one PR 57's parent
-    lowered, op for op (a change that means to move that kernel brings its
-    own hash)."""
+    ``W`` = 128, cell 10's shape, the Mosaic body is pinned (a change that
+    means to move that kernel brings its own hash: PR 57's parent's was
+    ``8f20c8758b8c3858``; PR 74 moved it and meant to: eight lanes that lie
+    in one tile row are added to the tile row held in registers)."""
     fn = row_update.sorted_tile_assign if assign else row_update.sorted_tile_add
     lowered = jax.jit(
         lambda t, ids, dl: fn(t, ids, dl, interpret=False), donate_argnums=(0,),
@@ -594,7 +595,7 @@ def test_mosaic_takes_the_tile_kernel_with_rows_at_their_own_width(
         _shape(one_chip, (lanes, w), jnp.float32),
     )
     if (width, w) == (128, 128):
-        assert _mosaic_body_sha(lowered.as_text()) == "8f20c8758b8c3858"
+        assert _mosaic_body_sha(lowered.as_text()) == "3466e093493e333b"
     compiled = lowered.compile()
     text = compiled.as_text()
     name = "sorted_row_assign_tiles" if assign else "sorted_row_update_tiles"
@@ -3101,27 +3102,27 @@ ON_THE_CHIP = {
     "fm_ps4_cell_4_on_four_tpus": (
         "fm_step", ("cell_4", "kernels"), "831429aecc471fca"),
     "w2v_cell_5_on_a_tpu": (
-        "w2v_step", ("kernels",), "16f834160caf82d7"),
+        "w2v_step", ("kernels",), "516e264b187449b7"),
     "lr_cell_6_on_a_tpu": (
         "lr_step", ("kernels",), "4316e6f312ec73b7"),
     "ft_cell_7_on_a_tpu": (
-        "ft_step", None, "714998ad7272db94"),
+        "ft_step", None, "f760a7fb1f7744dc"),
     "keyed_mf_cell_8_on_four_tpus": (
         "mf_dp4_step", ("whole_on_every_chip",), "4c18ed8a28ba52c5"),
     "difacto_cell_9_on_a_tpu": (
         "difacto_step", ("kernels",), "303f9a9ea4e1a64e"),
     "dlrm_cell_10_on_a_tpu": (
-        "dlrm_step", None, "0ea613ea9d5a0e78"),
+        "dlrm_step", None, "ce6e9158095e29a1"),
     "difacto_ps4_cell_12_on_four_tpus": (
         "difacto_ps4_step", ("kernels",), "cd80a9212976c0a2"),
     "glove_cell_13_on_a_tpu": (
-        "glove_step", ("kernels",), "04a7f054e433a5bc"),
+        "glove_step", ("kernels",), "15eb405c9fedc098"),
     "kge_cell_14_on_a_tpu": (
         "kge_step", ("auto",), "572a5cfb332f1ff5"),
     "dcn_cell_15_on_a_tpu": (
-        "dcn_step", None, "e0bb481e6420de82"),
+        "dcn_step", None, "5c6cdd0de7ad3c5e"),
     "dlrm_ps4_cell_16_on_four_tpus": (
-        "dlrm_ps4_step", None, "c1ca125a2e7b3fc2"),
+        "dlrm_ps4_step", None, "1c69cbd63e3d9cfc"),
     "wdl_cell_17_on_a_tpu": (
         "wdl_tpu_step", None, "6c8f66f09bc7e7b3"),
 }
@@ -3207,7 +3208,17 @@ def test_every_cells_step_text_is_the_parents(cell, want, request):
     every PR since PR 63 hashed by hand in a scratch script), read from the
     lowered text of the fixtures that compile those steps, a Mosaic body
     standing as the hash of its print without locations; each as PR 73's
-    parent lowers it.  Cell 11 runs cells 1 and 3's step."""
+    parent lowers it.  Cell 11 runs cells 1 and 3's step.  PR 74 moved the
+    six steps that call ``ops/row_update._tile_kernel`` and meant to (cells
+    5, 7, 10 and 16's add push, cell 13's combine and write-back, cell
+    15's write-back: the kernel's adds hold a tile row in registers where
+    the rows are one or five registers wide and added, cells 5, 10 and 16;
+    its plan hands every one of the six five words a block for three, the
+    only change to cells 7, 13 and 15, whose rows are added, or stored,
+    lane by lane as before; until then
+    ``16f834160caf82d7``, ``714998ad7272db94``, ``0ea613ea9d5a0e78``,
+    ``c1ca125a2e7b3fc2``, ``04a7f054e433a5bc``, ``e0bb481e6420de82``);
+    the other fourteen cases hash equal on the parent and on the tree."""
     if cell in ON_THE_CHIP:
         fixture, key, _ = ON_THE_CHIP[cell]
         held = request.getfixturevalue(fixture)
