@@ -10,6 +10,17 @@ rebuild processes a *microbatch of events per jitted step*:
     state', req, o = logic.step(state, batch, pulled) # the "training math"
     store'         = store.push(req.ids, req.deltas)  # sharded scatter-add
 
+A step may pull in several ROUNDS, as the reference's worker pulls again
+from ``onPullRecv`` (it is handed the client in both hooks): after each pull
+the logic is asked for the next round's keys, a function of the batch, the
+state and the rows pulled so far, inside the one jitted step:
+
+    ids_n  = logic.next_keys(state, batch, (pulled_0, ..., pulled_n-1))
+    pulled_n = store.pull(ids_n)                      # until it answers None
+
+and ``step`` then takes every round's rows.  A store that no request names
+is only read: its table leaves the step as it came in.
+
 The worker's mutable local state (e.g. MF user vectors) is an explicit
 pytree threaded through ``step`` — data-parallel across the ``dp`` mesh axis
 the way the reference's worker state is partitioned across
@@ -60,9 +71,28 @@ class BatchedWorkerLogic(abc.ABC, Generic[State, Batch, Out]):
         mask for variable counts).  The key block in C order is the PULL's
         lanes: lane ``n`` pulls row ``keys.reshape(-1)[n]``, in that order,
         and ``step``'s ``pulled`` is ``keys.shape + row``, whoever calls
-        ``step``.  The push's lanes are its request's: ``PushRequest.ids``
+        ``step``.  This is the step's FIRST round of pulls; a logic that
+        pulls again from what it pulled answers the further rounds' keys
+        in :meth:`next_keys`.  The push's lanes are its request's: ``PushRequest.ids``
         in C order, deltas and mask lane for lane, and a row's deltas are
         summed in lane order."""
+
+    def next_keys(
+        self, state: State, batch: Batch, pulled: Tuple[Any, ...]
+    ) -> Optional[Any]:
+        """The key block of the step's NEXT round of pulls, or ``None``
+        when the step has pulled all it needs.  A ROUND is one pull a store
+        it names; ``pulled`` holds the rows of the rounds so far, in order
+        (``pulled[0]`` those of :meth:`keys`), so round ``n``'s keys are a
+        function of the batch, the state and the rows of the rounds before
+        it (sampled neighbours of the nodes a pull returned:
+        ``models/graphsage.py``).  Asked while the step is TRACED
+        (``core/transform.make_train_step``): how many rounds there are is
+        read from ``len(pulled)``, static, and every round runs inside the
+        one jitted step.  A logic that answers a block here is handed
+        ``step``'s ``pulled`` as the TUPLE of every round's rows; the
+        default, one round, takes round 0's rows as they are."""
+        return None
 
     @abc.abstractmethod
     def step(

@@ -1246,6 +1246,7 @@ def arms(
     spec: StoreSpec, *, pull_lanes: Optional[int] = None,
     push_lanes: Optional[int] = None, lanes_over_workers: bool = False,
     push_width: Optional[int] = None, fields: Optional[int] = None,
+    only_read: bool = False,
 ) -> Arms:
     """THE one reader of which form a pull of ``pull_lanes`` ids and a push
     of ``push_lanes`` lanes take, from what the spec and the batch hold: the
@@ -1255,6 +1256,10 @@ def arms(
     of None asks whether ANY pull / push of the store may take a kernel
     (:func:`_preload_tile_kernel`).  ``lanes_over_workers``: the caller
     knows the batch's lanes lie split over ``dp`` (:func:`push_counted`).
+    ``only_read``: a step that only READS the store (its logic answers no
+    request for it: ``core/transform.make_train_step``), which takes no
+    push, asks for no push arm and notes none (a push of NO lanes is still a
+    push, and reads its arms as any other).
     ``push_width``: the lanes of a row the push is handed, None for what a
     STEP pushes (the worker's part where the spec names one,
     ``StoreSpec.worker_width``, else the whole row); a rule's sums are made
@@ -1302,6 +1307,8 @@ def arms(
     packed k 1, 5 regs (3: cell 7)     packed_selects          tile_add       selects          no         5 7     32 33 57
     5 regs over ps 4, dp 1             take                    tile_add       -                yes        none    33 67
     5 regs over ps 2, dp 2             take                    xla_add        -                no         none    33 67
+    dense 1 reg, only read             take                    -              -                no         18      75
+    int32 scalars, k 128, only read    packed_selects          -              -                no         18      75
     =================================  ======================  =============  ===============  =========  ======  ========
 
     A store whose ``update`` is a rule (``push`` ``"rule"``, ``shift``
@@ -1332,6 +1339,13 @@ def arms(
     2 regs, the worker's 128 / 256    packed_selects          row_kernel   tile_assign  no         15    64
     2 regs, 128 / 256 over ps 4       packed_selects          row_kernel   tile_assign  yes        none  64
     ================================  ======================  ===========  ===========  =========  ====  ========
+
+    A table of INTEGERS (a graph's neighbour ids and offsets, scalar rows
+    128 to a physical row: ``models/graphsage.py``) reads, on a TPU too, the
+    arms a CPU reads, and no refusal is noted for it: the kernels move
+    float32 rows and were never meant for ids.  Its pull picks a scalar row
+    out of its gathered physical row by ONE masked sum over the lanes
+    (``ops/packed._sub_row_slice`` at a row width of 1).
 
     Reasons the code does not show.  GSPMD partitions XLA's scatter-add and
     cannot partition Mosaic's call: the lane kernels, a rule's kernels and
@@ -1364,7 +1378,11 @@ def arms(
     cell 9's (a row named 3.6 times) all along."""
     from ..ops import dedup, packed, row_update
 
-    tpu = jax.default_backend() == "tpu"
+    # (a table of integers, a graph's adjacency lists and their offsets,
+    # takes XLA's arms wherever it lies: the kernels are float32's, nobody
+    # meant them for ids, and nothing is refused)
+    tpu = jax.default_backend() == "tpu" and jnp.issubdtype(
+        spec.dtype, jnp.floating)
     shape, rule = spec.table_shape(), spec.update != "add"
     phys = 1  # lanes of a physical row
     for s in shape[1:]:
@@ -1405,6 +1423,11 @@ def arms(
         kernel = lane_kernel(n, "the lane slice of a packed pull")
         pull = "packed_kernel" + by_field(n) if kernel else "packed_selects"
 
+    if only_read:
+        # a step that only READS the store (no request names it): no push
+        # arm is asked of it, and none noted (a narrow rule store's pull
+        # stays the gather: no push shares its rows)
+        return Arms(pull, "", "", "", "", False)
     if not rule:
         # the table the kernel sees: the whole one, or a shard's block
         block = (spec.rows_per_shard,) + shape[1:]
@@ -1487,8 +1510,10 @@ def _preload_tile_kernel(spec: StoreSpec) -> None:
 
 
 def step_counts(
-    spec: StoreSpec, counted: Optional[dict], *, pull_lanes: int,
-    push_lanes: int, fields: Optional[int] = None, compute_parts: int = 1,
+    spec: StoreSpec, counted: Optional[dict], *,
+    pull_lanes: Union[int, Tuple[int, ...]],
+    push_lanes: Optional[int], fields: Optional[int] = None,
+    compute_parts: int = 1,
     crossings: Optional[dict] = None, pulled: Optional[PulledRows] = None,
     store: Optional[str] = None,
 ) -> dict:
@@ -1536,6 +1561,15 @@ def step_counts(
     ``store_rule_keys`` it says how many lanes shared a fetched row: 3.6 on
     Criteo records, 1.0 where the arm sorted for nothing).
 
+    ``pull_lanes`` is a number where the step pulled from the store in ONE
+    round and pushes to it, every step until PR 75 (its outputs are what they
+    were).  A TUPLE, the lanes of each round that pulled from the store
+    (``BatchedWorkerLogic.next_keys``), or ``push_lanes`` ``None``, a store
+    the step only read: ``ps_pull_lanes`` is the lanes summed over the rounds
+    (the gauge ``store_pull_lanes``), a packed store's ``ps_slice_kernel``
+    is 1 where EVERY round's slice took the kernel, and a store only read
+    hands out no count of a push and no ``ps_shift_kernel``.
+
     ``store``: in a step over several stores (``GroupSpec``) every output
     of one store leaves as ``<name>@<store>``, and :func:`publish_counts`
     sets its gauges under the label ``store=<store>``."""
@@ -1546,6 +1580,10 @@ def step_counts(
             pulled=pulled)
         return {f"{name}@{store}": value for name, value in own.items()}
     out = dict(counted or {})
+    rounds = pull_lanes if isinstance(pull_lanes, tuple) else (pull_lanes,)
+    only_read = push_lanes is None
+    if isinstance(pull_lanes, tuple) or only_read:
+        out["ps_pull_lanes"] = jnp.asarray(sum(rounds), jnp.int32)
     if pulled is not None:
         out["ps_pull_distinct_rows"] = pulled.count
     if crossings:
@@ -1556,10 +1594,12 @@ def step_counts(
     if spec.worker_width is not None:
         out["ps_pull_row_lanes"] = jnp.asarray(spec.worker_width, jnp.int32)
     if spec.pack > 1:
-        arm = arms(spec, pull_lanes=pull_lanes, push_lanes=push_lanes,
-                   fields=fields)
+        by_round = [arms(spec, pull_lanes=n, push_lanes=push_lanes,
+                         fields=fields, only_read=only_read) for n in rounds]
+        arm = by_round[0]
         out["ps_slice_kernel"] = jnp.asarray(
-            arm.pull.startswith("packed_kernel"), jnp.int32)
+            all(a.pull.startswith("packed_kernel") for a in by_round),
+            jnp.int32)
         if arm.shift:
             out["ps_shift_kernel"] = jnp.asarray(
                 arm.shift.startswith("kernel"), jnp.int32)
@@ -1602,6 +1642,9 @@ def publish_counts(outs: dict, registry: Any, total, peak) -> None:
     # (1 where the step says nothing: the whole minibatch in every place)
     registry.gauge("store_compute_parts", component="train").set(
         peak(outs["ps_compute_parts"]) if "ps_compute_parts" in outs else 1)
+    if "ps_pull_lanes" in outs:
+        registry.gauge("store_pull_lanes", component="train").set(
+            total(outs["ps_pull_lanes"]))
     if "ps_mesh_kib" in outs:
         registry.gauge("store_mesh_bytes", component="train").set(
             int(peak(outs["ps_mesh_kib"])) * 1024)

@@ -304,6 +304,51 @@ def _traced_under(mesh):
     return jax.sharding.use_abstract_mesh(mesh.abstract_mesh)
 
 
+def _pull_rounds(logic, state, batch, pull) -> Tuple[list, Any]:
+    """A step's pulls, round by round, for the single store's step and the
+    group's alike: ``logic.keys(batch)``, then ``logic.next_keys(state,
+    batch, rows so far)`` until it answers ``None``
+    (``BatchedWorkerLogic.next_keys``: read with ``getattr``, a logic need
+    not subclass it), each block handed to ``pull(block, round)``, which
+    answers its rows.  Returns every round's key block and what
+    ``logic.step`` takes as ``pulled``: round 0's rows as they are where
+    that is the only round (the trace a step always had), else the tuple of
+    every round's."""
+    keys = [logic.keys(batch)]
+    rows = [pull(keys[0], 0)]
+    more = getattr(logic, "next_keys", None)
+    while more is not None:
+        block = more(state, batch, tuple(rows))
+        if block is None:
+            break
+        keys.append(block)
+        rows.append(pull(block, len(rows)))
+    return keys, rows[0] if len(rows) == 1 else tuple(rows)
+
+
+def _round_lanes(blocks: list):
+    """``core/store.step_counts``' ``pull_lanes`` of the key blocks a step
+    pulled from one store: the one block's lanes, a number (the outputs a
+    step of one round always had), or a tuple, a round each."""
+    if len(blocks) == 1:
+        return blocks[0].size
+    return tuple(block.size for block in blocks)
+
+
+def _first_left(lefts):
+    """What a step's pulls of one store left for ``step_counts`` to count
+    (``PulledRows``: a narrow rule store's distinct rows), whichever push
+    took them up: the first round's that left any."""
+    return next((left for left in lefts if left is not None), None)
+
+
+def _round_scope(n: int):
+    """``round.<n>`` on the ``op_name`` of the ops of a step's pull round
+    ``n`` >= 1, inside ``ps.pull``; round 0 opens none (a step of one round
+    keeps its text)."""
+    return jax.named_scope(f"round.{n}") if n else contextlib.nullcontext()
+
+
 def make_train_step(logic: BatchedWorkerLogic, spec) -> Callable:
     """Build the fused pull→compute→push step (to be jit-compiled).
 
@@ -345,6 +390,13 @@ def make_train_step(logic: BatchedWorkerLogic, spec) -> Callable:
     other logic computes the whole minibatch on every chip, which costs it
     nothing worth a scatter and a gather of its rows (FM, DiFacto: 0.16 /
     0.065 ms a step).
+
+    A logic may pull in several ROUNDS (``BatchedWorkerLogic.next_keys``:
+    round ``n``'s keys a function of the batch, the state and the rows of
+    the rounds before): every round is a pull like the first, under
+    ``ps.pull`` and, from the second on, ``round.<n>``
+    (:func:`_pull_rounds`, the seam this step and the group's share), all in
+    front of the compute.
 
     ``spec`` may be a ``core/store.GroupSpec``: SEVERAL named stores in the
     one step, each with its own key space, row width and rule
@@ -404,22 +456,35 @@ def make_train_step(logic: BatchedWorkerLogic, spec) -> Callable:
     def traced(table, state, batch, crossings):
         if lanes is not None:
             batch = jax.tree.map(over_workers, batch)
-        ids = logic.keys(batch)
-        parts = servers if ids.shape[0] % blocks == 0 else 1
-        # ps.* scopes are metadata on the ops' names (docs/observability.md):
-        # a trace reduction finds pull, compute and push by them whatever
-        # XLA numbers its fusions
-        with scope("ps.pull"):
-            # (of a rule store whose row has a worker's part, that part)
-            # (and what the pull leaves for a push of the same keys)
-            pulled, left = store_mod.pull_counted(
-                spec, table, ids, worker_part=True, turned=turned, kept=parts)
-            if parts > 1:
-                pulled = pulled_over_servers(pulled)
+        lefts, parts_of = [], []
+
+        def pull_round(ids, n):
+            parts = servers if ids.shape[0] % blocks == 0 else 1
+            # ps.* scopes are metadata on the ops' names
+            # (docs/observability.md): a trace reduction finds pull, compute
+            # and push by them whatever XLA numbers its fusions
+            with scope("ps.pull"), _round_scope(n):
+                # (of a rule store whose row has a worker's part, that part)
+                # (and what the pull leaves for a push of the same keys)
+                pulled, left = store_mod.pull_counted(
+                    spec, table, ids, worker_part=True, turned=turned,
+                    kept=parts)
+                if parts > 1:
+                    pulled = pulled_over_servers(pulled)
+            lefts.append(left)
+            parts_of.append(parts)
+            return pulled
+
+        keys, pulled = _pull_rounds(logic, state, batch, pull_round)
+        ids, parts = keys[0], parts_of[0]
         # (a step that splits nothing keeps its text to the letter)
         split_over = spec.mesh if parts > 1 else None
         with scope("ps.compute"), _traced_under(split_over):
             state, req, out = logic.step(state, batch, pulled)
+        # the rows a pull read are the push's where its ids are the very
+        # keys of that round; nothing wrote the table in between
+        left = next((held for block, held in zip(keys, lefts)
+                     if req.ids is block), None)
         with scope("ps.push"):
             deltas = req.deltas
             if parts > 1:
@@ -432,17 +497,17 @@ def make_train_step(logic: BatchedWorkerLogic, spec) -> Callable:
             table, counted = store_mod.push_counted(
                 spec, table, req.ids, deltas, req.mask,
                 lanes_over_workers=lanes is not None, turned=turned,
-                # the rows the pull read are the push's where its ids are
-                # the very keys pulled; nothing wrote the table in between
-                pulled=left if req.ids is ids else None,
+                pulled=left,
             )
         if isinstance(out, dict):
             # what the store counted on the device and which arms this
             # trace read leave the step with the logic's outputs
             out = {**out, **store_mod.step_counts(
-                spec, counted, pull_lanes=ids.size, push_lanes=req.ids.size,
+                spec, counted, pull_lanes=_round_lanes(keys),
+                push_lanes=req.ids.size,
                 fields=ids.shape[-1] if turned else None,
-                compute_parts=parts, crossings=crossings, pulled=left)}
+                compute_parts=parts, crossings=crossings,
+                pulled=_first_left(lefts))}
         return table, state, out
 
     return step
@@ -479,27 +544,43 @@ def _make_group_train_step(logic: BatchedWorkerLogic, spec) -> Callable:
     names = tuple(spec)
 
     def step(tables, state, batch):
-        keys = logic.keys(batch)
-        pulled, left = {}, {}
-        for name in names:
-            with scope("ps.pull"):
-                pulled[name], left[name] = store_mod.pull_counted(
-                    spec[name], tables[name], keys[name], worker_part=True,
-                    store=name)
+        lefts = []
+
+        def pull_round(keys, n):
+            rows, left = {}, {}
+            for name in names:
+                if name not in keys:
+                    continue
+                with scope("ps.pull"), _round_scope(n):
+                    rows[name], left[name] = store_mod.pull_counted(
+                        spec[name], tables[name], keys[name],
+                        worker_part=True, store=name)
+            lefts.append(left)
+            return rows
+
+        rounds, pulled = _pull_rounds(logic, state, batch, pull_round)
         with scope("ps.compute"):
             state, reqs, out = logic.step(state, batch, pulled)
         tables, counts = dict(tables), {}
         for name in names:
-            req = reqs[name]
-            ids = keys[name] if req.ids is None else req.ids
-            with scope("ps.push"):
-                tables[name], counted = store_mod.push_counted(
-                    spec[name], tables[name], ids, req.deltas, req.mask,
-                    pulled=left[name] if ids is keys[name] else None,
-                    store=name)
+            blocks = [keys[name] for keys in rounds if name in keys]
+            req, counted, left, ids = reqs.get(name), None, None, None
+            if req is not None:  # (a store no request names is only read)
+                ids = req.ids
+                if ids is None:
+                    # the keys of the ONE round that pulled from the store
+                    (ids,) = blocks
+                left = next((held[name] for keys, held in zip(rounds, lefts)
+                             if keys.get(name) is ids), None)
+                with scope("ps.push"):
+                    tables[name], counted = store_mod.push_counted(
+                        spec[name], tables[name], ids, req.deltas, req.mask,
+                        pulled=left, store=name)
             counts.update(store_mod.step_counts(
-                spec[name], counted, pull_lanes=keys[name].size,
-                push_lanes=ids.size, pulled=left[name], store=name))
+                spec[name], counted, pull_lanes=_round_lanes(blocks),
+                push_lanes=None if ids is None else ids.size,
+                pulled=_first_left(held.get(name) for held in lefts),
+                store=name))
         if isinstance(out, dict):
             out = {**out, **counts}
         return tables, state, out

@@ -209,6 +209,16 @@ def _sub_row_slice(
     if k == 1:
         return rows[:, :w]
     t = (ids.astype(jnp.int32) % k)[:, None]
+    if d == 1 and rows.dtype.itemsize == 4:
+        # SCALAR rows, 128 to a physical row (a graph's neighbour ids): the
+        # one lane `t` names, every other lane a zero WORD, summed as words,
+        # so the scalar's bits whatever they are (one word and 127 zeros: no
+        # float add ever sees a NaN or a -0.0).  One pass over the gathered
+        # rows where the selects below would be 127
+        lane = jax.lax.broadcasted_iota(jnp.int32, rows.shape, 1)
+        words = jax.lax.bitcast_convert_type(rows, jnp.int32)
+        picked = jnp.where(lane == t, words, 0).sum(axis=1, keepdims=True)
+        return jax.lax.bitcast_convert_type(picked, rows.dtype)
     out = rows[:, :w]
     for j in range(1, k):
         out = jnp.where(t == j, rows[:, j * d:j * d + w], out)

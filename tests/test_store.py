@@ -2633,6 +2633,12 @@ ARMS_ON_A_TPU = [
     ("5 regs over ps 2, dp 2", (640,), "add", "auto", (2, 2), 61,
      8_192, 8_192, False, ("take", "xla_add", "", "", "", False),
      1),  # the push on the shards: the batch lies split over dp, noted
+    # cell 18 (PR 75): stores a step only READS (`only_read`: no push arm is
+    # asked and none noted), one of them int32 scalar rows, XLA's arms
+    ("dense 1 reg, only read", (128,), "add", "auto", None, 40_000,
+     5_000, 0, False, ("take", "", "", "", "", False), 0),
+    ("int32 scalars, k 128, only read", (), "add", "auto", None, 40_000,
+     8_192, 0, False, ("packed_selects", "", "", "", "", False), 0),
     # cell 6 since PR 70: rows a sort carries, in one place, are read a
     # DISTINCT row at a time, for the logic and the rule both
     ("3 lanes, held at its tile of 4", (3,), _RULE, "auto", None, 1_000,
@@ -2714,6 +2720,8 @@ WORKER_WIDTHS = {
     "the worker's 20 / 36, fields 39": 20, "20 / 36, fields 39, over ps 4": 20,
     "2 regs, the worker's 128 / 256": 128, "2 regs, 128 / 256 over ps 4": 128,
 }
+# a table of integers (a graph's adjacency): XLA's arms on a TPU too
+DTYPES = {"int32 scalars, k 128, only read": jnp.int32}
 # the keys an example of a block of two axes that its logic takes TURNED
 FIELDS = {
     "packed k 7, fields 39": 39, "fields 39 over ps 4, dp 1": 39,
@@ -2735,6 +2743,9 @@ ARMS_OFF_IT = {
     "k 2, fields 26, 1,024+ <= rows/8": (
         "packed_selects", "xla_add", "selects", "", "", False),
     "packed k 1, 5 regs": ("packed_selects", "xla_add", "selects", "", "", False),
+    "dense 1 reg, only read": ("take", "", "", "", "", False),
+    "int32 scalars, k 128, only read": (
+        "packed_selects", "", "", "", "", False),
     "3 lanes, held at its tile of 4": (
         "narrow", "rule", "", "sort", "xla_set", False),
     "packed k 3 (36 lanes)": (
@@ -2778,7 +2789,7 @@ def test_the_arms_table(backend, row, mesh_devices, monkeypatch):
     spec = store_mod.StoreSpec(
         capacity, shape, update=rule, mesh=mesh or None,
         layout=store_mod._resolve_layout(layout, rule, shape),
-        worker_width=part)
+        worker_width=part, dtype=DTYPES.get(what, jnp.float32))
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
     monkeypatch.setattr(store_mod, "_REFUSALS_NOTED", set())
     n0 = row_update.refusal_count()
@@ -2787,7 +2798,7 @@ def test_the_arms_table(backend, row, mesh_devices, monkeypatch):
         return dataclasses.astuple(store_mod.arms(
             spec, pull_lanes=pull_lanes, push_lanes=push_lanes,
             lanes_over_workers=over_workers, fields=FIELDS.get(what),
-            **width))
+            only_read=what.endswith("only read"), **width))
 
     with warnings.catch_warnings(record=True) as caught:
         warnings.simplefilter("always")

@@ -3074,6 +3074,96 @@ def test_the_two_store_step_holds_one_gather_and_one_write_back_a_store(
         and "ps.delta_build" in line]
 
 
+# sage-papers100m-p8 (chipbench/configs): cell 18's three tables, as they lie
+SAGE_TABLES = {
+    "off": (108_464, 128), "nbr": (3_155_640, 128), "feat": (13_882_496, 128)}
+SAGE_SEEDS = 1_000
+
+
+@pytest.fixture(scope="module")
+def sage_tpu_step(one_chip):
+    """Cell 18's step over its THREE read-only stores at full size for a
+    described v5e, seven rounds of pulls in one program, compiled ONCE for
+    the tests that read it."""
+    from chipbench import spec as bench_spec
+    from chipbench.families import sage as family
+    from flink_parameter_server_tpu.models import graphsage as gs
+
+    cfg = bench_spec.resolve(
+        bench_spec.load_benchmark(), "sage-papers100m-p8.train-seeds-uniform",
+        dry_run=False)["cfg"]
+    model = family._model(cfg)
+    with _compiling_for_described_chips("tpu"):
+        spec = jax.eval_shape(lambda: gs.make_stores(model)).spec
+        assert {n: spec[n].table_shape() for n in spec} == SAGE_TABLES
+        n0 = row_update.refusal_count()
+        for name, pull in (("off", "packed_selects"),
+                           ("nbr", "packed_selects"), ("feat", "take")):
+            assert store_mod.arms(
+                spec[name], pull_lanes=100_000, only_read=True
+            ) == store_mod.Arms(pull, "", "", "", "", False)
+        assert row_update.refusal_count() == n0
+        logic = gs.GraphSage(model)
+        tables = {
+            name: _shape(one_chip, spec[name].table_shape(), spec[name].dtype)
+            for name in spec}
+        state = jax.tree.map(
+            lambda x: _shape(one_chip, x.shape, x.dtype),
+            jax.eval_shape(lambda: logic.init_state(jax.random.PRNGKey(0))))
+        batch = {
+            "seed": _shape(one_chip, (SAGE_SEEDS,), jnp.int32),
+            "label": _shape(one_chip, (SAGE_SEEDS,), jnp.int32),
+            "mask": _shape(one_chip, (SAGE_SEEDS,), jnp.bool_),
+        }
+        return CellStep(
+            make_train_step(logic, spec), tables, state, batch, backend="tpu")
+
+
+def test_the_chained_step_reads_its_three_tables_where_they_lie(sage_tpu_step):
+    """ONE program for seven rounds of pulls: the three tables donated and
+    aliased (8.78 GB: 7.11 of features, 1.62 of neighbour ids, 0.06 of row
+    ends), the two large ones yielded by no op but a parameter (no copy, no
+    scatter, no second features table), 0.80 GB of temporaries (the 413 MB
+    of pulled features and the deepest hop's 384 MB of physical rows).  One
+    gather a round, each under ``ps.pull``, its store's label and, from the
+    second on, its round's number, at the lanes the fan-outs give; the draws
+    between them under ``ps.sample``, the net under its two scopes."""
+    text, mem = sage_tpu_step.text, sage_tpu_step.memory
+    assert 8.78 * GB < mem.alias_size_in_bytes < 8.79 * GB
+    assert mem.temp_size_in_bytes < 0.85 * GB
+    lines = text.splitlines()
+    # (the 55.5 MB of row ends the compiler itself stages in the chip's fast
+    # memory, `S(1)`, for the three rounds that read it: its choice)
+    for rows, lanes in (SAGE_TABLES["nbr"], SAGE_TABLES["feat"]):
+        table = rf"[fs]32\[{rows},{lanes}\]"
+        yields = [
+            line.strip() for line in lines
+            if re.search(rf" = {table}\S* (?!parameter|get-tuple-element)",
+                         line)]
+        assert all(" bitcast(" in y for y in yields), yields
+    assert not [line for line in lines if re.search(r" scatter\(", line)]
+    gathers = [line for line in lines if re.search(
+        r" = [fs]32\[\d+,128\]\S* gather\(", line)]
+    want = [
+        (2_000, "ps.pull/store.off/"), (5_000, "ps.pull/round.1/store.nbr/"),
+        (10_000, "ps.pull/round.2/store.off/"),
+        (50_000, "ps.pull/round.3/store.nbr/"),
+        (100_000, "ps.pull/round.4/store.off/"),
+        (750_000, "ps.pull/round.5/store.nbr/"),
+        (806_000, "ps.pull/round.6/store.feat/")]
+    assert len(gathers) == len(want)
+    for (rows, where), line in zip(want, gathers):
+        assert f"32[{rows},128]" in line and where in line, line
+    for scope in ("jit(step)/ps.sample/", "ps.compute/ps.sage_dense/",
+                  "ps.compute/ps.dense_adam/"):
+        assert scope in text, scope
+    assert "tpu_custom_call" not in text and " while(" not in text
+    assert sorted(k for k in sage_tpu_step.outs if k.startswith("ps_")) == [
+        "ps_lanes_by_field@nbr", "ps_lanes_by_field@off", "ps_pull_lanes@feat",
+        "ps_pull_lanes@nbr", "ps_pull_lanes@off", "ps_slice_kernel@nbr",
+        "ps_slice_kernel@off"]
+
+
 def _step_text_sha(lowered_text):
     """sha256 of a step's lowered text, which carries no locations but in
     its Mosaic bodies: each stands as the hash of its print without them, so
@@ -3125,6 +3215,9 @@ ON_THE_CHIP = {
         "dlrm_ps4_step", None, "1c69cbd63e3d9cfc"),
     "wdl_cell_17_on_a_tpu": (
         "wdl_tpu_step", None, "6c8f66f09bc7e7b3"),
+    # (new in PR 75: as that PR's tree lowers it)
+    "sage_cell_18_on_a_tpu": (
+        "sage_tpu_step", None, "be7c94d5fa3142b2"),
 }
 
 
@@ -3218,7 +3311,14 @@ def test_every_cells_step_text_is_the_parents(cell, want, request):
     lane by lane as before; until then
     ``16f834160caf82d7``, ``714998ad7272db94``, ``0ea613ea9d5a0e78``,
     ``c1ca125a2e7b3fc2``, ``04a7f054e433a5bc``, ``e0bb481e6420de82``);
-    the other fourteen cases hash equal on the parent and on the tree."""
+    the other fourteen cases hash equal on the parent and on the tree.
+    PR 75 (a step that pulls again from what it pulled,
+    ``BatchedWorkerLogic.next_keys``; a store a step only reads; int32
+    scalar rows) moved none of the twenty: a logic of one round runs through
+    the rounds' seam (``core/transform._pull_rounds``) and traces the ops
+    and the names it traced, and ``arms`` reads a float32 store as it did;
+    the twenty-first case, cell 18's step, is new and as that PR's tree
+    lowers it."""
     if cell in ON_THE_CHIP:
         fixture, key, _ = ON_THE_CHIP[cell]
         held = request.getfixturevalue(fixture)
