@@ -541,7 +541,7 @@ def _pull(
             rows = mesh_moved(
                 "pull_rows_sum", _take_on_shards(spec, table, ids), kept)
         else:
-            rows = jnp.take(table, ids, axis=0)
+            rows = jnp.take(table, ids, axis=0, mode="clip")  # (no fill)
         return (rows if part is None else rows[..., :part]), None
     if arm == "narrow_distinct" and shared and ids.size:
         return _distinct_pull(spec, table, ids, part or spec.row_width)
@@ -605,12 +605,12 @@ def _distinct_pull(
 
 @functools.partial(jax.jit, static_argnums=(2,))
 def _narrow_pull(table: Array, ids: Array, width: int) -> Array:
-    """Rows ``ids`` of a narrow rule store's table without the zero lanes
-    that end them: the slice is the gather's own window (``slice_sizes``
-    ``(1, width)``), no op of its own.  Jitted so that an eager ``pull``
-    is that gather too: op by op the slice comes first and copies the
-    table (3.0 GB at cell 6's size: PERF.md section 6, PR 35)."""
-    return jnp.take(table[:, :width], ids, axis=0)
+    """Rows ``ids`` (clipped by every caller: no fill is selected after) of
+    a narrow rule store's table without the zero lanes that end them: the
+    slice is the gather's own window (``slice_sizes`` ``(1, width)``).
+    Jitted so that an eager ``pull`` is that gather too: op by op the slice
+    comes first and copies the table (3.0 GB in cell 6: PERF.md 6, PR 35)."""
+    return jnp.take(table[:, :width], ids, axis=0, mode="clip")
 
 
 def _phys_scatter_args(

@@ -945,12 +945,12 @@ def row_add(
     keep = None if mask is None else mask.reshape(-1)
     sid, order = sort_by_row(ids.reshape(-1), keep, state.shape[0])
     for lo, s, o in _calls(sid, order):
-        old = jnp.take(old_rows, o, axis=0)
+        # (a permutation: nothing to clip, and no fill to select after)
+        old = jnp.take(old_rows, o, axis=0, mode="clip")
         if lo:
             old = _open_run_reread(state, s, old)
-        state = sorted_row_update(
-            state, s, old, jnp.take(deltas, o, axis=0), interpret=interpret
-        )
+        new = jnp.take(deltas, o, axis=0, mode="clip")
+        state = sorted_row_update(state, s, old, new, interpret=interpret)
     return state
 
 

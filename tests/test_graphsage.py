@@ -148,11 +148,14 @@ def test_the_chained_step_pulls_what_separate_eager_pulls_pull():
 
 
 @pytest.mark.parametrize("what, want", [
-    ("single_store", "6096915f447dd6ab"), ("store_group", "22bde0b296d5fadc")])
+    ("single_store", "83c76269c615ef58"), ("store_group", "2ab0e375249ed376")])
 def test_a_one_round_logics_step_text_is_the_parents(what, want):
     """A logic that answers no ``next_keys`` lowers to the text PR 75's
     parent lowered (hashed there, commit 71290ba): the rounds' seam adds no
-    op and no name to a step of one round."""
+    op and no name to a step of one round.  PR 76 moved both and meant to
+    (the arm ``take`` and ``_narrow_pull`` gather with ``mode="clip"``: the
+    fill's wrap, compares and select gone, the gather clamping by itself;
+    ``6096915f447dd6ab`` and ``22bde0b296d5fadc`` until then)."""
     shape = jax.ShapeDtypeStruct
     if what == "single_store":
         logic = mfm.OnlineMatrixFactorization(

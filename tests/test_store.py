@@ -3460,3 +3460,77 @@ def test_spread_runs_is_a_gather_by_run_number(n, rows):
     compact[len(uniq):] = 7.0  # never read
     got = jax.jit(spread_runs)(jnp.asarray(compact), place, behind)
     assert np.array_equal(_bits(got), _bits(table[ids]))
+
+
+# -- a gather whose ids the code itself put in bounds does not fill (PR 76) ---
+@pytest.mark.parametrize("arm", ["take", "narrow", "narrow_distinct"])
+def test_a_pull_of_ids_it_has_clipped_reads_the_clipped_ids_rows(
+        arm, steer_arms):
+    """``_pull`` clips its ids and then gathers with ``mode="clip"``:
+    ``jnp.take``'s default would compare every id with the table's bounds
+    again and select, over everything it fetched, between the row and a NaN
+    that no lane can get.  A dead lane (-1) reads row 0, an id past the
+    table its last row, as ever, NaN and -0.0 in the rows and all; and the
+    traced pull holds no select."""
+    from flink_parameter_server_tpu.core import store as store_mod
+
+    narrow = arm != "take"
+    row = (3,) if narrow else (128,)
+    values = _init_values(CAP, row)
+    values[[0, CAP - 1], 0], values[5, 1] = np.nan, -0.0
+    store = ShardedParamStore.create(
+        CAP, row, init_fn=lambda ids: jnp.asarray(values)[ids],
+        update=(lambda current, combined: current + combined) if narrow
+        else "add")
+    spec, table = store.spec, store.table
+    last = spec.padded_capacity - 1
+    ids = jnp.array([[-1, 0, CAP - 1, CAP], [2 ** 31 - 1, -(2 ** 31), 5, 5],
+                     [last, last + 1, 7, -1]], jnp.int32)
+    if arm == "narrow_distinct":
+        steer_arms(pull="narrow_distinct")
+    assert store_mod.arms(spec, pull_lanes=ids.size).pull == arm
+    want = np.asarray(table)[np.clip(ids, 0, last)][..., :row[0]]
+    for pull in (store_mod.pull, store_mod.pull_counted):
+        run = jax.jit(lambda t, i: pull(spec, t, i))
+        got = run(table, ids)
+        rows, left = got if pull is store_mod.pull_counted else (got, None)
+        assert np.array_equal(_bits(rows), _bits(want)), pull.__name__
+        assert (left is not None) == (
+            arm == "narrow_distinct" and pull is store_mod.pull_counted)
+        if left is None:  # (the distinct pull spreads its rows by selects)
+            assert "stablehlo.select" not in run.lower(table, ids).as_text()
+
+
+def test_row_add_permutes_its_rows_with_no_fill_to_the_parents_bits(
+        monkeypatch):
+    """``row_add``'s two permutes take ``mode="clip"`` (``order`` is a
+    sort's permutation of the lanes): the table is the one the filling
+    permutes gave, kept here, bit for bit; a masked lane, a negative id and
+    one past the state sort to the end and their NaN reaches no row."""
+    from flink_parameter_server_tpu.ops import row_update
+
+    monkeypatch.setattr(row_update, "BLOCK", 128)
+    rng = np.random.default_rng(76)
+    rows, n = 32, 100
+    ids = rng.integers(0, rows, n).astype(np.int32)
+    ids[[3, 40]] = [-1, rows]
+    mask = rng.random(n) > 0.2
+    dead = ~mask | (ids < 0) | (ids >= rows)
+    state = rng.normal(size=(rows, 128)).astype(np.float32)
+    deltas = rng.normal(size=(n, 128)).astype(np.float32)
+    old = state[np.clip(ids, 0, rows - 1)]
+    deltas[dead], old[dead] = np.nan, np.nan
+
+    def parents(st, i, o, d, m):
+        sid, order = row_update.sort_by_row(i, m, st.shape[0])
+        return row_update.sorted_row_update(
+            st, sid, jnp.take(o, order, axis=0), jnp.take(d, order, axis=0),
+            interpret=True)
+
+    got, want = jax.jit(lambda *a: (
+        row_update.row_add(*a, interpret=True), parents(*a)))(
+            state, ids, old, deltas, mask)
+    assert np.array_equal(_bits(got), _bits(want))
+    assert np.isfinite(np.asarray(got)).all()
+    touched = np.unique(ids[~dead])
+    assert not np.array_equal(np.asarray(got)[touched], state[touched])

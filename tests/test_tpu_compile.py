@@ -688,6 +688,52 @@ def test_mf_step_default_arm_on_tpu_is_the_row_kernel(mf_tpu_step):
     )
 
 
+def _no_fill_pass_stands_behind_a_bounded_gather(step, rows, temporaries):
+    """``jnp.take``'s default mode compares every id with the table's bounds
+    after the gather and selects, over all it fetched, between the row and a
+    NaN: behind ids the caller has clipped (``core/store._pull``) or a
+    sort's permutation (``ops/row_update.row_add``) that is a read and a
+    write of the whole block for the gather's own output, which
+    ``mode="clip"`` drops (PR 76).  Held in a compiled ``step`` whose batch
+    gathers blocks of ``rows`` 128-lane rows: no instruction the chip
+    executes on its own (one outside every fused computation) selects such a
+    block for a ``jnp.take`` (a select that rides in its consumer costs no
+    pass: the MF logic's own gather of user ids it has NOT bounded keeps
+    one, under ``ps.state_pull``), the pull and the state push trace no fill
+    at all, no synchronous copy of such a block came in the pass's place,
+    and the temporaries stay under ``temporaries`` bytes."""
+    text = step.text
+    block = rf"f32\[{rows},128\]"
+    bodies, fused = _computations(text)
+    fills = [
+        line.strip()[:200] for name, lines in bodies.items()
+        if name not in fused for line in lines
+        if re.search(r'op_name="[^"]*jit\(_take\)/select_n"', line)
+        and re.search(block, line.split("(%", 1)[0])]  # (what it yields)
+    assert not fills, fills
+    assert not re.findall(
+        r'op_name="[^"]*ps\.(?:pull|state_push)/[^"]*jit\(_take\)/'
+        r'(?:select_n|and)"', text)
+    assert not re.findall(rf" = {block}\S* copy\(", text)
+    assert step.memory.temp_size_in_bytes < temporaries
+
+
+def test_the_mf_steps_pull_and_permutes_fill_nothing_behind_their_gathers(
+        mf_tpu_step):
+    """Cells 1, 3 and 11: the two-output ``broadcast_select_fusion
+    f32[65536,128]`` behind ``row_add``'s two permutes (the pass ROADMAP S4
+    counted round the kernel) is gone and the item pull traces no fill.  One
+    of that fusion's outputs lay in the chip's fast memory (``S(1)``); the
+    compiler now brings that block there by an asynchronous copy
+    (``copy-start`` / ``copy-done f32[65536,128]``, in flight across the
+    second permute and the item push), so the temporaries hold one block of
+    the batch more (71.5 -> 104.7 MB)."""
+    step = mf_tpu_step(BATCH)
+    _no_fill_pass_stands_behind_a_bounded_gather(step, BATCH, 0.105 * GB)
+    assert len(re.findall(
+        rf" = f32\[{BATCH},{DIM}\]\S* copy-done\(", step.text)) <= 1
+
+
 @pytest.fixture(scope="module")
 def mf_dp4_step(topo):
     """``staged -> CellStep``: cell 8's step at full size for four
@@ -3164,6 +3210,16 @@ def test_the_chained_step_reads_its_three_tables_where_they_lie(sage_tpu_step):
         "ps_slice_kernel@off"]
 
 
+def test_the_chained_steps_feature_pull_fills_nothing_behind_its_gather(
+        sage_tpu_step):
+    """Cell 18's 806,000 feature rows leave their gather as they are: the
+    parent's ``broadcast_select_fusion f32[806000,128]`` (1.26 ms of 13.17,
+    a read and a write of 413 MB: PERF.md section 6, PR 76) is gone, and the
+    temporaries stand where they stood (0.801 GB)."""
+    _no_fill_pass_stands_behind_a_bounded_gather(
+        sage_tpu_step, 806_000, 0.802 * GB)
+
+
 def _step_text_sha(lowered_text):
     """sha256 of a step's lowered text, which carries no locations but in
     its Mosaic bodies: each stands as the hash of its print without them, so
@@ -3186,7 +3242,7 @@ def _lowered_off_the_tpu(step, *args):
 # where the worker has not built the fixture for a neighbour.
 ON_THE_CHIP = {
     "mf_cells_1_3_11_on_a_tpu": (
-        "mf_tpu_step", (BATCH,), "d3dfc5c70ddec5b1"),
+        "mf_tpu_step", (BATCH,), "97006e03ec6c6b96"),
     "fm_cell_2_on_a_tpu": (
         "fm_step", ("cell_2", "kernels"), "54af7aba7d6f5d8a"),
     "fm_ps4_cell_4_on_four_tpus": (
@@ -3194,11 +3250,11 @@ ON_THE_CHIP = {
     "w2v_cell_5_on_a_tpu": (
         "w2v_step", ("kernels",), "516e264b187449b7"),
     "lr_cell_6_on_a_tpu": (
-        "lr_step", ("kernels",), "4316e6f312ec73b7"),
+        "lr_step", ("kernels",), "786ca19216aec82c"),
     "ft_cell_7_on_a_tpu": (
         "ft_step", None, "f760a7fb1f7744dc"),
     "keyed_mf_cell_8_on_four_tpus": (
-        "mf_dp4_step", ("whole_on_every_chip",), "4c18ed8a28ba52c5"),
+        "mf_dp4_step", ("whole_on_every_chip",), "ac2183a2ec5f1c80"),
     "difacto_cell_9_on_a_tpu": (
         "difacto_step", ("kernels",), "303f9a9ea4e1a64e"),
     "dlrm_cell_10_on_a_tpu": (
@@ -3214,19 +3270,18 @@ ON_THE_CHIP = {
     "dlrm_ps4_cell_16_on_four_tpus": (
         "dlrm_ps4_step", None, "1c69cbd63e3d9cfc"),
     "wdl_cell_17_on_a_tpu": (
-        "wdl_tpu_step", None, "6c8f66f09bc7e7b3"),
-    # (new in PR 75: as that PR's tree lowers it)
+        "wdl_tpu_step", None, "599b072726ea7c20"),
     "sage_cell_18_on_a_tpu": (
-        "sage_tpu_step", None, "be7c94d5fa3142b2"),
+        "sage_tpu_step", None, "a313cf88871cff4a"),
 }
 
 
 @pytest.mark.parametrize("cell, want", [
-    ("mf_cells_1_and_3", "467449ddc73eac39"),
+    ("mf_cells_1_and_3", "213275654bdc84e9"),
     ("fm_cell_2", "715a8a5e1325631a"),
     ("fm_ps4_cell_4", "b1244432ae0312eb"),
-    ("lr_cell_6", "f669bf2e1dddf416"),
-    ("keyed_mf_cell_8", "878ead5a803a932d"),
+    ("lr_cell_6", "9195922d2258936e"),
+    ("keyed_mf_cell_8", "1865234b35f082f2"),
 ] + [(cell, held[2]) for cell, held in ON_THE_CHIP.items()])
 def test_every_cells_step_text_is_the_parents(cell, want, request):
     """The lowered text of a step carries no locations, so a change that
@@ -3318,7 +3373,23 @@ def test_every_cells_step_text_is_the_parents(cell, want, request):
     the rounds' seam (``core/transform._pull_rounds``) and traces the ops
     and the names it traced, and ``arms`` reads a float32 store as it did;
     the twenty-first case, cell 18's step, is new and as that PR's tree
-    lowers it."""
+    lowers it.  PR 76 (``mode="clip"`` on the ``take`` arm, ``_narrow_pull``
+    and ``row_add``'s permutes: a gather whose ids the code has bounded
+    traces no fill) moved eight cases and meant to, each losing in front of
+    the gather the wrap of negative ids and the compares with the table's
+    bounds, behind it the ``select`` against NaN, and nothing else (the
+    gather clamps by itself): ``mf_cells_1_and_3`` (the item pull;
+    ``467449ddc73eac39`` until then), ``lr_cell_6`` (``_narrow_pull``;
+    ``f669bf2e1dddf416``), ``keyed_mf_cell_8`` (the item pull under ``dp``;
+    ``878ead5a803a932d``, and ``47d256f2590f4bc0`` with the mesh's output
+    left out), and as the
+    chip runs them cells 1, 3 and 11 (the item pull and ``row_add``'s two
+    permutes; ``d3dfc5c70ddec5b1``), cell 6 (``4316e6f312ec73b7``), cell 8
+    (``4c18ed8a28ba52c5``), cell 17 (the wide store's ``_narrow_pull``;
+    ``6c8f66f09bc7e7b3``) and cell 18 (``feat``'s pull;
+    ``be7c94d5fa3142b2``); ``fm_cell_2``, ``fm_ps4_cell_4`` and the rest did
+    not move, cell 14's among them (its pull is ``packed_selects``, which
+    gathered with ``mode="clip"`` already)."""
     if cell in ON_THE_CHIP:
         fixture, key, _ = ON_THE_CHIP[cell]
         held = request.getfixturevalue(fixture)
@@ -3369,7 +3440,7 @@ def test_every_cells_step_text_is_the_parents(cell, want, request):
     step = make_train_step(logic, spec)
     assert _step_text_sha(_lowered_off_the_tpu(step, *args)) == want
     before_the_mesh_names = {
-        "fm_ps4_cell_4": "7c6eef68c7b9b162", "keyed_mf_cell_8": "47d256f2590f4bc0"}
+        "fm_ps4_cell_4": "7c6eef68c7b9b162", "keyed_mf_cell_8": "cbe05fad0de5b5ad"}
     if cell in before_the_mesh_names:
 
         def without(*a):

@@ -160,7 +160,10 @@ def test_a_group_of_one_store_trains_the_single_store_s_bits(ids_none):
 # and models/dlrm_dcnv2 (a packed rule store with a worker's part)
 PARENT_JAX = "0.9.0"
 PARENT_TEXT = {
-    "lr": "3373b1a5464e62bcf607f99f021f748943b73b306c6b3ecdf162b98c80a4a7de",
+    # (PR 76: `_narrow_pull` gathers with `mode="clip"`, no fill behind ids
+    # its callers have bounded; 3373b1a5464e62bc... until then.  The other
+    # five trace neither it nor the arm `take` and did not move)
+    "lr": "2b520208de72c39478840bae21c6a682016dd412ef3127e60d0c50edb010ea0c",
     "dcn": "7a042098f1a40890e201b67955b5688bdcff4177b88d2f18b4befde0790c0396",
     # PR 72 (the set kernel copies spans; `_push_rule` carries its counts):
     # the stores whose write-back is NOT `tile_set`, at PR 72's parent
